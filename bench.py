@@ -1,6 +1,8 @@
 """Driver benchmark: flagship-model training MFU on one TPU chip.
 
-Prints ONE JSON line: {"metric", "value", "unit", "vs_baseline", ...}.
+Prints ONE JSON line: {"metric", "value", "unit", "vs_baseline", ...,
+"device": {...}} — on a TPU only. With no TPU, a device whose peak is not in
+_PEAK, or any failed phase, it raises (non-zero exit, no result line).
 vs_baseline is measured MFU / the 45% north-star target (BASELINE.md §ML —
 the reference publishes no in-tree ML numbers; 45% MFU is the driver-set
 target).
@@ -14,11 +16,9 @@ attention-score FLOPs — the PaLM-appendix convention — while the flash
 kernels skip above-diagonal blocks, so the attention term credits ~2x the
 score work actually done (<2% of total FLOPs at this size).
 
-Round-3 sweep note: this shape is a verified local optimum on one v5e
-(16 GB HBM). Denser alternatives all fail at compile for memory —
-B=16/L=2048, B=8/L=4096, and remat_policy="dots" at B>=4 — and
-"dots"@B=2 measures 47.1% vs full-remat@B=8's 48.1% (the recompute
-saved is outweighed by the smaller batch's MXU utilization).
+Shape note: denser alternatives all fail at compile for memory on one v5e
+(16 GB HBM) — B=16/L=2048, B=8/L=4096, remat_policy="dots" at B>=4 and
+remat_policy="selective" at B=8. Not measured on today's code.
 """
 
 from __future__ import annotations
@@ -28,7 +28,10 @@ import sys
 import time
 
 
-# bf16 peak FLOP/s per chip by device kind (public spec sheets).
+# Published bf16 peak FLOP/s per chip, keyed by jax's device_kind (Google
+# Cloud TPU documentation, per-generation system architecture pages). A
+# device that is not in the table is an error, not a default: an MFU over
+# the wrong peak is a wrong number under the right name.
 _PEAK = {
     "TPU v5 lite": 197e12,   # v5e
     "TPU v5": 459e12,        # v5p
@@ -38,11 +41,13 @@ _PEAK = {
 
 
 def _peak_flops(device) -> float:
-    kind = getattr(device, "device_kind", "")
-    for prefix, peak in sorted(_PEAK.items(), key=lambda kv: -len(kv[0])):
-        if kind.startswith(prefix):
-            return peak
-    return 197e12
+    try:
+        return _PEAK[device.device_kind]
+    except KeyError:
+        raise RuntimeError(
+            f"no published bf16 peak for device_kind "
+            f"{device.device_kind!r}; add it to bench._PEAK with its "
+            f"source") from None
 
 
 def _bench_8b_block(jax, llama, make_train_step, optax, dev) -> dict:
@@ -260,64 +265,47 @@ def _bench_sharded_per_host_bytes() -> dict:
 
 
 def main() -> None:
-    import dataclasses
-
     import jax
-    import jax.numpy as jnp
     import optax
 
+    from ray_tpu.accelerators.tpu import require_tpu_device
     from ray_tpu.models import llama
     from ray_tpu.train import make_train_step, profile_train_step
+    from ray_tpu.util import compile_cache
 
-    dev = jax.devices()[0]
-    on_tpu = (dev.platform == "tpu"
-              or getattr(dev, "device_kind", "").startswith("TPU"))
-    if on_tpu:
-        # Chosen by on-chip sweep: wide layers (head_dim 128, 12k ffn) keep
-        # the MXU fed; flash attention (Pallas fwd+bwd) never materializes
-        # [L,L] scores; adafactor frees HBM for the 1.2B-param model.
-        # remat_policy="selective" (save only matmul outputs) first — it
-        # trims the backward recompute that full remat pays; if this shape
-        # doesn't fit (r03 showed dots@B>=4 OOMs), fall back to "full".
-        cfg = llama.LlamaConfig(
-            vocab_size=32000, dim=3072, n_layers=8, n_heads=24,
-            n_kv_heads=12, ffn_dim=12288, attention="flash",
-            remat_policy="selective")
-        B, L, steps, warmup = 8, 2048, 10, 2
-    else:  # CI / no-TPU fallback keeps the contract observable
-        cfg = llama.LlamaConfig.tiny(remat_policy="selective")
-        B, L, steps, warmup = 4, 128, 4, 1
+    compile_cache.configure()
+    dev = require_tpu_device()
+    peak = _peak_flops(dev)
+    # Chosen by on-chip sweep: wide layers (head_dim 128, 12k ffn) keep
+    # the MXU fed; flash attention (Pallas fwd+bwd) never materializes
+    # [L,L] scores; adafactor frees HBM for the 1.2B-param model.
+    # remat_policy="full": at this shape the v5e compiler refuses
+    # "selective" for HBM (18.7 of 15.75 GiB, compiled for the described
+    # chip in PR 21) — no second policy is tried behind a failure.
+    cfg = llama.LlamaConfig(
+        vocab_size=32000, dim=3072, n_layers=8, n_heads=24,
+        n_kv_heads=12, ffn_dim=12288, attention="flash",
+        remat_policy="full")
+    B, L, steps, warmup = 8, 2048, 10, 2
 
-    tuned_blocks = None
-    if cfg.attention == "flash":
-        # eager sweep+cache so every later trace picks the tuned block
-        from ray_tpu.ops import autotune_blocks
-        tuned_blocks = autotune_blocks(L, L, cfg.head_dim, cfg.dtype)
+    # eager sweep+cache so every later trace picks the tuned block
+    from ray_tpu.ops import autotune_blocks
+    tuned_blocks = autotune_blocks(L, L, cfg.head_dim, cfg.dtype)
 
     tokens = jax.random.randint(jax.random.PRNGKey(1), (B, L), 0,
                                 cfg.vocab_size)
 
-    def build_and_warm(cfg):
-        params = llama.init_params(cfg, jax.random.PRNGKey(0))
-        init_fn, step_fn = make_train_step(
-            lambda p, b: llama.loss_fn(p, b, cfg), optax.adafactor(1e-3))
-        opt_state = init_fn(params)
-        t0 = time.perf_counter()
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    init_fn, step_fn = make_train_step(
+        lambda p, b: llama.loss_fn(p, b, cfg), optax.adafactor(1e-3))
+    opt_state = init_fn(params)
+    t0 = time.perf_counter()
+    params, opt_state, m = step_fn(params, opt_state, tokens)
+    float(m["loss"])
+    first_call_s = time.perf_counter() - t0  # compile + one step
+    for _ in range(warmup - 1):
         params, opt_state, m = step_fn(params, opt_state, tokens)
-        float(m["loss"])
-        first_call_s = time.perf_counter() - t0  # compile + one step
-        for _ in range(warmup - 1):
-            params, opt_state, m = step_fn(params, opt_state, tokens)
-        float(m["loss"])  # force sync after warmup
-        return params, opt_state, step_fn, m, first_call_s
-
-    try:
-        params, opt_state, step_fn, m, first_call_s = build_and_warm(cfg)
-    except Exception:  # noqa: BLE001 — selective remat didn't fit/compile
-        if cfg.remat_policy == "full":
-            raise
-        cfg = dataclasses.replace(cfg, remat_policy="full")
-        params, opt_state, step_fn, m, first_call_s = build_and_warm(cfg)
+    float(m["loss"])  # force sync after warmup
 
     # Steps chain through donated buffers, so the final fetch bounds the
     # whole sequence — standard pipelined-dispatch timing.
@@ -326,62 +314,37 @@ def main() -> None:
         params, opt_state, m = step_fn(params, opt_state, tokens)
     final_loss = float(m["loss"])
     dt = time.perf_counter() - t0
-    assert final_loss == final_loss, "NaN loss"
+    if final_loss != final_loss:
+        raise RuntimeError("NaN loss")
 
     tokens_per_sec = B * L * steps / dt
     flops_tok = llama.flops_per_token(cfg, L)
-    mfu = tokens_per_sec * flops_tok / _peak_flops(dev)
+    mfu = tokens_per_sec * flops_tok / peak
     # compile time = first call minus one steady-state step, reported
     # SEPARATELY so warm-up can never leak into the steady-state MFU
     compile_time_s = max(first_call_s - dt / steps, 0.0)
 
-    # per-phase attribution of the same step (fresh non-donating programs;
-    # additive evidence — the headline number above is already banked)
-    try:
-        bd = profile_train_step(
-            lambda p, b: llama.loss_fn(p, b, cfg), optax.adafactor(1e-3),
-            params, opt_state, tokens, steps=3, warmup=1, emit=False)
-        phase_breakdown = {k: round(v, 2) for k, v in bd.phase_ms().items()}
-    except Exception as e:  # noqa: BLE001
-        phase_breakdown = {"error": repr(e)[:160]}
+    # per-phase attribution of the same step (fresh non-donating programs)
+    bd = profile_train_step(
+        lambda p, b: llama.loss_fn(p, b, cfg), optax.adafactor(1e-3),
+        params, opt_state, tokens, steps=3, warmup=1, emit=False)
+    phase_breakdown = {k: round(v, 2) for k, v in bd.phase_ms().items()}
 
-    # async-checkpoint A/B + sharded-save proof (ISSUE 14); additive —
-    # failures here must not cost the headline MFU line
-    try:
-        ckpt_overlap = _bench_checkpoint_overlap(jax)
-    except Exception as e:  # noqa: BLE001
-        ckpt_overlap = {"error": repr(e)[:200]}
-    # child process: the embedded cluster logs READY lines to stdout,
-    # which must not pollute this process's single-JSON-line contract
-    try:
-        import subprocess
-        import tempfile
+    # async-checkpoint A/B (ISSUE 14), in THIS process: it holds the chip,
+    # so nothing here may start another process that wants one. The
+    # sharded-save proof boots a CPU cluster and is its own command
+    # (`python bench.py --sharded-ckpt-proof OUT.json`).
+    ckpt_overlap = _bench_checkpoint_overlap(jax)
+    with open("BENCH_ckpt.json", "w") as f:
+        json.dump({"metric": "checkpoint_overlap_ab", **ckpt_overlap}, f,
+                  indent=1)
 
-        out = tempfile.mktemp(suffix=".json")
-        subprocess.run([sys.executable, __file__,
-                        "--sharded-ckpt-proof", out],
-                       capture_output=True, timeout=300, check=True)
-        ckpt_overlap["sharded"] = json.load(open(out))
-    except Exception as e:  # noqa: BLE001
-        ckpt_overlap["sharded"] = {"error": repr(e)[:200]}
-    try:
-        with open("BENCH_ckpt.json", "w") as f:
-            json.dump({"metric": "checkpoint_overlap_ab",
-                       **ckpt_overlap}, f, indent=1)
-    except OSError:
-        pass
-
-    extra = {}
-    if on_tpu:
-        # free the 1.2B model's buffers first: the B=32 block bench needs
-        # the HBM the headline model occupies
-        del params, opt_state, tokens, step_fn, m
-        import gc
-        gc.collect()
-        try:
-            extra = _bench_8b_block(jax, llama, make_train_step, optax, dev)
-        except Exception as e:  # noqa: BLE001 — 8B-block evidence is
-            extra = {"llama8b_block_error": repr(e)[:200]}  # additive
+    # free the 1.2B model's buffers first: the B=32 block bench needs
+    # the HBM the headline model occupies
+    del params, opt_state, tokens, step_fn, m
+    import gc
+    gc.collect()
+    extra = _bench_8b_block(jax, llama, make_train_step, optax, dev)
     print(json.dumps({
         "metric": "llama_train_mfu_1chip",
         "value": round(mfu * 100, 2),
@@ -393,9 +356,10 @@ def main() -> None:
         "compile_time_s": round(compile_time_s, 2),
         "phase_breakdown_ms": phase_breakdown,
         "remat_policy": cfg.remat_policy,
-        "flash_blocks": list(tuned_blocks) if tuned_blocks else None,
+        "flash_blocks": list(tuned_blocks),
         "n_params": llama.num_params(cfg),
-        "device": str(getattr(dev, "device_kind", dev.platform)),
+        "device": {"platform": dev.platform, "kind": dev.device_kind,
+                   "count": len(jax.devices())},
         "batch": B, "seq_len": L, "optimizer": "adafactor",
         "final_loss": round(final_loss, 3),
         "checkpoint_overlap": ckpt_overlap,
@@ -408,10 +372,6 @@ if __name__ == "__main__":
         with open(sys.argv[2], "w") as f:
             json.dump(_bench_sharded_per_host_bytes(), f)
         sys.exit(0)
-    try:
-        main()
-    except Exception as e:  # noqa: BLE001 — the driver needs a line either way
-        print(json.dumps({"metric": "llama_train_mfu_1chip", "value": 0.0,
-                          "unit": "percent_of_peak_bf16", "vs_baseline": 0.0,
-                          "error": repr(e)[:300]}))
-        sys.exit(1)
+    # no TPU, an unknown device or a failed phase ends in a traceback and
+    # a non-zero exit — never in a result line
+    main()

@@ -1,11 +1,16 @@
 """Build the native C++ runtime library (libray_tpu_native.so).
 
 Invoked lazily on first import of ray_tpu.core._native (and by `make native`).
-Rebuilds when any source is newer than the built .so.
+The built .so is NOT tracked by git: a fresh checkout builds it from src/.
+Staleness is keyed on the CONTENT of the sources and flags (a digest kept
+in a stamp file next to the .so), never on mtimes — a copy of the tree
+does not preserve those, and a binary that does not match src/ must not
+load.
 """
 
 from __future__ import annotations
 
+import hashlib
 import os
 import subprocess
 import sys
@@ -13,6 +18,7 @@ import sys
 _THIS_DIR = os.path.dirname(os.path.abspath(__file__))
 SRC_DIR = os.path.join(_THIS_DIR, "src")
 LIB_PATH = os.path.join(_THIS_DIR, "libray_tpu_native.so")
+STAMP_PATH = LIB_PATH + ".stamp"
 
 SOURCES = [
     "shm_store.cc",
@@ -31,13 +37,23 @@ CXXFLAGS = [
 ]
 
 
+def source_digest() -> str:
+    """sha256 over the compiler flags and every source file's bytes."""
+    h = hashlib.sha256(" ".join(CXXFLAGS).encode())
+    for name in SOURCES:
+        with open(os.path.join(SRC_DIR, name), "rb") as f:
+            h.update(name.encode() + b"\0" + f.read())
+    return h.hexdigest()
+
+
 def needs_build() -> bool:
     if not os.path.exists(LIB_PATH):
         return True
-    lib_mtime = os.path.getmtime(LIB_PATH)
-    return any(
-        os.path.getmtime(os.path.join(SRC_DIR, s)) > lib_mtime for s in SOURCES
-    )
+    try:
+        with open(STAMP_PATH) as f:
+            return f.read().strip() != source_digest()
+    except OSError:
+        return True
 
 
 def build(verbose: bool = False) -> str:
@@ -56,8 +72,12 @@ def build(verbose: bool = False) -> str:
         try:
             if needs_build():
                 tmp = LIB_PATH + f".tmp.{os.getpid()}"
+                digest = source_digest()
                 subprocess.run(base_cmd + ["-o", tmp, "-lrt"], check=True)
                 os.replace(tmp, LIB_PATH)
+                with open(STAMP_PATH + ".tmp", "w") as sf:
+                    sf.write(digest + "\n")
+                os.replace(STAMP_PATH + ".tmp", STAMP_PATH)
         finally:
             fcntl.flock(lf, fcntl.LOCK_UN)
     return LIB_PATH

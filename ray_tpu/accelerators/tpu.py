@@ -12,12 +12,14 @@ python/ray/_private/accelerators/tpu.py:70 — chip-count validation at
    claim a whole ICI slice atomically;
  - leased workers get ``TPU_VISIBLE_CHIPS`` so concurrent workers on one
    host never fight over chips (the TPU runtime allows one owner per chip);
- - detection is env-driven (GKE-style TPU_* variables; the JAX fallback
-   probes local devices) since a metadata server is not assumed.
+ - detection is env-driven (GKE-style TPU_* variables) plus a count of
+   the host's TPU device files — never a jax probe, which would claim
+   the chips — since a metadata server is not assumed.
 """
 
 from __future__ import annotations
 
+import glob
 import os
 from typing import Dict, List, Optional, Tuple
 
@@ -38,45 +40,34 @@ class TPUAcceleratorManager:
     # ------------------------------------------------------------- detection
 
     @staticmethod
-    def detect(allow_jax_probe: bool = False) -> Optional[dict]:
-        """Detect this host's TPU topology.
-
-        Returns {version, pod_type, worker_id, num_chips} or None when the
-        host has no TPU. Sources, in order:
-          1. explicit env (TPU_ACCELERATOR_TYPE / TPU_WORKER_ID) — the
-             GKE/GCE path of the reference;
-          2. only if ``allow_jax_probe``: a live JAX TPU backend. Daemons
-             must NOT probe — initializing the jax TPU backend claims the
-             chips, starving the workers the daemon exists to serve.
-        """
-        accel = os.environ.get("TPU_ACCELERATOR_TYPE")  # e.g. "v5p-16"
-        if accel:
-            version = accel.split("-")[0]
-            worker_id = int(os.environ.get("TPU_WORKER_ID", "0"))
-            num_chips = TPUAcceleratorManager._chips_per_host(accel)
-            return {"version": version, "pod_type": accel,
-                    "worker_id": worker_id, "num_chips": num_chips}
-        if allow_jax_probe:
-            return TPUAcceleratorManager._detect_via_jax()
-        return None
+    def local_chip_count() -> int:
+        """Chips this host can actually open, counted from the device
+        files the TPU driver exposes (``/dev/accel*`` up to v4,
+        ``/dev/vfio/<n>`` from v5e on). Never touches jax: initialising a
+        TPU backend here would claim the chips this count is for."""
+        n = len(glob.glob("/dev/accel*"))
+        return n or sum(os.path.basename(p).isdigit()
+                        for p in glob.glob("/dev/vfio/*"))
 
     @staticmethod
-    def _detect_via_jax() -> Optional[dict]:
-        try:
-            import jax
-            devices = [d for d in jax.devices()
-                       if d.platform not in ("cpu", "gpu")]
-        except Exception:
+    def detect() -> Optional[dict]:
+        """Detect this host's TPU topology without importing jax.
+
+        Returns {version, pod_type, worker_id, num_chips} or None when the
+        host has no TPU. TPU_ACCELERATOR_TYPE / TPU_WORKER_ID (the GKE/GCE
+        path of the reference) name the slice; the chip count is what the
+        host's device files expose when there are any — the env describes
+        the slice a VM belongs to, which can be more than a container on
+        it may open (a one-chip share of a v5litepod-4 host) — and the
+        generation's per-host complement otherwise.
+        """
+        accel = os.environ.get("TPU_ACCELERATOR_TYPE")  # e.g. "v5p-16"
+        if not accel:
             return None
-        if not devices:
-            return None
-        kind = getattr(devices[0], "device_kind", "tpu").lower()
-        version = "v" + "".join(
-            ch for ch in kind.split("v")[-1] if ch.isalnum()) \
-            if "v" in kind else "tpu"
-        n = len(devices)
-        return {"version": version, "pod_type": f"{version}-{n}",
-                "worker_id": 0, "num_chips": n}
+        return {"version": accel.split("-")[0], "pod_type": accel,
+                "worker_id": int(os.environ.get("TPU_WORKER_ID", "0")),
+                "num_chips": TPUAcceleratorManager.local_chip_count()
+                or TPUAcceleratorManager._chips_per_host(accel)}
 
     # full-host chip complement per TPU generation (reference: tpu.py:143
     # topology tables — v2-v4/v5p hosts carry 4 chips, v5e/v6e up to 8)
@@ -158,3 +149,17 @@ class ChipAllocator:
         spawn that failed between allocation and registration)."""
         self.free.extend(chips)
         self.free.sort()
+
+
+def require_tpu_device():
+    """``jax.devices()[0]`` when it is a TPU, else RuntimeError. Measurement
+    paths (bench.py, bench_llm.py) start here: a run that finds no chip
+    fails — it never falls back to timing the CPU under a device metric's
+    name. Imports jax, so never call it from a daemon."""
+    import jax
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        raise RuntimeError(
+            f"no TPU: jax's first device is {dev.platform!r} "
+            f"({dev.device_kind!r}); this measurement only runs on the chip")
+    return dev

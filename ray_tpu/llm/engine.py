@@ -57,6 +57,14 @@ from ray_tpu.models.llama import LlamaConfig, init_params
 from ray_tpu.ops.paged_attention import kernels_supported
 
 
+#: tp=1 weights are BORN on the device by a jitted init (module-level, so
+#: engines with equal configs share the compile). Fused, the f32 draw of a
+#: bf16 weight never exists in HBM — the eager init's largest temporary is
+#: what OOMs an 8B-width model. (The page pool is plain zeros: eager is
+#: already temporary-free.)
+_init_params = jax.jit(init_params, static_argnums=(0,))
+
+
 class _SingleChipFns:
     """tp=1 dispatch: the module-level jits in llm.model (compile cache
     shared across engines with equal shapes), signatures matching
@@ -68,14 +76,27 @@ class _SingleChipFns:
         self._chunk = decode_chunk
         self._max_q = max_q_len
         self._rows = decode_rows
-        self._impl = "kernel" if kernels_supported() else "reference"
+        #: which paged-attention implementation the step programs
+        #: compile: the Pallas kernel on a TPU, the gather reference
+        #: elsewhere — observed, never configured (device_report())
+        self.paged_impl = "kernel" if kernels_supported() else "reference"
+
+    def init_params(self, seed: int):
+        return _init_params(self.cfg, jax.random.PRNGKey(seed))
+
+    def place_params(self, params):
+        return params
+
+    def init_kv(self, total_pages: int, page_size: int, kv_dtype):
+        return make_kv_cache(self.cfg, total_pages, page_size,
+                             kv_dtype=kv_dtype)
 
     def ragged_step(self, params, tokens, token_pos, token_page,
                     token_slot, page_table, q_start, q_len, kv_len, kv):
         return M.ragged_step(params, tokens, token_pos, token_page,
                              token_slot, page_table, q_start, q_len,
                              kv_len, kv, cfg=self.cfg,
-                             paged_impl=self._impl, max_q_len=self._max_q,
+                             paged_impl=self.paged_impl, max_q_len=self._max_q,
                              decode_rows=self._rows)
 
     def decode_loop(self, params, tokens, positions, kv, page_table,
@@ -83,7 +104,7 @@ class _SingleChipFns:
         return M.ragged_decode_loop(params, tokens, positions, kv,
                                     page_table, seq_lens,
                                     num_steps=self._chunk, cfg=self.cfg,
-                                    paged_impl=self._impl)
+                                    paged_impl=self.paged_impl)
 
     def copy_page(self, kv, src, dst):
         return M.copy_page(kv, src, dst)
@@ -93,13 +114,8 @@ class _SingleChipFns:
         module jits share their cache across engines): the O(1) compile
         budget the ragged design promises. In a fresh process running
         one engine this is exactly that engine's program count."""
-        n = 0
-        for f in (M.ragged_step, M.ragged_decode_loop, M.copy_page):
-            try:
-                n += f._cache_size()
-            except AttributeError:    # older jax: count the fn itself
-                n += 1
-        return n
+        return sum(f._cache_size() for f in (
+            M.ragged_step, M.ragged_decode_loop, M.copy_page))
 
 
 class InferenceEngine:
@@ -119,16 +135,14 @@ class InferenceEngine:
                  tp: int = 1, devices=None):
         from ray_tpu.core.config import GlobalConfig
         self.cfg = cfg
-        self.params = params if params is not None \
-            else init_params(cfg, jax.random.PRNGKey(seed))
         self.page_size = page_size
         self.max_batch = max_batch
         self.max_pages_per_seq = -(-max_seq_len // page_size)
         self.eos_token = eos_token
         # tokens decoded per pure-decode dispatch: each dispatch costs a
-        # full host<->device round trip (expensive over PCIe, brutal over
-        # a tunneled chip), so K steps ride one trip (vLLM multi-step
-        # scheduling); finished sequences overshoot at most K-1 tokens
+        # full host<->device round trip, so K steps ride one trip (vLLM
+        # multi-step scheduling); finished sequences overshoot at most
+        # K-1 tokens
         self.decode_chunk = max(1, decode_chunk)
         # scheduler knobs (None -> GlobalConfig llm_* defaults)
         self.prefill_chunk = max(
@@ -157,8 +171,6 @@ class InferenceEngine:
         # (quantized pages + bf16 per-token scales, ~1.9x capacity)
         self.kv_dtype = GlobalConfig.llm_kv_dtype \
             if kv_dtype is None else kv_dtype
-        self.kv = make_kv_cache(cfg, total_pages, page_size,
-                                kv_dtype=self.kv_dtype)
         # tensor parallelism: tp>1 shards weights + kv-heads over a
         # ('tp',) mesh and swaps in shard_map'd programs (llm/tp.py);
         # page allocator / slot bookkeeping below is layout-agnostic
@@ -171,11 +183,14 @@ class InferenceEngine:
                 cfg, self.mesh, decode_chunk=self.decode_chunk,
                 max_q_len=self.prefill_chunk, decode_rows=max_batch,
                 kv_quantized=(self.kv_dtype == "int8"))
-            self.params = self._fns.shard_params(self.params)
-            self.kv = self._fns.shard_caches(self.kv)
         else:
             self._fns = _SingleChipFns(cfg, self.decode_chunk,
                                        self.prefill_chunk, max_batch)
+        # weights and pool are created IN their final layout (sharded
+        # over the mesh under tp): no device ever stages the whole model
+        self.params = self._fns.init_params(seed) if params is None \
+            else self._fns.place_params(params)
+        self.kv = self._fns.init_kv(total_pages, page_size, self.kv_dtype)
         # XLA compile tracker seam (util/compile_tracker.py): the three
         # step entry points are wrapped so every compile is recorded
         # with its arg signature — ground truth the O(1)-compile
@@ -307,6 +322,40 @@ class InferenceEngine:
         """Compiled step programs resident for this engine's step fns
         (O(1) by design: mixed ragged step, decode loop, COW copy)."""
         return self._fns.compiled_step_programs()
+
+    def device_report(self) -> Dict[str, object]:
+        """Where this engine runs and what it compiled: the devices that
+        hold its weights, the paged-attention implementation its step
+        programs took ("kernel" | "reference" — chosen from the platform,
+        so a deployment can assert it never fell back), the resident
+        step-program count, and per device the bytes of weights + KV
+        pages it holds next to the allocator's own ``memory_stats()``
+        (None on backends that keep none, i.e. the CPU)."""
+        leaves = jax.tree.leaves((self.params, self.kv))
+        held: Dict[object, int] = {}
+        for leaf in leaves:
+            for shard in leaf.addressable_shards:
+                held[shard.device] = held.get(shard.device, 0) \
+                    + shard.data.nbytes
+        devices = sorted(held, key=lambda d: d.id)
+        per_device = []
+        for d in devices:
+            ms = d.memory_stats() or {}
+            per_device.append({
+                "id": d.id, "engine_bytes": held[d],
+                "bytes_in_use": ms.get("bytes_in_use"),
+                "peak_bytes_in_use": ms.get("peak_bytes_in_use"),
+                "bytes_limit": ms.get("bytes_limit")})
+        return {"platform": devices[0].platform,
+                "device_kind": devices[0].device_kind,
+                "device_count": len(jax.devices()),
+                "tp": self.tp,
+                "paged_impl": self._fns.paged_impl,
+                "compiled_step_programs": self.compiled_step_programs(),
+                "param_bytes": sum(x.nbytes
+                                   for x in jax.tree.leaves(self.params)),
+                "kv_bytes": sum(x.nbytes for x in self.kv.values()),
+                "devices": per_device}
 
     # ---------------------------------------------------------------- step
 

@@ -332,6 +332,39 @@ class LLMServer:
             }
         return out
 
+    def engine_report(self) -> Dict[str, Any]:
+        """What this replica runs on and what it compiled (the engine's
+        device_report()), plus this process's compile accounting: the
+        tracker's per-kind counts — persistent-cache hits and misses
+        among them — the seconds spent compiling, and where the
+        persistent compile cache lives."""
+        import os
+
+        from ray_tpu.util import compile_cache, compile_tracker
+        out = self.engine.device_report()
+        tracker = compile_tracker.get_global()
+        if tracker is not None:
+            out["compile_counts"] = tracker.stats()["counts"]
+            out["compile_seconds"] = {
+                name: round((tracker.callable_stats(name)
+                             or {}).get("wall_s", 0.0), 3)
+                for name in ("llm.ragged_step", "llm.decode_loop",
+                             "llm.copy_page")}
+        out["compile_cache_dir"] = os.environ.get(compile_cache.ENV_VAR)
+        out["pid"] = os.getpid()
+        return out
+
+    def plain_check(self, request: Dict[str, Any]) -> Dict[str, Any]:
+        """Score {"prompt_ids", "token_ids"} — a greedy continuation some
+        engine produced — against the plain forward path on THIS
+        replica's weights (llm.model.plain_greedy_check)."""
+        from ray_tpu.llm.model import plain_greedy_check
+        eng = self.engine
+        return plain_greedy_check(
+            eng.params, eng.cfg, list(request["prompt_ids"]),
+            list(request["token_ids"]),
+            seq_len=eng.max_pages_per_seq * eng.page_size)
+
     def request_records(self) -> List[Dict[str, Any]]:
         """Flight-recorder snapshot of this replica's engine (wire
         dicts; [] when the recorder is disabled). The same records ship
@@ -413,17 +446,18 @@ def build_llm_app(model_config: Optional[Dict[str, Any]] = None,
                   name: str = "llm", num_replicas: int = 1,
                   max_ongoing_requests: int = 16,
                   runtime_env: Optional[Dict[str, Any]] = None,
-                  use_tpu_resources: Optional[bool] = None,
                   model_name: str = "rtpu-llm"):
     """Bind an LLMServer deployment whose replica resources are DERIVED
     from the engine's tensor-parallel degree (reference: the LLM
     deployment's placement-group shorthand, vllm_models.py:128-153).
 
-    tp > 1 replicas reserve a {"TPU": tp} gang on one host — the engine
-    process drives all tp chips through one jax Mesh, so the gang and
-    the mesh are the same object. ``use_tpu_resources=False`` (or
-    leaving it None on a TPU-less test cluster... pass False) skips the
-    chip reservation so CPU-mesh tests can deploy the sharded engine.
+    Every replica reserves a {"TPU": tp} gang on one host — a replica
+    that runs a model holds its chips alone, tp == 1 included: a chip has
+    one owner, and only a worker leased with TPU resources may see one.
+    The engine process drives all tp chips through one jax Mesh, so the
+    gang and the mesh are the same object. (CPU test clusters advertise
+    fake ``TPU`` resources and give the replica virtual devices through
+    ``runtime_env``.)
 
     A tp-group larger than one host's chips needs one engine process
     per host under ``jax.distributed`` — not served by this builder;
@@ -432,22 +466,17 @@ def build_llm_app(model_config: Optional[Dict[str, Any]] = None,
     """
     from ray_tpu import serve as serve_mod
     engine_config = dict(engine_config or {})
-    tp = int(engine_config.get("tp", 1))
-    ray_actor_options: Dict[str, Any] = {}
-    if use_tpu_resources is None:
-        use_tpu_resources = tp > 1
-    if tp > 1 and use_tpu_resources:
-        bundles, strategy = placement_for_engine(tp)
-        if len(bundles) > 1:
-            raise NotImplementedError(
-                "tp groups spanning hosts need one engine process per "
-                "host (jax.distributed); shard within one host's chips "
-                "or raise chips_per_host")
-        ray_actor_options["resources"] = bundles[0]
+    bundles, _ = placement_for_engine(int(engine_config.get("tp", 1)))
+    if len(bundles) > 1:
+        raise NotImplementedError(
+            "tp groups spanning hosts need one engine process per "
+            "host (jax.distributed); shard within one host's chips "
+            "or raise chips_per_host")
+    ray_actor_options: Dict[str, Any] = {"resources": bundles[0]}
     if runtime_env:
         ray_actor_options["runtime_env"] = runtime_env
     dep = serve_mod.deployment(
         name=name, num_replicas=num_replicas,
         max_ongoing_requests=max_ongoing_requests,
-        ray_actor_options=ray_actor_options or None)(LLMServer)
+        ray_actor_options=ray_actor_options)(LLMServer)
     return dep.bind(model_config, engine_config, None, model_name)

@@ -25,6 +25,7 @@ Layout (classic Megatron, weights arrive pre-sliced inside shard_map):
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Sequence
 
 import jax
@@ -78,31 +79,12 @@ def validate_tp(cfg: LlamaConfig, tp: int) -> None:
             f"n_kv_heads={cfg.n_kv_heads}")
 
 
-def _default_devices():
-    """jax.devices(), honoring an explicit JAX_PLATFORMS env override.
-
-    Cluster worker processes can have the platform pinned at the
-    jax.config level by ambient site hooks (so the env var loses the
-    DEFAULT-backend vote), but an explicitly requested backend is always
-    reachable — this is what lets a deployment's runtime_env
-    {"JAX_PLATFORMS": "cpu", "XLA_FLAGS": ...device_count=N} give its
-    replica an N-device virtual mesh on test clusters."""
-    import os
-    first = os.environ.get("JAX_PLATFORMS", "").split(",")[0].strip()
-    if first:
-        try:
-            return jax.devices(first)
-        except RuntimeError:
-            pass
-    return jax.devices()
-
-
 def build_tp_mesh(tp: int,
                   devices: Optional[Sequence[jax.Device]] = None) -> Mesh:
     """1-D ('tp',) mesh over the first tp devices — adjacent ICI
     neighbours on TPU (jax.devices() is torus-ordered)."""
     import numpy as np
-    devices = list(devices if devices is not None else _default_devices())
+    devices = list(devices if devices is not None else jax.devices())
     if len(devices) < tp:
         raise ValueError(f"tp={tp} needs {tp} devices, have {len(devices)}")
     return Mesh(np.asarray(devices[:tp]), (TP_AXIS,))
@@ -126,13 +108,13 @@ class TPEngineFns:
         self.tp = mesh.shape[TP_AXIS]
         pspecs = tp_param_specs(cfg)
         rep = P()
-        kvs = kv_specs(kv_quantized)
+        kvs = self._kv_specs = kv_specs(kv_quantized)
 
         # the kernel/reference choice follows the MESH platform, not the
         # process default backend — a CPU test mesh inside a TPU-default
         # worker must take the gather reference, and vice versa
         from ray_tpu.ops.paged_attention import kernels_supported
-        paged_impl = "kernel" \
+        paged_impl = self.paged_impl = "kernel" \
             if kernels_supported(mesh.devices.flat[0]) else "reference"
 
         def step(params, tokens, token_pos, token_page, token_slot,
@@ -169,28 +151,37 @@ class TPEngineFns:
             in_specs=(kvs, rep, rep),
             out_specs=kvs),
             donate_argnums=(0,))
+        # the jits themselves, for the program count: the engine rebinds
+        # the three attributes above to compile-tracker wrappers
+        self._jits = (self.ragged_step, self.decode_loop, self.copy_page)
 
     def compiled_step_programs(self) -> int:
         """Resident compiled step programs for this mesh's fns."""
-        n = 0
-        for f in (self.ragged_step, self.decode_loop, self.copy_page):
-            try:
-                n += f._cache_size()
-            except AttributeError:
-                n += 1
-        return n
+        return sum(f._cache_size() for f in self._jits)
 
     # ------------------------------------------------------------ placement
+    # Weights and pool are created sharded (jit out_shardings): staging
+    # them whole on one device first would cap the model at ONE chip's
+    # HBM — the very limit tp exists to lift.
 
-    def shard_params(self, params: Params) -> Params:
-        shardings = jax.tree.map(
-            lambda s: NamedSharding(self.mesh, s), tp_param_specs(self.cfg),
-            is_leaf=lambda x: isinstance(x, P))
-        return jax.tree.map(jax.device_put, params, shardings)
+    def _shardings(self, specs):
+        return jax.tree.map(lambda s: NamedSharding(self.mesh, s), specs,
+                            is_leaf=lambda x: isinstance(x, P))
 
-    def shard_caches(self, kv: dict) -> dict:
-        return {name: jax.device_put(
-            leaf, NamedSharding(self.mesh,
-                                SCALE_SPEC if name.endswith("_scale")
-                                else CACHE_SPEC))
-            for name, leaf in kv.items()}
+    def init_params(self, seed: int) -> Params:
+        from ray_tpu.models.llama import init_params
+        return jax.jit(
+            functools.partial(init_params, self.cfg),
+            out_shardings=self._shardings(tp_param_specs(self.cfg)))(
+                jax.random.PRNGKey(seed))
+
+    def place_params(self, params: Params) -> Params:
+        return jax.device_put(
+            params, self._shardings(tp_param_specs(self.cfg)))
+
+    def init_kv(self, total_pages: int, page_size: int, kv_dtype) -> dict:
+        from ray_tpu.llm.cache import make_kv_cache
+        return jax.jit(
+            functools.partial(make_kv_cache, self.cfg, total_pages,
+                              page_size, kv_dtype=kv_dtype),
+            out_shardings=self._shardings(self._kv_specs))()

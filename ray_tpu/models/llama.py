@@ -257,16 +257,25 @@ def forward(params: Params, tokens: jax.Array, cfg: LlamaConfig,
                       preferred_element_type=jnp.float32)
 
 
+def flash_impl() -> str:
+    """What attention="flash" resolves to in this process: "kernel" (the
+    Pallas fwd+bwd kernels, on a TPU) or "blockwise" (the portable
+    lax.scan equivalent, anywhere else). Chosen from the platform, never
+    configured — a training loop reports it so a chip run can assert it
+    did not take the portable branch."""
+    from ray_tpu.ops.flash_attention import kernels_supported
+    return "kernel" if kernels_supported() else "blockwise"
+
+
 def _make_attn_fn(cfg: LlamaConfig, mesh):
     if cfg.attention == "full":
         return _full_attention
     if cfg.attention == "flash":
         from ray_tpu.ops import flash_attention
         from ray_tpu.ops.flash_attention import (blockwise_attention,
-                                                 flash_attention_sharded,
-                                                 kernels_supported)
-        if not kernels_supported():
-            # Portable fallback (CPU test meshes): same blockwise numerics.
+                                                 flash_attention_sharded)
+        if flash_impl() == "blockwise":
+            # CPU test meshes: same blockwise numerics.
             return lambda q, k, v: blockwise_attention(q, k, v).astype(q.dtype)
         if mesh is not None:
             return functools.partial(flash_attention_sharded, mesh=mesh)
@@ -352,9 +361,9 @@ def _loss_overlap(params: Params, tokens: jax.Array, cfg: LlamaConfig,
     """fsdp_overlap=True loss: full-manual shard_map over (dp, fsdp) with
     the prefetch-scheduled layer scan (parallel.fsdp_overlap) instead of
     GSPMD-placed gathers. Numerics match loss_fn exactly (parity-tested);
-    only the collective schedule differs. Requires pp == sp == tp == 1 —
-    jax 0.4.x shard_map_compat degrades partial-manual to full manual,
-    so every other parallelism axis must be trivial here.
+    only the collective schedule differs. Requires pp == sp == tp == 1:
+    the block is manual over EVERY mesh axis and only (dp, fsdp) appear
+    in its specs, so any other axis would just replicate the work.
     """
     from ray_tpu.parallel.fsdp_overlap import (drop_leading_dim,
                                                gather_params, overlap_scan,

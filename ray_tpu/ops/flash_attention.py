@@ -26,12 +26,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = float("-inf")
 
@@ -409,10 +405,17 @@ def _est_vmem_bytes(blk_q: int, blk_k: int, D: int, itemsize: int) -> int:
 def block_candidates(Lq: int, Lk: int, head_dim: int,
                      dtype=jnp.bfloat16) -> list:
     """(blk_q, blk_k) pairs that divide the sequence lengths, respect the
-    Mosaic >= 8 floor, and fit the VMEM model — heuristic-best first
-    (closest to the classic 256x256 flash block)."""
+    Mosaic tiling, and fit the VMEM model — heuristic-best first
+    (closest to the classic 256x256 flash block).
+
+    Tiling: blk_k sits on a sublane dimension (>= 8 floor). blk_q also
+    sits on the LANE dimension of the lse/delta blocks ``(1, 8, blk_q)``,
+    which the TPU lowering only accepts in multiples of 128 (or the whole
+    length) — every blk_q in 8..64 is refused for L=2048 (compiled for a
+    described v5e, tests/test_tpu_compile.py)."""
     itemsize = jnp.dtype(dtype).itemsize
-    qs = [b for b in _BLOCK_SIZES if b <= Lq and Lq % b == 0]
+    qs = [b for b in _BLOCK_SIZES if b <= Lq and Lq % b == 0
+          and (b % 128 == 0 or b == Lq)]
     ks = [b for b in _BLOCK_SIZES if b <= Lk and Lk % b == 0]
     pairs = [(bq, bk) for bq in qs for bk in ks
              if _est_vmem_bytes(bq, bk, head_dim, itemsize) <= _VMEM_BUDGET]
@@ -423,30 +426,28 @@ def block_candidates(Lq: int, Lk: int, head_dim: int,
 def _time_blocks(Lq, Lk, D, dtype, blk_q, blk_k, *, bh: int = 8,
                  reps: int = 3) -> float:
     """Wall-time one candidate: fwd kernel + both bwd kernels, jitted,
-    median-of-reps. Returns +inf when the candidate fails to compile."""
+    median-of-reps. Raises what the compiler raises for a candidate it
+    refuses."""
     import time as _time
-    try:
-        ks = jax.random.split(jax.random.PRNGKey(0), 4)
-        q = jax.random.normal(ks[0], (bh, Lq, D), dtype)
-        k = jax.random.normal(ks[1], (bh, Lk, D), dtype)
-        v = jax.random.normal(ks[2], (bh, Lk, D), dtype)
-        do = jax.random.normal(ks[3], (bh, Lq, D), dtype)
-        scale = D ** -0.5
-        fwd = jax.jit(lambda q, k, v: _fwd_call(
-            q, k, v, True, scale, blk_q, blk_k, False))
-        bwd = jax.jit(lambda q, k, v, o, lse, do: _bwd_call(
-            q, k, v, o, lse, do, True, scale, blk_q, blk_k, False))
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    q = jax.random.normal(ks[0], (bh, Lq, D), dtype)
+    k = jax.random.normal(ks[1], (bh, Lk, D), dtype)
+    v = jax.random.normal(ks[2], (bh, Lk, D), dtype)
+    do = jax.random.normal(ks[3], (bh, Lq, D), dtype)
+    scale = D ** -0.5
+    fwd = jax.jit(lambda q, k, v: _fwd_call(
+        q, k, v, True, scale, blk_q, blk_k, False))
+    bwd = jax.jit(lambda q, k, v, o, lse, do: _bwd_call(
+        q, k, v, o, lse, do, True, scale, blk_q, blk_k, False))
+    o, lse = fwd(q, k, v)
+    jax.block_until_ready(bwd(q, k, v, o, lse, do))  # warm both
+    times = []
+    for _ in range(reps):
+        t0 = _time.perf_counter()
         o, lse = fwd(q, k, v)
-        jax.block_until_ready(bwd(q, k, v, o, lse, do))  # warm both
-        times = []
-        for _ in range(reps):
-            t0 = _time.perf_counter()
-            o, lse = fwd(q, k, v)
-            jax.block_until_ready(bwd(q, k, v, o, lse, do))
-            times.append(_time.perf_counter() - t0)
-        return sorted(times)[len(times) // 2]
-    except Exception:  # noqa: BLE001 — a failing candidate just loses
-        return float("inf")
+        jax.block_until_ready(bwd(q, k, v, o, lse, do))
+        times.append(_time.perf_counter() - t0)
+    return sorted(times)[len(times) // 2]
 
 
 def autotune_blocks(Lq: int, Lk: Optional[int] = None, head_dim: int = 64,
@@ -457,10 +458,12 @@ def autotune_blocks(Lq: int, Lk: Optional[int] = None, head_dim: int = 64,
 
     measure=None → sweep-and-time only where the Mosaic kernels actually
     lower (real TPU; CPU hosts rank heuristically — timing interpret mode
-    would measure the emulator, not the kernel). Returns None when no
-    block >= the Mosaic floor divides the lengths (callers fall back to
-    the einsum/blockwise path). Call this EAGERLY (e.g. bench warm-up)
-    so jit traces hit the cache via get_tuned_blocks."""
+    would measure the emulator, not the kernel). A candidate the compiler
+    refuses just loses the sweep; if EVERY candidate is refused the
+    kernels do not work here and that is an error, not a pick. Returns
+    None when no block >= the Mosaic floor divides the lengths (callers
+    fall back to the einsum/blockwise path). Call this EAGERLY (e.g.
+    bench warm-up) so jit traces hit the cache via get_tuned_blocks."""
     Lk = Lq if Lk is None else Lk
     key = _block_cache_key(Lq, Lk, head_dim, dtype)
     if key in _BLOCK_CACHE:
@@ -472,8 +475,18 @@ def autotune_blocks(Lq: int, Lk: Optional[int] = None, head_dim: int = 64,
         measure = kernels_supported()
     best = cands[0]
     if measure and len(cands) > 1:
-        best = min(cands, key=lambda bk: _time_blocks(
-            Lq, Lk, head_dim, dtype, *bk))
+        timed, last_err = {}, None
+        for bk in cands:
+            try:
+                timed[bk] = _time_blocks(Lq, Lk, head_dim, dtype, *bk)
+            except Exception as e:  # noqa: BLE001 — this candidate loses
+                last_err = e
+        if not timed:
+            raise RuntimeError(
+                f"flash autotune: all {len(cands)} block candidates for "
+                f"Lq={Lq} Lk={Lk} head_dim={head_dim} failed to compile "
+                f"or run") from last_err
+        best = min(timed, key=timed.get)
     _BLOCK_CACHE[key] = best
     return best
 
@@ -518,11 +531,7 @@ flash_attention_block.defvjp(_block_vjp_fwd, _block_vjp_bwd)
 
 def kernels_supported() -> bool:
     """True when the Mosaic TPU kernels can actually lower here."""
-    if not _HAS_PALLAS:
-        return False
-    dev = jax.devices()[0]
-    return dev.platform == "tpu" or getattr(dev, "device_kind",
-                                            "").startswith("TPU")
+    return jax.devices()[0].platform == "tpu"
 
 
 def flash_attention_sharded(q, k, v, mesh, *, causal: bool = True,

@@ -42,12 +42,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = float("-inf")
 
@@ -178,11 +174,8 @@ def _paged_attention_pallas(q, k_pages, v_pages, page_table, seq_lens,
 
 
 def kernels_supported(device: Optional[jax.Device] = None) -> bool:
-    if not _HAS_PALLAS:
-        return False
     dev = device if device is not None else jax.devices()[0]
-    return dev.platform == "tpu" or getattr(dev, "device_kind",
-                                            "").startswith("TPU")
+    return dev.platform == "tpu"
 
 
 def paged_attention(q, k_pages, v_pages, page_table, seq_lens, *,

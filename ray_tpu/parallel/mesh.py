@@ -168,34 +168,19 @@ def build_mesh(spec: MeshSpec,
 
 
 def shard_map_compat(fn, mesh: Mesh, in_specs, out_specs, axis_names=None):
-    """shard_map across jax versions (check_rep → check_vma rename).
+    """``jax.shard_map`` with the replication (vma) check off — the
+    kernels and hand-written collectives inside these blocks carry no
+    varying-axis annotations.
 
     axis_names: optional set of mesh axes to treat as MANUAL; the rest stay
     auto (GSPMD keeps sharding them) — used to run the pipeline/ring loops
-    manually while fsdp/tp remain compiler-managed.
-
-    Only jax>=0.8's native axis_names= form is used for partial-manual.
-    The old experimental `auto=` spelling miscompiles on jax 0.4.x GSPMD
-    (manual-subgroup CHECK aborts in the SPMD partitioner, PartitionId
-    UNIMPLEMENTED for axis_index) so we degrade to FULL manual instead:
-    axes the specs don't mention become replicated rather than
-    compiler-sharded — same results, redundant compute on those axes.
+    manually while fsdp/tp remain compiler-managed. None = every mesh axis
+    is manual.
     """
-    try:
-        from jax import shard_map as _sm
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map as _sm
-    partial_variants = [{}]
-    if axis_names is not None:
-        partial_variants = [{"axis_names": set(axis_names)}, {}]
-    for extra in partial_variants:
-        for kw in ({"check_vma": False}, {"check_rep": False}, {}):
-            try:
-                return _sm(fn, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kw, **extra)
-            except TypeError:
-                continue
-    raise RuntimeError("no compatible shard_map signature found")
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs,
+                         axis_names=frozenset(axis_names or ()),
+                         check_vma=False)
 
 
 def named_sharding(mesh: Mesh, *axes) -> NamedSharding:
@@ -206,5 +191,3 @@ def named_sharding(mesh: Mesh, *axes) -> NamedSharding:
 def shard_constraint(x, mesh: Mesh, *axes):
     """with_sharding_constraint under an explicit mesh (no-op outside jit)."""
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, P(*axes)))
-
-

@@ -1673,6 +1673,10 @@ def connect_or_start(worker, address: Optional[str] = None,
                      namespace: str = "default") -> Dict[str, Any]:
     owned: list = []
     if address is None:
+        # a fresh checkout has no native library yet: build it here, not
+        # inside the daemons' startup deadlines
+        from ray_tpu._native.build import build as build_native
+        build_native()
         session = os.urandom(4).hex()
         # the driver's own log plane (and any process it spawns) files
         # under the same session log directory as the daemons
@@ -1690,19 +1694,27 @@ def connect_or_start(worker, address: Optional[str] = None,
         # wait until the node registers
         probe = RpcClient(address, name="probe")
         deadline = time.monotonic() + 30.0
-        while time.monotonic() < deadline:
-            if node_proc.poll() is not None:
-                raise RuntimeError(
-                    f"node daemon exited rc={node_proc.returncode}")
-            try:
-                if any(n["alive"] for n in probe.call("list_nodes")):
-                    break
-            except RpcError:
-                pass
-            time.sleep(0.05)
-        else:
-            raise RuntimeError("node daemon never registered")
-        probe.close()
+        try:
+            while time.monotonic() < deadline:
+                if node_proc.poll() is not None:
+                    raise RuntimeError(
+                        f"node daemon exited rc={node_proc.returncode}")
+                try:
+                    if any(n["alive"] for n in probe.call("list_nodes")):
+                        break
+                except RpcError:
+                    pass
+                time.sleep(0.05)
+            else:
+                raise RuntimeError("node daemon never registered")
+        except BaseException:
+            # a boot that fails half way must not leave its head (or a
+            # wedged node) behind: nobody else holds these processes
+            for proc in reversed(owned):
+                proc.kill()
+            raise
+        finally:
+            probe.close()
 
     backend = ClusterBackend.connect_as_driver(worker, address,
                                                owned_procs=owned)

@@ -112,12 +112,16 @@ def read_cgroup_pressure(cg_dir: str, which: str = "cpu") -> Optional[float]:
 
 
 def tpu_memory_samples() -> List[Sample]:
-    """HBM used/limit per local TPU device — ONLY when jax is already
-    live in this process (never imports it; importing here would claim
-    the chips and is meaningless on CPU anyway)."""
+    """HBM used/limit per local TPU device — ONLY when this process has
+    already initialised a jax backend. An imported-but-unused jax (a
+    driver that did ``from ray_tpu import train``) must stay untouched:
+    ``jax.local_devices()`` would initialise the backend and claim the
+    chip the train worker needs."""
     import sys
     jax = sys.modules.get("jax")
-    if jax is None:
+    bridge = sys.modules.get("jax._src.xla_bridge")
+    if jax is None or bridge is None \
+            or not bridge.backends_are_initialized():
         return []
     out: List[Sample] = []
     try:

@@ -316,6 +316,11 @@ class NodeDaemon:
         from ray_tpu.runtime.spawn import child_env
         extra = {"RTPU_SESSION": self.session,
                  "RTPU_NODE_ID": getattr(self, "node_id", "")}
+        if chips is None and getattr(self, "chips", None) is not None:
+            # a chip has one owner: on a TPU host only workers leased
+            # with TPU resources may see it, so every other worker's jax
+            # is held to the CPU (the ambient default there is the TPU)
+            extra["JAX_PLATFORMS"] = "cpu"
         if env_extra:
             extra.update(env_extra)
         env = child_env(extra)

@@ -839,6 +839,14 @@ def main() -> None:
     except Exception:
         pass
 
+    from ray_tpu.accelerators.tpu import TPU_VISIBLE_CHIPS_ENV
+    if os.environ.get(TPU_VISIBLE_CHIPS_ENV):
+        # leased chips: whatever this worker compiles for them goes
+        # through the persistent compile cache (jax not imported yet —
+        # this only places the directory)
+        from ray_tpu.util import compile_cache
+        compile_cache.configure()
+
     from ray_tpu.core.worker import global_worker
     from ray_tpu.runtime.cluster_backend import ClusterBackend
 

@@ -57,17 +57,6 @@ def setup_jax_worker(dist: Dict[str, Any]) -> None:
         return  # worker reuse within one group/restart
     import jax
     if dist["num_processes"] > 1:
-        if platform == "cpu" or dist.get("platform") is None \
-                and os.environ.get("JAX_PLATFORMS", "") == "cpu":
-            # multiprocess CPU collectives need the gloo backend (jax
-            # >= 0.4.34 defaults to none and raises "Multiprocess
-            # computations aren't implemented on the CPU backend");
-            # must be set before the backend initializes
-            try:
-                jax.config.update(
-                    "jax_cpu_collectives_implementation", "gloo")
-            except Exception:  # noqa: BLE001 — older jax: flag absent,
-                pass           # collectives work without it
         # NOTE: must run before ANY backend query (even
         # jax.process_count() would initialize a single-process backend
         # and the later initialize() could not register remote devices)

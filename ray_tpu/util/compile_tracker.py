@@ -18,8 +18,8 @@ Two observation paths feed the ring:
 
 - a lazily registered ``jax.monitoring`` duration/event listener pair
   picks up the ``/jax/core/compile/*`` pipeline phases (jaxpr trace,
-  MLIR lowering, backend compile) and compilation-cache misses that
-  XLA itself reports;
+  MLIR lowering, backend compile) and the persistent compilation
+  cache's hits and misses that XLA itself reports;
 - ``CompileTracker.wrap(fn)`` — the jit cache-miss seam — wraps a
   jitted callable and detects compiles by cache growth (via the jit's
   own ``_cache_size`` probe) or signature novelty, attributing the
@@ -51,6 +51,9 @@ from typing import Any, Callable, Dict, List, Optional, Sequence
 
 _COMPILE_EVENT_PREFIX = "/jax/core/compile/"
 _CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+#: in-flight accumulator key (not a phase: kept out of measured seconds)
+_HITS_KEY = "_persistent_cache_hits"
 
 # distinct callables tracked per process (LRU beyond this)
 _MAX_CALLABLES = 256
@@ -264,6 +267,13 @@ class CompileTracker:
         now = time.time()
         sig = [str(s) for s in signature]
         phases = dict(phases or {})
+        # a program LOADED from the persistent compilation cache still
+        # grows the jit's own cache (so it counts as one of this
+        # callable's compiled programs — the O(1)-program invariant is
+        # about resident executables, however they got here); the flag
+        # tells a cold compile from a warm load, whose "backend_compile"
+        # seconds are the cache retrieval
+        cache_hit = bool(phases.pop(_HITS_KEY, 0))
         measured = round(sum(phases.values()), 6)
         if not backend:
             backend = os.environ.get("JAX_PLATFORMS", "") or ""
@@ -300,7 +310,7 @@ class CompileTracker:
                    "backend": backend, "pid": self.pid,
                    "trace_id": ctx[0] if ctx else "",
                    "recompile": recompile, "diff": diff,
-                   "nth": st["compiles"]}
+                   "nth": st["compiles"], "cache_hit": cache_hit}
             self._append_locked(rec)
             self._counts[kind] = self._counts.get(kind, 0) + 1
             if recompile:
@@ -349,9 +359,16 @@ class CompileTracker:
             pass
 
     def note_cache_miss(self) -> None:
+        """Persistent compilation cache: compiled, then written."""
         with self._lock:
             self._counts["cache_miss"] = \
                 self._counts.get("cache_miss", 0) + 1
+
+    def note_cache_hit(self) -> None:
+        """Persistent compilation cache: loaded, not compiled."""
+        with self._lock:
+            self._counts["cache_hit"] = \
+                self._counts.get("cache_hit", 0) + 1
 
     def _append_locked(self, rec: dict) -> None:
         self._emitted_total += 1
@@ -490,11 +507,15 @@ def _on_jax_duration(event: str, duration: float, **_kw) -> None:
 
 
 def _on_jax_event(event: str, **_kw) -> None:
-    if event != _CACHE_MISS_EVENT:
+    if event not in (_CACHE_MISS_EVENT, _CACHE_HIT_EVENT):
         return
+    hit = event == _CACHE_HIT_EVENT
+    stack = getattr(_tls, "inflight", None)
+    if hit and stack:
+        stack[-1][_HITS_KEY] = stack[-1].get(_HITS_KEY, 0) + 1
     tracker = get_global()
     if tracker is not None:
-        tracker.note_cache_miss()
+        tracker.note_cache_hit() if hit else tracker.note_cache_miss()
 
 
 def _maybe_hook_jax() -> bool:

@@ -228,6 +228,59 @@ def test_wrap_attributes_inflight_monitoring_durations():
     assert rec["duration_s"] > 0
 
 
+def test_program_loaded_from_persistent_cache_stays_truthful(tmp_path):
+    """A program LOADED from jax's persistent compilation cache instead
+    of compiled: the jit's own cache still grows (the engine's
+    compiled-program invariant counts resident executables, however they
+    got here), the wrap seam still records it as this callable's
+    compile — flagged cache_hit, its "backend" seconds being the
+    retrieval — and the hit/miss counts tell a cold process from a warm
+    one. Real jax, real cache, on the CPU backend."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    saved = {k: getattr(jax.config, k) for k in (
+        "jax_enable_compilation_cache", "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    ct.stop_global()
+    try:
+        jax.config.update("jax_enable_compilation_cache", True)
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+        cc.reset_cache()
+        tr = ct.ensure_started(role="t")
+        assert tr is not None
+
+        def body(x):
+            return jnp.tanh(x @ x).sum() * 3.25
+
+        x = jnp.ones((32, 32), jnp.float32)
+        cold_jit = jax.jit(body)
+        tr.wrap(cold_jit, name="t.cached")(x)
+        jax.clear_caches()            # a "new process": memory cache gone
+        warm_jit = jax.jit(body)
+        assert warm_jit._cache_size() == 0
+        tr.wrap(warm_jit, name="t.cached")(x)
+        assert warm_jit._cache_size() == 1      # resident, though loaded
+
+        stats = tr.callable_stats("t.cached")
+        assert stats["compiles"] == 2 and stats["recompiles"] == 0
+        cold, warm = [r for r in tr.export()["records"]
+                      if r["name"] == "t.cached"]
+        assert (cold["cache_hit"], warm["cache_hit"]) == (False, True)
+        counts = tr.stats()["counts"]
+        assert counts["cache_miss"] >= 1 and counts["cache_hit"] >= 1
+        assert any(n.endswith("-cache") for n in
+                   __import__("os").listdir(tmp_path))
+    finally:
+        ct.stop_global()
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+
+
 def test_unattributed_backend_compile_still_ringed():
     """An un-wrapped jit's backend compile (no call in flight) must not
     vanish: it lands as a nameless record so `compiles` shows it."""

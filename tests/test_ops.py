@@ -131,6 +131,10 @@ class TestBlockAutotune:
         for bq, bk in cands:
             assert bq >= 8 and bk >= 8
             assert 2048 % bq == 0 and 2048 % bk == 0
+            # blk_q is a lane dimension of the lse/delta blocks: 128s only
+            assert bq % 128 == 0
+        # ... unless it is the whole (short) length
+        assert {bq for bq, _ in block_candidates(64, 64, 64)} == {64}
 
     def test_autotune_measures_and_caches(self, monkeypatch):
         import importlib
@@ -139,18 +143,45 @@ class TestBlockAutotune:
 
         def fake_timer(Lq, Lk, D, dtype, bq, bk, **kw):
             calls.append((bq, bk))
-            return abs(bq - 64) + abs(bk - 32)   # makes (64, 32) win
+            return abs(bq - 128) + abs(bk - 32)   # makes (128, 32) win
 
         monkeypatch.setattr(fa, "_time_blocks", fake_timer)
-        best = fa.autotune_blocks(128, 64, 32, jnp.float32, measure=True)
-        assert best == (64, 32)
+        best = fa.autotune_blocks(256, 64, 32, jnp.float32, measure=True)
+        assert best == (128, 32)
         assert calls, "measure=True must actually time candidates"
-        assert fa.get_tuned_blocks(128, 64, 32, jnp.float32) == (64, 32)
+        assert fa.get_tuned_blocks(256, 64, 32, jnp.float32) == (128, 32)
         # second call is a pure cache hit: no further timing
         n = len(calls)
-        assert fa.autotune_blocks(128, 64, 32, jnp.float32,
-                                  measure=True) == (64, 32)
+        assert fa.autotune_blocks(256, 64, 32, jnp.float32,
+                                  measure=True) == (128, 32)
         assert len(calls) == n
+
+    @pytest.mark.parametrize("refused,outcome", [
+        ("all", RuntimeError),        # the kernels do not work here
+        ("best", (256, 128)),         # one refused candidate just loses
+    ])
+    def test_autotune_refused_candidates(self, monkeypatch, refused,
+                                         outcome):
+        """A candidate the compiler refuses loses the sweep; when EVERY
+        candidate is refused there is nothing to pick — an error, never
+        the heuristic's first guess."""
+        import importlib
+        fa = importlib.import_module("ray_tpu.ops.flash_attention")
+
+        def timer(Lq, Lk, D, dtype, bq, bk, **kw):
+            if refused == "all" or (bq, bk) == (256, 256):
+                raise ValueError("Mosaic refused this block")
+            return abs(bq - 256) + abs(bk - 128)
+
+        monkeypatch.setattr(fa, "_time_blocks", timer)
+        if outcome is RuntimeError:
+            with pytest.raises(RuntimeError, match="all .* candidates"):
+                fa.autotune_blocks(2048, 2048, 128, measure=True)
+            assert fa.get_tuned_blocks(2048, 2048, 128,
+                                       jnp.bfloat16) is None
+        else:
+            assert fa.autotune_blocks(2048, 2048, 128,
+                                      measure=True) == outcome
 
     def test_autotune_heuristic_without_measure(self):
         import importlib

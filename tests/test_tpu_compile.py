@@ -1,0 +1,138 @@
+"""Rehearsal compiles for a DESCRIBED TPU v5e (no chip attached).
+
+The TPU compiler is installed wherever jax[tpu] is, and compiles for a
+topology that is only described (`jax.experimental.topologies`). That
+refuses what interpret mode lets through — a slice not aligned to the
+tiling, a kernel over its fast-memory budget, a program that does not
+fit HBM — at no chip time. Kept here: the main path's kernels at
+Llama-3-8B head shapes (32 q / 8 kv heads x 128) and one whole serving
+step. Each asserts the Mosaic kernel is IN the compiled program
+(`tpu_custom_call`): nothing here may pass by taking a reference branch.
+
+Nothing runs, so these say nothing about results or speed. The persistent
+compile cache is off around them (an entry written for a described device
+cannot be read back without the chip, and warns).
+"""
+
+import functools
+import importlib
+import os
+
+import pytest
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+fa = importlib.import_module("ray_tpu.ops.flash_attention")
+pa = importlib.import_module("ray_tpu.ops.paged_attention")
+
+HQ, HKV, D = 32, 8, 128          # Llama-3-8B attention head shapes
+FLASH_L = 2048
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """Sharding on one chip of a described v5e 2x2 host."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this install
+        pytest.skip(f"cannot describe a v5e topology here: {e!r}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _sds(chip, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+
+def _kernel_calls(lowered) -> int:
+    return lowered.compile().as_text().count("tpu_custom_call")
+
+
+def _pool(chip, kv, pages, ps):
+    """(k_pages, v_pages, k_scale, v_scale) shapes of one layer's pool."""
+    dt = jnp.int8 if kv == "int8" else jnp.bfloat16
+    page = _sds(chip, (pages, HKV, ps, D), dt)
+    scale = _sds(chip, (pages, HKV, ps), jnp.bfloat16) \
+        if kv == "int8" else None
+    return page, page, scale, scale
+
+
+@pytest.mark.parametrize("ps", [16, 32])
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_ragged_paged_attention_compiles(chip, kv, ps):
+    """The engine's mixed prefill+decode attention: 8 decode rows + 2
+    prefill chunks of 128 tokens over a 1k-token page table."""
+    T, R, max_pages = 8 + 2 * 128, 10, 1024 // ps
+    k, v, ks, vs = _pool(chip, kv, 256, ps)
+    row = _sds(chip, (R,), jnp.int32)
+    lowered = pa._ragged_attention_pallas.lower(
+        _sds(chip, (T, HQ, D), jnp.bfloat16), k, v,
+        _sds(chip, (R, max_pages), jnp.int32), row, row, row, ks, vs,
+        sm_scale=D ** -0.5)
+    assert _kernel_calls(lowered) == 1
+
+
+def test_decode_paged_attention_compiles(chip):
+    B, ps = 8, 16
+    k, v, _, _ = _pool(chip, "bf16", 256, ps)
+    lowered = pa._paged_attention_pallas.lower(
+        _sds(chip, (B, HQ, D), jnp.bfloat16), k, v,
+        _sds(chip, (B, 1024 // ps), jnp.int32), _sds(chip, (B,), jnp.int32),
+        sm_scale=D ** -0.5)
+    assert _kernel_calls(lowered) == 1
+
+
+@pytest.mark.parametrize(
+    "blk_q,blk_k", fa.block_candidates(FLASH_L, FLASH_L, D, jnp.bfloat16))
+def test_flash_fwd_bwd_compiles_at_every_candidate_block(chip, blk_q, blk_k):
+    """Every (blk_q, blk_k) the autotuner may pick for L=2048, head_dim
+    128 must compile, forward and backward — a pick the compiler refuses
+    is found here, not on the chip."""
+    x = _sds(chip, (1, FLASH_L, HQ // 8, D), jnp.bfloat16)   # 4 heads
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, blk_q=blk_q,
+                                  blk_k=blk_k).astype(jnp.float32).sum()
+
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, x, x)
+    assert _kernel_calls(lowered) == 3          # fwd, dq, dk/dv
+
+
+def test_whole_ragged_step_program_compiles(chip):
+    """One whole engine step at Llama-3-8B widths (2 layers; shapes from
+    jax.eval_shape, so no weights exist): embed, per-layer projections,
+    KV scatter into the page pool, the ragged kernel, logits, argmax."""
+    from ray_tpu.llm import model as M
+    from ray_tpu.llm.cache import make_kv_cache
+    from ray_tpu.models.llama import LlamaConfig, init_params
+    cfg = LlamaConfig.llama3_8b(n_layers=2, param_dtype="bfloat16")
+    max_batch, rows, chunk, ps, pages, max_seq = 8, 2, 512, 16, 640, 1024
+    T, R = max_batch + rows * chunk, max_batch + rows
+
+    def abstract(fn):
+        return jax.tree.map(lambda a: _sds(chip, a.shape, a.dtype),
+                            jax.eval_shape(fn))
+
+    params = abstract(functools.partial(init_params, cfg,
+                                        jax.random.PRNGKey(0)))
+    kv = abstract(functools.partial(make_kv_cache, cfg, pages, ps))
+    tok, row = _sds(chip, (T,), jnp.int32), _sds(chip, (R,), jnp.int32)
+    compiled = M.ragged_step.lower(
+        params, tok, tok, tok, tok,
+        _sds(chip, (R, max_seq // ps), jnp.int32), row, row, row, kv,
+        cfg=cfg, paged_impl="kernel", max_q_len=chunk,
+        decode_rows=max_batch).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16 * 2**30
