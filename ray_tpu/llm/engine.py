@@ -35,6 +35,17 @@ admission, scheduling), rebuilt TPU-first around ONE ragged step:
     program, dequantize inside the attention kernel;
   - pages allocate refcounted with decode headroom; under allocator
     pressure the engine LRU-evicts unreferenced cached pages.
+
+The engine is synchronous (dispatch -> readback -> book -> next step), so
+what the host does between two dispatches is device idle. Each phase of a
+step is a jax.profiler.TraceAnnotation, written into the profiler's own
+trace beside the device's events (a flag test when no trace runs):
+engine.step {kind, dispatch, decode_rows, prefill_rows, real_tokens,
+slot_tokens, waiting} and, partitioning it, engine.admit {admitted},
+engine.pack, engine.h2d {arrays}, engine.dispatch, engine.readback,
+engine.book {finished}, engine.metrics. The names are read by
+benchmark/readers/host_gaps.py (PERF.md lists them): renaming one, or
+moving where it opens and closes, changes a metric.
 """
 
 from __future__ import annotations
@@ -55,6 +66,8 @@ from ray_tpu.llm.cache import (SCRATCH_PAGE, PageAllocator, PrefixCache,
 from ray_tpu.llm import model as M
 from ray_tpu.models.llama import LlamaConfig, init_params
 from ray_tpu.ops.paged_attention import kernels_supported
+
+TraceAnnotation = jax.profiler.TraceAnnotation
 
 
 #: tp=1 weights are BORN on the device by a jitted init (module-level, so
@@ -191,6 +204,16 @@ class InferenceEngine:
         self.params = self._fns.init_params(seed) if params is None \
             else self._fns.place_params(params)
         self.kv = self._fns.init_kv(total_pages, page_size, self.kv_dtype)
+        # device_report()'s sizes, taken HERE: every step donates the
+        # pool, so its arrays die under a reader on another thread
+        self._param_bytes = sum(x.nbytes
+                                for x in jax.tree.leaves(self.params))
+        self._kv_bytes = sum(x.nbytes for x in self.kv.values())
+        self._held_bytes: Dict[int, int] = {}
+        for leaf in jax.tree.leaves((self.params, self.kv)):
+            for shard in leaf.addressable_shards:
+                self._held_bytes[shard.device.id] = self._held_bytes.get(
+                    shard.device.id, 0) + shard.data.nbytes
         # XLA compile tracker seam (util/compile_tracker.py): the three
         # step entry points are wrapped so every compile is recorded
         # with its arg signature — ground truth the O(1)-compile
@@ -277,13 +300,14 @@ class InferenceEngine:
         self._g_decode_tps = metrics_mod.llm_decode_tokens_per_s_gauge()
         self._g_queue = metrics_mod.llm_queue_depth_gauge()
         self._g_programs = metrics_mod.llm_compiled_programs_gauge()
-        self._g_dispatches = metrics_mod.llm_dispatches_per_step_gauge()
         self._g_pad_waste = metrics_mod.llm_padding_waste_gauge()
         self._g_slo_ttft = metrics_mod.llm_slo_ttft_attainment_gauge()
         self._g_slo_tpot = metrics_mod.llm_slo_tpot_attainment_gauge()
         self._g_preempts = metrics_mod.llm_preemptions_gauge()
         self._metrics_ts = time.monotonic()
         self._metrics_last = dict(self.stats)
+        # what the step in hand dispatched: engine.step's metadata
+        self._step_meta: Dict[str, object] = {"kind": "none"}
 
     # ------------------------------------------------------------ requests
 
@@ -330,19 +354,19 @@ class InferenceEngine:
         so a deployment can assert it never fell back), the resident
         step-program count, and per device the bytes of weights + KV
         pages it holds next to the allocator's own ``memory_stats()``
-        (None on backends that keep none, i.e. the CPU)."""
-        leaves = jax.tree.leaves((self.params, self.kv))
-        held: Dict[object, int] = {}
-        for leaf in leaves:
-            for shard in leaf.addressable_shards:
-                held[shard.device] = held.get(shard.device, 0) \
-                    + shard.data.nbytes
-        devices = sorted(held, key=lambda d: d.id)
+        (None on backends that keep none, i.e. the CPU). Safe from any
+        thread while the engine steps: placement is read from the
+        weights, which no step donates, and the sizes are the
+        constructor's."""
+        devices = sorted({shard.device
+                          for leaf in jax.tree.leaves(self.params)
+                          for shard in leaf.addressable_shards},
+                         key=lambda d: d.id)
         per_device = []
         for d in devices:
             ms = d.memory_stats() or {}
             per_device.append({
-                "id": d.id, "engine_bytes": held[d],
+                "id": d.id, "engine_bytes": self._held_bytes.get(d.id, 0),
                 "bytes_in_use": ms.get("bytes_in_use"),
                 "peak_bytes_in_use": ms.get("peak_bytes_in_use"),
                 "bytes_limit": ms.get("bytes_limit")})
@@ -352,9 +376,8 @@ class InferenceEngine:
                 "tp": self.tp,
                 "paged_impl": self._fns.paged_impl,
                 "compiled_step_programs": self.compiled_step_programs(),
-                "param_bytes": sum(x.nbytes
-                                   for x in jax.tree.leaves(self.params)),
-                "kv_bytes": sum(x.nbytes for x in self.kv.values()),
+                "param_bytes": self._param_bytes,
+                "kv_bytes": self._kv_bytes,
                 "devices": per_device}
 
     # ---------------------------------------------------------------- step
@@ -367,14 +390,22 @@ class InferenceEngine:
         (decode_chunk tokens per running sequence) when not. Returns
         {request_id: generated} for sequences that FINISHED this step."""
         finished: Dict[str, List[int]] = {}
-        self._admit()
-        if not self._ragged_dispatch(finished):
-            self._decode(finished)
-        if self._finished_at_prefill:
-            finished.update(self._finished_at_prefill)
-            self._finished_at_prefill = {}
-        self.stats["steps"] += 1
-        self._update_metrics()
+        with TraceAnnotation("engine.step") as span:
+            self._step_meta = {"kind": "none"}
+            with TraceAnnotation("engine.admit") as admit_span:
+                admitted = self._admit()
+                if admit_span.is_enabled():
+                    admit_span.set_metadata(admitted=admitted)
+            if not self._ragged_dispatch(finished):
+                self._decode(finished)
+            if self._finished_at_prefill:
+                finished.update(self._finished_at_prefill)
+                self._finished_at_prefill = {}
+            self.stats["steps"] += 1
+            self._update_metrics()
+            if span.is_enabled():
+                span.set_metadata(waiting=len(self.waiting),
+                                  **self._step_meta)
         return finished
 
     # ---------------------------------------------------------- scheduling
@@ -399,7 +430,7 @@ class InferenceEngine:
         if matched_pages:
             self._release_pages(matched_pages)
 
-    def _admit(self) -> None:
+    def _admit(self) -> int:
         """Admit waiting requests into the chunked-prefill pipeline: a
         sequence reserves a decode slot + pages up front (prefix-cache
         hits map shared pages read-only, copy-on-write if its tail
@@ -414,12 +445,20 @@ class InferenceEngine:
         the head no longer starves short prompts behind it. Aging
         guard: once the head has waited admit_age_cap_s, a head that
         fails for MEMORY stops the scan, so freed pages reach it
-        instead of being re-captured by younger requests forever."""
+        instead of being re-captured by younger requests forever.
+        Returns how many it admitted."""
         admitted: List[Tuple[SequenceState, List[int], List[int], bool]] = []
         with self._lock:
             if not self.waiting:
-                return
+                return 0
             now = time.monotonic()
+            # arrivals since the last scan sit at the tail (a preempted
+            # sequence re-queues at the head, already stamped): one stamp
+            # a request, nothing per step for those that have theirs
+            for seq in reversed(self.waiting):
+                if seq.record is None or seq.record.seen_ts is not None:
+                    break
+                seq.record.note_seen(now)
             head = self.waiting[0]
             head_aged = (now - head.enqueue_ts) > self.admit_age_cap_s
             free_slots = [i for i, s in enumerate(self._slots)
@@ -467,6 +506,7 @@ class InferenceEngine:
             self.stats["cached_tokens"] += seq.cached_tokens
             self._note_cached(seq.request_id, seq.cached_tokens)
             self._chunking.append(seq)
+        return len(admitted)
 
     # --------------------------------------------------- ragged mixed step
 
@@ -493,96 +533,112 @@ class InferenceEngine:
             budget -= C
         if not rows:
             return False
-        # decode rows advance one token: they need a page for it
-        for slot, seq in list(enumerate(self._slots)):
-            if seq is not None and not seq.prefilling:
-                self._ensure_pages(slot, seq, 1, finished)
-        active = [(i, s) for i, s in enumerate(self._slots)
-                  if s is not None and not s.prefilling]
-        ps = self.page_size
-        Tcap, R = self.ragged_tokens, self.ragged_rows
-        tokens = np.zeros(Tcap, np.int32)
-        token_pos = np.zeros(Tcap, np.int32)
-        token_page = np.full(Tcap, SCRATCH_PAGE, np.int32)
-        token_slot = np.zeros(Tcap, np.int32)
-        q_start = np.zeros(R, np.int32)
-        q_len = np.zeros(R, np.int32)
-        kv_len = np.zeros(R, np.int32)
-        ptab = np.full((R, self.max_pages_per_seq), SCRATCH_PAGE,
-                       np.int32)
-        q_start[:self.max_batch] = np.arange(self.max_batch,
-                                             dtype=np.int32)
-        ptab[:self.max_batch] = self._page_table
-        for i, s in active:
-            pos = int(self._positions[i])
-            tokens[i] = self._tokens[i]
-            token_pos[i] = pos
-            token_page[i] = self._page_table[i, pos // ps]
-            token_slot[i] = pos % ps
-            q_len[i] = 1
-            kv_len[i] = s.num_tokens
-        t0 = self.max_batch
-        for j, (seq, C) in enumerate(rows):
-            r = self.max_batch + j
-            start = seq.num_computed
-            pos = np.arange(start, start + C, dtype=np.int32)
-            tokens[t0:t0 + C] = seq.prompt[start:start + C]
-            token_pos[t0:t0 + C] = pos
-            pages = np.asarray(seq.pages, np.int32)
-            token_page[t0:t0 + C] = pages[pos // ps]
-            token_slot[t0:t0 + C] = pos % ps
-            ptab[r, :len(seq.pages)] = pages
-            q_start[r] = t0
-            q_len[r] = C
-            kv_len[r] = start + C
-            t0 += C
-        nxt, self.kv = self._fns.ragged_step(
-            self.params, jnp.asarray(tokens), jnp.asarray(token_pos),
-            jnp.asarray(token_page), jnp.asarray(token_slot),
-            jnp.asarray(ptab), jnp.asarray(q_start), jnp.asarray(q_len),
-            jnp.asarray(kv_len), self.kv)
-        nxt = np.asarray(nxt)                      # [R], ONE readback
-        now = time.monotonic()
-        chunk_tokens = sum(C for _, C in rows)
-        self.stats["ragged_dispatches"] += 1
-        disp_idx = self.stats["ragged_dispatches"]
-        self.stats["ragged_real_tokens"] += len(active) + chunk_tokens
-        self.stats["ragged_slot_tokens"] += Tcap
-        self.stats["prefill_tokens"] += chunk_tokens
-        if active:
-            self.stats["decode_steps"] += 1
-            self.stats["decode_tokens"] += len(active)
-        for slot, seq in active:
-            tok = int(nxt[slot])
-            if self.eos_token is not None and tok == self.eos_token:
-                self._note_finish(seq.request_id, "stop")
-                self._finish(slot, seq, finished)
-                continue
-            seq.generated.append(tok)
-            if seq.record is not None:
-                seq.record.note_decode(now, 1)
-            if self.track_progress:
-                self._progress.setdefault(seq.request_id, []).append(tok)
-            if len(seq.generated) >= seq.max_new_tokens:
-                self._finish(slot, seq, finished)
-                continue
-            self._tokens[slot] = tok
-            self._positions[slot] = seq.num_tokens - 1
-        for j, (seq, C) in enumerate(rows):
-            seq.num_computed += C
-            if seq.record is not None:
-                seq.record.note_chunk(now, C, disp_idx)
-            if seq.num_computed >= len(seq.prompt):
-                self._chunking.remove(seq)
-                seq.prefilling = False
-                self._postfill_book(seq, seq.slot, seq.pages,
-                                    int(nxt[self.max_batch + j]))
-                if not seq.done:
-                    # entering the decode batch: reserve the decode-loop
-                    # headroom NOW, before next step's admission scan can
-                    # hand these pages to a younger request
-                    self._ensure_pages(seq.slot, seq,
-                                       self.decode_chunk, finished)
+        with TraceAnnotation("engine.pack"):
+            # decode rows advance one token: they need a page for it
+            for slot, seq in list(enumerate(self._slots)):
+                if seq is not None and not seq.prefilling:
+                    self._ensure_pages(slot, seq, 1, finished)
+            active = [(i, s) for i, s in enumerate(self._slots)
+                      if s is not None and not s.prefilling]
+            ps = self.page_size
+            Tcap, R = self.ragged_tokens, self.ragged_rows
+            tokens = np.zeros(Tcap, np.int32)
+            token_pos = np.zeros(Tcap, np.int32)
+            token_page = np.full(Tcap, SCRATCH_PAGE, np.int32)
+            token_slot = np.zeros(Tcap, np.int32)
+            q_start = np.zeros(R, np.int32)
+            q_len = np.zeros(R, np.int32)
+            kv_len = np.zeros(R, np.int32)
+            ptab = np.full((R, self.max_pages_per_seq), SCRATCH_PAGE,
+                           np.int32)
+            q_start[:self.max_batch] = np.arange(self.max_batch,
+                                                 dtype=np.int32)
+            ptab[:self.max_batch] = self._page_table
+            for i, s in active:
+                pos = int(self._positions[i])
+                tokens[i] = self._tokens[i]
+                token_pos[i] = pos
+                token_page[i] = self._page_table[i, pos // ps]
+                token_slot[i] = pos % ps
+                q_len[i] = 1
+                kv_len[i] = s.num_tokens
+            t0 = self.max_batch
+            for j, (seq, C) in enumerate(rows):
+                r = self.max_batch + j
+                start = seq.num_computed
+                pos = np.arange(start, start + C, dtype=np.int32)
+                tokens[t0:t0 + C] = seq.prompt[start:start + C]
+                token_pos[t0:t0 + C] = pos
+                pages = np.asarray(seq.pages, np.int32)
+                token_page[t0:t0 + C] = pages[pos // ps]
+                token_slot[t0:t0 + C] = pos % ps
+                ptab[r, :len(seq.pages)] = pages
+                q_start[r] = t0
+                q_len[r] = C
+                kv_len[r] = start + C
+                t0 += C
+        with TraceAnnotation("engine.h2d", arrays=8):
+            args = [jnp.asarray(a) for a in (
+                tokens, token_pos, token_page, token_slot, ptab, q_start,
+                q_len, kv_len)]
+        with TraceAnnotation("engine.dispatch"):
+            nxt, self.kv = self._fns.ragged_step(self.params, *args,
+                                                 self.kv)
+        with TraceAnnotation("engine.readback"):
+            nxt = np.asarray(nxt)                  # [R], ONE readback
+        with TraceAnnotation("engine.book") as span:
+            n_done = len(finished) + len(self._finished_at_prefill)
+            now = time.monotonic()
+            chunk_tokens = sum(C for _, C in rows)
+            self.stats["ragged_dispatches"] += 1
+            disp_idx = self.stats["ragged_dispatches"]
+            self.stats["ragged_real_tokens"] += len(active) + chunk_tokens
+            self.stats["ragged_slot_tokens"] += Tcap
+            self.stats["prefill_tokens"] += chunk_tokens
+            if active:
+                self.stats["decode_steps"] += 1
+                self.stats["decode_tokens"] += len(active)
+            self._step_meta = {
+                "kind": "mixed", "dispatch": disp_idx,
+                "decode_rows": len(active), "prefill_rows": len(rows),
+                "real_tokens": len(active) + chunk_tokens,
+                "slot_tokens": Tcap}
+            for slot, seq in active:
+                tok = int(nxt[slot])
+                if self.eos_token is not None and tok == self.eos_token:
+                    self._note_finish(seq.request_id, "stop")
+                    self._finish(slot, seq, finished)
+                    continue
+                seq.generated.append(tok)
+                if seq.record is not None:
+                    seq.record.note_decode(now, 1, mixed=True)
+                if self.track_progress:
+                    self._progress.setdefault(seq.request_id,
+                                              []).append(tok)
+                if len(seq.generated) >= seq.max_new_tokens:
+                    self._finish(slot, seq, finished)
+                    continue
+                self._tokens[slot] = tok
+                self._positions[slot] = seq.num_tokens - 1
+            for j, (seq, C) in enumerate(rows):
+                seq.num_computed += C
+                if seq.record is not None:
+                    seq.record.note_chunk(now, C, disp_idx)
+                if seq.num_computed >= len(seq.prompt):
+                    self._chunking.remove(seq)
+                    seq.prefilling = False
+                    self._postfill_book(seq, seq.slot, seq.pages,
+                                        int(nxt[self.max_batch + j]))
+                    if not seq.done:
+                        # entering the decode batch: reserve the decode-
+                        # loop headroom NOW, before next step's admission
+                        # scan can hand these pages to a younger request
+                        self._ensure_pages(seq.slot, seq,
+                                           self.decode_chunk, finished)
+            if span.is_enabled():
+                span.set_metadata(
+                    finished=len(finished)
+                    + len(self._finished_at_prefill) - n_done)
         return True
 
     def _postfill_book(self, seq: SequenceState, slot: int,
@@ -613,7 +669,9 @@ class InferenceEngine:
             if eos_now:
                 seq.record.note_first(now)  # sampled, but never emitted
             else:
-                seq.record.note_decode(now, 1)
+                # a request's first token, or (re-admitted after a
+                # preemption) one more that a mixed step produced
+                seq.record.note_decode(now, 1, mixed=True)
         done_now = eos_now or len(seq.generated) + 1 >= seq.max_new_tokens
         if done_now:
             # first sampled token is EOS (drop it) or it used up the
@@ -737,52 +795,71 @@ class InferenceEngine:
     # ----------------------------------------------------- pure decode
 
     def _decode(self, finished: Dict[str, List[int]]) -> None:
-        for slot, seq in list(enumerate(self._slots)):
-            if seq is not None and not seq.prefilling:
-                self._ensure_pages(slot, seq, self.decode_chunk, finished)
-        active = [(i, s) for i, s in enumerate(self._slots)
-                  if s is not None and not s.prefilling]
-        if not active:
-            return
-        K = self.decode_chunk
-        seq_lens = np.ones(self.max_batch, np.int32)
-        for i, s in active:
-            seq_lens[i] = s.num_tokens
-        toks_out, self.kv, _, _ = self._fns.decode_loop(
-            self.params, jnp.asarray(self._tokens),
-            jnp.asarray(self._positions), self.kv,
-            jnp.asarray(self._page_table), jnp.asarray(seq_lens))
-        block = np.asarray(toks_out)               # [K, B], ONE readback
-        now = time.monotonic()
-        self.stats["decode_steps"] += K
-        self.stats["decode_tokens"] += K * len(active)
-        self.stats["decode_dispatches"] += 1
-        for slot, seq in active:
-            n_new, fin = 0, False
-            for j in range(K):
-                tok = int(block[j, slot])
-                if self.eos_token is not None and tok == self.eos_token:
-                    self._note_finish(seq.request_id, "stop")
-                    fin = True
-                    break
-                seq.generated.append(tok)
-                n_new += 1
-                if self.track_progress:
-                    self._progress.setdefault(seq.request_id,
-                                              []).append(tok)
-                if len(seq.generated) >= seq.max_new_tokens:
-                    fin = True
-                    break
-            # ONE record entry per dispatch (the K-step loop is one
-            # device round trip — per-token host timestamps would be
-            # fiction), noted BEFORE _finish so e2e covers every token
-            if n_new and seq.record is not None:
-                seq.record.note_decode(now, n_new)
-            if fin:
-                self._finish(slot, seq, finished)
-            else:
-                self._tokens[slot] = int(block[K - 1, slot])
-                self._positions[slot] = seq.num_tokens - 1
+        with TraceAnnotation("engine.pack"):
+            for slot, seq in list(enumerate(self._slots)):
+                if seq is not None and not seq.prefilling:
+                    self._ensure_pages(slot, seq, self.decode_chunk,
+                                       finished)
+            active = [(i, s) for i, s in enumerate(self._slots)
+                      if s is not None and not s.prefilling]
+            if not active:
+                return
+            K = self.decode_chunk
+            seq_lens = np.ones(self.max_batch, np.int32)
+            for i, s in active:
+                seq_lens[i] = s.num_tokens
+        with TraceAnnotation("engine.h2d", arrays=4):
+            tokens, positions, page_table, seq_lens = (
+                jnp.asarray(a) for a in (
+                    self._tokens, self._positions, self._page_table,
+                    seq_lens))
+        with TraceAnnotation("engine.dispatch"):
+            toks_out, self.kv, _, _ = self._fns.decode_loop(
+                self.params, tokens, positions, self.kv, page_table,
+                seq_lens)
+        with TraceAnnotation("engine.readback"):
+            block = np.asarray(toks_out)           # [K, B], ONE readback
+        with TraceAnnotation("engine.book") as span:
+            n_done = len(finished)
+            now = time.monotonic()
+            self.stats["decode_steps"] += K
+            self.stats["decode_tokens"] += K * len(active)
+            self.stats["decode_dispatches"] += 1
+            self._step_meta = {
+                "kind": "decode",
+                "dispatch": self.stats["decode_dispatches"],
+                "decode_rows": len(active), "prefill_rows": 0,
+                "real_tokens": K * len(active),
+                "slot_tokens": K * self.max_batch}
+            for slot, seq in active:
+                n_new, fin = 0, False
+                for j in range(K):
+                    tok = int(block[j, slot])
+                    if self.eos_token is not None \
+                            and tok == self.eos_token:
+                        self._note_finish(seq.request_id, "stop")
+                        fin = True
+                        break
+                    seq.generated.append(tok)
+                    n_new += 1
+                    if self.track_progress:
+                        self._progress.setdefault(seq.request_id,
+                                                  []).append(tok)
+                    if len(seq.generated) >= seq.max_new_tokens:
+                        fin = True
+                        break
+                # ONE record entry per dispatch (the K-step loop is one
+                # device round trip — per-token host timestamps would be
+                # fiction), noted BEFORE _finish so e2e covers every token
+                if n_new and seq.record is not None:
+                    seq.record.note_decode(now, n_new)
+                if fin:
+                    self._finish(slot, seq, finished)
+                else:
+                    self._tokens[slot] = int(block[K - 1, slot])
+                    self._positions[slot] = seq.num_tokens - 1
+            if span.is_enabled():
+                span.set_metadata(finished=len(finished) - n_done)
 
     def drain_progress(self) -> Dict[str, List[int]]:
         """Tokens generated since the previous drain, per request id
@@ -820,6 +897,10 @@ class InferenceEngine:
         dt = now - self._metrics_ts
         if dt < 1.0 and not force:
             return
+        with TraceAnnotation("engine.metrics"):
+            self._set_gauges(now, dt)
+
+    def _set_gauges(self, now: float, dt: float) -> None:
         s, last = self.stats, self._metrics_last
         self._metrics_last = dict(s)
         self._metrics_ts = now
@@ -834,8 +915,8 @@ class InferenceEngine:
             self._g_decode_tps.set(
                 (s["decode_tokens"] - last["decode_tokens"]) / dt)
         # ragged-step visibility: resident compiled programs (O(1) by
-        # design), device dispatches per scheduler step, and the padding
-        # fraction of ragged token slots over the gauge window
+        # design) and the padding fraction of ragged token slots over
+        # the gauge window
         programs = self.compiled_step_programs()
         self._g_programs.set(float(programs))
         # the >3-programs invariant was test-only until now: in
@@ -856,12 +937,6 @@ class InferenceEngine:
                     signature=culprit.get("signature", []))
         else:
             self._invariant_breached = False
-        d_steps = s["steps"] - last["steps"]
-        if d_steps > 0:
-            disp = sum(s[k] - last[k] for k in
-                       ("ragged_dispatches", "decode_dispatches",
-                        "cow_copies"))
-            self._g_dispatches.set(disp / d_steps)
         d_slots = s["ragged_slot_tokens"] - last["ragged_slot_tokens"]
         if d_slots > 0:
             d_real = s["ragged_real_tokens"] - last["ragged_real_tokens"]

@@ -6,10 +6,12 @@ feeding TTFT/TPOT/e2e histograms and preemption accounting): every
 request the engine touches gets ONE ``RequestRecord`` carrying its
 lifecycle event stream —
 
-  enqueue -> admit (queue wait, prefix cached_tokens) -> prefill chunks
-  (tokens, dispatch index) -> first token (TTFT) -> per-dispatch decode
-  timestamps (TPOT/ITL) -> page-pressure stalls / preemptions -> finish
-  (stop | length | evict)
+  enqueue -> first admission scan that saw it (the wait behind the
+  dispatch in flight) -> admit (queue wait, prefix cached_tokens) ->
+  prefill chunks (tokens, dispatch index) -> first token (TTFT) ->
+  per-dispatch decode timestamps (TPOT/ITL), and how many of those tokens
+  and how much of their time came from mixed steps -> page-pressure
+  stalls / preemptions -> finish (stop | length | evict)
 
 — held in a bounded ring (``FlightRecorder``), with O(1) cost per step
 event: timestamps are monotonic deltas against the record's enqueue
@@ -56,10 +58,10 @@ class RequestRecord:
     anchor ``t0_wall`` maps offsets back to clock time for display)."""
 
     __slots__ = ("rid", "trace_id", "t0", "t0_wall", "prompt_tokens",
-                 "max_new_tokens", "admits", "chunks", "first_ts",
-                 "last_ts", "n_generated", "stalls", "preempt_ts",
-                 "finish_ts", "finish_reason", "_dec_dt", "_dec_n",
-                 "_di", "_dec_over")
+                 "max_new_tokens", "seen_ts", "admits", "chunks",
+                 "first_ts", "last_ts", "n_generated", "mixed_tokens",
+                 "mixed_stall", "stalls", "preempt_ts", "finish_ts",
+                 "finish_reason", "_dec_dt", "_dec_n", "_di", "_dec_over")
 
     def __init__(self, rid: str, prompt_tokens: int, max_new_tokens: int,
                  trace_id: str = "",
@@ -70,11 +72,17 @@ class RequestRecord:
         self.t0_wall = time.time()
         self.prompt_tokens = prompt_tokens
         self.max_new_tokens = max_new_tokens
+        self.seen_ts: Optional[float] = None        # first admission scan
         self.admits: List[Tuple[float, int]] = []   # (ts, cached_tokens)
         self.chunks: List[Tuple[float, int, int]] = []  # (ts, n, dispatch)
         self.first_ts: Optional[float] = None       # TTFT
         self.last_ts: Optional[float] = None        # newest token
         self.n_generated = 0
+        # tokens after the first that a MIXED step produced (one a step,
+        # behind that step's prefill chunks), and the seconds each of them
+        # came after the token before it
+        self.mixed_tokens = 0
+        self.mixed_stall = 0.0
         self.stalls = 0
         self.preempt_ts: List[float] = []
         self.finish_ts: Optional[float] = None
@@ -87,6 +95,14 @@ class RequestRecord:
         self._dec_over = 0
 
     # ------------------------------------------------------------- events
+
+    def note_seen(self, now: float) -> None:
+        """The first admission scan after the enqueue: the dispatch that
+        was running when the request arrived has ended. What admission
+        itself refuses (no slot, no pages, beyond the lookahead) is the
+        rest of queue_wait. Idempotent."""
+        if self.seen_ts is None:
+            self.seen_ts = now - self.t0
 
     def note_admit(self, now: float, cached_tokens: int) -> None:
         """Admitted into a slot (one entry per admission — a preempted
@@ -113,19 +129,24 @@ class RequestRecord:
             self.first_ts = now - self.t0
             self.last_ts = self.first_ts
 
-    def note_decode(self, now: float, n_tokens: int) -> None:
-        """``n_tokens`` landed from one device dispatch. One preallocated
-        (delta_ts, n) entry per dispatch; past the cap only aggregates
-        move."""
+    def note_decode(self, now: float, n_tokens: int,
+                    mixed: bool = False) -> None:
+        """``n_tokens`` landed from one device dispatch: a decode loop,
+        or (``mixed``) a mixed step. One preallocated (delta_ts, n) entry
+        per dispatch; past the cap only aggregates move."""
         off = now - self.t0
         if self.first_ts is None:
             self.first_ts = off
-        elif self._di < len(self._dec_dt):
-            self._dec_dt[self._di] = off - (self.last_ts or off)
-            self._dec_n[self._di] = n_tokens
-            self._di += 1
         else:
-            self._dec_over += n_tokens
+            if mixed:
+                self.mixed_tokens += n_tokens
+                self.mixed_stall += off - self.last_ts
+            if self._di < len(self._dec_dt):
+                self._dec_dt[self._di] = off - self.last_ts
+                self._dec_n[self._di] = n_tokens
+                self._di += 1
+            else:
+                self._dec_over += n_tokens
         self.last_ts = off
         self.n_generated += n_tokens
 
@@ -138,6 +159,18 @@ class RequestRecord:
     @property
     def queue_wait(self) -> Optional[float]:
         return self.admits[0][0] if self.admits else None
+
+    @property
+    def wait_in_flight(self) -> Optional[float]:
+        return self.seen_ts
+
+    @property
+    def mixed_stall_share(self) -> Optional[float]:
+        """Share of the time between the first and the last token spent
+        waiting for tokens that mixed steps produced."""
+        if self.n_generated < 2 or self.last_ts <= self.first_ts:
+            return None
+        return self.mixed_stall / (self.last_ts - self.first_ts)
 
     @property
     def ttft(self) -> Optional[float]:
@@ -170,11 +203,15 @@ class RequestRecord:
             "admits": [[round(ts, 6), c] for ts, c in self.admits],
             "chunks": [[round(ts, 6), n, d] for ts, n, d in self.chunks],
             "queue_wait": self.queue_wait,
+            "wait_in_flight": self.wait_in_flight,
             "cached_tokens": self.cached_tokens(),
             "ttft": self.ttft,
             "tpot": self.tpot,
             "e2e": self.finish_ts,
             "n_generated": self.n_generated,
+            "mixed_tokens": self.mixed_tokens,
+            "mixed_stall": self.mixed_stall,
+            "mixed_stall_share": self.mixed_stall_share,
             "decode": [[round(dt, 6), n]
                        for dt, n in self.decode_entries()],
             "decode_overflow_tokens": self._dec_over,

@@ -21,7 +21,7 @@ import threading
 import time
 from typing import Any, Dict, Iterator, List, Optional
 
-from ray_tpu.llm.engine import InferenceEngine
+from ray_tpu.llm.engine import InferenceEngine, TraceAnnotation
 from ray_tpu.llm.tokenizer import ByteTokenizer
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.util import log_plane, trace_context
@@ -64,30 +64,46 @@ class LLMServer:
         self._thread.start()
 
     def _loop(self) -> None:
+        """The engine thread. Between two engine.step spans the device
+        waits for this loop, so its two phases are spans of the same trace
+        (serve.wait, serve.publish {streams}: names the benchmark reads,
+        see llm/engine.py)."""
         while True:
             if not self.engine.has_work():
-                self._wake.wait(timeout=0.05)
+                with TraceAnnotation("serve.wait"):
+                    self._wake.wait(timeout=0.05)
                 self._wake.clear()
                 continue
             finished = self.engine.step()
-            progress = self.engine.drain_progress()
-            with self._lock:
-                for rid, new_toks in progress.items():
-                    q = self._token_qs.get(rid)
-                    if q is not None and new_toks:
-                        q.put(list(new_toks))
-                for rid, toks in finished.items():
-                    q = self._token_qs.get(rid)
-                    if q is not None:
-                        q.put(None)  # end of stream
-                        continue
-                    if rid in self._abandoned:
-                        self._abandoned.discard(rid)
-                        continue
-                    self._results[rid] = toks
-                    ev = self._events.get(rid)
-                    if ev is not None:
-                        ev.set()
+            with TraceAnnotation("serve.publish") as span:
+                streams = self._publish(finished)
+                if span.is_enabled():
+                    span.set_metadata(streams=streams)
+
+    def _publish(self, finished: Dict[str, List[int]]) -> int:
+        """Hand the step's tokens to their waiters; returns how many
+        streams got some."""
+        streams = 0
+        progress = self.engine.drain_progress()
+        with self._lock:
+            for rid, new_toks in progress.items():
+                q = self._token_qs.get(rid)
+                if q is not None and new_toks:
+                    q.put(list(new_toks))
+                    streams += 1
+            for rid, toks in finished.items():
+                q = self._token_qs.get(rid)
+                if q is not None:
+                    q.put(None)  # end of stream
+                    continue
+                if rid in self._abandoned:
+                    self._abandoned.discard(rid)
+                    continue
+                self._results[rid] = toks
+                ev = self._events.get(rid)
+                if ev is not None:
+                    ev.set()
+        return streams
 
     def __call__(self, request: Dict[str, Any]) -> Dict[str, Any]:
         prompt = self._prompt_ids(request)

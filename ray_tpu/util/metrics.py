@@ -474,14 +474,6 @@ def llm_compiled_programs_gauge() -> Gauge:
                  description="compiled LLM step programs resident")
 
 
-def llm_dispatches_per_step_gauge() -> Gauge:
-    """Device dispatches per scheduler step over the gauge window
-    (ragged mixed steps + decode loops + COW copies). The steady-state
-    target is 1.0: each step is ONE program launch."""
-    return Gauge("llm_dispatches_per_step",
-                 description="device dispatches per engine step")
-
-
 def llm_padding_waste_gauge() -> Gauge:
     """Fraction of ragged-step token slots that carried padding instead
     of real prompt/decode tokens, over the gauge window — the cost of

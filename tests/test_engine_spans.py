@@ -1,0 +1,202 @@
+"""The engine host loop's trace annotations and the counts taken at the same
+boundaries: a short profiled run of a tiny served engine must yield
+engine.step spans whose children partition them and whose kinds equal the
+engine's own dispatch counters; the request log must say what a request
+waited for; device_report() must be callable while the engine steps.
+
+The span names are a contract with benchmark/readers/host_gaps.py (PERF.md
+lists them). Nothing here is a time: the trace is read for structure."""
+
+import glob
+import os
+import threading
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from ray_tpu.llm import InferenceEngine
+from ray_tpu.llm.serve_llm import LLMServer
+from ray_tpu.models.llama import LlamaConfig
+
+CHILDREN = {"engine.admit", "engine.pack", "engine.h2d", "engine.dispatch",
+            "engine.readback", "engine.book", "engine.metrics"}
+ENGINE = dict(page_size=8, total_pages=64, max_batch=4, max_seq_len=96,
+              prefill_chunk=16, prefill_rows=2, decode_chunk=4)
+
+
+@pytest.fixture(scope="module")
+def profiled(tmp_path_factory):
+    """A tiny LLMServer answering five overlapping requests under the
+    profiler (host spans only, as the benchmark's start_trace asks):
+    ([(name, start, end, {metadata})] in start order, the engine's
+    counters before, after)."""
+    server = LLMServer(model_config={"n_layers": 2, "dtype": jnp.float32},
+                       engine_config=ENGINE)
+    server({"prompt_ids": list(range(1, 20)), "max_tokens": 6})   # compile
+    log_dir = str(tmp_path_factory.mktemp("trace"))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    before = dict(server.engine.stats)
+    jax.profiler.start_trace(log_dir, profiler_options=opts)
+    try:
+        threads = [threading.Thread(target=server, args=({
+            "prompt_ids": list(range(3, 3 + n)), "max_tokens": 9},))
+            for n in (40, 7, 23, 5, 30)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        assert not any(t.is_alive() for t in threads)
+        after = dict(server.engine.stats)
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(os.path.join(log_dir, "plugins", "profile", "*",
+                                  "*.xplane.pb"))[-1]
+    events = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(("engine.", "serve.")):
+                    events.append((ev.name, ev.start_ns,
+                                   ev.start_ns + ev.duration_ns,
+                                   dict(ev.stats)))
+    events.sort(key=lambda e: (e[1], -e[2]))
+    return events, before, after
+
+
+def _steps_with_children(events):
+    steps = [e for e in events if e[0] == "engine.step"]
+    inside = [[c for c in events if c[0] in CHILDREN
+               and s[1] <= c[1] and c[2] <= s[2]] for s in steps]
+    return steps, inside
+
+
+def test_children_nest_inside_steps_and_do_not_overlap(profiled):
+    events, _, _ = profiled
+    steps, inside = _steps_with_children(events)
+    assert len(steps) >= 4
+    # every child lies in exactly one step: none is left outside
+    assert sum(len(c) for c in inside) \
+        == sum(1 for e in events if e[0] in CHILDREN)
+    for (_, s0, s1, _), kids in zip(steps, inside):
+        for a, b in zip(kids, kids[1:]):
+            assert a[2] <= b[1], (a, b)          # in order, no overlap
+        assert sum(k[2] - k[1] for k in kids) <= s1 - s0
+    for a, b in zip(steps, steps[1:]):
+        assert a[2] <= b[1]
+
+
+def test_a_dispatching_step_has_every_phase_once_in_order(profiled):
+    events, _, _ = profiled
+    steps, inside = _steps_with_children(events)
+    order = ["engine.admit", "engine.pack", "engine.h2d", "engine.dispatch",
+             "engine.readback", "engine.book"]
+    seen = 0
+    for step, kids in zip(steps, inside):
+        names = [k[0] for k in kids if k[0] != "engine.metrics"]
+        if step[3]["kind"] == "none":
+            assert "engine.dispatch" not in names
+            continue
+        assert names == order, names
+        seen += 1
+        h2d = next(k for k in kids if k[0] == "engine.h2d")
+        assert h2d[3]["arrays"] == (8 if step[3]["kind"] == "mixed" else 4)
+    assert seen >= 4
+
+
+def test_step_kinds_equal_the_dispatch_counters(profiled):
+    events, before, after = profiled
+    meta = [e[3] for e in events if e[0] == "engine.step"]
+    for kind, counter in (("mixed", "ragged_dispatches"),
+                          ("decode", "decode_dispatches")):
+        of_kind = [m for m in meta if m["kind"] == kind]
+        assert len(of_kind) == after[counter] - before[counter] > 0
+        # `dispatch` is the counter's value, the index the request log's
+        # chunks carry for a mixed step
+        assert [m["dispatch"] for m in of_kind] \
+            == list(range(before[counter] + 1, after[counter] + 1))
+    assert sum(m["real_tokens"] for m in meta if m["kind"] == "mixed") \
+        == after["ragged_real_tokens"] - before["ragged_real_tokens"]
+    assert sum(m["slot_tokens"] for m in meta if m["kind"] == "mixed") \
+        == after["ragged_slot_tokens"] - before["ragged_slot_tokens"]
+    assert sum(m["decode_rows"] * (1 if m["kind"] == "mixed"
+                                   else ENGINE["decode_chunk"])
+               for m in meta if m["kind"] != "none") \
+        == after["decode_tokens"] - before["decode_tokens"]
+    admitted = sum(e[3]["admitted"] for e in events
+                   if e[0] == "engine.admit")
+    assert admitted == 5
+
+
+def test_serve_spans_sit_between_steps(profiled):
+    events, _, _ = profiled
+    steps = [e for e in events if e[0] == "engine.step"]
+    publishes = [e for e in events if e[0] == "serve.publish"]
+    assert len(publishes) >= len(steps) - 1     # one after every step
+    for p in publishes + [e for e in events if e[0] == "serve.wait"]:
+        assert not any(s[1] < p[2] and p[1] < s[2] for s in steps), p
+    # __call__ requests wait on an event, not a stream
+    assert all(p[3]["streams"] == 0 for p in publishes)
+
+
+def test_request_log_tells_in_flight_wait_from_refusal():
+    """One slot, two requests: the second is seen by the first admission
+    scan after it arrived and refused until the first one ends."""
+    eng = InferenceEngine(LlamaConfig.tiny(n_layers=1, dtype=jnp.float32),
+                          page_size=8, total_pages=32, max_batch=1,
+                          max_seq_len=64, prefill_chunk=16, decode_chunk=2,
+                          request_log=True)
+    first = eng.add_request(list(range(1, 12)), max_new_tokens=5)
+    second = eng.add_request(list(range(2, 9)), max_new_tokens=5)
+    while eng.has_work():
+        eng.step()
+    a, b = (eng.request_log.get(r).to_dict() for r in (first, second))
+    for rec in (a, b):
+        assert 0 <= rec["wait_in_flight"] <= rec["queue_wait"]
+        assert rec["mixed_tokens"] <= rec["n_generated"] - 1
+        assert 0 <= rec["mixed_stall"] <= rec["e2e"] - rec["ttft"]
+    assert a["queue_wait"] == a["wait_in_flight"]   # admitted by that scan
+    assert b["queue_wait"] > b["wait_in_flight"]    # no slot until a ended
+    assert b["queue_wait"] >= a["e2e"] - (b["t0_wall"] - a["t0_wall"]) - 0.05
+    # a alone in the batch never shares a step with a prefill after its
+    # own; b's prefill rode no step that a decoded in (a had ended)
+    assert a["mixed_tokens"] == 0 and a["mixed_stall_share"] == 0.0
+
+
+def test_device_report_from_another_thread_while_stepping():
+    """Each step donates the page pool; the report must not walk it."""
+    eng = InferenceEngine(LlamaConfig.tiny(n_layers=1, dtype=jnp.float32),
+                          page_size=8, total_pages=32, max_batch=2,
+                          max_seq_len=64, prefill_chunk=16, decode_chunk=2)
+    want = eng.device_report()
+    assert want["kv_bytes"] == sum(x.nbytes for x in eng.kv.values()) > 0
+    assert want["param_bytes"] > 0
+    assert sum(d["engine_bytes"] for d in want["devices"]) \
+        == want["param_bytes"] + want["kv_bytes"]
+    eng.generate(list(range(1, 10)), max_new_tokens=3)          # compile
+    reports, errors = [], []
+    stop = threading.Event()
+
+    def reader():
+        try:
+            while not stop.is_set():
+                reports.append(eng.device_report())
+        except Exception as e:  # noqa: BLE001 — the test's verdict
+            errors.append(e)
+
+    t = threading.Thread(target=reader)
+    t.start()
+    try:
+        for _ in range(3):
+            eng.generate(list(range(1, 14)), max_new_tokens=12)
+    finally:
+        stop.set()
+        t.join(timeout=60)
+    assert not t.is_alive() and not errors, errors
+    assert len(reports) > 0
+    for key in ("platform", "tp", "paged_impl", "param_bytes", "kv_bytes"):
+        assert all(r[key] == want[key] for r in reports), key
+    assert all(r["devices"][0]["engine_bytes"]
+               == want["devices"][0]["engine_bytes"] for r in reports)

@@ -87,6 +87,73 @@ def test_record_decode_entry_cap_overflow_aggregates():
                                    rel=1e-6)
 
 
+# events after the first token: ("loop", n) a decode loop's n tokens,
+# ("mixed", 1) a mixed step's one, ("preempt",) eviction and re-admission
+# (the re-prefill's last chunk samples one more token: a mixed one)
+MIXED_CASES = {
+    "decode-only": [("loop", 8), ("loop", 8), ("loop", 3)],
+    "mixed-only": [("mixed", 1), ("mixed", 1), ("mixed", 1)],
+    "interleaved": [("loop", 8), ("mixed", 1), ("loop", 8), ("mixed", 1),
+                    ("mixed", 1), ("loop", 2)],
+    "single-token": [],
+    "preempted-and-readmitted": [("loop", 8), ("mixed", 1), ("preempt",),
+                                 ("loop", 8)],
+}
+
+
+@pytest.mark.parametrize("case", sorted(MIXED_CASES))
+def test_mixed_step_share_and_in_flight_wait(case):
+    """What a token gap is made of: tokens and seconds that came from
+    mixed steps, and the wait for the dispatch in flight inside the queue
+    wait. A loop dispatch takes 0.24 s, a mixed step 0.32 s."""
+    r = _rec(max_new_tokens=64)
+    t = r.t0 + 0.100
+    r.note_seen(t)
+    r.note_seen(t + 5.0)                    # one stamp, the first
+    t += 0.040                              # refused once, admitted next
+    r.note_admit(t, 0)
+    t += 0.32
+    r.note_decode(t, 1, mixed=True)         # the first token: TTFT only
+    loop_tokens = mixed_tokens = 0
+    stall = 0.0
+    for ev in MIXED_CASES[case]:
+        if ev[0] == "loop":
+            t += 0.24
+            r.note_decode(t, ev[1])
+            loop_tokens += ev[1]
+        elif ev[0] == "mixed":
+            t += 0.32
+            r.note_decode(t, 1, mixed=True)
+            mixed_tokens += 1
+            stall += 0.32
+        else:
+            t += 0.05
+            r.note_preempt(t)
+            t += 0.5                        # waits, then two chunk steps
+            r.note_admit(t, 0)
+            t += 0.64
+            r.note_decode(t, 1, mixed=True)
+            mixed_tokens += 1
+            stall += 0.05 + 0.5 + 0.64
+    d = r.to_dict()
+    assert d["mixed_tokens"] == mixed_tokens
+    assert d["mixed_tokens"] + loop_tokens == d["n_generated"] - 1
+    assert d["mixed_stall"] == pytest.approx(stall)
+    assert d["mixed_stall"] <= r.last_ts - r.first_ts + 1e-9
+    assert d["wait_in_flight"] == pytest.approx(0.100)
+    assert d["wait_in_flight"] <= d["queue_wait"] == pytest.approx(0.140)
+    if d["n_generated"] < 2:
+        assert d["mixed_stall_share"] is None
+    else:
+        assert d["mixed_stall_share"] == pytest.approx(
+            stall / (r.last_ts - r.first_ts))
+        assert 0.0 <= d["mixed_stall_share"] <= 1.0
+    if case == "mixed-only":
+        assert d["mixed_stall_share"] == pytest.approx(1.0)
+    if case == "decode-only":
+        assert d["mixed_stall_share"] == 0.0
+
+
 # ---------------------------------------------------------------- recorder
 
 
