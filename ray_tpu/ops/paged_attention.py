@@ -16,18 +16,30 @@ scalar-prefetch pattern:
   - ``ragged_paged_attention``: a RAGGED token batch ``[T, Hq, D]`` —
     concatenated query tokens from R sequences described by
     ``(q_start, q_len, kv_len)`` rows, where q_len is a prefill chunk
-    for some rows and 1 for decode rows. Grid (T, max_pages): the
-    scalar-prefetched page table (plus per-token row/visibility vectors
-    derived from the descriptors in-program) drives the BlockSpec
-    index_map, each grid step DMAs exactly one page, causal masking is
-    a per-token visible-length compare, and online-softmax scratch
-    carries across the page axis. One dispatch serves mixed
-    prefill+decode — the engine's whole step program;
-  - int8 KV pages: both ragged paths take optional per-(page, head,
-    slot) scale arrays ``[P, Hkv, ps]`` and dequantize in-kernel
-    (k_f32 = k_int8 * scale), halving KV HBM per token;
-  - GQA: q is grouped [kv_heads, q_per_kv, head_dim] and the score matmul
-    batches over kv_heads on the MXU.
+    for some rows and 1 for decode rows. One dispatch serves mixed
+    prefill+decode — the engine's whole step program. The kernel is
+    BLOCKED: a grid step is a tile of ONE row's query tokens (one token
+    for the first ``decode_rows`` rows, up to 128 for the others,
+    ceil(max_q_len / 128) tiles a row) and loops over blocks of several
+    of that row's pages. The pool stays in HBM; a block's pages come by
+    one async copy each into a double buffer, started a block ahead (the
+    next tile's first block behind the current tile's last), so K/V is
+    fetched once per tile, not once per token. The loop runs
+    ceil(visible / block) turns from the scalar-prefetched lengths:
+    pages past a tile's last visible position are neither copied nor
+    multiplied, empty rows and tiles past q_len cost one empty grid
+    step, and only blocks that straddle the tile's own positions are
+    causally masked. Operands go to the MXU in the pool's dtype (all
+    ``q_per_kv`` query heads of a KV head in one [tokens * q_per_kv, D]
+    operand) with fp32 accumulation; running max, sum and rescale are
+    fp32. XLA gathers q into tile order and the outputs back (rows may
+    start anywhere in q);
+  - int8 KV pages: the ragged paths take optional per-(page, head,
+    slot) scale arrays ``[P, Hkv, ps]``. The kernel feeds the int8
+    values to the MXU as bf16 (exact) and applies the scales to the
+    score columns and to the probabilities, so no dequantized K/V copy
+    exists; int8 halves KV HBM per token;
+  - GQA: the query heads of one KV head share each K/V fetch.
 
 The ``*_reference`` functions are the pure-JAX gather equivalents — the
 numerics oracles and the portable fallbacks on CPU test meshes.
@@ -228,23 +240,14 @@ def paged_attention(q, k_pages, v_pages, page_table, seq_lens, *,
 #   kv_len[r]-q_len[r]+j+1 slots of the row's pages (the row's OWN chunk
 #   K/V included — the caller scatters the chunk into the pages before
 #   attending, exactly like the decode step writes-then-attends).
-
-
-def _token_descriptors(q_start, q_len, kv_len, T: int):
-    """Per-token (owning row, visible kv length) from per-row descriptors.
-
-    O(R*T) int compare — noise next to attention; runs inside the jitted
-    wrapper so the host never materializes per-token metadata.
-    """
-    tvec = jnp.arange(T, dtype=jnp.int32)
-    in_row = (tvec[None, :] >= q_start[:, None]) & \
-             (tvec[None, :] < (q_start + q_len)[:, None])       # [R, T]
-    token_row = jnp.argmax(in_row, axis=0).astype(jnp.int32)
-    owned = jnp.any(in_row, axis=0)
-    vis = kv_len[token_row] - q_len[token_row] \
-        + (tvec - q_start[token_row]) + 1
-    token_vis = jnp.where(owned, vis, 0).astype(jnp.int32)
-    return token_row, token_vis
+#
+# Static hints (``decode_rows``, ``max_q_len``): the first decode_rows
+#   rows hold at most ONE token each, every other row at most max_q_len
+#   (default T). They are the tiling, for the kernel and the reference
+#   alike: decode rows are one-token tiles, the others ceil(max_q_len /
+#   bq) tiles of bq tokens (_ragged_tiling). A hint looser than the
+#   batch only costs time (empty tiles); a row LONGER than its hint is
+#   the caller's error.
 
 
 def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
@@ -259,11 +262,12 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
     k/v_scale: [P, Hkv, ps] per-(page, head, slot) dequant scales or
     None; page_table: [R, max_pages]; q_start/q_len/kv_len: [R].
 
-    ``decode_rows``/``max_q_len`` are STATIC cost hints, not semantics:
-    the first ``decode_rows`` rows must have q_len <= 1 and are computed
-    decode-style (one gathered score row each); the rest are prefill
-    rows computed on ``max_q_len``-sized blocks (default T). Wrong hints
-    that still satisfy the q_len bounds only cost time, never accuracy.
+    ``decode_rows``/``max_q_len`` are the STATIC tiling hints (see
+    "Ragged batch layout"): the first ``decode_rows`` rows must have
+    q_len <= 1 and are computed decode-style (one gathered score row
+    each); the rest are prefill rows computed on ``max_q_len``-sized
+    blocks (default T). Wrong hints that still satisfy the q_len bounds
+    only cost time, never accuracy.
     """
     T, Hq, D = q.shape
     R, max_pages = page_table.shape
@@ -330,110 +334,304 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
     return out.astype(q.dtype)
 
 
-def _ragged_kernel(tr_ref, vis_ref, pt_ref,          # scalar prefetch
-                   q_ref, k_ref, v_ref, *rest, sm_scale, page_size,
-                   q_per_kv, has_scales):
+#: score of a kv slot a token may not see; finite, so no inf - inf anywhere
+_MASK = -0.7 * float(jnp.finfo(jnp.float32).max)
+
+
+def _ragged_tiling(n_tokens: int, q_per_kv: int, page_size: int,
+                   max_pages: int):
+    """Static tiling of rows that hold at most ``n_tokens`` query tokens.
+
+    Returns (bq, nq, mrows, bkp): a row is ``nq`` tiles of ``bq`` tokens;
+    a tile's queries of one KV head are one ``[mrows, D]`` matmul operand
+    (``bq * q_per_kv`` rows, head-major, padded to the bf16 sublane
+    packing); a KV block is ``bkp`` pages. Chunk rows take MXU-sized
+    tiles of 128 tokens against blocks of 256 kv slots; one-token rows
+    are bound by the page copies, so they take blocks of 512 and fewer
+    loop turns. Measured on a v5e at Mistral-7B head shapes (PERF.md,
+    PR 25): 128- and 512-slot blocks for chunks and 256- and 1024-slot
+    blocks for one-token rows were within a fifth of these, 256-token
+    tiles a third slower.
+    """
+    bq = min(128, pl.cdiv(n_tokens, 8) * 8) if n_tokens > 1 else 1
+    nq = pl.cdiv(n_tokens, bq)
+    mrows = pl.cdiv(bq * q_per_kv, 16) * 16
+    bk = 256 if bq > 1 else 512
+    bkp = max(1, min(max_pages, bk // page_size))
+    return bq, nq, mrows, bkp
+
+
+def _ragged_kernel(q_len_ref, kv_len_ref, pt_ref,    # scalar prefetch
+                   q_ref, k_hbm, v_hbm, *rest, sm_scale, row0, bq, nq,
+                   has_scales):
+    """One grid step = one tile: ``bq`` query tokens of ONE row against
+    that row's pages, a block of ``bkp`` pages a loop turn.
+
+    q_ref/o_ref: [1, Hkv, mrows, D] the tile in head-major order (matmul
+    row = q_head_in_group * bq + token); k_hbm/v_hbm: the whole pool,
+    left in HBM; kbuf/vbuf: [2, Hkv, bkp, ps, D] double-buffered blocks,
+    one async copy a page, started a block ahead; a tile's last block
+    starts the NEXT tile's first (``ahead_ref`` carries "started" and
+    the buffer it went to across grid steps), so one-token tiles, which
+    walk one or two blocks, do not wait out each first copy. The loop
+    runs ceil(visible / bk) turns, so pages past the tile's last visible
+    position are neither copied nor multiplied, and only the blocks that
+    straddle the tile's own positions pay for a causal mask. Every
+    vector op is batched over the KV heads: one traced op, unrolled by
+    the compiler, which keeps all heads' matmuls and softmaxes in flight
+    and the program small to trace and lower at start-up.
+    """
     if has_scales:
-        ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
-    else:
-        o_ref, acc_ref, m_ref, l_ref = rest
-    t, pi = pl.program_id(0), pl.program_id(1)
-    n_pages = pl.num_programs(1)
-    vis = vis_ref[t]          # visible kv length of THIS token (0 = pad)
+        ks_ref, vs_ref, *rest = rest
+    o_ref, kbuf, vbuf, sem, ahead_ref, acc_ref, m_ref, l_ref = rest
+    Hkv, mrows, D = acc_ref.shape
+    _, _, bkp, ps, _ = kbuf.shape
+    bk = bkp * ps
+    max_pages = pt_ref.shape[1]
+    cdt = q_ref.dtype                       # MXU operand dtype
 
-    @pl.when(pi == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+    def tile(t):
+        """(row, position of the tile's first token, pages and blocks it
+        walks, how many of those blocks every token sees whole)"""
+        row = row0 + t // nq
+        off = (t % nq) * bq                 # tile's first token in its row
+        q_len, kv_len = q_len_ref[row], kv_len_ref[row]
+        n_valid = jnp.clip(q_len - off, 0, bq)
+        pos0 = kv_len - q_len + off
+        vis = jnp.where(n_valid > 0, pos0 + n_valid, 0)   # slots the last sees
+        n_blocks = pl.cdiv(vis, bk)
+        return (row, pos0, jnp.minimum(pl.cdiv(vis, ps), max_pages), n_blocks,
+                jnp.clip((pos0 + 1) // bk, 0, n_blocks))
 
-    page_start = pi * page_size
-    valid = vis - page_start
+    t, n_tiles = pl.program_id(0), pl.num_programs(0)
+    row, pos0, n_pages, n_blocks, n_full = tile(t)
+    nxt = jnp.minimum(t + 1, n_tiles - 1)
+    nxt_row, _, nxt_pages, nxt_blocks, _ = tile(nxt)
+    nxt_live = jnp.logical_and(t + 1 < n_tiles, nxt_blocks > 0)
 
-    @pl.when(valid > 0)
-    def _page():
-        q = q_ref[0].astype(jnp.float32)          # [Hq, D]
-        k = k_ref[0].astype(jnp.float32)          # [Hkv, ps, D]
-        v = v_ref[0].astype(jnp.float32)
-        if has_scales:
-            k = k * ks_ref[0].astype(jnp.float32)[..., None]
-            v = v * vs_ref[0].astype(jnp.float32)[..., None]
-        Hq = q.shape[0]
-        Hkv = k.shape[0]
-        qg = q.reshape(Hkv, q_per_kv, q.shape[-1])
-        s = lax.dot_general(
-            qg, k, (((2,), (2,)), ((0,), (0,)))) * sm_scale
-        col = lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        s = jnp.where(col < valid, s, _NEG_INF)
-        m_prev = m_ref[:, :1]                     # [Hq, 1]
-        l_prev = l_ref[:, :1]
-        s2 = s.reshape(Hq, page_size)
-        m_new = jnp.maximum(m_prev, s2.max(axis=-1, keepdims=True))
-        p = jnp.where(jnp.isneginf(s2), 0.0, jnp.exp(s2 - m_new))
-        corr = jnp.where(jnp.isneginf(m_prev), 0.0, jnp.exp(m_prev - m_new))
-        l_new = l_prev * corr + p.sum(axis=-1, keepdims=True)
-        pv = lax.dot_general(                      # [Hkv, qpk, D]
-            p.reshape(Hkv, q_per_kv, page_size), v,
-            (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        acc_ref[:] = acc_ref[:] * corr + pv.reshape(Hq, -1)
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+    def copy_pages(row, n_pages, b, slot, wait=False):
+        """Start (or wait for) the copies of block b's live pages."""
+        def page(i, _):
+            src = 0 if wait else pt_ref[row, b * bkp + i]
+            for hbm, buf, s in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
+                cp = pltpu.make_async_copy(hbm.at[src], buf.at[slot, :, i],
+                                           sem.at[s, slot])
+                cp.wait() if wait else cp.start()
+            return 0
+        lax.fori_loop(0, jnp.clip(n_pages - b * bkp, 0, bkp), page, 0)
 
-    @pl.when(pi == n_pages - 1)
-    def _finish():
-        # padding tokens never accumulate: l stays 0 -> output 0
-        l = jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
+    @pl.when(t == 0)
+    def _first():
+        # a masked slot's p is 0, and 0 * (VMEM nobody wrote) may be NaN
+        kbuf[...] = jnp.zeros_like(kbuf)
+        vbuf[...] = jnp.zeros_like(vbuf)
+        ahead_ref[0] = 0                    # nobody started my first block
+        ahead_ref[1] = 0                    # ... which goes to buffer 0
+
+    @pl.when(n_blocks == 0)
+    def _dead():                            # empty row, or a tile past q_len
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(n_blocks > 0)
+    def _live():
+        slot0 = ahead_ref[1]
+
+        @pl.when(ahead_ref[0] == 0)
+        def _():
+            copy_pages(row, n_pages, 0, slot0)
+
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _MASK)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        # kv slot - token, relative to the block's first slot and pos0
+        rel = lax.broadcasted_iota(jnp.int32, (mrows, bk), 1) \
+            - lax.broadcasted_iota(jnp.int32, (mrows, bk), 0) % bq
+
+        def block(b, masked):
+            slot = (slot0 + b) % 2
+
+            @pl.when(b + 1 < n_blocks)
+            def _():
+                copy_pages(row, n_pages, b + 1, 1 - slot)
+
+            @pl.when(jnp.logical_and(b + 1 == n_blocks, nxt_live))
+            def _():                        # the next tile's first block
+                copy_pages(nxt_row, nxt_pages, 0, 1 - slot)
+
+            copy_pages(row, n_pages, b, slot, wait=True)
+            # every op below is batched over the KV heads
+            k, v = kbuf[slot], vbuf[slot]           # [Hkv, bkp, ps, D]
+            if has_scales:              # int8 values are exact in bf16
+                k, v = k.astype(jnp.float32), v.astype(jnp.float32)
+            k = k.reshape(Hkv, bk, D).astype(cdt)
+            v = v.reshape(Hkv, bk, D).astype(cdt)
+            s = lax.dot_general(
+                q_ref[0], k, (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32) * sm_scale
+            if has_scales:              # dequantize the score columns
+                s = s * ks_ref[0, b]
+            if masked:
+                s = jnp.where(rel <= pos0 - b * bk, s, _MASK)
+            m_prev, l_prev = m_ref[:, :, :1], l_ref[:, :, :1]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_new = l_prev * corr + p.sum(axis=-1, keepdims=True)
+            if has_scales:              # ... and the probabilities
+                p = p * vs_ref[0, b]
+            pv = lax.dot_general(
+                p.astype(cdt), v, (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)
+            acc_ref[...] = acc_ref[...] * corr + pv
+            m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+            l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+            return b + 1
+
+        b = lax.fori_loop(0, n_full, lambda _, b: block(b, False), 0)
+        lax.fori_loop(n_full, n_blocks, lambda _, b: block(b, True), b)
+        ahead_ref[0] = nxt_live.astype(jnp.int32)
+        ahead_ref[1] = (slot0 + n_blocks) % 2
+        # slot 0 is visible to every token of a live tile: l >= 1
+        o_ref[0] = (acc_ref[...] / l_ref[:, :, :1]).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
-def _ragged_attention_pallas(q, k_pages, v_pages, page_table,
-                             q_start, q_len, kv_len, k_scale, v_scale,
-                             sm_scale: float, interpret: bool = False):
+def _scale_blocks(scale, page_table, bk: int):
+    """Per-row dequant scales in kv order, cut into the kernel's blocks:
+    [P, Hkv, ps] -> [R, n_blocks, Hkv, 1, bk] fp32 (a lane-dense row a
+    block and head; 1/D of the K/V bytes, gathered by XLA)."""
+    R, max_pages = page_table.shape
+    _, Hkv, ps = scale.shape
+    s = scale[page_table].astype(jnp.float32)     # [R, max_pages, Hkv, ps]
+    s = s.transpose(0, 2, 1, 3).reshape(R, Hkv, max_pages * ps)
+    nb = pl.cdiv(max_pages * ps, bk)
+    s = jnp.pad(s, ((0, 0), (0, 0), (0, nb * bk - max_pages * ps)))
+    return s.reshape(R, Hkv, nb, 1, bk).transpose(0, 2, 1, 3, 4)
+
+
+def _ragged_rows_pallas(q, k_pages, v_pages, page_table, q_start, q_len,
+                        kv_len, k_scale, v_scale, *, row0: int,
+                        n_rows: int, n_tokens: int, sm_scale: float,
+                        interpret: bool):
+    """Attention of rows ``row0 : row0 + n_rows`` (each at most
+    ``n_tokens`` query tokens) -> [n_rows * nq * bq, Hq, D], row-major by
+    (row, token); slots past a row's q_len hold garbage or zeros."""
     T, Hq, D = q.shape
     _, Hkv, ps, _ = k_pages.shape
     max_pages = page_table.shape[1]
-    q_per_kv = Hq // Hkv
-    token_row, token_vis = _token_descriptors(
-        q_start.astype(jnp.int32), q_len.astype(jnp.int32),
-        kv_len.astype(jnp.int32), T)
+    qpk = Hq // Hkv
+    bq, nq, mrows, bkp = _ragged_tiling(n_tokens, qpk, ps, max_pages)
+    n_tiles, bk = n_rows * nq, bkp * ps
 
+    # tile order: [tile, kv head, q head in group * bq + token, D]
+    tok = q_start[row0:row0 + n_rows, None] \
+        + jnp.arange(nq * bq, dtype=jnp.int32)
+    qt = q[jnp.clip(tok, 0, T - 1)].reshape(n_tiles, bq, Hkv, qpk, D)
+    qt = qt.transpose(0, 2, 3, 1, 4).reshape(n_tiles, Hkv, qpk * bq, D)
+    qt = jnp.pad(qt, ((0, 0), (0, 0), (0, mrows - qpk * bq), (0, 0)))
+
+    def tile_map(t, *_):
+        return (t, 0, 0, 0)
+
+    def row_map(t, *_):
+        return (row0 + t // nq, 0, 0, 0, 0)
+
+    tile_spec = pl.BlockSpec((1, Hkv, mrows, D), tile_map)
+    in_specs = [tile_spec, pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY)]
+    operands = [qt, k_pages, v_pages]
     has_scales = k_scale is not None
-    kv_spec = pl.BlockSpec(
-        (1, Hkv, ps, D), lambda t, p, tr, vis, pt: (pt[tr[t], p], 0, 0, 0))
-    in_specs = [
-        pl.BlockSpec((1, Hq, D), lambda t, p, tr, vis, pt: (t, 0, 0)),
-        kv_spec, kv_spec,
-    ]
-    operands = [q, k_pages, v_pages]
     if has_scales:
-        sc_spec = pl.BlockSpec(
-            (1, Hkv, ps), lambda t, p, tr, vis, pt: (pt[tr[t], p], 0, 0))
-        in_specs += [sc_spec, sc_spec]
-        operands += [k_scale, v_scale]
+        ks = _scale_blocks(k_scale, page_table, bk)
+        vs = _scale_blocks(v_scale, page_table, bk)
+        in_specs += [pl.BlockSpec((1,) + ks.shape[1:], row_map)] * 2
+        operands += [ks, vs]
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(T, max_pages),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, Hq, D),
-                               lambda t, p, tr, vis, pt: (t, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((Hq, D), jnp.float32),
-            pltpu.VMEM((Hq, 128), jnp.float32),
-            pltpu.VMEM((Hq, 128), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(_ragged_kernel, sm_scale=sm_scale,
-                               page_size=ps, q_per_kv=q_per_kv,
-                               has_scales=has_scales)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, Hq, D), q.dtype),
+    kv_buf = pltpu.VMEM((2, Hkv, bkp, ps, D), k_pages.dtype)
+    stat = pltpu.VMEM((Hkv, mrows, 128), jnp.float32)
+    # q and o tiles twice (pipelined), the page buffers, fp32 statistics,
+    # and a block's scores and probabilities for all heads. A quarter of
+    # headroom and no more: what the kernel is granted XLA takes from
+    # its own use of VMEM around it (the next layer's weight prefetch)
+    need = 4 * Hkv * mrows * D * q.dtype.itemsize \
+        + 4 * Hkv * bk * D * k_pages.dtype.itemsize \
+        + 3 * Hkv * mrows * 128 * 4 + 3 * Hkv * mrows * max(bk, 128) * 4
+    out = pl.pallas_call(
+        functools.partial(_ragged_kernel, sm_scale=sm_scale, row0=row0,
+                          bq=bq, nq=nq, has_scales=has_scales),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(n_tiles,),
+            in_specs=in_specs,
+            out_specs=tile_spec,
+            scratch_shapes=[
+                kv_buf, kv_buf, pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((2,), jnp.int32),
+                pltpu.VMEM((Hkv, mrows, D), jnp.float32), stat, stat],
+        ),
+        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
+        # half of every tile against a full page table: XLA's scheduler
+        # places the next layer's weight prefetches by this and by the
+        # VMEM limit (with no estimate, or 48 MB of VMEM, the mixed step
+        # compiled to 59 MB more temporaries: PERF.md, PR 25)
+        cost_estimate=pl.CostEstimate(
+            flops=2 * n_tiles * Hkv * mrows * D * max_pages * ps,
+            transcendentals=n_tiles * Hkv * mrows * max_pages * ps // 2,
+            bytes_accessed=2 * qt.size * q.dtype.itemsize
+            + n_tiles * Hkv * max_pages * ps * D * k_pages.dtype.itemsize),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=max(need * 5 // 4, 16 << 20)),
         interpret=interpret,
-    )(token_row, token_vis, page_table, *operands)
+    )(q_len, kv_len, page_table, *operands)
+    out = out[:, :, :qpk * bq].reshape(n_tiles, Hkv, qpk, bq, D)
+    return out.transpose(0, 3, 1, 2, 4).reshape(n_tiles * bq, Hq, D)
+
+
+def _token_rows(q_start, q_len, T: int):
+    """Per token: (does a row own it, that row, its index in the row's
+    span). O(R*T) int compare, inside the jitted wrapper, so the host
+    never builds per-token data."""
+    tvec = jnp.arange(T, dtype=jnp.int32)
+    in_row = (tvec[None, :] >= q_start[:, None]) & \
+             (tvec[None, :] < (q_start + q_len)[:, None])       # [R, T]
+    row = jnp.argmax(in_row, axis=0).astype(jnp.int32)
+    return jnp.any(in_row, axis=0), row, tvec - q_start[row]
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "sm_scale", "max_q_len", "decode_rows", "interpret"))
+def _ragged_attention_pallas(q, k_pages, v_pages, page_table,
+                             q_start, q_len, kv_len, k_scale, v_scale,
+                             sm_scale: float,
+                             max_q_len: Optional[int] = None,
+                             decode_rows: int = 0,
+                             interpret: bool = False):
+    """The blocked kernel over the static tiling the hints give: the
+    first ``decode_rows`` rows as one-token tiles, the others as
+    ceil(max_q_len / bq) tiles of bq tokens. XLA gathers q into tile
+    order and the outputs back into token order (rows may start anywhere
+    in q); tokens no row owns come back zero."""
+    T = q.shape[0]
+    R = page_table.shape[0]
+    q_start, q_len, kv_len = (a.astype(jnp.int32)
+                              for a in (q_start, q_len, kv_len))
+    Rd = min(decode_rows, R)
+    C = min(max_q_len if max_q_len is not None else T, T)
+    call = functools.partial(
+        _ragged_rows_pallas, q, k_pages, v_pages, page_table, q_start,
+        q_len, kv_len, k_scale, v_scale, sm_scale=sm_scale,
+        interpret=interpret)
+    owned, row, j = _token_rows(q_start, q_len, T)
+    out = jnp.zeros_like(q)
+    if R - Rd:
+        o = call(row0=Rd, n_rows=R - Rd, n_tokens=C)
+        slots = o.shape[0] // (R - Rd)             # a row's tiles, in tokens
+        out = o[jnp.maximum(row - Rd, 0) * slots + jnp.clip(j, 0, slots - 1)]
+    if Rd:
+        o = call(row0=0, n_rows=Rd, n_tokens=1)
+        out = jnp.where((row < Rd)[:, None, None],
+                        o[jnp.minimum(row, Rd - 1)], out)
+    return jnp.where(owned[:, None, None], out, 0)
 
 
 def ragged_paged_attention(q, k_pages, v_pages, page_table, q_start,
@@ -473,9 +671,8 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, q_start,
                 max_q_len=max_q_len, decode_rows=decode_rows)
         interpret = False
     return _ragged_attention_pallas(
-        q, k_pages, v_pages, page_table, q_start.astype(jnp.int32),
-        q_len.astype(jnp.int32), kv_len.astype(jnp.int32),
-        k_scale, v_scale, sm_scale, interpret)
+        q, k_pages, v_pages, page_table, q_start, q_len, kv_len,
+        k_scale, v_scale, sm_scale, max_q_len, decode_rows, interpret)
 
 
 # --------------------------------------------------------------------------

@@ -4,9 +4,10 @@ dense per-token oracle, across GQA configs, page-boundary-straddling
 chunks, degenerate single-row batches, and int8-quantized KV pages.
 
 The Pallas kernel runs in interpret mode (pallas_interpret marker) so
-the kernel logic — scalar-prefetched page indexing, per-token causal
-visibility, online softmax across the page grid axis — is exercised in
-tier-1 on CPU.
+the kernel logic — the static tiling from ``decode_rows`` / ``max_q_len``,
+page copies driven by the scalar-prefetched table, the per-tile block
+count and causal mask, online softmax across a tile's KV blocks — is
+exercised in tier-1 on CPU.
 """
 
 import jax
@@ -68,6 +69,14 @@ def _mixed_batch(key, Hq, Hkv, D, ps=8, pages=12, max_pages=4):
     return q, kp, vp, pt, q_start, q_len, kv_len
 
 
+def _unowned(args):
+    """Mask of the padding tokens of a ragged batch (owned by no row)."""
+    owned = np.zeros(args[0].shape[0], bool)
+    for s, l in zip(args[4], args[5]):
+        owned[int(s):int(s) + int(l)] = True
+    return ~owned
+
+
 @pytest.mark.parametrize("Hq,Hkv", [(8, 8), (8, 4), (8, 1)])
 def test_ragged_reference_matches_dense_gqa(Hq, Hkv):
     args = _mixed_batch(jax.random.PRNGKey(Hq * 10 + Hkv), Hq, Hkv, 32)
@@ -79,10 +88,7 @@ def test_ragged_reference_matches_dense_gqa(Hq, Hkv):
     got2 = ragged_paged_attention_reference(*args)
     np.testing.assert_allclose(np.asarray(got2), want, atol=1e-5)
     # padding tokens (owned by no row) must come back exactly zero
-    owned = np.zeros(args[0].shape[0], bool)
-    for s, l in zip(args[4], args[5]):
-        owned[int(s):int(s) + int(l)] = True
-    assert np.all(np.asarray(got)[~owned] == 0.0)
+    assert np.all(np.asarray(got)[_unowned(args)] == 0.0)
 
 
 @pytest.mark.pallas_interpret
@@ -167,6 +173,111 @@ def test_ragged_dispatcher_interpret_path():
     out = ragged_paged_attention(*args, impl="kernel", interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-2)
+
+
+# ------------------------------------------- what blocking can break
+#
+# name -> (Hq, Hkv, T, decode_rows, max_q_len, [(q_start, q_len, kv_len)]).
+# page_size 16, so prefill tiles are min(128, max_q_len rounded up to 8)
+# tokens against blocks of 256 kv slots and decode tiles are one token
+# against blocks of 384 (the whole 24-page table; 512 with a wider one).
+_SMALL_ROWS = [(0, 1, 50), (1, 1, 7), (2, 0, 0), (3, 1, 130), (4, 12, 44),
+               (16, 5, 5)]
+_BLOCKED_CASES = {
+    # a chunk that starts mid-tile in q and is no multiple of the tile
+    "chunk_mid_tile_ragged_len": (
+        8, 2, 176, 2, 160, [(0, 1, 40), (1, 1, 300), (5, 150, 150)]),
+    # cached prefixes that end mid-page and mid-block (70, 200 tokens)
+    "prefix_mid_page_mid_block": (
+        8, 2, 192, 0, 152, [(2, 150, 220), (152, 30, 230)]),
+    # kv_len exactly at a block edge, and one past it
+    "kv_len_at_block_edge": (
+        8, 2, 296, 4, 136,
+        [(0, 1, 256), (1, 1, 257), (2, 1, 128), (3, 1, 129),
+         (4, 128, 128), (132, 129, 129), (261, 28, 128)]),
+    "empty_rows_between_live": (
+        8, 2, 64, 4, 24,
+        [(0, 1, 33), (0, 0, 0), (1, 1, 18), (0, 0, 0),
+         (0, 0, 0), (4, 20, 20), (0, 0, 0), (24, 9, 50)]),
+    # gaps between rows and a long tail that no row owns
+    "padding_tokens_exact_zeros": (
+        8, 2, 96, 1, 16, [(3, 1, 20), (10, 7, 7), (30, 16, 40)]),
+    # the decode loop's shape, with free batch slots
+    "decode_rows_with_empty_slots": (
+        8, 2, 8, 8, 1,
+        [(0, 1, 17), (1, 0, 0), (2, 1, 256), (3, 0, 0), (4, 1, 1),
+         (5, 1, 300), (6, 0, 0), (7, 1, 96)]),
+    # Mistral-7B's head shapes and its tp=4 shard's, at a small T
+    "mistral_heads_32_8": (32, 8, 24, 4, 12, _SMALL_ROWS),
+    "tp4_shard_heads_8_2": (8, 2, 24, 4, 12, _SMALL_ROWS),
+}
+
+
+def _blocked_batch(name, kv, poison_unused_pages=False):
+    """bf16 q and a bf16 or int8 pool for one of _BLOCKED_CASES; every
+    row gets its own pages. ``poison_unused_pages`` points the table
+    entries past a row's length at a page of NaNs."""
+    Hq, Hkv, T, decode_rows, max_q_len, rows = _BLOCKED_CASES[name]
+    D, ps, max_pages, P = 128, 16, 24, 8 * 24 + 2
+    ks = jax.random.split(jax.random.PRNGKey(len(name)), 3)
+    q = jax.random.normal(ks[0], (T, Hq, D), jnp.float32)
+    kp = jax.random.normal(ks[1], (P, Hkv, ps, D), jnp.float32)
+    vp = jax.random.normal(ks[2], (P, Hkv, ps, D), jnp.float32)
+    pt = 1 + np.random.default_rng(len(name)).permutation(
+        len(rows) * max_pages).reshape(len(rows), max_pages)
+    if poison_unused_pages:
+        kp, vp = kp.at[P - 1].set(jnp.nan), vp.at[P - 1].set(jnp.nan)
+        for r, (_, _, kv_len) in enumerate(rows):
+            pt[r, -(-kv_len // ps):] = P - 1
+    q_start, q_len, kv_len = (jnp.array(c, jnp.int32) for c in zip(*rows))
+    q = q.astype(jnp.bfloat16)
+    if kv == "int8":
+        (kp, ksc), (vp, vsc) = quantize_kv(kp), quantize_kv(vp)
+    else:
+        kp, vp, ksc, vsc = (kp.astype(jnp.bfloat16),
+                            vp.astype(jnp.bfloat16), None, None)
+    args = (q, kp, vp, jnp.asarray(pt, jnp.int32), q_start, q_len, kv_len)
+    return args, dict(k_scale=ksc, v_scale=vsc, max_q_len=max_q_len,
+                      decode_rows=decode_rows)
+
+
+@pytest.mark.pallas_interpret
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+@pytest.mark.parametrize("name", list(_BLOCKED_CASES))
+def test_ragged_blocked_kernel_matches_reference(name, kv):
+    args, kw = _blocked_batch(name, kv)
+    ref = ragged_paged_attention_reference(
+        args[0].astype(jnp.float32), *args[1:], **kw)
+    out = ragged_paged_attention(*args, **kw, impl="kernel", interpret=True)
+    assert out.dtype == args[0].dtype and out.shape == args[0].shape
+    # bf16 operands and bf16 probabilities against an fp32 reference
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref), atol=3e-2)
+    assert np.all(np.asarray(out, np.float32)[_unowned(args)] == 0.0)
+
+
+@pytest.mark.pallas_interpret
+@pytest.mark.parametrize("name", ["chunk_mid_tile_ragged_len",
+                                  "decode_rows_with_empty_slots"])
+def test_ragged_blocked_kernel_skips_pages_past_length(name):
+    """Table entries past a row's length may name anything: here a page
+    of NaNs, which one copied page (p = 0 times NaN) would leak."""
+    args, kw = _blocked_batch(name, "bf16", poison_unused_pages=True)
+    want = _dense_oracle(*args)
+    out = ragged_paged_attention(*args, **kw, impl="kernel", interpret=True)
+    np.testing.assert_allclose(np.asarray(out, np.float32), want,
+                               atol=3e-2)
+
+
+def test_ragged_tiling_clamps_to_small_shapes():
+    """Tile sizes follow the shapes: MXU-sized for the engine's chunks,
+    clamped for the tiny chunks and page tables tier-1 runs."""
+    from ray_tpu.ops.paged_attention import _ragged_tiling
+    # (n_tokens, q_per_kv, page_size, max_pages) -> (bq, nq, mrows, bkp)
+    assert _ragged_tiling(512, 4, 16, 144) == (128, 4, 512, 16)
+    assert _ragged_tiling(1, 4, 16, 144) == (1, 1, 16, 32)
+    assert _ragged_tiling(4, 1, 8, 4) == (8, 1, 16, 4)
+    assert _ragged_tiling(130, 2, 32, 3) == (128, 2, 256, 3)
 
 
 # ---------------------------------------------------------------- int8 KV

@@ -59,13 +59,24 @@ def _kernel_calls(lowered) -> int:
     return lowered.compile().as_text().count("tpu_custom_call")
 
 
-def _pool(chip, kv, pages, ps):
+def _pool(chip, kv, pages, ps, hkv=HKV):
     """(k_pages, v_pages, k_scale, v_scale) shapes of one layer's pool."""
     dt = jnp.int8 if kv == "int8" else jnp.bfloat16
-    page = _sds(chip, (pages, HKV, ps, D), dt)
-    scale = _sds(chip, (pages, HKV, ps), jnp.bfloat16) \
+    page = _sds(chip, (pages, hkv, ps, D), dt)
+    scale = _sds(chip, (pages, hkv, ps), jnp.bfloat16) \
         if kv == "int8" else None
     return page, page, scale, scale
+
+
+def _ragged_kernel_calls(chip, kv, *, T, R, max_q_len, decode_rows, ps,
+                         pages, max_pages, hq=HQ, hkv=HKV) -> int:
+    """Pallas calls in the compiled ragged attention of one layer."""
+    k, v, ks, vs = _pool(chip, kv, pages, ps, hkv)
+    row = _sds(chip, (R,), jnp.int32)
+    return _kernel_calls(pa._ragged_attention_pallas.lower(
+        _sds(chip, (T, hq, D), jnp.bfloat16), k, v,
+        _sds(chip, (R, max_pages), jnp.int32), row, row, row, ks, vs,
+        sm_scale=D ** -0.5, max_q_len=max_q_len, decode_rows=decode_rows))
 
 
 @pytest.mark.parametrize("ps", [16, 32])
@@ -73,14 +84,28 @@ def _pool(chip, kv, pages, ps):
 def test_ragged_paged_attention_compiles(chip, kv, ps):
     """The engine's mixed prefill+decode attention: 8 decode rows + 2
     prefill chunks of 128 tokens over a 1k-token page table."""
-    T, R, max_pages = 8 + 2 * 128, 10, 1024 // ps
-    k, v, ks, vs = _pool(chip, kv, 256, ps)
-    row = _sds(chip, (R,), jnp.int32)
-    lowered = pa._ragged_attention_pallas.lower(
-        _sds(chip, (T, HQ, D), jnp.bfloat16), k, v,
-        _sds(chip, (R, max_pages), jnp.int32), row, row, row, ks, vs,
-        sm_scale=D ** -0.5)
-    assert _kernel_calls(lowered) == 1
+    assert _ragged_kernel_calls(
+        chip, kv, T=8 + 2 * 128, R=10, max_q_len=128, decode_rows=8, ps=ps,
+        pages=256, max_pages=1024 // ps) == 2   # one-token and chunk tiles
+
+
+@pytest.mark.parametrize("program,hq,hkv,kv", [
+    ("mixed", 32, 8, "bf16"), ("decode", 32, 8, "bf16"),
+    ("mixed", 8, 2, "bf16"),              # a tp=4 shard's local heads
+    ("mixed", 32, 8, "int8"), ("decode", 32, 8, "int8")])
+def test_ragged_kernel_compiles_at_benchmark_shapes(chip, program, hq, hkv,
+                                                    kv):
+    """The blocked kernel at mistral7b-serve-1chip's shapes (benchmark/
+    configs): the mixed step packs 16 decode rows + 2 chunks of 512 into
+    1040 slots, the decode loop is 16 one-token rows; 640 pages of 16,
+    a 144-page table a row."""
+    max_batch, rows, chunk = 16, 2, 512
+    T, R, max_q_len = (max_batch + rows * chunk, max_batch + rows, chunk) \
+        if program == "mixed" else (max_batch, max_batch, 1)
+    assert _ragged_kernel_calls(
+        chip, kv, T=T, R=R, max_q_len=max_q_len, decode_rows=max_batch,
+        ps=16, pages=640, max_pages=144, hq=hq, hkv=hkv) \
+        == (2 if program == "mixed" else 1)
 
 
 def test_decode_paged_attention_compiles(chip):
@@ -133,6 +158,6 @@ def test_whole_ragged_step_program_compiles(chip):
         _sds(chip, (R, max_seq // ps), jnp.int32), row, row, row, kv,
         cfg=cfg, paged_impl="kernel", max_q_len=chunk,
         decode_rows=max_batch).compile()
-    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.as_text().count("tpu_custom_call") == 2
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16 * 2**30
