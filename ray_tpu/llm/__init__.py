@@ -4,7 +4,9 @@ batching + serve deployment.
 Capability target: the reference's ray.serve.llm stack (reference:
 python/ray/llm/_internal/serve/ — vLLM engine wrapper, deployment,
 OpenAI-style router), rebuilt on JAX/Pallas instead of vLLM/CUDA:
-ops/paged_attention.py is the decode kernel, llm/engine.py the
+ops/paged_attention.py is the ragged paged-attention kernel (prefill
+chunks and decode rows in one call), ops/moe.py the dropless routed-expert
+layer, llm/model.py the one step program both run in, llm/engine.py the
 continuous-batching loop, llm/serve_llm.py the serve deployment.
 
 Submodules import lazily (PEP 562): the jax-heavy engine/serve stack

@@ -264,6 +264,11 @@ class InferenceEngine:
                       "ragged_dispatches": 0, "ragged_real_tokens": 0,
                       "ragged_slot_tokens": 0, "cow_copies": 0,
                       "preemptions": 0}
+        # counters the step programs reduce on the device and append to
+        # the tokens they return (none for a dense model): one stats key
+        # each, and metadata of the dispatch's engine.readback span
+        self._step_counters = M.step_counters(cfg)
+        self.stats.update(dict.fromkeys(self._step_counters, 0))
         # per-request flight recorder (llm/request_log.py): lifecycle
         # event stream per request + TTFT/TPOT/e2e/queue-wait histograms
         # + SLO attainment; None disables every hook (seq.record stays
@@ -584,8 +589,9 @@ class InferenceEngine:
         with TraceAnnotation("engine.dispatch"):
             nxt, self.kv = self._fns.ragged_step(self.params, *args,
                                                  self.kv)
-        with TraceAnnotation("engine.readback"):
+        with TraceAnnotation("engine.readback") as span:
             nxt = np.asarray(nxt)                  # [R], ONE readback
+            nxt = self._note_counters(nxt, R, span)
         with TraceAnnotation("engine.book") as span:
             n_done = len(finished) + len(self._finished_at_prefill)
             now = time.monotonic()
@@ -817,8 +823,10 @@ class InferenceEngine:
             toks_out, self.kv, _, _ = self._fns.decode_loop(
                 self.params, tokens, positions, self.kv, page_table,
                 seq_lens)
-        with TraceAnnotation("engine.readback"):
+        with TraceAnnotation("engine.readback") as span:
             block = np.asarray(toks_out)           # [K, B], ONE readback
+            block = self._note_counters(
+                block, K * self.max_batch, span).reshape(K, self.max_batch)
         with TraceAnnotation("engine.book") as span:
             n_done = len(finished)
             now = time.monotonic()
@@ -860,6 +868,20 @@ class InferenceEngine:
                     self._positions[slot] = seq.num_tokens - 1
             if span.is_enabled():
                 span.set_metadata(finished=len(finished) - n_done)
+
+    def _note_counters(self, out: np.ndarray, n_tokens: int, span):
+        """Split a step program's flat output into its tokens and the
+        counters behind them; the counters go into ``stats`` and onto the
+        readback span of this dispatch."""
+        if self._step_counters:
+            got = dict(zip(self._step_counters,
+                           out.reshape(-1)[n_tokens:].tolist()))
+            for key, n in got.items():
+                self.stats[key] += n
+            if span.is_enabled():
+                span.set_metadata(**got)
+            out = out.reshape(-1)[:n_tokens]
+        return out
 
     def drain_progress(self) -> Dict[str, List[int]]:
         """Tokens generated since the previous drain, per request id

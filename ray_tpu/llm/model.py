@@ -1,9 +1,18 @@
-"""Cache-aware Llama forward passes for inference.
+"""The serving step: one cache-aware decoder forward for inference.
 
 Role-equivalent to the reference's vLLM model executor (reference:
 llm/_internal/serve/deployments/llm/vllm/ — the reference ships no model
-code in-tree), rebuilt on ray_tpu's functional Llama (models/llama.py —
+code in-tree), rebuilt on ray_tpu's functional decoder (models/llama.py —
 same params pytree, so training checkpoints serve directly).
+
+ONE layer body with three variation points, each read from the
+configuration (LlamaConfig; the defaults are the Llama/Mistral block):
+an RMSNorm on the projected q and k before the rotary embedding
+(``qk_norm``), the feed-forward as dense SwiGLU or as dropless routed
+experts (``n_experts``, ops/moe.py), and the logits from the embedding
+table or from an ``lm_head`` of their own (``tie_embeddings``). A dense
+configuration lowers to the program it lowered to before the points
+existed. OLMoE-1B-7B is the first block that sets all three.
 
 ONE step program for everything (`_ragged_step_body`): the engine packs
 decode tokens and prefill-chunk tokens into a single RAGGED batch
@@ -38,7 +47,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ray_tpu.llm.cache import SCRATCH_PAGE
 from ray_tpu.models.llama import LlamaConfig, Params, _rmsnorm, _rope
+from ray_tpu.ops import moe
 from ray_tpu.ops.paged_attention import (ragged_paged_attention,
                                          write_ragged_kv)
 
@@ -58,6 +69,10 @@ def _project_qkv(lp, h, cfg: LlamaConfig):
     q = h @ lp["wq"].astype(cd)
     k = h @ lp["wk"].astype(cd)
     v = h @ lp["wv"].astype(cd)
+    if cfg.qk_norm:
+        # over the whole projected vector, before the split into heads
+        q = _rmsnorm(q, lp["q_norm"], cfg.norm_eps)
+        k = _rmsnorm(k, lp["k_norm"], cfg.norm_eps)
     q = q.reshape(B, L, q.shape[-1] // hd, hd)
     k = k.reshape(B, L, k.shape[-1] // hd, hd)
     v = v.reshape(B, L, v.shape[-1] // hd, hd)
@@ -74,16 +89,37 @@ def _mlp(lp, x, cfg: LlamaConfig, tp_axis=None):
     return x + _maybe_psum((gate * up) @ lp["w_down"].astype(cd), tp_axis)
 
 
-def _ragged_step_body(params: Params, tokens: jax.Array,
-                      token_pos: jax.Array, token_page: jax.Array,
-                      token_slot: jax.Array, page_table: jax.Array,
-                      q_start: jax.Array, q_len: jax.Array,
-                      kv_len: jax.Array, kv: KVCache, cfg: LlamaConfig,
-                      tp_axis: Optional[str] = None,
-                      paged_impl: Optional[str] = None,
-                      max_q_len: Optional[int] = None,
-                      decode_rows: int = 0,
-                      ) -> Tuple[jax.Array, KVCache]:
+def _moe_mlp(lp, experts, layer, x, valid, cfg: LlamaConfig, impl):
+    """The feed-forward as routed experts (ops/moe.py): ``experts`` is the
+    stacked [L, E, ...] weights, whole, and ``layer`` the index into
+    them; ``impl`` is the step's kernel-or-reference choice, the paged
+    attention's. Returns (x', the layer's routing counters)."""
+    h = _rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
+    y, counters = moe.moe_ffn(
+        h[0], valid, lp["router"], experts["w_gate"], experts["w_up"],
+        experts["w_down"], cfg.experts_per_token, cfg.norm_topk_prob,
+        layer=layer, impl=impl)
+    return x + y[None], counters
+
+
+#: the expert weights stay out of the layer scan's sliced inputs
+_EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
+
+
+def step_counters(cfg: LlamaConfig) -> Tuple[str, ...]:
+    """Names of the counters the step programs append to their tokens."""
+    return moe.COUNTERS if cfg.n_experts else ()
+
+
+def _ragged_forward(params: Params, tokens: jax.Array,
+                    token_pos: jax.Array, token_page: jax.Array,
+                    token_slot: jax.Array, page_table: jax.Array,
+                    q_start: jax.Array, q_len: jax.Array,
+                    kv_len: jax.Array, kv: KVCache, cfg: LlamaConfig,
+                    tp_axis: Optional[str] = None,
+                    paged_impl: Optional[str] = None,
+                    max_q_len: Optional[int] = None,
+                    decode_rows: int = 0):
     """ONE forward over a ragged mixed prefill+decode batch.
 
     tokens/token_pos: [T] the ragged token ids and absolute positions;
@@ -93,10 +129,14 @@ def _ragged_step_body(params: Params, tokens: jax.Array,
     (ops.paged_attention). kv: the pool dict — DONATED by every caller
     (an undonated pool copies multi-GB per step).
 
-    Returns (next_tok [R], kv): per row, argmax logits at its LAST valid
-    token — the next decode token for q_len==1 rows, the first sampled
-    token for a prefill chunk that just finished its prompt. Fused
+    Returns (next_tok [R], kv, counters): per row, argmax logits at its
+    LAST valid token — the next decode token for q_len==1 rows, the first
+    sampled token for a prefill chunk that just finished its prompt. Fused
     in-program so the whole mixed step is ONE dispatch + ONE readback.
+    ``counters`` is None for a dense configuration; with experts it is
+    the step's routing counters (ops.moe.COUNTERS, summed over layers,
+    valid tokens only: a padding token is one whose page is the scratch
+    page).
 
     Per layer: project/rope the ragged tokens, scatter their K/V into
     the pool (quantizing to int8 + scales when the pool carries scale
@@ -108,9 +148,16 @@ def _ragged_step_body(params: Params, tokens: jax.Array,
     cd = cfg.dtype
     x = params["embed"].astype(cd)[tokens][None]          # [1, T, d]
     quantized = "k_scale" in kv
+    layers = params["layers"]
+    if cfg.n_experts:
+        # closed over, not scanned: a scan slices its inputs, and a slice
+        # handed to the expert kernel is a copy of a layer's experts
+        experts = {k: layers[k] for k in _EXPERT_LEAVES}
+        layers = {k: v for k, v in layers.items() if k not in experts}
+        valid = token_page != SCRATCH_PAGE
 
     def layer(x, inp):
-        lp, kv_l = inp
+        lp, kv_l = inp[:2]
         h = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
         q, k, v = _project_qkv(lp, h, cfg)                # [1, T, H, D]
         q = _rope(q, token_pos, cfg.rope_theta)
@@ -124,20 +171,53 @@ def _ragged_step_body(params: Params, tokens: jax.Array,
             decode_rows=decode_rows, impl=paged_impl)
         o = o.reshape(1, T, -1).astype(cd)
         x = x + _maybe_psum(o @ lp["wo"].astype(cd), tp_axis)
-        x = _mlp(lp, x, cfg, tp_axis)
         kv_out = {"k": kc, "v": vc}
         if quantized:
             kv_out["k_scale"], kv_out["v_scale"] = ksc, vsc
+        if cfg.n_experts:
+            x, counters = _moe_mlp(lp, experts, inp[2], x, valid, cfg,
+                                   paged_impl)
+            return x, (kv_out, counters)
+        x = _mlp(lp, x, cfg, tp_axis)
         return x, kv_out
 
-    x, kv = lax.scan(layer, x, (params["layers"], kv))
+    counters = None
+    if cfg.n_experts:
+        x, (kv, per_layer) = lax.scan(
+            layer, x, (layers, kv, jnp.arange(cfg.n_layers, dtype=jnp.int32)))
+        counters = per_layer.sum(axis=0)
+    else:
+        x, kv = lax.scan(layer, x, (layers, kv))
     x = _rmsnorm(x, params["final_norm"], cfg.norm_eps)
     last = jnp.clip(q_start + q_len - 1, 0, T - 1)        # [R]
     xl = x[0][last]
-    logits = jnp.einsum("rd,vd->rv", xl.astype(cd),
-                        params["embed"].astype(cd),
+    head = params["embed"] if cfg.tie_embeddings else params["lm_head"]
+    logits = jnp.einsum("rd,vd->rv", xl.astype(cd), head.astype(cd),
                         preferred_element_type=jnp.float32)
-    return jnp.argmax(logits, axis=-1).astype(jnp.int32), kv
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32), kv, counters
+
+
+def _ragged_step_body(params: Params, tokens: jax.Array,
+                      token_pos: jax.Array, token_page: jax.Array,
+                      token_slot: jax.Array, page_table: jax.Array,
+                      q_start: jax.Array, q_len: jax.Array,
+                      kv_len: jax.Array, kv: KVCache, cfg: LlamaConfig,
+                      tp_axis: Optional[str] = None,
+                      paged_impl: Optional[str] = None,
+                      max_q_len: Optional[int] = None,
+                      decode_rows: int = 0,
+                      ) -> Tuple[jax.Array, KVCache]:
+    """The mixed step's program: ``_ragged_forward``, with a step's
+    counters (if its configuration has any) appended to the tokens, so
+    both ride the one device->host transfer: (out [R (+ n counters)], kv).
+    """
+    nxt, kv, counters = _ragged_forward(
+        params, tokens, token_pos, token_page, token_slot, page_table,
+        q_start, q_len, kv_len, kv, cfg, tp_axis, paged_impl, max_q_len,
+        decode_rows)
+    if counters is not None:
+        nxt = jnp.concatenate([nxt, counters])
+    return nxt, kv
 
 
 def _ragged_decode_loop(params: Params, tokens: jax.Array,
@@ -159,7 +239,9 @@ def _ragged_decode_loop(params: Params, tokens: jax.Array,
 
     Returns (tokens_out [num_steps, B], kv, final_positions,
     final_seq_lens) — positions/seq_lens advance by num_steps so the
-    next block chains without host recomputation.
+    next block chains without host recomputation. With experts,
+    tokens_out is flat [num_steps * B + n counters]: the tokens, then the
+    dispatch's counters summed over its steps (one transfer, as above).
     """
     R = tokens.shape[0]
     ps = kv["k"].shape[3]
@@ -172,14 +254,18 @@ def _ragged_decode_loop(params: Params, tokens: jax.Array,
         page_idx = jnp.clip(pos // ps, 0, max_pages - 1)
         token_page = page_table[ar, page_idx]
         token_slot = pos % ps
-        nxt, kv = _ragged_step_body(
+        nxt, kv, counters = _ragged_forward(
             params, tok, pos, token_page, token_slot, page_table,
             ar, ones, lens, kv, cfg, tp_axis, paged_impl,
             max_q_len=1, decode_rows=R)
-        return (nxt, pos + 1, kv, lens + 1), nxt
+        out = nxt if counters is None else (nxt, counters)
+        return (nxt, pos + 1, kv, lens + 1), out
 
     (_, positions, kv, seq_lens), toks_out = lax.scan(
         one, (tokens, positions, kv, seq_lens), None, length=num_steps)
+    if cfg.n_experts:
+        toks, counters = toks_out
+        toks_out = jnp.concatenate([toks.reshape(-1), counters.sum(axis=0)])
     return toks_out, kv, positions, seq_lens
 
 

@@ -58,7 +58,9 @@ def tp_param_specs(cfg: LlamaConfig) -> Params:
     col = P(None, None, TP_AXIS)   # [L, d, out] — shard out
     row = P(None, TP_AXIS, None)   # [L, in, d]  — shard in, psum after
     rep2 = P(None, None)
+    head = {} if cfg.tie_embeddings else {"lm_head": rep2}
     return {
+        **head,
         "embed": rep2,
         "layers": {
             "attn_norm": rep2,
@@ -73,6 +75,13 @@ def tp_param_specs(cfg: LlamaConfig) -> Params:
 def validate_tp(cfg: LlamaConfig, tp: int) -> None:
     if tp < 2:
         raise ValueError(f"tp must be >= 2 for a sharded engine, got {tp}")
+    if cfg.n_experts or cfg.qk_norm:
+        raise NotImplementedError(
+            f"tp={tp} is not served for this block: qk_norm normalises "
+            f"over all heads, which a head shard can only do with a "
+            f"collective the step does not have, and n_experts="
+            f"{cfg.n_experts} needs an expert-parallel layout, not the "
+            f"Megatron column/row split (ROADMAP R5)")
     if cfg.n_kv_heads % tp or cfg.n_heads % tp:
         raise ValueError(
             f"tp={tp} must divide n_heads={cfg.n_heads} and "

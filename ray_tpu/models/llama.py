@@ -54,6 +54,21 @@ class LlamaConfig:
     pp_microbatches: int = 4         # microbatch count when pp > 1
     fsdp_overlap: bool = False       # explicit prefetch-scheduled fsdp step
     int8_mlp: bool = False           # dynamic-W8A8 MLP matmuls (ops.int8)
+    # The block's variation points. The defaults are the Llama/Mistral
+    # block; the serving step (llm/model.py) follows them, the training
+    # forward below refuses what it has not got (_require_llama_block).
+    n_experts: int = 0               # 0 = dense SwiGLU; else routed experts
+    experts_per_token: int = 0       #   of width ffn_dim, this many a token
+    norm_topk_prob: bool = False     # renormalise the chosen experts' weights
+    qk_norm: bool = False            # RMSNorm on the projected q and k
+    tie_embeddings: bool = True      # logits from embed, else from lm_head
+
+    def __post_init__(self):
+        if self.n_experts and not \
+                0 < self.experts_per_token <= self.n_experts:
+            raise ValueError(
+                f"n_experts={self.n_experts} needs 0 < experts_per_token "
+                f"<= n_experts, got {self.experts_per_token}")
 
     @property
     def head_dim(self) -> int:
@@ -61,7 +76,14 @@ class LlamaConfig:
 
     @staticmethod
     def tiny(**kw) -> "LlamaConfig":
-        """Test-scale config for the virtual CPU mesh."""
+        """Test-scale config for the virtual CPU mesh; the serving entry
+        points build theirs here from a user's ``model_config``, so a key
+        that is no field is refused by name."""
+        names = [f.name for f in dataclasses.fields(LlamaConfig)]
+        unknown = sorted(set(kw) - set(names))
+        if unknown:
+            raise ValueError(f"unknown model_config key(s) {unknown}; "
+                             f"LlamaConfig has {names}")
         base = dict(vocab_size=256, dim=64, n_layers=4, n_heads=8,
                     n_kv_heads=4, ffn_dim=128, rope_theta=10000.0)
         base.update(kw)
@@ -88,7 +110,9 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Params:
         fan_in = fan_in if fan_in is not None else shape[-2]
         return (jax.random.normal(k, shape) * (fan_in ** -0.5)).astype(pd)
 
-    return {
+    # experts: the three MLP leaves gain an expert axis after the layer axis
+    E = (cfg.n_experts,) if cfg.n_experts else ()
+    params = {
         "embed": dense(ks[0], cfg.vocab_size, d, fan_in=d),
         "layers": {
             "attn_norm": norm_init(L, d),
@@ -97,12 +121,32 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Params:
             "wv": dense(ks[3], L, d, hkv * hd),
             "wo": dense(ks[4], L, hq * hd, d),
             "mlp_norm": norm_init(L, d),
-            "w_gate": dense(ks[5], L, d, f),
-            "w_up": dense(ks[6], L, d, f),
-            "w_down": dense(ks[7], L, f, d),
+            "w_gate": dense(ks[5], L, *E, d, f),
+            "w_up": dense(ks[6], L, *E, d, f),
+            "w_down": dense(ks[7], L, *E, f, d),
         },
         "final_norm": norm_init(d),
     }
+    # the leaves the variation points add, keyed off the same key without
+    # moving the dense tree's draws
+    if cfg.n_experts:
+        params["layers"]["router"] = dense(
+            jax.random.fold_in(key, 8), L, d, cfg.n_experts)
+    if cfg.qk_norm:
+        params["layers"]["q_norm"] = norm_init(L, hq * hd)
+        params["layers"]["k_norm"] = norm_init(L, hkv * hd)
+    if not cfg.tie_embeddings:
+        params["lm_head"] = dense(jax.random.fold_in(key, 9),
+                                  cfg.vocab_size, d, fan_in=d)
+    return params
+
+
+def _require_llama_block(cfg: LlamaConfig, what: str) -> None:
+    if cfg.n_experts or cfg.qk_norm or not cfg.tie_embeddings:
+        raise NotImplementedError(
+            f"{what} is written for the Llama/Mistral block; n_experts, "
+            f"qk_norm and an untied head are served by llm/model.py only "
+            f"(training them is models/mixtral.py's side, ROADMAP R5)")
 
 
 def param_specs(cfg: LlamaConfig) -> Params:
@@ -112,6 +156,7 @@ def param_specs(cfg: LlamaConfig) -> Params:
     gather), head/ffn-hidden dims over tp (Megatron) — the §2.6 inventory's
     TPU-native equivalents.
     """
+    _require_llama_block(cfg, "param_specs")
     return {
         "embed": P("tp", "fsdp"),
         "layers": {
@@ -237,6 +282,7 @@ def forward(params: Params, tokens: jax.Array, cfg: LlamaConfig,
     layer axis sharded over 'pp'); with attention='full' and pp==1 the whole
     forward is a single GSPMD program.
     """
+    _require_llama_block(cfg, "models.llama.forward")
     B, L = tokens.shape
     cd = cfg.dtype
     x = params["embed"].astype(cd)[tokens]
@@ -426,6 +472,7 @@ def loss_fn(params: Params, tokens: jax.Array, cfg: LlamaConfig,
 
 
 def num_params(cfg: LlamaConfig) -> int:
+    _require_llama_block(cfg, "num_params")
     d, L, f = cfg.dim, cfg.n_layers, cfg.ffn_dim
     hd = cfg.head_dim
     per_layer = (d * cfg.n_heads * hd + 2 * d * cfg.n_kv_heads * hd

@@ -161,3 +161,47 @@ def test_whole_ragged_step_program_compiles(chip):
     assert compiled.as_text().count("tpu_custom_call") == 2
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16 * 2**30
+
+
+@pytest.mark.parametrize("program", ["mixed", "decode"])
+def test_olmoe_step_programs_compile_at_benchmark_shapes(chip, program):
+    """olmoe-1b7b-serve-1chip's two step programs at its published widths
+    (2 of its 12 layers; shapes from jax.eval_shape): 64 experts top-8 of
+    width 1024 through the dropless expert kernel, q/k norm, the untied
+    head, and the blocked paged kernel at 16 KV heads with one query head
+    each. 32 decode rows, 2 chunks of 512, 1280 pages of 16."""
+    from ray_tpu.llm import model as M
+    from ray_tpu.llm.cache import make_kv_cache
+    from ray_tpu.models.llama import LlamaConfig, init_params
+    cfg = LlamaConfig(vocab_size=50304, dim=2048, n_layers=2, n_heads=16,
+                      n_kv_heads=16, ffn_dim=1024, rope_theta=10000.0,
+                      param_dtype="bfloat16", n_experts=64,
+                      experts_per_token=8, qk_norm=True,
+                      tie_embeddings=False)
+    max_batch, rows, chunk, ps, pages, max_seq = 32, 2, 512, 16, 1280, 1536
+
+    def abstract(fn):
+        return jax.tree.map(lambda a: _sds(chip, a.shape, a.dtype),
+                            jax.eval_shape(fn))
+
+    params = abstract(functools.partial(init_params, cfg,
+                                        jax.random.PRNGKey(0)))
+    kv = abstract(functools.partial(make_kv_cache, cfg, pages, ps))
+    table = functools.partial(_sds, chip, dtype=jnp.int32)
+    if program == "mixed":
+        T, R = max_batch + rows * chunk, max_batch + rows
+        tok, row = table((T,)), table((R,))
+        compiled = M.ragged_step.lower(
+            params, tok, tok, tok, tok, table((R, max_seq // ps)), row, row,
+            row, kv, cfg=cfg, paged_impl="kernel", max_q_len=chunk,
+            decode_rows=max_batch).compile()
+        kernels, out = 3, (R + 3,)      # chunk tiles, one-token tiles, experts
+    else:
+        row = table((max_batch,))
+        compiled = M.ragged_decode_loop.lower(
+            params, row, row, kv, table((max_batch, max_seq // ps)), row,
+            num_steps=8, cfg=cfg, paged_impl="kernel").compile()
+        kernels, out = 2, (8 * max_batch + 3,)
+    assert compiled.as_text().count("tpu_custom_call") == kernels
+    assert "_moe_experts_pallas" in compiled.as_text()
+    assert jax.tree.leaves(compiled.out_info)[0].shape == out
