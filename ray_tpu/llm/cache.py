@@ -243,6 +243,13 @@ def make_kv_cache(cfg: LlamaConfig, total_pages: int, page_size: int,
     dequant scales ride alongside — one pytree, so jit donation,
     shard_map specs and COW copies treat pages + scales as one unit.
     ``kv_dtype`` in {None/"model" (cfg dtype), "int8"}.
+
+    The discipline (llm/model.py): one buffer in this one row-major
+    layout for every program. The step programs take it donated, carry
+    it whole through their scans and update it in place — a layer is
+    written (``_kv_write_pallas``, whole pages by DMA) and read (the
+    attention kernel) by its index into the stack, never sliced out of
+    it; a token is one row of each head's [page_size, D] tile.
     """
     if kv_dtype not in (None, "model", "int8"):
         raise ValueError(f"kv_dtype must be 'model' or 'int8', "

@@ -24,10 +24,16 @@ attends; per row the last valid token's logits argmax fuses in-program,
 so a finishing prefill chunk's first token and every decode row's next
 token come back in ONE readback.
 
-The KV pool is a dict pytree {"k", "v"[, "k_scale", "v_scale"]} —
-layers stacked on the leading axis and threaded through the layer scan
-as scan xs/ys with jit donation (the decode-path discipline PR 3
-measured at ~4 ms/step vs 140 ms/step undonated).
+The KV pool is a dict pytree {"k", "v"[, "k_scale", "v_scale"]},
+layers stacked on the leading axis: ONE buffer in ONE layout, donated
+to the step program and updated in place. It is a CARRY of the layer
+scan (and so of the decode loop's step scan), never a scanned input or
+a stacked output: a layer is written and read by its INDEX, the write
+(`_kv_write_pallas`, aliased in to out) and the attention kernel both
+taking the whole stacked pool, so no layer is sliced out, converted to
+another layout or stacked back, and no step copies the pool (threaded
+as scan xs/ys it moved ~4 times a step: PERF.md, PR 27). Only the int8
+pool's scale leaves, which XLA reads, are scattered by XLA.
 
 Tensor parallelism (``tp_axis``): the step also runs INSIDE a
 ``shard_map`` block whose weights arrive pre-sliced Megatron-style
@@ -138,9 +144,9 @@ def _ragged_forward(params: Params, tokens: jax.Array,
     valid tokens only: a padding token is one whose page is the scratch
     page).
 
-    Per layer: project/rope the ragged tokens, scatter their K/V into
-    the pool (quantizing to int8 + scales when the pool carries scale
-    leaves), then ragged attention over the pool — each token causally
+    Per layer: project/rope the ragged tokens, write their K/V into
+    that layer of the pool in place (quantizing to int8 + scales when
+    the pool carries scale leaves), then ragged attention over it — each token causally
     sees its row's pages up to its own position, so a chunk's tokens see
     the prefix AND earlier tokens of the same chunk (just written).
     """
@@ -156,38 +162,37 @@ def _ragged_forward(params: Params, tokens: jax.Array,
         layers = {k: v for k, v in layers.items() if k not in experts}
         valid = token_page != SCRATCH_PAGE
 
-    def layer(x, inp):
-        lp, kv_l = inp[:2]
+    def layer(carry, inp):
+        x, kv = carry
+        lp, l = inp
         h = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
         q, k, v = _project_qkv(lp, h, cfg)                # [1, T, H, D]
         q = _rope(q, token_pos, cfg.rope_theta)
         k = _rope(k, token_pos, cfg.rope_theta)
+        hints = dict(layer=l, max_q_len=max_q_len, decode_rows=decode_rows,
+                     impl=paged_impl)
         kc, vc, ksc, vsc = write_ragged_kv(
-            kv_l["k"], kv_l["v"], k[0], v[0], token_page, token_slot,
-            kv_l.get("k_scale"), kv_l.get("v_scale"))
+            kv["k"], kv["v"], k[0], v[0], token_page, token_slot,
+            kv.get("k_scale"), kv.get("v_scale"), q_start=q_start,
+            q_len=q_len, **hints)
         o = ragged_paged_attention(
             q[0], kc, vc, page_table, q_start, q_len, kv_len,
-            k_scale=ksc, v_scale=vsc, max_q_len=max_q_len,
-            decode_rows=decode_rows, impl=paged_impl)
+            k_scale=ksc, v_scale=vsc, **hints)
         o = o.reshape(1, T, -1).astype(cd)
         x = x + _maybe_psum(o @ lp["wo"].astype(cd), tp_axis)
-        kv_out = {"k": kc, "v": vc}
+        kv = {"k": kc, "v": vc}
         if quantized:
-            kv_out["k_scale"], kv_out["v_scale"] = ksc, vsc
+            kv["k_scale"], kv["v_scale"] = ksc, vsc
         if cfg.n_experts:
-            x, counters = _moe_mlp(lp, experts, inp[2], x, valid, cfg,
-                                   paged_impl)
-            return x, (kv_out, counters)
-        x = _mlp(lp, x, cfg, tp_axis)
-        return x, kv_out
+            x, counters = _moe_mlp(lp, experts, l, x, valid, cfg, paged_impl)
+            return (x, kv), counters
+        return (_mlp(lp, x, cfg, tp_axis), kv), None
 
-    counters = None
-    if cfg.n_experts:
-        x, (kv, per_layer) = lax.scan(
-            layer, x, (layers, kv, jnp.arange(cfg.n_layers, dtype=jnp.int32)))
-        counters = per_layer.sum(axis=0)
-    else:
-        x, kv = lax.scan(layer, x, (layers, kv))
+    # the pool rides the scan as a carry, whole: each layer writes and
+    # reads it at its index
+    (x, kv), per_layer = lax.scan(
+        layer, (x, kv), (layers, jnp.arange(cfg.n_layers, dtype=jnp.int32)))
+    counters = per_layer.sum(axis=0) if cfg.n_experts else None
     x = _rmsnorm(x, params["final_norm"], cfg.norm_eps)
     last = jnp.clip(q_start + q_len - 1, 0, T - 1)        # [R]
     xl = x[0][last]

@@ -128,9 +128,10 @@ class TPEngineFns:
 
         def step(params, tokens, token_pos, token_page, token_slot,
                  page_table, q_start, q_len, kv_len, kv):
-            # per-shard: local kv-heads write their ragged K/V slice and
-            # attend over the local head slice of the page pool; the two
-            # psums per layer inside _ragged_step_body close the TP seam
+            # per-shard: local kv-heads write their ragged K/V slice in
+            # place into, and attend over, the local head slice of the
+            # stacked page pool (the scans' carry); the two psums per
+            # layer inside _ragged_step_body close the TP seam
             return M._ragged_step_body(
                 params, tokens, token_pos, token_page, token_slot,
                 page_table, q_start, q_len, kv_len, kv, cfg, TP_AXIS,

@@ -39,7 +39,12 @@ scalar-prefetch pattern:
     values to the MXU as bf16 (exact) and applies the scales to the
     score columns and to the probabilities, so no dequantized K/V copy
     exists; int8 halves KV HBM per token;
-  - GQA: the query heads of one KV head share each K/V fetch.
+  - GQA: the query heads of one KV head share each K/V fetch;
+  - the ragged paths take one layer's pool or the whole STACKED pool
+    ``[L, P, Hkv, ps, D]`` with a layer index (scalar prefetch): the
+    step programs carry the pool whole and never slice a layer out for
+    a custom call (a copy of it), and ``write_ragged_kv`` updates it in
+    place (``_kv_write_pallas``, pool aliased in to out).
 
 The ``*_reference`` functions are the pure-JAX gather equivalents — the
 numerics oracles and the portable fallbacks on CPU test meshes.
@@ -255,12 +260,15 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
                                      k_scale=None, v_scale=None,
                                      sm_scale: Optional[float] = None,
                                      max_q_len: Optional[int] = None,
-                                     decode_rows: int = 0) -> jax.Array:
+                                     decode_rows: int = 0,
+                                     layer=None) -> jax.Array:
     """Gather-based ragged paged attention (oracle + CPU fallback).
 
     q: [T, Hq, D]; k/v_pages: [P, Hkv, ps, D] (int8 when scales given);
     k/v_scale: [P, Hkv, ps] per-(page, head, slot) dequant scales or
-    None; page_table: [R, max_pages]; q_start/q_len/kv_len: [R].
+    None; page_table: [R, max_pages]; q_start/q_len/kv_len: [R]. With
+    ``layer`` the pool and scales are the stacked ones ([L, P, ...]) and
+    the rows' pages are gathered from that layer.
 
     ``decode_rows``/``max_q_len`` are the STATIC tiling hints (see
     "Ragged batch layout"): the first ``decode_rows`` rows must have
@@ -271,20 +279,19 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
     """
     T, Hq, D = q.shape
     R, max_pages = page_table.shape
-    _, Hkv, ps, _ = k_pages.shape
+    Hkv, ps, _ = k_pages.shape[-3:]
     if sm_scale is None:
         sm_scale = D ** -0.5
     max_kv = max_pages * ps
     qpk = Hq // Hkv
+    at = page_table if layer is None else (layer, page_table)
 
     # one page gather per row -> [R, Hkv, max_kv, D] fp32 (dequantized)
-    kr = k_pages[page_table]                     # [R, mp, Hkv, ps, D]
-    vr = v_pages[page_table]
-    kr = kr.astype(jnp.float32)
-    vr = vr.astype(jnp.float32)
+    kr = k_pages[at].astype(jnp.float32)         # [R, mp, Hkv, ps, D]
+    vr = v_pages[at].astype(jnp.float32)
     if k_scale is not None:
-        kr = kr * k_scale[page_table].astype(jnp.float32)[..., None]
-        vr = vr * v_scale[page_table].astype(jnp.float32)[..., None]
+        kr = kr * k_scale[at].astype(jnp.float32)[..., None]
+        vr = vr * v_scale[at].astype(jnp.float32)[..., None]
     kr = kr.transpose(0, 2, 1, 3, 4).reshape(R, Hkv, max_kv, D)
     vr = vr.transpose(0, 2, 1, 3, 4).reshape(R, Hkv, max_kv, D)
 
@@ -361,15 +368,17 @@ def _ragged_tiling(n_tokens: int, q_per_kv: int, page_size: int,
     return bq, nq, mrows, bkp
 
 
-def _ragged_kernel(q_len_ref, kv_len_ref, pt_ref,    # scalar prefetch
+def _ragged_kernel(layer_ref, q_len_ref, kv_len_ref, pt_ref,   # prefetch
                    q_ref, k_hbm, v_hbm, *rest, sm_scale, row0, bq, nq,
                    has_scales):
     """One grid step = one tile: ``bq`` query tokens of ONE row against
     that row's pages, a block of ``bkp`` pages a loop turn.
 
     q_ref/o_ref: [1, Hkv, mrows, D] the tile in head-major order (matmul
-    row = q_head_in_group * bq + token); k_hbm/v_hbm: the whole pool,
-    left in HBM; kbuf/vbuf: [2, Hkv, bkp, ps, D] double-buffered blocks,
+    row = q_head_in_group * bq + token); k_hbm/v_hbm: the whole STACKED
+    pool [L, P, Hkv, ps, D], left in HBM and read at ``[layer, page]``
+    (``layer_ref``: a layer sliced out for a custom call would be a copy
+    of it); kbuf/vbuf: [2, Hkv, bkp, ps, D] double-buffered blocks,
     one async copy a page, started a block ahead; a tile's last block
     starts the NEXT tile's first (``ahead_ref`` carries "started" and
     the buffer it went to across grid steps), so one-token tiles, which
@@ -389,6 +398,7 @@ def _ragged_kernel(q_len_ref, kv_len_ref, pt_ref,    # scalar prefetch
     bk = bkp * ps
     max_pages = pt_ref.shape[1]
     cdt = q_ref.dtype                       # MXU operand dtype
+    layer = layer_ref[0]
 
     def tile(t):
         """(row, position of the tile's first token, pages and blocks it
@@ -414,8 +424,8 @@ def _ragged_kernel(q_len_ref, kv_len_ref, pt_ref,    # scalar prefetch
         def page(i, _):
             src = 0 if wait else pt_ref[row, b * bkp + i]
             for hbm, buf, s in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
-                cp = pltpu.make_async_copy(hbm.at[src], buf.at[slot, :, i],
-                                           sem.at[s, slot])
+                cp = pltpu.make_async_copy(
+                    hbm.at[layer, src], buf.at[slot, :, i], sem.at[s, slot])
                 cp.wait() if wait else cp.start()
             return 0
         lax.fori_loop(0, jnp.clip(n_pages - b * bkp, 0, bkp), page, 0)
@@ -495,28 +505,30 @@ def _ragged_kernel(q_len_ref, kv_len_ref, pt_ref,    # scalar prefetch
         o_ref[0] = (acc_ref[...] / l_ref[:, :, :1]).astype(o_ref.dtype)
 
 
-def _scale_blocks(scale, page_table, bk: int):
+def _scale_blocks(scale, layer, page_table, bk: int):
     """Per-row dequant scales in kv order, cut into the kernel's blocks:
-    [P, Hkv, ps] -> [R, n_blocks, Hkv, 1, bk] fp32 (a lane-dense row a
-    block and head; 1/D of the K/V bytes, gathered by XLA)."""
+    [L, P, Hkv, ps] at ``layer`` -> [R, n_blocks, Hkv, 1, bk] fp32 (a
+    lane-dense row a block and head; 1/D of the K/V bytes, gathered by
+    XLA)."""
     R, max_pages = page_table.shape
-    _, Hkv, ps = scale.shape
-    s = scale[page_table].astype(jnp.float32)     # [R, max_pages, Hkv, ps]
+    _, _, Hkv, ps = scale.shape
+    s = scale[layer, page_table].astype(jnp.float32)  # [R, max_pages, Hkv, ps]
     s = s.transpose(0, 2, 1, 3).reshape(R, Hkv, max_pages * ps)
     nb = pl.cdiv(max_pages * ps, bk)
     s = jnp.pad(s, ((0, 0), (0, 0), (0, nb * bk - max_pages * ps)))
     return s.reshape(R, Hkv, nb, 1, bk).transpose(0, 2, 1, 3, 4)
 
 
-def _ragged_rows_pallas(q, k_pages, v_pages, page_table, q_start, q_len,
-                        kv_len, k_scale, v_scale, *, row0: int,
+def _ragged_rows_pallas(q, k_pages, v_pages, layer, page_table, q_start,
+                        q_len, kv_len, k_scale, v_scale, *, row0: int,
                         n_rows: int, n_tokens: int, sm_scale: float,
                         interpret: bool):
     """Attention of rows ``row0 : row0 + n_rows`` (each at most
-    ``n_tokens`` query tokens) -> [n_rows * nq * bq, Hq, D], row-major by
-    (row, token); slots past a row's q_len hold garbage or zeros."""
+    ``n_tokens`` query tokens) over layer ``layer`` ([1] int32) of the
+    stacked pool -> [n_rows * nq * bq, Hq, D], row-major by (row, token);
+    slots past a row's q_len hold garbage or zeros."""
     T, Hq, D = q.shape
-    _, Hkv, ps, _ = k_pages.shape
+    _, _, Hkv, ps, _ = k_pages.shape
     max_pages = page_table.shape[1]
     qpk = Hq // Hkv
     bq, nq, mrows, bkp = _ragged_tiling(n_tokens, qpk, ps, max_pages)
@@ -541,8 +553,8 @@ def _ragged_rows_pallas(q, k_pages, v_pages, page_table, q_start, q_len,
     operands = [qt, k_pages, v_pages]
     has_scales = k_scale is not None
     if has_scales:
-        ks = _scale_blocks(k_scale, page_table, bk)
-        vs = _scale_blocks(v_scale, page_table, bk)
+        ks = _scale_blocks(k_scale, layer[0], page_table, bk)
+        vs = _scale_blocks(v_scale, layer[0], page_table, bk)
         in_specs += [pl.BlockSpec((1,) + ks.shape[1:], row_map)] * 2
         operands += [ks, vs]
 
@@ -559,7 +571,7 @@ def _ragged_rows_pallas(q, k_pages, v_pages, page_table, q_start, q_len,
         functools.partial(_ragged_kernel, sm_scale=sm_scale, row0=row0,
                           bq=bq, nq=nq, has_scales=has_scales),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(n_tiles,),
             in_specs=in_specs,
             out_specs=tile_spec,
@@ -582,9 +594,29 @@ def _ragged_rows_pallas(q, k_pages, v_pages, page_table, q_start, q_len,
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=max(need * 5 // 4, 16 << 20)),
         interpret=interpret,
-    )(q_len, kv_len, page_table, *operands)
+    )(layer, q_len, kv_len, page_table, *operands)
     out = out[:, :, :qpk * bq].reshape(n_tiles, Hkv, qpk, bq, D)
     return out.transpose(0, 3, 1, 2, 4).reshape(n_tiles * bq, Hq, D)
+
+
+def _check_layer(k_pages, layer) -> None:
+    if (layer is None) != (k_pages.ndim == 4):
+        raise ValueError("a layer index goes with the stacked pool "
+                         "[L, P, Hkv, ps, D], and only with it")
+
+
+def _stacked(k_pages, v_pages, k_scale, v_scale, layer):
+    """The pool as the kernels take it: stacked [L, P, Hkv, ps, D] with
+    ``layer`` as a [1] int32 array. One layer's arrays (rank 4) become a
+    stack of one (adding the axis moves nothing)."""
+    _check_layer(k_pages, layer)
+    if layer is None:
+        k_pages, v_pages = k_pages[None], v_pages[None]
+        if k_scale is not None:
+            k_scale, v_scale = k_scale[None], v_scale[None]
+        layer = 0
+    return (k_pages, v_pages, k_scale, v_scale,
+            jnp.asarray(layer, jnp.int32).reshape(1))
 
 
 def _token_rows(q_start, q_len, T: int):
@@ -605,12 +637,16 @@ def _ragged_attention_pallas(q, k_pages, v_pages, page_table,
                              sm_scale: float,
                              max_q_len: Optional[int] = None,
                              decode_rows: int = 0,
-                             interpret: bool = False):
+                             interpret: bool = False, layer=None):
     """The blocked kernel over the static tiling the hints give: the
     first ``decode_rows`` rows as one-token tiles, the others as
     ceil(max_q_len / bq) tiles of bq tokens. XLA gathers q into tile
     order and the outputs back into token order (rows may start anywhere
-    in q); tokens no row owns come back zero."""
+    in q); tokens no row owns come back zero. The pool (and its scales)
+    is the stacked one, read at ``layer``; one layer's arrays
+    [P, Hkv, ps, D] are taken as a stack of one."""
+    k_pages, v_pages, k_scale, v_scale, layer = _stacked(
+        k_pages, v_pages, k_scale, v_scale, layer)
     T = q.shape[0]
     R = page_table.shape[0]
     q_start, q_len, kv_len = (a.astype(jnp.int32)
@@ -618,8 +654,8 @@ def _ragged_attention_pallas(q, k_pages, v_pages, page_table,
     Rd = min(decode_rows, R)
     C = min(max_q_len if max_q_len is not None else T, T)
     call = functools.partial(
-        _ragged_rows_pallas, q, k_pages, v_pages, page_table, q_start,
-        q_len, kv_len, k_scale, v_scale, sm_scale=sm_scale,
+        _ragged_rows_pallas, q, k_pages, v_pages, layer, page_table,
+        q_start, q_len, kv_len, k_scale, v_scale, sm_scale=sm_scale,
         interpret=interpret)
     owned, row, j = _token_rows(q_start, q_len, T)
     out = jnp.zeros_like(q)
@@ -634,82 +670,262 @@ def _ragged_attention_pallas(q, k_pages, v_pages, page_table,
     return jnp.where(owned[:, None, None], out, 0)
 
 
+def _use_reference(impl: Optional[str], interpret: Optional[bool]) -> bool:
+    """The ragged paths' dispatch rule: ``impl`` pins the choice, an
+    explicit ``interpret`` means the kernel, and otherwise the kernel
+    runs where the default backend is a TPU."""
+    if impl not in (None, "kernel", "reference"):
+        raise ValueError(f"impl must be 'kernel' or 'reference', "
+                         f"got {impl!r}")
+    if impl is not None:
+        return impl == "reference"
+    return interpret is None and not kernels_supported()
+
+
 def ragged_paged_attention(q, k_pages, v_pages, page_table, q_start,
                            q_len, kv_len, *, k_scale=None, v_scale=None,
                            sm_scale: Optional[float] = None,
                            max_q_len: Optional[int] = None,
                            decode_rows: int = 0,
                            interpret: Optional[bool] = None,
-                           impl: Optional[str] = None) -> jax.Array:
+                           impl: Optional[str] = None,
+                           layer=None) -> jax.Array:
     """Mixed prefill+decode attention over a ragged token batch in ONE
     dispatch. Dispatch rules identical to ``paged_attention``: Pallas
     kernel on TPU, gather reference elsewhere; ``impl`` pins the choice
     for mesh-specific programs, ``interpret=True`` runs the kernel
     through the Pallas interpreter on CPU (the tier-1 kernel tests).
+
+    k/v_pages (and the scales) are one layer's [P, Hkv, ps, D], or the
+    whole stacked pool [L, P, Hkv, ps, D] with ``layer`` the index to
+    read: the step programs pass the pool whole, because a layer sliced
+    out for a custom call is a copy of it.
     """
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
-    if q.shape[1] % k_pages.shape[1]:
+    if q.shape[1] % k_pages.shape[-3]:
         raise ValueError(
             f"q heads {q.shape[1]} not a multiple of kv heads "
-            f"{k_pages.shape[1]}")
+            f"{k_pages.shape[-3]}")
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale must be given together")
-    if impl == "reference":
+    _check_layer(k_pages, layer)
+    if _use_reference(impl, interpret):
         return ragged_paged_attention_reference(
             q, k_pages, v_pages, page_table, q_start, q_len, kv_len,
             k_scale=k_scale, v_scale=v_scale, sm_scale=sm_scale,
-            max_q_len=max_q_len, decode_rows=decode_rows)
-    if impl is not None and impl != "kernel":
-        raise ValueError(f"impl must be 'kernel' or 'reference', "
-                         f"got {impl!r}")
-    if interpret is None:
-        if impl is None and not kernels_supported():
-            return ragged_paged_attention_reference(
-                q, k_pages, v_pages, page_table, q_start, q_len, kv_len,
-                k_scale=k_scale, v_scale=v_scale, sm_scale=sm_scale,
-                max_q_len=max_q_len, decode_rows=decode_rows)
-        interpret = False
+            max_q_len=max_q_len, decode_rows=decode_rows, layer=layer)
     return _ragged_attention_pallas(
         q, k_pages, v_pages, page_table, q_start, q_len, kv_len,
-        k_scale, v_scale, sm_scale, max_q_len, decode_rows, interpret)
+        k_scale, v_scale, sm_scale, max_q_len, decode_rows,
+        bool(interpret), layer)
 
 
 # --------------------------------------------------------------------------
-# Page-cache update helper (the ragged step's one scatter per layer)
+# Page-cache update: the ragged step's one in-place write per layer
 # --------------------------------------------------------------------------
+#
+# The pool is ONE buffer in ONE layout, updated in place: inside a step
+# program every toucher of it is a custom call on the row-major stacked
+# array, so XLA has no layout to choose and nothing to copy. A token's
+# K/V [Hkv, D] is one ROW of each head's [ps, D] tile of its page, and in
+# a 16- or 8-bit pool rows share 32-bit words, so no DMA can place a
+# single token. The write therefore works on whole pages ("units": one
+# page touched by one row): XLA lays the new tokens out as page images
+# [U, Hkv, ps, D] (a gather of the step's K/V, a few MB at most), and the
+# kernel reads a unit's page, replaces the slots lo..hi-1 with the
+# image's and writes the page back, by page-sized DMAs. Units are static
+# in number: one for each of the first ``decode_rows`` rows, and
+# ceil((max_q_len + ps - 1) / ps) for each other row (a chunk may start
+# mid-page). A row's units touch distinct pages and live rows own
+# distinct pages; only the scratch page is written by several (padding,
+# garbage by contract).
+
+_WRITE_GROUP = 16        # units a grid step reads, merges and writes back
+
+
+def _kv_write_kernel(layer_ref, page_ref, lo_ref, hi_ref,   # scalar prefetch
+                     kimg_ref, vimg_ref, k_in, v_in, k_out, v_out,
+                     kbuf, vbuf, sem):
+    """One grid step = ``G`` units: read their pages from layer
+    ``layer_ref[0]`` of the pool, take slots lo..hi-1 from the images,
+    write the pages back. k_in/v_in are k_out/v_out (aliased): the pool
+    is read and written through the output refs. Units with hi <= lo
+    are dead and move nothing."""
+    del k_in, v_in
+    G, Hkv, ps, D = kbuf.shape
+    layer, u0 = layer_ref[0], pl.program_id(0) * G
+    pairs = ((k_out, kbuf, kimg_ref, 0), (v_out, vbuf, vimg_ref, 1))
+
+    def each_live(fn):
+        def unit(g, _):
+            @pl.when(hi_ref[u0 + g] > lo_ref[u0 + g])
+            def _():
+                fn(g, u0 + g)
+            return 0
+        lax.fori_loop(0, G, unit, 0)
+
+    def copy(g, u, to_pool: bool, wait: bool):
+        for hbm, buf, _, s in pairs:
+            page = hbm.at[layer, 0 if wait else page_ref[u]]
+            src, dst = (buf.at[g], page) if to_pool else (page, buf.at[g])
+            cp = pltpu.make_async_copy(src, dst, sem.at[s])
+            cp.wait() if wait else cp.start()
+
+    each_live(functools.partial(copy, to_pool=False, wait=False))
+    each_live(functools.partial(copy, to_pool=False, wait=True))
+
+    def merge(g, u):
+        slot = lax.broadcasted_iota(jnp.int32, (Hkv, ps, D), 1)
+        new = jnp.logical_and(slot >= lo_ref[u], slot < hi_ref[u])
+        for _, buf, img, _ in pairs:
+            buf[g] = jnp.where(new, img[g], buf[g])
+
+    each_live(merge)
+    each_live(functools.partial(copy, to_pool=True, wait=False))
+    each_live(functools.partial(copy, to_pool=True, wait=True))
+
+
+def _write_units(token_page, token_slot, q_start, q_len, *, T: int, ps: int,
+                 decode_rows: int, max_q_len: int):
+    """The write's units from the ragged descriptors, padded to whole
+    groups: (page [U], lo [U], hi [U], tok [U, ps]) where ``tok[u, s]``
+    is the token whose K/V slot ``s`` of unit ``u`` takes if lo <= s <
+    hi. Row r's tokens q_start[r] + j go to consecutive slots from
+    token_slot[q_start[r]] on, page after page (token_page of the first
+    token in each)."""
+    R = q_start.shape[0]
+    Rd = min(decode_rows, R)
+    first = jnp.clip(q_start, 0, T - 1)
+    s0 = token_slot[first]                               # [R]
+
+    def units(rows, n_pages):
+        k = jnp.arange(n_pages, dtype=jnp.int32)[None, :]
+        start, s, n = (a[rows, None] for a in (first, s0, q_len))
+        j_lo = jnp.maximum(k * ps - s, 0)                # first token, in row
+        j_hi = jnp.minimum((k + 1) * ps - s, n)
+        lo = s + j_lo - k * ps
+        hi = lo + jnp.maximum(j_hi - j_lo, 0)
+        t0 = jnp.clip(start + j_lo, 0, T - 1)
+        return [a.reshape(-1) for a in (token_page[t0], lo, hi, t0 - lo)]
+
+    parts = []
+    if Rd:
+        parts.append(units(slice(0, Rd), 1))
+    if R - Rd:
+        parts.append(units(slice(Rd, R), (max_q_len + 2 * ps - 2) // ps))
+    page, lo, hi, base = (jnp.concatenate(a) for a in zip(*parts))
+    pad = -page.shape[0] % min(_WRITE_GROUP, page.shape[0])
+    page, lo, hi, base = (jnp.pad(a, (0, pad)) for a in (page, lo, hi, base))
+    tok = jnp.clip(base[:, None] + jnp.arange(ps, dtype=jnp.int32), 0, T - 1)
+    return page, lo, hi, tok
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "max_q_len", "decode_rows", "interpret"))
+def _kv_write_pallas(k_pages, v_pages, k_t, v_t, layer, token_page,
+                     token_slot, q_start, q_len,
+                     max_q_len: Optional[int] = None, decode_rows: int = 0,
+                     interpret: bool = False):
+    """``k_t``/``v_t`` [T, Hkv, D] (already in the pool's dtype) into
+    layer ``layer`` ([1] int32) of the stacked pool, in place."""
+    T, Hkv, D = k_t.shape
+    ps = k_pages.shape[3]
+    C = min(max_q_len if max_q_len is not None else T, T)
+    page, lo, hi, tok = _write_units(
+        token_page.astype(jnp.int32), token_slot.astype(jnp.int32),
+        q_start.astype(jnp.int32), q_len.astype(jnp.int32), T=T, ps=ps,
+        decode_rows=decode_rows, max_q_len=C)
+    U = page.shape[0]
+    G = min(_WRITE_GROUP, U)
+    # page images, head-major like the pool: [U, Hkv, ps, D]
+    kimg, vimg = (a[tok].transpose(0, 2, 1, 3) for a in (k_t, v_t))
+
+    img_spec = pl.BlockSpec((G, Hkv, ps, D), lambda i, *_: (i, 0, 0, 0))
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+    buf = pltpu.VMEM((G, Hkv, ps, D), k_pages.dtype)
+    group_bytes = G * Hkv * ps * D * k_pages.dtype.itemsize
+    return pl.pallas_call(
+        _kv_write_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(U // G,),
+            in_specs=[img_spec, img_spec, pool_spec, pool_spec],
+            out_specs=[pool_spec, pool_spec],
+            scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2,))],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
+                   jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype)],
+        # operands count the scalar-prefetch arrays: the pools are 6 and 7
+        input_output_aliases={6: 0, 7: 1},
+        cost_estimate=pl.CostEstimate(
+            flops=0, transcendentals=0,
+            bytes_accessed=6 * U * Hkv * ps * D * k_pages.dtype.itemsize),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            # both images twice (pipelined), both page buffers, and the
+            # merge's temporaries
+            vmem_limit_bytes=max(12 * group_bytes, 16 << 20)),
+        interpret=interpret,
+    )(layer, page, lo, hi, kimg, vimg, k_pages, v_pages)
+
 
 def write_ragged_kv(k_pages, v_pages, k_t, v_t, token_page, token_slot,
-                    k_scale=None, v_scale=None):
-    """Scatter a ragged batch's per-token K/V into the page pool.
+                    k_scale=None, v_scale=None, *, layer=None,
+                    q_start=None, q_len=None,
+                    max_q_len: Optional[int] = None, decode_rows: int = 0,
+                    interpret: Optional[bool] = None,
+                    impl: Optional[str] = None):
+    """Write a ragged batch's per-token K/V into the page pool.
 
     k_t/v_t: [T, Hkv, D] this layer's roped K/V for every ragged token
     (decode rows and prefill chunks alike); token_page/token_slot: [T]
     destination page id and in-page slot — padding tokens point at page
-    0 (the scratch page, garbage by contract). When the pool is int8
-    (``k_scale``/``v_scale`` [P, Hkv, ps] given), rows quantize with
-    per-token/per-head scales (ops.int8.quantize_kv) and the scales
-    scatter alongside — every write stays local, nothing requantizes.
+    0 (the scratch page, garbage by contract). The pool is one layer's
+    [P, Hkv, ps, D], or the whole stacked [L, P, Hkv, ps, D] with
+    ``layer`` the index to write (the step programs: the pool is their
+    scan carry, updated in place). When the pool is int8
+    (``k_scale``/``v_scale`` given, shaped like the pool less D), rows
+    quantize with per-token/per-head scales (ops.int8.quantize_kv) and
+    the scales scatter alongside — every write stays local, nothing
+    requantizes.
+
+    Two implementations, chosen as the attention's is (``impl``,
+    ``interpret``): the reference is ``.at[page, :, slot].set``; the
+    kernel (``_kv_write_pallas``) writes whole pages by DMA, in place,
+    and needs the rows' ``q_start``/``q_len`` and the static hints the
+    attention takes — tokens no row owns are not written at all. The
+    scale leaves are read by XLA only and always take the scatter.
     Returns (k_pages, v_pages, k_scale, v_scale); scales pass through as
     None on fp pools.
     """
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale must be given together")
+    _check_layer(k_pages, layer)
+    at = (token_page, slice(None), token_slot)
+    if layer is not None:
+        at = (layer,) + at
     if k_scale is not None:
         from ray_tpu.ops.int8 import quantize_kv
-        kq, ks = quantize_kv(k_t)                 # [T, Hkv, D], [T, Hkv]
-        vq, vs = quantize_kv(v_t)
-        k_pages = k_pages.at[token_page, :, token_slot, :].set(kq)
-        v_pages = v_pages.at[token_page, :, token_slot, :].set(vq)
-        k_scale = k_scale.at[token_page, :, token_slot].set(
-            ks.astype(k_scale.dtype))
-        v_scale = v_scale.at[token_page, :, token_slot].set(
-            vs.astype(v_scale.dtype))
-    else:
-        # advanced indices at axes 0 and 2 are separated by a basic
-        # slice, so the indexed result is [T, Hkv, D]
-        k_pages = k_pages.at[token_page, :, token_slot, :].set(
-            k_t.astype(k_pages.dtype))
-        v_pages = v_pages.at[token_page, :, token_slot, :].set(
-            v_t.astype(v_pages.dtype))
+        k_t, ks = quantize_kv(k_t)                # [T, Hkv, D], [T, Hkv]
+        v_t, vs = quantize_kv(v_t)
+        k_scale = k_scale.at[at].set(ks.astype(k_scale.dtype))
+        v_scale = v_scale.at[at].set(vs.astype(v_scale.dtype))
+    k_t, v_t = k_t.astype(k_pages.dtype), v_t.astype(v_pages.dtype)
+    if _use_reference(impl, interpret):
+        # advanced indices separated by a basic slice: the indexed
+        # result is [T, Hkv, D]
+        k_pages = k_pages.at[at].set(k_t)
+        v_pages = v_pages.at[at].set(v_t)
+        return k_pages, v_pages, k_scale, v_scale
+    if q_start is None or q_len is None:
+        raise ValueError("the write kernel needs the rows' q_start/q_len")
+    one_layer = layer is None
+    k_pages, v_pages, _, _, layer = _stacked(k_pages, v_pages, None, None,
+                                             layer)
+    k_pages, v_pages = _kv_write_pallas(
+        k_pages, v_pages, k_t, v_t, layer, token_page, token_slot, q_start,
+        q_len, max_q_len, decode_rows, bool(interpret))
+    if one_layer:
+        k_pages, v_pages = k_pages[0], v_pages[0]
     return k_pages, v_pages, k_scale, v_scale

@@ -492,24 +492,34 @@ def test_compiled_step_programs_constant(params):
 
 def test_int8_kv_engine_greedy_equivalence():
     """kv_dtype="int8" (quantized pages + bf16 scales) must leave the
-    greedy stream unchanged — both the chunked prefill writes and the
-    decode appends round-trip through int8.
+    greedy stream where the plain path puts it — both the chunked
+    prefill writes and the decode appends round-trip through int8.
 
-    Weights are seeded so fp argmax margins exceed int8 round-trip
-    noise (~1e-2 relative); some random tiny models sit ON a tie and
-    flip legitimately. A paging/indexing bug still fails loudly: a
-    wrong-page read perturbs logits O(1), not O(1e-2)."""
+    Judged as ``plain_greedy_check`` and benchmark/checks.py judge a
+    stream: teacher-forced on the engine's own tokens, per position the
+    logit GAP between the plain path's top choice and the token the
+    engine emitted. int8 round-trip noise (~1e-2 relative) may flip a
+    near-tie, after which the two greedy streams are different streams
+    and ``got == want`` says nothing (with these weights the second
+    prompt's first token sits 0.011 logits from the top, on logits of
+    unit spread); a wrong page, slot or scale costs whole logits at
+    every position after it."""
+    from ray_tpu.llm.model import plain_greedy_check
     p8 = init_params(CFG, jax.random.PRNGKey(1))
     eng = InferenceEngine(CFG, p8, page_size=8, total_pages=64,
                           max_batch=4, max_seq_len=128, prefill_chunk=8,
                           kv_dtype="int8")
     assert eng.kv["k"].dtype == jnp.int8
     assert set(eng.kv) == {"k", "v", "k_scale", "v_scale"}
+    gaps, equal = [], []
     for prompt in ([5, 17, 42, 9, 100, 3, 77],
                    [(5 * i + 2) % CFG.vocab_size for i in range(20)]):
         got = eng.generate(prompt, max_new_tokens=10)
-        want = _oracle_greedy(p8, prompt, 10)
-        assert got == want, f"int8 KV diverged: {got} vs {want}"
+        check = plain_greedy_check(p8, CFG, prompt, got, 64)
+        gaps += check["gap"]
+        equal += [a == b for a, b in zip(got, check["plain_tokens"])]
+    assert max(gaps) < 0.05, f"int8 KV left the plain path: gaps {gaps}"
+    assert sum(equal) >= 0.9 * len(equal), equal
 
 
 def test_int8_kv_prefix_hit_cow_and_evict(params):

@@ -332,3 +332,150 @@ def test_write_ragged_kv_fp_and_int8():
                             vs2[page[t], :, slot[t]])
         np.testing.assert_allclose(np.asarray(got), np.asarray(v_t[t]),
                                    atol=2e-2)
+
+
+# --------------------------------------------------------------------------
+# The in-place write: _kv_write_pallas (interpreter) against .at[].set
+# --------------------------------------------------------------------------
+
+def _write_batch(ps, batch, rng):
+    """A ragged batch's write descriptors over rows that own their pages.
+    ``decode``: 4 one-token rows, one of them inactive. ``mixed``: the
+    same and two chunk rows, one that starts mid-page on a cached prefix
+    and crosses two page boundaries, one page-aligned that fills whole
+    pages. Tokens no row owns point at the scratch page. Returns (T,
+    token_page, token_slot, q_start, q_len, max_q_len, decode_rows)."""
+    Rd, pad = 4, 3
+    q_len = [1, 0, 1, 1]
+    first = [ps + 3, 0, 2 * ps - 1, 0]          # position of the row's token
+    if batch == "mixed":
+        q_len += [2 * ps + 5, 2 * ps]
+        first += [ps + ps // 2 + 1, 0]          # mid-page on 1.5 pages cached
+    R = len(q_len)
+    max_q_len = max(q_len)
+    q_start = np.concatenate([np.arange(Rd), Rd + np.cumsum(
+        [0] + q_len[Rd:-1])]).astype(np.int32)[:R]
+    T = int(q_start[-1] + q_len[-1]) + pad
+    pages = iter(rng.permutation(np.arange(1, 40)))
+    token_page = np.zeros(T, np.int32)           # scratch unless owned
+    token_slot = rng.integers(0, ps, T).astype(np.int32)
+    for r in range(R):
+        pos = first[r] + np.arange(q_len[r])
+        table = np.array([next(pages) for _ in range(6)])
+        token_page[q_start[r]:q_start[r] + q_len[r]] = table[pos // ps]
+        token_slot[q_start[r]:q_start[r] + q_len[r]] = pos % ps
+    return (T, token_page, token_slot, q_start, np.asarray(q_len, np.int32),
+            max_q_len, Rd)
+
+
+@pytest.mark.pallas_interpret
+@pytest.mark.parametrize("heads", ["all", "tp2-shard"])
+@pytest.mark.parametrize("batch", ["decode", "mixed"])
+@pytest.mark.parametrize("ps", [16, 32])
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_kv_write_in_place_matches_scatter(kv, ps, batch, heads):
+    """The step programs' write (whole pages by DMA, aliased in to out)
+    puts the same values into the same slots as ``.at[layer, page, :,
+    slot].set``: both pool dtypes, both page sizes, one-token rows alone
+    and with chunks (one starting mid-page), an inactive row, padding
+    tokens on the scratch page, a layer index other than 0 with every
+    other layer left bit-identical, and one tp=2 shard's half of the
+    heads (inside shard_map the kernel sees the local heads only)."""
+    rng = np.random.default_rng(ps + len(batch))
+    L, P, Hkv, D, layer = 3, 40, 4, 128, 1
+    T, token_page, token_slot, q_start, q_len, max_q_len, Rd = \
+        _write_batch(ps, batch, rng)
+    dt = jnp.int8 if kv == "int8" else jnp.bfloat16
+    shape = (L, P, Hkv, ps, D)
+    kp, vp = (jnp.asarray(rng.integers(-100, 100, shape), dt)
+              for _ in range(2))
+    scales = [None, None] if kv == "bf16" else \
+        [jnp.asarray(rng.uniform(0.5, 1.0, shape[:-1]), jnp.bfloat16)
+         for _ in range(2)]
+    k_t, v_t = (jnp.asarray(rng.normal(size=(T, Hkv, D)), jnp.bfloat16)
+                for _ in range(2))
+    if heads == "tp2-shard":                     # the second shard's heads
+        kp, vp, k_t, v_t = (a[..., Hkv // 2:, :, :] if a.ndim == 5
+                            else a[:, Hkv // 2:] for a in (kp, vp, k_t, v_t))
+        scales = [s if s is None else s[:, :, Hkv // 2:] for s in scales]
+    args = (kp, vp, k_t, v_t, jnp.asarray(token_page),
+            jnp.asarray(token_slot), *scales)
+    want = write_ragged_kv(*args, layer=layer, impl="reference")
+    got = write_ragged_kv(
+        *args, layer=layer, q_start=jnp.asarray(q_start),
+        q_len=jnp.asarray(q_len), max_q_len=max_q_len, decode_rows=Rd,
+        impl="kernel", interpret=True)
+    assert (got[2] is None) == (kv == "bf16")
+    for name, g, w, before in zip("k v k_scale v_scale".split(), got, want,
+                                  (kp, vp, *scales)):
+        if g is None:
+            continue
+        g, w, before = (np.asarray(a.astype(jnp.float32))
+                        for a in (g, w, before))
+        # the scratch page is garbage by contract; everything else equal
+        np.testing.assert_array_equal(g[:, 1:], w[:, 1:], err_msg=name)
+        others = [i for i in range(L) if i != layer]
+        np.testing.assert_array_equal(g[others], before[others],
+                                      err_msg=f"{name}: other layers")
+        assert not np.array_equal(g[layer, 1:], before[layer, 1:]), name
+
+
+@pytest.mark.pallas_interpret
+@pytest.mark.parametrize("kv_dtype", [None, "int8"])
+def test_step_programs_through_the_kernels_match_reference(monkeypatch,
+                                                           kv_dtype):
+    """Both step programs with the kernels (write and attention, through
+    the interpreter) under the layer scan that carries the stacked pool,
+    against the reference branch of the same programs: the same tokens,
+    and the same pool in every layer off the scratch page."""
+    from jax.experimental import pallas as pl
+    from ray_tpu.llm import model as M
+    from ray_tpu.llm.cache import make_kv_cache
+    from ray_tpu.models.llama import LlamaConfig, init_params
+    real = pl.pallas_call
+    monkeypatch.setattr(pl, "pallas_call", lambda *a, **kw: real(
+        *a, **{**kw, "interpret": True}))
+    cfg = LlamaConfig(vocab_size=64, dim=64, n_layers=3, n_heads=4,
+                      n_kv_heads=2, ffn_dim=96, dtype=jnp.float32,
+                      param_dtype=jnp.float32)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    ps, B, C = 8, 2, 12
+    rng = np.random.default_rng(3)
+
+    def pool():
+        kv = make_kv_cache(cfg, 12, ps, kv_dtype=kv_dtype)
+        return {n: jnp.asarray(rng.integers(-3, 4, a.shape), a.dtype)
+                if n in ("k", "v") else jnp.ones_like(a)
+                for n, a in kv.items()}
+
+    # two decode rows at positions 9 and 3, one chunk of 12 tokens from
+    # position 5 (mid-page) of a third sequence; two padding tokens
+    T = B + C + 2
+    table = np.array([[1, 2, 0], [3, 0, 0], [4, 5, 6]], np.int32)
+    pos = np.concatenate([[9, 3], 5 + np.arange(C), [0, 0]]).astype(np.int32)
+    row_of = np.concatenate([[0, 1], np.full(C, 2), [0, 0]])
+    page = table[row_of, pos // ps]
+    page[-2:] = 0
+    mixed = [jnp.asarray(a, jnp.int32) for a in (
+        rng.integers(0, 64, T), pos, page, pos % ps, table,
+        [0, 1, B], [1, 1, C], [10, 4, 5 + C])]
+    rng_state = rng.bit_generator.state
+    outs = {}
+    for impl in ("reference", "kernel"):
+        rng.bit_generator.state = rng_state         # the same pool twice
+        nxt, kv = M.ragged_step(params, *mixed, pool(), cfg=cfg,
+                                paged_impl=impl, max_q_len=C, decode_rows=B)
+        toks, kv, _, _ = M.ragged_decode_loop(
+            params, nxt[:B], jnp.asarray([10, 4], jnp.int32), kv,
+            jnp.asarray(table[:B]), jnp.asarray([11, 5], jnp.int32),
+            num_steps=3, cfg=cfg, paged_impl=impl)
+        outs[impl] = (np.asarray(nxt), np.asarray(toks),
+                      {n: np.asarray(a.astype(jnp.float32))
+                       for n, a in kv.items()})
+    (nxt_r, toks_r, kv_r), (nxt_k, toks_k, kv_k) = \
+        outs["reference"], outs["kernel"]
+    np.testing.assert_array_equal(nxt_k, nxt_r)
+    np.testing.assert_array_equal(toks_k, toks_r)
+    for n in kv_r:
+        np.testing.assert_allclose(kv_k[n][:, 1:], kv_r[n][:, 1:],
+                                   rtol=1e-5, atol=1e-5, err_msg=n)

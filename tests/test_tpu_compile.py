@@ -17,6 +17,7 @@ cannot be read back without the chip, and warns).
 import functools
 import importlib
 import os
+import re
 
 import pytest
 
@@ -134,59 +135,23 @@ def test_flash_fwd_bwd_compiles_at_every_candidate_block(chip, blk_q, blk_k):
     assert _kernel_calls(lowered) == 3          # fwd, dq, dk/dv
 
 
-def test_whole_ragged_step_program_compiles(chip):
-    """One whole engine step at Llama-3-8B widths (2 layers; shapes from
-    jax.eval_shape, so no weights exist): embed, per-layer projections,
-    KV scatter into the page pool, the ragged kernel, logits, argmax."""
+def _abstract(chip, fn):
+    return jax.tree.map(lambda a: _sds(chip, a.shape, a.dtype),
+                        jax.eval_shape(fn))
+
+
+def _compile_step_program(chip, cfg, program, *, max_batch, pages, max_seq,
+                          rows=2, chunk=512, ps=16):
+    """One of the engine's two step programs, compiled from shapes
+    (jax.eval_shape: no weights exist): the mixed step over max_batch
+    decode rows + ``rows`` chunks of ``chunk``, or the 8-step decode loop.
+    Returns (compiled, the pool's abstract pytree, rows of the result)."""
     from ray_tpu.llm import model as M
     from ray_tpu.llm.cache import make_kv_cache
-    from ray_tpu.models.llama import LlamaConfig, init_params
-    cfg = LlamaConfig.llama3_8b(n_layers=2, param_dtype="bfloat16")
-    max_batch, rows, chunk, ps, pages, max_seq = 8, 2, 512, 16, 640, 1024
-    T, R = max_batch + rows * chunk, max_batch + rows
-
-    def abstract(fn):
-        return jax.tree.map(lambda a: _sds(chip, a.shape, a.dtype),
-                            jax.eval_shape(fn))
-
-    params = abstract(functools.partial(init_params, cfg,
-                                        jax.random.PRNGKey(0)))
-    kv = abstract(functools.partial(make_kv_cache, cfg, pages, ps))
-    tok, row = _sds(chip, (T,), jnp.int32), _sds(chip, (R,), jnp.int32)
-    compiled = M.ragged_step.lower(
-        params, tok, tok, tok, tok,
-        _sds(chip, (R, max_seq // ps), jnp.int32), row, row, row, kv,
-        cfg=cfg, paged_impl="kernel", max_q_len=chunk,
-        decode_rows=max_batch).compile()
-    assert compiled.as_text().count("tpu_custom_call") == 2
-    mem = compiled.memory_analysis()
-    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16 * 2**30
-
-
-@pytest.mark.parametrize("program", ["mixed", "decode"])
-def test_olmoe_step_programs_compile_at_benchmark_shapes(chip, program):
-    """olmoe-1b7b-serve-1chip's two step programs at its published widths
-    (2 of its 12 layers; shapes from jax.eval_shape): 64 experts top-8 of
-    width 1024 through the dropless expert kernel, q/k norm, the untied
-    head, and the blocked paged kernel at 16 KV heads with one query head
-    each. 32 decode rows, 2 chunks of 512, 1280 pages of 16."""
-    from ray_tpu.llm import model as M
-    from ray_tpu.llm.cache import make_kv_cache
-    from ray_tpu.models.llama import LlamaConfig, init_params
-    cfg = LlamaConfig(vocab_size=50304, dim=2048, n_layers=2, n_heads=16,
-                      n_kv_heads=16, ffn_dim=1024, rope_theta=10000.0,
-                      param_dtype="bfloat16", n_experts=64,
-                      experts_per_token=8, qk_norm=True,
-                      tie_embeddings=False)
-    max_batch, rows, chunk, ps, pages, max_seq = 32, 2, 512, 16, 1280, 1536
-
-    def abstract(fn):
-        return jax.tree.map(lambda a: _sds(chip, a.shape, a.dtype),
-                            jax.eval_shape(fn))
-
-    params = abstract(functools.partial(init_params, cfg,
-                                        jax.random.PRNGKey(0)))
-    kv = abstract(functools.partial(make_kv_cache, cfg, pages, ps))
+    from ray_tpu.models.llama import init_params
+    params = _abstract(chip, functools.partial(init_params, cfg,
+                                               jax.random.PRNGKey(0)))
+    kv = _abstract(chip, functools.partial(make_kv_cache, cfg, pages, ps))
     table = functools.partial(_sds, chip, dtype=jnp.int32)
     if program == "mixed":
         T, R = max_batch + rows * chunk, max_batch + rows
@@ -195,13 +160,126 @@ def test_olmoe_step_programs_compile_at_benchmark_shapes(chip, program):
             params, tok, tok, tok, tok, table((R, max_seq // ps)), row, row,
             row, kv, cfg=cfg, paged_impl="kernel", max_q_len=chunk,
             decode_rows=max_batch).compile()
-        kernels, out = 3, (R + 3,)      # chunk tiles, one-token tiles, experts
-    else:
-        row = table((max_batch,))
-        compiled = M.ragged_decode_loop.lower(
-            params, row, row, kv, table((max_batch, max_seq // ps)), row,
-            num_steps=8, cfg=cfg, paged_impl="kernel").compile()
-        kernels, out = 2, (8 * max_batch + 3,)
-    assert compiled.as_text().count("tpu_custom_call") == kernels
-    assert "_moe_experts_pallas" in compiled.as_text()
-    assert jax.tree.leaves(compiled.out_info)[0].shape == out
+        return compiled, kv, R
+    row = table((max_batch,))
+    compiled = M.ragged_decode_loop.lower(
+        params, row, row, kv, table((max_batch, max_seq // ps)), row,
+        num_steps=8, cfg=cfg, paged_impl="kernel").compile()
+    return compiled, kv, 8 * max_batch
+
+
+def _olmoe_cfg(n_layers=2):
+    from ray_tpu.models.llama import LlamaConfig
+    return LlamaConfig(vocab_size=50304, dim=2048, n_layers=n_layers,
+                       n_heads=16, n_kv_heads=16, ffn_dim=1024,
+                       rope_theta=10000.0, param_dtype="bfloat16",
+                       n_experts=64, experts_per_token=8, qk_norm=True,
+                       tie_embeddings=False)
+
+
+def _mistral_cfg(n_layers=2):
+    from ray_tpu.models.llama import LlamaConfig
+    return LlamaConfig(vocab_size=32768, dim=4096, n_layers=n_layers,
+                       n_heads=32, n_kv_heads=8, ffn_dim=14336,
+                       rope_theta=1e6, param_dtype="bfloat16")
+
+
+#: the two serve configurations' widths and pools (benchmark/configs)
+_SERVE = {"mistral": (_mistral_cfg, dict(max_batch=16, pages=640,
+                                         max_seq=2304)),
+          "olmoe": (_olmoe_cfg, dict(max_batch=32, pages=1280,
+                                     max_seq=1536))}
+
+
+def test_whole_ragged_step_program_compiles(chip):
+    """One whole engine step at Llama-3-8B widths (2 layers): embed,
+    per-layer projections, the in-place KV write into the page pool, the
+    ragged kernel, logits, argmax."""
+    from ray_tpu.models.llama import LlamaConfig
+    cfg = LlamaConfig.llama3_8b(n_layers=2, param_dtype="bfloat16")
+    compiled, _, _ = _compile_step_program(
+        chip, cfg, "mixed", max_batch=8, pages=640, max_seq=1024)
+    # the write, chunk tiles, one-token tiles
+    assert compiled.as_text().count("tpu_custom_call") == 3
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16 * 2**30
+
+
+@pytest.mark.parametrize("program", ["mixed", "decode"])
+def test_olmoe_step_programs_compile_at_benchmark_shapes(chip, program):
+    """olmoe-1b7b-serve-1chip's two step programs at its published widths
+    (2 of its 12 layers): 64 experts top-8 of width 1024 through the
+    dropless expert kernel, q/k norm, the untied head, and the blocked
+    paged kernel at 16 KV heads with one query head each. 32 decode rows,
+    2 chunks of 512, 1280 pages of 16."""
+    make_cfg, sizes = _SERVE["olmoe"]
+    compiled, _, rows = _compile_step_program(chip, make_cfg(), program,
+                                              **sizes)
+    text = compiled.as_text()
+    # the write, the attention (chunk and one-token tiles | one-token),
+    # the experts
+    assert text.count("tpu_custom_call") == (4 if program == "mixed" else 3)
+    assert "_moe_experts_pallas" in text
+    assert jax.tree.leaves(compiled.out_info)[0].shape == (rows + 3,)
+
+
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%?[\w.\-]+ = (?P<result>.*?) (?P<op>[\w\-]+)\(")
+#: what may yield a pool- or layer-shaped result: the program's own
+#: plumbing and the in-place write
+_POOL_PLUMBING = {"parameter", "get-tuple-element", "tuple", "while",
+                  "bitcast", "custom-call"}
+#: layers that make a pool leaf 168 MB at each configuration's widths and
+#: pages: more than the chip's 128 MiB of VMEM, as at the cells' depths.
+#: A smaller leaf the compiler prefetches there, whole or by halves
+#: (copy-start / slice-start), which reads as a copy and is none
+_POOL_LAYERS = {"mistral": 8, "olmoe": 2}
+
+
+def _bytes_of(shape: str) -> int:
+    dims = re.match(r"\(?(bf16|s8|f32|s32)\[([\d,]*)\]", shape)
+    size = {"bf16": 2, "s8": 1, "f32": 4, "s32": 4}[dims.group(1)]
+    for d in filter(None, dims.group(2).split(",")):
+        size *= int(d)
+    return size
+
+
+@pytest.mark.parametrize("widths", sorted(_SERVE))
+@pytest.mark.parametrize("program", ["mixed", "decode"])
+def test_step_programs_update_the_pool_in_place(chip, program, widths):
+    """The KV pool is one buffer in one layout, updated in place: in the
+    compiled step programs at both serve configurations' widths and pool
+    shapes (``_POOL_LAYERS`` layers) nothing but the write kernel yields an array of
+    the pool's or of one layer's shape — no layout copy, no slice of a
+    layer out of the stack, no re-stack, no copy from one scan's output
+    to the other's carry — the pool is aliased from argument to result,
+    and the temporaries hold less than one pool. (Threaded through the
+    layer scan as xs/ys the pool moved about four times a step and was
+    held three times: PERF.md, PR 27.) The decode loop also hoists
+    transposed copies of stacked attention weights out of its step scan,
+    as it did before; they are weights, not pool, and are taken off."""
+    make_cfg, sizes = _SERVE[widths]
+    cfg = make_cfg(_POOL_LAYERS[widths])
+    compiled, kv, _ = _compile_step_program(chip, cfg, program, **sizes)
+    text = compiled.as_text()
+    pool = ",".join(map(str, kv["k"].shape))
+    one_layer = ",".join(map(str, kv["k"].shape[1:]))
+    shaped = re.compile(r"bf16\[(%s|%s)\]" % (pool, one_layer))
+    touched, weight_copies = [], 0
+    for line in text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m is None:
+            continue
+        if shaped.search(m["result"]) and m["op"] not in _POOL_PLUMBING:
+            touched.append(line.strip()[:160])
+        if m["op"] == "copy" and re.match(
+                r"bf16\[%d,\d+,\d+\]" % cfg.n_layers, m["result"]):
+            weight_copies += _bytes_of(m["result"])
+    assert not touched, touched
+    assert "_kv_write_pallas" in text
+    kernels = 1 + (2 if program == "mixed" else 1) + bool(cfg.n_experts)
+    assert text.count("tpu_custom_call") == kernels
+    pool_bytes = 2 * _bytes_of(f"bf16[{pool}]")
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes - weight_copies < pool_bytes // 2
