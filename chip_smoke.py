@@ -69,8 +69,8 @@ SERVE = {
                "layers peak at 13.7 of 15.75 GiB HBM, 32 need 17.7",
 }
 
-# Training keeps fp32 master weights and adafactor (what bench.py trains
-# with): weights + grads alone are 1.75 GiB a layer and 4.2 GiB for the
+# Training keeps fp32 master weights and adafactor (what the benchmark's
+# training cell trains with): weights + grads alone are 1.75 GiB a layer and 4.2 GiB for the
 # 128k-row embedding, and the [B, L, vocab] fp32 logits 2 GiB at 2x2048
 # tokens. 4 layers at 2x2048 compile to 14.0 GiB; 4 layers at 4x2048, or 6
 # at 2x2048, are refused by the TPU compiler for HBM.

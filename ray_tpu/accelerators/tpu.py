@@ -149,17 +149,3 @@ class ChipAllocator:
         spawn that failed between allocation and registration)."""
         self.free.extend(chips)
         self.free.sort()
-
-
-def require_tpu_device():
-    """``jax.devices()[0]`` when it is a TPU, else RuntimeError. Measurement
-    paths (bench.py, bench_llm.py) start here: a run that finds no chip
-    fails — it never falls back to timing the CPU under a device metric's
-    name. Imports jax, so never call it from a daemon."""
-    import jax
-    dev = jax.devices()[0]
-    if dev.platform != "tpu":
-        raise RuntimeError(
-            f"no TPU: jax's first device is {dev.platform!r} "
-            f"({dev.device_kind!r}); this measurement only runs on the chip")
-    return dev

@@ -62,73 +62,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.llm.cache import (SCRATCH_PAGE, PageAllocator, PrefixCache,
-                               SequenceState, kv_cache_tag, make_kv_cache)
+                               SequenceState, kv_cache_tag)
 from ray_tpu.llm import model as M
-from ray_tpu.models.llama import LlamaConfig, init_params
-from ray_tpu.ops.paged_attention import kernels_supported
+from ray_tpu.llm.tp import build_tp_mesh
+from ray_tpu.models.llama import LlamaConfig
 
 TraceAnnotation = jax.profiler.TraceAnnotation
-
-
-#: tp=1 weights are BORN on the device by a jitted init (module-level, so
-#: engines with equal configs share the compile). Fused, the f32 draw of a
-#: bf16 weight never exists in HBM — the eager init's largest temporary is
-#: what OOMs an 8B-width model. (The page pool is plain zeros: eager is
-#: already temporary-free.)
-_init_params = jax.jit(init_params, static_argnums=(0,))
-
-
-class _SingleChipFns:
-    """tp=1 dispatch: the module-level jits in llm.model (compile cache
-    shared across engines with equal shapes), signatures matching
-    llm.tp.TPEngineFns so the engine swaps implementations at one seam."""
-
-    def __init__(self, cfg: LlamaConfig, decode_chunk: int,
-                 max_q_len: int, decode_rows: int):
-        self.cfg = cfg
-        self._chunk = decode_chunk
-        self._max_q = max_q_len
-        self._rows = decode_rows
-        #: which paged-attention implementation the step programs
-        #: compile: the Pallas kernel on a TPU, the gather reference
-        #: elsewhere — observed, never configured (device_report())
-        self.paged_impl = "kernel" if kernels_supported() else "reference"
-
-    def init_params(self, seed: int):
-        return _init_params(self.cfg, jax.random.PRNGKey(seed))
-
-    def place_params(self, params):
-        return params
-
-    def init_kv(self, total_pages: int, page_size: int, kv_dtype):
-        return make_kv_cache(self.cfg, total_pages, page_size,
-                             kv_dtype=kv_dtype)
-
-    def ragged_step(self, params, tokens, token_pos, token_page,
-                    token_slot, page_table, q_start, q_len, kv_len, kv):
-        return M.ragged_step(params, tokens, token_pos, token_page,
-                             token_slot, page_table, q_start, q_len,
-                             kv_len, kv, cfg=self.cfg,
-                             paged_impl=self.paged_impl, max_q_len=self._max_q,
-                             decode_rows=self._rows)
-
-    def decode_loop(self, params, tokens, positions, kv, page_table,
-                    seq_lens):
-        return M.ragged_decode_loop(params, tokens, positions, kv,
-                                    page_table, seq_lens,
-                                    num_steps=self._chunk, cfg=self.cfg,
-                                    paged_impl=self.paged_impl)
-
-    def copy_page(self, kv, src, dst):
-        return M.copy_page(kv, src, dst)
-
-    def compiled_step_programs(self) -> int:
-        """Resident compiled step programs, process-wide (the three
-        module jits share their cache across engines): the O(1) compile
-        budget the ragged design promises. In a fresh process running
-        one engine this is exactly that engine's program count."""
-        return sum(f._cache_size() for f in (
-            M.ragged_step, M.ragged_decode_loop, M.copy_page))
 
 
 class InferenceEngine:
@@ -185,20 +124,14 @@ class InferenceEngine:
         self.kv_dtype = GlobalConfig.llm_kv_dtype \
             if kv_dtype is None else kv_dtype
         # tensor parallelism: tp>1 shards weights + kv-heads over a
-        # ('tp',) mesh and swaps in shard_map'd programs (llm/tp.py);
+        # ('tp',) mesh and the seam builds shard_map'd programs over it;
         # page allocator / slot bookkeeping below is layout-agnostic
         self.tp = max(1, tp)
-        self.mesh = None
-        if self.tp > 1:
-            from ray_tpu.llm.tp import TPEngineFns, build_tp_mesh
-            self.mesh = build_tp_mesh(self.tp, devices)
-            self._fns = TPEngineFns(
-                cfg, self.mesh, decode_chunk=self.decode_chunk,
-                max_q_len=self.prefill_chunk, decode_rows=max_batch,
-                kv_quantized=(self.kv_dtype == "int8"))
-        else:
-            self._fns = _SingleChipFns(cfg, self.decode_chunk,
-                                       self.prefill_chunk, max_batch)
+        self.mesh = build_tp_mesh(self.tp, devices) if self.tp > 1 else None
+        self._fns = M.StepPrograms(
+            cfg, decode_chunk=self.decode_chunk,
+            max_q_len=self.prefill_chunk, decode_rows=max_batch,
+            kv_quantized=(self.kv_dtype == "int8"), mesh=self.mesh)
         # weights and pool are created IN their final layout (sharded
         # over the mesh under tp): no device ever stages the whole model
         self.params = self._fns.init_params(seed) if params is None \
@@ -214,26 +147,11 @@ class InferenceEngine:
             for shard in leaf.addressable_shards:
                 self._held_bytes[shard.device.id] = self._held_bytes.get(
                     shard.device.id, 0) + shard.data.nbytes
-        # XLA compile tracker seam (util/compile_tracker.py): the three
-        # step entry points are wrapped so every compile is recorded
-        # with its arg signature — ground truth the O(1)-compile
-        # invariant below is cross-checked against in production, not
-        # just asserted in tests. The probe is compiled_step_programs
-        # itself: any growth across a single wrapped call belongs to
-        # that call.
-        from ray_tpu.util import compile_tracker
-        self._tracker = compile_tracker.ensure_started()
+        # the XLA compile tracker (util/compile_tracker.py) the seam
+        # records its compiles with, if one runs: _set_gauges
+        # cross-checks the O(1)-compile invariant against it
+        self._tracker = self._fns.tracker
         self._invariant_breached = False
-        if self._tracker is not None:
-            probe = self._fns.compiled_step_programs
-            self._fns.ragged_step = self._tracker.wrap(
-                self._fns.ragged_step, name="llm.ragged_step",
-                probe=probe)
-            self._fns.decode_loop = self._tracker.wrap(
-                self._fns.decode_loop, name="llm.decode_loop",
-                probe=probe)
-            self._fns.copy_page = self._tracker.wrap(
-                self._fns.copy_page, name="llm.copy_page", probe=probe)
         self.allocator = PageAllocator(total_pages)
         use_prefix = GlobalConfig.llm_prefix_cache \
             if prefix_cache is None else prefix_cache
@@ -540,11 +458,7 @@ class InferenceEngine:
             return False
         with TraceAnnotation("engine.pack"):
             # decode rows advance one token: they need a page for it
-            for slot, seq in list(enumerate(self._slots)):
-                if seq is not None and not seq.prefilling:
-                    self._ensure_pages(slot, seq, 1, finished)
-            active = [(i, s) for i, s in enumerate(self._slots)
-                      if s is not None and not s.prefilling]
+            active = self._decode_rows(1, finished)
             ps = self.page_size
             Tcap, R = self.ragged_tokens, self.ragged_rows
             tokens = np.zeros(Tcap, np.int32)
@@ -611,17 +525,7 @@ class InferenceEngine:
                 "slot_tokens": Tcap}
             for slot, seq in active:
                 tok = int(nxt[slot])
-                if self.eos_token is not None and tok == self.eos_token:
-                    self._note_finish(seq.request_id, "stop")
-                    self._finish(slot, seq, finished)
-                    continue
-                seq.generated.append(tok)
-                if seq.record is not None:
-                    seq.record.note_decode(now, 1, mixed=True)
-                if self.track_progress:
-                    self._progress.setdefault(seq.request_id,
-                                              []).append(tok)
-                if len(seq.generated) >= seq.max_new_tokens:
+                if self._book_tokens(seq, (tok,), now, mixed=True):
                     self._finish(slot, seq, finished)
                     continue
                 self._tokens[slot] = tok
@@ -670,41 +574,14 @@ class InferenceEngine:
             seq.prompt = seq.prompt[:seq.n_prompt]
             seq.generated = list(seq.restore_generated)
             seq.restore_generated = []
-        eos_now = self.eos_token is not None and first_tok == self.eos_token
-        if seq.record is not None:
-            if eos_now:
-                seq.record.note_first(now)  # sampled, but never emitted
-            else:
-                # a request's first token, or (re-admitted after a
-                # preemption) one more that a mixed step produced
-                seq.record.note_decode(now, 1, mixed=True)
-        done_now = eos_now or len(seq.generated) + 1 >= seq.max_new_tokens
-        if done_now:
-            # first sampled token is EOS (drop it) or it used up the
-            # token budget (keep it): finish without (re-)joining the
-            # decode batch
-            new = [] if eos_now else [first_tok]
-            out = seq.generated + new
-            seq.generated = out
-            seq.done = True
-            self._finished_at_prefill[seq.request_id] = out
-            if new and self.track_progress:
-                # only the NEW token streams; restored tokens already did
-                self._progress.setdefault(seq.request_id, []).extend(new)
-            self._note_finish(seq.request_id,
-                              "stop" if eos_now else "length")
-            if self.request_log is not None and seq.record is not None:
-                self.request_log.finish(
-                    seq.record, now, "stop" if eos_now else "length")
-            self._release_pages(pages)
-            if seq.slot is not None:
-                self._slots[seq.slot] = None
-                self._page_table[seq.slot, :] = SCRATCH_PAGE
-                seq.slot = None
+        # a request's first token, or (re-admitted after a preemption)
+        # one more that a mixed step produced: only the NEW token
+        # streams, restored tokens already did
+        if self._book_tokens(seq, (first_tok,), now, mixed=True):
+            # it is EOS (dropped) or it used up the token budget (kept):
+            # finish without (re-)joining the decode batch
+            self._finish(slot, seq, self._finished_at_prefill, now)
             return
-        seq.generated.append(first_tok)
-        if self.track_progress:
-            self._progress.setdefault(seq.request_id, []).append(first_tok)
         seq.slot = slot
         self._slots[slot] = seq
         with self._lock:
@@ -714,21 +591,74 @@ class InferenceEngine:
         self._positions[slot] = seq.num_tokens - 1
         self._tokens[slot] = first_tok
 
+    def _book_tokens(self, seq: SequenceState, toks, now: float, *,
+                     mixed: bool) -> Optional[str]:
+        """Take the tokens ONE dispatch produced for ``seq`` — one from a
+        mixed step (``mixed``: a decode row's next token, a finished
+        prompt's first), up to decode_chunk from a decode loop — in the
+        one order there is: an EOS token ends the sequence ("stop") and
+        is dropped with whatever the block decoded past it; any other is
+        appended and streamed (track_progress), and the one that uses up
+        max_new_tokens ends the sequence ("length") and is kept; the
+        request record gets ONE entry for the dispatch (the K-step loop
+        is one device round trip — per-token host timestamps would be
+        fiction), before the caller finishes the sequence, so e2e covers
+        every token. Returns the finish reason, or None: the sequence
+        goes on and the caller feeds it its last token."""
+        n_new, reason = 0, None
+        for tok in toks:
+            if self.eos_token is not None and tok == self.eos_token:
+                reason = "stop"
+                break
+            seq.generated.append(tok)
+            n_new += 1
+            if self.track_progress:
+                self._progress.setdefault(seq.request_id, []).append(tok)
+            if len(seq.generated) >= seq.max_new_tokens:
+                reason = "length"
+                break
+        if seq.record is not None:
+            if n_new:
+                seq.record.note_decode(now, n_new, mixed=mixed)
+            else:
+                # sampled, but never emitted. Stops the TTFT clock of a
+                # prompt whose FIRST sample is EOS; idempotent, so for a
+                # sequence that has its first token it does nothing
+                seq.record.note_first(now)
+        if reason is not None:
+            self._note_finish(seq.request_id, reason)
+        return reason
+
     def _finish(self, slot: int, seq: SequenceState,
-                finished: Dict[str, List[int]]) -> None:
+                finished: Dict[str, List[int]],
+                now: Optional[float] = None) -> None:
         if seq.request_id not in self._finish_reasons:
             self._note_finish(seq.request_id, "length")
         if self.request_log is not None and seq.record is not None:
             self.request_log.finish(
-                seq.record, time.monotonic(),
+                seq.record, time.monotonic() if now is None else now,
                 self._finish_reasons.get(seq.request_id, "length"))
         seq.done = True
         finished[seq.request_id] = list(seq.generated)
         self._release_pages(seq.pages)
         self._slots[slot] = None
         self._page_table[slot, :] = SCRATCH_PAGE
+        seq.slot = None
         with self._lock:
-            self.running.remove(seq)
+            # a prompt that finishes on its first token never joined
+            if seq in self.running:
+                self.running.remove(seq)
+
+    def _decode_rows(self, headroom: int, finished: Dict[str, List[int]],
+                     ) -> List[Tuple[int, SequenceState]]:
+        """Give every decode row its pages for ``headroom`` more tokens
+        (a row the pool cannot serve is preempted or evicted), then list
+        the rows that are left: [(slot, seq)]."""
+        for slot, seq in list(enumerate(self._slots)):
+            if seq is not None and not seq.prefilling:
+                self._ensure_pages(slot, seq, headroom, finished)
+        return [(i, s) for i, s in enumerate(self._slots)
+                if s is not None and not s.prefilling]
 
     def _ensure_pages(self, slot: int, seq: SequenceState, headroom: int,
                       finished: Dict[str, List[int]]) -> bool:
@@ -802,12 +732,7 @@ class InferenceEngine:
 
     def _decode(self, finished: Dict[str, List[int]]) -> None:
         with TraceAnnotation("engine.pack"):
-            for slot, seq in list(enumerate(self._slots)):
-                if seq is not None and not seq.prefilling:
-                    self._ensure_pages(slot, seq, self.decode_chunk,
-                                       finished)
-            active = [(i, s) for i, s in enumerate(self._slots)
-                      if s is not None and not s.prefilling]
+            active = self._decode_rows(self.decode_chunk, finished)
             if not active:
                 return
             K = self.decode_chunk
@@ -840,31 +765,11 @@ class InferenceEngine:
                 "real_tokens": K * len(active),
                 "slot_tokens": K * self.max_batch}
             for slot, seq in active:
-                n_new, fin = 0, False
-                for j in range(K):
-                    tok = int(block[j, slot])
-                    if self.eos_token is not None \
-                            and tok == self.eos_token:
-                        self._note_finish(seq.request_id, "stop")
-                        fin = True
-                        break
-                    seq.generated.append(tok)
-                    n_new += 1
-                    if self.track_progress:
-                        self._progress.setdefault(seq.request_id,
-                                                  []).append(tok)
-                    if len(seq.generated) >= seq.max_new_tokens:
-                        fin = True
-                        break
-                # ONE record entry per dispatch (the K-step loop is one
-                # device round trip — per-token host timestamps would be
-                # fiction), noted BEFORE _finish so e2e covers every token
-                if n_new and seq.record is not None:
-                    seq.record.note_decode(now, n_new)
-                if fin:
+                toks = block[:, slot].tolist()
+                if self._book_tokens(seq, toks, now, mixed=False):
                     self._finish(slot, seq, finished)
                 else:
-                    self._tokens[slot] = int(block[K - 1, slot])
+                    self._tokens[slot] = toks[-1]
                     self._positions[slot] = seq.num_tokens - 1
             if span.is_enabled():
                 span.set_metadata(finished=len(finished) - n_done)

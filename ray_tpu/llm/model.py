@@ -52,12 +52,18 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ray_tpu.llm.cache import SCRATCH_PAGE
-from ray_tpu.models.llama import LlamaConfig, Params, _rmsnorm, _rope
+from ray_tpu.llm import tp as TP
+from ray_tpu.llm.cache import SCRATCH_PAGE, make_kv_cache
+from ray_tpu.models.llama import (LlamaConfig, Params, _rmsnorm, _rope,
+                                  init_params)
 from ray_tpu.ops import moe
-from ray_tpu.ops.paged_attention import (ragged_paged_attention,
+from ray_tpu.ops.paged_attention import (kernels_supported,
+                                         ragged_paged_attention,
                                          write_ragged_kv)
+from ray_tpu.parallel.mesh import shard_map_compat
+from ray_tpu.util import compile_tracker
 
 KVCache = dict  # {"k", "v"[, "k_scale", "v_scale"]}, leading axis layers
 
@@ -276,8 +282,8 @@ def _ragged_decode_loop(params: Params, tokens: jax.Array,
 
 #: module-level jits (shared compile cache across engine instances with
 #: equal shapes/statics — many short-lived engines, e.g. a test suite,
-#: must not each pay the XLA compile). tp.py wraps the raw bodies in
-#: shard_map instead.
+#: must not each pay the XLA compile). Over a mesh, StepPrograms wraps the
+#: raw bodies in shard_map instead.
 ragged_step = functools.partial(jax.jit, static_argnames=(
     "cfg", "tp_axis", "paged_impl", "max_q_len", "decode_rows"),
     donate_argnames=("kv",))(_ragged_step_body)
@@ -290,8 +296,8 @@ ragged_decode_loop = functools.partial(jax.jit, static_argnames=(
 def _copy_page_body(kv: KVCache, src, dst) -> KVCache:
     """Copy-on-write: duplicate one page across all layers — pages AND
     their int8 scales, one tree_map (a prefix-hit sequence about to
-    write into a shared page copies it first). Plain body so tp.py can
-    shard_map it over local head shards."""
+    write into a shared page copies it first). Plain body so StepPrograms
+    can shard_map it over local head shards."""
     return jax.tree.map(
         lambda leaf: leaf.at[:, dst].set(
             lax.dynamic_index_in_dim(leaf, src, axis=1, keepdims=False)),
@@ -300,6 +306,138 @@ def _copy_page_body(kv: KVCache, src, dst) -> KVCache:
 
 copy_page = functools.partial(jax.jit, donate_argnames=("kv",))(
     _copy_page_body)
+
+
+#: one-device weights are BORN on the device by a jitted init (module-level:
+#: engines with equal configs share the compile). Fused, the f32 draw of a
+#: bf16 weight never exists in HBM — the eager init's largest temporary is
+#: what OOMs an 8B-width model. (The pool is plain zeros: eager has none.)
+_init_params = jax.jit(init_params, static_argnums=(0,))
+
+
+class StepPrograms:
+    """The ONE seam between the engine and its device programs: the mixed
+    ragged step, the multi-step decode loop and the COW page copy, the
+    static hints they compile under, the kernel-or-reference choice, and
+    where weights and the page pool are born (in their final layout: no
+    device ever stages a whole sharded model).
+
+    ``mesh=None``: the module-level jits above. A ('tp',) mesh: one
+    ``shard_map`` jit each over the raw bodies, built once per (cfg,
+    mesh), specs from llm/tp.py. Either way a program has ONE static
+    shape and compiles once; where a compile tracker runs, the three
+    callables record their compiles with it (llm.ragged_step,
+    llm.decode_loop, llm.copy_page).
+    """
+
+    def __init__(self, cfg: LlamaConfig, *, decode_chunk: int,
+                 max_q_len: int, decode_rows: int, kv_quantized: bool,
+                 mesh=None):
+        self.cfg = cfg
+        self.mesh = mesh
+        #: the paged-attention (and expert) implementation the programs
+        #: compile: the Pallas kernels on a TPU, the references elsewhere
+        #: — observed, never configured (device_report()), and from the
+        #: platform the programs RUN on: a CPU test mesh in a TPU-default
+        #: worker takes the reference
+        impl = self.paged_impl = "kernel" if kernels_supported(
+            None if mesh is None else mesh.devices.flat[0]) else "reference"
+        #: name -> (the jit itself, the static arguments of every call)
+        if mesh is None:
+            self.jits = {
+                "ragged_step": (ragged_step, dict(
+                    cfg=cfg, paged_impl=impl, max_q_len=max_q_len,
+                    decode_rows=decode_rows)),
+                "decode_loop": (ragged_decode_loop, dict(
+                    num_steps=decode_chunk, cfg=cfg, paged_impl=impl)),
+                "copy_page": (copy_page, {})}
+        else:
+            self.jits = self._shard_mapped(decode_chunk, max_q_len,
+                                           decode_rows, kv_quantized)
+        self.tracker = compile_tracker.ensure_started()
+        self.ragged_step = self._callable("ragged_step")
+        self.decode_loop = self._callable("decode_loop")
+        self.copy_page = self._callable("copy_page")
+
+    def _callable(self, name: str):
+        jit, statics = self.jits[name]
+        call = functools.partial(jit, **statics) if statics else jit
+        if self.tracker is None:
+            return call
+        # every compile is recorded with its arg signature, the ground
+        # truth for the O(1)-compile invariant in production. The probe
+        # is the program count: growth across one call is that call's
+        return self.tracker.wrap(call, name="llm." + name,
+                                 probe=self.compiled_step_programs)
+
+    def _shard_mapped(self, decode_chunk, max_q_len, decode_rows,
+                      kv_quantized):
+        """The three programs over ``self.mesh`` (and, kept for the
+        birth of weights and pool, the shardings of both)."""
+        cfg, impl, mesh = self.cfg, self.paged_impl, self.mesh
+        TP.validate_tp(cfg, mesh.shape[TP.TP_AXIS])
+        pspecs, kvs = TP.tp_param_specs(cfg), TP.kv_specs(kv_quantized)
+        rep = P()
+        self._param_sharding, self._kv_sharding = jax.tree.map(
+            lambda spec: NamedSharding(mesh, spec), (pspecs, kvs),
+            is_leaf=lambda x: isinstance(x, P))
+
+        def step(params, tokens, token_pos, token_page, token_slot,
+                 page_table, q_start, q_len, kv_len, kv):
+            # per-shard: local kv-heads write their ragged K/V slice in
+            # place into, and attend over, the local head slice of the
+            # stacked page pool (the scans' carry); the two psums per
+            # layer inside _ragged_step_body close the TP seam
+            return _ragged_step_body(
+                params, tokens, token_pos, token_page, token_slot,
+                page_table, q_start, q_len, kv_len, kv, cfg, TP.TP_AXIS,
+                impl, max_q_len, decode_rows)
+
+        def loop(params, tokens, positions, kv, page_table, seq_lens):
+            return _ragged_decode_loop(
+                params, tokens, positions, kv, page_table, seq_lens,
+                decode_chunk, cfg, TP.TP_AXIS, impl)
+
+        def sharded(fn, in_specs, out_specs, donate):
+            return jax.jit(shard_map_compat(
+                fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs),
+                donate_argnums=(donate,)), {}
+
+        return {
+            "ragged_step": sharded(
+                step, (pspecs, P(None), P(None), P(None), P(None),
+                       P(None, None), P(None), P(None), P(None), kvs),
+                (rep, kvs), 9),
+            "decode_loop": sharded(
+                loop, (pspecs, P(None), P(None), kvs, P(None, None),
+                       P(None)), (rep, kvs, rep, rep), 3),
+            "copy_page": sharded(_copy_page_body, (kvs, rep, rep), kvs, 0)}
+
+    def compiled_step_programs(self) -> int:
+        """Resident compiled step programs: the O(1) compile budget the
+        ragged design promises. Without a mesh the three module jits
+        share their cache across engines, so the count is process-wide
+        (in a fresh process running one engine, exactly that engine's)."""
+        return sum(jit._cache_size() for jit, _ in self.jits.values())
+
+    def init_params(self, seed: int) -> Params:
+        key = jax.random.PRNGKey(seed)
+        if self.mesh is None:
+            return _init_params(self.cfg, key)
+        return jax.jit(functools.partial(init_params, self.cfg),
+                       out_shardings=self._param_sharding)(key)
+
+    def place_params(self, params: Params) -> Params:
+        if self.mesh is None:
+            return params
+        return jax.device_put(params, self._param_sharding)
+
+    def init_kv(self, total_pages: int, page_size: int, kv_dtype) -> KVCache:
+        make = functools.partial(make_kv_cache, self.cfg, total_pages,
+                                 page_size, kv_dtype=kv_dtype)
+        if self.mesh is None:
+            return make()
+        return jax.jit(make, out_shardings=self._kv_sharding)()
 
 
 # ---------------------------------------------------------------------------

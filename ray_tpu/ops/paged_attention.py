@@ -10,9 +10,6 @@ scalar-prefetch pattern:
     ``[total_pages, kv_heads, page_size, head_dim]``; a sequence's cache
     is the pages named by its row of ``page_table`` — no per-sequence
     contiguous allocation, so fragmentation-free continuous batching;
-  - ``paged_attention``: one decode token per sequence
-    ``[B, q_heads, head_dim]``, grid (B, max_pages) — the original
-    decode-only kernel, kept as the single-token oracle;
   - ``ragged_paged_attention``: a RAGGED token batch ``[T, Hq, D]`` —
     concatenated query tokens from R sequences described by
     ``(q_start, q_len, kv_len)`` rows, where q_len is a prefill chunk
@@ -65,171 +62,9 @@ from jax.experimental.pallas import tpu as pltpu
 _NEG_INF = float("-inf")
 
 
-# --------------------------------------------------------------------------
-# Pure-JAX reference (portable fallback + numerics oracle)
-# --------------------------------------------------------------------------
-
-def paged_attention_reference(q, k_pages, v_pages, page_table, seq_lens, *,
-                              sm_scale: Optional[float] = None) -> jax.Array:
-    """Gather-based paged attention.
-
-    q:          [B, Hq, D]       one decode token per sequence
-    k/v_pages:  [P, Hkv, ps, D]  the shared page pool
-    page_table: [B, max_pages]   page ids per sequence (unused tail: any)
-    seq_lens:   [B]              valid KV tokens (incl. the current one)
-    returns     [B, Hq, D]
-    """
-    B, Hq, D = q.shape
-    P_, Hkv, ps, _ = k_pages.shape
-    max_pages = page_table.shape[1]
-    if sm_scale is None:
-        sm_scale = D ** -0.5
-    # gather pages -> [B, Hkv, max_pages*ps, D]
-    k = k_pages[page_table]  # [B, max_pages, Hkv, ps, D]
-    v = v_pages[page_table]
-    k = k.transpose(0, 2, 1, 3, 4).reshape(B, Hkv, max_pages * ps, D)
-    v = v.transpose(0, 2, 1, 3, 4).reshape(B, Hkv, max_pages * ps, D)
-    qg = q.reshape(B, Hkv, Hq // Hkv, D).astype(jnp.float32)
-    s = jnp.einsum("bgqd,bgtd->bgqt", qg, k.astype(jnp.float32)) * sm_scale
-    pos = jnp.arange(max_pages * ps)[None, None, None, :]
-    s = jnp.where(pos < seq_lens[:, None, None, None], s, _NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bgqt,bgtd->bgqd", p, v.astype(jnp.float32))
-    return o.reshape(B, Hq, D).astype(q.dtype)
-
-
-# --------------------------------------------------------------------------
-# Pallas kernel
-# --------------------------------------------------------------------------
-
-def _decode_kernel(page_table_ref, seq_lens_ref,  # scalar prefetch
-                   q_ref, k_ref, v_ref, o_ref,
-                   acc_ref, m_ref, l_ref, *, sm_scale, page_size,
-                   q_per_kv):
-    b, pi = pl.program_id(0), pl.program_id(1)
-    n_pages = pl.num_programs(1)
-    seq_len = seq_lens_ref[b]
-
-    @pl.when(pi == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-
-    # tokens this page holds for this sequence: (0, page_size]
-    page_start = pi * page_size
-    valid = seq_len - page_start
-
-    @pl.when(valid > 0)
-    def _page():
-        q = q_ref[0].astype(jnp.float32)         # [Hq, D]
-        k = k_ref[0]                              # [Hkv, ps, D]
-        v = v_ref[0]
-        Hq = q.shape[0]
-        Hkv = k.shape[0]
-        qg = q.reshape(Hkv, q_per_kv, q.shape[-1])
-        # batched over kv heads on the MXU: [Hkv, qpk, ps]
-        s = lax.dot_general(
-            qg, k.astype(jnp.float32),
-            (((2,), (2,)), ((0,), (0,)))) * sm_scale
-        col = lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        s = jnp.where(col < valid, s, _NEG_INF)
-        m_prev = m_ref[:, :1]                     # [Hq, 1]
-        l_prev = l_ref[:, :1]
-        s2 = s.reshape(Hq, page_size)
-        m_new = jnp.maximum(m_prev, s2.max(axis=-1, keepdims=True))
-        p = jnp.where(jnp.isneginf(s2), 0.0, jnp.exp(s2 - m_new))
-        corr = jnp.where(jnp.isneginf(m_prev), 0.0, jnp.exp(m_prev - m_new))
-        l_new = l_prev * corr + p.sum(axis=-1, keepdims=True)
-        pv = lax.dot_general(                      # [Hkv, qpk, D]
-            p.reshape(Hkv, q_per_kv, page_size).astype(v.dtype), v,
-            (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        acc_ref[:] = acc_ref[:] * corr + pv.reshape(Hq, -1)
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
-
-    @pl.when(pi == n_pages - 1)
-    def _finish():
-        l = jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
-def _paged_attention_pallas(q, k_pages, v_pages, page_table, seq_lens,
-                            sm_scale: float, interpret: bool = False):
-    B, Hq, D = q.shape
-    P_, Hkv, ps, _ = k_pages.shape
-    max_pages = page_table.shape[1]
-    q_per_kv = Hq // Hkv
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, max_pages),
-        in_specs=[
-            pl.BlockSpec((1, Hq, D), lambda b, p, pt, sl: (b, 0, 0)),
-            pl.BlockSpec((1, Hkv, ps, D),
-                         lambda b, p, pt, sl: (pt[b, p], 0, 0, 0)),
-            pl.BlockSpec((1, Hkv, ps, D),
-                         lambda b, p, pt, sl: (pt[b, p], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, Hq, D), lambda b, p, pt, sl: (b, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((Hq, D), jnp.float32),
-            pltpu.VMEM((Hq, 128), jnp.float32),
-            pltpu.VMEM((Hq, 128), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(_decode_kernel, sm_scale=sm_scale,
-                               page_size=ps, q_per_kv=q_per_kv)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
-        interpret=interpret,
-    )(page_table, seq_lens, q, k_pages, v_pages)
-
-
 def kernels_supported(device: Optional[jax.Device] = None) -> bool:
     dev = device if device is not None else jax.devices()[0]
     return dev.platform == "tpu"
-
-
-def paged_attention(q, k_pages, v_pages, page_table, seq_lens, *,
-                    sm_scale: Optional[float] = None,
-                    interpret: Optional[bool] = None,
-                    impl: Optional[str] = None) -> jax.Array:
-    """Dispatch: Pallas kernel on TPU, gather reference elsewhere.
-
-    ``interpret=True`` forces the kernel through the Pallas interpreter
-    (CPU) — used by tests to validate the kernel itself off-TPU.
-    ``impl`` pins the implementation outright ("kernel" | "reference"):
-    code that compiles for a SPECIFIC mesh (the tp serving engine) must
-    choose by the mesh's platform, because the process's default backend
-    (what the interpret=None autodetect sees) can be a different
-    accelerator than the mesh the program runs on.
-    """
-    if sm_scale is None:
-        sm_scale = q.shape[-1] ** -0.5
-    if q.shape[1] % k_pages.shape[1]:
-        raise ValueError(
-            f"q heads {q.shape[1]} not a multiple of kv heads "
-            f"{k_pages.shape[1]}")
-    if impl == "reference":
-        return paged_attention_reference(
-            q, k_pages, v_pages, page_table, seq_lens, sm_scale=sm_scale)
-    if impl is not None and impl != "kernel":
-        raise ValueError(f"impl must be 'kernel' or 'reference', "
-                         f"got {impl!r}")
-    if interpret is None:
-        if impl is None and not kernels_supported():
-            return paged_attention_reference(
-                q, k_pages, v_pages, page_table, seq_lens,
-                sm_scale=sm_scale)
-        interpret = False
-    return _paged_attention_pallas(
-        q, k_pages, v_pages, page_table,
-        seq_lens.astype(jnp.int32), sm_scale, interpret)
 
 
 # --------------------------------------------------------------------------
@@ -691,8 +526,8 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, q_start,
                            impl: Optional[str] = None,
                            layer=None) -> jax.Array:
     """Mixed prefill+decode attention over a ragged token batch in ONE
-    dispatch. Dispatch rules identical to ``paged_attention``: Pallas
-    kernel on TPU, gather reference elsewhere; ``impl`` pins the choice
+    dispatch. Dispatch rule (``_use_reference``): Pallas kernel on
+    TPU, gather reference elsewhere; ``impl`` pins the choice
     for mesh-specific programs, ``interpret=True`` runs the kernel
     through the Pallas interpreter on CPU (the tier-1 kernel tests).
 

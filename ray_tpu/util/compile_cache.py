@@ -1,8 +1,7 @@
 """Persistent XLA compile cache, placed from outside.
 
-Every process that compiles for the chip — runtime workers leased TPU
-chips, ``bench.py``, ``bench_llm.py`` — calls ``configure()`` once
-before it compiles. The directory is decided by the environment, not by
+Every process that compiles for the chip — the runtime's workers leased
+TPU chips — calls ``configure()`` once before it compiles. The directory is decided by the environment, not by
 code:
 
 - ``JAX_COMPILATION_CACHE_DIR`` set: jax reads the variable itself and

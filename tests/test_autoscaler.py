@@ -229,11 +229,15 @@ def test_tpu_slice_gang_scale_up_and_drain():
                 _system_config={"infeasible_grace_s": 60.0})
         pg = placement_group([{"TPU-v5e-8-head": 1}],
                              strategy="STRICT_PACK")
-        # pending gang bundle -> exactly one slice-create API call
-        deadline = time.monotonic() + 20
-        while time.monotonic() < deadline and not fake.requests:
+        # pending gang bundle -> exactly one slice-create API call. Wait
+        # for the POST itself: the first request is the restart
+        # reconcile's GET (list_live), the create a pass or more later
+        def posts():
+            return [r for r in fake.requests if r[0] == "POST"]
+        deadline = time.monotonic() + 60
+        while time.monotonic() < deadline and not posts():
             time.sleep(0.1)
-        creates = [r for r in fake.requests if r[0] == "POST"]
+        creates = posts()
         assert len(creates) == 1, fake.requests
         method, url, body = creates[0]
         assert "tpu.googleapis.com" in url and "nodes?nodeId=rtpu-" in url
@@ -241,7 +245,7 @@ def test_tpu_slice_gang_scale_up_and_drain():
         assert address in body["metadata"]["startup-script"]
         # capped at max_workers: no second create even while pending
         time.sleep(1.0)
-        assert len([r for r in fake.requests if r[0] == "POST"]) == 1
+        assert len(posts()) == 1
 
         # 'slice boots': stand in for the TPU VM with a local daemon that
         # registers under the provisioned node identity + slice resources

@@ -174,6 +174,12 @@ def test_instance_manager_imports_without_jax():
 # ----------------------------------------------- integration: full journal
 
 
+#: deadline for anything that waits on a process to boot (a python
+#: import, a daemon registering, a reconcile pass): seconds alone, tens of
+#: seconds beside five other xdist workers' clusters
+_BOOT_S = 120
+
+
 def _wait(predicate, timeout, period=0.2, desc="condition"):
     deadline = time.monotonic() + timeout
     while time.monotonic() < deadline:
@@ -196,7 +202,7 @@ def _boot_head(session):
             return True
         except RpcError:
             return False
-    _wait(up, 30, desc="head boot")
+    _wait(up, _BOOT_S, desc="head boot")
     return head_proc, address, probe
 
 
@@ -267,19 +273,55 @@ def test_full_lifecycle_journal_chain():
 # ----------------------------------------------------- chaos: crash launch
 
 
-def _spawn_runner(address, opts, fault=""):
+def _spawn_runner(address, opts, log_path, fault=""):
+    """The autoscaler as its own process, its output (and that of the node
+    daemons it launches, which inherit it) appended to ``log_path``: a
+    pipe would close under the daemons when the test drops a killed
+    runner's handle, and nobody drains it while they live."""
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     if fault:
         env["RTPU_FAULT_INJECT"] = fault
     else:
         env.pop("RTPU_FAULT_INJECT", None)
-    proc = subprocess.Popen(
-        [sys.executable, "-m", "ray_tpu.autoscaler", address,
-         json.dumps(opts)],
-        env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
-        text=True)
-    return proc
+    with open(log_path, "a", encoding="utf-8") as log:
+        return subprocess.Popen(
+            [sys.executable, "-m", "ray_tpu.autoscaler", address,
+             json.dumps(opts)],
+            env=env, stdout=log, stderr=subprocess.STDOUT)
+
+
+def _restart_reconciles(probe):
+    """How many restarts have replayed persisted records so far (each
+    journals one ``autoscaler_restart_reconcile`` when it finds any)."""
+    return len(probe.call("events_dump",
+                          {"type": "autoscaler_restart_reconcile"},
+                          timeout=10))
+
+
+def _node_state(probe, ledger_path, log_path):
+    """What a failed liveness assertion needs to be read: the provider's
+    ledger, each owned pid's state, the head's node table and the tail
+    of the runners' (and their daemons') output."""
+    def read(path):
+        try:
+            with open(path, encoding="utf-8", errors="replace") as f:
+                return f.read()
+        except OSError as e:
+            return repr(e)
+    ledger = read(ledger_path)
+    pids = {}
+    for line in ledger.splitlines():
+        try:
+            pid = json.loads(line)["pid"]
+        except (ValueError, KeyError):
+            continue
+        status = read(f"/proc/{pid}/status")
+        pids[pid] = next((ln for ln in status.splitlines()
+                          if ln.startswith("State:")), status)
+    return {"ledger": ledger, "pids": pids,
+            "nodes": probe.call("list_nodes", timeout=5),
+            "log_tail": read(log_path)[-3000:]}
 
 
 def _kill_ledger_pids(ledger_path):
@@ -311,6 +353,7 @@ def test_sigkill_mid_launch_restart_converges_no_orphans(tmp_path):
 
     session = os.urandom(4).hex()
     ledger = str(tmp_path / "provider.ledger")
+    log = str(tmp_path / "runners.log")
     opts = {"session": session, "ledger_path": ledger,
             "poll_period_s": 0.2,
             "node_types": {"w": {"resources": {"CPU": 1.0},
@@ -319,9 +362,9 @@ def test_sigkill_mid_launch_restart_converges_no_orphans(tmp_path):
     runner = None
     try:
         # --- crash: dies by SIGKILL right after the provider create
-        runner = _spawn_runner(address, opts,
+        runner = _spawn_runner(address, opts, log,
                                fault="autoscaler.post_create=kill9")
-        assert runner.wait(timeout=60) == -signal.SIGKILL
+        assert runner.wait(timeout=_BOOT_S) == -signal.SIGKILL
         keys = _wait(lambda: probe.call(
             "kv_keys", {"prefix": im.KV_PREFIX}, timeout=5), 10,
             desc="write-ahead record")
@@ -336,13 +379,13 @@ def test_sigkill_mid_launch_restart_converges_no_orphans(tmp_path):
         # the launched daemon registers with the head on its own
         _wait(lambda: any(n["node_id"] == nid and n["alive"]
                           for n in probe.call("list_nodes", timeout=5)),
-              45, desc="orphan node registration")
+              _BOOT_S, desc="orphan node registration")
 
         # --- restart: reconcile must adopt, not orphan-kill or relaunch
-        runner = _spawn_runner(address, opts)
+        runner = _spawn_runner(address, opts, log)
         _wait(lambda: probe.call(
             "kv_get", {"key": im.KV_PREFIX + nid},
-            timeout=5)["state"] == im.RUNNING, 45,
+            timeout=5)["state"] == im.RUNNING, _BOOT_S,
             desc="adoption to RUNNING")
         types = [e["type"] for e in _instance_events(probe, nid)]
         assert types == ["instance_requested", "instance_running"], types
@@ -350,7 +393,8 @@ def test_sigkill_mid_launch_restart_converges_no_orphans(tmp_path):
         assert len(traces) == 1
         # zero orphans: provider owns exactly the adopted node, nothing
         # was terminated, nothing unrecorded, no second launch
-        assert set(provider.list_live()) == {nid}
+        assert set(provider.list_live()) == {nid}, \
+            _node_state(probe, ledger, log)
         assert probe.call("kv_keys", {"prefix": im.KV_PREFIX},
                           timeout=5) == [im.KV_PREFIX + nid]
         evs = probe.call("events_dump", {}, timeout=10)
@@ -359,15 +403,23 @@ def test_sigkill_mid_launch_restart_converges_no_orphans(tmp_path):
                      "node_launch_failed")], evs
 
         # --- double restart: idempotency, no duplicate journal entries
+        seen = _restart_reconciles(probe)
         runner.send_signal(signal.SIGKILL)
-        runner.wait(timeout=10)
-        runner = _spawn_runner(address, opts)
-        time.sleep(3.0)  # several reconcile passes
-        assert runner.poll() is None, runner.stdout.read()
+        runner.wait(timeout=30)
+        runner = _spawn_runner(address, opts, log)
+        # the pass that could duplicate a transition is the restarted
+        # runner's FIRST, which replays the persisted record and journals
+        # that it did: wait for it (a loaded machine can spend a fixed
+        # sleep importing), then let a few ordinary passes follow
+        _wait(lambda: _restart_reconciles(probe) > seen, _BOOT_S,
+              desc="the second restart's reconcile")
+        time.sleep(1.0)
+        assert runner.poll() is None, _node_state(probe, ledger, log)
         types = [e["type"] for e in _instance_events(probe, nid)]
         assert types == ["instance_requested", "instance_running"], \
             f"double restart duplicated transitions: {types}"
-        assert set(provider.list_live()) == {nid}
+        assert set(provider.list_live()) == {nid}, \
+            _node_state(probe, ledger, log)
     finally:
         if runner is not None:
             runner.kill()
@@ -399,21 +451,22 @@ def test_requested_orphan_terminated_after_restart(tmp_path):
     opts2 = {**base, "node_types": {"w": {"resources": {"CPU": 1.0},
                                           "max_workers": 1,
                                           "min_workers": 0}}}
+    log = str(tmp_path / "runners.log")
     head_proc, address, probe = _boot_head(session)
     runner = None
     try:
-        runner = _spawn_runner(address, opts1,
+        runner = _spawn_runner(address, opts1, log,
                                fault="autoscaler.pre_create=kill9")
-        assert runner.wait(timeout=60) == -signal.SIGKILL
+        assert runner.wait(timeout=_BOOT_S) == -signal.SIGKILL
         keys = _wait(lambda: probe.call(
             "kv_keys", {"prefix": im.KV_PREFIX}, timeout=5), 10,
             desc="write-ahead record")
         nid = probe.call("kv_get", {"key": keys[0]}, timeout=5)["node_id"]
         time.sleep(1.0)  # age the record past the 0.5s orphan grace
 
-        runner = _spawn_runner(address, opts2)
+        runner = _spawn_runner(address, opts2, log)
         _wait(lambda: probe.call("kv_keys", {"prefix": im.KV_PREFIX},
-                                 timeout=5) == [], 30,
+                                 timeout=5) == [], _BOOT_S,
               desc="orphan record cleanup")
         chain = _instance_events(probe, nid)
         assert [e["type"] for e in chain] == [

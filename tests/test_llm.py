@@ -1,11 +1,15 @@
-"""LLM inference tests: paged attention numerics, engine-vs-oracle greedy
-decoding, continuous batching invariance, page recycling.
+"""LLM inference tests: engine-vs-oracle greedy decoding, continuous
+batching invariance, page recycling.
 
 The reference has no in-tree equivalent (vLLM does this on GPU); the
 oracle here is the training-path Llama forward (models/llama.py) run
 autoregressively on the full sequence each step — the engine's paged
 incremental path must reproduce its greedy choices exactly.
 """
+
+import json
+import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -15,8 +19,6 @@ import pytest
 from ray_tpu.llm import InferenceEngine
 from ray_tpu.llm.cache import PageAllocator
 from ray_tpu.models.llama import LlamaConfig, forward, init_params
-from ray_tpu.ops.paged_attention import (_paged_attention_pallas,
-                                         paged_attention_reference)
 
 CFG = LlamaConfig.tiny(n_layers=2, dtype=jnp.float32)
 
@@ -24,45 +26,6 @@ CFG = LlamaConfig.tiny(n_layers=2, dtype=jnp.float32)
 @pytest.fixture(scope="module")
 def params():
     return init_params(CFG, jax.random.PRNGKey(7))
-
-
-# ------------------------------------------------------------------ kernel
-
-
-def test_paged_attention_reference_matches_dense():
-    key = jax.random.PRNGKey(0)
-    B, Hq, Hkv, D, ps, P = 2, 8, 4, 64, 8, 10
-    ks = jax.random.split(key, 3)
-    q = jax.random.normal(ks[0], (B, Hq, D))
-    kp = jax.random.normal(ks[1], (P, Hkv, ps, D))
-    vp = jax.random.normal(ks[2], (P, Hkv, ps, D))
-    pt = jnp.array([[1, 2, 3], [4, 5, 6]], jnp.int32)
-    sl = jnp.array([11, 24], jnp.int32)
-    out = paged_attention_reference(q, kp, vp, pt, sl)
-    for b in range(B):
-        k = kp[pt[b]].transpose(1, 0, 2, 3).reshape(Hkv, -1, D)[:, :sl[b]]
-        v = vp[pt[b]].transpose(1, 0, 2, 3).reshape(Hkv, -1, D)[:, :sl[b]]
-        qg = q[b].reshape(Hkv, Hq // Hkv, D)
-        s = jnp.einsum("gqd,gtd->gqt", qg, k) * D ** -0.5
-        o = jnp.einsum("gqt,gtd->gqd",
-                       jax.nn.softmax(s, -1), v).reshape(Hq, D)
-        np.testing.assert_allclose(out[b], o, atol=1e-5)
-
-
-def test_paged_attention_pallas_interpret_matches_reference():
-    key = jax.random.PRNGKey(3)
-    B, Hq, Hkv, D, ps, P = 3, 8, 4, 128, 16, 12
-    ks = jax.random.split(key, 3)
-    q = jax.random.normal(ks[0], (B, Hq, D), jnp.float32)
-    kp = jax.random.normal(ks[1], (P, Hkv, ps, D), jnp.float32)
-    vp = jax.random.normal(ks[2], (P, Hkv, ps, D), jnp.float32)
-    pt = jnp.array([[0, 1, 2], [3, 4, 5], [6, 7, 8]], jnp.int32)
-    sl = jnp.array([5, 33, 48], jnp.int32)
-    ref = paged_attention_reference(q, kp, vp, pt, sl)
-    out = _paged_attention_pallas(q, kp, vp, pt, sl, D ** -0.5,
-                                  interpret=True)
-    # tolerance covers MXU-emulation dot precision, not logic
-    np.testing.assert_allclose(out, ref, atol=2e-2)
 
 
 # ------------------------------------------------------------------ engine
@@ -131,6 +94,133 @@ def test_eos_stops_and_pages_recycle(params):
     got = eng.generate(prompt, max_new_tokens=10)
     assert got == want, f"eos not honored: {got} vs {want}"
     assert eng.allocator.num_free == free0, "pages leaked after finish"
+
+
+# where each of a lone request's tokens is booked, decode_chunk 4: token 0
+# by the mixed step that finishes its prompt; token 1 by the next mixed
+# step's decode row when a second prompt arrives in between, else tokens
+# 1-4 by one decode block
+# (token, the kind of the step that books it, which step that is)
+_BOOKED_AT = {"first-token": (0, "mixed", 0), "mixed-row": (1, "mixed", 1),
+              "decode-block": (2, "decode", 1)}
+
+
+@pytest.fixture(scope="module")
+def learned(params):
+    """(prompt, its first six greedy tokens, no EOS set), the first three
+    distinct: a tiny greedy model repeats itself, and a token that came
+    before cannot be the EOS that ends the sequence later."""
+    eng = InferenceEngine(CFG, params, page_size=8, total_pages=32,
+                          max_batch=2, max_seq_len=64, decode_chunk=4)
+    for prompt in ([154, 179], [40, 171, 23, 143, 69], [109, 7]):
+        toks = eng.generate(prompt, max_new_tokens=6)
+        if len(set(toks[:3])) == 3:
+            return prompt, toks
+    raise AssertionError("no candidate prompt starts with three distinct "
+                         "greedy tokens")
+
+
+@pytest.mark.parametrize("where", list(_BOOKED_AT))
+@pytest.mark.parametrize("reason", ["stop", "length"])
+def test_token_booking_is_one_rule(params, learned, reason, where):
+    """One rule wherever a token is booked: EOS ends the sequence and is
+    dropped ("stop"), the token that uses up max_new_tokens ends it and
+    is kept ("length"); what was streamed and what the request record
+    counted is what was generated; pages and slot come back."""
+    prompt, toks = learned
+    at, kind, step = _BOOKED_AT[where]
+    eng = InferenceEngine(CFG, params, page_size=8, total_pages=32,
+                          max_batch=2, max_seq_len=64, decode_chunk=4,
+                          prefix_cache=False, request_log=True)
+    eng.track_progress = True
+    free0 = eng.allocator.num_free
+    if reason == "stop":
+        eng.eos_token = toks[at]
+    rid = eng.add_request(
+        prompt, max_new_tokens=len(toks) if reason == "stop" else at + 1)
+    done, streamed, kind_at_finish = {}, [], None
+    for i in range(50):
+        if i == 1 and where == "mixed-row":
+            # a prompt to prefill beside the first one's decode row (its
+            # own tokens never meet the first one's EOS: one of its own)
+            eng.add_request([t + 1 for t in prompt], max_new_tokens=1)
+        done.update(eng.step())
+        streamed += eng.drain_progress().get(rid, [])
+        if rid in done and kind_at_finish is None:
+            kind_at_finish = eng._step_meta["kind"], i
+        if not eng.has_work():
+            break
+    want = toks[:at] if reason == "stop" else toks[:at + 1]
+    assert done[rid] == want
+    assert eng.finish_reason(rid) == reason
+    assert streamed == want
+    # it ended where the case says: the step kind, and which step
+    assert kind_at_finish == (kind, step)
+    rec = eng.request_log.get(rid)
+    assert rec.n_generated == len(want) and rec.finish_reason == reason
+    assert rec.ttft is not None and rec.finish_ts >= rec.first_ts
+    assert sum(n for _, n in rec.decode_entries()) \
+        == max(0, len(want) - 1)         # the first token is the TTFT's
+    assert eng.allocator.num_free == free0, "pages leaked after finish"
+    assert eng._slots == [None] * eng.max_batch and not eng.running
+
+
+def _metric_patterns(metric_file):
+    """The module-name patterns of one of the benchmark's trace metrics,
+    read from its file: the test follows the yardstick, not a copy."""
+    path = os.path.join(os.path.dirname(__file__), os.pardir, "benchmark",
+                        "metrics", metric_file)
+    with open(path, encoding="utf-8") as f:
+        return [re.compile(p) for p in json.load(f)["args"]["patterns"]]
+
+
+@pytest.mark.parametrize("tp", [1, 2], ids=["tp1", "tp2"])
+def test_step_program_names_are_the_benchmarks(params, tp):
+    """The names the yardstick matches, pinned: the lowered module names
+    of the mixed step and the decode loop (benchmark/metrics/
+    mixed_step_ms*.json, decode_step_ms.*.json match them in the trace;
+    a renamed jit nulls four per-layer metrics in silence), the private
+    attribute benchmark/replica.py reads, and the compile tracker's
+    names for the step programs."""
+    # shapes no other test of this process uses: the warm-up must compile
+    eng = InferenceEngine(CFG, params, tp=tp, page_size=8, total_pages=40,
+                          max_batch=3, max_seq_len=72, decode_chunk=3,
+                          prefill_chunk=24)
+    names = ("llm.ragged_step", "llm.decode_loop")
+    tracker = eng._fns.tracker
+
+    def compiles():
+        return [(tracker.callable_stats(n) or {"compiles": 0})["compiles"]
+                for n in names]
+
+    before = compiles()
+    eng.generate([5, 17, 42], max_new_tokens=5)
+    assert all(b > a for a, b in zip(before, compiles())), \
+        (before, compiles())
+    assert eng._fns.paged_impl == eng.device_report()["paged_impl"] \
+        == "reference"
+
+    def i32(*shape):
+        return jnp.zeros(shape, jnp.int32)
+
+    T, R, B = eng.ragged_tokens, eng.ragged_rows, eng.max_batch
+    mp = eng.max_pages_per_seq
+    dispatched = {
+        "ragged_step": (eng.params, i32(T), i32(T), i32(T), i32(T),
+                        i32(R, mp), i32(R), i32(R), i32(R), eng.kv),
+        "decode_loop": (eng.params, i32(B), i32(B), eng.kv, i32(B, mp),
+                        i32(B))}
+    for program, metric_file in (("ragged_step", "mixed_step_ms.json"),
+                                 ("decode_loop",
+                                  "decode_step_ms.reason.json")):
+        jit, statics = eng._fns.jits[program]
+        text = jit.lower(*dispatched[program], **statics).as_text()
+        module = re.search(r"module @(\S+)", text).group(1)
+        assert any(rx.search(module)
+                   for rx in _metric_patterns(metric_file)), \
+            (module, metric_file)
+    if tp > 1:       # its own jits; tp=1 counts the process's shared ones
+        assert eng.compiled_step_programs() <= 3
 
 
 def test_page_allocator():

@@ -89,6 +89,7 @@ def _make_elastic_loop():
     decreases), checkpoint every step, rank 1 kills itself once."""
     def loop(cfg):
         import os
+        import time
 
         import jax
         import jax.numpy as jnp
@@ -135,6 +136,14 @@ def _make_elastic_loop():
                 if (ctx.get_rank() == 1 and ctx.step == cfg["kill_at"]
                         and not os.path.exists(cfg["marker"])):
                     open(cfg["marker"], "w").close()
+                    # die only once the step's checkpoint is COMMITTED:
+                    # report() returns when this rank's shard is down,
+                    # rank 0 writes the manifest after every rank's, and
+                    # a group torn down in between restores one step back
+                    deadline = time.monotonic() + 120
+                    while not os.path.exists(cfg["committed"]) \
+                            and time.monotonic() < deadline:
+                        time.sleep(0.05)
                     os._exit(1)
                 batch = shard_batch(jnp.asarray(fixed), mesh, spec=P("dp"))
                 params, opt_state, metrics = step_fn(
@@ -155,17 +164,19 @@ def test_elastic_shrink_on_worker_loss(cluster_rt, tmp_path):
     done-criterion; reference: train/v2 scaling_policy.py:29)."""
     marker = str(tmp_path / "killed-once")
     kill_at = 3
+    committed = str(tmp_path / "elastic1" / f"checkpoint_{kill_at:08d}"
+                    / "MANIFEST.json")
     # capacity-driven initial sizing is part of the policy under test:
     # wait until the previous tests' actors have released their CPUs so
     # the run deterministically starts at the full 4 workers
-    deadline = time.monotonic() + 30
+    deadline = time.monotonic() + 120
     while rt.available_resources().get("CPU", 0) < 4 and \
             time.monotonic() < deadline:
         time.sleep(0.2)
     trainer = train.JaxTrainer(
         _make_elastic_loop(),
         train_loop_config={"total_steps": 6, "kill_at": kill_at,
-                           "marker": marker},
+                           "marker": marker, "committed": committed},
         scaling_config=train.ScalingConfig(
             num_workers=4,
             min_workers=2,

@@ -1,5 +1,5 @@
 """Pallas kernel tests — run in interpret mode on the CPU mesh
-(the kernels themselves are exercised on real TPU by bench.py)."""
+(the kernels themselves run on a real TPU in benchmark/run.py)."""
 
 import functools
 

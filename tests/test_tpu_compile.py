@@ -109,16 +109,6 @@ def test_ragged_kernel_compiles_at_benchmark_shapes(chip, program, hq, hkv,
         == (2 if program == "mixed" else 1)
 
 
-def test_decode_paged_attention_compiles(chip):
-    B, ps = 8, 16
-    k, v, _, _ = _pool(chip, "bf16", 256, ps)
-    lowered = pa._paged_attention_pallas.lower(
-        _sds(chip, (B, HQ, D), jnp.bfloat16), k, v,
-        _sds(chip, (B, 1024 // ps), jnp.int32), _sds(chip, (B,), jnp.int32),
-        sm_scale=D ** -0.5)
-    assert _kernel_calls(lowered) == 1
-
-
 @pytest.mark.parametrize(
     "blk_q,blk_k", fa.block_candidates(FLASH_L, FLASH_L, D, jnp.bfloat16))
 def test_flash_fwd_bwd_compiles_at_every_candidate_block(chip, blk_q, blk_k):
