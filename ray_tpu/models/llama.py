@@ -325,8 +325,8 @@ def _make_attn_fn(cfg: LlamaConfig, mesh):
             return lambda q, k, v: blockwise_attention(q, k, v).astype(q.dtype)
         if mesh is not None:
             return functools.partial(flash_attention_sharded, mesh=mesh)
-        # blk=None: use the autotuned block for this shape when one is
-        # cached (bench warms the cache eagerly), else the classic 256
+        # blk=None: the kernels' blocks come from the static shape
+        # (ops.flash_attention.flash_tiling)
         return functools.partial(flash_attention, blk_q=None, blk_k=None)
     if mesh is None:
         raise ValueError(f"attention={cfg.attention!r} needs a mesh")

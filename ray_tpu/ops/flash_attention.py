@@ -7,10 +7,15 @@ stream KV blocks through VMEM with online-softmax accumulators in scratch,
 never materializing the [L, L] score matrix in HBM — in either pass.
 
   flash_attention(q, k, v)  [B, L, H, D] → [B, L, H, D]
-    fwd:  grid (B·H, Lq/blkq, Lk/blkk); saves per-row logsumexp.
-    bwd:  two kernels — dq over (B·H, nq, nk) and dk/dv over (B·H, nk, nq)
-          — recompute p = exp(s − lse) blockwise from the saved lse.
-    causal blocks above the diagonal are skipped in all three kernels.
+    fwd:  grid (B·H, query blocks, resident key blocks); a grid step
+          walks its resident keys in an inner loop; saves per-row
+          logsumexp.
+    bwd:  two kernels — dq (same walk) and dk/dv over (B·H, key blocks,
+          resident query blocks) — recompute p = exp(s − lse) blockwise
+          from the saved lse.
+    flash_tiling picks each kernel's blocks from the static shape; under
+    the causal mask no kernel visits or fetches a block above the
+    diagonal, and only the blocks the diagonal crosses are masked.
 
 `blockwise_attention` is the pure-JAX (lax.scan) equivalent: same online
 softmax, differentiable by autodiff, used as the numerics reference and as
@@ -20,7 +25,7 @@ a portable fallback.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -81,168 +86,394 @@ def blockwise_attention(q, k, v, *, causal: bool = True,
 
 
 # --------------------------------------------------------------------------
-# Pallas kernels ([BH, L, D] layout inside)
+# Tiling: a function of the static shape
 # --------------------------------------------------------------------------
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-                *, causal, sm_scale, blk_q, blk_k):
-    qi, ki = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
+class KernelTiling(NamedTuple):
+    """How one kernel tiles a head. ``block`` rows of the operand a grid
+    step owns (queries in the forward and dq, keys in dk/dv) meet the
+    other operand ``step`` rows an inner-loop turn, out of ``resident``
+    rows of it held in VMEM a grid step."""
+    block: int
+    step: int
+    resident: int
 
-    @pl.when(ki == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
 
-    run = True
+class FlashTiling(NamedTuple):
+    fwd: KernelTiling
+    dq: KernelTiling
+    dkv: KernelTiling
+
+
+#: rows wanted for a kernel's block and for its step, cut to the lengths
+#: by _fit. Timed on a v5e at [64, 4096, 128] bf16 causal, each kernel
+#: alone (PERF.md §5, PR 29, the table of candidates): all three are at
+#: their best or within 1 % of it at 512 x 512; 256 pays the fixed cost
+#: of a turn twice as often, 1024 wastes more above the diagonal of the
+#: blocks it crosses and takes 2-4 times as long to lower.
+_BLOCK = 512
+#: the resident operand pair (K and V, or Q and dO), double-buffered
+_RESIDENT_BYTES = 8 << 20
+
+
+def _fit(L: int, want: int) -> Optional[int]:
+    """A block of at most ``want`` rows for a length of L: the whole
+    length when that is short enough (a multiple of the 8-row sublane
+    tile), else the largest of want, want/2, ... 128 that divides it.
+    None: no block the TPU lowering takes tiles this length."""
+    if L <= want:
+        return L if L % 8 == 0 else None
+    b = want
+    while b >= 128:
+        if L % b == 0:
+            return b
+        b //= 2
+    return None
+
+
+def _resident(L: int, step: int, head_dim: int, itemsize: int) -> int:
+    """Rows of the streamed operand pair to keep in VMEM a grid step:
+    the whole length where two double-buffered [L, D] operands fit
+    _RESIDENT_BYTES, else the largest multiple of ``step`` that divides
+    L and fits. A step that is not a multiple of 128 rows is its own
+    resident block (an inner loop would slice inside a packed tile)."""
+    if step % 128 and step != L:
+        return step
+    cap = max(step, _RESIDENT_BYTES // (4 * head_dim * itemsize))
+    n = L // step
+    return step * max(d for d in range(1, n + 1)
+                      if n % d == 0 and d * step <= cap)
+
+
+def _kernel_tiling(L_own: int, L_other: int, head_dim: int, itemsize: int,
+                   block: Optional[int] = None, step: Optional[int] = None
+                   ) -> Optional[KernelTiling]:
+    """One kernel's tiling: blocks of ``L_own`` rows against steps of
+    ``L_other`` rows (given, or _BLOCK cut to the length)."""
+    block = _fit(L_own, _BLOCK) if block is None else block
+    step = _fit(L_other, _BLOCK) if step is None else step
+    if block is None or step is None:
+        return None
+    return KernelTiling(block, step,
+                        _resident(L_other, step, head_dim, itemsize))
+
+
+def flash_tiling(Lq: int, Lk: int, head_dim: int,
+                 dtype) -> Optional[FlashTiling]:
+    """The three kernels' tilings for this static shape, or None when a
+    length cannot be tiled for the TPU lowering (callers fall back to the
+    einsum/blockwise path). A length under one large block is its own
+    block. The causal flag does not enter: a causal call walks only the
+    blocks under its diagonal, a full one has none to skip or mask."""
+    itemsize = jnp.dtype(dtype).itemsize
+    qk = _kernel_tiling(Lq, Lk, head_dim, itemsize)
+    kq = _kernel_tiling(Lk, Lq, head_dim, itemsize)
+    return None if qk is None or kq is None else FlashTiling(qk, qk, kq)
+
+
+def _resolve_tiling(Lq, Lk, head_dim, dtype, blk_q, blk_k,
+                    interpret) -> FlashTiling:
+    """flash_tiling's choice, or an explicit blk_q / blk_k (tests, the
+    autotuner) as the query / key rows of all three kernels. Interpret
+    mode has no Mosaic tiling: there a length no block divides is one
+    block."""
+    t = flash_tiling(Lq, Lk, head_dim, dtype)
+    if blk_q is None and blk_k is None and t is not None:
+        return t
+    if t is None and not interpret and (blk_q is None or blk_k is None):
+        raise ValueError(
+            f"flash attention: lengths ({Lq}, {Lk}) divide into no block "
+            f"the TPU lowering takes (multiples of 128, or one whole "
+            f"length that is a multiple of 8)")
+    bq = min(blk_q, Lq) if blk_q else t.fwd.block if t else Lq
+    bk = min(blk_k, Lk) if blk_k else t.dkv.block if t else Lk
+    if Lq % bq or Lk % bk:
+        raise ValueError(f"L ({Lq},{Lk}) must divide blocks ({bq},{bk})")
+    itemsize = jnp.dtype(dtype).itemsize
+    qk = _kernel_tiling(Lq, Lk, head_dim, itemsize, bq, bk)
+    return FlashTiling(qk, qk, _kernel_tiling(Lk, Lq, head_dim, itemsize,
+                                              bk, bq))
+
+
+# --------------------------------------------------------------------------
+# Pallas kernels ([BH, L, D] layout inside)
+#
+# A grid step owns one block of rows (queries; keys in dk/dv) and walks
+# the other operand, resident in VMEM, in an inner lax.fori_loop. Under
+# the causal mask the loop runs only over the steps its block can see:
+# first the steps wholly under the diagonal, with no mask at all, then
+# the few the diagonal crosses, masked. Steps above the diagonal are
+# never visited, and where a head's keys are split over several resident
+# blocks the index maps name the last live block again, so a dead grid
+# step fetches nothing.
+#
+# No -inf guard: every row sees key 0 (the mask is aligned top-left, and
+# there is no other mask), key 0 is in the first step the loop takes, so
+# from there on a row's running max is finite and exp(-inf - m) is 0.
+# --------------------------------------------------------------------------
+
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T
+_NN = (((1,), (0,)), ((), ()))      # a @ b
+
+
+def _dot(a, b, dims):
+    # operands in their own dtype (bf16 on the MXU), f32 accumulation
+    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _lanes(x, n: int):
+    """x [rows, 128] with every lane of a row the same -> [rows, n]. Row
+    statistics are held lane-replicated: a [rows, 1] column costs as many
+    vector registers and a lane broadcast at every use (forward at steps
+    of 512 keys: 4.35 ms a call with columns, 2.83 so; PERF.md, PR 29)."""
+    rows, w = x.shape
+    if n % w:
+        return jnp.broadcast_to(x[:, :1], (rows, n))
+    return x if n == w else jnp.concatenate([x] * (n // w), axis=1)
+
+
+def _turn_rows(t, step, nsteps):
+    """Rows of the resident operand that turn ``t`` takes. A resident
+    block of one step is taken whole: its length need not be a multiple
+    of the 128 lanes a dynamic slice of the row vectors must start on."""
+    if nsteps == 1:
+        return slice(None)
+    return pl.ds(pl.multiple_of(t * step, step), step)
+
+
+def _walk_to_diagonal(turn, carry, causal, first_row, rows, col0, step,
+                      nsteps):
+    """Run ``turn(t, carry, masked)`` over those of the ``nsteps`` steps
+    of ``step`` columns from column ``col0`` that the ``rows`` rows from
+    ``first_row`` can see: unmasked over the steps every row sees whole,
+    masked over the rest that any row sees; all of them, unmasked, when
+    not causal."""
+    full = live = nsteps
     if causal:
-        run = ki * blk_k <= qi * blk_q + blk_q - 1
+        full = jnp.clip((first_row + 1 - col0) // step, 0, nsteps)
+        live = jnp.clip((first_row + rows - col0 + step - 1) // step, 0,
+                        nsteps)
+    carry = lax.fori_loop(0, full, functools.partial(turn, masked=False),
+                          carry)
+    if causal:
+        carry = lax.fori_loop(full, live,
+                              functools.partial(turn, masked=True), carry)
+    return carry
 
-    @pl.when(run)
-    def _block():
-        q = q_ref[0]
-        s = lax.dot_general(  # bf16×bf16 → f32 accumulate on the MXU
-            q, k_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            qpos = qi * blk_q + lax.broadcasted_iota(
-                jnp.int32, (blk_q, blk_k), 0)
-            kpos = ki * blk_k + lax.broadcasted_iota(
-                jnp.int32, (blk_q, blk_k), 1)
-            s = jnp.where(qpos >= kpos, s, _NEG_INF)
-        m_prev, l_prev = m_ref[:, :1], l_ref[:, :1]
+
+def _rows_ahead(causal, shape):
+    """[row, column] -> row - column of a score tile: the part of the
+    causal test that no loop turn changes, built once a grid step. A
+    turn compares it with one scalar, how far its columns start past
+    the tile's rows."""
+    if not causal:
+        return None
+    return (lax.broadcasted_iota(jnp.int32, shape, 0)
+            - lax.broadcasted_iota(jnp.int32, shape, 1))
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
+                *, causal, sm_scale, step):
+    blk_q, res_k = q_ref.shape[1], k_ref.shape[1]
+    qi, ri = pl.program_id(1), pl.program_id(2)
+    nsteps = res_k // step
+
+    @pl.when(ri == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    q = q_ref[0]
+    ahead = _rows_ahead(causal, (blk_q, step))
+
+    def turn(t, ml, masked):
+        m_prev, l_prev = ml                         # [blk_q, 128] each
+        at = _turn_rows(t, step, nsteps)
+        s = _dot(q, k_ref[0, at, :], _NT) * sm_scale
+        if masked:      # query position >= key position
+            s = jnp.where(ahead >= ri * res_k + t * step - qi * blk_q,
+                          s, _NEG_INF)
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        p = jnp.where(jnp.isneginf(s), 0.0, jnp.exp(s - m_new))
-        corr = jnp.where(jnp.isneginf(m_prev), 0.0, jnp.exp(m_prev - m_new))
-        l_new = l_prev * corr + p.sum(axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * corr + lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+        p = jnp.exp(s - _lanes(m_new, step))
+        corr = jnp.exp(m_prev - m_new)
+        acc_ref[...] = acc_ref[...] * _lanes(corr, acc_ref.shape[1]) + _dot(
+            p.astype(v_ref.dtype), v_ref[0, at, :], _NN)
+        return m_new, l_prev * corr + p.sum(axis=-1, keepdims=True)
 
-    @pl.when(ki == nk - 1)
+    m_ref[...], l_ref[...] = _walk_to_diagonal(
+        turn, (m_ref[...], l_ref[...]), causal, qi * blk_q, blk_q,
+        ri * res_k, step, nsteps)
+
+    @pl.when(ri == pl.num_programs(2) - 1)
     def _finish():
         l = jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
-        lse = m_ref[:, 0] + jnp.log(l[:, 0])
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        lse = m_ref[:, :1] + jnp.log(l)
         # lse is materialized [8, blk_q] (sublane-replicated) to satisfy
         # the TPU (8, 128) tiling floor for output blocks.
-        lse_ref[0] = jnp.broadcast_to(lse[None, :], lse_ref.shape[1:])
+        lse_ref[0] = jnp.broadcast_to(lse[:, 0][None, :], lse_ref.shape[1:])
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               dq_acc, *, causal, sm_scale, blk_q, blk_k):
-    qi, ki = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
+               dq_acc, *, causal, sm_scale, step):
+    blk_q, res_k = q_ref.shape[1], k_ref.shape[1]
+    qi, ri = pl.program_id(1), pl.program_id(2)
+    nsteps = res_k // step
 
-    @pl.when(ki == 0)
+    @pl.when(ri == 0)
     def _init():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
+        dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    run = True
-    if causal:
-        run = ki * blk_k <= qi * blk_q + blk_q - 1
+    q, do = q_ref[0], do_ref[0]
+    lse, delta = lse_ref[0, 0][:, None], delta_ref[0, 0][:, None]
+    ahead = _rows_ahead(causal, (blk_q, step))
 
-    @pl.when(run)
-    def _block():
-        s = lax.dot_general(q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            qpos = qi * blk_q + lax.broadcasted_iota(
-                jnp.int32, (blk_q, blk_k), 0)
-            kpos = ki * blk_k + lax.broadcasted_iota(
-                jnp.int32, (blk_q, blk_k), 1)
-            s = jnp.where(qpos >= kpos, s, _NEG_INF)
-        p = jnp.exp(s - lse_ref[0, 0][:, None])     # masked rows → exp(-inf)=0
-        dp = lax.dot_general(do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0, 0][:, None])
-        dq_acc[:] += lax.dot_general(
-            ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
+    def turn(t, carry, masked):
+        at = _turn_rows(t, step, nsteps)
+        k = k_ref[0, at, :]
+        s = _dot(q, k, _NT) * sm_scale
+        if masked:
+            s = jnp.where(ahead >= ri * res_k + t * step - qi * blk_q,
+                          s, _NEG_INF)
+        p = jnp.exp(s - lse)                        # masked → exp(-inf) = 0
+        ds = p * (_dot(do, v_ref[0, at, :], _NT) - delta)
+        dq_acc[...] += _dot(ds.astype(k.dtype), k, _NN)
+        return carry
 
-    @pl.when(ki == nk - 1)
+    _walk_to_diagonal(turn, 0, causal, qi * blk_q, blk_q, ri * res_k, step,
+                      nsteps)
+
+    @pl.when(ri == pl.num_programs(2) - 1)
     def _finish():
-        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+        dq_ref[0] = (dq_acc[...] * sm_scale).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_acc, dv_acc, *,
-                causal, sm_scale, blk_q, blk_k):
-    ki, qi = pl.program_id(1), pl.program_id(2)
-    nq = pl.num_programs(2)
+                dk_ref, dv_ref, dk_acc, dv_acc, *, causal, sm_scale, step):
+    """Scores are held transposed, [keys, queries]: lse and delta then
+    broadcast along sublanes as the rows they are stored as, and both
+    accumulating products are plain a @ b (p.T @ do would transpose a
+    whole score tile a turn)."""
+    blk_k, res_q = k_ref.shape[1], q_ref.shape[1]
+    ki, ri = pl.program_id(1), pl.program_id(2)
+    nsteps = res_q // step
 
-    @pl.when(qi == 0)
+    @pl.when(ri == 0)
     def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    run = True
+    k, v = k_ref[0], v_ref[0]
+    ahead = _rows_ahead(causal, (blk_k, step))
+
+    def turn(t, carry, masked):
+        at = _turn_rows(t, step, nsteps)
+        q, do = q_ref[0, at, :], do_ref[0, at, :]
+        s = _dot(k, q, _NT) * sm_scale              # [blk_k, step]
+        if masked:      # rows are keys here: key position <= query's
+            s = jnp.where(ahead <= ri * res_q + t * step - ki * blk_k,
+                          s, _NEG_INF)
+        p = jnp.exp(s - lse_ref[0, :1, at])
+        dv_acc[...] += _dot(p.astype(do.dtype), do, _NN)
+        ds = p * (_dot(v, do, _NT) - delta_ref[0, :1, at])
+        dk_acc[...] += _dot(ds.astype(q.dtype), q, _NN)
+        return carry
+
     if causal:
-        run = qi * blk_q + blk_q - 1 >= ki * blk_k
+        # query steps before the key block's first key see none of it;
+        # from its last key on they see all of it
+        q0, k0 = ri * res_q, ki * blk_k
+        lo = jnp.clip((k0 - q0) // step, 0, nsteps)
+        whole = jnp.clip((k0 + blk_k - 1 - q0 + step - 1) // step, lo,
+                         nsteps)
+        lax.fori_loop(lo, whole, functools.partial(turn, masked=True), 0)
+        lax.fori_loop(whole, nsteps, functools.partial(turn, masked=False),
+                      0)
+    else:
+        lax.fori_loop(0, nsteps, functools.partial(turn, masked=False), 0)
 
-    @pl.when(run)
-    def _block():
-        s = lax.dot_general(q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            qpos = qi * blk_q + lax.broadcasted_iota(
-                jnp.int32, (blk_q, blk_k), 0)
-            kpos = ki * blk_k + lax.broadcasted_iota(
-                jnp.int32, (blk_q, blk_k), 1)
-            s = jnp.where(qpos >= kpos, s, _NEG_INF)
-        p = jnp.exp(s - lse_ref[0, 0][:, None])
-        dv_acc[:] += lax.dot_general(
-            p.astype(do_ref.dtype), do_ref[0], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = lax.dot_general(do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0, 0][:, None])
-        dk_acc[:] += lax.dot_general(
-            ds.astype(q_ref.dtype), q_ref[0], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-
-    @pl.when(qi == nq - 1)
+    @pl.when(ri == pl.num_programs(2) - 1)
     def _finish():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+        dk_ref[0] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 # --------------------------------------------------------------------------
 # pallas_call wrappers
 # --------------------------------------------------------------------------
 
+def _params(vmem_bytes: int):
+    # the head and the block axis are independent; the resident axis
+    # accumulates into the scratch
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=min(max(2 * vmem_bytes, 32 << 20), 100 << 20))
+
+
+def _vmem_bytes(t: KernelTiling, D: int, itemsize: int, n_own: int,
+                n_acc: int) -> int:
+    """Rough VMEM a grid step holds: ``n_own`` double-buffered operand
+    blocks of the kernel's own rows, the double-buffered resident pair,
+    ``n_acc`` f32 accumulators, and the f32 / operand-dtype score tiles
+    of one turn (scores, probabilities, mask, their casts)."""
+    return (2 * n_own * t.block * D * itemsize
+            + 4 * t.resident * D * itemsize
+            + n_acc * t.block * max(D, 128) * 4
+            + 5 * t.block * t.step * 4)
+
+
+def _live_map(causal, own, resident, up):
+    """Index map of the resident operand: (head, resident block, 0).
+    Causal grid steps whose resident block lies wholly beyond the
+    diagonal name the nearest live block instead, which is the one
+    already in VMEM: nothing is fetched for them. ``up``: the live
+    blocks are those up to the diagonal (keys for a block of queries);
+    else those from it on (queries for a block of keys)."""
+    if not causal:
+        return lambda b, i, j: (b, j, 0)
+    if up:
+        return lambda b, i, j: (
+            b, jnp.minimum(j, (i * own + own - 1) // resident), 0)
+    return lambda b, i, j: (b, jnp.maximum(j, (i * own) // resident), 0)
+
+
+def _pairs(BH, Lq, Lk, causal):
+    """(query, key) pairs the kernels score: a product costs 2 * D flops
+    a pair, the softmax one exp."""
+    return BH * Lq * Lk // (2 if causal else 1)
+
+
 def _fwd_call(q, k, v, causal, sm_scale, blk_q, blk_k, interpret):
     BH, Lq, D = q.shape
     Lk = k.shape[1]
-    blk_q, blk_k = min(blk_q, Lq), min(blk_k, Lk)
-    if Lq % blk_q or Lk % blk_k:
-        raise ValueError(f"L ({Lq},{Lk}) must divide blocks ({blk_q},{blk_k})")
-    kernel = functools.partial(_fwd_kernel, causal=causal, sm_scale=sm_scale,
-                               blk_q=blk_q, blk_k=blk_k)
+    t = _resolve_tiling(Lq, Lk, D, q.dtype, blk_q, blk_k, interpret).fwd
+    isz = q.dtype.itemsize
+    own = pl.BlockSpec((1, t.block, D), lambda b, i, j: (b, i, 0))
+    res = pl.BlockSpec((1, t.resident, D),
+                       _live_map(causal, t.block, t.resident, up=True))
     return pl.pallas_call(
-        kernel,
-        grid=(BH, Lq // blk_q, Lk // blk_k),
-        in_specs=[
-            pl.BlockSpec((1, blk_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, blk_k, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, blk_k, D), lambda b, i, j: (b, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, blk_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, 8, blk_q), lambda b, i, j: (b, 0, i)),
-        ],
+        functools.partial(_fwd_kernel, causal=causal, sm_scale=sm_scale,
+                          step=t.step),
+        grid=(BH, Lq // t.block, Lk // t.resident),
+        in_specs=[own, res, res],
+        out_specs=[own,
+                   pl.BlockSpec((1, 8, t.block), lambda b, i, j: (b, 0, i))],
         out_shape=[
             jax.ShapeDtypeStruct((BH, Lq, D), q.dtype),
             jax.ShapeDtypeStruct((BH, 8, Lq), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((blk_q, D), jnp.float32),
-            pltpu.VMEM((blk_q, 128), jnp.float32),
-            pltpu.VMEM((blk_q, 128), jnp.float32),
+            pltpu.VMEM((t.block, D), jnp.float32),
+            pltpu.VMEM((t.block, 128), jnp.float32),
+            pltpu.VMEM((t.block, 128), jnp.float32),
         ],
+        cost_estimate=pl.CostEstimate(
+            flops=2 * 2 * D * _pairs(BH, Lq, Lk, causal),
+            transcendentals=_pairs(BH, Lq, Lk, causal),
+            bytes_accessed=(2 * q.size + k.size + v.size) * isz
+            + BH * 8 * Lq * 4),
+        compiler_params=_params(_vmem_bytes(t, D, isz, n_own=2, n_acc=3)),
         interpret=interpret,
     )(q, k, v)
 
@@ -251,7 +482,8 @@ def _bwd_call(q, k, v, o, lse, do, causal, sm_scale, blk_q, blk_k,
               interpret, dlse=None):
     BH, Lq, D = q.shape
     Lk = k.shape[1]
-    blk_q, blk_k = min(blk_q, Lq), min(blk_k, Lk)
+    tiling = _resolve_tiling(Lq, Lk, D, q.dtype, blk_q, blk_k, interpret)
+    isz = q.dtype.itemsize
     delta = jnp.einsum("bld,bld->bl", do.astype(jnp.float32),
                        o.astype(jnp.float32))
     if dlse is not None:
@@ -260,47 +492,54 @@ def _bwd_call(q, k, v, o, lse, do, causal, sm_scale, blk_q, blk_k,
         # delta' = delta − dlse (the flash_attention_block merge path)
         delta = delta - dlse.astype(jnp.float32)
     delta = jnp.broadcast_to(delta[:, None, :], (BH, 8, Lq))
+    pairs = _pairs(BH, Lq, Lk, causal)
+    rows = 2 * BH * 8 * Lq * 4                      # lse and delta
+
+    t = tiling.dq
+    own = pl.BlockSpec((1, t.block, D), lambda b, i, j: (b, i, 0))
+    own_row = pl.BlockSpec((1, 8, t.block), lambda b, i, j: (b, 0, i))
+    res = pl.BlockSpec((1, t.resident, D),
+                       _live_map(causal, t.block, t.resident, up=True))
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, causal=causal, sm_scale=sm_scale,
-                          blk_q=blk_q, blk_k=blk_k),
-        grid=(BH, Lq // blk_q, Lk // blk_k),
-        in_specs=[
-            pl.BlockSpec((1, blk_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, blk_k, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, blk_k, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, blk_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, 8, blk_q), lambda b, i, j: (b, 0, i)),
-            pl.BlockSpec((1, 8, blk_q), lambda b, i, j: (b, 0, i)),
-        ],
-        out_specs=pl.BlockSpec((1, blk_q, D), lambda b, i, j: (b, i, 0)),
+                          step=t.step),
+        grid=(BH, Lq // t.block, Lk // t.resident),
+        in_specs=[own, res, res, own, own_row, own_row],
+        out_specs=own,
         out_shape=jax.ShapeDtypeStruct((BH, Lq, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((blk_q, D), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((t.block, D), jnp.float32)],
+        cost_estimate=pl.CostEstimate(
+            flops=3 * 2 * D * pairs, transcendentals=pairs,
+            bytes_accessed=(3 * q.size + k.size + v.size) * isz + rows),
+        compiler_params=_params(_vmem_bytes(t, D, isz, n_own=3, n_acc=1)),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
+
+    t = tiling.dkv
+    own = pl.BlockSpec((1, t.block, D), lambda b, i, j: (b, i, 0))
+    live = _live_map(causal, t.block, t.resident, up=False)
+    res = pl.BlockSpec((1, t.resident, D), live)
+    res_row = pl.BlockSpec((1, 8, t.resident),
+                           lambda b, i, j: (b, 0, live(b, i, j)[1]))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, causal=causal, sm_scale=sm_scale,
-                          blk_q=blk_q, blk_k=blk_k),
-        grid=(BH, Lk // blk_k, Lq // blk_q),
-        in_specs=[
-            pl.BlockSpec((1, blk_k, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, blk_k, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, blk_q, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, blk_q, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, 8, blk_q), lambda b, i, j: (b, 0, j)),
-            pl.BlockSpec((1, 8, blk_q), lambda b, i, j: (b, 0, j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, blk_k, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, blk_k, D), lambda b, i, j: (b, i, 0)),
-        ],
+                          step=t.step),
+        grid=(BH, Lk // t.block, Lq // t.resident),
+        in_specs=[own, own, res, res, res_row, res_row],
+        out_specs=[own, own],
         out_shape=[
             jax.ShapeDtypeStruct((BH, Lk, D), k.dtype),
             jax.ShapeDtypeStruct((BH, Lk, D), v.dtype),
         ],
         scratch_shapes=[
-            pltpu.VMEM((blk_k, D), jnp.float32),
-            pltpu.VMEM((blk_k, D), jnp.float32),
+            pltpu.VMEM((t.block, D), jnp.float32),
+            pltpu.VMEM((t.block, D), jnp.float32),
         ],
+        cost_estimate=pl.CostEstimate(
+            flops=4 * 2 * D * pairs, transcendentals=pairs,
+            bytes_accessed=(2 * q.size + 2 * k.size + 2 * v.size) * isz
+            + rows),
+        compiler_params=_params(_vmem_bytes(t, D, isz, n_own=4, n_acc=2)),
         interpret=interpret,
     )(k, v, q, do, lse, delta)
     return dq, dk, dv
@@ -326,45 +565,28 @@ def flash_attention(q, k, v, causal: bool = True,
                     interpret: bool = False) -> jax.Array:
     """[B, L, H, D] flash attention; Pallas fwd+bwd, O(L·blk) memory.
 
-    blk_q/blk_k None → use the autotuned block for this (L, head_dim,
-    dtype, platform) when one is cached (see autotune_blocks), else the
-    classic 256. Thin facade over flash_attention_block (which also
-    exposes lse for the ring-attention merge); the discarded lse output
-    contributes a zero cotangent that the shared backward folds away."""
+    blk_q/blk_k None → the autotuned pair for this (L, head_dim, dtype,
+    platform) when one is cached (see autotune_blocks), else what
+    flash_tiling picks for the shape, per kernel. Thin facade over
+    flash_attention_block (which also exposes lse for the ring-attention
+    merge); the discarded lse output contributes a zero cotangent that
+    the shared backward folds away."""
     if blk_q is None or blk_k is None:
         tuned = get_tuned_blocks(q.shape[1], k.shape[1], q.shape[-1],
-                                 q.dtype) or (256, 256)
-        blk_q = tuned[0] if blk_q is None else blk_q
-        blk_k = tuned[1] if blk_k is None else blk_k
+                                 q.dtype)
+        if tuned is not None:
+            blk_q = tuned[0] if blk_q is None else blk_q
+            blk_k = tuned[1] if blk_k is None else blk_k
     return flash_attention_block(q, k, v, causal, sm_scale, blk_q, blk_k,
                                  interpret)[0]
 
 
 # --------------------------------------------------------------------------
-# Block API: (o, lse) with differentiable lse — the ring-attention inner
-# kernel (per-rotation fused block whose results merge by log-sum-exp)
-# --------------------------------------------------------------------------
-
-def pick_block(L: int, preferred: int = 256, min_block: int = 8
-               ) -> Optional[int]:
-    """Largest kernel block size <= preferred that divides L (Pallas grid
-    constraint); None when no divisor >= min_block exists. The default
-    floor of 8 matches the Mosaic sublane tiling — COMPILED kernels must
-    never run below it (callers fall back to the einsum/blockwise path
-    instead); only interpret-mode callers, where no Mosaic tiling exists,
-    may pass min_block=1 for tiny shards."""
-    for b in (preferred, 128, 64, 32, 16, 8, 4, 2, 1):
-        if min_block <= b <= preferred and L % b == 0:
-            return min(b, L)
-    return None
-
-
-# --------------------------------------------------------------------------
 # Block-size autotuning: sweep + cache per (Lq, Lk, head_dim, dtype,
-# platform). The fixed 256 default is tuned for long sequences; at bench
-# shapes (L=2048, head_dim 128) the best (blk_q, blk_k) depends on VMEM
-# pressure and MXU occupancy, so measure instead of guessing. CPU hosts
-# (tests) never measure — the heuristic ranking alone picks the block.
+# platform) of ONE (blk_q, blk_k) pair for all three kernels, which then
+# stands in for flash_tiling's per-kernel choice at that shape. Nothing
+# in the tree calls it (ROADMAP D14); CPU hosts (tests) never measure —
+# the heuristic ranking alone picks the block.
 # --------------------------------------------------------------------------
 
 _BLOCK_CACHE: dict = {}
@@ -491,13 +713,20 @@ def autotune_blocks(Lq: int, Lk: Optional[int] = None, head_dim: int = 64,
     return best
 
 
+# --------------------------------------------------------------------------
+# Block API: (o, lse) with differentiable lse — the ring-attention inner
+# kernel (per-rotation fused block whose results merge by log-sum-exp)
+# --------------------------------------------------------------------------
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention_block(q, k, v, causal: bool = True,
                           sm_scale: Optional[float] = None,
-                          blk_q: int = 256, blk_k: int = 256,
+                          blk_q: Optional[int] = 256,
+                          blk_k: Optional[int] = 256,
                           interpret: bool = False):
     """Fused attention of q against ONE KV block: returns (o [B,L,H,D],
-    lse [B,H,Lq]). lse is differentiable — its cotangent (nonzero when
+    lse [B,H,Lq]). blk_q / blk_k None: flash_tiling's choice for the
+    shape. lse is differentiable — its cotangent (nonzero when
     block results are merged across ring rotations) folds into the
     backward kernels' delta term, so the same Pallas kernels serve both
     the standalone and the ring-merged case."""

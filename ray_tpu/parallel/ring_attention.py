@@ -77,22 +77,21 @@ def _merge_blocks(o1, lse1, o2, lse2):
 
 def _resolve_fused_blocks(Lq: int, Lk: int, head_dim: int, dtype,
                           interpret: bool):
-    """(blk_q, blk_k) for the fused ring path, or None when the shard
-    lengths cannot meet the Mosaic >= 8 sublane floor. Tuned entries
-    (ops.flash_attention.autotune_blocks, shared cache) win; otherwise
-    the divisor heuristic. Only interpret mode — where no Mosaic tiling
-    exists — may go below the floor (tiny CPU test shards)."""
-    from ray_tpu.ops.flash_attention import get_tuned_blocks, pick_block
+    """(blk_q, blk_k) for the fused ring path: a tuned pair
+    (ops.flash_attention.autotune_blocks, shared cache), else (None,
+    None), which leaves each kernel the blocks flash_tiling picks for
+    the shard's shape. None when flash_tiling finds no tiling the TPU
+    lowering takes for these lengths; only interpret mode, where no
+    Mosaic tiling exists, runs those (tiny CPU test shards) as one
+    block."""
+    from ray_tpu.ops.flash_attention import flash_tiling, get_tuned_blocks
 
     tuned = get_tuned_blocks(Lq, Lk, head_dim, dtype)
     if tuned is not None:
         return tuned
-    floor = 1 if interpret else 8
-    blk_q = pick_block(Lq, min_block=floor)
-    blk_k = pick_block(Lk, min_block=floor)
-    if blk_q is None or blk_k is None:
-        return None
-    return blk_q, blk_k
+    if interpret or flash_tiling(Lq, Lk, head_dim, dtype) is not None:
+        return None, None
+    return None
 
 
 def _ring_fused(q, k, v, axis_name, causal, sm_scale, interpret,

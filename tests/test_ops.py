@@ -103,6 +103,79 @@ class TestFlashAttention:
                                    rtol=2e-3, atol=2e-3)
 
 
+def _naive_block(q, k, v, causal):
+    """(o, lse) of plain softmax attention in float32; the causal mask is
+    aligned top-left (query i sees keys <= i), as the kernels' is."""
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
+    if causal:
+        vis = jnp.arange(q.shape[1])[:, None] >= jnp.arange(k.shape[1])
+        s = jnp.where(vis, s, -jnp.inf)
+    lse = jax.nn.logsumexp(s, axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", jnp.exp(s - lse[..., None]), v), lse
+
+
+#: (Lq, Lk, blk_q, blk_k, resident bytes or None): what tiles occur
+_FLASH_CASES = {
+    # one block: the whole length
+    "one-block": (128, 128, None, None, None),
+    # flash_tiling's own blocks: under the diagonal, crossed, and steps
+    # the inner loops never reach
+    "picked": (2048, 2048, None, None, None),
+    # the ring's rotations: Lq != Lk
+    "long-keys": (256, 512, None, None, None),
+    "long-queries": (512, 256, 128, 128, None),
+    # a step that is its own resident block: dead GRID steps, which the
+    # index maps point back at the last live block
+    "small-pair": (256, 256, 64, 32, None),
+    # resident blocks of two steps each: inner loops AND dead grid steps
+    "split-resident": (1024, 1024, 128, 128, 2 * 4 * 32 * 4 * 128),
+}
+
+
+class TestFlashTiles:
+    """Forward, dq, dk, dv and the dlse path of flash_attention_block
+    against the naive product, at every kind of tile the kernels walk."""
+
+    @pytest.mark.parametrize("causal", [True, False],
+                             ids=["causal", "full"])
+    @pytest.mark.parametrize("case", list(_FLASH_CASES))
+    def test_block_matches_naive(self, monkeypatch, case, causal):
+        import importlib
+        fa = importlib.import_module("ray_tpu.ops.flash_attention")
+        Lq, Lk, blk_q, blk_k, resident = _FLASH_CASES[case]
+        if resident is not None:
+            monkeypatch.setattr(fa, "_RESIDENT_BYTES", resident)
+        B, H, D = 1, 2, 32
+        ks = jax.random.split(jax.random.PRNGKey(7), 5)
+        q = jax.random.normal(ks[0], (B, Lq, H, D))
+        k = jax.random.normal(ks[1], (B, Lk, H, D))
+        v = jax.random.normal(ks[2], (B, Lk, H, D))
+        w = jax.random.normal(ks[3], (B, Lq, H, D))
+        u = jax.random.normal(ks[4], (B, H, Lq))   # a nonzero dlse
+
+        def loss(fn, q, k, v):
+            o, lse = fn(q, k, v)
+            return (o * w).sum() + (lse * u).sum(), (o, lse)
+
+        def flash(q, k, v):
+            return fa.flash_attention_block(q, k, v, causal, None, blk_q,
+                                            blk_k, True)
+
+        grad = functools.partial(jax.value_and_grad, argnums=(1, 2, 3),
+                                 has_aux=True)
+        (_, (o, lse)), g = grad(loss)(flash, q, k, v)
+        (_, (o_ref, lse_ref)), g_ref = grad(loss)(
+            functools.partial(_naive_block, causal=causal), q, k, v)
+        np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
+                                   rtol=2e-4, atol=2e-4)
+        np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_ref),
+                                   rtol=2e-4, atol=2e-4)
+        for name, a, b in zip("qkv", g_ref, g):
+            np.testing.assert_allclose(
+                np.asarray(b), np.asarray(a), rtol=2e-3, atol=2e-3,
+                err_msg=f"d{name} mismatch")
+
+
 class TestBlockAutotune:
     @pytest.fixture(autouse=True)
     def _clean_cache(self):
@@ -111,15 +184,6 @@ class TestBlockAutotune:
         fa.clear_block_cache()
         yield
         fa.clear_block_cache()
-
-    def test_pick_block_floor(self):
-        import importlib
-        pick_block = importlib.import_module(
-            "ray_tpu.ops.flash_attention").pick_block
-        assert pick_block(256) == 256
-        assert pick_block(20) is None        # no divisor >= 8
-        assert pick_block(4) is None         # below the Mosaic floor
-        assert pick_block(4, min_block=1) == 4   # interpret-only escape
 
     def test_candidates_respect_floor_and_divisibility(self):
         import importlib

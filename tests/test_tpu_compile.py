@@ -125,6 +125,67 @@ def test_flash_fwd_bwd_compiles_at_every_candidate_block(chip, blk_q, blk_k):
     assert _kernel_calls(lowered) == 3          # fwd, dq, dk/dv
 
 
+def _flash_grad_kernel_calls(chip, Lq, Lk, causal, heads=4, blocks=None):
+    """Pallas calls in jit(grad) of one flash_attention_block call, with
+    a cotangent on lse too (the ring's merge sends one)."""
+    ra = importlib.import_module("ray_tpu.parallel.ring_attention")
+    q = _sds(chip, (1, Lq, heads, D), jnp.bfloat16)
+    k = _sds(chip, (1, Lk, heads, D), jnp.bfloat16)
+    blk_q, blk_k = blocks or ra._resolve_fused_blocks(
+        Lq, Lk, D, jnp.bfloat16, interpret=False)
+
+    def loss(q, k, v):
+        o, lse = fa.flash_attention_block(q, k, v, causal, None, blk_q,
+                                          blk_k)
+        return o.astype(jnp.float32).sum() + lse.sum()
+
+    return _kernel_calls(
+        jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, k, k))
+
+
+def test_flash_compiles_at_the_train_cells_shape(chip):
+    """mistral7b-train-1chip: 2 rows x 32 heads of 4096 x 128 bf16, causal,
+    the tiling flash_tiling picks. Exactly one custom call of each kind
+    (forward, dq, dk/dv): benchmark/metrics/flash_attn_*.json count them."""
+    assert _flash_grad_kernel_calls(chip, 4096, 4096, True, heads=64,
+                                    blocks=(None, None)) == 3
+
+
+@pytest.mark.parametrize("Lq,Lk,causal", [
+    (192, 192, True),      # the old divisor pick gave blk_q = 64: refused
+    (1024, 2048, False),   # an off-diagonal rotation with longer keys
+    (2304, 2304, True),    # 128 x 18: blocks of 256
+    (128, 128, True)])
+def test_ring_shard_blocks_compile(chip, Lq, Lk, causal):
+    """What _resolve_fused_blocks hands the fused ring path compiles,
+    forward and backward: a query block is a multiple of 128 lanes or
+    the whole shard (ROADMAP S2, fourth bullet)."""
+    assert _flash_grad_kernel_calls(chip, Lq, Lk, causal) == 3
+
+
+@pytest.mark.parametrize("shape,expect", [
+    # (Lq, Lk, head_dim, dtype): fwd, dq, dkv as (block, step, resident)
+    ((4096, 4096, 128, jnp.bfloat16),       # the train cell
+     ((512, 512, 4096), (512, 512, 4096), (512, 512, 4096))),
+    ((2048, 2048, 128, jnp.bfloat16),
+     ((512, 512, 2048), (512, 512, 2048), (512, 512, 2048))),
+    ((1024, 16384, 128, jnp.bfloat16),      # a ring shard, keys past VMEM
+     ((512, 512, 8192), (512, 512, 8192), (512, 512, 1024))),
+    ((128, 128, 128, jnp.bfloat16),         # one block: the whole length
+     ((128, 128, 128), (128, 128, 128), (128, 128, 128))),
+    ((192, 192, 128, jnp.bfloat16),
+     ((192, 192, 192), (192, 192, 192), (192, 192, 192))),
+    ((2304, 2304, 64, jnp.float32),         # 128 x 18
+     ((256, 256, 2304), (256, 256, 2304), (256, 256, 2304))),
+    ((20, 20, 128, jnp.bfloat16), None),    # no block the lowering takes
+    ((1000, 1000, 128, jnp.bfloat16), None)])
+def test_flash_tiling_table(shape, expect):
+    """The tiling is a function of the static shape alone; a change to
+    the choice shows here (PERF.md §5 has the timings behind it)."""
+    got = fa.flash_tiling(*shape)
+    assert (got if got is None else tuple(map(tuple, got))) == expect
+
+
 def _abstract(chip, fn):
     return jax.tree.map(lambda a: _sds(chip, a.shape, a.dtype),
                         jax.eval_shape(fn))
