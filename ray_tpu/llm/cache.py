@@ -18,7 +18,17 @@ Prefix cache design:
     length except through copy-on-write (engine copies the page first);
   - pages whose ONLY reference is the cache's are evictable, LRU order;
     the engine evicts under allocator pressure, so the cache is free
-    HBM turned into hit-rate rather than reserved memory.
+    HBM turned into hit-rate rather than reserved memory;
+  - a page holds KV and nothing else. A configuration with conv layers
+    (recurrent state per batch slot, ``make_kv_cache``) gets NO prefix
+    cache: a hit would restore the KV of the matched pages and not the
+    conv state at that position (``prefix_cache_supported``; saving the
+    state at page boundaries is open, ROADMAP Queue 2).
+
+Two kinds of device state live in the one pool pytree: pages, for the
+attention layers only, allocated and shared by the page; and the conv
+layers' state, one fixed-size entry a batch slot, owned by whoever holds
+the slot and never allocated or freed.
 """
 
 from __future__ import annotations
@@ -30,11 +40,12 @@ from typing import Dict, List, Optional, Tuple
 
 import jax.numpy as jnp
 
-from ray_tpu.models.llama import LlamaConfig
+from ray_tpu.models.llama import ATTENTION, CONV, LlamaConfig
 
 logger = logging.getLogger(__name__)
 
 SCRATCH_PAGE = 0
+LANES = 128     # of a TPU vector register: the minor tile of HBM layouts
 
 
 class DoubleFreeError(RuntimeError):
@@ -233,11 +244,18 @@ class PrefixCache:
         return freed
 
 
+#: the pool's leaf that is not pages: a conv layer's recurrent state
+STATE_LEAF = "conv"
+
+
 def make_kv_cache(cfg: LlamaConfig, total_pages: int, page_size: int,
-                  dtype=None, kv_dtype: Optional[str] = None):
+                  dtype=None, kv_dtype: Optional[str] = None,
+                  max_batch: int = 0, lane_pad: bool = False):
     """Device-resident paged KV pool as a dict pytree.
 
-    {"k", "v"}: [n_layers, total_pages, Hkv, page_size, D]. With
+    {"k", "v"}: [n_attn, total_pages, Hkv, page_size, D], one entry of
+    the leading axis for each ATTENTION layer (every layer, unless the
+    configuration names conv layers: those have no pages). With
     ``kv_dtype="int8"`` the pools are int8 and {"k_scale", "v_scale"}
     [n_layers, total_pages, Hkv, page_size] bf16 per-(page, head, slot)
     dequant scales ride alongside — one pytree, so jit donation,
@@ -250,20 +268,51 @@ def make_kv_cache(cfg: LlamaConfig, total_pages: int, page_size: int,
     written (``_kv_write_pallas``, whole pages by DMA) and read (the
     attention kernel) by its index into the stack, never sliced out of
     it; a token is one row of each head's [page_size, D] tile.
+
+    ``lane_pad`` (the pool the KERNELS take, on a TPU): D is head_dim
+    rounded up to the 128 lanes of a vector register. A narrower row is
+    padded to that in HBM whatever its shape says, and the kernels' page
+    DMAs cannot slice inside it (Mosaic refuses head_dim 64); the step
+    zero-pads q, k and v to the pool's D. At head_dim 128 it changes
+    nothing.
+
+    A configuration with conv layers gets one more leaf, ``STATE_LEAF``:
+    [n_conv, max_batch + 1, conv_kernel - 1, dim] in cfg.dtype, a conv
+    layer's last inputs for each BATCH SLOT (axis 1 is slots, not pages:
+    the page copy leaves it alone) and, last, a scratch slot that padding
+    tokens write. It rides the same dict, so it is donated, carried and
+    updated in place with the pages. Nothing ever zeroes a slot: a row
+    whose first token has position 0 reads zeros instead of its slot.
     """
     if kv_dtype not in (None, "model", "int8"):
         raise ValueError(f"kv_dtype must be 'model' or 'int8', "
                          f"got {kv_dtype!r}")
-    shape = (cfg.n_layers, total_pages, cfg.n_kv_heads, page_size,
-             cfg.head_dim)
+    shape = (len(cfg.layers_of(ATTENTION)), total_pages, cfg.n_kv_heads,
+             page_size,
+             -(-cfg.head_dim // LANES) * LANES if lane_pad else cfg.head_dim)
     if kv_dtype == "int8":
         from ray_tpu.ops.int8 import KV_SCALE_DTYPE
-        return {"k": jnp.zeros(shape, jnp.int8),
-                "v": jnp.zeros(shape, jnp.int8),
-                "k_scale": jnp.zeros(shape[:-1], KV_SCALE_DTYPE),
-                "v_scale": jnp.zeros(shape[:-1], KV_SCALE_DTYPE)}
-    dtype = dtype or cfg.dtype
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+        kv = {"k": jnp.zeros(shape, jnp.int8),
+              "v": jnp.zeros(shape, jnp.int8),
+              "k_scale": jnp.zeros(shape[:-1], KV_SCALE_DTYPE),
+              "v_scale": jnp.zeros(shape[:-1], KV_SCALE_DTYPE)}
+    else:
+        dtype = dtype or cfg.dtype
+        kv = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    n_conv = len(cfg.layers_of(CONV))
+    if n_conv:
+        if max_batch < 1:
+            raise ValueError("conv layers keep state per batch slot: "
+                             "make_kv_cache needs max_batch")
+        kv[STATE_LEAF] = jnp.zeros(
+            (n_conv, max_batch + 1, cfg.conv_kernel - 1, cfg.dim), cfg.dtype)
+    return kv
+
+
+def prefix_cache_supported(cfg: LlamaConfig) -> bool:
+    """Whether a page-aligned prefix hit restores ALL of a sequence's
+    state at that position: true where pages are the only state."""
+    return not cfg.layers_of(CONV)
 
 
 def kv_cache_tag(cfg: LlamaConfig, kv_dtype: Optional[str]) -> str:

@@ -52,6 +52,7 @@ from __future__ import annotations
 
 import collections
 import itertools
+import logging
 import threading
 import time
 import uuid
@@ -61,13 +62,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.llm.cache import (SCRATCH_PAGE, PageAllocator, PrefixCache,
-                               SequenceState, kv_cache_tag)
+from ray_tpu.llm.cache import (SCRATCH_PAGE, STATE_LEAF, PageAllocator,
+                               PrefixCache, SequenceState, kv_cache_tag,
+                               prefix_cache_supported)
 from ray_tpu.llm import model as M
 from ray_tpu.llm.tp import build_tp_mesh
 from ray_tpu.models.llama import LlamaConfig
 
 TraceAnnotation = jax.profiler.TraceAnnotation
+logger = logging.getLogger(__name__)
 
 
 class InferenceEngine:
@@ -136,12 +139,18 @@ class InferenceEngine:
         # over the mesh under tp): no device ever stages the whole model
         self.params = self._fns.init_params(seed) if params is None \
             else self._fns.place_params(params)
-        self.kv = self._fns.init_kv(total_pages, page_size, self.kv_dtype)
+        self.kv = self._fns.init_kv(total_pages, page_size, self.kv_dtype,
+                                    max_batch)
         # device_report()'s sizes, taken HERE: every step donates the
         # pool, so its arrays die under a reader on another thread
         self._param_bytes = sum(x.nbytes
                                 for x in jax.tree.leaves(self.params))
         self._kv_bytes = sum(x.nbytes for x in self.kv.values())
+        # conv layers' state: one entry a batch slot (+ the scratch slot
+        # padding writes), part of the pool pytree and of _kv_bytes
+        self._has_state = STATE_LEAF in self.kv
+        self._state_bytes = self.kv[STATE_LEAF].nbytes \
+            if self._has_state else 0
         self._held_bytes: Dict[int, int] = {}
         for leaf in jax.tree.leaves((self.params, self.kv)):
             for shard in leaf.addressable_shards:
@@ -155,6 +164,13 @@ class InferenceEngine:
         self.allocator = PageAllocator(total_pages)
         use_prefix = GlobalConfig.llm_prefix_cache \
             if prefix_cache is None else prefix_cache
+        if use_prefix and not prefix_cache_supported(cfg):
+            # a hit would restore the matched pages' KV and run the conv
+            # layers on zero state: no match is taken at all
+            logger.warning(
+                "prefix cache off: this configuration has conv layers, "
+                "whose state a page-aligned prefix hit does not restore")
+            use_prefix = False
         self.prefix: Optional[PrefixCache] = \
             PrefixCache(self.allocator, page_size,
                         kv_tag=kv_cache_tag(cfg, self.kv_dtype)) \
@@ -187,6 +203,12 @@ class InferenceEngine:
         # each, and metadata of the dispatch's engine.readback span
         self._step_counters = M.step_counters(cfg)
         self.stats.update(dict.fromkeys(self._step_counters, 0))
+        if self._has_state:
+            # the state's size, and rows that started at position 0 (a
+            # new or re-prefilled sequence: the step read zeros for its
+            # slot's state instead of what the last owner left)
+            self.stats.update(state_bytes=self._state_bytes,
+                              state_resets=0)
         # per-request flight recorder (llm/request_log.py): lifecycle
         # event stream per request + TTFT/TPOT/e2e/queue-wait histograms
         # + SLO attainment; None disables every hook (seq.record stays
@@ -276,7 +298,8 @@ class InferenceEngine:
         programs took ("kernel" | "reference" — chosen from the platform,
         so a deployment can assert it never fell back), the resident
         step-program count, and per device the bytes of weights + KV
-        pages it holds next to the allocator's own ``memory_stats()``
+        pages (+ conv state: ``state_bytes`` of ``kv_bytes``) it holds
+        next to the allocator's own ``memory_stats()``
         (None on backends that keep none, i.e. the CPU). Safe from any
         thread while the engine steps: placement is read from the
         weights, which no step donates, and the sizes are the
@@ -301,6 +324,7 @@ class InferenceEngine:
                 "compiled_step_programs": self.compiled_step_programs(),
                 "param_bytes": self._param_bytes,
                 "kv_bytes": self._kv_bytes,
+                "state_bytes": self._state_bytes,
                 "devices": per_device}
 
     # ---------------------------------------------------------------- step
@@ -470,6 +494,9 @@ class InferenceEngine:
             kv_len = np.zeros(R, np.int32)
             ptab = np.full((R, self.max_pages_per_seq), SCRATCH_PAGE,
                            np.int32)
+            # each token's conv-state slot: its sequence's batch slot,
+            # the scratch slot (max_batch) for padding
+            token_state = np.full(Tcap, self.max_batch, np.int32)
             q_start[:self.max_batch] = np.arange(self.max_batch,
                                                  dtype=np.int32)
             ptab[:self.max_batch] = self._page_table
@@ -481,6 +508,7 @@ class InferenceEngine:
                 token_slot[i] = pos % ps
                 q_len[i] = 1
                 kv_len[i] = s.num_tokens
+                token_state[i] = i
             t0 = self.max_batch
             for j, (seq, C) in enumerate(rows):
                 r = self.max_batch + j
@@ -495,14 +523,17 @@ class InferenceEngine:
                 q_start[r] = t0
                 q_len[r] = C
                 kv_len[r] = start + C
+                token_state[t0:t0 + C] = seq.slot
                 t0 += C
-        with TraceAnnotation("engine.h2d", arrays=8):
+        with TraceAnnotation("engine.h2d", arrays=8 + self._has_state):
             args = [jnp.asarray(a) for a in (
                 tokens, token_pos, token_page, token_slot, ptab, q_start,
                 q_len, kv_len)]
+            state_arg = {"token_state": jnp.asarray(token_state)} \
+                if self._has_state else {}
         with TraceAnnotation("engine.dispatch"):
             nxt, self.kv = self._fns.ragged_step(self.params, *args,
-                                                 self.kv)
+                                                 self.kv, **state_arg)
         with TraceAnnotation("engine.readback") as span:
             nxt = np.asarray(nxt)                  # [R], ONE readback
             nxt = self._note_counters(nxt, R, span)
@@ -515,6 +546,9 @@ class InferenceEngine:
             self.stats["ragged_real_tokens"] += len(active) + chunk_tokens
             self.stats["ragged_slot_tokens"] += Tcap
             self.stats["prefill_tokens"] += chunk_tokens
+            if self._has_state:
+                self.stats["state_resets"] += sum(
+                    seq.num_computed == 0 for seq, _ in rows)
             if active:
                 self.stats["decode_steps"] += 1
                 self.stats["decode_tokens"] += len(active)

@@ -5,14 +5,26 @@ llm/_internal/serve/deployments/llm/vllm/ — the reference ships no model
 code in-tree), rebuilt on ray_tpu's functional decoder (models/llama.py —
 same params pytree, so training checkpoints serve directly).
 
-ONE layer body with three variation points, each read from the
-configuration (LlamaConfig; the defaults are the Llama/Mistral block):
-an RMSNorm on the projected q and k before the rotary embedding
-(``qk_norm``), the feed-forward as dense SwiGLU or as dropless routed
-experts (``n_experts``, ops/moe.py), and the logits from the embedding
-table or from an ``lm_head`` of their own (``tie_embeddings``). A dense
-configuration lowers to the program it lowered to before the points
-existed. OLMoE-1B-7B is the first block that sets all three.
+ONE layer body with its variation points read from the configuration
+(LlamaConfig; the defaults are the Llama/Mistral block): an RMSNorm on
+the projected q and k before the rotary embedding (``qk_norm`` over the
+whole vector, ``qk_norm_per_head`` over each head), the feed-forward as
+dense SwiGLU or as dropless routed experts (``n_experts``, ops/moe.py;
+the router's score, selection bias, epsilon and scale are fields too),
+and the logits from the embedding table or from an ``lm_head`` of their
+own (``tie_embeddings``). A dense configuration lowers to the program it
+lowered to before the points existed. OLMoE-1B-7B is the first block
+that sets the first three.
+
+Layers that DIFFER (``layer_types``, ``n_dense_layers``; LFM2 is the
+first such block): a layer's operator is attention or a gated short
+convolution (``_short_conv``), its feed-forward dense for the leading
+layers and experts after. Weights are stacked per KIND (models/llama.py)
+and a layer finds its own by its ordinal among the layers of its kind.
+Depth does not unroll: the leading dense layers run once, then one
+``lax.scan`` over the PERIODS of the pattern, its body one period
+(``_hybrid_layers``). A conv layer has no pages; its state is the last
+``conv_kernel - 1`` inputs of its depthwise conv, per batch slot.
 
 ONE step program for everything (`_ragged_step_body`): the engine packs
 decode tokens and prefill-chunk tokens into a single RAGGED batch
@@ -25,10 +37,15 @@ so a finishing prefill chunk's first token and every decode row's next
 token come back in ONE readback.
 
 The KV pool is a dict pytree {"k", "v"[, "k_scale", "v_scale"]},
-layers stacked on the leading axis: ONE buffer in ONE layout, donated
-to the step program and updated in place. It is a CARRY of the layer
+the ATTENTION layers stacked on the leading axis (every layer, unless
+the configuration names conv layers), and, with conv layers, one more
+leaf {"conv"}: their state [n_conv, slots + 1, taps - 1, dim], a slot a
+batch slot and a scratch slot last (llm/cache.py). ONE buffer each in
+ONE layout, donated to the step program and updated in place. The pool
+is a CARRY of the layer
 scan (and so of the decode loop's step scan), never a scanned input or
-a stacked output: a layer is written and read by its INDEX, the write
+a stacked output: a layer is written and read by its INDEX (its ordinal
+among the layers of its kind), the write
 (`_kv_write_pallas`, aliased in to out) and the attention kernel both
 taking the whole stacked pool, so no layer is sliced out, converted to
 another layout or stacked back, and no step copies the pool (threaded
@@ -46,8 +63,9 @@ layer, the textbook Megatron schedule, riding ICI.
 
 from __future__ import annotations
 
+import collections
 import functools
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -55,9 +73,9 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu.llm import tp as TP
-from ray_tpu.llm.cache import SCRATCH_PAGE, make_kv_cache
-from ray_tpu.models.llama import (LlamaConfig, Params, _rmsnorm, _rope,
-                                  init_params)
+from ray_tpu.llm.cache import SCRATCH_PAGE, STATE_LEAF, make_kv_cache
+from ray_tpu.models.llama import (ATTENTION, CONV, LlamaConfig, Params,
+                                  _rmsnorm, _rope, init_params)
 from ray_tpu.ops import moe
 from ray_tpu.ops.paged_attention import (kernels_supported,
                                          ragged_paged_attention,
@@ -65,7 +83,7 @@ from ray_tpu.ops.paged_attention import (kernels_supported,
 from ray_tpu.parallel.mesh import shard_map_compat
 from ray_tpu.util import compile_tracker
 
-KVCache = dict  # {"k", "v"[, "k_scale", "v_scale"]}, leading axis layers
+KVCache = dict  # {"k", "v"[, "k_scale", "v_scale"][, "conv"]}: llm/cache.py
 
 
 def _maybe_psum(x, tp_axis):
@@ -81,13 +99,17 @@ def _project_qkv(lp, h, cfg: LlamaConfig):
     q = h @ lp["wq"].astype(cd)
     k = h @ lp["wk"].astype(cd)
     v = h @ lp["wv"].astype(cd)
-    if cfg.qk_norm:
+    if cfg.qk_norm and not cfg.qk_norm_per_head:
         # over the whole projected vector, before the split into heads
         q = _rmsnorm(q, lp["q_norm"], cfg.norm_eps)
         k = _rmsnorm(k, lp["k_norm"], cfg.norm_eps)
     q = q.reshape(B, L, q.shape[-1] // hd, hd)
     k = k.reshape(B, L, k.shape[-1] // hd, hd)
     v = v.reshape(B, L, v.shape[-1] // hd, hd)
+    if cfg.qk_norm_per_head:
+        # over each head's own head_dim, one [head_dim] weight for all
+        q = _rmsnorm(q, lp["q_norm"], cfg.norm_eps)
+        k = _rmsnorm(k, lp["k_norm"], cfg.norm_eps)
     return q, k, v
 
 
@@ -110,7 +132,9 @@ def _moe_mlp(lp, experts, layer, x, valid, cfg: LlamaConfig, impl):
     y, counters = moe.moe_ffn(
         h[0], valid, lp["router"], experts["w_gate"], experts["w_up"],
         experts["w_down"], cfg.experts_per_token, cfg.norm_topk_prob,
-        layer=layer, impl=impl)
+        layer=layer, impl=impl, score=cfg.router_score,
+        bias=lp.get("router_bias"), eps=cfg.router_eps,
+        scale=cfg.router_scale)
     return x + y[None], counters
 
 
@@ -123,6 +147,177 @@ def step_counters(cfg: LlamaConfig) -> Tuple[str, ...]:
     return moe.COUNTERS if cfg.n_experts else ()
 
 
+#: jax.named_scope names inside the step programs: they reach the device
+#: trace in each op's name path, where the benchmark's readers match them
+#: (the router's, "moe_router", is ops/moe.py's). Renaming one changes a
+#: metric.
+SCOPE_ATTENTION, SCOPE_CONV = "attention", "short_conv"
+
+
+class _ConvRows(NamedTuple):
+    """What the conv operator needs of the ragged batch: each token's
+    position and state slot (None: token t is slot t's one token), and the
+    rows' spans."""
+    token_pos: jax.Array
+    token_state: Optional[jax.Array]
+    q_start: jax.Array
+    q_len: jax.Array
+
+
+def _shift(a, n: int, fill):
+    """a[t - n] at t, ``fill`` where t < n."""
+    if n == 0:
+        return a
+    pad = jnp.full((n,) + a.shape[1:], fill, a.dtype)
+    return jnp.concatenate([pad, a[:-n]])
+
+
+def _short_conv(lp, l, x, state, rows: _ConvRows, cfg: LlamaConfig):
+    """The gated short convolution of one layer, on entry ``l`` of the
+    state (the layer's ordinal among the conv layers):
+
+        (B, C, u) = split3(rms(x) W_in);  v = B * u
+        c[t] = sum_j w[j] * v[t - (K-1) + j]     depthwise, causal
+        x' = x + (C * c) W_out
+
+    over a RAGGED batch: a token's earlier inputs are the tokens before it
+    in its own row where the row reaches back far enough, and otherwise
+    come from the row's slot of ``state`` [n_conv, slots + 1, K-1, d],
+    which holds the sequence's last K-1 inputs v (oldest first). A row
+    whose first token has position 0 reads zeros, whatever its slot holds:
+    a new sequence, or one re-prefilled after a preemption, needs no
+    reset. Each row's last K-1 inputs go back to its slot; rows without
+    tokens, and padding, go to the scratch slot (the last). v is rounded
+    to the compute dtype before it is used or stored, so a sequence
+    computes the same values however its tokens fall into chunks; the taps
+    are summed in float32. Returns (x', state)."""
+    cd = cfg.dtype
+    K = cfg.conv_kernel
+    T = x.shape[1]
+    scratch = state.shape[1] - 1
+    pos, slot = rows.token_pos, rows.token_state
+    with jax.named_scope(SCOPE_CONV):
+        h = _rmsnorm(x, lp["conv_norm"], cfg.norm_eps)[0]
+        B, C, u = jnp.split(h @ lp["w_in"].astype(cd), 3, axis=-1)
+        v = B * u                                          # [T, d]
+        if slot is None:
+            # the decode loop: every token is a row of its own, in slot t
+            saved = lax.dynamic_slice_in_dim(state[l], 0, T, axis=0)
+            saved = jnp.where((pos > 0)[:, None, None], saved, 0)
+            prev = [v] + [saved[:, K - 1 - s] for s in range(1, K)]
+        else:
+            # reach[t]: how many tokens before t are t's own row's, up to
+            # K-1 (a slot is in one row a step, at consecutive positions)
+            same = (slot == _shift(slot, 1, -1)) \
+                & (pos == _shift(pos, 1, -1) + 1)
+            chain, chains = jnp.ones(T, bool), []
+            for s in range(1, K):
+                chain = chain & _shift(same, s - 1, False)
+                chains.append(chain)
+            reach = sum(c.astype(jnp.int32) for c in chains)
+            saved = state[l, slot]                         # [T, K-1, d]
+            saved = jnp.where((pos - reach > 0)[:, None, None], saved, 0)
+            prev = [v]
+            for s in range(1, K):
+                # v[t - s]: in the chunk, or entry K-1-(s-reach) of the slot
+                at = jnp.clip(K - 1 - s + reach, 0, K - 2)
+                old = jnp.take_along_axis(
+                    saved, at[:, None, None], axis=1)[:, 0]
+                prev.append(jnp.where(chains[s - 1][:, None],
+                                      _shift(v, s, 0), old))
+        w = lp["w_conv"].astype(jnp.float32)               # [K, d]
+        c = sum(w[K - 1 - s] * prev[s].astype(jnp.float32)
+                for s in range(K))
+        y = (C.astype(jnp.float32) * c).astype(cd) @ lp["w_out"].astype(cd)
+        # the K-1 inputs up to and including each token, oldest first
+        upto = jnp.stack(prev[K - 2:0:-1] + [v], axis=1)   # [T, K-1, d]
+        if slot is None:
+            state = lax.dynamic_update_slice(
+                state, upto[None].astype(state.dtype), (l, 0, 0, 0))
+        else:
+            last = jnp.clip(rows.q_start + rows.q_len - 1, 0, T - 1)
+            row_slot = jnp.where(rows.q_len > 0, slot[last], scratch)
+            state = state.at[l, row_slot].set(
+                upto[last].astype(state.dtype))
+    return x + y[None], state
+
+
+def _pattern(cfg: LlamaConfig):
+    """(leading layers, one period, how many periods): each layer is
+    (operator kind, feed-forward kind). The leading dense layers run
+    before the scan; the rest must be whole repeats of its shortest
+    period, which is the scanned unit."""
+    L = cfg.n_layers
+    ops = cfg.layer_types or (ATTENTION,) * L
+    lead = cfg.n_dense_layers if cfg.n_experts else 0
+    kinds = [(ops[i], "moe" if cfg.n_experts and i >= lead else "dense")
+             for i in range(L)]
+    rest = kinds[lead:]
+    period = next(p for p in range(1, len(rest) + 1)
+                  if len(rest) % p == 0
+                  and rest == rest[:p] * (len(rest) // p))
+    return kinds[:lead], rest[:period], len(rest) // period
+
+
+def _hybrid_layers(layers, x, kv, cfg: LlamaConfig, attention, valid,
+                   impl, rows: _ConvRows):
+    """The layers of a block whose layers differ (cfg.hybrid): weights
+    stacked per kind (models/llama.py), a layer finding its own by its
+    ordinal among the layers of its kind: the attention layers' entry
+    of the page pool, the conv layers' entry of the state, the expert
+    layers' [layer, expert] weights, which stay closed over and whole.
+    Depth does not unroll: the leading layers run once, then ONE scan over
+    the periods of the pattern, its body one period. Both kinds of state
+    are carries, updated in place at their ordinals. Returns (x, kv,
+    counters summed over the expert layers, or None)."""
+    lead, period, n_periods = _pattern(cfg)
+    experts = {k: layers["moe"][k] for k in _EXPERT_LEAVES} \
+        if cfg.n_experts else None
+
+    def at(kind, i):
+        """Layer i's leaves of one kind's stack (not the experts')."""
+        return {k: w[i] for k, w in layers[kind].items()
+                if not (kind == "moe" and k in _EXPERT_LEAVES)}
+
+    def one(x, kv, counters, kinds, ordinal):
+        op, ffn = kinds
+        if op == ATTENTION:
+            x, kv = attention(at("attn", ordinal[op]), ordinal[op], x, kv)
+        else:
+            x, state = _short_conv(at("conv", ordinal[op]), ordinal[op], x,
+                                   kv[STATE_LEAF], rows, cfg)
+            kv = {**kv, STATE_LEAF: state}
+        if ffn == "moe":
+            x, c = _moe_mlp(at("moe", ordinal[ffn]), experts, ordinal[ffn],
+                            x, valid, cfg, impl)
+            counters = counters + c
+        else:
+            x = _mlp(at("dense", ordinal[ffn]), x, cfg)
+        return x, kv, counters
+
+    def run(carry, some, first, j=0, per=None):
+        """``some`` layers in turn; a layer's ordinal among its kind is
+        ``first`` + those before it here (+ j whole periods of ``per``)."""
+        x, kv, counters = carry
+        first = dict(first)
+        for kinds in some:
+            ordinal = {k: first[k] + (j * per[k] if per else 0)
+                       for k in kinds}
+            x, kv, counters = one(x, kv, counters, kinds, ordinal)
+            for k in kinds:
+                first[k] += 1
+        return (x, kv, counters), first
+
+    carry = (x, kv, jnp.zeros(len(moe.COUNTERS), jnp.int32))
+    carry, seen = run(carry, lead,
+                      dict.fromkeys((ATTENTION, CONV, "dense", "moe"), 0))
+    per = collections.Counter(k for kinds in period for k in kinds)
+    (x, kv, counters), _ = lax.scan(
+        lambda carry, j: (run(carry, period, seen, j, per)[0], None),
+        carry, jnp.arange(n_periods, dtype=jnp.int32))
+    return x, kv, counters if cfg.n_experts else None
+
+
 def _ragged_forward(params: Params, tokens: jax.Array,
                     token_pos: jax.Array, token_page: jax.Array,
                     token_slot: jax.Array, page_table: jax.Array,
@@ -131,7 +326,8 @@ def _ragged_forward(params: Params, tokens: jax.Array,
                     tp_axis: Optional[str] = None,
                     paged_impl: Optional[str] = None,
                     max_q_len: Optional[int] = None,
-                    decode_rows: int = 0):
+                    decode_rows: int = 0,
+                    token_state: Optional[jax.Array] = None):
     """ONE forward over a ragged mixed prefill+decode batch.
 
     tokens/token_pos: [T] the ragged token ids and absolute positions;
@@ -139,7 +335,11 @@ def _ragged_forward(params: Params, tokens: jax.Array,
     (padding tokens -> the scratch page); page_table [R, max_pages] +
     q_start/q_len/kv_len [R]: the per-row ragged descriptors
     (ops.paged_attention). kv: the pool dict — DONATED by every caller
-    (an undonated pool copies multi-GB per step).
+    (an undonated pool copies multi-GB per step). token_state [T], for a
+    configuration with conv layers only: each token's STATE slot (its
+    sequence's batch slot; the scratch slot, max_batch, for padding).
+    None there means the decode loop's layout: token t is slot t's one
+    token.
 
     Returns (next_tok [R], kv, counters): per row, argmax logits at its
     LAST valid token — the next decode token for q_len==1 rows, the first
@@ -161,44 +361,69 @@ def _ragged_forward(params: Params, tokens: jax.Array,
     x = params["embed"].astype(cd)[tokens][None]          # [1, T, d]
     quantized = "k_scale" in kv
     layers = params["layers"]
-    if cfg.n_experts:
-        # closed over, not scanned: a scan slices its inputs, and a slice
-        # handed to the expert kernel is a copy of a layer's experts
-        experts = {k: layers[k] for k in _EXPERT_LEAVES}
-        layers = {k: v for k, v in layers.items() if k not in experts}
-        valid = token_page != SCRATCH_PAGE
+    # a padding token is one whose page is the scratch page
+    valid = token_page != SCRATCH_PAGE if cfg.n_experts else None
 
-    def layer(carry, inp):
-        x, kv = carry
-        lp, l = inp
-        h = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
-        q, k, v = _project_qkv(lp, h, cfg)                # [1, T, H, D]
-        q = _rope(q, token_pos, cfg.rope_theta)
-        k = _rope(k, token_pos, cfg.rope_theta)
-        hints = dict(layer=l, max_q_len=max_q_len, decode_rows=decode_rows,
-                     impl=paged_impl)
-        kc, vc, ksc, vsc = write_ragged_kv(
-            kv["k"], kv["v"], k[0], v[0], token_page, token_slot,
-            kv.get("k_scale"), kv.get("v_scale"), q_start=q_start,
-            q_len=q_len, **hints)
-        o = ragged_paged_attention(
-            q[0], kc, vc, page_table, q_start, q_len, kv_len,
-            k_scale=ksc, v_scale=vsc, **hints)
-        o = o.reshape(1, T, -1).astype(cd)
-        x = x + _maybe_psum(o @ lp["wo"].astype(cd), tp_axis)
-        kv = {"k": kc, "v": vc}
+    def attention(lp, l, x, kv):
+        """The attention operator of one layer, on entry ``l`` of the
+        pool (the layer's ordinal among the attention layers)."""
+        with jax.named_scope(SCOPE_ATTENTION):
+            h = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
+            q, k, v = _project_qkv(lp, h, cfg)            # [1, T, H, D]
+            q = _rope(q, token_pos, cfg.rope_theta)
+            k = _rope(k, token_pos, cfg.rope_theta)
+            hints = dict(layer=l, max_q_len=max_q_len,
+                         decode_rows=decode_rows, impl=paged_impl)
+            hd, scale = q.shape[-1], {}
+            if kv["k"].shape[-1] != hd:
+                # a pool whose rows are padded to whole lanes
+                # (make_kv_cache, lane_pad): zeros past head_dim add
+                # nothing to a score and come back as zeros
+                q, k, v = (jnp.pad(a, ((0, 0),) * 3 + (
+                    (0, kv["k"].shape[-1] - hd),)) for a in (q, k, v))
+                scale = dict(sm_scale=hd ** -0.5)
+            kc, vc, ksc, vsc = write_ragged_kv(
+                kv["k"], kv["v"], k[0], v[0], token_page, token_slot,
+                kv.get("k_scale"), kv.get("v_scale"), q_start=q_start,
+                q_len=q_len, **hints)
+            o = ragged_paged_attention(
+                q[0], kc, vc, page_table, q_start, q_len, kv_len,
+                k_scale=ksc, v_scale=vsc, **hints, **scale)
+            o = o[..., :hd].reshape(1, T, -1).astype(cd)
+            x = x + _maybe_psum(o @ lp["wo"].astype(cd), tp_axis)
+        kv = {**kv, "k": kc, "v": vc}
         if quantized:
             kv["k_scale"], kv["v_scale"] = ksc, vsc
-        if cfg.n_experts:
-            x, counters = _moe_mlp(lp, experts, l, x, valid, cfg, paged_impl)
-            return (x, kv), counters
-        return (_mlp(lp, x, cfg, tp_axis), kv), None
+        return x, kv
 
-    # the pool rides the scan as a carry, whole: each layer writes and
-    # reads it at its index
-    (x, kv), per_layer = lax.scan(
-        layer, (x, kv), (layers, jnp.arange(cfg.n_layers, dtype=jnp.int32)))
-    counters = per_layer.sum(axis=0) if cfg.n_experts else None
+    if cfg.hybrid:
+        x, kv, counters = _hybrid_layers(
+            layers, x, kv, cfg, attention, valid, paged_impl,
+            _ConvRows(token_pos, token_state, q_start, q_len))
+    else:
+        if cfg.n_experts:
+            # closed over, not scanned: a scan slices its inputs, and a
+            # slice handed to the expert kernel is a copy of a layer's
+            # experts
+            experts = {k: layers[k] for k in _EXPERT_LEAVES}
+            layers = {k: v for k, v in layers.items() if k not in experts}
+
+        def layer(carry, inp):
+            x, kv = carry
+            lp, l = inp
+            x, kv = attention(lp, l, x, kv)
+            if cfg.n_experts:
+                x, counters = _moe_mlp(lp, experts, l, x, valid, cfg,
+                                       paged_impl)
+                return (x, kv), counters
+            return (_mlp(lp, x, cfg, tp_axis), kv), None
+
+        # the pool rides the scan as a carry, whole: each layer writes and
+        # reads it at its index
+        (x, kv), per_layer = lax.scan(
+            layer, (x, kv),
+            (layers, jnp.arange(cfg.n_layers, dtype=jnp.int32)))
+        counters = per_layer.sum(axis=0) if cfg.n_experts else None
     x = _rmsnorm(x, params["final_norm"], cfg.norm_eps)
     last = jnp.clip(q_start + q_len - 1, 0, T - 1)        # [R]
     xl = x[0][last]
@@ -217,6 +442,7 @@ def _ragged_step_body(params: Params, tokens: jax.Array,
                       paged_impl: Optional[str] = None,
                       max_q_len: Optional[int] = None,
                       decode_rows: int = 0,
+                      token_state: Optional[jax.Array] = None,
                       ) -> Tuple[jax.Array, KVCache]:
     """The mixed step's program: ``_ragged_forward``, with a step's
     counters (if its configuration has any) appended to the tokens, so
@@ -225,7 +451,7 @@ def _ragged_step_body(params: Params, tokens: jax.Array,
     nxt, kv, counters = _ragged_forward(
         params, tokens, token_pos, token_page, token_slot, page_table,
         q_start, q_len, kv_len, kv, cfg, tp_axis, paged_impl, max_q_len,
-        decode_rows)
+        decode_rows, token_state)
     if counters is not None:
         nxt = jnp.concatenate([nxt, counters])
     return nxt, kv
@@ -296,12 +522,14 @@ ragged_decode_loop = functools.partial(jax.jit, static_argnames=(
 def _copy_page_body(kv: KVCache, src, dst) -> KVCache:
     """Copy-on-write: duplicate one page across all layers — pages AND
     their int8 scales, one tree_map (a prefix-hit sequence about to
-    write into a shared page copies it first). Plain body so StepPrograms
-    can shard_map it over local head shards."""
-    return jax.tree.map(
+    write into a shared page copies it first). The conv state, whose
+    second axis is batch slots and not pages, passes through. Plain body
+    so StepPrograms can shard_map it over local head shards."""
+    pages = jax.tree.map(
         lambda leaf: leaf.at[:, dst].set(
             lax.dynamic_index_in_dim(leaf, src, axis=1, keepdims=False)),
-        kv)
+        {k: leaf for k, leaf in kv.items() if k != STATE_LEAF})
+    return {**kv, **pages}
 
 
 copy_page = functools.partial(jax.jit, donate_argnames=("kv",))(
@@ -432,9 +660,13 @@ class StepPrograms:
             return params
         return jax.device_put(params, self._param_sharding)
 
-    def init_kv(self, total_pages: int, page_size: int, kv_dtype) -> KVCache:
+    def init_kv(self, total_pages: int, page_size: int, kv_dtype,
+                max_batch: int = 0) -> KVCache:
+        # the kernels move pages by DMA, in rows of whole lanes
         make = functools.partial(make_kv_cache, self.cfg, total_pages,
-                                 page_size, kv_dtype=kv_dtype)
+                                 page_size, kv_dtype=kv_dtype,
+                                 max_batch=max_batch,
+                                 lane_pad=self.paged_impl == "kernel")
         if self.mesh is None:
             return make()
         return jax.jit(make, out_shardings=self._kv_sharding)()

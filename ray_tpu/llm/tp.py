@@ -74,6 +74,14 @@ def tp_param_specs(cfg: LlamaConfig) -> Params:
 def validate_tp(cfg: LlamaConfig, tp: int) -> None:
     if tp < 2:
         raise ValueError(f"tp must be >= 2 for a sharded engine, got {tp}")
+    if cfg.hybrid or cfg.qk_norm_per_head:
+        raise NotImplementedError(
+            f"tp={tp} is not served for this block: layer_types / "
+            f"n_dense_layers stack the weights per kind of layer and keep "
+            f"a conv state per batch slot, and neither has a partition "
+            f"spec here (the conv operator's w_in would split B, C and u "
+            f"each over the axis); qk_norm_per_head is refused with them, "
+            f"untested under a head shard")
     if cfg.n_experts or cfg.qk_norm:
         raise NotImplementedError(
             f"tp={tp} is not served for this block: qk_norm normalises "

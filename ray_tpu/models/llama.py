@@ -18,7 +18,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -34,6 +34,17 @@ from ray_tpu.parallel.ring_attention import (ring_attention,
 from ray_tpu.parallel.ulysses import ulysses_attention_sharded
 
 Params = Dict[str, Any]
+
+#: a layer's operator, as the published configurations name it
+ATTENTION, CONV = "full_attention", "conv"
+LAYER_KINDS = (ATTENTION, CONV)
+#: spread of the seeded router selection bias. The top 4 of 64 sigmoid
+#: scores lie ~0.013 apart, so 0.02 changes the chosen set for about half
+#: the tokens and leaves the load near even (busiest expert 2.3 x the mean
+#: at 107 rows; 1.9 with no bias). A trained bias BALANCES load; at 0.1 a
+#: drawn one decided the choice by itself (a fifth of the experts never
+#: hit, busiest 5.4 x the mean: PERF.md, PR 30)
+ROUTER_BIAS_STD = 0.02
 
 
 @dataclasses.dataclass(frozen=True)
@@ -62,8 +73,37 @@ class LlamaConfig:
     norm_topk_prob: bool = False     # renormalise the chosen experts' weights
     qk_norm: bool = False            # RMSNorm on the projected q and k
     tie_embeddings: bool = True      # logits from embed, else from lm_head
+    # A block whose layers DIFFER (LFM2: gated short convolutions with
+    # attention every fourth layer, two leading dense feed-forwards before
+    # the expert ones). Empty layer_types = every layer is attention.
+    layer_types: Tuple[str, ...] = ()    # per layer: LAYER_KINDS
+    conv_kernel: int = 3             # taps of the depthwise causal conv
+    n_dense_layers: int = 0          # leading layers with a dense SwiGLU
+    dense_ffn_dim: int = 0           #   of this width (experts: ffn_dim)
+    qk_norm_per_head: bool = False   # q/k RMSNorm over each head, not all
+    router_score: str = "softmax"    # softmax | sigmoid
+    router_bias: bool = False        # per-expert bias, for the choice only
+    router_eps: float = 0.0          # added to the renormalising sum
+    router_scale: float = 1.0        # on the routing weights
 
     def __post_init__(self):
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        bad = sorted(set(self.layer_types) - set(LAYER_KINDS))
+        if bad or (self.layer_types
+                   and len(self.layer_types) != self.n_layers):
+            raise ValueError(
+                f"layer_types must name one of {LAYER_KINDS} for each of "
+                f"the {self.n_layers} layers, got {self.layer_types}")
+        if self.router_score not in ("softmax", "sigmoid"):
+            raise ValueError(f"router_score must be 'softmax' or "
+                             f"'sigmoid', got {self.router_score!r}")
+        if self.n_dense_layers and not (
+                self.n_experts and self.dense_ffn_dim
+                and self.n_dense_layers < self.n_layers):
+            raise ValueError(
+                f"n_dense_layers={self.n_dense_layers} leading dense "
+                f"layers need n_experts, a dense_ffn_dim and fewer than "
+                f"n_layers={self.n_layers}")
         if self.n_experts and not \
                 0 < self.experts_per_token <= self.n_experts:
             raise ValueError(
@@ -73,6 +113,17 @@ class LlamaConfig:
     @property
     def head_dim(self) -> int:
         return self.dim // self.n_heads
+
+    @property
+    def hybrid(self) -> bool:
+        """The layers differ in kind (operator or feed-forward): weights
+        are stacked per kind and the serving step runs the pattern."""
+        return bool(self.layer_types or self.n_dense_layers)
+
+    def layers_of(self, kind: str) -> Tuple[int, ...]:
+        """Indices of the layers whose operator is ``kind``."""
+        types = self.layer_types or (ATTENTION,) * self.n_layers
+        return tuple(i for i, t in enumerate(types) if t == kind)
 
     @staticmethod
     def tiny(**kw) -> "LlamaConfig":
@@ -98,6 +149,8 @@ class LlamaConfig:
 
 
 def init_params(cfg: LlamaConfig, key: jax.Array) -> Params:
+    if cfg.hybrid:
+        return _init_hybrid_params(cfg, key)
     ks = jax.random.split(key, 8)
     d, L = cfg.dim, cfg.n_layers
     hq, hkv, hd, f = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.ffn_dim
@@ -132,7 +185,10 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Params:
     if cfg.n_experts:
         params["layers"]["router"] = dense(
             jax.random.fold_in(key, 8), L, d, cfg.n_experts)
-    if cfg.qk_norm:
+    if cfg.qk_norm_per_head:
+        params["layers"]["q_norm"] = norm_init(L, hd)
+        params["layers"]["k_norm"] = norm_init(L, hd)
+    elif cfg.qk_norm:
         params["layers"]["q_norm"] = norm_init(L, hq * hd)
         params["layers"]["k_norm"] = norm_init(L, hkv * hd)
     if not cfg.tie_embeddings:
@@ -141,7 +197,75 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Params:
     return params
 
 
+def _init_hybrid_params(cfg: LlamaConfig, key: jax.Array) -> Params:
+    """The tree of a block whose layers differ: ``layers`` holds one
+    stack per KIND, each on its own leading axis, a layer's entry at its
+    ordinal among the layers of that kind. Operators: "attn" (the
+    attention layers) and "conv" (the gated short convolutions: w_in
+    [d, 3d] to B, C and u, the depthwise taps w_conv [taps, d], w_out).
+    Feed-forwards: "dense" (the leading n_dense_layers, or all without
+    experts) and "moe" (the rest). The router's selection bias is float32
+    whatever the weights are held in, and drawn, not zero: a zero bias
+    would leave the choice and the weights the same experts."""
+    d, L, pd = cfg.dim, cfg.n_layers, cfg.param_dtype
+    hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    keys = iter(jax.random.split(key, 24))
+
+    def dense(*shape, fan_in=None, dtype=pd):
+        fan_in = fan_in if fan_in is not None else shape[-2]
+        return (jax.random.normal(next(keys), shape)
+                * (fan_in ** -0.5)).astype(dtype)
+
+    def swiglu(n, *E, f):
+        return {"mlp_norm": jnp.ones((n, d), pd),
+                "w_gate": dense(n, *E, d, f), "w_up": dense(n, *E, d, f),
+                "w_down": dense(n, *E, f, d)}
+
+    layers = {}
+    A, C = len(cfg.layers_of(ATTENTION)), len(cfg.layers_of(CONV))
+    if A:
+        layers["attn"] = {
+            "attn_norm": jnp.ones((A, d), pd),
+            "wq": dense(A, d, hq * hd), "wk": dense(A, d, hkv * hd),
+            "wv": dense(A, d, hkv * hd), "wo": dense(A, hq * hd, d)}
+        if cfg.qk_norm_per_head:
+            layers["attn"]["q_norm"] = jnp.ones((A, hd), pd)
+            layers["attn"]["k_norm"] = jnp.ones((A, hd), pd)
+        elif cfg.qk_norm:
+            layers["attn"]["q_norm"] = jnp.ones((A, hq * hd), pd)
+            layers["attn"]["k_norm"] = jnp.ones((A, hkv * hd), pd)
+    if C:
+        layers["conv"] = {
+            "conv_norm": jnp.ones((C, d), pd),
+            "w_in": dense(C, d, 3 * d),
+            "w_conv": dense(C, cfg.conv_kernel, d, fan_in=cfg.conv_kernel),
+            "w_out": dense(C, d, d)}
+    n_dense = cfg.n_dense_layers if cfg.n_experts else L
+    if n_dense:
+        layers["dense"] = swiglu(
+            n_dense, f=cfg.dense_ffn_dim if cfg.n_experts else cfg.ffn_dim)
+    if cfg.n_experts:
+        M = L - n_dense
+        layers["moe"] = swiglu(M, cfg.n_experts, f=cfg.ffn_dim)
+        layers["moe"]["router"] = dense(M, d, cfg.n_experts)
+        if cfg.router_bias:
+            layers["moe"]["router_bias"] = ROUTER_BIAS_STD * \
+                jax.random.normal(next(keys), (M, cfg.n_experts), jnp.float32)
+    params = {"embed": dense(cfg.vocab_size, d, fan_in=d),
+              "layers": layers, "final_norm": jnp.ones((d,), pd)}
+    if not cfg.tie_embeddings:
+        params["lm_head"] = dense(cfg.vocab_size, d, fan_in=d)
+    return params
+
+
 def _require_llama_block(cfg: LlamaConfig, what: str) -> None:
+    if cfg.hybrid or cfg.qk_norm_per_head:
+        raise NotImplementedError(
+            f"{what} is written for the Llama/Mistral block, every layer "
+            f"alike; layer_types (conv layers beside attention), "
+            f"n_dense_layers and qk_norm_per_head are served by "
+            f"llm/model.py only: the conv operator has no training "
+            f"forward or backward here")
     if cfg.n_experts or cfg.qk_norm or not cfg.tie_embeddings:
         raise NotImplementedError(
             f"{what} is written for the Llama/Mistral block; n_experts, "

@@ -5,9 +5,11 @@ buckets and drop what overflows; a server may not. Here every valid token
 is multiplied by exactly its ``top_k`` experts, whatever else is in the
 batch:
 
-  - route: softmax over the experts in float32, top-k (weights kept as
-    they are, or renormalised); padding slots get weight 0 and an expert
-    id past the last expert, so they join no group;
+  - route: softmax over the experts (or a sigmoid of each, with a
+    per-expert bias that enters the choice and not the weights) in
+    float32, top-k (weights kept as they are, or renormalised); padding
+    slots get weight 0 and an expert id past the last expert, so they
+    join no group;
   - group: the (token, expert) pairs are laid out expert by expert, each
     expert's group padded up to whole ROW TILES of ``tm`` rows, so a tile
     belongs to one expert. The layout is a cumulative sum over a one-hot
@@ -52,17 +54,45 @@ from ray_tpu.ops.paged_attention import kernels_supported
 COUNTERS = ("moe_pairs", "moe_hits", "moe_hot")
 
 
-def route(m, valid, router, top_k: int, renorm: bool):
+#: the router's jax.named_scope: it reaches the device trace in each op's
+#: name path, where the benchmark's readers match it
+SCOPE_ROUTER = "moe_router"
+
+
+@jax.named_scope(SCOPE_ROUTER)
+def route(m, valid, router, top_k: int, renorm: bool, *,
+          score: str = "softmax", bias=None, eps: float = 0.0,
+          scale: float = 1.0):
     """(weights [T, k] float32, experts [T, k] int32). The router runs in
     float32 at the highest matmul precision: T x d x E is nothing next to
     the experts, and a bf16 rounding of a logit flips a near-tie between
     the k-th and the (k+1)-th expert, which costs a whole expert's output.
-    Padding tokens: weight 0, expert id E (one past the last)."""
+    Padding tokens: weight 0, expert id E (one past the last).
+
+    ``score``: "softmax" over the experts, or "sigmoid" of each logit.
+    ``bias`` [E] float32 is added to the scores for the CHOICE only: the
+    weights are the chosen experts' scores without it. ``eps`` joins the
+    renormalising sum and ``scale`` multiplies the weights. The defaults
+    are OLMoE's routing, operation for operation."""
     logits = jnp.dot(m.astype(jnp.float32), router.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)
-    w, e = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    if score == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    elif score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"score must be 'softmax' or 'sigmoid', "
+                         f"got {score!r}")
+    if bias is None:
+        w, e = lax.top_k(scores, top_k)
+    else:
+        _, e = lax.top_k(scores + bias.astype(jnp.float32), top_k)
+        w = jnp.take_along_axis(scores, e, axis=-1)
     if renorm:
-        w = w / jnp.sum(w, axis=-1, keepdims=True)
+        total = jnp.sum(w, axis=-1, keepdims=True)
+        w = w / (total + eps if eps else total)
+    if scale != 1.0:
+        w = w * scale
     keep = valid[:, None]
     return jnp.where(keep, w, 0.0), \
         jnp.where(keep, e.astype(jnp.int32), router.shape[-1])
@@ -209,7 +239,7 @@ def _experts_reference(x_rows, tile_expert, layer, gate, up, down, tm: int):
 
 def moe_ffn(m, valid, router, gate, up, down, top_k: int, renorm: bool, *,
             layer=None, impl: Optional[str] = None,
-            interpret: bool = False):
+            interpret: bool = False, **routing):
     """m [T, d] (normed hidden states), valid [T] bool -> (y [T, d] in m's
     dtype, counters [3] int32 in COUNTERS' order).
 
@@ -217,13 +247,14 @@ def moe_ffn(m, valid, router, gate, up, down, top_k: int, renorm: bool, *,
     [L, E, ...] trees with ``layer`` the index to use (what the serving
     step passes: see the module docstring). y is 0 for padding tokens.
     ``impl``: "kernel" | "reference", None = the kernel on a TPU.
+    ``routing``: route's score, bias, eps and scale.
     """
     T, d = m.shape
     if layer is None:
         gate, up, down, layer = gate[None], up[None], down[None], 0
     layer = jnp.asarray(layer, jnp.int32)
     E, f = gate.shape[1], gate.shape[-1]
-    w, e = route(m, valid, router, top_k, renorm)
+    w, e = route(m, valid, router, top_k, renorm, **routing)
     tm, fb = _tiling(T * top_k, E, f)
     dest, tile_expert, n_used, counts = group(e, E, tm)
     n_rows = tile_expert.shape[0] * tm

@@ -121,6 +121,9 @@ def test_tiling_follows_the_static_shapes():
 
 @pytest.fixture(scope="module")
 def olmoe():
+    # compiled_step_programs() counts the process's shared jits: whatever
+    # file this worker ran before must not count against this engine
+    jax.clear_caches()
     cfg = LlamaConfig.tiny(**OLMOE)
     return cfg, InferenceEngine(cfg, **ENGINE)
 

@@ -202,15 +202,18 @@ def _compile_step_program(chip, cfg, program, *, max_batch, pages, max_seq,
     from ray_tpu.models.llama import init_params
     params = _abstract(chip, functools.partial(init_params, cfg,
                                                jax.random.PRNGKey(0)))
-    kv = _abstract(chip, functools.partial(make_kv_cache, cfg, pages, ps))
+    # the pool the kernels take (StepPrograms.init_kv on a TPU)
+    kv = _abstract(chip, functools.partial(
+        make_kv_cache, cfg, pages, ps, max_batch=max_batch, lane_pad=True))
     table = functools.partial(_sds, chip, dtype=jnp.int32)
     if program == "mixed":
         T, R = max_batch + rows * chunk, max_batch + rows
         tok, row = table((T,)), table((R,))
+        state = {"token_state": tok} if cfg.layers_of("conv") else {}
         compiled = M.ragged_step.lower(
             params, tok, tok, tok, tok, table((R, max_seq // ps)), row, row,
             row, kv, cfg=cfg, paged_impl="kernel", max_q_len=chunk,
-            decode_rows=max_batch).compile()
+            decode_rows=max_batch, **state).compile()
         return compiled, kv, R
     row = table((max_batch,))
     compiled = M.ragged_decode_loop.lower(
@@ -272,6 +275,48 @@ def test_olmoe_step_programs_compile_at_benchmark_shapes(chip, program):
     assert text.count("tpu_custom_call") == (4 if program == "mixed" else 3)
     assert "_moe_experts_pallas" in text
     assert jax.tree.leaves(compiled.out_info)[0].shape == (rows + 3,)
+
+
+def _lfm2_cfg(n_layers=6):
+    """lfm2-24b-a2b-serve-1chip's widths; 6 layers = the two leading dense
+    conv layers and ONE period (attn conv conv conv) of its ten."""
+    from ray_tpu.models.llama import LlamaConfig
+    pattern = ["conv", "conv"] + ["full_attention", "conv", "conv",
+                                  "conv"] * ((n_layers - 2) // 4)
+    return LlamaConfig(vocab_size=65536, dim=2048, n_layers=n_layers,
+                       n_heads=32, n_kv_heads=8, ffn_dim=1536,
+                       dense_ffn_dim=11776, n_dense_layers=2, n_experts=64,
+                       experts_per_token=4, norm_topk_prob=True,
+                       layer_types=pattern, qk_norm_per_head=True,
+                       router_score="sigmoid", router_bias=True,
+                       router_eps=1e-6, rope_theta=1e6,
+                       param_dtype="bfloat16")
+
+
+@pytest.mark.parametrize("program", ["mixed", "decode"])
+def test_lfm2_step_programs_compile_at_benchmark_shapes(chip, program):
+    """lfm2-24b-a2b-serve-1chip's two step programs at its published
+    widths (6 of its 10 layers): the paged kernels at head_dim 64 in a
+    pool of 128-lane rows (Mosaic refuses a 64-wide page DMA), 32 q / 8 kv
+    heads; the expert kernel at width 1536 (two width blocks of 768) once
+    for each expert layer of the period; the conv state carried beside
+    the pool, both aliased from argument to result. 128 decode rows, 2
+    chunks of 512, 10752 pages of 16."""
+    compiled, kv, rows = _compile_step_program(
+        chip, _lfm2_cfg(), program, max_batch=128, pages=10752, max_seq=3072)
+    assert kv["k"].shape == (1, 10752, 8, 16, 128)
+    assert kv["conv"].shape == (5, 129, 2, 2048)
+    text = compiled.as_text()
+    # the write, the attention (chunk and one-token tiles | one-token),
+    # and the experts of the period's four expert layers
+    assert text.count("tpu_custom_call") == (7 if program == "mixed" else 6)
+    assert "_moe_experts_pallas" in text
+    assert jax.tree.leaves(compiled.out_info)[0].shape == (rows + 3,)
+    mem = compiled.memory_analysis()
+    held = sum(_bytes_of(f"bf16[{','.join(map(str, a.shape))}]")
+               for a in kv.values())
+    assert mem.alias_size_in_bytes >= held
+    assert mem.temp_size_in_bytes < 2**28
 
 
 _INSTRUCTION = re.compile(
