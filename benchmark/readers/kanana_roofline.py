@@ -1,0 +1,48 @@
+"""A kernel's share of its roofline in the Kanana-2 block: readers/
+trace_roofline.py's and readers/moe_roofline.py's method (the least time
+the chip could take for the work the algorithm needs / the kernel's
+measured device time in the trace), with the work counted from this
+block's own shape numbers (kernel_cost_kanana.py): latent attention reads
+ONE row a cached token for all heads. Percent, not clamped; which bound it
+is goes into the run's notes. None where the trace holds no such kernel,
+or no counters.
+
+args: {"cost": "latent_attention" | "moe_experts", "patterns": [regex of
+       the kernel's HLO instruction names]}
+"""
+
+from __future__ import annotations
+
+from benchmark import kernel_cost, kernel_cost_kanana, trace_reduce
+from benchmark.readers.moe_roofline import traced_counters
+
+
+def read(data, args):
+    tr = data.get("trace_summary")
+    span = data.get("trace") or {}
+    if tr is None:
+        return None
+    seconds = tr.op_time(args["patterns"])
+    dims = kernel_cost_kanana.model_dims(data["config"])
+    notes = {}
+    if args["cost"] == "latent_attention":
+        flops, nbytes = kernel_cost_kanana.latent_attention_work(
+            data.get("request_log", ()), span["start"]["wall"],
+            span["stop"]["wall"], dims)
+    elif args["cost"] == "moe_experts":
+        path = span.get("dir") and trace_reduce.find_xplane(span["dir"])
+        counted = traced_counters(path) if path else None
+        if not counted or not counted.get("moe_pairs"):
+            return None
+        flops, nbytes = kernel_cost_kanana.moe_experts_work(
+            counted["moe_pairs"], counted["moe_hits"], dims)
+        notes["moe_traced_counters"] = counted
+    else:
+        raise ValueError(f"unknown cost model {args['cost']!r}")
+    if not seconds or not flops:
+        return None
+    pct, bound = kernel_cost.roofline_pct(flops, nbytes, seconds,
+                                          data["device"]["kind"])
+    notes[f"{args['cost']}_bound"] = bound
+    data.setdefault("notes", {}).update(notes)
+    return pct

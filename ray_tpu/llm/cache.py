@@ -276,6 +276,19 @@ def make_kv_cache(cfg: LlamaConfig, total_pages: int, page_size: int,
     zero-pads q, k and v to the pool's D. At head_dim 128 it changes
     nothing.
 
+    Latent attention (cfg.kv_lora_rank): ONE leaf, {"k"}: [n_attn,
+    total_pages, 1, page_size, W]. A token's row is its normed latent
+    (kv_lora_rank values) followed by its rotated shared key part
+    (qk_rope_head_dim), for all heads; there is no "v" leaf at all: the
+    value is the leading kv_lora_rank values of the same row, which the
+    kernels take as a lane slice of the K block they already hold. W is
+    ``latent_row_width``: the row itself, or with ``lane_pad`` whole
+    lanes (576 -> 640: four and a half 128-lane tiles cannot be moved by
+    a page DMA, and HBM tiles the row to 640 whatever its shape says, so
+    the padding costs no memory and a ninth more bytes read). Pages stay
+    the only state: the prefix cache, the copy on write and
+    recompute-preemption work on this leaf as on K and V.
+
     A configuration with conv layers gets one more leaf, ``STATE_LEAF``:
     [n_conv, max_batch + 1, conv_kernel - 1, dim] in cfg.dtype, a conv
     layer's last inputs for each BATCH SLOT (axis 1 is slots, not pages:
@@ -287,6 +300,15 @@ def make_kv_cache(cfg: LlamaConfig, total_pages: int, page_size: int,
     if kv_dtype not in (None, "model", "int8"):
         raise ValueError(f"kv_dtype must be 'model' or 'int8', "
                          f"got {kv_dtype!r}")
+    if cfg.kv_lora_rank:
+        if kv_dtype == "int8":
+            raise ValueError(
+                "kv_dtype 'int8' is not built for a latent pool "
+                "(kv_lora_rank): one scale a token would cover the normed "
+                "latent and the rotary key part, two ranges in one row")
+        return {"k": jnp.zeros(
+            (len(cfg.layers_of(ATTENTION)), total_pages, 1, page_size,
+             latent_row_width(cfg, lane_pad)), dtype or cfg.dtype)}
     shape = (len(cfg.layers_of(ATTENTION)), total_pages, cfg.n_kv_heads,
              page_size,
              -(-cfg.head_dim // LANES) * LANES if lane_pad else cfg.head_dim)
@@ -309,6 +331,13 @@ def make_kv_cache(cfg: LlamaConfig, total_pages: int, page_size: int,
     return kv
 
 
+def latent_row_width(cfg: LlamaConfig, lane_pad: bool = False) -> int:
+    """Values a token's row of a latent pool holds: the latent and the
+    shared rotary key part, rounded up to whole lanes with ``lane_pad``."""
+    w = cfg.kv_lora_rank + cfg.qk_rope_head_dim
+    return -(-w // LANES) * LANES if lane_pad else w
+
+
 def prefix_cache_supported(cfg: LlamaConfig) -> bool:
     """Whether a page-aligned prefix hit restores ALL of a sequence's
     state at that position: true where pages are the only state."""
@@ -317,10 +346,14 @@ def prefix_cache_supported(cfg: LlamaConfig) -> bool:
 
 def kv_cache_tag(cfg: LlamaConfig, kv_dtype: Optional[str]) -> str:
     """The PrefixCache hash seed for a pool config: pages written under
-    one KV storage scheme must never match a lookup under another."""
+    one KV storage scheme must never match a lookup under another (a
+    latent pool's pages hold other values than a K/V pool's)."""
     if kv_dtype == "int8":
         return "int8"
-    return str(jnp.dtype(cfg.dtype).name)
+    name = str(jnp.dtype(cfg.dtype).name)
+    if cfg.kv_lora_rank:
+        return f"{name}-latent{cfg.kv_lora_rank}+{cfg.qk_rope_head_dim}"
+    return name
 
 
 class SequenceState:

@@ -151,6 +151,13 @@ class InferenceEngine:
         self._has_state = STATE_LEAF in self.kv
         self._state_bytes = self.kv[STATE_LEAF].nbytes \
             if self._has_state else 0
+        # the pool's row as held (a latent pool's is wider than the
+        # latent where the kernels need whole lanes) and what one token
+        # costs in one layer's pages, over every page leaf
+        self._kv_row_width = self.kv["k"].shape[-1]
+        self._kv_token_layer_bytes = sum(
+            x.nbytes // (x.shape[0] * x.shape[1] * x.shape[3])
+            for k, x in self.kv.items() if k != STATE_LEAF)
         self._held_bytes: Dict[int, int] = {}
         for leaf in jax.tree.leaves((self.params, self.kv)):
             for shard in leaf.addressable_shards:
@@ -209,6 +216,11 @@ class InferenceEngine:
             # slot's state instead of what the last owner left)
             self.stats.update(state_bytes=self._state_bytes,
                               state_resets=0)
+        if cfg.kv_lora_rank:
+            # a latent pool: what a token costs a layer, and the row held
+            self.stats.update(
+                kv_token_layer_bytes=self._kv_token_layer_bytes,
+                kv_row_width=self._kv_row_width)
         # per-request flight recorder (llm/request_log.py): lifecycle
         # event stream per request + TTFT/TPOT/e2e/queue-wait histograms
         # + SLO attainment; None disables every hook (seq.record stays
@@ -298,7 +310,9 @@ class InferenceEngine:
         programs took ("kernel" | "reference" — chosen from the platform,
         so a deployment can assert it never fell back), the resident
         step-program count, and per device the bytes of weights + KV
-        pages (+ conv state: ``state_bytes`` of ``kv_bytes``) it holds
+        pages (+ conv state: ``state_bytes`` of ``kv_bytes``; beside
+        ``kv_bytes`` what a token costs in one layer's pages and the
+        pool's row width as held) it holds
         next to the allocator's own ``memory_stats()``
         (None on backends that keep none, i.e. the CPU). Safe from any
         thread while the engine steps: placement is read from the
@@ -324,6 +338,8 @@ class InferenceEngine:
                 "compiled_step_programs": self.compiled_step_programs(),
                 "param_bytes": self._param_bytes,
                 "kv_bytes": self._kv_bytes,
+                "kv_token_layer_bytes": self._kv_token_layer_bytes,
+                "kv_row_width": self._kv_row_width,
                 "state_bytes": self._state_bytes,
                 "devices": per_device}
 

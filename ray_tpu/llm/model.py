@@ -52,6 +52,18 @@ another layout or stacked back, and no step copies the pool (threaded
 as scan xs/ys it moved ~4 times a step: PERF.md, PR 27). Only the int8
 pool's scale leaves, which XLA reads, are scattered by XLA.
 
+Latent attention (``kv_lora_rank``; Kanana-2 is the first such block):
+the attention operator has no wk / wv (``_latent_attention``). A token's
+cache in a layer is ONE row of kv_lora_rank + qk_rope_head_dim values
+for all heads, in a pool of ONE leaf {"k"} with no "v": the normed
+latent and the rotated shared key part. Decode rows and prefill chunks
+both compute the ABSORBED form: the up-projection is folded into the
+query (w_uk) and into the output (w_uv), so the kernel runs multi-query
+attention over the latent rows, the value a lane slice of the K block it
+holds, and the cached prefix is never expanded to per-head K and V in
+HBM. A shared expert (``shared_ffn_dim``) is a dense SwiGLU on the
+feed-forward's normed input, added to the routed sum.
+
 Tensor parallelism (``tp_axis``): the step also runs INSIDE a
 ``shard_map`` block whose weights arrive pre-sliced Megatron-style
 (wq/wk/wv/w_gate/w_up column-sharded, wo/w_down row-sharded). Head
@@ -75,7 +87,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ray_tpu.llm import tp as TP
 from ray_tpu.llm.cache import SCRATCH_PAGE, STATE_LEAF, make_kv_cache
 from ray_tpu.models.llama import (ATTENTION, CONV, LlamaConfig, Params,
-                                  _rmsnorm, _rope, init_params)
+                                  _rmsnorm, _rope, _rope_pairs, init_params)
 from ray_tpu.ops import moe
 from ray_tpu.ops.paged_attention import (kernels_supported,
                                          ragged_paged_attention,
@@ -83,7 +95,8 @@ from ray_tpu.ops.paged_attention import (kernels_supported,
 from ray_tpu.parallel.mesh import shard_map_compat
 from ray_tpu.util import compile_tracker
 
-KVCache = dict  # {"k", "v"[, "k_scale", "v_scale"][, "conv"]}: llm/cache.py
+# {"k", "v"[, "k_scale", "v_scale"][, "conv"]}, or a latent pool's {"k"}
+KVCache = dict  # (llm/cache.py)
 
 
 def _maybe_psum(x, tp_axis):
@@ -135,7 +148,16 @@ def _moe_mlp(lp, experts, layer, x, valid, cfg: LlamaConfig, impl):
         layer=layer, impl=impl, score=cfg.router_score,
         bias=lp.get("router_bias"), eps=cfg.router_eps,
         scale=cfg.router_scale)
-    return x + y[None], counters
+    y = y[None]
+    if cfg.shared_ffn_dim:
+        # the expert every token takes: no gate of its own, counted once
+        # (a padding token's is garbage like the rest of its row)
+        cd = cfg.dtype
+        with jax.named_scope(SCOPE_SHARED):
+            gate = jax.nn.silu(h @ lp["w_shared_gate"].astype(cd))
+            y = y + (gate * (h @ lp["w_shared_up"].astype(cd))) \
+                @ lp["w_shared_down"].astype(cd)
+    return x + y, counters
 
 
 #: the expert weights stay out of the layer scan's sliced inputs
@@ -152,6 +174,10 @@ def step_counters(cfg: LlamaConfig) -> Tuple[str, ...]:
 #: (the router's, "moe_router", is ops/moe.py's). Renaming one changes a
 #: metric.
 SCOPE_ATTENTION, SCOPE_CONV = "attention", "short_conv"
+#: ... inside "attention", everything of the latent operator but the write
+#: and the kernel (wq, w_kva, the latent's norm, the rotary part, the two
+#: absorbed products, wo); and the shared expert
+SCOPE_MLA_PROJ, SCOPE_SHARED = "mla_proj", "moe_shared"
 
 
 class _ConvRows(NamedTuple):
@@ -240,6 +266,56 @@ def _short_conv(lp, l, x, state, rows: _ConvRows, cfg: LlamaConfig):
             state = state.at[l, row_slot].set(
                 upto[last].astype(state.dtype))
     return x + y[None], state
+
+
+def _latent_attention(lp, l, x, kv, cfg: LlamaConfig, token_pos, token_page,
+                      token_slot, page_table, q_start, q_len, kv_len, hints):
+    """Latent attention (MLA) of one layer in its ABSORBED form, for
+    decode rows and chunk rows alike, on entry ``l`` of the latent
+    pool. Per token: q = z wq, per head [q_nope, q_pe]; a = z w_kva,
+    c = rms(a[:rank]), k_pe = a[rank:], ONE for all heads; adjacent-
+    pair rotary on q_pe and k_pe. The row (c, k_pe) goes to the pool.
+    The published form expands c with kv_b_proj into per-head k_nope
+    and v; here q~_h = q_nope_h w_uk_h^T is scored against the rows
+    themselves (score_h = (q~_h . c + q_pe_h . k_pe) / sqrt(nope +
+    rope)), the kernel returns o~_h = sum_s p_h c[s], and o_h = o~_h
+    w_uv_h: equal in exact arithmetic, and the cache is read once for
+    all heads and never expanded in HBM. ``hints``: the static tiling
+    hints and the kernel-or-reference choice (max_q_len, decode_rows,
+    impl)."""
+    cd = cfg.dtype
+    r, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    T, W = x.shape[1], kv["k"].shape[-1]
+    with jax.named_scope(SCOPE_ATTENTION):
+        with jax.named_scope(SCOPE_MLA_PROJ):
+            h = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
+            hq = lp["w_uk"].shape[0]
+            q = (h @ lp["wq"].astype(cd)).reshape(1, T, hq, -1)
+            a = h @ lp["w_kva"].astype(cd)            # [1, T, r + rope]
+            c = _rmsnorm(a[..., :r], lp["kv_norm"], cfg.norm_eps)
+            q_pe = _rope_pairs(q[..., dn:], token_pos, cfg.rope_theta)
+            k_pe = _rope_pairs(a[:, :, None, r:], token_pos,
+                               cfg.rope_theta)        # [1, T, 1, rope]
+            q_lat = jnp.einsum("thn,hnr->thr", q[0, ..., :dn],
+                               lp["w_uk"].astype(cd))
+            # rows of the pool's width: zeros past rank + rope add
+            # nothing to a score
+            pad = ((0, 0), (0, 0), (0, W - r - q_pe.shape[-1]))
+            qq = jnp.pad(jnp.concatenate([q_lat, q_pe[0]], axis=-1), pad)
+            row = jnp.pad(jnp.concatenate([c[0][:, None], k_pe[0]],
+                                          axis=-1), pad)   # [T, 1, W]
+        hints = dict(hints, layer=l)
+        pool, _, _, _ = write_ragged_kv(
+            kv["k"], None, row, None, token_page, token_slot,
+            q_start=q_start, q_len=q_len, **hints)
+        o = ragged_paged_attention(
+            qq, pool, None, page_table, q_start, q_len, kv_len,
+            v_width=r, sm_scale=q.shape[-1] ** -0.5, **hints)
+        with jax.named_scope(SCOPE_MLA_PROJ):
+            o = jnp.einsum("thr,hrv->thv", o.astype(cd),
+                           lp["w_uv"].astype(cd)).reshape(1, T, -1)
+            x = x + o @ lp["wo"].astype(cd)
+    return x, {**kv, "k": pool}
 
 
 def _pattern(cfg: LlamaConfig):
@@ -367,6 +443,12 @@ def _ragged_forward(params: Params, tokens: jax.Array,
     def attention(lp, l, x, kv):
         """The attention operator of one layer, on entry ``l`` of the
         pool (the layer's ordinal among the attention layers)."""
+        if cfg.kv_lora_rank:
+            return _latent_attention(
+                lp, l, x, kv, cfg, token_pos, token_page, token_slot,
+                page_table, q_start, q_len, kv_len,
+                dict(max_q_len=max_q_len, decode_rows=decode_rows,
+                     impl=paged_impl))
         with jax.named_scope(SCOPE_ATTENTION):
             h = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
             q, k, v = _project_qkv(lp, h, cfg)            # [1, T, H, D]

@@ -74,6 +74,14 @@ def tp_param_specs(cfg: LlamaConfig) -> Params:
 def validate_tp(cfg: LlamaConfig, tp: int) -> None:
     if tp < 2:
         raise ValueError(f"tp must be >= 2 for a sharded engine, got {tp}")
+    if cfg.kv_lora_rank or cfg.shared_ffn_dim:
+        raise NotImplementedError(
+            f"tp={tp} is not served for this block: kv_lora_rank (latent "
+            f"attention) keeps ONE cache row a token for all heads, so "
+            f"the pool has no kv-head axis to shard (every shard would "
+            f"hold the whole latent and w_kva, and split w_uk / w_uv / wq "
+            f"/ wo by head: no spec here says so), and shared_ffn_dim "
+            f"comes with n_experts, which is refused below (ROADMAP R8)")
     if cfg.hybrid or cfg.qk_norm_per_head:
         raise NotImplementedError(
             f"tp={tp} is not served for this block: layer_types / "

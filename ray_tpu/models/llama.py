@@ -85,6 +85,19 @@ class LlamaConfig:
     router_bias: bool = False        # per-expert bias, for the choice only
     router_eps: float = 0.0          # added to the renormalising sum
     router_scale: float = 1.0        # on the routing weights
+    # Latent attention (MLA; Kanana-2, of the deepseek_v3 family, is the
+    # first such block): no wk / wv; a token's cache in a layer is ONE
+    # vector of kv_lora_rank + qk_rope_head_dim values for all heads, and
+    # the head sizes are fields, not dim / n_heads (head_dim below stays
+    # what it is for the blocks that have it). Its rotary part turns
+    # ADJACENT pairs (_rope_pairs: the family's published rope_interleave
+    # true), the one pairing built: a constant of the block, not a field.
+    kv_lora_rank: int = 0            # 0 = per-head K and V; else the rank
+    qk_nope_head_dim: int = 0        #   of the latent; a score head's part
+    qk_rope_head_dim: int = 0        #   from it, and its rotary part (ONE
+    v_head_dim: int = 0              #   key for all heads); a value head
+    shared_ffn_dim: int = 0          # a SwiGLU every token takes, added to
+    #                                  the routed experts' sum (0 = none)
 
     def __post_init__(self):
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
@@ -109,6 +122,30 @@ class LlamaConfig:
             raise ValueError(
                 f"n_experts={self.n_experts} needs 0 < experts_per_token "
                 f"<= n_experts, got {self.experts_per_token}")
+        if self.shared_ffn_dim and not self.n_experts:
+            raise ValueError(
+                f"shared_ffn_dim={self.shared_ffn_dim} is the width of the "
+                f"expert every token takes BESIDE the routed ones: it "
+                f"needs n_experts")
+        heads = (self.qk_nope_head_dim, self.qk_rope_head_dim,
+                 self.v_head_dim)
+        if self.kv_lora_rank:
+            if min(heads) <= 0 or self.qk_rope_head_dim % 2:
+                raise ValueError(
+                    f"kv_lora_rank={self.kv_lora_rank} (latent attention) "
+                    f"needs qk_nope_head_dim, an even qk_rope_head_dim and "
+                    f"v_head_dim, got {heads}")
+            if self.qk_norm or self.qk_norm_per_head \
+                    or CONV in self.layer_types:
+                raise ValueError(
+                    "kv_lora_rank (latent attention) has its own norm on "
+                    "the latent and no per-head K: qk_norm / "
+                    "qk_norm_per_head do not apply, and it is not built "
+                    "beside conv layers")
+        elif any(heads):
+            raise ValueError(
+                "qk_nope_head_dim, qk_rope_head_dim and v_head_dim "
+                "describe latent attention: they need kv_lora_rank")
 
     @property
     def head_dim(self) -> int:
@@ -116,9 +153,12 @@ class LlamaConfig:
 
     @property
     def hybrid(self) -> bool:
-        """The layers differ in kind (operator or feed-forward): weights
-        are stacked per kind and the serving step runs the pattern."""
-        return bool(self.layer_types or self.n_dense_layers)
+        """The layers differ in kind (operator or feed-forward), or the
+        block has leaves the Llama tree has no place for (latent
+        attention, a shared expert): weights are stacked per kind and the
+        serving step runs the pattern."""
+        return bool(self.layer_types or self.n_dense_layers
+                    or self.kv_lora_rank or self.shared_ffn_dim)
 
     def layers_of(self, kind: str) -> Tuple[int, ...]:
         """Indices of the layers whose operator is ``kind``."""
@@ -206,7 +246,17 @@ def _init_hybrid_params(cfg: LlamaConfig, key: jax.Array) -> Params:
     Feed-forwards: "dense" (the leading n_dense_layers, or all without
     experts) and "moe" (the rest). The router's selection bias is float32
     whatever the weights are held in, and drawn, not zero: a zero bias
-    would leave the choice and the weights the same experts."""
+    would leave the choice and the weights the same experts.
+
+    With latent attention (kv_lora_rank) "attn" holds no wk / wv: wq
+    [d, H (nope + rope)], the down-projection w_kva [d, rank + rope], the
+    latent's norm kv_norm [rank], wo [H v, d], and the published
+    up-projection kv_b_proj [rank, H (nope + v)] split per head into w_uk
+    [H, nope, rank] and w_uv [H, rank, v]: the two batched products of the
+    absorbed form read them as they lie, with no transposed copy for XLA
+    to hoist out of the step scan (PERF.md, "Left by PR 27"). A shared
+    expert (shared_ffn_dim) is three more leaves of "moe", sliced by the
+    layer scan like the router; the routed experts stay closed over."""
     d, L, pd = cfg.dim, cfg.n_layers, cfg.param_dtype
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     keys = iter(jax.random.split(key, 24))
@@ -223,7 +273,16 @@ def _init_hybrid_params(cfg: LlamaConfig, key: jax.Array) -> Params:
 
     layers = {}
     A, C = len(cfg.layers_of(ATTENTION)), len(cfg.layers_of(CONV))
-    if A:
+    if A and cfg.kv_lora_rank:
+        r, dn, dr, dv = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+                         cfg.qk_rope_head_dim, cfg.v_head_dim)
+        layers["attn"] = {
+            "attn_norm": jnp.ones((A, d), pd),
+            "wq": dense(A, d, hq * (dn + dr)), "w_kva": dense(A, d, r + dr),
+            "kv_norm": jnp.ones((A, r), pd),
+            "w_uk": dense(A, hq, dn, r, fan_in=r),
+            "w_uv": dense(A, hq, r, dv), "wo": dense(A, hq * dv, d)}
+    elif A:
         layers["attn"] = {
             "attn_norm": jnp.ones((A, d), pd),
             "wq": dense(A, d, hq * hd), "wk": dense(A, d, hkv * hd),
@@ -251,6 +310,11 @@ def _init_hybrid_params(cfg: LlamaConfig, key: jax.Array) -> Params:
         if cfg.router_bias:
             layers["moe"]["router_bias"] = ROUTER_BIAS_STD * \
                 jax.random.normal(next(keys), (M, cfg.n_experts), jnp.float32)
+        if cfg.shared_ffn_dim:
+            fs = cfg.shared_ffn_dim
+            layers["moe"].update(
+                w_shared_gate=dense(M, d, fs), w_shared_up=dense(M, d, fs),
+                w_shared_down=dense(M, fs, d))
     params = {"embed": dense(cfg.vocab_size, d, fan_in=d),
               "layers": layers, "final_norm": jnp.ones((d,), pd)}
     if not cfg.tie_embeddings:
@@ -259,6 +323,15 @@ def _init_hybrid_params(cfg: LlamaConfig, key: jax.Array) -> Params:
 
 
 def _require_llama_block(cfg: LlamaConfig, what: str) -> None:
+    if cfg.kv_lora_rank or cfg.shared_ffn_dim:
+        raise NotImplementedError(
+            f"{what} is written for the Llama/Mistral block: kv_lora_rank "
+            f"(latent attention: qk_nope_head_dim, qk_rope_head_dim, "
+            f"v_head_dim) and shared_ffn_dim are served "
+            f"by llm/model.py only: the latent is a CACHE format, its "
+            f"absorbed products exist only where a cache is read, and the "
+            f"training side has no dropless experts for a shared expert "
+            f"to stand beside (ROADMAP R4)")
     if cfg.hybrid or cfg.qk_norm_per_head:
         raise NotImplementedError(
             f"{what} is written for the Llama/Mistral block, every layer "
@@ -315,6 +388,24 @@ def _rope(x, positions, theta):
     x1, x2 = x[..., :d2].astype(jnp.float32), x[..., d2:].astype(jnp.float32)
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1).astype(x.dtype)
+
+
+def _rope_pairs(x, positions, theta):
+    """Rotary embedding over ADJACENT pairs (2j, 2j+1) of the last axis,
+    pair j turning at theta^(-2j/D) (the published rope_interleave layout
+    of the deepseek_v3 family); x: [B, L, H, D_even], positions as
+    _rope's. As a complex number, pair j is multiplied by
+    exp(i * position * theta^(-2j/D))."""
+    d2 = x.shape[-1] // 2
+    freqs = theta ** (-jnp.arange(0, d2, dtype=jnp.float32) / d2)
+    ang = positions[..., None].astype(jnp.float32) * freqs  # [..., L, d2]
+    if ang.ndim == 2:
+        ang = ang[None]
+    cos, sin = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]
+    xp = x.astype(jnp.float32).reshape(x.shape[:-1] + (d2, 2))
+    x1, x2 = xp[..., 0], xp[..., 1]
+    return jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                     axis=-1).reshape(x.shape).astype(x.dtype)
 
 
 def _full_attention(q, k, v):

@@ -41,7 +41,15 @@ scalar-prefetch pattern:
     ``[L, P, Hkv, ps, D]`` with a layer index (scalar prefetch): the
     step programs carry the pool whole and never slice a layer out for
     a custom call (a copy of it), and ``write_ragged_kv`` updates it in
-    place (``_kv_write_pallas``, pool aliased in to out).
+    place (``_kv_write_pallas``, pool aliased in to out);
+  - a LATENT pool (``v_pages=None`` with a ``v_width``): the absorbed
+    form of latent attention (MLA) is multi-query attention over ONE kv
+    head whose row is the token's normed latent followed by its shared
+    rotary key part; the score runs over the whole row and the value is
+    the row's leading ``v_width`` values. There is no V leaf: the kernel
+    fetches a K block once, into one buffer, and takes the value as a
+    lane slice of it; the write kernel moves one leaf. With K and V pools
+    both kernels lower as they did before the form existed.
 
 The ``*_reference`` functions are the pure-JAX gather equivalents — the
 numerics oracles and the portable fallbacks on CPU test meshes.
@@ -96,7 +104,9 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
                                      sm_scale: Optional[float] = None,
                                      max_q_len: Optional[int] = None,
                                      decode_rows: int = 0,
-                                     layer=None) -> jax.Array:
+                                     layer=None,
+                                     v_width: Optional[int] = None
+                                     ) -> jax.Array:
     """Gather-based ragged paged attention (oracle + CPU fallback).
 
     q: [T, Hq, D]; k/v_pages: [P, Hkv, ps, D] (int8 when scales given);
@@ -111,6 +121,10 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
     each); the rest are prefill rows computed on ``max_q_len``-sized
     blocks (default T). Wrong hints that still satisfy the q_len bounds
     only cost time, never accuracy.
+
+    A latent pool: ``v_pages=None`` and ``v_width``; the value is the
+    leading ``v_width`` values of the K row and the result [T, Hq,
+    v_width].
     """
     T, Hq, D = q.shape
     R, max_pages = page_table.shape
@@ -123,14 +137,19 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
 
     # one page gather per row -> [R, Hkv, max_kv, D] fp32 (dequantized)
     kr = k_pages[at].astype(jnp.float32)         # [R, mp, Hkv, ps, D]
-    vr = v_pages[at].astype(jnp.float32)
+    if v_pages is not None:
+        vr = v_pages[at].astype(jnp.float32)
     if k_scale is not None:
         kr = kr * k_scale[at].astype(jnp.float32)[..., None]
         vr = vr * v_scale[at].astype(jnp.float32)[..., None]
     kr = kr.transpose(0, 2, 1, 3, 4).reshape(R, Hkv, max_kv, D)
-    vr = vr.transpose(0, 2, 1, 3, 4).reshape(R, Hkv, max_kv, D)
+    if v_pages is not None:
+        vr = vr.transpose(0, 2, 1, 3, 4).reshape(R, Hkv, max_kv, D)
+    else:
+        vr = kr[..., :v_width]
+    Dv = vr.shape[-1]                            # the result's width
 
-    out = jnp.zeros((T, Hq, D), jnp.float32)
+    out = jnp.zeros((T, Hq, Dv), jnp.float32)
     tkv = jnp.arange(max_kv)
 
     def _safe_softmax(s):
@@ -148,7 +167,7 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
         s = jnp.where(tkv[None, None, None, :] < vis[:, None, None, None],
                       s, _NEG_INF)
         od = jnp.einsum("rgqt,rgtd->rgqd", _safe_softmax(s), vr[:Rd])
-        od = od.reshape(Rd, Hq, D)
+        od = od.reshape(Rd, Hq, Dv)
         od = jnp.where((q_len[:Rd] > 0)[:, None, None], od, 0.0)
         out = out.at[idx].add(od)
 
@@ -167,11 +186,11 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
         s = jnp.where(tkv[None, None, None, None, :]
                       < vis[:, :, None, None, None], s, _NEG_INF)
         oc = jnp.einsum("rcgqt,rgtd->rcgqd", _safe_softmax(s), vr[Rd:])
-        oc = oc.reshape(-1, C, Hq, D)
+        oc = oc.reshape(-1, C, Hq, Dv)
         oc = jnp.where((cvec[None, :] < q_len[Rd:, None])[:, :, None, None],
                        oc, 0.0)
         dest = starts[:, None] + cvec[None, :]        # [Rp, C] < T + C
-        out = out + jnp.zeros((T + C, Hq, D),
+        out = out + jnp.zeros((T + C, Hq, Dv),
                               jnp.float32).at[dest].add(oc)[:T]
     return out.astype(q.dtype)
 
@@ -196,6 +215,11 @@ def _ragged_tiling(n_tokens: int, q_per_kv: int, page_size: int,
     tiles a third slower.
     """
     bq = min(128, pl.cdiv(n_tokens, 8) * 8) if n_tokens > 1 else 1
+    if bq * q_per_kv > 1024:
+        # many query heads on one KV head (the latent form: 32 on 1): a
+        # tile of 128 tokens would be a 4096-row operand and ~50 MB of
+        # VMEM; 1024 rows keep it where 8 heads x 128 tokens are
+        bq = max(8, 1024 // q_per_kv // 8 * 8)
     nq = pl.cdiv(n_tokens, bq)
     mrows = pl.cdiv(bq * q_per_kv, 16) * 16
     bk = 256 if bq > 1 else 512
@@ -204,8 +228,8 @@ def _ragged_tiling(n_tokens: int, q_per_kv: int, page_size: int,
 
 
 def _ragged_kernel(layer_ref, q_len_ref, kv_len_ref, pt_ref,   # prefetch
-                   q_ref, k_hbm, v_hbm, *rest, sm_scale, row0, bq, nq,
-                   has_scales):
+                   q_ref, k_hbm, *rest, sm_scale, row0, bq, nq,
+                   has_scales, v_width=None):
     """One grid step = one tile: ``bq`` query tokens of ONE row against
     that row's pages, a block of ``bkp`` pages a loop turn.
 
@@ -224,12 +248,24 @@ def _ragged_kernel(layer_ref, q_len_ref, kv_len_ref, pt_ref,   # prefetch
     vector op is batched over the KV heads: one traced op, unrolled by
     the compiler, which keeps all heads' matmuls and softmaxes in flight
     and the program small to trace and lower at start-up.
+
+    A latent pool (``v_width``): no v_hbm and no vbuf; the value is the
+    leading ``v_width`` lanes of the K block, read from the same buffer,
+    and o_ref / acc_ref are that wide.
     """
+    latent = v_width is not None
+    if not latent:
+        v_hbm, *rest = rest
     if has_scales:
         ks_ref, vs_ref, *rest = rest
-    o_ref, kbuf, vbuf, sem, ahead_ref, acc_ref, m_ref, l_ref = rest
-    Hkv, mrows, D = acc_ref.shape
-    _, _, bkp, ps, _ = kbuf.shape
+    if latent:
+        o_ref, kbuf, sem, ahead_ref, acc_ref, m_ref, l_ref = rest
+        pools = ((k_hbm, kbuf, 0),)
+    else:
+        o_ref, kbuf, vbuf, sem, ahead_ref, acc_ref, m_ref, l_ref = rest
+        pools = ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1))
+    Hkv, mrows, _ = acc_ref.shape
+    _, _, bkp, ps, D = kbuf.shape
     bk = bkp * ps
     max_pages = pt_ref.shape[1]
     cdt = q_ref.dtype                       # MXU operand dtype
@@ -258,7 +294,7 @@ def _ragged_kernel(layer_ref, q_len_ref, kv_len_ref, pt_ref,   # prefetch
         """Start (or wait for) the copies of block b's live pages."""
         def page(i, _):
             src = 0 if wait else pt_ref[row, b * bkp + i]
-            for hbm, buf, s in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
+            for hbm, buf, s in pools:
                 cp = pltpu.make_async_copy(
                     hbm.at[layer, src], buf.at[slot, :, i], sem.at[s, slot])
                 cp.wait() if wait else cp.start()
@@ -268,8 +304,8 @@ def _ragged_kernel(layer_ref, q_len_ref, kv_len_ref, pt_ref,   # prefetch
     @pl.when(t == 0)
     def _first():
         # a masked slot's p is 0, and 0 * (VMEM nobody wrote) may be NaN
-        kbuf[...] = jnp.zeros_like(kbuf)
-        vbuf[...] = jnp.zeros_like(vbuf)
+        for _, buf, _ in pools:
+            buf[...] = jnp.zeros_like(buf)
         ahead_ref[0] = 0                    # nobody started my first block
         ahead_ref[1] = 0                    # ... which goes to buffer 0
 
@@ -305,11 +341,16 @@ def _ragged_kernel(layer_ref, q_len_ref, kv_len_ref, pt_ref,   # prefetch
 
             copy_pages(row, n_pages, b, slot, wait=True)
             # every op below is batched over the KV heads
-            k, v = kbuf[slot], vbuf[slot]           # [Hkv, bkp, ps, D]
-            if has_scales:              # int8 values are exact in bf16
-                k, v = k.astype(jnp.float32), v.astype(jnp.float32)
-            k = k.reshape(Hkv, bk, D).astype(cdt)
-            v = v.reshape(Hkv, bk, D).astype(cdt)
+            if latent:
+                k = kbuf[slot].reshape(Hkv, bk, D).astype(cdt)
+                v = kbuf[slot, :, :, :, :v_width].reshape(
+                    Hkv, bk, v_width).astype(cdt)
+            else:
+                k, v = kbuf[slot], vbuf[slot]       # [Hkv, bkp, ps, D]
+                if has_scales:          # int8 values are exact in bf16
+                    k, v = k.astype(jnp.float32), v.astype(jnp.float32)
+                k = k.reshape(Hkv, bk, D).astype(cdt)
+                v = v.reshape(Hkv, bk, D).astype(cdt)
             s = lax.dot_general(
                 q_ref[0], k, (((2,), (2,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32) * sm_scale
@@ -357,12 +398,15 @@ def _scale_blocks(scale, layer, page_table, bk: int):
 def _ragged_rows_pallas(q, k_pages, v_pages, layer, page_table, q_start,
                         q_len, kv_len, k_scale, v_scale, *, row0: int,
                         n_rows: int, n_tokens: int, sm_scale: float,
-                        interpret: bool):
+                        interpret: bool, v_width: Optional[int] = None):
     """Attention of rows ``row0 : row0 + n_rows`` (each at most
     ``n_tokens`` query tokens) over layer ``layer`` ([1] int32) of the
     stacked pool -> [n_rows * nq * bq, Hq, D], row-major by (row, token);
-    slots past a row's q_len hold garbage or zeros."""
+    slots past a row's q_len hold garbage or zeros. A latent pool
+    (``v_pages`` None): the result is [..., v_width]."""
     T, Hq, D = q.shape
+    latent = v_pages is None
+    Dv = v_width if latent else D
     _, _, Hkv, ps, _ = k_pages.shape
     max_pages = page_table.shape[1]
     qpk = Hq // Hkv
@@ -383,9 +427,9 @@ def _ragged_rows_pallas(q, k_pages, v_pages, layer, page_table, q_start,
         return (row0 + t // nq, 0, 0, 0, 0)
 
     tile_spec = pl.BlockSpec((1, Hkv, mrows, D), tile_map)
-    in_specs = [tile_spec, pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY)]
-    operands = [qt, k_pages, v_pages]
+    pools = [k_pages] if latent else [k_pages, v_pages]
+    in_specs = [tile_spec] + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools)
+    operands = [qt] + pools
     has_scales = k_scale is not None
     if has_scales:
         ks = _scale_blocks(k_scale, layer[0], page_table, bk)
@@ -400,22 +444,24 @@ def _ragged_rows_pallas(q, k_pages, v_pages, layer, page_table, q_start,
     # headroom and no more: what the kernel is granted XLA takes from
     # its own use of VMEM around it (the next layer's weight prefetch)
     need = 4 * Hkv * mrows * D * q.dtype.itemsize \
-        + 4 * Hkv * bk * D * k_pages.dtype.itemsize \
-        + 3 * Hkv * mrows * 128 * 4 + 3 * Hkv * mrows * max(bk, 128) * 4
+        + 2 * len(pools) * Hkv * bk * D * k_pages.dtype.itemsize \
+        + Hkv * mrows * (2 * 128 + Dv) * 4 \
+        + 3 * Hkv * mrows * max(bk, 128) * 4
     out = pl.pallas_call(
         functools.partial(_ragged_kernel, sm_scale=sm_scale, row0=row0,
-                          bq=bq, nq=nq, has_scales=has_scales),
+                          bq=bq, nq=nq, has_scales=has_scales,
+                          v_width=v_width),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(n_tiles,),
             in_specs=in_specs,
-            out_specs=tile_spec,
-            scratch_shapes=[
-                kv_buf, kv_buf, pltpu.SemaphoreType.DMA((2, 2)),
+            out_specs=pl.BlockSpec((1, Hkv, mrows, Dv), tile_map),
+            scratch_shapes=[kv_buf] * len(pools) + [
+                pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.SMEM((2,), jnp.int32),
-                pltpu.VMEM((Hkv, mrows, D), jnp.float32), stat, stat],
+                pltpu.VMEM((Hkv, mrows, Dv), jnp.float32), stat, stat],
         ),
-        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qt.shape[:-1] + (Dv,), q.dtype),
         # half of every tile against a full page table: XLA's scheduler
         # places the next layer's weight prefetches by this and by the
         # VMEM limit (with no estimate, or 48 MB of VMEM, the mixed step
@@ -430,8 +476,8 @@ def _ragged_rows_pallas(q, k_pages, v_pages, layer, page_table, q_start,
             vmem_limit_bytes=max(need * 5 // 4, 16 << 20)),
         interpret=interpret,
     )(layer, q_len, kv_len, page_table, *operands)
-    out = out[:, :, :qpk * bq].reshape(n_tiles, Hkv, qpk, bq, D)
-    return out.transpose(0, 3, 1, 2, 4).reshape(n_tiles * bq, Hq, D)
+    out = out[:, :, :qpk * bq].reshape(n_tiles, Hkv, qpk, bq, Dv)
+    return out.transpose(0, 3, 1, 2, 4).reshape(n_tiles * bq, Hq, Dv)
 
 
 def _check_layer(k_pages, layer) -> None:
@@ -446,7 +492,8 @@ def _stacked(k_pages, v_pages, k_scale, v_scale, layer):
     stack of one (adding the axis moves nothing)."""
     _check_layer(k_pages, layer)
     if layer is None:
-        k_pages, v_pages = k_pages[None], v_pages[None]
+        k_pages = k_pages[None]
+        v_pages = None if v_pages is None else v_pages[None]
         if k_scale is not None:
             k_scale, v_scale = k_scale[None], v_scale[None]
         layer = 0
@@ -466,13 +513,14 @@ def _token_rows(q_start, q_len, T: int):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "sm_scale", "max_q_len", "decode_rows", "interpret"))
+    "sm_scale", "max_q_len", "decode_rows", "interpret", "v_width"))
 def _ragged_attention_pallas(q, k_pages, v_pages, page_table,
                              q_start, q_len, kv_len, k_scale, v_scale,
                              sm_scale: float,
                              max_q_len: Optional[int] = None,
                              decode_rows: int = 0,
-                             interpret: bool = False, layer=None):
+                             interpret: bool = False, layer=None,
+                             v_width: Optional[int] = None):
     """The blocked kernel over the static tiling the hints give: the
     first ``decode_rows`` rows as one-token tiles, the others as
     ceil(max_q_len / bq) tiles of bq tokens. XLA gathers q into tile
@@ -491,9 +539,11 @@ def _ragged_attention_pallas(q, k_pages, v_pages, page_table,
     call = functools.partial(
         _ragged_rows_pallas, q, k_pages, v_pages, layer, page_table,
         q_start, q_len, kv_len, k_scale, v_scale, sm_scale=sm_scale,
-        interpret=interpret)
+        interpret=interpret, v_width=v_width)
     owned, row, j = _token_rows(q_start, q_len, T)
-    out = jnp.zeros_like(q)
+    # a latent pool's result is [T, Hq, v_width]
+    out = jnp.zeros_like(q) if v_pages is not None else jnp.zeros(
+        q.shape[:-1] + (v_width,), q.dtype)
     if R - Rd:
         o = call(row0=Rd, n_rows=R - Rd, n_tokens=C)
         slots = o.shape[0] // (R - Rd)             # a row's tiles, in tokens
@@ -524,7 +574,8 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, q_start,
                            decode_rows: int = 0,
                            interpret: Optional[bool] = None,
                            impl: Optional[str] = None,
-                           layer=None) -> jax.Array:
+                           layer=None,
+                           v_width: Optional[int] = None) -> jax.Array:
     """Mixed prefill+decode attention over a ragged token batch in ONE
     dispatch. Dispatch rule (``_use_reference``): Pallas kernel on
     TPU, gather reference elsewhere; ``impl`` pins the choice
@@ -535,7 +586,18 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, q_start,
     whole stacked pool [L, P, Hkv, ps, D] with ``layer`` the index to
     read: the step programs pass the pool whole, because a layer sliced
     out for a custom call is a copy of it.
+
+    A LATENT pool (the absorbed form of latent attention): ``v_pages``
+    None and ``v_width`` the value's width. k_pages has ONE kv head, a
+    token's row is scored whole against q [T, Hq, D] and its leading
+    ``v_width`` values are the value: the result is [T, Hq, v_width].
     """
+    if (v_pages is None) != (v_width is not None):
+        raise ValueError("a pool with no v leaf goes with a v_width (the "
+                         "value is that many leading values of the K "
+                         "row), and only with it")
+    if v_pages is None and k_scale is not None:
+        raise ValueError("a latent pool has no int8 form")
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     if q.shape[1] % k_pages.shape[-3]:
@@ -549,11 +611,12 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, q_start,
         return ragged_paged_attention_reference(
             q, k_pages, v_pages, page_table, q_start, q_len, kv_len,
             k_scale=k_scale, v_scale=v_scale, sm_scale=sm_scale,
-            max_q_len=max_q_len, decode_rows=decode_rows, layer=layer)
+            max_q_len=max_q_len, decode_rows=decode_rows, layer=layer,
+            v_width=v_width)
     return _ragged_attention_pallas(
         q, k_pages, v_pages, page_table, q_start, q_len, kv_len,
         k_scale, v_scale, sm_scale, max_q_len, decode_rows,
-        bool(interpret), layer)
+        bool(interpret), layer, v_width)
 
 
 # --------------------------------------------------------------------------
@@ -580,17 +643,21 @@ _WRITE_GROUP = 16        # units a grid step reads, merges and writes back
 
 
 def _kv_write_kernel(layer_ref, page_ref, lo_ref, hi_ref,   # scalar prefetch
-                     kimg_ref, vimg_ref, k_in, v_in, k_out, v_out,
-                     kbuf, vbuf, sem):
+                     *refs):
     """One grid step = ``G`` units: read their pages from layer
     ``layer_ref[0]`` of the pool, take slots lo..hi-1 from the images,
-    write the pages back. k_in/v_in are k_out/v_out (aliased): the pool
-    is read and written through the output refs. Units with hi <= lo
-    are dead and move nothing."""
-    del k_in, v_in
-    G, Hkv, ps, D = kbuf.shape
+    write the pages back. ``refs``: for each leaf of the pool (K and V,
+    or the one leaf of a latent pool) its images, then the leaves in,
+    the leaves out and a page buffer each, then the semaphores. The
+    leaves in are the leaves out (aliased): the pool is read and written
+    through the output refs. Units with hi <= lo are dead and move
+    nothing."""
+    n = (len(refs) - 1) // 4
+    imgs, outs, bufs, sem = refs[:n], refs[2 * n:3 * n], refs[3 * n:-1], \
+        refs[-1]
+    G, Hkv, ps, D = bufs[0].shape
     layer, u0 = layer_ref[0], pl.program_id(0) * G
-    pairs = ((k_out, kbuf, kimg_ref, 0), (v_out, vbuf, vimg_ref, 1))
+    pairs = tuple((outs[i], bufs[i], imgs[i], i) for i in range(n))
 
     def each_live(fn):
         def unit(g, _):
@@ -663,8 +730,11 @@ def _kv_write_pallas(k_pages, v_pages, k_t, v_t, layer, token_page,
                      max_q_len: Optional[int] = None, decode_rows: int = 0,
                      interpret: bool = False):
     """``k_t``/``v_t`` [T, Hkv, D] (already in the pool's dtype) into
-    layer ``layer`` ([1] int32) of the stacked pool, in place."""
+    layer ``layer`` ([1] int32) of the stacked pool, in place; a tuple of
+    the leaves written. A latent pool: ``v_pages`` and ``v_t`` None."""
     T, Hkv, D = k_t.shape
+    pools = [k_pages] if v_pages is None else [k_pages, v_pages]
+    n = len(pools)
     ps = k_pages.shape[3]
     C = min(max_q_len if max_q_len is not None else T, T)
     page, lo, hi, tok = _write_units(
@@ -674,7 +744,7 @@ def _kv_write_pallas(k_pages, v_pages, k_t, v_t, layer, token_page,
     U = page.shape[0]
     G = min(_WRITE_GROUP, U)
     # page images, head-major like the pool: [U, Hkv, ps, D]
-    kimg, vimg = (a[tok].transpose(0, 2, 1, 3) for a in (k_t, v_t))
+    imgs = [a[tok].transpose(0, 2, 1, 3) for a in (k_t, v_t)[:n]]
 
     img_spec = pl.BlockSpec((G, Hkv, ps, D), lambda i, *_: (i, 0, 0, 0))
     pool_spec = pl.BlockSpec(memory_space=pl.ANY)
@@ -685,24 +755,25 @@ def _kv_write_pallas(k_pages, v_pages, k_t, v_t, layer, token_page,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(U // G,),
-            in_specs=[img_spec, img_spec, pool_spec, pool_spec],
-            out_specs=[pool_spec, pool_spec],
-            scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2,))],
+            in_specs=[img_spec] * n + [pool_spec] * n,
+            out_specs=[pool_spec] * n,
+            scratch_shapes=[buf] * n + [pltpu.SemaphoreType.DMA((2,))],
         ),
-        out_shape=[jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
-                   jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype)],
-        # operands count the scalar-prefetch arrays: the pools are 6 and 7
-        input_output_aliases={6: 0, 7: 1},
+        out_shape=[jax.ShapeDtypeStruct(a.shape, a.dtype) for a in pools],
+        # operands count the scalar-prefetch arrays: with K and V the
+        # pools are 6 and 7
+        input_output_aliases={4 + n + i: i for i in range(n)},
         cost_estimate=pl.CostEstimate(
             flops=0, transcendentals=0,
-            bytes_accessed=6 * U * Hkv * ps * D * k_pages.dtype.itemsize),
+            bytes_accessed=3 * n * U * Hkv * ps * D
+            * k_pages.dtype.itemsize),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             # both images twice (pipelined), both page buffers, and the
             # merge's temporaries
-            vmem_limit_bytes=max(12 * group_bytes, 16 << 20)),
+            vmem_limit_bytes=max(6 * n * group_bytes, 16 << 20)),
         interpret=interpret,
-    )(layer, page, lo, hi, kimg, vimg, k_pages, v_pages)
+    )(layer, page, lo, hi, *imgs, *pools)
 
 
 def write_ragged_kv(k_pages, v_pages, k_t, v_t, token_page, token_slot,
@@ -733,9 +804,16 @@ def write_ragged_kv(k_pages, v_pages, k_t, v_t, token_page, token_slot,
     scale leaves are read by XLA only and always take the scatter.
     Returns (k_pages, v_pages, k_scale, v_scale); scales pass through as
     None on fp pools.
+
+    A latent pool (one leaf): ``v_pages`` and ``v_t`` None, k_t [T, 1, W]
+    the tokens' rows; v_pages comes back None.
     """
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale must be given together")
+    if (v_pages is None) != (v_t is None) or (
+            v_pages is None and k_scale is not None):
+        raise ValueError("a latent pool is one leaf: no v_pages, no v_t, "
+                         "no scales")
     _check_layer(k_pages, layer)
     at = (token_page, slice(None), token_slot)
     if layer is not None:
@@ -746,21 +824,26 @@ def write_ragged_kv(k_pages, v_pages, k_t, v_t, token_page, token_slot,
         v_t, vs = quantize_kv(v_t)
         k_scale = k_scale.at[at].set(ks.astype(k_scale.dtype))
         v_scale = v_scale.at[at].set(vs.astype(v_scale.dtype))
-    k_t, v_t = k_t.astype(k_pages.dtype), v_t.astype(v_pages.dtype)
+    k_t = k_t.astype(k_pages.dtype)
+    if v_t is not None:
+        v_t = v_t.astype(v_pages.dtype)
     if _use_reference(impl, interpret):
         # advanced indices separated by a basic slice: the indexed
         # result is [T, Hkv, D]
         k_pages = k_pages.at[at].set(k_t)
-        v_pages = v_pages.at[at].set(v_t)
+        if v_t is not None:
+            v_pages = v_pages.at[at].set(v_t)
         return k_pages, v_pages, k_scale, v_scale
     if q_start is None or q_len is None:
         raise ValueError("the write kernel needs the rows' q_start/q_len")
     one_layer = layer is None
     k_pages, v_pages, _, _, layer = _stacked(k_pages, v_pages, None, None,
                                              layer)
-    k_pages, v_pages = _kv_write_pallas(
+    k_pages, *v_pages = _kv_write_pallas(
         k_pages, v_pages, k_t, v_t, layer, token_page, token_slot, q_start,
         q_len, max_q_len, decode_rows, bool(interpret))
+    v_pages = v_pages[0] if v_pages else None
     if one_layer:
-        k_pages, v_pages = k_pages[0], v_pages[0]
+        k_pages = k_pages[0]
+        v_pages = None if v_pages is None else v_pages[0]
     return k_pages, v_pages, k_scale, v_scale

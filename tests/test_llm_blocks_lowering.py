@@ -1,0 +1,141 @@
+"""A block lowers to the code of its own fields and to no other block's.
+
+`LlamaConfig` is the configuration of several blocks (Mistral, OLMoE, LFM2,
+Kanana-2), and one decoder body in llm/model.py follows its fields. A
+configuration that sets none of a block's fields must take none of that
+block's code: the tests here read the jaxprs of both step programs and of
+the page copy, on the kernel path and on the reference path, and the
+parameter and pool trees, and look for what only another block brings.
+
+As a script, `python3 tests/test_llm_blocks_lowering.py [checkout]` prints
+a digest of each of those texts for the checkout given (default: this
+one). Run it on two commits and compare the lines: a PR that must leave a
+block's programs alone shows it so (text for text the same jaxpr, the
+kernels' bodies included), and its PERF.md entry keeps the finding; no
+digest is kept here, where every later change to model.py or a kernel
+would have to overwrite it.
+"""
+
+import hashlib
+import json
+import os
+import sys
+
+ROOT = os.path.abspath(sys.argv[1]) if __name__ == "__main__" \
+    and len(sys.argv) > 1 else \
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import pytest  # noqa: E402
+
+from ray_tpu.llm import model as M  # noqa: E402
+from ray_tpu.llm.cache import make_kv_cache  # noqa: E402
+from ray_tpu.models.llama import LlamaConfig, init_params  # noqa: E402
+
+_LFM2_PATTERN = ["conv", "conv"] + ["full_attention", "conv", "conv",
+                                    "conv"] * 2
+BLOCKS = {
+    "mistral": dict(n_layers=2),
+    "olmoe": dict(n_layers=2, n_kv_heads=8, n_experts=8, experts_per_token=2,
+                  qk_norm=True, tie_embeddings=False),
+    "lfm2": dict(n_layers=10, n_heads=8, n_kv_heads=2, ffn_dim=32,
+                 dense_ffn_dim=96, n_dense_layers=2, n_experts=8,
+                 experts_per_token=2, norm_topk_prob=True,
+                 layer_types=_LFM2_PATTERN, qk_norm_per_head=True,
+                 router_score="sigmoid", router_bias=True, router_eps=1e-6),
+    "kanana": dict(n_layers=3, n_heads=4, n_kv_heads=4, ffn_dim=16,
+                   dense_ffn_dim=96, n_dense_layers=1, n_experts=8,
+                   experts_per_token=3, norm_topk_prob=True,
+                   router_score="sigmoid", router_bias=True,
+                   router_eps=1e-20, router_scale=2.448, kv_lora_rank=32,
+                   qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+                   shared_ffn_dim=32, tie_embeddings=False)}
+
+#: what only a block's own fields may bring into a program's text or
+#: trees: named scopes, parameter leaves, and the shape of the pool
+ONLY_LATENT = ("mla_proj", "w_kva", "w_uk", "w_uv", "kv_norm")
+ONLY_SHARED = ("moe_shared", "w_shared_gate", "w_shared_up", "w_shared_down")
+ONLY_CONV = ("short_conv", "w_conv", "conv_norm")
+
+
+def _text(jaxpr) -> str:
+    """The jaxpr with each equation's named scopes beside it."""
+    return jaxpr.pretty_print(name_stack=True)
+
+
+def lowered(block: str) -> dict:
+    """name -> text: the parameter tree, and for the reference and the
+    kernel path the pool's tree and the jaxprs of the mixed step, the
+    decode loop and the page copy, of ``block`` at tiny widths."""
+    cfg = LlamaConfig.tiny(**BLOCKS[block])
+    params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    out = {f"{block}.params": str(jax.tree.map(
+        lambda a: (a.shape, str(a.dtype)), params))}
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)        # noqa: E731
+    T, R, mp = 12, 5, 4
+    for impl in ("reference", "kernel"):
+        kv = jax.eval_shape(lambda: make_kv_cache(
+            cfg, 16, 8, max_batch=3, lane_pad=impl == "kernel"))
+        extra = (i32(T),) if cfg.layer_types else ()
+        out[f"{block}.{impl}.pool"] = str(jax.tree.map(
+            lambda a: (a.shape, str(a.dtype)), kv))
+        out[f"{block}.{impl}.step"] = _text(jax.make_jaxpr(
+            lambda *a: M._ragged_step_body(
+                *a[:10], cfg=cfg, paged_impl=impl, max_q_len=8,
+                decode_rows=3,
+                token_state=a[10] if len(a) > 10 else None))(
+            params, i32(T), i32(T), i32(T), i32(T), i32(R, mp), i32(R),
+            i32(R), i32(R), kv, *extra))
+        out[f"{block}.{impl}.loop"] = _text(jax.make_jaxpr(
+            lambda p, t, pos, kv, pt, sl: M._ragged_decode_loop(
+                p, t, pos, kv, pt, sl, 4, cfg, None, impl))(
+            params, i32(3), i32(3), kv, i32(3, mp), i32(3)))
+        out[f"{block}.{impl}.copy"] = _text(jax.make_jaxpr(
+            M._copy_page_body)(kv, i32(), i32()))
+    return out
+
+
+@pytest.mark.parametrize("block", sorted(BLOCKS))
+def test_a_block_takes_no_other_blocks_code(block):
+    """None of the latent block's or the shared expert's scopes, leaves or
+    pool layout in a configuration that sets none of their fields (and no
+    conv operator where no layer is one): its pool keeps a `v` leaf beside
+    `k`, both per KV head."""
+    cfg = LlamaConfig.tiny(**BLOCKS[block])
+    texts = lowered(block)
+    everything = "\n".join(texts.values())
+    if cfg.kv_lora_rank:
+        # the control: the block that HAS the fields shows every word, so
+        # the search below finds what it looks for
+        missing = [w for w in ONLY_LATENT + ONLY_SHARED
+                   if w not in everything]
+        assert not missing, f"the latent block's texts lack {missing}"
+        assert not [w for w in ONLY_CONV if w in everything]
+        assert all("'v'" not in texts[f"{block}.{impl}.pool"]
+                   for impl in ("reference", "kernel"))
+        return
+    absent = ONLY_LATENT + ONLY_SHARED + (() if cfg.layer_types
+                                          else ONLY_CONV)
+    for name, text in texts.items():
+        found = [word for word in absent if word in text]
+        assert not found, f"{name} holds {found}"
+    for impl in ("reference", "kernel"):
+        kv = jax.eval_shape(lambda: make_kv_cache(
+            cfg, 16, 8, max_batch=3, lane_pad=impl == "kernel"))
+        assert kv["k"].shape == kv["v"].shape
+        assert kv["k"].shape[2] == cfg.n_kv_heads
+
+
+if __name__ == "__main__":
+    print(json.dumps({"jax": jax.__version__, "root": ROOT}))
+    for block in sorted(BLOCKS):
+        try:
+            texts = lowered(block)
+        except ValueError as e:      # a checkout from before the block
+            print(block, "not built here:", str(e)[:60])
+            continue
+        for name, text in texts.items():
+            print(name, hashlib.sha256(text.encode()).hexdigest()[:16])

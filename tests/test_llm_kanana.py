@@ -1,0 +1,383 @@
+"""The Kanana-2 block in the serving engine: latent attention (MLA) over a
+paged LATENT pool of one leaf, read once for all heads in the absorbed
+form, a shared expert beside routed experts (sigmoid scores, selection
+bias, renormalised, scaled by 2.448), one leading dense layer, an untied
+head, through the one ragged step and the decode loop, against the
+benchmark's plain reference (benchmark/reference_kanana.py: the PUBLISHED,
+expanded form) on seeded weights. Tiny widths on the CPU, float32 compute.
+
+TOL: everything runs in float32 here (cfg.dtype and the reference), so the
+two sides differ by summation order only (the absorbed products associate
+differently from the expanded ones): ~1e-6 on unit-variance logits. 1e-4
+leaves two orders of room and still fails a bf16 computation (~1e-2:
+test_absorbed_attention_equals_the_expanded_form runs one), a value taken
+from the wrong lanes of the row, a rotary over half-split pairs, a shared
+expert counted per routed expert or not at all, a routing scale left out,
+a prefix hit or a copy on write that misses the latent leaf (whole logits,
+or tenths).
+"""
+
+import dataclasses
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark import reference_kanana as ref  # noqa: E402
+from benchmark import reference_lfm2  # noqa: E402
+from ray_tpu.llm import InferenceEngine  # noqa: E402
+from ray_tpu.llm import model as M  # noqa: E402
+from ray_tpu.llm import tp as TP  # noqa: E402
+from ray_tpu.llm.cache import (kv_cache_tag, latent_row_width,  # noqa: E402
+                               make_kv_cache, prefix_cache_supported)
+from ray_tpu.models import llama  # noqa: E402
+from ray_tpu.models.llama import LlamaConfig, init_params  # noqa: E402
+from ray_tpu.ops import paged_attention as pa  # noqa: E402
+
+TOL = 1e-4
+D, E, K, H = 64, 8, 3, 4
+RANK, NOPE, ROPE, V = 32, 16, 8, 16
+KANANA = dict(dim=D, n_layers=3, n_heads=H, n_kv_heads=H, ffn_dim=16,
+              dense_ffn_dim=96, n_dense_layers=1, n_experts=E,
+              experts_per_token=K, norm_topk_prob=True,
+              router_score="sigmoid", router_bias=True, router_eps=1e-20,
+              router_scale=2.448, kv_lora_rank=RANK, qk_nope_head_dim=NOPE,
+              qk_rope_head_dim=ROPE, v_head_dim=V,
+              shared_ffn_dim=32, tie_embeddings=False, norm_eps=1e-6,
+              dtype=jnp.float32)
+ENGINE = dict(page_size=8, total_pages=64, max_batch=4, max_seq_len=128,
+              prefill_chunk=16, prefill_rows=2, decode_chunk=4, seed=3)
+
+
+def _run(eng):
+    done = {}
+    for _ in range(400):
+        done.update(eng.step())
+        if not eng.has_work():
+            return done
+    raise AssertionError("engine did not drain")
+
+
+def _worst_gap(eng, cfg, prompt, served, pad_to=96):
+    """How far under the reference's top LOGIT the served tokens sit,
+    teacher-forced over prompt + served: logits, not token identity."""
+    got = ref.score_greedy(eng.params, ref.dims_of(cfg), list(prompt),
+                           list(served), pad_to)
+    return max(got["gap"])
+
+
+def _seeded(cfg, seed=5):
+    """Weights whose norms are not ones: norms of ones would hide a norm
+    that is skipped, misplaced or over the wrong part of the row."""
+    params = init_params(cfg, jax.random.PRNGKey(seed))
+    for kind, stack in params["layers"].items():
+        for k in stack:
+            if k.endswith("norm"):
+                stack[k] = 1.0 + 0.5 * jax.random.normal(
+                    jax.random.PRNGKey(len(kind + k)), stack[k].shape)
+    return params
+
+
+@pytest.fixture(scope="module")
+def kanana():
+    jax.clear_caches()
+    cfg = LlamaConfig.tiny(**KANANA)
+    return cfg, InferenceEngine(cfg, _seeded(cfg), **ENGINE)
+
+
+# ------------------------------------------------------------ the operators
+
+def test_adjacent_pair_rotary_is_a_complex_rotation():
+    """Pair j of the last axis, as the complex number x[2j] + i x[2j+1],
+    is multiplied by exp(i * position * theta^(-2j/D)): the program's
+    _rope_pairs and the reference's rope_pairs against that statement."""
+    theta, S, Hh, Dr = 1e4, 11, 3, 8
+    x = np.asarray(jax.random.normal(jax.random.PRNGKey(0), (S, Hh, Dr)))
+    pos = np.arange(5, 5 + S)
+    z = x[..., 0::2] + 1j * x[..., 1::2]
+    ang = pos[:, None, None] * theta ** (-2.0 * np.arange(Dr // 2) / Dr)
+    want = z * np.exp(1j * ang)
+    want = np.stack([want.real, want.imag], axis=-1).reshape(S, Hh, Dr)
+    got = llama._rope_pairs(jnp.asarray(x)[None], jnp.asarray(pos), theta)[0]
+    assert float(np.abs(np.asarray(got) - want).max()) < 1e-5
+    # the reference's positions start at 0
+    want0 = z * np.exp(1j * np.arange(S)[:, None, None]
+                       * theta ** (-2.0 * np.arange(Dr // 2) / Dr))
+    want0 = np.stack([want0.real, want0.imag], axis=-1).reshape(S, Hh, Dr)
+    got0 = ref.rope_pairs(jnp.asarray(x), theta)
+    assert float(np.abs(np.asarray(got0) - want0).max()) < 1e-5
+    # and it is NOT the half-split pairing of the other blocks
+    half = llama._rope(jnp.asarray(x)[None], jnp.asarray(pos), theta)[0]
+    assert float(np.abs(np.asarray(half) - want).max()) > 0.1
+
+
+def _one_layer(cfg, S, seed=2):
+    params = _seeded(cfg, seed)
+    lp = {k: w[0] for k, w in params["layers"]["attn"].items()}
+    x = jax.random.normal(jax.random.PRNGKey(seed + 1), (1, S, cfg.dim))
+    return lp, x.astype(cfg.dtype)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, TOL),
+                                       (jnp.bfloat16, None)])
+def test_absorbed_attention_equals_the_expanded_form(dtype, tol):
+    """The program's operator (w_uk folded into the query, the kernel's
+    reference over the latent rows, w_uv on the way out) against the
+    reference's expanded attention (the latent up-projected to per-head
+    keys and values) on the same weights: one prompt of 21 tokens as one
+    chunk row over three pages. In float32 they agree to TOL; the same
+    operator computed in bf16 is off by two orders more, which TOL
+    therefore refuses."""
+    cfg = LlamaConfig.tiny(**{**KANANA, "dtype": dtype})
+    S, ps = 21, 8
+    lp, x = _one_layer(cfg, S)
+    kv = make_kv_cache(cfg, 8, ps)
+    pos = jnp.arange(S, dtype=jnp.int32)
+    pages = jnp.asarray([3, 5, 6], jnp.int32)
+    got, kv = M._latent_attention(
+        lp, 0, x, kv, cfg, pos, pages[pos // ps], pos % ps, pages[None],
+        jnp.zeros(1, jnp.int32), jnp.full(1, S, jnp.int32),
+        jnp.full(1, S, jnp.int32),
+        dict(max_q_len=S, decode_rows=0, impl="reference"))
+    f32 = jnp.float32
+    lp32 = {k: w.astype(f32) for k, w in lp.items()}
+    with jax.default_matmul_precision("highest"):
+        z = reference_lfm2._rmsnorm(x[0].astype(f32), lp32["attn_norm"],
+                                    cfg.norm_eps)
+        want = x[0].astype(f32) + ref.attention(z, lp32, ref.dims_of(cfg))
+    err = float(jnp.abs(got[0].astype(f32) - want).max())
+    if tol is not None:
+        assert err < tol, err
+    else:
+        assert err > 20 * TOL, err          # bf16 where float32 is stated
+    # what is cached: the normed latent, then the rotated shared key part
+    row = np.asarray(kv["k"][0, 3, 0, 2]).astype(np.float32)
+    assert row.shape == (RANK + ROPE,)
+
+
+@pytest.mark.parametrize("what", ["attention", "write"])
+def test_latent_kernels_in_interpret_mode(what):
+    """The kernel form with V inside the K block (one fetch, one buffer)
+    and the one-leaf write kernel, through the Pallas interpreter, against
+    the gather reference and the scatter: mixed decode rows and chunk rows,
+    a chunk that starts mid-page, an empty row, a row past its hint."""
+    ps, P, mp, W, vw, Hq = 8, 25, 6, 48, 32, 4
+    ks = jax.random.split(jax.random.PRNGKey(7), 4)
+    pool = jax.random.normal(ks[0], (2, P, 1, ps, W), jnp.float32)
+    page_table = jnp.asarray(
+        np.random.default_rng(1).permutation(np.arange(1, P))[:4 * mp]
+        .reshape(4, mp), jnp.int32)
+    # rows: two decode rows (one empty), two chunk rows
+    q_len = jnp.asarray([1, 0, 13, 5], jnp.int32)
+    kv_len = jnp.asarray([19, 0, 30, 5], jnp.int32)
+    q_start = jnp.asarray([0, 1, 2, 15], jnp.int32)
+    T = 20
+    q = jax.random.normal(ks[1], (T, Hq, W), jnp.float32)
+    hints = dict(max_q_len=13, decode_rows=2, layer=1)
+    if what == "attention":
+        want = pa.ragged_paged_attention_reference(
+            q, pool, None, page_table, q_start, q_len, kv_len, sm_scale=0.2,
+            v_width=vw, **hints)
+        got = pa.ragged_paged_attention(
+            q, pool, None, page_table, q_start, q_len, kv_len, sm_scale=0.2,
+            v_width=vw, interpret=True, **hints)
+        assert got.shape == (T, Hq, vw)
+        assert float(jnp.abs(got - want).max()) < 1e-5
+        # the value is the row's leading lanes, not a second leaf
+        other = pa.ragged_paged_attention_reference(
+            q, pool, pool[..., ::-1], page_table, q_start, q_len, kv_len,
+            sm_scale=0.2, **hints)
+        assert float(jnp.abs(other[..., :vw] - want).max()) > 0.1
+        return
+    rows = jax.random.normal(ks[2], (T, 1, W), jnp.float32)
+    # each token's destination: its row's pages from its position on
+    tok_row = np.asarray([0] + [2] * 13 + [3] * 5 + [1])       # token 19: pad
+    tok_pos = np.asarray([18] + list(range(17, 30)) + list(range(5)) + [0])
+    token_page = np.asarray(page_table)[tok_row, tok_pos // ps]
+    token_page[19] = 0                                         # scratch
+    token_slot = tok_pos % ps
+    q_start_w = jnp.asarray([0, 19, 1, 14], jnp.int32)
+    args = (jnp.asarray(token_page, jnp.int32),
+            jnp.asarray(token_slot, jnp.int32))
+    want, none, _, _ = pa.write_ragged_kv(
+        pool, None, rows, None, *args, layer=1, impl="reference")
+    got, none2, _, _ = pa.write_ragged_kv(
+        pool, None, rows, None, *args, layer=1, q_start=q_start_w,
+        q_len=q_len, max_q_len=13, decode_rows=2, interpret=True)
+    assert none is None and none2 is None
+    live = np.ones(P, bool)
+    live[0] = False                     # the scratch page: garbage
+    assert np.array_equal(np.asarray(got)[:, live], np.asarray(want)[:, live])
+    assert not np.array_equal(np.asarray(got)[1, live],
+                              np.asarray(pool)[1, live])
+    assert np.array_equal(np.asarray(got)[0], np.asarray(pool)[0])
+
+
+def test_shared_expert_counted_once_beside_the_scaled_routed_sum():
+    """One expert layer of the program (ops/moe.py's dropless layer, then
+    the shared SwiGLU on the same normed input) against the reference
+    layer: routed sum scaled by 2.448 + the shared expert ONCE; without
+    the scale, or with the shared expert left out, it is off by tenths."""
+    cfg = LlamaConfig.tiny(**KANANA)
+    params = _seeded(cfg)
+    moe = params["layers"]["moe"]
+    lp = {k: w[1] for k, w in moe.items() if k not in M._EXPERT_LEAVES}
+    experts = {k: moe[k] for k in M._EXPERT_LEAVES}
+    T = 24
+    x = jax.random.normal(jax.random.PRNGKey(4), (1, T, D))
+    valid = jnp.ones(T, bool)
+    with jax.default_matmul_precision("highest"):
+        got, counters = M._moe_mlp(lp, experts, 1, x, valid, cfg,
+                                   "reference")
+        z = reference_lfm2._rmsnorm(x[0], lp["mlp_norm"], cfg.norm_eps)
+        how = dict(score="sigmoid", eps=1e-20)
+        routed, _ = reference_lfm2.expert_layer(
+            z, lp["router"], lp["router_bias"], experts["w_gate"],
+            experts["w_up"], experts["w_down"], K, True, layer=1,
+            scale=2.448, **how)
+        shared = (jax.nn.silu(z @ lp["w_shared_gate"])
+                  * (z @ lp["w_shared_up"])) @ lp["w_shared_down"]
+        unscaled, _ = reference_lfm2.expert_layer(
+            z, lp["router"], lp["router_bias"], experts["w_gate"],
+            experts["w_up"], experts["w_down"], K, True, layer=1, **how)
+    want = x[0] + routed + shared
+    assert float(jnp.abs(got[0] - want).max()) < TOL
+    assert float(jnp.abs(routed - 2.448 * unscaled).max()) < TOL
+    assert float(jnp.abs(got[0] - (x[0] + routed)).max()) > 0.05
+    assert float(jnp.abs(got[0] - (x[0] + unscaled + shared)).max()) > 0.05
+    assert int(counters[0]) == T * K
+
+
+# ------------------------------------------------- the served path, end to end
+
+def test_served_path_matches_the_reference_across_chunk_boundaries(kanana):
+    """Prefill in chunks of 16 (a prompt of 57 crosses three boundaries
+    and starts its last chunk mid-page), then decode through the latent
+    pool, several sequences sharing steps: every served token's logit in
+    the reference's full forward is its top logit to TOL."""
+    cfg, eng = kanana
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(0, 256, n).tolist() for n in (57, 9, 23, 40)]
+    rids = [eng.add_request(p, 12) for p in prompts]
+    done = _run(eng)
+    for p, r in zip(prompts, rids):
+        assert len(done[r]) == 12
+        assert _worst_gap(eng, cfg, p, done[r]) < TOL
+    assert eng.compiled_step_programs() <= 3
+    assert eng.stats["moe_pairs"] > 0
+
+
+def test_prefix_hit_and_copy_on_write_on_the_latent_leaf(kanana):
+    """Pages are this block's only state, so the prefix cache is ON: the
+    same page-aligned prompt again takes a full hit, whose last token
+    lands inside a shared page (copy on write), and a longer prompt with
+    the same first pages takes a partial hit: both equal the reference."""
+    cfg, eng = kanana
+    assert prefix_cache_supported(cfg) and eng.prefix is not None
+    rng = np.random.default_rng(3)
+    base = rng.integers(0, 256, 32).tolist()          # 4 whole pages
+    first = eng.generate(base, 6)
+    before = dict(eng.stats)
+    again = eng.generate(base, 6)
+    assert eng.stats["cached_tokens"] - before["cached_tokens"] == 31
+    assert eng.stats["cow_copies"] == before["cow_copies"] + 1
+    assert again == first
+    assert _worst_gap(eng, cfg, base, again) < TOL
+    longer = base + rng.integers(0, 256, 13).tolist()
+    before = dict(eng.stats)
+    served = eng.generate(longer, 8)
+    assert eng.stats["cached_tokens"] - before["cached_tokens"] == 32
+    assert _worst_gap(eng, cfg, longer, served) < TOL
+
+
+def test_engine_preemption_gives_the_uninterrupted_continuation():
+    cfg = LlamaConfig.tiny(**KANANA)
+    params = _seeded(cfg)
+    small = InferenceEngine(cfg, params, **{
+        **ENGINE, "page_size": 4, "total_pages": 10, "max_seq_len": 32})
+    roomy = InferenceEngine(cfg, params, **{
+        **ENGINE, "page_size": 4, "max_seq_len": 32})
+    prompts = [list(range(1, 9)), list(range(3, 11))]
+    rids = [small.add_request(p, 16) for p in prompts]
+    done = _run(small)
+    assert small.stats["preemptions"] >= 1
+    for p, r in zip(prompts, rids):
+        assert done[r] == roomy.generate(p, 16)
+        assert _worst_gap(small, cfg, p, done[r], pad_to=32) < TOL
+
+
+# -------------------------------------------------------- pool, report, tags
+
+def test_latent_pool_is_one_leaf_and_the_engine_reports_it(kanana):
+    cfg, eng = kanana
+    kv = make_kv_cache(cfg, 16, 8)
+    assert set(kv) == {"k"}
+    assert kv["k"].shape == (3, 16, 1, 8, RANK + ROPE)
+    # on a TPU the row is held in whole lanes: 576 -> 640
+    wide = dataclasses.replace(cfg, kv_lora_rank=512, qk_rope_head_dim=64)
+    assert latent_row_width(wide) == 576
+    assert latent_row_width(wide, lane_pad=True) == 640
+    assert make_kv_cache(wide, 4, 16, lane_pad=True)["k"].shape[-1] == 640
+    with pytest.raises(ValueError, match="int8"):
+        make_kv_cache(cfg, 16, 8, kv_dtype="int8")
+    plain = LlamaConfig.tiny(dtype=jnp.float32)
+    assert kv_cache_tag(cfg, None) != kv_cache_tag(plain, None)
+    assert "latent" in kv_cache_tag(cfg, None)
+    report = eng.device_report()
+    row_bytes = (RANK + ROPE) * 4
+    assert report["kv_row_width"] == RANK + ROPE
+    assert report["kv_token_layer_bytes"] == row_bytes
+    assert report["kv_bytes"] == 3 * 64 * 8 * row_bytes
+    assert eng.stats["kv_token_layer_bytes"] == row_bytes
+    dense = InferenceEngine(plain, **ENGINE)
+    assert "kv_row_width" not in dense.stats
+    assert dense.device_report()["kv_token_layer_bytes"] \
+        == 2 * plain.n_kv_heads * plain.head_dim * 4
+
+
+# ------------------------------------------------ what refuses the new fields
+
+@pytest.mark.parametrize("what", ["forward", "param_specs", "num_params",
+                                  "validate_tp"])
+def test_training_side_and_tp_refuse_the_block_by_name(what):
+    cfg = LlamaConfig.tiny(**KANANA)
+    params = None
+    if what == "validate_tp":
+        call = lambda: TP.validate_tp(cfg, 2)                  # noqa: E731
+    elif what == "forward":
+        call = lambda: llama.forward(                          # noqa: E731
+            params, jnp.zeros((1, 4), jnp.int32), cfg)
+    else:
+        call = lambda: getattr(llama, what)(cfg)               # noqa: E731
+    with pytest.raises(NotImplementedError, match="kv_lora_rank") as e:
+        call()
+    assert "shared_ffn_dim" in str(e.value)
+    shared_only = {k: v for k, v in KANANA.items() if k not in (
+        "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
+        "v_head_dim")}
+    with pytest.raises(NotImplementedError, match="shared_ffn_dim"):
+        TP.validate_tp(LlamaConfig.tiny(**shared_only), 2)
+
+
+@pytest.mark.parametrize("over,match", [
+    (dict(kv_lora_rank=32), "qk_nope_head_dim"),
+    (dict(kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=7,
+          v_head_dim=16), "even qk_rope_head_dim"),
+    (dict(qk_rope_head_dim=8), "kv_lora_rank"),
+    (dict(v_head_dim=16), "kv_lora_rank"),
+    (dict(shared_ffn_dim=32), "n_experts"),
+    (dict(kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+          v_head_dim=16, qk_norm=True), "qk_norm"),
+    (dict(kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+          v_head_dim=16, n_layers=2,
+          layer_types=["conv", "full_attention"]), "conv layers")])
+def test_config_refuses_what_it_cannot_build(over, match):
+    with pytest.raises(ValueError, match=match):
+        LlamaConfig.tiny(**over)

@@ -379,3 +379,35 @@ def test_step_programs_update_the_pool_in_place(chip, program, widths):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= pool_bytes
     assert mem.temp_size_in_bytes - weight_copies < pool_bytes // 2
+
+
+def test_latent_kernels_compile_at_kanana2_shapes(chip):
+    """The kernel form of latent attention (MLA, absorbed): ONE kv head, 32
+    query heads, rows of 640 lanes (576 held in whole lanes) whose leading
+    512 are the value, no V leaf: the mixed step's shape (48 decode rows +
+    2 chunk rows of 512) and the one-leaf write, for a v5e. A row of 576
+    is refused by Mosaic ("must be aligned to tiling (128)"), which is why
+    the pool pads it."""
+    L, P, ps, W, vw, hq = 2, 512, 16, 640, 512, 32
+    T, R, mp = 48 + 2 * 512, 50, 608
+    pool = _sds(chip, (L, P, 1, ps, W), jnp.bfloat16)
+    row = _sds(chip, (R,), jnp.int32)
+    layer = _sds(chip, (), jnp.int32)
+    attn = pa._ragged_attention_pallas.lower(
+        _sds(chip, (T, hq, W), jnp.bfloat16), pool, None,
+        _sds(chip, (R, mp), jnp.int32), row, row, row, None, None,
+        sm_scale=192 ** -0.5, max_q_len=512, decode_rows=48, layer=layer,
+        v_width=vw)
+    assert _kernel_calls(attn) == 2          # chunk tiles, one-token tiles
+    tok = _sds(chip, (T,), jnp.int32)
+    write = pa._kv_write_pallas.lower(
+        pool, None, _sds(chip, (T, 1, W), jnp.bfloat16), None,
+        _sds(chip, (1,), jnp.int32), tok, tok, row, row, max_q_len=512,
+        decode_rows=48)
+    assert _kernel_calls(write) == 1
+    narrow = _sds(chip, (L, P, 1, ps, 576), jnp.bfloat16)
+    with pytest.raises(Exception, match="aligned to tiling"):
+        pa._kv_write_pallas.lower(
+            narrow, None, _sds(chip, (T, 1, 576), jnp.bfloat16), None,
+            _sds(chip, (1,), jnp.int32), tok, tok, row, row, max_q_len=512,
+            decode_rows=48).compile()
