@@ -199,8 +199,14 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
 _MASK = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
+#: the latent one-token tile: bytes of a KV block, and page copies started
+#: (or waited for) a turn of the page walk's loop (PERF.md, PR 34)
+_LATENT_BLOCK_BYTES = 2048 * 1280
+_LATENT_WALK_UNROLL = 16
+
+
 def _ragged_tiling(n_tokens: int, q_per_kv: int, page_size: int,
-                   max_pages: int):
+                   max_pages: int, latent_row_bytes: Optional[int] = None):
     """Static tiling of rows that hold at most ``n_tokens`` query tokens.
 
     Returns (bq, nq, mrows, bkp): a row is ``nq`` tiles of ``bq`` tokens;
@@ -208,11 +214,25 @@ def _ragged_tiling(n_tokens: int, q_per_kv: int, page_size: int,
     (``bq * q_per_kv`` rows, head-major, padded to the bf16 sublane
     packing); a KV block is ``bkp`` pages. Chunk rows take MXU-sized
     tiles of 128 tokens against blocks of 256 kv slots; one-token rows
-    are bound by the page copies, so they take blocks of 512 and fewer
-    loop turns. Measured on a v5e at Mistral-7B head shapes (PERF.md,
-    PR 25): 128- and 512-slot blocks for chunks and 256- and 1024-slot
-    blocks for one-token rows were within a fifth of these, 256-token
-    tiles a third slower.
+    take blocks of 512 and fewer loop turns. Measured on a v5e at
+    Mistral-7B head shapes (PERF.md, PR 25): 128- and 512-slot blocks for
+    chunks and 256- and 1024-slot blocks for one-token rows were within a
+    fifth of these, 256-token tiles a third slower. At those shapes a
+    block of 512 slots is 2-4 MB and a turn costs ~1 us beyond its read
+    (70-84 % of the read rate; ledger, PR 33).
+
+    ``latent_row_bytes`` (a latent pool: ONE kv head, one leaf, a slot
+    that many bytes): 512 slots of 1280 B are 0.66 MB, read in 0.8 us,
+    and a one-token turn took 2.0 us: 1.3 us the page walk (32 copies of
+    20 KB started and waited for one by one) and then 0.8 us the two
+    products and the softmax, one after the other (my chip runs, PR 34:
+    the kernel alone at Kanana-2's shape, ~466 turns a call: copies only
+    0.61 ms, compute only 0.36, both 0.93, against a read of 0.35). So its one-token tile takes blocks of _LATENT_BLOCK_BYTES
+    (2048 slots of 1280 B: the compute's fixed cost a turn falls by a
+    third) and _ragged_kernel walks their pages _LATENT_WALK_UNROLL to a
+    loop turn: 0.53 ms a call; 1024- and 4096-slot blocks 0.56 and 0.55,
+    walks of 8 and 32 pages 0.54 and 0.52. The chunk tile is compute-bound
+    and keeps its blocks.
     """
     bq = min(128, pl.cdiv(n_tokens, 8) * 8) if n_tokens > 1 else 1
     if bq * q_per_kv > 1024:
@@ -223,6 +243,8 @@ def _ragged_tiling(n_tokens: int, q_per_kv: int, page_size: int,
     nq = pl.cdiv(n_tokens, bq)
     mrows = pl.cdiv(bq * q_per_kv, 16) * 16
     bk = 256 if bq > 1 else 512
+    if bq == 1 and latent_row_bytes:
+        bk = max(bk, _LATENT_BLOCK_BYTES // latent_row_bytes // bk * bk)
     bkp = max(1, min(max_pages, bk // page_size))
     return bq, nq, mrows, bkp
 
@@ -251,7 +273,12 @@ def _ragged_kernel(layer_ref, q_len_ref, kv_len_ref, pt_ref,   # prefetch
 
     A latent pool (``v_width``): no v_hbm and no vbuf; the value is the
     leading ``v_width`` lanes of the K block, read from the same buffer,
-    and o_ref / acc_ref are that wide.
+    and o_ref / acc_ref are that wide. Its one-token tile is bound by
+    neither the copies nor the MXU but by their sum: a turn's scalar work
+    (start the next block's page copies, wait for this block's) and its
+    vector work run one after the other, so that tile walks its pages
+    ``walk`` to a loop turn over the larger blocks _ragged_tiling gives
+    it (the numbers are in that docstring).
     """
     latent = v_width is not None
     if not latent:
@@ -290,6 +317,10 @@ def _ragged_kernel(layer_ref, q_len_ref, kv_len_ref, pt_ref,   # prefetch
     nxt_row, _, nxt_pages, nxt_blocks, _ = tile(nxt)
     nxt_live = jnp.logical_and(t + 1 < n_tiles, nxt_blocks > 0)
 
+    # the latent one-token tile: a page is one head's 16 rows, its copy
+    # as short as the scalar work that starts it, so the walk is unrolled
+    walk = min(_LATENT_WALK_UNROLL, bkp) if latent and bq == 1 else 1
+
     def copy_pages(row, n_pages, b, slot, wait=False):
         """Start (or wait for) the copies of block b's live pages."""
         def page(i, _):
@@ -299,7 +330,17 @@ def _ragged_kernel(layer_ref, q_len_ref, kv_len_ref, pt_ref,   # prefetch
                     hbm.at[layer, src], buf.at[slot, :, i], sem.at[s, slot])
                 cp.wait() if wait else cp.start()
             return 0
-        lax.fori_loop(0, jnp.clip(n_pages - b * bkp, 0, bkp), page, 0)
+        n = jnp.clip(n_pages - b * bkp, 0, bkp)
+        if walk == 1:
+            lax.fori_loop(0, n, page, 0)
+            return
+
+        def pages(g, _):
+            for j in range(walk):
+                page(g * walk + j, 0)
+            return 0
+        lax.fori_loop(0, n // walk, pages, 0)
+        lax.fori_loop(n // walk * walk, n, page, 0)
 
     @pl.when(t == 0)
     def _first():
@@ -410,7 +451,9 @@ def _ragged_rows_pallas(q, k_pages, v_pages, layer, page_table, q_start,
     _, _, Hkv, ps, _ = k_pages.shape
     max_pages = page_table.shape[1]
     qpk = Hq // Hkv
-    bq, nq, mrows, bkp = _ragged_tiling(n_tokens, qpk, ps, max_pages)
+    bq, nq, mrows, bkp = _ragged_tiling(
+        n_tokens, qpk, ps, max_pages,
+        D * k_pages.dtype.itemsize if latent else None)
     n_tiles, bk = n_rows * nq, bkp * ps
 
     # tile order: [tile, kv head, q head in group * bq + token, D]

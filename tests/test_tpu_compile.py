@@ -385,7 +385,8 @@ def test_latent_kernels_compile_at_kanana2_shapes(chip):
     """The kernel form of latent attention (MLA, absorbed): ONE kv head, 32
     query heads, rows of 640 lanes (576 held in whole lanes) whose leading
     512 are the value, no V leaf: the mixed step's shape (48 decode rows +
-    2 chunk rows of 512) and the one-leaf write, for a v5e. A row of 576
+    2 chunk rows of 512), the decode loop's (48 one-token rows) and the
+    one-leaf write, for a v5e. A row of 576
     is refused by Mosaic ("must be aligned to tiling (128)"), which is why
     the pool pads it."""
     L, P, ps, W, vw, hq = 2, 512, 16, 640, 512, 32
@@ -399,6 +400,16 @@ def test_latent_kernels_compile_at_kanana2_shapes(chip):
         sm_scale=192 ** -0.5, max_q_len=512, decode_rows=48, layer=layer,
         v_width=vw)
     assert _kernel_calls(attn) == 2          # chunk tiles, one-token tiles
+    # the decode loop's call at the cell's size: 48 one-token rows over a
+    # pool of 21600 pages, in the blocks _ragged_tiling gives that tile
+    # (a block too large for VMEM is refused here, before any chip run)
+    rows = _sds(chip, (48,), jnp.int32)
+    decode = pa._ragged_attention_pallas.lower(
+        _sds(chip, (48, hq, W), jnp.bfloat16),
+        _sds(chip, (8, 21600, 1, ps, W), jnp.bfloat16), None,
+        _sds(chip, (48, mp), jnp.int32), rows, rows, rows, None, None,
+        sm_scale=192 ** -0.5, decode_rows=48, layer=layer, v_width=vw)
+    assert _kernel_calls(decode) == 1
     tok = _sds(chip, (T,), jnp.int32)
     write = pa._kv_write_pallas.lower(
         pool, None, _sds(chip, (T, 1, W), jnp.bfloat16), None,
