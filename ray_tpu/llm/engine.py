@@ -38,14 +38,41 @@ admission, scheduling), rebuilt TPU-first around ONE ragged step:
 
 The engine is synchronous (dispatch -> readback -> book -> next step), so
 what the host does between two dispatches is device idle. Each phase of a
-step is a jax.profiler.TraceAnnotation, written into the profiler's own
-trace beside the device's events (a flag test when no trace runs):
-engine.step {kind, dispatch, decode_rows, prefill_rows, real_tokens,
-slot_tokens, waiting} and, partitioning it, engine.admit {admitted},
-engine.pack, engine.h2d {arrays}, engine.dispatch, engine.readback,
-engine.book {finished}, engine.metrics. The names are read by
-benchmark/readers/host_gaps.py (PERF.md lists them): renaming one, or
-moving where it opens and closes, changes a metric.
+step goes through ONE helper (PhaseClocks.phase), which does two things:
+
+  - it opens a jax.profiler.TraceAnnotation, written into the profiler's
+    own trace beside the device's events (a flag test when no trace runs):
+    engine.step {kind, dispatch, decode_rows, real_tokens, slot_tokens}
+    and, partitioning it, engine.admit {admitted}, engine.pack,
+    engine.h2d, engine.dispatch, engine.readback {a sparse model's
+    routing counters}, engine.book, engine.metrics; the serve loop adds
+    serve.publish {streams} and serve.wait (llm/serve_llm.py). The names
+    are read by benchmark/readers/host_gaps.py (PERF.md lists them):
+    renaming one, or moving where it opens and closes, changes a metric;
+  - it clocks the phase, always: time.perf_counter_ns at both ends, the
+    difference added to engine.stats as wall_ns_<phase> for admit, pack,
+    h2d, dispatch, readback, book, metrics, publish, wait, and `other` =
+    what of engine.step no child covers, so a plain sum of the ten keys
+    is the engine thread's time. And the thread's own CPU time
+    (time.thread_time_ns) as ONE counter, cpu_ns_host: what the thread
+    ran outside engine.readback and serve.wait, which sleep by design.
+    It is read at the two ends of those two spans only (two reads a
+    dispatch: the clock is a system call of 5.6 us on the chip machine's
+    host). wall - cpu is the time the thread was NOT running: waiting for
+    the interpreter, descheduled, or blocked in a call that sleeps. When
+    a trace runs, and only then, every span also reads the CPU clock at
+    both ends and carries cpu_us (on a host whose kernel counts thread
+    CPU in scheduler ticks a span's cpu_us is 0 or a whole tick, 10 ms:
+    right in a sum over many spans, wrong in each). Read by
+    benchmark/metrics/engine_host_ms, engine_offcpu_pct,
+    engine_device_wait_pct (counters, whole window), idle_offcpu_pct
+    (spans), and by the llm_engine_device_wait_ratio gauge. Only the
+    thread that steps the engine writes them: no lock.
+
+The stream lanes (one actor lane thread a streaming request) write
+stream.deliver {tokens} for the time they are awake with an item
+(llm/serve_llm.py:LLMServer.stream): NOT an engine.* / serve.* name, those
+are one thread's properly nested sequence to host_gaps.py.
 """
 
 from __future__ import annotations
@@ -71,6 +98,98 @@ from ray_tpu.models.llama import LlamaConfig
 
 TraceAnnotation = jax.profiler.TraceAnnotation
 logger = logging.getLogger(__name__)
+
+#: the phases of the engine thread, in the order a step meets them; the
+#: counters wall_ns_<phase> of engine.stats (`other`: engine.step's own
+#: time; publish, wait: the serve loop's, between steps)
+PHASES = ("admit", "pack", "h2d", "dispatch", "readback", "book",
+          "metrics", "other", "publish", "wait")
+WALL_KEYS = tuple("wall_ns_" + p for p in PHASES)
+#: the thread's CPU time outside the two phases that sleep by design
+CPU_KEY = "cpu_ns_host"
+_SLEEPS = ("engine.readback", "serve.wait")
+#: span name -> its wall counter; engine.step books what its children
+#: left of it
+_SPAN_OF = {"other": "engine.step", "publish": "serve.publish",
+            "wait": "serve.wait"}
+_PHASE_KEY = {_SPAN_OF.get(p, "engine." + p): "wall_ns_" + p
+              for p in PHASES}
+
+
+# the two clocks, as module names: the helper runs ten times a dispatch
+# and a global is the cheapest thing to call (tests script them here)
+_wall_ns = time.perf_counter_ns
+_cpu_ns = time.thread_time_ns
+_thread = threading.get_ident
+_span_enter = TraceAnnotation.__enter__
+_span_exit = TraceAnnotation.__exit__
+
+
+class PhaseClocks:
+    """The engine thread's time by phase (module docstring).
+    ``phase(name)`` is the one way a span of the host loop is opened; the
+    counters live in ``stats`` (the engine's), so whoever copies that dict
+    has them."""
+
+    def __init__(self, stats: Dict[str, int]):
+        self.stats = stats
+        # wall of every phase closed so far: engine.step takes what was
+        # added while it was open off its own
+        self.child_wall = 0
+        # (thread, its CPU clock) when a sleeping phase last ended: the
+        # CPU up to the next one's start is the host's
+        self.cpu_mark = (None, 0)
+        stats.update(dict.fromkeys(WALL_KEYS + (CPU_KEY,), 0))
+
+    def phase(self, name: str) -> "_Phase":
+        p = _Phase(name)
+        p.clocks = self
+        p.wall_key = _PHASE_KEY[name]
+        p.sleeps = name in _SLEEPS
+        return p
+
+
+class _Phase(TraceAnnotation):
+    """One phase in hand: the span it is, and its clocks. The wall clock
+    is read at both ends of every phase; the thread's CPU clock only at
+    the ends of the two that sleep (what ran between two of them is
+    cpu_ns_host) and, while a trace runs, at both ends of every span for
+    its cpu_us. Both clocks are read in the same order at both ends and
+    inside the span: cpu_us <= its duration up to the clocks'
+    granularity."""
+
+    __slots__ = ("clocks", "wall_key", "sleeps", "wall0", "cpu0",
+                 "child_wall0")
+
+    def __enter__(self) -> "_Phase":
+        _span_enter(self)
+        c = self.clocks
+        self.child_wall0 = c.child_wall
+        self.wall0 = _wall_ns()
+        if self.sleeps:
+            self.cpu0 = cpu = _cpu_ns()
+            thread, mark = c.cpu_mark
+            if thread == _thread():
+                c.stats[CPU_KEY] += cpu - mark
+        else:
+            self.cpu0 = _cpu_ns() if self.is_enabled() else None
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        wall = _wall_ns() - self.wall0
+        c = self.clocks
+        if self.cpu0 is not None:
+            cpu = _cpu_ns()
+            if self.sleeps:
+                c.cpu_mark = (_thread(), cpu)
+            if self.is_enabled():
+                self.set_metadata(cpu_us=(cpu - self.cpu0) / 1e3)
+        _span_exit(self, exc_type, exc, tb)
+        if self.wall_key == "wall_ns_other":
+            wall -= c.child_wall - self.child_wall0
+        else:
+            c.child_wall += wall
+        c.stats[self.wall_key] += wall
 
 
 class InferenceEngine:
@@ -221,6 +340,9 @@ class InferenceEngine:
             self.stats.update(
                 kv_token_layer_bytes=self._kv_token_layer_bytes,
                 kv_row_width=self._kv_row_width)
+        # every span of the host loop and its wall / CPU counters; the
+        # serve loop opens serve.publish and serve.wait through it too
+        self.phase = PhaseClocks(self.stats).phase
         # per-request flight recorder (llm/request_log.py): lifecycle
         # event stream per request + TTFT/TPOT/e2e/queue-wait histograms
         # + SLO attainment; None disables every hook (seq.record stays
@@ -258,6 +380,7 @@ class InferenceEngine:
         self._g_queue = metrics_mod.llm_queue_depth_gauge()
         self._g_programs = metrics_mod.llm_compiled_programs_gauge()
         self._g_pad_waste = metrics_mod.llm_padding_waste_gauge()
+        self._g_device_wait = metrics_mod.llm_engine_device_wait_gauge()
         self._g_slo_ttft = metrics_mod.llm_slo_ttft_attainment_gauge()
         self._g_slo_tpot = metrics_mod.llm_slo_tpot_attainment_gauge()
         self._g_preempts = metrics_mod.llm_preemptions_gauge()
@@ -353,9 +476,9 @@ class InferenceEngine:
         (decode_chunk tokens per running sequence) when not. Returns
         {request_id: generated} for sequences that FINISHED this step."""
         finished: Dict[str, List[int]] = {}
-        with TraceAnnotation("engine.step") as span:
+        with self.phase("engine.step") as span:
             self._step_meta = {"kind": "none"}
-            with TraceAnnotation("engine.admit") as admit_span:
+            with self.phase("engine.admit") as admit_span:
                 admitted = self._admit()
                 if admit_span.is_enabled():
                     admit_span.set_metadata(admitted=admitted)
@@ -367,8 +490,7 @@ class InferenceEngine:
             self.stats["steps"] += 1
             self._update_metrics()
             if span.is_enabled():
-                span.set_metadata(waiting=len(self.waiting),
-                                  **self._step_meta)
+                span.set_metadata(**self._step_meta)
         return finished
 
     # ---------------------------------------------------------- scheduling
@@ -496,7 +618,7 @@ class InferenceEngine:
             budget -= C
         if not rows:
             return False
-        with TraceAnnotation("engine.pack"):
+        with self.phase("engine.pack"):
             # decode rows advance one token: they need a page for it
             active = self._decode_rows(1, finished)
             ps = self.page_size
@@ -541,20 +663,19 @@ class InferenceEngine:
                 kv_len[r] = start + C
                 token_state[t0:t0 + C] = seq.slot
                 t0 += C
-        with TraceAnnotation("engine.h2d", arrays=8 + self._has_state):
+        with self.phase("engine.h2d"):
             args = [jnp.asarray(a) for a in (
                 tokens, token_pos, token_page, token_slot, ptab, q_start,
                 q_len, kv_len)]
             state_arg = {"token_state": jnp.asarray(token_state)} \
                 if self._has_state else {}
-        with TraceAnnotation("engine.dispatch"):
+        with self.phase("engine.dispatch"):
             nxt, self.kv = self._fns.ragged_step(self.params, *args,
                                                  self.kv, **state_arg)
-        with TraceAnnotation("engine.readback") as span:
+        with self.phase("engine.readback") as span:
             nxt = np.asarray(nxt)                  # [R], ONE readback
             nxt = self._note_counters(nxt, R, span)
-        with TraceAnnotation("engine.book") as span:
-            n_done = len(finished) + len(self._finished_at_prefill)
+        with self.phase("engine.book"):
             now = time.monotonic()
             chunk_tokens = sum(C for _, C in rows)
             self.stats["ragged_dispatches"] += 1
@@ -570,7 +691,7 @@ class InferenceEngine:
                 self.stats["decode_tokens"] += len(active)
             self._step_meta = {
                 "kind": "mixed", "dispatch": disp_idx,
-                "decode_rows": len(active), "prefill_rows": len(rows),
+                "decode_rows": len(active),
                 "real_tokens": len(active) + chunk_tokens,
                 "slot_tokens": Tcap}
             for slot, seq in active:
@@ -595,10 +716,6 @@ class InferenceEngine:
                         # scan can hand these pages to a younger request
                         self._ensure_pages(seq.slot, seq,
                                            self.decode_chunk, finished)
-            if span.is_enabled():
-                span.set_metadata(
-                    finished=len(finished)
-                    + len(self._finished_at_prefill) - n_done)
         return True
 
     def _postfill_book(self, seq: SequenceState, slot: int,
@@ -781,7 +898,7 @@ class InferenceEngine:
     # ----------------------------------------------------- pure decode
 
     def _decode(self, finished: Dict[str, List[int]]) -> None:
-        with TraceAnnotation("engine.pack"):
+        with self.phase("engine.pack"):
             active = self._decode_rows(self.decode_chunk, finished)
             if not active:
                 return
@@ -789,21 +906,20 @@ class InferenceEngine:
             seq_lens = np.ones(self.max_batch, np.int32)
             for i, s in active:
                 seq_lens[i] = s.num_tokens
-        with TraceAnnotation("engine.h2d", arrays=4):
+        with self.phase("engine.h2d"):
             tokens, positions, page_table, seq_lens = (
                 jnp.asarray(a) for a in (
                     self._tokens, self._positions, self._page_table,
                     seq_lens))
-        with TraceAnnotation("engine.dispatch"):
+        with self.phase("engine.dispatch"):
             toks_out, self.kv, _, _ = self._fns.decode_loop(
                 self.params, tokens, positions, self.kv, page_table,
                 seq_lens)
-        with TraceAnnotation("engine.readback") as span:
+        with self.phase("engine.readback") as span:
             block = np.asarray(toks_out)           # [K, B], ONE readback
             block = self._note_counters(
                 block, K * self.max_batch, span).reshape(K, self.max_batch)
-        with TraceAnnotation("engine.book") as span:
-            n_done = len(finished)
+        with self.phase("engine.book"):
             now = time.monotonic()
             self.stats["decode_steps"] += K
             self.stats["decode_tokens"] += K * len(active)
@@ -811,7 +927,7 @@ class InferenceEngine:
             self._step_meta = {
                 "kind": "decode",
                 "dispatch": self.stats["decode_dispatches"],
-                "decode_rows": len(active), "prefill_rows": 0,
+                "decode_rows": len(active),
                 "real_tokens": K * len(active),
                 "slot_tokens": K * self.max_batch}
             for slot, seq in active:
@@ -821,8 +937,6 @@ class InferenceEngine:
                 else:
                     self._tokens[slot] = toks[-1]
                     self._positions[slot] = seq.num_tokens - 1
-            if span.is_enabled():
-                span.set_metadata(finished=len(finished) - n_done)
 
     def _note_counters(self, out: np.ndarray, n_tokens: int, span):
         """Split a step program's flat output into its tokens and the
@@ -874,7 +988,7 @@ class InferenceEngine:
         dt = now - self._metrics_ts
         if dt < 1.0 and not force:
             return
-        with TraceAnnotation("engine.metrics"):
+        with self.phase("engine.metrics"):
             self._set_gauges(now, dt)
 
     def _set_gauges(self, now: float, dt: float) -> None:
@@ -918,6 +1032,13 @@ class InferenceEngine:
         if d_slots > 0:
             d_real = s["ragged_real_tokens"] - last["ragged_real_tokens"]
             self._g_pad_waste.set(1.0 - d_real / d_slots)
+        # of the engine thread's time over the gauge window, the share it
+        # spent blocked on the device: the rest is the chip waiting for
+        # this loop (or for requests)
+        d_wall = sum(s[k] - last[k] for k in WALL_KEYS)
+        if d_wall > 0:
+            self._g_device_wait.set(
+                (s["wall_ns_readback"] - last["wall_ns_readback"]) / d_wall)
         if self.request_log is not None:
             a_ttft, a_tpot = self.request_log.slo_attainment()
             self._g_slo_ttft.set(a_ttft)

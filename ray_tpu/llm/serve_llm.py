@@ -11,7 +11,8 @@ Token streaming: ``stream()`` is a generator — under serve it runs as a
 streaming actor method, every yielded token batch becomes consumable
 before the request finishes, and the HTTP proxy turns it into SSE
 (``/v1/completions`` with ``"stream": true``, the reference's OpenAI
-contract).
+contract). Each item's way out of the replica, on the lane thread that
+runs the generator, is a stream.deliver span of a traced run (stream()).
 """
 
 from __future__ import annotations
@@ -58,6 +59,8 @@ class LLMServer:
         # fed by the engine thread, drained by stream() generators
         self._token_qs: Dict[str, "queue_mod.Queue"] = {}
         self._lock = threading.Lock()
+        # stream.deliver spans whose generator another thread closed
+        self._orphan_spans: List[Any] = []
         self._wake = threading.Event()
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="llm-engine")
@@ -65,17 +68,24 @@ class LLMServer:
 
     def _loop(self) -> None:
         """The engine thread. Between two engine.step spans the device
-        waits for this loop, so its two phases are spans of the same trace
-        (serve.wait, serve.publish {streams}: names the benchmark reads,
-        see llm/engine.py)."""
+        waits for this loop, so its two phases go through the engine's
+        phase helper like the step's own (llm/engine.py): spans of the
+        same trace, serve.wait and serve.publish {streams} (names the
+        benchmark reads), each with cpu_us, and the counters
+        wall_ns_publish / wall_ns_wait of engine.stats (serve.wait sleeps
+        by design: the thread's CPU counter, cpu_ns_host, is read at its
+        two ends and leaves it out). _publish wakes every stream that got
+        tokens; what those lane threads then do is stream.deliver
+        {tokens} (stream())."""
+        phase = self.engine.phase
         while True:
             if not self.engine.has_work():
-                with TraceAnnotation("serve.wait"):
+                with phase("serve.wait"):
                     self._wake.wait(timeout=0.05)
                 self._wake.clear()
                 continue
             finished = self.engine.step()
-            with TraceAnnotation("serve.publish") as span:
+            with phase("serve.publish") as span:
                 streams = self._publish(finished)
                 if span.is_enabled():
                     span.set_metadata(streams=streams)
@@ -161,14 +171,17 @@ class LLMServer:
         self._wake.set()
         produced: List[int] = []
         completed = False
+        awake = None
         try:
             while True:
                 item = q.get(timeout=300)
+                awake = self._lane_awake(len(item) if item else 0)
                 if item is None:
                     completed = True
                     break
                 produced.extend(item)
                 yield {"token_ids": item, "request_id": rid}
+                awake = self._lane_asleep(awake)
             with log_plane.request_context(rid):
                 log_plane.get_logger().info(
                     f"llm stream finished ({len(produced)} tok)")
@@ -177,6 +190,7 @@ class LLMServer:
                    "finish_reason": self.engine.finish_reason(rid),
                    "cached_tokens": self.engine.cached_tokens(rid)}
         finally:
+            self._lane_asleep(awake)
             with self._lock:
                 self._token_qs.pop(rid, None)
                 if not completed:
@@ -186,6 +200,45 @@ class LLMServer:
                     # _results forever, and drop any already-parked result
                     self._results.pop(rid, None)
                     self._abandoned.add(rid)
+
+    # stream.deliver {tokens}: a lane thread's awake time for one item,
+    # from q.get returning to the same lane asking for the next (between
+    # them: the yield through serve/replica.py's handle_request_streaming,
+    # the worker runtime's _send_stream_item: serialise, ship). Placed HERE
+    # and not in runtime/worker_main.py: this module has jax already, the
+    # runtime must not import it; and the generator's frame is the one
+    # place that sees both ends on the lane. Not a `with`: a span held
+    # across a yield would end on whichever thread closes an abandoned
+    # generator. No cpu_us here: the lanes hold the interpreter the engine
+    # thread waits for, and where the thread CPU clock is a system call
+    # (5.6 us a read on the chip machine) two reads an item cost more than
+    # the rest of the span ten times over.
+
+    def _lane_awake(self, tokens: int):
+        """Open the span on this lane; None (a flag test) when no trace
+        runs."""
+        if not TraceAnnotation.is_enabled():
+            if self._orphan_spans:      # parked during a trace that ended
+                self._orphan_spans.clear()
+            return None
+        span = TraceAnnotation("stream.deliver", tokens=tokens)
+        span.__enter__()
+        return span, threading.get_ident()
+
+    def _lane_asleep(self, awake) -> None:
+        """Close what _lane_awake opened, on the lane that opened it."""
+        if awake is None:
+            return
+        span, lane = awake
+        if threading.get_ident() != lane:
+            # the generator is being closed by another thread (a
+            # collector, a caller's close()): when the lane fell asleep is
+            # not known. A TraceMe writes its event where and when it is
+            # ended OR destroyed while a trace runs, so this one is kept
+            # until none does (_lane_awake drops them then)
+            self._orphan_spans.append(span)
+            return
+        span.__exit__(None, None, None)
 
     def _prompt_ids(self, request: Dict[str, Any]) -> List[int]:
         if "prompt_ids" in request:

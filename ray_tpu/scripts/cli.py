@@ -266,6 +266,12 @@ def _render_top(client, address: str) -> str:
         llm_line = (f"llm: decode {llm_decode:.0f} tok/s  "
                     f"prefill {pf:.0f} tok/s  kv_util {kv:.0%}  "
                     f"prefix_hit {hit:.0%}  queued {lq:g}")
+        # is the chip waiting for a replica's host loop: the share of
+        # the engine thread's time blocked on the device, mean over
+        # engines (near 100% = the chip is the bottleneck)
+        dev_wait = _gauge_mean("llm_engine_device_wait_ratio")
+        if dev_wait is not None:
+            llm_line += f"  dev_wait {dev_wait:.0%}"
         # request-level serving latencies from the flight-recorder
         # histograms (bucket upper bounds, hence the <=)
         ttft50 = _hist_quantile(metrics, "llm_ttft_seconds", 0.5)

@@ -484,6 +484,17 @@ def llm_padding_waste_gauge() -> Gauge:
                              "slots (0..1)")
 
 
+def llm_engine_device_wait_gauge() -> Gauge:
+    """Of the engine thread's time over the gauge window, the share it
+    spent blocked on the device's result (wall_ns_readback over the ten
+    wall_ns_* counters of llm/engine.py). Near 1: the chip is the
+    bottleneck; what is missing to 1 is the chip waiting for this
+    replica's host loop, or for requests."""
+    return Gauge("llm_engine_device_wait_ratio",
+                 description="share of the engine thread's time blocked "
+                             "on the device (0..1)")
+
+
 # Serving-latency buckets: sub-ms (cache hit / queue-free admit) up to
 # 30s (page-pressure starvation); TPOT gets a finer low end, e2e a
 # longer tail. vLLM exposes the same trio of request histograms.

@@ -26,6 +26,7 @@ if ROOT not in sys.path:
 
 from benchmark import reference_olmoe as ref  # noqa: E402
 from ray_tpu.llm import InferenceEngine  # noqa: E402
+from ray_tpu.llm.engine import CPU_KEY, WALL_KEYS  # noqa: E402
 from ray_tpu.models.llama import LlamaConfig, init_params  # noqa: E402
 from ray_tpu.ops import moe  # noqa: E402
 
@@ -274,7 +275,7 @@ def test_dense_configuration_is_untouched():
         "steps", "prefill_tokens", "decode_steps", "decode_tokens",
         "decode_dispatches", "cached_tokens", "ragged_dispatches",
         "ragged_real_tokens", "ragged_slot_tokens", "cow_copies",
-        "preemptions"}
+        "preemptions"} | set(WALL_KEYS + (CPU_KEY,))  # every model's clocks
     # the step programs' outputs keep their shapes: [R] and [K, B]
     from ray_tpu.llm import model as M
     kv = eng.kv

@@ -76,6 +76,8 @@ def test_hist_quantile_and_top_llm_line():
             "values": {"a": {"counts": [3, 1, 0], "sum": 0.02, "n": 4}}},
         "llm_decode_tokens_per_s": {"type": "gauge",
                                     "values": {"w0": 120.0}},
+        "llm_engine_device_wait_ratio": {"type": "gauge",
+                                         "values": {"w0": 0.9, "w1": 0.6}},
         "llm_slo_ttft_attainment": {"type": "gauge",
                                     "values": {"w0": 0.9, "w1": 0.7}},
         "llm_slo_tpot_attainment": {"type": "gauge",
@@ -95,6 +97,7 @@ def test_hist_quantile_and_top_llm_line():
 
     out = cli._render_top(FakeClient(), "127.0.0.1:1")
     assert "llm: decode 120 tok/s" in out
+    assert "dev_wait 75%" in out            # mean over engines, not sum
     assert "ttft p50<=10ms p99<=100ms" in out
     assert "tpot p50<=5.0ms" in out
     assert "slo ttft 80% tpot 75%" in out  # mean, not sum
