@@ -12,6 +12,11 @@ Transport: the executing worker sends each item to the owner's RPC server
 (``stream_item``, small values inline, large sealed into shm with the
 location) and finishes with the ordinary push-task reply carrying the
 final item count — so completion rides the existing retry/error machinery.
+A sync generator's worker keeps ONE item in flight: it pulls the generator
+again only once the owner's ``stream_item`` handler has acknowledged the
+item before (runtime/worker_main.py: _stream_out), so an owner that takes
+items in slower than they are made holds the producer back instead of
+queueing them without bound.
 Item readiness and completion travel on different sockets; the consumer
 therefore waits on item N's memory-store readiness OR a recorded total
 < N, whichever comes first (ordering between the two channels is not

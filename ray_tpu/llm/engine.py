@@ -46,9 +46,12 @@ step goes through ONE helper (PhaseClocks.phase), which does two things:
     and, partitioning it, engine.admit {admitted}, engine.pack,
     engine.h2d, engine.dispatch, engine.readback {a sparse model's
     routing counters}, engine.book, engine.metrics; the serve loop adds
-    serve.publish {streams} and serve.wait (llm/serve_llm.py). The names
-    are read by benchmark/readers/host_gaps.py (PERF.md lists them):
-    renaming one, or moving where it opens and closes, changes a metric;
+    serve.wait between steps and serve.publish {streams}, which it runs
+    from step()'s after_dispatch hook: inside engine.step, between
+    engine.dispatch and engine.readback, with the program on the device
+    (llm/serve_llm.py). The names are read by
+    benchmark/readers/host_gaps.py (PERF.md lists them): renaming one, or
+    moving where it opens and closes, changes a metric;
   - it clocks the phase, always: time.perf_counter_ns at both ends, the
     difference added to engine.stats as wall_ns_<phase> for admit, pack,
     h2d, dispatch, readback, book, metrics, publish, wait, and `other` =
@@ -83,7 +86,7 @@ import logging
 import threading
 import time
 import uuid
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -101,7 +104,7 @@ logger = logging.getLogger(__name__)
 
 #: the phases of the engine thread, in the order a step meets them; the
 #: counters wall_ns_<phase> of engine.stats (`other`: engine.step's own
-#: time; publish, wait: the serve loop's, between steps)
+#: time; publish, wait: the serve loop's, publish mostly inside a step)
 PHASES = ("admit", "pack", "h2d", "dispatch", "readback", "book",
           "metrics", "other", "publish", "wait")
 WALL_KEYS = tuple("wall_ns_" + p for p in PHASES)
@@ -468,13 +471,25 @@ class InferenceEngine:
 
     # ---------------------------------------------------------------- step
 
-    def step(self) -> Dict[str, List[int]]:
+    def step(self, after_dispatch: Optional[Callable[[], None]] = None,
+             ) -> Dict[str, List[int]]:
         """One scheduler step: admit waiting requests, then EITHER one
         ragged mixed dispatch (prefill chunks under the token budget +
         one decode token per running sequence, a single program) when
         prefill work is pending, OR one multi-step decode-loop dispatch
         (decode_chunk tokens per running sequence) when not. Returns
-        {request_id: generated} for sequences that FINISHED this step."""
+        {request_id: generated} for sequences that FINISHED this step.
+
+        ``after_dispatch`` is called once, with no arguments, right after
+        the engine.dispatch phase of whichever program the step launches
+        and before engine.readback: host work the caller wants done while
+        the device runs and this thread would only sleep on it. A step
+        that launches nothing never calls it. The serve loop hands the
+        PREVIOUS step's tokens to their waiters there (llm/serve_llm.py),
+        so a served token reaches its waiter one launch after it is booked
+        (at once when the engine runs dry); booking, the request log's
+        timestamps (the booking's, not the delivery's) and every program's
+        inputs are the same with and without it."""
         finished: Dict[str, List[int]] = {}
         with self.phase("engine.step") as span:
             self._step_meta = {"kind": "none"}
@@ -482,8 +497,8 @@ class InferenceEngine:
                 admitted = self._admit()
                 if admit_span.is_enabled():
                     admit_span.set_metadata(admitted=admitted)
-            if not self._ragged_dispatch(finished):
-                self._decode(finished)
+            if not self._ragged_dispatch(finished, after_dispatch):
+                self._decode(finished, after_dispatch)
             if self._finished_at_prefill:
                 finished.update(self._finished_at_prefill)
                 self._finished_at_prefill = {}
@@ -595,7 +610,9 @@ class InferenceEngine:
 
     # --------------------------------------------------- ragged mixed step
 
-    def _ragged_dispatch(self, finished: Dict[str, List[int]]) -> bool:
+    def _ragged_dispatch(self, finished: Dict[str, List[int]],
+                         after_dispatch: Optional[Callable[[], None]],
+                         ) -> bool:
         """Assemble and run ONE ragged mixed step, if prefill work is
         pending: decode rows first (slot r owns ragged token r), then up
         to prefill_rows chunk rows packed from token max_batch on, FIFO
@@ -672,6 +689,8 @@ class InferenceEngine:
         with self.phase("engine.dispatch"):
             nxt, self.kv = self._fns.ragged_step(self.params, *args,
                                                  self.kv, **state_arg)
+        if after_dispatch is not None:
+            after_dispatch()                       # the device is running
         with self.phase("engine.readback") as span:
             nxt = np.asarray(nxt)                  # [R], ONE readback
             nxt = self._note_counters(nxt, R, span)
@@ -897,7 +916,8 @@ class InferenceEngine:
 
     # ----------------------------------------------------- pure decode
 
-    def _decode(self, finished: Dict[str, List[int]]) -> None:
+    def _decode(self, finished: Dict[str, List[int]],
+                after_dispatch: Optional[Callable[[], None]]) -> None:
         with self.phase("engine.pack"):
             active = self._decode_rows(self.decode_chunk, finished)
             if not active:
@@ -915,6 +935,8 @@ class InferenceEngine:
             toks_out, self.kv, _, _ = self._fns.decode_loop(
                 self.params, tokens, positions, self.kv, page_table,
                 seq_lens)
+        if after_dispatch is not None:
+            after_dispatch()                       # the device is running
         with self.phase("engine.readback") as span:
             block = np.asarray(toks_out)           # [K, B], ONE readback
             block = self._note_counters(

@@ -13,6 +13,10 @@ before the request finishes, and the HTTP proxy turns it into SSE
 (``/v1/completions`` with ``"stream": true``, the reference's OpenAI
 contract). Each item's way out of the replica, on the lane thread that
 runs the generator, is a stream.deliver span of a traced run (stream()).
+The runtime resumes a lane only once the caller has acknowledged its last
+item, and the lane then takes everything the engine handed over meanwhile
+as ONE item: a way out slower than the engine carries fewer, larger
+items, never a growing backlog.
 """
 
 from __future__ import annotations
@@ -49,6 +53,10 @@ class LLMServer:
         cfg = LlamaConfig.tiny(**(model_config or {}))
         self.engine = InferenceEngine(cfg, **(engine_config or {}))
         self.engine.track_progress = True  # the serve loop drains it
+        # hand-overs to the waiters, and those made under a running program
+        self.engine.stats.update(publishes=0, publishes_overlapped=0)
+        # (finished, progress) of the last step, not yet handed over
+        self._held = None
         self.tokenizer = tokenizer or ByteTokenizer()
         self.model_name = model_name
         self.chat_template = chat_template or apply_chat_template
@@ -67,34 +75,76 @@ class LLMServer:
         self._thread.start()
 
     def _loop(self) -> None:
-        """The engine thread. Between two engine.step spans the device
-        waits for this loop, so its two phases go through the engine's
-        phase helper like the step's own (llm/engine.py): spans of the
-        same trace, serve.wait and serve.publish {streams} (names the
-        benchmark reads), each with cpu_us, and the counters
-        wall_ns_publish / wall_ns_wait of engine.stats (serve.wait sleeps
-        by design: the thread's CPU counter, cpu_ns_host, is read at its
-        two ends and leaves it out). _publish wakes every stream that got
-        tokens; what those lane threads then do is stream.deliver
-        {tokens} (stream())."""
-        phase = self.engine.phase
-        while True:
-            if not self.engine.has_work():
-                with phase("serve.wait"):
-                    self._wake.wait(timeout=0.05)
-                self._wake.clear()
-                continue
-            finished = self.engine.step()
-            with phase("serve.publish") as span:
-                streams = self._publish(finished)
-                if span.is_enabled():
-                    span.set_metadata(streams=streams)
+        """The engine thread. A step's tokens (its finished sequences and
+        the progress drained when it returns) are HELD and handed to
+        their waiters from inside the NEXT engine.step, by its
+        after_dispatch hook: between engine.dispatch and engine.readback,
+        while the device runs that step's program and this thread would
+        only sleep on it. Handed over before the next step instead, the
+        16-128 stream lanes a hand-over wakes take the interpreter from
+        this thread in the one stretch the device waits for (admit, pack,
+        h2d, dispatch). So a token reaches its waiter one launch after it
+        is booked; the request log's timestamps are the booking's, not
+        the delivery's. Flush rule: what no dispatch will carry is handed
+        over at once, in the old place: after a step that launched
+        nothing, and before serve.wait when the engine has run dry. No
+        token is held across a sleep. One thread, FIFO: every stream
+        still gets step N's tokens, then step N+1's, then its end.
 
-    def _publish(self, finished: Dict[str, List[int]]) -> int:
-        """Hand the step's tokens to their waiters; returns how many
+        The loop's two phases go through the engine's phase helper like
+        the step's own (llm/engine.py): spans of the same trace,
+        serve.wait and serve.publish {streams} (names the benchmark
+        reads; serve.publish nests in engine.step unless it is a flush),
+        each with cpu_us, and the counters wall_ns_publish / wall_ns_wait
+        of engine.stats (serve.wait sleeps by design: the thread's CPU
+        counter, cpu_ns_host, is read at its two ends and leaves it out).
+        engine.stats also counts `publishes` (hand-overs that had
+        something to hand over) and `publishes_overlapped` (those made
+        with a program on the device). _publish wakes every stream that
+        got tokens; what those lane threads then do is stream.deliver
+        {tokens} (stream())."""
+        while True:
+            self._turn()
+
+    def _turn(self) -> None:
+        """One turn of the loop: a step and what it leaves held, or the
+        flush and the sleep of an engine with no work."""
+        engine = self.engine
+        if not engine.has_work():
+            self._hand_over(overlapped=False)
+            with engine.phase("serve.wait"):
+                self._wake.wait(timeout=0.05)
+            self._wake.clear()
+            return
+        finished = engine.step(self._hand_over_under_the_device)
+        self._hand_over(overlapped=False)       # the step launched nothing
+        progress = engine.drain_progress()
+        if finished or progress:
+            self._held = (finished, progress)
+
+    def _hand_over_under_the_device(self) -> None:
+        """step()'s after_dispatch hook: a program is on the device."""
+        self._hand_over(overlapped=True)
+
+    def _hand_over(self, overlapped: bool) -> None:
+        """Publish what the last step left held, if anything."""
+        held, self._held = self._held, None
+        if held is None:
+            return
+        with self.engine.phase("serve.publish") as span:
+            streams = self._publish(*held)
+            if span.is_enabled():
+                span.set_metadata(streams=streams)
+        stats = self.engine.stats
+        stats["publishes"] += 1
+        if overlapped:
+            stats["publishes_overlapped"] += 1
+
+    def _publish(self, finished: Dict[str, List[int]],
+                 progress: Dict[str, List[int]]) -> int:
+        """Hand one step's tokens to their waiters; returns how many
         streams got some."""
         streams = 0
-        progress = self.engine.drain_progress()
         with self._lock:
             for rid, new_toks in progress.items():
                 q = self._token_qs.get(rid)
@@ -173,15 +223,29 @@ class LLMServer:
         completed = False
         awake = None
         try:
-            while True:
+            while not completed:
                 item = q.get(timeout=300)
-                awake = self._lane_awake(len(item) if item else 0)
-                if item is None:
-                    completed = True
-                    break
-                produced.extend(item)
-                yield {"token_ids": item, "request_id": rid}
-                awake = self._lane_asleep(awake)
+                completed = item is None
+                # everything the engine handed over while this lane was
+                # away leaves as ONE item: the runtime resumes a lane only
+                # once its last item is acknowledged (worker_main.py:
+                # _stream_out), so a way out that is slower than the engine
+                # carries fewer, larger items instead of a growing backlog
+                while not completed:
+                    try:
+                        more = q.get_nowait()
+                    except queue_mod.Empty:
+                        break
+                    if more is None:
+                        completed = True
+                    else:
+                        item.extend(more)
+                if item is not None:
+                    awake = self._lane_awake(len(item))
+                    produced.extend(item)
+                    yield {"token_ids": item, "request_id": rid}
+                    awake = self._lane_asleep(awake)
+            awake = self._lane_awake(0)         # the closing item's
             with log_plane.request_context(rid):
                 log_plane.get_logger().info(
                     f"llm stream finished ({len(produced)} tok)")
@@ -201,10 +265,11 @@ class LLMServer:
                     self._results.pop(rid, None)
                     self._abandoned.add(rid)
 
-    # stream.deliver {tokens}: a lane thread's awake time for one item,
-    # from q.get returning to the same lane asking for the next (between
-    # them: the yield through serve/replica.py's handle_request_streaming,
-    # the worker runtime's _send_stream_item: serialise, ship). Placed HERE
+    # stream.deliver {tokens}: a lane thread's time with one item, from
+    # q.get returning to the same lane asking for the next (between them:
+    # the yield through serve/replica.py's handle_request_streaming, the
+    # worker runtime's _send_stream_item: serialise, ship, and since PR 36
+    # its wait, asleep, for the caller's acknowledgement). Placed HERE
     # and not in runtime/worker_main.py: this module has jax already, the
     # runtime must not import it; and the generator's frame is the one
     # place that sees both ends on the lane. Not a `with`: a span held

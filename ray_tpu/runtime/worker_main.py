@@ -648,8 +648,10 @@ class Executor:
     # ------------------------------------------------------------ streaming
 
     def _send_stream_item(self, owner_client, payload: dict, index: int,
-                          value: Any) -> None:
-        """Ship one yielded value to the owner (inline or via shm)."""
+                          value: Any, acked: bool):
+        """Ship one yielded value to the owner (inline or via shm). With
+        `acked` the frame asks for a reply and its Future is returned: it
+        resolves once the owner's handler has stored the item."""
         cfg = config_mod.GlobalConfig
         oid = ObjectID.for_return(TaskID(payload["task_id"]), index)
         so = serialization.serialize(value)
@@ -669,21 +671,55 @@ class Executor:
             if caller and r.owner_id() == self.worker.worker_id:
                 self.worker.refcounter.add_borrower(r.id(), caller)
         self.backend.flush_borrows()  # adds-before-ship for borrowed refs
-        owner_client.oneway("stream_item", msg)
+        ack = None
+        if acked:
+            ack = owner_client.call_async("stream_item", msg)
+        else:
+            owner_client.oneway("stream_item", msg)
         for r in so.contained_refs:
             self.worker.refcounter.on_serialized_ref_done(r.id())
+        return ack
 
     def _stream_out(self, payload: dict, ctx, result: Any,
                     t_start: float) -> None:
         """Drain a generator task, shipping items as they are produced
-        (reference: streaming generator protocol, _raylet.pyx:1391)."""
+        (reference: streaming generator protocol, _raylet.pyx:1391).
+
+        Flow control, a window of ONE item: the generator is not pulled
+        again until the owner has acknowledged the item before (its
+        stream_item handler stored it). The send itself does not wait, so
+        a producer slower than the round trip never feels it; one that is
+        faster than the owner takes items in stays at most one item ahead
+        of it, and what it makes meanwhile it can hand over as one larger
+        item at the next pull (LLMServer.stream does). Unacknowledged
+        frames sent one way queued without bound in the owner's handler
+        pool instead: 128 token streams ran 2.5 s behind their engine.
+        Waiting with the next item already in hand instead (one wake an
+        item less) left those streams 0.23 s behind, this order 0.05 s
+        (PERF.md, PR 36). A lost or late acknowledgement is a lost frame
+        as before: the reply's count tells the consumer. (The async paths
+        below ship one way: they run on the actor's event loop, where a
+        wait would stall every coroutine.)"""
         owner = self.backend.object_plane.owner_client(
             WorkerID(payload["owner"]))
+        ack_timeout = config_mod.GlobalConfig.rpc_call_timeout_s
         i = 0
+        ack = None
         try:
-            for v in iter(result):
+            it = iter(result)
+            while True:
+                if ack is not None:
+                    try:
+                        ack.result(timeout=ack_timeout)
+                    except Exception:  # noqa: BLE001
+                        pass
+                try:
+                    v = next(it)
+                except StopIteration:
+                    break
                 i += 1
-                self._send_stream_item(owner, payload, i, v)
+                ack = self._send_stream_item(owner, payload, i, v,
+                                             acked=True)
         except BaseException as e:  # noqa: BLE001
             self._record_span(payload, t_start, ok=False)
             so = serialization.serialize_error(e)
@@ -733,7 +769,8 @@ class Executor:
                             i += 1
                             # blocking socket write; cheap enough on-loop
                             # for token-sized payloads
-                            self._send_stream_item(owner, payload, i, v)
+                            self._send_stream_item(owner, payload, i, v,
+                                                   acked=False)
                     except BaseException as e:  # noqa: BLE001
                         _stream_reply(i, e)
                         return None
@@ -749,7 +786,8 @@ class Executor:
                     try:
                         for v in iter(out):
                             i += 1
-                            self._send_stream_item(owner, payload, i, v)
+                            self._send_stream_item(owner, payload, i, v,
+                                                   acked=False)
                     except BaseException as e:  # noqa: BLE001
                         _stream_reply(i, e)
                         return None

@@ -221,3 +221,46 @@ def test_abandoned_stream_items_freed(rtpu_cluster):
     leaked_entries = global_worker.memory_store.size() - base_entries
     assert leaked_tracked <= 6, f"refcount entries leaked: {leaked_tracked}"
     assert leaked_entries <= 6, f"memory-store entries leaked: {leaked_entries}"
+
+
+def test_streaming_producer_stays_one_item_ahead(rtpu_cluster):
+    """Flow control (runtime/worker_main.py: _stream_out): the generator
+    is pulled again only once the owner has acknowledged the item before,
+    so an owner that is slow to take items in holds the producer at one
+    item in flight instead of queueing them all."""
+    ray_tpu = rtpu_cluster
+    from ray_tpu.core import worker as worker_mod
+    server = worker_mod.global_worker.backend.server
+    handle = server.handlers["stream_item"]
+    gate = threading.Event()
+    arrived = []
+
+    def slow(p, ctx):
+        arrived.append(p["index"])
+        gate.wait(30)
+        return handle(p, ctx)
+
+    server.handlers["stream_item"] = slow
+    try:
+        @ray_tpu.remote(num_returns="streaming")
+        def gen():
+            import time as _t
+            for i in range(4):
+                yield i, _t.monotonic()
+
+        g = gen.remote()
+        deadline = time.monotonic() + 60
+        while not arrived:
+            assert time.monotonic() < deadline
+            time.sleep(0.01)
+        time.sleep(0.5)     # unacknowledged, all four would be here by now
+        assert arrived == [1]
+        t_open = time.monotonic()
+        gate.set()
+        items = [ray_tpu.get(r, timeout=30) for r in g]
+    finally:
+        gate.set()
+        server.handlers["stream_item"] = handle
+    assert [i for i, _ in items] == [0, 1, 2, 3]
+    assert arrived == [1, 2, 3, 4]
+    assert items[1][1] >= t_open        # pulled after the acknowledgement

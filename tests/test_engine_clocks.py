@@ -81,7 +81,10 @@ def scripted(monkeypatch):
         now["wall"] += SCRIPT[name][0]
         now["cpu"] += SCRIPT[name][1]
 
-    def turn():
+    def turn(nested=False):
+        """One loop turn; `nested`: the last step's tokens handed over
+        from inside this step, between dispatch and readback (the serve
+        loop's order), instead of after it (a flush)."""
         with phase("serve.wait"):
             work("wait")
         with phase("engine.step"):
@@ -91,10 +94,14 @@ def scripted(monkeypatch):
                          "book", "metrics"):
                 with phase("engine." + name):
                     work(name)
+                if nested and name == "dispatch":
+                    with phase("serve.publish"):
+                        work("publish")
             now["wall"] += 4
             now["cpu"] += 3
-        with phase("serve.publish"):
-            work("publish")
+        if not nested:
+            with phase("serve.publish"):
+                work("publish")
 
     start = now["wall"]
     return (stats, turn, lambda: now["wall"] - start,
@@ -102,43 +109,53 @@ def scripted(monkeypatch):
             lambda on: now.update(trace=on))
 
 
+@pytest.mark.parametrize("nested", [False, True])
 @pytest.mark.parametrize("name", PHASES)
-def test_each_phase_lands_in_its_wall_counter(scripted, name):
+def test_each_phase_lands_in_its_wall_counter(scripted, name, nested):
+    """serve.publish inside engine.step (the serve loop's order) or after
+    it (a flush): its wall is publish's either way, never `other`'s."""
     stats, turn = scripted[:2]
     assert stats["wall_ns_" + name] == stats[CPU_KEY] == 0
     for _ in range(3):
-        turn()
+        turn(nested)
     assert stats["wall_ns_" + name] == 3 * SCRIPT[name][0]
     assert "cpu_ns_" + name not in stats           # one CPU counter, below
     assert stats["steps"] == 3                     # the rest is untouched
 
 
-def test_the_ten_wall_counters_partition_the_loops_time(scripted):
+@pytest.mark.parametrize("nested", [False, True, "mixed"])
+def test_the_ten_wall_counters_partition_the_loops_time(scripted, nested):
     stats, turn, elapsed = scripted[:3]
-    for _ in range(5):
-        turn()
+    for i in range(5):
+        turn(i % 2 == 0 if nested == "mixed" else nested)
     assert sum(stats[k] for k in WALL_KEYS) == elapsed() \
         == 5 * sum(w for w, _ in SCRIPT.values())
+    assert stats["wall_ns_other"] == 5 * SCRIPT["other"][0]
+    assert stats["wall_ns_publish"] == 5 * SCRIPT["publish"][0]
     assert len(WALL_KEYS) == 10
 
 
+@pytest.mark.parametrize("nested", [False, True])
 @pytest.mark.parametrize("traced", [False, True])
 def test_host_cpu_is_what_ran_outside_the_two_phases_that_sleep(scripted,
-                                                                traced):
+                                                                traced,
+                                                                nested):
     """cpu_ns_host: the thread's CPU between the end of one sleeping phase
     (engine.readback, serve.wait) and the start of the next, whatever the
     phases between them; tracing adds reads of the clock, not CPU."""
     stats, turn, _, reads, _, trace = scripted
     trace(traced)
     for _ in range(4):
-        turn()
-    # the tail of the last turn (book ... publish) is booked when the next
-    # sleeping phase starts
-    tail = sum(SCRIPT[p][1] for p in ("book", "metrics", "publish")) + 3
+        turn(nested)
+    # the tail of the last turn (book ... and a flush's publish; a nested
+    # publish ran before the readback) is booked when the next sleeping
+    # phase starts
+    tail = sum(SCRIPT[p][1] for p in ("book", "metrics")
+               + (() if nested else ("publish",))) + 3
     host = sum(SCRIPT[p][1] for p in HOST)
     assert stats[CPU_KEY] == 4 * host - tail
     before = reads()
-    turn()
+    turn(nested)
     assert stats[CPU_KEY] == 5 * host - tail
     # tracing off: the CPU clock is read at the two ends of readback and
     # of wait and nowhere else (two reads a dispatch: wait runs only when
@@ -146,17 +163,20 @@ def test_host_cpu_is_what_ran_outside_the_two_phases_that_sleep(scripted,
     assert reads() - before == (20 if traced else 4)
 
 
-def test_a_traced_span_carries_the_cpu_of_its_own_window(scripted):
+@pytest.mark.parametrize("nested", [False, True])
+def test_a_traced_span_carries_the_cpu_of_its_own_window(scripted, nested):
     stats, turn, _, _, given, trace = scripted
-    turn()
+    turn(nested)
     assert given == []                              # no trace: no cpu_us
     trace(True)
-    turn()
+    turn(nested)
     got = dict(given)
     assert len(given) == len(got) == 10
+    # engine.step's span holds its children's CPU, a nested publish's too
+    outside = ("wait",) if nested else ("publish", "wait")
     for name in PHASES:
         want = SCRIPT[name][1] if name != "other" else sum(
-            SCRIPT[p][1] for p in PHASES if p not in ("publish", "wait"))
+            SCRIPT[p][1] for p in PHASES if p not in outside)
         assert got["wall_ns_" + name] == pytest.approx(want / 1e3), name
     # a trace that starts or stops inside a span: nothing half-read
     trace(False)
@@ -311,11 +331,12 @@ def test_counters_equal_the_sums_over_the_spans(streamed, phase):
     mine = [s for s in spans if s[0] == name]
     wall = sum(s[2] - s[1] for s in mine)
     if phase == "other":
-        wall -= sum(s[2] - s[1] for s in spans
-                    if s[0].startswith("engine.") and s[0] != "engine.step")
+        steps = [s for s in spans if s[0] == "engine.step"]
+        wall -= sum(s[2] - s[1] for s in spans if s[0] != "engine.step"
+                    and any(t[1] <= s[1] and s[2] <= t[2] for t in steps))
     d_wall = streamed["after"]["wall_ns_" + phase] \
         - streamed["before"]["wall_ns_" + phase]
-    n = max(len(mine), 1) * (8 if phase == "other" else 1)
+    n = max(len(mine), 1) * (9 if phase == "other" else 1)
     # enclosed up to the two clocks' rates; more only by what the helper
     # runs between a span's edge and its clock (and a deschedule there).
     # engine.step's own is a difference: its children's edges count against
@@ -328,13 +349,19 @@ def test_counters_equal_the_sums_over_the_spans(streamed, phase):
 
 def test_host_cpu_counter_equals_the_spans_cpu_outside_the_sleeps(streamed):
     """cpu_ns_host against the same thing from the spans: engine.step's
-    and serve.publish's cpu_us less engine.readback's. The counter also
-    holds what runs between two spans (the loop's own few lines)."""
+    cpu_us (a serve.publish nested in it is part of that) and a flush's
+    serve.publish's, less engine.readback's. The counter also holds what
+    runs between two spans (the loop's own few lines)."""
     spans = _engine_thread(streamed)
+    steps = [s for s in spans if s[0] == "engine.step"]
+    flushes = [s for s in spans if s[0] == "serve.publish" and not any(
+        t[1] <= s[1] and s[2] <= t[2] for t in steps)]
+    assert flushes and len(flushes) < sum(
+        1 for s in spans if s[0] == "serve.publish")
     cpu = {n: sum(s[3]["cpu_us"] * 1e3 for s in spans if s[0] == n)
-           for n in ("engine.step", "serve.publish", "engine.readback")}
-    from_spans = cpu["engine.step"] + cpu["serve.publish"] \
-        - cpu["engine.readback"]
+           for n in ("engine.step", "engine.readback")}
+    from_spans = cpu["engine.step"] - cpu["engine.readback"] \
+        + sum(s[3]["cpu_us"] * 1e3 for s in flushes)
     d_host = streamed["after"][CPU_KEY] - streamed["before"][CPU_KEY]
     steps = sum(1 for s in spans if s[0] == "engine.step")
     assert d_host > 0 and steps >= 4
@@ -647,3 +674,116 @@ def test_metric_resolves_and_reads(name, probed):
                if not s.startswith(("wall_ns_", "cpu_ns_"))}
            for k in ("stats_open", "stats_close")}
     assert reader.read(dict(old, config={}), spec["args"]) is None
+
+
+# --------------------------------- (e) the hand-over, one loop turn at a time
+
+class _Turned(LLMServer):
+    """An LLMServer whose engine thread does nothing: the test turns the
+    loop itself (LLMServer._turn), so every hand-over has a known place."""
+
+    def _loop(self):
+        pass
+
+
+def test_hand_over_is_one_launch_late_and_at_once_when_the_engine_runs_dry():
+    import queue as queue_mod
+
+    server = _Turned(model_config={"n_layers": 1, "dtype": jnp.float32},
+                     engine_config=dict(ENGINE, seed=5))
+    server._thread.join(timeout=10)
+    eng, stats = server.engine, server.engine.stats
+    want = InferenceEngine(LlamaConfig.tiny(n_layers=1, dtype=jnp.float32),
+                           **dict(ENGINE, seed=5)).generate(
+        list(range(1, 12)), max_new_tokens=10)
+    q = queue_mod.Queue()
+    streamed = eng.add_request(list(range(1, 12)), 10)
+    server._token_qs[streamed] = q
+    called = eng.add_request(list(range(1, 12)), 10)
+    done = threading.Event()
+    server._events[called] = done
+
+    def drained():
+        out = []
+        while not q.empty():
+            out.append(q.get_nowait())
+        return out
+
+    elapsed0 = time.perf_counter_ns()
+    walls0 = sum(stats[k] for k in WALL_KEYS)
+    got, booked, turns = [], [], 0
+    while eng.has_work():
+        server._turn()
+        turns += 1
+        # what this turn's step booked is held, not delivered ...
+        held = server._held
+        assert held is not None and held[1].get(streamed)
+        booked.append(list(held[1][streamed]))
+        # ... and what the turn delivered (from inside its step, after its
+        # dispatch) is the step before's, whole and nothing else
+        items = drained()
+        assert items == ([booked[-2]] if turns > 1 else []), (turns, items)
+        got += [t for item in items for t in item]
+        # the engine never ran dry: every hand-over rode a dispatch
+        assert stats["publishes"] == stats["publishes_overlapped"] \
+            == turns - 1
+        assert not done.is_set() and called not in server._results
+    assert turns >= 3 and streamed in server._held[0]
+    # the engine has run dry: the next turn hands over at once, then sleeps
+    server._wake.set()
+    server._turn()
+    assert server._held is None
+    items = drained()
+    assert items == [booked[-1], None]              # the tokens, then the end
+    got += items[0]
+    assert got == want
+    assert done.is_set() and server._results[called] == want
+    assert stats["publishes"] == stats["publishes_overlapped"] + 1 == turns
+    # serve.publish ran inside engine.step: the ten walls still cover the
+    # turns, each phase once (a turn's own few lines are in none)
+    walls = sum(stats[k] for k in WALL_KEYS) - walls0
+    elapsed = time.perf_counter_ns() - elapsed0
+    assert 0.5 * elapsed < walls <= elapsed
+    assert stats["wall_ns_publish"] > 0 and stats["wall_ns_wait"] > 0
+    # an idle turn with nothing held publishes nothing
+    server._wake.set()
+    server._turn()
+    assert stats["publishes"] == turns
+
+
+def test_publish_overlap_pct_reads_the_two_counters():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    entry = bench["per_layer"][-1]
+    assert entry["name"] == "publish_overlap_pct"   # appended, at the end
+    assert entry["workloads"] == CELLS and entry["moves"] == "out_tok_per_s"
+    assert entry["source"] == "program_counter" and entry["unit"] == "%"
+    assert entry["layer"] == next(
+        m for m in bench["per_layer"]
+        if m["name"] == "batch_occupancy_pct")["layer"]
+    with open(os.path.join(ROOT, "benchmark", "metrics",
+                           "publish_overlap_pct.json")) as f:
+        spec = json.load(f)
+    assert spec["name"] == entry["name"]
+    reader = importlib.import_module("benchmark.readers." + spec["reader"])
+    server = LLMServer(model_config={"n_layers": 1, "dtype": jnp.float32},
+                       engine_config=ENGINE)
+    a = server.stats()
+    for n in (11, 7, 20):
+        server({"prompt_ids": list(range(1, n)), "max_tokens": 9})
+    b = server.stats()
+    data = {"stats_open": a, "stats_close": b, "config": {}, "window_s": 1.0}
+    value = reader.read(data, spec["args"])
+    # three lone requests: each ends over one flush, the rest overlapped
+    n, over = (b[k] - a[k] for k in ("publishes", "publishes_overlapped"))
+    assert n - over == 3 and over >= 3
+    assert value == pytest.approx(100.0 * over / n) and 0 < value < 100
+    # the parent's stats lack the two keys: nothing, not an error
+    old = {k: {s: v for s, v in data[k].items()
+               if not s.startswith("publishes")}
+           for k in ("stats_open", "stats_close")}
+    assert reader.read(dict(old, config={}), spec["args"]) is None
+    # an engine nobody serves has no such counters either
+    eng = InferenceEngine(LlamaConfig.tiny(n_layers=1, dtype=jnp.float32),
+                          **ENGINE)
+    assert "publishes" not in eng.stats

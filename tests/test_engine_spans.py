@@ -128,15 +128,138 @@ def test_step_kinds_equal_the_dispatch_counters(profiled):
     assert admitted == 5
 
 
-def test_serve_spans_sit_between_steps(profiled):
+def _publishes(events):
+    """serve.publish spans, split: (nested: [(publish, its step)], flushes:
+    those no engine.step overlaps)."""
+    steps = [e for e in events if e[0] == "engine.step"]
+    nested, flushes = [], []
+    for p in (e for e in events if e[0] == "serve.publish"):
+        over = [s for s in steps if s[1] < p[2] and p[1] < s[2]]
+        if over:
+            assert len(over) == 1 and over[0][1] <= p[1] \
+                and p[2] <= over[0][2], p       # wholly inside ONE step
+            nested.append((p, over[0]))
+        else:
+            flushes.append(p)
+    return nested, flushes
+
+
+def test_publish_lies_between_dispatch_and_readback_of_the_next_step(
+        profiled):
+    """A step's tokens go to their waiters from inside the NEXT step,
+    with that step's program on the device: after engine.dispatch ended
+    and before engine.readback began."""
+    events, _, _ = profiled
+    nested, _ = _publishes(events)
+    assert len(nested) >= 3
+    for p, step in nested:
+        kids = {c[0]: c for c in events if c[0] in CHILDREN
+                and step[1] <= c[1] and c[2] <= step[2]}
+        assert step[3]["kind"] != "none"
+        assert kids["engine.dispatch"][2] <= p[1], (p, kids)
+        assert p[2] <= kids["engine.readback"][1], (p, kids)
+    # a run of at least three consecutive steps, each with its publish
+    steps = [e for e in events if e[0] == "engine.step"]
+    with_publish = {id(step) for _, step in nested}
+    run = best = 0
+    for step in steps:
+        run = run + 1 if id(step) in with_publish else 0
+        best = max(best, run)
+    assert best >= 3
+    # __call__ requests wait on an event, not a stream
+    assert all(p[3]["streams"] == 0 for p, _ in nested)
+
+
+def test_a_publish_outside_a_step_is_a_flush_before_a_sleep(profiled):
+    """What no dispatch will carry is handed over at once: the only
+    serve.publish outside engine.step is the one the loop makes when the
+    engine has run dry, followed by serve.wait with no step between; and
+    serve.wait never overlaps a step."""
     events, _, _ = profiled
     steps = [e for e in events if e[0] == "engine.step"]
-    publishes = [e for e in events if e[0] == "serve.publish"]
-    assert len(publishes) >= len(steps) - 1     # one after every step
-    for p in publishes + [e for e in events if e[0] == "serve.wait"]:
-        assert not any(s[1] < p[2] and p[1] < s[2] for s in steps), p
-    # __call__ requests wait on an event, not a stream
-    assert all(p[3]["streams"] == 0 for p in publishes)
+    waits = [e for e in events if e[0] == "serve.wait"]
+    for w in waits:
+        assert not any(s[1] < w[2] and w[1] < s[2] for s in steps), w
+    nested, flushes = _publishes(events)
+    assert flushes                       # the five requests did end
+    for p in flushes:
+        after = [e for e in events
+                 if e[0] in ("engine.step", "serve.wait") and e[1] >= p[2]]
+        assert not after or after[0][0] == "serve.wait", (p, after[:1])
+    # every step that handed nothing over had nothing held: the step
+    # before it booked no token (a mixed step of prefill chunks only)
+    with_publish = {id(step) for _, step in nested}
+    for prev, step in zip(steps, steps[1:]):
+        if id(step) not in with_publish and step[3]["kind"] != "none":
+            chunks_only = prev[3]["kind"] == "mixed" \
+                and prev[3]["decode_rows"] == 0
+            flushed = any(prev[2] <= f[1] and f[2] <= step[1]
+                          for f in flushes)
+            assert chunks_only or flushed, (prev, step)
+
+
+def test_publish_counters_count_the_spans(profiled):
+    """engine.stats `publishes` / `publishes_overlapped`: every hand-over
+    that had something, and those made under a running program."""
+    events, before, after = profiled
+    nested, flushes = _publishes(events)
+    # the trace stops with the loop asleep: every hand-over is a span
+    assert after["publishes"] - before["publishes"] \
+        == len(nested) + len(flushes)
+    assert after["publishes_overlapped"] - before["publishes_overlapped"] \
+        == len(nested)
+    assert 0 < after["publishes_overlapped"] <= after["publishes"]
+
+
+def test_step_without_a_callable_is_unchanged_and_with_one_calls_it_once():
+    """generate(), llm/batch.py and every test that steps the engine
+    itself pass nothing: the same tokens, the same counters; a callable is
+    called once a launching step, between its dispatch and its readback,
+    and never by a step that launches nothing."""
+    def engine():
+        return InferenceEngine(
+            LlamaConfig.tiny(n_layers=1, dtype=jnp.float32), page_size=8,
+            total_pages=32, max_batch=2, max_seq_len=64, prefill_chunk=16,
+            decode_chunk=2, seed=3)
+
+    prompts = [list(range(1, 12)), list(range(2, 9)), list(range(4, 30))]
+    plain, hooked = engine(), engine()
+    calls = []
+
+    def hook():
+        s = hooked.stats
+        calls.append((s["wall_ns_dispatch"], s["wall_ns_readback"],
+                      s["ragged_dispatches"] + s["decode_dispatches"]))
+
+    done_plain, done_hooked = {}, {}
+    for eng, done, kw in ((plain, done_plain, {}),
+                          (hooked, done_hooked, {"after_dispatch": hook})):
+        rids = [eng.add_request(p, max_new_tokens=5) for p in prompts]
+        while eng.has_work():
+            seen = (eng.stats["wall_ns_dispatch"],
+                    eng.stats["wall_ns_readback"])
+            n_calls = len(calls)
+            got = eng.step(**kw)
+            done.update({rids.index(r): t for r, t in got.items()})
+            if kw and len(calls) > n_calls:
+                # dispatched (its wall counted), not read back nor booked
+                assert len(calls) == n_calls + 1
+                assert calls[-1][0] > seen[0] and calls[-1][1] == seen[1]
+                assert calls[-1][2] == eng.stats["ragged_dispatches"] \
+                    + eng.stats["decode_dispatches"] - 1
+    assert done_plain == done_hooked and len(done_plain) == 3
+    counters = ("steps", "decode_steps", "decode_tokens", "prefill_tokens",
+                "decode_dispatches", "ragged_dispatches")
+    assert {k: plain.stats[k] for k in counters} \
+        == {k: hooked.stats[k] for k in counters}
+    assert len(calls) == hooked.stats["ragged_dispatches"] \
+        + hooked.stats["decode_dispatches"]
+    # a step with nothing to launch calls nothing
+    assert not hooked.has_work()
+    assert hooked.step(after_dispatch=hook) == {}
+    assert len(calls) == hooked.stats["ragged_dispatches"] \
+        + hooked.stats["decode_dispatches"]
+    assert "publishes" not in plain.stats       # the serve loop's counters
 
 
 def test_request_log_tells_in_flight_wait_from_refusal():
