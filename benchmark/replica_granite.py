@@ -1,0 +1,20 @@
+"""The probed replica for the granite-4.0-h block: replica.py's probes
+unchanged, with the reference check bound to that block's plain reference
+(reference_granite.py) instead of the Llama/Mistral one."""
+
+from __future__ import annotations
+
+from typing import Any, Dict
+
+from benchmark.replica import ProbedLLMServer
+
+
+class ProbedGraniteServer(ProbedLLMServer):
+
+    def bench_reference_check(self, request: Dict[str, Any]
+                              ) -> Dict[str, Any]:
+        from benchmark import reference_granite
+        return reference_granite.score_greedy(
+            self.engine.params, reference_granite.dims_of(self.engine.cfg),
+            list(request["prompt_ids"]), list(request["token_ids"]),
+            int(request["pad_to"]))

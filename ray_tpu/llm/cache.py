@@ -19,16 +19,20 @@ Prefix cache design:
   - pages whose ONLY reference is the cache's are evictable, LRU order;
     the engine evicts under allocator pressure, so the cache is free
     HBM turned into hit-rate rather than reserved memory;
-  - a page holds KV and nothing else. A configuration with conv layers
-    (recurrent state per batch slot, ``make_kv_cache``) gets NO prefix
-    cache: a hit would restore the KV of the matched pages and not the
-    conv state at that position (``prefix_cache_supported``; saving the
-    state at page boundaries is open, ROADMAP Queue 2).
+  - a page holds KV and nothing else. A configuration with conv or
+    state-space layers (recurrent state per batch slot,
+    ``make_kv_cache``) gets NO prefix cache: a hit would restore the KV
+    of the matched pages and not the recurrent state at that position
+    (``prefix_cache_supported``; saving the state at page boundaries is
+    open, ROADMAP Queue 2).
 
 Two kinds of device state live in the one pool pytree: pages, for the
-attention layers only, allocated and shared by the page; and the conv
-layers' state, one fixed-size entry a batch slot, owned by whoever holds
-the slot and never allocated or freed.
+attention layers only, allocated and shared by the page; and the
+recurrent layers' state (``STATE_LEAVES``), one fixed-size entry a batch
+slot, owned by whoever holds the slot and never allocated or freed: a
+conv layer's last inputs (kilobytes a slot), a state-space layer's matrix
+state and its own conv's last inputs (megabytes a slot and layer: there
+``max_batch`` is a memory decision as ``total_pages`` is).
 """
 
 from __future__ import annotations
@@ -40,7 +44,7 @@ from typing import Dict, List, Optional, Tuple
 
 import jax.numpy as jnp
 
-from ray_tpu.models.llama import ATTENTION, CONV, LlamaConfig
+from ray_tpu.models.llama import ATTENTION, CONV, MAMBA, LlamaConfig
 
 logger = logging.getLogger(__name__)
 
@@ -244,8 +248,11 @@ class PrefixCache:
         return freed
 
 
-#: the pool's leaf that is not pages: a conv layer's recurrent state
-STATE_LEAF = "conv"
+#: the pool's leaves that are not pages, all [layers of the kind, slots + 1,
+#: ...]: a conv layer's recurrent state; a state-space layer's matrix state
+#: and the last inputs of its conv
+STATE_LEAF, SSM_LEAF, SSM_CONV_LEAF = "conv", "ssm", "ssm_conv"
+STATE_LEAVES = (STATE_LEAF, SSM_LEAF, SSM_CONV_LEAF)
 
 
 def make_kv_cache(cfg: LlamaConfig, total_pages: int, page_size: int,
@@ -296,6 +303,20 @@ def make_kv_cache(cfg: LlamaConfig, total_pages: int, page_size: int,
     tokens write. It rides the same dict, so it is donated, carried and
     updated in place with the pages. Nothing ever zeroes a slot: a row
     whose first token has position 0 reads zeros instead of its slot.
+
+    A configuration with state-space layers gets two, under the same
+    conventions: ``SSM_LEAF`` [n_ssm, max_batch + 1, ssm_state, ssm_heads
+    * ssm_head_dim], a layer's matrix state for each slot (a head's [P,
+    N] matrix lies transposed, its N columns as rows over all heads'
+    values: the layout the update kernel reads, ops/ssm.py), and
+    ``SSM_CONV_LEAF`` [n_ssm, max_batch + 1, ssm_conv - 1, ssm_channels],
+    the last inputs of its conv (x, B and C together, before the bias and
+    the SiLU), oldest first, both in cfg.dtype. The step reads and writes
+    both by dynamic slices only (llm/model.py ``_SsmConvState``,
+    ops/ssm.py): around a gather or a scatter of rows XLA re-laid the
+    whole conv leaf twice a layer and copied half the state leaf
+    (PERF.md, PR 37). The first is the largest thing a slot
+    owns by three orders: H P N values a layer.
     """
     if kv_dtype not in (None, "model", "int8"):
         raise ValueError(f"kv_dtype must be 'model' or 'int8', "
@@ -328,6 +349,17 @@ def make_kv_cache(cfg: LlamaConfig, total_pages: int, page_size: int,
                              "make_kv_cache needs max_batch")
         kv[STATE_LEAF] = jnp.zeros(
             (n_conv, max_batch + 1, cfg.conv_kernel - 1, cfg.dim), cfg.dtype)
+    n_ssm = len(cfg.layers_of(MAMBA))
+    if n_ssm:
+        if max_batch < 1:
+            raise ValueError("state-space layers keep state per batch "
+                             "slot: make_kv_cache needs max_batch")
+        kv[SSM_LEAF] = jnp.zeros(
+            (n_ssm, max_batch + 1, cfg.ssm_state,
+             cfg.ssm_heads * cfg.ssm_head_dim), cfg.dtype)
+        kv[SSM_CONV_LEAF] = jnp.zeros(
+            (n_ssm, max_batch + 1, cfg.ssm_conv - 1, cfg.ssm_channels),
+            cfg.dtype)
     return kv
 
 
@@ -341,7 +373,7 @@ def latent_row_width(cfg: LlamaConfig, lane_pad: bool = False) -> int:
 def prefix_cache_supported(cfg: LlamaConfig) -> bool:
     """Whether a page-aligned prefix hit restores ALL of a sequence's
     state at that position: true where pages are the only state."""
-    return not cfg.layers_of(CONV)
+    return not (cfg.layers_of(CONV) or cfg.layers_of(MAMBA))
 
 
 def kv_cache_tag(cfg: LlamaConfig, kv_dtype: Optional[str]) -> str:

@@ -92,7 +92,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.llm.cache import (SCRATCH_PAGE, STATE_LEAF, PageAllocator,
+from ray_tpu.llm.cache import (SCRATCH_PAGE, STATE_LEAVES, PageAllocator,
                                PrefixCache, SequenceState, kv_cache_tag,
                                prefix_cache_supported)
 from ray_tpu.llm import model as M
@@ -268,18 +268,21 @@ class InferenceEngine:
         self._param_bytes = sum(x.nbytes
                                 for x in jax.tree.leaves(self.params))
         self._kv_bytes = sum(x.nbytes for x in self.kv.values())
-        # conv layers' state: one entry a batch slot (+ the scratch slot
-        # padding writes), part of the pool pytree and of _kv_bytes
-        self._has_state = STATE_LEAF in self.kv
-        self._state_bytes = self.kv[STATE_LEAF].nbytes \
-            if self._has_state else 0
+        # recurrent layers' state (conv, state-space): one entry a batch
+        # slot (+ the scratch slot padding writes), part of the pool
+        # pytree and of _kv_bytes; what ONE slot owns of it is what
+        # max_batch costs beside the pages
+        state = [x for k, x in self.kv.items() if k in STATE_LEAVES]
+        self._has_state = bool(state)
+        self._state_bytes = sum(x.nbytes for x in state)
+        self._state_bytes_per_slot = self._state_bytes // (max_batch + 1)
         # the pool's row as held (a latent pool's is wider than the
         # latent where the kernels need whole lanes) and what one token
         # costs in one layer's pages, over every page leaf
         self._kv_row_width = self.kv["k"].shape[-1]
         self._kv_token_layer_bytes = sum(
             x.nbytes // (x.shape[0] * x.shape[1] * x.shape[3])
-            for k, x in self.kv.items() if k != STATE_LEAF)
+            for k, x in self.kv.items() if k not in STATE_LEAVES)
         self._held_bytes: Dict[int, int] = {}
         for leaf in jax.tree.leaves((self.params, self.kv)):
             for shard in leaf.addressable_shards:
@@ -294,11 +297,13 @@ class InferenceEngine:
         use_prefix = GlobalConfig.llm_prefix_cache \
             if prefix_cache is None else prefix_cache
         if use_prefix and not prefix_cache_supported(cfg):
-            # a hit would restore the matched pages' KV and run the conv
-            # layers on zero state: no match is taken at all
+            # a hit would restore the matched pages' KV and run the
+            # recurrent layers on zero state: no match is taken at all
             logger.warning(
-                "prefix cache off: this configuration has conv layers, "
-                "whose state a page-aligned prefix hit does not restore")
+                "prefix cache off: this configuration has conv or "
+                "state-space layers, whose state per batch slot (%d bytes) "
+                "a page-aligned prefix hit does not restore",
+                self._state_bytes_per_slot)
             use_prefix = False
         self.prefix: Optional[PrefixCache] = \
             PrefixCache(self.allocator, page_size,
@@ -336,8 +341,10 @@ class InferenceEngine:
             # the state's size, and rows that started at position 0 (a
             # new or re-prefilled sequence: the step read zeros for its
             # slot's state instead of what the last owner left)
-            self.stats.update(state_bytes=self._state_bytes,
-                              state_resets=0)
+            self.stats.update(
+                state_bytes=self._state_bytes,
+                state_bytes_per_slot=self._state_bytes_per_slot,
+                state_resets=0)
         if cfg.kv_lora_rank:
             # a latent pool: what a token costs a layer, and the row held
             self.stats.update(
@@ -436,7 +443,8 @@ class InferenceEngine:
         programs took ("kernel" | "reference" — chosen from the platform,
         so a deployment can assert it never fell back), the resident
         step-program count, and per device the bytes of weights + KV
-        pages (+ conv state: ``state_bytes`` of ``kv_bytes``; beside
+        pages (+ recurrent state: ``state_bytes`` of ``kv_bytes``,
+        ``state_bytes_per_slot`` of that a batch slot; beside
         ``kv_bytes`` what a token costs in one layer's pages and the
         pool's row width as held) it holds
         next to the allocator's own ``memory_stats()``
@@ -467,6 +475,7 @@ class InferenceEngine:
                 "kv_token_layer_bytes": self._kv_token_layer_bytes,
                 "kv_row_width": self._kv_row_width,
                 "state_bytes": self._state_bytes,
+                "state_bytes_per_slot": self._state_bytes_per_slot,
                 "devices": per_device}
 
     # ---------------------------------------------------------------- step

@@ -26,6 +26,17 @@ Depth does not unroll: the leading dense layers run once, then one
 (``_hybrid_layers``). A conv layer has no pages; its state is the last
 ``conv_kernel - 1`` inputs of its depthwise conv, per batch slot.
 
+A third operator, the selective state-space recurrence (``"mamba"`` in
+``layer_types``, ``_mamba``; granite-4.0-h is the first such block): per
+batch slot a MATRIX state [ssm_heads, ssm_head_dim, ssm_state] a layer,
+megabytes where the conv keeps kilobytes, beside the last inputs of its
+own conv over x, B and C. One-token rows update it in place (a Pallas
+kernel, ops/ssm.py), chunk rows scan it in the chunked form. That block
+also has attention with NO positional embedding (``rope``), a score scale
+that is a field (``attn_scale``), and multipliers on the embedding, on
+both branches of every layer and under the logits (``embed_scale``,
+``residual_scale``, ``logits_divisor``).
+
 ONE step program for everything (`_ragged_step_body`): the engine packs
 decode tokens and prefill-chunk tokens into a single RAGGED batch
 (`ops.paged_attention.ragged_paged_attention`), so prefill chunks and
@@ -40,7 +51,8 @@ The KV pool is a dict pytree {"k", "v"[, "k_scale", "v_scale"]},
 the ATTENTION layers stacked on the leading axis (every layer, unless
 the configuration names conv layers), and, with conv layers, one more
 leaf {"conv"}: their state [n_conv, slots + 1, taps - 1, dim], a slot a
-batch slot and a scratch slot last (llm/cache.py). ONE buffer each in
+batch slot and a scratch slot last (llm/cache.py); with state-space
+layers {"ssm", "ssm_conv"} under the same conventions. ONE buffer each in
 ONE layout, donated to the step program and updated in place. The pool
 is a CARRY of the layer
 scan (and so of the decode loop's step scan), never a scanned input or
@@ -85,22 +97,31 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu.llm import tp as TP
-from ray_tpu.llm.cache import SCRATCH_PAGE, STATE_LEAF, make_kv_cache
-from ray_tpu.models.llama import (ATTENTION, CONV, LlamaConfig, Params,
+from ray_tpu.llm.cache import (SCRATCH_PAGE, SSM_CONV_LEAF, SSM_LEAF,
+                               STATE_LEAF, STATE_LEAVES, make_kv_cache)
+from ray_tpu.models.llama import (ATTENTION, CONV, MAMBA, LlamaConfig, Params,
                                   _rmsnorm, _rope, _rope_pairs, init_params)
-from ray_tpu.ops import moe
+from ray_tpu.ops import moe, ssm
 from ray_tpu.ops.paged_attention import (kernels_supported,
                                          ragged_paged_attention,
                                          write_ragged_kv)
 from ray_tpu.parallel.mesh import shard_map_compat
 from ray_tpu.util import compile_tracker
 
-# {"k", "v"[, "k_scale", "v_scale"][, "conv"]}, or a latent pool's {"k"}
+# {"k", "v"[, "k_scale", "v_scale"][, "conv"][, "ssm", "ssm_conv"]}, or a
+# latent pool's {"k"}
 KVCache = dict  # (llm/cache.py)
 
 
 def _maybe_psum(x, tp_axis):
     return lax.psum(x, tp_axis) if tp_axis else x
+
+
+def _residual(x, y, cfg: LlamaConfig):
+    """x + residual_scale * y: a branch of a layer joins the stream."""
+    if cfg.residual_scale != 1.0:
+        y = y * jnp.asarray(cfg.residual_scale, y.dtype)
+    return x + y
 
 
 def _project_qkv(lp, h, cfg: LlamaConfig):
@@ -133,7 +154,8 @@ def _mlp(lp, x, cfg: LlamaConfig, tp_axis=None):
     up = h @ lp["w_up"].astype(cd)
     # w_down is row-parallel under tp: each shard holds ffn/tp rows, the
     # partial products sum across the axis (Megatron second collective)
-    return x + _maybe_psum((gate * up) @ lp["w_down"].astype(cd), tp_axis)
+    return _residual(
+        x, _maybe_psum((gate * up) @ lp["w_down"].astype(cd), tp_axis), cfg)
 
 
 def _moe_mlp(lp, experts, layer, x, valid, cfg: LlamaConfig, impl):
@@ -157,7 +179,7 @@ def _moe_mlp(lp, experts, layer, x, valid, cfg: LlamaConfig, impl):
             gate = jax.nn.silu(h @ lp["w_shared_gate"].astype(cd))
             y = y + (gate * (h @ lp["w_shared_up"].astype(cd))) \
                 @ lp["w_shared_down"].astype(cd)
-    return x + y, counters
+    return _residual(x, y, cfg), counters
 
 
 #: the expert weights stay out of the layer scan's sliced inputs
@@ -178,16 +200,23 @@ SCOPE_ATTENTION, SCOPE_CONV = "attention", "short_conv"
 #: and the kernel (wq, w_kva, the latent's norm, the rotary part, the two
 #: absorbed products, wo); and the shared expert
 SCOPE_MLA_PROJ, SCOPE_SHARED = "mla_proj", "moe_shared"
+#: ... the state-space operator: everything around the recurrence (in_proj,
+#: the conv, the gate and norm, out_proj); the one-token update (the Pallas
+#: kernel); the chunk rows' scan
+SCOPE_SSM_PROJ, SCOPE_SSM_UPDATE, SCOPE_SSM_SCAN = \
+    "ssm_proj", "ssm_update", "ssm_scan"
 
 
 class _ConvRows(NamedTuple):
-    """What the conv operator needs of the ragged batch: each token's
-    position and state slot (None: token t is slot t's one token), and the
-    rows' spans."""
+    """What an operator with state per batch slot needs of the ragged
+    batch: each token's position and state slot (None: token t is slot t's
+    one token), the rows' spans, and how many leading rows hold at most one
+    token (the step's static hint)."""
     token_pos: jax.Array
     token_state: Optional[jax.Array]
     q_start: jax.Array
     q_len: jax.Array
+    decode_rows: int = 0
 
 
 def _shift(a, n: int, fill):
@@ -198,6 +227,51 @@ def _shift(a, n: int, fill):
     return jnp.concatenate([pad, a[:-n]])
 
 
+def _conv_window(v, fetch, rows: _ConvRows, K: int):
+    """[v[t], v[t-1], .., v[t-(K-1)]] of a depthwise causal conv's input v
+    [T, ch] over a RAGGED batch: a token's earlier inputs are the tokens
+    before it in its own row where the row reaches back far enough, and
+    otherwise come from its slot's saved inputs, ``fetch()`` [T, K-1, ch]:
+    for each token the last K-1 inputs of its sequence before this step
+    (oldest first). A row whose first token has position 0 reads zeros,
+    whatever its slot holds: a new sequence, or one re-prefilled after a
+    preemption, needs no reset."""
+    T = v.shape[0]
+    pos, slot = rows.token_pos, rows.token_state
+    if slot is None:
+        # the decode loop: every token is a row of its own, in slot t
+        saved = fetch()
+        saved = jnp.where((pos > 0)[:, None, None], saved, 0)
+        return [v] + [saved[:, K - 1 - s] for s in range(1, K)]
+    # reach[t]: how many tokens before t are t's own row's, up to
+    # K-1 (a slot is in one row a step, at consecutive positions)
+    same = (slot == _shift(slot, 1, -1)) \
+        & (pos == _shift(pos, 1, -1) + 1)
+    chain, chains = jnp.ones(T, bool), []
+    for s in range(1, K):
+        chain = chain & _shift(same, s - 1, False)
+        chains.append(chain)
+    reach = sum(c.astype(jnp.int32) for c in chains)
+    saved = fetch()                                # [T, K-1, ch]
+    saved = jnp.where((pos - reach > 0)[:, None, None], saved, 0)
+    prev = [v]
+    for s in range(1, K):
+        # v[t - s]: in the chunk, or entry K-1-(s-reach) of the slot
+        at = jnp.clip(K - 1 - s + reach, 0, K - 2)
+        old = jnp.take_along_axis(
+            saved, at[:, None, None], axis=1)[:, 0]
+        prev.append(jnp.where(chains[s - 1][:, None],
+                              _shift(v, s, 0), old))
+    return prev
+
+
+def _conv_upto(prev):
+    """[T, K-1, ch]: the K-1 inputs up to and including each token, oldest
+    first (``prev``: ``_conv_window``'s): what a row leaves in its slot is
+    its last token's."""
+    return jnp.stack(prev[len(prev) - 2:0:-1] + [prev[0]], axis=1)
+
+
 def _short_conv(lp, l, x, state, rows: _ConvRows, cfg: LlamaConfig):
     """The gated short convolution of one layer, on entry ``l`` of the
     state (the layer's ordinal among the conv layers):
@@ -206,57 +280,30 @@ def _short_conv(lp, l, x, state, rows: _ConvRows, cfg: LlamaConfig):
         c[t] = sum_j w[j] * v[t - (K-1) + j]     depthwise, causal
         x' = x + (C * c) W_out
 
-    over a RAGGED batch: a token's earlier inputs are the tokens before it
-    in its own row where the row reaches back far enough, and otherwise
-    come from the row's slot of ``state`` [n_conv, slots + 1, K-1, d],
-    which holds the sequence's last K-1 inputs v (oldest first). A row
-    whose first token has position 0 reads zeros, whatever its slot holds:
-    a new sequence, or one re-prefilled after a preemption, needs no
-    reset. Each row's last K-1 inputs go back to its slot; rows without
-    tokens, and padding, go to the scratch slot (the last). v is rounded
-    to the compute dtype before it is used or stored, so a sequence
-    computes the same values however its tokens fall into chunks; the taps
-    are summed in float32. Returns (x', state)."""
+    over a RAGGED batch (``_conv_window``): the state [n_conv, slots + 1,
+    K-1, d] holds a sequence's last K-1 inputs v (oldest first). Each
+    row's last K-1 inputs go back to its slot; rows without tokens, and
+    padding, go to the scratch slot (the last). v is rounded to the
+    compute dtype before it is used or stored, so a sequence computes the
+    same values however its tokens fall into chunks; the taps are summed
+    in float32. Returns (x', state)."""
     cd = cfg.dtype
     K = cfg.conv_kernel
     T = x.shape[1]
     scratch = state.shape[1] - 1
-    pos, slot = rows.token_pos, rows.token_state
+    slot = rows.token_state
     with jax.named_scope(SCOPE_CONV):
         h = _rmsnorm(x, lp["conv_norm"], cfg.norm_eps)[0]
         B, C, u = jnp.split(h @ lp["w_in"].astype(cd), 3, axis=-1)
         v = B * u                                          # [T, d]
-        if slot is None:
-            # the decode loop: every token is a row of its own, in slot t
-            saved = lax.dynamic_slice_in_dim(state[l], 0, T, axis=0)
-            saved = jnp.where((pos > 0)[:, None, None], saved, 0)
-            prev = [v] + [saved[:, K - 1 - s] for s in range(1, K)]
-        else:
-            # reach[t]: how many tokens before t are t's own row's, up to
-            # K-1 (a slot is in one row a step, at consecutive positions)
-            same = (slot == _shift(slot, 1, -1)) \
-                & (pos == _shift(pos, 1, -1) + 1)
-            chain, chains = jnp.ones(T, bool), []
-            for s in range(1, K):
-                chain = chain & _shift(same, s - 1, False)
-                chains.append(chain)
-            reach = sum(c.astype(jnp.int32) for c in chains)
-            saved = state[l, slot]                         # [T, K-1, d]
-            saved = jnp.where((pos - reach > 0)[:, None, None], saved, 0)
-            prev = [v]
-            for s in range(1, K):
-                # v[t - s]: in the chunk, or entry K-1-(s-reach) of the slot
-                at = jnp.clip(K - 1 - s + reach, 0, K - 2)
-                old = jnp.take_along_axis(
-                    saved, at[:, None, None], axis=1)[:, 0]
-                prev.append(jnp.where(chains[s - 1][:, None],
-                                      _shift(v, s, 0), old))
+        prev = _conv_window(
+            v, lambda: lax.dynamic_slice_in_dim(state[l], 0, T, axis=0)
+            if slot is None else state[l, slot], rows, K)
         w = lp["w_conv"].astype(jnp.float32)               # [K, d]
         c = sum(w[K - 1 - s] * prev[s].astype(jnp.float32)
                 for s in range(K))
         y = (C.astype(jnp.float32) * c).astype(cd) @ lp["w_out"].astype(cd)
-        # the K-1 inputs up to and including each token, oldest first
-        upto = jnp.stack(prev[K - 2:0:-1] + [v], axis=1)   # [T, K-1, d]
+        upto = _conv_upto(prev)
         if slot is None:
             state = lax.dynamic_update_slice(
                 state, upto[None].astype(state.dtype), (l, 0, 0, 0))
@@ -265,7 +312,140 @@ def _short_conv(lp, l, x, state, rows: _ConvRows, cfg: LlamaConfig):
             row_slot = jnp.where(rows.q_len > 0, slot[last], scratch)
             state = state.at[l, row_slot].set(
                 upto[last].astype(state.dtype))
-    return x + y[None], state
+    return _residual(x, y[None], cfg), state
+
+
+class _SsmConvState:
+    """A state-space layer's conv inputs in ``SSM_CONV_LEAF`` [layers,
+    slots + 1, K-1, ch], read and written as ONE block of a layer's slots,
+    in the mixed step as in the decode loop: the step's leading
+    ``decode_rows`` tokens are slots 0.. in order (a row without a token
+    there keeps what its slot holds: a sequence between two chunks owns
+    it), and a chunk row's slot is picked out of the block, and put back
+    into it, by a one-hot product and a select. No gather, scatter or
+    one-slot slice touches the leaf: those want the slots outside the
+    tiles, the block wants them on the sublanes beside the tokens, and
+    XLA re-laid all 121 MB of it twice a layer between the two (PERF.md,
+    PR 37)."""
+
+    def __init__(self, state, l, rows: _ConvRows, T: int):
+        self.state, self.l, self.rows, self.T = state, l, rows, T
+        slot = rows.token_state
+        S = state.shape[1]
+        self.n = T if slot is None else S       # slots the block holds
+        if slot is None:
+            return
+        Rd = self.Rd = min(rows.decode_rows, rows.q_start.shape[0])
+        self.active = (slot[:Rd] != S - 1)[:, None, None]
+        q_start, q_len = rows.q_start[Rd:], rows.q_len[Rd:]
+        self.last = jnp.clip(q_start + q_len - 1, 0, T - 1)
+        # [Rc, S]: the slot of each chunk row that has tokens
+        self.holds = (jnp.arange(S)[None] == slot[self.last][:, None]) \
+            & (q_len > 0)[:, None]
+        t = jnp.arange(Rd, T, dtype=jnp.int32)[:, None]
+        self.own = (t >= q_start[None]) & (t < (q_start + q_len)[None])
+
+    def fetch(self):
+        """For each token, its slot's saved inputs [T, K-1, ch]."""
+        self.block = lax.dynamic_slice(
+            self.state, (self.l, 0, 0, 0),
+            (1, self.n) + self.state.shape[2:])[0]
+        if self.rows.token_state is None:
+            return self.block
+        # one-hot products: exact in any dtype at the highest precision
+        pick = functools.partial(jnp.einsum, precision="highest")
+        dtype = self.block.dtype
+        per_row = pick("rs,skc->rkc", self.holds.astype(dtype), self.block)
+        return jnp.concatenate([
+            self.block[:self.Rd],
+            pick("tr,rkc->tkc", self.own.astype(dtype), per_row)])
+
+    def store(self, upto):
+        """The leaf with each row's last inputs (``upto`` [T, K-1, ch],
+        ``_conv_upto``'s) in its slot."""
+        upto = upto.astype(self.state.dtype)
+        block = upto
+        if self.rows.token_state is not None:
+            block = jnp.concatenate([
+                jnp.where(self.active, upto[:self.Rd],
+                          self.block[:self.Rd]), self.block[self.Rd:]])
+            for r in range(self.holds.shape[0]):
+                block = jnp.where(self.holds[r][:, None, None],
+                                  upto[self.last[r]][None], block)
+        return lax.dynamic_update_slice(self.state, block[None],
+                                        (self.l, 0, 0, 0))
+
+
+def _mamba(lp, l, x, kv, rows: _ConvRows, cfg: LlamaConfig, impl):
+    """The state-space operator (Mamba-2) of one layer, on entry ``l`` of
+    both of its state leaves (the layer's ordinal among the mamba layers);
+    H heads of P, state N, conv over ch = H P + 2 N channels:
+
+        [g (H P), u (ch), dt_raw (H)] = rms(x) [W_gate, W_xbc, W_dt]
+        c[t] = silu(b + sum_j w[j] * u[t - (K-1) + j])    depthwise, causal
+        [xs (H, P), B (N), C (N)] = split(c[t])
+        dt = softplus(dt_raw + dt_bias);  A = -exp(A_log)
+        S_h[t] = exp(dt_h A_h) S_h[t-1] + dt_h xs_h[t] B[t]^T
+        y_h[t] = S_h[t] C[t] + D_h xs_h[t]
+        x' = x + residual_scale * rms(y * silu(g); gate_norm) W_out
+
+    over a RAGGED batch. The conv takes its earlier inputs as the short
+    conv does (``_conv_window``, from ``_SsmConvState``: u in the compute
+    dtype, before the bias and the SiLU). The recurrence (ops/ssm.py, over
+    ``SSM_LEAF``): the leading ``rows.decode_rows`` rows are one token
+    each, in the slot their token names, and take the in-place update
+    kernel; the rest are chunk rows and take the chunked scan, each from
+    its slot's state (zeros where its first position is 0) and leaving its
+    last state there. A one-token row's token t is slot t's, or names the
+    scratch slot (the engine's packing: decode rows are the batch slots in
+    order). In the decode loop every token is such a row, token t in slot
+    t. dt, A, the conv and the recurrence are float32. Returns
+    (x', kv)."""
+    cd, f32 = cfg.dtype, jnp.float32
+    H, P, N, K = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_conv
+    di, T = H * P, x.shape[1]
+    state = kv[SSM_LEAF]
+    scratch = state.shape[1] - 1
+    pos, slot = rows.token_pos, rows.token_state
+    with jax.named_scope(SCOPE_SSM_PROJ):
+        h = _rmsnorm(x, lp["mamba_norm"], cfg.norm_eps)[0]
+        g, u, dt = (h @ lp[k].astype(cd)
+                    for k in ("w_gate", "w_xbc", "w_dt"))
+        conv = _SsmConvState(kv[SSM_CONV_LEAF], l, rows, T)
+        prev = _conv_window(u, conv.fetch, rows, K)
+        w = lp["w_conv"].astype(f32)                       # [K, ch]
+        c = jax.nn.silu(lp["b_conv"].astype(f32) + sum(
+            w[K - 1 - s] * prev[s].astype(f32) for s in range(K)))
+        conv_state = conv.store(_conv_upto(prev))
+        xs, B, C = jnp.split(c, [di, di + N], axis=-1)
+        xs = xs.reshape(T, H, P)
+        dt = jax.nn.softplus(dt.astype(f32) + lp["dt_bias"].astype(f32))
+        A, D = -jnp.exp(lp["A_log"].astype(f32)), lp["D"].astype(f32)
+    Rd = T if slot is None else min(rows.decode_rows, rows.q_start.shape[0])
+    ys = []
+    if Rd:
+        with jax.named_scope(SCOPE_SSM_UPDATE):
+            y, state = ssm.ssm_decode_update(
+                state, xs[:Rd], dt[:Rd], A, B[:Rd], C[:Rd], D,
+                jnp.arange(Rd, dtype=jnp.int32) if slot is None
+                else slot[:Rd], pos[:Rd] == 0, layer=l, impl=impl)
+            ys.append(y)
+    if T - Rd:
+        with jax.named_scope(SCOPE_SSM_SCAN):
+            q_start, q_len = rows.q_start[Rd:] - Rd, rows.q_len[Rd:]
+            first = jnp.clip(q_start, 0, T - Rd - 1)
+            y, state = ssm.ssm_chunk_scan(
+                state, xs[Rd:], dt[Rd:], A, B[Rd:], C[Rd:], D, pos[Rd:],
+                q_start, q_len,
+                jnp.where(q_len > 0, slot[Rd:][first], scratch), layer=l,
+                chunk=cfg.ssm_chunk, impl=impl)
+            ys.append(y)
+    with jax.named_scope(SCOPE_SSM_PROJ):
+        y = jnp.concatenate(ys).reshape(T, di) * jax.nn.silu(g.astype(f32))
+        y = _rmsnorm(y.astype(cd), lp["gate_norm"], cfg.norm_eps)
+        y = y @ lp["w_out"].astype(cd)
+    return _residual(x, y[None], cfg), \
+        {**kv, SSM_LEAF: state, SSM_CONV_LEAF: conv_state}
 
 
 def _latent_attention(lp, l, x, kv, cfg: LlamaConfig, token_pos, token_page,
@@ -314,7 +494,7 @@ def _latent_attention(lp, l, x, kv, cfg: LlamaConfig, token_pos, token_page,
         with jax.named_scope(SCOPE_MLA_PROJ):
             o = jnp.einsum("thr,hrv->thv", o.astype(cd),
                            lp["w_uv"].astype(cd)).reshape(1, T, -1)
-            x = x + o @ lp["wo"].astype(cd)
+            x = _residual(x, o @ lp["wo"].astype(cd), cfg)
     return x, {**kv, "k": pool}
 
 
@@ -359,6 +539,9 @@ def _hybrid_layers(layers, x, kv, cfg: LlamaConfig, attention, valid,
         op, ffn = kinds
         if op == ATTENTION:
             x, kv = attention(at("attn", ordinal[op]), ordinal[op], x, kv)
+        elif op == MAMBA:
+            x, kv = _mamba(at("mamba", ordinal[op]), ordinal[op], x, kv,
+                           rows, cfg, impl)
         else:
             x, state = _short_conv(at("conv", ordinal[op]), ordinal[op], x,
                                    kv[STATE_LEAF], rows, cfg)
@@ -386,7 +569,8 @@ def _hybrid_layers(layers, x, kv, cfg: LlamaConfig, attention, valid,
 
     carry = (x, kv, jnp.zeros(len(moe.COUNTERS), jnp.int32))
     carry, seen = run(carry, lead,
-                      dict.fromkeys((ATTENTION, CONV, "dense", "moe"), 0))
+                      dict.fromkeys((ATTENTION, CONV, MAMBA, "dense", "moe"),
+                                    0))
     per = collections.Counter(k for kinds in period for k in kinds)
     (x, kv, counters), _ = lax.scan(
         lambda carry, j: (run(carry, period, seen, j, per)[0], None),
@@ -394,16 +578,16 @@ def _hybrid_layers(layers, x, kv, cfg: LlamaConfig, attention, valid,
     return x, kv, counters if cfg.n_experts else None
 
 
-def _ragged_forward(params: Params, tokens: jax.Array,
-                    token_pos: jax.Array, token_page: jax.Array,
-                    token_slot: jax.Array, page_table: jax.Array,
-                    q_start: jax.Array, q_len: jax.Array,
-                    kv_len: jax.Array, kv: KVCache, cfg: LlamaConfig,
-                    tp_axis: Optional[str] = None,
-                    paged_impl: Optional[str] = None,
-                    max_q_len: Optional[int] = None,
-                    decode_rows: int = 0,
-                    token_state: Optional[jax.Array] = None):
+def _ragged_logits(params: Params, tokens: jax.Array,
+                   token_pos: jax.Array, token_page: jax.Array,
+                   token_slot: jax.Array, page_table: jax.Array,
+                   q_start: jax.Array, q_len: jax.Array,
+                   kv_len: jax.Array, kv: KVCache, cfg: LlamaConfig,
+                   tp_axis: Optional[str] = None,
+                   paged_impl: Optional[str] = None,
+                   max_q_len: Optional[int] = None,
+                   decode_rows: int = 0,
+                   token_state: Optional[jax.Array] = None):
     """ONE forward over a ragged mixed prefill+decode batch.
 
     tokens/token_pos: [T] the ragged token ids and absolute positions;
@@ -417,10 +601,8 @@ def _ragged_forward(params: Params, tokens: jax.Array,
     None there means the decode loop's layout: token t is slot t's one
     token.
 
-    Returns (next_tok [R], kv, counters): per row, argmax logits at its
-    LAST valid token — the next decode token for q_len==1 rows, the first
-    sampled token for a prefill chunk that just finished its prompt. Fused
-    in-program so the whole mixed step is ONE dispatch + ONE readback.
+    Returns (logits [R, vocab] float32, kv, counters): per row, the logits
+    at its LAST valid token (``_ragged_forward`` takes their argmax).
     ``counters`` is None for a dense configuration; with experts it is
     the step's routing counters (ops.moe.COUNTERS, summed over layers,
     valid tokens only: a padding token is one whose page is the scratch
@@ -435,6 +617,8 @@ def _ragged_forward(params: Params, tokens: jax.Array,
     T = tokens.shape[0]
     cd = cfg.dtype
     x = params["embed"].astype(cd)[tokens][None]          # [1, T, d]
+    if cfg.embed_scale != 1.0:
+        x = x * jnp.asarray(cfg.embed_scale, cd)
     quantized = "k_scale" in kv
     layers = params["layers"]
     # a padding token is one whose page is the scratch page
@@ -452,18 +636,20 @@ def _ragged_forward(params: Params, tokens: jax.Array,
         with jax.named_scope(SCOPE_ATTENTION):
             h = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
             q, k, v = _project_qkv(lp, h, cfg)            # [1, T, H, D]
-            q = _rope(q, token_pos, cfg.rope_theta)
-            k = _rope(k, token_pos, cfg.rope_theta)
+            if cfg.rope:
+                q = _rope(q, token_pos, cfg.rope_theta)
+                k = _rope(k, token_pos, cfg.rope_theta)
             hints = dict(layer=l, max_q_len=max_q_len,
                          decode_rows=decode_rows, impl=paged_impl)
-            hd, scale = q.shape[-1], {}
+            hd = q.shape[-1]
+            scale = dict(sm_scale=cfg.attn_scale) if cfg.attn_scale else {}
             if kv["k"].shape[-1] != hd:
                 # a pool whose rows are padded to whole lanes
                 # (make_kv_cache, lane_pad): zeros past head_dim add
                 # nothing to a score and come back as zeros
                 q, k, v = (jnp.pad(a, ((0, 0),) * 3 + (
                     (0, kv["k"].shape[-1] - hd),)) for a in (q, k, v))
-                scale = dict(sm_scale=hd ** -0.5)
+                scale = scale or dict(sm_scale=hd ** -0.5)
             kc, vc, ksc, vsc = write_ragged_kv(
                 kv["k"], kv["v"], k[0], v[0], token_page, token_slot,
                 kv.get("k_scale"), kv.get("v_scale"), q_start=q_start,
@@ -472,7 +658,8 @@ def _ragged_forward(params: Params, tokens: jax.Array,
                 q[0], kc, vc, page_table, q_start, q_len, kv_len,
                 k_scale=ksc, v_scale=vsc, **hints, **scale)
             o = o[..., :hd].reshape(1, T, -1).astype(cd)
-            x = x + _maybe_psum(o @ lp["wo"].astype(cd), tp_axis)
+            x = _residual(x, _maybe_psum(o @ lp["wo"].astype(cd), tp_axis),
+                          cfg)
         kv = {**kv, "k": kc, "v": vc}
         if quantized:
             kv["k_scale"], kv["v_scale"] = ksc, vsc
@@ -481,7 +668,7 @@ def _ragged_forward(params: Params, tokens: jax.Array,
     if cfg.hybrid:
         x, kv, counters = _hybrid_layers(
             layers, x, kv, cfg, attention, valid, paged_impl,
-            _ConvRows(token_pos, token_state, q_start, q_len))
+            _ConvRows(token_pos, token_state, q_start, q_len, decode_rows))
     else:
         if cfg.n_experts:
             # closed over, not scanned: a scan slices its inputs, and a
@@ -512,6 +699,18 @@ def _ragged_forward(params: Params, tokens: jax.Array,
     head = params["embed"] if cfg.tie_embeddings else params["lm_head"]
     logits = jnp.einsum("rd,vd->rv", xl.astype(cd), head.astype(cd),
                         preferred_element_type=jnp.float32)
+    if cfg.logits_divisor != 1.0:
+        logits = logits / cfg.logits_divisor
+    return logits, kv, counters
+
+
+def _ragged_forward(*args, **kwargs):
+    """``_ragged_logits`` with the argmax fused in-program, so the whole
+    mixed step is ONE dispatch + ONE readback: (next_tok [R], kv,
+    counters), per row the next decode token for q_len == 1 rows, the
+    first sampled token for a prefill chunk that just finished its
+    prompt."""
+    logits, kv, counters = _ragged_logits(*args, **kwargs)
     return jnp.argmax(logits, axis=-1).astype(jnp.int32), kv, counters
 
 
@@ -604,13 +803,13 @@ ragged_decode_loop = functools.partial(jax.jit, static_argnames=(
 def _copy_page_body(kv: KVCache, src, dst) -> KVCache:
     """Copy-on-write: duplicate one page across all layers — pages AND
     their int8 scales, one tree_map (a prefix-hit sequence about to
-    write into a shared page copies it first). The conv state, whose
-    second axis is batch slots and not pages, passes through. Plain body
+    write into a shared page copies it first). The state leaves, whose
+    second axis is batch slots and not pages, pass through. Plain body
     so StepPrograms can shard_map it over local head shards."""
     pages = jax.tree.map(
         lambda leaf: leaf.at[:, dst].set(
             lax.dynamic_index_in_dim(leaf, src, axis=1, keepdims=False)),
-        {k: leaf for k, leaf in kv.items() if k != STATE_LEAF})
+        {k: leaf for k, leaf in kv.items() if k not in STATE_LEAVES})
     return {**kv, **pages}
 
 

@@ -74,6 +74,15 @@ def tp_param_specs(cfg: LlamaConfig) -> Params:
 def validate_tp(cfg: LlamaConfig, tp: int) -> None:
     if tp < 2:
         raise ValueError(f"tp must be >= 2 for a sharded engine, got {tp}")
+    if cfg.beyond_llama_block:
+        raise NotImplementedError(
+            f"tp={tp} is not served for this block: mamba layers keep a "
+            f"matrix state [ssm_heads, ssm_head_dim, ssm_state] per batch "
+            f"slot with no partition spec here (its heads would shard with "
+            f"w_in's gate and x columns, while B, C and the conv over them "
+            f"are shared by all heads), and rope=False, attn_scale, "
+            f"embed_scale, residual_scale and logits_divisor are refused "
+            f"with them, untested under a shard (ROADMAP R10b)")
     if cfg.kv_lora_rank or cfg.shared_ffn_dim:
         raise NotImplementedError(
             f"tp={tp} is not served for this block: kv_lora_rank (latent "

@@ -36,8 +36,8 @@ from ray_tpu.parallel.ulysses import ulysses_attention_sharded
 Params = Dict[str, Any]
 
 #: a layer's operator, as the published configurations name it
-ATTENTION, CONV = "full_attention", "conv"
-LAYER_KINDS = (ATTENTION, CONV)
+ATTENTION, CONV, MAMBA = "full_attention", "conv", "mamba"
+LAYER_KINDS = (ATTENTION, CONV, MAMBA)
 #: spread of the seeded router selection bias. The top 4 of 64 sigmoid
 #: scores lie ~0.013 apart, so 0.02 changes the chosen set for about half
 #: the tokens and leaves the load near even (busiest expert 2.3 x the mean
@@ -98,6 +98,24 @@ class LlamaConfig:
     v_head_dim: int = 0              #   key for all heads); a value head
     shared_ffn_dim: int = 0          # a SwiGLU every token takes, added to
     #                                  the routed experts' sum (0 = none)
+    # A selective state-space operator (Mamba-2; granite-4.0-h is the
+    # first such block): layer_types names "mamba" layers, whose cache in
+    # a layer is a MATRIX state [ssm_heads, ssm_head_dim, ssm_state] and
+    # the last ssm_conv - 1 inputs of a depthwise conv over
+    # heads * head_dim + 2 * state channels (x, B and C together), both per
+    # batch slot. B and C are shared by all heads (one group).
+    ssm_state: int = 0               # N: columns of a head's state
+    ssm_heads: int = 0               # H
+    ssm_head_dim: int = 0            # P: rows of a head's state
+    ssm_conv: int = 4                # taps of its depthwise causal conv
+    ssm_chunk: int = 256             # tokens a block of the chunked scan
+    # Multipliers of the granite family; 1 (and rope on, and a score
+    # scale of head_dim ** -0.5) is every other block.
+    embed_scale: float = 1.0         # on the embedding's output
+    residual_scale: float = 1.0      # on both branches of every layer
+    attn_scale: float = 0.0          # on q . k; 0 = head_dim ** -0.5
+    logits_divisor: float = 1.0      # logits = (x embed^T) / this
+    rope: bool = True                # False: no positional embedding
 
     def __post_init__(self):
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
@@ -127,6 +145,20 @@ class LlamaConfig:
                 f"shared_ffn_dim={self.shared_ffn_dim} is the width of the "
                 f"expert every token takes BESIDE the routed ones: it "
                 f"needs n_experts")
+        ssm = (self.ssm_state, self.ssm_heads, self.ssm_head_dim)
+        if MAMBA in self.layer_types:
+            if min(ssm) <= 0 or self.ssm_conv < 2 or self.ssm_chunk < 1:
+                raise ValueError(
+                    f"mamba layers need ssm_state, ssm_heads, ssm_head_dim, "
+                    f"at least 2 conv taps and a chunk, got {ssm}, "
+                    f"{self.ssm_conv}, {self.ssm_chunk}")
+            if self.n_experts or self.kv_lora_rank:
+                raise ValueError(
+                    "mamba layers are not built beside routed experts or "
+                    "latent attention")
+        elif any(ssm):
+            raise ValueError("ssm_state, ssm_heads and ssm_head_dim describe "
+                             "mamba layers: layer_types names none")
         heads = (self.qk_nope_head_dim, self.qk_rope_head_dim,
                  self.v_head_dim)
         if self.kv_lora_rank:
@@ -150,6 +182,21 @@ class LlamaConfig:
     @property
     def head_dim(self) -> int:
         return self.dim // self.n_heads
+
+    @property
+    def beyond_llama_block(self) -> bool:
+        """Mamba layers, attention without positions or with a score scale
+        of its own, or a multiplier: what llm/model.py alone serves and the
+        training forward and llm/tp.py refuse together, by name."""
+        return MAMBA in self.layer_types or not self.rope \
+            or bool(self.attn_scale) or (
+                self.embed_scale, self.residual_scale,
+                self.logits_divisor) != (1.0, 1.0, 1.0)
+
+    @property
+    def ssm_channels(self) -> int:
+        """Channels of a mamba layer's conv: x, then B, then C."""
+        return self.ssm_heads * self.ssm_head_dim + 2 * self.ssm_state
 
     @property
     def hybrid(self) -> bool:
@@ -241,8 +288,16 @@ def _init_hybrid_params(cfg: LlamaConfig, key: jax.Array) -> Params:
     """The tree of a block whose layers differ: ``layers`` holds one
     stack per KIND, each on its own leading axis, a layer's entry at its
     ordinal among the layers of that kind. Operators: "attn" (the
-    attention layers) and "conv" (the gated short convolutions: w_in
-    [d, 3d] to B, C and u, the depthwise taps w_conv [taps, d], w_out).
+    attention layers), "conv" (the gated short convolutions: w_in
+    [d, 3d] to B, C and u, the depthwise taps w_conv [taps, d], w_out)
+    and "mamba" (the state-space layers: the input projection [d, H P +
+    (H P + 2 N) + H] as its column groups w_gate, w_xbc and w_dt, to the
+    gate, the conv's input and dt: one matrix of 8512 columns is no whole
+    number of 128-lane tiles, and XLA kept a second, re-laid copy of all
+    its layers beside it; the depthwise taps w_conv
+    [taps, H P + 2 N] with their bias b_conv, dt_bias, A_log and D a
+    head, all five float32 whatever the weights are held in; the gated
+    norm's weight gate_norm [H P]; w_out).
     Feed-forwards: "dense" (the leading n_dense_layers, or all without
     experts) and "moe" (the rest). The router's selection bias is float32
     whatever the weights are held in, and drawn, not zero: a zero bias
@@ -299,6 +354,33 @@ def _init_hybrid_params(cfg: LlamaConfig, key: jax.Array) -> Params:
             "w_in": dense(C, d, 3 * d),
             "w_conv": dense(C, cfg.conv_kernel, d, fan_in=cfg.conv_kernel),
             "w_out": dense(C, d, d)}
+    S = len(cfg.layers_of(MAMBA))
+    if S:
+        H, di, ch = cfg.ssm_heads, cfg.ssm_heads * cfg.ssm_head_dim, \
+            cfg.ssm_channels
+        f32 = jnp.float32
+
+        def uniform(lo, hi):
+            return jax.random.uniform(next(keys), (S, H), f32, lo, hi)
+
+        # the family's initialisation: A = -exp(A_log) in -[1, 16] and
+        # softplus(dt_bias) log-uniform in [1e-3, 1e-1], so a head forgets
+        # over 1 to 1000 tokens: the state is alive and bounded
+        dt = jnp.exp(uniform(jnp.log(1e-3), jnp.log(1e-1)))
+        layers["mamba"] = {
+            "mamba_norm": jnp.ones((S, d), pd),
+            # the published in_proj [d, H P + ch + H], held as its three
+            # column groups: to the gate, the conv's input (x, B, C), dt
+            "w_gate": dense(S, d, di), "w_xbc": dense(S, d, ch),
+            "w_dt": dense(S, d, H),
+            "w_conv": dense(S, cfg.ssm_conv, ch, fan_in=cfg.ssm_conv,
+                            dtype=f32),
+            "b_conv": dense(S, ch, fan_in=cfg.ssm_conv, dtype=f32),
+            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),   # softplus^-1(dt)
+            "A_log": jnp.log(uniform(1.0, 16.0)),
+            "D": jnp.ones((S, H), f32),
+            "gate_norm": jnp.ones((S, di), pd),
+            "w_out": dense(S, di, d)}
     n_dense = cfg.n_dense_layers if cfg.n_experts else L
     if n_dense:
         layers["dense"] = swiglu(
@@ -323,6 +405,14 @@ def _init_hybrid_params(cfg: LlamaConfig, key: jax.Array) -> Params:
 
 
 def _require_llama_block(cfg: LlamaConfig, what: str) -> None:
+    if cfg.beyond_llama_block:
+        raise NotImplementedError(
+            f"{what} is written for the Llama/Mistral block: mamba layers "
+            f"(ssm_state, ssm_heads, ssm_head_dim: a selective state-space "
+            f"recurrence whose matrix state is a CACHE format, with no "
+            f"training scan or backward here), rope=False, attn_scale, "
+            f"embed_scale, residual_scale and logits_divisor are served by "
+            f"llm/model.py only (ROADMAP R4)")
     if cfg.kv_lora_rank or cfg.shared_ffn_dim:
         raise NotImplementedError(
             f"{what} is written for the Llama/Mistral block: kv_lora_rank "

@@ -36,7 +36,7 @@ MS = 1_000_000
 METRICS = ["engine_host_ms", "engine_offcpu_pct", "engine_device_wait_pct",
            "idle_offcpu_pct", "idle_lanes_pct"]
 CELLS = ["reason-1chip", "reason-moe-1chip", "reason-lfm2-1chip",
-         "context-kanana-1chip"]
+         "context-kanana-1chip", "reason-granite-1chip"]
 ENGINE = dict(page_size=8, total_pages=64, max_batch=4, max_seq_len=96,
               prefill_chunk=16, prefill_rows=2, decode_chunk=4)
 
@@ -754,8 +754,8 @@ def test_hand_over_is_one_launch_late_and_at_once_when_the_engine_runs_dry():
 def test_publish_overlap_pct_reads_the_two_counters():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    entry = bench["per_layer"][-1]
-    assert entry["name"] == "publish_overlap_pct"   # appended, at the end
+    entry = next(m for m in bench["per_layer"]
+                 if m["name"] == "publish_overlap_pct")
     assert entry["workloads"] == CELLS and entry["moves"] == "out_tok_per_s"
     assert entry["source"] == "program_counter" and entry["unit"] == "%"
     assert entry["layer"] == next(

@@ -1,7 +1,7 @@
 """A block lowers to the code of its own fields and to no other block's.
 
 `LlamaConfig` is the configuration of several blocks (Mistral, OLMoE, LFM2,
-Kanana-2), and one decoder body in llm/model.py follows its fields. A
+Kanana-2, granite-4.0-h), and one decoder body in llm/model.py follows its fields. A
 configuration that sets none of a block's fields must take none of that
 block's code: the tests here read the jaxprs of both step programs and of
 the page copy, on the kernel path and on the reference path, and the
@@ -52,13 +52,21 @@ BLOCKS = {
                    router_score="sigmoid", router_bias=True,
                    router_eps=1e-20, router_scale=2.448, kv_lora_rank=32,
                    qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
-                   shared_ffn_dim=32, tie_embeddings=False)}
+                   shared_ffn_dim=32, tie_embeddings=False),
+    "granite": dict(n_layers=8, n_heads=8, n_kv_heads=2, ffn_dim=96,
+                    layer_types=["mamba", "mamba", "full_attention",
+                                 "mamba"] * 2, ssm_heads=8, ssm_head_dim=16,
+                    ssm_state=16, ssm_chunk=8, rope=False,
+                    attn_scale=1 / 64, embed_scale=12.0,
+                    residual_scale=0.22, logits_divisor=8.0)}
 
 #: what only a block's own fields may bring into a program's text or
 #: trees: named scopes, parameter leaves, and the shape of the pool
 ONLY_LATENT = ("mla_proj", "w_kva", "w_uk", "w_uv", "kv_norm")
 ONLY_SHARED = ("moe_shared", "w_shared_gate", "w_shared_up", "w_shared_down")
-ONLY_CONV = ("short_conv", "w_conv", "conv_norm")
+ONLY_CONV = ("short_conv", "conv_norm")
+ONLY_SSM = ("ssm_proj", "ssm_update", "ssm_scan", "w_xbc", "A_log",
+            "ssm_conv")
 
 
 def _text(jaxpr) -> str:
@@ -113,12 +121,17 @@ def test_a_block_takes_no_other_blocks_code(block):
         missing = [w for w in ONLY_LATENT + ONLY_SHARED
                    if w not in everything]
         assert not missing, f"the latent block's texts lack {missing}"
-        assert not [w for w in ONLY_CONV if w in everything]
+        assert not [w for w in ONLY_CONV + ONLY_SSM if w in everything]
         assert all("'v'" not in texts[f"{block}.{impl}.pool"]
                    for impl in ("reference", "kernel"))
         return
-    absent = ONLY_LATENT + ONLY_SHARED + (() if cfg.layer_types
-                                          else ONLY_CONV)
+    absent = ONLY_LATENT + ONLY_SHARED \
+        + (() if "conv" in cfg.layer_types else ONLY_CONV) \
+        + (() if "mamba" in cfg.layer_types else ONLY_SSM)
+    if "mamba" in cfg.layer_types:
+        # the control for the state-space block's own words
+        missing = [w for w in ONLY_SSM if w not in everything]
+        assert not missing, f"the state-space block's texts lack {missing}"
     for name, text in texts.items():
         found = [word for word in absent if word in text]
         assert not found, f"{name} holds {found}"
