@@ -6,8 +6,10 @@ admission, scheduling), rebuilt TPU-first around ONE ragged step:
 
   - RAGGED SINGLE-DISPATCH STEP: every scheduler step packs the decode
     batch (one token per running sequence) and up to prefill_rows
-    prefill CHUNKS (bounded by the step token budget) into one ragged
-    token batch and runs ONE compiled program
+    prefill CHUNKS (bounded by the step token budget; one row a
+    prefilling sequence first, rows still free then to the NEXT chunks
+    of those sequences: _deal_chunk_rows) into one ragged token batch
+    and runs ONE compiled program
     (model._ragged_step_body over ops.ragged_paged_attention). The old
     engine compiled a per-length-bucket zoo — |len buckets| x |size
     buckets| prefill programs plus a chunk program per chunk length
@@ -28,7 +30,14 @@ admission, scheduling), rebuilt TPU-first around ONE ragged step:
   - CHUNKED PREFILL: every prompt computes in prefill_chunk-bounded
     chunks riding the mixed step under the per-step token budget —
     decode-priority scheduling, so one 2k-token prompt never stalls the
-    running batch behind a monolithic prefill dispatch;
+    running batch behind a monolithic prefill dispatch. The step costs
+    the same with one chunk row filled or all of them, so a sequence
+    that prefills with rows to spare computes several of its chunks in
+    ONE step, each a row of its own over the same page table (the step
+    writes every row's K/V before it attends, so a later row reads the
+    earlier row's tokens from the pool as it would a step later); not
+    with conv or state-space layers, whose chunk rows start from the
+    slot's state as the LAST step left it;
   - INT8 KV (kv_dtype="int8"): pages store int8 with bf16
     per-(token, head) scales carried in the same kv pytree — ~1.9x the
     concurrent sequences per HBM byte, quantize-on-write in the step
@@ -331,7 +340,11 @@ class InferenceEngine:
                       "decode_dispatches": 0, "cached_tokens": 0,
                       "ragged_dispatches": 0, "ragged_real_tokens": 0,
                       "ragged_slot_tokens": 0, "cow_copies": 0,
-                      "preemptions": 0}
+                      "preemptions": 0,
+                      # chunk rows packed into mixed steps and, of them,
+                      # rows that were a sequence's second or later in
+                      # their step (_deal_chunk_rows)
+                      "chunk_rows": 0, "chunk_rows_joined": 0}
         # counters the step programs reduce on the device and append to
         # the tokens they return (none for a dense model): one stats key
         # each, and metadata of the dispatch's engine.readback span
@@ -619,20 +632,33 @@ class InferenceEngine:
 
     # --------------------------------------------------- ragged mixed step
 
-    def _ragged_dispatch(self, finished: Dict[str, List[int]],
-                         after_dispatch: Optional[Callable[[], None]],
-                         ) -> bool:
-        """Assemble and run ONE ragged mixed step, if prefill work is
-        pending: decode rows first (slot r owns ragged token r), then up
-        to prefill_rows chunk rows packed from token max_batch on, FIFO
-        over the chunking queue under the step token budget. Rows whose
-        chunk finishes its prompt get their first sampled token from the
-        SAME dispatch (fused argmax) — no extra program, no extra
-        readback. Returns False (no dispatch) when no chunk work exists,
-        sending the step to the pure-decode loop instead."""
+    def _deal_chunk_rows(self) -> List[Tuple[SequenceState, int, int]]:
+        """Deal the mixed step's chunk rows: [(seq, start, n_tokens)], at
+        most prefill_rows rows of at most prefill_chunk tokens, their sum
+        under the step token budget. First one row a sequence, FIFO over
+        the chunking queue: a sequence never waits a step longer for a
+        row because another one has a long prompt. Rows still free then
+        go, FIFO again, to the NEXT chunks of the sequences just dealt a
+        row, each chunk a row of its own that starts where the row before
+        it ended: a sequence that prefills alone computes prefill_rows
+        chunks a step, not one. Two things the engine can see hold a
+        sequence to its one row:
+
+          - conv or state-space layers: a chunk row starts from its
+            slot's state and stores it at its end, so two rows of one
+            slot in one step would both start from the old state;
+          - a row that ends inside a page (cut short by the token
+            budget; a prefill_chunk that is no multiple of page_size) is
+            its sequence's last of the step: the write kernel moves
+            whole pages, a partial one as read-modify-write, and the
+            tail of one row and the head of the next on the SAME page in
+            one call would race. Full chunks keep a sequence's rows on
+            page boundaries: a prefix hit leaves num_computed on a page
+            multiple or, copied on write, one token short of the
+            prompt's end."""
         budget = self.step_token_budget \
             if self.step_token_budget > 0 else (1 << 30)
-        rows: List[Tuple[SequenceState, int]] = []
+        rows: List[Tuple[SequenceState, int, int]] = []
         for seq in self._chunking:
             if len(rows) >= self.prefill_rows:
                 break
@@ -640,8 +666,35 @@ class InferenceEngine:
                     len(seq.prompt) - seq.num_computed, budget)
             if C <= 0:
                 break  # step token budget exhausted
-            rows.append((seq, C))
+            rows.append((seq, seq.num_computed, C))
             budget -= C
+        if self._has_state:
+            return rows
+        for seq, start, C in list(rows):
+            end = start + C
+            while len(rows) < self.prefill_rows \
+                    and end % self.page_size == 0:
+                C = min(self.prefill_chunk, len(seq.prompt) - end, budget)
+                if C <= 0:
+                    break  # the prompt's end, or the budget's
+                rows.append((seq, end, C))
+                budget -= C
+                end += C
+        return rows
+
+    def _ragged_dispatch(self, finished: Dict[str, List[int]],
+                         after_dispatch: Optional[Callable[[], None]],
+                         ) -> bool:
+        """Assemble and run ONE ragged mixed step, if prefill work is
+        pending: decode rows first (slot r owns ragged token r), then up
+        to prefill_rows chunk rows packed from token max_batch on, as
+        _deal_chunk_rows deals them (a sequence may hold several, one
+        after another in position). Rows whose chunk finishes its prompt
+        get their first sampled token from the SAME dispatch (fused
+        argmax; the sequence's LAST row's) — no extra program, no extra
+        readback. Returns False (no dispatch) when no chunk work exists,
+        sending the step to the pure-decode loop instead."""
+        rows = self._deal_chunk_rows()
         if not rows:
             return False
         with self.phase("engine.pack"):
@@ -674,9 +727,8 @@ class InferenceEngine:
                 kv_len[i] = s.num_tokens
                 token_state[i] = i
             t0 = self.max_batch
-            for j, (seq, C) in enumerate(rows):
+            for j, (seq, start, C) in enumerate(rows):
                 r = self.max_batch + j
-                start = seq.num_computed
                 pos = np.arange(start, start + C, dtype=np.int32)
                 tokens[t0:t0 + C] = seq.prompt[start:start + C]
                 token_pos[t0:t0 + C] = pos
@@ -705,15 +757,19 @@ class InferenceEngine:
             nxt = self._note_counters(nxt, R, span)
         with self.phase("engine.book"):
             now = time.monotonic()
-            chunk_tokens = sum(C for _, C in rows)
+            chunk_tokens = sum(C for _, _, C in rows)
             self.stats["ragged_dispatches"] += 1
             disp_idx = self.stats["ragged_dispatches"]
             self.stats["ragged_real_tokens"] += len(active) + chunk_tokens
             self.stats["ragged_slot_tokens"] += Tcap
             self.stats["prefill_tokens"] += chunk_tokens
+            self.stats["chunk_rows"] += len(rows)
+            # a joined row starts past what its sequence has computed
+            self.stats["chunk_rows_joined"] += sum(
+                start > seq.num_computed for seq, start, _ in rows)
             if self._has_state:
                 self.stats["state_resets"] += sum(
-                    seq.num_computed == 0 for seq, _ in rows)
+                    start == 0 for _, start, _ in rows)
             if active:
                 self.stats["decode_steps"] += 1
                 self.stats["decode_tokens"] += len(active)
@@ -729,7 +785,7 @@ class InferenceEngine:
                     continue
                 self._tokens[slot] = tok
                 self._positions[slot] = seq.num_tokens - 1
-            for j, (seq, C) in enumerate(rows):
+            for j, (seq, _, C) in enumerate(rows):
                 seq.num_computed += C
                 if seq.record is not None:
                     seq.record.note_chunk(now, C, disp_idx)

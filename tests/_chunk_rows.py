@@ -1,0 +1,187 @@
+"""Cases of the mixed step's chunk-row deal (llm/engine.py:
+_deal_chunk_rows), written once and run by each block's test file on its
+own tiny engines: one with prefill_rows 1 (a sequence's chunks one a step,
+what the engine did before rows were joined) and one with prefill_rows 2,
+the same weights, page_size 8 and prefill_chunk 16. Float32 on the CPU, so
+the two engines give the same greedy tokens or something is wrong: which
+step a row rides in must not change what it computes.
+
+Not a test file: tests/test_llm.py (per-head pool) and
+tests/test_llm_kanana.py (latent pool) parametrise over CASES;
+tests/test_llm_lfm2.py and tests/test_llm_granite.py (recurrent state)
+hold their engines to one row a sequence.
+"""
+
+import numpy as np
+
+CHUNK = 16
+CASES = ("alone-2", "alone-3", "alone-5", "prefix-hit", "copy-on-write",
+         "together", "budget")
+
+
+def watch(eng):
+    """Record every deal of ``eng`` from now on: [[(rid, start, n)]], one
+    entry a mixed step."""
+    deals, deal = [], eng._deal_chunk_rows
+
+    def recording():
+        rows = deal()
+        if rows:
+            deals.append([(s.request_id, start, n) for s, start, n in rows])
+        return rows
+    eng._deal_chunk_rows = recording
+    return deals
+
+
+def spans(deals):
+    """The deals without their request ids: [[(start, n)]]."""
+    return [[(start, n) for _, start, n in d] for d in deals]
+
+
+def serve(eng, prompts, n_new=6):
+    """Add the prompts together, drain the engine, and check each mixed
+    step's books against its deal. Returns ([tokens a prompt], deals)."""
+    deals = watch(eng)
+    before = dict(eng.stats)
+    rids = [eng.add_request(list(p), n_new) for p in prompts]
+    done, seen = {}, 0
+    for _ in range(400):
+        done.update(eng.step())
+        if len(deals) > seen:                   # this step was a mixed one
+            seen = len(deals)
+            meta = eng._step_meta               # the engine.step span's
+            assert meta["kind"] == "mixed"
+            assert meta["real_tokens"] - meta["decode_rows"] \
+                == sum(n for _, _, n in deals[-1])
+        if not eng.has_work():
+            break
+    del eng._deal_chunk_rows                    # the class's again
+    assert set(rids) <= set(done)
+    rows = [r for d in deals for r in d]
+    joined = sum(len(d) - len({rid for rid, _, _ in d}) for d in deals)
+    got = {k: eng.stats[k] - before[k] for k in (
+        "chunk_rows", "chunk_rows_joined", "prefill_tokens",
+        "ragged_dispatches")}
+    assert got == {"chunk_rows": len(rows), "chunk_rows_joined": joined,
+                   "prefill_tokens": sum(n for _, _, n in rows),
+                   "ragged_dispatches": len(deals)}
+    budget = eng.step_token_budget or 1 << 30
+    for d in deals:
+        assert len(d) <= eng.prefill_rows
+        assert sum(n for _, _, n in d) <= budget
+        ends = {}
+        for rid, start, n in d:
+            assert 0 < n <= eng.prefill_chunk
+            if rid in ends:                     # a joined row: where the
+                assert start == ends[rid]       # last one ended, on a
+                assert start % eng.page_size == 0       # page's edge
+            ends[rid] = start + n
+    return [done[r] for r in rids], deals
+
+
+def check(case, one, two, vocab=256):
+    """Run ``case`` on both engines (``one``: prefill_rows 1, ``two``:
+    prefill_rows 2) and hold the second to the first and to the deal the
+    case expects."""
+    rng = np.random.default_rng(sum(map(ord, case)))
+    new = lambda n: rng.integers(0, vocab, n).tolist()       # noqa: E731
+    if case.startswith("alone-"):
+        # 2, 3 (the last one short) and 5 chunks, nobody else prefilling:
+        # two chunks a step, so half the mixed steps, rounded up
+        n = {"2": 32, "3": 45, "5": 80}[case[-1]]
+        prompt = new(n)
+        (want,), d1 = serve(one, [prompt])
+        (got,), d2 = serve(two, [prompt])
+        assert got == want
+        chunks = -(-n // CHUNK)
+        assert (len(d1), len(d2)) == (chunks, -(-chunks // 2))
+    elif case == "prefix-hit":
+        # a hit of 3 pages: the tail starts at 24, a page's edge past 0
+        # that is no multiple of the chunk, and its rows join there
+        base = new(40)
+        prompt = base[:24] + new(50)
+        for eng in (one, two):
+            serve(eng, [base])
+        hits = two.stats["cached_tokens"]
+        (want,), _ = serve(one, [prompt])
+        (got,), deals = serve(two, [prompt])
+        assert got == want
+        assert two.stats["cached_tokens"] - hits == 24
+        assert spans(deals) == [
+            [(24, 16), (40, 16)], [(56, 16), (72, 2)]]
+    elif case == "copy-on-write":
+        # every page of the prompt cached: ONE token is left to compute,
+        # inside the copied page; there is no second row to give
+        prompt = new(32)
+        for eng in (one, two):
+            serve(eng, [prompt])
+        cows = two.stats["cow_copies"]
+        (want,), _ = serve(one, [prompt])
+        (got,), deals = serve(two, [prompt])
+        assert got == want
+        assert two.stats["cow_copies"] == cows + 1
+        assert spans(deals) == [[(31, 1)]]
+    elif case == "together":
+        # two prompts that arrive together get one row each for as long
+        # as both prefill: the shorter one is not a step later than with
+        # one row a sequence; the longer one's tail then takes both rows
+        prompts = [new(75), new(30)]
+        want, _ = serve(one, prompts[:1])
+        want += serve(one, prompts[1:])[0]
+        got, deals = serve(two, prompts)
+        assert got == want
+        assert spans(deals) == [
+            [(0, 16), (0, 16)], [(16, 16), (16, 14)],
+            [(32, 16), (48, 16)], [(64, 11)]]
+    elif case == "budget":
+        # a budget of 20: the second row is cut to 4 tokens and ends
+        # inside a page, so the next step's row starts there and, ending
+        # inside a page too, stays alone; serve() holds every step to the
+        # budget and every joined row to a page's edge
+        prompt = new(70)
+        budgets = [eng.step_token_budget for eng in (one, two)]
+        one.step_token_budget = two.step_token_budget = 20
+        try:
+            (want,), _ = serve(one, [prompt])
+            (got,), deals = serve(two, [prompt])
+        finally:
+            one.step_token_budget, two.step_token_budget = budgets
+        assert got == want
+        assert spans(deals) == [
+            [(0, 16), (16, 4)], [(20, 16)], [(36, 16)], [(52, 16)],
+            [(68, 2)]]
+    else:
+        raise ValueError(case)
+
+
+def check_state_keeps_one_row(eng):
+    """``eng``: a block with conv or state-space layers and two chunk
+    rows. A chunk row starts from its slot's state and stores it at its
+    end: two rows of one slot in one step would both start from the old
+    state, so the deal stays one row a sequence whatever rows are free."""
+    assert eng._has_state and eng.prefill_rows == 2
+    _, deals = serve(eng, [list(range(7, 47))], n_new=3)
+    assert spans(deals) == [[(0, 16)], [(16, 16)], [(32, 8)]]
+    assert eng.stats["chunk_rows_joined"] == 0 < eng.stats["chunk_rows"]
+
+
+def check_preempted(make):
+    """``make(prefill_rows=n, **pool)`` builds the block's engine. A pool
+    too small for two sequences preempts one; its re-prefill of prompt +
+    generated tokens, folded into one prompt of several chunks, takes
+    both rows when it prefills alone and continues as the engine with
+    one row a sequence does (which continues as the uninterrupted one:
+    each block's own preemption test)."""
+    # no prefix cache: the re-prefill computes every token again, from 0
+    pool = dict(page_size=4, total_pages=10, max_seq_len=32, prefill_chunk=8,
+                prefix_cache=False)
+    prompts = [list(range(1, 9)), list(range(3, 11))]
+    want, _ = serve(make(prefill_rows=1, **pool), prompts, n_new=16)
+    two = make(prefill_rows=2, **pool)
+    got, deals = serve(two, prompts, n_new=16)
+    assert got == want
+    assert two.stats["preemptions"] >= 1
+    # past the first step, two rows from 0 on are a folded re-prefill
+    refilled = [d for d in deals[1:] if len(d) == 2
+                and d[0][0] == d[1][0] and d[0][1] == 0]
+    assert refilled, deals

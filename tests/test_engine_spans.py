@@ -119,6 +119,18 @@ def test_step_kinds_equal_the_dispatch_counters(profiled):
         == after["ragged_real_tokens"] - before["ragged_real_tokens"]
     assert sum(m["slot_tokens"] for m in meta if m["kind"] == "mixed") \
         == after["ragged_slot_tokens"] - before["ragged_slot_tokens"]
+    # a mixed step's real tokens: one a decode row, the rest its chunk
+    # rows', one row at least and prefill_rows at most, a sequence's
+    # second row in a step counted as joined
+    mixed = [m for m in meta if m["kind"] == "mixed"]
+    delta = {k: after[k] - before[k] for k in (
+        "prefill_tokens", "chunk_rows", "chunk_rows_joined")}
+    assert sum(m["real_tokens"] - m["decode_rows"] for m in mixed) \
+        == delta["prefill_tokens"]
+    assert len(mixed) <= delta["chunk_rows"] \
+        <= ENGINE["prefill_rows"] * len(mixed)
+    assert 0 <= delta["chunk_rows_joined"] \
+        <= delta["chunk_rows"] - len(mixed)
     assert sum(m["decode_rows"] * (1 if m["kind"] == "mixed"
                                    else ENGINE["decode_chunk"])
                for m in meta if m["kind"] != "none") \

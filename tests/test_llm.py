@@ -7,6 +7,7 @@ autoregressively on the full sequence each step — the engine's paged
 incremental path must reproduce its greedy choices exactly.
 """
 
+import importlib
 import json
 import os
 import re
@@ -16,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _chunk_rows import CASES, check, check_preempted
 from ray_tpu.llm import InferenceEngine
 from ray_tpu.llm.cache import PageAllocator
 from ray_tpu.models.llama import LlamaConfig, forward, init_params
@@ -309,6 +311,67 @@ def test_multi_prompt_single_ragged_dispatch(params):
 # ------------------------------------- chunked prefill + prefix caching
 
 
+@pytest.fixture(scope="module")
+def rows_1_and_2(params):
+    """The same weights behind one chunk row a step and behind two."""
+    return [InferenceEngine(CFG, params, page_size=8, total_pages=128,
+                            max_batch=4, max_seq_len=128, prefill_chunk=16,
+                            prefill_rows=n) for n in (1, 2)]
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_joined_chunk_rows_compute_what_one_row_a_step_does(rows_1_and_2,
+                                                            case):
+    """A sequence with rows to spare computes several chunks in ONE mixed
+    step (per-head K and V pool): the same tokens as one chunk a step,
+    alone, after a prefix hit, beside another prompt, under a budget."""
+    check(case, *rows_1_and_2, vocab=CFG.vocab_size)
+
+
+@pytest.mark.parametrize("name,cells,want,parent", [
+    ("chunk_rows_joined_pct", ["context-kanana-1chip", "reason-moe-1chip",
+                               "reason-granite-1chip"], 100.0 / 3, None),
+    ("chunk_tokens_a_step.kanana", ["context-kanana-1chip"], 45 / 2,
+     45 / 2)])
+def test_the_deals_two_metrics_read_the_counters(rows_1_and_2, name, cells,
+                                                 want, parent):
+    """The benchmark's two data files over a prompt of 45 served alone
+    (rows of 16 + 16, then 13): a third of the rows joined, 22.5 tokens a
+    step; and a program without the row counters (the parent commit, in
+    the driver's traced run of it) reads nothing for the share and does
+    not raise, and reads the tokens a step, whose keys are older."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)["per_layer"]
+    entry = next(m for m in bench if m["name"] == name)
+    assert entry["workloads"] == cells and entry["moves"] == "out_tok_per_s"
+    assert entry["source"] == "program_counter"
+    assert entry["layer"] == next(
+        m for m in bench if m["name"] == "batch_occupancy_pct")["layer"]
+    with open(os.path.join(root, "benchmark", "metrics",
+                           name + ".json")) as f:
+        spec = json.load(f)
+    assert spec["name"] == name
+    reader = importlib.import_module("benchmark.readers." + spec["reader"])
+    eng = rows_1_and_2[1]
+    a = dict(eng.stats)
+    # a prompt of its own a metric: the prefix cache holds the other's
+    eng.generate([(9 * i + len(name)) % CFG.vocab_size for i in range(45)],
+                 3)
+    data = {"stats_open": a, "stats_close": dict(eng.stats), "config": {}}
+    assert reader.read(data, spec["args"]) == pytest.approx(want)
+    old = {k: {s: v for s, v in data[k].items()
+               if not s.startswith("chunk_rows")}
+           for k in ("stats_open", "stats_close")}
+    got = reader.read(dict(old, config={}), spec["args"])
+    assert got == (None if parent is None else pytest.approx(parent))
+
+
+def test_a_preempted_sequences_re_prefill_takes_both_rows(params):
+    check_preempted(lambda **kw: InferenceEngine(
+        CFG, params, max_batch=4, decode_chunk=4, **kw))
+
+
 def test_chunked_prefill_matches_oracle(params):
     """Chunk-by-chunk prefill (chunk attention over prior paged KV) must
     reproduce the one-shot prefill greedy stream exactly."""
@@ -317,7 +380,8 @@ def test_chunked_prefill_matches_oracle(params):
                           prefix_cache=False, prefill_chunk=8)
     prompt = [(5 * i + 2) % CFG.vocab_size for i in range(20)]
     got = eng.generate(prompt, max_new_tokens=8)
-    assert eng.stats["ragged_dispatches"] == 3   # 8 + 8 + 4 tokens
+    # 8 + 8 tokens as the two rows of one step, then 4
+    assert eng.stats["ragged_dispatches"] == 2
     assert got == _oracle_greedy(params, prompt, 8)
 
 
@@ -509,7 +573,10 @@ def test_tp_chunked_prefill_prefix_and_cow(params):
     prompt = [(5 * i + 2) % CFG.vocab_size for i in range(20)]
     want = _oracle_greedy(params, prompt, 6)
     assert eng.generate(prompt, max_new_tokens=6) == want   # chunked cold
-    assert eng.stats["ragged_dispatches"] == 3
+    # two rows of 8 in one step (the sharded program reads the first
+    # row's tokens from the pool for the second), then 4
+    assert eng.stats["ragged_dispatches"] == 2
+    assert eng.stats["chunk_rows_joined"] == 1
     rid = eng.add_request(prompt, 6)                        # prefix hit
     done = {}
     for _ in range(100):

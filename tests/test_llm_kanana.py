@@ -30,6 +30,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+from _chunk_rows import CASES, check, check_preempted  # noqa: E402
 from benchmark import reference_kanana as ref  # noqa: E402
 from benchmark import reference_lfm2  # noqa: E402
 from ray_tpu.llm import InferenceEngine  # noqa: E402
@@ -295,6 +296,31 @@ def test_prefix_hit_and_copy_on_write_on_the_latent_leaf(kanana):
     served = eng.generate(longer, 8)
     assert eng.stats["cached_tokens"] - before["cached_tokens"] == 32
     assert _worst_gap(eng, cfg, longer, served) < TOL
+
+
+@pytest.fixture(scope="module")
+def rows_1_and_2():
+    """The same weights behind one chunk row a step and behind two."""
+    cfg = LlamaConfig.tiny(**KANANA)
+    params = _seeded(cfg)
+    return [InferenceEngine(cfg, params, **{**ENGINE, "prefill_rows": n})
+            for n in (1, 2)]
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_joined_chunk_rows_compute_what_one_row_a_step_does(rows_1_and_2,
+                                                            case):
+    """tests/_chunk_rows.py's cases on the LATENT pool: a later row of a
+    sequence reads the rows the step's earlier row wrote into the one
+    leaf, through the absorbed form, as it would a step later."""
+    check(case, *rows_1_and_2)
+
+
+def test_a_preempted_sequences_re_prefill_takes_both_rows():
+    cfg = LlamaConfig.tiny(**KANANA)
+    params = _seeded(cfg)
+    check_preempted(lambda **kw: InferenceEngine(
+        cfg, params, **{**ENGINE, **kw}))
 
 
 def test_engine_preemption_gives_the_uninterrupted_continuation():

@@ -29,6 +29,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+from _chunk_rows import check_state_keeps_one_row  # noqa: E402
 from benchmark import reference_lfm2 as ref  # noqa: E402
 from benchmark import reference_olmoe  # noqa: E402
 from ray_tpu.llm import InferenceEngine  # noqa: E402
@@ -261,6 +262,10 @@ def test_engine_chunked_prefill_and_decode_loop_match_reference(lfm2):
     assert len(served) == 13
     assert _worst_gap(eng, cfg, prompt, served) < TOL
     assert eng.compiled_step_programs() <= 2     # no page copy: no prefix
+
+
+def test_a_sequence_that_prefills_alone_keeps_one_row_a_step(lfm2):
+    check_state_keeps_one_row(lfm2[1])
 
 
 def test_engine_mixed_batch_with_padding_rows_matches_reference(lfm2):

@@ -208,20 +208,22 @@ def test_each_variation_point_against_the_reference(renorm, tied, qk_norm):
 
 def test_step_counters_equal_the_references_routing(olmoe):
     """moe_pairs / moe_hits / moe_hot against a numpy count of the
-    reference's routing, step by step: two prefill chunks, then eight
-    one-token decode steps (9 tokens asked: the first comes from the
-    prefill, the other eight fill two decode loops of 4 exactly, so no
-    step runs past the request's end)."""
+    reference's routing, step by step: ONE mixed step that holds both
+    prefill chunks as its two rows, then eight one-token decode steps
+    (9 tokens asked: the first comes from the prefill, the other eight
+    fill two decode loops of 4 exactly, so no step runs past the
+    request's end)."""
     cfg, eng = olmoe
     before = dict(eng.stats)
-    prompt, n_new = list(range(50, 79)), 9       # 29 = chunks of 16 + 13
+    prompt, n_new = list(range(50, 79)), 9       # 29 = rows of 16 + 13
     served = eng.generate(prompt, n_new)
+    assert eng.stats["ragged_dispatches"] - before["ragged_dispatches"] == 1
     fed = prompt + served[:-1]                   # every token the engine ran
     with jax.default_matmul_precision("highest"):
         _, chosen = ref.forward(eng.params, jnp.asarray(fed, jnp.int32),
                                 ref.dims_of(cfg))
     chosen = np.asarray(chosen)                  # [L, S, k]
-    steps = [(0, 16), (16, 29)] + [(i, i + 1) for i in range(29, len(fed))]
+    steps = [(0, 29)] + [(i, i + 1) for i in range(29, len(fed))]
     pairs = hits = hot = 0
     for lo, hi in steps:
         for layer in chosen:
@@ -275,7 +277,8 @@ def test_dense_configuration_is_untouched():
         "steps", "prefill_tokens", "decode_steps", "decode_tokens",
         "decode_dispatches", "cached_tokens", "ragged_dispatches",
         "ragged_real_tokens", "ragged_slot_tokens", "cow_copies",
-        "preemptions"} | set(WALL_KEYS + (CPU_KEY,))  # every model's clocks
+        "preemptions", "chunk_rows", "chunk_rows_joined"} \
+        | set(WALL_KEYS + (CPU_KEY,))                # every model's clocks
     # the step programs' outputs keep their shapes: [R] and [K, B]
     from ray_tpu.llm import model as M
     kv = eng.kv

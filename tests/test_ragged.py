@@ -580,3 +580,134 @@ def test_step_programs_through_the_kernels_match_reference(monkeypatch,
     for n in kv_r:
         np.testing.assert_allclose(kv_k[n][:, 1:], kv_r[n][:, 1:],
                                    rtol=1e-5, atol=1e-5, err_msg=n)
+
+
+# --------------------------------------------------------------------------
+# Two chunk rows of ONE sequence in one call (llm/engine.py:_deal_chunk_rows)
+# --------------------------------------------------------------------------
+
+@pytest.mark.pallas_interpret
+@pytest.mark.parametrize("pool", ["per_head", "latent"])
+def test_two_rows_of_one_sequence_in_one_call_equal_two_calls(pool):
+    """A sequence with one page cached computes its next two chunks as two
+    rows of ONE call (write, then attention, both kernels through the
+    interpreter, on the stacked pool), the rows' boundary on a page's
+    edge and the same page table in both: the second row reads the first
+    row's tokens from the pool. Equal to the two chunks a call apart, in
+    every written page and in every token's result, beside a decode row
+    of another sequence."""
+    ps, C, L, P, layer = 16, 32, 2, 12, 1
+    Hq, Hkv, D, vw = (4, 2, 128, None) if pool == "per_head" \
+        else (4, 1, 256, 128)
+    rng = np.random.default_rng(len(pool))
+    T = 1 + 2 * C
+    table = np.zeros((3, 6), np.int32)
+    table[0, 0] = 9                              # the decode row's sequence
+    table[1:] = [3, 7, 1, 5, 8, 2]               # one sequence, both rows
+    pos = np.concatenate([[5], ps + np.arange(2 * C)])
+    row_of = np.concatenate([[0], np.full(C, 1), np.full(C, 2)])
+    page, slot = table[row_of, pos // ps], pos % ps
+    q_start = jnp.asarray([0, 1, 1 + C], jnp.int32)
+    kv_len = np.array([6, ps + C, ps + 2 * C], np.int32)
+    q = jnp.asarray(rng.normal(size=(T, Hq, D)), jnp.float32)
+    k_t = jnp.asarray(rng.normal(size=(T, Hkv, D)), jnp.float32)
+    v_t = None if vw else jnp.asarray(rng.normal(size=(T, Hkv, D)),
+                                      jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(L, P, Hkv, ps, D)), jnp.float32)
+    vp = None if vw else jnp.asarray(rng.normal(size=kp.shape), jnp.float32)
+
+    def call(kp, vp, q_len):
+        """One step's write and attention for the rows ``q_len`` leaves
+        alive; the tokens of the others go to the scratch page."""
+        live = np.asarray(q_len)[row_of] > 0
+        hints = dict(layer=jnp.asarray([layer], jnp.int32), max_q_len=C,
+                     decode_rows=1, impl="kernel", interpret=True)
+        q_len = jnp.asarray(q_len, jnp.int32)
+        kp, vp, _, _ = write_ragged_kv(
+            kp, vp, k_t, v_t, jnp.asarray(np.where(live, page, 0)),
+            jnp.asarray(slot), q_start=q_start, q_len=q_len, **hints)
+        out = ragged_paged_attention(
+            q, kp, vp, jnp.asarray(table), q_start, q_len,
+            jnp.asarray(np.where(np.asarray(q_len) > 0, kv_len, 0)),
+            v_width=vw, **hints)
+        return kp, vp, np.asarray(out), live
+
+    kp1, vp1, out1, _ = call(kp, vp, [1, C, C])
+    kp2, vp2, first, a = call(kp, vp, [1, C, 0])
+    kp2, vp2, second, b = call(kp2, vp2, [0, 0, C])
+    np.testing.assert_array_equal(np.asarray(kp1)[:, 1:],
+                                  np.asarray(kp2)[:, 1:])
+    if vp is not None:
+        np.testing.assert_array_equal(np.asarray(vp1)[:, 1:],
+                                      np.asarray(vp2)[:, 1:])
+    assert a.sum() == 1 + C and b.sum() == C and not (a & b).any()
+    np.testing.assert_allclose(out1[a], first[a], rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(out1[b], second[b], rtol=1e-6, atol=1e-6)
+    # and the second row did read the first: without the first row's
+    # tokens in the pool its results are others
+    _, _, blind, _ = call(kp, vp, [0, 0, C])
+    assert np.abs(blind[b] - out1[b]).max() > 1e-2
+
+
+@pytest.mark.parametrize("state", [False, True])
+def test_the_deal_never_joins_two_rows_inside_a_page(state):
+    """llm/engine.py:_deal_chunk_rows over random queues, starts, budgets
+    and row counts: the first rows are the one-row-a-sequence deal
+    unchanged (FIFO, so nobody waits longer for a row than before); a
+    further row of a sequence starts where its last one ended and only on
+    a page's edge (the write kernel's units: two on one page in one call
+    would race); rows, chunk and budget are kept; with recurrent state
+    (``state``) nothing is joined at all."""
+    from ray_tpu.llm import InferenceEngine
+    from ray_tpu.llm.cache import SequenceState
+    from ray_tpu.models.llama import LlamaConfig
+    eng = InferenceEngine(LlamaConfig.tiny(n_layers=1), page_size=16,
+                          total_pages=8, max_batch=2, max_seq_len=64)
+    eng._has_state = state
+    rng = np.random.default_rng(int(state))
+    joined = 0
+    for _ in range(400):
+        ps = eng.page_size = int(rng.choice([4, 16]))
+        eng.prefill_chunk = ps * int(rng.integers(1, 5)) \
+            if rng.random() < 0.8 else int(rng.integers(1, 70))
+        eng.prefill_rows = int(rng.integers(1, 6))
+        eng.step_token_budget = int(rng.choice(
+            [0, rng.integers(1, 40), ps * rng.integers(1, 12)]))
+        eng._chunking = []
+        for i in range(int(rng.integers(0, 5))):
+            seq = SequenceState(f"r{i}", [0] * int(rng.integers(1, 200)), 4)
+            # where earlier steps left it: mostly a page's edge (a prefix
+            # hit, whole chunks), sometimes anywhere (a cut by the budget)
+            seq.num_computed = int(rng.integers(0, len(seq.prompt)))
+            if rng.random() < 0.7:
+                seq.num_computed -= seq.num_computed % ps
+            eng._chunking.append(seq)
+        rows = eng._deal_chunk_rows()
+        budget = eng.step_token_budget or 1 << 30
+        first, left = [], budget                 # the deal as it was
+        for seq in eng._chunking[:eng.prefill_rows]:
+            n = min(eng.prefill_chunk, len(seq.prompt) - seq.num_computed,
+                    left)
+            if n <= 0:
+                break
+            first.append((seq, seq.num_computed, n))
+            left -= n
+        assert rows[:len(first)] == first
+        assert len(rows) <= eng.prefill_rows
+        assert sum(n for _, _, n in rows) <= budget
+        ends = {id(seq): start + n for seq, start, n in first}
+        for seq, start, n in rows[len(first):]:
+            assert not state
+            assert start == ends[id(seq)] and start % ps == 0
+            assert 0 < n <= eng.prefill_chunk
+            assert start + n <= len(seq.prompt)
+            ends[id(seq)] = start + n
+            joined += 1
+        if not state and len(rows) < eng.prefill_rows \
+                and sum(n for _, _, n in rows) < budget:
+            # rows and budget to spare: every sequence with a row is at
+            # its prompt's end or inside a page
+            assert all(end == len(seq.prompt) or end % ps
+                       for seq in eng._chunking[:len(first)]
+                       for end in [ends[id(seq)]])
+    assert (joined == 0) == state
