@@ -19,8 +19,8 @@ Prefix cache design:
   - pages whose ONLY reference is the cache's are evictable, LRU order;
     the engine evicts under allocator pressure, so the cache is free
     HBM turned into hit-rate rather than reserved memory;
-  - a page holds KV and nothing else. A configuration with conv or
-    state-space layers (recurrent state per batch slot,
+  - a page holds KV and nothing else. A configuration with conv,
+    state-space or retention layers (recurrent state per batch slot,
     ``make_kv_cache``) gets NO prefix cache: a hit would restore the KV
     of the matched pages and not the recurrent state at that position
     (``prefix_cache_supported``; saving the state at page boundaries is
@@ -31,8 +31,13 @@ attention layers only, allocated and shared by the page; and the
 recurrent layers' state (``STATE_LEAVES``), one fixed-size entry a batch
 slot, owned by whoever holds the slot and never allocated or freed: a
 conv layer's last inputs (kilobytes a slot), a state-space layer's matrix
-state and its own conv's last inputs (megabytes a slot and layer: there
-``max_batch`` is a memory decision as ``total_pages`` is).
+state and its own conv's last inputs, a retention layer's matrix state and
+normaliser (megabytes a slot and layer: there ``max_batch`` is a memory
+decision as ``total_pages`` is). A configuration with NO attention layer
+has page leaves with no layer in them: the host's page accounting runs as
+ever over pages that hold nothing and cost nothing (a deployment sizes
+``total_pages`` so that they never bind), and what admits a sequence is a
+free slot.
 """
 
 from __future__ import annotations
@@ -44,7 +49,9 @@ from typing import Dict, List, Optional, Tuple
 
 import jax.numpy as jnp
 
-from ray_tpu.models.llama import ATTENTION, CONV, MAMBA, LlamaConfig
+from ray_tpu.models.llama import (ATTENTION, CONV, MAMBA, RETENTION,
+                                  LlamaConfig)
+from ray_tpu.ops.retention import expanded_dim
 
 logger = logging.getLogger(__name__)
 
@@ -250,9 +257,11 @@ class PrefixCache:
 
 #: the pool's leaves that are not pages, all [layers of the kind, slots + 1,
 #: ...]: a conv layer's recurrent state; a state-space layer's matrix state
-#: and the last inputs of its conv
+#: and the last inputs of its conv; a retention layer's matrix state and
+#: its normaliser
 STATE_LEAF, SSM_LEAF, SSM_CONV_LEAF = "conv", "ssm", "ssm_conv"
-STATE_LEAVES = (STATE_LEAF, SSM_LEAF, SSM_CONV_LEAF)
+RET_LEAF, RET_NORM_LEAF = "retention", "retention_norm"
+STATE_LEAVES = (STATE_LEAF, SSM_LEAF, SSM_CONV_LEAF, RET_LEAF, RET_NORM_LEAF)
 
 
 def make_kv_cache(cfg: LlamaConfig, total_pages: int, page_size: int,
@@ -317,6 +326,16 @@ def make_kv_cache(cfg: LlamaConfig, total_pages: int, page_size: int,
     whole conv leaf twice a layer and copied half the state leaf
     (PERF.md, PR 37). The first is the largest thing a slot
     owns by three orders: H P N values a layer.
+
+    A configuration with retention layers gets two as well: ``RET_LEAF``
+    [n_ret, max_batch + 1, n_kv_heads, D, head_dim] in cfg.dtype, a key/value
+    head's matrix state over the expanded key (D = ops/retention.py:
+    expanded_dim(head_dim), 8704 at 128: D on the sublanes, the value on
+    the lanes, a head's block contiguous: what the update kernel moves in
+    one DMA), and ``RET_NORM_LEAF`` [n_ret, max_batch + 1, n_kv_heads,
+    head_dim, head_dim] float32, its normaliser as a symmetric matrix.
+    Where every layer is one (no attention layer at all) ``k`` and ``v``
+    are [0, total_pages, ...]: leaves with no layer and no bytes.
     """
     if kv_dtype not in (None, "model", "int8"):
         raise ValueError(f"kv_dtype must be 'model' or 'int8', "
@@ -360,6 +379,14 @@ def make_kv_cache(cfg: LlamaConfig, total_pages: int, page_size: int,
         kv[SSM_CONV_LEAF] = jnp.zeros(
             (n_ssm, max_batch + 1, cfg.ssm_conv - 1, cfg.ssm_channels),
             cfg.dtype)
+    n_ret = len(cfg.layers_of(RETENTION))
+    if n_ret:
+        if max_batch < 1:
+            raise ValueError("retention layers keep state per batch slot: "
+                             "make_kv_cache needs max_batch")
+        hd, slots = cfg.head_dim, (n_ret, max_batch + 1, cfg.n_kv_heads)
+        kv[RET_LEAF] = jnp.zeros(slots + (expanded_dim(hd), hd), cfg.dtype)
+        kv[RET_NORM_LEAF] = jnp.zeros(slots + (hd, hd), jnp.float32)
     return kv
 
 
@@ -373,7 +400,8 @@ def latent_row_width(cfg: LlamaConfig, lane_pad: bool = False) -> int:
 def prefix_cache_supported(cfg: LlamaConfig) -> bool:
     """Whether a page-aligned prefix hit restores ALL of a sequence's
     state at that position: true where pages are the only state."""
-    return not (cfg.layers_of(CONV) or cfg.layers_of(MAMBA))
+    return not (cfg.layers_of(CONV) or cfg.layers_of(MAMBA)
+                or cfg.layers_of(RETENTION))
 
 
 def kv_cache_tag(cfg: LlamaConfig, kv_dtype: Optional[str]) -> str:

@@ -36,8 +36,8 @@ admission, scheduling), rebuilt TPU-first around ONE ragged step:
     ONE step, each a row of its own over the same page table (the step
     writes every row's K/V before it attends, so a later row reads the
     earlier row's tokens from the pool as it would a step later); not
-    with conv or state-space layers, whose chunk rows start from the
-    slot's state as the LAST step left it;
+    with conv, state-space or retention layers, whose chunk rows start
+    from the slot's state as the LAST step left it;
   - INT8 KV (kv_dtype="int8"): pages store int8 with bf16
     per-(token, head) scales carried in the same kv pytree — ~1.9x the
     concurrent sequences per HBM byte, quantize-on-write in the step
@@ -277,10 +277,10 @@ class InferenceEngine:
         self._param_bytes = sum(x.nbytes
                                 for x in jax.tree.leaves(self.params))
         self._kv_bytes = sum(x.nbytes for x in self.kv.values())
-        # recurrent layers' state (conv, state-space): one entry a batch
-        # slot (+ the scratch slot padding writes), part of the pool
-        # pytree and of _kv_bytes; what ONE slot owns of it is what
-        # max_batch costs beside the pages
+        # recurrent layers' state (conv, state-space, retention): one
+        # entry a batch slot (+ the scratch slot padding writes), part of
+        # the pool pytree and of _kv_bytes; what ONE slot owns of it is
+        # what max_batch costs beside the pages
         state = [x for k, x in self.kv.items() if k in STATE_LEAVES]
         self._has_state = bool(state)
         self._state_bytes = sum(x.nbytes for x in state)
@@ -291,7 +291,8 @@ class InferenceEngine:
         self._kv_row_width = self.kv["k"].shape[-1]
         self._kv_token_layer_bytes = sum(
             x.nbytes // (x.shape[0] * x.shape[1] * x.shape[3])
-            for k, x in self.kv.items() if k not in STATE_LEAVES)
+            for k, x in self.kv.items()
+            if k not in STATE_LEAVES and x.shape[0])  # no layer: no bytes
         self._held_bytes: Dict[int, int] = {}
         for leaf in jax.tree.leaves((self.params, self.kv)):
             for shard in leaf.addressable_shards:
@@ -309,8 +310,8 @@ class InferenceEngine:
             # a hit would restore the matched pages' KV and run the
             # recurrent layers on zero state: no match is taken at all
             logger.warning(
-                "prefix cache off: this configuration has conv or "
-                "state-space layers, whose state per batch slot (%d bytes) "
+                "prefix cache off: this configuration has conv, state-space "
+                "or retention layers, whose state per batch slot (%d bytes) "
                 "a page-aligned prefix hit does not restore",
                 self._state_bytes_per_slot)
             use_prefix = False
@@ -644,9 +645,9 @@ class InferenceEngine:
         chunks a step, not one. Two things the engine can see hold a
         sequence to its one row:
 
-          - conv or state-space layers: a chunk row starts from its
-            slot's state and stores it at its end, so two rows of one
-            slot in one step would both start from the old state;
+          - conv, state-space or retention layers: a chunk row starts
+            from its slot's state and stores it at its end, so two rows
+            of one slot in one step would both start from the old state;
           - a row that ends inside a page (cut short by the token
             budget; a prefill_chunk that is no multiple of page_size) is
             its sequence's last of the step: the write kernel moves

@@ -37,6 +37,16 @@ that is a field (``attn_scale``), and multipliers on the embedding, on
 both branches of every layer and under the logits (``embed_scale``,
 ``residual_scale``, ``logits_divisor``).
 
+A fourth operator, power retention of degree 2 (``"retention"`` in
+``layer_types``, ``_retention``; Brumby-14B is the first such block, and
+the first with NO attention layer): q, k and v projected, normed and
+rotated as attention's are, but no page is written or read: per batch slot
+and key/value head a matrix state over the degree-2 expansion of the key
+[D, head_dim], decayed a token by a sigmoid gate, beside its normaliser;
+the query heads of a group read the group's state. One-token rows update it
+in place (a Pallas kernel, ops/retention.py), chunk rows take the chunk
+form from their slot's state. The page leaves then have no layer.
+
 ONE step program for everything (`_ragged_step_body`): the engine packs
 decode tokens and prefill-chunk tokens into a single RAGGED batch
 (`ops.paged_attention.ragged_paged_attention`), so prefill chunks and
@@ -97,19 +107,21 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu.llm import tp as TP
-from ray_tpu.llm.cache import (SCRATCH_PAGE, SSM_CONV_LEAF, SSM_LEAF,
-                               STATE_LEAF, STATE_LEAVES, make_kv_cache)
-from ray_tpu.models.llama import (ATTENTION, CONV, MAMBA, LlamaConfig, Params,
-                                  _rmsnorm, _rope, _rope_pairs, init_params)
-from ray_tpu.ops import moe, ssm
+from ray_tpu.llm.cache import (RET_LEAF, RET_NORM_LEAF, SCRATCH_PAGE,
+                               SSM_CONV_LEAF, SSM_LEAF, STATE_LEAF,
+                               STATE_LEAVES, make_kv_cache)
+from ray_tpu.models.llama import (ATTENTION, CONV, MAMBA, RETENTION,
+                                  LlamaConfig, Params, _rmsnorm, _rope,
+                                  _rope_pairs, init_params)
+from ray_tpu.ops import moe, retention, ssm
 from ray_tpu.ops.paged_attention import (kernels_supported,
                                          ragged_paged_attention,
                                          write_ragged_kv)
 from ray_tpu.parallel.mesh import shard_map_compat
 from ray_tpu.util import compile_tracker
 
-# {"k", "v"[, "k_scale", "v_scale"][, "conv"][, "ssm", "ssm_conv"]}, or a
-# latent pool's {"k"}
+# {"k", "v"[, "k_scale", "v_scale"][, "conv"][, "ssm", "ssm_conv"][,
+# "retention", "retention_norm"]}, or a latent pool's {"k"}
 KVCache = dict  # (llm/cache.py)
 
 
@@ -205,6 +217,11 @@ SCOPE_MLA_PROJ, SCOPE_SHARED = "mla_proj", "moe_shared"
 #: kernel); the chunk rows' scan
 SCOPE_SSM_PROJ, SCOPE_SSM_UPDATE, SCOPE_SSM_SCAN = \
     "ssm_proj", "ssm_update", "ssm_scan"
+#: ... the retention operator: everything around the recurrence (the
+#: projections, the norms, the rotary embedding, the gate, wo); the
+#: one-token update (the Pallas kernel); the chunk rows' chunk form
+SCOPE_RET_PROJ, SCOPE_RET_UPDATE, SCOPE_RET_CHUNK = \
+    "retention_proj", "retention_update", "retention_chunk"
 
 
 class _ConvRows(NamedTuple):
@@ -448,6 +465,69 @@ def _mamba(lp, l, x, kv, rows: _ConvRows, cfg: LlamaConfig, impl):
         {**kv, SSM_LEAF: state, SSM_CONV_LEAF: conv_state}
 
 
+def _retention(lp, l, x, kv, rows: _ConvRows, cfg: LlamaConfig, impl):
+    """Power retention (degree 2) of one layer, on entry ``l`` of both of
+    its state leaves (the layer's ordinal among the retention layers); H
+    query heads and G key/value heads of d, query head j reading group
+    j // (H / G):
+
+        q_j = rope(rms(h Wq[j]; q_norm));  k_g = rope(rms(h Wk[g]; k_norm))
+        v_g = h Wv[g];  a_g = log sigmoid(h Wg[g] + b_g)      (float32)
+        w_j(t, s) = exp(a_g(s+1) + .. + a_g(t)) (d^-1/2 q_j(t) . k_g(s))^2
+        o_j(t) = sum_{s <= t} w_j(t, s) v_g(s) / (sum_{s <= t} w_j(t, s) + eps)
+        x' = x + residual_scale * concat_j(o_j) Wo
+
+    with h = rms(x; attn_norm), served as the recurrence it equals
+    (ops/retention.py: the scale goes on q and k as d^-1/4 each). The q/k
+    norm and the rotary embedding are the attention layers'
+    (``_project_qkv``, ``cfg.rope``), applied here, outside any kernel. Over
+    a RAGGED batch as ``_mamba`` takes it: the leading ``rows.decode_rows``
+    rows are one token each, in the slot their token names, and take the
+    in-place update kernel; the rest are chunk rows and take the chunk form,
+    each from its slot's state (zeros where its first position is 0) and
+    leaving its last state there. In the decode loop every token is a
+    one-token row, token t in slot t. Returns (x', kv)."""
+    cd, f32 = cfg.dtype, jnp.float32
+    T = x.shape[1]
+    state, norm = kv[RET_LEAF], kv[RET_NORM_LEAF]
+    scratch = state.shape[1] - 1
+    pos, slot = rows.token_pos, rows.token_state
+    with jax.named_scope(SCOPE_RET_PROJ):
+        h = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
+        q, k, v = _project_qkv(lp, h, cfg)                # [1, T, H | G, d]
+        if cfg.rope:
+            q = _rope(q, pos, cfg.rope_theta)
+            k = _rope(k, pos, cfg.rope_theta)
+        a = jax.nn.log_sigmoid(jnp.einsum(
+            "td,dg->tg", h[0], lp["w_g"].astype(cd),
+            preferred_element_type=f32) + lp["b_g"].astype(f32))
+        root = q.shape[-1] ** -0.25
+        q, k, v = q[0].astype(f32) * root, k[0].astype(f32) * root, v[0]
+    Rd = T if slot is None else min(rows.decode_rows, rows.q_start.shape[0])
+    os = []
+    if Rd:
+        with jax.named_scope(SCOPE_RET_UPDATE):
+            o, state, norm = retention.retention_decode_update(
+                state, norm, q[:Rd], k[:Rd], v[:Rd], a[:Rd],
+                jnp.arange(Rd, dtype=jnp.int32) if slot is None
+                else slot[:Rd], pos[:Rd] == 0, layer=l, impl=impl)
+            os.append(o)
+    if T - Rd:
+        with jax.named_scope(SCOPE_RET_CHUNK):
+            q_start, q_len = rows.q_start[Rd:] - Rd, rows.q_len[Rd:]
+            first = jnp.clip(q_start, 0, T - Rd - 1)
+            o, state, norm = retention.retention_chunk_scan(
+                state, norm, q[Rd:], k[Rd:], v[Rd:], a[Rd:], pos[Rd:],
+                q_start, q_len,
+                jnp.where(q_len > 0, slot[Rd:][first], scratch), layer=l,
+                chunk=cfg.retention_chunk, impl=impl)
+            os.append(o)
+    with jax.named_scope(SCOPE_RET_PROJ):
+        o = jnp.concatenate(os).astype(cd).reshape(1, T, -1)
+        x = _residual(x, o @ lp["wo"].astype(cd), cfg)
+    return x, {**kv, RET_LEAF: state, RET_NORM_LEAF: norm}
+
+
 def _latent_attention(lp, l, x, kv, cfg: LlamaConfig, token_pos, token_page,
                       token_slot, page_table, q_start, q_len, kv_len, hints):
     """Latent attention (MLA) of one layer in its ABSORBED form, for
@@ -542,6 +622,9 @@ def _hybrid_layers(layers, x, kv, cfg: LlamaConfig, attention, valid,
         elif op == MAMBA:
             x, kv = _mamba(at("mamba", ordinal[op]), ordinal[op], x, kv,
                            rows, cfg, impl)
+        elif op == RETENTION:
+            x, kv = _retention(at("retention", ordinal[op]), ordinal[op], x,
+                               kv, rows, cfg, impl)
         else:
             x, state = _short_conv(at("conv", ordinal[op]), ordinal[op], x,
                                    kv[STATE_LEAF], rows, cfg)
@@ -569,8 +652,8 @@ def _hybrid_layers(layers, x, kv, cfg: LlamaConfig, attention, valid,
 
     carry = (x, kv, jnp.zeros(len(moe.COUNTERS), jnp.int32))
     carry, seen = run(carry, lead,
-                      dict.fromkeys((ATTENTION, CONV, MAMBA, "dense", "moe"),
-                                    0))
+                      dict.fromkeys((ATTENTION, CONV, MAMBA, RETENTION,
+                                     "dense", "moe"), 0))
     per = collections.Counter(k for kinds in period for k in kinds)
     (x, kv, counters), _ = lax.scan(
         lambda carry, j: (run(carry, period, seen, j, per)[0], None),

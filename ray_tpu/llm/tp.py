@@ -80,7 +80,10 @@ def validate_tp(cfg: LlamaConfig, tp: int) -> None:
             f"matrix state [ssm_heads, ssm_head_dim, ssm_state] per batch "
             f"slot with no partition spec here (its heads would shard with "
             f"w_in's gate and x columns, while B, C and the conv over them "
-            f"are shared by all heads), and rope=False, attn_scale, "
+            f"are shared by all heads), retention layers keep a matrix "
+            f"state and a normaliser per batch slot and key/value head "
+            f"with none either (they would shard by key/value head with "
+            f"wq / wk / wv / w_g's columns), and rope=False, attn_scale, "
             f"embed_scale, residual_scale and logits_divisor are refused "
             f"with them, untested under a shard (ROADMAP R10b)")
     if cfg.kv_lora_rank or cfg.shared_ffn_dim:

@@ -36,8 +36,9 @@ from ray_tpu.parallel.ulysses import ulysses_attention_sharded
 Params = Dict[str, Any]
 
 #: a layer's operator, as the published configurations name it
-ATTENTION, CONV, MAMBA = "full_attention", "conv", "mamba"
-LAYER_KINDS = (ATTENTION, CONV, MAMBA)
+ATTENTION, CONV, MAMBA, RETENTION = \
+    "full_attention", "conv", "mamba", "retention"
+LAYER_KINDS = (ATTENTION, CONV, MAMBA, RETENTION)
 #: spread of the seeded router selection bias. The top 4 of 64 sigmoid
 #: scores lie ~0.013 apart, so 0.02 changes the chosen set for about half
 #: the tokens and leaves the load near even (busiest expert 2.3 x the mean
@@ -116,6 +117,14 @@ class LlamaConfig:
     attn_scale: float = 0.0          # on q . k; 0 = head_dim ** -0.5
     logits_divisor: float = 1.0      # logits = (x embed^T) / this
     rope: bool = True                # False: no positional embedding
+    # Power retention of degree 2 (Brumby-14B is the first such block):
+    # layer_types names "retention" layers, which project q, k and v as
+    # attention does (n_heads, n_kv_heads, head_dim, the q/k norm, the
+    # rotary embedding) and keep no page: per batch slot and key/value head
+    # a MATRIX state [D, head_dim] over the degree-2 expansion of the key
+    # (D = ops/retention.py:expanded_dim(head_dim)), decayed a token by a
+    # gate sigmoid(h w_g + b_g), beside its normaliser.
+    retention_chunk: int = 256       # tokens a block of its chunk form
 
     def __post_init__(self):
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
@@ -159,6 +168,18 @@ class LlamaConfig:
         elif any(ssm):
             raise ValueError("ssm_state, ssm_heads and ssm_head_dim describe "
                              "mamba layers: layer_types names none")
+        if RETENTION in self.layer_types:
+            if self.n_experts or self.kv_lora_rank:
+                raise ValueError(
+                    "retention layers are not built beside routed experts "
+                    "or a latent pool")
+            if self.n_heads % self.n_kv_heads or self.head_dim % 8 \
+                    or self.retention_chunk < 1:
+                raise ValueError(
+                    f"retention layers need n_kv_heads to divide n_heads, "
+                    f"a head_dim of whole blocks of 8 and a chunk, got "
+                    f"{self.n_heads}, {self.n_kv_heads}, {self.head_dim}, "
+                    f"{self.retention_chunk}")
         heads = (self.qk_nope_head_dim, self.qk_rope_head_dim,
                  self.v_head_dim)
         if self.kv_lora_rank:
@@ -185,10 +206,12 @@ class LlamaConfig:
 
     @property
     def beyond_llama_block(self) -> bool:
-        """Mamba layers, attention without positions or with a score scale
-        of its own, or a multiplier: what llm/model.py alone serves and the
-        training forward and llm/tp.py refuse together, by name."""
-        return MAMBA in self.layer_types or not self.rope \
+        """Mamba or retention layers, attention without positions or with
+        a score scale of its own, or a multiplier: what llm/model.py alone
+        serves and the training forward and llm/tp.py refuse together, by
+        name."""
+        return MAMBA in self.layer_types \
+            or RETENTION in self.layer_types or not self.rope \
             or bool(self.attn_scale) or (
                 self.embed_scale, self.residual_scale,
                 self.logits_divisor) != (1.0, 1.0, 1.0)
@@ -288,7 +311,9 @@ def _init_hybrid_params(cfg: LlamaConfig, key: jax.Array) -> Params:
     """The tree of a block whose layers differ: ``layers`` holds one
     stack per KIND, each on its own leading axis, a layer's entry at its
     ordinal among the layers of that kind. Operators: "attn" (the
-    attention layers), "conv" (the gated short convolutions: w_in
+    attention layers), "retention" (the power-retention layers: the
+    attention layers' leaves and the gate's projection w_g [d, n_kv_heads]
+    with its bias b_g, float32), "conv" (the gated short convolutions: w_in
     [d, 3d] to B, C and u, the depthwise taps w_conv [taps, d], w_out)
     and "mamba" (the state-space layers: the input projection [d, H P +
     (H P + 2 N) + H] as its column groups w_gate, w_xbc and w_dt, to the
@@ -326,6 +351,21 @@ def _init_hybrid_params(cfg: LlamaConfig, key: jax.Array) -> Params:
                 "w_gate": dense(n, *E, d, f), "w_up": dense(n, *E, d, f),
                 "w_down": dense(n, *E, f, d)}
 
+    def qkv(n):
+        """The leaves attention and retention layers share: the four
+        projections and the q/k norm the configuration names."""
+        stack = {
+            "attn_norm": jnp.ones((n, d), pd),
+            "wq": dense(n, d, hq * hd), "wk": dense(n, d, hkv * hd),
+            "wv": dense(n, d, hkv * hd), "wo": dense(n, hq * hd, d)}
+        if cfg.qk_norm_per_head:
+            stack["q_norm"] = jnp.ones((n, hd), pd)
+            stack["k_norm"] = jnp.ones((n, hd), pd)
+        elif cfg.qk_norm:
+            stack["q_norm"] = jnp.ones((n, hq * hd), pd)
+            stack["k_norm"] = jnp.ones((n, hkv * hd), pd)
+        return stack
+
     layers = {}
     A, C = len(cfg.layers_of(ATTENTION)), len(cfg.layers_of(CONV))
     if A and cfg.kv_lora_rank:
@@ -338,16 +378,7 @@ def _init_hybrid_params(cfg: LlamaConfig, key: jax.Array) -> Params:
             "w_uk": dense(A, hq, dn, r, fan_in=r),
             "w_uv": dense(A, hq, r, dv), "wo": dense(A, hq * dv, d)}
     elif A:
-        layers["attn"] = {
-            "attn_norm": jnp.ones((A, d), pd),
-            "wq": dense(A, d, hq * hd), "wk": dense(A, d, hkv * hd),
-            "wv": dense(A, d, hkv * hd), "wo": dense(A, hq * hd, d)}
-        if cfg.qk_norm_per_head:
-            layers["attn"]["q_norm"] = jnp.ones((A, hd), pd)
-            layers["attn"]["k_norm"] = jnp.ones((A, hd), pd)
-        elif cfg.qk_norm:
-            layers["attn"]["q_norm"] = jnp.ones((A, hq * hd), pd)
-            layers["attn"]["k_norm"] = jnp.ones((A, hkv * hd), pd)
+        layers["attn"] = qkv(A)
     if C:
         layers["conv"] = {
             "conv_norm": jnp.ones((C, d), pd),
@@ -381,6 +412,17 @@ def _init_hybrid_params(cfg: LlamaConfig, key: jax.Array) -> Params:
             "D": jnp.ones((S, H), f32),
             "gate_norm": jnp.ones((S, di), pd),
             "w_out": dense(S, di, d)}
+    Rt = len(cfg.layers_of(RETENTION))
+    if Rt:
+        # sigmoid(b_g) log-uniform in 1 - [1e-3, 1e-1]: a head forgets over
+        # 10 to 1000 tokens (a zero-mean gate forgets in three: no fault in
+        # the state could then be seen). w_g at a tenth of the fan-in
+        # spread: the token moves the gate, the bias sets its range
+        u = jnp.exp(jax.random.uniform(
+            next(keys), (Rt, hkv), jnp.float32, jnp.log(1e-3),
+            jnp.log(1e-1)))
+        layers["retention"] = {**qkv(Rt), "w_g": 0.1 * dense(Rt, d, hkv),
+                               "b_g": jnp.log1p(-u) - jnp.log(u)}
     n_dense = cfg.n_dense_layers if cfg.n_experts else L
     if n_dense:
         layers["dense"] = swiglu(
@@ -410,7 +452,9 @@ def _require_llama_block(cfg: LlamaConfig, what: str) -> None:
             f"{what} is written for the Llama/Mistral block: mamba layers "
             f"(ssm_state, ssm_heads, ssm_head_dim: a selective state-space "
             f"recurrence whose matrix state is a CACHE format, with no "
-            f"training scan or backward here), rope=False, attn_scale, "
+            f"training scan or backward here), retention layers (power "
+            f"retention: a gated matrix state over the expanded key, a "
+            f"CACHE format too), rope=False, attn_scale, "
             f"embed_scale, residual_scale and logits_divisor are served by "
             f"llm/model.py only (ROADMAP R4)")
     if cfg.kv_lora_rank or cfg.shared_ffn_dim:

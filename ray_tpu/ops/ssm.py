@@ -59,29 +59,40 @@ _F32 = jnp.float32
 # one token a row
 # --------------------------------------------------------------------------
 
-def _rows_state(state, layer, slots, H: int):
-    """The slots' states of one layer as float32 [R, H, P, N], read slot
-    after slot by dynamic_slice (``_store_rows`` says why no gather)."""
-    s = jnp.stack([lax.dynamic_slice(
-        state, (layer, slots[r], 0, 0), (1, 1) + state.shape[2:])[0, 0]
-        for r in range(slots.shape[0])]).astype(_F32)     # [R, N, H P]
-    return s.reshape(s.shape[:2] + (H, -1)).transpose(0, 2, 3, 1)
+def slot_rows(state, layer, slots):
+    """The slots' entries [R, ...] of one layer of a state leaf [layers,
+    slots + 1, ...], as held, read slot after slot by dynamic_slice
+    (``store_slot_rows`` says why no gather)."""
+    return jnp.stack([lax.dynamic_slice(
+        state, (layer, slots[r]) + (0,) * (state.ndim - 2),
+        (1, 1) + state.shape[2:])[0, 0] for r in range(slots.shape[0])])
 
 
-def _store_rows(state, layer, slots, s):
-    """[R, H, P, N] float32 back to the slots, as the leaf holds it, slot
+def store_slot_rows(state, layer, slots, rows):
+    """``rows`` [R, ...] (in the leaf's dtype) back to the slots, slot
     after slot by dynamic_update_slice: XLA reads and writes those in
     place, where a gather or scatter of rows this wide it split in two and
     ran on sliced COPIES of half the leaf (2.4 GB at the published
     sizes). Unrolled: the rows are the step's few chunk rows (or a CPU
     test's), and inside a loop of its own each write cost 0.27 ms, a
     hundred times its bytes (PERF.md, PR 37)."""
-    s = s.transpose(0, 3, 1, 2).reshape((s.shape[0], s.shape[3], -1))
-    s = s.astype(state.dtype)
-    for r in range(s.shape[0]):
-        state = lax.dynamic_update_slice(state, s[r][None, None],
-                                         (layer, slots[r], 0, 0))
+    for r in range(rows.shape[0]):
+        state = lax.dynamic_update_slice(
+            state, rows[r][None, None],
+            (layer, slots[r]) + (0,) * (state.ndim - 2))
     return state
+
+
+def _rows_state(state, layer, slots, H: int):
+    """The slots' states of one layer as float32 [R, H, P, N]."""
+    s = slot_rows(state, layer, slots).astype(_F32)       # [R, N, H P]
+    return s.reshape(s.shape[:2] + (H, -1)).transpose(0, 2, 3, 1)
+
+
+def _store_rows(state, layer, slots, s):
+    """[R, H, P, N] float32 back to the slots, as the leaf holds it."""
+    s = s.transpose(0, 3, 1, 2).reshape((s.shape[0], s.shape[3], -1))
+    return store_slot_rows(state, layer, slots, s.astype(state.dtype))
 
 
 def ssm_decode_reference(state, x, dt, A, B, C, D, slots, fresh, layer):

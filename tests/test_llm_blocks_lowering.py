@@ -1,7 +1,7 @@
 """A block lowers to the code of its own fields and to no other block's.
 
 `LlamaConfig` is the configuration of several blocks (Mistral, OLMoE, LFM2,
-Kanana-2, granite-4.0-h), and one decoder body in llm/model.py follows its fields. A
+Kanana-2, granite-4.0-h, Brumby), and one decoder body in llm/model.py follows its fields. A
 configuration that sets none of a block's fields must take none of that
 block's code: the tests here read the jaxprs of both step programs and of
 the page copy, on the kernel path and on the reference path, and the
@@ -58,7 +58,10 @@ BLOCKS = {
                                  "mamba"] * 2, ssm_heads=8, ssm_head_dim=16,
                     ssm_state=16, ssm_chunk=8, rope=False,
                     attn_scale=1 / 64, embed_scale=12.0,
-                    residual_scale=0.22, logits_divisor=8.0)}
+                    residual_scale=0.22, logits_divisor=8.0),
+    "brumby": dict(n_layers=2, n_heads=4, n_kv_heads=2, ffn_dim=96,
+                   layer_types=["retention"] * 2, qk_norm_per_head=True,
+                   tie_embeddings=False, retention_chunk=8)}
 
 #: what only a block's own fields may bring into a program's text or
 #: trees: named scopes, parameter leaves, and the shape of the pool
@@ -67,6 +70,8 @@ ONLY_SHARED = ("moe_shared", "w_shared_gate", "w_shared_up", "w_shared_down")
 ONLY_CONV = ("short_conv", "conv_norm")
 ONLY_SSM = ("ssm_proj", "ssm_update", "ssm_scan", "w_xbc", "A_log",
             "ssm_conv")
+ONLY_RETENTION = ("retention_proj", "retention_update", "retention_chunk",
+                  "retention_norm", "'b_g'")
 
 
 def _text(jaxpr) -> str:
@@ -121,17 +126,20 @@ def test_a_block_takes_no_other_blocks_code(block):
         missing = [w for w in ONLY_LATENT + ONLY_SHARED
                    if w not in everything]
         assert not missing, f"the latent block's texts lack {missing}"
-        assert not [w for w in ONLY_CONV + ONLY_SSM if w in everything]
+        assert not [w for w in ONLY_CONV + ONLY_SSM + ONLY_RETENTION
+                    if w in everything]
         assert all("'v'" not in texts[f"{block}.{impl}.pool"]
                    for impl in ("reference", "kernel"))
         return
     absent = ONLY_LATENT + ONLY_SHARED \
         + (() if "conv" in cfg.layer_types else ONLY_CONV) \
-        + (() if "mamba" in cfg.layer_types else ONLY_SSM)
-    if "mamba" in cfg.layer_types:
-        # the control for the state-space block's own words
-        missing = [w for w in ONLY_SSM if w not in everything]
-        assert not missing, f"the state-space block's texts lack {missing}"
+        + (() if "mamba" in cfg.layer_types else ONLY_SSM) \
+        + (() if "retention" in cfg.layer_types else ONLY_RETENTION)
+    for kind, words in (("mamba", ONLY_SSM), ("retention", ONLY_RETENTION)):
+        if kind in cfg.layer_types:
+            # the control for the block's own words
+            missing = [w for w in words if w not in everything]
+            assert not missing, f"the {kind} block's texts lack {missing}"
     for name, text in texts.items():
         found = [word for word in absent if word in text]
         assert not found, f"{name} holds {found}"
@@ -140,6 +148,9 @@ def test_a_block_takes_no_other_blocks_code(block):
             cfg, 16, 8, max_batch=3, lane_pad=impl == "kernel"))
         assert kv["k"].shape == kv["v"].shape
         assert kv["k"].shape[2] == cfg.n_kv_heads
+        # a layer for each attention layer: none where every layer is a
+        # retention layer
+        assert kv["k"].shape[0] == len(cfg.layers_of("full_attention"))
 
 
 if __name__ == "__main__":

@@ -210,7 +210,7 @@ def _compile_step_program(chip, cfg, program, *, max_batch, pages, max_seq,
         T, R = max_batch + rows * chunk, max_batch + rows
         tok, row = table((T,)), table((R,))
         state = {"token_state": tok} if cfg.layers_of("conv") \
-            or cfg.layers_of("mamba") else {}
+            or cfg.layers_of("mamba") or cfg.layers_of("retention") else {}
         compiled = M.ragged_step.lower(
             params, tok, tok, tok, tok, table((R, max_seq // ps)), row, row,
             row, kv, cfg=cfg, paged_impl="kernel", max_q_len=chunk,
@@ -469,3 +469,42 @@ def test_granite_step_programs_compile_at_benchmark_shapes(chip, program):
                for a in kv.values())
     assert mem.alias_size_in_bytes >= held
     assert mem.temp_size_in_bytes < 2**29 < kv["ssm"].size * 2
+
+
+def _brumby_cfg(n_layers=2):
+    """brumby-14b-serve-1chip's widths; the scan's body is one layer."""
+    from ray_tpu.models.llama import LlamaConfig
+    return LlamaConfig(vocab_size=151936, dim=5120, n_layers=n_layers,
+                       n_heads=40, n_kv_heads=8, ffn_dim=17408,
+                       rope_theta=1e6, norm_eps=1e-6,
+                       layer_types=["retention"] * n_layers,
+                       qk_norm_per_head=True, tie_embeddings=False,
+                       retention_chunk=256, param_dtype="bfloat16")
+
+
+@pytest.mark.parametrize("program", ["mixed", "decode"])
+def test_brumby_step_programs_compile_at_benchmark_shapes(chip, program):
+    """brumby-14b-serve-1chip's two step programs at its published widths
+    (two layers of the eight): the in-place update (Mosaic takes a key/value
+    head's [8704, 128] bf16 block, the dynamic one-row reads that build phi
+    from sublane-broadcast rows, and the transposes that turn k and the five
+    q into columns) ONCE, in the layer scan's body, and no paged write or
+    attention at all: the page leaves have no layer. Both state leaves
+    aliased from argument to result, and no second copy of the state among
+    the temporaries (1.46 GB at this depth). 32 decode rows, 1 chunk of
+    1024, 19457 pages of 16 that hold nothing."""
+    compiled, kv, rows = _compile_step_program(
+        chip, _brumby_cfg(), program, max_batch=32, pages=19457,
+        max_seq=9728, rows=1, chunk=1024)
+    assert kv["retention"].shape == (2, 33, 8, 8704, 128)
+    assert kv["retention_norm"].shape == (2, 33, 8, 128, 128)
+    assert kv["k"].shape == kv["v"].shape == (0, 19457, 8, 16, 128)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "_retention_update_pallas" in text
+    assert jax.tree.leaves(compiled.out_info)[0].shape == (
+        (rows,) if program == "mixed" else (8, 32))
+    mem = compiled.memory_analysis()
+    held = kv["retention"].size * 2 + kv["retention_norm"].size * 4
+    assert mem.alias_size_in_bytes >= held
+    assert mem.temp_size_in_bytes < 2**30 < kv["retention"].size * 2
