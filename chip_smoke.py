@@ -395,9 +395,9 @@ def _check_replica(tag: str, s: dict, spec: dict, driver_pid: int) -> list:
     if s["paged_impl"] != "kernel":
         bad.append(f"{tag}: paged attention impl {s['paged_impl']!r}, "
                    f"not 'kernel'")
-    if not 1 <= s["compiled_step_programs"] <= 3:
+    if not 1 <= s["compiled_step_programs"] <= s["step_program_budget"]:
         bad.append(f"{tag}: {s['compiled_step_programs']} compiled step "
-                   f"programs, want 1..3")
+                   f"programs, want 1..{s['step_program_budget']}")
     if s["worker_pid"] == driver_pid:
         bad.append(f"{tag}: the engine ran in the driver process")
     want = spec["max_tokens"]

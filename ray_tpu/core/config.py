@@ -75,7 +75,7 @@ _CONFIG_DEFS: dict[str, tuple[type, Any, str]] = {
     "llm_admit_lookahead": (int, 16, "waiting requests scanned past a non-admittable head for same-bucket/admissible prompts (head-of-line fix)"),
     "llm_admit_age_cap_s": (float, 5.0, "a head request older than this stops lookahead skipping so freed pages go to it first (no starvation)"),
     "llm_kv_dtype": (str, "model", "KV page storage scheme: 'model' (engine dtype) or 'int8' (quantized pages + bf16 per-token scales; ~1.9x concurrent sequences per HBM byte at head_dim 64)"),
-    "llm_ragged_prefill_rows": (int, 2, "prefill-chunk rows packed into each ragged step dispatch (ragged token capacity = max_batch + rows*prefill_chunk); more rows advance more prompts per step; when the queue is shallower than the rows a prompt's next chunks take the free ones (not with conv or state-space layers), the rest is padding"),
+    "llm_ragged_prefill_rows": (int, 2, "most prefill-chunk rows packed into one ragged step dispatch (ragged token capacity at most max_batch + rows*prefill_chunk); more rows advance more prompts per step; when the queue is shallower than the rows a prompt's next chunks take the free ones (not with conv, state-space or retention layers); the step then runs the smallest compiled shape that holds the rows dealt (1, 2, 4, ... below this number, and this number: one program each, all compiled when a served replica starts), and the rest of THAT shape is padding"),
     "llm_request_log": (bool, True, "per-request flight recorder (lifecycle events, TTFT/TPOT histograms, 'python -m ray_tpu requests'); disable to shave the last % off the step loop"),
     "llm_request_log_size": (int, 256, "request records kept in the engine-side ring (and in the head-side aggregate ring); oldest finished records evict first"),
     "llm_slo_ttft_ms": (float, 200.0, "time-to-first-token SLO target; llm_slo_ttft_attainment reports the fraction of finished requests under it"),

@@ -10,13 +10,20 @@ admission, scheduling), rebuilt TPU-first around ONE ragged step:
     prefilling sequence first, rows still free then to the NEXT chunks
     of those sequences: _deal_chunk_rows) into one ragged token batch
     and runs ONE compiled program
-    (model._ragged_step_body over ops.ragged_paged_attention). The old
-    engine compiled a per-length-bucket zoo — |len buckets| x |size
-    buckets| prefill programs plus a chunk program per chunk length
-    plus a separate decode program; this engine compiles O(1) programs
-    total (mixed step, decode loop, COW page copy — asserted <= 3), and
-    XLA never recompiles as sequences join, leave, or chunk (shape
-    change is the cardinal sin of TPU serving loops);
+    (model._ragged_step_body over ops.ragged_paged_attention), in the
+    SMALLEST of its few static shapes that holds the rows dealt: chunk
+    rows 1, 2, 4, ... below prefill_rows and prefill_rows itself
+    (model.chunk_row_shapes: {1, 2} at the default 2), so a lone
+    one-chunk prompt does not pay for a second, empty row of
+    prefill_chunk slots. The old engine compiled a
+    per-length-bucket zoo — |len buckets| x |size buckets| prefill
+    programs plus a chunk program per chunk length plus a separate
+    decode program; this engine compiles O(1) programs total (the mixed
+    step once a shape, decode loop, COW page copy — asserted <= 2 + the
+    number of shapes, StepPrograms.program_budget), a served replica
+    compiles them all before it takes a request (load_step_programs),
+    and XLA never recompiles as sequences join, leave, or chunk (shape
+    change outside that set is the cardinal sin of TPU serving loops);
   - pure-decode steps (no prefill work pending) run the multi-step
     decode loop instead: decode_chunk ragged steps scanned in ONE
     program with a single [K, B] readback, so steady-state decode pays
@@ -245,14 +252,16 @@ class InferenceEngine:
             if admit_age_cap_s is None else admit_age_cap_s
         # ragged batch geometry: every mixed step carries max_batch
         # decode rows (one per slot, inactive slots masked by q_len=0)
-        # plus prefill_rows chunk rows of up to prefill_chunk tokens —
-        # ONE static shape, so prompt mix never recompiles
+        # plus chunk rows of up to prefill_chunk tokens: as many as the
+        # smallest of the seam's shapes that holds the rows dealt
+        # (_mixed_shape), prefill_rows at most. A fixed, small set of
+        # static shapes, so prompt mix never recompiles; ragged_rows and
+        # ragged_tokens are the FULL shape's
         self.prefill_rows = max(
             1, GlobalConfig.llm_ragged_prefill_rows if prefill_rows is None
             else prefill_rows)
-        self.ragged_rows = max_batch + self.prefill_rows
-        self.ragged_tokens = max_batch + self.prefill_rows \
-            * self.prefill_chunk
+        self.ragged_rows, self.ragged_tokens = self._mixed_shape(
+            self.prefill_rows)
         # KV page storage scheme: "model" (cfg dtype) or "int8"
         # (quantized pages + bf16 per-token scales, ~1.9x capacity)
         self.kv_dtype = GlobalConfig.llm_kv_dtype \
@@ -265,6 +274,7 @@ class InferenceEngine:
         self._fns = M.StepPrograms(
             cfg, decode_chunk=self.decode_chunk,
             max_q_len=self.prefill_chunk, decode_rows=max_batch,
+            prefill_rows=self.prefill_rows,
             kv_quantized=(self.kv_dtype == "int8"), mesh=self.mesh)
         # weights and pool are created IN their final layout (sharded
         # over the mesh under tp): no device ever stages the whole model
@@ -345,7 +355,10 @@ class InferenceEngine:
                       # chunk rows packed into mixed steps and, of them,
                       # rows that were a sequence's second or later in
                       # their step (_deal_chunk_rows)
-                      "chunk_rows": 0, "chunk_rows_joined": 0}
+                      "chunk_rows": 0, "chunk_rows_joined": 0,
+                      # mixed steps that ran a shape of fewer chunk rows
+                      # than prefill_rows (of ragged_dispatches)
+                      "ragged_small_dispatches": 0}
         # counters the step programs reduce on the device and append to
         # the tokens they return (none for a dense model): one stats key
         # each, and metadata of the dispatch's engine.readback span
@@ -448,15 +461,45 @@ class InferenceEngine:
 
     def compiled_step_programs(self) -> int:
         """Compiled step programs resident for this engine's step fns
-        (O(1) by design: mixed ragged step, decode loop, COW copy)."""
+        (O(1) by design: the mixed ragged step once a shape, decode
+        loop, COW copy)."""
         return self._fns.compiled_step_programs()
+
+    def load_step_programs(self) -> None:
+        """Compile and load every program this engine can dispatch, on
+        an engine that has no work yet: the mixed step in each of its
+        shapes, the decode loop and, with a prefix cache, the page copy.
+        Each is RUN once on nothing but padding, through the callables a
+        step uses (so the jit's own cache and the compile tracker hold
+        them): every mixed row's q_len 0, every token on the scratch page
+        and the scratch state slot; the decode loop as an engine whose
+        slots are all free dispatches it; the scratch page copied onto
+        itself. Which shapes traffic reaches first is then nobody's luck:
+        nothing compiles after this returns. It books nothing: no counter
+        moves, no span opens, no request record exists. A served replica
+        calls it before its engine thread starts (LLMServer); a bare
+        engine compiles lazily, on first use."""
+        for n_rows in self._fns.row_shapes:
+            args, state_arg = self._upload_mixed(
+                *self._pack_mixed([], [], n_rows))
+            _, self.kv = self._fns.ragged_step(self.params, *args, self.kv,
+                                               **state_arg)
+        tokens, positions, page_table, seq_lens = self._upload_decode(
+            np.ones(self.max_batch, np.int32))
+        _, self.kv, _, _ = self._fns.decode_loop(
+            self.params, tokens, positions, self.kv, page_table, seq_lens)
+        if self.prefix is not None:
+            scratch = jnp.int32(SCRATCH_PAGE)
+            self.kv = self._fns.copy_page(self.kv, scratch, scratch)
+        jax.block_until_ready(self.kv)
 
     def device_report(self) -> Dict[str, object]:
         """Where this engine runs and what it compiled: the devices that
         hold its weights, the paged-attention implementation its step
         programs took ("kernel" | "reference" — chosen from the platform,
         so a deployment can assert it never fell back), the resident
-        step-program count, and per device the bytes of weights + KV
+        step-program count and the most it may be (the seam's budget: 2 +
+        the mixed step's shapes), and per device the bytes of weights + KV
         pages (+ recurrent state: ``state_bytes`` of ``kv_bytes``,
         ``state_bytes_per_slot`` of that a batch slot; beside
         ``kv_bytes`` what a token costs in one layer's pages and the
@@ -484,6 +527,7 @@ class InferenceEngine:
                 "tp": self.tp,
                 "paged_impl": self._fns.paged_impl,
                 "compiled_step_programs": self.compiled_step_programs(),
+                "step_program_budget": self._fns.program_budget,
                 "param_bytes": self._param_bytes,
                 "kv_bytes": self._kv_bytes,
                 "kv_token_layer_bytes": self._kv_token_layer_bytes,
@@ -683,6 +727,72 @@ class InferenceEngine:
                 end += C
         return rows
 
+    def _mixed_shape(self, n_rows: int) -> Tuple[int, int]:
+        """(rows, token slots) of the mixed step with ``n_rows`` chunk
+        rows: the decode rows first, one token each, then the chunks."""
+        return (self.max_batch + n_rows,
+                self.max_batch + n_rows * self.prefill_chunk)
+
+    def _pack_mixed(self, active: List[Tuple[int, SequenceState]],
+                    rows: List[Tuple[SequenceState, int, int]],
+                    n_rows: int):
+        """The mixed step's host arrays in its shape of ``n_rows`` chunk
+        rows (>= len(rows)): decode rows first (slot r owns ragged token
+        r), then the chunk rows packed from token max_batch on; what
+        holds no token is padding (q_len 0, the scratch page, the scratch
+        state slot). Returns (the program's eight arrays, token_state)."""
+        ps = self.page_size
+        R, Tcap = self._mixed_shape(n_rows)
+        tokens = np.zeros(Tcap, np.int32)
+        token_pos = np.zeros(Tcap, np.int32)
+        token_page = np.full(Tcap, SCRATCH_PAGE, np.int32)
+        token_slot = np.zeros(Tcap, np.int32)
+        q_start = np.zeros(R, np.int32)
+        q_len = np.zeros(R, np.int32)
+        kv_len = np.zeros(R, np.int32)
+        ptab = np.full((R, self.max_pages_per_seq), SCRATCH_PAGE,
+                       np.int32)
+        # each token's conv-state slot: its sequence's batch slot,
+        # the scratch slot (max_batch) for padding
+        token_state = np.full(Tcap, self.max_batch, np.int32)
+        q_start[:self.max_batch] = np.arange(self.max_batch,
+                                             dtype=np.int32)
+        ptab[:self.max_batch] = self._page_table
+        for i, s in active:
+            pos = int(self._positions[i])
+            tokens[i] = self._tokens[i]
+            token_pos[i] = pos
+            token_page[i] = self._page_table[i, pos // ps]
+            token_slot[i] = pos % ps
+            q_len[i] = 1
+            kv_len[i] = s.num_tokens
+            token_state[i] = i
+        t0 = self.max_batch
+        for j, (seq, start, C) in enumerate(rows):
+            r = self.max_batch + j
+            pos = np.arange(start, start + C, dtype=np.int32)
+            tokens[t0:t0 + C] = seq.prompt[start:start + C]
+            token_pos[t0:t0 + C] = pos
+            pages = np.asarray(seq.pages, np.int32)
+            token_page[t0:t0 + C] = pages[pos // ps]
+            token_slot[t0:t0 + C] = pos % ps
+            ptab[r, :len(seq.pages)] = pages
+            q_start[r] = t0
+            q_len[r] = C
+            kv_len[r] = start + C
+            token_state[t0:t0 + C] = seq.slot
+            t0 += C
+        return (tokens, token_pos, token_page, token_slot, ptab, q_start,
+                q_len, kv_len), token_state
+
+    def _upload_mixed(self, arrays, token_state):
+        """_pack_mixed's arrays on the device: (the program's positional
+        arguments, its token_state keyword where the block has state)."""
+        args = [jnp.asarray(a) for a in arrays]
+        state_arg = {"token_state": jnp.asarray(token_state)} \
+            if self._has_state else {}
+        return args, state_arg
+
     def _ragged_dispatch(self, finished: Dict[str, List[int]],
                          after_dispatch: Optional[Callable[[], None]],
                          ) -> bool:
@@ -693,61 +803,24 @@ class InferenceEngine:
         after another in position). Rows whose chunk finishes its prompt
         get their first sampled token from the SAME dispatch (fused
         argmax; the sequence's LAST row's) — no extra program, no extra
-        readback. Returns False (no dispatch) when no chunk work exists,
-        sending the step to the pure-decode loop instead."""
+        readback. The step runs in the smallest of the seam's shapes
+        that holds the rows dealt (StepPrograms.row_shapes), and its
+        arrays, its counters and its span follow THAT shape; a step dealt
+        prefill_rows rows is the full shape's. Returns False (no
+        dispatch) when no chunk work exists, sending the step to the
+        pure-decode loop instead."""
         rows = self._deal_chunk_rows()
         if not rows:
             return False
+        # the smallest compiled shape that holds the deal
+        n_rows = next(n for n in self._fns.row_shapes if n >= len(rows))
+        R, Tcap = self._mixed_shape(n_rows)
         with self.phase("engine.pack"):
             # decode rows advance one token: they need a page for it
             active = self._decode_rows(1, finished)
-            ps = self.page_size
-            Tcap, R = self.ragged_tokens, self.ragged_rows
-            tokens = np.zeros(Tcap, np.int32)
-            token_pos = np.zeros(Tcap, np.int32)
-            token_page = np.full(Tcap, SCRATCH_PAGE, np.int32)
-            token_slot = np.zeros(Tcap, np.int32)
-            q_start = np.zeros(R, np.int32)
-            q_len = np.zeros(R, np.int32)
-            kv_len = np.zeros(R, np.int32)
-            ptab = np.full((R, self.max_pages_per_seq), SCRATCH_PAGE,
-                           np.int32)
-            # each token's conv-state slot: its sequence's batch slot,
-            # the scratch slot (max_batch) for padding
-            token_state = np.full(Tcap, self.max_batch, np.int32)
-            q_start[:self.max_batch] = np.arange(self.max_batch,
-                                                 dtype=np.int32)
-            ptab[:self.max_batch] = self._page_table
-            for i, s in active:
-                pos = int(self._positions[i])
-                tokens[i] = self._tokens[i]
-                token_pos[i] = pos
-                token_page[i] = self._page_table[i, pos // ps]
-                token_slot[i] = pos % ps
-                q_len[i] = 1
-                kv_len[i] = s.num_tokens
-                token_state[i] = i
-            t0 = self.max_batch
-            for j, (seq, start, C) in enumerate(rows):
-                r = self.max_batch + j
-                pos = np.arange(start, start + C, dtype=np.int32)
-                tokens[t0:t0 + C] = seq.prompt[start:start + C]
-                token_pos[t0:t0 + C] = pos
-                pages = np.asarray(seq.pages, np.int32)
-                token_page[t0:t0 + C] = pages[pos // ps]
-                token_slot[t0:t0 + C] = pos % ps
-                ptab[r, :len(seq.pages)] = pages
-                q_start[r] = t0
-                q_len[r] = C
-                kv_len[r] = start + C
-                token_state[t0:t0 + C] = seq.slot
-                t0 += C
+            packed = self._pack_mixed(active, rows, n_rows)
         with self.phase("engine.h2d"):
-            args = [jnp.asarray(a) for a in (
-                tokens, token_pos, token_page, token_slot, ptab, q_start,
-                q_len, kv_len)]
-            state_arg = {"token_state": jnp.asarray(token_state)} \
-                if self._has_state else {}
+            args, state_arg = self._upload_mixed(*packed)
         with self.phase("engine.dispatch"):
             nxt, self.kv = self._fns.ragged_step(self.params, *args,
                                                  self.kv, **state_arg)
@@ -763,6 +836,8 @@ class InferenceEngine:
             disp_idx = self.stats["ragged_dispatches"]
             self.stats["ragged_real_tokens"] += len(active) + chunk_tokens
             self.stats["ragged_slot_tokens"] += Tcap
+            if n_rows < self.prefill_rows:
+                self.stats["ragged_small_dispatches"] += 1
             self.stats["prefill_tokens"] += chunk_tokens
             self.stats["chunk_rows"] += len(rows)
             # a joined row starts past what its sequence has computed
@@ -993,10 +1068,8 @@ class InferenceEngine:
             for i, s in active:
                 seq_lens[i] = s.num_tokens
         with self.phase("engine.h2d"):
-            tokens, positions, page_table, seq_lens = (
-                jnp.asarray(a) for a in (
-                    self._tokens, self._positions, self._page_table,
-                    seq_lens))
+            tokens, positions, page_table, seq_lens = \
+                self._upload_decode(seq_lens)
         with self.phase("engine.dispatch"):
             toks_out, self.kv, _, _ = self._fns.decode_loop(
                 self.params, tokens, positions, self.kv, page_table,
@@ -1025,6 +1098,12 @@ class InferenceEngine:
                 else:
                     self._tokens[slot] = toks[-1]
                     self._positions[slot] = seq.num_tokens - 1
+
+    def _upload_decode(self, seq_lens: np.ndarray):
+        """The decode loop's inputs on the device: every slot's token,
+        position and pages as the engine holds them, and ``seq_lens``."""
+        return tuple(jnp.asarray(a) for a in (
+            self._tokens, self._positions, self._page_table, seq_lens))
 
     def _note_counters(self, out: np.ndarray, n_tokens: int, span):
         """Split a step program's flat output into its tokens and the
@@ -1098,19 +1177,21 @@ class InferenceEngine:
         # the gauge window
         programs = self.compiled_step_programs()
         self._g_programs.set(float(programs))
-        # the >3-programs invariant was test-only until now: in
-        # production, cross-check against the compile tracker and raise
-        # ONE llm_compile_invariant_breach cluster-journal event per
-        # excursion, carrying the tracker's signature diff — the exact
-        # argument whose shape moved. Re-arms if the count ever drops
-        # (fresh process / cache clear).
-        if programs > 3:
+        # the O(1)-programs invariant (the seam's budget: decode loop,
+        # page copy, the mixed step once a shape) was test-only until
+        # now: in production, cross-check against the compile tracker
+        # and raise ONE llm_compile_invariant_breach cluster-journal
+        # event per excursion, carrying the tracker's signature diff —
+        # the exact argument whose shape moved. Re-arms if the count
+        # ever drops (fresh process / cache clear).
+        budget = self._fns.program_budget
+        if programs > budget:
             if not self._invariant_breached and self._tracker is not None:
                 self._invariant_breached = True
                 culprit = self._tracker.last_recompile("llm.") or {}
                 self._tracker.stage_journal_event(
                     "llm_compile_invariant_breach",
-                    programs=programs, budget=3,
+                    programs=programs, budget=budget,
                     callable=culprit.get("name", ""),
                     diff=culprit.get("diff", []),
                     signature=culprit.get("signature", []))

@@ -907,6 +907,18 @@ copy_page = functools.partial(jax.jit, donate_argnames=("kv",))(
 _init_params = jax.jit(init_params, static_argnums=(0,))
 
 
+def chunk_row_shapes(prefill_rows: int) -> Tuple[int, ...]:
+    """The chunk-row counts the mixed step compiles for: 1, 2, 4, ...
+    below ``prefill_rows``, and ``prefill_rows`` itself ({1} at 1, {1, 2}
+    at 2, {1, 2, 4} at 4, {1, 2, 4, 6} at 6). A step runs the smallest
+    that holds the rows it was dealt."""
+    shapes, r = [], 1
+    while r < prefill_rows:
+        shapes.append(r)
+        r *= 2
+    return (*shapes, prefill_rows)
+
+
 class StepPrograms:
     """The ONE seam between the engine and its device programs: the mixed
     ragged step, the multi-step decode loop and the COW page copy, the
@@ -916,17 +928,24 @@ class StepPrograms:
 
     ``mesh=None``: the module-level jits above. A ('tp',) mesh: one
     ``shard_map`` jit each over the raw bodies, built once per (cfg,
-    mesh), specs from llm/tp.py. Either way a program has ONE static
-    shape and compiles once; where a compile tracker runs, the three
-    callables record their compiles with it (llm.ragged_step,
-    llm.decode_loop, llm.copy_page).
+    mesh), specs from llm/tp.py. Either way the decode loop and the page
+    copy have ONE static shape each and the mixed step ``row_shapes`` of
+    them (``chunk_row_shapes(prefill_rows)``: decode_rows + r rows,
+    decode_rows + r * max_q_len token slots; one jit, one body, a trace a
+    shape); each compiles once, ``program_budget`` programs in all, and
+    where a compile tracker runs, the three callables record their
+    compiles with it (llm.ragged_step, llm.decode_loop, llm.copy_page).
     """
 
     def __init__(self, cfg: LlamaConfig, *, decode_chunk: int,
                  max_q_len: int, decode_rows: int, kv_quantized: bool,
-                 mesh=None):
+                 prefill_rows: int = 1, mesh=None):
         self.cfg = cfg
         self.mesh = mesh
+        #: chunk rows of each shape the mixed step may be called in, and
+        #: what the engine's programs may number: more is a breach
+        self.row_shapes = chunk_row_shapes(prefill_rows)
+        self.program_budget = 2 + len(self.row_shapes)
         #: the paged-attention (and expert) implementation the programs
         #: compile: the Pallas kernels on a TPU, the references elsewhere
         #: — observed, never configured (device_report()), and from the
@@ -1007,9 +1026,10 @@ class StepPrograms:
 
     def compiled_step_programs(self) -> int:
         """Resident compiled step programs: the O(1) compile budget the
-        ragged design promises. Without a mesh the three module jits
-        share their cache across engines, so the count is process-wide
-        (in a fresh process running one engine, exactly that engine's)."""
+        ragged design promises (``program_budget``). Without a mesh the
+        three module jits share their cache across engines, so the count
+        is process-wide (in a fresh process running one engine, exactly
+        that engine's)."""
         return sum(jit._cache_size() for jit, _ in self.jits.values())
 
     def init_params(self, seed: int) -> Params:

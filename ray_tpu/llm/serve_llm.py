@@ -52,6 +52,10 @@ class LLMServer:
                  chat_template=None):
         cfg = LlamaConfig.tiny(**(model_config or {}))
         self.engine = InferenceEngine(cfg, **(engine_config or {}))
+        # every program the engine can dispatch, compiled and loaded now:
+        # nothing compiles once the replica is ready, whatever shapes the
+        # traffic reaches first
+        self.engine.load_step_programs()
         self.engine.track_progress = True  # the serve loop drains it
         # hand-overs to the waiters, and those made under a running program
         self.engine.stats.update(publishes=0, publishes_overlapped=0)

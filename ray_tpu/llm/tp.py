@@ -35,10 +35,13 @@ from ray_tpu.models.llama import LlamaConfig, Params
 
 TP_AXIS = "tp"
 
-#: paged KV pool [n_layers, pages, Hkv, page_size, D] — heads sharded
-CACHE_SPEC = P(None, None, TP_AXIS, None, None)
+#: paged KV pool [n_layers, pages, Hkv, page_size, D] — heads sharded.
+#: Written as a program's result comes back (no trailing unsharded axes):
+#: the pool as it is born and as a step returns it are then ONE entry of
+#: a jit's cache, and compiled_step_programs() counts a program once
+CACHE_SPEC = P(None, None, TP_AXIS)
 #: int8 KV scale arrays [n_layers, pages, Hkv, page_size] — same axis
-SCALE_SPEC = P(None, None, TP_AXIS, None)
+SCALE_SPEC = P(None, None, TP_AXIS)
 
 
 def kv_specs(quantized: bool) -> dict:

@@ -466,19 +466,23 @@ def llm_queue_depth_gauge() -> Gauge:
 
 
 def llm_compiled_programs_gauge() -> Gauge:
-    """Compiled LLM step programs resident (ragged mixed step + decode
-    loop + COW page copy). O(1) by design — a rise means the engine
-    started recompiling on shape changes, the regression the ragged
-    single-dispatch step exists to prevent."""
+    """Compiled LLM step programs resident: the ragged mixed step once a
+    chunk-row shape (1, 2, 4, ... below llm_ragged_prefill_rows, and that
+    number: two at the default), the decode loop, the COW page copy. A
+    served replica shows the whole number (4 at the default; 3 without a
+    prefix cache) from its start. O(1) by design — a rise past it means
+    the engine started recompiling on shape changes, the regression the
+    ragged single-dispatch step exists to prevent."""
     return Gauge("llm_compiled_step_programs",
                  description="compiled LLM step programs resident")
 
 
 def llm_padding_waste_gauge() -> Gauge:
     """Fraction of ragged-step token slots that carried padding instead
-    of real prompt/decode tokens, over the gauge window — the cost of
-    the fixed ragged shape; high values say shrink prefill_rows or
-    prefill_chunk for this workload."""
+    of real prompt/decode tokens, over the gauge window, of the shapes
+    the steps RAN (the smallest compiled one that held each step's
+    chunk rows) — the cost of the fixed ragged shapes; high values say
+    shrink prefill_chunk for this workload."""
     return Gauge("llm_ragged_padding_waste",
                  description="padding fraction of ragged step token "
                              "slots (0..1)")

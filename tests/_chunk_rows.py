@@ -6,10 +6,19 @@ the same weights, page_size 8 and prefill_chunk 16. Float32 on the CPU, so
 the two engines give the same greedy tokens or something is wrong: which
 step a row rides in must not change what it computes.
 
+And cases of the SHAPE a mixed step runs in (llm/engine.py:
+_ragged_dispatch): the smallest of the seam's compiled shapes that holds
+the rows dealt. serve() holds every mixed step of every case, old and new,
+to that rule and the books to the shapes run; SHAPE_CASES feed an engine
+with two chunk rows one-row and two-row steps in turn and hold it, token
+for token, to the same engine pinned to its full shape (the one static
+shape every mixed step ran in before the set existed).
+
 Not a test file: tests/test_llm.py (per-head pool) and
 tests/test_llm_kanana.py (latent pool) parametrise over CASES;
-tests/test_llm_lfm2.py and tests/test_llm_granite.py (recurrent state)
-hold their engines to one row a sequence.
+tests/test_llm_lfm2.py, tests/test_llm_granite.py and
+tests/test_llm_brumby.py (recurrent state) hold their engines to one row a
+sequence; all of them, and tests/test_llm_moe.py, over SHAPE_CASES.
 """
 
 import numpy as np
@@ -17,6 +26,7 @@ import numpy as np
 CHUNK = 16
 CASES = ("alone-2", "alone-3", "alone-5", "prefix-hit", "copy-on-write",
          "together", "budget")
+SHAPE_CASES = ("lone", "pair", "in-turn")
 
 
 def watch(eng):
@@ -38,14 +48,39 @@ def spans(deals):
     return [[(start, n) for _, start, n in d] for d in deals]
 
 
-def serve(eng, prompts, n_new=6):
-    """Add the prompts together, drain the engine, and check each mixed
-    step's books against its deal. Returns ([tokens a prompt], deals)."""
+def pin_full_shape(eng):
+    """Hold ``eng`` to ONE mixed-step shape, its full one, whatever it is
+    dealt: what every engine did before a step chose among shapes."""
+    eng._fns.row_shapes = (eng.prefill_rows,)
+    return eng
+
+
+def shape_of(eng, deal):
+    """(chunk rows, rows, token slots) of the shape ``eng`` must run
+    ``deal`` in: the smallest of its seam's that holds the deal's rows."""
+    n = min(r for r in eng._fns.row_shapes if r >= len(deal))
+    return n, eng.max_batch + n, eng.max_batch + n * eng.prefill_chunk
+
+
+def serve(eng, prompts, n_new=6, at=None):
+    """Add the prompts (together, or prompt i before step ``at[i]``),
+    drain the engine, and check each mixed step's books against its deal
+    and the arrays it dispatched against the shape the deal asks for.
+    Returns ([tokens a prompt], deals)."""
     deals = watch(eng)
     before = dict(eng.stats)
-    rids = [eng.add_request(list(p), n_new) for p in prompts]
-    done, seen = {}, 0
-    for _ in range(400):
+    at = list(at or [0] * len(prompts))
+    rids, dispatched, run = {}, [], eng._fns.ragged_step
+
+    def recording(params, tokens, pos, page, slot, page_table, *rest, **kw):
+        dispatched.append((page_table.shape[0], tokens.shape[0]))
+        return run(params, tokens, pos, page, slot, page_table, *rest, **kw)
+    eng._fns.ragged_step = recording
+    done, seen, slots = {}, 0, []
+    for step in range(400):
+        for i, p in enumerate(prompts):
+            if at[i] == step:
+                rids[i] = eng.add_request(list(p), n_new)
         done.update(eng.step())
         if len(deals) > seen:                   # this step was a mixed one
             seen = len(deals)
@@ -53,18 +88,28 @@ def serve(eng, prompts, n_new=6):
             assert meta["kind"] == "mixed"
             assert meta["real_tokens"] - meta["decode_rows"] \
                 == sum(n for _, _, n in deals[-1])
-        if not eng.has_work():
+            n, R, T = shape_of(eng, deals[-1])
+            assert dispatched[-1] == (R, T) and len(dispatched) == seen
+            assert meta["slot_tokens"] == T
+            slots.append((n, T))
+        if len(rids) == len(prompts) and not eng.has_work():
             break
     del eng._deal_chunk_rows                    # the class's again
+    eng._fns.ragged_step = run
+    rids = [rids[i] for i in range(len(prompts))]
     assert set(rids) <= set(done)
     rows = [r for d in deals for r in d]
     joined = sum(len(d) - len({rid for rid, _, _ in d}) for d in deals)
     got = {k: eng.stats[k] - before[k] for k in (
         "chunk_rows", "chunk_rows_joined", "prefill_tokens",
-        "ragged_dispatches")}
+        "ragged_dispatches", "ragged_small_dispatches",
+        "ragged_slot_tokens")}
     assert got == {"chunk_rows": len(rows), "chunk_rows_joined": joined,
                    "prefill_tokens": sum(n for _, _, n in rows),
-                   "ragged_dispatches": len(deals)}
+                   "ragged_dispatches": len(deals),
+                   "ragged_small_dispatches": sum(
+                       n < eng.prefill_rows for n, _ in slots),
+                   "ragged_slot_tokens": sum(T for _, T in slots)}
     budget = eng.step_token_budget or 1 << 30
     for d in deals:
         assert len(d) <= eng.prefill_rows
@@ -152,6 +197,49 @@ def check(case, one, two, vocab=256):
             [(68, 2)]]
     else:
         raise ValueError(case)
+
+
+def check_shapes(case, shaped, full, vocab=256):
+    """Run ``case`` on ``shaped`` (prefill_rows 2: a step runs the
+    one-row shape or the two-row one) and on ``full`` (the same weights
+    and settings behind pin_full_shape): the same deals, the same tokens,
+    and each step of ``shaped`` in the shape its deal asks for, which
+    serve() checks."""
+    assert shaped.prefill_rows == full.prefill_rows == 2
+    assert shaped._fns.row_shapes == (1, 2) and full._fns.row_shapes == (2,)
+    rng = np.random.default_rng(sum(map(ord, case)) + 1)
+    new = lambda n: rng.integers(0, vocab, n).tolist()       # noqa: E731
+    if case == "lone":
+        # a prompt of one chunk, nobody else: ONE row, the small shape
+        prompts, at, rows = [new(11)], None, [1]
+    elif case == "pair":
+        # two one-chunk prompts together: both rows, the full shape
+        prompts, at, rows = [new(9), new(14)], None, [2]
+    elif case == "in-turn":
+        # arrivals while others decode: full and small steps in turn,
+        # beside decode rows
+        prompts = [new(40), new(10), new(12), new(7), new(20)]
+        at, rows = [0, 0, 3, 6, 6], None
+    else:
+        raise ValueError(case)
+    want, d_full = serve(full, prompts, at=at)
+    small = shaped.stats["ragged_small_dispatches"]
+    got, deals = serve(shaped, prompts, at=at)
+    assert got == want and deals_of(deals) == deals_of(d_full)
+    n_small = shaped.stats["ragged_small_dispatches"] - small
+    assert n_small == sum(len(d) == 1 for d in deals)
+    if rows is None:                            # both shapes, in turn
+        assert 0 < n_small < len(deals)
+    else:
+        assert [len(d) for d in deals] == rows
+    assert full.stats["ragged_small_dispatches"] == 0
+
+
+def deals_of(deals):
+    """The deals with each request id replaced by its order of arrival."""
+    order = {}
+    return [[(order.setdefault(rid, len(order)), start, n)
+             for rid, start, n in d] for d in deals]
 
 
 def check_state_keeps_one_row(eng):

@@ -335,15 +335,19 @@ def test_stage_journal_event_stamps_identity():
     """Arbitrary staged events (the engine's invariant breach) carry
     the process identity without caller plumbing, and staging is
     bounded."""
+    from ray_tpu.llm.model import chunk_row_shapes
+    # the seam's budget at the default two chunk rows: decode loop, page
+    # copy, the mixed step in each of its shapes {1, 2}
+    budget = 2 + len(chunk_row_shapes(2))
     tr = ct.CompileTracker(role="worker", node="n1", worker="w1")
     tr.stage_journal_event("llm_compile_invariant_breach",
-                           programs=4, budget=3)
+                           programs=budget + 1, budget=budget)
     evs = tr.drain_journal_events()
     assert len(evs) == 1
     ev = evs[0]
     assert ev["type"] == "llm_compile_invariant_breach"
     assert ev["role"] == "worker" and ev["worker"] == "w1"
-    assert ev["programs"] == 4 and ev["budget"] == 3
+    assert ev["programs"] == 5 and ev["budget"] == 4
     for i in range(200):
         tr.stage_journal_event("e", i=i)
     assert len(tr.drain_journal_events()) == ct._MAX_JOURNAL

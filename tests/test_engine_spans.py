@@ -119,6 +119,18 @@ def test_step_kinds_equal_the_dispatch_counters(profiled):
         == after["ragged_real_tokens"] - before["ragged_real_tokens"]
     assert sum(m["slot_tokens"] for m in meta if m["kind"] == "mixed") \
         == after["ragged_slot_tokens"] - before["ragged_slot_tokens"]
+    # a mixed step's slot_tokens is the shape it RAN in: max_batch decode
+    # slots + the smallest compiled number of chunk rows that held its
+    # deal; the steps that ran the smaller one are counted
+    shapes = {ENGINE["max_batch"] + r * ENGINE["prefill_chunk"]
+              for r in (1, ENGINE["prefill_rows"])}
+    slots = [m["slot_tokens"] for m in meta if m["kind"] == "mixed"]
+    assert set(slots) <= shapes
+    assert sum(s == min(shapes) for s in slots) \
+        == after["ragged_small_dispatches"] \
+        - before["ragged_small_dispatches"]
+    assert all(m["real_tokens"] <= m["slot_tokens"] for m in meta
+               if m["kind"] == "mixed")
     # a mixed step's real tokens: one a decode row, the rest its chunk
     # rows', one row at least and prefill_rows at most, a sequence's
     # second row in a step counted as joined

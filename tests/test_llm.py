@@ -17,7 +17,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _chunk_rows import CASES, check, check_preempted
+from _chunk_rows import (CASES, SHAPE_CASES, check, check_preempted,
+                         check_shapes, pin_full_shape, serve, shape_of)
 from ray_tpu.llm import InferenceEngine
 from ray_tpu.llm.cache import PageAllocator
 from ray_tpu.models.llama import LlamaConfig, forward, init_params
@@ -222,7 +223,7 @@ def test_step_program_names_are_the_benchmarks(params, tp):
                    for rx in _metric_patterns(metric_file)), \
             (module, metric_file)
     if tp > 1:       # its own jits; tp=1 counts the process's shared ones
-        assert eng.compiled_step_programs() <= 3
+        assert eng.compiled_step_programs() <= eng._fns.program_budget == 4
 
 
 def test_page_allocator():
@@ -328,6 +329,126 @@ def test_joined_chunk_rows_compute_what_one_row_a_step_does(rows_1_and_2,
     check(case, *rows_1_and_2, vocab=CFG.vocab_size)
 
 
+@pytest.fixture(scope="module", params=[1, 2], ids=["tp1", "tp2"])
+def shaped_and_full(params, request):
+    """The same weights behind the set of mixed-step shapes and behind
+    the full shape alone (what every step ran in before the set); tp=2:
+    the shard_map programs on the CPU mesh, a trace a shape too."""
+    make = lambda: InferenceEngine(                          # noqa: E731
+        CFG, params, page_size=8, total_pages=128, max_batch=4,
+        max_seq_len=128, prefill_chunk=16, prefill_rows=2, decode_chunk=4,
+        tp=request.param)
+    return [make(), pin_full_shape(make())]
+
+
+@pytest.mark.parametrize("case", SHAPE_CASES)
+def test_a_mixed_step_runs_the_smallest_shape_that_holds_its_rows(
+        shaped_and_full, case):
+    """A lone one-chunk prompt runs the one-row shape, two prompts the
+    two-row one, and arrivals beside decode rows both in turn: token for
+    token what the full shape alone serves, the books the shapes' run."""
+    check_shapes(case, *shaped_and_full, vocab=CFG.vocab_size)
+    shaped = shaped_and_full[0]
+    if shaped.tp > 1:                       # its own jits: its own count
+        assert shaped.compiled_step_programs() \
+            <= shaped._fns.program_budget == 4
+
+
+@pytest.mark.parametrize("prefill_rows,shapes,dealt,ran", [
+    (1, (1,), 1, 1), (1, (1,), 3, 1), (4, (1, 2, 4), 1, 1),
+    (4, (1, 2, 4), 2, 2), (4, (1, 2, 4), 3, 4), (4, (1, 2, 4), 4, 4),
+    (6, (1, 2, 4, 6), 5, 6)])
+def test_the_shapes_follow_from_prefill_rows(params, rows_1_and_2,
+                                             prefill_rows, shapes, dealt,
+                                             ran):
+    """1, 2, 4, ... below prefill_rows and prefill_rows itself, no
+    setting: a deal of 3 rows of 4 runs the 4-row shape, of 5 of 6 the
+    6-row one. ``dealt`` one-chunk prompts arrive together (one row a
+    step where prefill_rows is 1) and are served what each is alone."""
+    eng = InferenceEngine(CFG, params, page_size=8, total_pages=128,
+                          max_batch=6, max_seq_len=128, prefill_chunk=16,
+                          prefill_rows=prefill_rows)
+    assert eng._fns.row_shapes == shapes
+    assert eng._fns.program_budget == 2 + len(shapes)
+    assert (eng.ragged_rows, eng.ragged_tokens) \
+        == (6 + prefill_rows, 6 + 16 * prefill_rows)
+    prompts = [[(7 * i + 3 * j + prefill_rows) % CFG.vocab_size
+                for i in range(5 + 2 * j)] for j in range(dealt)]
+    # serve() holds each step's arrays and books to shape_of(its deal)
+    got, deals = serve(eng, prompts)
+    assert len(deals[0]) == min(dealt, prefill_rows)
+    assert shape_of(eng, deals[0]) == (ran, 6 + ran, 6 + 16 * ran)
+    for p, out in zip(prompts, got):
+        assert out == rows_1_and_2[0].generate(p, 6)
+
+
+def _bench_entry(name):
+    """(BENCHMARK.json's per_layer list, the entry ``name``, its data
+    file, its reader module)."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)["per_layer"]
+    with open(os.path.join(root, "benchmark", "metrics",
+                           name + ".json")) as f:
+        spec = json.load(f)
+    assert spec["name"] == name
+    return bench, next(m for m in bench if m["name"] == name), spec, \
+        importlib.import_module("benchmark.readers." + spec["reader"])
+
+
+_CLOSED_LOOP = ["reason-1chip", "reason-moe-1chip", "reason-lfm2-1chip",
+                "context-kanana-1chip", "reason-granite-1chip",
+                "context-brumby-1chip"]
+
+
+@pytest.mark.parametrize("name,cells,moves", [
+    ("mixed_small_shape_pct", _CLOSED_LOOP, "out_tok_per_s"),
+    ("mixed_small_shape_pct.chat", ["chat-1chip"], "tpot_p50_ms")])
+def test_the_small_shapes_metric_reads_the_counter(rows_1_and_2, name,
+                                                   cells, moves):
+    """The data file over a prompt of 45 served alone (rows of 16 + 16 in
+    the full shape, then 13 in the one-row shape): half the mixed steps
+    small; 0 on an engine with one shape (prefill_rows 1: the control
+    cell); and a program without the counter (the parent commit, in the
+    driver's traced run of it) reads nothing and does not raise."""
+    bench, entry, spec, reader = _bench_entry(name)
+    assert entry["workloads"] == cells and entry["moves"] == moves
+    assert entry["source"] == "program_counter"
+    assert entry["layer"] == next(
+        m for m in bench if m["name"] == "batch_occupancy_pct")["layer"]
+    for eng, want in zip(rows_1_and_2, (0.0, 50.0)):
+        a = dict(eng.stats)
+        eng.generate([(11 * i + len(name)) % CFG.vocab_size
+                      for i in range(45)], 3)
+        data = {"stats_open": a, "stats_close": dict(eng.stats),
+                "config": {}}
+        assert reader.read(data, spec["args"]) == pytest.approx(want)
+    old = {k: {s: v for s, v in data[k].items()
+               if s != "ragged_small_dispatches"}
+           for k in ("stats_open", "stats_close")}
+    assert reader.read(dict(old, config={}), spec["args"]) is None
+
+
+@pytest.mark.parametrize("name,like", [
+    ("mixed_step_ms.reason", "mixed_step_ms.granite"),
+    ("mixed_step_time_pct.reason", "mixed_step_time_pct.granite")])
+def test_the_claimed_cells_mixed_step_metrics_are_granites_readers(name,
+                                                                   like):
+    """reason-1chip had no reading of its mixed step: the two data files
+    are granite's readers and patterns under new names, so the parent's
+    trace gives both (both shapes are traces of one jit: one module
+    name, and the reading is a mean over the shapes run)."""
+    bench, entry, spec, _ = _bench_entry(name)
+    _, other, other_spec, _ = _bench_entry(like)
+    assert entry["workloads"] == ["reason-1chip"]
+    assert {k: entry[k] for k in ("unit", "better", "source", "layer",
+                                  "moves")} \
+        == {k: other[k] for k in ("unit", "better", "source", "layer",
+                                  "moves")}
+    assert (spec["reader"], spec["args"]) \
+        == (other_spec["reader"], other_spec["args"])
+
+
 @pytest.mark.parametrize("name,cells,want,parent", [
     ("chunk_rows_joined_pct", ["context-kanana-1chip", "reason-moe-1chip",
                                "reason-granite-1chip"], 100.0 / 3, None),
@@ -340,19 +461,11 @@ def test_the_deals_two_metrics_read_the_counters(rows_1_and_2, name, cells,
     step; and a program without the row counters (the parent commit, in
     the driver's traced run of it) reads nothing for the share and does
     not raise, and reads the tokens a step, whose keys are older."""
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "BENCHMARK.json")) as f:
-        bench = json.load(f)["per_layer"]
-    entry = next(m for m in bench if m["name"] == name)
+    bench, entry, spec, reader = _bench_entry(name)
     assert entry["workloads"] == cells and entry["moves"] == "out_tok_per_s"
     assert entry["source"] == "program_counter"
     assert entry["layer"] == next(
         m for m in bench if m["name"] == "batch_occupancy_pct")["layer"]
-    with open(os.path.join(root, "benchmark", "metrics",
-                           name + ".json")) as f:
-        spec = json.load(f)
-    assert spec["name"] == name
-    reader = importlib.import_module("benchmark.readers." + spec["reader"])
     eng = rows_1_and_2[1]
     a = dict(eng.stats)
     # a prompt of its own a metric: the prefix cache holds the other's
@@ -623,11 +736,13 @@ def test_mixed_length_prompts_share_one_dispatch(params):
 def test_compiled_step_programs_constant(params):
     """The compile-count contract: an engine serving wildly varying
     prompt lengths, chunk boundaries and batch occupancies compiles at
-    most THREE step programs (ragged mixed step, decode loop, COW
-    copy) — no per-length-bucket program zoo."""
+    most 2 + its mixed-step shapes programs (decode loop, COW copy, the
+    ragged mixed step once a chunk-row shape: FOUR with two chunk rows)
+    — no per-length-bucket program zoo."""
     eng = InferenceEngine(CFG, params, page_size=8, total_pages=64,
                           max_batch=3, max_seq_len=80, decode_chunk=3,
                           prefill_chunk=10)
+    assert eng._fns.row_shapes == (1, 2) and eng._fns.program_budget == 4
     before = eng.compiled_step_programs()
     for plen in (1, 4, 9, 10, 11, 23, 30):
         prompt = [(3 * i + 1) % CFG.vocab_size for i in range(plen)]
@@ -636,12 +751,97 @@ def test_compiled_step_programs_constant(params):
     eng.generate([(3 * i + 1) % CFG.vocab_size for i in range(16)], 4)
     eng.generate([(3 * i + 1) % CFG.vocab_size for i in range(16)], 4)
     assert eng.stats["cow_copies"] >= 1
+    # two prompts together take both chunk rows: the full shape (a chunk
+    # of 10 ends inside a page of 8, so a lone prompt keeps to one row)
+    for plen in (5, 12):
+        eng.add_request([(7 * i + 2) % CFG.vocab_size for i in range(plen)],
+                        4)
+    while eng.has_work():
+        eng.step()
+    assert 0 < eng.stats["ragged_small_dispatches"] \
+        < eng.stats["ragged_dispatches"]
+    # at most: the module-level jits are the process's, and another test
+    # of this file may have compiled one of these shapes already
     compiled = eng.compiled_step_programs() - before
-    assert 1 <= compiled <= 3, \
-        f"expected <=3 compiled step programs, got {compiled}"
+    assert 1 <= compiled <= eng._fns.program_budget, \
+        f"expected <=4 compiled step programs, got {compiled}"
     # spot-check parity so the count isn't trivially cheap
     p = [(3 * i + 1) % CFG.vocab_size for i in range(23)]
     assert eng.generate(p, 4) == _oracle_greedy(params, p, 4)
+
+
+@pytest.mark.parametrize("prefill_rows,budget", [(1, 3), (2, 4), (4, 5)])
+def test_a_program_past_the_seams_budget_is_a_breach(params, prefill_rows,
+                                                     budget):
+    """The gauge pass holds the resident programs to the SEAM's number
+    (2 + the mixed step's shapes), not to a literal: at the budget no
+    event; one past it (a shape outside the set compiled) ONE
+    llm_compile_invariant_breach event that carries the budget; again
+    only after the count has come back under it."""
+    eng = InferenceEngine(CFG, params, page_size=8, total_pages=64,
+                          max_batch=3, max_seq_len=80, decode_chunk=3,
+                          prefill_chunk=10, prefill_rows=prefill_rows)
+    assert eng._fns.program_budget == budget
+    tracker = eng._tracker
+    tracker.drain_journal_events()
+    resident = [budget, budget + 1, budget + 2, budget, budget + 1]
+    eng.compiled_step_programs = lambda: resident.pop(0)
+    events = []
+    for _ in range(5):
+        eng._update_metrics(force=True)
+        events.append([e for e in tracker.drain_journal_events()
+                       if e["type"] == "llm_compile_invariant_breach"])
+    assert [len(e) for e in events] == [0, 1, 0, 0, 1]
+    assert [(e[0]["programs"], e[0]["budget"]) for e in events if e] \
+        == [(budget + 1, budget), (budget + 1, budget)]
+
+
+def test_a_replica_compiles_nothing_once_it_is_ready(params):
+    """LLMServer brings every program its engine can dispatch to a
+    compiled, loaded state before its engine thread starts: both
+    mixed-step shapes, the decode loop and the page copy. One-row,
+    two-row, decode-only and copy-on-write traffic then moves neither
+    the compile tracker's counts nor the program count; and loading
+    booked nothing. A bare engine of the same shapes compiles lazily."""
+    from ray_tpu.llm.serve_llm import LLMServer
+    from ray_tpu.util import compile_tracker
+    # shapes no other test of this process uses: the replica must compile
+    engine = dict(params=params, page_size=8, total_pages=48, max_batch=5,
+                  max_seq_len=88, decode_chunk=5, prefill_chunk=24)
+    bare = InferenceEngine(CFG, **engine)
+    before = bare.compiled_step_programs()
+    assert bare._fns.program_budget == 4
+    server = LLMServer(dict(n_layers=2, dtype=jnp.float32), engine)
+    eng = server.engine
+    assert eng.compiled_step_programs() - before == 4
+    assert {k: v for k, v in eng.stats.items()
+            if v and not k.startswith(("wall_ns_", "cpu_ns_"))} == {}
+    assert eng.request_log is None or len(eng.request_log) == 0
+    counts = dict(compile_tracker.get_global().stats()["counts"])
+
+    def ask(n, start=3):
+        return server({"prompt_ids": [(5 * i + start) % CFG.vocab_size
+                                      for i in range(n)],
+                       "max_tokens": 7})["token_ids"]
+
+    import threading
+    one_row = ask(9)                                    # alone: one row
+    ask(40)                                             # alone: two rows
+    threads = [threading.Thread(target=ask, args=(n, n))
+               for n in (11, 30, 20)]                   # beside decode rows
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120)
+    ask(16)
+    ask(16)                     # every page cached: a copy-on-write
+    stats = eng.stats
+    assert stats["cow_copies"] >= 1 and stats["decode_dispatches"] >= 1
+    assert 0 < stats["ragged_small_dispatches"] < stats["ragged_dispatches"]
+    assert dict(compile_tracker.get_global().stats()["counts"]) == counts
+    assert eng.compiled_step_programs() - before == 4
+    assert one_row == _oracle_greedy(
+        params, [(5 * i + 3) % CFG.vocab_size for i in range(9)], 7)
 
 
 # ------------------------------------------------------------ int8 KV

@@ -31,7 +31,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from _chunk_rows import check_state_keeps_one_row  # noqa: E402
+from _chunk_rows import (check_state_keeps_one_row,  # noqa: E402
+                         SHAPE_CASES, check_shapes, pin_full_shape)
 from benchmark import reference_granite as ref  # noqa: E402
 from ray_tpu.llm import InferenceEngine, tp  # noqa: E402
 from ray_tpu.llm import model as M  # noqa: E402
@@ -289,11 +290,31 @@ def test_engine_chunked_prefill_and_decode_loop_match_reference(
     served = eng.generate(prompt, n_new)
     assert len(served) == n_new
     assert _worst_gap(eng, cfg, prompt, served) < TOL
-    assert eng.compiled_step_programs() <= 2     # no page copy: no prefix
+    # no page copy: no prefix cache
+    assert eng.compiled_step_programs() <= eng._fns.program_budget - 1 == 3
 
 
 def test_a_sequence_that_prefills_alone_keeps_one_row_a_step(granite):
     check_state_keeps_one_row(granite[1])
+
+
+@pytest.fixture(scope="module")
+def shaped_and_full():
+    """The same weights behind the set of mixed-step shapes and behind
+    the full shape alone (what every step ran in before the set)."""
+    cfg = LlamaConfig.tiny(**GRANITE)
+    params = _seeded(cfg)
+    return [InferenceEngine(cfg, params, **ENGINE),
+            pin_full_shape(InferenceEngine(cfg, params, **ENGINE))]
+
+
+@pytest.mark.parametrize("case", SHAPE_CASES)
+def test_a_mixed_step_runs_the_smallest_shape_that_holds_its_rows(
+        shaped_and_full, case):
+    """One-row and two-row steps in turn through the STATE-SPACE layers:
+    a row's slot and the scratch slot are addressed through token_state
+    in either shape, and the tokens are the full shape's."""
+    check_shapes(case, *shaped_and_full)
 
 
 def test_engine_mixed_batch_with_padding_rows_matches_reference(granite):

@@ -30,7 +30,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from _chunk_rows import CASES, check, check_preempted  # noqa: E402
+from _chunk_rows import (CASES, check, check_preempted,  # noqa: E402
+                         SHAPE_CASES, check_shapes, pin_full_shape)
 from benchmark import reference_kanana as ref  # noqa: E402
 from benchmark import reference_lfm2  # noqa: E402
 from ray_tpu.llm import InferenceEngine  # noqa: E402
@@ -271,7 +272,7 @@ def test_served_path_matches_the_reference_across_chunk_boundaries(kanana):
     for p, r in zip(prompts, rids):
         assert len(done[r]) == 12
         assert _worst_gap(eng, cfg, p, done[r]) < TOL
-    assert eng.compiled_step_programs() <= 3
+    assert eng.compiled_step_programs() <= eng._fns.program_budget == 4
     assert eng.stats["moe_pairs"] > 0
 
 
@@ -314,6 +315,24 @@ def test_joined_chunk_rows_compute_what_one_row_a_step_does(rows_1_and_2,
     sequence reads the rows the step's earlier row wrote into the one
     leaf, through the absorbed form, as it would a step later."""
     check(case, *rows_1_and_2)
+
+
+@pytest.fixture(scope="module")
+def shaped_and_full():
+    """The same weights behind the set of mixed-step shapes and behind
+    the full shape alone (what every step ran in before the set)."""
+    cfg = LlamaConfig.tiny(**KANANA)
+    params = _seeded(cfg)
+    return [InferenceEngine(cfg, params, **ENGINE),
+            pin_full_shape(InferenceEngine(cfg, params, **ENGINE))]
+
+
+@pytest.mark.parametrize("case", SHAPE_CASES)
+def test_a_mixed_step_runs_the_smallest_shape_that_holds_its_rows(
+        shaped_and_full, case):
+    """One-row and two-row steps in turn on the LATENT pool: the tokens
+    of the full shape alone, each step in the shape its deal asks for."""
+    check_shapes(case, *shaped_and_full)
 
 
 def test_a_preempted_sequences_re_prefill_takes_both_rows():

@@ -24,6 +24,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+from _chunk_rows import (SHAPE_CASES, check_shapes,  # noqa: E402
+                         pin_full_shape)
 from benchmark import reference_olmoe as ref  # noqa: E402
 from ray_tpu.llm import InferenceEngine  # noqa: E402
 from ray_tpu.llm.engine import CPU_KEY, WALL_KEYS  # noqa: E402
@@ -147,7 +149,7 @@ def test_engine_chunked_prefill_and_decode_loop_match_reference(olmoe):
     served = eng.generate(prompt, 13)
     assert len(served) == 13
     assert _worst_gap(eng, cfg, prompt, served) < TOL
-    assert eng.compiled_step_programs() <= 3
+    assert eng.compiled_step_programs() <= eng._fns.program_budget == 4
 
 
 def test_engine_batch_with_prefix_hit_and_cow_matches_reference(olmoe):
@@ -162,7 +164,7 @@ def test_engine_batch_with_prefix_hit_and_cow_matches_reference(olmoe):
     assert eng.stats["cow_copies"] > before["cow_copies"]
     for p, out in zip(prompts, [first] + [done[r] for r in rids]):
         assert _worst_gap(eng, cfg, p, out) < TOL
-    assert eng.compiled_step_programs() <= 3
+    assert eng.compiled_step_programs() <= eng._fns.program_budget == 4
 
 
 def test_engine_preemption_matches_reference():
@@ -263,6 +265,25 @@ def test_config_refuses_unknown_keys_and_bad_expert_counts():
         LLMServer(model_config={"hidden_size": 64})
 
 
+@pytest.fixture(scope="module")
+def shaped_and_full():
+    """The same weights behind the set of mixed-step shapes and behind
+    the full shape alone (what every step ran in before the set)."""
+    cfg = LlamaConfig.tiny(**OLMOE)
+    params = init_params(cfg, jax.random.PRNGKey(5))
+    return [InferenceEngine(cfg, params, **ENGINE),
+            pin_full_shape(InferenceEngine(cfg, params, **ENGINE))]
+
+
+@pytest.mark.parametrize("case", SHAPE_CASES)
+def test_a_mixed_step_runs_the_smallest_shape_that_holds_its_rows(
+        shaped_and_full, case):
+    """One-row and two-row steps in turn through the EXPERTS' block: the
+    routing counters ride behind the tokens of whichever shape ran, and
+    the tokens are the full shape's."""
+    check_shapes(case, *shaped_and_full)
+
+
 # ------------------------------------------- a dense configuration is as it was
 
 def test_dense_configuration_is_untouched():
@@ -277,7 +298,8 @@ def test_dense_configuration_is_untouched():
         "steps", "prefill_tokens", "decode_steps", "decode_tokens",
         "decode_dispatches", "cached_tokens", "ragged_dispatches",
         "ragged_real_tokens", "ragged_slot_tokens", "cow_copies",
-        "preemptions", "chunk_rows", "chunk_rows_joined"} \
+        "preemptions", "chunk_rows", "chunk_rows_joined",
+        "ragged_small_dispatches"} \
         | set(WALL_KEYS + (CPU_KEY,))                # every model's clocks
     # the step programs' outputs keep their shapes: [R] and [K, B]
     from ray_tpu.llm import model as M

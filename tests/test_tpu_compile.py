@@ -191,11 +191,26 @@ def _abstract(chip, fn):
                         jax.eval_shape(fn))
 
 
-def _compile_step_program(chip, cfg, program, *, max_batch, pages, max_seq,
-                          rows=2, chunk=512, ps=16):
-    """One of the engine's two step programs, compiled from shapes
+_COMPILED = {}
+
+
+def _compile_step_program(chip, cfg, program, **sizes):
+    """_compile_step_program_once, kept: a shape compiled for one test is
+    not compiled again for another (the one-row tests compare with the
+    full shape the configuration's own test compiled)."""
+    key = (cfg, program, tuple(sorted(sizes.items())))
+    if key not in _COMPILED:
+        _COMPILED[key] = _compile_step_program_once(chip, cfg, program,
+                                                    **sizes)
+    return _COMPILED[key]
+
+
+def _compile_step_program_once(chip, cfg, program, *, max_batch, pages,
+                               max_seq, rows=2, chunk=512, ps=16):
+    """One of the engine's step programs, compiled from shapes
     (jax.eval_shape: no weights exist): the mixed step over max_batch
-    decode rows + ``rows`` chunks of ``chunk``, or the 8-step decode loop.
+    decode rows + ``rows`` chunks of ``chunk`` (one of its shapes,
+    llm/model.py:chunk_row_shapes), or the 8-step decode loop.
     Returns (compiled, the pool's abstract pytree, rows of the result)."""
     from ray_tpu.llm import model as M
     from ray_tpu.llm.cache import make_kv_cache
@@ -508,3 +523,60 @@ def test_brumby_step_programs_compile_at_benchmark_shapes(chip, program):
     held = kv["retention"].size * 2 + kv["retention_norm"].size * 4
     assert mem.alias_size_in_bytes >= held
     assert mem.temp_size_in_bytes < 2**30 < kv["retention"].size * 2
+
+
+def _kanana_cfg(n_layers=2):
+    """kanana2-30b-a3b-serve-1chip's widths from its own file; 2 layers =
+    the leading dense layer and ONE expert layer (the scan's body)."""
+    import json
+    import os
+
+    from benchmark.runners import serve_kanana
+    from ray_tpu.models.llama import LlamaConfig
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "kanana2-30b-a3b-serve-1chip.json")) as f:
+        fields = serve_kanana.model_fields(json.load(f))
+    return LlamaConfig.tiny(**{**fields, "n_layers": n_layers})
+
+
+#: configuration -> (its widths, the sizes of its full mixed-step shape):
+#: benchmark/configs/*.json's engine settings
+_MIXED = {
+    "mistral": (_mistral_cfg, _SERVE["mistral"][1]),
+    "olmoe": (_olmoe_cfg, _SERVE["olmoe"][1]),
+    "lfm2": (_lfm2_cfg, dict(max_batch=128, pages=10752, max_seq=3072)),
+    "kanana": (_kanana_cfg, dict(max_batch=48, pages=21600, max_seq=9728)),
+    "granite": (_granite_cfg, dict(max_batch=128, pages=10752,
+                                   max_seq=3072)),
+    "brumby": (_brumby_cfg, dict(max_batch=32, pages=19457, max_seq=9728,
+                                 rows=1, chunk=1024))}
+
+
+@pytest.mark.parametrize("widths", sorted(_MIXED))
+def test_one_row_mixed_step_compiles_at_benchmark_shapes(chip, widths):
+    """The SMALLEST shape of each serve configuration's mixed step
+    (max_batch decode rows + ONE chunk row; llm/engine.py runs it when a
+    step is dealt one row): it compiles for the described v5e with the
+    kernels the full shape has (_ragged_tiling and the state kernels read
+    their sizes from the operands), returns max_batch + 1 rows, aliases
+    the pool as the full shape does, and needs no more memory to speak
+    of: the same arguments, and temporaries smaller or, in mistral's
+    case, 38 MB larger (its two-row program packs them into 2 MB, its
+    one-row program takes 40: compiled for v5e, PR 42); the room is 64
+    MiB. brumby's full shape IS one row."""
+    make_cfg, sizes = _MIXED[widths]
+    cfg = make_cfg()
+    full, kv, full_rows = _compile_step_program(chip, cfg, "mixed", **sizes)
+    one, _, rows = _compile_step_program(chip, cfg, "mixed",
+                                         **{**sizes, "rows": 1})
+    assert rows == sizes["max_batch"] + 1 <= full_rows
+    assert (rows == full_rows) == (widths == "brumby")
+    counters = 3 if cfg.n_experts else 0
+    assert jax.tree.leaves(one.out_info)[0].shape == (rows + counters,)
+    assert one.as_text().count("tpu_custom_call") \
+        == full.as_text().count("tpu_custom_call") > 0
+    m1, m2 = one.memory_analysis(), full.memory_analysis()
+    assert m1.alias_size_in_bytes == m2.alias_size_in_bytes > 0
+    assert m1.argument_size_in_bytes <= m2.argument_size_in_bytes
+    assert m1.temp_size_in_bytes <= m2.temp_size_in_bytes + 2**26

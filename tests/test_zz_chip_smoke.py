@@ -119,7 +119,9 @@ def test_one_chip_phases_rehearsed_on_cpu(fake_chips):
     _only_not_a_tpu(res["train"], NOT_A_TPU["train"])
 
     srv, trn = res["srv"], res["trn"]
-    assert srv["tokens_out"] == 6 * 4 and srv["compiled_step_programs"] <= 3
+    assert srv["tokens_out"] == 6 * 4
+    # the replica compiled every program before its first request
+    assert srv["compiled_step_programs"] == srv["step_program_budget"] == 4
     assert all(c["equal"] and c["max_gap"] == 0.0
                for c in srv["plain_check"])
     assert srv["worker_pid"] != srv["driver_pid"]
