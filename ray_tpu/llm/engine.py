@@ -50,7 +50,15 @@ admission, scheduling), rebuilt TPU-first around ONE ragged step:
     concurrent sequences per HBM byte, quantize-on-write in the step
     program, dequantize inside the attention kernel;
   - pages allocate refcounted with decode headroom; under allocator
-    pressure the engine LRU-evicts unreferenced cached pages.
+    pressure the engine LRU-evicts unreferenced cached pages;
+  - ONE DESCRIPTOR a dispatch: every integer a program takes (tokens,
+    positions, pages, the rows' spans, the page table) is a field of one
+    flat int32 buffer kept on the host a program shape (_descriptor_turns, in
+    the seam's layout: model.step_layout / decode_layout), filled in
+    place by array operations over all decode rows at once, and sent in
+    ONE host-to-device transfer (stats h2d_arrays: 1 a dispatch); the
+    program cuts it at static offsets. The stretch between booking one
+    program and launching the next is the one the chip waits for.
 
 The engine is synchronous (dispatch -> readback -> book -> next step), so
 what the host does between two dispatches is device idle. Each phase of a
@@ -211,6 +219,21 @@ class _Phase(TraceAnnotation):
         c.stats[self.wall_key] += wall
 
 
+def _descriptor_turns(layout: M.Layout):
+    """One program shape's integer inputs on the host, for ``next()``:
+    (a flat int32 buffer in the seam's ``layout``, the numpy views of its
+    fields, cut once, a list for the filler's notes on what it left in
+    the buffer), TWO of them taking turns for ever. ``device_put`` may
+    alias a host buffer instead of copying it (the CPU backend does) or
+    still read it as it returns, so the buffer a program was launched
+    with is left alone while the NEXT one's is filled; by the time its
+    turn comes again that program has been read back. What a buffer holds
+    when its turn comes is its last fill."""
+    size = M.layout_size(layout)
+    return itertools.cycle([(buf, M.cut(buf, layout), []) for buf in (
+        np.zeros(size, np.int32), np.zeros(size, np.int32))])
+
+
 class InferenceEngine:
     def __init__(self, cfg: LlamaConfig, params=None, *,
                  page_size: int = 16, total_pages: int = 256,
@@ -274,6 +297,7 @@ class InferenceEngine:
         self._fns = M.StepPrograms(
             cfg, decode_chunk=self.decode_chunk,
             max_q_len=self.prefill_chunk, decode_rows=max_batch,
+            max_pages=self.max_pages_per_seq,
             prefill_rows=self.prefill_rows,
             kv_quantized=(self.kv_dtype == "int8"), mesh=self.mesh)
         # weights and pool are created IN their final layout (sharded
@@ -346,6 +370,16 @@ class InferenceEngine:
                                    SCRATCH_PAGE, np.int32)
         self._positions = np.zeros(max_batch, np.int32)
         self._tokens = np.zeros(max_batch, np.int32)
+        # what a dispatch sends: the decode loop's descriptor and the
+        # mixed step's in each of its shapes, kept across steps
+        self._decode_desc = _descriptor_turns(self._fns.decode_layout)
+        self._step_descs = {n: _descriptor_turns(layout) for n, layout
+                            in self._fns.step_layouts.items()}
+        self._slot_ids = np.arange(max_batch, dtype=np.int32)
+        # a mixed step's padding where it is not 0
+        self._padding = {"token_page": SCRATCH_PAGE,
+                         "page_table": SCRATCH_PAGE,
+                         "token_state": max_batch}
         self.stats = {"steps": 0, "prefill_tokens": 0,
                       "decode_steps": 0, "decode_tokens": 0,
                       "decode_dispatches": 0, "cached_tokens": 0,
@@ -358,7 +392,11 @@ class InferenceEngine:
                       "chunk_rows": 0, "chunk_rows_joined": 0,
                       # mixed steps that ran a shape of fewer chunk rows
                       # than prefill_rows (of ragged_dispatches)
-                      "ragged_small_dispatches": 0}
+                      "ragged_small_dispatches": 0,
+                      # host arrays sent to the device for dispatches
+                      # (one descriptor each), booked WITH the dispatch:
+                      # a ratio of the two over any window is exact
+                      "h2d_arrays": 0}
         # counters the step programs reduce on the device and append to
         # the tokens they return (none for a dense model): one stats key
         # each, and metadata of the dispatch's engine.readback span
@@ -480,14 +518,11 @@ class InferenceEngine:
         calls it before its engine thread starts (LLMServer); a bare
         engine compiles lazily, on first use."""
         for n_rows in self._fns.row_shapes:
-            args, state_arg = self._upload_mixed(
-                *self._pack_mixed([], [], n_rows))
-            _, self.kv = self._fns.ragged_step(self.params, *args, self.kv,
-                                               **state_arg)
-        tokens, positions, page_table, seq_lens = self._upload_decode(
-            np.ones(self.max_batch, np.int32))
+            _, self.kv = self._fns.ragged_step(
+                self.params, jax.device_put(self._pack_mixed([], [], n_rows)),
+                self.kv)
         _, self.kv, _, _ = self._fns.decode_loop(
-            self.params, tokens, positions, self.kv, page_table, seq_lens)
+            self.params, jax.device_put(self._pack_decode([])), self.kv)
         if self.prefix is not None:
             scratch = jnp.int32(SCRATCH_PAGE)
             self.kv = self._fns.copy_page(self.kv, scratch, scratch)
@@ -733,65 +768,69 @@ class InferenceEngine:
         return (self.max_batch + n_rows,
                 self.max_batch + n_rows * self.prefill_chunk)
 
+    def _decode_mask(self, active: List[Tuple[int, SequenceState]],
+                     ) -> np.ndarray:
+        """[max_batch] bool: the slots that hold a decode row."""
+        on = np.zeros(self.max_batch, bool)
+        on[[i for i, _ in active]] = True
+        return on
+
     def _pack_mixed(self, active: List[Tuple[int, SequenceState]],
                     rows: List[Tuple[SequenceState, int, int]],
-                    n_rows: int):
-        """The mixed step's host arrays in its shape of ``n_rows`` chunk
-        rows (>= len(rows)): decode rows first (slot r owns ragged token
-        r), then the chunk rows packed from token max_batch on; what
-        holds no token is padding (q_len 0, the scratch page, the scratch
-        state slot). Returns (the program's eight arrays, token_state)."""
-        ps = self.page_size
-        R, Tcap = self._mixed_shape(n_rows)
-        tokens = np.zeros(Tcap, np.int32)
-        token_pos = np.zeros(Tcap, np.int32)
-        token_page = np.full(Tcap, SCRATCH_PAGE, np.int32)
-        token_slot = np.zeros(Tcap, np.int32)
-        q_start = np.zeros(R, np.int32)
-        q_len = np.zeros(R, np.int32)
-        kv_len = np.zeros(R, np.int32)
-        ptab = np.full((R, self.max_pages_per_seq), SCRATCH_PAGE,
-                       np.int32)
-        # each token's conv-state slot: its sequence's batch slot,
-        # the scratch slot (max_batch) for padding
-        token_state = np.full(Tcap, self.max_batch, np.int32)
-        q_start[:self.max_batch] = np.arange(self.max_batch,
-                                             dtype=np.int32)
-        ptab[:self.max_batch] = self._page_table
-        for i, s in active:
-            pos = int(self._positions[i])
-            tokens[i] = self._tokens[i]
-            token_pos[i] = pos
-            token_page[i] = self._page_table[i, pos // ps]
-            token_slot[i] = pos % ps
-            q_len[i] = 1
-            kv_len[i] = s.num_tokens
-            token_state[i] = i
-        t0 = self.max_batch
+                    n_rows: int) -> np.ndarray:
+        """Fill the mixed step's descriptor in its shape of ``n_rows``
+        chunk rows (>= len(rows)) and return it: decode rows first (slot r
+        owns ragged token r; all of them at once, from the slots' arrays
+        and the mask of those that decode: a decode row's position is its
+        sequence's last token's, so its kv_len is that + 1), then the
+        chunk rows packed from token max_batch on; what holds no token is
+        padding (q_len 0, the scratch page, the scratch state slot)."""
+        ps, B = self.page_size, self.max_batch
+        buf, f, filled = next(self._step_descs[n_rows])
+        on = self._decode_mask(active)
+        pos = np.where(on, self._positions, 0)
+        f["tokens"][:B] = np.where(on, self._tokens, 0)
+        f["token_pos"][:B] = pos
+        f["token_page"][:B] = np.where(
+            on, self._page_table[self._slot_ids, pos // ps], SCRATCH_PAGE)
+        f["token_slot"][:B] = pos % ps
+        f["q_start"][:B] = self._slot_ids
+        f["q_len"][:B] = on
+        f["kv_len"][:B] = np.where(on, pos + 1, 0)
+        f["page_table"][:B] = self._page_table
+        # each token's state slot: its sequence's batch slot, the scratch
+        # slot (max_batch) for padding
+        state = f.get("token_state")
+        if state is not None:
+            state[:B] = np.where(on, self._slot_ids, B)
+        # past the chunk rows' tokens and rows, padding: only what this
+        # buffer's LAST fill wrote there is not (a fill of a whole field
+        # lets the interpreter go, and in the stretch the chip waits for)
+        r_end, t_end = B + len(rows), B + sum(C for _, _, C in rows)
+        r_old, t_old = filled or self._mixed_shape(n_rows)
+        filled[:] = r_end, t_end
+        for name, field in f.items():
+            lo, hi = (r_end, r_old) if name in M.ROW_FIELDS \
+                else (t_end, t_old)
+            field[lo:hi] = self._padding.get(name, 0)
+        t0 = B
         for j, (seq, start, C) in enumerate(rows):
-            r = self.max_batch + j
+            r, t1 = B + j, t0 + C
             pos = np.arange(start, start + C, dtype=np.int32)
-            tokens[t0:t0 + C] = seq.prompt[start:start + C]
-            token_pos[t0:t0 + C] = pos
             pages = np.asarray(seq.pages, np.int32)
-            token_page[t0:t0 + C] = pages[pos // ps]
-            token_slot[t0:t0 + C] = pos % ps
-            ptab[r, :len(seq.pages)] = pages
-            q_start[r] = t0
-            q_len[r] = C
-            kv_len[r] = start + C
-            token_state[t0:t0 + C] = seq.slot
-            t0 += C
-        return (tokens, token_pos, token_page, token_slot, ptab, q_start,
-                q_len, kv_len), token_state
-
-    def _upload_mixed(self, arrays, token_state):
-        """_pack_mixed's arrays on the device: (the program's positional
-        arguments, its token_state keyword where the block has state)."""
-        args = [jnp.asarray(a) for a in arrays]
-        state_arg = {"token_state": jnp.asarray(token_state)} \
-            if self._has_state else {}
-        return args, state_arg
+            f["tokens"][t0:t1] = seq.prompt[start:start + C]
+            f["token_pos"][t0:t1] = pos
+            f["token_page"][t0:t1] = pages[pos // ps]
+            f["token_slot"][t0:t1] = pos % ps
+            f["page_table"][r, :len(pages)] = pages
+            f["page_table"][r, len(pages):] = SCRATCH_PAGE
+            f["q_start"][r] = t0
+            f["q_len"][r] = C
+            f["kv_len"][r] = start + C
+            if state is not None:
+                state[t0:t1] = seq.slot
+            t0 = t1
+        return buf
 
     def _ragged_dispatch(self, finished: Dict[str, List[int]],
                          after_dispatch: Optional[Callable[[], None]],
@@ -818,12 +857,11 @@ class InferenceEngine:
         with self.phase("engine.pack"):
             # decode rows advance one token: they need a page for it
             active = self._decode_rows(1, finished)
-            packed = self._pack_mixed(active, rows, n_rows)
+            desc = self._pack_mixed(active, rows, n_rows)
         with self.phase("engine.h2d"):
-            args, state_arg = self._upload_mixed(*packed)
+            desc = jax.device_put(desc)         # the ONE transfer
         with self.phase("engine.dispatch"):
-            nxt, self.kv = self._fns.ragged_step(self.params, *args,
-                                                 self.kv, **state_arg)
+            nxt, self.kv = self._fns.ragged_step(self.params, desc, self.kv)
         if after_dispatch is not None:
             after_dispatch()                       # the device is running
         with self.phase("engine.readback") as span:
@@ -833,6 +871,7 @@ class InferenceEngine:
             now = time.monotonic()
             chunk_tokens = sum(C for _, _, C in rows)
             self.stats["ragged_dispatches"] += 1
+            self.stats["h2d_arrays"] += 1           # its descriptor
             disp_idx = self.stats["ragged_dispatches"]
             self.stats["ragged_real_tokens"] += len(active) + chunk_tokens
             self.stats["ragged_slot_tokens"] += Tcap
@@ -1064,16 +1103,12 @@ class InferenceEngine:
             if not active:
                 return
             K = self.decode_chunk
-            seq_lens = np.ones(self.max_batch, np.int32)
-            for i, s in active:
-                seq_lens[i] = s.num_tokens
+            desc = self._pack_decode(active)
         with self.phase("engine.h2d"):
-            tokens, positions, page_table, seq_lens = \
-                self._upload_decode(seq_lens)
+            desc = jax.device_put(desc)         # the ONE transfer
         with self.phase("engine.dispatch"):
             toks_out, self.kv, _, _ = self._fns.decode_loop(
-                self.params, tokens, positions, self.kv, page_table,
-                seq_lens)
+                self.params, desc, self.kv)
         if after_dispatch is not None:
             after_dispatch()                       # the device is running
         with self.phase("engine.readback") as span:
@@ -1085,6 +1120,7 @@ class InferenceEngine:
             self.stats["decode_steps"] += K
             self.stats["decode_tokens"] += K * len(active)
             self.stats["decode_dispatches"] += 1
+            self.stats["h2d_arrays"] += 1           # its descriptor
             self._step_meta = {
                 "kind": "decode",
                 "dispatch": self.stats["decode_dispatches"],
@@ -1099,11 +1135,18 @@ class InferenceEngine:
                     self._tokens[slot] = toks[-1]
                     self._positions[slot] = seq.num_tokens - 1
 
-    def _upload_decode(self, seq_lens: np.ndarray):
-        """The decode loop's inputs on the device: every slot's token,
-        position and pages as the engine holds them, and ``seq_lens``."""
-        return tuple(jnp.asarray(a) for a in (
-            self._tokens, self._positions, self._page_table, seq_lens))
+    def _pack_decode(self, active: List[Tuple[int, SequenceState]],
+                     ) -> np.ndarray:
+        """Fill the decode loop's descriptor and return it: every slot's
+        token, position and pages as the engine holds them; a decode row's
+        length is its position + 1, a free slot's 1."""
+        buf, f, _ = next(self._decode_desc)
+        f["tokens"][:] = self._tokens
+        f["positions"][:] = self._positions
+        f["seq_lens"][:] = np.where(self._decode_mask(active),
+                                    self._positions + 1, 1)
+        f["page_table"][:] = self._page_table
+        return buf
 
     def _note_counters(self, out: np.ndarray, n_tokens: int, span):
         """Split a step program's flat output into its tokens and the

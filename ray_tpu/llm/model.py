@@ -74,6 +74,14 @@ another layout or stacked back, and no step copies the pool (threaded
 as scan xs/ys it moved ~4 times a step: PERF.md, PR 27). Only the int8
 pool's scale leaves, which XLA reads, are scattered by XLA.
 
+ONE DESCRIPTOR a dispatch: the two step programs take (params, desc, kv),
+``desc`` ONE flat int32 array that holds every integer input of the
+dispatch (``step_layout`` / ``decode_layout``: the fields, each with its
+shape, one after another), and ``cut`` it at static offsets into the arrays
+their bodies take (``_on_descriptor``). The engine fills the same layout's
+numpy views on the host and makes one host-to-device transfer where it
+made one a field (llm/engine.py: _descriptor_turns).
+
 Latent attention (``kv_lora_rank``; Kanana-2 is the first such block):
 the attention operator has no wk / wv (``_latent_attention``). A token's
 cache in a layer is ONE row of kv_lora_rank + qk_rope_head_dim values
@@ -99,6 +107,7 @@ from __future__ import annotations
 
 import collections
 import functools
+import math
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -109,7 +118,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ray_tpu.llm import tp as TP
 from ray_tpu.llm.cache import (RET_LEAF, RET_NORM_LEAF, SCRATCH_PAGE,
                                SSM_CONV_LEAF, SSM_LEAF, STATE_LEAF,
-                               STATE_LEAVES, make_kv_cache)
+                               STATE_LEAVES, make_kv_cache,
+                               prefix_cache_supported)
 from ray_tpu.models.llama import (ATTENTION, CONV, MAMBA, RETENTION,
                                   LlamaConfig, Params, _rmsnorm, _rope,
                                   _rope_pairs, init_params)
@@ -870,17 +880,85 @@ def _ragged_decode_loop(params: Params, tokens: jax.Array,
     return toks_out, kv, positions, seq_lens
 
 
+#: a descriptor's layout: its fields in order, each (name, shape); the
+#: names are the bodies' argument names
+Layout = Tuple[Tuple[str, Tuple[int, ...]], ...]
+#: the mixed step's fields with an entry a ROW; the others have one a token
+ROW_FIELDS = ("q_start", "q_len", "kv_len", "page_table")
+
+
+def step_layout(decode_rows: int, chunk_rows: int, max_q_len: int,
+                max_pages: int, has_state: bool) -> Layout:
+    """The mixed step's descriptor in its shape of ``chunk_rows`` chunk
+    rows: what ``_ragged_step_body`` takes per token (``token_state``
+    only where the block has state per batch slot), per row, and the rows'
+    page table."""
+    R = decode_rows + chunk_rows
+    T = decode_rows + chunk_rows * max_q_len
+    per_token = ("tokens", "token_pos", "token_page", "token_slot") \
+        + (("token_state",) if has_state else ())
+    return (*((name, (T,)) for name in per_token),
+            *((name, (R,)) for name in ROW_FIELDS[:-1]),
+            (ROW_FIELDS[-1], (R, max_pages)))
+
+
+def decode_layout(decode_rows: int, max_pages: int) -> Layout:
+    """The decode loop's descriptor: what ``_ragged_decode_loop`` takes a
+    batch slot, and the slots' page table."""
+    return (*((name, (decode_rows,))
+              for name in ("tokens", "positions", "seq_lens")),
+            ("page_table", (decode_rows, max_pages)))
+
+
+def layout_size(layout: Layout) -> int:
+    return sum(math.prod(shape) for _, shape in layout)
+
+
+def layout_of(desc, layouts: Tuple[Layout, ...]) -> Layout:
+    """The one of ``layouts`` (of different lengths) that ``desc`` is in:
+    the one of its length."""
+    layout, = (lay for lay in layouts if layout_size(lay) == desc.shape[0])
+    return layout
+
+
+def cut(desc, layout: Layout) -> dict:
+    """{field: array} of ``layout`` out of the flat ``desc``, at static
+    offsets: views of a numpy array (the host's side), static slices of a
+    traced one (the program's)."""
+    fields, at = {}, 0
+    for name, shape in layout:
+        n = math.prod(shape)
+        fields[name] = desc[at:at + n].reshape(shape)
+        at += n
+    return fields
+
+
+def _on_descriptor(body):
+    """``body`` as a program of (params, desc, kv): the descriptor cut
+    into the arrays the body takes, and nothing else. ``layouts`` are
+    the layouts the caller may send, of different lengths: the trace
+    takes the one of ``desc``'s length (a shape, so static). It goes by
+    the body's NAME, so the compiled module is jit_<body> as it was when
+    the arrays came one by one (benchmark/readers match the two modules
+    by that name)."""
+    def program(params, desc, kv, *, layouts, **statics):
+        return body(params, kv=kv, **cut(desc, layout_of(desc, layouts)),
+                    **statics)
+    program.__name__ = program.__qualname__ = body.__name__
+    return program
+
+
 #: module-level jits (shared compile cache across engine instances with
 #: equal shapes/statics — many short-lived engines, e.g. a test suite,
 #: must not each pay the XLA compile). Over a mesh, StepPrograms wraps the
 #: raw bodies in shard_map instead.
 ragged_step = functools.partial(jax.jit, static_argnames=(
-    "cfg", "tp_axis", "paged_impl", "max_q_len", "decode_rows"),
-    donate_argnames=("kv",))(_ragged_step_body)
+    "layouts", "cfg", "tp_axis", "paged_impl", "max_q_len", "decode_rows"),
+    donate_argnames=("kv",))(_on_descriptor(_ragged_step_body))
 
 ragged_decode_loop = functools.partial(jax.jit, static_argnames=(
-    "num_steps", "cfg", "tp_axis", "paged_impl"),
-    donate_argnames=("kv",))(_ragged_decode_loop)
+    "layouts", "num_steps", "cfg", "tp_axis", "paged_impl"),
+    donate_argnames=("kv",))(_on_descriptor(_ragged_decode_loop))
 
 
 def _copy_page_body(kv: KVCache, src, dst) -> KVCache:
@@ -935,11 +1013,16 @@ class StepPrograms:
     shape); each compiles once, ``program_budget`` programs in all, and
     where a compile tracker runs, the three callables record their
     compiles with it (llm.ragged_step, llm.decode_loop, llm.copy_page).
+
+    The two step programs are called ``(params, desc, kv)``: ``desc`` one
+    flat int32 array in ``decode_layout`` or ``step_layouts[chunk rows]``
+    (a mixed step's shape IS its descriptor's length: the trace finds its
+    layout by it). Over a mesh it is one replicated operand.
     """
 
     def __init__(self, cfg: LlamaConfig, *, decode_chunk: int,
-                 max_q_len: int, decode_rows: int, kv_quantized: bool,
-                 prefill_rows: int = 1, mesh=None):
+                 max_q_len: int, decode_rows: int, max_pages: int,
+                 kv_quantized: bool, prefill_rows: int = 1, mesh=None):
         self.cfg = cfg
         self.mesh = mesh
         #: chunk rows of each shape the mixed step may be called in, and
@@ -953,18 +1036,27 @@ class StepPrograms:
         #: worker takes the reference
         impl = self.paged_impl = "kernel" if kernels_supported(
             None if mesh is None else mesh.devices.flat[0]) else "reference"
+        #: the descriptors: the decode loop's, and the mixed step's by its
+        #: chunk rows; token_state rides where a layer keeps state a slot
+        self.decode_layout = decode_layout(decode_rows, max_pages)
+        self.step_layouts = {
+            n: step_layout(decode_rows, n, max_q_len, max_pages,
+                           not prefix_cache_supported(cfg))
+            for n in self.row_shapes}
+        step_statics = dict(
+            layouts=tuple(self.step_layouts.values()), cfg=cfg,
+            paged_impl=impl, max_q_len=max_q_len, decode_rows=decode_rows)
+        loop_statics = dict(
+            layouts=(self.decode_layout,), num_steps=decode_chunk, cfg=cfg,
+            paged_impl=impl)
         #: name -> (the jit itself, the static arguments of every call)
         if mesh is None:
-            self.jits = {
-                "ragged_step": (ragged_step, dict(
-                    cfg=cfg, paged_impl=impl, max_q_len=max_q_len,
-                    decode_rows=decode_rows)),
-                "decode_loop": (ragged_decode_loop, dict(
-                    num_steps=decode_chunk, cfg=cfg, paged_impl=impl)),
-                "copy_page": (copy_page, {})}
+            self.jits = {"ragged_step": (ragged_step, step_statics),
+                         "decode_loop": (ragged_decode_loop, loop_statics),
+                         "copy_page": (copy_page, {})}
         else:
-            self.jits = self._shard_mapped(decode_chunk, max_q_len,
-                                           decode_rows, kv_quantized)
+            self.jits = self._shard_mapped(step_statics, loop_statics,
+                                           kv_quantized)
         self.tracker = compile_tracker.ensure_started()
         self.ragged_step = self._callable("ragged_step")
         self.decode_loop = self._callable("decode_loop")
@@ -981,11 +1073,10 @@ class StepPrograms:
         return self.tracker.wrap(call, name="llm." + name,
                                  probe=self.compiled_step_programs)
 
-    def _shard_mapped(self, decode_chunk, max_q_len, decode_rows,
-                      kv_quantized):
+    def _shard_mapped(self, step_statics, loop_statics, kv_quantized):
         """The three programs over ``self.mesh`` (and, kept for the
         birth of weights and pool, the shardings of both)."""
-        cfg, impl, mesh = self.cfg, self.paged_impl, self.mesh
+        cfg, mesh = self.cfg, self.mesh
         TP.validate_tp(cfg, mesh.shape[TP.TP_AXIS])
         pspecs, kvs = TP.tp_param_specs(cfg), TP.kv_specs(kv_quantized)
         rep = P()
@@ -993,21 +1084,14 @@ class StepPrograms:
             lambda spec: NamedSharding(mesh, spec), (pspecs, kvs),
             is_leaf=lambda x: isinstance(x, P))
 
-        def step(params, tokens, token_pos, token_page, token_slot,
-                 page_table, q_start, q_len, kv_len, kv):
-            # per-shard: local kv-heads write their ragged K/V slice in
-            # place into, and attend over, the local head slice of the
-            # stacked page pool (the scans' carry); the two psums per
-            # layer inside _ragged_step_body close the TP seam
-            return _ragged_step_body(
-                params, tokens, token_pos, token_page, token_slot,
-                page_table, q_start, q_len, kv_len, kv, cfg, TP.TP_AXIS,
-                impl, max_q_len, decode_rows)
-
-        def loop(params, tokens, positions, kv, page_table, seq_lens):
-            return _ragged_decode_loop(
-                params, tokens, positions, kv, page_table, seq_lens,
-                decode_chunk, cfg, TP.TP_AXIS, impl)
+        # per-shard: local kv-heads write their ragged K/V slice in place
+        # into, and attend over, the local head slice of the stacked page
+        # pool (the scans' carry); the two psums per layer inside
+        # _ragged_step_body close the TP seam
+        step = functools.partial(_on_descriptor(_ragged_step_body),
+                                 tp_axis=TP.TP_AXIS, **step_statics)
+        loop = functools.partial(_on_descriptor(_ragged_decode_loop),
+                                 tp_axis=TP.TP_AXIS, **loop_statics)
 
         def sharded(fn, in_specs, out_specs, donate):
             return jax.jit(shard_map_compat(
@@ -1015,13 +1099,9 @@ class StepPrograms:
                 donate_argnums=(donate,)), {}
 
         return {
-            "ragged_step": sharded(
-                step, (pspecs, P(None), P(None), P(None), P(None),
-                       P(None, None), P(None), P(None), P(None), kvs),
-                (rep, kvs), 9),
-            "decode_loop": sharded(
-                loop, (pspecs, P(None), P(None), kvs, P(None, None),
-                       P(None)), (rep, kvs, rep, rep), 3),
+            "ragged_step": sharded(step, (pspecs, rep, kvs), (rep, kvs), 2),
+            "decode_loop": sharded(loop, (pspecs, rep, kvs),
+                                   (rep, kvs, rep, rep), 2),
             "copy_page": sharded(_copy_page_body, (kvs, rep, rep), kvs, 0)}
 
     def compiled_step_programs(self) -> int:

@@ -14,14 +14,30 @@ with two chunk rows one-row and two-row steps in turn and hold it, token
 for token, to the same engine pinned to its full shape (the one static
 shape every mixed step ran in before the set existed).
 
+And the DESCRIPTOR a dispatch sends (llm/engine.py: _descriptor_turns; the
+layouts are llm/model.py's): one flat int32 buffer filled in place, cut by
+the program. reference_mixed / reference_decode are the packing the engine
+did before (PR 42's _pack_mixed and _upload_decode: fresh arrays a step,
+a Python loop over the decode rows), kept here as the plain reference;
+check_descriptor holds every field of every descriptor of a scripted mix
+to them byte for byte, and the served tokens to an engine that dispatches
+the reference's arrays. fields_of cuts a dispatched descriptor for the
+block files that read what a step was sent.
+
 Not a test file: tests/test_llm.py (per-head pool) and
 tests/test_llm_kanana.py (latent pool) parametrise over CASES;
 tests/test_llm_lfm2.py, tests/test_llm_granite.py and
 tests/test_llm_brumby.py (recurrent state) hold their engines to one row a
-sequence; all of them, and tests/test_llm_moe.py, over SHAPE_CASES.
+sequence; all of them, and tests/test_llm_moe.py, over SHAPE_CASES and
+through check_descriptor.
 """
 
+import collections
+
 import numpy as np
+
+from ray_tpu.llm import model as M
+from ray_tpu.llm.cache import SCRATCH_PAGE
 
 CHUNK = 16
 CASES = ("alone-2", "alone-3", "alone-5", "prefix-hit", "copy-on-write",
@@ -72,9 +88,10 @@ def serve(eng, prompts, n_new=6, at=None):
     at = list(at or [0] * len(prompts))
     rids, dispatched, run = {}, [], eng._fns.ragged_step
 
-    def recording(params, tokens, pos, page, slot, page_table, *rest, **kw):
-        dispatched.append((page_table.shape[0], tokens.shape[0]))
-        return run(params, tokens, pos, page, slot, page_table, *rest, **kw)
+    def recording(params, desc, kv):
+        f = fields_of(eng, desc)
+        dispatched.append((f["page_table"].shape[0], f["tokens"].shape[0]))
+        return run(params, desc, kv)
     eng._fns.ragged_step = recording
     done, seen, slots = {}, 0, []
     for step in range(400):
@@ -273,3 +290,154 @@ def check_preempted(make):
     refilled = [d for d in deals[1:] if len(d) == 2
                 and d[0][0] == d[1][0] and d[0][1] == 0]
     assert refilled, deals
+
+
+# ------------------------------------------------------- the descriptor
+
+
+def fields_of(eng, desc):
+    """{field: numpy array} of a descriptor ``eng`` dispatched (a device
+    array or the host's buffer), by the layout of its length."""
+    desc = np.asarray(desc)
+    return M.cut(desc, M.layout_of(desc, (
+        eng._fns.decode_layout, *eng._fns.step_layouts.values())))
+
+
+def as_descriptor(**fields):
+    """(desc, layout) of arrays handed over by name, in the order given:
+    a step program's inputs for a test that makes its own."""
+    fields = {n: np.asarray(a, np.int32) for n, a in fields.items()}
+    return (np.concatenate([a.reshape(-1) for a in fields.values()]),
+            tuple((n, a.shape) for n, a in fields.items()))
+
+
+def reference_mixed(eng, active, rows, n_rows):
+    """The mixed step's arrays as the engine packed them before it kept a
+    descriptor: fresh arrays, a decode row at a time."""
+    ps = eng.page_size
+    R, Tcap = eng._mixed_shape(n_rows)
+    f = {name: np.zeros(Tcap, np.int32)
+         for name in ("tokens", "token_pos", "token_slot")}
+    f["token_page"] = np.full(Tcap, SCRATCH_PAGE, np.int32)
+    f.update({name: np.zeros(R, np.int32)
+              for name in ("q_start", "q_len", "kv_len")})
+    f["page_table"] = np.full((R, eng.max_pages_per_seq), SCRATCH_PAGE,
+                              np.int32)
+    token_state = np.full(Tcap, eng.max_batch, np.int32)
+    f["q_start"][:eng.max_batch] = np.arange(eng.max_batch, dtype=np.int32)
+    f["page_table"][:eng.max_batch] = eng._page_table
+    for i, s in active:
+        pos = int(eng._positions[i])
+        f["tokens"][i] = eng._tokens[i]
+        f["token_pos"][i] = pos
+        f["token_page"][i] = eng._page_table[i, pos // ps]
+        f["token_slot"][i] = pos % ps
+        f["q_len"][i] = 1
+        f["kv_len"][i] = s.num_tokens
+        token_state[i] = i
+    t0 = eng.max_batch
+    for j, (seq, start, C) in enumerate(rows):
+        r = eng.max_batch + j
+        pos = np.arange(start, start + C, dtype=np.int32)
+        f["tokens"][t0:t0 + C] = seq.prompt[start:start + C]
+        f["token_pos"][t0:t0 + C] = pos
+        pages = np.asarray(seq.pages, np.int32)
+        f["token_page"][t0:t0 + C] = pages[pos // ps]
+        f["token_slot"][t0:t0 + C] = pos % ps
+        f["page_table"][r, :len(seq.pages)] = pages
+        f["q_start"][r] = t0
+        f["q_len"][r] = C
+        f["kv_len"][r] = start + C
+        token_state[t0:t0 + C] = seq.slot
+        t0 += C
+    if eng._has_state:
+        f["token_state"] = token_state
+    return f
+
+
+def reference_decode(eng, active):
+    """The decode loop's arrays as the engine sent them before: the
+    slots' arrays as held, and each decode row's length."""
+    seq_lens = np.ones(eng.max_batch, np.int32)
+    for i, s in active:
+        seq_lens[i] = s.num_tokens
+    return {"tokens": eng._tokens.copy(), "positions": eng._positions.copy(),
+            "seq_lens": seq_lens, "page_table": eng._page_table.copy()}
+
+
+def hold_to_reference(eng, dispatch_reference=False):
+    """From now on every descriptor ``eng`` packs is held, field by field
+    and byte for byte, to the reference packing of the same step; with
+    ``dispatch_reference`` the step is SENT the reference's arrays (laid
+    out one after another), not the engine's buffer. Returns a Counter of
+    the steps seen: "decode", "mixed-<chunk rows dealt>"."""
+    seen = collections.Counter()
+    pack_mixed, pack_decode = eng._pack_mixed, eng._pack_decode
+
+    def held(buf, layout, want):
+        got = M.cut(buf, layout)
+        assert buf.dtype == np.int32 and list(got) == [n for n, _ in layout]
+        assert set(want) == set(got)
+        for name, shape in layout:
+            assert want[name].dtype == np.int32 \
+                and want[name].shape == shape, name
+            assert got[name].tobytes() == want[name].tobytes(), name
+        if dispatch_reference:
+            return as_descriptor(**{n: want[n] for n, _ in layout})[0]
+        return buf
+
+    def mixed(active, rows, n_rows):
+        want = reference_mixed(eng, active, rows, n_rows)
+        seen[f"mixed-{len(rows)}"] += 1
+        return held(pack_mixed(active, rows, n_rows),
+                    eng._fns.step_layouts[n_rows], want)
+
+    def decode(active):
+        want = reference_decode(eng, active)
+        seen["decode"] += 1
+        return held(pack_decode(active), eng._fns.decode_layout, want)
+
+    eng._pack_mixed, eng._pack_decode = mixed, decode
+    return seen
+
+
+def check_descriptor(make, vocab=256):
+    """``make(**settings)`` builds the block's engine over the same
+    weights. A scripted mix (a prompt of three chunks alone: two joined
+    rows, or one a step where the block keeps state; two arrivals beside
+    its tail: two sequences' rows and decode rows; a lone short prompt:
+    one row; decode loops between and after; the first prompt's first
+    four pages again: a copy-on-write hit where the block has a prefix
+    cache; two short prompts together, in buffers that longer rows left
+    their tokens in; then a pool too small for two sequences: a
+    preemption and its folded re-prefill) served twice: by the engine as
+    it is, every field of every descriptor it fills held to the reference
+    packing, and by an engine that dispatches the reference's arrays. The
+    same tokens."""
+    rng = np.random.default_rng(43)
+    new = lambda n: rng.integers(0, vocab, n).tolist()       # noqa: E731
+    first = new(40)
+    roomy = ([first, new(10), new(12), new(7), first[:32], new(20), new(5)],
+             [0, 1, 1, 6, 14, 20, 20], 6, {})
+    tight = ([list(range(1, 9)), list(range(3, 11))], None, 16,
+             dict(page_size=4, total_pages=10, max_seq_len=32,
+                  prefix_cache=False))
+    for prompts, at, n_new, settings in (roomy, tight):
+        eng, parent = make(**settings), make(**settings)
+        seen = hold_to_reference(eng)
+        hold_to_reference(parent, dispatch_reference=True)
+        got, deals = serve(eng, prompts, n_new=n_new, at=at)
+        want, _ = serve(parent, prompts, n_new=n_new, at=at)
+        assert got == want
+        assert seen["decode"] and seen["mixed-1"], seen
+        assert sum(seen.values()) == eng.stats["h2d_arrays"] \
+            == eng.stats["decode_dispatches"] \
+            + eng.stats["ragged_dispatches"]
+        if settings:
+            assert eng.stats["preemptions"] >= 1
+            continue
+        joined = any(len({rid for rid, _, _ in d}) < len(d) for d in deals)
+        assert joined == (not eng._has_state), deals
+        assert seen["mixed-2"], seen
+        assert any(len({rid for rid, _, _ in d}) == 2 for d in deals)
+        assert eng.stats["cow_copies"] == (eng.prefix is not None)

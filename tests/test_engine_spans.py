@@ -89,7 +89,16 @@ def test_children_nest_inside_steps_and_do_not_overlap(profiled):
 
 
 def test_a_dispatching_step_has_every_phase_once_in_order(profiled):
-    events, _, _ = profiled
+    """... and sends ONE host array: engine.h2d opens once a dispatch and
+    h2d_arrays counts the descriptors it sent, with the two clocks the
+    benchmark's engine_h2d_ms and engine_host_ms read still running."""
+    events, before, after = profiled
+    assert after["h2d_arrays"] - before["h2d_arrays"] \
+        == sum(after[k] - before[k]
+               for k in ("ragged_dispatches", "decode_dispatches")) \
+        == sum(e[0] == "engine.h2d" for e in events) > 0
+    assert all(after[k] > before[k]
+               for k in ("wall_ns_pack", "wall_ns_h2d"))
     steps, inside = _steps_with_children(events)
     order = ["engine.admit", "engine.pack", "engine.h2d", "engine.dispatch",
              "engine.readback", "engine.book"]

@@ -17,9 +17,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _chunk_rows import (CASES, SHAPE_CASES, check, check_preempted,
-                         check_shapes, pin_full_shape, serve, shape_of)
+from _chunk_rows import (CASES, SHAPE_CASES, check, check_descriptor,
+                         check_preempted, check_shapes, fields_of,
+                         pin_full_shape, serve, shape_of)
 from ray_tpu.llm import InferenceEngine
+from ray_tpu.llm import model as M
 from ray_tpu.llm.cache import PageAllocator
 from ray_tpu.models.llama import LlamaConfig, forward, init_params
 
@@ -203,21 +205,14 @@ def test_step_program_names_are_the_benchmarks(params, tp):
     assert eng._fns.paged_impl == eng.device_report()["paged_impl"] \
         == "reference"
 
-    def i32(*shape):
-        return jnp.zeros(shape, jnp.int32)
-
-    T, R, B = eng.ragged_tokens, eng.ragged_rows, eng.max_batch
-    mp = eng.max_pages_per_seq
-    dispatched = {
-        "ragged_step": (eng.params, i32(T), i32(T), i32(T), i32(T),
-                        i32(R, mp), i32(R), i32(R), i32(R), eng.kv),
-        "decode_loop": (eng.params, i32(B), i32(B), eng.kv, i32(B, mp),
-                        i32(B))}
+    layouts = {"ragged_step": eng._fns.step_layouts[eng.prefill_rows],
+               "decode_loop": eng._fns.decode_layout}
     for program, metric_file in (("ragged_step", "mixed_step_ms.json"),
                                  ("decode_loop",
                                   "decode_step_ms.reason.json")):
         jit, statics = eng._fns.jits[program]
-        text = jit.lower(*dispatched[program], **statics).as_text()
+        desc = jnp.zeros(M.layout_size(layouts[program]), jnp.int32)
+        text = jit.lower(eng.params, desc, eng.kv, **statics).as_text()
         module = re.search(r"module @(\S+)", text).group(1)
         assert any(rx.search(module)
                    for rx in _metric_patterns(metric_file)), \
@@ -478,6 +473,53 @@ def test_the_deals_two_metrics_read_the_counters(rows_1_and_2, name, cells,
            for k in ("stats_open", "stats_close")}
     got = reader.read(dict(old, config={}), spec["args"])
     assert got == (None if parent is None else pytest.approx(parent))
+
+
+@pytest.mark.parametrize("name,want,parent", [
+    ("engine_h2d_ms", "wall_ns_h2d", "wall_ns_h2d"),
+    ("h2d_arrays_a_dispatch", 1.0, None)])
+def test_the_transfers_two_metrics_read_the_counters(rows_1_and_2, name,
+                                                     want, parent):
+    """The benchmark's two data files over a prompt served alone (mixed
+    steps, then decode loops): exactly one host array a dispatch, and the
+    engine.h2d clock a dispatch in ms; a program without the array counter
+    (the parent commit, in the driver's traced run of it) reads nothing
+    for the first and does not raise, and reads the clock, which is
+    older."""
+    bench, entry, spec, reader = _bench_entry(name)
+    assert entry["workloads"] == _CLOSED_LOOP
+    assert (entry["moves"], entry["better"], entry["source"]) \
+        == ("out_tok_per_s", "lower", "program_counter")
+    assert entry["layer"] == next(
+        m for m in bench if m["name"] == "engine_host_ms")["layer"]
+    eng = rows_1_and_2[1]
+    a = dict(eng.stats)
+    eng.generate([(13 * i + len(name)) % CFG.vocab_size for i in range(45)],
+                 9)
+    b = dict(eng.stats)
+    n = sum(b[k] - a[k] for k in ("decode_dispatches", "ragged_dispatches"))
+    assert b["decode_dispatches"] > a["decode_dispatches"]
+
+    def expect(w):
+        return w if not isinstance(w, str) else (b[w] - a[w]) / n * 1e-6
+    data = {"stats_open": a, "stats_close": b, "config": {}}
+    assert reader.read(data, spec["args"]) == pytest.approx(expect(want))
+    old = {k: {s: v for s, v in data[k].items() if s != "h2d_arrays"}
+           for k in ("stats_open", "stats_close")}
+    got = reader.read(dict(old, config={}), spec["args"])
+    assert got == (None if parent is None else pytest.approx(expect(parent)))
+
+
+@pytest.mark.parametrize("tp", [1, 2], ids=["tp1", "tp2"])
+def test_a_descriptor_holds_the_arrays_the_engine_packed_before(params, tp):
+    """Per-head K and V pool, one device and the shard_map programs: every
+    field of every descriptor is the old packing's array byte for byte,
+    and the tokens are those of an engine sent the old arrays."""
+    settings = dict(page_size=8, total_pages=128, max_batch=4,
+                    max_seq_len=128, prefill_chunk=16, prefill_rows=2,
+                    decode_chunk=4, tp=tp)
+    check_descriptor(lambda **kw: InferenceEngine(
+        CFG, params, **{**settings, **kw}), vocab=CFG.vocab_size)
 
 
 def test_a_preempted_sequences_re_prefill_takes_both_rows(params):
@@ -838,10 +880,70 @@ def test_a_replica_compiles_nothing_once_it_is_ready(params):
     stats = eng.stats
     assert stats["cow_copies"] >= 1 and stats["decode_dispatches"] >= 1
     assert 0 < stats["ragged_small_dispatches"] < stats["ragged_dispatches"]
+    # one descriptor went up a dispatch, and none for the loading
+    assert stats["h2d_arrays"] \
+        == stats["decode_dispatches"] + stats["ragged_dispatches"]
     assert dict(compile_tracker.get_global().stats()["counts"]) == counts
     assert eng.compiled_step_programs() - before == 4
     assert one_row == _oracle_greedy(
         params, [(5 * i + 3) % CFG.vocab_size for i in range(9)], 7)
+
+
+def test_one_transfer_a_dispatch_and_no_buffer_refilled_under_it(params):
+    """Decode-only, one-row, two-row and copy-on-write steps: each
+    dispatch sends ONE host array (h2d_arrays + 1, engine.pack and
+    engine.h2d opened once and clocked), a step that launches nothing
+    sends none, and the descriptor a step was launched with still reads
+    what was sent after the NEXT step's is packed and sent (device_put
+    may alias the host's buffer, as the CPU backend does: the engine
+    fills the other one)."""
+    import collections
+    eng = InferenceEngine(CFG, params, page_size=8, total_pages=128,
+                          max_batch=4, max_seq_len=128, prefill_chunk=16,
+                          prefill_rows=2, decode_chunk=4)
+    opened, phase = collections.Counter(), eng.phase
+    sent = []   # (the device array a program was launched with, a copy)
+
+    def counting(name):
+        opened[name] += 1
+        return phase(name)
+
+    def holding(run):
+        def launch(params, desc, kv):
+            sent.append((desc, np.array(desc)))
+            return run(params, desc, kv)
+        return launch
+    eng.phase = counting
+    eng._fns.ragged_step = holding(eng._fns.ragged_step)
+    eng._fns.decode_loop = holding(eng._fns.decode_loop)
+    prompt = [(7 * i + 1) % CFG.vocab_size for i in range(32)]
+    arrivals = {0: prompt, 9: prompt[:9], 10: prompt[3:14],
+                20: prompt}         # alone: two rows; two beside decode
+    kinds = collections.Counter()   # rows; every page cached: a copy
+    for step in range(60):
+        if step in arrivals:
+            eng.add_request(arrivals[step], 10)
+        before, n_sent = dict(eng.stats), len(sent)
+        eng.step()
+        d = {k: eng.stats[k] - before[k] for k in (
+            "h2d_arrays", "decode_dispatches", "ragged_dispatches",
+            "wall_ns_pack", "wall_ns_h2d")}
+        launched = d["decode_dispatches"] + d["ragged_dispatches"]
+        assert launched in (0, 1)
+        assert d["h2d_arrays"] == len(sent) - n_sent == launched
+        assert opened["engine.h2d"] == opened["engine.dispatch"] \
+            == len(sent)
+        if launched:
+            assert d["wall_ns_pack"] > 0 and d["wall_ns_h2d"] > 0
+            kinds[eng._step_meta["kind"],
+                  fields_of(eng, sent[-1][0])["tokens"].size] += 1
+            for dev, was in sent[-2:]:
+                assert np.asarray(dev).tobytes() == was.tobytes()
+        if step > 20 and not eng.has_work():
+            break
+    assert not eng.has_work() and eng.stats["cow_copies"] == 1
+    assert opened["engine.pack"] >= len(sent)   # a dry engine packs nothing
+    assert set(kinds) == {("decode", 4), ("mixed", 4 + 16), ("mixed", 4 + 32)}
 
 
 # ------------------------------------------------------------ int8 KV

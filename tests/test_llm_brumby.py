@@ -33,8 +33,9 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from _chunk_rows import (check_state_keeps_one_row,  # noqa: E402
-                         SHAPE_CASES, check_shapes, pin_full_shape)
+from _chunk_rows import (check_descriptor,  # noqa: E402
+                         check_state_keeps_one_row, SHAPE_CASES,
+                         check_shapes, pin_full_shape)
 from benchmark import reference_brumby as ref  # noqa: E402
 from ray_tpu.llm import InferenceEngine, tp  # noqa: E402
 from ray_tpu.llm import model as M  # noqa: E402
@@ -307,6 +308,16 @@ def test_engine_chunked_prefill_and_decode_loop_match_reference(
     assert _worst_gap(eng, cfg, prompt, served) < TOL
     # no page copy: no prefix cache
     assert eng.compiled_step_programs() <= eng._fns.program_budget - 1 == 3
+
+
+def test_a_descriptor_holds_the_arrays_the_engine_packed_before():
+    """Retention layers and no page in use: token_state is a field of the
+    descriptor, the page fields ride as ever; every field the old
+    packing's."""
+    cfg = LlamaConfig.tiny(**BRUMBY)
+    params = _seeded(cfg)
+    check_descriptor(lambda **kw: InferenceEngine(
+        cfg, params, **{**ENGINE, **kw}))
 
 
 def test_a_sequence_that_prefills_alone_keeps_one_row_a_step(brumby):

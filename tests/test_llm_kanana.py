@@ -30,8 +30,9 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from _chunk_rows import (CASES, check, check_preempted,  # noqa: E402
-                         SHAPE_CASES, check_shapes, pin_full_shape)
+from _chunk_rows import (CASES, check, check_descriptor,  # noqa: E402
+                         check_preempted, SHAPE_CASES, check_shapes,
+                         pin_full_shape)
 from benchmark import reference_kanana as ref  # noqa: E402
 from benchmark import reference_lfm2  # noqa: E402
 from ray_tpu.llm import InferenceEngine  # noqa: E402
@@ -333,6 +334,15 @@ def test_a_mixed_step_runs_the_smallest_shape_that_holds_its_rows(
     """One-row and two-row steps in turn on the LATENT pool: the tokens
     of the full shape alone, each step in the shape its deal asks for."""
     check_shapes(case, *shaped_and_full)
+
+
+def test_a_descriptor_holds_the_arrays_the_engine_packed_before():
+    """A latent pool (one leaf, no token_state): joined rows, a
+    copy-on-write hit and a preemption, every field the old packing's."""
+    cfg = LlamaConfig.tiny(**KANANA)
+    params = _seeded(cfg)
+    check_descriptor(lambda **kw: InferenceEngine(
+        cfg, params, **{**ENGINE, **kw}))
 
 
 def test_a_preempted_sequences_re_prefill_takes_both_rows():

@@ -24,8 +24,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from _chunk_rows import (SHAPE_CASES, check_shapes,  # noqa: E402
-                         pin_full_shape)
+from _chunk_rows import (SHAPE_CASES, check_descriptor,  # noqa: E402
+                         check_shapes, pin_full_shape)
 from benchmark import reference_olmoe as ref  # noqa: E402
 from ray_tpu.llm import InferenceEngine  # noqa: E402
 from ray_tpu.llm.engine import CPU_KEY, WALL_KEYS  # noqa: E402
@@ -284,6 +284,16 @@ def test_a_mixed_step_runs_the_smallest_shape_that_holds_its_rows(
     check_shapes(case, *shaped_and_full)
 
 
+def test_a_descriptor_holds_the_arrays_the_engine_packed_before():
+    """Routed experts over a per-head pool: the routing counters ride
+    behind the tokens whatever the step was sent in; every field of every
+    descriptor the old packing's."""
+    cfg = LlamaConfig.tiny(**OLMOE)
+    params = init_params(cfg, jax.random.PRNGKey(5))
+    check_descriptor(lambda **kw: InferenceEngine(
+        cfg, params, **{**ENGINE, **kw}))
+
+
 # ------------------------------------------- a dense configuration is as it was
 
 def test_dense_configuration_is_untouched():
@@ -299,7 +309,7 @@ def test_dense_configuration_is_untouched():
         "decode_dispatches", "cached_tokens", "ragged_dispatches",
         "ragged_real_tokens", "ragged_slot_tokens", "cow_copies",
         "preemptions", "chunk_rows", "chunk_rows_joined",
-        "ragged_small_dispatches"} \
+        "ragged_small_dispatches", "h2d_arrays"} \
         | set(WALL_KEYS + (CPU_KEY,))                # every model's clocks
     # the step programs' outputs keep their shapes: [R] and [K, B]
     from ray_tpu.llm import model as M

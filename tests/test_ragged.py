@@ -530,6 +530,7 @@ def test_step_programs_through_the_kernels_match_reference(monkeypatch,
     against the reference branch of the same programs: the same tokens,
     and the same pool in every layer off the scratch page."""
     from jax.experimental import pallas as pl
+    from _chunk_rows import as_descriptor
     from ray_tpu.llm import model as M
     from ray_tpu.llm.cache import make_kv_cache
     from ray_tpu.models.llama import LlamaConfig, init_params
@@ -557,19 +558,23 @@ def test_step_programs_through_the_kernels_match_reference(monkeypatch,
     row_of = np.concatenate([[0, 1], np.full(C, 2), [0, 0]])
     page = table[row_of, pos // ps]
     page[-2:] = 0
-    mixed = [jnp.asarray(a, jnp.int32) for a in (
-        rng.integers(0, 64, T), pos, page, pos % ps, table,
-        [0, 1, B], [1, 1, C], [10, 4, 5 + C])]
+    mixed, step_layout = as_descriptor(
+        tokens=rng.integers(0, 64, T), token_pos=pos, token_page=page,
+        token_slot=pos % ps, page_table=table, q_start=[0, 1, B],
+        q_len=[1, 1, C], kv_len=[10, 4, 5 + C])
     rng_state = rng.bit_generator.state
     outs = {}
     for impl in ("reference", "kernel"):
         rng.bit_generator.state = rng_state         # the same pool twice
-        nxt, kv = M.ragged_step(params, *mixed, pool(), cfg=cfg,
+        nxt, kv = M.ragged_step(params, mixed, pool(),
+                                layouts=(step_layout,), cfg=cfg,
                                 paged_impl=impl, max_q_len=C, decode_rows=B)
+        decode, loop_layout = as_descriptor(
+            tokens=nxt[:B], positions=[10, 4], seq_lens=[11, 5],
+            page_table=table[:B])
         toks, kv, _, _ = M.ragged_decode_loop(
-            params, nxt[:B], jnp.asarray([10, 4], jnp.int32), kv,
-            jnp.asarray(table[:B]), jnp.asarray([11, 5], jnp.int32),
-            num_steps=3, cfg=cfg, paged_impl=impl)
+            params, decode, kv, layouts=(loop_layout,), num_steps=3,
+            cfg=cfg, paged_impl=impl)
         outs[impl] = (np.asarray(nxt), np.asarray(toks),
                       {n: np.asarray(a.astype(jnp.float32))
                        for n, a in kv.items()})

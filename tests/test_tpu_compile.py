@@ -220,22 +220,18 @@ def _compile_step_program_once(chip, cfg, program, *, max_batch, pages,
     # the pool the kernels take (StepPrograms.init_kv on a TPU)
     kv = _abstract(chip, functools.partial(
         make_kv_cache, cfg, pages, ps, max_batch=max_batch, lane_pad=True))
-    table = functools.partial(_sds, chip, dtype=jnp.int32)
-    if program == "mixed":
-        T, R = max_batch + rows * chunk, max_batch + rows
-        tok, row = table((T,)), table((R,))
-        state = {"token_state": tok} if cfg.layers_of("conv") \
-            or cfg.layers_of("mamba") or cfg.layers_of("retention") else {}
-        compiled = M.ragged_step.lower(
-            params, tok, tok, tok, tok, table((R, max_seq // ps)), row, row,
-            row, kv, cfg=cfg, paged_impl="kernel", max_q_len=chunk,
-            decode_rows=max_batch, **state).compile()
-        return compiled, kv, R
-    row = table((max_batch,))
-    compiled = M.ragged_decode_loop.lower(
-        params, row, row, kv, table((max_batch, max_seq // ps)), row,
-        num_steps=8, cfg=cfg, paged_impl="kernel").compile()
-    return compiled, kv, 8 * max_batch
+    # the engine's own seam over these sizes: its layouts and statics
+    fns = M.StepPrograms(cfg, decode_chunk=8, max_q_len=chunk,
+                         decode_rows=max_batch, max_pages=max_seq // ps,
+                         kv_quantized=False, prefill_rows=rows)
+    name, layout, n_out = {
+        "mixed": ("ragged_step", fns.step_layouts[rows], max_batch + rows),
+        "decode": ("decode_loop", fns.decode_layout, 8 * max_batch)}[program]
+    jit, statics = fns.jits[name]
+    desc = _sds(chip, (M.layout_size(layout),), jnp.int32)
+    compiled = jit.lower(params, desc, kv, **{
+        **statics, "paged_impl": "kernel"}).compile()
+    return compiled, kv, n_out
 
 
 def _olmoe_cfg(n_layers=2):
