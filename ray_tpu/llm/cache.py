@@ -255,13 +255,61 @@ class PrefixCache:
         return freed
 
 
-#: the pool's leaves that are not pages, all [layers of the kind, slots + 1,
-#: ...]: a conv layer's recurrent state; a state-space layer's matrix state
-#: and the last inputs of its conv; a retention layer's matrix state and
-#: its normaliser
+#: the pool's leaves that are not pages: a conv layer's last inputs; a
+#: state-space layer's matrix state and the last inputs of its conv; a
+#: retention layer's matrix state and its normaliser
 STATE_LEAF, SSM_LEAF, SSM_CONV_LEAF = "conv", "ssm", "ssm_conv"
 RET_LEAF, RET_NORM_LEAF = "retention", "retention_norm"
-STATE_LEAVES = (STATE_LEAF, SSM_LEAF, SSM_CONV_LEAF, RET_LEAF, RET_NORM_LEAF)
+
+#: what a layer of each kind keeps per BATCH SLOT: kind -> {leaf: cfg ->
+#: (its shape after [layers of the kind, max_batch + 1], its dtype)}. The
+#: ONE place that says so: ``make_kv_cache`` builds the leaves from it, the
+#: page copy and the engine's accounting tell them from pages by it
+#: (``STATE_LEAVES``), and whether a configuration keeps any decides the
+#: prefix cache and the mixed step's descriptor (``keeps_slot_state``). The
+#: body that reads a leaf is llm/model.py's, by the same kind.
+SLOT_STATE = {
+    ATTENTION: {},          # pages, and nothing a slot
+    CONV: {
+        # the last inputs of the depthwise conv, oldest first
+        STATE_LEAF: lambda c: ((c.conv_kernel - 1, c.dim), c.dtype)},
+    MAMBA: {
+        # a head's [P, N] matrix lies transposed, its N columns as rows
+        # over all heads' values: the layout the update kernel reads
+        # (ops/ssm.py). The largest thing a slot owns by three orders:
+        # H P N values a layer
+        SSM_LEAF: lambda c: (
+            (c.ssm_state, c.ssm_heads * c.ssm_head_dim), c.dtype),
+        # the last inputs of its conv (x, B and C together, before the
+        # bias and the SiLU), oldest first
+        SSM_CONV_LEAF: lambda c: (
+            (c.ssm_conv - 1, c.ssm_channels), c.dtype)},
+    RETENTION: {
+        # a key/value head's matrix state over the expanded key (D =
+        # expanded_dim(head_dim), 8704 at 128): D on the sublanes, the
+        # value on the lanes, a head's block contiguous: what the update
+        # kernel moves in one DMA (ops/retention.py)
+        RET_LEAF: lambda c: (
+            (c.n_kv_heads, expanded_dim(c.head_dim), c.head_dim), c.dtype),
+        # its normaliser as a symmetric matrix
+        RET_NORM_LEAF: lambda c: (
+            (c.n_kv_heads, c.head_dim, c.head_dim), jnp.float32)},
+}
+STATE_LEAVES = tuple(leaf for leaves in SLOT_STATE.values()
+                     for leaf in leaves)
+
+
+def slot_state_kinds(cfg: LlamaConfig) -> Tuple[str, ...]:
+    """The kinds of ``cfg``'s layers that keep state per batch slot."""
+    return tuple(kind for kind, leaves in SLOT_STATE.items()
+                 if leaves and cfg.layers_of(kind))
+
+
+def keeps_slot_state(cfg: LlamaConfig) -> bool:
+    """Whether some layer keeps state per batch slot (``SLOT_STATE``):
+    the pool then has leaves beside its pages, and a token of the mixed
+    step names its slot."""
+    return bool(slot_state_kinds(cfg))
 
 
 def make_kv_cache(cfg: LlamaConfig, total_pages: int, page_size: int,
@@ -271,12 +319,14 @@ def make_kv_cache(cfg: LlamaConfig, total_pages: int, page_size: int,
 
     {"k", "v"}: [n_attn, total_pages, Hkv, page_size, D], one entry of
     the leading axis for each ATTENTION layer (every layer, unless the
-    configuration names conv layers: those have no pages). With
+    configuration names others: those have no pages). With
     ``kv_dtype="int8"`` the pools are int8 and {"k_scale", "v_scale"}
     [n_layers, total_pages, Hkv, page_size] bf16 per-(page, head, slot)
     dequant scales ride alongside — one pytree, so jit donation,
     shard_map specs and COW copies treat pages + scales as one unit.
-    ``kv_dtype`` in {None/"model" (cfg dtype), "int8"}.
+    ``kv_dtype`` in {None/"model" (cfg dtype), "int8"}. Where no layer is
+    an attention layer ``k`` and ``v`` are [0, total_pages, ...]: leaves
+    with no layer and no bytes.
 
     The discipline (llm/model.py): one buffer in this one row-major
     layout for every program. The step programs take it donated, carry
@@ -305,88 +355,52 @@ def make_kv_cache(cfg: LlamaConfig, total_pages: int, page_size: int,
     the only state: the prefix cache, the copy on write and
     recompute-preemption work on this leaf as on K and V.
 
-    A configuration with conv layers gets one more leaf, ``STATE_LEAF``:
-    [n_conv, max_batch + 1, conv_kernel - 1, dim] in cfg.dtype, a conv
-    layer's last inputs for each BATCH SLOT (axis 1 is slots, not pages:
-    the page copy leaves it alone) and, last, a scratch slot that padding
-    tokens write. It rides the same dict, so it is donated, carried and
-    updated in place with the pages. Nothing ever zeroes a slot: a row
-    whose first token has position 0 reads zeros instead of its slot.
-
-    A configuration with state-space layers gets two, under the same
-    conventions: ``SSM_LEAF`` [n_ssm, max_batch + 1, ssm_state, ssm_heads
-    * ssm_head_dim], a layer's matrix state for each slot (a head's [P,
-    N] matrix lies transposed, its N columns as rows over all heads'
-    values: the layout the update kernel reads, ops/ssm.py), and
-    ``SSM_CONV_LEAF`` [n_ssm, max_batch + 1, ssm_conv - 1, ssm_channels],
-    the last inputs of its conv (x, B and C together, before the bias and
-    the SiLU), oldest first, both in cfg.dtype. The step reads and writes
-    both by dynamic slices only (llm/model.py ``_SsmConvState``,
-    ops/ssm.py): around a gather or a scatter of rows XLA re-laid the
-    whole conv leaf twice a layer and copied half the state leaf
-    (PERF.md, PR 37). The first is the largest thing a slot
-    owns by three orders: H P N values a layer.
-
-    A configuration with retention layers gets two as well: ``RET_LEAF``
-    [n_ret, max_batch + 1, n_kv_heads, D, head_dim] in cfg.dtype, a key/value
-    head's matrix state over the expanded key (D = ops/retention.py:
-    expanded_dim(head_dim), 8704 at 128: D on the sublanes, the value on
-    the lanes, a head's block contiguous: what the update kernel moves in
-    one DMA), and ``RET_NORM_LEAF`` [n_ret, max_batch + 1, n_kv_heads,
-    head_dim, head_dim] float32, its normaliser as a symmetric matrix.
-    Where every layer is one (no attention layer at all) ``k`` and ``v``
-    are [0, total_pages, ...]: leaves with no layer and no bytes.
+    State per batch slot (``SLOT_STATE``: which kinds, which leaves, in
+    what shape): each leaf is [layers of its kind, max_batch + 1, ...].
+    Axis 1 is BATCH SLOTS, not pages (the page copy leaves it alone), and
+    the last is a scratch slot that padding tokens write. The leaves ride
+    the same dict, so they are donated, carried and updated in place with
+    the pages. Nothing ever zeroes a slot: a row whose first token has
+    position 0 reads zeros instead of its slot. The step reads and writes
+    the state-space leaves by dynamic slices only (llm/model.py
+    ``_SsmConvState``, ops/ssm.py): around a gather or a scatter of rows
+    XLA re-laid the whole conv leaf twice a layer and copied half the
+    state leaf (PERF.md, PR 37).
     """
     if kv_dtype not in (None, "model", "int8"):
         raise ValueError(f"kv_dtype must be 'model' or 'int8', "
                          f"got {kv_dtype!r}")
+    kinds = slot_state_kinds(cfg)
+    if kinds and max_batch < 1:
+        raise ValueError(f"{', '.join(kinds)} layers keep state per batch "
+                         f"slot: make_kv_cache needs max_batch")
     if cfg.kv_lora_rank:
         if kv_dtype == "int8":
             raise ValueError(
                 "kv_dtype 'int8' is not built for a latent pool "
                 "(kv_lora_rank): one scale a token would cover the normed "
                 "latent and the rotary key part, two ranges in one row")
-        return {"k": jnp.zeros(
+        kv = {"k": jnp.zeros(
             (len(cfg.layers_of(ATTENTION)), total_pages, 1, page_size,
              latent_row_width(cfg, lane_pad)), dtype or cfg.dtype)}
-    shape = (len(cfg.layers_of(ATTENTION)), total_pages, cfg.n_kv_heads,
-             page_size,
-             -(-cfg.head_dim // LANES) * LANES if lane_pad else cfg.head_dim)
-    if kv_dtype == "int8":
-        from ray_tpu.ops.int8 import KV_SCALE_DTYPE
-        kv = {"k": jnp.zeros(shape, jnp.int8),
-              "v": jnp.zeros(shape, jnp.int8),
-              "k_scale": jnp.zeros(shape[:-1], KV_SCALE_DTYPE),
-              "v_scale": jnp.zeros(shape[:-1], KV_SCALE_DTYPE)}
     else:
-        dtype = dtype or cfg.dtype
-        kv = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
-    n_conv = len(cfg.layers_of(CONV))
-    if n_conv:
-        if max_batch < 1:
-            raise ValueError("conv layers keep state per batch slot: "
-                             "make_kv_cache needs max_batch")
-        kv[STATE_LEAF] = jnp.zeros(
-            (n_conv, max_batch + 1, cfg.conv_kernel - 1, cfg.dim), cfg.dtype)
-    n_ssm = len(cfg.layers_of(MAMBA))
-    if n_ssm:
-        if max_batch < 1:
-            raise ValueError("state-space layers keep state per batch "
-                             "slot: make_kv_cache needs max_batch")
-        kv[SSM_LEAF] = jnp.zeros(
-            (n_ssm, max_batch + 1, cfg.ssm_state,
-             cfg.ssm_heads * cfg.ssm_head_dim), cfg.dtype)
-        kv[SSM_CONV_LEAF] = jnp.zeros(
-            (n_ssm, max_batch + 1, cfg.ssm_conv - 1, cfg.ssm_channels),
-            cfg.dtype)
-    n_ret = len(cfg.layers_of(RETENTION))
-    if n_ret:
-        if max_batch < 1:
-            raise ValueError("retention layers keep state per batch slot: "
-                             "make_kv_cache needs max_batch")
-        hd, slots = cfg.head_dim, (n_ret, max_batch + 1, cfg.n_kv_heads)
-        kv[RET_LEAF] = jnp.zeros(slots + (expanded_dim(hd), hd), cfg.dtype)
-        kv[RET_NORM_LEAF] = jnp.zeros(slots + (hd, hd), jnp.float32)
+        shape = (len(cfg.layers_of(ATTENTION)), total_pages, cfg.n_kv_heads,
+                 page_size, -(-cfg.head_dim // LANES) * LANES if lane_pad
+                 else cfg.head_dim)
+        if kv_dtype == "int8":
+            from ray_tpu.ops.int8 import KV_SCALE_DTYPE
+            kv = {"k": jnp.zeros(shape, jnp.int8),
+                  "v": jnp.zeros(shape, jnp.int8),
+                  "k_scale": jnp.zeros(shape[:-1], KV_SCALE_DTYPE),
+                  "v_scale": jnp.zeros(shape[:-1], KV_SCALE_DTYPE)}
+        else:
+            dtype = dtype or cfg.dtype
+            kv = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    for kind in kinds:
+        slots = (len(cfg.layers_of(kind)), max_batch + 1)
+        for leaf, of in SLOT_STATE[kind].items():
+            shape, leaf_dtype = of(cfg)
+            kv[leaf] = jnp.zeros(slots + shape, leaf_dtype)
     return kv
 
 
@@ -400,8 +414,7 @@ def latent_row_width(cfg: LlamaConfig, lane_pad: bool = False) -> int:
 def prefix_cache_supported(cfg: LlamaConfig) -> bool:
     """Whether a page-aligned prefix hit restores ALL of a sequence's
     state at that position: true where pages are the only state."""
-    return not (cfg.layers_of(CONV) or cfg.layers_of(MAMBA)
-                or cfg.layers_of(RETENTION))
+    return not keeps_slot_state(cfg)
 
 
 def kv_cache_tag(cfg: LlamaConfig, kv_dtype: Optional[str]) -> str:

@@ -118,7 +118,7 @@ import numpy as np
 
 from ray_tpu.llm.cache import (SCRATCH_PAGE, STATE_LEAVES, PageAllocator,
                                PrefixCache, SequenceState, kv_cache_tag,
-                               prefix_cache_supported)
+                               prefix_cache_supported, slot_state_kinds)
 from ray_tpu.llm import model as M
 from ray_tpu.llm.tp import build_tp_mesh
 from ray_tpu.models.llama import LlamaConfig
@@ -344,9 +344,9 @@ class InferenceEngine:
             # a hit would restore the matched pages' KV and run the
             # recurrent layers on zero state: no match is taken at all
             logger.warning(
-                "prefix cache off: this configuration has conv, state-space "
-                "or retention layers, whose state per batch slot (%d bytes) "
-                "a page-aligned prefix hit does not restore",
+                "prefix cache off: this configuration has %s layers, whose "
+                "state per batch slot (%d bytes) a page-aligned prefix hit "
+                "does not restore", " and ".join(slot_state_kinds(cfg)),
                 self._state_bytes_per_slot)
             use_prefix = False
         self.prefix: Optional[PrefixCache] = \

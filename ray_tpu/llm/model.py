@@ -5,102 +5,107 @@ llm/_internal/serve/deployments/llm/vllm/ — the reference ships no model
 code in-tree), rebuilt on ray_tpu's functional decoder (models/llama.py —
 same params pytree, so training checkpoints serve directly).
 
-ONE layer body with its variation points read from the configuration
-(LlamaConfig; the defaults are the Llama/Mistral block): an RMSNorm on
-the projected q and k before the rotary embedding (``qk_norm`` over the
-whole vector, ``qk_norm_per_head`` over each head), the feed-forward as
-dense SwiGLU or as dropless routed experts (``n_experts``, ops/moe.py;
-the router's score, selection bias, epsilon and scale are fields too),
-and the logits from the embedding table or from an ``lm_head`` of their
-own (``tie_embeddings``). A dense configuration lowers to the program it
-lowered to before the points existed. OLMoE-1B-7B is the first block
-that sets the first three.
+ONE WALK over the layers, for every block (``_layers``). ``_pattern``
+reads the configuration as (leading layers, one period, how many
+periods), each layer an (operator kind, feed-forward kind): the leading
+layers run once, then ONE ``lax.scan`` over the periods, its body one
+period, so depth does not unroll. The Llama / Mistral and OLMoE blocks
+are patterns of period 1. Weights are one stack per KIND
+(models/llama.py; the Llama tree holds every layer's leaves flat and is
+split by name, ``_stacks``), closed over and indexed by a layer's ordinal
+among the layers of its kind; the routed experts' [layer, expert] weights
+go to their kernel whole. The feed-forward is a dense SwiGLU (``_mlp``) or
+dropless routed experts (``_moe_mlp``, ops/moe.py; the router's score,
+selection bias, epsilon and scale are fields), with a shared expert
+beside them where ``shared_ffn_dim`` says so. The logits come from the
+embedding table or an ``lm_head`` (``tie_embeddings``); ``embed_scale``,
+``residual_scale`` and ``logits_divisor`` multiply the embedding, both
+branches of every layer and the logits (1 everywhere but the granite
+family). A configuration lowers to the code of its own fields and to no
+other block's (tests/test_llm_blocks_lowering.py).
 
-Layers that DIFFER (``layer_types``, ``n_dense_layers``; LFM2 is the
-first such block): a layer's operator is attention or a gated short
-convolution (``_short_conv``), its feed-forward dense for the leading
-layers and experts after. Weights are stacked per KIND (models/llama.py)
-and a layer finds its own by its ordinal among the layers of its kind.
-Depth does not unroll: the leading dense layers run once, then one
-``lax.scan`` over the PERIODS of the pattern, its body one period
-(``_hybrid_layers``). A conv layer has no pages; its state is the last
-``conv_kernel - 1`` inputs of its depthwise conv, per batch slot.
+ONE TABLE of layer operators (``OPERATORS``): kind -> (its stack of
+weights, its body), every body ``(lp, l, x, kv, rows, cfg, impl) ->
+(x, kv)``: the layer's weights, its ordinal ``l``, the stream, the pool,
+the ragged batch (``_Rows``) and the kernel-or-reference choice. What a
+kind keeps per batch slot is declared beside it in llm/cache.py
+(``SLOT_STATE``), by the same key:
 
-A third operator, the selective state-space recurrence (``"mamba"`` in
-``layer_types``, ``_mamba``; granite-4.0-h is the first such block): per
-batch slot a MATRIX state [ssm_heads, ssm_head_dim, ssm_state] a layer,
-megabytes where the conv keeps kilobytes, beside the last inputs of its
-own conv over x, B and C. One-token rows update it in place (a Pallas
-kernel, ops/ssm.py), chunk rows scan it in the chunked form. That block
-also has attention with NO positional embedding (``rope``), a score scale
-that is a field (``attn_scale``), and multipliers on the embedding, on
-both branches of every layer and under the logits (``embed_scale``,
-``residual_scale``, ``logits_divisor``).
+  - attention (``_attention``; the only operator of the Llama, Mistral
+    and OLMoE blocks): pages, and nothing a slot. Variation points: an
+    RMSNorm on the projected q and k before the rotary embedding
+    (``qk_norm`` over the whole vector, ``qk_norm_per_head`` over each
+    head), no positional embedding at all (``rope``), a score scale of
+    its own (``attn_scale``). With ``kv_lora_rank`` it is latent attention
+    (``_latent_attention``; Kanana-2 is the first such block): no wk /
+    wv, a token's cache in a layer ONE row of kv_lora_rank +
+    qk_rope_head_dim values for all heads in a pool of ONE leaf {"k"},
+    decode rows and chunks both in the ABSORBED form (w_uk folded into
+    the query, w_uv into the output), so the kernel runs multi-query
+    attention over the latent rows, the value a lane slice of the K block
+    it holds, and the cached prefix is never expanded in HBM.
+  - the gated short convolution (``_short_conv``; LFM2): no pages; the
+    last ``conv_kernel - 1`` inputs of its depthwise conv, kilobytes a
+    slot, gathered and scattered by slot.
+  - the selective state-space recurrence (``_mamba``; granite-4.0-h): a
+    MATRIX state [ssm_heads, ssm_head_dim, ssm_state] a layer, megabytes
+    a slot, beside the last inputs of its own conv over x, B and C
+    (``_SsmConvState``: one block, no gather).
+  - power retention of degree 2 (``_retention``; Brumby-14B, which has
+    NO attention layer: the page leaves then have no layer): q, k and v
+    projected, normed and rotated as attention's, and per slot and
+    key/value head a matrix state over the degree-2 expansion of the key
+    [D, head_dim], decayed a token by a sigmoid gate, beside its
+    normaliser.
 
-A fourth operator, power retention of degree 2 (``"retention"`` in
-``layer_types``, ``_retention``; Brumby-14B is the first such block, and
-the first with NO attention layer): q, k and v projected, normed and
-rotated as attention's are, but no page is written or read: per batch slot
-and key/value head a matrix state over the degree-2 expansion of the key
-[D, head_dim], decayed a token by a sigmoid gate, beside its normaliser;
-the query heads of a group read the group's state. One-token rows update it
-in place (a Pallas kernel, ops/retention.py), chunk rows take the chunk
-form from their slot's state. The page leaves then have no layer.
+The two recurrences take the rows of a ragged batch by ONE protocol
+(``_slot_rows``): the leading one-token rows update their slots in place
+(a Pallas kernel each, ops/ssm.py, ops/retention.py), chunk rows start
+from their slot's state and leave their last state there.
 
 ONE step program for everything (`_ragged_step_body`): the engine packs
 decode tokens and prefill-chunk tokens into a single RAGGED batch
 (`ops.paged_attention.ragged_paged_attention`), so prefill chunks and
 decode steps share one compiled program instead of a per-length-bucket
-zoo. Per layer the step writes every ragged token's K/V into the paged
-pool (`write_ragged_kv` — quantizing when the pool is int8) and then
-attends; per row the last valid token's logits argmax fuses in-program,
-so a finishing prefill chunk's first token and every decode row's next
-token come back in ONE readback.
+zoo. Per attention layer the step writes every ragged token's K/V into
+the paged pool (`write_ragged_kv` — quantizing when the pool is int8) and
+then attends; per row the last valid token's logits argmax fuses
+in-program, so a finishing prefill chunk's first token and every decode
+row's next token come back in ONE readback. `_ragged_decode_loop` is the
+same step with every batch slot a one-token row, scanned ``num_steps``
+times in one program.
 
-The KV pool is a dict pytree {"k", "v"[, "k_scale", "v_scale"]},
-the ATTENTION layers stacked on the leading axis (every layer, unless
-the configuration names conv layers), and, with conv layers, one more
-leaf {"conv"}: their state [n_conv, slots + 1, taps - 1, dim], a slot a
-batch slot and a scratch slot last (llm/cache.py); with state-space
-layers {"ssm", "ssm_conv"} under the same conventions. ONE buffer each in
-ONE layout, donated to the step program and updated in place. The pool
-is a CARRY of the layer
-scan (and so of the decode loop's step scan), never a scanned input or
-a stacked output: a layer is written and read by its INDEX (its ordinal
-among the layers of its kind), the write
+The pool is a dict pytree (llm/cache.py: ``make_kv_cache``): page leaves
+{"k", "v"[, "k_scale", "v_scale"]} (a latent pool: {"k"}), the ATTENTION
+layers stacked on the leading axis, and the leaves ``SLOT_STATE``
+declares, [layers of the kind, slots + 1, ...], a slot a batch slot and a
+scratch slot last. ONE buffer each in ONE layout, donated to the step
+program and updated in place. The pool is a CARRY of the layer scan (and
+so of the decode loop's step scan), never a scanned input or a stacked
+output: a layer is written and read by its INDEX, the write
 (`_kv_write_pallas`, aliased in to out) and the attention kernel both
 taking the whole stacked pool, so no layer is sliced out, converted to
-another layout or stacked back, and no step copies the pool (threaded
-as scan xs/ys it moved ~4 times a step: PERF.md, PR 27). Only the int8
+another layout or stacked back, and no step copies the pool (threaded as
+scan xs/ys it moved ~4 times a step: PERF.md, PR 27). Only the int8
 pool's scale leaves, which XLA reads, are scattered by XLA.
 
 ONE DESCRIPTOR a dispatch: the two step programs take (params, desc, kv),
 ``desc`` ONE flat int32 array that holds every integer input of the
 dispatch (``step_layout`` / ``decode_layout``: the fields, each with its
-shape, one after another), and ``cut`` it at static offsets into the arrays
+shape, one after another; ``token_state`` is a field where some layer
+keeps state a slot), and ``cut`` it at static offsets into the arrays
 their bodies take (``_on_descriptor``). The engine fills the same layout's
 numpy views on the host and makes one host-to-device transfer where it
 made one a field (llm/engine.py: _descriptor_turns).
 
-Latent attention (``kv_lora_rank``; Kanana-2 is the first such block):
-the attention operator has no wk / wv (``_latent_attention``). A token's
-cache in a layer is ONE row of kv_lora_rank + qk_rope_head_dim values
-for all heads, in a pool of ONE leaf {"k"} with no "v": the normed
-latent and the rotated shared key part. Decode rows and prefill chunks
-both compute the ABSORBED form: the up-projection is folded into the
-query (w_uk) and into the output (w_uv), so the kernel runs multi-query
-attention over the latent rows, the value a lane slice of the K block it
-holds, and the cached prefix is never expanded to per-head K and V in
-HBM. A shared expert (``shared_ffn_dim``) is a dense SwiGLU on the
-feed-forward's normed input, added to the routed sum.
-
-Tensor parallelism (``tp_axis``): the step also runs INSIDE a
-``shard_map`` block whose weights arrive pre-sliced Megatron-style
-(wq/wk/wv/w_gate/w_up column-sharded, wo/w_down row-sharded). Head
-counts derive from the LOCAL weight shapes, attention runs on the local
-kv-head shard of the pool with zero communication, and the two
-row-parallel projections psum over ``tp_axis`` — two collectives per
-layer, the textbook Megatron schedule, riding ICI.
+Tensor parallelism (``tp_axis``, in ``_Rows``): the same walk also runs
+INSIDE a ``shard_map`` block whose weights arrive pre-sliced
+Megatron-style (wq/wk/wv/w_gate/w_up column-sharded, wo/w_down
+row-sharded; llm/tp.py says which blocks). Head counts derive from the
+LOCAL weight shapes, attention runs on the local kv-head shard of the
+pool with zero communication, and the two row-parallel projections psum
+over ``tp_axis`` — two collectives per layer, the textbook Megatron
+schedule, riding ICI.
 """
 
 from __future__ import annotations
@@ -118,8 +123,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ray_tpu.llm import tp as TP
 from ray_tpu.llm.cache import (RET_LEAF, RET_NORM_LEAF, SCRATCH_PAGE,
                                SSM_CONV_LEAF, SSM_LEAF, STATE_LEAF,
-                               STATE_LEAVES, make_kv_cache,
-                               prefix_cache_supported)
+                               STATE_LEAVES, keeps_slot_state, make_kv_cache)
 from ray_tpu.models.llama import (ATTENTION, CONV, MAMBA, RETENTION,
                                   LlamaConfig, Params, _rmsnorm, _rope,
                                   _rope_pairs, init_params)
@@ -204,8 +208,14 @@ def _moe_mlp(lp, experts, layer, x, valid, cfg: LlamaConfig, impl):
     return _residual(x, y, cfg), counters
 
 
-#: the expert weights stay out of the layer scan's sliced inputs
+#: the routed experts' weights: handed to the expert kernel whole, [layer,
+#: expert, ...], with the layer's index, never a layer's slice of them (a
+#: slice handed to the kernel is a copy of a layer's experts)
 _EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
+#: the feed-forward's leaves of the Llama tree (models/llama.py:
+#: init_params), which holds every layer's leaves flat: the rest are the
+#: attention's
+_FFN_LEAVES = ("mlp_norm", "router") + _EXPERT_LEAVES
 
 
 def step_counters(cfg: LlamaConfig) -> Tuple[str, ...]:
@@ -234,16 +244,25 @@ SCOPE_RET_PROJ, SCOPE_RET_UPDATE, SCOPE_RET_CHUNK = \
     "retention_proj", "retention_update", "retention_chunk"
 
 
-class _ConvRows(NamedTuple):
-    """What an operator with state per batch slot needs of the ragged
-    batch: each token's position and state slot (None: token t is slot t's
-    one token), the rows' spans, and how many leading rows hold at most one
-    token (the step's static hint)."""
+class _Rows(NamedTuple):
+    """What a step hands every operator of its ragged batch. For all: each
+    token's position and the rows' spans, and how many leading rows hold at
+    most one token (static). For an operator with state per batch slot:
+    each token's state slot (None: token t is slot t's one token). For
+    attention: each token's page and place in it, the rows' pages and
+    lengths, the longest row (static), and the mesh axis its heads are
+    sharded over, if any (static: the step's ``tp_axis``)."""
     token_pos: jax.Array
     token_state: Optional[jax.Array]
     q_start: jax.Array
     q_len: jax.Array
     decode_rows: int = 0
+    token_page: Optional[jax.Array] = None
+    token_slot: Optional[jax.Array] = None
+    page_table: Optional[jax.Array] = None
+    kv_len: Optional[jax.Array] = None
+    max_q_len: Optional[int] = None
+    tp_axis: Optional[str] = None
 
 
 def _shift(a, n: int, fill):
@@ -254,7 +273,7 @@ def _shift(a, n: int, fill):
     return jnp.concatenate([pad, a[:-n]])
 
 
-def _conv_window(v, fetch, rows: _ConvRows, K: int):
+def _conv_window(v, fetch, rows: _Rows, K: int):
     """[v[t], v[t-1], .., v[t-(K-1)]] of a depthwise causal conv's input v
     [T, ch] over a RAGGED batch: a token's earlier inputs are the tokens
     before it in its own row where the row reaches back far enough, and
@@ -299,9 +318,9 @@ def _conv_upto(prev):
     return jnp.stack(prev[len(prev) - 2:0:-1] + [prev[0]], axis=1)
 
 
-def _short_conv(lp, l, x, state, rows: _ConvRows, cfg: LlamaConfig):
-    """The gated short convolution of one layer, on entry ``l`` of the
-    state (the layer's ordinal among the conv layers):
+def _short_conv(lp, l, x, kv, rows: _Rows, cfg: LlamaConfig, impl=None):
+    """The gated short convolution of one layer, on entry ``l`` of
+    ``STATE_LEAF`` (the layer's ordinal among the conv layers):
 
         (B, C, u) = split3(rms(x) W_in);  v = B * u
         c[t] = sum_j w[j] * v[t - (K-1) + j]     depthwise, causal
@@ -313,10 +332,11 @@ def _short_conv(lp, l, x, state, rows: _ConvRows, cfg: LlamaConfig):
     padding, go to the scratch slot (the last). v is rounded to the
     compute dtype before it is used or stored, so a sequence computes the
     same values however its tokens fall into chunks; the taps are summed
-    in float32. Returns (x', state)."""
+    in float32; no kernel, so ``impl`` chooses nothing. Returns (x', kv)."""
     cd = cfg.dtype
     K = cfg.conv_kernel
     T = x.shape[1]
+    state = kv[STATE_LEAF]
     scratch = state.shape[1] - 1
     slot = rows.token_state
     with jax.named_scope(SCOPE_CONV):
@@ -339,7 +359,7 @@ def _short_conv(lp, l, x, state, rows: _ConvRows, cfg: LlamaConfig):
             row_slot = jnp.where(rows.q_len > 0, slot[last], scratch)
             state = state.at[l, row_slot].set(
                 upto[last].astype(state.dtype))
-    return _residual(x, y[None], cfg), state
+    return _residual(x, y[None], cfg), {**kv, STATE_LEAF: state}
 
 
 class _SsmConvState:
@@ -355,7 +375,7 @@ class _SsmConvState:
     XLA re-laid all 121 MB of it twice a layer between the two (PERF.md,
     PR 37)."""
 
-    def __init__(self, state, l, rows: _ConvRows, T: int):
+    def __init__(self, state, l, rows: _Rows, T: int):
         self.state, self.l, self.rows, self.T = state, l, rows, T
         slot = rows.token_state
         S = state.shape[1]
@@ -403,7 +423,46 @@ class _SsmConvState:
                                         (self.l, 0, 0, 0))
 
 
-def _mamba(lp, l, x, kv, rows: _ConvRows, cfg: LlamaConfig, impl):
+def _slot_rows(rows: _Rows, state, operands, scopes, update, chunk):
+    """A recurrent operator's rows of a RAGGED batch, once for all such
+    operators. ``state`` is the tuple of its leaves [layers, slots + 1,
+    ...], ``operands`` its per-token inputs [T, ...]. The leading
+    ``rows.decode_rows`` rows are one token each, token t in the slot it
+    names (or naming the scratch slot, the last: the engine's packing, the
+    batch slots in order), and take ``update(*state, *operands, slots,
+    fresh)``, the in-place form, ``fresh`` where the position is 0. The
+    rest are chunk rows and take ``chunk(*state, *operands, pos, q_start,
+    q_len, row_slot)``: each starts from its slot's state (zeros where its
+    first position is 0: the form's own business, by ``pos``) and leaves
+    its last state there, a row without tokens in the scratch slot. In the
+    decode loop (no ``rows.token_state``) every token is a one-token row,
+    token t in slot t. Both return (y, *state). ``scopes`` names the
+    update's, the chunk form's and the scope the two outputs are joined
+    in. Returns (y [T, ...], state)."""
+    pos, slot = rows.token_pos, rows.token_state
+    T, scratch = pos.shape[0], state[0].shape[1] - 1
+    Rd = T if slot is None else min(rows.decode_rows, rows.q_start.shape[0])
+    ys = []
+    if Rd:
+        with jax.named_scope(scopes[0]):
+            y, *state = update(
+                *state, *(a[:Rd] for a in operands),
+                jnp.arange(Rd, dtype=jnp.int32) if slot is None
+                else slot[:Rd], pos[:Rd] == 0)
+            ys.append(y)
+    if T - Rd:
+        with jax.named_scope(scopes[1]):
+            q_start, q_len = rows.q_start[Rd:] - Rd, rows.q_len[Rd:]
+            first = jnp.clip(q_start, 0, T - Rd - 1)
+            y, *state = chunk(
+                *state, *(a[Rd:] for a in operands), pos[Rd:], q_start,
+                q_len, jnp.where(q_len > 0, slot[Rd:][first], scratch))
+            ys.append(y)
+    with jax.named_scope(scopes[2]):
+        return jnp.concatenate(ys), state
+
+
+def _mamba(lp, l, x, kv, rows: _Rows, cfg: LlamaConfig, impl):
     """The state-space operator (Mamba-2) of one layer, on entry ``l`` of
     both of its state leaves (the layer's ordinal among the mamba layers);
     H heads of P, state N, conv over ch = H P + 2 N channels:
@@ -419,21 +478,13 @@ def _mamba(lp, l, x, kv, rows: _ConvRows, cfg: LlamaConfig, impl):
     over a RAGGED batch. The conv takes its earlier inputs as the short
     conv does (``_conv_window``, from ``_SsmConvState``: u in the compute
     dtype, before the bias and the SiLU). The recurrence (ops/ssm.py, over
-    ``SSM_LEAF``): the leading ``rows.decode_rows`` rows are one token
-    each, in the slot their token names, and take the in-place update
-    kernel; the rest are chunk rows and take the chunked scan, each from
-    its slot's state (zeros where its first position is 0) and leaving its
-    last state there. A one-token row's token t is slot t's, or names the
-    scratch slot (the engine's packing: decode rows are the batch slots in
-    order). In the decode loop every token is such a row, token t in slot
-    t. dt, A, the conv and the recurrence are float32. Returns
+    ``SSM_LEAF``) takes the rows as ``_slot_rows`` deals them: the
+    in-place update kernel for the one-token rows, the chunked scan for the
+    chunk rows. dt, A, the conv and the recurrence are float32. Returns
     (x', kv)."""
     cd, f32 = cfg.dtype, jnp.float32
     H, P, N, K = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_conv
     di, T = H * P, x.shape[1]
-    state = kv[SSM_LEAF]
-    scratch = state.shape[1] - 1
-    pos, slot = rows.token_pos, rows.token_state
     with jax.named_scope(SCOPE_SSM_PROJ):
         h = _rmsnorm(x, lp["mamba_norm"], cfg.norm_eps)[0]
         g, u, dt = (h @ lp[k].astype(cd)
@@ -448,34 +499,23 @@ def _mamba(lp, l, x, kv, rows: _ConvRows, cfg: LlamaConfig, impl):
         xs = xs.reshape(T, H, P)
         dt = jax.nn.softplus(dt.astype(f32) + lp["dt_bias"].astype(f32))
         A, D = -jnp.exp(lp["A_log"].astype(f32)), lp["D"].astype(f32)
-    Rd = T if slot is None else min(rows.decode_rows, rows.q_start.shape[0])
-    ys = []
-    if Rd:
-        with jax.named_scope(SCOPE_SSM_UPDATE):
-            y, state = ssm.ssm_decode_update(
-                state, xs[:Rd], dt[:Rd], A, B[:Rd], C[:Rd], D,
-                jnp.arange(Rd, dtype=jnp.int32) if slot is None
-                else slot[:Rd], pos[:Rd] == 0, layer=l, impl=impl)
-            ys.append(y)
-    if T - Rd:
-        with jax.named_scope(SCOPE_SSM_SCAN):
-            q_start, q_len = rows.q_start[Rd:] - Rd, rows.q_len[Rd:]
-            first = jnp.clip(q_start, 0, T - Rd - 1)
-            y, state = ssm.ssm_chunk_scan(
-                state, xs[Rd:], dt[Rd:], A, B[Rd:], C[Rd:], D, pos[Rd:],
-                q_start, q_len,
-                jnp.where(q_len > 0, slot[Rd:][first], scratch), layer=l,
-                chunk=cfg.ssm_chunk, impl=impl)
-            ys.append(y)
+    y, (state,) = _slot_rows(
+        rows, (kv[SSM_LEAF],), (xs, dt, B, C),
+        (SCOPE_SSM_UPDATE, SCOPE_SSM_SCAN, SCOPE_SSM_PROJ),
+        lambda state, xs, dt, B, C, *slots: ssm.ssm_decode_update(
+            state, xs, dt, A, B, C, D, *slots, layer=l, impl=impl),
+        lambda state, xs, dt, B, C, *spans: ssm.ssm_chunk_scan(
+            state, xs, dt, A, B, C, D, *spans, layer=l, chunk=cfg.ssm_chunk,
+            impl=impl))
     with jax.named_scope(SCOPE_SSM_PROJ):
-        y = jnp.concatenate(ys).reshape(T, di) * jax.nn.silu(g.astype(f32))
+        y = y.reshape(T, di) * jax.nn.silu(g.astype(f32))
         y = _rmsnorm(y.astype(cd), lp["gate_norm"], cfg.norm_eps)
         y = y @ lp["w_out"].astype(cd)
     return _residual(x, y[None], cfg), \
         {**kv, SSM_LEAF: state, SSM_CONV_LEAF: conv_state}
 
 
-def _retention(lp, l, x, kv, rows: _ConvRows, cfg: LlamaConfig, impl):
+def _retention(lp, l, x, kv, rows: _Rows, cfg: LlamaConfig, impl):
     """Power retention (degree 2) of one layer, on entry ``l`` of both of
     its state leaves (the layer's ordinal among the retention layers); H
     query heads and G key/value heads of d, query head j reading group
@@ -491,17 +531,12 @@ def _retention(lp, l, x, kv, rows: _ConvRows, cfg: LlamaConfig, impl):
     (ops/retention.py: the scale goes on q and k as d^-1/4 each). The q/k
     norm and the rotary embedding are the attention layers'
     (``_project_qkv``, ``cfg.rope``), applied here, outside any kernel. Over
-    a RAGGED batch as ``_mamba`` takes it: the leading ``rows.decode_rows``
-    rows are one token each, in the slot their token names, and take the
-    in-place update kernel; the rest are chunk rows and take the chunk form,
-    each from its slot's state (zeros where its first position is 0) and
-    leaving its last state there. In the decode loop every token is a
-    one-token row, token t in slot t. Returns (x', kv)."""
+    a RAGGED batch as ``_slot_rows`` deals it: the in-place update kernel
+    for the one-token rows, the chunk form for the chunk rows, both over
+    ``RET_LEAF`` and ``RET_NORM_LEAF``. Returns (x', kv)."""
     cd, f32 = cfg.dtype, jnp.float32
     T = x.shape[1]
-    state, norm = kv[RET_LEAF], kv[RET_NORM_LEAF]
-    scratch = state.shape[1] - 1
-    pos, slot = rows.token_pos, rows.token_state
+    pos = rows.token_pos
     with jax.named_scope(SCOPE_RET_PROJ):
         h = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
         q, k, v = _project_qkv(lp, h, cfg)                # [1, T, H | G, d]
@@ -513,33 +548,20 @@ def _retention(lp, l, x, kv, rows: _ConvRows, cfg: LlamaConfig, impl):
             preferred_element_type=f32) + lp["b_g"].astype(f32))
         root = q.shape[-1] ** -0.25
         q, k, v = q[0].astype(f32) * root, k[0].astype(f32) * root, v[0]
-    Rd = T if slot is None else min(rows.decode_rows, rows.q_start.shape[0])
-    os = []
-    if Rd:
-        with jax.named_scope(SCOPE_RET_UPDATE):
-            o, state, norm = retention.retention_decode_update(
-                state, norm, q[:Rd], k[:Rd], v[:Rd], a[:Rd],
-                jnp.arange(Rd, dtype=jnp.int32) if slot is None
-                else slot[:Rd], pos[:Rd] == 0, layer=l, impl=impl)
-            os.append(o)
-    if T - Rd:
-        with jax.named_scope(SCOPE_RET_CHUNK):
-            q_start, q_len = rows.q_start[Rd:] - Rd, rows.q_len[Rd:]
-            first = jnp.clip(q_start, 0, T - Rd - 1)
-            o, state, norm = retention.retention_chunk_scan(
-                state, norm, q[Rd:], k[Rd:], v[Rd:], a[Rd:], pos[Rd:],
-                q_start, q_len,
-                jnp.where(q_len > 0, slot[Rd:][first], scratch), layer=l,
-                chunk=cfg.retention_chunk, impl=impl)
-            os.append(o)
+    o, (state, norm) = _slot_rows(
+        rows, (kv[RET_LEAF], kv[RET_NORM_LEAF]), (q, k, v, a),
+        (SCOPE_RET_UPDATE, SCOPE_RET_CHUNK, SCOPE_RET_PROJ),
+        functools.partial(retention.retention_decode_update, layer=l,
+                          impl=impl),
+        functools.partial(retention.retention_chunk_scan, layer=l,
+                          chunk=cfg.retention_chunk, impl=impl))
     with jax.named_scope(SCOPE_RET_PROJ):
-        o = jnp.concatenate(os).astype(cd).reshape(1, T, -1)
+        o = o.astype(cd).reshape(1, T, -1)
         x = _residual(x, o @ lp["wo"].astype(cd), cfg)
     return x, {**kv, RET_LEAF: state, RET_NORM_LEAF: norm}
 
 
-def _latent_attention(lp, l, x, kv, cfg: LlamaConfig, token_pos, token_page,
-                      token_slot, page_table, q_start, q_len, kv_len, hints):
+def _latent_attention(lp, l, x, kv, rows: _Rows, cfg: LlamaConfig, impl):
     """Latent attention (MLA) of one layer in its ABSORBED form, for
     decode rows and chunk rows alike, on entry ``l`` of the latent
     pool. Per token: q = z wq, per head [q_nope, q_pe]; a = z w_kva,
@@ -550,12 +572,11 @@ def _latent_attention(lp, l, x, kv, cfg: LlamaConfig, token_pos, token_page,
     themselves (score_h = (q~_h . c + q_pe_h . k_pe) / sqrt(nope +
     rope)), the kernel returns o~_h = sum_s p_h c[s], and o_h = o~_h
     w_uv_h: equal in exact arithmetic, and the cache is read once for
-    all heads and never expanded in HBM. ``hints``: the static tiling
-    hints and the kernel-or-reference choice (max_q_len, decode_rows,
-    impl)."""
+    all heads and never expanded in HBM."""
     cd = cfg.dtype
     r, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
     T, W = x.shape[1], kv["k"].shape[-1]
+    token_pos, q_start, q_len = rows.token_pos, rows.q_start, rows.q_len
     with jax.named_scope(SCOPE_ATTENTION):
         with jax.named_scope(SCOPE_MLA_PROJ):
             h = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
@@ -574,18 +595,77 @@ def _latent_attention(lp, l, x, kv, cfg: LlamaConfig, token_pos, token_page,
             qq = jnp.pad(jnp.concatenate([q_lat, q_pe[0]], axis=-1), pad)
             row = jnp.pad(jnp.concatenate([c[0][:, None], k_pe[0]],
                                           axis=-1), pad)   # [T, 1, W]
-        hints = dict(hints, layer=l)
+        hints = dict(max_q_len=rows.max_q_len, decode_rows=rows.decode_rows,
+                     impl=impl, layer=l)
         pool, _, _, _ = write_ragged_kv(
-            kv["k"], None, row, None, token_page, token_slot,
+            kv["k"], None, row, None, rows.token_page, rows.token_slot,
             q_start=q_start, q_len=q_len, **hints)
         o = ragged_paged_attention(
-            qq, pool, None, page_table, q_start, q_len, kv_len,
+            qq, pool, None, rows.page_table, q_start, q_len, rows.kv_len,
             v_width=r, sm_scale=q.shape[-1] ** -0.5, **hints)
         with jax.named_scope(SCOPE_MLA_PROJ):
             o = jnp.einsum("thr,hrv->thv", o.astype(cd),
                            lp["w_uv"].astype(cd)).reshape(1, T, -1)
             x = _residual(x, o @ lp["wo"].astype(cd), cfg)
     return x, {**kv, "k": pool}
+
+
+def _attention(lp, l, x, kv, rows: _Rows, cfg: LlamaConfig, impl):
+    """The attention operator of one layer, on entry ``l`` of the page
+    pool (the layer's ordinal among the attention layers): project and
+    rotate the ragged tokens, write their K/V into that layer of the pool
+    in place (quantizing to int8 + scales when the pool carries scale
+    leaves), then ragged attention over it: each token causally sees its
+    row's pages up to its own position, so a chunk's tokens see the prefix
+    AND earlier tokens of the same chunk (just written). Returns
+    (x', kv)."""
+    if cfg.kv_lora_rank:
+        return _latent_attention(lp, l, x, kv, rows, cfg, impl)
+    cd = cfg.dtype
+    T = x.shape[1]
+    token_pos, q_start, q_len = rows.token_pos, rows.q_start, rows.q_len
+    with jax.named_scope(SCOPE_ATTENTION):
+        h = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
+        q, k, v = _project_qkv(lp, h, cfg)            # [1, T, H, D]
+        if cfg.rope:
+            q = _rope(q, token_pos, cfg.rope_theta)
+            k = _rope(k, token_pos, cfg.rope_theta)
+        hints = dict(layer=l, max_q_len=rows.max_q_len,
+                     decode_rows=rows.decode_rows, impl=impl)
+        hd = q.shape[-1]
+        scale = dict(sm_scale=cfg.attn_scale) if cfg.attn_scale else {}
+        if kv["k"].shape[-1] != hd:
+            # a pool whose rows are padded to whole lanes
+            # (make_kv_cache, lane_pad): zeros past head_dim add
+            # nothing to a score and come back as zeros
+            q, k, v = (jnp.pad(a, ((0, 0),) * 3 + (
+                (0, kv["k"].shape[-1] - hd),)) for a in (q, k, v))
+            scale = scale or dict(sm_scale=hd ** -0.5)
+        kc, vc, ksc, vsc = write_ragged_kv(
+            kv["k"], kv["v"], k[0], v[0], rows.token_page, rows.token_slot,
+            kv.get("k_scale"), kv.get("v_scale"), q_start=q_start,
+            q_len=q_len, **hints)
+        o = ragged_paged_attention(
+            q[0], kc, vc, rows.page_table, q_start, q_len, rows.kv_len,
+            k_scale=ksc, v_scale=vsc, **hints, **scale)
+        o = o[..., :hd].reshape(1, T, -1).astype(cd)
+        # wo is row-parallel under tp (Megatron first collective)
+        x = _residual(x, _maybe_psum(o @ lp["wo"].astype(cd), rows.tp_axis),
+                      cfg)
+    kv = {**kv, "k": kc, "v": vc}
+    if "k_scale" in kv:
+        kv["k_scale"], kv["v_scale"] = ksc, vsc
+    return x, kv
+
+
+#: THE table of layer operators: kind -> (its stack in params["layers"],
+#: its body ``(lp, l, x, kv, rows, cfg, impl) -> (x, kv)``: the layer's
+#: weights, its ordinal among the layers of its kind, the stream, the
+#: pool, the ragged batch, and the kernel-or-reference choice). What a
+#: kind keeps in the pool beside pages is llm/cache.py's ``SLOT_STATE``,
+#: by the same key.
+OPERATORS = {ATTENTION: ("attn", _attention), CONV: ("conv", _short_conv),
+             MAMBA: ("mamba", _mamba), RETENTION: ("retention", _retention)}
 
 
 def _pattern(cfg: LlamaConfig):
@@ -605,18 +685,34 @@ def _pattern(cfg: LlamaConfig):
     return kinds[:lead], rest[:period], len(rest) // period
 
 
-def _hybrid_layers(layers, x, kv, cfg: LlamaConfig, attention, valid,
-                   impl, rows: _ConvRows):
-    """The layers of a block whose layers differ (cfg.hybrid): weights
-    stacked per kind (models/llama.py), a layer finding its own by its
-    ordinal among the layers of its kind: the attention layers' entry
-    of the page pool, the conv layers' entry of the state, the expert
-    layers' [layer, expert] weights, which stay closed over and whole.
-    Depth does not unroll: the leading layers run once, then ONE scan over
-    the periods of the pattern, its body one period. Both kinds of state
-    are carries, updated in place at their ordinals. Returns (x, kv,
+def _stacks(layers, cfg: LlamaConfig):
+    """``params["layers"]`` as one stack of leaves per kind. The tree of a
+    block whose layers differ is that already (models/llama.py:
+    _init_hybrid_params). The Llama tree (init_params: every layer
+    attention and ONE kind of feed-forward; training, the references and
+    llm/tp.py's specs share it) holds every layer's leaves flat, and is
+    split by name, the same arrays: no copy."""
+    if "mlp_norm" not in layers:
+        return layers
+    return {"attn": {k: w for k, w in layers.items()
+                     if k not in _FFN_LEAVES},
+            "moe" if cfg.n_experts else "dense":
+                {k: w for k, w in layers.items() if k in _FFN_LEAVES}}
+
+
+def _layers(layers, x, kv, rows: _Rows, valid, cfg: LlamaConfig, impl):
+    """THE walk over the layers, of every block: the weights one stack per
+    kind (``_stacks``), a layer finding its own by its ordinal among the
+    layers of its kind: its operator's entry of the page pool or of its
+    state leaves, the expert layers' [layer, expert] weights, which stay
+    closed over and whole. Depth does not unroll: the leading layers run
+    once, then ONE scan over the periods of the pattern (``_pattern``),
+    its body one period; the Llama block is a pattern of period 1. The
+    pool is a carry, whole, updated in place at the ordinals; the weights
+    are closed over and indexed by the scan's counter. Returns (x, kv,
     counters summed over the expert layers, or None)."""
     lead, period, n_periods = _pattern(cfg)
+    layers = _stacks(layers, cfg)
     experts = {k: layers["moe"][k] for k in _EXPERT_LEAVES} \
         if cfg.n_experts else None
 
@@ -627,24 +723,15 @@ def _hybrid_layers(layers, x, kv, cfg: LlamaConfig, attention, valid,
 
     def one(x, kv, counters, kinds, ordinal):
         op, ffn = kinds
-        if op == ATTENTION:
-            x, kv = attention(at("attn", ordinal[op]), ordinal[op], x, kv)
-        elif op == MAMBA:
-            x, kv = _mamba(at("mamba", ordinal[op]), ordinal[op], x, kv,
-                           rows, cfg, impl)
-        elif op == RETENTION:
-            x, kv = _retention(at("retention", ordinal[op]), ordinal[op], x,
-                               kv, rows, cfg, impl)
-        else:
-            x, state = _short_conv(at("conv", ordinal[op]), ordinal[op], x,
-                                   kv[STATE_LEAF], rows, cfg)
-            kv = {**kv, STATE_LEAF: state}
+        stack, body = OPERATORS[op]
+        x, kv = body(at(stack, ordinal[op]), ordinal[op], x, kv, rows, cfg,
+                     impl)
         if ffn == "moe":
             x, c = _moe_mlp(at("moe", ordinal[ffn]), experts, ordinal[ffn],
                             x, valid, cfg, impl)
             counters = counters + c
         else:
-            x = _mlp(at("dense", ordinal[ffn]), x, cfg)
+            x = _mlp(at("dense", ordinal[ffn]), x, cfg, rows.tp_axis)
         return x, kv, counters
 
     def run(carry, some, first, j=0, per=None):
@@ -661,9 +748,8 @@ def _hybrid_layers(layers, x, kv, cfg: LlamaConfig, attention, valid,
         return (x, kv, counters), first
 
     carry = (x, kv, jnp.zeros(len(moe.COUNTERS), jnp.int32))
-    carry, seen = run(carry, lead,
-                      dict.fromkeys((ATTENTION, CONV, MAMBA, RETENTION,
-                                     "dense", "moe"), 0))
+    carry, seen = run(carry, lead, dict.fromkeys((*OPERATORS, "dense",
+                                                  "moe"), 0))
     per = collections.Counter(k for kinds in period for k in kinds)
     (x, kv, counters), _ = lax.scan(
         lambda carry, j: (run(carry, period, seen, j, per)[0], None),
@@ -688,8 +774,8 @@ def _ragged_logits(params: Params, tokens: jax.Array,
     (padding tokens -> the scratch page); page_table [R, max_pages] +
     q_start/q_len/kv_len [R]: the per-row ragged descriptors
     (ops.paged_attention). kv: the pool dict — DONATED by every caller
-    (an undonated pool copies multi-GB per step). token_state [T], for a
-    configuration with conv layers only: each token's STATE slot (its
+    (an undonated pool copies multi-GB per step). token_state [T], where
+    some layer keeps state per batch slot: each token's STATE slot (its
     sequence's batch slot; the scratch slot, max_batch, for padding).
     None there means the decode loop's layout: token t is slot t's one
     token.
@@ -701,91 +787,20 @@ def _ragged_logits(params: Params, tokens: jax.Array,
     valid tokens only: a padding token is one whose page is the scratch
     page).
 
-    Per layer: project/rope the ragged tokens, write their K/V into
-    that layer of the pool in place (quantizing to int8 + scales when
-    the pool carries scale leaves), then ragged attention over it — each token causally
-    sees its row's pages up to its own position, so a chunk's tokens see
-    the prefix AND earlier tokens of the same chunk (just written).
+    The layers are ``_layers``'s: one walk for every block.
     """
     T = tokens.shape[0]
     cd = cfg.dtype
     x = params["embed"].astype(cd)[tokens][None]          # [1, T, d]
     if cfg.embed_scale != 1.0:
         x = x * jnp.asarray(cfg.embed_scale, cd)
-    quantized = "k_scale" in kv
-    layers = params["layers"]
     # a padding token is one whose page is the scratch page
     valid = token_page != SCRATCH_PAGE if cfg.n_experts else None
-
-    def attention(lp, l, x, kv):
-        """The attention operator of one layer, on entry ``l`` of the
-        pool (the layer's ordinal among the attention layers)."""
-        if cfg.kv_lora_rank:
-            return _latent_attention(
-                lp, l, x, kv, cfg, token_pos, token_page, token_slot,
-                page_table, q_start, q_len, kv_len,
-                dict(max_q_len=max_q_len, decode_rows=decode_rows,
-                     impl=paged_impl))
-        with jax.named_scope(SCOPE_ATTENTION):
-            h = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
-            q, k, v = _project_qkv(lp, h, cfg)            # [1, T, H, D]
-            if cfg.rope:
-                q = _rope(q, token_pos, cfg.rope_theta)
-                k = _rope(k, token_pos, cfg.rope_theta)
-            hints = dict(layer=l, max_q_len=max_q_len,
-                         decode_rows=decode_rows, impl=paged_impl)
-            hd = q.shape[-1]
-            scale = dict(sm_scale=cfg.attn_scale) if cfg.attn_scale else {}
-            if kv["k"].shape[-1] != hd:
-                # a pool whose rows are padded to whole lanes
-                # (make_kv_cache, lane_pad): zeros past head_dim add
-                # nothing to a score and come back as zeros
-                q, k, v = (jnp.pad(a, ((0, 0),) * 3 + (
-                    (0, kv["k"].shape[-1] - hd),)) for a in (q, k, v))
-                scale = scale or dict(sm_scale=hd ** -0.5)
-            kc, vc, ksc, vsc = write_ragged_kv(
-                kv["k"], kv["v"], k[0], v[0], token_page, token_slot,
-                kv.get("k_scale"), kv.get("v_scale"), q_start=q_start,
-                q_len=q_len, **hints)
-            o = ragged_paged_attention(
-                q[0], kc, vc, page_table, q_start, q_len, kv_len,
-                k_scale=ksc, v_scale=vsc, **hints, **scale)
-            o = o[..., :hd].reshape(1, T, -1).astype(cd)
-            x = _residual(x, _maybe_psum(o @ lp["wo"].astype(cd), tp_axis),
-                          cfg)
-        kv = {**kv, "k": kc, "v": vc}
-        if quantized:
-            kv["k_scale"], kv["v_scale"] = ksc, vsc
-        return x, kv
-
-    if cfg.hybrid:
-        x, kv, counters = _hybrid_layers(
-            layers, x, kv, cfg, attention, valid, paged_impl,
-            _ConvRows(token_pos, token_state, q_start, q_len, decode_rows))
-    else:
-        if cfg.n_experts:
-            # closed over, not scanned: a scan slices its inputs, and a
-            # slice handed to the expert kernel is a copy of a layer's
-            # experts
-            experts = {k: layers[k] for k in _EXPERT_LEAVES}
-            layers = {k: v for k, v in layers.items() if k not in experts}
-
-        def layer(carry, inp):
-            x, kv = carry
-            lp, l = inp
-            x, kv = attention(lp, l, x, kv)
-            if cfg.n_experts:
-                x, counters = _moe_mlp(lp, experts, l, x, valid, cfg,
-                                       paged_impl)
-                return (x, kv), counters
-            return (_mlp(lp, x, cfg, tp_axis), kv), None
-
-        # the pool rides the scan as a carry, whole: each layer writes and
-        # reads it at its index
-        (x, kv), per_layer = lax.scan(
-            layer, (x, kv),
-            (layers, jnp.arange(cfg.n_layers, dtype=jnp.int32)))
-        counters = per_layer.sum(axis=0) if cfg.n_experts else None
+    x, kv, counters = _layers(
+        params["layers"], x, kv,
+        _Rows(token_pos, token_state, q_start, q_len, decode_rows,
+              token_page, token_slot, page_table, kv_len, max_q_len,
+              tp_axis), valid, cfg, paged_impl)
     x = _rmsnorm(x, params["final_norm"], cfg.norm_eps)
     last = jnp.clip(q_start + q_len - 1, 0, T - 1)        # [R]
     xl = x[0][last]
@@ -1041,7 +1056,7 @@ class StepPrograms:
         self.decode_layout = decode_layout(decode_rows, max_pages)
         self.step_layouts = {
             n: step_layout(decode_rows, n, max_q_len, max_pages,
-                           not prefix_cache_supported(cfg))
+                           keeps_slot_state(cfg))
             for n in self.row_shapes}
         step_statics = dict(
             layouts=tuple(self.step_layouts.values()), cfg=cfg,

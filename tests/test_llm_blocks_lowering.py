@@ -7,13 +7,20 @@ block's code: the tests here read the jaxprs of both step programs and of
 the page copy, on the kernel path and on the reference path, and the
 parameter and pool trees, and look for what only another block brings.
 
+The pool and the walk are declared ONCE each (llm/cache.py: SLOT_STATE,
+llm/model.py: OPERATORS and _pattern): the tests here hold every block's
+pool, descriptor and engine to those declarations.
+
 As a script, `python3 tests/test_llm_blocks_lowering.py [checkout]` prints
 a digest of each of those texts for the checkout given (default: this
-one). Run it on two commits and compare the lines: a PR that must leave a
-block's programs alone shows it so (text for text the same jaxpr, the
-kernels' bodies included), and its PERF.md entry keeps the finding; no
-digest is kept here, where every later change to model.py or a kernel
-would have to overwrite it.
+one), and beside each step program's the `scan` / `while` equations of
+its jaxpr, the kernels' bodies not counted: one scan is the walk over the
+layers (and one more the decode loop's steps), whatever else is there an
+operator's or a reference's own. Run it on two commits and compare the
+lines: a PR that must leave a block's programs alone shows it so (text
+for text the same jaxpr, the kernels' bodies included), and its PERF.md
+entry keeps the finding; no digest is kept here, where every later change
+to model.py or a kernel would have to overwrite it.
 """
 
 import hashlib
@@ -32,8 +39,10 @@ import jax.numpy as jnp  # noqa: E402
 import pytest  # noqa: E402
 
 from ray_tpu.llm import model as M  # noqa: E402
+from ray_tpu.llm import cache as C  # noqa: E402
 from ray_tpu.llm.cache import make_kv_cache  # noqa: E402
-from ray_tpu.models.llama import LlamaConfig, init_params  # noqa: E402
+from ray_tpu.models.llama import (LAYER_KINDS, LlamaConfig,  # noqa: E402
+                                  init_params)
 
 _LFM2_PATTERN = ["conv", "conv"] + ["full_attention", "conv", "conv",
                                     "conv"] * 2
@@ -74,15 +83,32 @@ ONLY_RETENTION = ("retention_proj", "retention_update", "retention_chunk",
                   "retention_norm", "'b_g'")
 
 
-def _text(jaxpr) -> str:
-    """The jaxpr with each equation's named scopes beside it."""
-    return jaxpr.pretty_print(name_stack=True)
+def _text(t) -> str:
+    """A jaxpr with each equation's named scopes beside it (a tree's text
+    as it is)."""
+    return t if isinstance(t, str) else t.pretty_print(name_stack=True)
 
 
-def lowered(block: str) -> dict:
-    """name -> text: the parameter tree, and for the reference and the
-    kernel path the pool's tree and the jaxprs of the mixed step, the
-    decode loop and the page copy, of ``block`` at tiny widths."""
+def loops(jaxpr) -> dict:
+    """How many `scan` and `while` equations ``jaxpr`` holds at any depth,
+    a kernel's body (`pallas_call`) not entered."""
+    n = dict(scan=0, **{"while": 0})
+    todo = [jaxpr.jaxpr]
+    while todo:
+        for eqn in todo.pop().eqns:
+            name = eqn.primitive.name
+            if name in n:
+                n[name] += 1
+            if name != "pallas_call":
+                todo += jax.core.jaxprs_in_params(eqn.params)
+    return n
+
+
+def traced(block: str) -> dict:
+    """name -> a tree's text or a jaxpr: the parameter tree, and for
+    the reference and the kernel path the pool's tree and the jaxprs of
+    the mixed step, the decode loop and the page copy, of ``block`` at
+    tiny widths."""
     cfg = LlamaConfig.tiny(**BLOCKS[block])
     params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
     out = {f"{block}.params": str(jax.tree.map(
@@ -95,20 +121,25 @@ def lowered(block: str) -> dict:
         extra = (i32(T),) if cfg.layer_types else ()
         out[f"{block}.{impl}.pool"] = str(jax.tree.map(
             lambda a: (a.shape, str(a.dtype)), kv))
-        out[f"{block}.{impl}.step"] = _text(jax.make_jaxpr(
+        out[f"{block}.{impl}.step"] = jax.make_jaxpr(
             lambda *a: M._ragged_step_body(
                 *a[:10], cfg=cfg, paged_impl=impl, max_q_len=8,
                 decode_rows=3,
                 token_state=a[10] if len(a) > 10 else None))(
             params, i32(T), i32(T), i32(T), i32(T), i32(R, mp), i32(R),
-            i32(R), i32(R), kv, *extra))
-        out[f"{block}.{impl}.loop"] = _text(jax.make_jaxpr(
+            i32(R), i32(R), kv, *extra)
+        out[f"{block}.{impl}.loop"] = jax.make_jaxpr(
             lambda p, t, pos, kv, pt, sl: M._ragged_decode_loop(
                 p, t, pos, kv, pt, sl, 4, cfg, None, impl))(
-            params, i32(3), i32(3), kv, i32(3, mp), i32(3)))
-        out[f"{block}.{impl}.copy"] = _text(jax.make_jaxpr(
-            M._copy_page_body)(kv, i32(), i32()))
+            params, i32(3), i32(3), kv, i32(3, mp), i32(3))
+        out[f"{block}.{impl}.copy"] = jax.make_jaxpr(
+            M._copy_page_body)(kv, i32(), i32())
     return out
+
+
+def lowered(block: str) -> dict:
+    """``traced`` with every jaxpr as its text."""
+    return {name: _text(t) for name, t in traced(block).items()}
 
 
 @pytest.mark.parametrize("block", sorted(BLOCKS))
@@ -153,13 +184,94 @@ def test_a_block_takes_no_other_blocks_code(block):
         assert kv["k"].shape[0] == len(cfg.layers_of("full_attention"))
 
 
+#: (leading layers, one period, periods) of each block: what the one walk
+#: runs; the Llama tree's two blocks are patterns of period 1
+PATTERNS = {
+    "mistral": ([], [("full_attention", "dense")], 2),
+    "olmoe": ([], [("full_attention", "moe")], 2),
+    "lfm2": ([("conv", "dense")] * 2,
+             [("full_attention", "moe")] + [("conv", "moe")] * 3, 2),
+    "kanana": ([("full_attention", "dense")], [("full_attention", "moe")],
+               2),
+    "granite": ([], [("mamba", "dense"), ("mamba", "dense"),
+                     ("full_attention", "dense"), ("mamba", "dense")], 2),
+    "brumby": ([], [("retention", "dense")], 2)}
+
+
+@pytest.mark.parametrize("block", sorted(BLOCKS))
+def test_every_block_is_a_pattern_of_the_one_walk(block):
+    cfg = LlamaConfig.tiny(**BLOCKS[block])
+    assert M._pattern(cfg) == PATTERNS[block]
+    # the walk finds a stack for every kind the pattern names, the Llama
+    # tree's by the split of its flat leaves: the same arrays
+    params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    stacks = M._stacks(params["layers"], cfg)
+    lead, period, _ = PATTERNS[block]
+    assert set(stacks) == {M.OPERATORS[op][0] for op, _ in lead + period} \
+        | {ffn for _, ffn in lead + period}
+    assert sorted(map(id, jax.tree.leaves(stacks))) \
+        == sorted(map(id, jax.tree.leaves(params["layers"])))
+
+
+def test_every_kind_of_layer_is_declared_once_in_each_table():
+    """A kind of layer is a body and a stack in llm/model.py's table and
+    its state a slot in llm/cache.py's: nothing else lists the kinds."""
+    assert set(M.OPERATORS) == set(C.SLOT_STATE) == set(LAYER_KINDS)
+    for kind, (stack, body) in M.OPERATORS.items():
+        assert isinstance(stack, str) and callable(body), kind
+    assert C.STATE_LEAVES == ("conv", "ssm", "ssm_conv", "retention",
+                              "retention_norm")
+
+
+@pytest.mark.parametrize("block", sorted(BLOCKS))
+def test_pool_descriptor_and_engine_follow_the_declared_state(block):
+    """The pool is the page leaves and exactly the leaves SLOT_STATE
+    declares for the kinds the block has, in their shapes and dtypes; one
+    predicate says whether there are any, and the prefix cache, the mixed
+    step's descriptor and the engine's stats all follow it."""
+    from ray_tpu.llm import InferenceEngine
+    cfg = LlamaConfig.tiny(**BLOCKS[block])
+    kinds = set(cfg.layer_types) or {"full_attention"}
+    declared = {
+        leaf: ((len(cfg.layers_of(kind)), 3 + 1) + of(cfg)[0],
+               jnp.dtype(of(cfg)[1]))
+        for kind in kinds for leaf, of in C.SLOT_STATE[kind].items()}
+    assert C.slot_state_kinds(cfg) == tuple(
+        k for k in C.SLOT_STATE if k in kinds and C.SLOT_STATE[k])
+    has_state = C.keeps_slot_state(cfg)
+    assert has_state == bool(declared) \
+        == (not C.prefix_cache_supported(cfg))
+    kv = jax.eval_shape(lambda: make_kv_cache(cfg, 16, 8, max_batch=3))
+    pages = {"k"} if cfg.kv_lora_rank else {"k", "v"}
+    assert set(kv) == pages | set(declared)
+    assert {leaf: (kv[leaf].shape, kv[leaf].dtype) for leaf in declared} \
+        == declared
+    if has_state:
+        with pytest.raises(ValueError, match="needs max_batch"):
+            make_kv_cache(cfg, 16, 8)
+    layout = dict(M.step_layout(3, 2, 8, 4, has_state))
+    fns = M.StepPrograms(cfg, decode_chunk=4, max_q_len=8, decode_rows=3,
+                         max_pages=4, kv_quantized=False, prefill_rows=2)
+    assert fns.step_layouts[2] == M.step_layout(3, 2, 8, 4, has_state)
+    assert ("token_state" in layout) == has_state
+    eng = InferenceEngine(cfg, page_size=8, total_pages=16, max_batch=3,
+                          max_seq_len=32, prefill_chunk=8, prefill_rows=2,
+                          decode_chunk=4, prefix_cache=True)
+    assert ("state_bytes" in eng.stats) == has_state
+    assert (eng.prefix is None) == has_state
+    assert eng.device_report()["state_bytes"] == sum(
+        a.nbytes for k, a in eng.kv.items() if k in declared)
+
+
 if __name__ == "__main__":
     print(json.dumps({"jax": jax.__version__, "root": ROOT}))
     for block in sorted(BLOCKS):
         try:
-            texts = lowered(block)
+            found = traced(block)
         except ValueError as e:      # a checkout from before the block
             print(block, "not built here:", str(e)[:60])
             continue
-        for name, text in texts.items():
-            print(name, hashlib.sha256(text.encode()).hexdigest()[:16])
+        for name, t in found.items():
+            print(name, hashlib.sha256(_text(t).encode()).hexdigest()[:16],
+                  *(() if isinstance(t, str) or name.endswith(".copy")
+                    else (f"{k}={n}" for k, n in loops(t).items())))

@@ -144,11 +144,12 @@ def test_absorbed_attention_equals_the_expanded_form(dtype, tol):
     kv = make_kv_cache(cfg, 8, ps)
     pos = jnp.arange(S, dtype=jnp.int32)
     pages = jnp.asarray([3, 5, 6], jnp.int32)
-    got, kv = M._latent_attention(
-        lp, 0, x, kv, cfg, pos, pages[pos // ps], pos % ps, pages[None],
-        jnp.zeros(1, jnp.int32), jnp.full(1, S, jnp.int32),
-        jnp.full(1, S, jnp.int32),
-        dict(max_q_len=S, decode_rows=0, impl="reference"))
+    rows = M._Rows(
+        pos, None, jnp.zeros(1, jnp.int32), jnp.full(1, S, jnp.int32),
+        token_page=pages[pos // ps], token_slot=pos % ps,
+        page_table=pages[None], kv_len=jnp.full(1, S, jnp.int32),
+        max_q_len=S)
+    got, kv = M._latent_attention(lp, 0, x, kv, rows, cfg, "reference")
     f32 = jnp.float32
     lp32 = {k: w.astype(f32) for k, w in lp.items()}
     with jax.default_matmul_precision("highest"):

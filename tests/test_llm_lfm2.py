@@ -193,12 +193,11 @@ def test_conv_operator_over_a_ragged_batch(dtype, within):
                 q_start[r], q_len[r] = t0, hi - lo
                 if r >= R - 2:
                     t += hi - lo
-            rows = M._ConvRows(*map(jnp.asarray, (pos, slot, q_start,
-                                                   q_len)))
-            y, state = M._short_conv(lp, 3, jnp.asarray(x, dtype)[None],
-                                     state, rows, cfg)
+            rows = M._Rows(*map(jnp.asarray, (pos, slot, q_start, q_len)))
+            y, kv = M._short_conv(lp, 3, jnp.asarray(x, dtype)[None],
+                                  {STATE_LEAF: state}, rows, cfg)
             return (y[0] - jnp.asarray(x, dtype)).astype(jnp.float32), \
-                state, q_start
+                kv[STATE_LEAF], q_start
 
         state = 9.0 + make_kv_cache(cfg, 4, 8, max_batch=4)[STATE_LEAF]
         # sequences 0 and 1 prefill into slots 0 and 1 (over stale state)
@@ -217,10 +216,10 @@ def test_conv_operator_over_a_ragged_batch(dtype, within):
         # slot 3 goes on at position 7; slot 2's sequence 3 ended)
         x = np.zeros((4, D), np.float32)
         x[0], x[1], x[3] = seqs[0][8], seqs[1][5], seqs[2][7]
-        rows = M._ConvRows(jnp.asarray([9, 6, 0, 7], jnp.int32), None,
-                           None, None)
-        y2, _ = M._short_conv(lp, 3, jnp.asarray(x, dtype)[None], state,
-                              rows, cfg)
+        rows = M._Rows(jnp.asarray([9, 6, 0, 7], jnp.int32), None, None,
+                       None)
+        y2, _ = M._short_conv(lp, 3, jnp.asarray(x, dtype)[None],
+                              {STATE_LEAF: state}, rows, cfg)
         worst = max(worst, float(jnp.abs(
             (y2[0, 3] - jnp.asarray(x[3], dtype)).astype(jnp.float32)
             - want[2][7]).max()))
@@ -497,12 +496,11 @@ def test_config_refuses_what_it_cannot_build(over, match):
 @pytest.mark.parametrize("block", ["dense", "olmoe"])
 def test_configurations_without_the_new_fields_are_untouched(block,
                                                              monkeypatch):
-    """The Llama/Mistral and OLMoE blocks build the parameter tree and the
-    step programs they built before the pattern existed: none of the new
-    code is reached, the pool has its two leaves, every layer one entry,
-    and the mixed step takes no state argument. (That the jaxprs are the
-    parent commit's, equation for equation, was checked against it when
-    the fields were added: PERF.md, PR 30.)"""
+    """The Llama/Mistral and OLMoE blocks build the parameter tree they
+    built before the pattern existed and run the one walk as a pattern of
+    period 1: no other operator's body is reached, the pool has its two
+    leaves, every layer one entry, and the mixed step takes no state
+    argument."""
     over = dict(n_layers=2, dtype=jnp.float32)
     if block == "olmoe":
         over.update(n_kv_heads=8, n_experts=E, experts_per_token=K,
@@ -511,9 +509,11 @@ def test_configurations_without_the_new_fields_are_untouched(block,
     assert not cfg.hybrid
 
     def unreachable(*a, **k):
-        raise AssertionError("the pattern's code ran for a plain block")
-    for name in ("_hybrid_layers", "_short_conv", "_pattern"):
-        monkeypatch.setattr(M, name, unreachable)
+        raise AssertionError("another block's operator ran for a plain one")
+    monkeypatch.setattr(M, "OPERATORS", {
+        kind: (stack, body if kind == "full_attention" else unreachable)
+        for kind, (stack, body) in M.OPERATORS.items()})
+    monkeypatch.setattr(M, "_latent_attention", unreachable)
     params = init_params(cfg, jax.random.PRNGKey(0))
     assert set(params["layers"]) == {
         "attn_norm", "wq", "wk", "wv", "wo", "mlp_norm", "w_gate", "w_up",
