@@ -38,6 +38,24 @@ has page leaves with no layer in them: the host's page accounting runs as
 ever over pages that hold nothing and cost nothing (a deployment sizes
 ``total_pages`` so that they never bind), and what admits a sequence is a
 free slot.
+
+A SECOND PAGE GROUP, where some layers see a window only (``WINDOW``
+layers; MiMo-V2-Flash is the first such block): their pages live in leaves
+of their own (``WINDOW_LEAVES``: "k_win" / "v_win", a second LEAF of the
+one pool pytree, not a second manager), counted by a ``PageAllocator`` of
+their own, and a sequence owns pages of each group (``SequenceState.pages``
+and ``.win_pages``). The full group holds a sequence's whole context; the
+window group returns every page that lies wholly behind the window once a
+step is booked (``window_first_page``), so it holds a constant a sequence
+however long the context, and its page table is COMPACT: a base (the
+logical page entry 0 stands for) and ``window_table_width`` entries a row,
+so that neither the table nor the descriptor grows with the context for the
+group that does not. The group is sized from the engine's own geometry
+(``window_group_pages``: what every batch slot and every chunk row can hold
+at once), so it never binds. No prefix cache with such a group
+(``prefix_cache_supported``): a hit at a page boundary would need the
+window's tokens before it, which are gone (keeping a cached boundary's last
+window of pages is open, ROADMAP Queue 2).
 """
 
 from __future__ import annotations
@@ -49,7 +67,7 @@ from typing import Dict, List, Optional, Tuple
 
 import jax.numpy as jnp
 
-from ray_tpu.models.llama import (ATTENTION, CONV, MAMBA, RETENTION,
+from ray_tpu.models.llama import (ATTENTION, CONV, MAMBA, RETENTION, WINDOW,
                                   LlamaConfig)
 from ray_tpu.ops.retention import expanded_dim
 
@@ -270,6 +288,7 @@ RET_LEAF, RET_NORM_LEAF = "retention", "retention_norm"
 #: body that reads a leaf is llm/model.py's, by the same kind.
 SLOT_STATE = {
     ATTENTION: {},          # pages, and nothing a slot
+    WINDOW: {},             # pages of the second group, nothing a slot
     CONV: {
         # the last inputs of the depthwise conv, oldest first
         STATE_LEAF: lambda c: ((c.conv_kernel - 1, c.dim), c.dtype)},
@@ -299,6 +318,40 @@ STATE_LEAVES = tuple(leaf for leaves in SLOT_STATE.values()
                      for leaf in leaves)
 
 
+#: the window layers' page leaves: the second page group's
+WINDOW_LEAVES = ("k_win", "v_win")
+
+
+def window_first_page(pos: int, window: int, page_size: int) -> int:
+    """The first logical page a token at position ``pos`` still sees with
+    a window of ``window`` positions (the token itself counted): every
+    page before it lies wholly behind the window of ``pos`` and of every
+    later position."""
+    return max(0, pos - (window - 1)) // page_size
+
+
+def window_table_width(window: int, n_tokens: int, page_size: int) -> int:
+    """Entries of a row's COMPACT window page table: a row of at most
+    ``n_tokens`` consecutive tokens reads and writes window - 1 + n_tokens
+    positions, which may start anywhere in their first page."""
+    return -(-(window - 1 + n_tokens) // page_size) + 1
+
+
+def window_group_pages(cfg: LlamaConfig, page_size: int, max_batch: int,
+                       decode_chunk: int, prefill_chunk: int,
+                       prefill_rows: int) -> int:
+    """Pages of the window group (0: the configuration has no window
+    layer): what every batch slot holds across a decode block and every
+    chunk row across its chunk, and the scratch page. A sequence between
+    two steps holds less than a decode block's, so the group never binds:
+    what admits a sequence is a slot and pages of the FULL group."""
+    if not cfg.layers_of(WINDOW):
+        return 0
+    w = cfg.sliding_window
+    return max_batch * window_table_width(w, decode_chunk, page_size) \
+        + prefill_rows * window_table_width(w, prefill_chunk, page_size) + 1
+
+
 def slot_state_kinds(cfg: LlamaConfig) -> Tuple[str, ...]:
     """The kinds of ``cfg``'s layers that keep state per batch slot."""
     return tuple(kind for kind, leaves in SLOT_STATE.items()
@@ -314,12 +367,20 @@ def keeps_slot_state(cfg: LlamaConfig) -> bool:
 
 def make_kv_cache(cfg: LlamaConfig, total_pages: int, page_size: int,
                   dtype=None, kv_dtype: Optional[str] = None,
-                  max_batch: int = 0, lane_pad: bool = False):
+                  max_batch: int = 0, lane_pad: bool = False,
+                  window_pages: int = 0):
     """Device-resident paged KV pool as a dict pytree.
 
-    {"k", "v"}: [n_attn, total_pages, Hkv, page_size, D], one entry of
-    the leading axis for each ATTENTION layer (every layer, unless the
-    configuration names others: those have no pages). With
+    {"k", "v"}: [n_attn, total_pages, Hkv, page_size, Dk | Dv], one entry
+    of the leading axis for each ATTENTION layer (every layer, unless the
+    configuration names others: those have no pages). Dk and Dv are the
+    score head's and the value head's width (``cfg.qk_head_dim``,
+    ``cfg.v_dim``: both head_dim unless the configuration says otherwise;
+    192 and 128 give K rows of 256 lanes and V rows of 128 under
+    ``lane_pad``). With WINDOW layers a SECOND PAGE GROUP rides the same
+    dict, {"k_win", "v_win"}: [n_window, window_pages, window_kv_heads,
+    page_size, Dk | Dv] (``WINDOW_LEAVES``; the module docstring says how
+    it is counted and freed; ``window_group_pages`` sizes it). With
     ``kv_dtype="int8"`` the pools are int8 and {"k_scale", "v_scale"}
     [n_layers, total_pages, Hkv, page_size] bf16 per-(page, head, slot)
     dequant scales ride alongside — one pytree, so jit donation,
@@ -384,18 +445,37 @@ def make_kv_cache(cfg: LlamaConfig, total_pages: int, page_size: int,
             (len(cfg.layers_of(ATTENTION)), total_pages, 1, page_size,
              latent_row_width(cfg, lane_pad)), dtype or cfg.dtype)}
     else:
+        def rows(width):
+            return -(-width // LANES) * LANES if lane_pad else width
+
         shape = (len(cfg.layers_of(ATTENTION)), total_pages, cfg.n_kv_heads,
-                 page_size, -(-cfg.head_dim // LANES) * LANES if lane_pad
-                 else cfg.head_dim)
+                 page_size)
+        dk, dv = rows(cfg.qk_head_dim), rows(cfg.v_dim)
+        windowed = bool(cfg.layers_of(WINDOW))
         if kv_dtype == "int8":
+            if windowed or dk != dv:
+                raise ValueError(
+                    "kv_dtype 'int8' is not built for a pool of two page "
+                    "groups or of K and V rows of different width")
             from ray_tpu.ops.int8 import KV_SCALE_DTYPE
-            kv = {"k": jnp.zeros(shape, jnp.int8),
-                  "v": jnp.zeros(shape, jnp.int8),
-                  "k_scale": jnp.zeros(shape[:-1], KV_SCALE_DTYPE),
-                  "v_scale": jnp.zeros(shape[:-1], KV_SCALE_DTYPE)}
+            kv = {"k": jnp.zeros(shape + (dk,), jnp.int8),
+                  "v": jnp.zeros(shape + (dv,), jnp.int8),
+                  "k_scale": jnp.zeros(shape, KV_SCALE_DTYPE),
+                  "v_scale": jnp.zeros(shape, KV_SCALE_DTYPE)}
         else:
             dtype = dtype or cfg.dtype
-            kv = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+            kv = {"k": jnp.zeros(shape + (dk,), dtype),
+                  "v": jnp.zeros(shape + (dv,), dtype)}
+        if windowed:
+            if window_pages < 2:
+                raise ValueError(
+                    "sliding_attention layers keep their pages in a second "
+                    "group: make_kv_cache needs window_pages "
+                    "(window_group_pages)")
+            shape = (len(cfg.layers_of(WINDOW)), window_pages,
+                     cfg.window_kv_heads, page_size)
+            kv.update(k_win=jnp.zeros(shape + (dk,), dtype),
+                      v_win=jnp.zeros(shape + (dv,), dtype))
     for kind in kinds:
         slots = (len(cfg.layers_of(kind)), max_batch + 1)
         for leaf, of in SLOT_STATE[kind].items():
@@ -413,19 +493,30 @@ def latent_row_width(cfg: LlamaConfig, lane_pad: bool = False) -> int:
 
 def prefix_cache_supported(cfg: LlamaConfig) -> bool:
     """Whether a page-aligned prefix hit restores ALL of a sequence's
-    state at that position: true where pages are the only state."""
-    return not keeps_slot_state(cfg)
+    state at that position: true where pages are the only state and no
+    page group frees behind a window (the window's tokens before a cached
+    boundary are gone)."""
+    return not keeps_slot_state(cfg) and not cfg.layers_of(WINDOW)
 
 
 def kv_cache_tag(cfg: LlamaConfig, kv_dtype: Optional[str]) -> str:
     """The PrefixCache hash seed for a pool config: pages written under
     one KV storage scheme must never match a lookup under another (a
-    latent pool's pages hold other values than a K/V pool's)."""
+    latent pool's pages hold other values than a K/V pool's; K and V rows
+    of different width, and a second page group behind a window, name
+    themselves too, though a configuration with such a group has no prefix
+    cache to seed)."""
     if kv_dtype == "int8":
         return "int8"
     name = str(jnp.dtype(cfg.dtype).name)
     if cfg.kv_lora_rank:
         return f"{name}-latent{cfg.kv_lora_rank}+{cfg.qk_rope_head_dim}"
+    if cfg.qk_head_dim != cfg.v_dim:
+        name += f"-k{cfg.qk_head_dim}v{cfg.v_dim}"
+    if cfg.layers_of(WINDOW):
+        # two groups: the full layers' pages, and the window layers' that
+        # free behind the window
+        name += f"-window{cfg.sliding_window}x{cfg.window_kv_heads}"
     return name
 
 
@@ -439,6 +530,11 @@ class SequenceState:
         self.max_new_tokens = max_new_tokens
         self.generated: List[int] = []
         self.pages: List[int] = []
+        # the window group's (a configuration with window layers): the
+        # pages of logical pages win_base .. win_base + len(win_pages) - 1;
+        # those before win_base were freed behind the window
+        self.win_pages: List[int] = []
+        self.win_base = 0
         self.slot: Optional[int] = None     # decode batch slot
         self.done = False
         self.enqueue_ts = enqueue_ts        # admission age (HOL fairness)

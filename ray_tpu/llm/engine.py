@@ -51,6 +51,16 @@ admission, scheduling), rebuilt TPU-first around ONE ragged step:
     program, dequantize inside the attention kernel;
   - pages allocate refcounted with decode headroom; under allocator
     pressure the engine LRU-evicts unreferenced cached pages;
+  - A SECOND PAGE GROUP where some layers see a window only (llm/cache.py):
+    a sequence owns pages of both groups; the window group's are taken as
+    a dispatch's tokens need them (_extend_window) and given
+    back behind the window when the dispatch is booked (_trim_window,
+    inside engine.book), so a sequence holds a constant of them however
+    long its context; its rows' page tables are COMPACT (a base and a few
+    entries: the descriptor does not grow with the context for the group
+    that does not); stats window_pages_freed, and page_steps_full /
+    page_steps_window, each group's pages in use added at every dispatch;
+    no prefix cache there; preemption stays recompute from 0;
   - ONE DESCRIPTOR a dispatch: every integer a program takes (tokens,
     positions, pages, the rows' spans, the page table) is a field of one
     flat int32 buffer kept on the host a program shape (_descriptor_turns, in
@@ -116,9 +126,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.llm.cache import (SCRATCH_PAGE, STATE_LEAVES, PageAllocator,
-                               PrefixCache, SequenceState, kv_cache_tag,
-                               prefix_cache_supported, slot_state_kinds)
+from ray_tpu.llm.cache import (SCRATCH_PAGE, STATE_LEAVES, WINDOW_LEAVES,
+                               PageAllocator, PrefixCache, SequenceState,
+                               kv_cache_tag, prefix_cache_supported,
+                               slot_state_kinds, window_first_page,
+                               window_group_pages)
 from ray_tpu.llm import model as M
 from ray_tpu.llm.tp import build_tp_mesh
 from ray_tpu.models.llama import LlamaConfig
@@ -299,13 +311,20 @@ class InferenceEngine:
             max_q_len=self.prefill_chunk, decode_rows=max_batch,
             max_pages=self.max_pages_per_seq,
             prefill_rows=self.prefill_rows,
-            kv_quantized=(self.kv_dtype == "int8"), mesh=self.mesh)
+            kv_quantized=(self.kv_dtype == "int8"), mesh=self.mesh,
+            page_size=page_size)
         # weights and pool are created IN their final layout (sharded
         # over the mesh under tp): no device ever stages the whole model
         self.params = self._fns.init_params(seed) if params is None \
             else self._fns.place_params(params)
+        # a second page group for window layers, sized from the geometry
+        # above so that it never binds (llm/cache.py); 0 = no such layer
+        window_pages = window_group_pages(
+            cfg, page_size, max_batch, self.decode_chunk,
+            self.prefill_chunk, self.prefill_rows)
+        self._window = cfg.sliding_window if window_pages else 0
         self.kv = self._fns.init_kv(total_pages, page_size, self.kv_dtype,
-                                    max_batch)
+                                    max_batch, window_pages)
         # device_report()'s sizes, taken HERE: every step donates the
         # pool, so its arrays die under a reader on another thread
         self._param_bytes = sum(x.nbytes
@@ -326,7 +345,8 @@ class InferenceEngine:
         self._kv_token_layer_bytes = sum(
             x.nbytes // (x.shape[0] * x.shape[1] * x.shape[3])
             for k, x in self.kv.items()
-            if k not in STATE_LEAVES and x.shape[0])  # no layer: no bytes
+            if k not in STATE_LEAVES + WINDOW_LEAVES
+            and x.shape[0])                           # no layer: no bytes
         self._held_bytes: Dict[int, int] = {}
         for leaf in jax.tree.leaves((self.params, self.kv)):
             for shard in leaf.addressable_shards:
@@ -338,16 +358,27 @@ class InferenceEngine:
         self._tracker = self._fns.tracker
         self._invariant_breached = False
         self.allocator = PageAllocator(total_pages)
+        # the window group's pages are counted on their own
+        self.window_allocator: Optional[PageAllocator] = \
+            PageAllocator(window_pages) if window_pages else None
         use_prefix = GlobalConfig.llm_prefix_cache \
             if prefix_cache is None else prefix_cache
         if use_prefix and not prefix_cache_supported(cfg):
             # a hit would restore the matched pages' KV and run the
-            # recurrent layers on zero state: no match is taken at all
-            logger.warning(
-                "prefix cache off: this configuration has %s layers, whose "
-                "state per batch slot (%d bytes) a page-aligned prefix hit "
-                "does not restore", " and ".join(slot_state_kinds(cfg)),
-                self._state_bytes_per_slot)
+            # recurrent layers on zero state, or the window layers on a
+            # window whose pages are gone: no match is taken at all
+            if self._window:
+                logger.warning(
+                    "prefix cache off: this configuration has window "
+                    "layers, whose page group frees behind the window of "
+                    "%d tokens", self._window)
+            else:
+                logger.warning(
+                    "prefix cache off: this configuration has %s layers, "
+                    "whose state per batch slot (%d bytes) a page-aligned "
+                    "prefix hit does not restore",
+                    " and ".join(slot_state_kinds(cfg)),
+                    self._state_bytes_per_slot)
             use_prefix = False
         self.prefix: Optional[PrefixCache] = \
             PrefixCache(self.allocator, page_size,
@@ -370,6 +401,12 @@ class InferenceEngine:
                                    SCRATCH_PAGE, np.int32)
         self._positions = np.zeros(max_batch, np.int32)
         self._tokens = np.zeros(max_batch, np.int32)
+        # ... and the decode rows' COMPACT tables in the window group, with
+        # the logical page each starts at (_sync_window keeps them)
+        self._page_table_win = np.full(
+            (max_batch, self._fns.window_pages["decode"]), SCRATCH_PAGE,
+            np.int32)
+        self._page_base_win = np.zeros(max_batch, np.int32)
         # what a dispatch sends: the decode loop's descriptor and the
         # mixed step's in each of its shapes, kept across steps
         self._decode_desc = _descriptor_turns(self._fns.decode_layout)
@@ -379,6 +416,8 @@ class InferenceEngine:
         # a mixed step's padding where it is not 0
         self._padding = {"token_page": SCRATCH_PAGE,
                          "page_table": SCRATCH_PAGE,
+                         "token_page_win": SCRATCH_PAGE,
+                         "page_table_win": SCRATCH_PAGE,
                          "token_state": max_batch}
         self.stats = {"steps": 0, "prefill_tokens": 0,
                       "decode_steps": 0, "decode_tokens": 0,
@@ -410,6 +449,13 @@ class InferenceEngine:
                 state_bytes=self._state_bytes,
                 state_bytes_per_slot=self._state_bytes_per_slot,
                 state_resets=0)
+        if self._window:
+            # pages the window group returned behind the window; and, added
+            # at every dispatch, the pages IN USE of each group (monotonic,
+            # so a window's difference is exact): their ratio is the share
+            # of one lifetime's pages that the window layers hold
+            self.stats.update(window_pages_freed=0, page_steps_full=0,
+                              page_steps_window=0)
         if cfg.kv_lora_rank:
             # a latent pool: what a token costs a layer, and the row held
             self.stats.update(
@@ -627,6 +673,62 @@ class InferenceEngine:
         if self.prefix is not None:
             self.prefix.note_release(pages)
 
+    def _release_window(self, slot: int, seq: SequenceState) -> None:
+        """Return every page ``seq`` holds in the window group, and leave
+        its slot's compact table on the scratch page from base 0 (a free
+        slot still decodes, into the page its table names)."""
+        if seq.win_pages:
+            self.window_allocator.free(seq.win_pages)
+        seq.win_pages, seq.win_base = [], 0
+        self._page_table_win[slot, :] = SCRATCH_PAGE
+        self._page_base_win[slot] = 0
+
+    def _extend_window(self, seq: SequenceState, upto: int) -> int:
+        """Window-group pages for positions up to ``upto`` (a position the
+        coming dispatch writes); how many were added. The group is sized
+        from the engine's geometry so that it cannot run out
+        (window_group_pages): running out is a fault of the accounting, and
+        raised as one."""
+        short = max(0, upto // self.page_size + 1
+                    - (seq.win_base + len(seq.win_pages)))
+        if short:
+            extra = self.window_allocator.alloc(short)
+            if extra is None:
+                raise RuntimeError(
+                    f"the window page group has {short} pages too few: "
+                    f"{self.window_allocator.num_free} free of "
+                    f"{self.window_allocator.total_pages}")
+            seq.win_pages.extend(extra)
+        return short
+
+    def _trim_window(self, seq: SequenceState, next_pos: int) -> int:
+        """Free the window-group pages that lie wholly behind the window
+        of ``next_pos``, the next position ``seq`` computes (and so of
+        every later one); how many that were."""
+        n = window_first_page(next_pos, self._window, self.page_size) \
+            - seq.win_base
+        if n <= 0:
+            return 0
+        self.window_allocator.free(seq.win_pages[:n])
+        del seq.win_pages[:n]
+        seq.win_base += n
+        self.stats["window_pages_freed"] += n
+        return n
+
+    def _sync_window(self, slot: int, seq: SequenceState) -> None:
+        """A decode row's compact table and base as ``seq`` holds them."""
+        cols = self._page_table_win.shape[1]
+        pages = seq.win_pages[:cols]
+        self._page_table_win[slot, :len(pages)] = pages
+        self._page_table_win[slot, len(pages):] = SCRATCH_PAGE
+        self._page_base_win[slot] = seq.win_base
+
+    def _book_page_steps(self) -> None:
+        """Add each group's pages in use to its counter (a dispatch)."""
+        for key, alloc in (("page_steps_full", self.allocator),
+                           ("page_steps_window", self.window_allocator)):
+            self.stats[key] += alloc.total_pages - 1 - alloc.num_free
+
     def _unmatch(self, matched_pages: List[int]) -> None:
         """Undo a PrefixCache.match whose sequence did not admit."""
         if matched_pages:
@@ -803,6 +905,18 @@ class InferenceEngine:
         state = f.get("token_state")
         if state is not None:
             state[:B] = np.where(on, self._slot_ids, B)
+        win = self._window
+        if win:
+            # the decode rows' compact tables, as kept; a token's page is
+            # its table's entry at its logical page less the row's base
+            cols = self._page_table_win.shape[1]
+            f["page_table_win"][:B, :cols] = self._page_table_win
+            f["page_table_win"][:B, cols:] = SCRATCH_PAGE
+            f["page_base_win"][:B] = self._page_base_win
+            f["token_page_win"][:B] = np.where(
+                on, self._page_table_win[self._slot_ids, np.clip(
+                    pos // ps - self._page_base_win, 0, cols - 1)],
+                SCRATCH_PAGE)
         # past the chunk rows' tokens and rows, padding: only what this
         # buffer's LAST fill wrote there is not (a fill of a whole field
         # lets the interpreter go, and in the stretch the chip waits for)
@@ -829,6 +943,17 @@ class InferenceEngine:
             f["kv_len"][r] = start + C
             if state is not None:
                 state[t0:t1] = seq.slot
+            if win:
+                # the row's table starts at the first page its first token
+                # sees (at or past what the sequence still holds)
+                base = window_first_page(start, win, ps)
+                own = np.asarray(seq.win_pages, np.int32)
+                held = own[base - seq.win_base:][
+                    :f["page_table_win"].shape[1]]
+                f["page_table_win"][r, :len(held)] = held
+                f["page_table_win"][r, len(held):] = SCRATCH_PAGE
+                f["page_base_win"][r] = base
+                f["token_page_win"][t0:t1] = own[pos // ps - seq.win_base]
             t0 = t1
         return buf
 
@@ -851,6 +976,8 @@ class InferenceEngine:
         rows = self._deal_chunk_rows()
         if not rows:
             return False
+        for seq, start, C in rows if self._window else ():
+            self._extend_window(seq, start + C - 1)    # the rows' pages
         # the smallest compiled shape that holds the deal
         n_rows = next(n for n in self._fns.row_shapes if n >= len(rows))
         R, Tcap = self._mixed_shape(n_rows)
@@ -877,6 +1004,8 @@ class InferenceEngine:
             self.stats["ragged_slot_tokens"] += Tcap
             if n_rows < self.prefill_rows:
                 self.stats["ragged_small_dispatches"] += 1
+            if self._window:
+                self._book_page_steps()
             self.stats["prefill_tokens"] += chunk_tokens
             self.stats["chunk_rows"] += len(rows)
             # a joined row starts past what its sequence has computed
@@ -900,8 +1029,13 @@ class InferenceEngine:
                     continue
                 self._tokens[slot] = tok
                 self._positions[slot] = seq.num_tokens - 1
+                if self._window \
+                        and self._trim_window(seq, seq.num_tokens - 1):
+                    self._sync_window(slot, seq)
             for j, (seq, _, C) in enumerate(rows):
                 seq.num_computed += C
+                if self._window:
+                    self._trim_window(seq, seq.num_computed)
                 if seq.record is not None:
                     seq.record.note_chunk(now, C, disp_idx)
                 if seq.num_computed >= len(seq.prompt):
@@ -956,6 +1090,8 @@ class InferenceEngine:
         self._page_table[slot, :len(pages)] = pages
         self._positions[slot] = seq.num_tokens - 1
         self._tokens[slot] = first_tok
+        if self._window:
+            self._sync_window(slot, seq)
 
     def _book_tokens(self, seq: SequenceState, toks, now: float, *,
                      mixed: bool) -> Optional[str]:
@@ -1007,6 +1143,8 @@ class InferenceEngine:
         seq.done = True
         finished[seq.request_id] = list(seq.generated)
         self._release_pages(seq.pages)
+        if self._window:
+            self._release_window(slot, seq)
         self._slots[slot] = None
         self._page_table[slot, :] = SCRATCH_PAGE
         seq.slot = None
@@ -1044,6 +1182,12 @@ class InferenceEngine:
                 return False
             self._page_table[slot, len(seq.pages)] = extra[0]
             seq.pages.extend(extra)
+        if self._window:
+            # the same positions' pages in the window group
+            upto = min(seq.num_tokens + headroom,
+                       self.max_pages_per_seq * self.page_size) - 1
+            if self._extend_window(seq, upto):
+                self._sync_window(slot, seq)
         return True
 
     #: recompute-preemptions allowed per sequence before it finishes
@@ -1077,6 +1221,8 @@ class InferenceEngine:
             seq.record.note_preempt(now)
         self._release_pages(seq.pages)
         seq.pages = []
+        if self._window:
+            self._release_window(slot, seq)
         self._slots[slot] = None
         self._page_table[slot, :] = SCRATCH_PAGE
         seq.slot = None
@@ -1121,6 +1267,8 @@ class InferenceEngine:
             self.stats["decode_tokens"] += K * len(active)
             self.stats["decode_dispatches"] += 1
             self.stats["h2d_arrays"] += 1           # its descriptor
+            if self._window:
+                self._book_page_steps()
             self._step_meta = {
                 "kind": "decode",
                 "dispatch": self.stats["decode_dispatches"],
@@ -1134,6 +1282,9 @@ class InferenceEngine:
                 else:
                     self._tokens[slot] = toks[-1]
                     self._positions[slot] = seq.num_tokens - 1
+                    if self._window \
+                            and self._trim_window(seq, seq.num_tokens - 1):
+                        self._sync_window(slot, seq)
 
     def _pack_decode(self, active: List[Tuple[int, SequenceState]],
                      ) -> np.ndarray:
@@ -1146,6 +1297,9 @@ class InferenceEngine:
         f["seq_lens"][:] = np.where(self._decode_mask(active),
                                     self._positions + 1, 1)
         f["page_table"][:] = self._page_table
+        if self._window:
+            f["page_table_win"][:] = self._page_table_win
+            f["page_base_win"][:] = self._page_base_win
         return buf
 
     def _note_counters(self, out: np.ndarray, n_tokens: int, span):

@@ -58,6 +58,14 @@ kind keeps per batch slot is declared beside it in llm/cache.py
     [D, head_dim], decayed a token by a sigmoid gate, beside its
     normaliser.
 
+  - window attention (``_window_attention``; MiMo-V2-Flash, whose full
+    layers take a score head wider than the value head, a partial rotary
+    embedding and a value scale through ``_attention``): the attention
+    operator on its own key/value heads and rotary base over a SECOND page
+    group (llm/cache.py), a token seeing the last ``sliding_window``
+    positions through its row's compact page table, a float32 sink a query
+    head in the softmax's denominator; nothing a slot.
+
 The two recurrences take the rows of a ragged batch by ONE protocol
 (``_slot_rows``): the leading one-token rows update their slots in place
 (a Pallas kernel each, ops/ssm.py, ops/retention.py), chunk rows start
@@ -111,6 +119,7 @@ schedule, riding ICI.
 from __future__ import annotations
 
 import collections
+import contextlib
 import functools
 import math
 from typing import NamedTuple, Optional, Tuple
@@ -123,8 +132,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ray_tpu.llm import tp as TP
 from ray_tpu.llm.cache import (RET_LEAF, RET_NORM_LEAF, SCRATCH_PAGE,
                                SSM_CONV_LEAF, SSM_LEAF, STATE_LEAF,
-                               STATE_LEAVES, keeps_slot_state, make_kv_cache)
-from ray_tpu.models.llama import (ATTENTION, CONV, MAMBA, RETENTION,
+                               STATE_LEAVES, WINDOW_LEAVES, keeps_slot_state,
+                               make_kv_cache, window_table_width)
+from ray_tpu.models.llama import (ATTENTION, CONV, MAMBA, RETENTION, WINDOW,
                                   LlamaConfig, Params, _rmsnorm, _rope,
                                   _rope_pairs, init_params)
 from ray_tpu.ops import moe, retention, ssm
@@ -134,8 +144,8 @@ from ray_tpu.ops.paged_attention import (kernels_supported,
 from ray_tpu.parallel.mesh import shard_map_compat
 from ray_tpu.util import compile_tracker
 
-# {"k", "v"[, "k_scale", "v_scale"][, "conv"][, "ssm", "ssm_conv"][,
-# "retention", "retention_norm"]}, or a latent pool's {"k"}
+# {"k", "v"[, "k_scale", "v_scale"][, "k_win", "v_win"][, "conv"][, "ssm",
+# "ssm_conv"][, "retention", "retention_norm"]}, or a latent pool's {"k"}
 KVCache = dict  # (llm/cache.py)
 
 
@@ -154,7 +164,7 @@ def _project_qkv(lp, h, cfg: LlamaConfig):
     """Head counts come from the (possibly tp-sliced) weight shapes, not
     cfg — under shard_map each device projects its local head shard."""
     cd = cfg.dtype
-    hd = cfg.head_dim
+    hd, vd = cfg.qk_head_dim, cfg.v_dim      # head_dim, unless fields say
     B, L, _ = h.shape
     q = h @ lp["wq"].astype(cd)
     k = h @ lp["wk"].astype(cd)
@@ -165,7 +175,7 @@ def _project_qkv(lp, h, cfg: LlamaConfig):
         k = _rmsnorm(k, lp["k_norm"], cfg.norm_eps)
     q = q.reshape(B, L, q.shape[-1] // hd, hd)
     k = k.reshape(B, L, k.shape[-1] // hd, hd)
-    v = v.reshape(B, L, v.shape[-1] // hd, hd)
+    v = v.reshape(B, L, v.shape[-1] // vd, vd)
     if cfg.qk_norm_per_head:
         # over each head's own head_dim, one [head_dim] weight for all
         q = _rmsnorm(q, lp["q_norm"], cfg.norm_eps)
@@ -195,7 +205,8 @@ def _moe_mlp(lp, experts, layer, x, valid, cfg: LlamaConfig, impl):
         experts["w_down"], cfg.experts_per_token, cfg.norm_topk_prob,
         layer=layer, impl=impl, score=cfg.router_score,
         bias=lp.get("router_bias"), eps=cfg.router_eps,
-        scale=cfg.router_scale)
+        scale=cfg.router_scale,
+        **(dict(held=cfg.experts_held) if cfg.experts_held else {}))
     y = y[None]
     if cfg.shared_ffn_dim:
         # the expert every token takes: no gate of its own, counted once
@@ -219,8 +230,12 @@ _FFN_LEAVES = ("mlp_norm", "router") + _EXPERT_LEAVES
 
 
 def step_counters(cfg: LlamaConfig) -> Tuple[str, ...]:
-    """Names of the counters the step programs append to their tokens."""
-    return moe.COUNTERS if cfg.n_experts else ()
+    """Names of the counters the step programs append to their tokens:
+    the routing counters and, where a layer holds a share of its experts,
+    the pairs routed to experts held elsewhere."""
+    if not cfg.n_experts:
+        return ()
+    return moe.COUNTERS + ((moe.COUNTER_ABSENT,) if cfg.experts_held else ())
 
 
 #: jax.named_scope names inside the step programs: they reach the device
@@ -242,6 +257,12 @@ SCOPE_SSM_PROJ, SCOPE_SSM_UPDATE, SCOPE_SSM_SCAN = \
 #: one-token update (the Pallas kernel); the chunk rows' chunk form
 SCOPE_RET_PROJ, SCOPE_RET_UPDATE, SCOPE_RET_CHUNK = \
     "retention_proj", "retention_update", "retention_chunk"
+#: ... a block with window layers: the whole window operator (inside
+#: "attention"), and of each of the two operators everything but the write
+#: and the kernel (the projections, the rotary embedding, the value scale,
+#: wo)
+SCOPE_WINDOW, SCOPE_WINDOW_PROJ, SCOPE_FULL_PROJ = \
+    "attn_window", "attn_window_proj", "attn_full_proj"
 
 
 class _Rows(NamedTuple):
@@ -251,7 +272,9 @@ class _Rows(NamedTuple):
     each token's state slot (None: token t is slot t's one token). For
     attention: each token's page and place in it, the rows' pages and
     lengths, the longest row (static), and the mesh axis its heads are
-    sharded over, if any (static: the step's ``tp_axis``)."""
+    sharded over, if any (static: the step's ``tp_axis``). For window
+    attention, the same of the second page group: each token's page there,
+    the rows' COMPACT tables and the logical page each starts at."""
     token_pos: jax.Array
     token_state: Optional[jax.Array]
     q_start: jax.Array
@@ -263,6 +286,9 @@ class _Rows(NamedTuple):
     kv_len: Optional[jax.Array] = None
     max_q_len: Optional[int] = None
     tp_axis: Optional[str] = None
+    token_page_win: Optional[jax.Array] = None
+    page_table_win: Optional[jax.Array] = None
+    page_base_win: Optional[jax.Array] = None
 
 
 def _shift(a, n: int, fill):
@@ -621,38 +647,88 @@ def _attention(lp, l, x, kv, rows: _Rows, cfg: LlamaConfig, impl):
     (x', kv)."""
     if cfg.kv_lora_rank:
         return _latent_attention(lp, l, x, kv, rows, cfg, impl)
+    return _paged_attention(lp, l, x, kv, rows, cfg, impl)
+
+
+def _window_attention(lp, l, x, kv, rows: _Rows, cfg: LlamaConfig, impl):
+    """Window attention of one layer, on entry ``l`` of the SECOND page
+    group (``WINDOW_LEAVES``; the layer's ordinal among the window layers):
+    the attention operator on its own key/value heads and rotary base, a
+    token at position t seeing t - sliding_window < s <= t of its row's
+    pages, found in the row's compact table from its base on, with the
+    layer's sinks (one float32 logit a query head) in the softmax's
+    denominator. Returns (x', kv)."""
+    with jax.named_scope(SCOPE_ATTENTION):
+        return _paged_attention(lp, l, x, kv, rows, cfg, impl, window=True)
+
+
+def _rotate(a, pos, theta, cfg: LlamaConfig):
+    """The rotary embedding of q or k [1, T, H, D]: over the whole head,
+    or over its leading ``rotary_dim`` values (half-split pairs (i, i +
+    rotary_dim / 2)), the rest passing unchanged."""
+    if not cfg.rotary_dim or cfg.rotary_dim == a.shape[-1]:
+        return _rope(a, pos, theta)
+    r = cfg.rotary_dim
+    return jnp.concatenate([_rope(a[..., :r], pos, theta), a[..., r:]],
+                           axis=-1)
+
+
+def _paged_attention(lp, l, x, kv, rows: _Rows, cfg: LlamaConfig, impl,
+                     window: bool = False):
+    """``_attention``'s body, for both page groups: the full group's
+    leaves {"k", "v"} with ``cfg.rope_theta``, or (``window``) the second
+    group's with the window kind's rotary base, its window and its sinks.
+    A block with window layers names its two operators' projections in the
+    trace (SCOPE_FULL_PROJ, SCOPE_WINDOW_PROJ; the window operator whole:
+    SCOPE_WINDOW); every other block's program is what it was."""
     cd = cfg.dtype
     T = x.shape[1]
     token_pos, q_start, q_len = rows.token_pos, rows.q_start, rows.q_len
-    with jax.named_scope(SCOPE_ATTENTION):
-        h = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
-        q, k, v = _project_qkv(lp, h, cfg)            # [1, T, H, D]
-        if cfg.rope:
-            q = _rope(q, token_pos, cfg.rope_theta)
-            k = _rope(k, token_pos, cfg.rope_theta)
+    kl, vl = WINDOW_LEAVES if window else ("k", "v")
+    named = bool(cfg.layers_of(WINDOW))
+    proj = jax.named_scope(SCOPE_WINDOW_PROJ if window else SCOPE_FULL_PROJ) \
+        if named else contextlib.nullcontext()
+    with jax.named_scope(SCOPE_WINDOW if window else SCOPE_ATTENTION):
+        with proj:
+            h = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
+            q, k, v = _project_qkv(lp, h, cfg)        # [1, T, H, D]
+            if cfg.rope:
+                theta = cfg.window_rope_theta if window else cfg.rope_theta
+                q = _rotate(q, token_pos, theta, cfg)
+                k = _rotate(k, token_pos, theta, cfg)
+            if cfg.value_scale != 1.0:
+                # on v: by linearity the same number as on the output
+                v = v * jnp.asarray(cfg.value_scale, v.dtype)
         hints = dict(layer=l, max_q_len=rows.max_q_len,
                      decode_rows=rows.decode_rows, impl=impl)
-        hd = q.shape[-1]
+        hd, vd = q.shape[-1], v.shape[-1]
         scale = dict(sm_scale=cfg.attn_scale) if cfg.attn_scale else {}
-        if kv["k"].shape[-1] != hd:
+        if kv[kl].shape[-1] != hd:
             # a pool whose rows are padded to whole lanes
             # (make_kv_cache, lane_pad): zeros past head_dim add
             # nothing to a score and come back as zeros
             q, k, v = (jnp.pad(a, ((0, 0),) * 3 + (
-                (0, kv["k"].shape[-1] - hd),)) for a in (q, k, v))
+                (0, kv[leaf].shape[-1] - a.shape[-1]),))
+                for a, leaf in ((q, kl), (k, kl), (v, vl)))
             scale = scale or dict(sm_scale=hd ** -0.5)
+        token_page, page_table = rows.token_page, rows.page_table
+        if window:
+            token_page, page_table = rows.token_page_win, rows.page_table_win
+            scale.update(window=cfg.sliding_window, sink=lp.get("sink"),
+                         page_base=rows.page_base_win)
         kc, vc, ksc, vsc = write_ragged_kv(
-            kv["k"], kv["v"], k[0], v[0], rows.token_page, rows.token_slot,
+            kv[kl], kv[vl], k[0], v[0], token_page, rows.token_slot,
             kv.get("k_scale"), kv.get("v_scale"), q_start=q_start,
             q_len=q_len, **hints)
         o = ragged_paged_attention(
-            q[0], kc, vc, rows.page_table, q_start, q_len, rows.kv_len,
+            q[0], kc, vc, page_table, q_start, q_len, rows.kv_len,
             k_scale=ksc, v_scale=vsc, **hints, **scale)
-        o = o[..., :hd].reshape(1, T, -1).astype(cd)
-        # wo is row-parallel under tp (Megatron first collective)
-        x = _residual(x, _maybe_psum(o @ lp["wo"].astype(cd), rows.tp_axis),
-                      cfg)
-    kv = {**kv, "k": kc, "v": vc}
+        with proj:
+            o = o[..., :vd].reshape(1, T, -1).astype(cd)
+            # wo is row-parallel under tp (Megatron first collective)
+            x = _residual(
+                x, _maybe_psum(o @ lp["wo"].astype(cd), rows.tp_axis), cfg)
+    kv = {**kv, kl: kc, vl: vc}
     if "k_scale" in kv:
         kv["k_scale"], kv["v_scale"] = ksc, vsc
     return x, kv
@@ -665,7 +741,8 @@ def _attention(lp, l, x, kv, rows: _Rows, cfg: LlamaConfig, impl):
 #: kind keeps in the pool beside pages is llm/cache.py's ``SLOT_STATE``,
 #: by the same key.
 OPERATORS = {ATTENTION: ("attn", _attention), CONV: ("conv", _short_conv),
-             MAMBA: ("mamba", _mamba), RETENTION: ("retention", _retention)}
+             MAMBA: ("mamba", _mamba), RETENTION: ("retention", _retention),
+             WINDOW: ("attn_window", _window_attention)}
 
 
 def _pattern(cfg: LlamaConfig):
@@ -747,7 +824,8 @@ def _layers(layers, x, kv, rows: _Rows, valid, cfg: LlamaConfig, impl):
                 first[k] += 1
         return (x, kv, counters), first
 
-    carry = (x, kv, jnp.zeros(len(moe.COUNTERS), jnp.int32))
+    carry = (x, kv, jnp.zeros(
+        len(moe.COUNTERS) + bool(cfg.experts_held), jnp.int32))
     carry, seen = run(carry, lead, dict.fromkeys((*OPERATORS, "dense",
                                                   "moe"), 0))
     per = collections.Counter(k for kinds in period for k in kinds)
@@ -766,7 +844,10 @@ def _ragged_logits(params: Params, tokens: jax.Array,
                    paged_impl: Optional[str] = None,
                    max_q_len: Optional[int] = None,
                    decode_rows: int = 0,
-                   token_state: Optional[jax.Array] = None):
+                   token_state: Optional[jax.Array] = None,
+                   token_page_win: Optional[jax.Array] = None,
+                   page_table_win: Optional[jax.Array] = None,
+                   page_base_win: Optional[jax.Array] = None):
     """ONE forward over a ragged mixed prefill+decode batch.
 
     tokens/token_pos: [T] the ragged token ids and absolute positions;
@@ -778,7 +859,10 @@ def _ragged_logits(params: Params, tokens: jax.Array,
     some layer keeps state per batch slot: each token's STATE slot (its
     sequence's batch slot; the scratch slot, max_batch, for padding).
     None there means the decode loop's layout: token t is slot t's one
-    token.
+    token. token_page_win [T], page_table_win [R, a window row's pages],
+    page_base_win [R], where some layer is a window layer: each token's
+    page in the SECOND page group, the rows' compact tables there and the
+    logical page each starts at (llm/cache.py).
 
     Returns (logits [R, vocab] float32, kv, counters): per row, the logits
     at its LAST valid token (``_ragged_forward`` takes their argmax).
@@ -800,7 +884,8 @@ def _ragged_logits(params: Params, tokens: jax.Array,
         params["layers"], x, kv,
         _Rows(token_pos, token_state, q_start, q_len, decode_rows,
               token_page, token_slot, page_table, kv_len, max_q_len,
-              tp_axis), valid, cfg, paged_impl)
+              tp_axis, token_page_win, page_table_win, page_base_win),
+        valid, cfg, paged_impl)
     x = _rmsnorm(x, params["final_norm"], cfg.norm_eps)
     last = jnp.clip(q_start + q_len - 1, 0, T - 1)        # [R]
     xl = x[0][last]
@@ -832,15 +917,17 @@ def _ragged_step_body(params: Params, tokens: jax.Array,
                       max_q_len: Optional[int] = None,
                       decode_rows: int = 0,
                       token_state: Optional[jax.Array] = None,
-                      ) -> Tuple[jax.Array, KVCache]:
+                      **window_pages) -> Tuple[jax.Array, KVCache]:
     """The mixed step's program: ``_ragged_forward``, with a step's
     counters (if its configuration has any) appended to the tokens, so
     both ride the one device->host transfer: (out [R (+ n counters)], kv).
+    ``window_pages``: the second page group's fields of the descriptor
+    (``_ragged_logits``'s token_page_win, page_table_win, page_base_win).
     """
     nxt, kv, counters = _ragged_forward(
         params, tokens, token_pos, token_page, token_slot, page_table,
         q_start, q_len, kv_len, kv, cfg, tp_axis, paged_impl, max_q_len,
-        decode_rows, token_state)
+        decode_rows, token_state, **window_pages)
     if counters is not None:
         nxt = jnp.concatenate([nxt, counters])
     return nxt, kv
@@ -851,7 +938,9 @@ def _ragged_decode_loop(params: Params, tokens: jax.Array,
                         page_table: jax.Array, seq_lens: jax.Array,
                         num_steps: int, cfg: LlamaConfig,
                         tp_axis: Optional[str] = None,
-                        paged_impl: Optional[str] = None):
+                        paged_impl: Optional[str] = None,
+                        page_table_win: Optional[jax.Array] = None,
+                        page_base_win: Optional[jax.Array] = None):
     """``num_steps`` greedy decode steps in ONE device program.
 
     The pure-decode fast path: every batch slot is one ragged decode row
@@ -868,6 +957,12 @@ def _ragged_decode_loop(params: Params, tokens: jax.Array,
     next block chains without host recomputation. With experts,
     tokens_out is flat [num_steps * B + n counters]: the tokens, then the
     dispatch's counters summed over its steps (one transfer, as above).
+
+    page_table_win [B, a window row's pages over the block] and
+    page_base_win [B], where some layer is a window layer: the slots'
+    compact tables in the second page group, which cover the window of the
+    block's first token up to its last token's page, and the logical page
+    each starts at; both stay as they are over the block's steps.
     """
     R = tokens.shape[0]
     ps = kv["k"].shape[3]
@@ -880,10 +975,17 @@ def _ragged_decode_loop(params: Params, tokens: jax.Array,
         page_idx = jnp.clip(pos // ps, 0, max_pages - 1)
         token_page = page_table[ar, page_idx]
         token_slot = pos % ps
+        window = {}
+        if page_table_win is not None:
+            at = jnp.clip(pos // ps - page_base_win, 0,
+                          page_table_win.shape[1] - 1)
+            window = dict(token_page_win=page_table_win[ar, at],
+                          page_table_win=page_table_win,
+                          page_base_win=page_base_win)
         nxt, kv, counters = _ragged_forward(
             params, tok, pos, token_page, token_slot, page_table,
             ar, ones, lens, kv, cfg, tp_axis, paged_impl,
-            max_q_len=1, decode_rows=R)
+            max_q_len=1, decode_rows=R, **window)
         out = nxt if counters is None else (nxt, counters)
         return (nxt, pos + 1, kv, lens + 1), out
 
@@ -899,30 +1001,45 @@ def _ragged_decode_loop(params: Params, tokens: jax.Array,
 #: names are the bodies' argument names
 Layout = Tuple[Tuple[str, Tuple[int, ...]], ...]
 #: the mixed step's fields with an entry a ROW; the others have one a token
-ROW_FIELDS = ("q_start", "q_len", "kv_len", "page_table")
+ROW_FIELDS = ("q_start", "q_len", "kv_len", "page_table", "page_base_win",
+              "page_table_win")
 
 
 def step_layout(decode_rows: int, chunk_rows: int, max_q_len: int,
-                max_pages: int, has_state: bool) -> Layout:
+                max_pages: int, has_state: bool,
+                window_pages: int = 0) -> Layout:
     """The mixed step's descriptor in its shape of ``chunk_rows`` chunk
     rows: what ``_ragged_step_body`` takes per token (``token_state``
     only where the block has state per batch slot), per row, and the rows'
-    page table."""
+    page table. ``window_pages`` (a block with window layers: the entries
+    of a row's compact table in the second page group): each token's page
+    there, the rows' tables and the logical page each starts at; the
+    table's width does not grow with the context."""
     R = decode_rows + chunk_rows
     T = decode_rows + chunk_rows * max_q_len
     per_token = ("tokens", "token_pos", "token_page", "token_slot") \
-        + (("token_state",) if has_state else ())
+        + (("token_state",) if has_state else ()) \
+        + (("token_page_win",) if window_pages else ())
+    per_row = ("q_start", "q_len", "kv_len") \
+        + (("page_base_win",) if window_pages else ())
     return (*((name, (T,)) for name in per_token),
-            *((name, (R,)) for name in ROW_FIELDS[:-1]),
-            (ROW_FIELDS[-1], (R, max_pages)))
+            *((name, (R,)) for name in per_row),
+            ("page_table", (R, max_pages)),
+            *((("page_table_win", (R, window_pages)),)
+              if window_pages else ()))
 
 
-def decode_layout(decode_rows: int, max_pages: int) -> Layout:
+def decode_layout(decode_rows: int, max_pages: int,
+                  window_pages: int = 0) -> Layout:
     """The decode loop's descriptor: what ``_ragged_decode_loop`` takes a
-    batch slot, and the slots' page table."""
-    return (*((name, (decode_rows,))
-              for name in ("tokens", "positions", "seq_lens")),
-            ("page_table", (decode_rows, max_pages)))
+    batch slot, and the slots' page table (with window layers: their
+    compact tables in the second group and the page each starts at)."""
+    per_slot = ("tokens", "positions", "seq_lens") \
+        + (("page_base_win",) if window_pages else ())
+    return (*((name, (decode_rows,)) for name in per_slot),
+            ("page_table", (decode_rows, max_pages)),
+            *((("page_table_win", (decode_rows, window_pages)),)
+              if window_pages else ()))
 
 
 def layout_size(layout: Layout) -> int:
@@ -980,12 +1097,16 @@ def _copy_page_body(kv: KVCache, src, dst) -> KVCache:
     """Copy-on-write: duplicate one page across all layers — pages AND
     their int8 scales, one tree_map (a prefix-hit sequence about to
     write into a shared page copies it first). The state leaves, whose
-    second axis is batch slots and not pages, pass through. Plain body
-    so StepPrograms can shard_map it over local head shards."""
+    second axis is batch slots and not pages, pass through, and so do a
+    second page group's leaves, whose pages are numbered on their own (a
+    configuration with such a group has no prefix cache, so nothing is
+    ever copied for it: every leaf comes back whole). Plain body so
+    StepPrograms can shard_map it over local head shards."""
     pages = jax.tree.map(
         lambda leaf: leaf.at[:, dst].set(
             lax.dynamic_index_in_dim(leaf, src, axis=1, keepdims=False)),
-        {k: leaf for k, leaf in kv.items() if k not in STATE_LEAVES})
+        {k: leaf for k, leaf in kv.items()
+         if k not in STATE_LEAVES + WINDOW_LEAVES})
     return {**kv, **pages}
 
 
@@ -1037,7 +1158,8 @@ class StepPrograms:
 
     def __init__(self, cfg: LlamaConfig, *, decode_chunk: int,
                  max_q_len: int, decode_rows: int, max_pages: int,
-                 kv_quantized: bool, prefill_rows: int = 1, mesh=None):
+                 kv_quantized: bool, prefill_rows: int = 1, mesh=None,
+                 page_size: int = 16):
         self.cfg = cfg
         self.mesh = mesh
         #: chunk rows of each shape the mixed step may be called in, and
@@ -1053,10 +1175,19 @@ class StepPrograms:
             None if mesh is None else mesh.devices.flat[0]) else "reference"
         #: the descriptors: the decode loop's, and the mixed step's by its
         #: chunk rows; token_state rides where a layer keeps state a slot
-        self.decode_layout = decode_layout(decode_rows, max_pages)
+        #: entries of a row's compact table in the second page group (0: no
+        #: window layer), in the mixed step and over a decode block
+        ps = page_size
+        self.window_pages = {
+            "step": window_table_width(cfg.sliding_window, max_q_len, ps),
+            "decode": window_table_width(cfg.sliding_window, decode_chunk,
+                                         ps)} \
+            if cfg.layers_of(WINDOW) else {"step": 0, "decode": 0}
+        self.decode_layout = decode_layout(decode_rows, max_pages,
+                                           self.window_pages["decode"])
         self.step_layouts = {
             n: step_layout(decode_rows, n, max_q_len, max_pages,
-                           keeps_slot_state(cfg))
+                           keeps_slot_state(cfg), self.window_pages["step"])
             for n in self.row_shapes}
         step_statics = dict(
             layouts=tuple(self.step_layouts.values()), cfg=cfg,
@@ -1140,12 +1271,13 @@ class StepPrograms:
         return jax.device_put(params, self._param_sharding)
 
     def init_kv(self, total_pages: int, page_size: int, kv_dtype,
-                max_batch: int = 0) -> KVCache:
+                max_batch: int = 0, window_pages: int = 0) -> KVCache:
         # the kernels move pages by DMA, in rows of whole lanes
         make = functools.partial(make_kv_cache, self.cfg, total_pages,
                                  page_size, kv_dtype=kv_dtype,
                                  max_batch=max_batch,
-                                 lane_pad=self.paged_impl == "kernel")
+                                 lane_pad=self.paged_impl == "kernel",
+                                 window_pages=window_pages)
         if self.mesh is None:
             return make()
         return jax.jit(make, out_shardings=self._kv_sharding)()

@@ -77,6 +77,16 @@ def tp_param_specs(cfg: LlamaConfig) -> Params:
 def validate_tp(cfg: LlamaConfig, tp: int) -> None:
     if tp < 2:
         raise ValueError(f"tp must be >= 2 for a sharded engine, got {tp}")
+    if cfg.window_block:
+        raise NotImplementedError(
+            f"tp={tp} is not served for this block: sliding_attention "
+            f"layers keep their pages in a second page group with its own "
+            f"key/value heads (window_kv_heads), for whose leaves there is "
+            f"no partition spec here, and score_head_dim / value_head_dim, "
+            f"rotary_dim, value_scale and experts_held (a share of the "
+            f"experts is the other way a layer is divided among chips: the "
+            f"exchange between the shares is not built) are refused with "
+            f"them, untested under a shard (ROADMAP R5a, R10b)")
     if cfg.beyond_llama_block:
         raise NotImplementedError(
             f"tp={tp} is not served for this block: mamba layers keep a "

@@ -36,9 +36,16 @@ from ray_tpu.parallel.ulysses import ulysses_attention_sharded
 Params = Dict[str, Any]
 
 #: a layer's operator, as the published configurations name it
-ATTENTION, CONV, MAMBA, RETENTION = \
-    "full_attention", "conv", "mamba", "retention"
-LAYER_KINDS = (ATTENTION, CONV, MAMBA, RETENTION)
+ATTENTION, CONV, MAMBA, RETENTION, WINDOW = \
+    "full_attention", "conv", "mamba", "retention", "sliding_attention"
+LAYER_KINDS = (ATTENTION, CONV, MAMBA, RETENTION, WINDOW)
+#: range of the seeded attention sinks (float32, one a query head of a
+#: window layer): a full window's 128 scores under seeded weights sum to
+#: about e^4.9, so a sink in [3, 6] takes between a sixth and three quarters
+#: of a row's mass (benchmark/configs/mimo-v2-flash-serve-1chip.json has the
+#: reading); one seeded at 0 would take under 1 % and no check could see it
+#: left out
+SINK_RANGE = (3.0, 6.0)
 #: spread of the seeded router selection bias. The top 4 of 64 sigmoid
 #: scores lie ~0.013 apart, so 0.02 changes the chosen set for about half
 #: the tokens and leaves the load near even (busiest expert 2.3 x the mean
@@ -125,9 +132,32 @@ class LlamaConfig:
     # (D = ops/retention.py:expanded_dim(head_dim)), decayed a token by a
     # gate sigmoid(h w_g + b_g), beside its normaliser.
     retention_chunk: int = 256       # tokens a block of its chunk form
+    # Attention whose score head is not its value head, nor dim / n_heads
+    # (MiMo-V2-Flash is the first such block outside the latent one: 192
+    # and 128), rotated over its leading rotary_dim values only, the values
+    # scaled. 0 / 1.0 = the Llama block's.
+    score_head_dim: int = 0          # 0 = head_dim; a q / k head's width
+    value_head_dim: int = 0          # 0 = head_dim; a v head's width
+    rotary_dim: int = 0              # 0 = the whole score head is rotated
+    value_scale: float = 1.0         # on v (by linearity: on the output)
+    # Window attention: layer_types names "sliding_attention" layers, which
+    # see the last sliding_window positions (the token itself counted), on
+    # their own number of key/value heads and their own rotary base, with a
+    # learned sink a query head in the softmax's denominator (attn_sink).
+    # Their pages are a SECOND page group that frees behind the window
+    # (llm/cache.py).
+    sliding_window: int = 0          # W: a token at t sees t - W < s <= t
+    window_kv_heads: int = 0         # key/value heads of a window layer
+    window_rope_theta: float = 0.0   # its rotary base
+    attn_sink: bool = False          # a float32 logit a head, no value
+    # The chip's share of an expert layer: (first, n) = this chip holds
+    # experts first .. first + n - 1 of n_experts. The router keeps all
+    # n_experts outputs; pairs routed elsewhere go nowhere (ops/moe.py).
+    experts_held: Tuple[int, ...] = ()
 
     def __post_init__(self):
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        object.__setattr__(self, "experts_held", tuple(self.experts_held))
         bad = sorted(set(self.layer_types) - set(LAYER_KINDS))
         if bad or (self.layer_types
                    and len(self.layer_types) != self.n_layers):
@@ -180,6 +210,52 @@ class LlamaConfig:
                     f"a head_dim of whole blocks of 8 and a chunk, got "
                     f"{self.n_heads}, {self.n_kv_heads}, {self.head_dim}, "
                     f"{self.retention_chunk}")
+        window = (self.sliding_window, self.window_kv_heads,
+                  self.window_rope_theta)
+        if WINDOW in self.layer_types:
+            if min(window) <= 0 or self.n_heads % self.window_kv_heads:
+                raise ValueError(
+                    f"sliding_attention layers need a sliding_window, "
+                    f"window_kv_heads that divide n_heads and a "
+                    f"window_rope_theta, got {window}")
+            if self.kv_lora_rank or self.qk_norm or self.qk_norm_per_head \
+                    or not self.rope or self.attn_scale or len(
+                        set(self.layer_types) - {ATTENTION, WINDOW}):
+                raise ValueError(
+                    "sliding_attention layers are built beside "
+                    "full_attention layers only, with the rotary embedding "
+                    "and the score scale of the head: not beside a latent "
+                    "pool, a q/k norm, rope=False, attn_scale, or conv, "
+                    "mamba or retention layers")
+        elif any(window) or self.attn_sink:
+            raise ValueError(
+                "sliding_window, window_kv_heads, window_rope_theta and "
+                "attn_sink describe sliding_attention layers: layer_types "
+                "names none")
+        if (self.score_head_dim or self.value_head_dim or self.rotary_dim
+                or self.value_scale != 1.0):
+            dk, dv = self.qk_head_dim, self.v_dim
+            if self.kv_lora_rank or RETENTION in self.layer_types \
+                    or self.qk_norm:
+                raise ValueError(
+                    "score_head_dim, value_head_dim, rotary_dim and "
+                    "value_scale describe per-head K and V attention with "
+                    "no norm over the whole projected vector: not a latent "
+                    "pool, retention layers or qk_norm")
+            if min(dk, dv) <= 0 or dk % 2 or self.rotary_dim % 2 \
+                    or not 0 <= self.rotary_dim <= dk:
+                raise ValueError(
+                    f"need an even score head and an even rotary_dim "
+                    f"within it, got {dk}, {dv}, {self.rotary_dim}")
+        if self.experts_held:
+            first, n = self.experts_held if len(self.experts_held) == 2 \
+                else (-1, 0)
+            if not self.n_experts or first < 0 or n < 1 \
+                    or first + n > self.n_experts or self.shared_ffn_dim:
+                raise ValueError(
+                    f"experts_held = (first, n) names a chip's share of "
+                    f"n_experts={self.n_experts} routed experts, with no "
+                    f"shared expert beside them; got {self.experts_held}")
         heads = (self.qk_nope_head_dim, self.qk_rope_head_dim,
                  self.v_head_dim)
         if self.kv_lora_rank:
@@ -205,16 +281,36 @@ class LlamaConfig:
         return self.dim // self.n_heads
 
     @property
+    def qk_head_dim(self) -> int:
+        """A q / k head's width of per-head K and V attention."""
+        return self.score_head_dim or self.head_dim
+
+    @property
+    def v_dim(self) -> int:
+        """A v head's width of per-head K and V attention."""
+        return self.value_head_dim or self.head_dim
+
+    @property
+    def window_block(self) -> bool:
+        """Window layers, head widths of their own, a partial rotary
+        embedding, a value scale, or a share of the experts (MiMo-V2-Flash
+        is the first such block)."""
+        return WINDOW in self.layer_types or bool(
+            self.score_head_dim or self.value_head_dim or self.rotary_dim
+            or self.experts_held) or self.value_scale != 1.0
+
+    @property
     def beyond_llama_block(self) -> bool:
         """Mamba or retention layers, attention without positions or with
-        a score scale of its own, or a multiplier: what llm/model.py alone
-        serves and the training forward and llm/tp.py refuse together, by
-        name."""
+        a score scale of its own, a multiplier, or the window block's
+        fields: what llm/model.py alone serves and the training forward and
+        llm/tp.py refuse together, by name."""
         return MAMBA in self.layer_types \
             or RETENTION in self.layer_types or not self.rope \
             or bool(self.attn_scale) or (
                 self.embed_scale, self.residual_scale,
-                self.logits_divisor) != (1.0, 1.0, 1.0)
+                self.logits_divisor) != (1.0, 1.0, 1.0) \
+            or self.window_block
 
     @property
     def ssm_channels(self) -> int:
@@ -311,7 +407,11 @@ def _init_hybrid_params(cfg: LlamaConfig, key: jax.Array) -> Params:
     """The tree of a block whose layers differ: ``layers`` holds one
     stack per KIND, each on its own leading axis, a layer's entry at its
     ordinal among the layers of that kind. Operators: "attn" (the
-    attention layers), "retention" (the power-retention layers: the
+    attention layers: a q / k head ``qk_head_dim`` wide and a v head
+    ``v_dim``), "attn_window" (the window layers: the same leaves on
+    window_kv_heads key/value heads and, with attn_sink, "sink" [n_heads]
+    float32, seeded in SINK_RANGE), "retention" (the power-retention
+    layers: the
     attention layers' leaves and the gate's projection w_g [d, n_kv_heads]
     with its bias b_g, float32), "conv" (the gated short convolutions: w_in
     [d, 3d] to B, C and u, the depthwise taps w_conv [taps, d], w_out)
@@ -336,9 +436,12 @@ def _init_hybrid_params(cfg: LlamaConfig, key: jax.Array) -> Params:
     absorbed form read them as they lie, with no transposed copy for XLA
     to hoist out of the step scan (PERF.md, "Left by PR 27"). A shared
     expert (shared_ffn_dim) is three more leaves of "moe", sliced by the
-    layer scan like the router; the routed experts stay closed over."""
+    layer scan like the router; the routed experts stay closed over. With
+    experts_held = (first, n) the three expert leaves hold those n experts
+    only and the router every expert's column."""
     d, L, pd = cfg.dim, cfg.n_layers, cfg.param_dtype
-    hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    hq, hd = cfg.n_heads, cfg.head_dim
+    dk, dv = cfg.qk_head_dim, cfg.v_dim
     keys = iter(jax.random.split(key, 24))
 
     def dense(*shape, fan_in=None, dtype=pd):
@@ -351,13 +454,14 @@ def _init_hybrid_params(cfg: LlamaConfig, key: jax.Array) -> Params:
                 "w_gate": dense(n, *E, d, f), "w_up": dense(n, *E, d, f),
                 "w_down": dense(n, *E, f, d)}
 
-    def qkv(n):
-        """The leaves attention and retention layers share: the four
-        projections and the q/k norm the configuration names."""
+    def qkv(n, hkv=cfg.n_kv_heads):
+        """The leaves attention, window and retention layers share: the
+        four projections (a q / k head dk wide, a v head dv) and the q/k
+        norm the configuration names."""
         stack = {
             "attn_norm": jnp.ones((n, d), pd),
-            "wq": dense(n, d, hq * hd), "wk": dense(n, d, hkv * hd),
-            "wv": dense(n, d, hkv * hd), "wo": dense(n, hq * hd, d)}
+            "wq": dense(n, d, hq * dk), "wk": dense(n, d, hkv * dk),
+            "wv": dense(n, d, hkv * dv), "wo": dense(n, hq * dv, d)}
         if cfg.qk_norm_per_head:
             stack["q_norm"] = jnp.ones((n, hd), pd)
             stack["k_norm"] = jnp.ones((n, hd), pd)
@@ -366,6 +470,7 @@ def _init_hybrid_params(cfg: LlamaConfig, key: jax.Array) -> Params:
             stack["k_norm"] = jnp.ones((n, hkv * hd), pd)
         return stack
 
+    hkv = cfg.n_kv_heads
     layers = {}
     A, C = len(cfg.layers_of(ATTENTION)), len(cfg.layers_of(CONV))
     if A and cfg.kv_lora_rank:
@@ -379,6 +484,14 @@ def _init_hybrid_params(cfg: LlamaConfig, key: jax.Array) -> Params:
             "w_uv": dense(A, hq, r, dv), "wo": dense(A, hq * dv, d)}
     elif A:
         layers["attn"] = qkv(A)
+    Wn = len(cfg.layers_of(WINDOW))
+    if Wn:
+        # the window layers: their own key/value heads and, with attn_sink,
+        # one float32 logit a query head (SINK_RANGE)
+        layers["attn_window"] = qkv(Wn, cfg.window_kv_heads)
+        if cfg.attn_sink:
+            layers["attn_window"]["sink"] = jax.random.uniform(
+                next(keys), (Wn, hq), jnp.float32, *SINK_RANGE)
     if C:
         layers["conv"] = {
             "conv_norm": jnp.ones((C, d), pd),
@@ -429,7 +542,11 @@ def _init_hybrid_params(cfg: LlamaConfig, key: jax.Array) -> Params:
             n_dense, f=cfg.dense_ffn_dim if cfg.n_experts else cfg.ffn_dim)
     if cfg.n_experts:
         M = L - n_dense
-        layers["moe"] = swiglu(M, cfg.n_experts, f=cfg.ffn_dim)
+        # a chip's share: the held experts' matrices only; the router keeps
+        # every expert's column
+        layers["moe"] = swiglu(
+            M, cfg.experts_held[1] if cfg.experts_held else cfg.n_experts,
+            f=cfg.ffn_dim)
         layers["moe"]["router"] = dense(M, d, cfg.n_experts)
         if cfg.router_bias:
             layers["moe"]["router_bias"] = ROUTER_BIAS_STD * \
@@ -447,6 +564,15 @@ def _init_hybrid_params(cfg: LlamaConfig, key: jax.Array) -> Params:
 
 
 def _require_llama_block(cfg: LlamaConfig, what: str) -> None:
+    if cfg.window_block:
+        raise NotImplementedError(
+            f"{what} is written for the Llama/Mistral block: "
+            f"sliding_attention layers (sliding_window, window_kv_heads, "
+            f"window_rope_theta, attn_sink: a mask, a sink in the softmax "
+            f"and a second page group that are CACHE-side, with no "
+            f"training attention here), score_head_dim / value_head_dim, "
+            f"rotary_dim, value_scale and experts_held (a chip's share of "
+            f"an expert layer) are served by llm/model.py only (ROADMAP R4)")
     if cfg.beyond_llama_block:
         raise NotImplementedError(
             f"{what} is written for the Llama/Mistral block: mamba layers "

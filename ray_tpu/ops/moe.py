@@ -31,6 +31,14 @@ batch:
 (``moe_pairs``, ``moe_hits``, ``moe_hot``) are reduced here, on the
 device, over valid tokens only.
 
+A chip's SHARE of the layer (``held=(first, n)``): the weights hold experts
+first .. first + n - 1 only. The router keeps every expert's output and its
+k a token, the weights are renormalised over all k chosen, and a pair whose
+expert is held elsewhere goes nowhere (the expert id past the last that
+padding has): grouping and the kernel run over the n held. Nothing stands
+in for the absent chips or their traffic; the step counts the pairs it did
+not serve (``moe_absent``) beside the ones it did.
+
 On a backend without the kernel (CPU test meshes) the tiles go through an
 einsum over gathered expert weights: the same layout, the same numerics
 contract, the portable fallback and the oracle of the kernel's tests.
@@ -52,6 +60,9 @@ from ray_tpu.ops.paged_attention import kernels_supported
 
 #: what a step program reports about its routing, in this order
 COUNTERS = ("moe_pairs", "moe_hits", "moe_hot")
+#: ... and, after them, where the layer holds a share of its experts: the
+#: pairs routed to experts it does not hold
+COUNTER_ABSENT = "moe_absent"
 
 
 #: the router's jax.named_scope: it reaches the device trace in each op's
@@ -98,20 +109,30 @@ def route(m, valid, router, top_k: int, renorm: bool, *,
         jnp.where(keep, e.astype(jnp.int32), router.shape[-1])
 
 
-def _tiling(n_pairs: int, n_experts: int, f: int) -> Tuple[int, int]:
+#: elements of one weight block [d, fb] the kernel holds at most: 1024
+#: columns at d = 2048 (4 MB in bf16; three matrices, double-buffered: 24 MB
+#: of VMEM), the shapes PERF.md's table (PR 26) was measured at
+_BLOCK_ELEMS = 2048 * 1024
+
+
+def _tiling(n_pairs: int, n_experts: int, f: int, d: int) -> Tuple[int, int]:
     """(tm, fb) from the static shapes. A row tile is the power of two
     nearest above the mean group, held to [16, 128]: 16 rows is one bf16
     sublane tile (the decode loop's groups hold ~4 pairs: a pure weight
     stream, 90 % of its roofline at 16 x 1024), and 128 is within a tenth
     of the best tile both for a mixed step full of real tokens (256 wins)
     and for one that is a quarter real (64 wins). The width block is the
-    whole expert width up to 1024: contiguous in HBM, one grid step a
-    tile. The table is in PERF.md (PR 26)."""
+    whole expert width up to 1024 columns and up to _BLOCK_ELEMS elements
+    of a [d, fb] block (model width d = 2048: the same 1024; d = 4096: 512,
+    where 1024 would be 8 MB a matrix and 48 MB of VMEM for the three,
+    double-buffered): contiguous in HBM, one grid step a tile where the
+    expert is no wider. The table, measured at d = 2048, is in PERF.md
+    (PR 26)."""
     tm = 16
     while tm < 128 and tm * n_experts < n_pairs:
         tm *= 2
     fb = f
-    while fb > 1024 and fb % 2 == 0:
+    while (fb > 1024 or d * fb > _BLOCK_ELEMS) and fb % 2 == 0:
         fb //= 2
     return tm, fb
 
@@ -239,7 +260,8 @@ def _experts_reference(x_rows, tile_expert, layer, gate, up, down, tm: int):
 
 def moe_ffn(m, valid, router, gate, up, down, top_k: int, renorm: bool, *,
             layer=None, impl: Optional[str] = None,
-            interpret: bool = False, **routing):
+            interpret: bool = False, held: Optional[Tuple[int, int]] = None,
+            **routing):
     """m [T, d] (normed hidden states), valid [T] bool -> (y [T, d] in m's
     dtype, counters [3] int32 in COUNTERS' order).
 
@@ -248,6 +270,11 @@ def moe_ffn(m, valid, router, gate, up, down, top_k: int, renorm: bool, *,
     step passes: see the module docstring). y is 0 for padding tokens.
     ``impl``: "kernel" | "reference", None = the kernel on a TPU.
     ``routing``: route's score, bias, eps and scale.
+
+    ``held`` = (first, n): the weights are [.., n, ..], experts first ..
+    first + n - 1 of the router's E (module docstring); y is then this
+    chip's part of the layer's output, and the counters are [4]: COUNTERS
+    over the held experts, then COUNTER_ABSENT.
     """
     T, d = m.shape
     if layer is None:
@@ -255,7 +282,19 @@ def moe_ffn(m, valid, router, gate, up, down, top_k: int, renorm: bool, *,
     layer = jnp.asarray(layer, jnp.int32)
     E, f = gate.shape[1], gate.shape[-1]
     w, e = route(m, valid, router, top_k, renorm, **routing)
-    tm, fb = _tiling(T * top_k, E, f)
+    if held is None:
+        tm, fb = _tiling(T * top_k, E, f, d)
+    else:
+        first, n = held
+        if n != E:
+            raise ValueError(f"held={held} names {n} experts; the weights "
+                             f"hold {E}")
+        n_routed = router.shape[-1]
+        chosen = (e < n_routed).sum()          # the valid tokens' pairs
+        e = e - first
+        e = jnp.where((e >= 0) & (e < n), e, n)
+        # the mean group is the share's: pairs * n / the router's experts
+        tm, fb = _tiling(-(-T * top_k * n // n_routed), E, f, d)
     dest, tile_expert, n_used, counts = group(e, E, tm)
     n_rows = tile_expert.shape[0] * tm
     # each row's token (row 0's for the padding inside a tile: finite)
@@ -279,5 +318,7 @@ def moe_ffn(m, valid, router, gate, up, down, top_k: int, renorm: bool, *,
     picked = jnp.where((dest < n_rows)[..., None],
                        picked.astype(jnp.float32), 0.0)
     y = (w[..., None] * picked).sum(axis=1).astype(m.dtype)
-    counters = jnp.stack([counts.sum(), (counts > 0).sum(), counts.max()])
-    return y, counters.astype(jnp.int32)
+    counters = [counts.sum(), (counts > 0).sum(), counts.max()]
+    if held is not None:
+        counters.append(chosen - counts.sum())
+    return y, jnp.stack(counters).astype(jnp.int32)

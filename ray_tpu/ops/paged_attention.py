@@ -51,6 +51,28 @@ scalar-prefetch pattern:
     lane slice of it; the write kernel moves one leaf. With K and V pools
     both kernels lower as they did before the form existed.
 
+Three STATIC options of the ragged paths, for a block whose attention
+layers differ (MiMo-V2-Flash is the first): a program that sets none lowers
+as it did before they existed.
+
+  - a WINDOW (``window=W``): a token at position t sees t - W < s <= t. A
+    tile's page walk starts at the first page that holds a visible
+    position (it already stopped at the last), the mask gets its lower
+    edge, and a tile is ONE block of ceil((W - 1 + bq) / ps) + 1 pages
+    rounded up to whole lanes, laid from that first page on. The rows'
+    page table may then be COMPACT (``page_base`` [R]: the logical page a
+    row's entry 0 stands for; the kernel subtracts it), so neither the
+    table nor the pool grows with the context (llm/cache.py: the group
+    that frees behind the window). The window form lowers under a kernel
+    name of its own (``ragged_window_kernel``);
+  - a SINK (``sink`` [Hq] float32): one logit a query head that joins the
+    running maximum and the denominator and carries no value. The kernel
+    folds it in where a tile's accumulation STARTS (m = sink, l = 1, acc =
+    0: a first key of score ``sink`` and value zero), which the running
+    rescale then treats as any other;
+  - K and V rows of different width: the two leaves' own last axes (q is
+    as wide as K, the result as wide as V).
+
 The ``*_reference`` functions are the pure-JAX gather equivalents — the
 numerics oracles and the portable fallbacks on CPU test meshes.
 """
@@ -105,7 +127,9 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
                                      max_q_len: Optional[int] = None,
                                      decode_rows: int = 0,
                                      layer=None,
-                                     v_width: Optional[int] = None
+                                     v_width: Optional[int] = None,
+                                     window: Optional[int] = None,
+                                     sink=None, page_base=None
                                      ) -> jax.Array:
     """Gather-based ragged paged attention (oracle + CPU fallback).
 
@@ -125,6 +149,11 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
     A latent pool: ``v_pages=None`` and ``v_width``; the value is the
     leading ``v_width`` values of the K row and the result [T, Hq,
     v_width].
+
+    ``window``, ``sink`` [Hq], ``page_base`` [R] (module docstring): a
+    token sees the last ``window`` positions only; the sink joins every
+    row's softmax as one more logit with no value; entry j of row r's page
+    table is the row's logical page page_base[r] + j.
     """
     T, Hq, D = q.shape
     R, max_pages = page_table.shape
@@ -144,19 +173,37 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
         vr = vr * v_scale[at].astype(jnp.float32)[..., None]
     kr = kr.transpose(0, 2, 1, 3, 4).reshape(R, Hkv, max_kv, D)
     if v_pages is not None:
-        vr = vr.transpose(0, 2, 1, 3, 4).reshape(R, Hkv, max_kv, D)
+        vr = vr.transpose(0, 2, 1, 3, 4).reshape(
+            R, Hkv, max_kv, vr.shape[-1])
     else:
         vr = kr[..., :v_width]
     Dv = vr.shape[-1]                            # the result's width
 
     out = jnp.zeros((T, Hq, Dv), jnp.float32)
     tkv = jnp.arange(max_kv)
+    if sink is not None:
+        sink = sink.astype(jnp.float32).reshape(Hkv, qpk, 1)
 
     def _safe_softmax(s):
         m = jnp.max(s, axis=-1, keepdims=True)
+        if sink is not None:
+            # one more logit a head: mass in the denominator, no value
+            m = jnp.maximum(m, sink)
+            p = jnp.where(jnp.isneginf(s), 0.0, jnp.exp(s - m))
+            return p / (p.sum(axis=-1, keepdims=True) + jnp.exp(sink - m))
         p = jnp.where(jnp.isneginf(s), 0.0,
                       jnp.exp(s - jnp.where(jnp.isneginf(m), 0.0, m)))
         return p / jnp.maximum(p.sum(axis=-1, keepdims=True), 1e-30)
+
+    def seen(vis, base):
+        """[..., max_kv] bool: the slots a token that sees ``vis`` [...]
+        positions reads, of a row whose table starts at logical page
+        ``base`` (broadcast against vis)."""
+        at = tkv if base is None else tkv + base[..., None] * ps
+        ok = at < vis[..., None]
+        if window is not None:
+            ok = ok & (at >= vis[..., None] - window)
+        return ok
 
     Rd = decode_rows
     if Rd:
@@ -164,8 +211,12 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
         qd = q[idx].reshape(Rd, Hkv, qpk, D).astype(jnp.float32)
         s = jnp.einsum("rgqd,rgtd->rgqt", qd, kr[:Rd]) * sm_scale
         vis = jnp.where(q_len[:Rd] > 0, kv_len[:Rd], 0)
-        s = jnp.where(tkv[None, None, None, :] < vis[:, None, None, None],
-                      s, _NEG_INF)
+        if window is None and page_base is None:
+            ok = tkv[None, None, None, :] < vis[:, None, None, None]
+        else:
+            ok = seen(vis, None if page_base is None else page_base[:Rd]
+                      )[:, None, None, :]
+        s = jnp.where(ok, s, _NEG_INF)
         od = jnp.einsum("rgqt,rgtd->rgqd", _safe_softmax(s), vr[:Rd])
         od = od.reshape(Rd, Hq, Dv)
         od = jnp.where((q_len[:Rd] > 0)[:, None, None], od, 0.0)
@@ -183,8 +234,12 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
         cvec = jnp.arange(C)
         vis = kv_len[Rd:, None] - q_len[Rd:, None] + cvec[None, :] + 1
         vis = jnp.where(cvec[None, :] < q_len[Rd:, None], vis, 0)
-        s = jnp.where(tkv[None, None, None, None, :]
-                      < vis[:, :, None, None, None], s, _NEG_INF)
+        if window is None and page_base is None:
+            ok = tkv[None, None, None, None, :] < vis[:, :, None, None, None]
+        else:
+            ok = seen(vis, None if page_base is None
+                      else page_base[Rd:, None])[:, :, None, None, :]
+        s = jnp.where(ok, s, _NEG_INF)
         oc = jnp.einsum("rcgqt,rgtd->rcgqd", _safe_softmax(s), vr[Rd:])
         oc = oc.reshape(-1, C, Hq, Dv)
         oc = jnp.where((cvec[None, :] < q_len[Rd:, None])[:, :, None, None],
@@ -206,7 +261,8 @@ _LATENT_WALK_UNROLL = 16
 
 
 def _ragged_tiling(n_tokens: int, q_per_kv: int, page_size: int,
-                   max_pages: int, latent_row_bytes: Optional[int] = None):
+                   max_pages: int, latent_row_bytes: Optional[int] = None,
+                   window: Optional[int] = None):
     """Static tiling of rows that hold at most ``n_tokens`` query tokens.
 
     Returns (bq, nq, mrows, bkp): a row is ``nq`` tiles of ``bq`` tokens;
@@ -233,6 +289,13 @@ def _ragged_tiling(n_tokens: int, q_per_kv: int, page_size: int,
     loop turn: 0.53 ms a call; 1024- and 4096-slot blocks 0.56 and 0.55,
     walks of 8 and 32 pages 0.54 and 0.52. The chunk tile is compute-bound
     and keeps its blocks.
+
+    ``window``: a tile's tokens see W - 1 + bq slots between them, so a
+    tile is ONE block, laid from the tile's first visible page on:
+    ceil((W - 1 + bq) / ps) + 1 pages (the span may start anywhere in its
+    first page), rounded up to whole 128-lane score columns (W = 128, ps =
+    16: 16 pages, 256 columns, for a one-token tile and for a chunk tile,
+    which holds 64 tokens).
     """
     bq = min(128, pl.cdiv(n_tokens, 8) * 8) if n_tokens > 1 else 1
     if bq * q_per_kv > 1024:
@@ -240,18 +303,27 @@ def _ragged_tiling(n_tokens: int, q_per_kv: int, page_size: int,
         # tile of 128 tokens would be a 4096-row operand and ~50 MB of
         # VMEM; 1024 rows keep it where 8 heads x 128 tokens are
         bq = max(8, 1024 // q_per_kv // 8 * 8)
+    if window is not None and bq > 64:
+        # the block is the window plus the tile: tiles of 64 tokens score
+        # 256 columns a token where tiles of 128 would score 384, in a
+        # third of the VMEM
+        bq = 64
     nq = pl.cdiv(n_tokens, bq)
     mrows = pl.cdiv(bq * q_per_kv, 16) * 16
     bk = 256 if bq > 1 else 512
     if bq == 1 and latent_row_bytes:
         bk = max(bk, _LATENT_BLOCK_BYTES // latent_row_bytes // bk * bk)
+    if window is not None:
+        lanes = pl.cdiv((pl.cdiv(window - 1 + bq, page_size) + 1)
+                        * page_size, 128) * 128
+        return bq, nq, mrows, pl.cdiv(lanes, page_size)
     bkp = max(1, min(max_pages, bk // page_size))
     return bq, nq, mrows, bkp
 
 
 def _ragged_kernel(layer_ref, q_len_ref, kv_len_ref, pt_ref,   # prefetch
-                   q_ref, k_hbm, *rest, sm_scale, row0, bq, nq,
-                   has_scales, v_width=None):
+                   *rest, sm_scale, row0, bq, nq, has_scales, v_width=None,
+                   window=None, has_sink=False):
     """One grid step = one tile: ``bq`` query tokens of ONE row against
     that row's pages, a block of ``bkp`` pages a loop turn.
 
@@ -279,7 +351,22 @@ def _ragged_kernel(layer_ref, q_len_ref, kv_len_ref, pt_ref,   # prefetch
     vector work run one after the other, so that tile walks its pages
     ``walk`` to a loop turn over the larger blocks _ragged_tiling gives
     it (the numbers are in that docstring).
+
+    ``window`` (static): one more scalar-prefetch operand, base_ref [R], the
+    logical page a row's table starts at. A tile's walk and its blocks are
+    laid from ``first``, the page that holds the first position its first
+    token sees; the mask has both edges in every block. ``has_sink``: one
+    more operand after q, sink_ref [Hkv, mrows, 128] float32 (each matmul
+    row's own head's logit over the lanes), which the running maximum and
+    the denominator START from. K and V blocks are as wide as their own
+    leaves.
     """
+    if window is not None:
+        base_ref, *rest = rest
+    q_ref, *rest = rest
+    if has_sink:
+        sink_ref, *rest = rest
+    k_hbm, *rest = rest
     latent = v_width is not None
     if not latent:
         v_hbm, *rest = rest
@@ -291,7 +378,7 @@ def _ragged_kernel(layer_ref, q_len_ref, kv_len_ref, pt_ref,   # prefetch
     else:
         o_ref, kbuf, vbuf, sem, ahead_ref, acc_ref, m_ref, l_ref = rest
         pools = ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1))
-    Hkv, mrows, _ = acc_ref.shape
+    Hkv, mrows, Dv = acc_ref.shape
     _, _, bkp, ps, D = kbuf.shape
     bk = bkp * ps
     max_pages = pt_ref.shape[1]
@@ -307,6 +394,13 @@ def _ragged_kernel(layer_ref, q_len_ref, kv_len_ref, pt_ref,   # prefetch
         n_valid = jnp.clip(q_len - off, 0, bq)
         pos0 = kv_len - q_len + off
         vis = jnp.where(n_valid > 0, pos0 + n_valid, 0)   # slots the last sees
+        if window is not None:
+            # the walk starts at the page of the first slot the FIRST
+            # token sees, and the blocks are laid from there
+            first = jnp.maximum(pos0 - (window - 1), 0) // ps
+            n_blocks = pl.cdiv(jnp.maximum(vis - first * ps, 0), bk)
+            return (row, pos0, (first, pl.cdiv(vis, ps), base_ref[row]),
+                    n_blocks, 0)
         n_blocks = pl.cdiv(vis, bk)
         return (row, pos0, jnp.minimum(pl.cdiv(vis, ps), max_pages), n_blocks,
                 jnp.clip((pos0 + 1) // bk, 0, n_blocks))
@@ -316,6 +410,8 @@ def _ragged_kernel(layer_ref, q_len_ref, kv_len_ref, pt_ref,   # prefetch
     nxt = jnp.minimum(t + 1, n_tiles - 1)
     nxt_row, _, nxt_pages, nxt_blocks, _ = tile(nxt)
     nxt_live = jnp.logical_and(t + 1 < n_tiles, nxt_blocks > 0)
+    # the first slot of block 0 (a window's blocks start at its first page)
+    org = n_pages[0] * ps if window is not None else 0
 
     # the latent one-token tile: a page is one head's 16 rows, its copy
     # as short as the scalar work that starts it, so the walk is unrolled
@@ -323,8 +419,17 @@ def _ragged_kernel(layer_ref, q_len_ref, kv_len_ref, pt_ref,   # prefetch
 
     def copy_pages(row, n_pages, b, slot, wait=False):
         """Start (or wait for) the copies of block b's live pages."""
+        if window is not None:
+            # logical pages first .. end - 1, found in the row's compact
+            # table at their distance from its base
+            first, end, base = n_pages
+            n_pages, at = end - first, first - base
+
+        def entry(i):
+            return b * bkp + i if window is None else at + b * bkp + i
+
         def page(i, _):
-            src = 0 if wait else pt_ref[row, b * bkp + i]
+            src = 0 if wait else pt_ref[row, entry(i)]
             for hbm, buf, s in pools:
                 cp = pltpu.make_async_copy(
                     hbm.at[layer, src], buf.at[slot, :, i], sem.at[s, slot])
@@ -363,8 +468,13 @@ def _ragged_kernel(layer_ref, q_len_ref, kv_len_ref, pt_ref,   # prefetch
             copy_pages(row, n_pages, 0, slot0)
 
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _MASK)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        if has_sink:
+            # a first key of score sink and value zero
+            m_ref[...] = sink_ref[...]
+            l_ref[...] = jnp.ones_like(l_ref)
+        else:
+            m_ref[...] = jnp.full_like(m_ref, _MASK)
+            l_ref[...] = jnp.zeros_like(l_ref)
         # kv slot - token, relative to the block's first slot and pos0
         rel = lax.broadcasted_iota(jnp.int32, (mrows, bk), 1) \
             - lax.broadcasted_iota(jnp.int32, (mrows, bk), 0) % bq
@@ -391,13 +501,16 @@ def _ragged_kernel(layer_ref, q_len_ref, kv_len_ref, pt_ref,   # prefetch
                 if has_scales:          # int8 values are exact in bf16
                     k, v = k.astype(jnp.float32), v.astype(jnp.float32)
                 k = k.reshape(Hkv, bk, D).astype(cdt)
-                v = v.reshape(Hkv, bk, D).astype(cdt)
+                v = v.reshape(Hkv, bk, Dv).astype(cdt)
             s = lax.dot_general(
                 q_ref[0], k, (((2,), (2,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32) * sm_scale
             if has_scales:              # dequantize the score columns
                 s = s * ks_ref[0, b]
-            if masked:
+            if window is not None:
+                edge = pos0 - org - b * bk
+                s = jnp.where((rel <= edge) & (rel > edge - window), s, _MASK)
+            elif masked:
                 s = jnp.where(rel <= pos0 - b * bk, s, _MASK)
             m_prev, l_prev = m_ref[:, :, :1], l_ref[:, :, :1]
             m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
@@ -418,7 +531,8 @@ def _ragged_kernel(layer_ref, q_len_ref, kv_len_ref, pt_ref,   # prefetch
         lax.fori_loop(n_full, n_blocks, lambda _, b: block(b, True), b)
         ahead_ref[0] = nxt_live.astype(jnp.int32)
         ahead_ref[1] = (slot0 + n_blocks) % 2
-        # slot 0 is visible to every token of a live tile: l >= 1
+        # slot 0 (a window: the token itself) is visible to every token of
+        # a live tile: l >= 1
         o_ref[0] = (acc_ref[...] / l_ref[:, :, :1]).astype(o_ref.dtype)
 
 
@@ -439,21 +553,25 @@ def _scale_blocks(scale, layer, page_table, bk: int):
 def _ragged_rows_pallas(q, k_pages, v_pages, layer, page_table, q_start,
                         q_len, kv_len, k_scale, v_scale, *, row0: int,
                         n_rows: int, n_tokens: int, sm_scale: float,
-                        interpret: bool, v_width: Optional[int] = None):
+                        interpret: bool, v_width: Optional[int] = None,
+                        window: Optional[int] = None, sink=None,
+                        page_base=None):
     """Attention of rows ``row0 : row0 + n_rows`` (each at most
     ``n_tokens`` query tokens) over layer ``layer`` ([1] int32) of the
-    stacked pool -> [n_rows * nq * bq, Hq, D], row-major by (row, token);
+    stacked pool -> [n_rows * nq * bq, Hq, Dv], row-major by (row, token);
     slots past a row's q_len hold garbage or zeros. A latent pool
-    (``v_pages`` None): the result is [..., v_width]."""
+    (``v_pages`` None): the result is [..., v_width]. ``window``, ``sink``
+    [Hq] float32 and ``page_base`` [R] int32 (with a window, always): the
+    module docstring's."""
     T, Hq, D = q.shape
     latent = v_pages is None
-    Dv = v_width if latent else D
+    Dv = v_width if latent else v_pages.shape[-1]
     _, _, Hkv, ps, _ = k_pages.shape
     max_pages = page_table.shape[1]
     qpk = Hq // Hkv
     bq, nq, mrows, bkp = _ragged_tiling(
         n_tokens, qpk, ps, max_pages,
-        D * k_pages.dtype.itemsize if latent else None)
+        D * k_pages.dtype.itemsize if latent else None, window)
     n_tiles, bk = n_rows * nq, bkp * ps
 
     # tile order: [tile, kv head, q head in group * bq + token, D]
@@ -471,8 +589,19 @@ def _ragged_rows_pallas(q, k_pages, v_pages, layer, page_table, q_start,
 
     tile_spec = pl.BlockSpec((1, Hkv, mrows, D), tile_map)
     pools = [k_pages] if latent else [k_pages, v_pages]
-    in_specs = [tile_spec] + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools)
-    operands = [qt] + pools
+    in_specs, operands = [tile_spec], [qt]
+    if sink is not None:
+        # each matmul row's own head's logit, over the lanes of the
+        # running maximum it starts: [Hkv, mrows, 128], one block for all
+        # tiles (fetched once)
+        rows = jnp.repeat(sink.astype(jnp.float32).reshape(Hkv, qpk), bq,
+                          axis=1)
+        rows = jnp.pad(rows, ((0, 0), (0, mrows - qpk * bq)))
+        in_specs.append(pl.BlockSpec((Hkv, mrows, 128),
+                                     lambda t, *_: (0, 0, 0)))
+        operands.append(jnp.broadcast_to(rows[..., None], (Hkv, mrows, 128)))
+    in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * len(pools)
+    operands += pools
     has_scales = k_scale is not None
     if has_scales:
         ks = _scale_blocks(k_scale, layer[0], page_table, bk)
@@ -480,7 +609,8 @@ def _ragged_rows_pallas(q, k_pages, v_pages, layer, page_table, q_start,
         in_specs += [pl.BlockSpec((1,) + ks.shape[1:], row_map)] * 2
         operands += [ks, vs]
 
-    kv_buf = pltpu.VMEM((2, Hkv, bkp, ps, D), k_pages.dtype)
+    kv_bufs = [pltpu.VMEM((2, Hkv, bkp, ps, a.shape[-1]), a.dtype)
+               for a in pools]
     stat = pltpu.VMEM((Hkv, mrows, 128), jnp.float32)
     # q and o tiles twice (pipelined), the page buffers, fp32 statistics,
     # and a block's scores and probabilities for all heads. A quarter of
@@ -490,16 +620,28 @@ def _ragged_rows_pallas(q, k_pages, v_pages, layer, page_table, q_start,
         + 2 * len(pools) * Hkv * bk * D * k_pages.dtype.itemsize \
         + Hkv * mrows * (2 * 128 + Dv) * 4 \
         + 3 * Hkv * mrows * max(bk, 128) * 4
+    options, prefetch = {}, [layer, q_len, kv_len, page_table]
+    if window is not None:
+        # the window form lowers under a name of its own: the readers find
+        # a kernel in the trace by it (and the other form's must not move)
+        options.update(window=window, has_sink=sink is not None)
+        prefetch.append(page_base.astype(jnp.int32))
+        if sink is not None:
+            need += 2 * Hkv * mrows * 128 * 4
+    elif sink is not None:
+        raise ValueError("a sink is built for the window form only")
+    kernel = functools.partial(_ragged_kernel, sm_scale=sm_scale, row0=row0,
+                               bq=bq, nq=nq, has_scales=has_scales,
+                               v_width=v_width, **options)
     out = pl.pallas_call(
-        functools.partial(_ragged_kernel, sm_scale=sm_scale, row0=row0,
-                          bq=bq, nq=nq, has_scales=has_scales,
-                          v_width=v_width),
+        kernel,
+        **(dict(name="ragged_window_kernel") if window is not None else {}),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=len(prefetch),
             grid=(n_tiles,),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, Hkv, mrows, Dv), tile_map),
-            scratch_shapes=[kv_buf] * len(pools) + [
+            scratch_shapes=kv_bufs + [
                 pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.SMEM((2,), jnp.int32),
                 pltpu.VMEM((Hkv, mrows, Dv), jnp.float32), stat, stat],
@@ -518,7 +660,7 @@ def _ragged_rows_pallas(q, k_pages, v_pages, layer, page_table, q_start,
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=max(need * 5 // 4, 16 << 20)),
         interpret=interpret,
-    )(layer, q_len, kv_len, page_table, *operands)
+    )(*prefetch, *operands)
     out = out[:, :, :qpk * bq].reshape(n_tiles, Hkv, qpk, bq, Dv)
     return out.transpose(0, 3, 1, 2, 4).reshape(n_tiles * bq, Hq, Dv)
 
@@ -556,14 +698,17 @@ def _token_rows(q_start, q_len, T: int):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "sm_scale", "max_q_len", "decode_rows", "interpret", "v_width"))
+    "sm_scale", "max_q_len", "decode_rows", "interpret", "v_width",
+    "window"))
 def _ragged_attention_pallas(q, k_pages, v_pages, page_table,
                              q_start, q_len, kv_len, k_scale, v_scale,
                              sm_scale: float,
                              max_q_len: Optional[int] = None,
                              decode_rows: int = 0,
                              interpret: bool = False, layer=None,
-                             v_width: Optional[int] = None):
+                             v_width: Optional[int] = None,
+                             window: Optional[int] = None, sink=None,
+                             page_base=None):
     """The blocked kernel over the static tiling the hints give: the
     first ``decode_rows`` rows as one-token tiles, the others as
     ceil(max_q_len / bq) tiles of bq tokens. XLA gathers q into tile
@@ -583,10 +728,15 @@ def _ragged_attention_pallas(q, k_pages, v_pages, page_table,
         _ragged_rows_pallas, q, k_pages, v_pages, layer, page_table,
         q_start, q_len, kv_len, k_scale, v_scale, sm_scale=sm_scale,
         interpret=interpret, v_width=v_width)
+    if window is not None:
+        if page_base is None:       # a table that starts at page 0
+            page_base = jnp.zeros(R, jnp.int32)
+        call = functools.partial(call, window=window, sink=sink,
+                                 page_base=page_base)
     owned, row, j = _token_rows(q_start, q_len, T)
-    # a latent pool's result is [T, Hq, v_width]
-    out = jnp.zeros_like(q) if v_pages is not None else jnp.zeros(
-        q.shape[:-1] + (v_width,), q.dtype)
+    # a latent pool's result is [T, Hq, v_width]; with V rows narrower
+    # than K's, [T, Hq, the V leaf's width]
+    out = jnp.zeros(q.shape[:-1] + (v_width or v_pages.shape[-1],), q.dtype)
     if R - Rd:
         o = call(row0=Rd, n_rows=R - Rd, n_tokens=C)
         slots = o.shape[0] // (R - Rd)             # a row's tiles, in tokens
@@ -618,7 +768,9 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, q_start,
                            interpret: Optional[bool] = None,
                            impl: Optional[str] = None,
                            layer=None,
-                           v_width: Optional[int] = None) -> jax.Array:
+                           v_width: Optional[int] = None,
+                           window: Optional[int] = None, sink=None,
+                           page_base=None) -> jax.Array:
     """Mixed prefill+decode attention over a ragged token batch in ONE
     dispatch. Dispatch rule (``_use_reference``): Pallas kernel on
     TPU, gather reference elsewhere; ``impl`` pins the choice
@@ -634,7 +786,18 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, q_start,
     None and ``v_width`` the value's width. k_pages has ONE kv head, a
     token's row is scored whole against q [T, Hq, D] and its leading
     ``v_width`` values are the value: the result is [T, Hq, v_width].
+
+    ``window`` (static), ``sink`` [Hq] float32, ``page_base`` [R] int32:
+    the module docstring's three options; a sink and a page base come with
+    a window. q is as wide as the K leaf's rows and the result as wide as
+    the V leaf's.
     """
+    if window is None and (sink is not None or page_base is not None):
+        raise ValueError("a sink and a compact page table (page_base) are "
+                         "built for window attention: they need a window")
+    if window is not None and (k_scale is not None or v_pages is None):
+        raise ValueError("window attention is built over fp K and V pools: "
+                         "no int8 scales, no latent pool")
     if (v_pages is None) != (v_width is not None):
         raise ValueError("a pool with no v leaf goes with a v_width (the "
                          "value is that many leading values of the K "
@@ -655,11 +818,13 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, q_start,
             q, k_pages, v_pages, page_table, q_start, q_len, kv_len,
             k_scale=k_scale, v_scale=v_scale, sm_scale=sm_scale,
             max_q_len=max_q_len, decode_rows=decode_rows, layer=layer,
-            v_width=v_width)
+            v_width=v_width, **({} if window is None else dict(
+                window=window, sink=sink, page_base=page_base)))
     return _ragged_attention_pallas(
         q, k_pages, v_pages, page_table, q_start, q_len, kv_len,
         k_scale, v_scale, sm_scale, max_q_len, decode_rows,
-        bool(interpret), layer, v_width)
+        bool(interpret), layer, v_width, **({} if window is None else dict(
+            window=window, sink=sink, page_base=page_base)))
 
 
 # --------------------------------------------------------------------------
@@ -698,7 +863,7 @@ def _kv_write_kernel(layer_ref, page_ref, lo_ref, hi_ref,   # scalar prefetch
     n = (len(refs) - 1) // 4
     imgs, outs, bufs, sem = refs[:n], refs[2 * n:3 * n], refs[3 * n:-1], \
         refs[-1]
-    G, Hkv, ps, D = bufs[0].shape
+    G = bufs[0].shape[0]
     layer, u0 = layer_ref[0], pl.program_id(0) * G
     pairs = tuple((outs[i], bufs[i], imgs[i], i) for i in range(n))
 
@@ -721,10 +886,14 @@ def _kv_write_kernel(layer_ref, page_ref, lo_ref, hi_ref,   # scalar prefetch
     each_live(functools.partial(copy, to_pool=False, wait=True))
 
     def merge(g, u):
-        slot = lax.broadcasted_iota(jnp.int32, (Hkv, ps, D), 1)
-        new = jnp.logical_and(slot >= lo_ref[u], slot < hi_ref[u])
+        news = {}                   # one mask a page shape (K's and V's)
         for _, buf, img, _ in pairs:
-            buf[g] = jnp.where(new, img[g], buf[g])
+            shape = buf.shape[1:]                       # (Hkv, ps, D)
+            if shape not in news:
+                slot = lax.broadcasted_iota(jnp.int32, shape, 1)
+                news[shape] = jnp.logical_and(slot >= lo_ref[u],
+                                              slot < hi_ref[u])
+            buf[g] = jnp.where(news[shape], img[g], buf[g])
 
     each_live(merge)
     each_live(functools.partial(copy, to_pool=True, wait=False))
@@ -772,9 +941,10 @@ def _kv_write_pallas(k_pages, v_pages, k_t, v_t, layer, token_page,
                      token_slot, q_start, q_len,
                      max_q_len: Optional[int] = None, decode_rows: int = 0,
                      interpret: bool = False):
-    """``k_t``/``v_t`` [T, Hkv, D] (already in the pool's dtype) into
-    layer ``layer`` ([1] int32) of the stacked pool, in place; a tuple of
-    the leaves written. A latent pool: ``v_pages`` and ``v_t`` None."""
+    """``k_t``/``v_t`` [T, Hkv, D] (already in the pool's dtype; each as
+    wide as its own leaf's rows) into layer ``layer`` ([1] int32) of the
+    stacked pool, in place; a tuple of the leaves written. A latent pool:
+    ``v_pages`` and ``v_t`` None."""
     T, Hkv, D = k_t.shape
     pools = [k_pages] if v_pages is None else [k_pages, v_pages]
     n = len(pools)
@@ -789,18 +959,20 @@ def _kv_write_pallas(k_pages, v_pages, k_t, v_t, layer, token_page,
     # page images, head-major like the pool: [U, Hkv, ps, D]
     imgs = [a[tok].transpose(0, 2, 1, 3) for a in (k_t, v_t)[:n]]
 
-    img_spec = pl.BlockSpec((G, Hkv, ps, D), lambda i, *_: (i, 0, 0, 0))
+    widths = [a.shape[-1] for a in pools]
+    img_specs = [pl.BlockSpec((G, Hkv, ps, w), lambda i, *_: (i, 0, 0, 0))
+                 for w in widths]
     pool_spec = pl.BlockSpec(memory_space=pl.ANY)
-    buf = pltpu.VMEM((G, Hkv, ps, D), k_pages.dtype)
+    bufs = [pltpu.VMEM((G, Hkv, ps, w), k_pages.dtype) for w in widths]
     group_bytes = G * Hkv * ps * D * k_pages.dtype.itemsize
     return pl.pallas_call(
         _kv_write_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(U // G,),
-            in_specs=[img_spec] * n + [pool_spec] * n,
+            in_specs=img_specs + [pool_spec] * n,
             out_specs=[pool_spec] * n,
-            scratch_shapes=[buf] * n + [pltpu.SemaphoreType.DMA((2,))],
+            scratch_shapes=bufs + [pltpu.SemaphoreType.DMA((2,))],
         ),
         out_shape=[jax.ShapeDtypeStruct(a.shape, a.dtype) for a in pools],
         # operands count the scalar-prefetch arrays: with K and V the

@@ -1,7 +1,7 @@
 """A block lowers to the code of its own fields and to no other block's.
 
 `LlamaConfig` is the configuration of several blocks (Mistral, OLMoE, LFM2,
-Kanana-2, granite-4.0-h, Brumby), and one decoder body in llm/model.py follows its fields. A
+Kanana-2, granite-4.0-h, Brumby, MiMo-V2-Flash), and one decoder body in llm/model.py follows its fields. A
 configuration that sets none of a block's fields must take none of that
 block's code: the tests here read the jaxprs of both step programs and of
 the page copy, on the kernel path and on the reference path, and the
@@ -70,7 +70,16 @@ BLOCKS = {
                     residual_scale=0.22, logits_divisor=8.0),
     "brumby": dict(n_layers=2, n_heads=4, n_kv_heads=2, ffn_dim=96,
                    layer_types=["retention"] * 2, qk_norm_per_head=True,
-                   tie_embeddings=False, retention_chunk=8)}
+                   tie_embeddings=False, retention_chunk=8),
+    "mimo": dict(n_layers=5, n_heads=8, n_kv_heads=2, window_kv_heads=4,
+                 ffn_dim=32, dense_ffn_dim=96, n_dense_layers=1, n_experts=16,
+                 experts_per_token=4, norm_topk_prob=True,
+                 router_score="sigmoid", router_bias=True,
+                 experts_held=(4, 8), tie_embeddings=False,
+                 layer_types=["full_attention", "sliding_attention"] * 2
+                 + ["full_attention"], score_head_dim=24, value_head_dim=16,
+                 rotary_dim=8, value_scale=0.707, sliding_window=16,
+                 window_rope_theta=1e4, attn_sink=True)}
 
 #: what only a block's own fields may bring into a program's text or
 #: trees: named scopes, parameter leaves, and the shape of the pool
@@ -81,6 +90,10 @@ ONLY_SSM = ("ssm_proj", "ssm_update", "ssm_scan", "w_xbc", "A_log",
             "ssm_conv")
 ONLY_RETENTION = ("retention_proj", "retention_update", "retention_chunk",
                   "retention_norm", "'b_g'")
+ONLY_WINDOW = ("attn_window", "attn_full_proj", "k_win", "v_win", "'sink'",
+               "ragged_window_kernel")
+#: entries of a window row's compact table at the sizes traced below
+_WINDOW_PAGES = 4
 
 
 def _text(t) -> str:
@@ -115,12 +128,31 @@ def traced(block: str) -> dict:
         lambda a: (a.shape, str(a.dtype)), params))}
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)        # noqa: E731
     T, R, mp = 12, 5, 4
+    windowed = "sliding_attention" in cfg.layer_types
     for impl in ("reference", "kernel"):
         kv = jax.eval_shape(lambda: make_kv_cache(
-            cfg, 16, 8, max_batch=3, lane_pad=impl == "kernel"))
-        extra = (i32(T),) if cfg.layer_types else ()
+            cfg, 16, 8, max_batch=3, lane_pad=impl == "kernel",
+            **(dict(window_pages=9) if windowed else {})))
+        extra = (i32(T),) if cfg.layer_types and not windowed else ()
         out[f"{block}.{impl}.pool"] = str(jax.tree.map(
             lambda a: (a.shape, str(a.dtype)), kv))
+        if windowed:
+            # the second page group's fields ride the descriptor
+            out[f"{block}.{impl}.step"] = jax.make_jaxpr(
+                lambda *a: M._ragged_step_body(
+                    *a[:10], cfg=cfg, paged_impl=impl, max_q_len=8,
+                    decode_rows=3, token_page_win=a[10],
+                    page_table_win=a[11], page_base_win=a[12]))(
+                params, i32(T), i32(T), i32(T), i32(T), i32(R, mp), i32(R),
+                i32(R), i32(R), kv, i32(T), i32(R, _WINDOW_PAGES), i32(R))
+            out[f"{block}.{impl}.loop"] = jax.make_jaxpr(
+                lambda p, t, pos, kv, pt, sl, wt, wb: M._ragged_decode_loop(
+                    p, t, pos, kv, pt, sl, 4, cfg, None, impl, wt, wb))(
+                params, i32(3), i32(3), kv, i32(3, mp), i32(3),
+                i32(3, _WINDOW_PAGES), i32(3))
+            out[f"{block}.{impl}.copy"] = jax.make_jaxpr(
+                M._copy_page_body)(kv, i32(), i32())
+            continue
         out[f"{block}.{impl}.step"] = jax.make_jaxpr(
             lambda *a: M._ragged_step_body(
                 *a[:10], cfg=cfg, paged_impl=impl, max_q_len=8,
@@ -165,8 +197,10 @@ def test_a_block_takes_no_other_blocks_code(block):
     absent = ONLY_LATENT + ONLY_SHARED \
         + (() if "conv" in cfg.layer_types else ONLY_CONV) \
         + (() if "mamba" in cfg.layer_types else ONLY_SSM) \
-        + (() if "retention" in cfg.layer_types else ONLY_RETENTION)
-    for kind, words in (("mamba", ONLY_SSM), ("retention", ONLY_RETENTION)):
+        + (() if "retention" in cfg.layer_types else ONLY_RETENTION) \
+        + (() if "sliding_attention" in cfg.layer_types else ONLY_WINDOW)
+    for kind, words in (("mamba", ONLY_SSM), ("retention", ONLY_RETENTION),
+                        ("sliding_attention", ONLY_WINDOW)):
         if kind in cfg.layer_types:
             # the control for the block's own words
             missing = [w for w in words if w not in everything]
@@ -175,6 +209,10 @@ def test_a_block_takes_no_other_blocks_code(block):
         found = [word for word in absent if word in text]
         assert not found, f"{name} holds {found}"
     for impl in ("reference", "kernel"):
+        if "sliding_attention" in cfg.layer_types:
+            # two widths and two groups: held to their shapes in
+            # tests/test_llm_mimo.py
+            continue
         kv = jax.eval_shape(lambda: make_kv_cache(
             cfg, 16, 8, max_batch=3, lane_pad=impl == "kernel"))
         assert kv["k"].shape == kv["v"].shape
@@ -195,7 +233,9 @@ PATTERNS = {
                2),
     "granite": ([], [("mamba", "dense"), ("mamba", "dense"),
                      ("full_attention", "dense"), ("mamba", "dense")], 2),
-    "brumby": ([], [("retention", "dense")], 2)}
+    "brumby": ([], [("retention", "dense")], 2),
+    "mimo": ([("full_attention", "dense")],
+             [("sliding_attention", "moe"), ("full_attention", "moe")], 2)}
 
 
 @pytest.mark.parametrize("block", sorted(BLOCKS))
@@ -239,10 +279,15 @@ def test_pool_descriptor_and_engine_follow_the_declared_state(block):
     assert C.slot_state_kinds(cfg) == tuple(
         k for k in C.SLOT_STATE if k in kinds and C.SLOT_STATE[k])
     has_state = C.keeps_slot_state(cfg)
-    assert has_state == bool(declared) \
-        == (not C.prefix_cache_supported(cfg))
-    kv = jax.eval_shape(lambda: make_kv_cache(cfg, 16, 8, max_batch=3))
-    pages = {"k"} if cfg.kv_lora_rank else {"k", "v"}
+    windowed = "sliding_attention" in kinds
+    assert has_state == bool(declared)
+    # pages are the only state, and none of them frees behind a window
+    assert C.prefix_cache_supported(cfg) == (not has_state and not windowed)
+    kv = jax.eval_shape(lambda: make_kv_cache(
+        cfg, 16, 8, max_batch=3, **(dict(window_pages=9) if windowed
+                                    else {})))
+    pages = {"k"} if cfg.kv_lora_rank else {"k", "v"} \
+        | (set(C.WINDOW_LEAVES) if windowed else set())
     assert set(kv) == pages | set(declared)
     assert {leaf: (kv[leaf].shape, kv[leaf].dtype) for leaf in declared} \
         == declared
@@ -251,14 +296,20 @@ def test_pool_descriptor_and_engine_follow_the_declared_state(block):
             make_kv_cache(cfg, 16, 8)
     layout = dict(M.step_layout(3, 2, 8, 4, has_state))
     fns = M.StepPrograms(cfg, decode_chunk=4, max_q_len=8, decode_rows=3,
-                         max_pages=4, kv_quantized=False, prefill_rows=2)
-    assert fns.step_layouts[2] == M.step_layout(3, 2, 8, 4, has_state)
+                         max_pages=4, kv_quantized=False, prefill_rows=2,
+                         page_size=8)
+    assert fns.step_layouts[2] == M.step_layout(
+        3, 2, 8, 4, has_state, fns.window_pages["step"])
+    assert bool(fns.window_pages["step"]) == windowed \
+        == ("page_table_win" in dict(fns.decode_layout))
     assert ("token_state" in layout) == has_state
     eng = InferenceEngine(cfg, page_size=8, total_pages=16, max_batch=3,
                           max_seq_len=32, prefill_chunk=8, prefill_rows=2,
                           decode_chunk=4, prefix_cache=True)
     assert ("state_bytes" in eng.stats) == has_state
-    assert (eng.prefix is None) == has_state
+    assert ("page_steps_window" in eng.stats) == windowed \
+        == (eng.window_allocator is not None)
+    assert (eng.prefix is None) == (has_state or windowed)
     assert eng.device_report()["state_bytes"] == sum(
         a.nbytes for k, a in eng.kv.items() if k in declared)
 

@@ -115,9 +115,9 @@ def test_moe_ffn_is_dropless_a_token_ignores_its_batch():
 
 
 def test_tiling_follows_the_static_shapes():
-    assert moe._tiling(32 * 8, 64, 1024) == (16, 1024)      # decode loop
-    assert moe._tiling(1056 * 8, 64, 1024)[0] == 128        # mixed step
-    assert moe._tiling(40 * 2, 8, 128) == (16, 128)
+    assert moe._tiling(32 * 8, 64, 1024, 2048) == (16, 1024)      # decode loop
+    assert moe._tiling(1056 * 8, 64, 1024, 2048)[0] == 128        # mixed step
+    assert moe._tiling(40 * 2, 8, 128, 64) == (16, 128)
 
 
 # ------------------------------------------------------------ the engine

@@ -206,24 +206,32 @@ def _compile_step_program(chip, cfg, program, **sizes):
 
 
 def _compile_step_program_once(chip, cfg, program, *, max_batch, pages,
-                               max_seq, rows=2, chunk=512, ps=16):
+                               max_seq, rows=2, chunk=512, ps=16,
+                               pool_rows=None):
     """One of the engine's step programs, compiled from shapes
     (jax.eval_shape: no weights exist): the mixed step over max_batch
     decode rows + ``rows`` chunks of ``chunk`` (one of its shapes,
     llm/model.py:chunk_row_shapes), or the 8-step decode loop.
+    ``pool_rows``: the chunk rows the engine's pool is sized for where
+    that is not ``rows`` (a window group holds what every shape's rows can:
+    the smaller shapes run over the full shape's pool).
     Returns (compiled, the pool's abstract pytree, rows of the result)."""
     from ray_tpu.llm import model as M
-    from ray_tpu.llm.cache import make_kv_cache
+    from ray_tpu.llm.cache import make_kv_cache, window_group_pages
     from ray_tpu.models.llama import init_params
     params = _abstract(chip, functools.partial(init_params, cfg,
                                                jax.random.PRNGKey(0)))
-    # the pool the kernels take (StepPrograms.init_kv on a TPU)
+    # the pool the kernels take (StepPrograms.init_kv on a TPU); a second
+    # page group where the configuration has window layers, at the
+    # engine's own size
     kv = _abstract(chip, functools.partial(
-        make_kv_cache, cfg, pages, ps, max_batch=max_batch, lane_pad=True))
+        make_kv_cache, cfg, pages, ps, max_batch=max_batch, lane_pad=True,
+        window_pages=window_group_pages(cfg, ps, max_batch, 8, chunk,
+                                        pool_rows or rows)))
     # the engine's own seam over these sizes: its layouts and statics
     fns = M.StepPrograms(cfg, decode_chunk=8, max_q_len=chunk,
                          decode_rows=max_batch, max_pages=max_seq // ps,
-                         kv_quantized=False, prefill_rows=rows)
+                         kv_quantized=False, prefill_rows=rows, page_size=ps)
     name, layout, n_out = {
         "mixed": ("ragged_step", fns.step_layouts[rows], max_batch + rows),
         "decode": ("decode_loop", fns.decode_layout, 8 * max_batch)}[program]
@@ -536,6 +544,61 @@ def _kanana_cfg(n_layers=2):
     return LlamaConfig.tiny(**{**fields, "n_layers": n_layers})
 
 
+def _mimo_cfg(n_layers=7):
+    """mimo-v2-flash-serve-1chip's widths from its own file: the dense
+    full-attention layer and one whole period (five window layers and a
+    full one), 16 of 256 experts held, an eighth of the vocabulary."""
+    import json
+    import os
+
+    from benchmark.runners import serve_mimo
+    from ray_tpu.models.llama import LlamaConfig
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "mimo-v2-flash-serve-1chip.json")) as f:
+        config = json.load(f)
+    return LlamaConfig.tiny(**serve_mimo.model_fields(
+        {**config, "num_hidden_layers": n_layers}))
+
+
+_MIMO_SIZES = dict(max_batch=96, pages=12800, max_seq=19456, ps=64,
+                   pool_rows=2)
+
+
+@pytest.mark.parametrize("program", ["mixed", "decode"])
+def test_mimo_step_programs_compile_at_benchmark_shapes(chip, program):
+    """mimo-v2-flash-serve-1chip's two step programs at its published
+    widths and its whole cut (7 layers): Mosaic takes the ragged kernel at
+    16 query heads a key/value head over K rows of 256 lanes and V rows of
+    128, the window form (ONE block a tile from the tile's first visible
+    page on, a compact table read at its distance from the row's base, the
+    sink's block fetched once) under a name of its own, the write of two
+    leaves of different width, and the expert kernel over the 16 held
+    experts at d = 4096 in width blocks of 512. Both page groups aliased
+    from argument to result, and both programs' peak (arguments +
+    temporaries; the configuration file keeps the numbers) fits the chip
+    beside the reference's scoring. 96 decode rows, 2 chunks of 512, 12800
+    pages of 64 and the window group's 407."""
+    compiled, kv, rows = _compile_step_program(chip, _mimo_cfg(), program,
+                                               **_MIMO_SIZES)
+    assert kv["k"].shape == (2, 12800, 4, 64, 256)
+    assert kv["v"].shape == (2, 12800, 4, 64, 128)
+    assert kv["k_win"].shape == (5, 407, 8, 64, 256)
+    assert kv["v_win"].shape == (5, 407, 8, 64, 128)
+    text = compiled.as_text()
+    assert "ragged_window_kernel" in text and "_moe_experts_pallas" in text
+    # 4 counters: the routing's three and the pairs held elsewhere
+    assert jax.tree.leaves(compiled.out_info)[0].shape == (rows + 4,)
+    mem = compiled.memory_analysis()
+    held = sum(_bytes_of(f"bf16[{','.join(map(str, a.shape))}]")
+               for a in kv.values())
+    assert mem.alias_size_in_bytes >= held
+    peak = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    print(f"mimo {program}: arguments {mem.argument_size_in_bytes / 1e9:.3f}"
+          f" GB, temporaries {mem.temp_size_in_bytes / 1e9:.3f} GB")
+    assert peak < 13.6e9      # + the reference's 1.93 GB: under 15.5
+
+
 #: configuration -> (its widths, the sizes of its full mixed-step shape):
 #: benchmark/configs/*.json's engine settings
 _MIXED = {
@@ -546,7 +609,8 @@ _MIXED = {
     "granite": (_granite_cfg, dict(max_batch=128, pages=10752,
                                    max_seq=3072)),
     "brumby": (_brumby_cfg, dict(max_batch=32, pages=19457, max_seq=9728,
-                                 rows=1, chunk=1024))}
+                                 rows=1, chunk=1024)),
+    "mimo": (_mimo_cfg, _MIMO_SIZES)}
 
 
 @pytest.mark.parametrize("widths", sorted(_MIXED))
@@ -568,7 +632,7 @@ def test_one_row_mixed_step_compiles_at_benchmark_shapes(chip, widths):
                                          **{**sizes, "rows": 1})
     assert rows == sizes["max_batch"] + 1 <= full_rows
     assert (rows == full_rows) == (widths == "brumby")
-    counters = 3 if cfg.n_experts else 0
+    counters = (3 + bool(cfg.experts_held)) if cfg.n_experts else 0
     assert jax.tree.leaves(one.out_info)[0].shape == (rows + counters,)
     assert one.as_text().count("tpu_custom_call") \
         == full.as_text().count("tpu_custom_call") > 0
