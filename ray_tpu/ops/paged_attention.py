@@ -260,16 +260,27 @@ _LATENT_BLOCK_BYTES = 2048 * 1280
 _LATENT_WALK_UNROLL = 16
 
 
+#: the chunk tile of a table that reaches far (``max_pages * page_size``
+#: slots or more): its KV block doubles until a slot row of the block meets
+#: this many values of K and V (a latent tile's 256 slots of 640 + 512), as
+#: long as the block's float32 scores fit this much VMEM (PERF.md, PR 48)
+_LONG_REACH = 8192
+_CHUNK_BLOCK_VALUES = 256 * (640 + 512)
+_SCORES_VMEM = 16 << 20
+
+
 def _ragged_tiling(n_tokens: int, q_per_kv: int, page_size: int,
                    max_pages: int, latent_row_bytes: Optional[int] = None,
-                   window: Optional[int] = None):
+                   window: Optional[int] = None, kv_heads: int = 1,
+                   kv_width: Optional[int] = None):
     """Static tiling of rows that hold at most ``n_tokens`` query tokens.
 
     Returns (bq, nq, mrows, bkp): a row is ``nq`` tiles of ``bq`` tokens;
     a tile's queries of one KV head are one ``[mrows, D]`` matmul operand
     (``bq * q_per_kv`` rows, head-major, padded to the bf16 sublane
     packing); a KV block is ``bkp`` pages. Chunk rows take MXU-sized
-    tiles of 128 tokens against blocks of 256 kv slots; one-token rows
+    tiles of 128 tokens against blocks of 256 kv slots (more under a long
+    table, below); one-token rows
     take blocks of 512 and fewer loop turns. Measured on a v5e at
     Mistral-7B head shapes (PERF.md, PR 25): 128- and 512-slot blocks for
     chunks and 256- and 1024-slot blocks for one-token rows were within a
@@ -289,6 +300,33 @@ def _ragged_tiling(n_tokens: int, q_per_kv: int, page_size: int,
     loop turn: 0.53 ms a call; 1024- and 4096-slot blocks 0.56 and 0.55,
     walks of 8 and 32 pages 0.54 and 0.52. The chunk tile is compute-bound
     and keeps its blocks.
+
+    ``kv_heads``, ``kv_width`` (the values of K and of V a slot and head
+    brings to the two products) size the CHUNK tile's block where the table
+    reaches _LONG_REACH slots or more. A loop turn's fixed cost there is the
+    float32 accumulator's rescale and the two running statistics kept
+    across lanes, ``kv_heads * mrows`` rows of each, whatever the block;
+    the turn's products grow with ``bk * kv_width``. MiMo-V2-Flash's full
+    layers (4 KV heads x 1024 operand rows, 256 + 128 values, prefixes of
+    thousands) ran blocks of 256 slots at 29-31 % of the MXU's peak, the
+    same time with the page copies taken out (my chip runs, PR 48: the tile
+    alone at 2 rows x 512 tokens on prefixes of 2300 + 3400 / 0 + 9000 /
+    12000 + 512 slots: 2.75 / 3.99 / 5.30 ms a call). Blocks of 512: 2.12 /
+    3.00 / 3.95; **1024: 1.73 / 2.16 / 2.62 (kept: 46-64 % of the peak)**;
+    2048 ([4, 1024, 2048] scores, 32 MB): 1.96 / 2.56 / 3.05; the heads
+    taken in turn inside a block of 1024: 1.81 / 2.36 / 2.84. So a block
+    doubles until a slot row of it holds _CHUNK_BLOCK_VALUES, while its
+    scores fit _SCORES_VMEM. The latent tile (one head, 640 + 512 values)
+    holds that many at 256 slots and keeps them: 1024 bought its call 5 %
+    (2.76 -> 2.63 ms). A short table keeps 256 whatever its rows: a tile
+    multiplies whole blocks, and the contexts of a few hundred slots that
+    PR 25 measured fill one or two. The ONE-TOKEN tile of that shape keeps
+    512 slots walked a page a turn: 1.554 ms a call for 325 k cached slots
+    of 3072 B (79 % of the read rate; the copies alone 1.518, the products
+    alone 0.739); blocks of 1024 / 2048 / 4096: 1.584 / 1.588 / 1.635; the
+    walk 4 / 8 / 16 pages a turn at 512 / 1024 / 2048 slots: 1.544 / 1.577
+    / 1.571: the page copies of 128 + 64 KB are bound by HBM, not by the
+    scalar work that starts them, and the products hide under them.
 
     ``window``: a tile's tokens see W - 1 + bq slots between them, so a
     tile is ONE block, laid from the tile's first visible page on:
@@ -313,6 +351,11 @@ def _ragged_tiling(n_tokens: int, q_per_kv: int, page_size: int,
     bk = 256 if bq > 1 else 512
     if bq == 1 and latent_row_bytes:
         bk = max(bk, _LATENT_BLOCK_BYTES // latent_row_bytes // bk * bk)
+    if bq > 1 and kv_width and max_pages * page_size >= _LONG_REACH:
+        # [kv_heads, mrows, bk] float32 scores of the block twice as long
+        while bk * kv_width < _CHUNK_BLOCK_VALUES \
+                and kv_heads * mrows * (2 * bk) * 4 <= _SCORES_VMEM:
+            bk *= 2
     if window is not None:
         lanes = pl.cdiv((pl.cdiv(window - 1 + bq, page_size) + 1)
                         * page_size, 128) * 128
@@ -350,7 +393,12 @@ def _ragged_kernel(layer_ref, q_len_ref, kv_len_ref, pt_ref,   # prefetch
     (start the next block's page copies, wait for this block's) and its
     vector work run one after the other, so that tile walks its pages
     ``walk`` to a loop turn over the larger blocks _ragged_tiling gives
-    it (the numbers are in that docstring).
+    it (the numbers are in that docstring). A per-head pool's one-token
+    tile is bound by the copies themselves where a page is long (MiMo's
+    full layers: 128 KB of K and 64 KB of V a page, 80 % of the HBM rate
+    with no compute at all, 79 % with it) and keeps a page a turn: walks
+    of 4 to 32 pages over blocks of 512 to 4096 slots came within 2 % of
+    it either way (PERF.md, PR 48).
 
     ``window`` (static): one more scalar-prefetch operand, base_ref [R], the
     logical page a row's table starts at. A tile's walk and its blocks are
@@ -571,7 +619,7 @@ def _ragged_rows_pallas(q, k_pages, v_pages, layer, page_table, q_start,
     qpk = Hq // Hkv
     bq, nq, mrows, bkp = _ragged_tiling(
         n_tokens, qpk, ps, max_pages,
-        D * k_pages.dtype.itemsize if latent else None, window)
+        D * k_pages.dtype.itemsize if latent else None, window, Hkv, D + Dv)
     n_tiles, bk = n_rows * nq, bkp * ps
 
     # tile order: [tile, kv head, q head in group * bq + token, D]

@@ -278,42 +278,75 @@ def test_ragged_tiling_clamps_to_small_shapes():
     assert _ragged_tiling(130, 2, 32, 3) == (128, 2, 256, 3)
 
 
-# (n_tokens, q_per_kv, max_pages[, latent row bytes]) at the shapes the
-# benchmark's serve cells run, pages of 16: what _ragged_tiling gave them
+# ((n_tokens, q_per_kv, page_size, max_pages), the pool's other numbers) at
+# the shapes the benchmark's serve cells run: what _ragged_tiling gave them
 # before the latent one-token tile had a rule of its own (PR 33's tree),
-# and that tile's value
+# that tile's value, and since PR 48 the chunk tile of a table that reaches
+# 8192 slots or more over slot rows narrower than the latent pool's (the
+# window block's full layers')
 _BENCHMARK_TILINGS = {
-    "mistral_one_token": ((1, 4, 144), (1, 1, 16, 32)),
-    "mistral_chunk": ((512, 4, 144), (128, 4, 512, 16)),
-    "olmoe_one_token": ((1, 1, 96), (1, 1, 16, 32)),
-    "olmoe_chunk": ((512, 1, 96), (128, 4, 128, 16)),
-    "lfm2_one_token": ((1, 4, 192), (1, 1, 16, 32)),
-    "lfm2_chunk": ((512, 4, 192), (128, 4, 512, 16)),
-    "latent_chunk": ((512, 32, 608, 1280), (32, 16, 1024, 16)),
+    "mistral_one_token": (((1, 4, 16, 144), {}), (1, 1, 16, 32)),
+    "mistral_chunk": (((512, 4, 16, 144), {}), (128, 4, 512, 16)),
+    "olmoe_one_token": (((1, 1, 16, 96), {}), (1, 1, 16, 32)),
+    "olmoe_chunk": (((512, 1, 16, 96), {}), (128, 4, 128, 16)),
+    "lfm2_one_token": (((1, 4, 16, 192), {}), (1, 1, 16, 32)),
+    "lfm2_chunk": (((512, 4, 16, 192), {}), (128, 4, 512, 16)),
+    # a slot row of 640 + 512 values: 256 slots hold a block's worth (1024
+    # bought 5 %, PR 48: not kept), though the table reaches 9728 slots
+    "latent_chunk": (((512, 32, 16, 608),
+                      dict(latent_row_bytes=1280, kv_width=1152)),
+                     (32, 16, 1024, 16)),
     # 2048 slots of 1280 B a block (was 512: (1, 1, 32, 32))
-    "latent_one_token": ((1, 32, 608, 1280), (1, 1, 32, 128)),
+    "latent_one_token": (((1, 32, 16, 608), dict(latent_row_bytes=1280)),
+                         (1, 1, 32, 128)),
     # the same bytes a block where a slot is wider, and never more pages
     # than the table has
-    "latent_one_token_fp32": ((1, 32, 608, 2560), (1, 1, 32, 64)),
-    "latent_one_token_short_table": ((1, 32, 40, 1280), (1, 1, 32, 40)),
+    "latent_one_token_fp32": (((1, 32, 16, 608),
+                               dict(latent_row_bytes=2560)), (1, 1, 32, 64)),
+    "latent_one_token_short_table": (((1, 32, 16, 40),
+                                      dict(latent_row_bytes=1280)),
+                                     (1, 1, 32, 40)),
+    # the window block (MiMo-V2-Flash), pages of 64. Full layers: 4 KV
+    # heads of 16 query heads, a table of 19456 slots: the one-token tile
+    # keeps its 512 slots (PR 48's study: no block, walk or order of the
+    # heads' chains beat it), the chunk tile takes 1024 (was 256:
+    # (64, 8, 1024, 4))
+    "mimo_full_one_token": (((1, 16, 64, 304),
+                             dict(kv_heads=4, kv_width=384)), (1, 1, 16, 8)),
+    "mimo_full_chunk": (((512, 16, 64, 304), dict(kv_heads=4, kv_width=384)),
+                        (64, 8, 1024, 16)),
+    # window layers: 8 KV heads of 8, ONE block of 256 slots a tile
+    "mimo_window_one_token": (((1, 8, 64, 8),
+                               dict(kv_heads=8, kv_width=384, window=128)),
+                              (1, 1, 16, 4)),
+    "mimo_window_chunk": (((512, 8, 64, 8),
+                           dict(kv_heads=8, kv_width=384, window=128)),
+                          (64, 8, 512, 4)),
+    # a long table alone does not grow a block whose scores would not fit:
+    # 16 KV heads of 8 query heads keep 256 slots
+    "long_table_many_heads_chunk": (((512, 8, 16, 1024),
+                                     dict(kv_heads=16, kv_width=256)),
+                                    (128, 4, 1024, 16)),
+    # ... and Mistral's heads under a long table take 1024 (2048 slots of
+    # 256 values would meet the latent block's, their scores would not fit)
+    "long_table_mistral_chunk": (((512, 4, 16, 1024),
+                                  dict(kv_heads=8, kv_width=256)),
+                                 (128, 4, 512, 64)),
 }
 
 
 @pytest.mark.parametrize("name", list(_BENCHMARK_TILINGS))
 def test_ragged_tiling_at_the_benchmarks_shapes(name):
-    """(bq, nq, mrows, bkp) pinned: the per-head forms and the latent
-    chunk tile keep their tiling whatever the latent one-token tile
-    takes."""
+    """(bq, nq, mrows, bkp) pinned: the per-head forms of the short-context
+    cells keep their tiling whatever the latent one-token tile and the
+    long tables' chunk tiles take."""
     from ray_tpu.ops.paged_attention import _ragged_tiling
-    (n_tokens, q_per_kv, max_pages, *row_bytes), want = \
-        _BENCHMARK_TILINGS[name]
-    assert _ragged_tiling(n_tokens, q_per_kv, 16, max_pages,
-                          *row_bytes) == want
-    if not row_bytes:       # a per-head shape never asks with row bytes
+    (args, kw), want = _BENCHMARK_TILINGS[name]
+    assert _ragged_tiling(*args, **kw) == want
+    if "latent_row_bytes" not in kw:
         return
     # the latent form differs from the per-head one in one-token tiles only
-    per_head = _ragged_tiling(n_tokens, q_per_kv, 16, max_pages)
-    assert (per_head == want) == (n_tokens > 1)
+    assert (_ragged_tiling(*args) == want) == (args[0] > 1)
 
 
 # the latent one-token tile at Kanana-2's head shape (32 query heads on
@@ -378,6 +411,112 @@ def test_latent_one_token_tile_matches_reference(case):
     assert np.all(got[1] == 0.0)                    # the empty row
     # fp32 throughout; bf16 operands and probabilities as the chip runs it
     np.testing.assert_allclose(got, np.asarray(want),
+                               atol=1e-4 if dtype == jnp.float32 else 3e-2)
+
+
+# the window block's FULL layers (MiMo-V2-Flash): 4 KV heads of 16 query
+# heads, K rows of 256 lanes and V rows of 128, pages of 64, a table that
+# reaches 8192 slots; small pool
+_FULL_HKV, _FULL_QPK, _FULL_DK, _FULL_DV, _FULL_PS, _FULL_MP = \
+    4, 16, 256, 128, 64, 128
+
+
+def _full_tiling(n_tokens):
+    from ray_tpu.ops.paged_attention import _ragged_tiling
+    return _ragged_tiling(n_tokens, _FULL_QPK, _FULL_PS, _FULL_MP,
+                          kv_heads=_FULL_HKV, kv_width=_FULL_DK + _FULL_DV)
+
+
+def _full_layer_batch(lens, q_lens, dtype):
+    """Rows of ``lens`` cached slots (their last ``q_lens`` the query
+    tokens; an empty row still owns one slot of q) over a stacked pool of
+    two layers whose pages are dealt at random; table entries past a row's
+    length name a page of NaNs. -> (q, pool and descriptors for
+    ragged_paged_attention, the same with the NaNs zeroed for the gather
+    path)."""
+    lens, q_lens = np.asarray(lens), np.asarray(q_lens)
+    ps, mp = _FULL_PS, _FULL_MP
+    need = -(-lens // ps)
+    P = int(need.sum()) + 2
+    pt = np.full((len(lens), mp), P - 1, np.int32)      # the page of NaNs
+    perm = 1 + np.random.default_rng(int(lens.sum())).permutation(P - 2)
+    at = 0
+    for r, n in enumerate(need):
+        pt[r, :n] = perm[at:at + n]
+        at += n
+    ks = jax.random.split(jax.random.PRNGKey(int(lens.sum())), 3)
+    k = jax.random.normal(ks[0], (2, P, _FULL_HKV, ps, _FULL_DK), jnp.float32)
+    v = jax.random.normal(ks[1], (2, P, _FULL_HKV, ps, _FULL_DV), jnp.float32)
+    k, v = (a.at[:, P - 1].set(jnp.nan).astype(dtype) for a in (k, v))
+    spans = np.maximum(q_lens, 1)
+    q = jax.random.normal(ks[2], (int(spans.sum()), _FULL_HKV * _FULL_QPK,
+                                  _FULL_DK), jnp.float32).astype(dtype)
+    q_start = np.cumsum(spans) - spans
+    rows = (jnp.asarray(pt), jnp.asarray(q_start, jnp.int32),
+            jnp.asarray(q_lens, jnp.int32), jnp.asarray(lens, jnp.int32))
+    zeroed = tuple(jnp.nan_to_num(a.astype(jnp.float32)) for a in (k, v))
+    return q, (k, v) + rows, zeroed + rows
+
+
+# a one-token case's length = blocks * (the tile's block) + slots
+_FULL_ONE_TOKEN = {
+    "1": (0, 1), "63": (0, 63), "64": (0, 64), "65": (0, 65),
+    "block-1": (1, -1), "block": (1, 0), "block+1": (1, 1),
+    "2*block+5": (2, 5), "bf16:block+1": (1, 1), "bf16:3*block-70": (3, -70)}
+
+
+@pytest.mark.pallas_interpret
+@pytest.mark.parametrize("case", list(_FULL_ONE_TOKEN))
+def test_full_layer_one_token_tile_matches_reference(case):
+    """Three decode rows (the case's length, an EMPTY row, a row whose last
+    page is partial) through the per-head one-token tile at the window
+    block's full-layer shape, in interpret mode against the gather path:
+    lengths around a page of 64 and around the tile's block, whatever
+    _ragged_tiling gives it."""
+    dtype = jnp.bfloat16 if case.startswith("bf16:") else jnp.float32
+    blocks, slots = _FULL_ONE_TOKEN[case]
+    bk = _FULL_PS * _full_tiling(1)[3]
+    lens = np.array([blocks * bk + slots, 0, 150])
+    q, args, zeroed = _full_layer_batch(lens, lens > 0, dtype)
+    kw = dict(sm_scale=192 ** -0.5, decode_rows=3, layer=1)
+    want = ragged_paged_attention_reference(q.astype(jnp.float32), *zeroed,
+                                            **kw)
+    got = ragged_paged_attention(q, *args, interpret=True, **kw)
+    assert got.shape == (3, 64, _FULL_DV) and got.dtype == dtype
+    got = np.asarray(got, np.float32)
+    assert np.all(got[1] == 0.0)                    # the empty row
+    np.testing.assert_allclose(got, np.asarray(want),
+                               atol=1e-4 if dtype == jnp.float32 else 3e-2)
+
+
+# a chunk case: (blocks, slots) of the prefix the chunk's 70 tokens follow
+_FULL_CHUNK = {"0": (0, 0), "block-70": (1, -70), "block-30": (1, -30),
+               "block+1": (1, 1), "bf16:2*block-5": (2, -5)}
+
+
+@pytest.mark.pallas_interpret
+@pytest.mark.parametrize("case", list(_FULL_CHUNK))
+def test_full_layer_chunk_tile_matches_reference(case):
+    """Two chunk rows of 70 and 9 tokens (two tiles of 64 a row: a whole
+    one and a partial one, and a tile past the second row's length) behind
+    one decode row, at the window block's full-layer shape, whose table
+    reaches far enough for the long chunk block: prefixes that end a tile
+    short of the block's edge, inside it and past it, so a block boundary
+    falls before, inside and after a tile's own (masked) positions."""
+    dtype = jnp.bfloat16 if case.startswith("bf16:") else jnp.float32
+    bq, _, mrows, bkp = _full_tiling(70)
+    assert (bq, mrows, bkp * _FULL_PS) == (64, 1024, 1024)
+    blocks, slots = _FULL_CHUNK[case]
+    prefix = blocks * bkp * _FULL_PS + slots
+    q_lens = np.array([1, 70, 9])
+    lens = np.array([200, prefix + 70, 130 + 9])
+    q, args, zeroed = _full_layer_batch(lens, q_lens, dtype)
+    kw = dict(sm_scale=192 ** -0.5, decode_rows=1, max_q_len=70, layer=0)
+    want = ragged_paged_attention_reference(q.astype(jnp.float32), *zeroed,
+                                            **kw)
+    got = ragged_paged_attention(q, *args, interpret=True, **kw)
+    assert got.shape == (80, 64, _FULL_DV) and got.dtype == dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want),
                                atol=1e-4 if dtype == jnp.float32 else 3e-2)
 
 

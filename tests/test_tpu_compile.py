@@ -444,6 +444,33 @@ def test_latent_kernels_compile_at_kanana2_shapes(chip):
             decode_rows=48).compile()
 
 
+def test_full_layer_kernels_compile_at_mimo_shapes(chip):
+    """The blocked kernel at the window block's FULL layers, the
+    benchmark's sizes (mimo-v2-flash-serve-1chip): 4 KV heads of 16 query
+    heads, K rows of 256 lanes and V rows of 128, 12800 pages of 64, a
+    304-page table a row. The mixed step's call (96 one-token rows + 2
+    chunks of 512: the chunk tile is 64 tokens x 16 heads = 1024 operand
+    rows against blocks of 1024 slots, [4, 1024, 1024] float32 scores and
+    ~100 MB of VMEM granted: a block too large is refused here, before any
+    chip run) and the decode loop's (96 one-token rows, blocks of 512)."""
+    L, P, ps, mp, hq, hkv = 2, 12800, 64, 304, 64, 4
+    shape = dict(kv_heads=hkv, kv_width=256 + 128)
+    assert pa._ragged_tiling(512, hq // hkv, ps, mp, **shape) \
+        == (64, 8, 1024, 16)
+    assert pa._ragged_tiling(1, hq // hkv, ps, mp, **shape) == (1, 1, 16, 8)
+    k = _sds(chip, (L, P, hkv, ps, 256), jnp.bfloat16)
+    v = _sds(chip, (L, P, hkv, ps, 128), jnp.bfloat16)
+    layer = _sds(chip, (), jnp.int32)
+    for T, R, max_q_len, calls in ((96 + 2 * 512, 98, 512, 2), (96, 96, 1, 1)):
+        row = _sds(chip, (R,), jnp.int32)
+        lowered = pa._ragged_attention_pallas.lower(
+            _sds(chip, (T, hq, 256), jnp.bfloat16), k, v,
+            _sds(chip, (R, mp), jnp.int32), row, row, row, None, None,
+            sm_scale=192 ** -0.5, max_q_len=max_q_len, decode_rows=96,
+            layer=layer)
+        assert _kernel_calls(lowered) == calls
+
+
 def _granite_cfg(n_layers=10):
     """granite4-h-micro-serve-1chip's widths; 10 layers = ONE period of its
     four (5 mamba, attention, 4 mamba)."""
