@@ -58,8 +58,10 @@ admission, scheduling), rebuilt TPU-first around ONE ragged step:
     inside engine.book), so a sequence holds a constant of them however
     long its context; its rows' page tables are COMPACT (a base and a few
     entries: the descriptor does not grow with the context for the group
-    that does not); stats window_pages_freed, and page_steps_full /
-    page_steps_window, each group's pages in use added at every dispatch;
+    that does not); stats window_pages_freed, page_steps_full /
+    page_steps_window, each group's pages in use added at every dispatch,
+    and rows_inside_window, the decode row-steps of sequences no longer
+    than the window;
     no prefix cache there; preemption stays recompute from 0;
   - ONE DESCRIPTOR a dispatch: every integer a program takes (tokens,
     positions, pages, the rows' spans, the page table) is a field of one
@@ -454,8 +456,13 @@ class InferenceEngine:
             # at every dispatch, the pages IN USE of each group (monotonic,
             # so a window's difference is exact): their ratio is the share
             # of one lifetime's pages that the window layers hold
+            # ... and the decode row-steps (the unit of decode_tokens: a
+            # row of a mixed step is one, of a decode block decode_chunk)
+            # whose sequence still lay inside the window when its dispatch
+            # left: a window layer is a full layer to such a row, and its
+            # group has freed nothing for it
             self.stats.update(window_pages_freed=0, page_steps_full=0,
-                              page_steps_window=0)
+                              page_steps_window=0, rows_inside_window=0)
         if cfg.kv_lora_rank:
             # a latent pool: what a token costs a layer, and the row held
             self.stats.update(
@@ -723,11 +730,17 @@ class InferenceEngine:
         self._page_table_win[slot, len(pages):] = SCRATCH_PAGE
         self._page_base_win[slot] = seq.win_base
 
-    def _book_page_steps(self) -> None:
-        """Add each group's pages in use to its counter (a dispatch)."""
+    def _book_page_steps(self, active, steps: int) -> None:
+        """Add each group's pages in use to its counter (a dispatch), and
+        to rows_inside_window the dispatch's decode rows (``active``, before
+        their tokens are booked) whose sequence, the token the dispatch
+        computed first counted, is no longer than the window, times the
+        ``steps`` each took."""
         for key, alloc in (("page_steps_full", self.allocator),
                            ("page_steps_window", self.window_allocator)):
             self.stats[key] += alloc.total_pages - 1 - alloc.num_free
+        self.stats["rows_inside_window"] += steps * sum(
+            seq.num_tokens <= self._window for _, seq in active)
 
     def _unmatch(self, matched_pages: List[int]) -> None:
         """Undo a PrefixCache.match whose sequence did not admit."""
@@ -1005,7 +1018,7 @@ class InferenceEngine:
             if n_rows < self.prefill_rows:
                 self.stats["ragged_small_dispatches"] += 1
             if self._window:
-                self._book_page_steps()
+                self._book_page_steps(active, 1)
             self.stats["prefill_tokens"] += chunk_tokens
             self.stats["chunk_rows"] += len(rows)
             # a joined row starts past what its sequence has computed
@@ -1268,7 +1281,7 @@ class InferenceEngine:
             self.stats["decode_dispatches"] += 1
             self.stats["h2d_arrays"] += 1           # its descriptor
             if self._window:
-                self._book_page_steps()
+                self._book_page_steps(active, K)
             self._step_meta = {
                 "kind": "decode",
                 "dispatch": self.stats["decode_dispatches"],

@@ -65,6 +65,11 @@ kind keeps per batch slot is declared beside it in llm/cache.py
     group (llm/cache.py), a token seeing the last ``sliding_window``
     positions through its row's compact page table, a float32 sink a query
     head in the softmax's denominator; nothing a slot.
+  - a GATED block (Trinity-Mini; ``cfg.gated_block``): both attention
+    operators' output times sigmoid(h w_og) before wo (``attn_gate``),
+    every branch through a norm of its own before it joins the stream
+    (``post_norms``: ``_residual``), and the rotary embedding on the window
+    layers only (``full_rope=False``), beside the per-head q/k norm.
 
 The two recurrences take the rows of a ragged batch by ONE protocol
 (``_slot_rows``): the leading one-token rows update their slots in place
@@ -153,8 +158,12 @@ def _maybe_psum(x, tp_axis):
     return lax.psum(x, tp_axis) if tp_axis else x
 
 
-def _residual(x, y, cfg: LlamaConfig):
-    """x + residual_scale * y: a branch of a layer joins the stream."""
+def _residual(x, y, cfg: LlamaConfig, post_norm=None):
+    """x + residual_scale * y: a branch of a layer joins the stream,
+    through a norm of its own where the block has one (``post_norm``: the
+    branch's ``*_post_norm`` leaf, or None)."""
+    if post_norm is not None:
+        y = _rmsnorm(y, post_norm, cfg.norm_eps)
     if cfg.residual_scale != 1.0:
         y = y * jnp.asarray(cfg.residual_scale, y.dtype)
     return x + y
@@ -191,7 +200,8 @@ def _mlp(lp, x, cfg: LlamaConfig, tp_axis=None):
     # w_down is row-parallel under tp: each shard holds ffn/tp rows, the
     # partial products sum across the axis (Megatron second collective)
     return _residual(
-        x, _maybe_psum((gate * up) @ lp["w_down"].astype(cd), tp_axis), cfg)
+        x, _maybe_psum((gate * up) @ lp["w_down"].astype(cd), tp_axis), cfg,
+        lp["mlp_post_norm"] if cfg.post_norms else None)
 
 
 def _moe_mlp(lp, experts, layer, x, valid, cfg: LlamaConfig, impl):
@@ -216,7 +226,8 @@ def _moe_mlp(lp, experts, layer, x, valid, cfg: LlamaConfig, impl):
             gate = jax.nn.silu(h @ lp["w_shared_gate"].astype(cd))
             y = y + (gate * (h @ lp["w_shared_up"].astype(cd))) \
                 @ lp["w_shared_down"].astype(cd)
-    return _residual(x, y, cfg), counters
+    return _residual(x, y, cfg, lp["mlp_post_norm"] if cfg.post_norms
+                     else None), counters
 
 
 #: the routed experts' weights: handed to the expert kernel whole, [layer,
@@ -263,6 +274,12 @@ SCOPE_RET_PROJ, SCOPE_RET_UPDATE, SCOPE_RET_CHUNK = \
 #: wo)
 SCOPE_WINDOW, SCOPE_WINDOW_PROJ, SCOPE_FULL_PROJ = \
     "attn_window", "attn_window_proj", "attn_full_proj"
+#: ... a gated block (models/llama.py: gated_block): the output gate
+#: (inside its operator's projection scope: sigmoid(h w_og) and the
+#: product with the attention output), and the head with the greedy choice
+#: over its logits (no other block's head is named: its ops keep the
+#: names they had)
+SCOPE_GATE, SCOPE_HEAD = "attn_gate", "lm_head"
 
 
 class _Rows(NamedTuple):
@@ -692,7 +709,8 @@ def _paged_attention(lp, l, x, kv, rows: _Rows, cfg: LlamaConfig, impl,
         with proj:
             h = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
             q, k, v = _project_qkv(lp, h, cfg)        # [1, T, H, D]
-            if cfg.rope:
+            if cfg.rope and (window or cfg.full_rope):
+                # (a gated block's full layers carry no position)
                 theta = cfg.window_rope_theta if window else cfg.rope_theta
                 q = _rotate(q, token_pos, theta, cfg)
                 k = _rotate(k, token_pos, theta, cfg)
@@ -725,9 +743,17 @@ def _paged_attention(lp, l, x, kv, rows: _Rows, cfg: LlamaConfig, impl,
             k_scale=ksc, v_scale=vsc, **hints, **scale)
         with proj:
             o = o[..., :vd].reshape(1, T, -1).astype(cd)
+            if cfg.attn_gate:
+                # one sigmoid a value of every head's output, from the
+                # layer's normed input
+                with jax.named_scope(SCOPE_GATE):
+                    g = jax.nn.sigmoid(
+                        (h @ lp["w_og"].astype(cd)).astype(jnp.float32))
+                    o = (o * g).astype(cd)
             # wo is row-parallel under tp (Megatron first collective)
             x = _residual(
-                x, _maybe_psum(o @ lp["wo"].astype(cd), rows.tp_axis), cfg)
+                x, _maybe_psum(o @ lp["wo"].astype(cd), rows.tp_axis), cfg,
+                lp["attn_post_norm"] if cfg.post_norms else None)
     kv = {**kv, kl: kc, vl: vc}
     if "k_scale" in kv:
         kv["k_scale"], kv["v_scale"] = ksc, vsc
@@ -890,21 +916,35 @@ def _ragged_logits(params: Params, tokens: jax.Array,
     last = jnp.clip(q_start + q_len - 1, 0, T - 1)        # [R]
     xl = x[0][last]
     head = params["embed"] if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("rd,vd->rv", xl.astype(cd), head.astype(cd),
-                        preferred_element_type=jnp.float32)
+    with _head_scope(cfg):
+        logits = jnp.einsum("rd,vd->rv", xl.astype(cd), head.astype(cd),
+                            preferred_element_type=jnp.float32)
     if cfg.logits_divisor != 1.0:
         logits = logits / cfg.logits_divisor
     return logits, kv, counters
 
 
-def _ragged_forward(*args, **kwargs):
+def _head_scope(cfg: LlamaConfig):
+    """SCOPE_HEAD for a gated block's head and greedy choice; no scope for
+    any other block's."""
+    return jax.named_scope(SCOPE_HEAD) if cfg.gated_block \
+        else contextlib.nullcontext()
+
+
+def _ragged_forward(params, tokens, token_pos, token_page, token_slot,
+                    page_table, q_start, q_len, kv_len, kv,
+                    cfg: LlamaConfig, *hints, **kwargs):
     """``_ragged_logits`` with the argmax fused in-program, so the whole
     mixed step is ONE dispatch + ONE readback: (next_tok [R], kv,
     counters), per row the next decode token for q_len == 1 rows, the
     first sampled token for a prefill chunk that just finished its
     prompt."""
-    logits, kv, counters = _ragged_logits(*args, **kwargs)
-    return jnp.argmax(logits, axis=-1).astype(jnp.int32), kv, counters
+    logits, kv, counters = _ragged_logits(
+        params, tokens, token_pos, token_page, token_slot, page_table,
+        q_start, q_len, kv_len, kv, cfg, *hints, **kwargs)
+    with _head_scope(cfg):
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return nxt, kv, counters
 
 
 def _ragged_step_body(params: Params, tokens: jax.Array,

@@ -83,10 +83,13 @@ def validate_tp(cfg: LlamaConfig, tp: int) -> None:
             f"layers keep their pages in a second page group with its own "
             f"key/value heads (window_kv_heads), for whose leaves there is "
             f"no partition spec here, and score_head_dim / value_head_dim, "
-            f"rotary_dim, value_scale and experts_held (a share of the "
+            f"rotary_dim, value_scale, experts_held (a share of the "
             f"experts is the other way a layer is divided among chips: the "
-            f"exchange between the shares is not built) are refused with "
-            f"them, untested under a shard (ROADMAP R5a, R10b)")
+            f"exchange between the shares is not built), attn_gate (w_og "
+            f"would shard by head with wq's columns), post_norms (a norm "
+            f"over the whole width AFTER the row-parallel sum) and "
+            f"full_rope=False are refused with them, untested under a "
+            f"shard (ROADMAP R5a, R10b)")
     if cfg.beyond_llama_block:
         raise NotImplementedError(
             f"tp={tp} is not served for this block: mamba layers keep a "

@@ -150,6 +150,13 @@ class LlamaConfig:
     window_kv_heads: int = 0         # key/value heads of a window layer
     window_rope_theta: float = 0.0   # its rotary base
     attn_sink: bool = False          # a float32 logit a head, no value
+    # An attention operator whose output is gated and whose branches are
+    # normed twice, in a block whose window layers rotate and whose full
+    # layers carry no position (Trinity-Mini, of the afmoe family, is the
+    # first such block). Off = every other block's program, text for text.
+    attn_gate: bool = False          # sigmoid(h w_og) on o, before wo
+    post_norms: bool = False         # x + rms(branch; *_post_norm), both
+    full_rope: bool = True           # False: full layers are not rotated
     # The chip's share of an expert layer: (first, n) = this chip holds
     # experts first .. first + n - 1 of n_experts. The router keeps all
     # n_experts outputs; pairs routed elsewhere go nowhere (ops/moe.py).
@@ -218,20 +225,37 @@ class LlamaConfig:
                     f"sliding_attention layers need a sliding_window, "
                     f"window_kv_heads that divide n_heads and a "
                     f"window_rope_theta, got {window}")
-            if self.kv_lora_rank or self.qk_norm or self.qk_norm_per_head \
-                    or not self.rope or self.attn_scale or len(
+            if self.kv_lora_rank or self.qk_norm or not self.rope \
+                    or self.attn_scale or len(
                         set(self.layer_types) - {ATTENTION, WINDOW}):
                 raise ValueError(
                     "sliding_attention layers are built beside "
-                    "full_attention layers only, with the rotary embedding "
-                    "and the score scale of the head: not beside a latent "
-                    "pool, a q/k norm, rope=False, attn_scale, or conv, "
-                    "mamba or retention layers")
+                    "full_attention layers only, rotated (full_rope=False "
+                    "leaves the FULL layers without positions) and with "
+                    "the score scale of the head: not beside a latent "
+                    "pool, a q/k norm over the whole projected vector "
+                    "(qk_norm_per_head is served), rope=False, attn_scale, "
+                    "or conv, mamba or retention layers")
         elif any(window) or self.attn_sink:
             raise ValueError(
                 "sliding_window, window_kv_heads, window_rope_theta and "
                 "attn_sink describe sliding_attention layers: layer_types "
                 "names none")
+        if self.gated_block:
+            if not self.full_rope and WINDOW not in self.layer_types:
+                raise ValueError(
+                    "full_rope=False describes a block whose window layers "
+                    "rotate and whose full layers do not: layer_types "
+                    "names no sliding_attention layer (rope=False is the "
+                    "block with no positions at all)")
+            if self.kv_lora_rank or len(
+                    set(self.layer_types) - {ATTENTION, WINDOW}):
+                raise ValueError(
+                    "attn_gate, post_norms and full_rope describe per-head "
+                    "K and V attention (full_attention and "
+                    "sliding_attention layers): not a latent pool, nor "
+                    "conv, mamba or retention layers, whose operators have "
+                    "no gate and no second norm")
         if (self.score_head_dim or self.value_head_dim or self.rotary_dim
                 or self.value_scale != 1.0):
             dk, dv = self.qk_head_dim, self.v_dim
@@ -291,13 +315,21 @@ class LlamaConfig:
         return self.value_head_dim or self.head_dim
 
     @property
+    def gated_block(self) -> bool:
+        """An output gate on attention, a norm after each branch, or full
+        layers without positions beside window layers that rotate
+        (Trinity-Mini is the first such block)."""
+        return self.attn_gate or self.post_norms or not self.full_rope
+
+    @property
     def window_block(self) -> bool:
         """Window layers, head widths of their own, a partial rotary
         embedding, a value scale, or a share of the experts (MiMo-V2-Flash
-        is the first such block)."""
+        is the first such block), or the gated block's fields."""
         return WINDOW in self.layer_types or bool(
             self.score_head_dim or self.value_head_dim or self.rotary_dim
-            or self.experts_held) or self.value_scale != 1.0
+            or self.experts_held) or self.value_scale != 1.0 \
+            or self.gated_block
 
     @property
     def beyond_llama_block(self) -> bool:
@@ -321,10 +353,12 @@ class LlamaConfig:
     def hybrid(self) -> bool:
         """The layers differ in kind (operator or feed-forward), or the
         block has leaves the Llama tree has no place for (latent
-        attention, a shared expert): weights are stacked per kind and the
-        serving step runs the pattern."""
+        attention, a shared expert, an output gate, a norm after a branch):
+        weights are stacked per kind and the serving step runs the
+        pattern."""
         return bool(self.layer_types or self.n_dense_layers
-                    or self.kv_lora_rank or self.shared_ffn_dim)
+                    or self.kv_lora_rank or self.shared_ffn_dim
+                    or self.attn_gate or self.post_norms)
 
     def layers_of(self, kind: str) -> Tuple[int, ...]:
         """Indices of the layers whose operator is ``kind``."""
@@ -410,7 +444,10 @@ def _init_hybrid_params(cfg: LlamaConfig, key: jax.Array) -> Params:
     attention layers: a q / k head ``qk_head_dim`` wide and a v head
     ``v_dim``), "attn_window" (the window layers: the same leaves on
     window_kv_heads key/value heads and, with attn_sink, "sink" [n_heads]
-    float32, seeded in SINK_RANGE), "retention" (the power-retention
+    float32, seeded in SINK_RANGE; both stacks, in a gated block: "w_og"
+    [d, n_heads * v_dim], the output gate's projection (attn_gate), and
+    "attn_post_norm" [d] beside "mlp_post_norm" in both feed-forward
+    stacks (post_norms)), "retention" (the power-retention
     layers: the
     attention layers' leaves and the gate's projection w_g [d, n_kv_heads]
     with its bias b_g, float32), "conv" (the gated short convolutions: w_in
@@ -450,9 +487,12 @@ def _init_hybrid_params(cfg: LlamaConfig, key: jax.Array) -> Params:
                 * (fan_in ** -0.5)).astype(dtype)
 
     def swiglu(n, *E, f):
-        return {"mlp_norm": jnp.ones((n, d), pd),
-                "w_gate": dense(n, *E, d, f), "w_up": dense(n, *E, d, f),
-                "w_down": dense(n, *E, f, d)}
+        stack = {"mlp_norm": jnp.ones((n, d), pd),
+                 "w_gate": dense(n, *E, d, f), "w_up": dense(n, *E, d, f),
+                 "w_down": dense(n, *E, f, d)}
+        if cfg.post_norms:
+            stack["mlp_post_norm"] = jnp.ones((n, d), pd)
+        return stack
 
     def qkv(n, hkv=cfg.n_kv_heads):
         """The leaves attention, window and retention layers share: the
@@ -463,11 +503,23 @@ def _init_hybrid_params(cfg: LlamaConfig, key: jax.Array) -> Params:
             "wq": dense(n, d, hq * dk), "wk": dense(n, d, hkv * dk),
             "wv": dense(n, d, hkv * dv), "wo": dense(n, hq * dv, d)}
         if cfg.qk_norm_per_head:
-            stack["q_norm"] = jnp.ones((n, hd), pd)
-            stack["k_norm"] = jnp.ones((n, hd), pd)
+            # over each head: as wide as a q / k head IS (dk, which is
+            # head_dim only where no field says otherwise)
+            stack["q_norm"] = jnp.ones((n, dk), pd)
+            stack["k_norm"] = jnp.ones((n, dk), pd)
         elif cfg.qk_norm:
             stack["q_norm"] = jnp.ones((n, hq * hd), pd)
             stack["k_norm"] = jnp.ones((n, hkv * hd), pd)
+        return stack
+
+    def gated(stack, n):
+        """... and what the attention and window operators of a gated
+        block add: the gate's projection, as wide as the heads' output,
+        and the norm after the branch."""
+        if cfg.attn_gate:
+            stack["w_og"] = dense(n, d, hq * dv)
+        if cfg.post_norms:
+            stack["attn_post_norm"] = jnp.ones((n, d), pd)
         return stack
 
     hkv = cfg.n_kv_heads
@@ -483,12 +535,12 @@ def _init_hybrid_params(cfg: LlamaConfig, key: jax.Array) -> Params:
             "w_uk": dense(A, hq, dn, r, fan_in=r),
             "w_uv": dense(A, hq, r, dv), "wo": dense(A, hq * dv, d)}
     elif A:
-        layers["attn"] = qkv(A)
+        layers["attn"] = gated(qkv(A), A)
     Wn = len(cfg.layers_of(WINDOW))
     if Wn:
         # the window layers: their own key/value heads and, with attn_sink,
         # one float32 logit a query head (SINK_RANGE)
-        layers["attn_window"] = qkv(Wn, cfg.window_kv_heads)
+        layers["attn_window"] = gated(qkv(Wn, cfg.window_kv_heads), Wn)
         if cfg.attn_sink:
             layers["attn_window"]["sink"] = jax.random.uniform(
                 next(keys), (Wn, hq), jnp.float32, *SINK_RANGE)
@@ -571,8 +623,11 @@ def _require_llama_block(cfg: LlamaConfig, what: str) -> None:
             f"window_rope_theta, attn_sink: a mask, a sink in the softmax "
             f"and a second page group that are CACHE-side, with no "
             f"training attention here), score_head_dim / value_head_dim, "
-            f"rotary_dim, value_scale and experts_held (a chip's share of "
-            f"an expert layer) are served by llm/model.py only (ROADMAP R4)")
+            f"rotary_dim, value_scale, experts_held (a chip's share of "
+            f"an expert layer), attn_gate (a sigmoid gate on the attention "
+            f"output), post_norms (a norm after each branch) and "
+            f"full_rope=False (a rotation by kind of layer) are served by "
+            f"llm/model.py only (ROADMAP R4)")
     if cfg.beyond_llama_block:
         raise NotImplementedError(
             f"{what} is written for the Llama/Mistral block: mamba layers "

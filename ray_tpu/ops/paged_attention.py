@@ -59,7 +59,9 @@ as it did before they existed.
     tile's page walk starts at the first page that holds a visible
     position (it already stopped at the last), the mask gets its lower
     edge, and a tile is ONE block of ceil((W - 1 + bq) / ps) + 1 pages
-    rounded up to whole lanes, laid from that first page on. The rows'
+    rounded up to whole lanes, laid from that first page on (a chunk
+    tile's window longer than _WINDOW_BLOCK slots: in blocks of that
+    many). The rows'
     page table may then be COMPACT (``page_base`` [R]: the logical page a
     row's entry 0 stands for; the kernel subtracts it), so neither the
     table nor the pool grows with the context (llm/cache.py: the group
@@ -265,6 +267,12 @@ _LATENT_WALK_UNROLL = 16
 #: this many values of K and V (a latent tile's 256 slots of 640 + 512), as
 #: long as the block's float32 scores fit this much VMEM (PERF.md, PR 48)
 _LONG_REACH = 8192
+#: slots of a window tile's block at most (the long table's chunk block),
+#: and the double-buffered K and V pages up to which a ONE-TOKEN tile still
+#: takes its whole window as one block (W = 2048 at 4 heads of 128 + 128
+#: values: 8.9 MB). Measured at that shape (PERF.md, PR 49; _ragged_tiling)
+_WINDOW_BLOCK = 1024
+_WINDOW_ROW_BYTES = 16 << 20
 _CHUNK_BLOCK_VALUES = 256 * (640 + 512)
 _SCORES_VMEM = 16 << 20
 
@@ -333,7 +341,22 @@ def _ragged_tiling(n_tokens: int, q_per_kv: int, page_size: int,
     ceil((W - 1 + bq) / ps) + 1 pages (the span may start anywhere in its
     first page), rounded up to whole 128-lane score columns (W = 128, ps =
     16: 16 pages, 256 columns, for a one-token tile and for a chunk tile,
-    which holds 64 tokens).
+    which holds 64 tokens). A window whose one block would be longer than
+    _WINDOW_BLOCK slots (W = 2048 at pages of 64: 34 pages, 2176 columns:
+    17.8 MB of float32 scores a chunk tile of 64 tokens x 8 heads on 4
+    key/value heads, three times over in VMEM) is walked in blocks of that
+    many, laid from the same first page: the kernel's loop, mask and copies
+    are the full form's with both edges. Measured at that shape, the
+    kernel alone, one layer's call (my chip run, PR 49: 128 one-token rows
+    of which an eighth lie inside the window, reading 505 MB; and 2 chunk
+    rows of 1024 tokens after prefixes of 8192 and 0), blocks of 256 / 512
+    / 1024 / ONE of 2176 slots: the one-token rows 0.950 / 0.837 / 0.855 /
+    **0.742** ms (65 / 74 / 72 / 83 % of the HBM peak: a turn's fixed cost
+    is paid once a row), the chunk rows 1.049 / 0.930 / **0.774** / 0.764
+    (a last block partly masked costs less than the turns it saves; ONE
+    block would be 53 MB of scores for 1 % more). So a chunk tile takes
+    blocks of _WINDOW_BLOCK = 1024, and a one-token tile ONE block while
+    its double-buffered pages stay under _WINDOW_ROW_BYTES (8.9 MB here).
     """
     bq = min(128, pl.cdiv(n_tokens, 8) * 8) if n_tokens > 1 else 1
     if bq * q_per_kv > 1024:
@@ -359,6 +382,12 @@ def _ragged_tiling(n_tokens: int, q_per_kv: int, page_size: int,
     if window is not None:
         lanes = pl.cdiv((pl.cdiv(window - 1 + bq, page_size) + 1)
                         * page_size, 128) * 128
+        # a long window is walked in blocks, as a full table is; a
+        # one-token tile's scores are nothing, so it keeps ONE block while
+        # its pages (bf16, twice over) fit _WINDOW_ROW_BYTES
+        if bq > 1 or 4 * kv_heads * lanes * (kv_width or 0) \
+                > _WINDOW_ROW_BYTES:
+            lanes = min(lanes, max(_WINDOW_BLOCK, page_size))
         return bq, nq, mrows, pl.cdiv(lanes, page_size)
     bkp = max(1, min(max_pages, bk // page_size))
     return bq, nq, mrows, bkp

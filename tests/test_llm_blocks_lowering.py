@@ -1,7 +1,8 @@
 """A block lowers to the code of its own fields and to no other block's.
 
 `LlamaConfig` is the configuration of several blocks (Mistral, OLMoE, LFM2,
-Kanana-2, granite-4.0-h, Brumby, MiMo-V2-Flash), and one decoder body in llm/model.py follows its fields. A
+Kanana-2, granite-4.0-h, Brumby, MiMo-V2-Flash, Trinity-Mini), and one
+decoder body in llm/model.py follows its fields. A
 configuration that sets none of a block's fields must take none of that
 block's code: the tests here read the jaxprs of both step programs and of
 the page copy, on the kernel path and on the reference path, and the
@@ -79,7 +80,19 @@ BLOCKS = {
                  layer_types=["full_attention", "sliding_attention"] * 2
                  + ["full_attention"], score_head_dim=24, value_head_dim=16,
                  rotary_dim=8, value_scale=0.707, sliding_window=16,
-                 window_rope_theta=1e4, attn_sink=True)}
+                 window_rope_theta=1e4, attn_sink=True),
+    "trinity": dict(n_layers=5, n_heads=8, n_kv_heads=2, window_kv_heads=2,
+                    ffn_dim=32, dense_ffn_dim=96, n_dense_layers=1,
+                    n_experts=16, experts_per_token=4, norm_topk_prob=True,
+                    router_score="sigmoid", router_bias=True,
+                    router_eps=1e-20, router_scale=2.826, shared_ffn_dim=32,
+                    tie_embeddings=False,
+                    layer_types=["sliding_attention"] * 4
+                    + ["full_attention"], score_head_dim=16,
+                    value_head_dim=16, sliding_window=16,
+                    window_rope_theta=1e4, rope_theta=1e4,
+                    qk_norm_per_head=True, attn_gate=True, post_norms=True,
+                    full_rope=False, embed_scale=8.0)}
 
 #: what only a block's own fields may bring into a program's text or
 #: trees: named scopes, parameter leaves, and the shape of the pool
@@ -92,6 +105,12 @@ ONLY_RETENTION = ("retention_proj", "retention_update", "retention_chunk",
                   "retention_norm", "'b_g'")
 ONLY_WINDOW = ("attn_window", "attn_full_proj", "k_win", "v_win", "'sink'",
                "ragged_window_kernel")
+#: ... a gated block's: the gate's and the head's scopes, the gate's
+#: projection, the norms after a branch
+ONLY_GATED = ("attn_gate", "w_og", "attn_post_norm", "mlp_post_norm")
+#: ... and its head's scope, in a program's text (in a parameter tree it
+#: is every untied head's leaf)
+SCOPE_HEAD = "lm_head"
 #: entries of a window row's compact table at the sizes traced below
 _WINDOW_PAGES = 4
 
@@ -194,7 +213,8 @@ def test_a_block_takes_no_other_blocks_code(block):
         assert all("'v'" not in texts[f"{block}.{impl}.pool"]
                    for impl in ("reference", "kernel"))
         return
-    absent = ONLY_LATENT + ONLY_SHARED \
+    absent = ONLY_LATENT + (() if cfg.shared_ffn_dim else ONLY_SHARED) \
+        + (() if cfg.gated_block else ONLY_GATED) \
         + (() if "conv" in cfg.layer_types else ONLY_CONV) \
         + (() if "mamba" in cfg.layer_types else ONLY_SSM) \
         + (() if "retention" in cfg.layer_types else ONLY_RETENTION) \
@@ -202,12 +222,19 @@ def test_a_block_takes_no_other_blocks_code(block):
     for kind, words in (("mamba", ONLY_SSM), ("retention", ONLY_RETENTION),
                         ("sliding_attention", ONLY_WINDOW)):
         if kind in cfg.layer_types:
-            # the control for the block's own words
-            missing = [w for w in words if w not in everything]
+            # the control for the block's own words (a sink is MiMo's)
+            missing = [w for w in words if w not in everything
+                       and (cfg.attn_sink or w != "'sink'")]
             assert not missing, f"the {kind} block's texts lack {missing}"
+    if cfg.gated_block:
+        missing = [w for w in ONLY_GATED + ONLY_SHARED
+                   if w not in everything]
+        assert not missing, f"the gated block's texts lack {missing}"
     for name, text in texts.items():
         found = [word for word in absent if word in text]
         assert not found, f"{name} holds {found}"
+        if name.endswith((".step", ".loop")):
+            assert (SCOPE_HEAD in text) == cfg.gated_block, name
     for impl in ("reference", "kernel"):
         if "sliding_attention" in cfg.layer_types:
             # two widths and two groups: held to their shapes in
@@ -235,7 +262,10 @@ PATTERNS = {
                      ("full_attention", "dense"), ("mamba", "dense")], 2),
     "brumby": ([], [("retention", "dense")], 2),
     "mimo": ([("full_attention", "dense")],
-             [("sliding_attention", "moe"), ("full_attention", "moe")], 2)}
+             [("sliding_attention", "moe"), ("full_attention", "moe")], 2),
+    "trinity": ([("sliding_attention", "dense")],
+                [("sliding_attention", "moe")] * 3
+                + [("full_attention", "moe")], 1)}
 
 
 @pytest.mark.parametrize("block", sorted(BLOCKS))

@@ -565,8 +565,22 @@ def test_config_refuses_what_is_not_built():
         tiny(sliding_window=0)
     with pytest.raises(ValueError, match="full_attention layers only"):
         tiny(layer_types=[FULL, WIN, FULL, WIN, "conv"])
+    # a norm over the whole projected vector stays refused beside window
+    # layers; the norm over each head is served since PR 49 (compared with
+    # its reference in tests/test_llm_trinity.py), a head's weight as wide
+    # as the score head in both stacks
     with pytest.raises(ValueError, match="full_attention layers only"):
-        tiny(qk_norm_per_head=True)
+        tiny(qk_norm=True, score_head_dim=0, value_head_dim=0, rotary_dim=0)
+    served = jax.eval_shape(lambda: init_params(
+        tiny(qk_norm_per_head=True), jax.random.PRNGKey(0)))["layers"]
+    assert served["attn"]["q_norm"].shape == (3, 24)
+    assert served["attn_window"]["k_norm"].shape == (2, 24)
+    with pytest.raises(ValueError, match="full_attention layers only"):
+        tiny(rope=False)
+    with pytest.raises(ValueError, match="full_attention layers only"):
+        tiny(layer_types=[FULL, WIN, FULL, WIN, "retention"], n_experts=0,
+             experts_per_token=0, n_dense_layers=0, experts_held=(),
+             router_bias=False)
     with pytest.raises(ValueError, match="layer_types names none"):
         tiny(layer_types=[FULL] * 5)
     with pytest.raises(ValueError, match="even rotary_dim"):
