@@ -8,7 +8,13 @@ SURVEY.md §2.5 Ray LLM row):
     hidden, pp shards the stacked layer dim
   * layers are STACKED on axis 0 and applied with `lax.scan` + remat: one
     compiled layer body regardless of depth (XLA-friendly, constant compile
-    time), and the stack shards over `pp` for pipeline parallelism
+    time), and the stack shards over `pp` for pipeline parallelism. The
+    remat boundary keeps a layer's input and, where the attention is the
+    flash kernel, the kernel's output and log-sum-exp (the tags
+    ops.flash_attention.FLASH_SAVE_NAMES, read by remat_policy_fn): the
+    one thing in a layer dearer to recompute than to hold. Each layer's
+    weights are cast to the compute dtype in that layer's turn of the loop
+    (_in_its_turn): one copy a turn, none of the whole stack
   * attention: "full" (GSPMD auto-sharded), "ring" (manual `sp` ring over
     ICI — ray_tpu.parallel.ring_attention), or "ulysses" (all-to-all)
   * bf16 activations/compute, fp32 params & softmax/logit accumulators
@@ -26,6 +32,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
+from ray_tpu.ops.flash_attention import FLASH_SAVE_NAMES, kernel_calls
 from ray_tpu.parallel.attention import causal_attention
 from ray_tpu.parallel.mesh import shard_map_compat
 from ray_tpu.parallel.pipeline import pipeline_apply
@@ -738,20 +745,37 @@ SELECTIVE_SAVE_NAMES = ("attn_q", "attn_k", "attn_v", "attn_o",
 def remat_policy_fn(name: str):
     """Config string → jax.checkpoint policy (shared with mixtral).
 
-    "dots" saves EVERY dot output — including the [B, H, L, L] attention
-    scores, whose save cost scales L²; "selective" saves only the 7 named
-    projection outputs per layer (all [B, L, ·]), recomputing norms/rope/
-    attention — the TorchTitan-style middle ground between full remat
-    (max recompute) and dots (max residual memory)."""
+    What each keeps a layer, beside the layer's input:
+      "full"       the flash forward kernel's two outputs, o [B·H, L, D]
+                   and lse [B·H, 8, L] float32 (FLASH_SAVE_NAMES), where
+                   the program has them; everything else is recomputed.
+                   With any other attention (attention="full", the
+                   blockwise fallback off the TPU, ulysses) no value
+                   carries the names and nothing is kept: the rule follows
+                   what the program contains, not a knob
+      "selective"  those, and the 7 named projection outputs per layer
+                   (all [B, L, ·]), recomputing norms/rope/attention — the
+                   TorchTitan-style middle ground
+      "dots"       EVERY dot output — including the [B, H, L, L] attention
+                   scores of attention="full", whose save cost scales L²;
+                   the kernel is no dot and runs twice ("dots_no_batch"
+                   likewise)
+    The kernel's outputs are kept because nothing else can rebuild them:
+    2.73 ms a layer at [2, 4096, 32 x 128] for 72 MiB, twice the time a
+    byte of any projection "selective" keeps (PERF.md, PR 51). Under ring
+    attention the block kernel runs once a ROTATION and each rotation's
+    o / lse is kept: sp x those bytes a layer (no benchmark cell runs it;
+    whoever adds the long-context training cell sizes it first)."""
     if name == "full":
-        return None
+        return jax.checkpoint_policies.save_only_these_names(
+            *FLASH_SAVE_NAMES)
     if name == "dots":
         return jax.checkpoint_policies.checkpoint_dots
     if name == "dots_no_batch":
         return jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims
     if name == "selective":
         return jax.checkpoint_policies.save_only_these_names(
-            *SELECTIVE_SAVE_NAMES)
+            *SELECTIVE_SAVE_NAMES, *FLASH_SAVE_NAMES)
     raise ValueError(f"unknown remat_policy {name!r}")
 
 
@@ -791,16 +815,58 @@ def _layer(lp: Params, x, cfg: LlamaConfig, positions, attn_fn):
     return x
 
 
+@jax.custom_vjp
+def _held(ws):
+    """ws behind an optimization_barrier: XLA makes the values once, where
+    this stands, and everything after reads them. The cotangents are handed
+    straight back (a barrier's own transpose would hold them too, and a
+    weight gradient would be written out in the compute dtype before it
+    reaches the masters' stack)."""
+    return lax.optimization_barrier(ws)
+
+
+_held.defvjp(lambda ws: (lax.optimization_barrier(ws), None),
+             lambda _, g: (g,))
+
+
+def _in_its_turn(lp: Params, turn, cd) -> Params:
+    """The layer's weights in the compute dtype, cast in THIS turn of the
+    scan and held (_layer's own casts then do nothing; they stay for the
+    callers that hand it masters). Two things XLA does otherwise, both
+    read off the train cell (PERF.md, PR 51):
+      * it moves the cast of a slice of a loop-invariant stack out of the
+        loop: compute-dtype copies of EVERY layer's weights, alive through
+        the forward and the backward scan (2.6 GB of the chip's 16.9, for
+        which its own rematerialisation then pays in time). A zero that
+        depends on the scan's counter, added before the cast, keeps the
+        cast in its turn (w + 0.0 is w: no value changes); an
+        optimization_barrier alone does not (compiled for v5e);
+      * it then fuses the cast into every product that reads the weight,
+        a fifth slower each than one that reads the compute dtype: _held
+        makes one copy a turn instead.
+    The weight gradients reach the masters' dtype stack straight from the
+    products' float32 accumulators, as they did."""
+    zero = turn.astype(jnp.float32) * 0.0
+    return _held(jax.tree.map(
+        lambda w: w if w.dtype == cd
+        else (w + zero.astype(w.dtype)).astype(cd), lp))
+
+
 def _scan_layers(layers: Params, x, cfg: LlamaConfig, positions, attn_fn):
-    body = functools.partial(_layer, cfg=cfg, positions=positions,
-                             attn_fn=attn_fn)
+    layer = functools.partial(_layer, cfg=cfg, positions=positions,
+                              attn_fn=attn_fn)
+
+    def body(lp_turn, x):
+        return layer(_in_its_turn(*lp_turn, cfg.dtype), x)
+
     if cfg.remat:
         body = jax.checkpoint(body, policy=remat_policy_fn(cfg.remat_policy))
 
-    def step(x, lp):
-        return body(lp, x), None
+    def step(x, lp_turn):
+        return body(lp_turn, x), None
 
-    x, _ = lax.scan(step, x, layers)
+    n = jax.tree.leaves(layers)[0].shape[0]
+    x, _ = lax.scan(step, x, (layers, jnp.arange(n)))
     return x
 
 
@@ -841,6 +907,22 @@ def flash_impl() -> str:
     did not take the portable branch."""
     from ray_tpu.ops.flash_attention import kernels_supported
     return "kernel" if kernels_supported() else "blockwise"
+
+
+def flash_forward_calls(cfg: LlamaConfig, seq_len: int) -> int:
+    """Calls of the flash FORWARD kernel in one layer's value-and-grad at
+    this sequence length, counted in the traced program and not read off
+    a flag: 1 where the remat boundary keeps the kernel's output and
+    log-sum-exp (or there is no remat), 2 where the backward runs the
+    kernel again to rebuild them, 0 where the attention is not the kernel
+    (flash_impl() "blockwise", any other cfg.attention)."""
+    one = dataclasses.replace(cfg, n_layers=1)
+    params = jax.eval_shape(functools.partial(init_params, one),
+                            jax.random.PRNGKey(0))
+    tokens = jax.ShapeDtypeStruct((1, seq_len), jnp.int32)
+    grad = jax.grad(functools.partial(loss_fn, cfg=one))
+    return kernel_calls(jax.make_jaxpr(grad)(params, tokens).jaxpr)[
+        "_fwd_kernel"]
 
 
 def _make_attn_fn(cfg: LlamaConfig, mesh):

@@ -16,6 +16,10 @@ never materializing the [L, L] score matrix in HBM — in either pass.
     flash_tiling picks each kernel's blocks from the static shape; under
     the causal mask no kernel visits or fetches a block above the
     diagonal, and only the blocks the diagonal crosses are masked.
+    The forward kernel's two outputs carry the checkpoint names
+    FLASH_SAVE_NAMES: a remat policy that saves them (models/llama.py:
+    remat_policy_fn) hands the backward its o and lse, and the forward
+    kernel does not run a second time under the remat boundary.
 
 `blockwise_attention` is the pure-JAX (lax.scan) equivalent: same online
 softmax, differentiable by autodiff, used as the numerics reference and as
@@ -25,11 +29,13 @@ a portable fallback.
 from __future__ import annotations
 
 import functools
+from collections import Counter
 from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -718,6 +724,13 @@ def autotune_blocks(Lq: int, Lk: Optional[int] = None, head_dim: int = 64,
 # kernel (per-rotation fused block whose results merge by log-sum-exp)
 # --------------------------------------------------------------------------
 
+#: checkpoint_name tags on the forward kernel's two outputs, o [B·H, L, D]
+#: and lse [B·H, 8, L] float32: the only residuals of the backward that
+#: nothing but the kernel can rebuild. models/llama.py:remat_policy_fn
+#: reads them ("full" and "selective" save these names); nothing else does
+FLASH_SAVE_NAMES = ("flash_o", "flash_lse")
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention_block(q, k, v, causal: bool = True,
                           sm_scale: Optional[float] = None,
@@ -736,10 +749,17 @@ def flash_attention_block(q, k, v, causal: bool = True,
 
 
 def _block_vjp_fwd(q, k, v, causal, sm_scale, blk_q, blk_k, interpret):
+    """Forward rule: the kernel's o and lse are tagged FLASH_SAVE_NAMES
+    before anything is derived from them, so both the returned values and
+    the residuals come from the tagged arrays: under a jax.checkpoint whose
+    policy saves the names the backward is handed them, and only q, k and v
+    are rebuilt. Outside a checkpoint the tags do nothing."""
     B, Lq, H, D = q.shape
     scale = sm_scale if sm_scale is not None else D ** -0.5
     o, lse = _fwd_call(_bhl(q), _bhl(k), _bhl(v), causal, scale,
                        blk_q, blk_k, interpret)
+    o = checkpoint_name(o, FLASH_SAVE_NAMES[0])
+    lse = checkpoint_name(lse, FLASH_SAVE_NAMES[1])
     lse_bhl = lse[:, 0, :].reshape(B, H, Lq)
     return (_blhd(o, B, H), lse_bhl), (q, k, v, o, lse)
 
@@ -756,6 +776,21 @@ def _block_vjp_bwd(causal, sm_scale, blk_q, blk_k, interpret, res, g):
 
 
 flash_attention_block.defvjp(_block_vjp_fwd, _block_vjp_bwd)
+
+
+def kernel_calls(jaxpr) -> Counter:
+    """pallas_calls in a jaxpr by the kernel function's name, sub-jaxprs
+    included (a scan body counts once, however many turns it takes):
+    {"_fwd_kernel": 1, "_dq_kernel": 1, "_dkv_kernel": 1} is one layer's
+    value-and-grad with the forward's outputs kept."""
+    counts: Counter = Counter()
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            counts[eqn.params["jaxpr"].debug_info.func_name] += 1
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            counts += kernel_calls(sub)
+    return counts
 
 
 def kernels_supported() -> bool:

@@ -182,33 +182,180 @@ class TestMLP:
         assert float(l1) < float(l0)
 
 
+def _use_interpreted_flash_kernel(monkeypatch):
+    """attention="flash" resolves to the Pallas kernels in interpret mode
+    (off the TPU the program takes the blockwise branch by itself)."""
+    import importlib
+    fa = importlib.import_module("ray_tpu.ops.flash_attention")
+    monkeypatch.setattr(
+        llama, "_make_attn_fn",
+        lambda cfg, mesh: functools.partial(fa.flash_attention, blk_q=None,
+                                            blk_k=None, interpret=True))
+    return fa
+
+
 class TestRematPolicies:
     """remat_policy must be a pure speed/memory lever: every policy
     computes identical losses AND gradients (ISSUE 7 parity guard)."""
 
-    def _loss_and_grads(self, policy):
+    def _loss_and_grads(self, attention, L, **remat):
         cfg = llama.LlamaConfig.tiny(n_layers=2, dtype=jnp.float32,
-                                     remat_policy=policy)
+                                     attention=attention, **remat)
         params = llama.init_params(cfg, jax.random.PRNGKey(0))
-        tokens = make_inputs(cfg, B=2, L=16)
+        tokens = make_inputs(cfg, B=2, L=L)
         loss, grads = jax.jit(jax.value_and_grad(
             functools.partial(llama.loss_fn, cfg=cfg)))(params, tokens)
         return float(loss), grads
 
-    def test_policies_identical_loss_and_grads(self):
-        ref_loss, ref_grads = self._loss_and_grads("full")
-        for policy in ("dots", "selective"):
-            loss, grads = self._loss_and_grads(policy)
-            assert loss == pytest.approx(ref_loss, abs=1e-6), policy
-            for got, ref in zip(jax.tree.leaves(grads),
-                                jax.tree.leaves(ref_grads)):
-                np.testing.assert_allclose(
-                    np.asarray(got), np.asarray(ref),
-                    rtol=1e-5, atol=1e-6, err_msg=policy)
+    @pytest.mark.parametrize("attention,L", [("full", 16), ("flash", 128)],
+                             ids=["full", "flash_kernel"])
+    @pytest.mark.parametrize("other", [{"remat_policy": "dots"},
+                                       {"remat_policy": "selective"},
+                                       {"remat": False}],
+                             ids=["dots", "selective", "no_remat"])
+    def test_policies_identical_loss_and_grads(self, monkeypatch, attention,
+                                               L, other):
+        if attention == "flash":
+            _use_interpreted_flash_kernel(monkeypatch)
+        ref_loss, ref_grads = self._loss_and_grads(attention, L,
+                                                   remat_policy="full")
+        loss, grads = self._loss_and_grads(attention, L, **other)
+        assert loss == pytest.approx(ref_loss, abs=1e-6), other
+        for got, ref in zip(jax.tree.leaves(grads),
+                            jax.tree.leaves(ref_grads)):
+            np.testing.assert_allclose(
+                np.asarray(got), np.asarray(ref),
+                rtol=1e-5, atol=1e-6, err_msg=str(other))
 
     def test_unknown_policy_raises(self):
         with pytest.raises(ValueError, match="remat_policy"):
             llama.remat_policy_fn("nope")
+
+    @pytest.mark.parametrize("remat,forward_calls", [
+        ({"remat_policy": "full"}, 1), ({"remat_policy": "selective"}, 1),
+        ({"remat": False}, 1),
+        # no dot, so not kept: the backward runs the forward kernel again
+        ({"remat_policy": "dots"}, 2), ({"remat_policy": "dots_no_batch"}, 2),
+    ], ids=["full", "selective", "no_remat", "dots", "dots_no_batch"])
+    def test_forward_kernel_calls_a_layer(self, monkeypatch, remat,
+                                          forward_calls):
+        """What the remat boundary keeps decides how often the flash
+        forward runs: counted in the traced program, not read off a flag."""
+        fa = _use_interpreted_flash_kernel(monkeypatch)
+        cfg = llama.LlamaConfig.tiny(n_layers=2, dtype=jnp.float32,
+                                     attention="flash", **remat)
+        assert llama.flash_forward_calls(cfg, 128) == forward_calls
+        params = jax.eval_shape(functools.partial(llama.init_params, cfg),
+                                jax.random.PRNGKey(0))
+        jaxpr = jax.make_jaxpr(jax.grad(functools.partial(
+            llama.loss_fn, cfg=cfg)))(
+                params, jax.ShapeDtypeStruct((2, 128), jnp.int32))
+        # one scan body forward, one backward: three kernels a layer where
+        # the forward's outputs are kept, four where they are not
+        assert fa.kernel_calls(jaxpr.jaxpr) == {
+            "_fwd_kernel": forward_calls, "_dq_kernel": 1, "_dkv_kernel": 1}
+
+    def test_no_kernel_no_forward_calls(self):
+        # off the TPU attention="flash" is the blockwise scan: no kernel
+        cfg = llama.LlamaConfig.tiny(n_layers=2, attention="flash")
+        assert llama.flash_impl() == "blockwise"
+        assert llama.flash_forward_calls(cfg, 64) == 0
+        assert llama.flash_forward_calls(
+            dataclasses.replace(cfg, attention="full"), 64) == 0
+
+    def _saved(self, capsys, cfg, L, policy):
+        """Lines of print_saved_residuals for one checkpointed layer."""
+        params = llama.init_params(cfg, jax.random.PRNGKey(0))
+        lp = jax.tree.map(lambda a: a[0], params["layers"])
+        body = jax.checkpoint(
+            functools.partial(llama._layer, cfg=cfg,
+                              positions=jnp.arange(L),
+                              attn_fn=llama._make_attn_fn(cfg, None)),
+            policy=llama.remat_policy_fn(policy))
+        capsys.readouterr()
+        jax.ad_checkpoint.print_saved_residuals(
+            body, lp, jnp.zeros((2, L, cfg.dim), cfg.dtype))
+        return [ln for ln in capsys.readouterr().out.splitlines()
+                if " from the argument " not in ln
+                and not ln.endswith("from a constant")]
+
+    # "selective": six projections beside them (nothing in the backward
+    # reads mlp_down's output, so the seventh name is never a residual)
+    @pytest.mark.parametrize("policy,others", [("full", 0), ("selective", 6)])
+    def test_kernel_outputs_are_saved_by_name(self, monkeypatch, capsys,
+                                              policy, others):
+        _use_interpreted_flash_kernel(monkeypatch)
+        cfg = llama.LlamaConfig.tiny(n_layers=2, attention="flash")
+        B, L, BH = 2, 128, 2 * cfg.n_heads
+        kept = self._saved(capsys, cfg, L, policy)
+        assert len(kept) == 2 + others, kept
+        # lse by its name; o by its shape (jax puts a reduce_precision on
+        # a kept bf16 value, and the line names that and not the tag)
+        assert any(ln.startswith(f"f32[{BH},8,{L}] named 'flash_lse'")
+                   for ln in kept), kept
+        assert any(ln.startswith(f"bf16[{BH},{L},{cfg.head_dim}] ")
+                   for ln in kept), kept
+
+    @pytest.mark.parametrize("attention", ["full", "flash"])
+    def test_full_keeps_only_the_layer_input_without_the_kernel(
+            self, capsys, attention):
+        # attention="flash" off the TPU is the blockwise scan: no value
+        # carries the names, and "full" keeps what it always kept
+        cfg = llama.LlamaConfig.tiny(n_layers=2, attention=attention)
+        assert self._saved(capsys, cfg, 32, "full") == []
+
+    def test_cast_in_its_turn_changes_no_value(self):
+        """_scan_layers casts each layer's weights in that layer's turn,
+        after adding a zero that depends on the scan's counter, and holds
+        them (_in_its_turn, so that no compute-dtype copy of the whole
+        stack is made): loss and every gradient equal, bit for bit, those
+        of a plain scan over the masters, and the gradients come back in
+        the masters' dtype."""
+        cfg = llama.LlamaConfig.tiny(n_layers=2)
+        assert cfg.dtype == jnp.bfloat16 and cfg.param_dtype == jnp.float32
+        params = llama.init_params(cfg, jax.random.PRNGKey(0))
+        tokens = make_inputs(cfg, B=2, L=16)
+
+        def over_the_masters(params, tokens):
+            body = jax.checkpoint(
+                functools.partial(llama._layer, cfg=cfg,
+                                  positions=jnp.arange(tokens.shape[1]),
+                                  attn_fn=llama._full_attention),
+                policy=llama.remat_policy_fn(cfg.remat_policy))
+            x, _ = jax.lax.scan(lambda x, lp: (body(lp, x), None),
+                                params["embed"].astype(cfg.dtype)[tokens],
+                                params["layers"])
+            x = llama._rmsnorm(x, params["final_norm"], cfg.norm_eps)
+            logits = jnp.einsum("bld,vd->blv", x,
+                                params["embed"].astype(cfg.dtype),
+                                preferred_element_type=jnp.float32)
+            return llama._nll_mean(logits, tokens)
+
+        loss, grads = jax.jit(jax.value_and_grad(functools.partial(
+            llama.loss_fn, cfg=cfg)))(params, tokens)
+        ref_loss, ref = jax.jit(jax.value_and_grad(over_the_masters))(
+            params, tokens)
+        assert float(loss) == float(ref_loss)
+        for got, want, p in zip(jax.tree.leaves(grads), jax.tree.leaves(ref),
+                                jax.tree.leaves(params)):
+            assert got.dtype == p.dtype == jnp.float32
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    def test_in_its_turn_casts_and_changes_no_value(self):
+        lp = {"w": jnp.arange(16, dtype=jnp.bfloat16).reshape(4, 4),
+              "m": jnp.array([-0.0, 1.0000001, -3.14159, 1e-30], jnp.float32)}
+        out = llama._in_its_turn(lp, jnp.int32(3), jnp.bfloat16)
+        for k, w in lp.items():
+            assert out[k].dtype == jnp.bfloat16
+            np.testing.assert_array_equal(np.asarray(out[k]),
+                                          np.asarray(w.astype(jnp.bfloat16)))
+        # the cotangent of a held weight comes back as it is, in the
+        # master's dtype
+        g = jax.grad(lambda m: llama._in_its_turn(
+            {"m": m}, jnp.int32(0), jnp.bfloat16)["m"].astype(
+                jnp.float32).sum())(lp["m"])
+        assert g.dtype == jnp.float32
+        np.testing.assert_array_equal(np.asarray(g), np.ones(4, np.float32))
 
 
 class TestFsdpOverlap:

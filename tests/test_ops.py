@@ -176,6 +176,55 @@ class TestFlashTiles:
                 err_msg=f"d{name} mismatch")
 
 
+class TestFlashSaveNames:
+    """The forward kernel's outputs carry FLASH_SAVE_NAMES: a checkpoint
+    that saves the names runs the forward once, and the merged (dlse != 0)
+    backward still matches the naive product."""
+
+    @pytest.mark.parametrize("saved,forward_calls", [(True, 1), (False, 2)],
+                             ids=["names_saved", "nothing_saved"])
+    def test_dlse_path_under_a_checkpoint(self, saved, forward_calls):
+        import importlib
+        fa = importlib.import_module("ray_tpu.ops.flash_attention")
+        B, L, H, D = 1, 128, 2, 32
+        ks = jax.random.split(jax.random.PRNGKey(11), 5)
+        q, k, v, w = (jax.random.normal(kk, (B, L, H, D)) for kk in ks[:4])
+        u = jax.random.normal(ks[4], (B, H, L))    # a nonzero dlse
+        policy = jax.checkpoint_policies.save_only_these_names(
+            *(fa.FLASH_SAVE_NAMES if saved else ()))
+
+        def loss(fn, q, k, v):
+            o, lse = fn(q, k, v)
+            return (o * w).sum() + (lse * u).sum()
+
+        flash = jax.checkpoint(
+            lambda q, k, v: fa.flash_attention_block(q, k, v, True, None,
+                                                     64, 64, True),
+            policy=policy)
+        grad = functools.partial(jax.grad, argnums=(1, 2, 3))
+        g = grad(loss)(flash, q, k, v)
+        g_ref = grad(loss)(functools.partial(_naive_block, causal=True),
+                           q, k, v)
+        for name, a, b in zip("qkv", g_ref, g):
+            np.testing.assert_allclose(
+                np.asarray(b), np.asarray(a), rtol=2e-3, atol=2e-3,
+                err_msg=f"d{name} mismatch")
+        jaxpr = jax.make_jaxpr(jax.grad(functools.partial(loss, flash),
+                                        argnums=(0, 1, 2)))(q, k, v)
+        assert fa.kernel_calls(jaxpr.jaxpr) == {
+            "_fwd_kernel": forward_calls, "_dq_kernel": 1, "_dkv_kernel": 1}
+
+    def test_names_do_nothing_outside_a_checkpoint(self):
+        import importlib
+        fa = importlib.import_module("ray_tpu.ops.flash_attention")
+        q, k, v = make_qkv(B=1, L=128, H=2, D=32)
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda q: fa.flash_attention(q, k, v, interpret=True,
+                                         blk_q=64, blk_k=64).sum()))(q)
+        assert fa.kernel_calls(jaxpr.jaxpr) == {
+            "_fwd_kernel": 1, "_dq_kernel": 1, "_dkv_kernel": 1}
+
+
 class TestBlockAutotune:
     @pytest.fixture(autouse=True)
     def _clean_cache(self):

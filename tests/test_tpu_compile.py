@@ -151,6 +151,53 @@ def test_flash_compiles_at_the_train_cells_shape(chip):
                                     blocks=(None, None)) == 3
 
 
+def test_train_layer_keeps_the_forward_kernels_outputs(chip, monkeypatch):
+    """Three layers of mistral7b-train-1chip under its remat ("full"), value
+    and gradient, through the one-device mesh the trainer hands loss_fn:
+    the compiled program holds THREE flash kernels, not four (the forward
+    is not run again under the remat boundary), and each is still the
+    instruction benchmark/metrics/flash_attn_roofline.json looks for (the
+    region a kernel is traced in decides its name's prefix). No
+    compute-dtype copy of a whole stacked weight is made (_in_its_turn;
+    without it XLA holds one of each through both loops)."""
+    import json
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from ray_tpu.models import llama
+    from ray_tpu.parallel import MeshSpec, build_mesh
+    monkeypatch.setattr(fa, "kernels_supported", lambda: True)
+    cfg = llama.LlamaConfig(
+        vocab_size=32768, dim=4096, n_layers=3, n_heads=32, n_kv_heads=8,
+        ffn_dim=14336, rope_theta=1e6, attention="flash")
+    assert cfg.remat and cfg.remat_policy == "full"
+    mesh = build_mesh(MeshSpec(), devices=list(chip.device_set))
+    layers = jax.eval_shape(functools.partial(llama.init_params, cfg),
+                            jax.random.PRNGKey(0))["layers"]
+    layers = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=NamedSharding(mesh, P())), layers)
+    x = jax.ShapeDtypeStruct((2, 4096, cfg.dim), cfg.dtype,
+                             sharding=NamedSharding(mesh, P()))
+
+    def loss(layers, x):
+        return llama._scan_layers(
+            layers, x, cfg, jnp.arange(4096),
+            llama._make_attn_fn(cfg, mesh)).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        layers, x).compile().as_text()
+    calls = [ln.strip().removeprefix("ROOT ").lstrip("%")
+             for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 3, [c[:60] for c in calls]
+    with open(os.path.join(os.path.dirname(__file__), os.pardir, "benchmark",
+                           "metrics", "flash_attn_roofline.json")) as f:
+        kernels = json.load(f)["args"]["kernels"]
+    for kind, patterns in kernels.items():
+        found = [c[:40] for c in calls
+                 if any(re.search(p, c) for p in patterns)]
+        assert len(found) == 1, (kind, found)
+    assert not any(c.startswith("rematted_computation") for c in calls)
+    assert "f32[3,4096,14336]" in text and "bf16[3,4096,14336]" not in text
+
+
 @pytest.mark.parametrize("Lq,Lk,causal", [
     (192, 192, True),      # the old divisor pick gave blk_q = 64: refused
     (1024, 2048, False),   # an off-diagonal rotation with longer keys
