@@ -341,6 +341,13 @@ def _plain_checks(handle, prompts: list, results: list) -> list:
     return out
 
 
+def _compile_wall_s(compile_seconds) -> float:
+    """Seconds of the calls that compiled, over every callable of the
+    replica's report (engine_report(): one dict a name)."""
+    return round(sum(c["wall_s"] for c in (compile_seconds or {}).values()),
+                 1)
+
+
 def _serve_summary(dep: dict, spec: dict) -> dict:
     """One deployment's line: the replica's report plus what the client
     side saw (token ids themselves are left out)."""
@@ -382,7 +389,7 @@ def run_serve(spec: dict, seed: int) -> dict:
            "prompts_equal_to_plain_path": sum(
                bool(c and c["equal"]) for c in checks),
            "wall_s": round(time.time() - t0, 1)}
-    out["compile_s"] = round(sum((out["compile_seconds"] or {}).values()), 1)
+    out["compile_s"] = _compile_wall_s(out["compile_seconds"])
     out["cache"] = _cache_state(out["compile_counts"] or {})
     return out
 
@@ -679,8 +686,7 @@ def run_serve_tp(spec: dict, seed: int, tp: int = 4) -> dict:
            "single": _serve_summary(single, spec),
            "prompts_with_identical_tokens": sum(same),
            "plain_check": checks, "wall_s": round(time.time() - t0, 1)}
-    out["compile_s"] = round(sum(
-        (out["sharded"]["compile_seconds"] or {}).values()), 1)
+    out["compile_s"] = _compile_wall_s(out["sharded"]["compile_seconds"])
     out["cache"] = _cache_state(out["sharded"]["compile_counts"] or {})
     return out
 

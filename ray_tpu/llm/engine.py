@@ -136,6 +136,7 @@ from ray_tpu.llm.cache import (SCRATCH_PAGE, STATE_LEAVES, WINDOW_LEAVES,
 from ray_tpu.llm import model as M
 from ray_tpu.llm.tp import build_tp_mesh
 from ray_tpu.models.llama import LlamaConfig
+from ray_tpu.util import startup_clocks
 
 TraceAnnotation = jax.profiler.TraceAnnotation
 logger = logging.getLogger(__name__)
@@ -264,6 +265,13 @@ class InferenceEngine:
                  request_log: Optional[bool] = None,
                  tp: int = 1, devices=None):
         from ray_tpu.core.config import GlobalConfig
+        # start-up clocks (util/startup_clocks.py): backend, weights and
+        # pool here, programs in load_step_programs; stats keys, once made
+        startup: Dict[str, int] = {}
+        with startup_clocks.phase("backend", startup):
+            # the accelerator runtime's start, on its own: the weights'
+            # birth below would start it anyway, and hide it in its time
+            jax.devices()
         self.cfg = cfg
         self.page_size = page_size
         self.max_batch = max_batch
@@ -317,16 +325,20 @@ class InferenceEngine:
             page_size=page_size)
         # weights and pool are created IN their final layout (sharded
         # over the mesh under tp): no device ever stages the whole model
-        self.params = self._fns.init_params(seed) if params is None \
-            else self._fns.place_params(params)
+        with startup_clocks.phase("weights", startup):
+            self.params = jax.block_until_ready(
+                self._fns.init_params(seed) if params is None
+                else self._fns.place_params(params))
         # a second page group for window layers, sized from the geometry
         # above so that it never binds (llm/cache.py); 0 = no such layer
         window_pages = window_group_pages(
             cfg, page_size, max_batch, self.decode_chunk,
             self.prefill_chunk, self.prefill_rows)
         self._window = cfg.sliding_window if window_pages else 0
-        self.kv = self._fns.init_kv(total_pages, page_size, self.kv_dtype,
-                                    max_batch, window_pages)
+        with startup_clocks.phase("pool", startup):
+            self.kv = jax.block_until_ready(self._fns.init_kv(
+                total_pages, page_size, self.kv_dtype, max_batch,
+                window_pages))
         # device_report()'s sizes, taken HERE: every step donates the
         # pool, so its arrays die under a reader on another thread
         self._param_bytes = sum(x.nbytes
@@ -471,6 +483,9 @@ class InferenceEngine:
         # every span of the host loop and its wall / CPU counters; the
         # serve loop opens serve.publish and serve.wait through it too
         self.phase = PhaseClocks(self.stats).phase
+        self.stats.update(startup)
+        #: load_step_programs' records, one a program (startup_clocks)
+        self.startup_programs: List[Dict[str, object]] = []
         # per-request flight recorder (llm/request_log.py): lifecycle
         # event stream per request + TTFT/TPOT/e2e/queue-wait histograms
         # + SLO attainment; None disables every hook (seq.record stays
@@ -566,20 +581,45 @@ class InferenceEngine:
         and the scratch state slot; the decode loop as an engine whose
         slots are all free dispatches it; the scratch page copied onto
         itself. Which shapes traffic reaches first is then nobody's luck:
-        nothing compiles after this returns. It books nothing: no counter
-        moves, no span opens, no request record exists. A served replica
-        calls it before its engine thread starts (LLMServer); a bare
-        engine compiles lazily, on first use."""
-        for n_rows in self._fns.row_shapes:
-            _, self.kv = self._fns.ragged_step(
+        nothing compiles after this returns. It books nothing of the
+        steady state: no counter of a step moves, no engine.* span opens,
+        no request record exists. What it does write is its own start-up
+        clock (util/startup_clocks.py): the span startup.programs and the
+        key startup_ns_programs and, inside it, one span startup.program
+        and one record in ``startup_programs`` a program (each blocked on:
+        its run_s is the run on padding), with their sums in
+        startup_ns_trace_lower, startup_ns_backend_compile and
+        startup_programs_cold. A served replica calls it before its engine
+        thread starts (LLMServer); a bare engine compiles lazily, on first
+        use."""
+        fns = self._fns
+
+        def mixed(n_rows: int) -> None:
+            _, self.kv = fns.ragged_step(
                 self.params, jax.device_put(self._pack_mixed([], [], n_rows)),
                 self.kv)
-        _, self.kv, _, _ = self._fns.decode_loop(
-            self.params, jax.device_put(self._pack_decode([])), self.kv)
-        if self.prefix is not None:
+
+        def decode() -> None:
+            _, self.kv, _, _ = fns.decode_loop(
+                self.params, jax.device_put(self._pack_decode([])), self.kv)
+
+        def copy() -> None:
             scratch = jnp.int32(SCRATCH_PAGE)
-            self.kv = self._fns.copy_page(self.kv, scratch, scratch)
-        jax.block_until_ready(self.kv)
+            self.kv = fns.copy_page(self.kv, scratch, scratch)
+
+        turns = [("llm.ragged_step", {"rows": n}, lambda n=n: mixed(n))
+                 for n in fns.row_shapes] + [("llm.decode_loop", {}, decode)]
+        if self.prefix is not None:
+            turns.append(("llm.copy_page", {}, copy))
+        loaded = self.startup_programs = []
+        with startup_clocks.phase("programs", self.stats):
+            for name, meta, run in turns:
+                with startup_clocks.program(name, fns.tracker,
+                                            **meta) as rec:
+                    run()
+                    jax.block_until_ready(self.kv)
+                loaded.append(rec)
+        self.stats.update(startup_clocks.program_totals(loaded))
 
     def device_report(self) -> Dict[str, object]:
         """Where this engine runs and what it compiled: the devices that

@@ -127,7 +127,7 @@ import collections
 import contextlib
 import functools
 import math
-from typing import NamedTuple, Optional, Tuple
+from typing import List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -1244,20 +1244,29 @@ class StepPrograms:
             self.jits = self._shard_mapped(step_statics, loop_statics,
                                            kv_quantized)
         self.tracker = compile_tracker.ensure_started()
+        #: what the seam wrapped, as the tracker names it: the three step
+        #: programs and, once called, the births of weights and pool
+        self.tracked: List[str] = []
         self.ragged_step = self._callable("ragged_step")
         self.decode_loop = self._callable("decode_loop")
         self.copy_page = self._callable("copy_page")
 
-    def _callable(self, name: str):
-        jit, statics = self.jits[name]
-        call = functools.partial(jit, **statics) if statics else jit
+    def _callable(self, name: str, call=None, probe=None):
+        """``call`` (the step program ``name`` with its statics, without
+        one) as the engine calls it: where a compile tracker runs, every
+        compile is recorded as ``llm.<name>`` with its arg signature, the
+        ground truth for the O(1)-compile invariant in production.
+        ``probe`` counts compiled programs, and growth across one call is
+        that call's: the step programs' count without one."""
+        if call is None:
+            jit, statics = self.jits[name]
+            call = functools.partial(jit, **statics) if statics else jit
         if self.tracker is None:
             return call
-        # every compile is recorded with its arg signature, the ground
-        # truth for the O(1)-compile invariant in production. The probe
-        # is the program count: growth across one call is that call's
+        if "llm." + name not in self.tracked:
+            self.tracked.append("llm." + name)
         return self.tracker.wrap(call, name="llm." + name,
-                                 probe=self.compiled_step_programs)
+                                 probe=probe or self.compiled_step_programs)
 
     def _shard_mapped(self, step_statics, loop_statics, kv_quantized):
         """The three programs over ``self.mesh`` (and, kept for the
@@ -1299,11 +1308,18 @@ class StepPrograms:
         return sum(jit._cache_size() for jit, _ in self.jits.values())
 
     def init_params(self, seed: int) -> Params:
-        key = jax.random.PRNGKey(seed)
         if self.mesh is None:
-            return _init_params(self.cfg, key)
-        return jax.jit(functools.partial(init_params, self.cfg),
-                       out_shardings=self._param_sharding)(key)
+            jit = functools.partial(_init_params, self.cfg)
+            probe = _init_params._cache_size
+        else:
+            jit = jax.jit(functools.partial(init_params, self.cfg),
+                          out_shardings=self._param_sharding)
+            probe = jit._cache_size
+        # the key is made inside the tracked call: its small programs are
+        # this name's compiles too, not nameless ring records
+        return self._callable(
+            "init_params", lambda seed: jit(jax.random.PRNGKey(seed)),
+            probe)(seed)
 
     def place_params(self, params: Params) -> Params:
         if self.mesh is None:
@@ -1319,8 +1335,12 @@ class StepPrograms:
                                  lane_pad=self.paged_impl == "kernel",
                                  window_pages=window_pages)
         if self.mesh is None:
-            return make()
-        return jax.jit(make, out_shardings=self._kv_sharding)()
+            # eager zeros, a small program a leaf shape: no jit's cache to
+            # probe, so a call compiled if the tracker's listener saw the
+            # backend compile during it (a probe that never grows)
+            return self._callable("init_kv", make, lambda: 0)()
+        jit = jax.jit(make, out_shardings=self._kv_sharding)
+        return self._callable("init_kv", jit, jit._cache_size)()
 
 
 # ---------------------------------------------------------------------------

@@ -29,7 +29,7 @@ from typing import Any, Dict, Iterator, List, Optional
 from ray_tpu.llm.engine import InferenceEngine, TraceAnnotation
 from ray_tpu.llm.tokenizer import ByteTokenizer
 from ray_tpu.models.llama import LlamaConfig
-from ray_tpu.util import log_plane, trace_context
+from ray_tpu.util import log_plane, startup_clocks, trace_context
 
 
 def _ambient_trace_id() -> str:
@@ -50,6 +50,9 @@ class LLMServer:
                  engine_config: Optional[Dict[str, Any]] = None,
                  tokenizer=None, model_name: str = "rtpu-llm",
                  chat_template=None):
+        # this replica's start-up clocks (util/startup_clocks.py): the
+        # worker's record where the runtime opened one, else one from here
+        startup_clocks.begin()
         cfg = LlamaConfig.tiny(**(model_config or {}))
         self.engine = InferenceEngine(cfg, **(engine_config or {}))
         # every program the engine can dispatch, compiled and loaded now:
@@ -57,8 +60,13 @@ class LLMServer:
         # traffic reaches first
         self.engine.load_step_programs()
         self.engine.track_progress = True  # the serve loop drains it
-        # hand-overs to the waiters, and those made under a running program
-        self.engine.stats.update(publishes=0, publishes_overlapped=0)
+        # hand-overs to the waiters, and those made under a running
+        # program; and every start-up key, so that none is new to the
+        # dict once the engine thread reads it
+        stats = self.engine.stats
+        stats.update(publishes=0, publishes_overlapped=0)
+        for key in startup_clocks.SERVE_KEYS:
+            stats.setdefault(key, 0)
         # (finished, progress) of the last step, not yet handed over
         self._held = None
         self.tokenizer = tokenizer or ByteTokenizer()
@@ -77,6 +85,10 @@ class LLMServer:
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="llm-engine")
         self._thread.start()
+        # written once, here: a window's difference of these keys is 0
+        startup_clocks.finish(stats, startup_clocks.SERVE_PHASES)
+        startup_clocks.log_summary(stats, startup_clocks.SERVE_PHASES,
+                                   self.engine.startup_programs)
 
     def _loop(self) -> None:
         """The engine thread. A step's tokens (its finished sequences and
@@ -473,9 +485,14 @@ class LLMServer:
     def engine_report(self) -> Dict[str, Any]:
         """What this replica runs on and what it compiled (the engine's
         device_report()), plus this process's compile accounting: the
-        tracker's per-kind counts — persistent-cache hits and misses
-        among them — the seconds spent compiling, and where the
-        persistent compile cache lives."""
+        tracker's per-kind counts (``compile_counts``: persistent-cache
+        hits and misses among them), where the persistent compile cache
+        lives, and ``compile_seconds``: for every callable the seam
+        wrapped (llm.init_params, llm.init_kv, the step programs) one
+        dict {wall_s, trace_s, lower_s, backend_s, compiles, cache_hits}
+        — seconds of the calls that compiled, of them tracing, lowering
+        and in the backend (a cache hit's backend seconds are the
+        retrieval), and how many compiles were persistent-cache hits."""
         import os
 
         from ray_tpu.util import compile_cache, compile_tracker
@@ -483,11 +500,13 @@ class LLMServer:
         tracker = compile_tracker.get_global()
         if tracker is not None:
             out["compile_counts"] = tracker.stats()["counts"]
-            out["compile_seconds"] = {
-                name: round((tracker.callable_stats(name)
-                             or {}).get("wall_s", 0.0), 3)
-                for name in ("llm.ragged_step", "llm.decode_loop",
-                             "llm.copy_page")}
+            fields = ("wall_s", "trace_s", "lower_s", "backend_s",
+                      "compiles", "cache_hits")
+            out["compile_seconds"] = {}
+            for name in self.engine._fns.tracked:
+                st = tracker.callable_stats(name) or {}
+                out["compile_seconds"][name] = {
+                    k: round(st.get(k, 0), 3) for k in fields}
         out["compile_cache_dir"] = os.environ.get(compile_cache.ENV_VAR)
         out["pid"] = os.getpid()
         return out

@@ -119,9 +119,12 @@ def tpu_memory_samples() -> List[Sample]:
     chip the train worker needs."""
     import sys
     jax = sys.modules.get("jax")
-    bridge = sys.modules.get("jax._src.xla_bridge")
-    if jax is None or bridge is None \
-            or not bridge.backends_are_initialized():
+    # a module another thread is still importing is in sys.modules
+    # without its functions: a flush that raised here would lose the
+    # events it had drained (a worker's first spans, while jax loads)
+    initialized = getattr(sys.modules.get("jax._src.xla_bridge"),
+                          "backends_are_initialized", None)
+    if jax is None or initialized is None or not initialized():
         return []
     out: List[Sample] = []
     try:

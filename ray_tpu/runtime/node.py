@@ -29,6 +29,7 @@ from ray_tpu.core._native import ShmStore
 from ray_tpu.core.ids import NodeID, WorkerID
 from ray_tpu.runtime.protocol import ClientPool, RpcError, RpcServer
 from ray_tpu.util import metrics as metrics_mod
+from ray_tpu.util import startup_clocks
 
 
 def _proc_dead(proc) -> bool:
@@ -51,7 +52,7 @@ def _proc_dead(proc) -> bool:
 class _WorkerEntry:
     __slots__ = ("worker_id", "proc", "address", "ready", "state", "actor_id",
                  "chips", "env_key", "idle_since", "cgroup_leaf",
-                 "out_path", "err_path", "log_path")
+                 "out_path", "err_path", "log_path", "leased_wall_ns")
 
     def __init__(self, worker_id: bytes, proc: subprocess.Popen,
                  env_key: str = ""):
@@ -73,6 +74,11 @@ class _WorkerEntry:
         # workers by runtime_env hash, worker_pool.h:224)
         self.env_key = env_key
         self.idle_since: Optional[float] = None
+        # when a lease took this worker out of the idle pool (wall ns):
+        # where its start-up clocks begin instead of at the spawn, which
+        # no lease caused (util/startup_clocks.py); None for a worker
+        # spawned for its lease
+        self.leased_wall_ns: Optional[int] = None
 
 
 class NodeDaemon:
@@ -315,7 +321,9 @@ class NodeDaemon:
         worker_id = WorkerID.from_random().binary()
         from ray_tpu.runtime.spawn import child_env
         extra = {"RTPU_SESSION": self.session,
-                 "RTPU_NODE_ID": getattr(self, "node_id", "")}
+                 "RTPU_NODE_ID": getattr(self, "node_id", ""),
+                 # where the worker's start-up clocks begin
+                 startup_clocks.SPAWN_ENV: str(time.time_ns())}
         if chips is None and getattr(self, "chips", None) is not None:
             # a chip has one owner: on a TPU host only workers leased
             # with TPU resources may see it, so every other worker's jax
@@ -663,6 +671,7 @@ class NodeDaemon:
                     if _proc_dead(entry.proc):
                         continue  # the waitpid loop reports the death
                     entry.state = "leased"
+                    entry.leased_wall_ns = time.time_ns()
                     return {"worker_id": wid, "worker_addr": entry.address}
             # count in-flight spawns too — concurrent lease RPCs must not
             # overshoot the pool cap between check and spawn
@@ -803,6 +812,7 @@ class NodeDaemon:
         self._clients.get(entry.address).call("become_actor", {
             "spec_bytes": p["spec_bytes"],
             "num_restarts": p.get("num_restarts", 0),
+            "lease_wall_ns": entry.leased_wall_ns,
         })
         return True
 

@@ -140,4 +140,8 @@ def actor_to_wire(spec: ActorCreationSpec) -> Tuple[dict, list]:
         "method_groups": dict(spec.method_groups),
         "owner": spec.owner.binary() if spec.owner else b"",
     }
+    # the creation is a span of the ambient trace like any submit: the
+    # worker runs the constructor under it (worker_main._become_actor),
+    # so what the constructor records (its start-up spans) hangs there
+    trace_context.stamp(payload)
     return payload, contained

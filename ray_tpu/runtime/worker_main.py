@@ -36,7 +36,7 @@ from ray_tpu.runtime import wire
 from ray_tpu.runtime.protocol import (_COMBINED_DONE, DEFERRED, RpcClient,
                                       RpcError)
 from ray_tpu.util import metrics as metrics_mod
-from ray_tpu.util import trace_context
+from ray_tpu.util import startup_clocks, trace_context
 
 
 class _LogShipper:
@@ -435,8 +435,17 @@ class Executor:
         spec = pickle_loads(payload["spec_bytes"])
         self.actor_id = spec["actor_id"]
         num_restarts = payload.get("num_restarts", 0)
+        # the creation's span, ambient for the constructor; and the
+        # worker's start-up clocks, from the lease that took it out of the
+        # pool or else from its spawn (util/startup_clocks.py)
+        t_start = time.time()
+        trace_tok = trace_context.activate(
+            spec.get("trace_id"), spec.get("span_id"))
+        startup_clocks.begin(payload.get("lease_wall_ns")
+                             or startup_clocks.spawn_stamp())
         try:
-            cls = cloudpickle.loads(spec["cls_bytes"])
+            with startup_clocks.phase("import"):
+                cls = cloudpickle.loads(spec["cls_bytes"])
             args, kwargs = self._resolve_args(spec["args"], spec["kwargs"])
             self.actor_instance = cls(*args, **kwargs)
             # ALL extra lanes (default max_concurrency and groups) start
@@ -479,6 +488,12 @@ class Executor:
             except RpcError:
                 pass
             return
+        finally:
+            trace_context.deactivate(trace_tok)
+            self._record_creation_span(spec, t_start)
+            # clocks the constructor did not finish wait for the method
+            # that does (_TrainWorker.run): idle until then
+            startup_clocks.pause()
         try:
             self.backend.head.call("actor_ready", {
                 "actor_id": spec["actor_id"],
@@ -486,6 +501,20 @@ class Executor:
                 "address": self.backend.server.address})
         except RpcError:
             pass
+
+    def _record_creation_span(self, spec: dict, t_start: float) -> None:
+        """The constructor's span (kind actor_create) under the trace the
+        creation was submitted in: the parent of its start-up spans."""
+        buf = getattr(self.backend, "event_buffer", None)
+        if buf is None or not spec.get("span_id"):
+            return
+        buf.record(name=f"{spec.get('name') or 'actor'}.__init__",
+                   task_id=bytes(spec["actor_id"]).hex()[:16],
+                   kind="actor_create", start=t_start, end=time.time(),
+                   ok=self.actor_instance is not None,
+                   trace_id=spec.get("trace_id", ""),
+                   span_id=spec["span_id"],
+                   parent_span_id=spec.get("parent_span_id", ""))
 
     def _execute(self, payload: dict, ctx) -> None:
         task_id = payload["task_id"]

@@ -646,10 +646,18 @@ def _fmt_compile_record(rec: dict) -> str:
     mark = "RECOMPILE " if rec.get("recompile") else ""
     sig = rec.get("signature") or []
     sig_s = ", ".join(sig[:6]) + (", ..." if len(sig) > 6 else "")
+    # the measured seconds by phase; a persistent-cache hit's backend
+    # seconds are the cache's retrieval
+    split = "".join(
+        f" {label} {rec[key] * 1e3:.1f}" for label, key in (
+            ("trace", "trace_s"), ("lower", "lower_s"),
+            ("backend", "backend_s")) if rec.get(key))
+    if rec.get("cache_hit"):
+        split += " hit"
     line = (f"{ts}  {rec.get('role', '?'):<7}"
             f"{(rec.get('worker') or '')[:12]:<13}"
             f"{mark}{name}  [{rec.get('kind', '?')}] {dur * 1e3:.1f}ms"
-            f"  ({sig_s})")
+            f"{' =' + split if split else ''}  ({sig_s})")
     for d in rec.get("diff") or []:
         line += f"\n           diff {d}"
     return line
@@ -668,7 +676,8 @@ def _render_compiles(client, args) -> str:
                     "processes flush every metrics_export_period_s; is "
                     "compile_tracker_enabled on?)")
         lines.append(f"{'callable':<28} {'compiles':>8} {'recompiles':>10}"
-                     f" {'seconds':>9} {'procs':>6}  last signature")
+                     f" {'hits':>5} {'seconds':>9} {'trace+lower':>11}"
+                     f" {'backend':>9} {'procs':>6}  last signature")
         rows = sorted(agg.items(),
                       key=lambda kv: (-kv[1]["recompiles"],
                                       -kv[1]["seconds"]))
@@ -676,7 +685,11 @@ def _render_compiles(client, args) -> str:
             sig = a.get("last_sig") or []
             sig_s = ", ".join(sig[:4]) + (", ..." if len(sig) > 4 else "")
             lines.append(f"{name:<28} {a['compiles']:>8}"
-                         f" {a['recompiles']:>10} {a['seconds']:>9.3f}"
+                         f" {a['recompiles']:>10}"
+                         f" {a.get('cache_hits', 0):>5}"
+                         f" {a['seconds']:>9.3f}"
+                         f" {a.get('trace_lower_s', 0.0):>11.3f}"
+                         f" {a.get('backend_s', 0.0):>9.3f}"
                          f" {a['procs']:>6}  ({sig_s})")
             for d in a.get("last_diff") or []:
                 lines.append(f"{'':<28} diff {d}")

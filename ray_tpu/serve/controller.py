@@ -34,6 +34,7 @@ from typing import Any, Dict, List, Optional
 import ray_tpu
 from ray_tpu.exceptions import ActorError
 from ray_tpu.serve.replica import Replica
+from ray_tpu.util import startup_clocks, trace_context
 
 logger = logging.getLogger("ray_tpu.serve")
 
@@ -72,6 +73,9 @@ class _DeploymentState:
         self.replicas: List[Any] = []          # live ActorHandles
         self.ready: set = set()                # actor-id hexes that passed
         #                                        a health probe (constructed)
+        # actor-id hex -> the serve.replica_start span in hand, of a
+        # replica no health probe has found ready yet (_start_replica)
+        self.starting: Dict[str, Dict[str, Any]] = {}
         self.draining: List[Any] = []          # scale-down victims finishing
         self.drain_deadline: Dict[str, float] = {}
         self.version = 0
@@ -306,6 +310,7 @@ class ServeController:
         st.draining = []
         st.drain_deadline.clear()
         st.ready.clear()
+        st.starting.clear()
         self._bump_version(st)
 
     def _start_replica(self, st: _DeploymentState):
@@ -326,10 +331,25 @@ class ServeController:
             # deployment's ray_actor_options runtime_env)
             opts["runtime_env"] = spec["runtime_env"]
         cls = ray_tpu.remote(**opts)(Replica)
-        return cls.remote(st.name, rid, spec["serialized_callable"],
-                          tuple(spec.get("init_args") or ()),
-                          dict(spec.get("init_kwargs") or {}),
-                          spec.get("user_config"))
+        # the controller's side of a replica's start, ONE span
+        # serve.replica_start: from this request to the health poll that
+        # first finds the replica ready (_check_replica_health closes it).
+        # The creation is submitted under it, so the replica's own
+        # start-up spans (util/startup_clocks.py) share its trace; its
+        # length less theirs is the lease and the poll's latency
+        span = {"start": time.time(), "replica_id": rid, "polls": 0,
+                "trace": (trace_context.new_trace_id(),
+                          trace_context.new_span_id())}
+        tok = trace_context.activate(*span["trace"])
+        try:
+            handle = cls.remote(st.name, rid, spec["serialized_callable"],
+                                tuple(spec.get("init_args") or ()),
+                                dict(spec.get("init_kwargs") or {}),
+                                spec.get("user_config"))
+        finally:
+            trace_context.deactivate(tok)
+        st.starting[handle.actor_id.hex()] = span
+        return handle
 
     def _reconcile_loop(self) -> None:
         while not self._stop.is_set():
@@ -371,6 +391,7 @@ class ServeController:
                         self._bump_version(st)
                         stale = []
                 for h in stale:
+                    st.starting.pop(h.actor_id.hex(), None)
                     try:
                         ray_tpu.kill(h)
                     except Exception:  # noqa: BLE001
@@ -437,14 +458,20 @@ class ServeController:
         ready_ids = {r.id() for r in ready}
         dead = []
         for h, ref in probes:
+            span = st.starting.get(h.actor_id.hex())
+            if span is not None:
+                span["polls"] += 1
             if ref.id() not in ready_ids:
                 continue
             try:
                 ray_tpu.get(ref)
                 st.ready.add(h.actor_id.hex())
+                if span is not None:
+                    self._replica_started(st, h.actor_id.hex())
             except ActorError:
                 dead.append(h)
                 st.ready.discard(h.actor_id.hex())
+                st.starting.pop(h.actor_id.hex(), None)
             except Exception:  # noqa: BLE001 — app error in user
                 pass                         # check_health: keep for now
         if dead:
@@ -466,6 +493,16 @@ class ServeController:
         elif ready_ids and st.consecutive_failures:
             st.consecutive_failures = 0
             st.backoff_until = 0.0
+
+    @staticmethod
+    def _replica_started(st: _DeploymentState, actor_hex: str) -> None:
+        """Record the serve.replica_start span of a replica a health
+        probe found ready for the first time."""
+        span = st.starting.pop(actor_hex)
+        startup_clocks.span(
+            "serve.replica_start", span["start"], time.time(),
+            ids=(*span["trace"], ""), replica_id=span["replica_id"],
+            deployment=st.name, polls=span["polls"])
 
     def _autoscale(self, st: _DeploymentState) -> None:
         cfg = st.spec.get("autoscaling_config")

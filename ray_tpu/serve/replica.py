@@ -17,6 +17,7 @@ import cloudpickle
 
 import ray_tpu
 from ray_tpu.serve.multiplex import MUX_KWARG, _set_request_model_id
+from ray_tpu.util import startup_clocks
 
 
 class Replica:
@@ -26,7 +27,10 @@ class Replica:
                  user_config: Optional[Dict[str, Any]] = None):
         self.deployment_name = deployment_name
         self.replica_id = replica_id
-        target = cloudpickle.loads(serialized_callable)
+        # unpickling the user class imports what it needs (ray_tpu.llm
+        # and jax, for a served model): its own start-up phase
+        with startup_clocks.phase("import"):
+            target = cloudpickle.loads(serialized_callable)
         if inspect.isclass(target):
             self.callable = target(*init_args, **init_kwargs)
         else:

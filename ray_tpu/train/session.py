@@ -36,6 +36,10 @@ class TrainContext:
         self.mesh_spec = mesh_spec
         self.reported: List[Dict[str, Any]] = []
         self.step = 0
+        # this worker's start-up clocks (util/startup_clocks.py):
+        # startup_ns_<phase> up to the loop's entry, written once there
+        # (train/worker_group.py)
+        self.startup: Dict[str, int] = {}
         self._last_report_t: Optional[float] = None
         # step-hiccup telemetry: steady-state step time (EMA over steps
         # with no save in flight) vs the worst step seen during a save

@@ -41,8 +41,17 @@ def make_train_step(loss_fn: Callable[[Any, Any], jax.Array],
     GradientTransformation. Both functions are jitted; sharding propagates
     from the committed input arrays (use shard_params first), so the same
     step runs 1-chip or any dp/fsdp/tp/pp/sp mesh unchanged.
+
+    Where a compile tracker runs (util/compile_tracker.py) both go through
+    its seam, as ``train.init`` and ``train.step``: every compile is
+    recorded with its signature and its seconds by phase, so a step that
+    compiles a second time is `train.step compiles: 2` with the diff that
+    caused it (`python -m ray_tpu compiles`), at two cache-size probes and
+    a clock pair a call.
     """
     import optax
+
+    from ray_tpu.util import compile_tracker
 
     @jax.jit
     def init_fn(params):
@@ -56,4 +65,8 @@ def make_train_step(loss_fn: Callable[[Any, Any], jax.Array],
         gnorm = optax.global_norm(grads)
         return params, opt_state, {"loss": loss, "grad_norm": gnorm}
 
-    return init_fn, step_fn
+    tracker = compile_tracker.ensure_started()
+    if tracker is None:
+        return init_fn, step_fn
+    return (tracker.wrap(init_fn, name="train.init"),
+            tracker.wrap(step_fn, name="train.step"))

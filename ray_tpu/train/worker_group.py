@@ -13,6 +13,7 @@ from typing import Any, Callable, List, Optional
 import ray_tpu
 from ray_tpu.train.checkpoint import Checkpoint, CheckpointManager
 from ray_tpu.train.session import TrainContext, _set_context
+from ray_tpu.util import startup_clocks
 
 
 class WorkerGroupError(RuntimeError):
@@ -37,12 +38,17 @@ class _TrainWorker:
             jax_dist: Optional[dict] = None,
             mesh_spec=None,
             restore_fallbacks: tuple = ()) -> List[dict]:
+        # this worker's start-up clocks, up to the loop's entry: the
+        # record the runtime opened when the worker became this actor (a
+        # fresh one in a process it did not lease)
+        startup_clocks.begin()
         if jax_dist is not None:
             # multi-host bootstrap BEFORE the user loop: after this,
             # jax.devices() is the global set (reference analog:
             # train/torch/config.py:66 process-group setup)
             from ray_tpu.train.backend import setup_jax_worker
-            setup_jax_worker({**jax_dist, "process_id": self.rank})
+            with startup_clocks.phase("mesh"):
+                setup_jax_worker({**jax_dist, "process_id": self.rank})
         cc = ckpt_cfg or {}
         # every rank gets a manager over the same root: saves are sharded
         # (each host uploads shard-<rank>.npz; rank 0 commits the manifest)
@@ -68,6 +74,8 @@ class _TrainWorker:
             # checkpoints never collide with (or sort below) earlier ones.
             ctx.step = CheckpointManager.step_of(restore_path)
         _set_context(ctx)
+        startup_clocks.finish(ctx.startup, startup_clocks.TRAIN_PHASES)
+        startup_clocks.log_summary(ctx.startup, startup_clocks.TRAIN_PHASES)
         try:
             fn(dict(ctx.train_loop_config)) if _wants_arg(fn) else fn()
             # drain the async writer before declaring the loop done —

@@ -16,7 +16,7 @@ storms as the dominant unexplained-latency failure on TPU pods).
 
 Two observation paths feed the ring:
 
-- a lazily registered ``jax.monitoring`` duration/event listener pair
+- a lazily registered ``jax.monitoring`` time-span/event listener pair
   picks up the ``/jax/core/compile/*`` pipeline phases (jaxpr trace,
   MLIR lowering, backend compile) and the persistent compilation
   cache's hits and misses that XLA itself reports;
@@ -54,6 +54,14 @@ _CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 #: in-flight accumulator key (not a phase: kept out of measured seconds)
 _HITS_KEY = "_persistent_cache_hits"
+#: in-flight accumulator key: [(start, seconds)] of the phase events seen so
+#: far and held by none yet, for _own_seconds (dropped before the record)
+_SPANS_KEY = "_phase_spans"
+#: a compile's seconds by pipeline phase, as a callable's stats and a ring
+#: record name them: (their key, the /jax/core/compile/ phase it sums);
+#: with a persistent-cache hit backend_s is the cache's retrieval
+_SPLIT = (("trace_s", "jaxpr_trace"), ("lower_s", "jaxpr_to_mlir_module"),
+          ("backend_s", "backend_compile"))
 
 # distinct callables tracked per process (LRU beyond this)
 _MAX_CALLABLES = 256
@@ -150,7 +158,7 @@ def fingerprint(name: str, signature: Sequence[str]) -> str:
 
 # thread-local in-flight attribution stack: CompileTracker.wrap pushes
 # an accumulator dict around the wrapped call; the anonymous
-# jax.monitoring duration listener adds compile-phase seconds to the
+# jax.monitoring time-span listener adds compile-phase seconds to the
 # top entry instead of recording an unattributed compile
 _tls = threading.local()
 
@@ -178,6 +186,7 @@ class CompileTracker:
         self._emitted_since = 0
         self._dropped_since = 0
         # name -> {"compiles","recompiles","wall_s","measured_s",
+        #          "trace_s","lower_s","backend_s","cache_hits","cold",
         #          "last_sig","last_diff"}; LRU-bounded
         self._per_callable: "collections.OrderedDict[str, dict]" = \
             collections.OrderedDict()
@@ -226,6 +235,7 @@ class CompileTracker:
             finally:
                 wall = time.perf_counter() - t0
                 stack.pop()
+                acc.pop(_SPANS_KEY, None)
                 compiled = False
                 if before is not None:
                     try:
@@ -253,6 +263,15 @@ class CompileTracker:
         except Exception:  # noqa: BLE001 — jit objects lack some attrs
             pass
         wrapped.__rtpu_compile_wrapped__ = fn  # type: ignore[attr-defined]
+        # the whole of a jit's own surface (lower, trace, eval_shape,
+        # clear_cache, _cache_size, ...) stays in reach through the
+        # wrapper: train/train_step.py hands wrapped jits to user loops
+        for attr in dir(fn):
+            if not attr.startswith("__") and not hasattr(wrapped, attr):
+                try:
+                    setattr(wrapped, attr, getattr(fn, attr))
+                except Exception:  # noqa: BLE001 — best effort
+                    pass
         return wrapped
 
     # ------------------------------------------------------ recording
@@ -275,6 +294,7 @@ class CompileTracker:
         # seconds are the cache retrieval
         cache_hit = bool(phases.pop(_HITS_KEY, 0))
         measured = round(sum(phases.values()), 6)
+        split = {key: phases.get(kind, 0.0) for key, kind in _SPLIT}
         if not backend:
             backend = os.environ.get("JAX_PLATFORMS", "") or ""
         from ray_tpu.util import trace_context
@@ -285,8 +305,9 @@ class CompileTracker:
                 if len(self._per_callable) >= _MAX_CALLABLES:
                     self._per_callable.popitem(last=False)
                 st = {"compiles": 0, "recompiles": 0, "wall_s": 0.0,
-                      "measured_s": 0.0, "last_sig": None,
-                      "last_diff": []}
+                      "measured_s": 0.0, "trace_s": 0.0, "lower_s": 0.0,
+                      "backend_s": 0.0, "cache_hits": 0, "cold": 0,
+                      "last_sig": None, "last_diff": []}
                 self._per_callable[name] = st
             else:
                 self._per_callable.move_to_end(name)
@@ -296,6 +317,9 @@ class CompileTracker:
             st["compiles"] += 1
             st["wall_s"] += wall_s
             st["measured_s"] += measured
+            for key, seconds in split.items():
+                st[key] += seconds
+            st["cache_hits" if cache_hit else "cold"] += 1
             st["last_sig"] = sig
             if recompile:
                 st["recompiles"] += 1
@@ -305,8 +329,8 @@ class CompileTracker:
                    "signature": sig, "kind": kind,
                    "duration_s": round(wall_s, 6),
                    "measured_s": measured,
-                   "backend_s": round(phases.get("backend_compile",
-                                                 0.0), 6),
+                   **{key: round(seconds, 6)
+                      for key, seconds in split.items()},
                    "backend": backend, "pid": self.pid,
                    "trace_id": ctx[0] if ctx else "",
                    "recompile": recompile, "diff": diff,
@@ -420,8 +444,11 @@ class CompileTracker:
     # ------------------------------------------------------- queries
 
     def callable_stats(self, name: str) -> Optional[dict]:
-        """Cumulative per-callable compile accounting (compiles,
-        recompiles, wall/measured seconds, last signature + diff)."""
+        """Cumulative per-callable compile accounting: compiles,
+        recompiles, wall/measured seconds, the measured seconds by phase
+        (trace_s, lower_s, backend_s), how many of the compiles were
+        persistent-cache hits (cache_hits) and how many were not (cold),
+        last signature + diff."""
         with self._lock:
             st = self._per_callable.get(name)
             return dict(st) if st is not None else None
@@ -490,20 +517,43 @@ _hook_lock = threading.Lock()
 _jax_hooked = False
 
 
-def _on_jax_duration(event: str, duration: float, **_kw) -> None:
+def _on_jax_span(event: str, start_time: float, end_time: float,
+                 **_kw) -> None:
+    """One ``/jax/core/compile/*`` phase, with the span jax itself timed
+    (jax._src.dispatch.log_elapsed_time reports every phase both as a
+    duration and as a span; the span is the one listened to)."""
     if not event.startswith(_COMPILE_EVENT_PREFIX):
         return
     kind = event[len(_COMPILE_EVENT_PREFIX):]
     if kind.endswith("_duration"):
         kind = kind[:-len("_duration")]
+    start, duration = float(start_time), float(end_time) - float(start_time)
     stack = getattr(_tls, "inflight", None)
     if stack:
         acc = stack[-1]
-        acc[kind] = acc.get(kind, 0.0) + float(duration)
+        acc[kind] = acc.get(kind, 0.0) + _own_seconds(acc, start, duration)
         return
     tracker = get_global()
     if tracker is not None:
-        tracker.note_monitor_duration(kind, float(duration))
+        tracker.note_monitor_duration(kind, duration)
+
+
+def _own_seconds(acc: dict, start: float, duration: float) -> float:
+    """What one phase event adds to a call's seconds of its kind. jax
+    times a jit met inside another's trace on its own AND as part of the
+    trace that met it, and a function traced while another is lowered
+    inside that lowering (the inner event fires first, the outer one
+    holds it), so a plain sum counts nested phases twice and can pass the
+    call's own wall time. An event that began before earlier ones did
+    holds them: they are taken off what it adds, and stay with their own
+    kind. Events of one thread nest properly or not at all, and fire as
+    they end."""
+    spans = acc.setdefault(_SPANS_KEY, [])
+    held = 0.0
+    while spans and spans[-1][0] >= start - 1e-6:
+        held += spans.pop()[1]
+    spans.append((start, duration))
+    return duration - held
 
 
 def _on_jax_event(event: str, **_kw) -> None:
@@ -532,8 +582,7 @@ def _maybe_hook_jax() -> bool:
             return True
         try:
             from jax import monitoring  # noqa: PLC0415 — jax is loaded
-            monitoring.register_event_duration_secs_listener(
-                _on_jax_duration)
+            monitoring.register_event_time_span_listener(_on_jax_span)
             monitoring.register_event_listener(_on_jax_event)
         except Exception:  # noqa: BLE001 — tracking never breaks jax
             return False
@@ -548,11 +597,7 @@ def _unhook_jax() -> None:
             return
         try:
             from jax import monitoring
-            unreg = getattr(
-                monitoring,
-                "_unregister_event_duration_listener_by_callback", None)
-            if unreg is not None:
-                unreg(_on_jax_duration)
+            monitoring.unregister_event_time_span_listener(_on_jax_span)
             unreg_ev = getattr(
                 monitoring, "_unregister_event_listener_by_callback",
                 None)
@@ -706,11 +751,17 @@ class CompileStore:
                         name = rec.get("name") or "<unattributed>"
                         a = agg.setdefault(name, {
                             "compiles": 0, "recompiles": 0,
-                            "seconds": 0.0, "procs": set(),
+                            "cache_hits": 0, "seconds": 0.0,
+                            "trace_lower_s": 0.0, "backend_s": 0.0,
+                            "procs": set(),
                             "last_sig": [], "last_diff": []})
                         a["compiles"] += 1
+                        a["cache_hits"] += bool(rec.get("cache_hit"))
                         a["seconds"] += rec.get("measured_s") or \
                             rec.get("duration_s") or 0.0
+                        a["trace_lower_s"] += (rec.get("trace_s") or 0.0) \
+                            + (rec.get("lower_s") or 0.0)
+                        a["backend_s"] += rec.get("backend_s") or 0.0
                         a["procs"].add(m.get("worker") or "")
                         if rec.get("recompile"):
                             a["recompiles"] += 1
@@ -732,7 +783,8 @@ class CompileStore:
         if by_callable:
             for a in agg.values():
                 a["procs"] = len(a["procs"])
-                a["seconds"] = round(a["seconds"], 6)
+                for key in ("seconds", "trace_lower_s", "backend_s"):
+                    a[key] = round(a[key], 6)
             result["by_callable"] = agg
         return result
 

@@ -199,6 +199,13 @@ def test_wrap_probeless_signature_novelty_path():
     assert st["last_sig"] == ["f32[5]", "flag=bool"]
 
 
+def _fire(event: str, duration: float) -> None:
+    """jax.monitoring reporting one phase as it ends: a span of
+    ``duration`` seconds that ends now, handed to the tracker's listener."""
+    now = time.time()
+    ct._on_jax_span(event, now - duration, now)
+
+
 def test_wrap_attributes_inflight_monitoring_durations():
     """The thread-local attribution stack: /jax/core/compile/* phase
     durations reported DURING a wrapped call are folded into that
@@ -209,12 +216,14 @@ def test_wrap_attributes_inflight_monitoring_durations():
     ct.stop_global()
 
     def fn(x):
-        # simulate jax.monitoring firing while the call is in flight
-        ct._on_jax_duration("/jax/core/compile/jaxpr_trace_duration",
-                            0.05)
-        ct._on_jax_duration(
-            "/jax/core/compile/backend_compile_duration", 0.125)
-        ct._on_jax_duration("/jax/unrelated/event", 99.0)  # ignored
+        # simulate jax.monitoring firing while the call is in flight, each
+        # phase as it ends (an event that began before the last one ended
+        # would HOLD it: test_callable_stats_keep_the_split_and_the_hits)
+        time.sleep(0.05)
+        _fire("/jax/core/compile/jaxpr_trace_duration", 0.05)
+        time.sleep(0.125)
+        _fire("/jax/core/compile/backend_compile_duration", 0.125)
+        _fire("/jax/unrelated/event", 99.0)  # ignored
         return x
 
     wrapped = tr.wrap(fn, name="t.attr", probe=lambda: 0)  # no growth
@@ -226,6 +235,123 @@ def test_wrap_attributes_inflight_monitoring_durations():
     assert rec["backend_s"] == 0.125
     assert rec["measured_s"] == pytest.approx(0.175)
     assert rec["duration_s"] > 0
+
+
+def test_callable_stats_keep_the_split_and_the_hits():
+    """What the listener attributes to a wrapped call is kept by phase
+    (trace_s, lower_s, backend_s), per callable and in the ring record,
+    with how many compiles were persistent-cache hits and how many cold;
+    a trace event that HOLDS earlier ones (jax times a nested jit's trace
+    on its own and inside the trace that met it) counts them once; and a
+    wrapped call's compile is this callable's alone: no nameless record,
+    no backend_compile count beside it."""
+    tr = ct.CompileTracker(role="w", storm_threshold=0)
+    ct.stop_global()
+    fire, pre = _fire, "/jax/core/compile/"
+
+    def fn(x, hit):
+        time.sleep(0.05)
+        fire(pre + "jaxpr_trace_duration", 0.01)       # a nested jit ...
+        time.sleep(0.02)
+        fire(pre + "jaxpr_trace_duration", 0.015)      # ... and a second
+        fire(pre + "jaxpr_trace_duration", 0.05)       # the trace holding both
+        time.sleep(0.02)
+        fire(pre + "jaxpr_to_mlir_module_duration", 0.02)
+        if hit:
+            ct._on_jax_event("/jax/compilation_cache/cache_hits")
+        time.sleep(0.03)
+        fire(pre + "backend_compile_duration", 0.03)
+        return x
+
+    # a probe that never grows: the listener's word that the call compiled
+    wrapped = tr.wrap(fn, name="t.split", probe=lambda: 0)
+    for hit in (False, True):
+        wrapped(np.zeros((2,), np.float32), hit)
+    st = tr.callable_stats("t.split")
+    assert st["compiles"] == 2 and st["recompiles"] == 0
+    assert (st["cache_hits"], st["cold"]) == (1, 1)
+    assert st["trace_s"] == pytest.approx(0.10)        # 2 x 0.05, not 0.15
+    assert st["lower_s"] == pytest.approx(0.04)
+    assert st["backend_s"] == pytest.approx(0.06)
+    assert st["measured_s"] == pytest.approx(0.20)
+    assert st["trace_s"] + st["lower_s"] + st["backend_s"] <= st["wall_s"]
+    e = tr.export()
+    cold, warm = e["records"]                          # and nothing nameless
+    assert (cold["cache_hit"], warm["cache_hit"]) == (False, True)
+    for rec in (cold, warm):
+        assert rec["name"] == "t.split"
+        assert (rec["trace_s"], rec["lower_s"], rec["backend_s"]) == \
+            (0.05, 0.02, 0.03)
+        assert rec["measured_s"] == pytest.approx(0.10)
+    assert e["counts"] == {"jit": 2}, e["counts"]
+    # the head's per-callable view adds them up
+    store = ct.CompileStore()
+    store.ingest("w", e, role="worker", worker="w1")
+    agg = store.dump(by_callable=True)["by_callable"]["t.split"]
+    assert agg["compiles"] == 2 and agg["cache_hits"] == 1
+    assert agg["trace_lower_s"] == pytest.approx(0.14)
+    assert agg["backend_s"] == pytest.approx(0.06)
+    from ray_tpu.scripts.cli import _fmt_compile_record
+    line = _fmt_compile_record(store.dump()["records"][1])
+    assert "= trace 50.0 lower 20.0 backend 30.0 hit" in line, line
+
+
+def test_train_step_builders_go_through_the_seam():
+    """make_train_step's two jits are train.init and train.step to the
+    tracker: a compile each, their seconds by phase, and a second
+    signature is a recompile with its diff; the jit's own surface (lower,
+    trace, eval_shape, clear_cache, _cache_size) stays in reach through
+    the wrapper. Nothing is ringed nameless for them."""
+    import jax.numpy as jnp
+    import optax
+    from ray_tpu.train.train_step import make_train_step
+    ct.stop_global()
+    try:
+        tr = ct.ensure_started(role="t")
+        init_fn, step_fn = make_train_step(
+            lambda p, b: jnp.sum((b @ p) ** 2), optax.sgd(0.1),
+            donate=False)
+        assert init_fn.__rtpu_compile_wrapped__ is not None
+        params = jnp.ones((4, 3))
+        opt_state = init_fn(params)
+        text = step_fn.lower(params, opt_state, jnp.ones((2, 4))).as_text()
+        assert "module @jit_step_fn" in text
+        batch = jnp.ones((2, 4))
+        assert step_fn.trace(params, opt_state, batch).jaxpr is not None
+        shapes = step_fn.eval_shape(params, opt_state, batch)
+        assert shapes[0].shape == (4, 3) and shapes[2]["loss"].shape == ()
+        assert callable(step_fn.clear_cache)
+        for rows in (2, 2, 8):
+            params, opt_state, m = step_fn(params, opt_state,
+                                           jnp.ones((rows, 4)))
+        assert np.isfinite(float(m["loss"]))
+        assert step_fn._cache_size() == 2
+        init, step = (tr.callable_stats(n)
+                      for n in ("train.init", "train.step"))
+        assert init["compiles"] == 1 and init["recompiles"] == 0
+        assert step["compiles"] == 2 and step["recompiles"] == 1
+        assert step["last_diff"] == ["arg[2]: f32[2,4] -> f32[8,4]"]
+        assert step["cold"] == 2 and step["cache_hits"] == 0
+        assert step["trace_s"] > 0 and step["lower_s"] > 0 \
+            and step["backend_s"] > 0
+        assert step["trace_s"] + step["lower_s"] + step["backend_s"] \
+            <= step["wall_s"]
+        named = [r for r in tr.export()["records"] if r["name"]]
+        assert [r["name"] for r in named] == ["train.init", "train.step",
+                                              "train.step"]
+        assert named[-1]["recompile"] and named[-1]["trace_s"] > 0
+        # with the tracker off the bare jits come back
+        from ray_tpu.core.config import GlobalConfig
+        ct.stop_global()
+        GlobalConfig.apply({"compile_tracker_enabled": False})
+        try:
+            bare_init, bare_step = make_train_step(
+                lambda p, b: jnp.sum(p), optax.sgd(0.1))
+            assert not hasattr(bare_step, "__rtpu_compile_wrapped__")
+        finally:
+            GlobalConfig.apply({"compile_tracker_enabled": True})
+    finally:
+        ct.stop_global()
 
 
 def test_program_loaded_from_persistent_cache_stays_truthful(tmp_path,

@@ -29,6 +29,7 @@ from _chunk_rows import (SHAPE_CASES, check_descriptor,  # noqa: E402
 from benchmark import reference_olmoe as ref  # noqa: E402
 from ray_tpu.llm import InferenceEngine  # noqa: E402
 from ray_tpu.llm.engine import CPU_KEY, WALL_KEYS  # noqa: E402
+from ray_tpu.util import startup_clocks  # noqa: E402
 from ray_tpu.models.llama import LlamaConfig, init_params  # noqa: E402
 from ray_tpu.ops import moe  # noqa: E402
 
@@ -310,7 +311,8 @@ def test_dense_configuration_is_untouched():
         "ragged_real_tokens", "ragged_slot_tokens", "cow_copies",
         "preemptions", "chunk_rows", "chunk_rows_joined",
         "ragged_small_dispatches", "h2d_arrays"} \
-        | set(WALL_KEYS + (CPU_KEY,))                # every model's clocks
+        | set(WALL_KEYS + (CPU_KEY,)) \
+        | set(startup_clocks.ENGINE_KEYS)            # every model's clocks
     # the step programs' outputs keep their shapes: [R] and [K, B]
     from ray_tpu.llm import model as M
     kv = eng.kv
