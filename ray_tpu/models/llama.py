@@ -779,6 +779,29 @@ def remat_policy_fn(name: str):
     raise ValueError(f"unknown remat_policy {name!r}")
 
 
+def remat_scan_body(body, cfg):
+    """body, the function a lax.scan runs once a layer, behind cfg's remat
+    boundary (shared with mixtral): the ONE place a layer's boundary is
+    built. prevent_cse=False, always, because the body is a scan's.
+    jax.checkpoint's default puts an optimization_barrier on everything
+    the rematted computation is handed, so that XLA cannot merge the
+    recomputation with the first computation; in a scan the two are in two
+    different while loops and cannot be merged anyway (jax's documentation
+    names this setting for a checkpointed scan body), and the fence only
+    costs copies: every operand of the backward turn has to exist as a
+    buffer of its own before the turn starts, so XLA copies a float32
+    slice of each master and the kept x out of their stacks before it
+    reads them (eight passes a layer-turn in the train cell, and layouts
+    the rope's fusions are held to: PERF.md, PR 53). Without it the
+    backward's casts read the stack in place, as the forward's do. A
+    caller that checkpoints something that is NOT a scan body has no place
+    here and keeps jax's default."""
+    if not cfg.remat:
+        return body
+    return jax.checkpoint(body, policy=remat_policy_fn(cfg.remat_policy),
+                          prevent_cse=False)
+
+
 def _layer(lp: Params, x, cfg: LlamaConfig, positions, attn_fn):
     """One transformer block; lp leaves have the layer axis removed."""
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -859,8 +882,7 @@ def _scan_layers(layers: Params, x, cfg: LlamaConfig, positions, attn_fn):
     def body(lp_turn, x):
         return layer(_in_its_turn(*lp_turn, cfg.dtype), x)
 
-    if cfg.remat:
-        body = jax.checkpoint(body, policy=remat_policy_fn(cfg.remat_policy))
+    body = remat_scan_body(body, cfg)
 
     def step(x, lp_turn):
         return body(lp_turn, x), None
@@ -1046,11 +1068,9 @@ def _loss_overlap(params: Params, tokens: jax.Array, cfg: LlamaConfig,
         positions = jnp.arange(L)
         embed = gather_params(params["embed"], specs["embed"], "fsdp")
         x = embed.astype(cd)[tokens]
-        body = functools.partial(_layer, cfg=cfg, positions=positions,
-                                 attn_fn=attn_fn)
-        if cfg.remat:
-            body = jax.checkpoint(body,
-                                  policy=remat_policy_fn(cfg.remat_policy))
+        body = remat_scan_body(
+            functools.partial(_layer, cfg=cfg, positions=positions,
+                              attn_fn=attn_fn), cfg)
         x = overlap_scan(params["layers"], lspecs, x, body, cfg.n_layers,
                          axis_name="fsdp")
         x = _rmsnorm(x, params["final_norm"], cfg.norm_eps)

@@ -26,7 +26,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.models.llama import (_full_attention, _nll_mean, _rmsnorm,
-                                  _rope, remat_policy_fn)
+                                  _rope, remat_scan_body)
 from ray_tpu.parallel.mesh import shard_map_compat
 from ray_tpu.parallel.moe import _routing, moe_ffn, moe_ffn_sharded
 
@@ -180,10 +180,9 @@ def forward(params: Params, tokens: jax.Array, cfg: MixtralConfig,
     x = params["embed"].astype(cd)[tokens]
     positions = jnp.arange(L)
 
-    body = functools.partial(_layer, cfg=cfg, positions=positions,
-                             mesh=mesh)
-    if cfg.remat:
-        body = jax.checkpoint(body, policy=remat_policy_fn(cfg.remat_policy))
+    body = remat_scan_body(
+        functools.partial(_layer, cfg=cfg, positions=positions, mesh=mesh),
+        cfg)
 
     def step(x, lp):
         x, aux = body(lp, x)
@@ -222,11 +221,9 @@ def _loss_overlap(params: Params, tokens: jax.Array, cfg: MixtralConfig,
         positions = jnp.arange(L)
         embed = gather_params(params["embed"], specs["embed"], "fsdp")
         x = embed.astype(cd)[tokens]
-        body = functools.partial(_layer, cfg=cfg, positions=positions,
-                                 mesh=None)
-        if cfg.remat:
-            body = jax.checkpoint(body,
-                                  policy=remat_policy_fn(cfg.remat_policy))
+        body = remat_scan_body(
+            functools.partial(_layer, cfg=cfg, positions=positions,
+                              mesh=None), cfg)
         x, aux = overlap_scan(params["layers"], lspecs, x, body,
                               cfg.n_layers, axis_name="fsdp", has_aux=True)
         x = _rmsnorm(x, params["final_norm"], cfg.norm_eps)

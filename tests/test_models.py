@@ -10,7 +10,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ray_tpu.models import llama
+from ray_tpu.models import llama, mixtral
 from ray_tpu.models.mlp import MLPConfig, mlp_init, mlp_apply, mlp_loss
 from ray_tpu.parallel import MeshSpec, build_mesh
 
@@ -356,6 +356,104 @@ class TestRematPolicies:
                 jnp.float32).sum())(lp["m"])
         assert g.dtype == jnp.float32
         np.testing.assert_array_equal(np.asarray(g), np.ones(4, np.float32))
+
+
+def _llama_scan_case():
+    cfg = llama.LlamaConfig.tiny(n_layers=3, dtype=jnp.float32)
+    assert cfg.remat and cfg.remat_policy == "full"
+    assert cfg.n_kv_heads < cfg.n_heads               # GQA
+
+    def plain(params, tokens):
+        # no checkpoint, no held copy: _layer over the masters
+        x, _ = jax.lax.scan(
+            lambda x, lp: (llama._layer(
+                lp, x, cfg, jnp.arange(tokens.shape[1]),
+                llama._full_attention), None),
+            params["embed"][tokens], params["layers"])
+        x = llama._rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        logits = jnp.einsum("bld,vd->blv", x, params["embed"],
+                            preferred_element_type=jnp.float32)
+        return llama._nll_mean(logits, tokens)
+
+    # _held's barrier, once in the forward turn and once in the recomputed
+    return (functools.partial(llama.loss_fn, cfg=cfg), plain,
+            llama.init_params(cfg, jax.random.PRNGKey(0)),
+            make_inputs(cfg, B=2, L=16), 2)
+
+
+def _mixtral_scan_case():
+    cfg = mixtral.MixtralConfig.tiny(n_layers=3, dtype=jnp.float32)
+    assert cfg.remat
+    return (functools.partial(mixtral.loss_fn, cfg=cfg),
+            functools.partial(mixtral.loss_fn,
+                              cfg=dataclasses.replace(cfg, remat=False)),
+            mixtral.init_params(cfg, jax.random.PRNGKey(0)),
+            make_inputs(cfg, B=2, L=16), 0)
+
+
+def _overlap_case(mod, config):
+    """mod's fsdp_overlap loss (the prefetch-scheduled scan) on a mesh
+    that shards fsdp."""
+    mesh = build_mesh(MeshSpec(dp=2, fsdp=4))
+    cfg = config.tiny(n_layers=3, dtype=jnp.float32, fsdp_overlap=True)
+    # mixtral's specs name ep; this mesh does not
+    shardings = jax.tree.map(
+        lambda s: NamedSharding(mesh, P(*[
+            ax if ax in mesh.shape else None for ax in s])),
+        mod.param_specs(cfg), is_leaf=lambda x: isinstance(x, P))
+    return (functools.partial(mod.loss_fn, cfg=cfg, mesh=mesh),
+            functools.partial(mod.loss_fn, mesh=mesh,
+                              cfg=dataclasses.replace(cfg, remat=False)),
+            jax.device_put(mod.init_params(cfg, jax.random.PRNGKey(0)),
+                           shardings),
+            jax.device_put(make_inputs(cfg, B=8, L=16),
+                           NamedSharding(mesh, P(("dp", "fsdp"), None))), 0)
+
+
+#: every caller of llama.remat_scan_body that runs on the CPU -> (loss
+#: behind cfg's remat boundary, the same loss with no boundary, params,
+#: tokens, optimization_barriers the program itself places)
+_BOUNDARY_CASES = {
+    "llama_scan": _llama_scan_case,
+    "llama_overlap": functools.partial(_overlap_case, llama,
+                                       llama.LlamaConfig),
+    "mixtral_scan": _mixtral_scan_case,
+    "mixtral_overlap": functools.partial(_overlap_case, mixtral,
+                                         mixtral.MixtralConfig),
+}
+
+
+@pytest.mark.parametrize("case", _BOUNDARY_CASES.values(),
+                         ids=_BOUNDARY_CASES.keys())
+def test_a_scan_bodys_remat_boundary_fences_nothing(case):
+    """llama.remat_scan_body builds every layer scan's remat boundary with
+    prevent_cse=False: the lowered gradient holds the optimization_barriers
+    the program places itself (_held's) and none from jax.checkpoint, whose
+    default fences every operand of the rematted turn (one more barrier,
+    and on the chip a copy of each operand out of its stack: PERF.md,
+    PR 53). The boundary changes no value: float32 on the CPU, loss and
+    every gradient are those of the same scan with no boundary at all."""
+    with_boundary, without, params, tokens, own = case()
+    vag = jax.jit(jax.value_and_grad(with_boundary))
+    traced = vag.trace(params, tokens)
+
+    def remats(jaxpr):
+        for eqn in jaxpr.eqns:
+            if "prevent_cse" in eqn.params:
+                yield eqn.params["prevent_cse"]
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from remats(sub)
+
+    found = list(remats(traced.jaxpr.jaxpr))
+    assert found and not any(found), found
+    assert traced.lower().as_text().count("optimization_barrier") == own
+    loss, grads = vag(params, tokens)
+    ref_loss, ref = jax.jit(jax.value_and_grad(without))(params, tokens)
+    assert float(loss) == pytest.approx(float(ref_loss), abs=1e-6)
+    for got, want in zip(jax.tree.leaves(grads), jax.tree.leaves(ref)):
+        assert got.dtype == jnp.float32
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-6)
 
 
 class TestFsdpOverlap:

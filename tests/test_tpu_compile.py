@@ -151,20 +151,14 @@ def test_flash_compiles_at_the_train_cells_shape(chip):
                                     blocks=(None, None)) == 3
 
 
-def test_train_layer_keeps_the_forward_kernels_outputs(chip, monkeypatch):
+@pytest.fixture(scope="module")
+def train_layers(chip):
     """Three layers of mistral7b-train-1chip under its remat ("full"), value
-    and gradient, through the one-device mesh the trainer hands loss_fn:
-    the compiled program holds THREE flash kernels, not four (the forward
-    is not run again under the remat boundary), and each is still the
-    instruction benchmark/metrics/flash_attn_roofline.json looks for (the
-    region a kernel is traced in decides its name's prefix). No
-    compute-dtype copy of a whole stacked weight is made (_in_its_turn;
-    without it XLA holds one of each through both loops)."""
-    import json
+    and gradient through _scan_layers, on the one-device mesh the trainer
+    hands loss_fn, compiled once for the tests below: (text, memory)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
     from ray_tpu.models import llama
     from ray_tpu.parallel import MeshSpec, build_mesh
-    monkeypatch.setattr(fa, "kernels_supported", lambda: True)
     cfg = llama.LlamaConfig(
         vocab_size=32768, dim=4096, n_layers=3, n_heads=32, n_kv_heads=8,
         ffn_dim=14336, rope_theta=1e6, attention="flash")
@@ -182,10 +176,41 @@ def test_train_layer_keeps_the_forward_kernels_outputs(chip, monkeypatch):
             layers, x, cfg, jnp.arange(4096),
             llama._make_attn_fn(cfg, mesh)).astype(jnp.float32).sum()
 
-    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
-        layers, x).compile().as_text()
-    calls = [ln.strip().removeprefix("ROOT ").lstrip("%")
-             for ln in text.splitlines() if "tpu_custom_call" in ln]
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(fa, "kernels_supported", lambda: True)
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            layers, x).compile()
+    return compiled.as_text(), compiled.memory_analysis()
+
+
+def _instructions(text):
+    """(name, result type, line) of every instruction of a compiled
+    text that stands in a loop's body or the entry: what is written to a
+    buffer of its own. An instruction inside a fused computation is a value
+    in flight and is left out."""
+    fused, out = False, []
+    for ln in text.splitlines():
+        if re.match(r"^(ENTRY )?%?[\w.\-]+ \(.*\) -> .* \{$", ln):
+            fused = ln.lstrip("%").startswith("fused_computation")
+            continue
+        m = re.match(r"^\s*(?:ROOT )?%?([\w.\-]+) = (.+?) [a-z][\w\-]*\(", ln)
+        if m and not fused:
+            out.append((*m.groups(), ln.strip().removeprefix("ROOT ")
+                        .lstrip("%")))
+    return out
+
+
+def test_train_layer_keeps_the_forward_kernels_outputs(train_layers):
+    """The compiled program holds THREE flash kernels, not four (the forward
+    is not run again under the remat boundary), and each is still the
+    instruction benchmark/metrics/flash_attn_roofline.json looks for (the
+    region a kernel is traced in decides its name's prefix). No
+    compute-dtype copy of a whole stacked weight is made (_in_its_turn;
+    without it XLA holds one of each through both loops)."""
+    import json
+    text, _ = train_layers
+    calls = [ln for _, _, ln in _instructions(text)
+             if "tpu_custom_call" in ln]
     assert len(calls) == 3, [c[:60] for c in calls]
     with open(os.path.join(os.path.dirname(__file__), os.pardir, "benchmark",
                            "metrics", "flash_attn_roofline.json")) as f:
@@ -196,6 +221,33 @@ def test_train_layer_keeps_the_forward_kernels_outputs(chip, monkeypatch):
         assert len(found) == 1, (kind, found)
     assert not any(c.startswith("rematted_computation") for c in calls)
     assert "f32[3,4096,14336]" in text and "bf16[3,4096,14336]" not in text
+
+
+def test_train_layers_backward_reads_the_stacks_in_place(train_layers):
+    """The remat boundary of a scan's body fences nothing it is handed
+    (llama.remat_scan_body, prevent_cse=False): the backward turn's casts
+    read the masters' float32 stacks in place, as the forward's do, and no
+    float32 slice of a master is written out first. With jax.checkpoint's
+    default every operand of the rematted turn is a buffer of its own:
+    seven dynamic-slice_bitcast_fusion instructions with these result
+    types, and two more for the kept x and the kept o (PERF.md, PR 53; the
+    kept o is still sliced out, under another name: a kernel takes whole
+    buffers). The products, the kernels and what is recomputed stay what
+    they were, and less is held. Fails the day jax's default, or XLA's fusion of a slice
+    into the cast that reads it, changes."""
+    text, memory = train_layers
+    inst = _instructions(text)
+    masters = ("f32[4096,14336]", "f32[14336,4096]", "f32[4096,4096]",
+               "f32[4096,1024]")
+    assert [(n, r) for n, r, _ in inst if r.startswith(masters)] == []
+    # the one slice left is the kept log-sum-exp's (the dq kernel's operand)
+    assert [r.split("{")[0] for n, r, _ in inst
+            if n.startswith("dynamic-slice_bitcast_fusion")] \
+        == ["f32[64,8,4096]"]
+    # 9 forward + 9 recomputed products, 9 gradients; the parent's 2.536 GB
+    assert text.count(" convolution(") == 27
+    assert not any(".remat" in n for n, _, _ in inst)
+    assert memory.temp_size_in_bytes < 2.45e9, memory.temp_size_in_bytes
 
 
 @pytest.mark.parametrize("Lq,Lk,causal", [
