@@ -1,7 +1,12 @@
 """What can be checked without a chip (run: python3 benchmark/selftest.py).
 
   files      BENCHMARK.json against the files it names: every metric has a
-             metric file and a reader, every cell a configuration and a mix
+             metric file and a reader, every cell a configuration and a mix;
+             per_layer holds 128 entries at most and ONE entry a reading
+             (reader, arguments, `moves`), the cells in its list
+  fold       every (cell, reading) of the list as PR 53 left it
+             (fixtures/per_layer_pr53.json) is reported by exactly one
+             entry of today's list: a fold loses and doubles nothing
   traffic    every seed offers a cell the same number of requests, the same
              multiset of prompt and output lengths and (open loop) the same
              due times; the order and the token ids are the seed's
@@ -53,6 +58,21 @@ TINY_TRAFFIC = {
 }
 
 
+#: entries that repeat a bare-named entry's reader and arguments under a
+#: cell's suffix, kept only because a test under tests/ names them and a
+#: `benchmark` PR may not touch tests/ (PERF.md section 7, "Left by PR 54").
+#: The list only shrinks: once no test names one, a `benchmark` PR moves its
+#: cells into the bare entry's list and takes it out of here.
+HELD_BY_TESTS = frozenset((
+    "decode_step_ms.reason", "decode_step_ms.mimo",
+    "mixed_step_ms.reason", "mixed_step_ms.granite", "mixed_step_ms.mimo",
+    "mixed_step_time_pct.reason", "mixed_step_time_pct.granite",
+    "mixed_step_time_pct.mimo", "device_idle_pct.mimo",
+    "engine_host_gap_ms.reason", "engine_host_gap_ms.mimo",
+    "idle_prep_pct.reason", "idle_prep_pct.mimo",
+    "paged_attn_time_pct.mimo", "moe_ffn_time_pct.mimo"))
+
+
 def _bench():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         return json.load(f)
@@ -61,6 +81,17 @@ def _bench():
 def _load(kind, name):
     from benchmark import run
     return run.load(kind, name)
+
+
+def _reading(reader: str, args: dict, moves: str) -> tuple:
+    """What a per_layer entry reads: a reader, its arguments, and the
+    end-to-end metric the number moves."""
+    return (reader, json.dumps(args, sort_keys=True), moves)
+
+
+def _reading_of(m: dict) -> tuple:
+    spec = _load("metrics", m["name"])
+    return _reading(spec["reader"], spec.get("args", {}), m["moves"])
 
 
 def check_files() -> None:
@@ -89,8 +120,39 @@ def check_files() -> None:
         assert any(c["name"] == w["config"] for c in b["configs"])
     n4 = sum(w["chips"] == 4 for w in b["workloads"])
     assert n4 <= max(1, len(b["workloads"]) // 4)
+    assert len(b["per_layer"]) <= 128, len(b["per_layer"])
+    # one entry a reading: a cell that reads what an accepted cell reads
+    # joins that entry's list, and spends an entry only on what is its own
+    one, held = {}, []
+    for m in b["per_layer"]:
+        reading = _reading_of(m)
+        if m["name"] in HELD_BY_TESTS:
+            held.append((m, reading))
+            continue
+        assert reading not in one, (m["name"], one[reading]["name"])
+        one[reading] = m
+    assert {m["name"] for m, _ in held} == HELD_BY_TESTS
+    for m, reading in held:     # a repeat of a bare entry, no cell in both
+        bare = one[reading]
+        assert bare["name"] == m["name"].rsplit(".", 1)[0], m["name"]
+        assert not set(m["workloads"]) & set(bare["workloads"]), m["name"]
     print(f"files: {len(b['workloads'])} cells, {len(b['end_to_end'])} "
           f"end-to-end and {len(b['per_layer'])} per-layer metrics resolve")
+
+
+def check_fold() -> None:
+    with open(os.path.join(HERE, "fixtures", "per_layer_pr53.json")) as f:
+        was = json.load(f)["per_layer"]
+    now = [(_reading_of(m), m["workloads"]) for m in _bench()["per_layer"]]
+    pairs = 0
+    for old in was:
+        reading = _reading(old["reader"], old["args"], old["moves"])
+        for cell in old["workloads"]:
+            n = sum(r == reading and cell in cells for r, cells in now)
+            assert n == 1, (old["name"], cell, n)
+            pairs += 1
+    print(f"fold: {pairs} (cell, reading) pairs of PR 53's {len(was)} "
+          f"entries are each reported by one of today's {len(now)}")
 
 
 def check_traffic() -> None:
@@ -192,7 +254,28 @@ def check_cost() -> None:
         pass
     else:
         raise AssertionError("an unknown device_kind must be an error")
-    print("cost: paged and flash work, roofline share and the peaks table")
+    # the routing counters' two ratios over a block's own dims
+    from benchmark.readers import trinity_counters
+    with open(os.path.join(HERE, "configs",
+                           "trinity-mini-serve-1chip.json")) as f:
+        config = json.load(f)       # 128 experts x 4 expert layers, all held
+    keys = ("moe_pairs", "moe_hits", "moe_hot", "decode_steps")
+    data = {"config": config, "stats_open": dict.fromkeys(keys, 0),
+            "stats_close": {"moe_pairs": 40960, "moe_hits": 4608,
+                            "moe_hot": 1760, "decode_steps": 10}}
+    assert trinity_counters.read(data, {"quantity": "hit_pct"}) \
+        == 100 * 4608 / (10 * 4 * 128) == 90.0
+    assert trinity_counters.read(data, {"quantity": "load_skew"}) \
+        == 1760 * 128 / 40960 == 5.5
+    for quantity in ("hit_pct", "load_skew"):
+        assert trinity_counters.read({"config": config},
+                                     {"quantity": quantity}) is None
+        assert trinity_counters.read(
+            {"config": config, "stats_open": {"decode_steps": 1},
+             "stats_close": {"decode_steps": 5}},
+            {"quantity": quantity}) is None
+    print("cost: paged and flash work, roofline share, the peaks table and "
+          "the routing counters' ratios")
 
 
 def rehearse(workload: str, trace: int = 1, seconds: float = 3.0) -> dict:
@@ -242,6 +325,7 @@ def main() -> int:
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     if not args.only:
         check_files()
+        check_fold()
         check_traffic()
         check_cost()
         check_reduce()
