@@ -32,8 +32,12 @@ recurrent layers' state (``STATE_LEAVES``), one fixed-size entry a batch
 slot, owned by whoever holds the slot and never allocated or freed: a
 conv layer's last inputs (kilobytes a slot), a state-space layer's matrix
 state and its own conv's last inputs, a retention layer's matrix state and
-normaliser (megabytes a slot and layer: there ``max_batch`` is a memory
-decision as ``total_pages`` is). A configuration with NO attention layer
+normaliser, a gated-delta-rule layer's FLOAT32 matrix state and its conv's
+last inputs (megabytes a slot and layer: there ``max_batch`` is a memory
+decision as ``total_pages`` is). Both kinds live in one pool where a
+configuration has both, a LATENT page leaf beside slot-state leaves too
+(GigaChat3.5: one {"k"} leaf, no "v", and the delta layers' two leaves).
+A configuration with NO attention layer
 has page leaves with no layer in them: the host's page accounting runs as
 ever over pages that hold nothing and cost nothing (a deployment sizes
 ``total_pages`` so that they never bind), and what admits a sequence is a
@@ -67,8 +71,8 @@ from typing import Dict, List, Optional, Tuple
 
 import jax.numpy as jnp
 
-from ray_tpu.models.llama import (ATTENTION, CONV, MAMBA, RETENTION, WINDOW,
-                                  LlamaConfig)
+from ray_tpu.models.llama import (ATTENTION, CONV, DELTA, MAMBA, RETENTION,
+                                  WINDOW, LlamaConfig)
 from ray_tpu.ops.retention import expanded_dim
 
 logger = logging.getLogger(__name__)
@@ -275,9 +279,11 @@ class PrefixCache:
 
 #: the pool's leaves that are not pages: a conv layer's last inputs; a
 #: state-space layer's matrix state and the last inputs of its conv; a
-#: retention layer's matrix state and its normaliser
+#: retention layer's matrix state and its normaliser; a gated-delta-rule
+#: layer's matrix state and the last inputs of its conv
 STATE_LEAF, SSM_LEAF, SSM_CONV_LEAF = "conv", "ssm", "ssm_conv"
 RET_LEAF, RET_NORM_LEAF = "retention", "retention_norm"
+DELTA_LEAF, DELTA_CONV_LEAF = "delta", "delta_conv"
 
 #: what a layer of each kind keeps per BATCH SLOT: kind -> {leaf: cfg ->
 #: (its shape after [layers of the kind, max_batch + 1], its dtype)}. The
@@ -313,9 +319,28 @@ SLOT_STATE = {
         # its normaliser as a symmetric matrix
         RET_NORM_LEAF: lambda c: (
             (c.n_kv_heads, c.head_dim, c.head_dim), jnp.float32)},
+    DELTA: {
+        # a VALUE head's matrix state, the key on the sublanes and the
+        # value on the lanes, FLOAT32 whatever the model's dtype: the
+        # family carries its recurrent state in float32 (4 MB a slot and
+        # layer at 64 heads of 128 x 128), and the update kernel moves a
+        # slot's heads as they lie (ops/delta.py)
+        DELTA_LEAF: lambda c: (
+            (c.delta_value_heads, c.delta_key_dim, c.delta_value_dim),
+            jnp.float32),
+        # the last inputs of its conv (q, k and v together, before the
+        # SiLU), oldest first
+        DELTA_CONV_LEAF: lambda c: (
+            (c.delta_conv - 1, delta_channels(c)), c.dtype)},
 }
 STATE_LEAVES = tuple(leaf for leaves in SLOT_STATE.values()
                      for leaf in leaves)
+
+
+def delta_channels(cfg: LlamaConfig) -> int:
+    """Channels of a gated-delta-rule layer's conv: q, then k, then v."""
+    return 2 * cfg.delta_key_heads * cfg.delta_key_dim \
+        + cfg.delta_value_heads * cfg.delta_value_dim
 
 
 #: the window layers' page leaves: the second page group's

@@ -71,10 +71,19 @@ kind keeps per batch slot is declared beside it in llm/cache.py
     (``post_norms``: ``_residual``), and the rotary embedding on the window
     layers only (``full_rope=False``), beside the per-head q/k norm.
 
-The two recurrences take the rows of a ragged batch by ONE protocol
+  - gated-delta-rule linear attention (``_delta``; GigaChat3.5, whose
+    every fourth layer is LATENT attention with a low-rank query, YaRN
+    frequencies, the gated block's output gate and second norm, every norm
+    a sigmoid-gated one (``_norm``) and every SwiGLU clamped): no pages;
+    per slot and VALUE head a FLOAT32 matrix state [dk, dv] that a token
+    decays and then corrects along its key (it reads what it is about to
+    write), beside the last inputs of its conv over q, k and v. The first
+    block whose pool holds a latent page leaf AND slot-state leaves.
+
+The three recurrences take the rows of a ragged batch by ONE protocol
 (``_slot_rows``): the leading one-token rows update their slots in place
-(a Pallas kernel each, ops/ssm.py, ops/retention.py), chunk rows start
-from their slot's state and leave their last state there.
+(a Pallas kernel each, ops/ssm.py, ops/retention.py, ops/delta.py), chunk
+rows start from their slot's state and leave their last state there.
 
 ONE step program for everything (`_ragged_step_body`): the engine packs
 decode tokens and prefill-chunk tokens into a single RAGGED batch
@@ -135,14 +144,15 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu.llm import tp as TP
-from ray_tpu.llm.cache import (RET_LEAF, RET_NORM_LEAF, SCRATCH_PAGE,
-                               SSM_CONV_LEAF, SSM_LEAF, STATE_LEAF,
-                               STATE_LEAVES, WINDOW_LEAVES, keeps_slot_state,
+from ray_tpu.llm.cache import (DELTA_CONV_LEAF, DELTA_LEAF, RET_LEAF,
+                               RET_NORM_LEAF, SCRATCH_PAGE, SSM_CONV_LEAF,
+                               SSM_LEAF, STATE_LEAF, STATE_LEAVES,
+                               WINDOW_LEAVES, keeps_slot_state,
                                make_kv_cache, window_table_width)
-from ray_tpu.models.llama import (ATTENTION, CONV, MAMBA, RETENTION, WINDOW,
-                                  LlamaConfig, Params, _rmsnorm, _rope,
-                                  _rope_pairs, init_params)
-from ray_tpu.ops import moe, retention, ssm
+from ray_tpu.models.llama import (ATTENTION, CONV, DELTA, MAMBA, RETENTION,
+                                  WINDOW, LlamaConfig, Params, _rmsnorm,
+                                  _rope, _rope_pairs, init_params)
+from ray_tpu.ops import delta, moe, retention, ssm
 from ray_tpu.ops.paged_attention import (kernels_supported,
                                          ragged_paged_attention,
                                          write_ragged_kv)
@@ -151,6 +161,7 @@ from ray_tpu.util import compile_tracker
 
 # {"k", "v"[, "k_scale", "v_scale"][, "k_win", "v_win"][, "conv"][, "ssm",
 # "ssm_conv"][, "retention", "retention_norm"]}, or a latent pool's {"k"}
+# [, "delta", "delta_conv"]
 KVCache = dict  # (llm/cache.py)
 
 
@@ -158,12 +169,21 @@ def _maybe_psum(x, tp_axis):
     return lax.psum(x, tp_axis) if tp_axis else x
 
 
+def _norm(x, w, cfg: LlamaConfig):
+    """The block's RMSNorm: x / rms(x) * w, or with ``norm_gate`` (a
+    zero-centred gated norm) * norm_gate * sigmoid(w), which is 1 at w = 0
+    where the gate is 2."""
+    if cfg.norm_gate:
+        w = cfg.norm_gate * jax.nn.sigmoid(w.astype(jnp.float32))
+    return _rmsnorm(x, w, cfg.norm_eps)
+
+
 def _residual(x, y, cfg: LlamaConfig, post_norm=None):
     """x + residual_scale * y: a branch of a layer joins the stream,
     through a norm of its own where the block has one (``post_norm``: the
     branch's ``*_post_norm`` leaf, or None)."""
     if post_norm is not None:
-        y = _rmsnorm(y, post_norm, cfg.norm_eps)
+        y = _norm(y, post_norm, cfg)
     if cfg.residual_scale != 1.0:
         y = y * jnp.asarray(cfg.residual_scale, y.dtype)
     return x + y
@@ -194,9 +214,9 @@ def _project_qkv(lp, h, cfg: LlamaConfig):
 
 def _mlp(lp, x, cfg: LlamaConfig, tp_axis=None):
     cd = cfg.dtype
-    h = _rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
-    gate = jax.nn.silu(h @ lp["w_gate"].astype(cd))
-    up = h @ lp["w_up"].astype(cd)
+    h = _norm(x, lp["mlp_norm"], cfg)
+    gate = moe.gate_half(h @ lp["w_gate"].astype(cd), cfg.ffn_clamp)
+    up = moe.up_half(h @ lp["w_up"].astype(cd), cfg.ffn_clamp)
     # w_down is row-parallel under tp: each shard holds ffn/tp rows, the
     # partial products sum across the axis (Megatron second collective)
     return _residual(
@@ -209,13 +229,14 @@ def _moe_mlp(lp, experts, layer, x, valid, cfg: LlamaConfig, impl):
     stacked [L, E, ...] weights, whole, and ``layer`` the index into
     them; ``impl`` is the step's kernel-or-reference choice, the paged
     attention's. Returns (x', the layer's routing counters)."""
-    h = _rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
+    h = _norm(x, lp["mlp_norm"], cfg)
     y, counters = moe.moe_ffn(
         h[0], valid, lp["router"], experts["w_gate"], experts["w_up"],
         experts["w_down"], cfg.experts_per_token, cfg.norm_topk_prob,
         layer=layer, impl=impl, score=cfg.router_score,
         bias=lp.get("router_bias"), eps=cfg.router_eps,
         scale=cfg.router_scale,
+        clamp=cfg.ffn_clamp,
         **(dict(held=cfg.experts_held) if cfg.experts_held else {}))
     y = y[None]
     if cfg.shared_ffn_dim:
@@ -223,9 +244,10 @@ def _moe_mlp(lp, experts, layer, x, valid, cfg: LlamaConfig, impl):
         # (a padding token's is garbage like the rest of its row)
         cd = cfg.dtype
         with jax.named_scope(SCOPE_SHARED):
-            gate = jax.nn.silu(h @ lp["w_shared_gate"].astype(cd))
-            y = y + (gate * (h @ lp["w_shared_up"].astype(cd))) \
-                @ lp["w_shared_down"].astype(cd)
+            gate = moe.gate_half(h @ lp["w_shared_gate"].astype(cd),
+                                 cfg.ffn_clamp)
+            up = moe.up_half(h @ lp["w_shared_up"].astype(cd), cfg.ffn_clamp)
+            y = y + (gate * up) @ lp["w_shared_down"].astype(cd)
     return _residual(x, y, cfg, lp["mlp_post_norm"] if cfg.post_norms
                      else None), counters
 
@@ -280,6 +302,11 @@ SCOPE_WINDOW, SCOPE_WINDOW_PROJ, SCOPE_FULL_PROJ = \
 #: over its logits (no other block's head is named: its ops keep the
 #: names they had)
 SCOPE_GATE, SCOPE_HEAD = "attn_gate", "lm_head"
+#: ... the gated-delta-rule operator: everything around the recurrence (the
+#: projections, the conv, the L2 norm, the gates, the output norm, w_out);
+#: the one-token update (the Pallas kernel); the chunk rows' chunk form
+SCOPE_DELTA_PROJ, SCOPE_DELTA_UPDATE, SCOPE_DELTA_CHUNK = \
+    "delta_proj", "delta_update", "delta_chunk"
 
 
 class _Rows(NamedTuple):
@@ -604,6 +631,70 @@ def _retention(lp, l, x, kv, rows: _Rows, cfg: LlamaConfig, impl):
     return x, {**kv, RET_LEAF: state, RET_NORM_LEAF: norm}
 
 
+def _delta(lp, l, x, kv, rows: _Rows, cfg: LlamaConfig, impl):
+    """Gated-delta-rule linear attention of one layer, on entry ``l`` of
+    both of its state leaves (the layer's ordinal among the delta layers);
+    Hk key heads and Hv value heads, key head j serving value heads
+    j Hv/Hk .. (j+1) Hv/Hk - 1; conv over ch = 2 Hk dk + Hv dv channels:
+
+        [u (ch), z (Hv dv), b (Hv), a (Hv)] = h [W_qkv, W_z, W_ba]
+        [q, k, v] = split(silu(sum_j w[j] * u[t - (K-1) + j]))  depthwise
+        q_j <- q_j / |q_j| dk^-1/2;  k_j <- k_j / |k_j|
+        beta = sigmoid(b);  g = -exp(A_log) softplus(a + dt_bias)  (float32)
+        S_h <- e^g S_h;  S_h <- S_h + k (x) beta (v_h - S_h^T k);  o_h = S_h^T q
+        y_h = rms(o_h; eps) * s sigmoid(w~) * s sigmoid(z_h)   s = gate scale
+        x' = x + post(concat_h(y_h) W_out)
+
+    with h the pre-norm of x, over a RAGGED batch. The conv takes its
+    earlier inputs as the state-space operator's does (``_conv_window``,
+    from ``_SsmConvState`` over ``DELTA_CONV_LEAF``: u in the compute
+    dtype, before the SiLU; no bias). The recurrence (ops/delta.py, over
+    ``DELTA_LEAF``, float32) takes the rows as ``_slot_rows`` deals them:
+    the in-place update kernel for the one-token rows, the chunk form for
+    the chunk rows. The gates, the conv, the L2 norm and the output norm
+    are float32. Returns (x', kv)."""
+    cd, f32 = cfg.dtype, jnp.float32
+    Hk, Hv = cfg.delta_key_heads, cfg.delta_value_heads
+    dk, dv, K = cfg.delta_key_dim, cfg.delta_value_dim, cfg.delta_conv
+    T = x.shape[1]
+    with jax.named_scope(SCOPE_DELTA_PROJ):
+        h = _norm(x, lp["delta_norm"], cfg)[0]
+        u, z, ba = (h @ lp[k].astype(cd) for k in ("w_qkv", "w_z", "w_ba"))
+        conv = _SsmConvState(kv[DELTA_CONV_LEAF], l, rows, T)
+        prev = _conv_window(u, conv.fetch, rows, K)
+        w = lp["w_conv"].astype(f32)                       # [K, ch]
+        c = jax.nn.silu(sum(w[K - 1 - s] * prev[s].astype(f32)
+                            for s in range(K)))
+        conv_state = conv.store(_conv_upto(prev))
+        q, k, v = jnp.split(c, [Hk * dk, 2 * Hk * dk], axis=-1)
+
+        def unit(a):
+            a = a.reshape(T, Hk, dk)
+            return a * lax.rsqrt(jnp.sum(a * a, axis=-1, keepdims=True)
+                                 + 1e-6)
+
+        q, k, v = unit(q) * dk ** -0.5, unit(k), v.reshape(T, Hv, dv)
+        b, a = jnp.split(ba.astype(f32), 2, axis=-1)
+        beta = jax.nn.sigmoid(b)
+        g = -jnp.exp(lp["A_log"].astype(f32)) \
+            * jax.nn.softplus(a + lp["dt_bias"].astype(f32))
+    o, (state,) = _slot_rows(
+        rows, (kv[DELTA_LEAF],), (q, k, v, g, beta),
+        (SCOPE_DELTA_UPDATE, SCOPE_DELTA_CHUNK, SCOPE_DELTA_PROJ),
+        functools.partial(delta.delta_decode_update, layer=l, impl=impl),
+        functools.partial(delta.delta_chunk_scan, layer=l,
+                          chunk=cfg.delta_chunk, impl=impl))
+    with jax.named_scope(SCOPE_DELTA_PROJ):
+        s = cfg.delta_gate_scale
+        y = _rmsnorm(o, s * jax.nn.sigmoid(lp["gate_norm"].astype(f32)),
+                     cfg.delta_norm_eps) \
+            * (s * jax.nn.sigmoid(z.astype(f32).reshape(T, Hv, dv)))
+        y = y.reshape(T, Hv * dv).astype(cd) @ lp["w_out"].astype(cd)
+    return _residual(x, y[None], cfg,
+                     lp["attn_post_norm"] if cfg.post_norms else None), \
+        {**kv, DELTA_LEAF: state, DELTA_CONV_LEAF: conv_state}
+
+
 def _latent_attention(lp, l, x, kv, rows: _Rows, cfg: LlamaConfig, impl):
     """Latent attention (MLA) of one layer in its ABSORBED form, for
     decode rows and chunk rows alike, on entry ``l`` of the latent
@@ -615,21 +706,30 @@ def _latent_attention(lp, l, x, kv, rows: _Rows, cfg: LlamaConfig, impl):
     themselves (score_h = (q~_h . c + q_pe_h . k_pe) / sqrt(nope +
     rope)), the kernel returns o~_h = sum_s p_h c[s], and o_h = o~_h
     w_uv_h: equal in exact arithmetic, and the cache is read once for
-    all heads and never expanded in HBM."""
+    all heads and never expanded in HBM. Variation points (GigaChat3.5 is
+    the first block with them): a low-rank query (``q_lora_rank``: q =
+    rms(z wq_a) wq), YaRN frequencies on the rotary part (``rope_yarn``),
+    a score scale of its own (``attn_scale``: the family's mscale^2 on
+    1 / sqrt(nope + rope)), and the gated block's output gate and second
+    norm (``attn_gate``: o_h * sigmoid(z w_og) after w_uv;
+    ``post_norms``)."""
     cd = cfg.dtype
     r, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
     T, W = x.shape[1], kv["k"].shape[-1]
     token_pos, q_start, q_len = rows.token_pos, rows.q_start, rows.q_len
+    rope = functools.partial(_rope_pairs, positions=token_pos,
+                             theta=cfg.rope_theta, yarn=cfg.rope_yarn)
     with jax.named_scope(SCOPE_ATTENTION):
         with jax.named_scope(SCOPE_MLA_PROJ):
-            h = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
+            h = _norm(x, lp["attn_norm"], cfg)
             hq = lp["w_uk"].shape[0]
-            q = (h @ lp["wq"].astype(cd)).reshape(1, T, hq, -1)
+            hq_in = _norm(h @ lp["wq_a"].astype(cd), lp["q_a_norm"], cfg) \
+                if cfg.q_lora_rank else h
+            q = (hq_in @ lp["wq"].astype(cd)).reshape(1, T, hq, -1)
             a = h @ lp["w_kva"].astype(cd)            # [1, T, r + rope]
-            c = _rmsnorm(a[..., :r], lp["kv_norm"], cfg.norm_eps)
-            q_pe = _rope_pairs(q[..., dn:], token_pos, cfg.rope_theta)
-            k_pe = _rope_pairs(a[:, :, None, r:], token_pos,
-                               cfg.rope_theta)        # [1, T, 1, rope]
+            c = _norm(a[..., :r], lp["kv_norm"], cfg)
+            q_pe = rope(q[..., dn:])
+            k_pe = rope(a[:, :, None, r:])            # [1, T, 1, rope]
             q_lat = jnp.einsum("thn,hnr->thr", q[0, ..., :dn],
                                lp["w_uk"].astype(cd))
             # rows of the pool's width: zeros past rank + rope add
@@ -645,11 +745,18 @@ def _latent_attention(lp, l, x, kv, rows: _Rows, cfg: LlamaConfig, impl):
             q_start=q_start, q_len=q_len, **hints)
         o = ragged_paged_attention(
             qq, pool, None, rows.page_table, q_start, q_len, rows.kv_len,
-            v_width=r, sm_scale=q.shape[-1] ** -0.5, **hints)
+            v_width=r, sm_scale=cfg.attn_scale or q.shape[-1] ** -0.5,
+            **hints)
         with jax.named_scope(SCOPE_MLA_PROJ):
             o = jnp.einsum("thr,hrv->thv", o.astype(cd),
                            lp["w_uv"].astype(cd)).reshape(1, T, -1)
-            x = _residual(x, o @ lp["wo"].astype(cd), cfg)
+            if cfg.attn_gate:
+                with jax.named_scope(SCOPE_GATE):
+                    g = jax.nn.sigmoid(
+                        (h @ lp["w_og"].astype(cd)).astype(jnp.float32))
+                    o = (o * g).astype(cd)
+            x = _residual(x, o @ lp["wo"].astype(cd), cfg,
+                          lp["attn_post_norm"] if cfg.post_norms else None)
     return x, {**kv, "k": pool}
 
 
@@ -768,7 +875,8 @@ def _paged_attention(lp, l, x, kv, rows: _Rows, cfg: LlamaConfig, impl,
 #: by the same key.
 OPERATORS = {ATTENTION: ("attn", _attention), CONV: ("conv", _short_conv),
              MAMBA: ("mamba", _mamba), RETENTION: ("retention", _retention),
-             WINDOW: ("attn_window", _window_attention)}
+             WINDOW: ("attn_window", _window_attention),
+             DELTA: ("delta", _delta)}
 
 
 def _pattern(cfg: LlamaConfig):
@@ -912,7 +1020,7 @@ def _ragged_logits(params: Params, tokens: jax.Array,
               token_page, token_slot, page_table, kv_len, max_q_len,
               tp_axis, token_page_win, page_table_win, page_base_win),
         valid, cfg, paged_impl)
-    x = _rmsnorm(x, params["final_norm"], cfg.norm_eps)
+    x = _norm(x, params["final_norm"], cfg)
     last = jnp.clip(q_start + q_len - 1, 0, T - 1)        # [R]
     xl = x[0][last]
     head = params["embed"] if cfg.tie_embeddings else params["lm_head"]

@@ -77,6 +77,17 @@ def tp_param_specs(cfg: LlamaConfig) -> Params:
 def validate_tp(cfg: LlamaConfig, tp: int) -> None:
     if tp < 2:
         raise ValueError(f"tp must be >= 2 for a sharded engine, got {tp}")
+    if cfg.delta_block:
+        raise NotImplementedError(
+            f"tp={tp} is not served for this block: linear_attention "
+            f"layers (delta_key_heads, delta_value_heads, delta_key_dim, "
+            f"delta_value_dim) keep a float32 matrix state and their "
+            f"conv's inputs per batch slot with no partition spec here "
+            f"(the state would shard by value head with w_qkv's, w_z's and "
+            f"w_ba's columns, two value heads to a key head), beside a "
+            f"latent pool that has no head axis to shard; q_lora_rank, "
+            f"rope_yarn, norm_gate and ffn_clamp are refused with them, "
+            f"untested under a shard (ROADMAP R10b)")
     if cfg.window_block:
         raise NotImplementedError(
             f"tp={tp} is not served for this block: sliding_attention "

@@ -24,6 +24,8 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import itertools
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -43,9 +45,10 @@ from ray_tpu.parallel.ulysses import ulysses_attention_sharded
 Params = Dict[str, Any]
 
 #: a layer's operator, as the published configurations name it
-ATTENTION, CONV, MAMBA, RETENTION, WINDOW = \
-    "full_attention", "conv", "mamba", "retention", "sliding_attention"
-LAYER_KINDS = (ATTENTION, CONV, MAMBA, RETENTION, WINDOW)
+ATTENTION, CONV, MAMBA, RETENTION, WINDOW, DELTA = \
+    "full_attention", "conv", "mamba", "retention", "sliding_attention", \
+    "linear_attention"
+LAYER_KINDS = (ATTENTION, CONV, MAMBA, RETENTION, WINDOW, DELTA)
 #: range of the seeded attention sinks (float32, one a query head of a
 #: window layer): a full window's 128 scores under seeded weights sum to
 #: about e^4.9, so a sink in [3, 6] takes between a sixth and three quarters
@@ -168,10 +171,36 @@ class LlamaConfig:
     # experts first .. first + n - 1 of n_experts. The router keeps all
     # n_experts outputs; pairs routed elsewhere go nowhere (ops/moe.py).
     experts_held: Tuple[int, ...] = ()
+    # Gated-delta-rule linear attention beside latent attention
+    # (GigaChat3.5, model_type gigachat3_5, is the first such block):
+    # layer_types names "linear_attention" layers, which keep no page: per
+    # batch slot and VALUE head a float32 matrix state [delta_key_dim,
+    # delta_value_dim] that each token decays and then CORRECTS along its
+    # key (ops/delta.py), beside the last delta_conv - 1 inputs of a
+    # depthwise conv over q, k and v together. Key head j serves value
+    # heads j Hv/Hk .. (j+1) Hv/Hk - 1.
+    delta_key_heads: int = 0         # Hk
+    delta_value_heads: int = 0       # Hv
+    delta_key_dim: int = 0           # dk: a q / k head, rows of the state
+    delta_value_dim: int = 0         # dv: a v head, columns of the state
+    delta_conv: int = 4              # taps of its depthwise causal conv
+    delta_chunk: int = 64            # tokens a block of its chunk form
+    delta_norm_eps: float = 1e-6     # of the per-head norm on its output
+    delta_gate_scale: float = 2.0    # y = rms(o) s sigmoid(w) s sigmoid(z)
+    # ... and what that block's latent attention and feed-forward add. Off
+    # (0 / ()) = every other block's program.
+    q_lora_rank: int = 0             # q = rms(h wq_a) wq: a low-rank query
+    #: YaRN on the latent operator's rotary part: (factor, original
+    #: positions, beta_fast, beta_slow, mscale, mscale_all_dim)
+    rope_yarn: Tuple[float, ...] = ()
+    norm_gate: float = 0.0           # every norm x/rms * this * sigmoid(w)
+    ffn_clamp: float = 0.0           # silu(min(g, c)) * clip(u, -c, c)
 
     def __post_init__(self):
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
         object.__setattr__(self, "experts_held", tuple(self.experts_held))
+        object.__setattr__(self, "rope_yarn",
+                           tuple(float(v) for v in self.rope_yarn))
         bad = sorted(set(self.layer_types) - set(LAYER_KINDS))
         if bad or (self.layer_types
                    and len(self.layer_types) != self.n_layers):
@@ -255,14 +284,15 @@ class LlamaConfig:
                     "rotate and whose full layers do not: layer_types "
                     "names no sliding_attention layer (rope=False is the "
                     "block with no positions at all)")
-            if self.kv_lora_rank or len(
-                    set(self.layer_types) - {ATTENTION, WINDOW}):
+            if (self.kv_lora_rank and not self.full_rope) or len(
+                    set(self.layer_types) - {ATTENTION, WINDOW, DELTA}):
                 raise ValueError(
-                    "attn_gate, post_norms and full_rope describe per-head "
-                    "K and V attention (full_attention and "
-                    "sliding_attention layers): not a latent pool, nor "
-                    "conv, mamba or retention layers, whose operators have "
-                    "no gate and no second norm")
+                    "attn_gate, post_norms and full_rope describe attention "
+                    "(full_attention, latent or not, and sliding_attention "
+                    "layers) and linear_attention layers, which take the "
+                    "second norm: not conv, mamba or retention layers, "
+                    "whose operators have no gate and no second norm, and "
+                    "the latent operator always rotates")
         if (self.score_head_dim or self.value_head_dim or self.rotary_dim
                 or self.value_scale != 1.0):
             dk, dv = self.qk_head_dim, self.v_dim
@@ -282,11 +312,12 @@ class LlamaConfig:
             first, n = self.experts_held if len(self.experts_held) == 2 \
                 else (-1, 0)
             if not self.n_experts or first < 0 or n < 1 \
-                    or first + n > self.n_experts or self.shared_ffn_dim:
+                    or first + n > self.n_experts:
                 raise ValueError(
                     f"experts_held = (first, n) names a chip's share of "
-                    f"n_experts={self.n_experts} routed experts, with no "
-                    f"shared expert beside them; got {self.experts_held}")
+                    f"n_experts={self.n_experts} routed experts (a shared "
+                    f"expert beside them is whole on every chip); got "
+                    f"{self.experts_held}")
         heads = (self.qk_nope_head_dim, self.qk_rope_head_dim,
                  self.v_head_dim)
         if self.kv_lora_rank:
@@ -302,10 +333,46 @@ class LlamaConfig:
                     "the latent and no per-head K: qk_norm / "
                     "qk_norm_per_head do not apply, and it is not built "
                     "beside conv layers")
-        elif any(heads):
+        elif any(heads) or self.q_lora_rank or self.rope_yarn:
             raise ValueError(
-                "qk_nope_head_dim, qk_rope_head_dim and v_head_dim "
-                "describe latent attention: they need kv_lora_rank")
+                "qk_nope_head_dim, qk_rope_head_dim, v_head_dim, "
+                "q_lora_rank and rope_yarn describe latent attention: they "
+                "need kv_lora_rank")
+        if self.rope_yarn and (len(self.rope_yarn) != 6
+                               or self.rope_yarn[0] <= 1.0):
+            raise ValueError(
+                f"rope_yarn = (factor > 1, original positions, beta_fast, "
+                f"beta_slow, mscale, mscale_all_dim), got {self.rope_yarn}")
+        if self.norm_gate and not self.kv_lora_rank:
+            raise ValueError(
+                "norm_gate gates EVERY norm of a block, and only latent "
+                "attention (kv_lora_rank) and the linear_attention layers "
+                "beside it take their norms through it: per-head K and V "
+                "attention would be served with a plain norm")
+        delta = (self.delta_key_heads, self.delta_value_heads,
+                 self.delta_key_dim, self.delta_value_dim)
+        if DELTA in self.layer_types:
+            chunk = self.delta_chunk
+            if min(delta) <= 0 or self.delta_value_heads \
+                    % self.delta_key_heads or self.delta_conv < 2 \
+                    or chunk < 1 or chunk & (chunk - 1):
+                raise ValueError(
+                    f"linear_attention layers need delta_key_heads that "
+                    f"divide delta_value_heads, delta_key_dim, "
+                    f"delta_value_dim, at least 2 conv taps and a chunk "
+                    f"that is a power of two, got {delta}, "
+                    f"{self.delta_conv}, {chunk}")
+            if len(set(self.layer_types) - {ATTENTION, DELTA}):
+                raise ValueError(
+                    "linear_attention layers are built beside "
+                    "full_attention layers only (latent or not): not "
+                    "beside conv, mamba, retention or sliding_attention "
+                    "layers")
+        elif any(delta):
+            raise ValueError(
+                "delta_key_heads, delta_value_heads, delta_key_dim and "
+                "delta_value_dim describe linear_attention layers: "
+                "layer_types names none")
 
     @property
     def head_dim(self) -> int:
@@ -327,6 +394,15 @@ class LlamaConfig:
         layers without positions beside window layers that rotate
         (Trinity-Mini is the first such block)."""
         return self.attn_gate or self.post_norms or not self.full_rope
+
+    @property
+    def delta_block(self) -> bool:
+        """Gated-delta-rule layers, a low-rank query, YaRN frequencies,
+        sigmoid-gated norms or a clamped SwiGLU (GigaChat3.5 is the first
+        such block): what llm/model.py alone serves."""
+        return DELTA in self.layer_types or bool(
+            self.q_lora_rank or self.rope_yarn or self.norm_gate
+            or self.ffn_clamp)
 
     @property
     def window_block(self) -> bool:
@@ -482,23 +558,50 @@ def _init_hybrid_params(cfg: LlamaConfig, key: jax.Array) -> Params:
     expert (shared_ffn_dim) is three more leaves of "moe", sliced by the
     layer scan like the router; the routed experts stay closed over. With
     experts_held = (first, n) the three expert leaves hold those n experts
-    only and the router every expert's column."""
+    only and the router every expert's column. With q_lora_rank the query
+    is two matrices and a norm between them: wq_a [d, rank], q_a_norm
+    [rank], and wq [rank, H (nope + rope)]; a gated block's w_og and
+    attn_post_norm join the latent stack as they join the others.
+
+    "delta" (the gated-delta-rule layers): delta_norm; the projection to q,
+    k and v as ONE matrix w_qkv [d, 2 Hk dk + Hv dv] (its columns the
+    conv's channels, in that order: 128 whole lane tiles at the published
+    sizes), to the output gate w_z [d, Hv dv] and to the write strength and
+    the decay w_ba [d, 2 Hv] (b, then a); the depthwise taps w_conv [taps,
+    channels], A_log and dt_bias a value head, the three float32 whatever
+    the weights are held in; the output norm's weight gate_norm [dv], one
+    for all heads; w_out [Hv dv, d].
+
+    With norm_gate every norm's weight w enters as norm_gate * sigmoid(w)
+    and is drawn uniform in [-0.5, 0.5] (a factor of 0.76 to 1.24 at 2:
+    at w = 0 the factor is 1 and no check could see the sigmoid left out);
+    without it every norm's weight is one."""
     d, L, pd = cfg.dim, cfg.n_layers, cfg.param_dtype
     hq, hd = cfg.n_heads, cfg.head_dim
     dk, dv = cfg.qk_head_dim, cfg.v_dim
-    keys = iter(jax.random.split(key, 24))
+    # 24 keys split as they always were (a block's draws do not move when
+    # another block gains a leaf), then as many more as a block asks for
+    keys = itertools.chain(
+        jax.random.split(key, 24),
+        (jax.random.fold_in(key, 24 + i) for i in itertools.count()))
 
     def dense(*shape, fan_in=None, dtype=pd):
         fan_in = fan_in if fan_in is not None else shape[-2]
         return (jax.random.normal(next(keys), shape)
                 * (fan_in ** -0.5)).astype(dtype)
 
+    def norm(*shape):
+        if not cfg.norm_gate:
+            return jnp.ones(shape, pd)
+        return jax.random.uniform(next(keys), shape, jnp.float32, -0.5,
+                                  0.5).astype(pd)
+
     def swiglu(n, *E, f):
-        stack = {"mlp_norm": jnp.ones((n, d), pd),
+        stack = {"mlp_norm": norm(n, d),
                  "w_gate": dense(n, *E, d, f), "w_up": dense(n, *E, d, f),
                  "w_down": dense(n, *E, f, d)}
         if cfg.post_norms:
-            stack["mlp_post_norm"] = jnp.ones((n, d), pd)
+            stack["mlp_post_norm"] = norm(n, d)
         return stack
 
     def qkv(n, hkv=cfg.n_kv_heads):
@@ -519,14 +622,14 @@ def _init_hybrid_params(cfg: LlamaConfig, key: jax.Array) -> Params:
             stack["k_norm"] = jnp.ones((n, hkv * hd), pd)
         return stack
 
-    def gated(stack, n):
+    def gated(stack, n, dv=dv):
         """... and what the attention and window operators of a gated
         block add: the gate's projection, as wide as the heads' output,
         and the norm after the branch."""
         if cfg.attn_gate:
             stack["w_og"] = dense(n, d, hq * dv)
         if cfg.post_norms:
-            stack["attn_post_norm"] = jnp.ones((n, d), pd)
+            stack["attn_post_norm"] = norm(n, d)
         return stack
 
     hkv = cfg.n_kv_heads
@@ -535,12 +638,15 @@ def _init_hybrid_params(cfg: LlamaConfig, key: jax.Array) -> Params:
     if A and cfg.kv_lora_rank:
         r, dn, dr, dv = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
                          cfg.qk_rope_head_dim, cfg.v_head_dim)
-        layers["attn"] = {
-            "attn_norm": jnp.ones((A, d), pd),
-            "wq": dense(A, d, hq * (dn + dr)), "w_kva": dense(A, d, r + dr),
-            "kv_norm": jnp.ones((A, r), pd),
+        rq = cfg.q_lora_rank
+        layers["attn"] = gated({
+            "attn_norm": norm(A, d),
+            **({"wq_a": dense(A, d, rq), "q_a_norm": norm(A, rq)}
+               if rq else {}),
+            "wq": dense(A, rq or d, hq * (dn + dr)),
+            "w_kva": dense(A, d, r + dr), "kv_norm": norm(A, r),
             "w_uk": dense(A, hq, dn, r, fan_in=r),
-            "w_uv": dense(A, hq, r, dv), "wo": dense(A, hq * dv, d)}
+            "w_uv": dense(A, hq, r, dv), "wo": dense(A, hq * dv, d)}, A, dv)
     elif A:
         layers["attn"] = gated(qkv(A), A)
     Wn = len(cfg.layers_of(WINDOW))
@@ -584,6 +690,33 @@ def _init_hybrid_params(cfg: LlamaConfig, key: jax.Array) -> Params:
             "D": jnp.ones((S, H), f32),
             "gate_norm": jnp.ones((S, di), pd),
             "w_out": dense(S, di, d)}
+    Dn = len(cfg.layers_of(DELTA))
+    if Dn:
+        Hk, Hv = cfg.delta_key_heads, cfg.delta_value_heads
+        wide = Hv * cfg.delta_value_dim
+        ch = 2 * Hk * cfg.delta_key_dim + wide
+        f32 = jnp.float32
+
+        def per_head(lo, hi):
+            return jax.random.uniform(next(keys), (Dn, Hv), f32, lo, hi)
+
+        # the state-space layers' draw, for its reason: A = exp(A_log) in
+        # [1, 16] and softplus(dt_bias) log-uniform in [1e-3, 1e-1], so
+        # g = -A softplus(a + dt_bias) lets a head forget over 1 to 1000
+        # tokens: the state is alive, and old writes still weigh in it
+        dt = jnp.exp(per_head(jnp.log(1e-3), jnp.log(1e-1)))
+        layers["delta"] = {
+            "delta_norm": norm(Dn, d),
+            "w_qkv": dense(Dn, d, ch), "w_z": dense(Dn, d, wide),
+            "w_ba": dense(Dn, d, 2 * Hv),
+            "w_conv": dense(Dn, cfg.delta_conv, ch, fan_in=cfg.delta_conv,
+                            dtype=f32),
+            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),   # softplus^-1(dt)
+            "A_log": jnp.log(per_head(1.0, 16.0)),
+            "gate_norm": norm(Dn, cfg.delta_value_dim),
+            "w_out": dense(Dn, wide, d)}
+        if cfg.post_norms:
+            layers["delta"]["attn_post_norm"] = norm(Dn, d)
     Rt = len(cfg.layers_of(RETENTION))
     if Rt:
         # sigmoid(b_g) log-uniform in 1 - [1e-3, 1e-1]: a head forgets over
@@ -616,13 +749,21 @@ def _init_hybrid_params(cfg: LlamaConfig, key: jax.Array) -> Params:
                 w_shared_gate=dense(M, d, fs), w_shared_up=dense(M, d, fs),
                 w_shared_down=dense(M, fs, d))
     params = {"embed": dense(cfg.vocab_size, d, fan_in=d),
-              "layers": layers, "final_norm": jnp.ones((d,), pd)}
+              "layers": layers, "final_norm": norm(d)}
     if not cfg.tie_embeddings:
         params["lm_head"] = dense(cfg.vocab_size, d, fan_in=d)
     return params
 
 
 def _require_llama_block(cfg: LlamaConfig, what: str) -> None:
+    if cfg.delta_block:
+        raise NotImplementedError(
+            f"{what} is written for the Llama/Mistral block: "
+            f"linear_attention layers (delta_key_heads, delta_value_heads, "
+            f"delta_key_dim, delta_value_dim: the gated delta rule, whose "
+            f"float32 matrix state is a CACHE format, with no training "
+            f"scan or backward here), q_lora_rank, rope_yarn, norm_gate "
+            f"and ffn_clamp are served by llm/model.py only (ROADMAP R4)")
     if cfg.window_block:
         raise NotImplementedError(
             f"{what} is written for the Llama/Mistral block: "
@@ -712,7 +853,34 @@ def _rope(x, positions, theta):
         [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1).astype(x.dtype)
 
 
-def _rope_pairs(x, positions, theta):
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature term: 0.1 mscale ln(factor) + 1."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_freqs(dim: int, theta: float, yarn: Tuple[float, ...]):
+    """The dim / 2 rotary frequencies under YaRN (``LlamaConfig.rope_yarn``):
+    pair j turns at theta^(-2j/dim) where it completes more than beta_fast
+    turns over the original positions, at 1 / factor of that where fewer
+    than beta_slow, and at a linear blend of the two between. Returns
+    (freqs [dim / 2] float32, the factor on cos and sin: mscale's term over
+    mscale_all_dim's, 1 where the two are equal)."""
+    factor, original, fast, slow, mscale, mscale_all = yarn
+
+    def turns_at(n):
+        return dim * math.log(original / (n * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(turns_at(fast)), 0)
+    high = min(math.ceil(turns_at(slow)), dim - 1)
+    j = jnp.arange(0, dim // 2, dtype=jnp.float32)
+    plain = theta ** (-2.0 * j / dim)
+    ramp = jnp.clip((j - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return plain / factor * ramp + plain * (1.0 - ramp), \
+        yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all)
+
+
+def _rope_pairs(x, positions, theta, yarn: Tuple[float, ...] = ()):
     """Rotary embedding over ADJACENT pairs (2j, 2j+1) of the last axis,
     pair j turning at theta^(-2j/D) (the published rope_interleave layout
     of the deepseek_v3 family); x: [B, L, H, D_even], positions as
@@ -720,10 +888,15 @@ def _rope_pairs(x, positions, theta):
     exp(i * position * theta^(-2j/D))."""
     d2 = x.shape[-1] // 2
     freqs = theta ** (-jnp.arange(0, d2, dtype=jnp.float32) / d2)
+    scale = 1.0
+    if yarn:
+        freqs, scale = yarn_freqs(x.shape[-1], theta, yarn)
     ang = positions[..., None].astype(jnp.float32) * freqs  # [..., L, d2]
     if ang.ndim == 2:
         ang = ang[None]
     cos, sin = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     xp = x.astype(jnp.float32).reshape(x.shape[:-1] + (d2, 2))
     x1, x2 = xp[..., 0], xp[..., 1]
     return jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos],

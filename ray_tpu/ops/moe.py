@@ -164,8 +164,20 @@ def group(experts, n_experts: int, tm: int):
     return dest.reshape(T, k), tile_expert, tile_end[-1], counts
 
 
+def gate_half(g, clamp: float = 0.0):
+    """silu(g), the gate half of a SwiGLU; with ``clamp`` = c (a published
+    swiglu_limit) silu(min(g, c)): held from above."""
+    return jax.nn.silu(jnp.minimum(g, clamp) if clamp else g)
+
+
+def up_half(u, clamp: float = 0.0):
+    """The linear half of a SwiGLU; with ``clamp`` = c clip(u, -c, c)."""
+    return jnp.clip(u, -clamp, clamp) if clamp else u
+
+
 def _experts_kernel(te_ref, meta_ref,                 # scalar prefetch
-                    x_ref, g_ref, u_ref, d_ref, o_ref, acc_ref, *, nf):
+                    x_ref, g_ref, u_ref, d_ref, o_ref, acc_ref, *, nf,
+                    clamp=0.0):
     t, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(t < meta_ref[0])
@@ -177,7 +189,7 @@ def _experts_kernel(te_ref, meta_ref,                 # scalar prefetch
         x = x_ref[...]                                       # [tm, d]
         g = jnp.dot(x, g_ref[...], preferred_element_type=jnp.float32)
         u = jnp.dot(x, u_ref[...], preferred_element_type=jnp.float32)
-        h = (jax.nn.silu(g) * u).astype(x.dtype)             # [tm, fb]
+        h = (gate_half(g, clamp) * up_half(u, clamp)).astype(x.dtype)
         acc_ref[...] += jnp.dot(h, d_ref[...],
                                 preferred_element_type=jnp.float32)
 
@@ -186,9 +198,11 @@ def _experts_kernel(te_ref, meta_ref,                 # scalar prefetch
             o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("tm", "fb", "interpret"))
+@functools.partial(jax.jit, static_argnames=("tm", "fb", "interpret",
+                                             "clamp"))
 def _moe_experts_pallas(x_rows, tile_expert, n_used, layer, gate, up, down,
-                        tm: int, fb: int, interpret: bool = False):
+                        tm: int, fb: int, interpret: bool = False,
+                        clamp: float = 0.0):
     """x_rows [n_tiles * tm, d] in tile order; gate / up [L, E, d, f],
     down [L, E, f, d]; returns the experts' outputs, row for row. Tiles
     from n_used on are pinned to the last live tile's blocks, so they
@@ -221,7 +235,7 @@ def _moe_experts_pallas(x_rows, tile_expert, n_used, layer, gate, up, down,
     need = 2 * 3 * d * fb * gate.dtype.itemsize + 4 * tm * d * isz \
         + tm * d * 4 + 3 * tm * fb * 4
     return pl.pallas_call(
-        functools.partial(_experts_kernel, nf=nf),
+        functools.partial(_experts_kernel, nf=nf, clamp=clamp),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(n_tiles, nf),
@@ -247,21 +261,22 @@ def _moe_experts_pallas(x_rows, tile_expert, n_used, layer, gate, up, down,
     )(tile_expert, meta, x_rows, gate, up, down)
 
 
-def _experts_reference(x_rows, tile_expert, layer, gate, up, down, tm: int):
+def _experts_reference(x_rows, tile_expert, layer, gate, up, down, tm: int,
+                       clamp: float = 0.0):
     """The tiles through an einsum over gathered expert weights."""
     n_rows, d = x_rows.shape
     cd = x_rows.dtype
     xt = x_rows.reshape(n_rows // tm, tm, d)
     g, u, dn = (w[layer][tile_expert].astype(cd) for w in (gate, up, down))
-    h = jax.nn.silu(jnp.einsum("ntd,ndf->ntf", xt, g)) \
-        * jnp.einsum("ntd,ndf->ntf", xt, u)
+    h = gate_half(jnp.einsum("ntd,ndf->ntf", xt, g), clamp) \
+        * up_half(jnp.einsum("ntd,ndf->ntf", xt, u), clamp)
     return jnp.einsum("ntf,nfd->ntd", h, dn).reshape(n_rows, d)
 
 
 def moe_ffn(m, valid, router, gate, up, down, top_k: int, renorm: bool, *,
             layer=None, impl: Optional[str] = None,
             interpret: bool = False, held: Optional[Tuple[int, int]] = None,
-            **routing):
+            clamp: float = 0.0, **routing):
     """m [T, d] (normed hidden states), valid [T] bool -> (y [T, d] in m's
     dtype, counters [3] int32 in COUNTERS' order).
 
@@ -269,7 +284,8 @@ def moe_ffn(m, valid, router, gate, up, down, top_k: int, renorm: bool, *,
     [L, E, ...] trees with ``layer`` the index to use (what the serving
     step passes: see the module docstring). y is 0 for padding tokens.
     ``impl``: "kernel" | "reference", None = the kernel on a TPU.
-    ``routing``: route's score, bias, eps and scale.
+    ``routing``: route's score, bias, eps and scale. ``clamp``:
+    ``gate_half``'s and ``up_half``'s (0: none).
 
     ``held`` = (first, n): the weights are [.., n, ..], experts first ..
     first + n - 1 of the router's E (module docstring); y is then this
@@ -306,10 +322,11 @@ def moe_ffn(m, valid, router, gate, up, down, top_k: int, renorm: bool, *,
         impl = "kernel" if kernels_supported() or interpret else "reference"
     if impl == "kernel":
         y_rows = _moe_experts_pallas(x_rows, tile_expert, n_used, layer,
-                                     gate, up, down, tm, fb, interpret)
+                                     gate, up, down, tm, fb, interpret,
+                                     clamp)
     elif impl == "reference":
         y_rows = _experts_reference(x_rows, tile_expert, layer, gate, up,
-                                    down, tm)
+                                    down, tm, clamp)
     else:
         raise ValueError(f"impl must be 'kernel' or 'reference', "
                          f"got {impl!r}")

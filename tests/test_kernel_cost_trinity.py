@@ -189,26 +189,39 @@ OWN = ["paged_attn_roofline.trinity", "window_attn_roofline.trinity",
 
 
 def test_the_cell_and_its_metrics_are_in_the_benchmark():
+    """The cell, its configuration and its metrics, found BY NAME and by
+    what an entry reads (reader, arguments, `moves`, the cell in its
+    `workloads`), never by their place in a list: a later PR appends its
+    own cell, configuration and entries after them."""
+    from benchmark.selftest import _reading_of
     bench = _bench()
     cell = next(w for w in bench["workloads"] if w["name"] == CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "trinity-mini-serve-1chip", "mixed-window", 1)
-    assert bench["workloads"][-1] is cell and len(cell["why"]) <= 200
-    entry = bench["configs"][-1]
-    assert entry["name"] == cell["config"]
+    assert len(cell["why"]) <= 200
+    entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
     assert entry["reduced"] == _config()["reduced"] == [
         "num_hidden_layers", "num_dense_layers", "layer_types"]
     assert len(bench["per_layer"]) <= 128
-    assert [m["name"] for m in bench["per_layer"][-3:]] == OWN
-    for m in bench["per_layer"][-3:]:
+    by_name = {m["name"]: m for m in bench["end_to_end"]
+               + bench["per_layer"]}
+    costs = {"paged_attn_roofline.trinity": "full_attention",
+             "window_attn_roofline.trinity": "window_attention",
+             "moe_ffn_roofline.trinity": "moe_experts"}
+    assert sorted(costs) == sorted(OWN)
+    for name in OWN:
+        m = by_name[name]
         assert m["workloads"] == [CELL] and m["moves"] == "out_tok_per_s"
-        assert m["name"].endswith("_roofline.trinity") and m["unit"] == "%"
-        assert _load("metrics", m["name"] + ".json")["name"] == m["name"]
+        assert m["unit"] == "%" and m in bench["per_layer"]
+        spec = _load("metrics", name + ".json")
+        assert spec["name"] == name
+        reader, args, moves = _reading_of(m)
+        assert (reader, json.loads(args)["cost"], moves) == (
+            "trinity_roofline", costs[name], "out_tok_per_s")
     for name in ["out_tok_per_s", "replica_ready_s", "batch_occupancy_pct",
                  "engine_host_ms", "chunk_rows_joined_pct"] + SHARED_BY_SPEC:
-        m = next(m for m in bench["end_to_end"] + bench["per_layer"]
-                 if m["name"] == name)
-        assert m["workloads"][-1] == CELL, name
+        m = by_name[name]
+        assert CELL in m["workloads"], name
         assert name not in SHARED_BY_SPEC \
             or m["moves"] == "out_tok_per_s", name
     assert not any(w["chips"] == 4 for w in bench["workloads"])

@@ -394,7 +394,8 @@ def _bench_entry(name):
 _CLOSED_LOOP = ["reason-1chip", "reason-moe-1chip", "reason-lfm2-1chip",
                 "context-kanana-1chip", "reason-granite-1chip",
                 "context-brumby-1chip", "context-mimo-1chip",
-                "mixed-trinity-1chip"]
+                "mixed-trinity-1chip",
+                "reason-gigachat-1chip"]
 
 
 @pytest.mark.parametrize("name,cells,moves", [
@@ -448,7 +449,8 @@ def test_the_claimed_cells_mixed_step_metrics_are_granites_readers(name,
 @pytest.mark.parametrize("name,cells,want,parent", [
     ("chunk_rows_joined_pct", ["context-kanana-1chip", "reason-moe-1chip",
                                "reason-granite-1chip", "context-mimo-1chip",
-                               "mixed-trinity-1chip"],
+                               "mixed-trinity-1chip",
+                               "reason-gigachat-1chip"],
      100.0 / 3, None),
     ("chunk_tokens_a_step.kanana", ["context-kanana-1chip"], 45 / 2,
      45 / 2)])

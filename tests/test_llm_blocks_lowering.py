@@ -92,15 +92,32 @@ BLOCKS = {
                     value_head_dim=16, sliding_window=16,
                     window_rope_theta=1e4, rope_theta=1e4,
                     qk_norm_per_head=True, attn_gate=True, post_norms=True,
-                    full_rope=False, embed_scale=8.0)}
+                    full_rope=False, embed_scale=8.0),
+    "gigachat": dict(n_layers=5, n_heads=4, n_kv_heads=4, ffn_dim=32,
+                     dense_ffn_dim=96, n_dense_layers=1, n_experts=8,
+                     experts_per_token=3, norm_topk_prob=True,
+                     router_score="sigmoid", router_bias=True,
+                     router_eps=1e-20, router_scale=2.5, shared_ffn_dim=32,
+                     experts_held=(2, 4), tie_embeddings=False,
+                     layer_types=["linear_attention"] * 4
+                     + ["full_attention"], kv_lora_rank=32,
+                     qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+                     q_lora_rank=24, rope_yarn=(8, 16, 32, 1, 1, 1),
+                     attn_scale=0.3, attn_gate=True, post_norms=True,
+                     norm_gate=2.0, ffn_clamp=10.0, delta_key_heads=2,
+                     delta_value_heads=4, delta_key_dim=8,
+                     delta_value_dim=16, delta_chunk=8)}
 
 #: what only a block's own fields may bring into a program's text or
 #: trees: named scopes, parameter leaves, and the shape of the pool
 ONLY_LATENT = ("mla_proj", "w_kva", "w_uk", "w_uv", "kv_norm")
 ONLY_SHARED = ("moe_shared", "w_shared_gate", "w_shared_up", "w_shared_down")
 ONLY_CONV = ("short_conv", "conv_norm")
-ONLY_SSM = ("ssm_proj", "ssm_update", "ssm_scan", "w_xbc", "A_log",
-            "ssm_conv")
+ONLY_SSM = ("ssm_proj", "ssm_update", "ssm_scan", "w_xbc", "ssm_conv")
+#: ... the two recurrences with a decay a head
+ONLY_DECAY = ("A_log", "dt_bias")
+ONLY_DELTA = ("delta_proj", "delta_update", "delta_chunk", "delta_norm",
+              "w_qkv", "w_ba", "delta_conv", "wq_a", "q_a_norm")
 ONLY_RETENTION = ("retention_proj", "retention_update", "retention_chunk",
                   "retention_norm", "'b_g'")
 ONLY_WINDOW = ("attn_window", "attn_full_proj", "k_win", "v_win", "'sink'",
@@ -202,24 +219,38 @@ def test_a_block_takes_no_other_blocks_code(block):
     cfg = LlamaConfig.tiny(**BLOCKS[block])
     texts = lowered(block)
     everything = "\n".join(texts.values())
+    if "linear_attention" in cfg.layer_types:
+        # the control for the delta block's own words, beside the latent
+        # operator's, the shared expert's and the gated block's, which it
+        # has too; and nothing of the other recurrences or of a window
+        missing = [w for w in ONLY_DELTA + ONLY_DECAY + ONLY_LATENT
+                   + ONLY_SHARED + ONLY_GATED if w not in everything]
+        assert not missing, f"the delta block's texts lack {missing}"
+        assert not [w for w in ONLY_CONV + ONLY_SSM + ONLY_RETENTION
+                    + ONLY_WINDOW if w in everything]
+        assert all("'v'" not in texts[f"{block}.{impl}.pool"]
+                   for impl in ("reference", "kernel"))
+        return
     if cfg.kv_lora_rank:
         # the control: the block that HAS the fields shows every word, so
         # the search below finds what it looks for
         missing = [w for w in ONLY_LATENT + ONLY_SHARED
                    if w not in everything]
         assert not missing, f"the latent block's texts lack {missing}"
-        assert not [w for w in ONLY_CONV + ONLY_SSM + ONLY_RETENTION
-                    if w in everything]
+        assert not [w for w in ONLY_CONV + ONLY_SSM + ONLY_DECAY
+                    + ONLY_RETENTION + ONLY_DELTA if w in everything]
         assert all("'v'" not in texts[f"{block}.{impl}.pool"]
                    for impl in ("reference", "kernel"))
         return
-    absent = ONLY_LATENT + (() if cfg.shared_ffn_dim else ONLY_SHARED) \
+    absent = ONLY_LATENT + ONLY_DELTA \
+        + (() if cfg.shared_ffn_dim else ONLY_SHARED) \
         + (() if cfg.gated_block else ONLY_GATED) \
         + (() if "conv" in cfg.layer_types else ONLY_CONV) \
-        + (() if "mamba" in cfg.layer_types else ONLY_SSM) \
+        + (() if "mamba" in cfg.layer_types else ONLY_SSM + ONLY_DECAY) \
         + (() if "retention" in cfg.layer_types else ONLY_RETENTION) \
         + (() if "sliding_attention" in cfg.layer_types else ONLY_WINDOW)
-    for kind, words in (("mamba", ONLY_SSM), ("retention", ONLY_RETENTION),
+    for kind, words in (("mamba", ONLY_SSM + ONLY_DECAY),
+                        ("retention", ONLY_RETENTION),
                         ("sliding_attention", ONLY_WINDOW)):
         if kind in cfg.layer_types:
             # the control for the block's own words (a sink is MiMo's)
@@ -265,7 +296,10 @@ PATTERNS = {
              [("sliding_attention", "moe"), ("full_attention", "moe")], 2),
     "trinity": ([("sliding_attention", "dense")],
                 [("sliding_attention", "moe")] * 3
-                + [("full_attention", "moe")], 1)}
+                + [("full_attention", "moe")], 1),
+    "gigachat": ([("linear_attention", "dense")],
+                 [("linear_attention", "moe")] * 3
+                 + [("full_attention", "moe")], 1)}
 
 
 @pytest.mark.parametrize("block", sorted(BLOCKS))
@@ -290,7 +324,7 @@ def test_every_kind_of_layer_is_declared_once_in_each_table():
     for kind, (stack, body) in M.OPERATORS.items():
         assert isinstance(stack, str) and callable(body), kind
     assert C.STATE_LEAVES == ("conv", "ssm", "ssm_conv", "retention",
-                              "retention_norm")
+                              "retention_norm", "delta", "delta_conv")
 
 
 @pytest.mark.parametrize("block", sorted(BLOCKS))

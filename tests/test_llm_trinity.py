@@ -473,11 +473,11 @@ def test_config_refuses_what_is_not_built():
     with pytest.raises(ValueError, match="full_rope=False describes"):
         tiny(layer_types=[FULL] * 5, sliding_window=0, window_kv_heads=0,
              window_rope_theta=0.0)
-    # the gate and the second norm are the per-head K and V operators'
-    with pytest.raises(ValueError, match="no gate and no second norm"):
-        LlamaConfig.tiny(dim=64, attn_gate=True, kv_lora_rank=32,
-                         qk_nope_head_dim=8, qk_rope_head_dim=8,
-                         v_head_dim=8)
+    # the gate and the second norm are the attention operators' (the latent
+    # one's too since PR 55) and the delta rule's, and no other operator's
+    assert LlamaConfig.tiny(dim=64, attn_gate=True, kv_lora_rank=32,
+                            qk_nope_head_dim=8, qk_rope_head_dim=8,
+                            v_head_dim=8).gated_block
     with pytest.raises(ValueError, match="no gate and no second norm"):
         LlamaConfig.tiny(dim=64, post_norms=True,
                          layer_types=["conv", FULL, "conv", FULL])

@@ -14,6 +14,13 @@ from ray_tpu.util import state
 
 @pytest.fixture(scope="module")
 def state_rt():
+    # this PROCESS's compile tracker outlives the test file that started it
+    # (an in-process engine does, llm/model.py:StepPrograms), and the first
+    # head the process meets is handed whatever it staged: recompile storms
+    # of an earlier file of the same pytest worker would be counted beside
+    # the one test_compiles_cli_smoke seeds. The driver starts a new one
+    from ray_tpu.util import compile_tracker
+    compile_tracker.stop_global()
     rt.init(num_cpus=2, _system_config={
         "object_store_memory_bytes": 64 * 1024 * 1024})
     yield rt

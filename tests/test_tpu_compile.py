@@ -785,6 +785,82 @@ def test_trinity_step_programs_compile_at_benchmark_shapes(chip, program):
     assert peak < 13.8e9      # + the reference's 0.73 GB: under 15.5
 
 
+def _gigachat_cfg(n_layers=5):
+    """gigachat35-432b-a28b-serve-1chip's widths from its own file: the
+    dense delta layer and one whole period (three delta layers and the
+    latent one), 16 of 256 experts held, an eighth of the vocabulary."""
+    import json
+    import os
+
+    from benchmark.runners import serve_gigachat
+    from ray_tpu.models.llama import LlamaConfig
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "gigachat35-432b-a28b-serve-1chip.json")) as f:
+        config = json.load(f)
+    assert config["num_hidden_layers"] == n_layers
+    return LlamaConfig.tiny(**serve_gigachat.model_fields(config))
+
+
+_GIGACHAT_SIZES = dict(max_batch=176, pages=9216, max_seq=19456, ps=64)
+
+
+def test_delta_update_kernel_compiles_at_published_shapes(chip):
+    """ops/delta.py's one-token update at 176 rows of 64 value heads of 128
+    x 128 float32 over a leaf of 4 layers and 177 slots: Mosaic takes a
+    slot's 4 MB block in and out, a head's k and q as ONE lane broadcast
+    over the tile each (no transpose); that the leaf is aliased from the
+    program's argument to its result is held on the whole step programs
+    below, which donate it."""
+    from ray_tpu.ops import delta
+    R, Hv, dk, dv, L, S = 176, 64, 128, 128, 4, 177
+    f32 = jnp.float32
+    compiled = delta._delta_update_pallas.lower(
+        _sds(chip, (L, S, Hv, dk, dv), f32), _sds(chip, (R, Hv, dk), f32),
+        _sds(chip, (R, Hv, dk), f32), _sds(chip, (R, Hv, dv), f32),
+        _sds(chip, (R, Hv), f32), _sds(chip, (R, Hv), f32),
+        _sds(chip, (R,), jnp.int32), _sds(chip, (R,), jnp.bool_),
+        _sds(chip, (1,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "_delta_update_pallas" in text
+
+
+def test_gigachat_mixed_step_compiles_at_benchmark_shapes(chip):
+    """gigachat35-432b-a28b-serve-1chip's mixed step (the program that holds
+    every kernel and both forms of the recurrence; the decode loop's
+    numbers are in the configuration file, compiled the same way) at its
+    published widths and its whole cut (5 layers): the delta update kernel
+    over a float32 state leaf of 177 slots, the latent kernels at 64 query
+    heads on the one row a token (640 lanes) over a 304-page table, the
+    expert kernel over the 16 HELD experts at d = 7168, f = 2048 with the
+    clamp, and the chunk form's solve as matmuls; ONE pool whose latent
+    leaf and both state leaves are aliased from argument to result, and the
+    program's peak (arguments + temporaries; the configuration file keeps
+    the numbers) fits the chip beside the reference's scoring. 176 decode
+    rows, 2 chunks of 512, 9216 pages of 64."""
+    compiled, kv, rows = _compile_step_program(
+        chip, _gigachat_cfg(), "mixed", **_GIGACHAT_SIZES)
+    assert {k: (a.shape, a.dtype.name) for k, a in kv.items()} == {
+        "k": ((1, 9216, 1, 64, 640), "bfloat16"),
+        "delta": ((4, 177, 64, 128, 128), "float32"),
+        "delta_conv": ((4, 177, 3, 16384), "bfloat16")}
+    text = compiled.as_text()
+    assert "_delta_update_pallas" in text and "_moe_experts_pallas" in text
+    # 4 updates, the latent write and its tiles, 4 expert layers
+    assert text.count("tpu_custom_call") == 11
+    # the routing's three counters and the absent pairs behind the tokens
+    assert jax.tree.leaves(compiled.out_info)[0].shape == (rows + 4,)
+    mem = compiled.memory_analysis()
+    held = sum(a.size * a.dtype.itemsize for a in kv.values())
+    assert mem.alias_size_in_bytes >= held
+    peak = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    print(f"gigachat mixed: arguments "
+          f"{mem.argument_size_in_bytes / 1e9:.3f} GB, temporaries "
+          f"{mem.temp_size_in_bytes / 1e9:.3f} GB")
+    assert peak < 13.9e9      # + the reference's 1.58 GB: under 15.5
+
+
 #: configuration -> (its widths, the sizes of its full mixed-step shape):
 #: benchmark/configs/*.json's engine settings
 _MIXED = {
