@@ -9,12 +9,18 @@ SURVEY.md §2.5 Ray LLM row):
   * layers are STACKED on axis 0 and applied with `lax.scan` + remat: one
     compiled layer body regardless of depth (XLA-friendly, constant compile
     time), and the stack shards over `pp` for pipeline parallelism. The
-    remat boundary keeps a layer's input and, where the attention is the
-    flash kernel, the kernel's output and log-sum-exp (the tags
+    remat boundary ("full") keeps a layer's input and, where the attention
+    is the flash kernel, the kernel's output and log-sum-exp (the tags
     ops.flash_attention.FLASH_SAVE_NAMES, read by remat_policy_fn): the
-    one thing in a layer dearer to recompute than to hold. Each layer's
-    weights are cast to the compute dtype in that layer's turn of the loop
-    (_in_its_turn): one copy a turn, none of the whole stack
+    one thing in a layer dearer to recompute than to hold. Beside them it
+    keeps ONE MLP projection's output (FULL_KEEPS_PROJECTION) where the
+    shapes say that what it then holds across the scan is no more than
+    the compute-dtype copy of the whole stack of weights, which it held
+    through both loops until _in_its_turn (full_remat_keeps: the budget is
+    read off the stacked leaves, the cost off the body's own trace; the
+    same decision on the CPU, for a described chip and on the chip). Each
+    layer's weights are cast to the compute dtype in that layer's turn of
+    the loop (_in_its_turn): one copy a turn, none of the whole stack
   * attention: "full" (GSPMD auto-sharded), "ring" (manual `sp` ring over
     ICI — ray_tpu.parallel.ring_attention), or "ulysses" (all-to-all)
   * bf16 activations/compute, fp32 params & softmax/logit accumulators
@@ -22,6 +28,7 @@ SURVEY.md §2.5 Ray LLM row):
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import itertools
@@ -41,6 +48,7 @@ from ray_tpu.parallel.pipeline import pipeline_apply
 from ray_tpu.parallel.ring_attention import (ring_attention,
                                              ring_attention_sharded)
 from ray_tpu.parallel.ulysses import ulysses_attention_sharded
+from ray_tpu.util import compile_tracker
 
 Params = Dict[str, Any]
 
@@ -915,6 +923,14 @@ SELECTIVE_SAVE_NAMES = ("attn_q", "attn_k", "attn_v", "attn_o",
                         "moe_out")  # mixtral's combined expert output
 
 
+#: the ONE projection output remat_policy="full" keeps beside the kernel's
+#: two, where full_remat_keeps finds the room. mlp_gate costs the same
+#: bytes and saves the same product, and a third of the time: XLA then
+#: reads the stack in five fusions of the backward turn, not three, and
+#: two of its products slow down (PERF.md, PR 56: both on the chip)
+FULL_KEEPS_PROJECTION = "mlp_up"
+
+
 def remat_policy_fn(name: str):
     """Config string → jax.checkpoint policy (shared with mixtral).
 
@@ -925,7 +941,11 @@ def remat_policy_fn(name: str):
                    With any other attention (attention="full", the
                    blockwise fallback off the TPU, ulysses) no value
                    carries the names and nothing is kept: the rule follows
-                   what the program contains, not a knob
+                   what the program contains, not a knob. That is the
+                   name alone. The boundary of a layer scan
+                   (remat_scan_body, which sees the shapes) keeps ONE
+                   named projection beside them, FULL_KEEPS_PROJECTION,
+                   where full_remat_keeps says the shapes pay for it
       "selective"  those, and the 7 named projection outputs per layer
                    (all [B, L, ·]), recomputing norms/rope/attention — the
                    TorchTitan-style middle ground
@@ -952,10 +972,71 @@ def remat_policy_fn(name: str):
     raise ValueError(f"unknown remat_policy {name!r}")
 
 
-def remat_scan_body(body, cfg):
+def _named_bytes(jaxpr, names) -> collections.Counter:
+    """Bytes of the checkpoint_name-tagged values of a jaxpr by tag,
+    sub-jaxprs included (as ops.flash_attention.kernel_calls walks them),
+    for the tags in ``names``."""
+    found: collections.Counter = collections.Counter()
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "name" and eqn.params["name"] in names:
+            aval = eqn.outvars[0].aval
+            found[eqn.params["name"]] += aval.size * aval.dtype.itemsize
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _named_bytes(sub, names)
+    return found
+
+
+def full_remat_keeps(body, args, stacked, cd) -> Dict[str, Any]:
+    """What a remat_policy="full" boundary around ``body`` keeps a layer,
+    decided from shapes and dtypes alone (nothing here asks a device, a
+    flag or a model's name, so the CPU, a described chip and the chip
+    build the same program). ``args``: what the body is handed in one turn
+    (arrays, tracers or ShapeDtypeStructs); ``stacked``: the layer leaves
+    as the scan holds them, [n_layers, ...] each; ``cd``: the compute
+    dtype.
+
+    The kernel's two outputs always (FLASH_SAVE_NAMES). Beside them
+    FULL_KEEPS_PROJECTION, [B, L, ffn_dim], where the body has a value of
+    that name and
+
+        n_layers x (its bytes + the kernel's kept bytes, as the body's
+        own trace gives them) <= the stacked leaves' bytes in ``cd``
+
+    The right side is what "full" held across both scans anyway until
+    _in_its_turn (PR 51): XLA's compute-dtype copy of the whole stack. So
+    "full", the floor a user at the HBM limit has nothing below, never
+    holds more beyond the carried x than it did then, and where the
+    activations outgrow the weights (more rows, longer rows) it is what it
+    was. Both sides are read as the scan sees them, global shapes under
+    GSPMD and a shard's under shard_map: they shrink together under fsdp /
+    tp. At mistral7b-train-1chip, 6 x (235 + 75.5 MB) = 1.86 GB against
+    2.62 GB: kept, one MLP product a layer-turn less; at 3 rows of 4096
+    (2.79 GB) or 2 of 8192 (3.72 GB) not.
+
+    Returns {"names": what the policy saves, "bytes": n_layers x their
+    bytes, "budget_bytes": the right side}."""
+    n = jax.tree.leaves(stacked)[0].shape[0]
+    budget = sum(w.size for w in jax.tree.leaves(stacked)) \
+        * jnp.dtype(cd).itemsize
+    names = (*FLASH_SAVE_NAMES, FULL_KEEPS_PROJECTION)
+    # under vjp: the kernel's tags are placed by its forward RULE
+    sizes = _named_bytes(
+        jax.make_jaxpr(lambda *a: jax.vjp(body, *a)[0])(*args).jaxpr, names)
+    if n * sum(sizes.values()) > budget:
+        del sizes[FULL_KEEPS_PROJECTION]
+    return {"names": tuple(t for t in names if sizes[t]),
+            "bytes": n * sum(sizes.values()), "budget_bytes": budget}
+
+
+def remat_scan_body(body, cfg, stacked=None):
     """body, the function a lax.scan runs once a layer, behind cfg's remat
     boundary (shared with mixtral): the ONE place a layer's boundary is
-    built. prevent_cse=False, always, because the body is a scan's.
+    built. With ``stacked`` (the layer leaves the scan runs over) a "full"
+    boundary keeps what full_remat_keeps allows at the shapes the body is
+    handed in its first turn, and says so to the compile tracker
+    (`remat_kept` on the record of the compile in flight: `python -m
+    ray_tpu compiles`); without, the names alone decide, as before.
+    prevent_cse=False, always, because the body is a scan's.
     jax.checkpoint's default puts an optimization_barrier on everything
     the rematted computation is handed, so that XLA cannot merge the
     recomputation with the first computation; in a scan the two are in two
@@ -971,8 +1052,19 @@ def remat_scan_body(body, cfg):
     here and keeps jax's default."""
     if not cfg.remat:
         return body
-    return jax.checkpoint(body, policy=remat_policy_fn(cfg.remat_policy),
-                          prevent_cse=False)
+    if cfg.remat_policy != "full" or stacked is None:
+        return jax.checkpoint(body, policy=remat_policy_fn(cfg.remat_policy),
+                              prevent_cse=False)
+
+    def bounded(*args):
+        kept = full_remat_keeps(body, args, stacked, cfg.dtype)
+        compile_tracker.note_traced(remat_kept=kept)
+        return jax.checkpoint(
+            body, prevent_cse=False,
+            policy=jax.checkpoint_policies.save_only_these_names(
+                *kept["names"]))(*args)
+
+    return bounded
 
 
 def _layer(lp: Params, x, cfg: LlamaConfig, positions, attn_fn):
@@ -1055,7 +1147,7 @@ def _scan_layers(layers: Params, x, cfg: LlamaConfig, positions, attn_fn):
     def body(lp_turn, x):
         return layer(_in_its_turn(*lp_turn, cfg.dtype), x)
 
-    body = remat_scan_body(body, cfg)
+    body = remat_scan_body(body, cfg, layers)
 
     def step(x, lp_turn):
         return body(lp_turn, x), None
@@ -1243,7 +1335,7 @@ def _loss_overlap(params: Params, tokens: jax.Array, cfg: LlamaConfig,
         x = embed.astype(cd)[tokens]
         body = remat_scan_body(
             functools.partial(_layer, cfg=cfg, positions=positions,
-                              attn_fn=attn_fn), cfg)
+                              attn_fn=attn_fn), cfg, params["layers"])
         x = overlap_scan(params["layers"], lspecs, x, body, cfg.n_layers,
                          axis_name="fsdp")
         x = _rmsnorm(x, params["final_norm"], cfg.norm_eps)

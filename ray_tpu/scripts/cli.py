@@ -660,6 +660,10 @@ def _fmt_compile_record(rec: dict) -> str:
             f"{' =' + split if split else ''}  ({sig_s})")
     for d in rec.get("diff") or []:
         line += f"\n           diff {d}"
+    # what the program said of itself while it was traced (remat_kept: the
+    # names a "full" remat boundary keeps, their bytes, the budget)
+    for key, fact in (rec.get("traced") or {}).items():
+        line += f"\n           {key} {json.dumps(fact)}"
     return line
 
 

@@ -57,6 +57,9 @@ _HITS_KEY = "_persistent_cache_hits"
 #: in-flight accumulator key: [(start, seconds)] of the phase events seen so
 #: far and held by none yet, for _own_seconds (dropped before the record)
 _SPANS_KEY = "_phase_spans"
+#: in-flight accumulator key: what the program being traced said of itself
+#: (note_traced), carried onto the compile's record as "traced"
+_TRACED_KEY = "_traced"
 #: a compile's seconds by pipeline phase, as a callable's stats and a ring
 #: record name them: (their key, the /jax/core/compile/ phase it sums);
 #: with a persistent-cache hit backend_s is the cache's retrieval
@@ -293,6 +296,7 @@ class CompileTracker:
         # tells a cold compile from a warm load, whose "backend_compile"
         # seconds are the cache retrieval
         cache_hit = bool(phases.pop(_HITS_KEY, 0))
+        traced = phases.pop(_TRACED_KEY, None)
         measured = round(sum(phases.values()), 6)
         split = {key: phases.get(kind, 0.0) for key, kind in _SPLIT}
         if not backend:
@@ -335,6 +339,8 @@ class CompileTracker:
                    "trace_id": ctx[0] if ctx else "",
                    "recompile": recompile, "diff": diff,
                    "nth": st["compiles"], "cache_hit": cache_hit}
+            if traced:
+                rec["traced"] = traced
             self._append_locked(rec)
             self._counts[kind] = self._counts.get(kind, 0) + 1
             if recompile:
@@ -566,6 +572,17 @@ def _on_jax_event(event: str, **_kw) -> None:
     tracker = get_global()
     if tracker is not None:
         tracker.note_cache_hit() if hit else tracker.note_cache_miss()
+
+
+def note_traced(**facts: Any) -> None:
+    """What a program decided while it was traced (models/llama.py: the
+    names its remat boundary keeps, their bytes and the budget they were
+    held to), from the code being traced: it rides on the record of the
+    wrapped call in flight on this thread, as ``traced``. Outside a
+    wrapped call nothing is kept."""
+    stack = getattr(_tls, "inflight", None)
+    if stack:
+        stack[-1].setdefault(_TRACED_KEY, {}).update(facts)
 
 
 def _maybe_hook_jax() -> bool:

@@ -354,6 +354,45 @@ def test_train_step_builders_go_through_the_seam():
         ct.stop_global()
 
 
+def test_train_step_record_says_what_the_remat_boundary_keeps():
+    """The names a "full" remat boundary keeps, their bytes across the
+    scan and the budget they were held to (models/llama.py:
+    full_remat_keeps) ride on the record of the train.step compile that
+    traced them, and `ray_tpu compiles` prints them; a compile that traced
+    no such boundary carries nothing."""
+    import functools
+    import jax
+    import jax.numpy as jnp
+    import optax
+    from ray_tpu.models import llama
+    from ray_tpu.scripts.cli import _fmt_compile_record
+    from ray_tpu.train.train_step import make_train_step
+    cfg = llama.LlamaConfig.tiny(n_layers=2)
+    B, L, item = 2, 16, jnp.dtype(cfg.dtype).itemsize
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    tokens = jnp.zeros((B, L), jnp.int32)
+    ct.stop_global()
+    try:
+        tr = ct.ensure_started(role="t")
+        init_fn, step_fn = make_train_step(
+            functools.partial(llama.loss_fn, cfg=cfg), optax.sgd(0.1),
+            donate=False)
+        step_fn(params, init_fn(params), tokens)
+        init, step = [r for r in tr.export()["records"] if r["name"]]
+        assert (init["name"], step["name"]) == ("train.init", "train.step")
+        assert "traced" not in init
+        assert step["traced"] == {"remat_kept": {
+            "names": ("mlp_up",),
+            "bytes": cfg.n_layers * B * L * cfg.ffn_dim * item,
+            "budget_bytes": sum(w.size for w in jax.tree.leaves(
+                params["layers"])) * item}}
+        assert '\n           remat_kept {"names": ["mlp_up"], "bytes": ' \
+            in _fmt_compile_record(step)
+        assert "remat_kept" not in _fmt_compile_record(init)
+    finally:
+        ct.stop_global()
+
+
 def test_program_loaded_from_persistent_cache_stays_truthful(tmp_path,
                                                              monkeypatch):
     """A program LOADED from jax's persistent compilation cache instead

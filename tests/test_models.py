@@ -264,14 +264,21 @@ class TestRematPolicies:
             dataclasses.replace(cfg, attention="full"), 64) == 0
 
     def _saved(self, capsys, cfg, L, policy):
-        """Lines of print_saved_residuals for one checkpointed layer."""
+        """Lines of print_saved_residuals for one checkpointed layer:
+        behind jax.checkpoint with the policy of that name alone, or
+        (policy "scan") behind the boundary _scan_layers builds, which
+        is handed the stacked layers and reads the shapes."""
         params = llama.init_params(cfg, jax.random.PRNGKey(0))
         lp = jax.tree.map(lambda a: a[0], params["layers"])
-        body = jax.checkpoint(
-            functools.partial(llama._layer, cfg=cfg,
-                              positions=jnp.arange(L),
-                              attn_fn=llama._make_attn_fn(cfg, None)),
-            policy=llama.remat_policy_fn(policy))
+        layer = functools.partial(llama._layer, cfg=cfg,
+                                  positions=jnp.arange(L),
+                                  attn_fn=llama._make_attn_fn(cfg, None))
+        if policy == "scan":
+            assert cfg.remat and cfg.remat_policy == "full"
+            body = llama.remat_scan_body(layer, cfg, params["layers"])
+        else:
+            body = jax.checkpoint(layer,
+                                  policy=llama.remat_policy_fn(policy))
         capsys.readouterr()
         jax.ad_checkpoint.print_saved_residuals(
             body, lp, jnp.zeros((2, L, cfg.dim), cfg.dtype))
@@ -280,13 +287,20 @@ class TestRematPolicies:
                 and not ln.endswith("from a constant")]
 
     # "selective": six projections beside them (nothing in the backward
-    # reads mlp_down's output, so the seventh name is never a residual)
-    @pytest.mark.parametrize("policy,others", [("full", 0), ("selective", 6)])
+    # reads mlp_down's output, so the seventh name is never a residual).
+    # "scan": the boundary _scan_layers builds under "full", at a shape
+    # full_remat_keeps lets keep the one projection (2 x 128 tokens: 262
+    # kB a layer with the kernel's two against 591 kB of weights at dim
+    # 256) and at one it does not (2 x 1024)
+    @pytest.mark.parametrize("policy,dim,L,others", [
+        ("full", 64, 128, 0), ("selective", 64, 128, 6),
+        ("scan", 256, 128, 1), ("scan", 256, 1024, 0)],
+        ids=["full", "selective", "scan_keeps", "scan_over_budget"])
     def test_kernel_outputs_are_saved_by_name(self, monkeypatch, capsys,
-                                              policy, others):
+                                              policy, dim, L, others):
         _use_interpreted_flash_kernel(monkeypatch)
-        cfg = llama.LlamaConfig.tiny(n_layers=2, attention="flash")
-        B, L, BH = 2, 128, 2 * cfg.n_heads
+        cfg = llama.LlamaConfig.tiny(n_layers=2, attention="flash", dim=dim)
+        B, BH = 2, 2 * cfg.n_heads
         kept = self._saved(capsys, cfg, L, policy)
         assert len(kept) == 2 + others, kept
         # lse by its name; o by its shape (jax puts a reduce_precision on
@@ -295,14 +309,70 @@ class TestRematPolicies:
                    for ln in kept), kept
         assert any(ln.startswith(f"bf16[{BH},{L},{cfg.head_dim}] ")
                    for ln in kept), kept
+        if policy == "scan":
+            assert sum(ln.startswith(f"bf16[{B},{L},{cfg.ffn_dim}] ")
+                       for ln in kept) == others, kept
 
+    @pytest.mark.parametrize("policy,L,kept", [
+        ("full", 32, 0), ("scan", 32, 1), ("scan", 1024, 0)],
+        ids=["by_name", "scan_keeps", "scan_over_budget"])
     @pytest.mark.parametrize("attention", ["full", "flash"])
     def test_full_keeps_only_the_layer_input_without_the_kernel(
-            self, capsys, attention):
+            self, capsys, attention, policy, L, kept):
         # attention="flash" off the TPU is the blockwise scan: no value
-        # carries the names, and "full" keeps what it always kept
+        # carries the kernel's names, and "full" by its name alone keeps
+        # what it always kept; the scan's boundary keeps the ONE named
+        # projection where the shapes pay for it, and nothing where not
         cfg = llama.LlamaConfig.tiny(n_layers=2, attention=attention)
-        assert self._saved(capsys, cfg, 32, "full") == []
+        saved = self._saved(capsys, cfg, L, policy)
+        assert len(saved) == kept, saved
+        assert all(ln.startswith(f"bf16[2,{L},{cfg.ffn_dim}] ")
+                   for ln in saved), saved
+
+    #: 6 layers of mistral7b-train-1chip's widths in bf16: the budget
+    CELL_BUDGET = 6 * 218_112_000 * 2
+    UP, O_LSE = 4096 * 14336 * 2, 32 * 4096 * (128 * 2 + 8 * 4)  # a row
+
+    @pytest.mark.parametrize("kernel,B,L,names,held", [
+        (True, 2, 4096, ("flash_o", "flash_lse", "mlp_up"),
+         6 * 2 * (UP + O_LSE)),                       # 1.86 GB of 2.62
+        (True, 3, 4096, ("flash_o", "flash_lse"), 6 * 3 * O_LSE),
+        (True, 2, 8192, ("flash_o", "flash_lse"), 6 * 2 * 2 * O_LSE),
+        (False, 2, 4096, ("mlp_up",), 6 * 2 * UP),
+        (False, 4, 4096, (), 0)],
+        ids=["cell", "3x4096", "2x8192", "cell_no_kernel", "4x4096_none"])
+    def test_full_remat_keeps_follows_the_shapes(self, monkeypatch, kernel,
+                                                 B, L, names, held):
+        """The rule alone, on shapes (nothing is computed): at the train
+        cell's shapes the ONE projection is kept beside the kernel's two,
+        with a row more or rows twice as long it is not, and "full" is
+        what it was; the kernel's outputs count where the body has them."""
+        if kernel:
+            _use_interpreted_flash_kernel(monkeypatch)
+        cfg = llama.LlamaConfig(
+            vocab_size=32768, dim=4096, n_layers=6, n_heads=32,
+            n_kv_heads=8, ffn_dim=14336, rope_theta=1e6, attention="flash")
+        stacked = jax.eval_shape(functools.partial(llama.init_params, cfg),
+                                 jax.random.PRNGKey(0))["layers"]
+        lp = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype), stacked)
+        body = functools.partial(llama._layer, cfg=cfg,
+                                 positions=jnp.arange(L),
+                                 attn_fn=llama._make_attn_fn(cfg, None))
+        x = jax.ShapeDtypeStruct((B, L, cfg.dim), cfg.dtype)
+        assert llama.full_remat_keeps(body, (lp, x), stacked, cfg.dtype) \
+            == {"names": names, "bytes": held,
+                "budget_bytes": self.CELL_BUDGET}
+
+    def test_full_remat_keeps_nothing_a_body_does_not_name(self):
+        # models/mixtral.py's layer has no mlp_up: whatever the shapes
+        stacked = {"w": jax.ShapeDtypeStruct((4, 64, 64), jnp.float32)}
+        lp = {"w": jax.ShapeDtypeStruct((64, 64), jnp.float32)}
+        x = jax.ShapeDtypeStruct((2, 8, 64), jnp.bfloat16)
+        assert llama.full_remat_keeps(
+            lambda lp, x: x @ lp["w"].astype(x.dtype), (lp, x), stacked,
+            jnp.bfloat16) == {"names": (), "bytes": 0,
+                              "budget_bytes": 4 * 64 * 64 * 2}
 
     def test_cast_in_its_turn_changes_no_value(self):
         """_scan_layers casts each layer's weights in that layer's turn,
@@ -358,7 +428,9 @@ class TestRematPolicies:
         np.testing.assert_array_equal(np.asarray(g), np.ones(4, np.float32))
 
 
-def _llama_scan_case():
+def _llama_scan_case(L=16):
+    """L 16: "full" keeps mlp_up (full_remat_keeps: 3 x 16 kB of it
+    against 3 x 148 kB of float32 weights); L 256: over, nothing kept."""
     cfg = llama.LlamaConfig.tiny(n_layers=3, dtype=jnp.float32)
     assert cfg.remat and cfg.remat_policy == "full"
     assert cfg.n_kv_heads < cfg.n_heads               # GQA
@@ -378,7 +450,7 @@ def _llama_scan_case():
     # _held's barrier, once in the forward turn and once in the recomputed
     return (functools.partial(llama.loss_fn, cfg=cfg), plain,
             llama.init_params(cfg, jax.random.PRNGKey(0)),
-            make_inputs(cfg, B=2, L=16), 2)
+            make_inputs(cfg, B=2, L=L), 2)
 
 
 def _mixtral_scan_case():
@@ -415,6 +487,7 @@ def _overlap_case(mod, config):
 #: tokens, optimization_barriers the program itself places)
 _BOUNDARY_CASES = {
     "llama_scan": _llama_scan_case,
+    "llama_scan_over_budget": functools.partial(_llama_scan_case, 256),
     "llama_overlap": functools.partial(_overlap_case, llama,
                                        llama.LlamaConfig),
     "mixtral_scan": _mixtral_scan_case,
@@ -454,6 +527,32 @@ def test_a_scan_bodys_remat_boundary_fences_nothing(case):
         assert got.dtype == jnp.float32
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("remat,L,same", [
+    ({}, 16, False), ({}, 256, True),
+    ({"remat_policy": "selective"}, 16, True),
+    ({"remat_policy": "dots"}, 16, True), ({"remat": False}, 16, True)],
+    ids=["full_keeps", "full_over_budget", "selective", "dots", "no_remat"])
+def test_only_a_full_boundary_with_room_is_another_program(monkeypatch,
+                                                           remat, L, same):
+    """What the shapes decide is the whole of the change: "full" over its
+    budget, and every other policy, lower to the text of a boundary built
+    from the policy's name alone (remat_scan_body without the stacked
+    layers: what every caller got before full_remat_keeps)."""
+    cfg = llama.LlamaConfig.tiny(n_layers=3, **remat)
+    params = jax.eval_shape(functools.partial(llama.init_params, cfg),
+                            jax.random.PRNGKey(0))
+    tokens = jax.ShapeDtypeStruct((2, L), jnp.int32)
+
+    def text():
+        return jax.jit(jax.grad(functools.partial(
+            llama.loss_fn, cfg=cfg))).lower(params, tokens).as_text()
+
+    shaped, by_shape = text(), llama.remat_scan_body
+    monkeypatch.setattr(llama, "remat_scan_body",
+                        lambda body, cfg, stacked=None: by_shape(body, cfg))
+    assert (text() == shaped) == same
 
 
 class TestFsdpOverlap:

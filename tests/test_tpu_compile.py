@@ -233,8 +233,9 @@ def test_train_layers_backward_reads_the_stacks_in_place(train_layers):
     types, and two more for the kept x and the kept o (PERF.md, PR 53; the
     kept o is still sliced out, under another name: a kernel takes whole
     buffers). The products, the kernels and what is recomputed stay what
-    they were, and less is held. Fails the day jax's default, or XLA's fusion of a slice
-    into the cast that reads it, changes."""
+    they were but for the ONE MLP product "full" keeps where the shapes
+    pay for it (PR 56). Fails the day jax's default, or XLA's fusion of a
+    slice into the cast that reads it, changes."""
     text, memory = train_layers
     inst = _instructions(text)
     masters = ("f32[4096,14336]", "f32[14336,4096]", "f32[4096,4096]",
@@ -244,10 +245,25 @@ def test_train_layers_backward_reads_the_stacks_in_place(train_layers):
     assert [r.split("{")[0] for n, r, _ in inst
             if n.startswith("dynamic-slice_bitcast_fusion")] \
         == ["f32[64,8,4096]"]
-    # 9 forward + 9 recomputed products, 9 gradients; the parent's 2.536 GB
-    assert text.count(" convolution(") == 27
+    # 9 forward + 8 recomputed products, 9 gradients: "full" keeps mlp_up
+    # at these shapes (llama.full_remat_keeps: 3 x 310 MB against 1.31 GB
+    # of bf16 weights) and the backward turn does not run its product
+    # again. ONE stack holds it, written by the product's own fusion and
+    # read where it lies by the backward's fusions: no instruction of its
+    # own slices a layer's [2, 4096, 14336] out of it first
+    assert text.count(" convolution(") == 26
+    stack = "bf16[3,2,4096,14336]"
+    made = [ln for _, r, ln in inst if stack in r and not re.search(
+        r" (parameter|tuple|get-tuple-element|while)\(", ln)]
+    assert sorted(("dynamic-update-slice" in ln and " fusion(" in ln,
+                   'custom_call_target="AllocateBuffer"' in ln)
+                  for ln in made) == [(False, True), (True, False)], made
+    assert not [n for n, r, ln in inst if "dynamic-slice" in n
+                and (stack in ln or r.startswith(("bf16[2,4096,14336]",
+                                                  "bf16[1,2,4096,14336]")))]
     assert not any(".remat" in n for n, _, _ in inst)
-    assert memory.temp_size_in_bytes < 2.45e9, memory.temp_size_in_bytes
+    # 2.366 GB without the kept stack (0.70 GB): this compile reads 3.477
+    assert memory.temp_size_in_bytes < 3.55e9, memory.temp_size_in_bytes
 
 
 @pytest.mark.parametrize("Lq,Lk,causal", [
