@@ -578,10 +578,31 @@ class SequenceState:
         self.preempt_count = 0
         self.restore_generated: List[int] = []
         self.record = None                  # flight-recorder RequestRecord
+        # what launched programs the engine has not booked yet hold of it
+        # (llm/engine.py runs one program ahead): how many of them it is a
+        # row of, the tokens they give it unless one of them is EOS, and
+        # whether the last token max_new_tokens allows is among those (it
+        # is then a row of no later program)
+        self.flights = 0
+        self.unbooked = 0
+        self.ended = False
 
     @property
     def num_tokens(self) -> int:
         return len(self.prompt) + len(self.generated)
 
+    @property
+    def num_launched(self) -> int:
+        """num_tokens once every launched program is booked."""
+        return self.num_tokens + self.unbooked
+
+    @property
+    def tokens_left(self) -> int:
+        """Tokens max_new_tokens still allows past the launched ones (a
+        preempted sequence's generated tokens lie folded in its prompt
+        until its re-prefill is booked: restore_generated)."""
+        return self.max_new_tokens - len(self.generated) \
+            - len(self.restore_generated) - self.unbooked
+
     def pages_needed(self, page_size: int, headroom: int = 0) -> int:
-        return -(-(self.num_tokens + headroom) // page_size)
+        return -(-(self.num_launched + headroom) // page_size)

@@ -69,22 +69,55 @@ admission, scheduling), rebuilt TPU-first around ONE ragged step:
     the seam's layout: model.step_layout / decode_layout), filled in
     place by array operations over all decode rows at once, and sent in
     ONE host-to-device transfer (stats h2d_arrays: 1 a dispatch); the
-    program cuts it at static offsets. The stretch between booking one
-    program and launching the next is the one the chip waits for.
+    program cuts it at static offsets;
+  - ONE PROGRAM AHEAD: the engine keeps one program queued behind the one
+    that is running. What a dispatch does to the engine's STRUCTURES is
+    known when it is packed (every decode row advances by 1 or
+    decode_chunk positions, a chunk row by its chunk, a row whose
+    max_new_tokens runs out ends there, a prompt whose last chunk is in
+    it becomes a decode row: _take_off sets all that at the launch); only
+    the tokens' VALUES, and whether one is EOS, wait for the readback
+    (_book). So a step packs, sends and launches program N+1 from the
+    structures as N will leave them WHILE N runs, then reads N back and
+    books it; each decode row's newest token reaches N+1 on the device
+    (the step programs hand every slot's newest token from one to the
+    next: model._newest_from; the descriptor says -1 for a token the
+    host has not read). What was predicted and turns out otherwise is
+    retired late, never computed wrong: a row found to have stopped on
+    EOS when N is booked is delivered then, its tokens from N+1 are
+    dropped and its slot and pages released when N+1 is booked (stats
+    late_retired_rows); what the engine cannot do with a program in
+    flight (preempt: it folds tokens it does not have yet; a window
+    group without a spare page for the look-ahead) waits until that
+    program is booked (stats ahead_drains), and a decode loop is queued
+    only where no arrival could have been admitted before a row ends
+    anyway (_launch). stats ahead_dispatches counts the dispatches
+    launched with a program unbooked. The engine decides all of it from
+    its own state: no setting selects it.
 
-The engine is synchronous (dispatch -> readback -> book -> next step), so
-what the host does between two dispatches is device idle. Each phase of a
-step goes through ONE helper (PhaseClocks.phase), which does two things:
+A step is admit -> pack -> h2d -> dispatch (of the NEXT program) ->
+after_dispatch -> readback -> book (of the program that was in flight; of
+the one just launched where nothing may be queued behind it, which is the
+old synchronous order: dispatch -> readback -> book -> next step). With a
+program queued the host's stretch between two dispatches runs under the
+device; where the order is synchronous it is device idle, as it was.
+Each phase of a step goes through ONE helper (PhaseClocks.phase), which
+does two things:
 
   - it opens a jax.profiler.TraceAnnotation, written into the profiler's
     own trace beside the device's events (a flag test when no trace runs):
     engine.step {kind, dispatch, decode_rows, real_tokens, slot_tokens}
     and, partitioning it, engine.admit {admitted}, engine.pack,
     engine.h2d, engine.dispatch, engine.readback {a sparse model's
-    routing counters}, engine.book, engine.metrics; the serve loop adds
+    routing counters}, engine.book, engine.metrics, each at most once a
+    step and in that order (kind, dispatch and the counts are the program
+    the step BOOKED; `launched` the kind it launched, `ahead` whether the
+    booked one had been launched behind another; pack .. dispatch and
+    readback, book belong to different programs where the engine runs
+    ahead); the serve loop adds
     serve.wait between steps and serve.publish {streams}, which it runs
     from step()'s after_dispatch hook: inside engine.step, between
-    engine.dispatch and engine.readback, with the program on the device
+    engine.dispatch and engine.readback, with a program on the device
     (llm/serve_llm.py). The names are read by
     benchmark/readers/host_gaps.py (PERF.md lists them): renaming one, or
     moving where it opens and closes, changes a metric;
@@ -240,13 +273,38 @@ def _descriptor_turns(layout: M.Layout):
     fields, cut once, a list for the filler's notes on what it left in
     the buffer), TWO of them taking turns for ever. ``device_put`` may
     alias a host buffer instead of copying it (the CPU backend does) or
-    still read it as it returns, so the buffer a program was launched
-    with is left alone while the NEXT one's is filled; by the time its
-    turn comes again that program has been read back. What a buffer holds
-    when its turn comes is its last fill."""
+    still read it as it returns, so a buffer is left alone from its fill
+    until the program launched with it has been read back. Two are as
+    many as that can be at once: the engine fills a buffer with at most
+    ONE program in flight (the one launched before), and books that one
+    before it fills again, so the buffer whose turn comes belongs to a
+    program that has been read back. What a buffer holds when its turn
+    comes is its last fill."""
     size = M.layout_size(layout)
     return itertools.cycle([(buf, M.cut(buf, layout), []) for buf in (
         np.zeros(size, np.int32), np.zeros(size, np.int32))])
+
+
+class _Flight:
+    """A program launched and not booked yet: what its booking needs of
+    the dispatch as it was packed (_take_off), and its tokens, still on
+    the device."""
+
+    __slots__ = ("kind", "rows", "n_rows", "ahead", "active", "out",
+                 "rows_joined", "pages_in_use", "inside_window")
+
+    def __init__(self, kind: str, rows, n_rows: int, ahead: bool):
+        self.kind = kind            # "mixed" | "decode"
+        self.rows = rows            # a mixed step's chunk rows
+        self.n_rows = n_rows        # ... and the shape it ran in
+        #: launched with the program before it still unbooked
+        self.ahead = ahead
+        #: [(slot, seq, tokens of this program it takes)], decode rows
+        self.active: List[Tuple[int, SequenceState, int]] = []
+        self.out = None             # tokens (+ counters): a device array
+        self.rows_joined = 0
+        self.pages_in_use = (0, 0)  # full group, window group
+        self.inside_window = 0
 
 
 class InferenceEngine:
@@ -427,8 +485,18 @@ class InferenceEngine:
         self._step_descs = {n: _descriptor_turns(layout) for n, layout
                             in self._fns.step_layouts.items()}
         self._slot_ids = np.arange(max_batch, dtype=np.int32)
+        # the program launched and not booked, if any (step), and every
+        # slot's newest token as the newest program leaves it: a device
+        # array that goes from one program to the next unread
+        self._flight: Optional[_Flight] = None
+        self._last = self._fns.init_last(max_batch)
+        # launch a program behind the one in flight where _launch's rules
+        # allow. The engine decides from its own state; the tests set
+        # False to hold it to one program at a time. No configuration does
+        self._run_ahead = True
         # a mixed step's padding where it is not 0
         self._padding = {"token_page": SCRATCH_PAGE,
+                         "newest_slot": max_batch,
                          "page_table": SCRATCH_PAGE,
                          "token_page_win": SCRATCH_PAGE,
                          "page_table_win": SCRATCH_PAGE,
@@ -449,7 +517,14 @@ class InferenceEngine:
                       # host arrays sent to the device for dispatches
                       # (one descriptor each), booked WITH the dispatch:
                       # a ratio of the two over any window is exact
-                      "h2d_arrays": 0}
+                      "h2d_arrays": 0,
+                      # dispatches launched with the program before them
+                      # still unbooked (booked with the dispatch too);
+                      # rows found ended (EOS) after their next dispatch
+                      # was packed; times a page group could not serve
+                      # the look-ahead and the pipeline drained (_launch)
+                      "ahead_dispatches": 0, "late_retired_rows": 0,
+                      "ahead_drains": 0}
         # counters the step programs reduce on the device and append to
         # the tokens they return (none for a dense model): one stats key
         # each, and metadata of the dispatch's engine.readback span
@@ -497,7 +572,6 @@ class InferenceEngine:
             self.request_log: Optional[FlightRecorder] = FlightRecorder()
         else:
             self.request_log = None
-        self._finished_at_prefill: Dict[str, List[int]] = {}
         # tokens generated since the last drain_progress() call, per live
         # request — the incremental surface token streaming rides on
         # (reference: vLLM engine step() yielding RequestOutputs per step).
@@ -563,7 +637,8 @@ class InferenceEngine:
 
     def has_work(self) -> bool:
         with self._lock:
-            return bool(self.waiting or self.running or self._chunking)
+            return bool(self.waiting or self.running or self._chunking
+                        or self._flight is not None)
 
     def compiled_step_programs(self) -> int:
         """Compiled step programs resident for this engine's step fns
@@ -595,13 +670,14 @@ class InferenceEngine:
         fns = self._fns
 
         def mixed(n_rows: int) -> None:
-            _, self.kv = fns.ragged_step(
+            _, self.kv, self._last = fns.ragged_step(
                 self.params, jax.device_put(self._pack_mixed([], [], n_rows)),
-                self.kv)
+                self.kv, self._last)
 
         def decode() -> None:
-            _, self.kv, _, _ = fns.decode_loop(
-                self.params, jax.device_put(self._pack_decode([])), self.kv)
+            _, self.kv, _, _, self._last = fns.decode_loop(
+                self.params, jax.device_put(self._pack_decode([])), self.kv,
+                self._last)
 
         def copy() -> None:
             scratch = jnp.int32(SCRATCH_PAGE)
@@ -668,23 +744,33 @@ class InferenceEngine:
 
     def step(self, after_dispatch: Optional[Callable[[], None]] = None,
              ) -> Dict[str, List[int]]:
-        """One scheduler step: admit waiting requests, then EITHER one
-        ragged mixed dispatch (prefill chunks under the token budget +
-        one decode token per running sequence, a single program) when
-        prefill work is pending, OR one multi-step decode-loop dispatch
-        (decode_chunk tokens per running sequence) when not. Returns
-        {request_id: generated} for sequences that FINISHED this step.
+        """One scheduler step: admit waiting requests, LAUNCH at most one
+        program — one ragged mixed dispatch (prefill chunks under the
+        token budget + one decode token per running sequence, a single
+        program) when prefill work is pending, one multi-step decode-loop
+        dispatch (decode_chunk tokens per running sequence) when not —
+        and BOOK at most one: the program that was in flight when the step
+        began (the one just launched is then queued behind it and stays
+        unbooked: the next step's), else the one just launched unless the
+        next may be queued behind it (_next_may_follow). A step that has
+        nothing to launch, or may not launch it behind the program in
+        flight (_launch), books what is in flight: the pipeline is empty
+        again. Returns {request_id: generated} for the sequences that the
+        booking found FINISHED.
 
-        ``after_dispatch`` is called once, with no arguments, right after
-        the engine.dispatch phase of whichever program the step launches
-        and before engine.readback: host work the caller wants done while
+        ``after_dispatch`` is called once, with no arguments, by every
+        step that is about to sleep on a program: right after the
+        engine.dispatch phase of the program the step launches, or, where
+        it launches none and books the one in flight, before that
+        program's engine.readback. Host work the caller wants done while
         the device runs and this thread would only sleep on it. A step
-        that launches nothing never calls it. The serve loop hands the
-        PREVIOUS step's tokens to their waiters there (llm/serve_llm.py),
-        so a served token reaches its waiter one launch after it is booked
-        (at once when the engine runs dry); booking, the request log's
-        timestamps (the booking's, not the delivery's) and every program's
-        inputs are the same with and without it."""
+        that neither launches nor books never calls it. The serve loop
+        hands the PREVIOUS step's tokens to their waiters there
+        (llm/serve_llm.py), so a served token reaches its waiter one
+        launch after it is booked (before the next readback where nothing
+        is launched; at once when the engine runs dry); booking, the
+        request log's timestamps (the booking's, not the delivery's) and
+        every program's inputs are the same with and without it."""
         finished: Dict[str, List[int]] = {}
         with self.phase("engine.step") as span:
             self._step_meta = {"kind": "none"}
@@ -692,11 +778,18 @@ class InferenceEngine:
                 admitted = self._admit()
                 if admit_span.is_enabled():
                     admit_span.set_metadata(admitted=admitted)
-            if not self._ragged_dispatch(finished, after_dispatch):
-                self._decode(finished, after_dispatch)
-            if self._finished_at_prefill:
-                finished.update(self._finished_at_prefill)
-                self._finished_at_prefill = {}
+            flying = self._flight
+            launched = self._launch(finished)
+            if flying is None and launched is not None \
+                    and not self._next_may_follow():
+                flying = launched             # the synchronous order
+            if after_dispatch is not None \
+                    and (launched is not None or flying is not None):
+                after_dispatch()              # the device is running
+            if flying is not None:
+                self._book(flying, finished)
+            self._step_meta["launched"] = \
+                "none" if launched is None else launched.kind
             self.stats["steps"] += 1
             self._update_metrics()
             if span.is_enabled():
@@ -720,27 +813,45 @@ class InferenceEngine:
         if self.prefix is not None:
             self.prefix.note_release(pages)
 
+    def _blank_slot(self, slot: int) -> None:
+        """Leave ``slot``'s tables as a free slot's: every entry the
+        scratch page, the compact table from base 0. A slot that is no
+        decode row still decodes in the loop, at length 1 into the page
+        its tables name, and a window row's kernel walks its table from
+        the base: the tables of a sequence that has ENDED but still
+        holds the slot (its last program is in flight) must not stand
+        under a length that is no longer theirs (a page index out of the
+        table's range halts the chip)."""
+        self._page_table[slot, :] = SCRATCH_PAGE
+        if self._window:
+            self._page_table_win[slot, :] = SCRATCH_PAGE
+            self._page_base_win[slot] = 0
+
     def _release_window(self, slot: int, seq: SequenceState) -> None:
-        """Return every page ``seq`` holds in the window group, and leave
-        its slot's compact table on the scratch page from base 0 (a free
-        slot still decodes, into the page its table names)."""
+        """Return every page ``seq`` holds in the window group (its
+        slot's tables: _blank_slot)."""
         if seq.win_pages:
             self.window_allocator.free(seq.win_pages)
         seq.win_pages, seq.win_base = [], 0
-        self._page_table_win[slot, :] = SCRATCH_PAGE
-        self._page_base_win[slot] = 0
 
-    def _extend_window(self, seq: SequenceState, upto: int) -> int:
+    def _extend_window(self, seq: SequenceState, upto: int,
+                       ahead: bool = False) -> Optional[int]:
         """Window-group pages for positions up to ``upto`` (a position the
         coming dispatch writes); how many were added. The group is sized
         from the engine's geometry so that it cannot run out
-        (window_group_pages): running out is a fault of the accounting, and
-        raised as one."""
+        (window_group_pages) between two programs: running out is a fault
+        of the accounting, and raised as one. Not so ``ahead``, with a
+        program in flight whose rows still hold the pages their booking
+        will give back behind the window (a page a decode row, a chunk's
+        pages a chunk row): None then, and the caller books that program
+        first (_launch)."""
         short = max(0, upto // self.page_size + 1
                     - (seq.win_base + len(seq.win_pages)))
         if short:
             extra = self.window_allocator.alloc(short)
             if extra is None:
+                if ahead:
+                    return None
                 raise RuntimeError(
                     f"the window page group has {short} pages too few: "
                     f"{self.window_allocator.num_free} free of "
@@ -750,8 +861,9 @@ class InferenceEngine:
 
     def _trim_window(self, seq: SequenceState, next_pos: int) -> int:
         """Free the window-group pages that lie wholly behind the window
-        of ``next_pos``, the next position ``seq`` computes (and so of
-        every later one); how many that were."""
+        of ``next_pos``, the next position ``seq`` computes past the
+        program being booked (and so of every later one: a program
+        launched behind that one starts there); how many that were."""
         n = window_first_page(next_pos, self._window, self.page_size) \
             - seq.win_base
         if n <= 0:
@@ -763,24 +875,17 @@ class InferenceEngine:
         return n
 
     def _sync_window(self, slot: int, seq: SequenceState) -> None:
-        """A decode row's compact table and base as ``seq`` holds them."""
+        """A decode row's compact table and base for the next program:
+        from the first page its next position (``_positions``) still sees,
+        which is the first ``seq`` holds once every program launched
+        before is booked, and a page or a block's further on until then."""
         cols = self._page_table_win.shape[1]
-        pages = seq.win_pages[:cols]
+        base = window_first_page(int(self._positions[slot]), self._window,
+                                 self.page_size)
+        pages = seq.win_pages[base - seq.win_base:][:cols]
         self._page_table_win[slot, :len(pages)] = pages
         self._page_table_win[slot, len(pages):] = SCRATCH_PAGE
-        self._page_base_win[slot] = seq.win_base
-
-    def _book_page_steps(self, active, steps: int) -> None:
-        """Add each group's pages in use to its counter (a dispatch), and
-        to rows_inside_window the dispatch's decode rows (``active``, before
-        their tokens are booked) whose sequence, the token the dispatch
-        computed first counted, is no longer than the window, times the
-        ``steps`` each took."""
-        for key, alloc in (("page_steps_full", self.allocator),
-                           ("page_steps_window", self.window_allocator)):
-            self.stats[key] += alloc.total_pages - 1 - alloc.num_free
-        self.stats["rows_inside_window"] += steps * sum(
-            seq.num_tokens <= self._window for _, seq in active)
+        self._page_base_win[slot] = base
 
     def _unmatch(self, matched_pages: List[int]) -> None:
         """Undo a PrefixCache.match whose sequence did not admit."""
@@ -930,6 +1035,15 @@ class InferenceEngine:
         on[[i for i, _ in active]] = True
         return on
 
+    def _unread(self, active: List[Tuple[int, SequenceState]],
+                ) -> np.ndarray:
+        """[max_batch] bool: the decode rows whose newest token no booking
+        has read yet. It is in ``_last``, on the device: the program takes
+        it there (a NEGATIVE token in the descriptor says so)."""
+        unread = np.zeros(self.max_batch, bool)
+        unread[[i for i, seq in active if seq.unbooked]] = True
+        return unread
+
     def _pack_mixed(self, active: List[Tuple[int, SequenceState]],
                     rows: List[Tuple[SequenceState, int, int]],
                     n_rows: int) -> np.ndarray:
@@ -944,7 +1058,8 @@ class InferenceEngine:
         buf, f, filled = next(self._step_descs[n_rows])
         on = self._decode_mask(active)
         pos = np.where(on, self._positions, 0)
-        f["tokens"][:B] = np.where(on, self._tokens, 0)
+        f["tokens"][:B] = np.where(
+            on, np.where(self._unread(active), -1, self._tokens), 0)
         f["token_pos"][:B] = pos
         f["token_page"][:B] = np.where(
             on, self._page_table[self._slot_ids, pos // ps], SCRATCH_PAGE)
@@ -952,6 +1067,9 @@ class InferenceEngine:
         f["q_start"][:B] = self._slot_ids
         f["q_len"][:B] = on
         f["kv_len"][:B] = np.where(on, pos + 1, 0)
+        # a decode row's token is its slot's newest; so is the token of
+        # the chunk row that ends a prompt, below
+        f["newest_slot"][:B] = np.where(on, self._slot_ids, B)
         f["page_table"][:B] = self._page_table
         # each token's state slot: its sequence's batch slot, the scratch
         # slot (max_batch) for padding
@@ -994,6 +1112,8 @@ class InferenceEngine:
             f["q_start"][r] = t0
             f["q_len"][r] = C
             f["kv_len"][r] = start + C
+            f["newest_slot"][r] = seq.slot \
+                if start + C >= len(seq.prompt) else B
             if state is not None:
                 state[t0:t1] = seq.slot
             if win:
@@ -1010,115 +1130,255 @@ class InferenceEngine:
             t0 = t1
         return buf
 
-    def _ragged_dispatch(self, finished: Dict[str, List[int]],
-                         after_dispatch: Optional[Callable[[], None]],
-                         ) -> bool:
-        """Assemble and run ONE ragged mixed step, if prefill work is
-        pending: decode rows first (slot r owns ragged token r), then up
-        to prefill_rows chunk rows packed from token max_batch on, as
-        _deal_chunk_rows deals them (a sequence may hold several, one
-        after another in position). Rows whose chunk finishes its prompt
-        get their first sampled token from the SAME dispatch (fused
-        argmax; the sequence's LAST row's) — no extra program, no extra
-        readback. The step runs in the smallest of the seam's shapes
+    def _launch(self, finished: Dict[str, List[int]],
+                ) -> Optional["_Flight"]:
+        """Pack, send and launch the next program from the structures as
+        the launched ones leave them, or None. ONE ragged mixed step if
+        prefill work is pending: decode rows first (slot r owns ragged
+        token r), then up to prefill_rows chunk rows packed from token
+        max_batch on, as _deal_chunk_rows deals them (a sequence may hold
+        several, one after another in position). Rows whose chunk finishes
+        its prompt get their first sampled token from the SAME dispatch
+        (fused argmax; the sequence's LAST row's) — no extra program, no
+        extra readback. The step runs in the smallest of the seam's shapes
         that holds the rows dealt (StepPrograms.row_shapes), and its
         arrays, its counters and its span follow THAT shape; a step dealt
-        prefill_rows rows is the full shape's. Returns False (no
-        dispatch) when no chunk work exists, sending the step to the
-        pure-decode loop instead."""
+        prefill_rows rows is the full shape's. With no chunk work, the
+        pure-decode loop over the decode rows, if there are any.
+
+        With a program IN FLIGHT (launched and not booked: its tokens are
+        on the device, and each row's newest reaches this program there,
+        model._newest_from) the launch is AHEAD, and two things hold it
+        back; the step then books the program in flight and the next one
+        goes on from an empty pipeline:
+
+          - a decode loop is queued behind a running program only when
+            no batch slot is free: a request that arrives meanwhile could
+            not have been admitted before a row ends anyway. And not
+            while a request WAITS and a row is known to end in the
+            program in flight (by length; or found stopped on EOS a
+            booking ago): that booking frees the slot, and the request
+            would wait a loop longer for it than with one program at a
+            time (_loop_may_follow). A mixed step is queued whenever
+            there is chunk work for it;
+          - what the engine cannot do without the tokens in flight
+            (stats ahead_drains): a page group that cannot serve the
+            look-ahead. _ensure_pages preempts, and preemption folds the
+            generated tokens into the prompt; the window group is sized
+            for one program's rows. Running ahead never preempts, evicts
+            or raises: the synchronous order does, next step, if it still
+            must."""
+        ahead = self._flight is not None
         rows = self._deal_chunk_rows()
-        if not rows:
-            return False
+        if ahead and not rows and not self._loop_may_follow():
+            return None
         for seq, start, C in rows if self._window else ():
-            self._extend_window(seq, start + C - 1)    # the rows' pages
-        # the smallest compiled shape that holds the deal
-        n_rows = next(n for n in self._fns.row_shapes if n >= len(rows))
-        R, Tcap = self._mixed_shape(n_rows)
+            # the rows' pages
+            if self._extend_window(seq, start + C - 1, ahead) is None:
+                self.stats["ahead_drains"] += 1
+                return None
+        flight = None
         with self.phase("engine.pack"):
-            # decode rows advance one token: they need a page for it
-            active = self._decode_rows(1, finished)
-            desc = self._pack_mixed(active, rows, n_rows)
+            # decode rows advance one token, or a block: pages for it
+            active = self._decode_rows(
+                1 if rows else self.decode_chunk, finished, ahead)
+            if active is None:
+                self.stats["ahead_drains"] += 1
+            elif rows:
+                # the smallest compiled shape that holds the deal
+                n_rows = next(n for n in self._fns.row_shapes
+                              if n >= len(rows))
+                desc = self._pack_mixed(active, rows, n_rows)
+                flight = self._take_off(active, rows, n_rows, ahead)
+            elif active:
+                desc = self._pack_decode(active)
+                flight = self._take_off(active, rows, 0, ahead)
+        if flight is None:
+            return None
         with self.phase("engine.h2d"):
             desc = jax.device_put(desc)         # the ONE transfer
         with self.phase("engine.dispatch"):
-            nxt, self.kv = self._fns.ragged_step(self.params, desc, self.kv)
-        if after_dispatch is not None:
-            after_dispatch()                       # the device is running
-        with self.phase("engine.readback") as span:
-            nxt = np.asarray(nxt)                  # [R], ONE readback
-            nxt = self._note_counters(nxt, R, span)
-        with self.phase("engine.book"):
-            now = time.monotonic()
-            chunk_tokens = sum(C for _, _, C in rows)
-            self.stats["ragged_dispatches"] += 1
-            self.stats["h2d_arrays"] += 1           # its descriptor
-            disp_idx = self.stats["ragged_dispatches"]
-            self.stats["ragged_real_tokens"] += len(active) + chunk_tokens
-            self.stats["ragged_slot_tokens"] += Tcap
-            if n_rows < self.prefill_rows:
-                self.stats["ragged_small_dispatches"] += 1
-            if self._window:
-                self._book_page_steps(active, 1)
-            self.stats["prefill_tokens"] += chunk_tokens
-            self.stats["chunk_rows"] += len(rows)
-            # a joined row starts past what its sequence has computed
-            self.stats["chunk_rows_joined"] += sum(
-                start > seq.num_computed for seq, start, _ in rows)
-            if self._has_state:
-                self.stats["state_resets"] += sum(
-                    start == 0 for _, start, _ in rows)
-            if active:
-                self.stats["decode_steps"] += 1
-                self.stats["decode_tokens"] += len(active)
-            self._step_meta = {
-                "kind": "mixed", "dispatch": disp_idx,
-                "decode_rows": len(active),
-                "real_tokens": len(active) + chunk_tokens,
-                "slot_tokens": Tcap}
-            for slot, seq in active:
-                tok = int(nxt[slot])
-                if self._book_tokens(seq, (tok,), now, mixed=True):
-                    self._finish(slot, seq, finished)
-                    continue
-                self._tokens[slot] = tok
-                self._positions[slot] = seq.num_tokens - 1
-                if self._window \
-                        and self._trim_window(seq, seq.num_tokens - 1):
-                    self._sync_window(slot, seq)
-            for j, (seq, _, C) in enumerate(rows):
-                seq.num_computed += C
-                if self._window:
-                    self._trim_window(seq, seq.num_computed)
-                if seq.record is not None:
-                    seq.record.note_chunk(now, C, disp_idx)
-                if seq.num_computed >= len(seq.prompt):
-                    self._chunking.remove(seq)
-                    seq.prefilling = False
-                    self._postfill_book(seq, seq.slot, seq.pages,
-                                        int(nxt[self.max_batch + j]))
-                    if not seq.done:
-                        # entering the decode batch: reserve the decode-
-                        # loop headroom NOW, before next step's admission
-                        # scan can hand these pages to a younger request
-                        self._ensure_pages(seq.slot, seq,
-                                           self.decode_chunk, finished)
-        return True
+            # kv and the newest tokens: the outputs of the program before,
+            # ready or not
+            if rows:
+                flight.out, self.kv, self._last = self._fns.ragged_step(
+                    self.params, desc, self.kv, self._last)
+            else:
+                flight.out, self.kv, _, _, self._last = \
+                    self._fns.decode_loop(self.params, desc, self.kv,
+                                          self._last)
+        self._flight = flight
+        return flight
 
-    def _postfill_book(self, seq: SequenceState, slot: int,
-                       pages: List[int], first_tok: int) -> None:
-        """Post-prefill bookkeeping: publish full prompt pages into the
-        prefix cache, then either finish immediately (EOS / 1-token
-        budget) or join the decode batch with the already-sampled first
-        token."""
-        seq.pages = pages
+    def _take_off(self, active: List[Tuple[int, SequenceState]],
+                  rows: List[Tuple[SequenceState, int, int]], n_rows: int,
+                  ahead: bool) -> "_Flight":
+        """The engine's structures as the program just packed WILL leave
+        them, set now: what a dispatch does to them is known when it is
+        packed. Every decode row advances by the tokens it takes (1 of a
+        mixed step, decode_chunk of a loop, or what max_new_tokens leaves:
+        the row then ENDS in this program and is no row of the next), a
+        chunk row by its chunk, and a prompt whose last chunk this is
+        joins the decode rows (_join). Only the tokens' values, and
+        whether one is EOS, wait for the booking (_book), which takes what
+        it needs of the dispatch from the record returned."""
+        K = 1 if rows else self.decode_chunk
+        flight = _Flight("mixed" if rows else "decode", rows, n_rows, ahead)
+        if self._window:
+            # before the rows advance: as the synchronous booking saw them
+            flight.pages_in_use = tuple(
+                alloc.total_pages - 1 - alloc.num_free
+                for alloc in (self.allocator, self.window_allocator))
+            flight.inside_window = K * sum(
+                seq.num_launched <= self._window for _, seq in active)
+        # a joined row starts past what its sequence has computed
+        flight.rows_joined = sum(
+            start > seq.num_computed for seq, start, _ in rows)
+        for slot, seq in active:
+            take = min(K, seq.tokens_left)
+            seq.unbooked += take
+            seq.flights += 1
+            seq.ended = seq.tokens_left == 0
+            flight.active.append((slot, seq, take))
+        if active:
+            slots = np.fromiter((slot for slot, _ in active), np.int64,
+                                len(active))
+            pos = self._positions[slots] + K
+            self._positions[slots] = pos
+            if self._window:
+                # rows whose window left a page behind: their tables
+                w, ps = self._window - 1, self.page_size
+                moved = np.maximum(pos - w, 0) // ps \
+                    != np.maximum(pos - K - w, 0) // ps
+                for i in np.flatnonzero(moved):
+                    self._sync_window(*active[i])
+            for slot, seq in active:
+                if seq.ended:       # no row of the next program
+                    self._blank_slot(slot)
+        for seq in dict.fromkeys(seq for seq, _, _ in rows):
+            seq.flights += 1
+        for seq, start, C in rows:
+            seq.num_computed = start + C
+            if seq.num_computed >= len(seq.prompt):
+                self._join(seq)
+        return flight
+
+    def _join(self, seq: SequenceState) -> None:
+        """The program just packed computes the last chunk of ``seq``'s
+        prompt and samples its first token (a re-admitted sequence's next
+        one): publish the full prompt pages into the prefix cache, and
+        make the sequence a decode row of the next program, unless that
+        token is the last its max_new_tokens allows."""
+        self._chunking.remove(seq)
+        seq.prefilling = False
         if self.prefix is not None:
             # registering BEFORE a possible immediate finish keeps
             # recently-finished prompts reusable (their pages go
             # evictable-LRU, not back to the free list); for a preempted
             # sequence the prompt is still FOLDED here, so the pages
-            # holding generated-token KV publish too
-            self.prefix.register(seq.prompt, pages)
-        now = time.monotonic()
+            # holding generated-token KV publish too. Whoever matches
+            # them reads them in a LATER program: the device runs this
+            # one first
+            self.prefix.register(seq.prompt, seq.pages)
+        seq.unbooked += 1
+        if seq.tokens_left == 0:
+            seq.ended = True
+            return
+        slot = seq.slot
+        with self._lock:
+            self.running.append(seq)
+        self._page_table[slot, :] = SCRATCH_PAGE
+        self._page_table[slot, :len(seq.pages)] = seq.pages
+        self._positions[slot] = seq.num_launched - 1
+        if self._window:
+            self._sync_window(slot, seq)
+
+    def _book(self, flight: "_Flight",
+              finished: Dict[str, List[int]]) -> None:
+        """Read ``flight``'s tokens back and book them: the values that
+        _take_off left open. A row found to have stopped on EOS is
+        delivered here (_finish); if it is a row of the program launched
+        behind this one too, its tokens there are dropped and its slot and
+        pages released when THAT one is booked."""
+        mixed = flight.kind == "mixed"
+        B, K = self.max_batch, 1 if mixed else self.decode_chunk
+        R, Tcap = self._mixed_shape(flight.n_rows)
+        with self.phase("engine.readback") as span:
+            out = np.asarray(flight.out)           # ONE readback
+            out = self._note_counters(out, R if mixed else K * B, span)
+            if not mixed:
+                out = out.reshape(K, B)
+        with self.phase("engine.book"):
+            if self._flight is flight:
+                self._flight = None
+            now = time.monotonic()
+            stats, active, rows = self.stats, flight.active, flight.rows
+            stats["h2d_arrays"] += 1                # its descriptor
+            stats["ahead_dispatches"] += flight.ahead
+            if mixed:
+                chunk_tokens = sum(C for _, _, C in rows)
+                stats["ragged_dispatches"] += 1
+                disp_idx = stats["ragged_dispatches"]
+                real = len(active) + chunk_tokens
+                stats["ragged_real_tokens"] += real
+                stats["ragged_slot_tokens"] += Tcap
+                if flight.n_rows < self.prefill_rows:
+                    stats["ragged_small_dispatches"] += 1
+                stats["prefill_tokens"] += chunk_tokens
+                stats["chunk_rows"] += len(rows)
+                stats["chunk_rows_joined"] += flight.rows_joined
+                if self._has_state:
+                    stats["state_resets"] += sum(
+                        start == 0 for _, start, _ in rows)
+                if active:
+                    stats["decode_steps"] += 1
+                    stats["decode_tokens"] += len(active)
+            else:
+                stats["decode_steps"] += K
+                stats["decode_tokens"] += K * len(active)
+                stats["decode_dispatches"] += 1
+                disp_idx = stats["decode_dispatches"]
+                real, Tcap = K * len(active), K * B
+            if self._window:
+                for key, n in zip(("page_steps_full", "page_steps_window"),
+                                  flight.pages_in_use):
+                    stats[key] += n
+                stats["rows_inside_window"] += flight.inside_window
+            self._step_meta = {
+                "kind": flight.kind, "dispatch": disp_idx,
+                "decode_rows": len(active), "real_tokens": real,
+                "slot_tokens": Tcap, "ahead": flight.ahead}
+            for slot, seq, take in active:
+                seq.unbooked -= take
+                seq.flights -= 1
+                if seq.done:
+                    # found ended when the program before was booked: its
+                    # tokens here are dropped, its structures free now
+                    if not seq.flights:
+                        self._release(slot, seq)
+                    continue
+                toks = (int(out[slot]),) if mixed \
+                    else out[:take, slot].tolist()
+                self._tokens[slot] = toks[-1]
+                if self._book_tokens(seq, toks, now, mixed=mixed):
+                    self._finish(slot, seq, finished, now)
+                elif self._window:
+                    self._trim_window(seq, seq.num_tokens - 1)
+            for seq in dict.fromkeys(seq for seq, _, _ in rows):
+                seq.flights -= 1
+            for j, (seq, start, C) in enumerate(rows):
+                if self._window:
+                    self._trim_window(seq, start + C)
+                if seq.record is not None:
+                    seq.record.note_chunk(now, C, disp_idx)
+                if start + C >= len(seq.prompt):
+                    self._postfill_book(seq, int(out[B + j]), finished, now)
+
+    def _postfill_book(self, seq: SequenceState, first_tok: int,
+                       finished: Dict[str, List[int]], now: float) -> None:
+        """Book the token the program that ended ``seq``'s prompt sampled:
+        finish (EOS / a 1-token budget), or go on as the decode row that
+        _join made of it."""
         if seq.restore_generated:
             # recompute re-prefill done: unfold the prompt/generated
             # split (the folded re-prefill recomputed KV for every
@@ -1127,24 +1387,22 @@ class InferenceEngine:
             seq.prompt = seq.prompt[:seq.n_prompt]
             seq.generated = list(seq.restore_generated)
             seq.restore_generated = []
+        seq.unbooked -= 1
+        slot = seq.slot
+        self._tokens[slot] = first_tok
         # a request's first token, or (re-admitted after a preemption)
         # one more that a mixed step produced: only the NEW token
         # streams, restored tokens already did
         if self._book_tokens(seq, (first_tok,), now, mixed=True):
-            # it is EOS (dropped) or it used up the token budget (kept):
-            # finish without (re-)joining the decode batch
-            self._finish(slot, seq, self._finished_at_prefill, now)
-            return
-        seq.slot = slot
-        self._slots[slot] = seq
-        with self._lock:
-            self.running.append(seq)
-        self._page_table[slot, :] = SCRATCH_PAGE
-        self._page_table[slot, :len(pages)] = pages
-        self._positions[slot] = seq.num_tokens - 1
-        self._tokens[slot] = first_tok
-        if self._window:
-            self._sync_window(slot, seq)
+            # it is EOS (dropped) or it used up the token budget (kept)
+            self._finish(slot, seq, finished, now)
+        elif not seq.ended:
+            # in the decode batch for more programs than those launched:
+            # reserve the decode-loop headroom NOW, before next step's
+            # admission scan can hand these pages to a younger request
+            # (with a program in flight: what there is)
+            self._ensure_pages(slot, seq, self.decode_chunk, finished,
+                               ahead=self._flight is not None)
 
     def _book_tokens(self, seq: SequenceState, toks, now: float, *,
                      mixed: bool) -> Optional[str]:
@@ -1187,6 +1445,11 @@ class InferenceEngine:
     def _finish(self, slot: int, seq: SequenceState,
                 finished: Dict[str, List[int]],
                 now: Optional[float] = None) -> None:
+        """Deliver ``seq``, ended: its tokens, its reason, its record.
+        Its slot and pages go back at once, unless it is a row of a
+        program still in flight (it stopped on EOS with the next program
+        packed: stats late_retired_rows), which has written into them:
+        that program's booking releases them."""
         if seq.request_id not in self._finish_reasons:
             self._note_finish(seq.request_id, "length")
         if self.request_log is not None and seq.record is not None:
@@ -1195,33 +1458,74 @@ class InferenceEngine:
                 self._finish_reasons.get(seq.request_id, "length"))
         seq.done = True
         finished[seq.request_id] = list(seq.generated)
+        if seq.flights:
+            self.stats["late_retired_rows"] += 1
+            self._blank_slot(slot)      # no row of the program after
+        else:
+            self._release(slot, seq)
+
+    def _release(self, slot: int, seq: SequenceState) -> None:
         self._release_pages(seq.pages)
         if self._window:
             self._release_window(slot, seq)
         self._slots[slot] = None
-        self._page_table[slot, :] = SCRATCH_PAGE
+        self._blank_slot(slot)
         seq.slot = None
         with self._lock:
             # a prompt that finishes on its first token never joined
             if seq in self.running:
                 self.running.remove(seq)
 
+    @staticmethod
+    def _goes_on(seq: Optional[SequenceState]) -> bool:
+        """Whether ``seq`` is a row of the next program too, as far as the
+        launched ones say: it has not ended by length in one of them, nor
+        been found ended."""
+        return seq is not None and not seq.ended and not seq.done
+
+    def _loop_may_follow(self) -> bool:
+        """Whether a decode loop may be queued behind the program in
+        flight (_launch): no slot is free, and no request waits for one
+        that the program in flight is known to free."""
+        if None in self._slots:
+            return False
+        return not self.waiting or all(map(self._goes_on, self._slots))
+
+    def _next_may_follow(self) -> bool:
+        """Whether the program the NEXT step launches may be queued behind
+        the one just launched, from what the engine holds now (_launch's
+        rule, before the next admission): chunk work, or a request and a
+        slot for it, make it a mixed step; else it is a decode loop. If
+        not, this step books what it launched: the synchronous order, one
+        program at a time."""
+        if not self._run_ahead:
+            return False
+        if self._chunking or self.waiting and None in self._slots:
+            return True
+        return self._loop_may_follow()
+
     def _decode_rows(self, headroom: int, finished: Dict[str, List[int]],
-                     ) -> List[Tuple[int, SequenceState]]:
+                     ahead: bool = False,
+                     ) -> Optional[List[Tuple[int, SequenceState]]]:
         """Give every decode row its pages for ``headroom`` more tokens
         (a row the pool cannot serve is preempted or evicted), then list
-        the rows that are left: [(slot, seq)]."""
+        the rows that are left: [(slot, seq)]. ``ahead``: None instead,
+        if some row cannot be served (_launch)."""
         for slot, seq in list(enumerate(self._slots)):
-            if seq is not None and not seq.prefilling:
-                self._ensure_pages(slot, seq, headroom, finished)
+            if self._goes_on(seq) and not seq.prefilling \
+                    and not self._ensure_pages(slot, seq, headroom,
+                                               finished, ahead) and ahead:
+                return None
         return [(i, s) for i, s in enumerate(self._slots)
-                if s is not None and not s.prefilling]
+                if self._goes_on(s) and not s.prefilling]
 
     def _ensure_pages(self, slot: int, seq: SequenceState, headroom: int,
-                      finished: Dict[str, List[int]]) -> bool:
-        """Pages for num_tokens + headroom (a decode block may overshoot
+                      finished: Dict[str, List[int]],
+                      ahead: bool = False) -> bool:
+        """Pages for num_launched + headroom (a decode block may overshoot
         past EOS/max_new_tokens into the sequence's own pages). False =
-        evicted for lack of cache memory."""
+        evicted for lack of cache memory or, ``ahead`` (a program is in
+        flight), left as it is: nothing is preempted then."""
         need = min(seq.pages_needed(self.page_size, headroom=headroom),
                    self.max_pages_per_seq)
         while len(seq.pages) < need:
@@ -1231,15 +1535,19 @@ class InferenceEngine:
                 # preemption mode) — release this sequence's pages and
                 # re-queue it at the waiting head; repeat offenders and
                 # unsatisfiable sequences finish with reason "evict"
-                self._preempt(slot, seq, finished)
+                if not ahead:
+                    self._preempt(slot, seq, finished)
                 return False
             self._page_table[slot, len(seq.pages)] = extra[0]
             seq.pages.extend(extra)
         if self._window:
             # the same positions' pages in the window group
-            upto = min(seq.num_tokens + headroom,
+            upto = min(seq.num_launched + headroom,
                        self.max_pages_per_seq * self.page_size) - 1
-            if self._extend_window(seq, upto):
+            added = self._extend_window(seq, upto, ahead)
+            if added is None:
+                return False
+            if added:
                 self._sync_window(slot, seq)
         return True
 
@@ -1277,7 +1585,7 @@ class InferenceEngine:
         if self._window:
             self._release_window(slot, seq)
         self._slots[slot] = None
-        self._page_table[slot, :] = SCRATCH_PAGE
+        self._blank_slot(slot)
         seq.slot = None
         seq.restore_generated = list(seq.generated)
         seq.prompt = seq.prompt + seq.generated
@@ -1295,57 +1603,14 @@ class InferenceEngine:
 
     # ----------------------------------------------------- pure decode
 
-    def _decode(self, finished: Dict[str, List[int]],
-                after_dispatch: Optional[Callable[[], None]]) -> None:
-        with self.phase("engine.pack"):
-            active = self._decode_rows(self.decode_chunk, finished)
-            if not active:
-                return
-            K = self.decode_chunk
-            desc = self._pack_decode(active)
-        with self.phase("engine.h2d"):
-            desc = jax.device_put(desc)         # the ONE transfer
-        with self.phase("engine.dispatch"):
-            toks_out, self.kv, _, _ = self._fns.decode_loop(
-                self.params, desc, self.kv)
-        if after_dispatch is not None:
-            after_dispatch()                       # the device is running
-        with self.phase("engine.readback") as span:
-            block = np.asarray(toks_out)           # [K, B], ONE readback
-            block = self._note_counters(
-                block, K * self.max_batch, span).reshape(K, self.max_batch)
-        with self.phase("engine.book"):
-            now = time.monotonic()
-            self.stats["decode_steps"] += K
-            self.stats["decode_tokens"] += K * len(active)
-            self.stats["decode_dispatches"] += 1
-            self.stats["h2d_arrays"] += 1           # its descriptor
-            if self._window:
-                self._book_page_steps(active, K)
-            self._step_meta = {
-                "kind": "decode",
-                "dispatch": self.stats["decode_dispatches"],
-                "decode_rows": len(active),
-                "real_tokens": K * len(active),
-                "slot_tokens": K * self.max_batch}
-            for slot, seq in active:
-                toks = block[:, slot].tolist()
-                if self._book_tokens(seq, toks, now, mixed=False):
-                    self._finish(slot, seq, finished)
-                else:
-                    self._tokens[slot] = toks[-1]
-                    self._positions[slot] = seq.num_tokens - 1
-                    if self._window \
-                            and self._trim_window(seq, seq.num_tokens - 1):
-                        self._sync_window(slot, seq)
-
     def _pack_decode(self, active: List[Tuple[int, SequenceState]],
                      ) -> np.ndarray:
         """Fill the decode loop's descriptor and return it: every slot's
-        token, position and pages as the engine holds them; a decode row's
-        length is its position + 1, a free slot's 1."""
+        token (-1: unread, on the device), position and pages as the
+        engine holds them; a decode row's length is its position + 1, a
+        free slot's 1."""
         buf, f, _ = next(self._decode_desc)
-        f["tokens"][:] = self._tokens
+        f["tokens"][:] = np.where(self._unread(active), -1, self._tokens)
         f["positions"][:] = self._positions
         f["seq_lens"][:] = np.where(self._decode_mask(active),
                                     self._positions + 1, 1)

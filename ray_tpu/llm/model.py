@@ -111,14 +111,25 @@ another layout or stacked back, and no step copies the pool (threaded as
 scan xs/ys it moved ~4 times a step: PERF.md, PR 27). Only the int8
 pool's scale leaves, which XLA reads, are scattered by XLA.
 
-ONE DESCRIPTOR a dispatch: the two step programs take (params, desc, kv),
-``desc`` ONE flat int32 array that holds every integer input of the
+ONE DESCRIPTOR a dispatch: the two step programs take (params, desc, kv,
+last), ``desc`` ONE flat int32 array that holds every integer input of the
 dispatch (``step_layout`` / ``decode_layout``: the fields, each with its
 shape, one after another; ``token_state`` is a field where some layer
 keeps state a slot), and ``cut`` it at static offsets into the arrays
 their bodies take (``_on_descriptor``). The engine fills the same layout's
 numpy views on the host and makes one host-to-device transfer where it
 made one a field (llm/engine.py: _descriptor_turns).
+
+THE SLOTS' NEWEST TOKENS stay on the device from one program to the next:
+each step program returns, last, every batch slot's newest token
+([decode_rows] int32: the decode loop's last step; the mixed step's decode
+rows and, at its slot, the first token of a prompt whose last chunk the
+step ran), and takes the array the program before it returned as ``last``.
+Where the descriptor's token of a decode row is NEGATIVE the program reads
+that slot's entry of ``last`` instead (``_newest_from``): the one input of
+a dispatch that depends on the program before it never has to pass through
+the host, so the engine can pack and launch a program while the one before
+it still runs (llm/engine.py).
 
 Tensor parallelism (``tp_axis``, in ``_Rows``): the same walk also runs
 INSIDE a ``shard_map`` block whose weights arrive pre-sliced
@@ -140,6 +151,7 @@ from typing import List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -1055,6 +1067,18 @@ def _ragged_forward(params, tokens, token_pos, token_page, token_slot,
     return nxt, kv, counters
 
 
+def _newest_from(tokens: jax.Array, last: Optional[jax.Array], n: int):
+    """``tokens`` with each of its first ``n`` entries that is NEGATIVE
+    replaced by that batch slot's entry of ``last``, the [n] newest tokens
+    the program before this one returned: a decode row whose token the
+    host had not read when it packed this program (llm/engine.py runs one
+    program ahead) takes it on the device. ``last`` None: as they are."""
+    if last is None:
+        return tokens
+    head = tokens[:n]
+    return tokens.at[:n].set(jnp.where(head < 0, last, head))
+
+
 def _ragged_step_body(params: Params, tokens: jax.Array,
                       token_pos: jax.Array, token_page: jax.Array,
                       token_slot: jax.Array, page_table: jax.Array,
@@ -1065,20 +1089,39 @@ def _ragged_step_body(params: Params, tokens: jax.Array,
                       max_q_len: Optional[int] = None,
                       decode_rows: int = 0,
                       token_state: Optional[jax.Array] = None,
-                      **window_pages) -> Tuple[jax.Array, KVCache]:
+                      newest_slot: Optional[jax.Array] = None,
+                      last: Optional[jax.Array] = None,
+                      **window_pages) -> Tuple[jax.Array, KVCache, jax.Array]:
     """The mixed step's program: ``_ragged_forward``, with a step's
     counters (if its configuration has any) appended to the tokens, so
-    both ride the one device->host transfer: (out [R (+ n counters)], kv).
+    both ride the one device->host transfer: (out [R (+ n counters)], kv,
+    newest [decode_rows]).
     ``window_pages``: the second page group's fields of the descriptor
     (``_ragged_logits``'s token_page_win, page_table_win, page_base_win).
-    """
+
+    ``last`` [decode_rows] and ``newest``: every batch slot's newest
+    token, as the program before this one left it and as this one does.
+    A decode row whose ``tokens`` entry is negative reads its slot's entry
+    of ``last`` (``_newest_from``); ``newest`` is ``last`` with each row's
+    sampled token written to the slot ``newest_slot`` [R] names for that
+    row: a decode row's own slot, the sequence's slot for the chunk row
+    that ends a prompt, ``decode_rows`` (past the end: dropped) for every
+    other row. Without ``newest_slot`` the decode rows (q_len 1) write
+    theirs and no chunk row does; without ``last`` no token is negative
+    and ``newest`` starts from zeros."""
+    B = decode_rows
     nxt, kv, counters = _ragged_forward(
-        params, tokens, token_pos, token_page, token_slot, page_table,
-        q_start, q_len, kv_len, kv, cfg, tp_axis, paged_impl, max_q_len,
-        decode_rows, token_state, **window_pages)
+        params, _newest_from(tokens, last, B), token_pos, token_page,
+        token_slot, page_table, q_start, q_len, kv_len, kv, cfg, tp_axis,
+        paged_impl, max_q_len, decode_rows, token_state, **window_pages)
+    if newest_slot is None:
+        rows = jnp.arange(q_len.shape[0], dtype=jnp.int32)
+        newest_slot = jnp.where((rows < B) & (q_len == 1), rows, B)
+    newest = (jnp.zeros(B, jnp.int32) if last is None else last) \
+        .at[newest_slot].set(nxt, mode="drop")
     if counters is not None:
         nxt = jnp.concatenate([nxt, counters])
-    return nxt, kv
+    return nxt, kv, newest
 
 
 def _ragged_decode_loop(params: Params, tokens: jax.Array,
@@ -1088,7 +1131,8 @@ def _ragged_decode_loop(params: Params, tokens: jax.Array,
                         tp_axis: Optional[str] = None,
                         paged_impl: Optional[str] = None,
                         page_table_win: Optional[jax.Array] = None,
-                        page_base_win: Optional[jax.Array] = None):
+                        page_base_win: Optional[jax.Array] = None,
+                        last: Optional[jax.Array] = None):
     """``num_steps`` greedy decode steps in ONE device program.
 
     The pure-decode fast path: every batch slot is one ragged decode row
@@ -1101,8 +1145,11 @@ def _ragged_decode_loop(params: Params, tokens: jax.Array,
     into their OWN pages; the host truncates on readback.
 
     Returns (tokens_out [num_steps, B], kv, final_positions,
-    final_seq_lens) — positions/seq_lens advance by num_steps so the
-    next block chains without host recomputation. With experts,
+    final_seq_lens, newest [B]) — positions/seq_lens advance by num_steps
+    so the next block chains without host recomputation; ``newest`` is
+    the last step's tokens, every slot's newest, for the NEXT program's
+    ``last``: a slot whose ``tokens`` entry is negative starts from its
+    entry of this program's ``last`` (``_newest_from``). With experts,
     tokens_out is flat [num_steps * B + n counters]: the tokens, then the
     dispatch's counters summed over its steps (one transfer, as above).
 
@@ -1137,20 +1184,21 @@ def _ragged_decode_loop(params: Params, tokens: jax.Array,
         out = nxt if counters is None else (nxt, counters)
         return (nxt, pos + 1, kv, lens + 1), out
 
-    (_, positions, kv, seq_lens), toks_out = lax.scan(
-        one, (tokens, positions, kv, seq_lens), None, length=num_steps)
+    (newest, positions, kv, seq_lens), toks_out = lax.scan(
+        one, (_newest_from(tokens, last, R), positions, kv, seq_lens), None,
+        length=num_steps)
     if cfg.n_experts:
         toks, counters = toks_out
         toks_out = jnp.concatenate([toks.reshape(-1), counters.sum(axis=0)])
-    return toks_out, kv, positions, seq_lens
+    return toks_out, kv, positions, seq_lens, newest
 
 
 #: a descriptor's layout: its fields in order, each (name, shape); the
 #: names are the bodies' argument names
 Layout = Tuple[Tuple[str, Tuple[int, ...]], ...]
 #: the mixed step's fields with an entry a ROW; the others have one a token
-ROW_FIELDS = ("q_start", "q_len", "kv_len", "page_table", "page_base_win",
-              "page_table_win")
+ROW_FIELDS = ("q_start", "q_len", "kv_len", "newest_slot", "page_table",
+              "page_base_win", "page_table_win")
 
 
 def step_layout(decode_rows: int, chunk_rows: int, max_q_len: int,
@@ -1168,7 +1216,7 @@ def step_layout(decode_rows: int, chunk_rows: int, max_q_len: int,
     per_token = ("tokens", "token_pos", "token_page", "token_slot") \
         + (("token_state",) if has_state else ()) \
         + (("token_page_win",) if window_pages else ())
-    per_row = ("q_start", "q_len", "kv_len") \
+    per_row = ("q_start", "q_len", "kv_len", "newest_slot") \
         + (("page_base_win",) if window_pages else ())
     return (*((name, (T,)) for name in per_token),
             *((name, (R,)) for name in per_row),
@@ -1214,16 +1262,18 @@ def cut(desc, layout: Layout) -> dict:
 
 
 def _on_descriptor(body):
-    """``body`` as a program of (params, desc, kv): the descriptor cut
-    into the arrays the body takes, and nothing else. ``layouts`` are
+    """``body`` as a program of (params, desc, kv, last): the descriptor
+    cut into the arrays the body takes, the slots' newest tokens as the
+    program before left them (``_newest_from``; None: none is read), and
+    nothing else. ``layouts`` are
     the layouts the caller may send, of different lengths: the trace
     takes the one of ``desc``'s length (a shape, so static). It goes by
     the body's NAME, so the compiled module is jit_<body> as it was when
     the arrays came one by one (benchmark/readers match the two modules
     by that name)."""
-    def program(params, desc, kv, *, layouts, **statics):
-        return body(params, kv=kv, **cut(desc, layout_of(desc, layouts)),
-                    **statics)
+    def program(params, desc, kv, last=None, *, layouts, **statics):
+        return body(params, kv=kv, last=last,
+                    **cut(desc, layout_of(desc, layouts)), **statics)
     program.__name__ = program.__qualname__ = body.__name__
     return program
 
@@ -1298,10 +1348,15 @@ class StepPrograms:
     where a compile tracker runs, the three callables record their
     compiles with it (llm.ragged_step, llm.decode_loop, llm.copy_page).
 
-    The two step programs are called ``(params, desc, kv)``: ``desc`` one
-    flat int32 array in ``decode_layout`` or ``step_layouts[chunk rows]``
-    (a mixed step's shape IS its descriptor's length: the trace finds its
-    layout by it). Over a mesh it is one replicated operand.
+    The two step programs are called ``(params, desc, kv, last)``:
+    ``desc`` one flat int32 array in ``decode_layout`` or
+    ``step_layouts[chunk rows]`` (a mixed step's shape IS its descriptor's
+    length: the trace finds its layout by it), ``last`` the [decode_rows]
+    newest tokens the program before returned (``init_last`` for the
+    first), which each returns anew as its LAST result: the engine hands
+    it on without reading it, so a program may be launched before the one
+    before it has been read back. Over a mesh both are replicated
+    operands.
     """
 
     def __init__(self, cfg: LlamaConfig, *, decode_chunk: int,
@@ -1402,9 +1457,10 @@ class StepPrograms:
                 donate_argnums=(donate,)), {}
 
         return {
-            "ragged_step": sharded(step, (pspecs, rep, kvs), (rep, kvs), 2),
-            "decode_loop": sharded(loop, (pspecs, rep, kvs),
-                                   (rep, kvs, rep, rep), 2),
+            "ragged_step": sharded(step, (pspecs, rep, kvs, rep),
+                                   (rep, kvs, rep), 2),
+            "decode_loop": sharded(loop, (pspecs, rep, kvs, rep),
+                                   (rep, kvs, rep, rep, rep), 2),
             "copy_page": sharded(_copy_page_body, (kvs, rep, rep), kvs, 0)}
 
     def compiled_step_programs(self) -> int:
@@ -1433,6 +1489,13 @@ class StepPrograms:
         if self.mesh is None:
             return params
         return jax.device_put(params, self._param_sharding)
+
+    def init_last(self, decode_rows: int) -> jax.Array:
+        """The first program's ``last``: no slot has a token yet."""
+        zeros = np.zeros(decode_rows, np.int32)
+        if self.mesh is None:
+            return jax.device_put(zeros)
+        return jax.device_put(zeros, NamedSharding(self.mesh, P()))
 
     def init_kv(self, total_pages: int, page_size: int, kv_dtype,
                 max_batch: int = 0, window_pages: int = 0) -> KVCache:

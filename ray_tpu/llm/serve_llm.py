@@ -91,21 +91,38 @@ class LLMServer:
                                    self.engine.startup_programs)
 
     def _loop(self) -> None:
-        """The engine thread. A step's tokens (its finished sequences and
-        the progress drained when it returns) are HELD and handed to
-        their waiters from inside the NEXT engine.step, by its
-        after_dispatch hook: between engine.dispatch and engine.readback,
-        while the device runs that step's program and this thread would
-        only sleep on it. Handed over before the next step instead, the
-        16-128 stream lanes a hand-over wakes take the interpreter from
-        this thread in the one stretch the device waits for (admit, pack,
-        h2d, dispatch). So a token reaches its waiter one launch after it
-        is booked; the request log's timestamps are the booking's, not
-        the delivery's. Flush rule: what no dispatch will carry is handed
-        over at once, in the old place: after a step that launched
-        nothing, and before serve.wait when the engine has run dry. No
-        token is held across a sleep. One thread, FIFO: every stream
-        still gets step N's tokens, then step N+1's, then its end.
+        """The engine thread. A step's tokens (the finished sequences and
+        the progress of the program it BOOKED, drained when it returns)
+        are HELD and handed to their waiters from inside the NEXT
+        engine.step, by its after_dispatch hook: right after that step's
+        engine.dispatch and before its engine.readback, with a program on
+        the device and this thread about to sleep on one. Handed over
+        before the next step instead, the 16-128 stream lanes a hand-over
+        wakes take the interpreter from this thread in the stretch before
+        a launch (admit, pack, h2d, dispatch), which the device waits for
+        wherever the engine has no program queued. So a token reaches its
+        waiter one launch after it is booked; the request log's timestamps
+        are the booking's, not the delivery's.
+
+        The engine runs one program ahead where it can (llm/engine.py): a
+        step launches program N+1 and then books program N, which was
+        launched a step earlier. A token's way is then: computed by N,
+        booked at the end of the step that launched N+1 (as soon as N has
+        ended on the device), handed over under the launch of N+2, about
+        one host stretch (admit .. dispatch) after N ended; what it was
+        before, with the device busy meanwhile. A step that launched a
+        program and booked none (the pipeline filling) returns nothing and
+        holds nothing. A step that launches nothing and books the program
+        in flight (the pipeline draining) calls the hook before it sleeps
+        on that program: what the step before booked does not wait a whole
+        program for its hand-over. Its own tokens fall under the flush
+        rule.
+
+        Flush rule: what no dispatch will carry is handed over at once,
+        in the old place: after a step that launched nothing, and before
+        serve.wait when the engine has run dry. No token is held across a
+        sleep. One thread, FIFO: every stream still gets step N's tokens,
+        then step N+1's, then its end.
 
         The loop's two phases go through the engine's phase helper like
         the step's own (llm/engine.py): spans of the same trace,
@@ -133,13 +150,14 @@ class LLMServer:
             self._wake.clear()
             return
         finished = engine.step(self._hand_over_under_the_device)
-        self._hand_over(overlapped=False)       # the step launched nothing
+        self._hand_over(overlapped=False)       # it slept on no program
         progress = engine.drain_progress()
         if finished or progress:
             self._held = (finished, progress)
 
     def _hand_over_under_the_device(self) -> None:
-        """step()'s after_dispatch hook: a program is on the device."""
+        """step()'s after_dispatch hook: a program is on the device, just
+        launched or still in flight."""
         self._hand_over(overlapped=True)
 
     def _hand_over(self, overlapped: bool) -> None:

@@ -7,7 +7,7 @@ the two engines give the same greedy tokens or something is wrong: which
 step a row rides in must not change what it computes.
 
 And cases of the SHAPE a mixed step runs in (llm/engine.py:
-_ragged_dispatch): the smallest of the seam's compiled shapes that holds
+_launch): the smallest of the seam's compiled shapes that holds
 the rows dealt. serve() holds every mixed step of every case, old and new,
 to that rule and the books to the shapes run; SHAPE_CASES feed an engine
 with two chunk rows one-row and two-row steps in turn and hold it, token
@@ -82,16 +82,20 @@ def serve(eng, prompts, n_new=6, at=None):
     """Add the prompts (together, or prompt i before step ``at[i]``),
     drain the engine, and check each mixed step's books against its deal
     and the arrays it dispatched against the shape the deal asks for.
-    Returns ([tokens a prompt], deals)."""
+    The engine is held to ONE program at a time (_run_ahead False): a
+    step's books are then its own deal's, which is what is checked here;
+    tests/test_llm_ahead.py holds the engine that runs ahead to this one's
+    tokens. Returns ([tokens a prompt], deals)."""
+    eng._run_ahead = False
     deals = watch(eng)
     before = dict(eng.stats)
     at = list(at or [0] * len(prompts))
     rids, dispatched, run = {}, [], eng._fns.ragged_step
 
-    def recording(params, desc, kv):
+    def recording(params, desc, *rest):
         f = fields_of(eng, desc)
         dispatched.append((f["page_table"].shape[0], f["tokens"].shape[0]))
-        return run(params, desc, kv)
+        return run(params, desc, *rest)
     eng._fns.ragged_step = recording
     done, seen, slots = {}, 0, []
     for step in range(400):
@@ -321,6 +325,8 @@ def reference_mixed(eng, active, rows, n_rows):
     f["token_page"] = np.full(Tcap, SCRATCH_PAGE, np.int32)
     f.update({name: np.zeros(R, np.int32)
               for name in ("q_start", "q_len", "kv_len")})
+    # the slot whose newest token a row's sampled token is: none's
+    f["newest_slot"] = np.full(R, eng.max_batch, np.int32)
     f["page_table"] = np.full((R, eng.max_pages_per_seq), SCRATCH_PAGE,
                               np.int32)
     token_state = np.full(Tcap, eng.max_batch, np.int32)
@@ -334,6 +340,7 @@ def reference_mixed(eng, active, rows, n_rows):
         f["token_slot"][i] = pos % ps
         f["q_len"][i] = 1
         f["kv_len"][i] = s.num_tokens
+        f["newest_slot"][i] = i
         token_state[i] = i
     t0 = eng.max_batch
     for j, (seq, start, C) in enumerate(rows):
@@ -348,6 +355,8 @@ def reference_mixed(eng, active, rows, n_rows):
         f["q_start"][r] = t0
         f["q_len"][r] = C
         f["kv_len"][r] = start + C
+        if start + C >= len(seq.prompt):        # its first token
+            f["newest_slot"][r] = seq.slot
         token_state[t0:t0 + C] = seq.slot
         t0 += C
     if eng._has_state:

@@ -91,7 +91,11 @@ def test_children_nest_inside_steps_and_do_not_overlap(profiled):
 def test_a_dispatching_step_has_every_phase_once_in_order(profiled):
     """... and sends ONE host array: engine.h2d opens once a dispatch and
     h2d_arrays counts the descriptors it sent, with the two clocks the
-    benchmark's engine_h2d_ms and engine_host_ms read still running."""
+    benchmark's engine_h2d_ms and engine_host_ms read still running. A
+    step launches at most one program (`launched`: pack, h2d, dispatch)
+    and books at most one (`kind`: readback, book), in that order: the
+    one it launched, or the one the step before left in flight, behind
+    which this step's is then queued."""
     events, before, after = profiled
     assert after["h2d_arrays"] - before["h2d_arrays"] \
         == sum(after[k] - before[k]
@@ -102,15 +106,20 @@ def test_a_dispatching_step_has_every_phase_once_in_order(profiled):
     steps, inside = _steps_with_children(events)
     order = ["engine.admit", "engine.pack", "engine.h2d", "engine.dispatch",
              "engine.readback", "engine.book"]
-    seen = 0
+    seen = ahead = 0
     for step, kids in zip(steps, inside):
         names = [k[0] for k in kids if k[0] != "engine.metrics"]
-        if step[3]["kind"] == "none":
-            assert "engine.dispatch" not in names
-            continue
-        assert names == order, names
-        seen += 1
+        launched = step[3]["launched"] != "none"
+        booked = step[3]["kind"] != "none"
+        # engine.pack opens before the engine knows it launches nothing
+        assert [n for n in names if n != "engine.pack" or launched] \
+            == [n for n in order if n == "engine.admit"
+                or (launched and n in order[1:4])
+                or (booked and n in order[4:])], (names, step[3])
+        seen += launched and booked
+        ahead += bool(step[3].get("ahead"))
     assert seen >= 4
+    assert ahead == after["ahead_dispatches"] - before["ahead_dispatches"] > 0
 
 
 def test_step_kinds_equal_the_dispatch_counters(profiled):
@@ -188,9 +197,13 @@ def test_publish_lies_between_dispatch_and_readback_of_the_next_step(
     for p, step in nested:
         kids = {c[0]: c for c in events if c[0] in CHILDREN
                 and step[1] <= c[1] and c[2] <= step[2]}
-        assert step[3]["kind"] != "none"
-        assert kids["engine.dispatch"][2] <= p[1], (p, kids)
-        assert p[2] <= kids["engine.readback"][1], (p, kids)
+        # the step sleeps on a program: the one it launched, or (it
+        # launched none) the one in flight, which it books
+        assert step[3]["launched"] != "none" or step[3]["kind"] != "none"
+        if step[3]["launched"] != "none":
+            assert kids["engine.dispatch"][2] <= p[1], (p, kids)
+        if step[3]["kind"] != "none":
+            assert p[2] <= kids["engine.readback"][1], (p, kids)
     # a run of at least three consecutive steps, each with its publish
     steps = [e for e in events if e[0] == "engine.step"]
     with_publish = {id(step) for _, step in nested}
@@ -205,9 +218,11 @@ def test_publish_lies_between_dispatch_and_readback_of_the_next_step(
 
 def test_a_publish_outside_a_step_is_a_flush_before_a_sleep(profiled):
     """What no dispatch will carry is handed over at once: the only
-    serve.publish outside engine.step is the one the loop makes when the
-    engine has run dry, followed by serve.wait with no step between; and
-    serve.wait never overlaps a step."""
+    serve.publish outside engine.step is the one the loop makes after a
+    step that launched nothing (it booked the program in flight, and the
+    pipeline is empty) or when the engine has run dry, followed by
+    serve.wait with no step between; and serve.wait never overlaps a
+    step."""
     events, _, _ = profiled
     steps = [e for e in events if e[0] == "engine.step"]
     waits = [e for e in events if e[0] == "serve.wait"]
@@ -218,13 +233,18 @@ def test_a_publish_outside_a_step_is_a_flush_before_a_sleep(profiled):
     for p in flushes:
         after = [e for e in events
                  if e[0] in ("engine.step", "serve.wait") and e[1] >= p[2]]
-        assert not after or after[0][0] == "serve.wait", (p, after[:1])
+        before = [s for s in steps if s[2] <= p[1]]
+        assert not after or after[0][0] == "serve.wait" \
+            or before[-1][3]["launched"] == "none", (p, after[:1])
     # every step that handed nothing over had nothing held: the step
-    # before it booked no token (a mixed step of prefill chunks only)
+    # before it booked no token (none at all, or a mixed step of prefill
+    # chunks only)
     with_publish = {id(step) for _, step in nested}
     for prev, step in zip(steps, steps[1:]):
-        if id(step) not in with_publish and step[3]["kind"] != "none":
-            chunks_only = prev[3]["kind"] == "mixed" \
+        if id(step) not in with_publish and (
+                step[3]["launched"] != "none" or step[3]["kind"] != "none"):
+            chunks_only = prev[3]["kind"] == "none" \
+                or prev[3]["kind"] == "mixed" \
                 and prev[3]["decode_rows"] == 0
             flushed = any(prev[2] <= f[1] and f[2] <= step[1]
                           for f in flushes)
@@ -247,8 +267,9 @@ def test_publish_counters_count_the_spans(profiled):
 def test_step_without_a_callable_is_unchanged_and_with_one_calls_it_once():
     """generate(), llm/batch.py and every test that steps the engine
     itself pass nothing: the same tokens, the same counters; a callable is
-    called once a launching step, between its dispatch and its readback,
-    and never by a step that launches nothing."""
+    called once by a step that launches a program (after its dispatch) or
+    books the one in flight, before the step reads any program back, and
+    never by a step that does neither."""
     def engine():
         return InferenceEngine(
             LlamaConfig.tiny(n_layers=1, dtype=jnp.float32), page_size=8,
@@ -270,28 +291,36 @@ def test_step_without_a_callable_is_unchanged_and_with_one_calls_it_once():
         rids = [eng.add_request(p, max_new_tokens=5) for p in prompts]
         while eng.has_work():
             seen = (eng.stats["wall_ns_dispatch"],
-                    eng.stats["wall_ns_readback"])
+                    eng.stats["wall_ns_readback"],
+                    eng.stats["ragged_dispatches"]
+                    + eng.stats["decode_dispatches"])
             n_calls = len(calls)
             got = eng.step(**kw)
             done.update({rids.index(r): t for r, t in got.items()})
+            booked = eng.stats["ragged_dispatches"] \
+                + eng.stats["decode_dispatches"] - seen[2]
+            launched = eng.stats["wall_ns_dispatch"] > seen[0]
+            assert booked in (0, 1)
+            if kw:
+                # called once, by a step that launched or booked: after
+                # the dispatch (its wall counted), before any readback or
+                # booking
+                assert len(calls) - n_calls == (launched or booked == 1)
             if kw and len(calls) > n_calls:
-                # dispatched (its wall counted), not read back nor booked
-                assert len(calls) == n_calls + 1
-                assert calls[-1][0] > seen[0] and calls[-1][1] == seen[1]
-                assert calls[-1][2] == eng.stats["ragged_dispatches"] \
-                    + eng.stats["decode_dispatches"] - 1
+                assert (calls[-1][0] > seen[0]) == launched
+                assert calls[-1][1:] == seen[1:]
     assert done_plain == done_hooked and len(done_plain) == 3
     counters = ("steps", "decode_steps", "decode_tokens", "prefill_tokens",
                 "decode_dispatches", "ragged_dispatches")
     assert {k: plain.stats[k] for k in counters} \
         == {k: hooked.stats[k] for k in counters}
-    assert len(calls) == hooked.stats["ragged_dispatches"] \
+    n_calls = len(calls)
+    assert n_calls >= hooked.stats["ragged_dispatches"] \
         + hooked.stats["decode_dispatches"]
-    # a step with nothing to launch calls nothing
+    # a step with nothing to launch and nothing to book calls nothing
     assert not hooked.has_work()
     assert hooked.step(after_dispatch=hook) == {}
-    assert len(calls) == hooked.stats["ragged_dispatches"] \
-        + hooked.stats["decode_dispatches"]
+    assert len(calls) == n_calls
     assert "publishes" not in plain.stats       # the serve loop's counters
 
 

@@ -137,6 +137,10 @@ def test_token_booking_is_one_rule(params, learned, reason, where):
     eng = InferenceEngine(CFG, params, page_size=8, total_pages=32,
                           max_batch=2, max_seq_len=64, decode_chunk=4,
                           prefix_cache=False, request_log=True)
+    # _BOOKED_AT's steps are those of one program at a time (running
+    # ahead books a step later where a program is left in flight:
+    # tests/test_llm_ahead.py holds that order to this one's tokens)
+    eng._run_ahead = False
     eng.track_progress = True
     free0 = eng.allocator.num_free
     if reason == "stop":
@@ -212,7 +216,8 @@ def test_step_program_names_are_the_benchmarks(params, tp):
                                   "decode_step_ms.reason.json")):
         jit, statics = eng._fns.jits[program]
         desc = jnp.zeros(M.layout_size(layouts[program]), jnp.int32)
-        text = jit.lower(eng.params, desc, eng.kv, **statics).as_text()
+        text = jit.lower(eng.params, desc, eng.kv, eng._last,
+                         **statics).as_text()
         module = re.search(r"module @(\S+)", text).group(1)
         assert any(rx.search(module)
                    for rx in _metric_patterns(metric_file)), \
@@ -639,6 +644,7 @@ def test_decode_interleaves_with_chunked_prefill(params):
                           max_batch=4, max_seq_len=256, decode_chunk=4,
                           prefix_cache=False, prefill_chunk=8,
                           step_token_budget=8)
+    eng._run_ahead = False      # the books below are read step by step
     a = [9, 4, 33, 2, 71]
     b = [(5 * i + 1) % CFG.vocab_size for i in range(40)]
     wa = _oracle_greedy(params, a, 28)    # 7 decode dispatches of 4:
@@ -897,12 +903,13 @@ def test_a_replica_compiles_nothing_once_it_is_ready(params):
 
 def test_one_transfer_a_dispatch_and_no_buffer_refilled_under_it(params):
     """Decode-only, one-row, two-row and copy-on-write steps: each
-    dispatch sends ONE host array (h2d_arrays + 1, engine.pack and
-    engine.h2d opened once and clocked), a step that launches nothing
-    sends none, and the descriptor a step was launched with still reads
-    what was sent after the NEXT step's is packed and sent (device_put
-    may alias the host's buffer, as the CPU backend does: the engine
-    fills the other one)."""
+    dispatch sends ONE host array (h2d_arrays + 1 when it is booked,
+    engine.pack and engine.h2d opened once and clocked), a step that
+    launches nothing sends none, and the descriptor a step was launched
+    with still reads what was sent after the NEXT step's is packed and
+    sent (device_put may alias the host's buffer, as the CPU backend does:
+    the engine fills the other one), with that program still in flight
+    where the engine runs ahead."""
     import collections
     eng = InferenceEngine(CFG, params, page_size=8, total_pages=128,
                           max_batch=4, max_seq_len=128, prefill_chunk=16,
@@ -915,17 +922,20 @@ def test_one_transfer_a_dispatch_and_no_buffer_refilled_under_it(params):
         return phase(name)
 
     def holding(run):
-        def launch(params, desc, kv):
-            sent.append((desc, np.array(desc)))
-            return run(params, desc, kv)
+        def launch(params, desc, *rest):
+            sent.append((desc, np.array(desc), eng._flight is not None))
+            return run(params, desc, *rest)
         return launch
     eng.phase = counting
     eng._fns.ragged_step = holding(eng._fns.ragged_step)
     eng._fns.decode_loop = holding(eng._fns.decode_loop)
     prompt = [(7 * i + 1) % CFG.vocab_size for i in range(32)]
     arrivals = {0: prompt, 9: prompt[:9], 10: prompt[3:14],
-                20: prompt}         # alone: two rows; two beside decode
-    kinds = collections.Counter()   # rows; every page cached: a copy
+                20: prompt,         # alone: two rows; two beside decode
+                # rows; every page cached: a copy; four chunks: two steps,
+                # the second launched behind the first
+                30: [(11 * i + 5) % CFG.vocab_size for i in range(52)]}
+    kinds = collections.Counter()
     for step in range(60):
         if step in arrivals:
             eng.add_request(arrivals[step], 10)
@@ -934,20 +944,27 @@ def test_one_transfer_a_dispatch_and_no_buffer_refilled_under_it(params):
         d = {k: eng.stats[k] - before[k] for k in (
             "h2d_arrays", "decode_dispatches", "ragged_dispatches",
             "wall_ns_pack", "wall_ns_h2d")}
-        launched = d["decode_dispatches"] + d["ragged_dispatches"]
-        assert launched in (0, 1)
-        assert d["h2d_arrays"] == len(sent) - n_sent == launched
+        booked = d["decode_dispatches"] + d["ragged_dispatches"]
+        launched = len(sent) - n_sent
+        assert booked in (0, 1) and launched in (0, 1)
+        assert d["h2d_arrays"] == booked
+        # what is launched and not booked is the ONE program in flight
+        assert len(sent) - eng.stats["h2d_arrays"] \
+            == (eng._flight is not None)
         assert opened["engine.h2d"] == opened["engine.dispatch"] \
             == len(sent)
         if launched:
             assert d["wall_ns_pack"] > 0 and d["wall_ns_h2d"] > 0
-            kinds[eng._step_meta["kind"],
+            kinds[eng._step_meta["launched"],
                   fields_of(eng, sent[-1][0])["tokens"].size] += 1
-            for dev, was in sent[-2:]:
+            for dev, was, _ in sent[-2:]:
                 assert np.asarray(dev).tobytes() == was.tobytes()
-        if step > 20 and not eng.has_work():
+        if step > 30 and not eng.has_work():
             break
     assert not eng.has_work() and eng.stats["cow_copies"] == 1
+    assert len(sent) == eng.stats["h2d_arrays"]
+    assert 0 < sum(ahead for _, _, ahead in sent) \
+        == eng.stats["ahead_dispatches"]
     assert opened["engine.pack"] >= len(sent)   # a dry engine packs nothing
     assert set(kinds) == {("decode", 4), ("mixed", 4 + 16), ("mixed", 4 + 32)}
 

@@ -705,13 +705,13 @@ def test_step_programs_through_the_kernels_match_reference(monkeypatch,
     outs = {}
     for impl in ("reference", "kernel"):
         rng.bit_generator.state = rng_state         # the same pool twice
-        nxt, kv = M.ragged_step(params, mixed, pool(),
+        nxt, kv, _ = M.ragged_step(params, mixed, pool(),
                                 layouts=(step_layout,), cfg=cfg,
                                 paged_impl=impl, max_q_len=C, decode_rows=B)
         decode, loop_layout = as_descriptor(
             tokens=nxt[:B], positions=[10, 4], seq_lens=[11, 5],
             page_table=table[:B])
-        toks, kv, _, _ = M.ragged_decode_loop(
+        toks, kv, _, _, _ = M.ragged_decode_loop(
             params, decode, kv, layouts=(loop_layout,), num_steps=3,
             cfg=cfg, paged_impl=impl)
         outs[impl] = (np.asarray(nxt), np.asarray(toks),

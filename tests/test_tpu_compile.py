@@ -329,7 +329,10 @@ def _compile_step_program_once(chip, cfg, program, *, max_batch, pages,
     llm/model.py:chunk_row_shapes), or the 8-step decode loop.
     ``pool_rows``: the chunk rows the engine's pool is sized for where
     that is not ``rows`` (a window group holds what every shape's rows can:
-    the smaller shapes run over the full shape's pool).
+    the smaller shapes run over the full shape's pool). Every program
+    takes the slots' newest tokens as its fourth operand and returns them
+    as its last result (the engine launches a program before it has read
+    the one before: llm/engine.py), in no more programs than before.
     Returns (compiled, the pool's abstract pytree, rows of the result)."""
     from ray_tpu.llm import model as M
     from ray_tpu.llm.cache import make_kv_cache, window_group_pages
@@ -352,8 +355,14 @@ def _compile_step_program_once(chip, cfg, program, *, max_batch, pages,
         "decode": ("decode_loop", fns.decode_layout, 8 * max_batch)}[program]
     jit, statics = fns.jits[name]
     desc = _sds(chip, (M.layout_size(layout),), jnp.int32)
-    compiled = jit.lower(params, desc, kv, **{
+    last = _sds(chip, (max_batch,), jnp.int32)
+    compiled = jit.lower(params, desc, kv, last, **{
         **statics, "paged_impl": "kernel"}).compile()
+    newest = jax.tree.leaves(compiled.out_info)[-1]
+    assert (newest.shape, newest.dtype) == ((max_batch,), jnp.int32)
+    assert len(jax.tree.leaves(compiled.args_info)) \
+        == len(jax.tree.leaves((params, kv))) + 2
+    assert fns.program_budget == 2 + len(fns.row_shapes) <= 4
     return compiled, kv, n_out
 
 
