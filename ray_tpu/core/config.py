@@ -69,14 +69,6 @@ _CONFIG_DEFS: dict[str, tuple[type, Any, str]] = {
     "train_health_poll_s": (float, 2.0, "train controller worker poll"),
     "train_straggler_factor": (float, 2.0, "cross-host straggler attribution: rank 0 compares per-host train phase times each step, and a host slower than the fastest host by more than this factor raises train_phase_skew_s{phase,host} plus a train_straggler journal event naming the lagging host; 0 disables the comparison"),
     # --- llm serving ---
-    "llm_prefix_cache": (bool, True, "share page-aligned prompt-prefix KV pages across requests (vLLM-style automatic prefix caching; LRU-evicted under allocator pressure)"),
-    "llm_prefill_chunk": (int, 512, "prompts (or uncached tails) longer than this prefill in chunks interleaved with decode steps, so one long prompt never stalls the running batch for a full prefill dispatch"),
-    "llm_step_token_budget": (int, 2048, "max prefill tokens scheduled per engine step (decode-priority continuous batching); 0 = unbounded"),
-    "llm_admit_lookahead": (int, 16, "waiting requests scanned past a non-admittable head for same-bucket/admissible prompts (head-of-line fix)"),
-    "llm_admit_age_cap_s": (float, 5.0, "a head request older than this stops lookahead skipping so freed pages go to it first (no starvation)"),
-    "llm_kv_dtype": (str, "model", "KV page storage scheme: 'model' (engine dtype) or 'int8' (quantized pages + bf16 per-token scales; ~1.9x concurrent sequences per HBM byte at head_dim 64)"),
-    "llm_ragged_prefill_rows": (int, 2, "most prefill-chunk rows packed into one ragged step dispatch (ragged token capacity at most max_batch + rows*prefill_chunk); more rows advance more prompts per step; when the queue is shallower than the rows a prompt's next chunks take the free ones (not with conv, state-space or retention layers); the step then runs the smallest compiled shape that holds the rows dealt (1, 2, 4, ... below this number, and this number: one program each, all compiled when a served replica starts), and the rest of THAT shape is padding"),
-    "llm_request_log": (bool, True, "per-request flight recorder (lifecycle events, TTFT/TPOT histograms, 'python -m ray_tpu requests'); disable to shave the last % off the step loop"),
     "llm_request_log_size": (int, 256, "request records kept in the engine-side ring (and in the head-side aggregate ring); oldest finished records evict first"),
     "llm_slo_ttft_ms": (float, 200.0, "time-to-first-token SLO target; llm_slo_ttft_attainment reports the fraction of finished requests under it"),
     "llm_slo_tpot_ms": (float, 20.0, "time-per-output-token SLO target (mean inter-token latency after the first); llm_slo_tpot_attainment reports attainment"),
@@ -86,7 +78,7 @@ _CONFIG_DEFS: dict[str, tuple[type, Any, str]] = {
     "serve_slo_eval_period_s": (float, 1.0, "SLO policy evaluation period (controller reconcile passes between policy decisions are a no-op)"),
     "serve_slo_scale_down_evals": (int, 10, "consecutive over-target evaluations (with attainment headroom at n-1 replicas) before a drain-and-pack scale-down; hysteresis against diurnal noise"),
     "serve_overload_steps": (int, 3, "consecutive below-target evaluations AT max replicas before the degradation ladder escalates one level (admission tightening, then shedding)"),
-    "serve_overload_budget_factor": (float, 0.5, "per-level multiplier applied to llm_step_token_budget while overloaded: level n runs at budget*factor**n (tighter admission keeps decode TPOT alive at the cost of prefill throughput)"),
+    "serve_overload_budget_factor": (float, 0.5, "per-level multiplier applied to the engine's step_token_budget while overloaded: level n runs at budget*factor**n (tighter admission keeps decode TPOT alive at the cost of prefill throughput)"),
     "serve_overload_max_level": (int, 3, "degradation ladder ceiling; at max level with a configured shed model, excess requests re-route to the cheaper model via multiplex routing (overload_shed_total counts them)"),
     # --- instance lifecycle (runtime/instance_manager.py) ---
     "instance_orphan_grace_s": (float, 15.0, "restart reconcile terminates a REQUESTED/ALLOCATED instance whose node never registered only after this age — younger launches may still be booting and get adopted instead (raise well above slice boot time for cloud providers)"),

@@ -313,16 +313,20 @@ class InferenceEngine:
                  max_batch: int = 8, max_seq_len: int = 1024,
                  eos_token: Optional[int] = None, seed: int = 0,
                  decode_chunk: int = 8,
-                 prefix_cache: Optional[bool] = None,
-                 prefill_chunk: Optional[int] = None,
-                 step_token_budget: Optional[int] = None,
-                 admit_lookahead: Optional[int] = None,
-                 admit_age_cap_s: Optional[float] = None,
-                 kv_dtype: Optional[str] = None,
-                 prefill_rows: Optional[int] = None,
-                 request_log: Optional[bool] = None,
+                 prefix_cache: Optional[bool] = True,
+                 prefill_chunk: Optional[int] = 512,
+                 step_token_budget: Optional[int] = 2048,
+                 admit_lookahead: Optional[int] = 16,
+                 admit_age_cap_s: Optional[float] = 5.0,
+                 kv_dtype: Optional[str] = "model",
+                 prefill_rows: Optional[int] = 2,
+                 request_log: Optional[bool] = True,
                  tp: int = 1, devices=None):
-        from ray_tpu.core.config import GlobalConfig
+        def given(name: str, value):
+            """None for an argument (an engine_config key left null) is
+            the default above."""
+            return InferenceEngine.__init__.__kwdefaults__[name] \
+                if value is None else value
         # start-up clocks (util/startup_clocks.py): backend, weights and
         # pool here, programs in load_step_programs; stats keys, once made
         startup: Dict[str, int] = {}
@@ -340,19 +344,17 @@ class InferenceEngine:
         # multi-step scheduling); finished sequences overshoot at most
         # K-1 tokens
         self.decode_chunk = max(1, decode_chunk)
-        # scheduler knobs (None -> GlobalConfig llm_* defaults)
-        self.prefill_chunk = max(
-            1, GlobalConfig.llm_prefill_chunk if prefill_chunk is None
-            else prefill_chunk)
-        self.step_token_budget = \
-            GlobalConfig.llm_step_token_budget \
-            if step_token_budget is None else step_token_budget
-        self.admit_lookahead = max(
-            1, GlobalConfig.llm_admit_lookahead if admit_lookahead is None
-            else admit_lookahead)
-        self.admit_age_cap_s = \
-            GlobalConfig.llm_admit_age_cap_s \
-            if admit_age_cap_s is None else admit_age_cap_s
+        # the scheduler's settings: a prompt (or an uncached tail) longer
+        # than prefill_chunk prefills in chunks beside the decode rows;
+        # step_token_budget caps the prefill tokens a step schedules (0 =
+        # no cap); admission scans admit_lookahead waiting requests past a
+        # head it cannot admit, until the head is admit_age_cap_s old
+        self.prefill_chunk = max(1, given("prefill_chunk", prefill_chunk))
+        self.step_token_budget = given("step_token_budget",
+                                       step_token_budget)
+        self.admit_lookahead = max(1, given("admit_lookahead",
+                                            admit_lookahead))
+        self.admit_age_cap_s = given("admit_age_cap_s", admit_age_cap_s)
         # ragged batch geometry: every mixed step carries max_batch
         # decode rows (one per slot, inactive slots masked by q_len=0)
         # plus chunk rows of up to prefill_chunk tokens: as many as the
@@ -360,15 +362,12 @@ class InferenceEngine:
         # (_mixed_shape), prefill_rows at most. A fixed, small set of
         # static shapes, so prompt mix never recompiles; ragged_rows and
         # ragged_tokens are the FULL shape's
-        self.prefill_rows = max(
-            1, GlobalConfig.llm_ragged_prefill_rows if prefill_rows is None
-            else prefill_rows)
+        self.prefill_rows = max(1, given("prefill_rows", prefill_rows))
         self.ragged_rows, self.ragged_tokens = self._mixed_shape(
             self.prefill_rows)
         # KV page storage scheme: "model" (cfg dtype) or "int8"
         # (quantized pages + bf16 per-token scales, ~1.9x capacity)
-        self.kv_dtype = GlobalConfig.llm_kv_dtype \
-            if kv_dtype is None else kv_dtype
+        self.kv_dtype = given("kv_dtype", kv_dtype)
         # tensor parallelism: tp>1 shards weights + kv-heads over a
         # ('tp',) mesh and the seam builds shard_map'd programs over it;
         # page allocator / slot bookkeeping below is layout-agnostic
@@ -433,8 +432,7 @@ class InferenceEngine:
         # the window group's pages are counted on their own
         self.window_allocator: Optional[PageAllocator] = \
             PageAllocator(window_pages) if window_pages else None
-        use_prefix = GlobalConfig.llm_prefix_cache \
-            if prefix_cache is None else prefix_cache
+        use_prefix = given("prefix_cache", prefix_cache)
         if use_prefix and not prefix_cache_supported(cfg):
             # a hit would restore the matched pages' KV and run the
             # recurrent layers on zero state, or the window layers on a
@@ -565,9 +563,7 @@ class InferenceEngine:
         # event stream per request + TTFT/TPOT/e2e/queue-wait histograms
         # + SLO attainment; None disables every hook (seq.record stays
         # None, so the step loop pays one is-None check per event)
-        use_reclog = GlobalConfig.llm_request_log \
-            if request_log is None else request_log
-        if use_reclog:
+        if given("request_log", request_log):
             from ray_tpu.llm.request_log import FlightRecorder
             self.request_log: Optional[FlightRecorder] = FlightRecorder()
         else:

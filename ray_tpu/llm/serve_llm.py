@@ -564,9 +564,8 @@ class LLMServer:
         if level == 0:
             self.engine.step_token_budget = self._base_token_budget
         else:
-            from ray_tpu.core.config import GlobalConfig
-            base = self._base_token_budget or \
-                GlobalConfig.llm_step_token_budget or 2048
+            base = self._base_token_budget or InferenceEngine.__init__ \
+                .__kwdefaults__["step_token_budget"]
             self.engine.step_token_budget = max(
                 64, int(base * (budget_factor ** level)))
         return self.engine.step_token_budget

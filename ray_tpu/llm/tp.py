@@ -31,7 +31,8 @@ from typing import Optional, Sequence
 import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ray_tpu.models.llama import LlamaConfig, Params
+from ray_tpu.models.llama import (LlamaConfig, Params,
+                                  mechanisms_beyond, named)
 
 TP_AXIS = "tp"
 
@@ -77,65 +78,13 @@ def tp_param_specs(cfg: LlamaConfig) -> Params:
 def validate_tp(cfg: LlamaConfig, tp: int) -> None:
     if tp < 2:
         raise ValueError(f"tp must be >= 2 for a sharded engine, got {tp}")
-    if cfg.delta_block:
+    # an untied head is the one mechanism beyond the Llama/Mistral block
+    # with a spec above (tp_param_specs)
+    found = mechanisms_beyond(cfg, served=("untied head",))
+    if found:
         raise NotImplementedError(
-            f"tp={tp} is not served for this block: linear_attention "
-            f"layers (delta_key_heads, delta_value_heads, delta_key_dim, "
-            f"delta_value_dim) keep a float32 matrix state and their "
-            f"conv's inputs per batch slot with no partition spec here "
-            f"(the state would shard by value head with w_qkv's, w_z's and "
-            f"w_ba's columns, two value heads to a key head), beside a "
-            f"latent pool that has no head axis to shard; q_lora_rank, "
-            f"rope_yarn, norm_gate and ffn_clamp are refused with them, "
-            f"untested under a shard (ROADMAP R10b)")
-    if cfg.window_block:
-        raise NotImplementedError(
-            f"tp={tp} is not served for this block: sliding_attention "
-            f"layers keep their pages in a second page group with its own "
-            f"key/value heads (window_kv_heads), for whose leaves there is "
-            f"no partition spec here, and score_head_dim / value_head_dim, "
-            f"rotary_dim, value_scale, experts_held (a share of the "
-            f"experts is the other way a layer is divided among chips: the "
-            f"exchange between the shares is not built), attn_gate (w_og "
-            f"would shard by head with wq's columns), post_norms (a norm "
-            f"over the whole width AFTER the row-parallel sum) and "
-            f"full_rope=False are refused with them, untested under a "
-            f"shard (ROADMAP R5a, R10b)")
-    if cfg.beyond_llama_block:
-        raise NotImplementedError(
-            f"tp={tp} is not served for this block: mamba layers keep a "
-            f"matrix state [ssm_heads, ssm_head_dim, ssm_state] per batch "
-            f"slot with no partition spec here (its heads would shard with "
-            f"w_in's gate and x columns, while B, C and the conv over them "
-            f"are shared by all heads), retention layers keep a matrix "
-            f"state and a normaliser per batch slot and key/value head "
-            f"with none either (they would shard by key/value head with "
-            f"wq / wk / wv / w_g's columns), and rope=False, attn_scale, "
-            f"embed_scale, residual_scale and logits_divisor are refused "
-            f"with them, untested under a shard (ROADMAP R10b)")
-    if cfg.kv_lora_rank or cfg.shared_ffn_dim:
-        raise NotImplementedError(
-            f"tp={tp} is not served for this block: kv_lora_rank (latent "
-            f"attention) keeps ONE cache row a token for all heads, so "
-            f"the pool has no kv-head axis to shard (every shard would "
-            f"hold the whole latent and w_kva, and split w_uk / w_uv / wq "
-            f"/ wo by head: no spec here says so), and shared_ffn_dim "
-            f"comes with n_experts, which is refused below (ROADMAP R8)")
-    if cfg.hybrid or cfg.qk_norm_per_head:
-        raise NotImplementedError(
-            f"tp={tp} is not served for this block: layer_types / "
-            f"n_dense_layers stack the weights per kind of layer and keep "
-            f"a conv state per batch slot, and neither has a partition "
-            f"spec here (the conv operator's w_in would split B, C and u "
-            f"each over the axis); qk_norm_per_head is refused with them, "
-            f"untested under a head shard")
-    if cfg.n_experts or cfg.qk_norm:
-        raise NotImplementedError(
-            f"tp={tp} is not served for this block: qk_norm normalises "
-            f"over all heads, which a head shard can only do with a "
-            f"collective the step does not have, and n_experts="
-            f"{cfg.n_experts} needs an expert-parallel layout, not the "
-            f"Megatron column/row split (ROADMAP R5)")
+            f"tp={tp} is not served for this block: {named(found)} have no "
+            f"partition spec here")
     if cfg.n_kv_heads % tp or cfg.n_heads % tp:
         raise ValueError(
             f"tp={tp} must divide n_heads={cfg.n_heads} and "

@@ -72,6 +72,83 @@ SINK_RANGE = (3.0, 6.0)
 #: hit, busiest 5.4 x the mean: PERF.md, PR 30)
 ROUTER_BIAS_STD = 0.02
 
+#: The Llama/Mistral block's fields and the run options: what every consumer
+#: of a LlamaConfig takes. layer_types is the switch of the layer kinds below.
+LLAMA_BLOCK = (
+    "vocab_size", "dim", "n_layers", "n_heads", "n_kv_heads", "ffn_dim",
+    "rope_theta", "norm_eps", "attention", "dtype", "param_dtype", "remat",
+    "remat_policy", "pp_microbatches", "fsdp_overlap", "int8_mlp",
+    "layer_types")
+#: Every other field, under the ONE mechanism it describes. A mechanism is ON
+#: where layer_types names it (a layer kind) or a field of it is set off its
+#: default (mechanisms_beyond); the configuration's cross-checks and both
+#: refusals (the training side's below, llm/tp.py's) are read off this
+#: table, and a field that is in neither tuple fails
+#: tests/test_llama_mechanisms.py.
+MECHANISMS = {
+    "experts": ("n_experts", "experts_per_token", "norm_topk_prob",
+                "router_score", "router_bias", "router_eps", "router_scale",
+                "n_dense_layers", "dense_ffn_dim", "shared_ffn_dim"),
+    "experts_held": ("experts_held",),
+    "qk_norm": ("qk_norm",),
+    "qk_norm_per_head": ("qk_norm_per_head",),
+    "untied head": ("tie_embeddings",),
+    "latent attention": ("kv_lora_rank", "qk_nope_head_dim",
+                         "qk_rope_head_dim", "v_head_dim", "q_lora_rank",
+                         "rope_yarn"),
+    ATTENTION: (),
+    CONV: ("conv_kernel",),
+    MAMBA: ("ssm_state", "ssm_heads", "ssm_head_dim", "ssm_conv",
+            "ssm_chunk"),
+    RETENTION: ("retention_chunk",),
+    WINDOW: ("sliding_window", "window_kv_heads", "window_rope_theta",
+             "attn_sink"),
+    DELTA: ("delta_key_heads", "delta_value_heads", "delta_key_dim",
+            "delta_value_dim", "delta_conv", "delta_chunk", "delta_norm_eps",
+            "delta_gate_scale"),
+    "head widths": ("score_head_dim", "value_head_dim", "rotary_dim",
+                    "value_scale"),
+    "multipliers": ("embed_scale", "residual_scale", "logits_divisor"),
+    "attn_scale": ("attn_scale",),
+    "no positions": ("rope",),
+    "gated block": ("attn_gate", "post_norms", "full_rope"),
+    "norm_gate": ("norm_gate",),
+    "ffn_clamp": ("ffn_clamp",)}
+#: Constants of a mechanism's arithmetic (taps, a chunk, an epsilon, the
+#: router's score) that nothing reads where the mechanism is off: set alone
+#: they do not turn it on, and a configuration that sets them is accepted as
+#: it always was
+IDLE_WHERE_OFF = frozenset((
+    "experts_per_token", "norm_topk_prob", "router_score", "router_bias",
+    "router_eps", "router_scale", "dense_ffn_dim", "conv_kernel", "ssm_conv",
+    "ssm_chunk", "retention_chunk", "delta_conv", "delta_chunk",
+    "delta_norm_eps", "delta_gate_scale"))
+#: What adds to any block: no mechanism below is refused beside these
+_ANYWHERE = (ATTENTION, "untied head", "multipliers", "ffn_clamp",
+             "experts_held", "norm_gate")
+#: mechanism -> the mechanisms it is BUILT beside (llm/model.py has the
+#: program, a reference under benchmark/ the comparison); one with no row
+#: here is built beside whatever lists it. A new mechanism is in no row:
+#: refused beside these until a row says it is served
+BUILT_BESIDE = {
+    MAMBA: _ANYWHERE + (CONV, RETENTION, "qk_norm", "qk_norm_per_head",
+                        "head widths", "attn_scale", "no positions"),
+    RETENTION: _ANYWHERE + (CONV, MAMBA, "qk_norm", "qk_norm_per_head",
+                            "attn_scale", "no positions"),
+    WINDOW: _ANYWHERE + ("experts", "qk_norm_per_head", "head widths",
+                         "gated block"),
+    DELTA: _ANYWHERE + ("experts", "latent attention", "qk_norm",
+                        "qk_norm_per_head", "head widths", "attn_scale",
+                        "no positions", "gated block"),
+    "gated block": _ANYWHERE + (
+        WINDOW, DELTA, "experts", "latent attention", "qk_norm",
+        "qk_norm_per_head", "head widths", "attn_scale", "no positions"),
+    "head widths": _ANYWHERE + (
+        CONV, MAMBA, WINDOW, DELTA, "experts", "qk_norm_per_head",
+        "attn_scale", "no positions", "gated block"),
+    "latent attention": _ANYWHERE + (DELTA, "experts", "attn_scale",
+                                     "no positions", "gated block")}
+
 
 @dataclasses.dataclass(frozen=True)
 class LlamaConfig:
@@ -93,7 +170,8 @@ class LlamaConfig:
     int8_mlp: bool = False           # dynamic-W8A8 MLP matmuls (ops.int8)
     # The block's variation points. The defaults are the Llama/Mistral
     # block; the serving step (llm/model.py) follows them, the training
-    # forward below refuses what it has not got (_require_llama_block).
+    # forward below refuses what it has not got (MECHANISMS,
+    # _require_llama_block).
     n_experts: int = 0               # 0 = dense SwiGLU; else routed experts
     experts_per_token: int = 0       #   of width ffn_dim, this many a token
     norm_topk_prob: bool = False     # renormalise the chosen experts' weights
@@ -235,82 +313,60 @@ class LlamaConfig:
                 f"shared_ffn_dim={self.shared_ffn_dim} is the width of the "
                 f"expert every token takes BESIDE the routed ones: it "
                 f"needs n_experts")
+        # the cross-checks, off the table: a mechanism whose switch is off
+        # and whose fields are set; one beside what it is not built beside
+        on = mechanisms_beyond(self)
+        switches = {kind: kind in self.layer_types for kind in LAYER_KINDS}
+        switches["latent attention"] = bool(self.kv_lora_rank)
+        for name, switched in switches.items():
+            if name in on and not switched:
+                raise ValueError(
+                    f"{', '.join(on[name])} describe {named([name])}: "
+                    + ("layer_types names none" if name in LAYER_KINDS
+                       else "they need kv_lora_rank"))
+        for name, beside in BUILT_BESIDE.items():
+            others = [m for m in on if m != name and m not in beside]
+            if name in on and others:
+                raise ValueError(
+                    f"{named([name])}: not built beside {named(others)}")
+        if not self.full_rope and WINDOW not in self.layer_types:
+            raise ValueError(
+                "full_rope=False describes a block whose window layers "
+                "rotate and whose full layers do not: layer_types "
+                "names no sliding_attention layer (rope=False is the "
+                "block with no positions at all)")
+        if self.norm_gate and not self.kv_lora_rank:
+            raise ValueError(
+                "norm_gate gates EVERY norm of a block, and only latent "
+                "attention (kv_lora_rank) and the linear_attention layers "
+                "beside it take their norms through it: per-head K and V "
+                "attention would be served with a plain norm")
+        # ... and the values a mechanism that is on needs
         ssm = (self.ssm_state, self.ssm_heads, self.ssm_head_dim)
-        if MAMBA in self.layer_types:
-            if min(ssm) <= 0 or self.ssm_conv < 2 or self.ssm_chunk < 1:
-                raise ValueError(
-                    f"mamba layers need ssm_state, ssm_heads, ssm_head_dim, "
-                    f"at least 2 conv taps and a chunk, got {ssm}, "
-                    f"{self.ssm_conv}, {self.ssm_chunk}")
-            if self.n_experts or self.kv_lora_rank:
-                raise ValueError(
-                    "mamba layers are not built beside routed experts or "
-                    "latent attention")
-        elif any(ssm):
-            raise ValueError("ssm_state, ssm_heads and ssm_head_dim describe "
-                             "mamba layers: layer_types names none")
-        if RETENTION in self.layer_types:
-            if self.n_experts or self.kv_lora_rank:
-                raise ValueError(
-                    "retention layers are not built beside routed experts "
-                    "or a latent pool")
-            if self.n_heads % self.n_kv_heads or self.head_dim % 8 \
-                    or self.retention_chunk < 1:
-                raise ValueError(
-                    f"retention layers need n_kv_heads to divide n_heads, "
-                    f"a head_dim of whole blocks of 8 and a chunk, got "
-                    f"{self.n_heads}, {self.n_kv_heads}, {self.head_dim}, "
-                    f"{self.retention_chunk}")
+        if MAMBA in self.layer_types and (
+                min(ssm) <= 0 or self.ssm_conv < 2 or self.ssm_chunk < 1):
+            raise ValueError(
+                f"mamba layers need ssm_state, ssm_heads, ssm_head_dim, "
+                f"at least 2 conv taps and a chunk, got {ssm}, "
+                f"{self.ssm_conv}, {self.ssm_chunk}")
+        if RETENTION in self.layer_types and (
+                self.n_heads % self.n_kv_heads or self.head_dim % 8
+                or self.retention_chunk < 1):
+            raise ValueError(
+                f"retention layers need n_kv_heads to divide n_heads, "
+                f"a head_dim of whole blocks of 8 and a chunk, got "
+                f"{self.n_heads}, {self.n_kv_heads}, {self.head_dim}, "
+                f"{self.retention_chunk}")
         window = (self.sliding_window, self.window_kv_heads,
                   self.window_rope_theta)
-        if WINDOW in self.layer_types:
-            if min(window) <= 0 or self.n_heads % self.window_kv_heads:
-                raise ValueError(
-                    f"sliding_attention layers need a sliding_window, "
-                    f"window_kv_heads that divide n_heads and a "
-                    f"window_rope_theta, got {window}")
-            if self.kv_lora_rank or self.qk_norm or not self.rope \
-                    or self.attn_scale or len(
-                        set(self.layer_types) - {ATTENTION, WINDOW}):
-                raise ValueError(
-                    "sliding_attention layers are built beside "
-                    "full_attention layers only, rotated (full_rope=False "
-                    "leaves the FULL layers without positions) and with "
-                    "the score scale of the head: not beside a latent "
-                    "pool, a q/k norm over the whole projected vector "
-                    "(qk_norm_per_head is served), rope=False, attn_scale, "
-                    "or conv, mamba or retention layers")
-        elif any(window) or self.attn_sink:
+        if WINDOW in self.layer_types and (
+                min(window) <= 0 or self.n_heads % self.window_kv_heads):
             raise ValueError(
-                "sliding_window, window_kv_heads, window_rope_theta and "
-                "attn_sink describe sliding_attention layers: layer_types "
-                "names none")
-        if self.gated_block:
-            if not self.full_rope and WINDOW not in self.layer_types:
-                raise ValueError(
-                    "full_rope=False describes a block whose window layers "
-                    "rotate and whose full layers do not: layer_types "
-                    "names no sliding_attention layer (rope=False is the "
-                    "block with no positions at all)")
-            if (self.kv_lora_rank and not self.full_rope) or len(
-                    set(self.layer_types) - {ATTENTION, WINDOW, DELTA}):
-                raise ValueError(
-                    "attn_gate, post_norms and full_rope describe attention "
-                    "(full_attention, latent or not, and sliding_attention "
-                    "layers) and linear_attention layers, which take the "
-                    "second norm: not conv, mamba or retention layers, "
-                    "whose operators have no gate and no second norm, and "
-                    "the latent operator always rotates")
-        if (self.score_head_dim or self.value_head_dim or self.rotary_dim
-                or self.value_scale != 1.0):
+                f"sliding_attention layers need a sliding_window, "
+                f"window_kv_heads that divide n_heads and a "
+                f"window_rope_theta, got {window}")
+        if "head widths" in on:
             dk, dv = self.qk_head_dim, self.v_dim
-            if self.kv_lora_rank or RETENTION in self.layer_types \
-                    or self.qk_norm:
-                raise ValueError(
-                    "score_head_dim, value_head_dim, rotary_dim and "
-                    "value_scale describe per-head K and V attention with "
-                    "no norm over the whole projected vector: not a latent "
-                    "pool, retention layers or qk_norm")
             if min(dk, dv) <= 0 or dk % 2 or self.rotary_dim % 2 \
                     or not 0 <= self.rotary_dim <= dk:
                 raise ValueError(
@@ -328,59 +384,30 @@ class LlamaConfig:
                     f"{self.experts_held}")
         heads = (self.qk_nope_head_dim, self.qk_rope_head_dim,
                  self.v_head_dim)
-        if self.kv_lora_rank:
-            if min(heads) <= 0 or self.qk_rope_head_dim % 2:
-                raise ValueError(
-                    f"kv_lora_rank={self.kv_lora_rank} (latent attention) "
-                    f"needs qk_nope_head_dim, an even qk_rope_head_dim and "
-                    f"v_head_dim, got {heads}")
-            if self.qk_norm or self.qk_norm_per_head \
-                    or CONV in self.layer_types:
-                raise ValueError(
-                    "kv_lora_rank (latent attention) has its own norm on "
-                    "the latent and no per-head K: qk_norm / "
-                    "qk_norm_per_head do not apply, and it is not built "
-                    "beside conv layers")
-        elif any(heads) or self.q_lora_rank or self.rope_yarn:
+        if self.kv_lora_rank and (
+                min(heads) <= 0 or self.qk_rope_head_dim % 2):
             raise ValueError(
-                "qk_nope_head_dim, qk_rope_head_dim, v_head_dim, "
-                "q_lora_rank and rope_yarn describe latent attention: they "
-                "need kv_lora_rank")
+                f"kv_lora_rank={self.kv_lora_rank} (latent attention) "
+                f"needs qk_nope_head_dim, an even qk_rope_head_dim and "
+                f"v_head_dim, got {heads}")
         if self.rope_yarn and (len(self.rope_yarn) != 6
                                or self.rope_yarn[0] <= 1.0):
             raise ValueError(
                 f"rope_yarn = (factor > 1, original positions, beta_fast, "
                 f"beta_slow, mscale, mscale_all_dim), got {self.rope_yarn}")
-        if self.norm_gate and not self.kv_lora_rank:
-            raise ValueError(
-                "norm_gate gates EVERY norm of a block, and only latent "
-                "attention (kv_lora_rank) and the linear_attention layers "
-                "beside it take their norms through it: per-head K and V "
-                "attention would be served with a plain norm")
         delta = (self.delta_key_heads, self.delta_value_heads,
                  self.delta_key_dim, self.delta_value_dim)
-        if DELTA in self.layer_types:
-            chunk = self.delta_chunk
-            if min(delta) <= 0 or self.delta_value_heads \
-                    % self.delta_key_heads or self.delta_conv < 2 \
-                    or chunk < 1 or chunk & (chunk - 1):
-                raise ValueError(
-                    f"linear_attention layers need delta_key_heads that "
-                    f"divide delta_value_heads, delta_key_dim, "
-                    f"delta_value_dim, at least 2 conv taps and a chunk "
-                    f"that is a power of two, got {delta}, "
-                    f"{self.delta_conv}, {chunk}")
-            if len(set(self.layer_types) - {ATTENTION, DELTA}):
-                raise ValueError(
-                    "linear_attention layers are built beside "
-                    "full_attention layers only (latent or not): not "
-                    "beside conv, mamba, retention or sliding_attention "
-                    "layers")
-        elif any(delta):
+        chunk = self.delta_chunk
+        if DELTA in self.layer_types and (
+                min(delta) <= 0 or self.delta_value_heads
+                % self.delta_key_heads or self.delta_conv < 2
+                or chunk < 1 or chunk & (chunk - 1)):
             raise ValueError(
-                "delta_key_heads, delta_value_heads, delta_key_dim and "
-                "delta_value_dim describe linear_attention layers: "
-                "layer_types names none")
+                f"linear_attention layers need delta_key_heads that "
+                f"divide delta_value_heads, delta_key_dim, "
+                f"delta_value_dim, at least 2 conv taps and a chunk "
+                f"that is a power of two, got {delta}, "
+                f"{self.delta_conv}, {chunk}")
 
     @property
     def head_dim(self) -> int:
@@ -402,38 +429,6 @@ class LlamaConfig:
         layers without positions beside window layers that rotate
         (Trinity-Mini is the first such block)."""
         return self.attn_gate or self.post_norms or not self.full_rope
-
-    @property
-    def delta_block(self) -> bool:
-        """Gated-delta-rule layers, a low-rank query, YaRN frequencies,
-        sigmoid-gated norms or a clamped SwiGLU (GigaChat3.5 is the first
-        such block): what llm/model.py alone serves."""
-        return DELTA in self.layer_types or bool(
-            self.q_lora_rank or self.rope_yarn or self.norm_gate
-            or self.ffn_clamp)
-
-    @property
-    def window_block(self) -> bool:
-        """Window layers, head widths of their own, a partial rotary
-        embedding, a value scale, or a share of the experts (MiMo-V2-Flash
-        is the first such block), or the gated block's fields."""
-        return WINDOW in self.layer_types or bool(
-            self.score_head_dim or self.value_head_dim or self.rotary_dim
-            or self.experts_held) or self.value_scale != 1.0 \
-            or self.gated_block
-
-    @property
-    def beyond_llama_block(self) -> bool:
-        """Mamba or retention layers, attention without positions or with
-        a score scale of its own, a multiplier, or the window block's
-        fields: what llm/model.py alone serves and the training forward and
-        llm/tp.py refuse together, by name."""
-        return MAMBA in self.layer_types \
-            or RETENTION in self.layer_types or not self.rope \
-            or bool(self.attn_scale) or (
-                self.embed_scale, self.residual_scale,
-                self.logits_divisor) != (1.0, 1.0, 1.0) \
-            or self.window_block
 
     @property
     def ssm_channels(self) -> int:
@@ -477,6 +472,41 @@ class LlamaConfig:
                     n_kv_heads=8, ffn_dim=14336)
         base.update(kw)
         return LlamaConfig(**base)
+
+
+#: a field's default: what "set" is measured against
+_DEFAULTS = {f.name: f.default for f in dataclasses.fields(LlamaConfig)}
+
+
+def mechanisms_beyond(cfg: LlamaConfig, served=()) -> Dict[str, Tuple]:
+    """mechanism -> those of its fields ``cfg`` sets off their defaults,
+    for every mechanism of MECHANISMS that is ON in ``cfg`` (layer_types
+    names it, or a field that is no idle constant is set) and that the
+    consumer does not name in ``served``. Empty: the consumer has all of
+    ``cfg``. The one question behind the cross-checks above, the training
+    side's refusal below and llm/tp.py's."""
+    found = {}
+    for name, fields in MECHANISMS.items():
+        differ = tuple(f for f in fields
+                       if getattr(cfg, f) != _DEFAULTS[f])
+        if name not in served and (name in cfg.layer_types
+                                   or not IDLE_WHERE_OFF.issuperset(differ)):
+            found[name] = differ
+    return found
+
+
+def named(mechanisms) -> str:
+    """Mechanisms (mechanisms_beyond's answer, or their names) as a
+    message names them: layer kinds as the published configurations do,
+    each with the fields found set."""
+    fields = mechanisms if isinstance(mechanisms, dict) else {}
+    parts = []
+    for m in mechanisms:
+        # a mechanism of one field is named for it: not said twice
+        found = [f for f in fields.get(m, ()) if f != m]
+        parts.append((f"{m} layers" if m in LAYER_KINDS else m)
+                     + (f" ({', '.join(found)})" if found else ""))
+    return ", ".join(parts)
 
 
 def init_params(cfg: LlamaConfig, key: jax.Array) -> Params:
@@ -764,57 +794,11 @@ def _init_hybrid_params(cfg: LlamaConfig, key: jax.Array) -> Params:
 
 
 def _require_llama_block(cfg: LlamaConfig, what: str) -> None:
-    if cfg.delta_block:
+    found = mechanisms_beyond(cfg)
+    if found:
         raise NotImplementedError(
-            f"{what} is written for the Llama/Mistral block: "
-            f"linear_attention layers (delta_key_heads, delta_value_heads, "
-            f"delta_key_dim, delta_value_dim: the gated delta rule, whose "
-            f"float32 matrix state is a CACHE format, with no training "
-            f"scan or backward here), q_lora_rank, rope_yarn, norm_gate "
-            f"and ffn_clamp are served by llm/model.py only (ROADMAP R4)")
-    if cfg.window_block:
-        raise NotImplementedError(
-            f"{what} is written for the Llama/Mistral block: "
-            f"sliding_attention layers (sliding_window, window_kv_heads, "
-            f"window_rope_theta, attn_sink: a mask, a sink in the softmax "
-            f"and a second page group that are CACHE-side, with no "
-            f"training attention here), score_head_dim / value_head_dim, "
-            f"rotary_dim, value_scale, experts_held (a chip's share of "
-            f"an expert layer), attn_gate (a sigmoid gate on the attention "
-            f"output), post_norms (a norm after each branch) and "
-            f"full_rope=False (a rotation by kind of layer) are served by "
-            f"llm/model.py only (ROADMAP R4)")
-    if cfg.beyond_llama_block:
-        raise NotImplementedError(
-            f"{what} is written for the Llama/Mistral block: mamba layers "
-            f"(ssm_state, ssm_heads, ssm_head_dim: a selective state-space "
-            f"recurrence whose matrix state is a CACHE format, with no "
-            f"training scan or backward here), retention layers (power "
-            f"retention: a gated matrix state over the expanded key, a "
-            f"CACHE format too), rope=False, attn_scale, "
-            f"embed_scale, residual_scale and logits_divisor are served by "
-            f"llm/model.py only (ROADMAP R4)")
-    if cfg.kv_lora_rank or cfg.shared_ffn_dim:
-        raise NotImplementedError(
-            f"{what} is written for the Llama/Mistral block: kv_lora_rank "
-            f"(latent attention: qk_nope_head_dim, qk_rope_head_dim, "
-            f"v_head_dim) and shared_ffn_dim are served "
-            f"by llm/model.py only: the latent is a CACHE format, its "
-            f"absorbed products exist only where a cache is read, and the "
-            f"training side has no dropless experts for a shared expert "
-            f"to stand beside (ROADMAP R4)")
-    if cfg.hybrid or cfg.qk_norm_per_head:
-        raise NotImplementedError(
-            f"{what} is written for the Llama/Mistral block, every layer "
-            f"alike; layer_types (conv layers beside attention), "
-            f"n_dense_layers and qk_norm_per_head are served by "
-            f"llm/model.py only: the conv operator has no training "
-            f"forward or backward here")
-    if cfg.n_experts or cfg.qk_norm or not cfg.tie_embeddings:
-        raise NotImplementedError(
-            f"{what} is written for the Llama/Mistral block; n_experts, "
-            f"qk_norm and an untied head are served by llm/model.py only "
-            f"(training them is models/mixtral.py's side, ROADMAP R5)")
+            f"{what} is written for the Llama/Mistral block, not for "
+            f"{named(found)}: served by llm/model.py only")
 
 
 def param_specs(cfg: LlamaConfig) -> Params:

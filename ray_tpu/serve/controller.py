@@ -17,7 +17,7 @@ Two autoscaling policies:
   flight recorders) drives replica count up on breach and drains down on
   sustained headroom; when attainment keeps falling AT max replicas a
   degradation ladder tightens engine admission (``set_overload_level``
-  scales ``llm_step_token_budget`` down per level) and finally sheds
+  scales the engine's ``step_token_budget`` down per level) and finally sheds
   requests to a cheaper multiplexed model via the routing table's
   ``shed_to`` field. Every decision is journaled into the head's
   ClusterEventJournal so ``events --follow`` replays a whole storm.

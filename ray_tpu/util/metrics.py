@@ -467,7 +467,7 @@ def llm_queue_depth_gauge() -> Gauge:
 
 def llm_compiled_programs_gauge() -> Gauge:
     """Compiled LLM step programs resident: the ragged mixed step once a
-    chunk-row shape (1, 2, 4, ... below llm_ragged_prefill_rows, and that
+    chunk-row shape (1, 2, 4, ... below the engine's prefill_rows, and that
     number: two at the default), the decode loop, the COW page copy. A
     served replica shows the whole number (4 at the default; 3 without a
     prefix cache) from its start. O(1) by design — a rise past it means

@@ -5,7 +5,6 @@ skew and gaps are known, and on a recorded TPU trace of the spans
 metric of BENCHMARK.json that reads the spans or the new request-log
 fields has its metric file and reader."""
 
-import importlib
 import json
 import os
 import sys
@@ -23,13 +22,15 @@ MS = 1_000_000
 FIXTURES = os.path.join(ROOT, "benchmark", "fixtures")
 ANCHORS = {"start_after": ["^DoEnqueueProgram$"],
            "end_before": ["^tpu::System::Execute=>Done$"]}
+#: (what the entry reads: a word of tests/_readings.py, a cell it reads it
+#: for): found by the reading, whatever the entry is called
 NEW_METRICS = [
-    "engine_host_gap_ms.chat", "engine_host_gap_ms.reason",
-    "idle_prep_pct.chat", "idle_prep_pct.reason",
-    "idle_post_pct.chat", "idle_post_pct.reason",
-    "idle_attributed_pct.chat", "idle_attributed_pct.reason",
-    "trace_clock_skew_ms.chat", "trace_clock_skew_ms.reason",
-    "tpot_mixed_stall_pct", "queue_wait_in_flight_p50_ms"]
+    (reading, cell) for reading in (
+        "engine_host_gap_ms", "idle_prep_pct", "idle_post_pct",
+        "idle_attributed_pct", "trace_clock_skew_ms")
+    for cell in ("chat-1chip", "reason-1chip")] + [
+    ("tpot_mixed_stall_pct", "chat-1chip"),
+    ("queue_wait_in_flight_p50_ms", "chat-1chip")]
 
 
 def built_planes(skew_ms: float):
@@ -185,27 +186,20 @@ def test_recorded_tpu_trace_reproduces_its_expected_analysis():
     assert set(summary.module_n) == set(want["modules"])
 
 
-@pytest.mark.parametrize("name", NEW_METRICS)
-def test_new_metric_has_entry_file_and_reader(name):
+@pytest.mark.parametrize("reading,cell", NEW_METRICS)
+def test_new_metric_has_entry_file_and_reader(reading, cell):
+    from _readings import entry
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    entry = next(m for m in bench["per_layer"] if m["name"] == name)
-    with open(os.path.join(ROOT, "benchmark", "metrics",
-                           name + ".json")) as f:
-        spec = json.load(f)
-    assert spec["name"] == name
-    reader = importlib.import_module("benchmark.readers." + spec["reader"])
+    m, args, reader = entry(reading, cell)
     assert callable(reader.read)
     cells = {w["name"] for w in bench["workloads"]}
-    moved = next(m for m in bench["end_to_end"]
-                 if m["name"] == entry["moves"])
-    assert set(entry["workloads"]) <= cells
-    assert set(entry["workloads"]) <= set(moved.get("workloads", cells))
-    if spec["reader"] == "host_gaps":
-        assert entry["source"] == "program_span"
-        assert spec["args"]["quantity"] in (
-            "gap_ms", "attributed_pct", "share_pct", "skew_ms")
+    moved = next(e for e in bench["end_to_end"] if e["name"] == m["moves"])
+    assert set(m["workloads"]) <= cells
+    assert set(m["workloads"]) <= set(moved.get("workloads", cells))
+    if reader is host_gaps:
+        assert m["source"] == "program_span" and args["anchors"] == ANCHORS
     else:
         # the request log's record must carry the field
         from ray_tpu.llm.request_log import RequestRecord
-        assert spec["args"]["field"] in RequestRecord("r", 1, 1).to_dict()
+        assert args["field"] in RequestRecord("r", 1, 1).to_dict()

@@ -23,6 +23,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+from _readings import entry  # noqa: E402
 from benchmark import checks, checks_gigachat, hold_gigachat  # noqa: E402
 from benchmark import kernel_cost_gigachat as kc  # noqa: E402
 from benchmark import kernel_cost_kanana, loadgen  # noqa: E402
@@ -30,6 +31,7 @@ from benchmark.readers import (engine_clocks, gigachat_counters,  # noqa: E402
                                gigachat_roofline)
 from benchmark.runners import serve_gigachat, serve_kanana  # noqa: E402
 from ray_tpu.llm import model as M  # noqa: E402
+from ray_tpu.models.llama import mechanisms_beyond  # noqa: E402
 
 CELL = "reason-gigachat-1chip"
 CONFIG = "gigachat35-432b-a28b-serve-1chip"
@@ -47,8 +49,8 @@ OWN = {"delta_update_time_pct.gigachat": ("trace_share", None),
        "moe_load_skew.gigachat": ("gigachat_counters", None)}
 #: accepted entries whose readers' arguments hold for this block too: the
 #: cell joins their lists and spends no entry
-SHARED = ["moe_absent_pct.mimo", "mla_proj_time_pct.kanana",
-          "moe_shared_time_pct.kanana", "attn_gate_time_pct",
+SHARED = ["moe_absent_pct", "mla_proj_time_pct",
+          "moe_shared_time_pct", "attn_gate_time_pct",
           "lm_head_time_pct", "decode_step_ms", "mixed_step_ms",
           "mixed_step_time_pct", "device_idle_pct", "engine_host_gap_ms",
           "idle_prep_pct", "paged_attn_time_pct", "moe_ffn_time_pct",
@@ -202,7 +204,8 @@ def test_published_keys_map_to_the_programs_fields():
             cfg.attn_gate, cfg.post_norms) == (2.0, 2.0, 10.0, True, True)
     assert set(config["program_fields"]) == {
         "param_dtype", "router_bias", "router_eps", "delta_chunk"}
-    assert not cfg.tie_embeddings and cfg.delta_block and cfg.gated_block
+    assert not cfg.tie_embeddings and cfg.gated_block
+    assert "linear_attention" in mechanisms_beyond(cfg)
     other = serve_gigachat.model_fields(
         {**config, "program_fields": {**config["program_fields"],
                                       "norm_gate": 0.0}})
@@ -299,7 +302,8 @@ def test_rehearsal_cut_keeps_both_kinds_of_layer():
     assert (cfg.kv_lora_rank, cfg.q_lora_rank, cfg.n_experts,
             cfg.experts_held, cfg.shared_ffn_dim, cfg.delta_chunk) \
         == (256, 24, 8, (2, 4), 32, 8)
-    assert cfg.delta_block and cfg.dtype == "float32"
+    assert "linear_attention" in mechanisms_beyond(cfg) \
+        and cfg.dtype == "float32"
     assert _config()["n_routed_experts"] == 16              # a copy
 
 
@@ -351,39 +355,32 @@ def test_traffic_file_holds_what_the_issue_names():
 
 
 def test_the_cell_and_its_metrics_are_in_the_benchmark():
-    """By name and by what an entry reads, never by place: a later PR
-    appends after them."""
-    from benchmark.selftest import _reading_of
+    """The cell and its configuration by name, its metrics by what an
+    entry reads (tests/_readings.py), never by an entry's name or place: a
+    later PR appends after them, a `benchmark` PR renames and folds."""
     bench = _bench()
     cell = next(w for w in bench["workloads"] if w["name"] == CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         CONFIG, "reason-delta", 1)
     assert len(cell["why"]) <= 200
-    entry = next(c for c in bench["configs"] if c["name"] == CONFIG)
-    assert entry["reduced"] == _config()["reduced"]
-    assert entry["file"] == f"benchmark/configs/{CONFIG}.json"
+    config = next(c for c in bench["configs"] if c["name"] == CONFIG)
+    assert config["reduced"] == _config()["reduced"]
+    assert config["file"] == f"benchmark/configs/{CONFIG}.json"
     assert len(bench["per_layer"]) <= 128 and len(OWN) <= 12
-    by_name = {m["name"]: m for m in bench["end_to_end"]
-               + bench["per_layer"]}
-    assert sorted(n for n in by_name if n.endswith(".gigachat")) \
-        == sorted(OWN)
     for name, (reader, cost) in OWN.items():
-        m = by_name[name]
-        assert m["workloads"] == [CELL] and m["moves"] == "out_tok_per_s"
-        got, args, moves = _reading_of(m)
-        assert (got, moves) == (reader, "out_tok_per_s")
-        assert json.loads(args).get("cost") == cost
-        if name.endswith("_roofline.gigachat"):
+        # the data file is the cell's own; the entry is the one that reads
+        # what it reads
+        spec = _load("metrics", name + ".json")
+        m, args, _ = entry(reader, CELL, **spec["args"])
+        assert (spec["reader"], args.get("cost")) == (reader, cost)
+        assert m["moves"] == "out_tok_per_s"
+        if reader == "gigachat_roofline":
             assert m["unit"] == "%" and m["better"] == "higher"
-    for name in ["out_tok_per_s"] + SHARED:
-        assert CELL in by_name[name]["workloads"], name
-    # the lists whose exact content tests/test_llm.py holds stay as they are
-    for name in ("chunk_tokens_a_step.kanana", "mixed_step_ms.reason",
-                 "mixed_step_time_pct.reason"):
-        assert CELL not in by_name[name]["workloads"], name
-    # one entry a reading: no cell in a bare entry AND in its held repeat
-    for name in ("decode_step_ms", "mixed_step_ms", "device_idle_pct"):
-        assert CELL not in by_name[name + ".mimo"]["workloads"]
+    assert CELL in next(m for m in bench["end_to_end"]
+                        if m["name"] == "out_tok_per_s")["workloads"]
+    # one entry a reading for the cell (entry() fails on two)
+    for reading in SHARED:
+        entry(reading, CELL)
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 0
 
 

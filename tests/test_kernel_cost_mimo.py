@@ -20,12 +20,15 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+from _readings import entry  # noqa: E402
 from benchmark import checks, checks_mimo, hold_mimo  # noqa: E402
 from benchmark import kernel_cost, kernel_cost_mimo as kc  # noqa: E402
 from benchmark import loadgen  # noqa: E402
 from benchmark.readers import (engine_clocks, mimo_counters,  # noqa: E402
                                mimo_roofline)
 from benchmark.runners import serve_kanana, serve_mimo  # noqa: E402
+
+CELL = "context-mimo-1chip"
 
 
 def _load(*path):
@@ -148,10 +151,10 @@ def test_the_readers_say_nothing_where_there_is_nothing_to_read():
                                    "stats_close": {"a": 2}}, args) is None
     # a program from before the block has none of the three counters
     old = {"stats_open": {"moe_pairs": 1}, "stats_close": {"moe_pairs": 5}}
-    for name in ("moe_absent_pct.mimo", "window_pages_held_pct.mimo"):
-        spec = _load("metrics", name + ".json")
-        assert spec["reader"] == "engine_clocks"
-        assert engine_clocks.read(dict(old), spec["args"]) is None
+    for reading in ("moe_absent_pct", "window_pages_held_pct"):
+        _, args, reader = entry(reading, CELL)
+        assert reader is engine_clocks
+        assert engine_clocks.read(dict(old), args) is None
 
 
 def test_counter_metrics_on_hand_counts():
@@ -169,8 +172,11 @@ def test_counter_metrics_on_hand_counts():
     assert read("moe_experts_hit_pct.mimo", mimo_counters) \
         == 100 * 720 / (10 * 6 * 16)
     assert read("moe_load_skew.mimo", mimo_counters) == 90 * 16 / 480
-    assert read("moe_absent_pct.mimo", engine_clocks) == 93.75
-    assert read("window_pages_held_pct.mimo", engine_clocks) == 2.5
+    # ... and the two the cell shares, found by what they read
+    assert engine_clocks.read(
+        dict(data), entry("moe_absent_pct", CELL)[1]) == 93.75
+    assert engine_clocks.read(
+        dict(data), entry("window_pages_held_pct", CELL)[1]) == 2.5
 
 
 def test_published_keys_map_to_the_programs_fields():

@@ -19,6 +19,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+from _readings import entry  # noqa: E402
 from benchmark import checks, checks_trinity, hold_trinity  # noqa: E402
 from benchmark import kernel_cost, kernel_cost_trinity as kc  # noqa: E402
 from benchmark import loadgen  # noqa: E402
@@ -175,64 +176,55 @@ def test_expert_work_is_all_128_experts():
     assert 3 * 2048 * 1024 * 128 * 2 == pytest.approx(1.61e9, rel=1e-2)
 
 
-#: the accepted metrics whose data files read this block as they read the
-#: block they were written for (the same reader, the same arguments): the
-#: cell joins their lists, since `per_layer` holds 128 entries at most
-SHARED_BY_SPEC = [f"{q}.mimo" for q in (
+#: what the accepted metrics read that this block is read by as the block
+#: they were written for is (the same reader, the same arguments; words of
+#: tests/_readings.py): the cell joins their lists, since `per_layer` holds
+#: 128 entries at most
+SHARED_BY_SPEC = [
     "decode_step_ms", "mixed_step_ms", "mixed_step_time_pct",
     "device_idle_pct", "engine_host_gap_ms", "idle_prep_pct",
     "paged_attn_time_pct", "window_attn_time_pct",
     "attn_window_proj_time_pct", "moe_ffn_time_pct",
-    "window_pages_held_pct")] + ["moe_shared_time_pct.kanana"]
-OWN = ["paged_attn_roofline.trinity", "window_attn_roofline.trinity",
-       "moe_ffn_roofline.trinity"]
+    "window_pages_held_pct", "moe_shared_time_pct"]
+#: the cell's own: the reader's `cost` argument of each
+OWN = ["full_attention", "window_attention", "moe_experts"]
 
 
 def test_the_cell_and_its_metrics_are_in_the_benchmark():
-    """The cell, its configuration and its metrics, found BY NAME and by
+    """The cell and its configuration, found by name, and its metrics by
     what an entry reads (reader, arguments, `moves`, the cell in its
-    `workloads`), never by their place in a list: a later PR appends its
-    own cell, configuration and entries after them."""
-    from benchmark.selftest import _reading_of
+    `workloads`: tests/_readings.py), never by an entry's name or place
+    in a list: a later PR appends its own cell, configuration and entries
+    after them, and a `benchmark` PR may rename and fold entries."""
     bench = _bench()
     cell = next(w for w in bench["workloads"] if w["name"] == CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "trinity-mini-serve-1chip", "mixed-window", 1)
     assert len(cell["why"]) <= 200
-    entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
-    assert entry["reduced"] == _config()["reduced"] == [
+    config = next(c for c in bench["configs"] if c["name"] == cell["config"])
+    assert config["reduced"] == _config()["reduced"] == [
         "num_hidden_layers", "num_dense_layers", "layer_types"]
     assert len(bench["per_layer"]) <= 128
-    by_name = {m["name"]: m for m in bench["end_to_end"]
-               + bench["per_layer"]}
-    costs = {"paged_attn_roofline.trinity": "full_attention",
-             "window_attn_roofline.trinity": "window_attention",
-             "moe_ffn_roofline.trinity": "moe_experts"}
-    assert sorted(costs) == sorted(OWN)
-    for name in OWN:
-        m = by_name[name]
-        assert m["workloads"] == [CELL] and m["moves"] == "out_tok_per_s"
-        assert m["unit"] == "%" and m in bench["per_layer"]
-        spec = _load("metrics", name + ".json")
-        assert spec["name"] == name
-        reader, args, moves = _reading_of(m)
-        assert (reader, json.loads(args)["cost"], moves) == (
-            "trinity_roofline", costs[name], "out_tok_per_s")
-    for name in ["out_tok_per_s", "replica_ready_s", "batch_occupancy_pct",
-                 "engine_host_ms", "chunk_rows_joined_pct"] + SHARED_BY_SPEC:
-        m = by_name[name]
-        assert CELL in m["workloads"], name
-        assert name not in SHARED_BY_SPEC \
-            or m["moves"] == "out_tok_per_s", name
+    for cost in OWN:
+        m, _, reader = entry("trinity_roofline", CELL, cost=cost)
+        assert reader is trinity_roofline
+        assert (m["moves"], m["unit"]) == ("out_tok_per_s", "%")
+    assert CELL in next(m for m in bench["end_to_end"]
+                        if m["name"] == "out_tok_per_s")["workloads"]
+    for reading in ["replica_ready_s", "batch_occupancy_pct",
+                    "engine_host_ms", "chunk_rows_joined_pct"]:
+        entry(reading, CELL)
+    for reading in SHARED_BY_SPEC:
+        assert entry(reading, CELL)[0]["moves"] == "out_tok_per_s", reading
     assert not any(w["chips"] == 4 for w in bench["workloads"])
 
 
 def test_the_readers_say_nothing_where_there_is_nothing_to_read():
     config = _config()
-    for name in OWN:
-        spec = _load("metrics", name + ".json")
-        assert spec["reader"] == "trinity_roofline"
-        assert trinity_roofline.read({"config": config}, spec["args"]) is None
+    for cost in OWN:
+        spec = {"args": entry("trinity_roofline", CELL, cost=cost)[1]}
+        assert trinity_roofline.read({"config": config},
+                                     spec["args"]) is None
 
         class NoKernel:
             def op_time(self, patterns):
@@ -243,12 +235,8 @@ def test_the_readers_say_nothing_where_there_is_nothing_to_read():
         assert trinity_roofline.read(data, spec["args"]) is None
     # the scopes the shared data files name are the program's, and so are
     # the two that no metric reads yet (PERF.md section 7)
-    for name, scope in (("moe_shared_time_pct.kanana", M.SCOPE_SHARED),
-                        ("attn_window_proj_time_pct.mimo",
-                         M.SCOPE_WINDOW_PROJ)):
-        spec = _load("metrics", name + ".json")
-        assert (spec["reader"], spec["args"]) == ("trace_scope",
-                                                  {"scope": scope})
+    for scope in (M.SCOPE_SHARED, M.SCOPE_WINDOW_PROJ):
+        assert entry("trace_scope", CELL, scope=scope)[1] == {"scope": scope}
     assert (M.SCOPE_GATE, M.SCOPE_HEAD) == ("attn_gate", "lm_head")
 
 

@@ -7,9 +7,6 @@ autoregressively on the full sequence each step — the engine's paged
 incremental path must reproduce its greedy choices exactly.
 """
 
-import importlib
-import json
-import os
 import re
 
 import jax
@@ -20,6 +17,7 @@ import pytest
 from _chunk_rows import (CASES, SHAPE_CASES, check, check_descriptor,
                          check_preempted, check_shapes, fields_of,
                          pin_full_shape, serve, shape_of)
+from _readings import entry
 from ray_tpu.llm import InferenceEngine
 from ray_tpu.llm import model as M
 from ray_tpu.llm.cache import PageAllocator
@@ -174,20 +172,19 @@ def test_token_booking_is_one_rule(params, learned, reason, where):
     assert eng._slots == [None] * eng.max_batch and not eng.running
 
 
-def _metric_patterns(metric_file):
+def _metric_patterns(reading):
     """The module-name patterns of one of the benchmark's trace metrics,
-    read from its file: the test follows the yardstick, not a copy."""
-    path = os.path.join(os.path.dirname(__file__), os.pardir, "benchmark",
-                        "metrics", metric_file)
-    with open(path, encoding="utf-8") as f:
-        return [re.compile(p) for p in json.load(f)["args"]["patterns"]]
+    read from the data file of the entry that reads it for reason-1chip:
+    the test follows the yardstick, not a copy."""
+    _, args, _ = entry(reading, "reason-1chip")
+    return [re.compile(p) for p in args["patterns"]]
 
 
 @pytest.mark.parametrize("tp", [1, 2], ids=["tp1", "tp2"])
 def test_step_program_names_are_the_benchmarks(params, tp):
     """The names the yardstick matches, pinned: the lowered module names
-    of the mixed step and the decode loop (benchmark/metrics/
-    mixed_step_ms*.json, decode_step_ms.*.json match them in the trace;
+    of the mixed step and the decode loop (the data files of the entries
+    that read trace_module match them in the trace;
     a renamed jit nulls four per-layer metrics in silence), the private
     attribute benchmark/replica.py reads, and the compile tracker's
     names for the step programs."""
@@ -211,9 +208,8 @@ def test_step_program_names_are_the_benchmarks(params, tp):
 
     layouts = {"ragged_step": eng._fns.step_layouts[eng.prefill_rows],
                "decode_loop": eng._fns.decode_layout}
-    for program, metric_file in (("ragged_step", "mixed_step_ms.json"),
-                                 ("decode_loop",
-                                  "decode_step_ms.reason.json")):
+    for program, metric_file in (("ragged_step", "mixed_step_ms"),
+                                 ("decode_loop", "decode_step_ms")):
         jit, statics = eng._fns.jits[program]
         desc = jnp.zeros(M.layout_size(layouts[program]), jnp.int32)
         text = jit.lower(eng.params, desc, eng.kv, eng._last,
@@ -382,20 +378,6 @@ def test_the_shapes_follow_from_prefill_rows(params, rows_1_and_2,
         assert out == rows_1_and_2[0].generate(p, 6)
 
 
-def _bench_entry(name):
-    """(BENCHMARK.json's per_layer list, the entry ``name``, its data
-    file, its reader module)."""
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "BENCHMARK.json")) as f:
-        bench = json.load(f)["per_layer"]
-    with open(os.path.join(root, "benchmark", "metrics",
-                           name + ".json")) as f:
-        spec = json.load(f)
-    assert spec["name"] == name
-    return bench, next(m for m in bench if m["name"] == name), spec, \
-        importlib.import_module("benchmark.readers." + spec["reader"])
-
-
 _CLOSED_LOOP = ["reason-1chip", "reason-moe-1chip", "reason-lfm2-1chip",
                 "context-kanana-1chip", "reason-granite-1chip",
                 "context-brumby-1chip", "context-mimo-1chip",
@@ -403,52 +385,60 @@ _CLOSED_LOOP = ["reason-1chip", "reason-moe-1chip", "reason-lfm2-1chip",
                 "reason-gigachat-1chip"]
 
 
-@pytest.mark.parametrize("name,cells,moves", [
-    ("mixed_small_shape_pct", _CLOSED_LOOP, "out_tok_per_s"),
-    ("mixed_small_shape_pct.chat", ["chat-1chip"], "tpot_p50_ms")])
-def test_the_small_shapes_metric_reads_the_counter(rows_1_and_2, name,
-                                                   cells, moves):
+def _read_for(reading, cells, moves, layer_of):
+    """(the reader's module, the data file's arguments) of ``reading``
+    (tests/_readings.py), held the same for every one of ``cells``: each
+    is IN the workloads of the one entry that reads it, which moves
+    ``moves`` off the program's counters, in the layer of the entry that
+    reads ``layer_of`` (for reason-1chip: every such entry has it)."""
+    found = [entry(reading, cell) for cell in cells]
+    layer = entry(layer_of, "reason-1chip")[0]["layer"]
+    for m, args, reader in found:
+        assert (m["moves"], m["source"], m["layer"]) \
+            == (moves, "program_counter", layer)
+        assert (args, reader) == found[0][1:]
+    return found[0][2], found[0][1]
+
+
+@pytest.mark.parametrize("cells,moves", [
+    (_CLOSED_LOOP, "out_tok_per_s"), (["chat-1chip"], "tpot_p50_ms")],
+    ids=["closed-loop", "chat"])
+def test_the_small_shapes_metric_reads_the_counter(rows_1_and_2, cells,
+                                                   moves):
     """The data file over a prompt of 45 served alone (rows of 16 + 16 in
     the full shape, then 13 in the one-row shape): half the mixed steps
     small; 0 on an engine with one shape (prefill_rows 1: the control
     cell); and a program without the counter (the parent commit, in the
     driver's traced run of it) reads nothing and does not raise."""
-    bench, entry, spec, reader = _bench_entry(name)
-    assert entry["workloads"] == cells and entry["moves"] == moves
-    assert entry["source"] == "program_counter"
-    assert entry["layer"] == next(
-        m for m in bench if m["name"] == "batch_occupancy_pct")["layer"]
+    reader, args = _read_for("mixed_small_shape_pct", cells, moves,
+                             "batch_occupancy_pct")
     for eng, want in zip(rows_1_and_2, (0.0, 50.0)):
         a = dict(eng.stats)
-        eng.generate([(11 * i + len(name)) % CFG.vocab_size
+        eng.generate([(11 * i + len(moves)) % CFG.vocab_size
                       for i in range(45)], 3)
         data = {"stats_open": a, "stats_close": dict(eng.stats),
                 "config": {}}
-        assert reader.read(data, spec["args"]) == pytest.approx(want)
+        assert reader.read(data, args) == pytest.approx(want)
     old = {k: {s: v for s, v in data[k].items()
                if s != "ragged_small_dispatches"}
            for k in ("stats_open", "stats_close")}
-    assert reader.read(dict(old, config={}), spec["args"]) is None
+    assert reader.read(dict(old, config={}), args) is None
 
 
-@pytest.mark.parametrize("name,like", [
-    ("mixed_step_ms.reason", "mixed_step_ms.granite"),
-    ("mixed_step_time_pct.reason", "mixed_step_time_pct.granite")])
-def test_the_claimed_cells_mixed_step_metrics_are_granites_readers(name,
-                                                                   like):
-    """reason-1chip had no reading of its mixed step: the two data files
-    are granite's readers and patterns under new names, so the parent's
-    trace gives both (both shapes are traces of one jit: one module
-    name, and the reading is a mean over the shapes run)."""
-    bench, entry, spec, _ = _bench_entry(name)
-    _, other, other_spec, _ = _bench_entry(like)
-    assert entry["workloads"] == ["reason-1chip"]
-    assert {k: entry[k] for k in ("unit", "better", "source", "layer",
-                                  "moves")} \
+@pytest.mark.parametrize("reading", ["mixed_step_ms",
+                                     "mixed_step_time_pct"])
+def test_the_claimed_cells_mixed_step_metrics_are_granites_readers(reading):
+    """reason-1chip's mixed step is read as granite's is: the same reader
+    and patterns, so the parent's trace gives both (both shapes are traces
+    of one jit: one module name, and the reading is a mean over the shapes
+    run)."""
+    mine, args, reader = entry(reading, "reason-1chip")
+    other, other_args, other_reader = entry(reading, "reason-granite-1chip")
+    assert {k: mine[k] for k in ("unit", "better", "source", "layer",
+                                 "moves")} \
         == {k: other[k] for k in ("unit", "better", "source", "layer",
                                   "moves")}
-    assert (spec["reader"], spec["args"]) \
-        == (other_spec["reader"], other_spec["args"])
+    assert (reader, args) == (other_reader, other_args)
 
 
 @pytest.mark.parametrize("name,cells,want,parent", [
@@ -457,8 +447,7 @@ def test_the_claimed_cells_mixed_step_metrics_are_granites_readers(name,
                                "mixed-trinity-1chip",
                                "reason-gigachat-1chip"],
      100.0 / 3, None),
-    ("chunk_tokens_a_step.kanana", ["context-kanana-1chip"], 45 / 2,
-     45 / 2)])
+    ("chunk_tokens_a_step", ["context-kanana-1chip"], 45 / 2, 45 / 2)])
 def test_the_deals_two_metrics_read_the_counters(rows_1_and_2, name, cells,
                                                  want, parent):
     """The benchmark's two data files over a prompt of 45 served alone
@@ -466,22 +455,19 @@ def test_the_deals_two_metrics_read_the_counters(rows_1_and_2, name, cells,
     step; and a program without the row counters (the parent commit, in
     the driver's traced run of it) reads nothing for the share and does
     not raise, and reads the tokens a step, whose keys are older."""
-    bench, entry, spec, reader = _bench_entry(name)
-    assert entry["workloads"] == cells and entry["moves"] == "out_tok_per_s"
-    assert entry["source"] == "program_counter"
-    assert entry["layer"] == next(
-        m for m in bench if m["name"] == "batch_occupancy_pct")["layer"]
+    reader, args = _read_for(name, cells, "out_tok_per_s",
+                             "batch_occupancy_pct")
     eng = rows_1_and_2[1]
     a = dict(eng.stats)
     # a prompt of its own a metric: the prefix cache holds the other's
     eng.generate([(9 * i + len(name)) % CFG.vocab_size for i in range(45)],
                  3)
     data = {"stats_open": a, "stats_close": dict(eng.stats), "config": {}}
-    assert reader.read(data, spec["args"]) == pytest.approx(want)
+    assert reader.read(data, args) == pytest.approx(want)
     old = {k: {s: v for s, v in data[k].items()
                if not s.startswith("chunk_rows")}
            for k in ("stats_open", "stats_close")}
-    got = reader.read(dict(old, config={}), spec["args"])
+    got = reader.read(dict(old, config={}), args)
     assert got == (None if parent is None else pytest.approx(parent))
 
 
@@ -496,12 +482,9 @@ def test_the_transfers_two_metrics_read_the_counters(rows_1_and_2, name,
     (the parent commit, in the driver's traced run of it) reads nothing
     for the first and does not raise, and reads the clock, which is
     older."""
-    bench, entry, spec, reader = _bench_entry(name)
-    assert entry["workloads"] == _CLOSED_LOOP
-    assert (entry["moves"], entry["better"], entry["source"]) \
-        == ("out_tok_per_s", "lower", "program_counter")
-    assert entry["layer"] == next(
-        m for m in bench if m["name"] == "engine_host_ms")["layer"]
+    reader, args = _read_for(name, _CLOSED_LOOP, "out_tok_per_s",
+                             "engine_host_ms")
+    assert entry(name, "reason-1chip")[0]["better"] == "lower"
     eng = rows_1_and_2[1]
     a = dict(eng.stats)
     eng.generate([(13 * i + len(name)) % CFG.vocab_size for i in range(45)],
@@ -513,10 +496,10 @@ def test_the_transfers_two_metrics_read_the_counters(rows_1_and_2, name,
     def expect(w):
         return w if not isinstance(w, str) else (b[w] - a[w]) / n * 1e-6
     data = {"stats_open": a, "stats_close": b, "config": {}}
-    assert reader.read(data, spec["args"]) == pytest.approx(expect(want))
+    assert reader.read(data, args) == pytest.approx(expect(want))
     old = {k: {s: v for s, v in data[k].items() if s != "h2d_arrays"}
            for k in ("stats_open", "stats_close")}
-    got = reader.read(dict(old, config={}), spec["args"])
+    got = reader.read(dict(old, config={}), args)
     assert got == (None if parent is None else pytest.approx(expect(parent)))
 
 

@@ -8,7 +8,7 @@ the booking. Float32 on the CPU, greedy: whatever the order of launches
 and bookings, every request's tokens, finish reason and cached tokens are
 those of the same engine held to one program at a time (``_run_ahead``
 False, the order every engine had before), in every block the engine
-serves (the tiny blocks of tests/test_llm_blocks_lowering.py), or
+serves (the tiny blocks of tests/_blocks.py), or
 something is booked wrong. The cases the late booking has to get right:
 a row that stops on EOS after its next program was packed, a pool that
 cannot serve the look-ahead, a copy-on-write admission beside a program
@@ -26,8 +26,8 @@ import pytest
 from ray_tpu.llm import model as M
 from ray_tpu.llm.cache import SCRATCH_PAGE
 from ray_tpu.llm.engine import InferenceEngine
-from ray_tpu.models.llama import LlamaConfig, init_params
-from test_llm_blocks_lowering import BLOCKS
+from ray_tpu.models.llama import init_params
+from _blocks import BLOCKS, config
 
 ENGINE = dict(page_size=8, total_pages=128, max_batch=4, max_seq_len=128,
               prefill_chunk=16, prefill_rows=2, decode_chunk=4)
@@ -36,7 +36,7 @@ COUNTERS = ("ahead_dispatches", "late_retired_rows", "ahead_drains")
 
 @functools.lru_cache(maxsize=None)
 def _block(block: str):
-    cfg = LlamaConfig.tiny(**{**BLOCKS[block], "dtype": jnp.float32})
+    cfg = config(block, dtype=jnp.float32)
     return cfg, init_params(cfg, jax.random.PRNGKey(0))
 
 

@@ -1,10 +1,9 @@
 """A block lowers to the code of its own fields and to no other block's.
 
-`LlamaConfig` is the configuration of several blocks (Mistral, OLMoE, LFM2,
-Kanana-2, granite-4.0-h, Brumby, MiMo-V2-Flash, Trinity-Mini), and one
-decoder body in llm/model.py follows its fields. A
-configuration that sets none of a block's fields must take none of that
-block's code: the tests here read the jaxprs of both step programs and of
+`LlamaConfig` is the configuration of several blocks (the rows of
+tests/_blocks.py:BLOCKS), and one decoder body in llm/model.py follows its
+fields. A configuration that sets none of a block's fields must take none
+of that block's code: the tests here read the jaxprs of both step programs and of
 the page copy, on the kernel path and on the reference path, and the
 parameter and pool trees, and look for what only another block brings.
 
@@ -39,74 +38,11 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import pytest  # noqa: E402
 
+from _blocks import BLOCKS, config  # noqa: E402
 from ray_tpu.llm import model as M  # noqa: E402
 from ray_tpu.llm import cache as C  # noqa: E402
 from ray_tpu.llm.cache import make_kv_cache  # noqa: E402
-from ray_tpu.models.llama import (LAYER_KINDS, LlamaConfig,  # noqa: E402
-                                  init_params)
-
-_LFM2_PATTERN = ["conv", "conv"] + ["full_attention", "conv", "conv",
-                                    "conv"] * 2
-BLOCKS = {
-    "mistral": dict(n_layers=2),
-    "olmoe": dict(n_layers=2, n_kv_heads=8, n_experts=8, experts_per_token=2,
-                  qk_norm=True, tie_embeddings=False),
-    "lfm2": dict(n_layers=10, n_heads=8, n_kv_heads=2, ffn_dim=32,
-                 dense_ffn_dim=96, n_dense_layers=2, n_experts=8,
-                 experts_per_token=2, norm_topk_prob=True,
-                 layer_types=_LFM2_PATTERN, qk_norm_per_head=True,
-                 router_score="sigmoid", router_bias=True, router_eps=1e-6),
-    "kanana": dict(n_layers=3, n_heads=4, n_kv_heads=4, ffn_dim=16,
-                   dense_ffn_dim=96, n_dense_layers=1, n_experts=8,
-                   experts_per_token=3, norm_topk_prob=True,
-                   router_score="sigmoid", router_bias=True,
-                   router_eps=1e-20, router_scale=2.448, kv_lora_rank=32,
-                   qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
-                   shared_ffn_dim=32, tie_embeddings=False),
-    "granite": dict(n_layers=8, n_heads=8, n_kv_heads=2, ffn_dim=96,
-                    layer_types=["mamba", "mamba", "full_attention",
-                                 "mamba"] * 2, ssm_heads=8, ssm_head_dim=16,
-                    ssm_state=16, ssm_chunk=8, rope=False,
-                    attn_scale=1 / 64, embed_scale=12.0,
-                    residual_scale=0.22, logits_divisor=8.0),
-    "brumby": dict(n_layers=2, n_heads=4, n_kv_heads=2, ffn_dim=96,
-                   layer_types=["retention"] * 2, qk_norm_per_head=True,
-                   tie_embeddings=False, retention_chunk=8),
-    "mimo": dict(n_layers=5, n_heads=8, n_kv_heads=2, window_kv_heads=4,
-                 ffn_dim=32, dense_ffn_dim=96, n_dense_layers=1, n_experts=16,
-                 experts_per_token=4, norm_topk_prob=True,
-                 router_score="sigmoid", router_bias=True,
-                 experts_held=(4, 8), tie_embeddings=False,
-                 layer_types=["full_attention", "sliding_attention"] * 2
-                 + ["full_attention"], score_head_dim=24, value_head_dim=16,
-                 rotary_dim=8, value_scale=0.707, sliding_window=16,
-                 window_rope_theta=1e4, attn_sink=True),
-    "trinity": dict(n_layers=5, n_heads=8, n_kv_heads=2, window_kv_heads=2,
-                    ffn_dim=32, dense_ffn_dim=96, n_dense_layers=1,
-                    n_experts=16, experts_per_token=4, norm_topk_prob=True,
-                    router_score="sigmoid", router_bias=True,
-                    router_eps=1e-20, router_scale=2.826, shared_ffn_dim=32,
-                    tie_embeddings=False,
-                    layer_types=["sliding_attention"] * 4
-                    + ["full_attention"], score_head_dim=16,
-                    value_head_dim=16, sliding_window=16,
-                    window_rope_theta=1e4, rope_theta=1e4,
-                    qk_norm_per_head=True, attn_gate=True, post_norms=True,
-                    full_rope=False, embed_scale=8.0),
-    "gigachat": dict(n_layers=5, n_heads=4, n_kv_heads=4, ffn_dim=32,
-                     dense_ffn_dim=96, n_dense_layers=1, n_experts=8,
-                     experts_per_token=3, norm_topk_prob=True,
-                     router_score="sigmoid", router_bias=True,
-                     router_eps=1e-20, router_scale=2.5, shared_ffn_dim=32,
-                     experts_held=(2, 4), tie_embeddings=False,
-                     layer_types=["linear_attention"] * 4
-                     + ["full_attention"], kv_lora_rank=32,
-                     qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
-                     q_lora_rank=24, rope_yarn=(8, 16, 32, 1, 1, 1),
-                     attn_scale=0.3, attn_gate=True, post_norms=True,
-                     norm_gate=2.0, ffn_clamp=10.0, delta_key_heads=2,
-                     delta_value_heads=4, delta_key_dim=8,
-                     delta_value_dim=16, delta_chunk=8)}
+from ray_tpu.models.llama import LAYER_KINDS, init_params  # noqa: E402
 
 #: what only a block's own fields may bring into a program's text or
 #: trees: named scopes, parameter leaves, and the shape of the pool
@@ -158,7 +94,7 @@ def traced(block: str) -> dict:
     the reference and the kernel path the pool's tree and the jaxprs of
     the mixed step, the decode loop and the page copy, of ``block`` at
     tiny widths."""
-    cfg = LlamaConfig.tiny(**BLOCKS[block])
+    cfg = config(block)
     params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
     out = {f"{block}.params": str(jax.tree.map(
         lambda a: (a.shape, str(a.dtype)), params))}
@@ -216,7 +152,7 @@ def test_a_block_takes_no_other_blocks_code(block):
     pool layout in a configuration that sets none of their fields (and no
     conv operator where no layer is one): its pool keeps a `v` leaf beside
     `k`, both per KV head."""
-    cfg = LlamaConfig.tiny(**BLOCKS[block])
+    cfg = config(block)
     texts = lowered(block)
     everything = "\n".join(texts.values())
     if "linear_attention" in cfg.layer_types:
@@ -304,7 +240,7 @@ PATTERNS = {
 
 @pytest.mark.parametrize("block", sorted(BLOCKS))
 def test_every_block_is_a_pattern_of_the_one_walk(block):
-    cfg = LlamaConfig.tiny(**BLOCKS[block])
+    cfg = config(block)
     assert M._pattern(cfg) == PATTERNS[block]
     # the walk finds a stack for every kind the pattern names, the Llama
     # tree's by the split of its flat leaves: the same arrays
@@ -334,7 +270,7 @@ def test_pool_descriptor_and_engine_follow_the_declared_state(block):
     predicate says whether there are any, and the prefix cache, the mixed
     step's descriptor and the engine's stats all follow it."""
     from ray_tpu.llm import InferenceEngine
-    cfg = LlamaConfig.tiny(**BLOCKS[block])
+    cfg = config(block)
     kinds = set(cfg.layer_types) or {"full_attention"}
     declared = {
         leaf: ((len(cfg.layers_of(kind)), 3 + 1) + of(cfg)[0],

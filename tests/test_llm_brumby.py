@@ -33,16 +33,17 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+from _blocks import (chunked_logits, reference_logits, run,  # noqa: E402
+                     seeded)
 from _chunk_rows import (check_descriptor,  # noqa: E402
                          check_state_keeps_one_row, SHAPE_CASES,
                          check_shapes, pin_full_shape)
-from benchmark import reference_brumby as ref  # noqa: E402
-from ray_tpu.llm import InferenceEngine, tp  # noqa: E402
+from ray_tpu.llm import InferenceEngine  # noqa: E402
 from ray_tpu.llm import model as M  # noqa: E402
 from ray_tpu.llm.cache import (RET_LEAF, RET_NORM_LEAF,  # noqa: E402
-                               make_kv_cache, prefix_cache_supported)
-from ray_tpu.models import llama  # noqa: E402
-from ray_tpu.models.llama import LlamaConfig, init_params  # noqa: E402
+                               prefix_cache_supported)
+from ray_tpu.models.llama import (LlamaConfig,  # noqa: E402
+                                  mechanisms_beyond)
 from ray_tpu.ops import retention  # noqa: E402
 
 TOL = 1e-4
@@ -55,71 +56,11 @@ ENGINE = dict(page_size=8, total_pages=64, max_batch=4, max_seq_len=128,
               prefill_chunk=16, prefill_rows=2, decode_chunk=4, seed=3)
 
 
-def _run(eng):
-    done = {}
-    for _ in range(400):
-        done.update(eng.step())
-        if not eng.has_work():
-            return done
-    raise AssertionError("engine did not drain")
-
-
-def _worst_gap(eng, cfg, prompt, served, pad_to=96):
-    got = ref.score_greedy(eng.params, ref.dims_of(cfg), list(prompt),
-                           list(served), pad_to)
-    return max(got["gap"])
-
-
-def _seeded(cfg, seed=5):
-    """Weights whose norms are not ones: ones would hide a norm that is
-    skipped or misplaced."""
-    params = init_params(cfg, jax.random.PRNGKey(seed))
-    for kind, stack in params["layers"].items():
-        for k in stack:
-            if k.endswith("norm"):
-                stack[k] = 1.0 + 0.5 * jax.random.normal(
-                    jax.random.PRNGKey(len(kind + k)), stack[k].shape)
-    return params
-
-
 @pytest.fixture(scope="module")
 def brumby():
     jax.clear_caches()
     cfg = LlamaConfig.tiny(**BRUMBY)
-    return cfg, InferenceEngine(cfg, _seeded(cfg), **ENGINE)
-
-
-def _reference_logits(params, cfg, tokens):
-    with jax.default_matmul_precision("highest"):
-        return ref.forward(params, jnp.asarray(tokens, jnp.int32),
-                           ref.dims_of(cfg))
-
-
-def _chunked_logits(cfg, params, prompt, chunk, slot=1, kv=None):
-    """The prompt through the mixed step's forward as ONE chunk row of at
-    most ``chunk`` tokens a step (behind two idle decode rows and before
-    padding), in slot ``slot``: (logits after the last chunk, the pool)."""
-    ps, pages, T, R = 8, 16, 2 + chunk + 3, 3
-    if kv is None:
-        kv = make_kv_cache(cfg, pages, ps, max_batch=3)
-    table = np.zeros((R, pages), np.int32)
-    for lo in range(0, len(prompt), chunk):
-        n = min(chunk, len(prompt) - lo)
-        tok, pos = np.zeros(T, np.int32), np.zeros(T, np.int32)
-        page, at = np.zeros(T, np.int32), np.zeros(T, np.int32)
-        state = np.full(T, 3, np.int32)
-        where = np.arange(lo, lo + n)
-        tok[2:2 + n], pos[2:2 + n] = prompt[lo:lo + n], where
-        state[2:2 + n] = slot
-        q_start = np.asarray([0, 1, 2], np.int32)
-        q_len = np.asarray([0, 0, n], np.int32)
-        kv_len = np.asarray([0, 0, lo + n], np.int32)
-        logits, kv, _ = M._ragged_logits(
-            params, *map(jnp.asarray, (tok, pos, page, at, table, q_start,
-                                       q_len, kv_len)), kv, cfg,
-            paged_impl="reference", max_q_len=chunk, decode_rows=2,
-            token_state=jnp.asarray(state))
-    return logits[2], kv
+    return cfg, InferenceEngine(cfg, seeded(cfg), **ENGINE)
 
 
 # ------------------------------------------------------ ops/retention.py
@@ -291,23 +232,7 @@ def test_param_tree_pool_and_pattern(brumby):
     assert report["state_bytes_per_slot"] == per_slot
     assert report["kv_bytes"] == 5 * per_slot
     assert M._pattern(cfg) == ([], [("retention", "dense")], 3)
-    assert cfg.beyond_llama_block and cfg.hybrid
-
-
-@pytest.mark.parametrize("n_prompt,n_new", [(40, 13), (5, 9), (16, 6)])
-def test_engine_chunked_prefill_and_decode_loop_match_reference(
-        brumby, n_prompt, n_new):
-    """A prompt of 40 in chunks of 16: the state crosses two chunk
-    boundaries between steps (and five retention blocks of 8 inside them),
-    then the decode loop carries it token by token; a prompt shorter than
-    one block; one that ends on a chunk's edge."""
-    cfg, eng = brumby
-    prompt = list(range(1, 1 + n_prompt))
-    served = eng.generate(prompt, n_new)
-    assert len(served) == n_new
-    assert _worst_gap(eng, cfg, prompt, served) < TOL
-    # no page copy: no prefix cache
-    assert eng.compiled_step_programs() <= eng._fns.program_budget - 1 == 3
+    assert "retention" in mechanisms_beyond(cfg) and cfg.hybrid
 
 
 def test_a_descriptor_holds_the_arrays_the_engine_packed_before():
@@ -315,7 +240,7 @@ def test_a_descriptor_holds_the_arrays_the_engine_packed_before():
     descriptor, the page fields ride as ever; every field the old
     packing's."""
     cfg = LlamaConfig.tiny(**BRUMBY)
-    params = _seeded(cfg)
+    params = seeded(cfg)
     check_descriptor(lambda **kw: InferenceEngine(
         cfg, params, **{**ENGINE, **kw}))
 
@@ -329,7 +254,7 @@ def shaped_and_full():
     """The same weights behind the set of mixed-step shapes and behind
     the full shape alone (what every step ran in before the set)."""
     cfg = LlamaConfig.tiny(**BRUMBY)
-    params = _seeded(cfg)
+    params = seeded(cfg)
     return [InferenceEngine(cfg, params, **ENGINE),
             pin_full_shape(InferenceEngine(cfg, params, **ENGINE))]
 
@@ -341,95 +266,6 @@ def test_a_mixed_step_runs_the_smallest_shape_that_holds_its_rows(
     row's slot and the scratch slot are addressed through token_state in
     either shape, and the tokens are the full shape's."""
     check_shapes(case, *shaped_and_full)
-
-
-def test_engine_mixed_batch_with_padding_rows_matches_reference(brumby):
-    """Four sequences of different lengths: two prompts' chunk rows in one
-    mixed step beside decode rows, idle slots and padding tokens, the mixed
-    step and the decode loop taking turns."""
-    cfg, eng = brumby
-    prompts = [list(range(3, 3 + n)) for n in (37, 9, 22)]
-    rids = [eng.add_request(p, n) for p, n in zip(prompts, (11, 7, 5))]
-    eng.step()
-    late = list(range(100, 119))
-    rids.append(eng.add_request(late, 6))
-    done = _run(eng)
-    for p, r in zip(prompts + [late], rids):
-        assert _worst_gap(eng, cfg, p, done[r]) < TOL
-
-
-@pytest.mark.parametrize("chunk", [7, 12, 16, 64])
-def test_the_same_prompt_at_four_chunk_sizes(brumby, chunk):
-    """LOGITS, not tokens: a prompt of 45 through the mixed step's forward
-    in chunks of 7 and 12 (a chunk boundary INSIDE a retention block of 8,
-    and between two steps), of 16 and whole, against the reference's full
-    forward at its last position."""
-    cfg, eng = brumby
-    prompt = list(range(9, 54))
-    want = _reference_logits(eng.params, cfg, prompt)[-1]
-    got, _ = _chunked_logits(cfg, eng.params, prompt, chunk)
-    assert float(jnp.abs(got - want).max()) < TOL
-
-
-def test_decode_rows_carry_the_state_token_by_token(brumby):
-    """LOGITS at every decoded position: a prompt of 19 as a chunk row, then
-    9 tokens one-token row by one-token row through the mixed step's
-    forward (the update's path), against the reference's full forward."""
-    cfg, eng = brumby
-    toks = list(range(20, 48))
-    want = _reference_logits(eng.params, cfg, toks)
-    _, kv = _chunked_logits(cfg, eng.params, toks[:19], 32, slot=1)
-    T = 2 + 4
-    for i in range(19, 28):
-        tok, pos = np.zeros(T, np.int32), np.zeros(T, np.int32)
-        state = np.full(T, 3, np.int32)
-        tok[1], pos[1], state[1] = toks[i], i, 1
-        logits, kv, _ = M._ragged_logits(
-            eng.params, *map(jnp.asarray, (
-                tok, pos, np.zeros(T, np.int32), np.zeros(T, np.int32),
-                np.zeros((3, 16), np.int32), np.asarray([0, 1, 2], np.int32),
-                np.asarray([0, 1, 0], np.int32),
-                np.asarray([0, i + 1, 0], np.int32))), kv, cfg,
-            paged_impl="reference", max_q_len=4, decode_rows=2,
-            token_state=jnp.asarray(state))
-        assert float(jnp.abs(logits[1] - want[i]).max()) < TOL
-
-
-def test_a_reused_slot_starts_from_zero_state(brumby):
-    """One slot, two sequences in turn: the second finds the first's state
-    in its slot (nothing zeroes it) and must not read it."""
-    cfg, eng = brumby
-    one = InferenceEngine(cfg, eng.params, **{**ENGINE, "max_batch": 1})
-    first, second = list(range(60, 85)), list(range(5, 23))
-    one.generate(first, 6)
-    for leaf in (RET_LEAF, RET_NORM_LEAF):
-        left = np.asarray(one.kv[leaf])[:, 0]
-        assert np.abs(left).max(axis=tuple(range(1, left.ndim))).min() > 0
-    before = one.stats["state_resets"]
-    served = one.generate(second, 9)
-    assert one.stats["state_resets"] == before + 1
-    assert _worst_gap(one, cfg, second, served) < TOL
-
-
-def test_engine_preemption_gives_the_uninterrupted_continuation():
-    """The host's page accounting stays as it is over pages that hold
-    nothing: a pool of 10 pages preempts, the sequence re-prefills from
-    position 0 (its slot's state unread) and continues as if never
-    stopped."""
-    cfg = LlamaConfig.tiny(**BRUMBY)
-    params = _seeded(cfg)
-    small = InferenceEngine(cfg, params, **{
-        **ENGINE, "page_size": 4, "total_pages": 10, "max_seq_len": 32})
-    roomy = InferenceEngine(cfg, params, **{
-        **ENGINE, "page_size": 4, "max_seq_len": 32})
-    prompts = [list(range(1, 9)), list(range(3, 11))]
-    rids = [small.add_request(p, 16) for p in prompts]
-    done = _run(small)
-    assert small.stats["preemptions"] >= 1
-    assert small.stats["state_resets"] >= len(prompts) + 1
-    for p, r in zip(prompts, rids):
-        assert done[r] == roomy.generate(p, 16)
-        assert _worst_gap(small, cfg, p, done[r], pad_to=32) < TOL
 
 
 def test_a_pool_with_no_paged_layer_admits_by_slots(brumby, caplog):
@@ -457,7 +293,7 @@ def test_a_pool_with_no_paged_layer_admits_by_slots(brumby, caplog):
     on.step()
     assert len(on.waiting) == 1 and all(s is not None for s in on._slots)
     assert on.allocator.num_free > on.max_pages_per_seq    # pages do not bind
-    done = _run(on)
+    done = run(on)
     assert set(rids) <= set(done)
     assert M.copy_page._cache_size() == copies   # never compiled, never run
     assert on.stats["preemptions"] == 0
@@ -475,10 +311,10 @@ def test_no_part_of_the_operator_is_left_out(brumby, leaf):
         jax.random.PRNGKey(1), stack[leaf].shape).astype(stack[leaf].dtype)
     other = {**eng.params, "layers": {**eng.params["layers"],
                                       "retention": stack}}
-    want = _reference_logits(other, cfg, prompt)[-1]
-    got, _ = _chunked_logits(cfg, other, prompt, 16)
+    want = reference_logits("brumby", other, cfg, prompt)[-1]
+    got, _ = chunked_logits(cfg, other, prompt, 16)
     assert float(jnp.abs(got - want).max()) < TOL
-    stale, _ = _chunked_logits(cfg, eng.params, prompt, 16)
+    stale, _ = chunked_logits(cfg, eng.params, prompt, 16)
     assert float(jnp.abs(stale - want).max()) > 100 * TOL
 
 
@@ -489,31 +325,20 @@ def test_the_scale_and_the_rotary_embedding_are_applied(brumby):
     tolerance far under TOL's room it still is)."""
     cfg, eng = brumby
     prompt = list(range(9, 40))
-    got, _ = _chunked_logits(cfg, eng.params, prompt, 16)
+    got, _ = chunked_logits(cfg, eng.params, prompt, 16)
     other = dataclasses.replace(cfg, rope_theta=cfg.rope_theta * 4)
-    want = _reference_logits(eng.params, other, prompt)[-1]
+    want = reference_logits("brumby", eng.params, other, prompt)[-1]
     assert float(jnp.abs(got - want).max()) > 100 * TOL
-    no_rope, _ = _chunked_logits(dataclasses.replace(cfg, rope=False),
+    no_rope, _ = chunked_logits(dataclasses.replace(cfg, rope=False),
                                  eng.params, prompt, 16)
     assert float(jnp.abs(no_rope - got).max()) > 100 * TOL
 
 
-def test_copy_page_leaves_both_state_leaves_alone(brumby):
-    cfg, eng = brumby
-    kv = make_kv_cache(cfg, 8, 8, max_batch=3)
-    kv = {k: jax.random.normal(jax.random.PRNGKey(i), x.shape).astype(
-        x.dtype) for i, (k, x) in enumerate(kv.items())}
-    want = {k: np.asarray(x) for k, x in kv.items()}
-    out = M.copy_page(kv, jnp.int32(1), jnp.int32(2))
-    for k, x in out.items():
-        assert np.array_equal(np.asarray(x), want[k]), k
-
-
 def test_config_refuses_what_is_not_built():
-    with pytest.raises(ValueError, match="routed experts or a latent"):
+    with pytest.raises(ValueError, match="retention.*beside experts"):
         LlamaConfig.tiny(**{**BRUMBY, "n_experts": 4,
                             "experts_per_token": 2})
-    with pytest.raises(ValueError, match="routed experts or a latent"):
+    with pytest.raises(ValueError, match="beside latent attention"):
         LlamaConfig.tiny(**{**BRUMBY, "kv_lora_rank": 32,
                             "qk_nope_head_dim": 8, "qk_rope_head_dim": 8,
                             "v_head_dim": 8, "qk_norm_per_head": False})
@@ -522,13 +347,3 @@ def test_config_refuses_what_is_not_built():
     with pytest.raises(ValueError, match="n_kv_heads to divide"):
         LlamaConfig.tiny(**{**BRUMBY, "n_heads": 8, "n_kv_heads": 3})
 
-
-def test_training_forward_and_tp_refuse_the_block_by_name():
-    cfg = LlamaConfig.tiny(**BRUMBY)
-    with pytest.raises(NotImplementedError, match="retention layers"):
-        llama.forward(init_params(cfg, jax.random.PRNGKey(0)),
-                      jnp.zeros((1, 8), jnp.int32), cfg)
-    with pytest.raises(NotImplementedError, match="retention layers"):
-        llama.param_specs(cfg)
-    with pytest.raises(NotImplementedError, match="retention layers"):
-        tp.validate_tp(cfg, 2)

@@ -32,9 +32,10 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+from _blocks import served_logits  # noqa: E402
 from _chunk_rows import check_state_keeps_one_row  # noqa: E402
 from benchmark import reference_gigachat as ref  # noqa: E402
-from ray_tpu.llm import InferenceEngine, tp  # noqa: E402
+from ray_tpu.llm import InferenceEngine  # noqa: E402
 from ray_tpu.llm import model as M  # noqa: E402
 from ray_tpu.llm.cache import (DELTA_CONV_LEAF, DELTA_LEAF,  # noqa: E402
                                SLOT_STATE, STATE_LEAVES, keeps_slot_state,
@@ -63,22 +64,6 @@ ENGINE = dict(page_size=8, total_pages=64, max_batch=4, max_seq_len=128,
               prefill_chunk=16, prefill_rows=2, decode_chunk=4, seed=3)
 
 
-def _run(eng):
-    done = {}
-    for _ in range(400):
-        done.update(eng.step())
-        if not eng.has_work():
-            return done
-    raise AssertionError("engine did not drain")
-
-
-def _worst_gap(eng, cfg, prompt, served, pad_to=96):
-    with jax.default_matmul_precision("highest"):
-        got = ref.score_greedy(eng.params, ref.dims_of(cfg), list(prompt),
-                               list(served), pad_to)
-    return max(got["gap"])
-
-
 @pytest.fixture(scope="module")
 def giga():
     jax.clear_caches()
@@ -91,37 +76,6 @@ def _reference_logits(params, cfg, tokens, **how):
     with jax.default_matmul_precision("highest"):
         return ref.forward(params, jnp.asarray(tokens, jnp.int32),
                            ref.dims_of(cfg), **how)[0]
-
-
-def _chunked_logits(cfg, params, prompt, chunk, slot=1, kv=None):
-    """The prompt through the mixed step's forward as ONE chunk row of at
-    most ``chunk`` tokens a step (behind two idle decode rows and before
-    padding), in slot ``slot``: (logits after each chunk, the pool)."""
-    ps, pages, T, R = 8, 16, 2 + chunk + 3, 3
-    if kv is None:
-        kv = make_kv_cache(cfg, pages, ps, max_batch=3)
-    table = np.zeros((R, pages), np.int32)
-    table[2, :pages - 1] = 1 + np.arange(pages - 1)
-    out = []
-    for lo in range(0, len(prompt), chunk):
-        n = min(chunk, len(prompt) - lo)
-        tok, pos = np.zeros(T, np.int32), np.zeros(T, np.int32)
-        page, at = np.zeros(T, np.int32), np.zeros(T, np.int32)
-        state = np.full(T, 3, np.int32)
-        where = np.arange(lo, lo + n)
-        tok[2:2 + n], pos[2:2 + n] = prompt[lo:lo + n], where
-        page[2:2 + n], at[2:2 + n] = 1 + where // ps, where % ps
-        state[2:2 + n] = slot
-        q_start = np.asarray([0, 1, 2], np.int32)
-        q_len = np.asarray([0, 0, n], np.int32)
-        kv_len = np.asarray([0, 0, lo + n], np.int32)
-        logits, kv, _ = M._ragged_logits(
-            params, *map(jnp.asarray, (tok, pos, page, at, table, q_start,
-                                       q_len, kv_len)), kv, cfg,
-            paged_impl="reference", max_q_len=chunk, decode_rows=2,
-            token_state=jnp.asarray(state))
-        out.append(logits[2])
-    return out, kv
 
 
 def test_param_tree_pool_and_pattern(giga):
@@ -170,106 +124,8 @@ def test_param_tree_pool_and_pattern(giga):
         [(DELTA, "moe")] * 3 + [("full_attention", "moe")], 1)
 
 
-@pytest.mark.parametrize("n_prompt,n_new", [(40, 13), (5, 9), (16, 6)])
-def test_engine_chunked_prefill_and_decode_loop_match_reference(
-        giga, n_prompt, n_new):
-    """A prompt of 40 in chunks of 16: the delta state, its conv inputs and
-    the latent pages cross two chunk boundaries (and five blocks of the
-    chunk form's 8), then the decode loop carries them token by token; a
-    prompt shorter than a chunk; one that ends on a chunk's edge."""
-    cfg, eng = giga
-    prompt = list(range(1, 1 + n_prompt))
-    served = eng.generate(prompt, n_new)
-    assert len(served) == n_new
-    assert _worst_gap(eng, cfg, prompt, served) < TOL
-    assert eng.compiled_step_programs() <= eng._fns.program_budget - 1
-
-
-@pytest.mark.parametrize("chunk", [4, 16, 64])
-def test_logits_of_a_prompt_over_several_chunks(giga, chunk):
-    """Logits, not tokens: after every chunk the row's last logits are the
-    reference's at that position, whatever the chunk."""
-    cfg, eng = giga
-    prompt = list(np.random.default_rng(1).integers(0, 128, 37))
-    want = _reference_logits(eng.params, cfg, prompt)
-    got, _ = _chunked_logits(cfg, eng.params, prompt, chunk)
-    for i, logits in enumerate(got):
-        at = min(len(prompt), (i + 1) * chunk) - 1
-        assert float(jnp.abs(logits - want[at]).max()) < TOL, (chunk, i)
-
-
-def test_decode_rows_continue_a_chunk_row(giga):
-    """Prefill as a chunk row, then one-token rows through the UPDATE form
-    (the mixed step's decode rows): logits of every step against the
-    reference's full forward."""
-    cfg, eng = giga
-    toks = list(np.random.default_rng(2).integers(0, 128, 30))
-    want = _reference_logits(eng.params, cfg, toks)
-    _, kv = _chunked_logits(cfg, eng.params, toks[:21], 32, slot=1)
-    ps, pages = 8, 16
-    table = np.zeros((3, pages), np.int32)
-    table[1, :pages - 1] = 1 + np.arange(pages - 1)
-    for t in range(21, 30):
-        T = 2 + 4
-        tok, pos = np.zeros(T, np.int32), np.zeros(T, np.int32)
-        page, at = np.zeros(T, np.int32), np.zeros(T, np.int32)
-        state = np.full(T, 3, np.int32)
-        tok[1], pos[1], page[1], at[1], state[1] = \
-            toks[t], t, 1 + t // ps, t % ps, 1
-        logits, kv, _ = M._ragged_logits(
-            eng.params, *map(jnp.asarray, (
-                tok, pos, page, at, table, np.asarray([0, 1, 2], np.int32),
-                np.asarray([0, 1, 0], np.int32),
-                np.asarray([0, t + 1, 0], np.int32))), kv, cfg,
-            paged_impl="reference", max_q_len=4, decode_rows=2,
-            token_state=jnp.asarray(state))
-        assert float(jnp.abs(logits[1] - want[t]).max()) < TOL, t
-
-
-def test_engine_mixed_batch_with_padding_rows_matches_reference(giga):
-    cfg, eng = giga
-    rng = np.random.default_rng(3)
-    prompts = [list(rng.integers(0, 128, n)) for n in (33, 9, 20)]
-    rids = [eng.add_request(p, n) for p, n in zip(prompts, (11, 7, 5))]
-    eng.step()
-    late = list(rng.integers(0, 128, 18))
-    rids.append(eng.add_request(late, 6))
-    done = _run(eng)
-    for rid, prompt in zip(rids, prompts + [late]):
-        assert _worst_gap(eng, cfg, prompt, done[rid]) < TOL
-
-
-def test_two_sequences_swap_a_slot(giga):
-    """A slot's second owner starts from zero state and zero conv inputs,
-    whatever the first left there: an engine of ONE slot serves two
-    sequences in turn, and the second is the reference's."""
-    cfg, eng = giga
-    one = InferenceEngine(cfg, eng.params, **{**ENGINE, "max_batch": 1})
-    rng = np.random.default_rng(4)
-    first, second = (list(rng.integers(0, 128, n)) for n in (27, 19))
-    a = one.add_request(first, 9)
-    b = one.add_request(second, 8)
-    done = _run(one)
-    assert float(jnp.abs(one.kv[DELTA_LEAF][:, 0]).max()) > 0
-    assert _worst_gap(one, cfg, first, done[a]) < TOL
-    assert _worst_gap(one, cfg, second, done[b]) < TOL
-    assert one.stats["state_resets"] == 2
-
-
 def test_a_sequence_that_prefills_alone_keeps_one_row_a_step(giga):
     check_state_keeps_one_row(giga[1])
-
-
-def test_copy_page_copies_the_latent_leaf_and_no_state(giga):
-    cfg, _ = giga
-    kv = make_kv_cache(cfg, 8, 8, max_batch=4)
-    kv = {k: jnp.arange(a.size, dtype=a.dtype).reshape(a.shape)
-          for k, a in kv.items()}
-    out = M._copy_page_body(dict(kv), jnp.int32(3), jnp.int32(5))
-    for leaf in (DELTA_LEAF, DELTA_CONV_LEAF):
-        assert np.array_equal(np.asarray(out[leaf]), np.asarray(kv[leaf]))
-    assert np.array_equal(np.asarray(out["k"][:, 5]),
-                          np.asarray(kv["k"][:, 3]))
 
 
 @pytest.mark.parametrize("fault", ref.FAULTS)
@@ -280,7 +136,7 @@ def test_no_part_is_left_out(giga, fault):
     in the norms."""
     cfg, eng = giga
     prompt = list(np.random.default_rng(6).integers(0, 128, 40))
-    got, _ = _chunked_logits(cfg, eng.params, prompt, 64)
+    got, _ = served_logits(cfg, eng.params, prompt, chunk=64)
     whole = _reference_logits(eng.params, cfg, prompt)[-1]
     broken = _reference_logits(eng.params, cfg, prompt, fault=fault)[-1]
     assert float(jnp.abs(got[-1] - whole).max()) < TOL
@@ -292,8 +148,8 @@ def test_the_clamp_is_seen(giga):
     cfg, eng = giga
     prompt = list(np.random.default_rng(6).integers(0, 128, 24))
     loose = dataclasses.replace(cfg, ffn_clamp=0.0)
-    a, _ = _chunked_logits(cfg, eng.params, prompt, 32)
-    b, _ = _chunked_logits(loose, eng.params, prompt, 32)
+    a, _ = served_logits(cfg, eng.params, prompt, chunk=32)
+    b, _ = served_logits(loose, eng.params, prompt, chunk=32)
     assert float(jnp.abs(a[-1] - b[-1]).max()) > 100 * TOL
 
 
@@ -365,7 +221,7 @@ def test_the_shares_add_up_to_the_uncut_layer(giga):
     assert sum(absent) == 3 * 23 * 3
 
 
-def test_config_refuses_half_a_delta_block():
+def test_config_refuses_half_a_delta_rule_block():
     with pytest.raises(ValueError, match="delta_key_heads"):
         LlamaConfig.tiny(n_layers=2, layer_types=[DELTA, DELTA])
     with pytest.raises(ValueError, match="layer_types names none"):
@@ -373,7 +229,7 @@ def test_config_refuses_half_a_delta_block():
                          delta_key_dim=8, delta_value_dim=8)
     with pytest.raises(ValueError, match="power of two"):
         LlamaConfig.tiny(**{**GIGA, "delta_chunk": 24})
-    with pytest.raises(ValueError, match="full_attention layers only"):
+    with pytest.raises(ValueError, match="linear_attention.*beside conv"):
         LlamaConfig.tiny(n_layers=2, layer_types=[DELTA, "conv"],
                          delta_key_heads=2, delta_value_heads=4,
                          delta_key_dim=8, delta_value_dim=8)
@@ -386,17 +242,3 @@ def test_config_refuses_half_a_delta_block():
         LlamaConfig.tiny(norm_gate=2.0)
 
 
-#: latent attention alone, every norm gated
-LATENT = dict(kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=8,
-              v_head_dim=8, norm_gate=2.0)
-
-
-@pytest.mark.parametrize("fields", [GIGA, LATENT, dict(ffn_clamp=10.0)])
-def test_training_forward_and_tp_refuse_the_block_by_name(fields):
-    cfg = LlamaConfig.tiny(**fields)
-    for refuse in (lambda: llama.forward({}, jnp.zeros((1, 4), jnp.int32),
-                                         cfg),
-                   lambda: llama.param_specs(cfg),
-                   lambda: tp.validate_tp(cfg, 2)):
-        with pytest.raises(NotImplementedError, match="linear_attention"):
-            refuse()

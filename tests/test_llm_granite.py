@@ -18,7 +18,6 @@ rotary embedding, a score scale of head_dim ** -0.5, a multiplier of 1.
 """
 
 import dataclasses
-import logging
 import os
 import sys
 
@@ -31,15 +30,14 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+from _blocks import chunked_logits, reference_logits, seeded  # noqa: E402
 from _chunk_rows import (check_descriptor,  # noqa: E402
                          check_state_keeps_one_row, SHAPE_CASES,
                          check_shapes, pin_full_shape)
-from benchmark import reference_granite as ref  # noqa: E402
-from ray_tpu.llm import InferenceEngine, tp  # noqa: E402
+from ray_tpu.llm import InferenceEngine  # noqa: E402
 from ray_tpu.llm import model as M  # noqa: E402
 from ray_tpu.llm.cache import (SSM_CONV_LEAF, SSM_LEAF,  # noqa: E402
                                make_kv_cache, prefix_cache_supported)
-from ray_tpu.models import llama  # noqa: E402
 from ray_tpu.models.llama import LlamaConfig, init_params  # noqa: E402
 from ray_tpu.ops import ssm  # noqa: E402
 
@@ -54,73 +52,11 @@ ENGINE = dict(page_size=8, total_pages=64, max_batch=4, max_seq_len=128,
               prefill_chunk=16, prefill_rows=2, decode_chunk=4, seed=3)
 
 
-def _run(eng):
-    done = {}
-    for _ in range(400):
-        done.update(eng.step())
-        if not eng.has_work():
-            return done
-    raise AssertionError("engine did not drain")
-
-
-def _worst_gap(eng, cfg, prompt, served, pad_to=96):
-    got = ref.score_greedy(eng.params, ref.dims_of(cfg), list(prompt),
-                           list(served), pad_to)
-    return max(got["gap"])
-
-
-def _seeded(cfg, seed=5):
-    """Weights whose norms and D are not ones: ones would hide a norm that
-    is skipped or misplaced, and a D that is left out would read as x."""
-    params = init_params(cfg, jax.random.PRNGKey(seed))
-    for kind, stack in params["layers"].items():
-        for k in stack:
-            if k.endswith("norm") or k == "D":
-                stack[k] = 1.0 + 0.5 * jax.random.normal(
-                    jax.random.PRNGKey(len(kind + k)), stack[k].shape)
-    return params
-
-
 @pytest.fixture(scope="module")
 def granite():
     jax.clear_caches()
     cfg = LlamaConfig.tiny(**GRANITE)
-    return cfg, InferenceEngine(cfg, _seeded(cfg), **ENGINE)
-
-
-def _reference_logits(params, cfg, tokens):
-    with jax.default_matmul_precision("highest"):
-        return ref.forward(params, jnp.asarray(tokens, jnp.int32),
-                           ref.dims_of(cfg))
-
-
-def _chunked_logits(cfg, params, prompt, chunk, slot=1, kv=None):
-    """The prompt through the mixed step's forward as ONE chunk row of at
-    most ``chunk`` tokens a step (behind two idle decode rows and before
-    padding), in slot ``slot``: (logits after the last chunk, the pool)."""
-    ps, pages, T, R = 8, 16, 2 + chunk + 3, 3
-    if kv is None:
-        kv = make_kv_cache(cfg, pages, ps, max_batch=3)
-    table = np.zeros((R, pages), np.int32)
-    table[2, :pages - 1] = 1 + np.arange(pages - 1)
-    for lo in range(0, len(prompt), chunk):
-        n = min(chunk, len(prompt) - lo)
-        tok, pos = np.zeros(T, np.int32), np.zeros(T, np.int32)
-        page, at = np.zeros(T, np.int32), np.zeros(T, np.int32)
-        state = np.full(T, 3, np.int32)
-        where = np.arange(lo, lo + n)
-        tok[2:2 + n], pos[2:2 + n] = prompt[lo:lo + n], where
-        page[2:2 + n], at[2:2 + n] = 1 + where // ps, where % ps
-        state[2:2 + n] = slot
-        q_start = np.asarray([0, 1, 2], np.int32)
-        q_len = np.asarray([0, 0, n], np.int32)
-        kv_len = np.asarray([0, 0, lo + n], np.int32)
-        logits, kv, _ = M._ragged_logits(
-            params, *map(jnp.asarray, (tok, pos, page, at, table, q_start,
-                                       q_len, kv_len)), kv, cfg,
-            paged_impl="reference", max_q_len=chunk, decode_rows=2,
-            token_state=jnp.asarray(state))
-    return logits[2], kv
+    return cfg, InferenceEngine(cfg, seeded(cfg), **ENGINE)
 
 
 # ----------------------------------------------------------- ops/ssm.py
@@ -279,28 +215,12 @@ def test_param_tree_pool_and_pattern(granite):
         ("mamba", "dense")], 2)
 
 
-@pytest.mark.parametrize("n_prompt,n_new", [(40, 13), (5, 9), (16, 6)])
-def test_engine_chunked_prefill_and_decode_loop_match_reference(
-        granite, n_prompt, n_new):
-    """A prompt of 40 in chunks of 16: both kinds of state cross two chunk
-    boundaries (and five blocks of the scan's 8), then the decode loop
-    carries them token by token; a prompt shorter than the conv's reach;
-    one that ends on a chunk's edge."""
-    cfg, eng = granite
-    prompt = list(range(1, 1 + n_prompt))
-    served = eng.generate(prompt, n_new)
-    assert len(served) == n_new
-    assert _worst_gap(eng, cfg, prompt, served) < TOL
-    # no page copy: no prefix cache
-    assert eng.compiled_step_programs() <= eng._fns.program_budget - 1 == 3
-
-
 def test_a_descriptor_holds_the_arrays_the_engine_packed_before():
     """State-space layers: token_state is a field of the descriptor, a
     sequence keeps one row a step, no prefix cache; every field the old
     packing's."""
     cfg = LlamaConfig.tiny(**GRANITE)
-    params = _seeded(cfg)
+    params = seeded(cfg)
     check_descriptor(lambda **kw: InferenceEngine(
         cfg, params, **{**ENGINE, **kw}))
 
@@ -314,7 +234,7 @@ def shaped_and_full():
     """The same weights behind the set of mixed-step shapes and behind
     the full shape alone (what every step ran in before the set)."""
     cfg = LlamaConfig.tiny(**GRANITE)
-    params = _seeded(cfg)
+    params = seeded(cfg)
     return [InferenceEngine(cfg, params, **ENGINE),
             pin_full_shape(InferenceEngine(cfg, params, **ENGINE))]
 
@@ -328,83 +248,6 @@ def test_a_mixed_step_runs_the_smallest_shape_that_holds_its_rows(
     check_shapes(case, *shaped_and_full)
 
 
-def test_engine_mixed_batch_with_padding_rows_matches_reference(granite):
-    """Four sequences of different lengths: chunk rows beside decode rows,
-    idle slots and padding tokens in the same steps, the mixed step and
-    the decode loop taking turns."""
-    cfg, eng = granite
-    prompts = [list(range(3, 3 + n)) for n in (37, 9, 22)]
-    rids = [eng.add_request(p, n) for p, n in zip(prompts, (11, 7, 5))]
-    eng.step()
-    late = list(range(100, 119))
-    rids.append(eng.add_request(late, 6))
-    done = _run(eng)
-    for p, r in zip(prompts + [late], rids):
-        assert _worst_gap(eng, cfg, p, done[r]) < TOL
-
-
-@pytest.mark.parametrize("chunk", [7, 16, 64])
-def test_the_same_prompt_at_three_chunk_sizes(granite, chunk):
-    """LOGITS, not tokens: a prompt of 45 through the mixed step's forward
-    in chunks of 7, of 16 and whole, against the reference's full forward
-    at its last position."""
-    cfg, eng = granite
-    prompt = list(range(9, 54))
-    want = _reference_logits(eng.params, cfg, prompt)[-1]
-    got, _ = _chunked_logits(cfg, eng.params, prompt, chunk)
-    assert float(jnp.abs(got - want).max()) < TOL
-
-
-def test_a_reused_slot_starts_from_zero_state(granite):
-    """One slot, two sequences in turn: the second finds the first's state
-    in its slot (nothing zeroes it) and must not read it."""
-    cfg, eng = granite
-    one = InferenceEngine(cfg, eng.params, **{**ENGINE, "max_batch": 1})
-    first, second = list(range(60, 85)), list(range(5, 23))
-    one.generate(first, 6)
-    for leaf in (SSM_LEAF, SSM_CONV_LEAF):
-        left = np.asarray(one.kv[leaf])[:, 0]
-        assert np.abs(left).max(axis=tuple(range(1, left.ndim))).min() > 0
-    before = one.stats["state_resets"]
-    served = one.generate(second, 9)
-    assert one.stats["state_resets"] == before + 1
-    assert _worst_gap(one, cfg, second, served) < TOL
-
-
-def test_engine_preemption_gives_the_uninterrupted_continuation():
-    cfg = LlamaConfig.tiny(**GRANITE)
-    params = _seeded(cfg)
-    small = InferenceEngine(cfg, params, **{
-        **ENGINE, "page_size": 4, "total_pages": 10, "max_seq_len": 32})
-    roomy = InferenceEngine(cfg, params, **{
-        **ENGINE, "page_size": 4, "max_seq_len": 32})
-    prompts = [list(range(1, 9)), list(range(3, 11))]
-    rids = [small.add_request(p, 16) for p in prompts]
-    done = _run(small)
-    assert small.stats["preemptions"] >= 1
-    # a re-prefill starts at position 0: its slot's state is not read
-    assert small.stats["state_resets"] >= len(prompts) + 1
-    for p, r in zip(prompts, rids):
-        assert done[r] == roomy.generate(p, 16)
-        assert _worst_gap(small, cfg, p, done[r], pad_to=32) < TOL
-
-
-def test_prefix_cache_takes_no_match_with_state_space_layers(granite,
-                                                             caplog):
-    cfg, eng = granite
-    with caplog.at_level(logging.WARNING, logger="ray_tpu.llm.engine"):
-        on = InferenceEngine(cfg, eng.params, **ENGINE, prefix_cache=True)
-    said = [r.message for r in caplog.records
-            if "prefix cache off" in r.message]
-    assert len(said) == 1 and str(eng.stats["state_bytes_per_slot"]) in said[0]
-    assert on.prefix is None
-    prompt = list(range(7, 7 + 32))              # four full pages
-    want = eng.generate(prompt, 7)
-    assert on.generate(prompt, 7) == want
-    assert on.generate(prompt, 7) == want
-    assert on.stats["cached_tokens"] == 0 and on.stats["cow_copies"] == 0
-
-
 @pytest.mark.parametrize("field,other", [
     ("embed_scale", 1.0), ("residual_scale", 1.0), ("logits_divisor", 1.0),
     ("attn_scale", 8 ** -0.5)])
@@ -416,16 +259,16 @@ def test_each_multiplier_is_told_apart_by_the_reference(granite, field,
     score scale at head_dim ** -0.5)."""
     cfg, eng = granite
     prompt = list(range(11, 40))
-    got, _ = _chunked_logits(cfg, eng.params, prompt, 16)
-    want = _reference_logits(eng.params, cfg, prompt)[-1]
+    got, _ = chunked_logits(cfg, eng.params, prompt, 16)
+    want = reference_logits("granite", eng.params, cfg, prompt)[-1]
     assert float(jnp.abs(got - want).max()) < TOL
-    wrong = _reference_logits(eng.params, dataclasses.replace(
+    wrong = reference_logits("granite", eng.params, dataclasses.replace(
         cfg, **{field: other}), prompt)[-1]
     assert float(jnp.abs(got - wrong).max()) > 10 * TOL
     # and the program follows the field: served with it at 1, it differs
     cfg2 = dataclasses.replace(cfg, **{field: 0.0 if field == "attn_scale"
                                        else other})
-    got2, _ = _chunked_logits(cfg2, eng.params, prompt, 16)
+    got2, _ = chunked_logits(cfg2, eng.params, prompt, 16)
     assert float(jnp.abs(got2 - got).max()) > 10 * TOL
     assert float(jnp.abs(got2 - wrong).max()) < TOL
 
@@ -435,10 +278,10 @@ def test_attention_applies_no_rotary_embedding(granite):
     same weights served WITH the rotary embedding are not."""
     cfg, eng = granite
     prompt = list(range(30, 62))
-    want = _reference_logits(eng.params, cfg, prompt)[-1]
-    got, _ = _chunked_logits(cfg, eng.params, prompt, 16)
+    want = reference_logits("granite", eng.params, cfg, prompt)[-1]
+    got, _ = chunked_logits(cfg, eng.params, prompt, 16)
     assert float(jnp.abs(got - want).max()) < TOL
-    roped, _ = _chunked_logits(dataclasses.replace(cfg, rope=True),
+    roped, _ = chunked_logits(dataclasses.replace(cfg, rope=True),
                                eng.params, prompt, 16)
     assert float(jnp.abs(roped - want).max()) > 5 * TOL
 
@@ -451,13 +294,13 @@ def test_no_part_of_the_operator_is_left_out(granite, leaf):
     not agree with what was served."""
     cfg, eng = granite
     prompt = list(range(2, 30))
-    got, _ = _chunked_logits(cfg, eng.params, prompt, 16)
+    got, _ = chunked_logits(cfg, eng.params, prompt, 16)
     stack = eng.params["layers"]["mamba"]
     flat = jnp.ones_like(stack[leaf]) if leaf == "gate_norm" \
         else jnp.zeros_like(stack[leaf])
     params = {**eng.params, "layers": {**eng.params["layers"], "mamba": {
         **stack, leaf: flat}}}
-    wrong = _reference_logits(params, cfg, prompt)[-1]
+    wrong = reference_logits("granite", params, cfg, prompt)[-1]
     assert float(jnp.abs(got - wrong).max()) > 10 * TOL
 
 
@@ -468,21 +311,9 @@ def test_lane_padded_pool_keeps_the_configured_scale(granite):
     prompt = list(range(20, 55))
     kv = make_kv_cache(cfg, 16, 8, max_batch=3, lane_pad=True)
     assert kv["k"].shape[-1] == 128
-    padded, _ = _chunked_logits(cfg, eng.params, prompt, 16, kv=kv)
-    plain, _ = _chunked_logits(cfg, eng.params, prompt, 16)
+    padded, _ = chunked_logits(cfg, eng.params, prompt, 16, kv=kv)
+    plain, _ = chunked_logits(cfg, eng.params, prompt, 16)
     assert float(jnp.abs(padded - plain).max()) < 1e-6
-
-
-def test_copy_page_leaves_both_state_leaves_alone(granite):
-    cfg, _ = granite
-    kv = make_kv_cache(cfg, 8, 8, max_batch=4)
-    kv = {k: jnp.arange(a.size, dtype=a.dtype).reshape(a.shape)
-          for k, a in kv.items()}
-    out = M._copy_page_body(dict(kv), jnp.int32(3), jnp.int32(5))
-    for leaf in (SSM_LEAF, SSM_CONV_LEAF):
-        assert np.array_equal(np.asarray(out[leaf]), np.asarray(kv[leaf]))
-    assert np.array_equal(np.asarray(out["k"][:, 5]),
-                          np.asarray(kv["k"][:, 3]))
 
 
 # ------------------------------------------------------------ refusals
@@ -492,20 +323,7 @@ def test_config_refuses_half_a_state_space_block():
         LlamaConfig.tiny(n_layers=2, layer_types=["mamba", "mamba"])
     with pytest.raises(ValueError, match="layer_types names none"):
         LlamaConfig.tiny(ssm_state=16, ssm_heads=8, ssm_head_dim=16)
-    with pytest.raises(ValueError, match="routed experts"):
+    with pytest.raises(ValueError, match="mamba.*beside experts"):
         LlamaConfig.tiny(**{**GRANITE, "n_experts": 4,
                             "experts_per_token": 2})
 
-
-@pytest.mark.parametrize("fields", [
-    GRANITE, dict(rope=False), dict(attn_scale=1 / 64),
-    dict(embed_scale=12.0), dict(residual_scale=0.22),
-    dict(logits_divisor=8.0)])
-def test_training_forward_and_tp_refuse_the_block_by_name(fields):
-    cfg = LlamaConfig.tiny(**fields)
-    for refuse in (lambda: llama.forward({}, jnp.zeros((1, 4), jnp.int32),
-                                         cfg),
-                   lambda: llama.param_specs(cfg),
-                   lambda: tp.validate_tp(cfg, 2)):
-        with pytest.raises(NotImplementedError, match="mamba"):
-            refuse()

@@ -30,6 +30,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+from _blocks import seeded  # noqa: E402
 from _chunk_rows import (CASES, check, check_descriptor,  # noqa: E402
                          check_preempted, SHAPE_CASES, check_shapes,
                          pin_full_shape)
@@ -37,11 +38,10 @@ from benchmark import reference_kanana as ref  # noqa: E402
 from benchmark import reference_lfm2  # noqa: E402
 from ray_tpu.llm import InferenceEngine  # noqa: E402
 from ray_tpu.llm import model as M  # noqa: E402
-from ray_tpu.llm import tp as TP  # noqa: E402
 from ray_tpu.llm.cache import (kv_cache_tag, latent_row_width,  # noqa: E402
-                               make_kv_cache, prefix_cache_supported)
+                               make_kv_cache)
 from ray_tpu.models import llama  # noqa: E402
-from ray_tpu.models.llama import LlamaConfig, init_params  # noqa: E402
+from ray_tpu.models.llama import LlamaConfig  # noqa: E402
 from ray_tpu.ops import paged_attention as pa  # noqa: E402
 
 TOL = 1e-4
@@ -59,40 +59,11 @@ ENGINE = dict(page_size=8, total_pages=64, max_batch=4, max_seq_len=128,
               prefill_chunk=16, prefill_rows=2, decode_chunk=4, seed=3)
 
 
-def _run(eng):
-    done = {}
-    for _ in range(400):
-        done.update(eng.step())
-        if not eng.has_work():
-            return done
-    raise AssertionError("engine did not drain")
-
-
-def _worst_gap(eng, cfg, prompt, served, pad_to=96):
-    """How far under the reference's top LOGIT the served tokens sit,
-    teacher-forced over prompt + served: logits, not token identity."""
-    got = ref.score_greedy(eng.params, ref.dims_of(cfg), list(prompt),
-                           list(served), pad_to)
-    return max(got["gap"])
-
-
-def _seeded(cfg, seed=5):
-    """Weights whose norms are not ones: norms of ones would hide a norm
-    that is skipped, misplaced or over the wrong part of the row."""
-    params = init_params(cfg, jax.random.PRNGKey(seed))
-    for kind, stack in params["layers"].items():
-        for k in stack:
-            if k.endswith("norm"):
-                stack[k] = 1.0 + 0.5 * jax.random.normal(
-                    jax.random.PRNGKey(len(kind + k)), stack[k].shape)
-    return params
-
-
 @pytest.fixture(scope="module")
 def kanana():
     jax.clear_caches()
     cfg = LlamaConfig.tiny(**KANANA)
-    return cfg, InferenceEngine(cfg, _seeded(cfg), **ENGINE)
+    return cfg, InferenceEngine(cfg, seeded(cfg), **ENGINE)
 
 
 # ------------------------------------------------------------ the operators
@@ -122,7 +93,7 @@ def test_adjacent_pair_rotary_is_a_complex_rotation():
 
 
 def _one_layer(cfg, S, seed=2):
-    params = _seeded(cfg, seed)
+    params = seeded(cfg, seed)
     lp = {k: w[0] for k, w in params["layers"]["attn"].items()}
     x = jax.random.normal(jax.random.PRNGKey(seed + 1), (1, S, cfg.dim))
     return lp, x.astype(cfg.dtype)
@@ -230,7 +201,7 @@ def test_shared_expert_counted_once_beside_the_scaled_routed_sum():
     layer: routed sum scaled by 2.448 + the shared expert ONCE; without
     the scale, or with the shared expert left out, it is off by tenths."""
     cfg = LlamaConfig.tiny(**KANANA)
-    params = _seeded(cfg)
+    params = seeded(cfg)
     moe = params["layers"]["moe"]
     lp = {k: w[1] for k, w in moe.items() if k not in M._EXPERT_LEAVES}
     experts = {k: moe[k] for k in M._EXPERT_LEAVES}
@@ -261,51 +232,11 @@ def test_shared_expert_counted_once_beside_the_scaled_routed_sum():
 
 # ------------------------------------------------- the served path, end to end
 
-def test_served_path_matches_the_reference_across_chunk_boundaries(kanana):
-    """Prefill in chunks of 16 (a prompt of 57 crosses three boundaries
-    and starts its last chunk mid-page), then decode through the latent
-    pool, several sequences sharing steps: every served token's logit in
-    the reference's full forward is its top logit to TOL."""
-    cfg, eng = kanana
-    rng = np.random.default_rng(0)
-    prompts = [rng.integers(0, 256, n).tolist() for n in (57, 9, 23, 40)]
-    rids = [eng.add_request(p, 12) for p in prompts]
-    done = _run(eng)
-    for p, r in zip(prompts, rids):
-        assert len(done[r]) == 12
-        assert _worst_gap(eng, cfg, p, done[r]) < TOL
-    assert eng.compiled_step_programs() <= eng._fns.program_budget == 4
-    assert eng.stats["moe_pairs"] > 0
-
-
-def test_prefix_hit_and_copy_on_write_on_the_latent_leaf(kanana):
-    """Pages are this block's only state, so the prefix cache is ON: the
-    same page-aligned prompt again takes a full hit, whose last token
-    lands inside a shared page (copy on write), and a longer prompt with
-    the same first pages takes a partial hit: both equal the reference."""
-    cfg, eng = kanana
-    assert prefix_cache_supported(cfg) and eng.prefix is not None
-    rng = np.random.default_rng(3)
-    base = rng.integers(0, 256, 32).tolist()          # 4 whole pages
-    first = eng.generate(base, 6)
-    before = dict(eng.stats)
-    again = eng.generate(base, 6)
-    assert eng.stats["cached_tokens"] - before["cached_tokens"] == 31
-    assert eng.stats["cow_copies"] == before["cow_copies"] + 1
-    assert again == first
-    assert _worst_gap(eng, cfg, base, again) < TOL
-    longer = base + rng.integers(0, 256, 13).tolist()
-    before = dict(eng.stats)
-    served = eng.generate(longer, 8)
-    assert eng.stats["cached_tokens"] - before["cached_tokens"] == 32
-    assert _worst_gap(eng, cfg, longer, served) < TOL
-
-
 @pytest.fixture(scope="module")
 def rows_1_and_2():
     """The same weights behind one chunk row a step and behind two."""
     cfg = LlamaConfig.tiny(**KANANA)
-    params = _seeded(cfg)
+    params = seeded(cfg)
     return [InferenceEngine(cfg, params, **{**ENGINE, "prefill_rows": n})
             for n in (1, 2)]
 
@@ -324,7 +255,7 @@ def shaped_and_full():
     """The same weights behind the set of mixed-step shapes and behind
     the full shape alone (what every step ran in before the set)."""
     cfg = LlamaConfig.tiny(**KANANA)
-    params = _seeded(cfg)
+    params = seeded(cfg)
     return [InferenceEngine(cfg, params, **ENGINE),
             pin_full_shape(InferenceEngine(cfg, params, **ENGINE))]
 
@@ -341,32 +272,16 @@ def test_a_descriptor_holds_the_arrays_the_engine_packed_before():
     """A latent pool (one leaf, no token_state): joined rows, a
     copy-on-write hit and a preemption, every field the old packing's."""
     cfg = LlamaConfig.tiny(**KANANA)
-    params = _seeded(cfg)
+    params = seeded(cfg)
     check_descriptor(lambda **kw: InferenceEngine(
         cfg, params, **{**ENGINE, **kw}))
 
 
 def test_a_preempted_sequences_re_prefill_takes_both_rows():
     cfg = LlamaConfig.tiny(**KANANA)
-    params = _seeded(cfg)
+    params = seeded(cfg)
     check_preempted(lambda **kw: InferenceEngine(
         cfg, params, **{**ENGINE, **kw}))
-
-
-def test_engine_preemption_gives_the_uninterrupted_continuation():
-    cfg = LlamaConfig.tiny(**KANANA)
-    params = _seeded(cfg)
-    small = InferenceEngine(cfg, params, **{
-        **ENGINE, "page_size": 4, "total_pages": 10, "max_seq_len": 32})
-    roomy = InferenceEngine(cfg, params, **{
-        **ENGINE, "page_size": 4, "max_seq_len": 32})
-    prompts = [list(range(1, 9)), list(range(3, 11))]
-    rids = [small.add_request(p, 16) for p in prompts]
-    done = _run(small)
-    assert small.stats["preemptions"] >= 1
-    for p, r in zip(prompts, rids):
-        assert done[r] == roomy.generate(p, 16)
-        assert _worst_gap(small, cfg, p, done[r], pad_to=32) < TOL
 
 
 # -------------------------------------------------------- pool, report, tags
@@ -400,28 +315,6 @@ def test_latent_pool_is_one_leaf_and_the_engine_reports_it(kanana):
 
 # ------------------------------------------------ what refuses the new fields
 
-@pytest.mark.parametrize("what", ["forward", "param_specs", "num_params",
-                                  "validate_tp"])
-def test_training_side_and_tp_refuse_the_block_by_name(what):
-    cfg = LlamaConfig.tiny(**KANANA)
-    params = None
-    if what == "validate_tp":
-        call = lambda: TP.validate_tp(cfg, 2)                  # noqa: E731
-    elif what == "forward":
-        call = lambda: llama.forward(                          # noqa: E731
-            params, jnp.zeros((1, 4), jnp.int32), cfg)
-    else:
-        call = lambda: getattr(llama, what)(cfg)               # noqa: E731
-    with pytest.raises(NotImplementedError, match="kv_lora_rank") as e:
-        call()
-    assert "shared_ffn_dim" in str(e.value)
-    shared_only = {k: v for k, v in KANANA.items() if k not in (
-        "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
-        "v_head_dim")}
-    with pytest.raises(NotImplementedError, match="shared_ffn_dim"):
-        TP.validate_tp(LlamaConfig.tiny(**shared_only), 2)
-
-
 @pytest.mark.parametrize("over,match", [
     (dict(kv_lora_rank=32), "qk_nope_head_dim"),
     (dict(kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=7,
@@ -430,10 +323,11 @@ def test_training_side_and_tp_refuse_the_block_by_name(what):
     (dict(v_head_dim=16), "kv_lora_rank"),
     (dict(shared_ffn_dim=32), "n_experts"),
     (dict(kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
-          v_head_dim=16, qk_norm=True), "qk_norm"),
+          v_head_dim=16, qk_norm=True), "latent attention.*beside qk_norm"),
     (dict(kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
           v_head_dim=16, n_layers=2,
-          layer_types=["conv", "full_attention"]), "conv layers")])
+          layer_types=["conv", "full_attention"]),
+     "latent attention.*beside .*conv layers")])
 def test_config_refuses_what_it_cannot_build(over, match):
     with pytest.raises(ValueError, match=match):
         LlamaConfig.tiny(**over)

@@ -16,7 +16,6 @@ vector where it is over each head (whole logits, or tenths).
 """
 
 import dataclasses
-import logging
 import os
 import sys
 
@@ -29,6 +28,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+from _blocks import seeded, worst_gap  # noqa: E402
 from _chunk_rows import (check_descriptor,  # noqa: E402
                          check_state_keeps_one_row, SHAPE_CASES,
                          check_shapes, pin_full_shape)
@@ -52,40 +52,13 @@ ENGINE = dict(page_size=8, total_pages=64, max_batch=4, max_seq_len=128,
               prefill_chunk=16, prefill_rows=2, decode_chunk=4, seed=3)
 
 
-def _run(eng):
-    done = {}
-    for _ in range(400):
-        done.update(eng.step())
-        if not eng.has_work():
-            return done
-    raise AssertionError("engine did not drain")
-
-
-def _worst_gap(eng, cfg, prompt, served, pad_to=96):
-    got = ref.score_greedy(eng.params, ref.dims_of(cfg), list(prompt),
-                           list(served), pad_to)
-    return max(got["gap"])
-
-
-def _seeded(cfg, seed=5):
-    """Weights whose norms are not ones: norms of ones would hide a norm
-    that is skipped, misplaced or over the wrong axis."""
-    params = init_params(cfg, jax.random.PRNGKey(seed))
-    for kind, stack in params["layers"].items():
-        for k in stack:
-            if k.endswith("norm"):
-                stack[k] = 1.0 + 0.5 * jax.random.normal(
-                    jax.random.PRNGKey(len(kind + k)), stack[k].shape)
-    return params
-
-
 @pytest.fixture(scope="module")
 def lfm2():
     # compiled_step_programs() counts the process's shared jits: whatever
     # file this worker ran before must not count against this engine
     jax.clear_caches()
     cfg = LlamaConfig.tiny(**LFM2)
-    return cfg, InferenceEngine(cfg, _seeded(cfg), **ENGINE)
+    return cfg, InferenceEngine(cfg, seeded(cfg), **ENGINE)
 
 
 # ------------------------------------------------------------- ops/moe.route
@@ -163,7 +136,7 @@ def test_conv_operator_over_a_ragged_batch(dtype, within):
     In bfloat16 the same comparison is NOT within TOL: the tolerance
     tells the precisions apart."""
     cfg = LlamaConfig.tiny(**{**LFM2, "dtype": dtype})
-    lp = {k: w[3] for k, w in _seeded(cfg)["layers"]["conv"].items()}
+    lp = {k: w[3] for k, w in seeded(cfg)["layers"]["conv"].items()}
     key = jax.random.PRNGKey(7)
     seqs = [jax.random.normal(jax.random.fold_in(key, i), (n, D))
             for i, n in enumerate((9, 6, 14, 5))]
@@ -254,24 +227,12 @@ def test_param_tree_and_pool_follow_the_pattern(lfm2):
         [("full_attention", "moe")] + [("conv", "moe")] * 3, 2)
 
 
-def test_engine_chunked_prefill_and_decode_loop_match_reference(lfm2):
-    """A prompt of 40 in chunks of 16: the conv state crosses two chunk
-    boundaries, then the decode loop carries it token by token."""
-    cfg, eng = lfm2
-    prompt = list(range(1, 41))
-    served = eng.generate(prompt, 13)
-    assert len(served) == 13
-    assert _worst_gap(eng, cfg, prompt, served) < TOL
-    # no page copy: no prefix cache
-    assert eng.compiled_step_programs() <= eng._fns.program_budget - 1 == 3
-
-
 def test_a_descriptor_holds_the_arrays_the_engine_packed_before():
     """Conv layers beside attention and experts: token_state is a field of
     the descriptor, a sequence keeps one row a step; every field the old
     packing's."""
     cfg = LlamaConfig.tiny(**LFM2)
-    params = _seeded(cfg)
+    params = seeded(cfg)
     check_descriptor(lambda **kw: InferenceEngine(
         cfg, params, **{**ENGINE, **kw}))
 
@@ -285,7 +246,7 @@ def shaped_and_full():
     """The same weights behind the set of mixed-step shapes and behind
     the full shape alone (what every step ran in before the set)."""
     cfg = LlamaConfig.tiny(**LFM2)
-    params = _seeded(cfg)
+    params = seeded(cfg)
     return [InferenceEngine(cfg, params, **ENGINE),
             pin_full_shape(InferenceEngine(cfg, params, **ENGINE))]
 
@@ -297,72 +258,6 @@ def test_a_mixed_step_runs_the_smallest_shape_that_holds_its_rows(
     experts: the tokens of the full shape alone, each step in the shape
     its deal asks for."""
     check_shapes(case, *shaped_and_full)
-
-
-def test_engine_mixed_batch_with_padding_rows_matches_reference(lfm2):
-    """Four sequences of different lengths: chunks beside decode rows,
-    idle slots and padding tokens in the same steps, and the mixed step
-    and the decode loop taking turns."""
-    cfg, eng = lfm2
-    prompts = [list(range(3, 3 + n)) for n in (37, 9, 22)]
-    rids = [eng.add_request(p, n) for p, n in zip(prompts, (11, 7, 5))]
-    eng.step()
-    late = list(range(100, 119))
-    rids.append(eng.add_request(late, 6))
-    done = _run(eng)
-    for p, r in zip(prompts + [late], rids):
-        assert _worst_gap(eng, cfg, p, done[r]) < TOL
-
-
-def test_engine_preemption_gives_the_uninterrupted_continuation():
-    cfg = LlamaConfig.tiny(**LFM2)
-    params = _seeded(cfg)
-    small = InferenceEngine(cfg, params, **{
-        **ENGINE, "page_size": 4, "total_pages": 10, "max_seq_len": 32})
-    roomy = InferenceEngine(cfg, params, **{
-        **ENGINE, "page_size": 4, "max_seq_len": 32})
-    prompts = [list(range(1, 9)), list(range(3, 11))]
-    rids = [small.add_request(p, 16) for p in prompts]
-    done = _run(small)
-    assert small.stats["preemptions"] >= 1
-    # a re-prefill starts at position 0: its slot's state is not read
-    assert small.stats["state_resets"] >= len(prompts) + 1
-    for p, r in zip(prompts, rids):
-        assert done[r] == roomy.generate(p, 16)
-        assert _worst_gap(small, cfg, p, done[r], pad_to=32) < TOL
-
-
-def test_a_reused_slot_starts_from_zero_state(lfm2):
-    """One slot, two sequences in turn: the second finds the first's
-    state in its slot and must not read it."""
-    cfg, eng = lfm2
-    one = InferenceEngine(cfg, eng.params, **{**ENGINE, "max_batch": 1})
-    first, second = list(range(60, 85)), list(range(5, 23))
-    one.generate(first, 6)
-    left = np.asarray(one.kv[STATE_LEAF])[:, 0]
-    assert np.abs(left).min(axis=(1, 2)).max() > 0    # state was left
-    before = one.stats["state_resets"]
-    served = one.generate(second, 9)
-    assert one.stats["state_resets"] == before + 1
-    assert _worst_gap(one, cfg, second, served) < TOL
-
-
-def test_prefix_cache_takes_no_match_with_conv_layers(lfm2, caplog):
-    """The same prompt twice with the prefix cache asked for: no hit is
-    taken (a hit would restore KV and not the conv state), the engine
-    says so once at start-up, and both runs match the run with it off."""
-    cfg, eng = lfm2
-    with caplog.at_level(logging.WARNING, logger="ray_tpu.llm.engine"):
-        on = InferenceEngine(cfg, eng.params, **ENGINE, prefix_cache=True)
-    assert sum("prefix cache off" in r.message for r in caplog.records) == 1
-    assert on.prefix is None
-    off = InferenceEngine(cfg, eng.params, **ENGINE, prefix_cache=False)
-    prompt = list(range(7, 7 + 32))              # four full pages
-    want = off.generate(prompt, 7)
-    assert on.generate(prompt, 7) == want
-    assert on.generate(prompt, 7) == want
-    assert on.stats["cached_tokens"] == 0 and on.stats["cow_copies"] == 0
-    assert _worst_gap(on, cfg, prompt, want) < TOL
 
 
 def test_step_counters_equal_the_references_routing(lfm2):
@@ -402,16 +297,16 @@ def test_each_new_field_is_told_apart_by_the_reference(lfm2, field, other):
     cfg, eng = lfm2
     prompt = list(range(11, 40))
     served = eng.generate(prompt, 9)
-    assert _worst_gap(eng, cfg, prompt, served) < TOL
+    assert worst_gap("lfm2", eng, prompt, served) < TOL
     if field in ("n_dense_layers", "layer_types"):
         # another tree: serve it, and score it against this one's fields
         over = {field: other}
         if field == "n_dense_layers":
             over.update(dense_ffn_dim=0, layer_types=PATTERN[2:] + PATTERN[:2])
         cfg2 = LlamaConfig.tiny(**{**LFM2, **over})
-        eng2 = InferenceEngine(cfg2, _seeded(cfg2), **ENGINE)
+        eng2 = InferenceEngine(cfg2, seeded(cfg2), **ENGINE)
         served2 = eng2.generate(prompt, 9)
-        assert _worst_gap(eng2, cfg2, prompt, served2) < TOL
+        assert worst_gap("lfm2", eng2, prompt, served2) < TOL
         return
     dims = ref.dims_of(dataclasses.replace(cfg, **{field: other}))
     params = eng.params
@@ -439,44 +334,10 @@ def test_lane_padded_pool_serves_the_same_tokens(lfm2):
     prompt = list(range(20, 55))
     served = padded.generate(prompt, 10)
     assert served == eng.generate(prompt, 10)
-    assert _worst_gap(padded, cfg, prompt, served) < TOL
-
-
-def test_copy_page_leaves_the_state_alone(lfm2):
-    cfg, _ = lfm2
-    kv = make_kv_cache(cfg, 8, 8, max_batch=4)
-    kv = {k: jnp.arange(a.size, dtype=a.dtype).reshape(a.shape)
-          for k, a in kv.items()}
-    state = np.asarray(kv[STATE_LEAF])
-    out = M._copy_page_body(dict(kv), jnp.int32(3), jnp.int32(5))
-    assert np.array_equal(np.asarray(out[STATE_LEAF]), state)
-    assert np.array_equal(np.asarray(out["k"][:, 5]),
-                          np.asarray(kv["k"][:, 3]))
+    assert worst_gap("lfm2", padded, prompt, served) < TOL
 
 
 # --------------------------------------------------------------- refusals
-
-def test_tp_refuses_the_block_with_a_reason():
-    from ray_tpu.llm.tp import validate_tp
-    cfg = LlamaConfig.tiny(**LFM2)
-    with pytest.raises(NotImplementedError, match="tp=2"):
-        validate_tp(dataclasses.replace(
-            cfg, n_experts=0, experts_per_token=0, n_dense_layers=0), 2)
-    with pytest.raises(NotImplementedError, match="qk_norm_per_head"):
-        validate_tp(LlamaConfig.tiny(n_kv_heads=8, qk_norm_per_head=True), 2)
-    with pytest.raises(NotImplementedError, match="layer_types"):
-        InferenceEngine(cfg, **ENGINE, tp=2)
-
-
-@pytest.mark.parametrize("what", ["forward", "param_specs", "num_params"])
-def test_training_side_refuses_the_block(what):
-    from ray_tpu.models import llama
-    cfg = LlamaConfig.tiny(**LFM2)
-    args = {"forward": ({}, jnp.zeros((1, 4), jnp.int32), cfg),
-            "param_specs": (cfg,), "num_params": (cfg,)}[what]
-    with pytest.raises(NotImplementedError, match="conv operator"):
-        getattr(llama, what)(*args)
-
 
 @pytest.mark.parametrize("over,match", [
     (dict(layer_types=["conv", "attention"], n_layers=2), "layer_types"),

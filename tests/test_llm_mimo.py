@@ -17,7 +17,6 @@ left out, a base of the compact table off by a page.
 """
 
 import dataclasses
-import logging
 import os
 import sys
 
@@ -30,15 +29,15 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+from _blocks import chunked_logits, seeded, worst_gap  # noqa: E402
 from benchmark import reference_mimo as ref  # noqa: E402
-from ray_tpu.llm import InferenceEngine, tp  # noqa: E402
+from ray_tpu.llm import InferenceEngine  # noqa: E402
 from ray_tpu.llm import model as M  # noqa: E402
-from ray_tpu.llm.cache import (WINDOW_LEAVES, kv_cache_tag,  # noqa: E402
-                               make_kv_cache, prefix_cache_supported,
+from ray_tpu.llm.cache import (kv_cache_tag, make_kv_cache,  # noqa: E402
                                window_first_page, window_group_pages,
                                window_table_width)
-from ray_tpu.models import llama  # noqa: E402
-from ray_tpu.models.llama import LlamaConfig, init_params  # noqa: E402
+from ray_tpu.models.llama import (LlamaConfig, init_params,  # noqa: E402
+                                  mechanisms_beyond)
 from ray_tpu.ops import moe  # noqa: E402
 from ray_tpu.ops.paged_attention import ragged_paged_attention  # noqa: E402
 
@@ -58,43 +57,14 @@ ENGINE = dict(page_size=PS, total_pages=64, max_batch=4, max_seq_len=128,
               prefill_chunk=16, prefill_rows=2, decode_chunk=4, seed=3)
 
 
-def _run(eng):
-    done = {}
-    for _ in range(400):
-        done.update(eng.step())
-        if not eng.has_work():
-            return done
-    raise AssertionError("engine did not drain")
-
-
-def _worst_gap(eng, cfg, prompt, served, pad_to=128):
-    got = ref.score_greedy(eng.params, ref.dims_of(cfg), list(prompt),
-                           list(served), pad_to)
-    return max(got["gap"])
-
-
-def _seeded(cfg, seed=5):
-    """Weights whose norms are not ones: ones would hide a norm that is
-    skipped or misplaced."""
-    params = init_params(cfg, jax.random.PRNGKey(seed))
-    for kind, stack in params["layers"].items():
-        for k in stack:
-            if k.endswith("norm"):
-                stack[k] = 1.0 + 0.5 * jax.random.normal(
-                    jax.random.PRNGKey(len(kind + k)), stack[k].shape)
-    return params
-
-
 @pytest.fixture(scope="module")
 def mimo():
     jax.clear_caches()
     cfg = LlamaConfig.tiny(**MIMO)
-    return cfg, InferenceEngine(cfg, _seeded(cfg), **ENGINE)
+    return cfg, InferenceEngine(cfg, seeded(cfg), **ENGINE)
 
 
 _forward = jax.jit(ref.forward, static_argnames=("dims", "hold", "fault"))
-_step = jax.jit(M._ragged_logits, static_argnames=(
-    "cfg", "paged_impl", "max_q_len", "decode_rows"))
 
 
 def _reference_logits(params, cfg, tokens, fault=None):
@@ -102,47 +72,6 @@ def _reference_logits(params, cfg, tokens, fault=None):
         return _forward(params, jnp.asarray(tokens, jnp.int32),
                         ref.dims_of(cfg), fault=fault)[0]
 
-
-#: pages of the hand-built window group: a RING, so that a logical page
-#: lands on a physical page an earlier one used (freed behind the window)
-RING = 6
-
-
-def _chunked_logits(cfg, params, prompt, chunk, kv=None, lo=0):
-    """``prompt[lo:]`` through the mixed step's forward as ONE chunk row of
-    at most ``chunk`` tokens a step (behind two idle decode rows and before
-    padding): (logits after the last chunk, the pool). The full group's
-    table names pages 1.. in order; the window group's is COMPACT (its
-    width the seam's) over a ring of RING pages."""
-    pages, T, R = 16, 2 + chunk + 3, 3
-    cols = window_table_width(W, chunk, PS)
-    assert cols <= RING + 1 or chunk > 16
-    ring = max(RING, cols)
-    if kv is None:
-        kv = make_kv_cache(cfg, pages + 1, PS, window_pages=ring + 1)
-    table = np.zeros((R, pages), np.int32)
-    table[2] = 1 + np.arange(pages)
-    for lo in range(lo, len(prompt), chunk):
-        n = min(chunk, len(prompt) - lo)
-        tok, pos = np.zeros(T, np.int32), np.zeros(T, np.int32)
-        page, at, wpage = (np.zeros(T, np.int32) for _ in range(3))
-        where = np.arange(lo, lo + n)
-        tok[2:2 + n], pos[2:2 + n] = prompt[lo:lo + n], where
-        page[2:2 + n], at[2:2 + n] = 1 + where // PS, where % PS
-        wpage[2:2 + n] = 1 + (where // PS) % ring
-        base = window_first_page(lo, W, PS)
-        wtable, wbase = np.zeros((R, cols), np.int32), np.zeros(R, np.int32)
-        wtable[2], wbase[2] = 1 + (base + np.arange(cols)) % ring, base
-        logits, kv, _ = _step(
-            params, *map(jnp.asarray, (
-                tok, pos, page, at, table, np.asarray([0, 1, 2], np.int32),
-                np.asarray([0, 0, n], np.int32),
-                np.asarray([0, 0, lo + n], np.int32))), kv, cfg,
-            paged_impl="reference", max_q_len=chunk, decode_rows=2,
-            token_page_win=jnp.asarray(wpage),
-            page_table_win=jnp.asarray(wtable),
-            page_base_win=jnp.asarray(wbase))
-    return logits[2], kv
 
 
 # ----------------------------------------------------------- the kernel
@@ -258,7 +187,7 @@ def test_param_tree_pool_and_pattern(mimo):
         window_pages=3, lane_pad=True)
     assert padded["k"].shape[-1] == padded["k_win"].shape[-1] == 256
     assert padded["v"].shape[-1] == padded["v_win"].shape[-1] == 128
-    assert cfg.beyond_llama_block and cfg.hybrid
+    assert WIN in mechanisms_beyond(cfg) and cfg.hybrid
     assert M.step_counters(cfg) == moe.COUNTERS + ("moe_absent",)
     assert kv_cache_tag(cfg, None) == "float32-k24v16-window16x4"
     # the descriptor carries the second table and its base, at a width
@@ -276,70 +205,11 @@ def test_param_tree_pool_and_pattern(mimo):
 
 # ------------------------------------------------------------ the engine
 
-@pytest.mark.parametrize("n_prompt,n_new", [(70, 13), (5, 20), (16, 6)])
-def test_engine_chunked_prefill_and_decode_loop_match_reference(
-        mimo, n_prompt, n_new):
-    """A prompt of 70 in chunk rows of 16, two a step: far past the window
-    and the ring of freed pages, then the decode loop; a prompt shorter
-    than the window whose decode crosses it; one that ends on a chunk's
-    edge."""
-    cfg, eng = mimo
-    prompt = list(range(1, 1 + n_prompt))
-    freed = eng.stats["window_pages_freed"]
-    served = eng.generate(prompt, n_new)
-    assert len(served) == n_new
-    assert _worst_gap(eng, cfg, prompt, served) < TOL
-    # all pages are back, and of the pages one lifetime holds the window
-    # group freed all but the window's
-    assert eng.window_allocator.num_free \
-        == eng.window_allocator.total_pages - 1
-    assert eng.allocator.num_free == eng.allocator.total_pages - 1
-    if n_prompt == 70:
-        assert eng.stats["window_pages_freed"] - freed >= 70 // PS - 3
-    # no page copy: no prefix cache
-    assert eng.prefix is None
-    assert eng.compiled_step_programs() <= eng._fns.program_budget - 1 == 3
-
-
-def test_engine_mixed_batch_with_padding_rows_matches_reference(mimo):
-    """Four sequences of different lengths: two prompts' chunk rows in one
-    mixed step beside decode rows, idle slots and padding tokens, the mixed
-    step and the decode loop taking turns."""
-    cfg, eng = mimo
-    prompts = [list(range(3, 3 + n)) for n in (37, 9, 52)]
-    rids = [eng.add_request(p, n) for p, n in zip(prompts, (11, 27, 5))]
-    eng.step()
-    late = list(range(100, 119))
-    rids.append(eng.add_request(late, 6))
-    absent = eng.stats["moe_absent"], eng.stats["moe_pairs"]
-    done = _run(eng)
-    for p, r in zip(prompts + [late], rids):
-        assert _worst_gap(eng, cfg, p, done[r]) < TOL
-    # the share: both kinds of pair were counted (8 of 16 experts held)
-    assert eng.stats["moe_absent"] > absent[0]
-    assert eng.stats["moe_pairs"] > absent[1]
-    assert eng.stats["page_steps_window"] < eng.stats["page_steps_full"]
-
-
-@pytest.mark.parametrize("chunk", [7, 12, 16, 64])
-def test_the_same_prompt_at_four_chunk_sizes(mimo, chunk):
-    """LOGITS, not tokens: a prompt of 61 through the mixed step's forward
-    in chunks of 7 and 12 (a chunk boundary inside a page, a window that
-    starts inside one), of 16 and whole, the window group a ring of pages
-    written over and over, against the reference's full forward at its last
-    position."""
-    cfg, eng = mimo
-    prompt = list(range(9, 70))
-    want = _reference_logits(eng.params, cfg, prompt)[-1]
-    got, _ = _chunked_logits(cfg, eng.params, prompt, chunk)
-    assert float(jnp.abs(got - want).max()) < TOL
-
-
 def test_window_pages_are_freed_and_reused_while_the_first_still_decodes():
     """One long sequence decodes on; what it frees behind its window a
     second sequence takes, in a group too small to hold both lifetimes."""
     cfg = LlamaConfig.tiny(**MIMO)
-    eng = InferenceEngine(cfg, _seeded(cfg), **{**ENGINE, "max_batch": 2})
+    eng = InferenceEngine(cfg, seeded(cfg), **{**ENGINE, "max_batch": 2})
     long, short = list(range(1, 61)), list(range(70, 100))
     first = eng.add_request(long, 40)
     held = []
@@ -366,72 +236,8 @@ def test_window_pages_are_freed_and_reused_while_the_first_still_decodes():
     assert eng.stats["window_pages_freed"] > 10
     assert pages & (set(range(1, eng.window_allocator.total_pages)) - used)
     del freed_by_first
-    assert _worst_gap(eng, cfg, long, done[first]) < TOL
-    assert _worst_gap(eng, cfg, short, done[second]) < TOL
-
-
-def test_engine_preemption_gives_the_uninterrupted_continuation():
-    """A full group of 10 pages preempts: the sequence gives back its pages
-    of BOTH groups, re-prefills from position 0 and continues as if never
-    stopped."""
-    cfg = LlamaConfig.tiny(**MIMO)
-    params = _seeded(cfg)
-    how = {**ENGINE, "page_size": 4, "max_seq_len": 32}
-    small = InferenceEngine(cfg, params, **{**how, "total_pages": 10})
-    roomy = InferenceEngine(cfg, params, **how)
-    prompts = [list(range(1, 9)), list(range(3, 11))]
-    rids = [small.add_request(p, 16) for p in prompts]
-    done = _run(small)
-    assert small.stats["preemptions"] >= 1
-    for p, r in zip(prompts, rids):
-        assert done[r] == roomy.generate(p, 16)
-        assert _worst_gap(small, cfg, p, done[r], pad_to=32) < TOL
-    assert small.window_allocator.num_free \
-        == small.window_allocator.total_pages - 1
-
-
-def test_a_reused_slot_starts_its_compact_table_at_base_zero(mimo):
-    """One slot, two sequences in turn: the first leaves the slot with a
-    base far from 0; the freed slot's table is the scratch page from base 0
-    (a free slot still decodes), and the second starts there."""
-    cfg, eng = mimo
-    one = InferenceEngine(cfg, eng.params, **{**ENGINE, "max_batch": 1})
-    first, second = list(range(40, 105)), list(range(5, 23))
-    rid = one.add_request(first, 6)
-    bases = []
-    while one.has_work():
-        one.step()
-        bases.append(int(one._page_base_win[0]))
-    del rid
-    assert max(bases) >= window_first_page(64, W, PS)
-    assert one._page_base_win[0] == 0 and not one._page_table_win.any()
-    served = one.generate(second, 9)
-    assert _worst_gap(one, cfg, second, served) < TOL
-
-
-def test_no_prefix_cache_and_no_page_copy_with_a_window_group(mimo, caplog):
-    cfg, eng = mimo
-    assert not prefix_cache_supported(cfg)
-    with caplog.at_level(logging.WARNING, logger="ray_tpu.llm.engine"):
-        on = InferenceEngine(cfg, eng.params, **ENGINE, prefix_cache=True)
-    said = [r.message for r in caplog.records
-            if "prefix cache off" in r.message]
-    assert len(said) == 1 and "window" in said[0] and on.prefix is None
-    prompt = list(range(7, 7 + 32))              # four full pages
-    want = eng.generate(prompt, 7)
-    assert on.generate(prompt, 7) == want
-    assert on.generate(prompt, 7) == want
-    assert on.stats["cached_tokens"] == 0 and on.stats["cow_copies"] == 0
-    # the page copy leaves the second group's leaves whole
-    kv = {k: jax.random.normal(jax.random.PRNGKey(i), x.shape).astype(
-        x.dtype) for i, (k, x) in enumerate(make_kv_cache(
-            cfg, 8, PS, window_pages=5).items())}
-    before = {k: np.asarray(x) for k, x in kv.items()}
-    out = M.copy_page(kv, jnp.int32(1), jnp.int32(2))
-    assert set(out) == {"k", "v", *WINDOW_LEAVES}
-    for k in WINDOW_LEAVES:
-        assert np.array_equal(np.asarray(out[k]), before[k])
-    assert np.array_equal(np.asarray(out["k"])[:, 2], before["k"][:, 1])
+    assert worst_gap("mimo", eng, long, done[first]) < TOL
+    assert worst_gap("mimo", eng, short, done[second]) < TOL
 
 
 # ------------------------------------------- nothing may be left out
@@ -449,9 +255,9 @@ def test_no_leaf_of_either_operator_is_left_out(mimo, kind, leaf):
         jax.random.PRNGKey(1), stack[leaf].shape).astype(stack[leaf].dtype)
     other = {**eng.params, "layers": {**eng.params["layers"], kind: stack}}
     want = _reference_logits(other, cfg, prompt)[-1]
-    got, _ = _chunked_logits(cfg, other, prompt, 16)
+    got, _ = chunked_logits(cfg, other, prompt, 16)
     assert float(jnp.abs(got - want).max()) < TOL
-    stale, _ = _chunked_logits(cfg, eng.params, prompt, 16)
+    stale, _ = chunked_logits(cfg, eng.params, prompt, 16)
     assert float(jnp.abs(stale - want).max()) > 100 * TOL
 
 
@@ -467,7 +273,7 @@ def test_no_scalar_of_either_operator_is_left_out(mimo, change):
     prompt = list(range(9, 50))
     other = dataclasses.replace(cfg, **change)
     params = eng.params
-    got, _ = _chunked_logits(other, params, prompt, 16)
+    got, _ = chunked_logits(other, params, prompt, 16)
     want = _reference_logits(params, other, prompt)[-1]
     assert float(jnp.abs(got - want).max()) < TOL
     old = _reference_logits(eng.params, cfg, prompt)[-1]
@@ -563,21 +369,21 @@ def test_config_refuses_what_is_not_built():
         tiny(window_kv_heads=3)
     with pytest.raises(ValueError, match="window_kv_heads that divide"):
         tiny(sliding_window=0)
-    with pytest.raises(ValueError, match="full_attention layers only"):
+    with pytest.raises(ValueError, match="sliding_attention.*beside conv"):
         tiny(layer_types=[FULL, WIN, FULL, WIN, "conv"])
     # a norm over the whole projected vector stays refused beside window
     # layers; the norm over each head is served since PR 49 (compared with
     # its reference in tests/test_llm_trinity.py), a head's weight as wide
     # as the score head in both stacks
-    with pytest.raises(ValueError, match="full_attention layers only"):
+    with pytest.raises(ValueError, match="sliding_attention.*beside qk_norm"):
         tiny(qk_norm=True, score_head_dim=0, value_head_dim=0, rotary_dim=0)
     served = jax.eval_shape(lambda: init_params(
         tiny(qk_norm_per_head=True), jax.random.PRNGKey(0)))["layers"]
     assert served["attn"]["q_norm"].shape == (3, 24)
     assert served["attn_window"]["k_norm"].shape == (2, 24)
-    with pytest.raises(ValueError, match="full_attention layers only"):
+    with pytest.raises(ValueError, match="sliding_attention.*beside no positions"):
         tiny(rope=False)
-    with pytest.raises(ValueError, match="full_attention layers only"):
+    with pytest.raises(ValueError, match="retention layers: not built beside sliding_att"):
         tiny(layer_types=[FULL, WIN, FULL, WIN, "retention"], n_experts=0,
              experts_per_token=0, n_dense_layers=0, experts_held=(),
              router_bias=False)
@@ -589,7 +395,7 @@ def test_config_refuses_what_is_not_built():
         tiny(experts_held=(12, 8))
     with pytest.raises(ValueError, match="chip's share"):
         LlamaConfig.tiny(dim=64, experts_held=(0, 2))
-    with pytest.raises(ValueError, match="not a latent pool"):
+    with pytest.raises(ValueError, match="head widths.*beside latent attention"):
         LlamaConfig.tiny(dim=64, value_scale=0.5, kv_lora_rank=32,
                          qk_nope_head_dim=8, qk_rope_head_dim=8,
                          v_head_dim=8)
@@ -599,17 +405,3 @@ def test_config_refuses_what_is_not_built():
     with pytest.raises(ValueError, match="window_pages"):
         make_kv_cache(cfg, 8, PS)
 
-
-def test_training_forward_and_tp_refuse_the_block_by_name():
-    cfg = LlamaConfig.tiny(**MIMO)
-    with pytest.raises(NotImplementedError, match="sliding_attention"):
-        llama.forward(init_params(cfg, jax.random.PRNGKey(0)),
-                      jnp.zeros((1, 8), jnp.int32), cfg)
-    with pytest.raises(NotImplementedError, match="sliding_attention"):
-        llama.param_specs(cfg)
-    with pytest.raises(NotImplementedError, match="sliding_attention"):
-        tp.validate_tp(cfg, 2)
-    plain = LlamaConfig.tiny(dim=64, score_head_dim=16)
-    assert plain.beyond_llama_block
-    with pytest.raises(NotImplementedError, match="score_head_dim"):
-        llama.num_params(plain)

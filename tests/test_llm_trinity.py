@@ -29,13 +29,14 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+from _blocks import seeded, served_logits, worst_gap  # noqa: E402
 from benchmark import reference_trinity as ref  # noqa: E402
-from ray_tpu.llm import InferenceEngine, tp  # noqa: E402
+from ray_tpu.llm import InferenceEngine  # noqa: E402
 from ray_tpu.llm import model as M  # noqa: E402
-from ray_tpu.llm.cache import (make_kv_cache, window_first_page,  # noqa: E402
+from ray_tpu.llm.cache import (window_first_page,  # noqa: E402
                                window_group_pages, window_table_width)
-from ray_tpu.models import llama  # noqa: E402
-from ray_tpu.models.llama import LlamaConfig, init_params  # noqa: E402
+from ray_tpu.models.llama import (LlamaConfig,  # noqa: E402
+                                  mechanisms_beyond)
 from ray_tpu.ops import paged_attention as PA  # noqa: E402
 
 TOL = 1e-4
@@ -55,43 +56,14 @@ ENGINE = dict(page_size=PS, total_pages=64, max_batch=4, max_seq_len=128,
               prefill_chunk=16, prefill_rows=2, decode_chunk=4, seed=3)
 
 
-def _run(eng):
-    done = {}
-    for _ in range(400):
-        done.update(eng.step())
-        if not eng.has_work():
-            return done
-    raise AssertionError("engine did not drain")
-
-
-def _worst_gap(eng, cfg, prompt, served, pad_to=128):
-    got = ref.score_greedy(eng.params, ref.dims_of(cfg), list(prompt),
-                           list(served), pad_to)
-    return max(got["gap"])
-
-
-def _seeded(cfg, seed=5):
-    """Weights whose norms are not ones: ones would hide a norm that is
-    skipped or misplaced (there are eight kinds of norm here)."""
-    params = init_params(cfg, jax.random.PRNGKey(seed))
-    for kind, stack in params["layers"].items():
-        for k in stack:
-            if k.endswith("norm"):
-                stack[k] = 1.0 + 0.5 * jax.random.normal(
-                    jax.random.PRNGKey(len(kind + k)), stack[k].shape)
-    return params
-
-
 @pytest.fixture(scope="module")
 def trinity():
     jax.clear_caches()
     cfg = LlamaConfig.tiny(**TRINITY)
-    return cfg, InferenceEngine(cfg, _seeded(cfg), **ENGINE)
+    return cfg, InferenceEngine(cfg, seeded(cfg), **ENGINE)
 
 
 _forward = jax.jit(ref.forward, static_argnames=("dims", "hold", "fault"))
-_step = jax.jit(M._ragged_logits, static_argnames=(
-    "cfg", "paged_impl", "max_q_len", "decode_rows"))
 
 
 def _reference_logits(params, cfg, tokens, fault=None):
@@ -101,55 +73,11 @@ def _reference_logits(params, cfg, tokens, fault=None):
 
 
 def _served_logits(cfg, params, tokens, n_prompt, chunk=16):
-    """``tokens`` through the mixed step's forward, teacher-forced: the
-    first ``n_prompt`` as ONE chunk row of at most ``chunk`` tokens a step,
-    the rest one a step as a DECODE row (row 0 of two, the other idle):
-    logits [len(tokens) - n_prompt + 1, vocab] at the prompt's last token
-    and at every later one. The full group's table names pages 1.. in
-    order; the window group's (where the block has one) is COMPACT over a
-    ring of pages, so a logical page lands on a physical page an earlier
-    one used and freed."""
-    pages, T, R = 16, 2 + chunk + 3, 3
-    windowed = bool(cfg.layers_of(WIN))
-    cols = window_table_width(cfg.sliding_window, chunk, PS) \
-        if windowed else 0
-    ring = cols + 1
-    kv = make_kv_cache(cfg, pages + 1, PS,
-                       **(dict(window_pages=ring + 1) if windowed else {}))
-    table = np.zeros((R, pages), np.int32)
-    table[0] = table[2] = 1 + np.arange(pages)
-    pieces = [(lo, min(chunk, n_prompt - lo), 2)
-              for lo in range(0, n_prompt, chunk)] \
-        + [(t, 1, 0) for t in range(n_prompt, len(tokens))]
-    out = []
-    for lo, n, row in pieces:
-        t0 = 2 if row == 2 else 0
-        tok, pos = np.zeros(T, np.int32), np.zeros(T, np.int32)
-        page, at, wpage = (np.zeros(T, np.int32) for _ in range(3))
-        where = np.arange(lo, lo + n)
-        tok[t0:t0 + n], pos[t0:t0 + n] = tokens[lo:lo + n], where
-        page[t0:t0 + n], at[t0:t0 + n] = 1 + where // PS, where % PS
-        q_start = np.asarray([0, 1, 2], np.int32)
-        q_len, kv_len = np.zeros(R, np.int32), np.zeros(R, np.int32)
-        q_len[row], kv_len[row] = n, lo + n
-        extra = {}
-        if windowed:
-            wpage[t0:t0 + n] = 1 + (where // PS) % ring
-            base = window_first_page(lo, cfg.sliding_window, PS)
-            wtable = np.zeros((R, cols), np.int32)
-            wbase = np.zeros(R, np.int32)
-            wtable[row], wbase[row] = 1 + (base + np.arange(cols)) % ring, \
-                base
-            extra = dict(token_page_win=jnp.asarray(wpage),
-                         page_table_win=jnp.asarray(wtable),
-                         page_base_win=jnp.asarray(wbase))
-        logits, kv, _ = _step(
-            params, *map(jnp.asarray, (tok, pos, page, at, table, q_start,
-                                       q_len, kv_len)), kv, cfg,
-            paged_impl="reference", max_q_len=chunk, decode_rows=2, **extra)
-        if lo + n >= n_prompt:
-            out.append(logits[row])
-    return jnp.stack(out)
+    """``tokens`` through the mixed step's forward (tests/_blocks.py:
+    served_logits): logits [len(tokens) - n_prompt + 1, vocab] at the
+    prompt's last token and at every later one."""
+    out, _ = served_logits(cfg, params, tokens, n_prompt, chunk)
+    return jnp.stack(out[n_prompt - len(tokens) - 1:])
 
 
 # ------------------------------------------------ the tree and the pool
@@ -180,8 +108,8 @@ def test_param_tree_pool_and_pattern(trinity):
     assert eng.kv["k"].shape == eng.kv["v"].shape == (1, 64, 2, PS, 16)
     assert eng.kv["k_win"].shape == eng.kv["v_win"].shape \
         == (4, group, 2, PS, 16)
-    assert cfg.gated_block and cfg.window_block and cfg.hybrid
-    assert cfg.beyond_llama_block
+    assert cfg.gated_block and cfg.hybrid
+    assert {"gated block", WIN} <= set(mechanisms_beyond(cfg))
     # a block that sets none of the three is no gated block
     assert not LlamaConfig.tiny().gated_block
     assert eng.prefix is None
@@ -269,71 +197,6 @@ def test_kernel_walks_a_long_window_in_blocks_in_interpret_mode(
 
 # ------------------------------------------------------------ the engine
 
-@pytest.mark.parametrize("n_prompt,n_new", [(6, 8), (11, 14), (70, 13)])
-def test_logits_through_both_page_groups_match_reference(
-        trinity, n_prompt, n_new):
-    """LOGITS at every served position: a sequence that stays inside the
-    window (14 tokens), one that crosses it while decoding (11 + 14), one
-    far beyond it (70 in chunks of 16 over a ring of freed pages, then
-    13 decode rows)."""
-    cfg, eng = trinity
-    tokens = list(np.random.default_rng(n_prompt).integers(
-        0, cfg.vocab_size, n_prompt + n_new))
-    want = _reference_logits(eng.params, cfg, tokens)[n_prompt - 1:]
-    got = _served_logits(cfg, eng.params, tokens, n_prompt)
-    assert got.shape == want.shape
-    assert float(jnp.abs(got - want).max()) < TOL
-
-
-@pytest.mark.parametrize("n_prompt,n_new", [(5, 9), (5, 20), (70, 13),
-                                            (16, 6)])
-def test_engine_chunked_prefill_and_decode_loop_match_reference(
-        trinity, n_prompt, n_new):
-    """The engine itself, chunk rows two a step and then the decode loop:
-    inside the window, across it, far beyond it, and a prompt that ends on
-    the window's and a chunk's edge."""
-    cfg, eng = trinity
-    prompt = list(range(1, 1 + n_prompt))
-    freed = eng.stats["window_pages_freed"]
-    inside, rows = eng.stats["rows_inside_window"], \
-        eng.stats["decode_tokens"]
-    served = eng.generate(prompt, n_new)
-    assert len(served) == n_new
-    assert _worst_gap(eng, cfg, prompt, served) < TOL
-    assert eng.window_allocator.num_free \
-        == eng.window_allocator.total_pages - 1
-    assert eng.allocator.num_free == eng.allocator.total_pages - 1
-    inside = eng.stats["rows_inside_window"] - inside
-    rows = eng.stats["decode_tokens"] - rows
-    if n_prompt + n_new <= W:
-        # nothing freed, and every decode row-step lay inside the window
-        assert eng.stats["window_pages_freed"] == freed
-        assert inside == rows > 0
-    elif n_prompt < W:
-        assert 0 < inside < rows
-    else:
-        assert inside == 0 < rows
-    if n_prompt == 70:
-        assert eng.stats["window_pages_freed"] - freed >= 70 // PS - 3
-
-
-def test_engine_mixed_batch_with_padding_rows_matches_reference(trinity):
-    """Four sequences of different lengths, short ones that hold their
-    whole window group beside long ones that free it: chunk rows beside
-    decode rows, idle slots and padding, both programs taking turns."""
-    cfg, eng = trinity
-    prompts = [list(range(3, 3 + n)) for n in (37, 9, 52)]
-    rids = [eng.add_request(p, n) for p, n in zip(prompts, (11, 27, 5))]
-    eng.step()
-    late = list(range(100, 113))
-    rids.append(eng.add_request(late, 2))
-    done = _run(eng)
-    for p, r in zip(prompts + [late], rids):
-        assert _worst_gap(eng, cfg, p, done[r]) < TOL
-    assert eng.stats["page_steps_window"] > 0
-    assert eng.stats["moe_pairs"] > 0
-
-
 def test_inside_the_window_a_window_layer_is_a_full_layer():
     """A sequence wholly inside the window gives the same logits with
     every window layer declared full (and rotated, as the window layers
@@ -343,7 +206,7 @@ def test_inside_the_window_a_window_layer_is_a_full_layer():
     as_full = LlamaConfig.tiny(**{
         **TRINITY, "layer_types": [FULL] * 5, "full_rope": True,
         "sliding_window": 0, "window_kv_heads": 0, "window_rope_theta": 0.0})
-    params = _seeded(as_window)
+    params = seeded(as_window)
     assert "attn" not in params["layers"]
     renamed = {**params, "layers": {
         **{k: v for k, v in params["layers"].items() if k != "attn_window"},
@@ -368,7 +231,7 @@ def test_engine_preempts_and_readmits_a_sequence_that_freed_window_pages():
     groups, re-prefills from position 0 and continues as if never
     stopped."""
     cfg = LlamaConfig.tiny(**TRINITY)
-    params = _seeded(cfg)
+    params = seeded(cfg)
     how = {**ENGINE, "page_size": 4, "max_seq_len": 64, "max_batch": 2}
     small = InferenceEngine(cfg, params, **{**how, "total_pages": 18})
     roomy = InferenceEngine(cfg, params, **how)
@@ -388,7 +251,7 @@ def test_engine_preempts_and_readmits_a_sequence_that_freed_window_pages():
     assert freed_at_preemption and freed_at_preemption > 0
     for p, r in zip(prompts, rids):
         assert done[r] == roomy.generate(p, 16)
-        assert _worst_gap(small, cfg, p, done[r], pad_to=64) < TOL
+        assert worst_gap("trinity", small, p, done[r], pad_to=64) < TOL
     assert small.window_allocator.num_free \
         == small.window_allocator.total_pages - 1
     assert small.allocator.num_free == small.allocator.total_pages - 1
@@ -459,13 +322,13 @@ def test_config_refuses_what_is_not_built():
         return LlamaConfig.tiny(**{**TRINITY, **kw})
     # window layers beside a norm over the whole projected vector, a
     # latent pool, or conv / mamba / retention layers
-    with pytest.raises(ValueError, match="full_attention layers only"):
+    with pytest.raises(ValueError, match="sliding_attention.*beside qk_norm"):
         tiny(qk_norm_per_head=False, qk_norm=True, score_head_dim=0,
              value_head_dim=0)
-    with pytest.raises(ValueError, match="full_attention layers only"):
+    with pytest.raises(ValueError, match="sliding_attention.*beside no positions"):
         tiny(rope=False)
     for kind in ("conv", "retention"):
-        with pytest.raises(ValueError, match="full_attention layers only"):
+        with pytest.raises(ValueError, match=f"not built beside|{kind} layers"):
             tiny(layer_types=[WIN] * 4 + [kind], n_experts=0,
                  experts_per_token=0, n_dense_layers=0, shared_ffn_dim=0,
                  router_bias=False)
@@ -478,29 +341,11 @@ def test_config_refuses_what_is_not_built():
     assert LlamaConfig.tiny(dim=64, attn_gate=True, kv_lora_rank=32,
                             qk_nope_head_dim=8, qk_rope_head_dim=8,
                             v_head_dim=8).gated_block
-    with pytest.raises(ValueError, match="no gate and no second norm"):
+    with pytest.raises(ValueError, match="gated block.*beside conv"):
         LlamaConfig.tiny(dim=64, post_norms=True,
                          layer_types=["conv", FULL, "conv", FULL])
-    with pytest.raises(ValueError, match="no gate and no second norm"):
+    with pytest.raises(ValueError, match="mamba layers: not built beside gated block"):
         LlamaConfig.tiny(dim=64, attn_gate=True, ssm_state=8, ssm_heads=4,
                          ssm_head_dim=8,
                          layer_types=["mamba", FULL, "mamba", FULL])
 
-
-def test_training_forward_and_tp_refuse_the_block_by_name():
-    cfg = LlamaConfig.tiny(**TRINITY)
-    with pytest.raises(NotImplementedError, match="attn_gate"):
-        llama.forward(init_params(cfg, jax.random.PRNGKey(0)),
-                      jnp.zeros((1, 8), jnp.int32), cfg)
-    with pytest.raises(NotImplementedError, match="post_norms"):
-        llama.param_specs(cfg)
-    with pytest.raises(NotImplementedError, match="attn_gate"):
-        tp.validate_tp(cfg, 2)
-    # each field alone, on the Llama block
-    for field in (dict(attn_gate=True), dict(post_norms=True)):
-        plain = LlamaConfig.tiny(dim=64, **field)
-        assert plain.gated_block and plain.window_block and plain.hybrid
-        with pytest.raises(NotImplementedError, match="full_rope"):
-            llama.num_params(plain)
-        with pytest.raises(NotImplementedError, match="post_norms"):
-            tp.validate_tp(plain, 2)
