@@ -89,18 +89,29 @@ admission, scheduling), rebuilt TPU-first around ONE ragged step:
     late_retired_rows); what the engine cannot do with a program in
     flight (preempt: it folds tokens it does not have yet; a window
     group without a spare page for the look-ahead) waits until that
-    program is booked (stats ahead_drains), and a decode loop is queued
-    only where no arrival could have been admitted before a row ends
-    anyway (_launch). stats ahead_dispatches counts the dispatches
+    program is booked (stats ahead_drains). One launch is made LATE,
+    not at once: with a slot free and nobody waiting, a decode loop
+    queued at once would make a request that arrives meanwhile wait a
+    whole loop for a slot it could have had, so the engine holds the
+    launch back until the program in flight is DUE (its expected end
+    less the host's own stretch in front of a launch, both from the
+    engine's clocks: _clock, step), sleeps until then, admits whoever
+    came, and launches the mixed step or the loop so that it lands on
+    the device as the flight ends. An arrival misses that program only
+    if it comes inside the stretch, as it always did; the stretch itself
+    runs under the device (stats held_launches, late_launches,
+    late_mixed_launches). stats ahead_dispatches counts the dispatches
     launched with a program unbooked. The engine decides all of it from
-    its own state: no setting selects it.
+    its own state and clocks: no setting selects it.
 
 A step is admit -> pack -> h2d -> dispatch (of the NEXT program) ->
 after_dispatch -> readback -> book (of the program that was in flight; of
 the one just launched where nothing may be queued behind it, which is the
-old synchronous order: dispatch -> readback -> book -> next step). With a
-program queued the host's stretch between two dispatches runs under the
-device; where the order is synchronous it is device idle, as it was.
+old synchronous order: dispatch -> readback -> book -> next step). A step
+that holds its launch back is admit -> after_dispatch -> hold -> admit ->
+pack -> h2d -> dispatch -> readback -> book. With a program queued the
+host's stretch between two dispatches runs under the device; where the
+order is synchronous it is device idle, as it was.
 Each phase of a step goes through ONE helper (PhaseClocks.phase), which
 does two things:
 
@@ -114,7 +125,11 @@ does two things:
     the step BOOKED; `launched` the kind it launched, `ahead` whether the
     booked one had been launched behind another; pack .. dispatch and
     readback, book belong to different programs where the engine runs
-    ahead); the serve loop adds
+    ahead), but for a step that HELD its launch back (`held`): it sleeps
+    in engine.hold until the flight is due and opens engine.admit a
+    second time after it (`late`: it then launched behind the flight
+    still running; `end_late_us`: by how much the flight outran what was
+    expected of it, where its readback marked the end); the serve loop adds
     serve.wait between steps and serve.publish {streams}, which it runs
     from step()'s after_dispatch hook: inside engine.step, between
     engine.dispatch and engine.readback, with a program on the device
@@ -125,12 +140,15 @@ does two things:
     difference added to engine.stats as wall_ns_<phase> for admit, pack,
     h2d, dispatch, readback, book, metrics, publish, wait, and `other` =
     what of engine.step no child covers, so a plain sum of the ten keys
-    is the engine thread's time. And the thread's own CPU time
+    is the engine thread's time (engine.hold's goes to `wait`: time the
+    thread sleeps by design with nothing to do for the device, like
+    serve.wait, and no part of the host's work a dispatch). And the
+    thread's own CPU time
     (time.thread_time_ns) as ONE counter, cpu_ns_host: what the thread
-    ran outside engine.readback and serve.wait, which sleep by design.
-    It is read at the two ends of those two spans only (two reads a
-    dispatch: the clock is a system call of 5.6 us on the chip machine's
-    host). wall - cpu is the time the thread was NOT running: waiting for
+    ran outside engine.readback, engine.hold and serve.wait, which sleep
+    by design. It is read at the two ends of those spans only (two reads
+    a dispatch, two more a hold: the clock is a system call of 5.6 us on
+    the chip machine's host). wall - cpu is the time the thread was NOT running: waiting for
     the interpreter, descheduled, or blocked in a call that sleeps. When
     a trace runs, and only then, every span also reads the CPU clock at
     both ends and carries cpu_us (on a host whose kernel counts thread
@@ -155,7 +173,7 @@ import logging
 import threading
 import time
 import uuid
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Deque, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -180,15 +198,17 @@ logger = logging.getLogger(__name__)
 PHASES = ("admit", "pack", "h2d", "dispatch", "readback", "book",
           "metrics", "other", "publish", "wait")
 WALL_KEYS = tuple("wall_ns_" + p for p in PHASES)
-#: the thread's CPU time outside the two phases that sleep by design
+#: the thread's CPU time outside the phases that sleep by design
 CPU_KEY = "cpu_ns_host"
-_SLEEPS = ("engine.readback", "serve.wait")
+_SLEEPS = ("engine.readback", "engine.hold", "serve.wait")
 #: span name -> its wall counter; engine.step books what its children
-#: left of it
+#: left of it, and engine.hold (a step asleep until the program in flight
+#: is due: step) is time the thread waits by design, like serve.wait
 _SPAN_OF = {"other": "engine.step", "publish": "serve.publish",
             "wait": "serve.wait"}
 _PHASE_KEY = {_SPAN_OF.get(p, "engine." + p): "wall_ns_" + p
               for p in PHASES}
+_PHASE_KEY["engine.hold"] = "wall_ns_wait"
 
 
 # the two clocks, as module names: the helper runs ten times a dispatch
@@ -196,6 +216,7 @@ _PHASE_KEY = {_SPAN_OF.get(p, "engine." + p): "wall_ns_" + p
 _wall_ns = time.perf_counter_ns
 _cpu_ns = time.thread_time_ns
 _thread = threading.get_ident
+_sleep = time.sleep
 _span_enter = TraceAnnotation.__enter__
 _span_exit = TraceAnnotation.__exit__
 
@@ -226,14 +247,16 @@ class PhaseClocks:
 
 class _Phase(TraceAnnotation):
     """One phase in hand: the span it is, and its clocks. The wall clock
-    is read at both ends of every phase; the thread's CPU clock only at
-    the ends of the two that sleep (what ran between two of them is
+    is read at both ends of every phase (``wall0``, ``wall1``: a launch's
+    stretch and a program's two ends are read off them, step and _clock);
+    the thread's CPU clock only at
+    the ends of those that sleep (what ran between two of them is
     cpu_ns_host) and, while a trace runs, at both ends of every span for
     its cpu_us. Both clocks are read in the same order at both ends and
     inside the span: cpu_us <= its duration up to the clocks'
     granularity."""
 
-    __slots__ = ("clocks", "wall_key", "sleeps", "wall0", "cpu0",
+    __slots__ = ("clocks", "wall_key", "sleeps", "wall0", "wall1", "cpu0",
                  "child_wall0")
 
     def __enter__(self) -> "_Phase":
@@ -251,7 +274,8 @@ class _Phase(TraceAnnotation):
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        wall = _wall_ns() - self.wall0
+        self.wall1 = _wall_ns()
+        wall = self.wall1 - self.wall0
         c = self.clocks
         if self.cpu0 is not None:
             cpu = _cpu_ns()
@@ -287,11 +311,12 @@ def _descriptor_turns(layout: M.Layout):
 
 class _Flight:
     """A program launched and not booked yet: what its booking needs of
-    the dispatch as it was packed (_take_off), and its tokens, still on
-    the device."""
+    the dispatch as it was packed (_take_off), its tokens, still on the
+    device, and the two ends of its run there on the host's wall clock."""
 
     __slots__ = ("kind", "rows", "n_rows", "ahead", "active", "out",
-                 "rows_joined", "pages_in_use", "inside_window")
+                 "rows_joined", "pages_in_use", "inside_window", "began",
+                 "ended", "blocked")
 
     def __init__(self, kind: str, rows, n_rows: int, ahead: bool):
         self.kind = kind            # "mixed" | "decode"
@@ -305,6 +330,60 @@ class _Flight:
         self.rows_joined = 0
         self.pages_in_use = (0, 0)  # full group, window group
         self.inside_window = 0
+        #: when it began to run: its dispatch's end or, launched ahead,
+        #: the end of the program before it (set at THAT one's booking)
+        self.began = 0
+        #: its readback's end, and whether that readback BLOCKED (the
+        #: program was still running when it started): `ended` is then
+        #: the program's end, else only a time by which it had ended
+        self.ended = 0
+        self.blocked = False
+
+
+def _lower_quartile(runs) -> int:
+    return sorted(runs)[len(runs) // 4]
+
+
+def _landed(flight: _Flight) -> bool:
+    """Whether ``flight``'s program has ended on the device: its tokens
+    can be read without blocking (a module name: the tests script it)."""
+    return flight.out.is_ready()
+
+
+#: THE LATE DECISION'S THREE CONSTANTS (InferenceEngine.step), each with
+#: the measurement that chose it (my chip runs, PR 61, four traced runs of
+#: reason-lfm2-1chip, every booked program's host-clocked run beside its
+#: device time in the trace).
+#:
+#: A program's time on the DEVICE is a constant of its kind and shape:
+#: decode loops 132.5-134.3 ms, one-row mixed steps 21.2-22.3, two-row
+#: ones 25.4-26.1, whatever the rows hold. What the HOST clocks of it
+#: (_clock: from one blocked readback's end to the next) is that plus the
+#: difference of two wake-ups, each late by however long the thread
+#: waited for the interpreter: medians within -3.1 .. +3.3 ms of the
+#: device's, but single readings off by -52 .. +145 ms, a few in a
+#: hundred. So the estimate is taken over the newest _RUNS_KEPT runs and
+#: is their LOWER QUARTILE (_expected_end_ns): two wild readings on
+#: either side do not move it, and it leans early, which is the cheap
+#: side (a launch that lands early is queued and takes a few ms off the
+#: window an arrival can still be admitted in; one that lands late
+#: leaves the chip idle).
+_RUNS_KEPT = 8
+#: What the due time keeps clear of the flight's expected end besides
+#: the stretch: the readback that marked the flight's beginning woke late
+#: by 0.3-3.3 ms in the median where the stream lanes were at work (the
+#: mixed steps, booked 10-20 ms after a hand-over), and the flight's end
+#: is expected later by as much; at 1.5 ms the flights that landed
+#: before their launch (6-10 in 8 s) were mixed steps, all but one or
+#: two. A readback that still blocks for about the margin is also what
+#: marks the flight's end for the next estimate.
+_HOLD_MARGIN_NS = 3_000_000
+#: The hold sleeps in slices and looks at the flight between them: an
+#: estimate that is far off (a program's first run; rows that all ended)
+#: costs one slice of idle, not the whole estimate, and the flight found
+#: landed gives _clock a bound that is a slice wide. Thirteen looks in a
+#: decode loop's length.
+_HOLD_SLICE_NS = 10_000_000
 
 
 class InferenceEngine:
@@ -492,6 +571,14 @@ class InferenceEngine:
         # allow. The engine decides from its own state; the tests set
         # False to hold it to one program at a time. No configuration does
         self._run_ahead = True
+        # the two times a held launch is due by (step), from the engine's
+        # own clocks: how long the newest programs of a kind and shape ran
+        # (_clock: from the end of one blocked readback to the end of the
+        # next), and the host's stretch (engine.admit's start to
+        # engine.dispatch's end) in front of the newest launch of each
+        # kind
+        self._program_ns: Dict[Tuple[str, int], Deque[int]] = {}
+        self._stretch_ns: Dict[str, int] = {}
         # a mixed step's padding where it is not 0
         self._padding = {"token_page": SCRATCH_PAGE,
                          "newest_slot": max_batch,
@@ -522,7 +609,17 @@ class InferenceEngine:
                       # was packed; times a page group could not serve
                       # the look-ahead and the pipeline drained (_launch)
                       "ahead_dispatches": 0, "late_retired_rows": 0,
-                      "ahead_drains": 0}
+                      "ahead_drains": 0,
+                      # steps that found a program in flight and nothing
+                      # to queue behind it but a decode loop over a FREE
+                      # slot (step: the hold-back); those that launched
+                      # a program behind the flight while it still ran
+                      # (at its due time); and, of those, the mixed steps
+                      # (a request came during the hold). held - late:
+                      # the pipeline drained (the flight had landed, no
+                      # estimate yet, nothing to launch, ahead_drains)
+                      "held_launches": 0, "late_launches": 0,
+                      "late_mixed_launches": 0}
         # counters the step programs reduce on the device and append to
         # the tokens they return (none for a dense model): one stats key
         # each, and metadata of the dispatch's engine.readback span
@@ -754,11 +851,49 @@ class InferenceEngine:
         again. Returns {request_id: generated} for the sequences that the
         booking found FINISHED.
 
+        THE LATE DECISION. A program in flight, no chunk work, a slot
+        free and nobody waiting (_held_back: the normal state after every
+        booking that ended a row, in a closed loop): a decode loop queued
+        now would make whoever arrives during the flight wait a loop
+        longer for the free slot; booking the flight first and packing
+        then, as the engine did, leaves the chip idle for the whole
+        stretch (admit .. dispatch). The rule protects an arrival only
+        until the next program is packed, so the step moves that moment
+        to where it costs nothing: it hands over what the caller holds
+        (``after_dispatch``), sleeps (engine.hold) until the flight is
+        DUE, which is its expected end (_expected_end_ns: when it began,
+        and how long the newest programs of its kind and shape ran that
+        were booked with both ends marked: _clock) less the stretch of
+        the newest launches and a margin (_lead_ns), admits AGAIN, and
+        launches what is due behind the flight: a mixed step if anyone
+        came, else the decode loop, free slot or not (the slot decodes at
+        length 1 through blank tables, as a free slot does in any loop:
+        _blank_slot). Then it books the flight, whose readback blocks for
+        about the margin. It never sleeps on a program that has landed:
+        one found landed when the step looks is booked at once, the
+        pipeline is empty and the step goes on as before; the hold looks
+        between its slices (_hold) and ends with the flight if that ends
+        first, and the launch is made all the same (the chip is idle). A
+        flight whose due time has passed is followed at once. Before a
+        kind and shape has been clocked once the step does what the
+        engine did before: it books the flight, and the next step
+        launches on an empty pipeline. Unchanged: a request that
+        WAITS at a full batch while a row is known to end in the flight
+        still drains the pipeline (_loop_may_follow), as does a page
+        group that cannot serve the look-ahead (_launch); with chunk work
+        pending or no slot free the launch is made ahead at once. stats
+        held_launches counts the steps that came to the hold-back,
+        late_launches those that launched behind the flight still
+        running, late_mixed_launches the mixed steps among them.
+
         ``after_dispatch`` is called once, with no arguments, by every
         step that is about to sleep on a program: right after the
         engine.dispatch phase of the program the step launches, or, where
         it launches none and books the one in flight, before that
-        program's engine.readback. Host work the caller wants done while
+        program's engine.readback; by a step that holds, before the hold
+        and not again after its launch (a finished row's client can only
+        come back after the hand-over, and the hold is what it comes back
+        in). Host work the caller wants done while
         the device runs and this thread would only sleep on it. A step
         that neither launches nor books never calls it. The serve loop
         hands the PREVIOUS step's tokens to their waiters there
@@ -770,27 +905,73 @@ class InferenceEngine:
         finished: Dict[str, List[int]] = {}
         with self.phase("engine.step") as span:
             self._step_meta = {"kind": "none"}
-            with self.phase("engine.admit") as admit_span:
-                admitted = self._admit()
-                if admit_span.is_enabled():
-                    admit_span.set_metadata(admitted=admitted)
+            stats = self.stats
+            admit = self._admit_phase()
             flying = self._flight
-            launched = self._launch(finished)
+            handed = held = due = False
+            expected = None
+            if flying is not None and self._held_back():
+                held = True
+                stats["held_launches"] += 1
+                expected = self._expected_end_ns(flying)
+                if expected is not None and not _landed(flying):
+                    wake = expected - self._lead_ns()
+                    if wake > _wall_ns():
+                        # what the caller holds first: the finished rows'
+                        # clients can only come back after it
+                        if after_dispatch is not None:
+                            after_dispatch()
+                        handed = True
+                        self._hold(flying, wake)
+                        admit = self._admit_phase()
+                    due = True
+            launched = self._launch(finished, due)
+            if launched is not None:
+                self._stretch_ns[launched.kind] = launched.began - admit.wall0
+            # late: behind the flight STILL running (one that landed
+            # during the hold is followed all the same: the chip is idle)
+            late = due and launched is not None and not _landed(flying)
+            stats["late_launches"] += late
+            stats["late_mixed_launches"] += late and launched.kind == "mixed"
             if flying is None and launched is not None \
                     and not self._next_may_follow():
                 flying = launched             # the synchronous order
-            if after_dispatch is not None \
+            if after_dispatch is not None and not handed \
                     and (launched is not None or flying is not None):
                 after_dispatch()              # the device is running
             if flying is not None:
                 self._book(flying, finished)
-            self._step_meta["launched"] = \
-                "none" if launched is None else launched.kind
-            self.stats["steps"] += 1
+            self._step_meta.update(
+                launched="none" if launched is None else launched.kind,
+                held=held, late=late)
+            if expected is not None and flying.blocked:
+                # how far off the flight's expected end was (+: it ran
+                # longer), where its readback marked the end
+                self._step_meta["end_late_us"] = \
+                    (flying.ended - expected) / 1e3
+            stats["steps"] += 1
             self._update_metrics()
             if span.is_enabled():
                 span.set_metadata(**self._step_meta)
         return finished
+
+    def _hold(self, flight: "_Flight", wake: int) -> None:
+        """engine.hold: sleep until ``wake`` (the flight is due), in
+        slices, and no longer than the flight runs."""
+        with self.phase("engine.hold"):
+            left = wake - _wall_ns()
+            while left > 0 and not _landed(flight):
+                _sleep(min(left, _HOLD_SLICE_NS) / 1e9)
+                left = wake - _wall_ns()
+
+    def _admit_phase(self) -> "_Phase":
+        """engine.admit, once: the span, closed (its wall0 is where the
+        host's stretch in front of a launch starts)."""
+        with self.phase("engine.admit") as span:
+            admitted = self._admit()
+            if span.is_enabled():
+                span.set_metadata(admitted=admitted)
+        return span
 
     # ---------------------------------------------------------- scheduling
 
@@ -1126,7 +1307,7 @@ class InferenceEngine:
             t0 = t1
         return buf
 
-    def _launch(self, finished: Dict[str, List[int]],
+    def _launch(self, finished: Dict[str, List[int]], due: bool = False,
                 ) -> Optional["_Flight"]:
         """Pack, send and launch the next program from the structures as
         the launched ones leave them, or None. ONE ragged mixed step if
@@ -1148,10 +1329,13 @@ class InferenceEngine:
         back; the step then books the program in flight and the next one
         goes on from an empty pipeline:
 
-          - a decode loop is queued behind a running program only when
-            no batch slot is free: a request that arrives meanwhile could
-            not have been admitted before a row ends anyway. And not
-            while a request WAITS and a row is known to end in the
+          - a decode loop is queued behind a running program at once
+            only when no batch slot is free: a request that arrives
+            meanwhile could not have been admitted before a row ends
+            anyway. Behind a FREE slot it is queued when the flight is
+            ``due`` (step's late decision: the arrival it would have
+            made wait has been admitted by then, or did not come). And
+            never while a request WAITS and a row is known to end in the
             program in flight (by length; or found stopped on EOS a
             booking ago): that booking frees the slot, and the request
             would wait a loop longer for it than with one program at a
@@ -1166,7 +1350,7 @@ class InferenceEngine:
             must."""
         ahead = self._flight is not None
         rows = self._deal_chunk_rows()
-        if ahead and not rows and not self._loop_may_follow():
+        if ahead and not rows and not self._loop_may_follow(due):
             return None
         for seq, start, C in rows if self._window else ():
             # the rows' pages
@@ -1193,7 +1377,7 @@ class InferenceEngine:
             return None
         with self.phase("engine.h2d"):
             desc = jax.device_put(desc)         # the ONE transfer
-        with self.phase("engine.dispatch"):
+        with self.phase("engine.dispatch") as span:
             # kv and the newest tokens: the outputs of the program before,
             # ready or not
             if rows:
@@ -1203,6 +1387,9 @@ class InferenceEngine:
                 flight.out, self.kv, _, _, self._last = \
                     self._fns.decode_loop(self.params, desc, self.kv,
                                           self._last)
+        # it runs from now, or from the end of the program in flight: that
+        # one's booking says when that was
+        flight.began = span.wall1
         self._flight = flight
         return flight
 
@@ -1299,11 +1486,13 @@ class InferenceEngine:
         mixed = flight.kind == "mixed"
         B, K = self.max_batch, 1 if mixed else self.decode_chunk
         R, Tcap = self._mixed_shape(flight.n_rows)
+        flight.blocked = not _landed(flight)
         with self.phase("engine.readback") as span:
             out = np.asarray(flight.out)           # ONE readback
             out = self._note_counters(out, R if mixed else K * B, span)
             if not mixed:
                 out = out.reshape(K, B)
+        self._clock(flight, span.wall1)
         with self.phase("engine.book"):
             if self._flight is flight:
                 self._flight = None
@@ -1369,6 +1558,28 @@ class InferenceEngine:
                     seq.record.note_chunk(now, C, disp_idx)
                 if start + C >= len(seq.prompt):
                     self._postfill_book(seq, int(out[B + j]), finished, now)
+
+    def _clock(self, flight: "_Flight", ended: int) -> None:
+        """``flight``'s readback ended at ``ended``: how long its program
+        ran, for the next ones of its kind and shape (_expected_end_ns).
+        A readback that BLOCKED marks the program's end; one that did not
+        says only that the program had ended by then, a bound that is
+        kept where it is under the estimate (one that was too long comes
+        down in a few programs). The program queued behind it, if any,
+        began when this one ended."""
+        flight.ended = ended
+        key = (flight.kind, flight.n_rows)
+        ran, runs = ended - flight.began, self._program_ns.get(key)
+        if flight.blocked:
+            if runs is None:
+                runs = self._program_ns[key] = collections.deque(
+                    maxlen=_RUNS_KEPT)
+            runs.append(ran)
+        elif runs and ran < _lower_quartile(runs):
+            runs.append(ran)
+        behind = self._flight
+        if behind is not None and behind is not flight:
+            behind.began = max(behind.began, ended)
 
     def _postfill_book(self, seq: SequenceState, first_tok: int,
                        finished: Dict[str, List[int]], now: float) -> None:
@@ -1479,26 +1690,56 @@ class InferenceEngine:
         been found ended."""
         return seq is not None and not seq.ended and not seq.done
 
-    def _loop_may_follow(self) -> bool:
+    def _loop_may_follow(self, due: bool = False) -> bool:
         """Whether a decode loop may be queued behind the program in
-        flight (_launch): no slot is free, and no request waits for one
-        that the program in flight is known to free."""
-        if None in self._slots:
+        flight (_launch). Not while a request waits for a slot that the
+        program in flight is known to free; else at once where no slot is
+        free, and behind a free slot only once the flight is ``due``
+        (step decides that: the late decision)."""
+        if self.waiting and not all(map(self._goes_on, self._slots)):
             return False
-        return not self.waiting or all(map(self._goes_on, self._slots))
+        return due or None not in self._slots
+
+    def _held_back(self) -> bool:
+        """Whether all that keeps a program from being queued behind the
+        one in flight is a free slot that an arrival could still take:
+        no chunk work (a mixed step is queued at once), nobody waiting,
+        a slot free. The launch is then made when the flight is due
+        (step), not when it is booked."""
+        return not self._chunking and not self.waiting \
+            and None in self._slots
+
+    def _expected_end_ns(self, flight: "_Flight") -> Optional[int]:
+        """When ``flight`` should end, on the wall clock: its beginning
+        and the lower quartile of the newest runs of its kind and shape
+        that were clocked (_clock; _RUNS_KEPT says why that); None before
+        one was."""
+        runs = self._program_ns.get((flight.kind, flight.n_rows))
+        return flight.began + _lower_quartile(runs) if runs else None
+
+    def _lead_ns(self) -> int:
+        """How long before the flight's expected end a held launch is
+        due: the host's stretch in front of a launch (the longer of the
+        newest mixed step's and the newest decode loop's: which of the
+        two it will be is not known yet) and the margin."""
+        return max(self._stretch_ns.values()) + _HOLD_MARGIN_NS
 
     def _next_may_follow(self) -> bool:
         """Whether the program the NEXT step launches may be queued behind
         the one just launched, from what the engine holds now (_launch's
         rule, before the next admission): chunk work, or a request and a
-        slot for it, make it a mixed step; else it is a decode loop. If
+        slot for it, make it a mixed step; else it is a decode loop, which
+        follows at once with every slot taken and, behind a free slot,
+        when the program just launched is due (the next step holds for
+        that, if a program of this kind and shape has been clocked). If
         not, this step books what it launched: the synchronous order, one
         program at a time."""
         if not self._run_ahead:
             return False
         if self._chunking or self.waiting and None in self._slots:
             return True
-        return self._loop_may_follow()
+        return self._loop_may_follow() or self._held_back() \
+            and self._expected_end_ns(self._flight) is not None
 
     def _decode_rows(self, headroom: int, finished: Dict[str, List[int]],
                      ahead: bool = False,

@@ -118,6 +118,17 @@ class LLMServer:
         program for its hand-over. Its own tokens fall under the flush
         rule.
 
+        A step that HOLDS its launch back (a program in flight, a slot
+        free, nobody waiting: InferenceEngine.step's late decision) calls
+        the hook first, then sleeps in engine.hold until the flight is
+        due, then admits again and launches. So the tokens of program N
+        are handed over at the START of the hold under N+1, the clients
+        of the rows that N ended come back during that hold (some tens of
+        ms later, with the lanes that the hand-over woke long done), and
+        the admission after the hold puts them into the mixed step that
+        lands on the device as N+1 ends. The hook is called once in such
+        a step, never again after its launch.
+
         Flush rule: what no dispatch will carry is handed over at once,
         in the old place: after a step that launched nothing, and before
         serve.wait when the engine has run dry. No token is held across a
@@ -130,7 +141,9 @@ class LLMServer:
         reads; serve.publish nests in engine.step unless it is a flush),
         each with cpu_us, and the counters wall_ns_publish / wall_ns_wait
         of engine.stats (serve.wait sleeps by design: the thread's CPU
-        counter, cpu_ns_host, is read at its two ends and leaves it out).
+        counter, cpu_ns_host, is read at its two ends and leaves it out;
+        wall_ns_wait also holds the steps' engine.hold, the other sleep
+        with nothing to do for the device).
         engine.stats also counts `publishes` (hand-overs that had
         something to hand over) and `publishes_overlapped` (those made
         with a program on the device). _publish wakes every stream that

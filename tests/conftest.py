@@ -85,3 +85,27 @@ def cpu_mesh_devices():
     devices = jax.devices("cpu")
     assert len(devices) >= 8, "conftest must provide 8 virtual devices"
     return devices
+
+
+# The engine makes a launch it holds back behind a free slot when the
+# program in flight is DUE, by its own clocks (llm/engine.py: step). On the
+# CPU, where a tiny program runs a millisecond or two, whether a step finds
+# its flight landed, due or still to wait for is the machine's timing: the
+# tokens are the same either way, the order of launches and bookings is
+# not. The suite reads that order (spans, counters, hand-overs), so here no
+# program is ever clocked: every engine does what it does before it has
+# seen a (kind, shape) once, which is what it did before the late decision.
+# The tests OF the late decision ask for the real estimate (`late_decision`)
+# and script the clocks and the device (tests/test_llm_ahead.py).
+from ray_tpu.llm.engine import InferenceEngine  # noqa: E402
+
+_EXPECTED_END_NS = InferenceEngine._expected_end_ns
+InferenceEngine._expected_end_ns = lambda self, flight: None
+
+
+@pytest.fixture
+def late_decision(monkeypatch):
+    """The engine as it runs outside the tests: programs are clocked and a
+    held launch is made when the flight is due."""
+    monkeypatch.setattr(InferenceEngine, "_expected_end_ns",
+                        _EXPECTED_END_NS)

@@ -55,6 +55,9 @@ SCRIPT = {"wait": (50_000, 40), "admit": (100, 70), "pack": (1000, 90),
 
 #: the phases whose CPU is the host's: all but the two that sleep
 HOST = [p for p in PHASES if p not in ("readback", "wait")]
+#: a step's hold (engine.hold: asleep until the flight is due), which is
+#: no phase of its own: (wall ns, CPU ns) as SCRIPT's
+HOLD = (20_000, 15)
 
 
 @pytest.fixture
@@ -84,20 +87,32 @@ def scripted(monkeypatch):
         now["wall"] += SCRIPT[name][0]
         now["cpu"] += SCRIPT[name][1]
 
-    def turn(nested=False):
+    def turn(nested=False, held=False):
         """One loop turn; `nested`: the last step's tokens handed over
         from inside this step, between dispatch and readback (the serve
-        loop's order), instead of after it (a flush)."""
+        loop's order), instead of after it (a flush). `held`: the step
+        holds its launch back (llm/engine.py: step): after its admission
+        it hands over (if `nested`), sleeps in engine.hold and admits
+        again before it packs."""
         with phase("serve.wait"):
             work("wait")
         with phase("engine.step"):
             now["wall"] += 7
             now["cpu"] += 5
+            if held:
+                with phase("engine.admit"):
+                    work("admit")
+                if nested:
+                    with phase("serve.publish"):
+                        work("publish")
+                with phase("engine.hold"):
+                    now["wall"] += HOLD[0]
+                    now["cpu"] += HOLD[1]
             for name in ("admit", "pack", "h2d", "dispatch", "readback",
                          "book", "metrics"):
                 with phase("engine." + name):
                     work(name)
-                if nested and name == "dispatch":
+                if nested and not held and name == "dispatch":
                     with phase("serve.publish"):
                         work("publish")
             now["wall"] += 4
@@ -186,6 +201,50 @@ def test_a_traced_span_carries_the_cpu_of_its_own_window(scripted, nested):
     with engine_mod.PhaseClocks(stats).phase("engine.pack"):
         trace(True)
     assert len(given) == 10
+
+
+@pytest.mark.parametrize("nested", [False, True])
+def test_a_hold_is_wall_of_wait_and_the_ten_counters_still_partition(
+        scripted, nested):
+    """engine.hold sleeps by design, like serve.wait: its wall goes to
+    wall_ns_wait (not to `other`, which engine_host_ms sums), the step's
+    own time is still what no child covers, and with a hold in every
+    other turn the ten counters add up to the thread's time."""
+    stats, turn, elapsed = scripted[:3]
+    for i in range(6):
+        turn(nested, held=i % 2 == 0)
+    assert sum(stats[k] for k in WALL_KEYS) == elapsed() \
+        == 6 * sum(w for w, _ in SCRIPT.values()) \
+        + 3 * (HOLD[0] + SCRIPT["admit"][0])
+    assert stats["wall_ns_wait"] == 6 * SCRIPT["wait"][0] + 3 * HOLD[0]
+    assert stats["wall_ns_admit"] == 9 * SCRIPT["admit"][0]
+    assert stats["wall_ns_other"] == 6 * SCRIPT["other"][0]
+    assert stats["wall_ns_publish"] == 6 * SCRIPT["publish"][0]
+    assert "wall_ns_hold" not in stats and len(WALL_KEYS) == 10
+
+
+@pytest.mark.parametrize("traced", [False, True])
+def test_host_cpu_leaves_the_hold_out(scripted, traced):
+    """cpu_ns_host with a hold in the step: the CPU of both admissions and
+    of the hand-over before the hold is the host's, what the thread is
+    charged while it sleeps in engine.hold is not; the CPU clock is read
+    at the hold's two ends too (two more reads a held step)."""
+    stats, turn, _, reads, given, trace = scripted
+    trace(traced)
+    for _ in range(3):
+        turn(True, held=True)
+    tail = sum(SCRIPT[p][1] for p in ("book", "metrics")) + 3
+    host = sum(SCRIPT[p][1] for p in HOST) + SCRIPT["admit"][1]
+    assert stats[CPU_KEY] == 3 * host - tail
+    before = reads()
+    turn(True, held=True)
+    assert stats[CPU_KEY] == 4 * host - tail
+    # wait, hold, readback: two reads each; traced: all twelve spans
+    assert reads() - before == (24 if traced else 6)
+    if traced:
+        holds = [us for key, us in given if key == "wall_ns_wait"
+                 and us == pytest.approx(HOLD[1] / 1e3)]
+        assert len(holds) == 4          # engine.hold's own cpu_us
 
 
 def test_another_threads_cpu_clock_is_not_subtracted(scripted):
@@ -752,6 +811,71 @@ def test_hand_over_is_one_launch_late_and_at_once_when_the_engine_runs_dry():
     server._wake.set()
     server._turn()
     assert stats["publishes"] == turns
+
+
+def test_a_step_that_holds_hands_over_once_and_before_it_sleeps(
+        late_decision, monkeypatch):
+    """The serve loop under the late decision (llm/engine.py: step), one
+    turn at a time on a scripted clock and device: a step that holds its
+    launch back hands the step before's tokens over BEFORE it sleeps (the
+    clients of the rows that ended can only come back after that), once,
+    with a program on the device; a request that comes during the hold is
+    admitted when the hold ends and rides the mixed step launched behind
+    the flight; every stream still gets its tokens in order."""
+    import queue as queue_mod
+
+    from _scripted_device import ScriptedDevice
+
+    device = ScriptedDevice(monkeypatch)
+    server = _Turned(model_config={"n_layers": 1, "dtype": jnp.float32},
+                     engine_config=dict(ENGINE, seed=5))
+    server._thread.join(timeout=10)
+    eng, stats = server.engine, server.engine.stats
+    device.runs(eng)
+    prompts = [list(range(1, 12)), list(range(3, 9))]
+    plain = InferenceEngine(LlamaConfig.tiny(n_layers=1, dtype=jnp.float32),
+                            **dict(ENGINE, seed=5))
+    want = [plain.generate(p, max_new_tokens=n)
+            for p, n in zip(prompts, (40, 9))]
+    queues = [queue_mod.Queue(), queue_mod.Queue()]
+    first = eng.add_request(prompts[0], 40)
+    server._token_qs[first] = queues[0]
+    seen_in_hold = []
+
+    def in_hold(k):
+        # the hand-over is done: nothing is held while the step sleeps
+        seen_in_hold.append((server._held, stats["publishes"],
+                             stats["publishes_overlapped"]))
+        if k == 2:
+            rid = eng.add_request(prompts[1], 9)
+            server._token_qs[rid] = queues[1]
+    device.during_hold = in_hold
+    mixed_late = None
+    while eng.has_work():
+        publishes = stats["publishes"]
+        holds = len(device.holds)
+        server._turn()
+        meta = eng._step_meta
+        if len(device.holds) > holds:
+            assert meta["held"] and stats["publishes"] - publishes <= 1
+            if meta["late"] and meta["launched"] == "mixed":
+                mixed_late = len(device.holds) - 1
+    server._wake.set()
+    server._turn()                                  # the flush
+    assert len(device.holds) >= 5 and mixed_late == 2
+    assert all(held is None for held, _, _ in seen_in_hold)
+    # every hand-over but the last flush rode a program on the device
+    assert stats["publishes"] == stats["publishes_overlapped"] + 1
+    assert stats["late_mixed_launches"] == 1
+    assert stats["held_launches"] >= stats["late_launches"] >= 5
+    for q, tokens in zip(queues, want):
+        got, item = [], q.get_nowait()
+        while item is not None:
+            got += item
+            item = q.get_nowait()
+        assert got == tokens and q.empty()
+    # the holds are the wall of `wait`, beside the flush's own sleep
+    assert stats["wall_ns_wait"] >= sum(ns for _, ns in device.holds)
 
 
 def test_publish_overlap_pct_reads_the_two_counters():

@@ -383,3 +383,83 @@ def test_device_report_from_another_thread_while_stepping():
         assert all(r[key] == want[key] for r in reports), key
     assert all(r["devices"][0]["engine_bytes"]
                == want["devices"][0]["engine_bytes"] for r in reports)
+
+
+def test_a_held_step_sleeps_in_engine_hold_between_two_admissions(
+        tmp_path, late_decision, monkeypatch):
+    """The late decision's spans (llm/engine.py: step), on a scripted
+    clock and device so that every machine sees the same steps: a step
+    that holds its launch back is engine.admit, engine.hold, engine.admit
+    again, then pack .. dispatch of what it launches and readback, book of
+    the flight, all inside the one engine.step, whose metadata says
+    `held`, whether it launched `late` (behind the flight still running)
+    and what (`launched`); engine.hold opens in no other step, and the
+    three counters count what the spans say."""
+    from _scripted_device import ScriptedDevice
+
+    device = ScriptedDevice(monkeypatch)
+    eng = InferenceEngine(LlamaConfig.tiny(n_layers=1, dtype=jnp.float32),
+                          **ENGINE)
+    eng.generate(list(range(1, 20)), max_new_tokens=6)      # compile
+    eng._program_ns.clear()         # what ran on the CPU is no estimate
+    device.runs(eng)
+    eng.add_request(list(range(1, 12)), 40)
+    device.during_hold = lambda k: k == 2 and eng.add_request(
+        list(range(3, 9)), 9)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    before = dict(eng.stats)
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        while eng.has_work():
+            eng.step()
+    finally:
+        jax.profiler.stop_trace()
+    after = dict(eng.stats)
+    path = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*",
+                                  "*.xplane.pb"))[-1]
+    events = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("engine."):
+                    events.append((ev.name, ev.start_ns,
+                                   ev.start_ns + ev.duration_ns,
+                                   dict(ev.stats)))
+    events.sort(key=lambda e: (e[1], -e[2]))
+    steps = [e for e in events if e[0] == "engine.step"]
+    kids = [[c[0] for c in events
+             if c[0] not in ("engine.step", "engine.metrics")
+             and s[1] <= c[1] and c[2] <= s[2]] for s in steps]
+    # every hold lies in a step, and only in steps that say `held`
+    assert sum(names.count("engine.hold") for names in kids) \
+        == sum(e[0] == "engine.hold" for e in events) \
+        == len(device.holds) >= 5
+    launch = ["engine.pack", "engine.h2d", "engine.dispatch"]
+    booking = ["engine.readback", "engine.book"]
+    held = late = late_mixed = 0
+    for step, names in zip(steps, kids):
+        meta = step[3]
+        launched = meta["launched"] != "none"
+        if "engine.hold" in names:
+            assert meta["held"] and meta["kind"] != "none"
+            # pack opens before the engine knows it launches nothing
+            assert [n for n in names if n != "engine.pack" or launched] \
+                == ["engine.admit", "engine.hold", "engine.admit"] \
+                + (launch if launched else []) + booking, (names, meta)
+        else:
+            assert names.count("engine.admit") == 1
+        held += bool(meta["held"])
+        late += bool(meta["late"])
+        late_mixed += bool(meta["late"]) and meta["launched"] == "mixed"
+        if meta["late"]:
+            assert meta["held"] and launched and meta["ahead"] is not None
+    assert (held, late, late_mixed) == tuple(
+        after[k] - before[k] for k in (
+            "held_launches", "late_launches", "late_mixed_launches"))
+    assert late >= 5 and late_mixed == 1 and held > late
+    # where the flight's end was marked, the step says how far off the
+    # estimate was: nothing, on a device that runs a kind a fixed time
+    assert {m["end_late_us"] for _, _, _, m in steps
+            if "end_late_us" in m} == {0.0}

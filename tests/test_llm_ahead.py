@@ -14,6 +14,14 @@ a row that stops on EOS after its next program was packed, a pool that
 cannot serve the look-ahead, a copy-on-write admission beside a program
 in flight, the window group at its size; and the rule that keeps a decode
 loop from being queued where an arrival would have to wait for it.
+
+That rule is a LATE decision: a launch held back behind a free slot is
+made when the program in flight is due (its expected end less the host's
+stretch), with whoever arrived by then. The suite's engines never clock a
+program (tests/conftest.py), so the cases up to "the late decision" below
+see the rule as it stands before a (kind, shape) has been seen; the cases
+after it ask for the real estimate (``late_decision``) and run on a clock
+and a device that the script moves (tests/_scripted_device.py).
 """
 
 import functools
@@ -28,10 +36,12 @@ from ray_tpu.llm.cache import SCRATCH_PAGE
 from ray_tpu.llm.engine import InferenceEngine
 from ray_tpu.models.llama import init_params
 from _blocks import BLOCKS, config
+from _scripted_device import ScriptedDevice
 
 ENGINE = dict(page_size=8, total_pages=128, max_batch=4, max_seq_len=128,
               prefill_chunk=16, prefill_rows=2, decode_chunk=4)
-COUNTERS = ("ahead_dispatches", "late_retired_rows", "ahead_drains")
+COUNTERS = ("ahead_dispatches", "late_retired_rows", "ahead_drains",
+            "held_launches", "late_launches", "late_mixed_launches")
 
 
 @functools.lru_cache(maxsize=None)
@@ -77,10 +87,12 @@ def _hold_idle_slots_blank(eng):
 
 
 def _serve(block: str, ahead: bool, requests, at=None, prepare=None,
-           **settings):
+           device=None, **settings):
     """Serve ``requests`` (request i added before step ``at[i]``, default
-    all before the first) on a fresh engine of ``block`` (``prepare(eng)``
-    first, if given) and drain it.
+    all before the first; ``("hold", k)``: inside the k-th hold of
+    ``device``, the scripted one its programs then run on) on a fresh
+    engine of ``block`` (``prepare(eng)`` first, if given) and drain it.
+    Every step's engine.step metadata goes to ``eng.metas``.
     Returns ([(tokens, finish reason, cached tokens)], engine)."""
     cfg, params = _block(block)
     eng = InferenceEngine(cfg, params, **{**ENGINE, **settings})
@@ -89,12 +101,19 @@ def _serve(block: str, ahead: bool, requests, at=None, prepare=None,
     if prepare is not None:
         prepare(eng)
     at = list(at or [0] * len(requests))
-    rids, done = {}, {}
-    for step in range(4000):
+    rids, done, eng.metas = {}, {}, []
+
+    def arrive(when):
         for i, (prompt, n_new) in enumerate(requests):
-            if at[i] == step:
+            if at[i] == when:
                 rids[i] = eng.add_request(prompt, n_new)
+    if device is not None:
+        device.runs(eng)
+        device.during_hold = lambda k: arrive(("hold", k))
+    for step in range(4000):
+        arrive(step)
         done.update(eng.step())
+        eng.metas.append(eng._step_meta)
         if len(rids) == len(requests) and not eng.has_work():
             break
     assert not eng.has_work() and eng._flight is None
@@ -345,3 +364,298 @@ def test_forced_synchronous_order_books_what_it_launched():
         assert eng._flight is None
         assert eng.stats["h2d_arrays"] - before["h2d_arrays"] == 1
     assert not any(eng.stats[k] for k in COUNTERS)
+
+
+# ------------------------------------------------------- the late decision
+
+#: blocks whose slot state or window tables a loop over a FREE slot walks
+#: (blank: _hold_idle_slots_blank), and the plain block beside them
+LATE_BLOCKS = sorted(name for name, block in BLOCKS.items()
+                     if block.state or block.window) + ["mistral"]
+
+
+def _held(eng):
+    """(steps that came to the hold-back, those that launched behind the
+    running flight, the mixed steps among them), from the steps' own
+    metadata: what the three counters must say."""
+    held = [m for m in eng.metas if m["held"]]
+    late = [m for m in held if m["late"]]
+    assert all(m["launched"] != "none" for m in late)
+    assert not any(m["late"] for m in eng.metas if not m["held"])
+    return (len(held), len(late),
+            sum(m["launched"] == "mixed" for m in late))
+
+
+def _late_equals_sync(block, requests, at, device, **settings):
+    """Serve on the scripted device, deciding late, and one program at a
+    time: the same tokens, reasons and free pages."""
+    got, late = _serve(block, True, requests, at, device=device, **settings)
+    plain = [when if isinstance(when, int) else 0 for when in at]
+    want, sync = _serve(block, False, requests, plain, **settings)
+    for i, (g, w) in enumerate(zip(got, want)):
+        assert g == w, f"request {i}: late {g}, one at a time {w}"
+    assert _free(late) == _free(sync)
+    stats = late.stats
+    assert (stats["held_launches"], stats["late_launches"],
+            stats["late_mixed_launches"]) == _held(late)
+    return late
+
+
+@pytest.mark.parametrize("block", LATE_BLOCKS)
+def test_a_held_launch_is_made_when_the_flight_is_due(
+        block, late_decision, monkeypatch):
+    """Three rows on four slots, then arrivals inside holds: once a kind
+    of program has been clocked, a step that finds one in flight and only
+    a decode loop to queue behind a FREE slot hands over, sleeps until the
+    flight is due, admits again and launches behind it: a mixed step if a
+    request came during the hold, else the loop, free slot or not (every
+    descriptor's idle slots blank: _serve). The tokens are the synchronous
+    engine's."""
+    device = ScriptedDevice(monkeypatch)
+    cfg, _ = _block(block)
+    requests = _requests(7, 7, cfg.vocab_size, most_new=30)
+    at = [0, 0, 0, ("hold", 1), ("hold", 3), ("hold", 3), 25]
+    seen = []
+    eng = _late_equals_sync(block, requests, at, device,
+                            prepare=lambda eng: seen.append(_launches(eng)))
+    held, late, late_mixed = _held(eng)
+    assert late >= 8 and late_mixed == 2 and held > late
+    # a loop was queued behind a running program with a slot free ...
+    assert any(kind == "decode" and ahead and free
+               for kind, ahead, free, _, _ in seen[0])
+    # ... and every hold was a sleep by design: the wall of `wait`
+    assert len(device.holds) >= late
+    assert eng.stats["wall_ns_wait"] >= sum(ns for _, ns in device.holds)
+    # where both ends of the flight were clocked, it ended when expected
+    # (the scripted device runs a kind for a fixed time)
+    assert {m["end_late_us"] for m in eng.metas
+            if "end_late_us" in m} == {0.0}
+
+
+def test_no_launch_on_an_empty_pipeline_once_the_kinds_are_clocked(
+        late_decision, monkeypatch):
+    """The point of it. Two rows of four decode for a long time: after
+    the first loop was clocked (one drain), every loop is launched behind
+    the one before it, and the device does not wait for the host again."""
+    device = ScriptedDevice(monkeypatch)
+    cfg, _ = _block("mistral")
+    requests = [(prompt, 60) for prompt, _ in
+                _requests(19, 2, cfg.vocab_size, longest=30)]
+    got, eng = _serve("mistral", True, requests, device=device)
+    loops = eng.stats["decode_dispatches"]
+    assert loops >= 14
+    # held: the mixed step's drain (no loop clocked yet), the first loop's
+    # return unbooked is no hold; from then on every step holds and
+    # launches late, but the last (nothing is left to launch)
+    assert eng.stats["late_launches"] >= loops - 3
+    assert eng.stats["held_launches"] - eng.stats["late_launches"] <= 3
+    idle_at_the_start = device.idle_ns
+    # the same again with no program ever clocked (the rule as it was):
+    # every loop is launched and booked in one step, on an empty pipeline,
+    # and the device waits a stretch before each
+    device2 = ScriptedDevice(monkeypatch)
+    monkeypatch.setattr(InferenceEngine, "_expected_end_ns",
+                        lambda self, flight: None)
+    _, drained = _serve("mistral", True, requests, device=device2)
+    assert drained.stats["late_launches"] == 0 and not device2.holds
+    assert drained.stats["decode_dispatches"] == loops
+    assert device2.idle_ns > 4 * max(idle_at_the_start, 1)
+    assert device2.idle_ns >= (loops - 1) * 6 * device2.TICK_NS
+
+
+def test_a_flight_that_has_landed_is_booked_at_once_with_no_sleep(
+        late_decision, monkeypatch):
+    """Programs that take no time (the CPU's tiny ones, to the engine's
+    eye), on an engine that believes they run for milliseconds: whenever
+    a step looks, its flight has landed, whatever the estimate says. It
+    never sleeps, launches nothing late, and books at once, as before."""
+    device = ScriptedDevice(monkeypatch, run_ns={"mixed": 0, "decode": 0})
+    cfg, _ = _block("mistral")
+    requests = _requests(7, 6, cfg.vocab_size, most_new=30)
+
+    def believe(eng):
+        eng._program_ns.update({("mixed", 1): [4_000_000],
+                                ("mixed", 2): [4_000_000],
+                                ("decode", 0): [9_000_000]})
+    eng = _late_equals_sync("mistral", requests, [0, 0, 0, 6, 9, 30],
+                            device, prepare=believe)
+    assert eng.stats["held_launches"] > 5
+    assert eng.stats["late_launches"] == 0 and device.holds == []
+    assert eng.stats["wall_ns_wait"] == 0
+
+
+def test_a_program_never_clocked_is_booked_at_once_with_no_sleep(
+        monkeypatch):
+    """No ``late_decision``: the suite's engines (tests/conftest.py), and
+    any engine before it has seen a (kind, shape) once. Nothing sleeps,
+    nothing is launched behind a free slot: a program in flight with only
+    a loop to follow it is booked at once (the hold-back, counted), and a
+    loop is launched and booked in one step."""
+    device = ScriptedDevice(monkeypatch)
+    cfg, _ = _block("mistral")
+    requests = _requests(7, 6, cfg.vocab_size, most_new=30)
+    seen = []
+    eng = _late_equals_sync(
+        "mistral", requests, [0, 0, 0, 6, 9, 30], device,
+        prepare=lambda eng: seen.append(_launches(eng)))
+    assert eng.stats["held_launches"] >= 2
+    assert eng.stats["late_launches"] == 0 and device.holds == []
+    loops = [rest for kind, *rest in seen[0] if kind == "decode"]
+    assert len(loops) >= 8
+    assert not any(ahead and free for ahead, free, _, _ in loops)
+
+
+def test_an_estimate_that_is_too_long_comes_down_in_a_few_programs(
+        late_decision, monkeypatch):
+    """The estimate fails: from one launch on a loop runs a third of the
+    time the loops before it ran. The hold looks at the flight between
+    its slices and ends with it; the launch is made all the same (the
+    chip is idle), the booking keeps how long the flight can have run at
+    most, and after a few such programs the lower quartile of the newest
+    runs is the new time: the launches are late again."""
+    device = ScriptedDevice(monkeypatch,
+                            run_ns={"decode": 27_000_000})
+    cfg, params = _block("mistral")
+    requests = [(prompt, 90) for prompt, _ in
+                _requests(19, 2, cfg.vocab_size, longest=30)]
+    eng = InferenceEngine(cfg, params, **ENGINE)
+    _hold_idle_slots_blank(eng)
+    device.runs(eng)
+    rids = [eng.add_request(prompt, n_new) for prompt, n_new in requests]
+    done, metas, changed_at = {}, [], None
+    while eng.has_work():
+        if changed_at is None and eng.stats["late_launches"] == 4:
+            device.run_ns["decode"] = 9_000_000     # from the next launch
+            changed_at = len(metas)
+        done.update(eng.step())
+        metas.append(eng._step_meta)
+    # before the change every hold of a loop was three slices long ...
+    slices = [ns for _, ns in device.holds]
+    assert slices.count(10_000_000) >= 6
+    # ... after it the flight lands inside the first slice: the step
+    # launches behind a landed flight (held, not late), a few times
+    missed = [i for i, m in enumerate(metas)
+              if i > changed_at and m["held"] and not m["late"]
+              and m["launched"] == "decode"]
+    assert 1 <= len(missed) <= 4
+    assert all(m["held"] and m["late"] for m in metas[missed[-1] + 1:-1])
+    assert len(metas) - missed[-1] > 6
+    runs = sorted(eng._program_ns["decode", 0])
+    assert runs[len(runs) // 4] <= 9_100_000
+    # the device waited for the host at each miss, and only there
+    want, _ = _serve("mistral", False, requests)
+    assert [(done[r], eng.finish_reason(r)) for r in rids] \
+        == [(tokens, reason) for tokens, reason, _ in want]
+
+
+def test_a_waiter_at_a_full_batch_still_drains(late_decision, monkeypatch):
+    """Every slot taken, a fifth request waiting, a row ending by length
+    in the flight: no loop is queued behind it and nothing is held (the
+    hold-back is for a slot that is free while NOBODY waits): the booking
+    frees the slot and the request takes it in the next step, on an empty
+    pipeline, as before."""
+    device = ScriptedDevice(monkeypatch)
+    cfg, params = _block("mistral")
+    requests = [(prompt, n_new) for (prompt, _), n_new in zip(
+        _requests(23, 5, cfg.vocab_size, 16), (19, 33, 33, 41, 9))]
+    eng = InferenceEngine(cfg, params, **ENGINE)
+    device.runs(eng)
+    seen = _launches(eng)
+    for prompt, n_new in requests:
+        eng.add_request(prompt, n_new)
+    admitted_at = None
+    while eng.has_work():
+        eng.step()
+        if admitted_at is None and not eng.waiting:
+            admitted_at = len(seen) - 1
+            assert eng.stats["held_launches"] == 0 and not device.holds
+    loops = [rest for kind, *rest in seen[:admitted_at] if kind == "decode"]
+    assert sum(ahead for ahead, *_ in loops) >= 2
+    assert all(all_on for ahead, _, all_on, _ in loops if ahead)
+    assert seen[admitted_at][:2] == ("mixed", False)
+    # later, slots free and nobody waiting: the late decision
+    assert eng.stats["late_launches"] > 0
+    _late_equals_sync("mistral", requests, [0] * 5,
+                      ScriptedDevice(monkeypatch))
+
+
+def test_the_hook_is_called_once_and_before_the_hold(late_decision,
+                                                     monkeypatch):
+    """after_dispatch in a step that holds: once, BEFORE the sleep (the
+    finished rows' clients can only come back after the hand-over), and
+    not again after the launch the step then makes."""
+    device = ScriptedDevice(monkeypatch)
+    cfg, params = _block("mistral")
+    eng = InferenceEngine(cfg, params, **ENGINE)
+    device.runs(eng)
+    for prompt, _ in _requests(19, 2, cfg.vocab_size, longest=30):
+        eng.add_request(prompt, 40)
+    calls = []
+    device.during_hold = lambda k: calls.append("hold")
+    slept = 0
+    while eng.has_work():
+        n = len(calls)
+        eng.step(lambda: calls.append("hook"))
+        new = calls[n:]
+        meta = eng._step_meta
+        assert new.count("hook") == (
+            meta["launched"] != "none" or meta["kind"] != "none")
+        if "hold" in new:
+            assert new == ["hook", "hold"]
+            slept += 1
+    assert slept >= 5 and slept == len(device.holds)    # one slice each
+
+
+@pytest.mark.parametrize("block", ["mistral", "lfm2", "mimo"])
+def test_the_real_clocks_serve_the_synchronous_engines_tokens(
+        block, late_decision):
+    """Nothing scripted: the machine's clock, the backend's is_ready, a
+    real sleep. Which steps hold and which find their flight landed is
+    this machine's timing; the tokens, the reasons and the free pages are
+    not, and the three counters stay consistent."""
+    cfg, _ = _block(block)
+    requests = _requests(7, 9, cfg.vocab_size, most_new=40)
+    got, eng, _ = _both(block, requests, [0, 0, 0, 3, 8, 8, 20, 40, 41])
+    stats = eng.stats
+    assert stats["held_launches"] >= stats["late_launches"] \
+        >= stats["late_mixed_launches"] >= 0
+    assert (stats["held_launches"], stats["late_launches"],
+            stats["late_mixed_launches"]) == _held(eng)
+
+
+def test_late_launch_pct_reads_the_two_counters(late_decision, monkeypatch):
+    """The per-layer metric of the late decision, found by what it reads
+    (tests/_readings.py): late_launches over held_launches of the window,
+    in the cells dispatch_ahead_pct is read in; nothing, not an error, for
+    a program without the counters (the parent of the PR that brought
+    them)."""
+    from _readings import entry
+
+    cell = "reason-lfm2-1chip"
+    got, args, reader = entry("engine_clocks", cell, num=["late_launches"])
+    ahead, _, _ = entry("engine_clocks", cell, num=["ahead_dispatches"])
+    assert args["den"] == ["held_launches"] and args["scale"] == 100
+    for key in ("unit", "better", "source", "layer", "moves", "workloads"):
+        assert got[key] == ahead[key], key
+    assert got["source"] == "program_counter" and got["unit"] == "%"
+    device = ScriptedDevice(monkeypatch)
+    cfg, params = _block("mistral")
+    eng = InferenceEngine(cfg, params, **ENGINE)
+    device.runs(eng)
+    for prompt, _ in _requests(19, 2, cfg.vocab_size, longest=30):
+        eng.add_request(prompt, 40)
+    for _ in range(6):
+        eng.step()
+    before = dict(eng.stats)
+    while eng.has_work():
+        eng.step()
+    after = dict(eng.stats)
+    held, late = (after[k] - before[k]
+                  for k in ("held_launches", "late_launches"))
+    assert held > late >= 5
+    data = {"stats_open": before, "stats_close": after, "config": {},
+            "window_s": 1.0}
+    assert reader.read(data, args) == pytest.approx(100.0 * late / held)
+    old = {k: {s: v for s, v in data[k].items() if "_launches" not in s}
+           for k in ("stats_open", "stats_close")}
+    assert reader.read(dict(old, config={}), args) is None

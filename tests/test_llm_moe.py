@@ -242,7 +242,8 @@ def test_dense_configuration_is_untouched():
         "ragged_real_tokens", "ragged_slot_tokens", "cow_copies",
         "preemptions", "chunk_rows", "chunk_rows_joined",
         "ragged_small_dispatches", "h2d_arrays", "ahead_dispatches",
-        "late_retired_rows", "ahead_drains"} \
+        "late_retired_rows", "ahead_drains", "held_launches",
+        "late_launches", "late_mixed_launches"} \
         | set(WALL_KEYS + (CPU_KEY,)) \
         | set(startup_clocks.ENGINE_KEYS)            # every model's clocks
     # the step programs' outputs keep their shapes: [R] and [K, B]
