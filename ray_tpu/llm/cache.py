@@ -36,7 +36,12 @@ normaliser, a gated-delta-rule layer's FLOAT32 matrix state and its conv's
 last inputs (megabytes a slot and layer: there ``max_batch`` is a memory
 decision as ``total_pages`` is). Both kinds live in one pool where a
 configuration has both, a LATENT page leaf beside slot-state leaves too
-(GigaChat3.5: one {"k"} leaf, no "v", and the delta layers' two leaves).
+(GigaChat3.5: one {"k"} leaf, no "v", and the delta layers' two leaves);
+ALL THREE kinds of cache at once in Phi-4-mini-flash's pool: a Mamba-1
+layer's float32 state and conv inputs a slot (``SLOT_STATE[MAMBA1]``), the
+window page group, and a full group of ONE layer whose pages the cross
+layers read and do not write (a differential pair of 64-wide heads held as
+one 128-lane head: ``page_heads``).
 A configuration with NO attention layer
 has page leaves with no layer in them: the host's page accounting runs as
 ever over pages that hold nothing and cost nothing (a deployment sizes
@@ -71,8 +76,8 @@ from typing import Dict, List, Optional, Tuple
 
 import jax.numpy as jnp
 
-from ray_tpu.models.llama import (ATTENTION, CONV, DELTA, MAMBA, RETENTION,
-                                  WINDOW, LlamaConfig)
+from ray_tpu.models.llama import (ATTENTION, CONV, CROSS, DELTA, GMU, MAMBA,
+                                  MAMBA1, RETENTION, WINDOW, LlamaConfig)
 from ray_tpu.ops.retention import expanded_dim
 
 logger = logging.getLogger(__name__)
@@ -284,6 +289,7 @@ class PrefixCache:
 STATE_LEAF, SSM_LEAF, SSM_CONV_LEAF = "conv", "ssm", "ssm_conv"
 RET_LEAF, RET_NORM_LEAF = "retention", "retention_norm"
 DELTA_LEAF, DELTA_CONV_LEAF = "delta", "delta_conv"
+SSM1_LEAF, SSM1_CONV_LEAF = "ssm1", "ssm1_conv"
 
 #: what a layer of each kind keeps per BATCH SLOT: kind -> {leaf: cfg ->
 #: (its shape after [layers of the kind, max_batch + 1], its dtype)}. The
@@ -332,6 +338,20 @@ SLOT_STATE = {
         # SiLU), oldest first
         DELTA_CONV_LEAF: lambda c: (
             (c.delta_conv - 1, delta_channels(c)), c.dtype)},
+    MAMBA1: {
+        # a Mamba-1 layer's state, one value a (state index, channel) pair,
+        # the state index on the sublanes and the channels on the lanes
+        # (the state-space leaf's layout: what both kernels of
+        # ops/selective_scan.py read), FLOAT32 whatever the model's dtype:
+        # a pair that decays by 0.9999 a token takes increments a
+        # thousandth of what it holds, which a bfloat16 state drops
+        SSM1_LEAF: lambda c: ((c.ssm1_state, c.ssm1_channels), jnp.float32),
+        # the last inputs of its conv (before the bias and the SiLU),
+        # oldest first
+        SSM1_CONV_LEAF: lambda c: (
+            (c.ssm1_conv - 1, c.ssm1_channels), c.dtype)},
+    GMU: {},                # reads the newest mamba1 layer's output: nothing
+    CROSS: {},              # reads the newest full layer's pages: nothing
 }
 STATE_LEAVES = tuple(leaf for leaves in SLOT_STATE.values()
                      for leaf in leaves)
@@ -375,6 +395,19 @@ def window_group_pages(cfg: LlamaConfig, page_size: int, max_batch: int,
     w = cfg.sliding_window
     return max_batch * window_table_width(w, decode_chunk, page_size) \
         + prefill_rows * window_table_width(w, prefill_chunk, page_size) + 1
+
+
+def page_heads(cfg: LlamaConfig, window: bool = False) -> Tuple[int, int,
+                                                                 int]:
+    """(key/value heads, K row, V row) of a page group as the pool holds
+    them (before any lane padding). Differential attention holds a PAIR of
+    adjacent heads as one head twice as wide, [k1 | k2] and [v1 | v2]: at a
+    published head of 64 that is a whole 128-lane row and no byte of it
+    padding, and the kernels score a pair's two queries against it as
+    [q1 | 0] and [0 | q2] (llm/model.py: _diff_attention)."""
+    heads = cfg.window_kv_heads if window else cfg.n_kv_heads
+    pair = 2 if cfg.diff_attention else 1
+    return heads // pair, cfg.qk_head_dim * pair, cfg.v_dim * pair
 
 
 def slot_state_kinds(cfg: LlamaConfig) -> Tuple[str, ...]:
@@ -473,9 +506,9 @@ def make_kv_cache(cfg: LlamaConfig, total_pages: int, page_size: int,
         def rows(width):
             return -(-width // LANES) * LANES if lane_pad else width
 
-        shape = (len(cfg.layers_of(ATTENTION)), total_pages, cfg.n_kv_heads,
-                 page_size)
-        dk, dv = rows(cfg.qk_head_dim), rows(cfg.v_dim)
+        hkv, dk, dv = page_heads(cfg)
+        shape = (len(cfg.layers_of(ATTENTION)), total_pages, hkv, page_size)
+        dk, dv = rows(dk), rows(dv)
         windowed = bool(cfg.layers_of(WINDOW))
         if kv_dtype == "int8":
             if windowed or dk != dv:
@@ -498,7 +531,7 @@ def make_kv_cache(cfg: LlamaConfig, total_pages: int, page_size: int,
                     "group: make_kv_cache needs window_pages "
                     "(window_group_pages)")
             shape = (len(cfg.layers_of(WINDOW)), window_pages,
-                     cfg.window_kv_heads, page_size)
+                     page_heads(cfg, window=True)[0], page_size)
             kv.update(k_win=jnp.zeros(shape + (dk,), dtype),
                       v_win=jnp.zeros(shape + (dv,), dtype))
     for kind in kinds:

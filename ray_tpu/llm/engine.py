@@ -62,7 +62,11 @@ admission, scheduling), rebuilt TPU-first around ONE ragged step:
     page_steps_window, each group's pages in use added at every dispatch,
     and rows_inside_window, the decode row-steps of sequences no longer
     than the window;
-    no prefix cache there; preemption stays recompute from 0;
+    no prefix cache there; preemption stays recompute from 0; where
+    layers without pages of their own read ONE layer's (cross attention:
+    Phi-4-mini-flash, the first configuration with state a slot, a window
+    group and a full group at once), stats kv_token_layer_bytes and
+    shared_kv_readers say what a token costs there and how many read it;
   - ONE DESCRIPTOR a dispatch: every integer a program takes (tokens,
     positions, pages, the rows' spans, the page table) is a field of one
     flat int32 buffer kept on the host a program shape (_descriptor_turns, in
@@ -186,7 +190,7 @@ from ray_tpu.llm.cache import (SCRATCH_PAGE, STATE_LEAVES, WINDOW_LEAVES,
                                window_group_pages)
 from ray_tpu.llm import model as M
 from ray_tpu.llm.tp import build_tp_mesh
-from ray_tpu.models.llama import LlamaConfig
+from ray_tpu.models.llama import ATTENTION, CROSS, LlamaConfig
 from ray_tpu.util import startup_clocks
 
 TraceAnnotation = jax.profiler.TraceAnnotation
@@ -650,6 +654,14 @@ class InferenceEngine:
             self.stats.update(
                 kv_token_layer_bytes=self._kv_token_layer_bytes,
                 kv_row_width=self._kv_row_width)
+        if cfg.layers_of(CROSS):
+            # pages that layers with none of their own read: what a token
+            # costs in the layer that holds them, and how many layers read
+            # that one layer's pages (itself counted)
+            self.stats.update(
+                kv_token_layer_bytes=self._kv_token_layer_bytes,
+                shared_kv_readers=len(cfg.layers_of(CROSS))
+                + bool(cfg.layers_of(ATTENTION)))
         # every span of the host loop and its wall / CPU counters; the
         # serve loop opens serve.publish and serve.wait through it too
         self.phase = PhaseClocks(self.stats).phase

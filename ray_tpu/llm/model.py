@@ -6,11 +6,13 @@ code in-tree), rebuilt on ray_tpu's functional decoder (models/llama.py —
 same params pytree, so training checkpoints serve directly).
 
 ONE WALK over the layers, for every block (``_layers``). ``_pattern``
-reads the configuration as (leading layers, one period, how many
-periods), each layer an (operator kind, feed-forward kind): the leading
-layers run once, then ONE ``lax.scan`` over the periods, its body one
-period, so depth does not unroll. The Llama / Mistral and OLMoE blocks
-are patterns of period 1. Weights are one stack per KIND
+reads the configuration as (leading layers, then one period and how many
+periods for each SEGMENT of the rest), each layer an (operator kind,
+feed-forward kind): the leading layers run once, then ONE ``lax.scan`` a
+segment over its periods, its body one period, so depth does not unroll.
+Every block but one is ONE segment (the Llama / Mistral and OLMoE blocks
+patterns of period 1); a decoder-hybrid-decoder, whose depth is not one
+period repeated, is three. Weights are one stack per KIND
 (models/llama.py; the Llama tree holds every layer's leaves flat and is
 split by name, ``_stacks``), closed over and indexed by a layer's ordinal
 among the layers of its kind; the routed experts' [layer, expert] weights
@@ -80,10 +82,28 @@ kind keeps per batch slot is declared beside it in llm/cache.py
     write), beside the last inputs of its conv over q, k and v. The first
     block whose pool holds a latent page leaf AND slot-state leaves.
 
-The three recurrences take the rows of a ragged batch by ONE protocol
+  - a DECODER-HYBRID-DECODER (Phi-4-mini-flash; models/llama.py: MAMBA1,
+    GMU, CROSS): the Mamba-1 selective scan (``_mamba1``: every (channel,
+    state index) pair of a float32 state [N, channels] a slot decays on its
+    own, beside the last inputs of its conv; its scan output, before the
+    gate, rides the walk's carry as the MEMORY); the gated memory unit
+    (``_gmu``: W2(m * silu(h W1)) on the newest memory; nothing kept);
+    DIFFERENTIAL attention on the full and the window operator
+    (``_diff_attention``: a pair of adjacent heads ONE 128-lane head of
+    the pool, its two queries [q1 | 0] and [0 | q2], so the paged kernels
+    run as they are; the combine after them) and on cross attention
+    (``_cross_attention``: own queries over the pages the newest full
+    layer wrote, no write, nothing kept); every norm a LayerNorm with a
+    bias (``_norm``), biases on the attention projections; no positions.
+    The first block with state a slot, a window page group AND a full
+    group at once, and the first whose full group has ONE layer that
+    eight operators read.
+
+The four recurrences take the rows of a ragged batch by ONE protocol
 (``_slot_rows``): the leading one-token rows update their slots in place
-(a Pallas kernel each, ops/ssm.py, ops/retention.py, ops/delta.py), chunk
-rows start from their slot's state and leave their last state there.
+(a Pallas kernel each, ops/ssm.py, ops/retention.py, ops/delta.py,
+ops/selective_scan.py), chunk rows start from their slot's state and leave
+their last state there.
 
 ONE step program for everything (`_ragged_step_body`): the engine packs
 decode tokens and prefill-chunk tokens into a single RAGGED batch
@@ -157,14 +177,16 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu.llm import tp as TP
 from ray_tpu.llm.cache import (DELTA_CONV_LEAF, DELTA_LEAF, RET_LEAF,
-                               RET_NORM_LEAF, SCRATCH_PAGE, SSM_CONV_LEAF,
-                               SSM_LEAF, STATE_LEAF, STATE_LEAVES,
-                               WINDOW_LEAVES, keeps_slot_state,
-                               make_kv_cache, window_table_width)
-from ray_tpu.models.llama import (ATTENTION, CONV, DELTA, MAMBA, RETENTION,
-                                  WINDOW, LlamaConfig, Params, _rmsnorm,
-                                  _rope, _rope_pairs, init_params)
-from ray_tpu.ops import delta, moe, retention, ssm
+                               RET_NORM_LEAF, SCRATCH_PAGE, SSM1_CONV_LEAF,
+                               SSM1_LEAF, SSM_CONV_LEAF, SSM_LEAF,
+                               STATE_LEAF, STATE_LEAVES, WINDOW_LEAVES,
+                               keeps_slot_state, make_kv_cache,
+                               window_table_width)
+from ray_tpu.models.llama import (ATTENTION, CONV, CROSS, DELTA, GMU, MAMBA,
+                                  MAMBA1, RETENTION, WINDOW, LlamaConfig,
+                                  Params, _rmsnorm, _rope, _rope_pairs,
+                                  init_params)
+from ray_tpu.ops import delta, moe, retention, selective_scan, ssm
 from ray_tpu.ops.paged_attention import (kernels_supported,
                                          ragged_paged_attention,
                                          write_ragged_kv)
@@ -172,8 +194,8 @@ from ray_tpu.parallel.mesh import shard_map_compat
 from ray_tpu.util import compile_tracker
 
 # {"k", "v"[, "k_scale", "v_scale"][, "k_win", "v_win"][, "conv"][, "ssm",
-# "ssm_conv"][, "retention", "retention_norm"]}, or a latent pool's {"k"}
-# [, "delta", "delta_conv"]
+# "ssm_conv"][, "retention", "retention_norm"][, "ssm1", "ssm1_conv"]}, or a
+# latent pool's {"k"}[, "delta", "delta_conv"]
 KVCache = dict  # (llm/cache.py)
 
 
@@ -181,10 +203,18 @@ def _maybe_psum(x, tp_axis):
     return lax.psum(x, tp_axis) if tp_axis else x
 
 
-def _norm(x, w, cfg: LlamaConfig):
+def _norm(x, w, cfg: LlamaConfig, b=None):
     """The block's RMSNorm: x / rms(x) * w, or with ``norm_gate`` (a
     zero-centred gated norm) * norm_gate * sigmoid(w), which is 1 at w = 0
-    where the gate is 2."""
+    where the gate is 2; with ``layer_norm`` a LayerNorm, (x - mean) /
+    sqrt(var + eps) * w + ``b`` (the weight's ``*_b`` leaf)."""
+    if cfg.layer_norm:
+        xf = x.astype(jnp.float32)
+        xf = xf - jnp.mean(xf, axis=-1, keepdims=True)
+        xf = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
+                            + cfg.norm_eps)
+        return (xf * w.astype(jnp.float32)
+                + b.astype(jnp.float32)).astype(x.dtype)
     if cfg.norm_gate:
         w = cfg.norm_gate * jax.nn.sigmoid(w.astype(jnp.float32))
     return _rmsnorm(x, w, cfg.norm_eps)
@@ -226,7 +256,7 @@ def _project_qkv(lp, h, cfg: LlamaConfig):
 
 def _mlp(lp, x, cfg: LlamaConfig, tp_axis=None):
     cd = cfg.dtype
-    h = _norm(x, lp["mlp_norm"], cfg)
+    h = _norm(x, lp["mlp_norm"], cfg, lp.get("mlp_norm_b"))
     gate = moe.gate_half(h @ lp["w_gate"].astype(cd), cfg.ffn_clamp)
     up = moe.up_half(h @ lp["w_up"].astype(cd), cfg.ffn_clamp)
     # w_down is row-parallel under tp: each shard holds ffn/tp rows, the
@@ -319,6 +349,26 @@ SCOPE_GATE, SCOPE_HEAD = "attn_gate", "lm_head"
 #: the one-token update (the Pallas kernel); the chunk rows' chunk form
 SCOPE_DELTA_PROJ, SCOPE_DELTA_UPDATE, SCOPE_DELTA_CHUNK = \
     "delta_proj", "delta_update", "delta_chunk"
+
+
+#: ... a decoder-hybrid-decoder (models/llama.py: MAMBA1, GMU, CROSS): the
+#: whole Mamba-1 operator, and inside it everything around the recurrence
+#: (the projections, the conv, dt, the gate, w_out), the one-token update
+#: and the chunk rows' scan (a Pallas kernel each); the gated memory unit;
+#: inside "attention" the whole cross operator, its projections, and its
+#: reading of the newest full layer's pages (the kernel alone); and the
+#: differential combine of every attention operator (the two softmaxes'
+#: difference, the norm over a pair, 1 - lambda_init), inside the
+#: operator's projection scope
+SCOPE_SSM1, SCOPE_SSM1_PROJ, SCOPE_SSM1_UPDATE, SCOPE_SSM1_SCAN = \
+    "ssm1", "ssm1_proj", "ssm1_update", "ssm1_scan"
+SCOPE_GMU, SCOPE_CROSS, SCOPE_CROSS_PROJ, SCOPE_SHARED_KV, SCOPE_DIFF = \
+    "gmu", "attn_cross", "attn_cross_proj", "shared_kv", "attn_diff"
+#: what one layer hands a later one that is neither a page nor a slot's
+#: state rides the walk's carry beside the pool, under this key (``_layers``
+#: puts it there and takes it out): the newest Mamba-1 layer's scan output
+#: [T, channels], after the D skip and before the gate
+MEMORY = "memory"
 
 
 class _Rows(NamedTuple):
@@ -783,6 +833,8 @@ def _attention(lp, l, x, kv, rows: _Rows, cfg: LlamaConfig, impl):
     (x', kv)."""
     if cfg.kv_lora_rank:
         return _latent_attention(lp, l, x, kv, rows, cfg, impl)
+    if cfg.diff_attention:
+        return _diff_attention(lp, l, x, kv, rows, cfg, impl)
     return _paged_attention(lp, l, x, kv, rows, cfg, impl)
 
 
@@ -795,6 +847,9 @@ def _window_attention(lp, l, x, kv, rows: _Rows, cfg: LlamaConfig, impl):
     layer's sinks (one float32 logit a query head) in the softmax's
     denominator. Returns (x', kv)."""
     with jax.named_scope(SCOPE_ATTENTION):
+        if cfg.diff_attention:
+            return _diff_attention(lp, l, x, kv, rows, cfg, impl,
+                                   window=True)
         return _paged_attention(lp, l, x, kv, rows, cfg, impl, window=True)
 
 
@@ -879,6 +934,208 @@ def _paged_attention(lp, l, x, kv, rows: _Rows, cfg: LlamaConfig, impl,
     return x, kv
 
 
+def _mamba1(lp, l, x, kv, rows: _Rows, cfg: LlamaConfig, impl):
+    """The Mamba-1 operator (a selective scan) of one layer, on entry ``l``
+    of both of its state leaves (the layer's ordinal among the mamba1
+    layers); C = ssm1_expand * dim channels, state N, dt of rank R:
+
+        [u | z] = ln(x) [W_x | W_z]
+        xs[t] = silu(b + sum_j w[j] * u[t - (K-1) + j])   depthwise, causal
+        [delta (R) | B (N) | C (N)] = xs W_xproj
+        dt = softplus(delta W_dt + b_dt);  A = -exp(A_log)        [N, C]
+        S[n, c] <- exp(dt[c] A[n, c]) S[n, c] + dt[c] xs[c] B[n]
+        m[c] = sum_n S[n, c] C[n] + D[c] xs[c]
+        x' = x + (m * silu(z)) W_out
+
+    over a RAGGED batch. The conv takes its earlier inputs as the
+    state-space operator's does (``_conv_window``, from ``_SsmConvState``
+    over ``SSM1_CONV_LEAF``: u in the compute dtype, before the bias and
+    the SiLU). The recurrence (ops/selective_scan.py, over ``SSM1_LEAF``,
+    float32) takes the rows as ``_slot_rows`` deals them: the in-place
+    update kernel for the one-token rows, the scan kernel for the chunk
+    rows. dt, A, the conv and the recurrence are float32. m, in the
+    compute dtype, is handed on as the MEMORY: whatever gated memory unit
+    comes next reads the newest. Returns (x', kv)."""
+    cd, f32 = cfg.dtype, jnp.float32
+    N, R, K = cfg.ssm1_state, cfg.ssm1_dt_rank, cfg.ssm1_conv
+    T = x.shape[1]
+    with jax.named_scope(SCOPE_SSM1):
+        with jax.named_scope(SCOPE_SSM1_PROJ):
+            h = _norm(x, lp["mamba1_norm"], cfg, lp.get("mamba1_norm_b"))[0]
+            u, z = h @ lp["w_x"].astype(cd), h @ lp["w_z"].astype(cd)
+            conv = _SsmConvState(kv[SSM1_CONV_LEAF], l, rows, T)
+            prev = _conv_window(u, conv.fetch, rows, K)
+            w = lp["w_conv"].astype(f32)                   # [K, C]
+            xs = jax.nn.silu(lp["b_conv"].astype(f32) + sum(
+                w[K - 1 - s] * prev[s].astype(f32) for s in range(K)))
+            conv_state = conv.store(_conv_upto(prev))
+            delta_, B, C = jnp.split(xs.astype(cd) @ lp["w_xproj"].astype(cd),
+                                     [R, R + N], axis=-1)
+            dt = jax.nn.softplus(
+                (delta_ @ lp["w_dt"].astype(cd)).astype(f32)
+                + lp["b_dt"].astype(f32))
+            A, D = -jnp.exp(lp["A_log"].astype(f32)), lp["D"].astype(f32)
+        m, (state,) = _slot_rows(
+            rows, (kv[SSM1_LEAF],), (xs, dt, B, C),
+            (SCOPE_SSM1_UPDATE, SCOPE_SSM1_SCAN, SCOPE_SSM1_PROJ),
+            lambda state, xs, dt, B, C, *slots:
+            selective_scan.selective_decode_update(
+                state, xs, dt, A, B, C, D, *slots, layer=l, impl=impl),
+            lambda state, xs, dt, B, C, *spans:
+            selective_scan.selective_chunk_scan(
+                state, xs, dt, A, B, C, D, *spans, layer=l, impl=impl))
+        with jax.named_scope(SCOPE_SSM1_PROJ):
+            y = (m * jax.nn.silu(z.astype(f32))).astype(cd) \
+                @ lp["w_out"].astype(cd)
+    return _residual(x, y[None], cfg), \
+        {**kv, SSM1_LEAF: state, SSM1_CONV_LEAF: conv_state,
+         MEMORY: m.astype(cd)}
+
+
+def _gmu(lp, l, x, kv, rows: _Rows, cfg: LlamaConfig, impl=None):
+    """A gated memory unit: x' = x + (m * silu(ln(x) W_in)) W_out, m the
+    MEMORY the newest Mamba-1 layer before it left, at the same token.
+    Keeps nothing, so its ordinal ``l`` indexes nothing; no kernel, so
+    ``impl`` chooses nothing. Returns (x', kv)."""
+    cd, f32 = cfg.dtype, jnp.float32
+    with jax.named_scope(SCOPE_GMU):
+        h = _norm(x, lp["gmu_norm"], cfg, lp.get("gmu_norm_b"))
+        g = jax.nn.silu((h @ lp["w_in"].astype(cd)).astype(f32))
+        y = (kv[MEMORY].astype(f32)[None] * g).astype(cd) \
+            @ lp["w_out"].astype(cd)
+    return _residual(x, y, cfg), kv
+
+
+def _biased(lp, h, name: str, cfg: LlamaConfig):
+    """h W_name (+ b_name where the block has biases), [T, out]."""
+    y = h @ lp["w" + name].astype(cfg.dtype)
+    return y + lp["b" + name].astype(cfg.dtype) if cfg.attn_bias else y
+
+
+def _pair_queries(q, width: int):
+    """The queries of a differential pair as the paged kernels take them:
+    q [T, H, d], heads (2j, 2j+1) pair j's q1 and q2, to [T, H, width]:
+    head 2j as [q1 | 0], head 2j+1 as [0 | q2] (then zeros up to a padded
+    pool's ``width``). Against a key row [k1 | k2] the zeros add exactly 0
+    to a score, so head 2j's softmax is q1 k1's and head 2j+1's q2 k2's,
+    each over the joined value row [v1 | v2]."""
+    T, H, d = q.shape
+    eye = jnp.eye(2, dtype=q.dtype)[None, None, :, :, None]
+    q = (q.reshape(T, H // 2, 2, 1, d) * eye).reshape(T, H, 2 * d)
+    return jnp.pad(q, ((0, 0), (0, 0), (0, width - 2 * d)))
+
+
+def _before(cfg: LlamaConfig, kind: str, of: str) -> np.ndarray:
+    """For each layer of ``kind``, in order: the ordinal among the ``of``
+    layers of the newest one before it (a static table a layer indexes
+    with its own ordinal)."""
+    return np.asarray([sum(j < i for j in cfg.layers_of(of)) - 1
+                       for i in cfg.layers_of(kind)], np.int32)
+
+
+def _diff_combine(o, lp, l, kind: str, cfg: LlamaConfig):
+    """The differential combine of one layer: o [T, H, 2 dv] the kernel's
+    output, heads (2j, 2j+1) pair j's two softmaxes over the pair's joined
+    values; lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init,
+    lambda_init = 0.8 - 0.6 exp(-0.3 i) from the layer's index i IN THE
+    MODEL (a static table, read at the layer's ordinal ``l`` among the
+    layers of its ``kind``); rms(a1 - lambda a2) over the pair's 2 dv
+    values times (1 - lambda_init). float32. Returns [T, H dv]."""
+    f32 = jnp.float32
+    T, H, w = o.shape
+    with jax.named_scope(SCOPE_DIFF):
+        init = jnp.asarray(np.asarray(
+            [0.8 - 0.6 * math.exp(-0.3 * i) for i in cfg.layers_of(kind)],
+            np.float32))[l]
+        lam = jnp.exp(jnp.sum(lp["lambda_q1"].astype(f32)
+                              * lp["lambda_k1"].astype(f32))) \
+            - jnp.exp(jnp.sum(lp["lambda_q2"].astype(f32)
+                              * lp["lambda_k2"].astype(f32))) + init
+        a = o.astype(f32).reshape(T, H // 2, 2, w)
+        d = _rmsnorm(a[:, :, 0] - lam * a[:, :, 1], lp["subln"].astype(f32),
+                     cfg.norm_eps) * (1.0 - init)
+        return d.astype(cfg.dtype).reshape(T, -1)
+
+
+def _diff_attention(lp, l, x, kv, rows: _Rows, cfg: LlamaConfig, impl,
+                    window: bool = False):
+    """DIFFERENTIAL attention of one layer over the full page group or
+    (``window``) the second one, on entry ``l`` of it; heads dh wide, query
+    heads (2j, 2j+1) pair j's q1 and q2, key/value heads likewise, query
+    pair j on key/value pair j // (query pairs a key/value pair):
+
+        [q | k | v] = ln(x) [Wq | Wk | Wv] + b
+        a_i = softmax(q_i k_i^T / sqrt(dh) + mask) [v1 | v2]       i = 1, 2
+        x' = x + concat_j(rms(a1 - lambda a2) (1 - lambda_init)) Wo + bo
+
+    no positional embedding. The pool holds a key/value PAIR as one head
+    [k1 | k2], [v1 | v2] (llm/cache.py: page_heads) and the kernels take
+    the queries as ``_pair_queries`` lays them: the published four products
+    a pair through the paged kernels as they are, at 128 lanes where the
+    published head is 64. The combine is ``_diff_combine``'s, after the
+    kernel. Returns (x', kv)."""
+    cd = cfg.dtype
+    T = x.shape[1]
+    kl, vl = WINDOW_LEAVES if window else ("k", "v")
+    kind = WINDOW if window else ATTENTION
+    proj = jax.named_scope(SCOPE_WINDOW_PROJ if window else SCOPE_FULL_PROJ)
+    with jax.named_scope(SCOPE_WINDOW if window else SCOPE_ATTENTION):
+        with proj:
+            h = _norm(x, lp["attn_norm"], cfg, lp.get("attn_norm_b"))[0]
+            q, k, v = (_biased(lp, h, name, cfg) for name in "qkv")
+            dh, wk, wv = cfg.qk_head_dim, kv[kl].shape[-1], kv[vl].shape[-1]
+            q = _pair_queries(q.reshape(T, -1, dh), wk)
+            # a pair of adjacent heads is one row of the pool, as they lie
+            k = k.reshape(T, -1, 2 * dh)
+            v = v.reshape(T, -1, 2 * cfg.v_dim)
+            k, v = (jnp.pad(a, ((0, 0), (0, 0), (0, width - a.shape[-1])))
+                    for a, width in ((k, wk), (v, wv)))
+        hints = dict(layer=l, max_q_len=rows.max_q_len,
+                     decode_rows=rows.decode_rows, impl=impl)
+        token_page, page_table, seen = rows.token_page, rows.page_table, {}
+        if window:
+            token_page, page_table = rows.token_page_win, rows.page_table_win
+            seen = dict(window=cfg.sliding_window,
+                        page_base=rows.page_base_win)
+        kc, vc, _, _ = write_ragged_kv(
+            kv[kl], kv[vl], k, v, token_page, rows.token_slot,
+            q_start=rows.q_start, q_len=rows.q_len, **hints)
+        o = ragged_paged_attention(
+            q, kc, vc, page_table, rows.q_start, rows.q_len, rows.kv_len,
+            sm_scale=dh ** -0.5, **hints, **seen)
+        with proj:
+            o = _diff_combine(o[..., :2 * cfg.v_dim], lp, l, kind, cfg)
+            x = _residual(x, _biased(lp, o, "o", cfg)[None], cfg)
+    return x, {**kv, kl: kc, vl: vc}
+
+
+def _cross_attention(lp, l, x, kv, rows: _Rows, cfg: LlamaConfig, impl):
+    """Differential CROSS attention of one layer: its own queries (and
+    lambdas, norm weight and lambda_init) over the pages the NEWEST full-
+    attention layer before it wrote, of the same sequence, all positions up
+    to the token's own. It projects no key and no value, writes no page and
+    keeps nothing: ``l`` is its ordinal among the cross layers, and the
+    entry of the pool it reads is ``_before``'s. Returns (x', kv)."""
+    T = x.shape[1]
+    source = jnp.asarray(_before(cfg, CROSS, ATTENTION))[l]
+    with jax.named_scope(SCOPE_ATTENTION), jax.named_scope(SCOPE_CROSS):
+        with jax.named_scope(SCOPE_CROSS_PROJ):
+            h = _norm(x, lp["attn_norm"], cfg, lp.get("attn_norm_b"))[0]
+            q = _pair_queries(
+                _biased(lp, h, "q", cfg).reshape(T, -1, cfg.qk_head_dim),
+                kv["k"].shape[-1])
+        with jax.named_scope(SCOPE_SHARED_KV):
+            o = ragged_paged_attention(
+                q, kv["k"], kv["v"], rows.page_table, rows.q_start,
+                rows.q_len, rows.kv_len, sm_scale=cfg.qk_head_dim ** -0.5,
+                layer=source, max_q_len=rows.max_q_len,
+                decode_rows=rows.decode_rows, impl=impl)
+        with jax.named_scope(SCOPE_CROSS_PROJ):
+            o = _diff_combine(o[..., :2 * cfg.v_dim], lp, l, CROSS, cfg)
+            x = _residual(x, _biased(lp, o, "o", cfg)[None], cfg)
+    return x, kv
+
+
 #: THE table of layer operators: kind -> (its stack in params["layers"],
 #: its body ``(lp, l, x, kv, rows, cfg, impl) -> (x, kv)``: the layer's
 #: weights, its ordinal among the layers of its kind, the stream, the
@@ -888,24 +1145,54 @@ def _paged_attention(lp, l, x, kv, rows: _Rows, cfg: LlamaConfig, impl,
 OPERATORS = {ATTENTION: ("attn", _attention), CONV: ("conv", _short_conv),
              MAMBA: ("mamba", _mamba), RETENTION: ("retention", _retention),
              WINDOW: ("attn_window", _window_attention),
-             DELTA: ("delta", _delta)}
+             DELTA: ("delta", _delta), MAMBA1: ("mamba1", _mamba1),
+             GMU: ("gmu", _gmu), CROSS: ("attn_cross", _cross_attention)}
+
+
+#: what a scan of its own costs, counted in layer bodies traced: a run of
+#: layers becomes a segment of the walk where that saves more bodies than
+#: this. At 8 a cut model's one period that is run once stays one scan
+#: (Trinity-Mini's four layers, MiMo-V2-Flash's six), and a depth that is
+#: not one period (a decoder-hybrid-decoder's 32 layers) is three
+_SEGMENT_COST = 8
 
 
 def _pattern(cfg: LlamaConfig):
-    """(leading layers, one period, how many periods): each layer is
-    (operator kind, feed-forward kind). The leading dense layers run
-    before the scan; the rest must be whole repeats of its shortest
-    period, which is the scanned unit."""
+    """(leading layers, then for each SEGMENT of the rest: one period, how
+    many periods): each layer is (operator kind, feed-forward kind). The
+    leading dense layers run before the scans; the rest is covered by
+    segments, each whole repeats of its period and a scan of its own, so
+    that the layer bodies traced, and ``_SEGMENT_COST`` a segment, come to
+    the least (fewest segments, then the shortest first period, where two
+    covers cost the same). A depth that is one period repeated is one
+    segment, of its shortest period."""
     L = cfg.n_layers
     ops = cfg.layer_types or (ATTENTION,) * L
     lead = cfg.n_dense_layers if cfg.n_experts else 0
     kinds = [(ops[i], "moe" if cfg.n_experts and i >= lead else "dense")
              for i in range(L)]
     rest = kinds[lead:]
-    period = next(p for p in range(1, len(rest) + 1)
-                  if len(rest) % p == 0
-                  and rest == rest[:p] * (len(rest) // p))
-    return kinds[:lead], rest[:period], len(rest) // period
+    n = len(rest)
+    # best[i]: (cost, segments, their (period, repeats)) of rest[i:]
+    best = {n: (0, 0, ())}
+    for i in range(n - 1, -1, -1):
+        covers = []
+        for p in range(1, n - i + 1):
+            r = 1
+            while True:
+                cost, segs, tail = best[i + p * r]
+                covers.append((p + _SEGMENT_COST + cost, segs + 1, p,
+                               ((p, r),) + tail))
+                if rest[i + p * r:i + p * (r + 1)] != rest[i:i + p]:
+                    break
+                r += 1
+        cost, segs, _, cover = min(covers)
+        best[i] = (cost, segs, cover)
+    out, i = [kinds[:lead]], 0
+    for p, r in best[0][2]:
+        out += [rest[i:i + p], r]
+        i += p * r
+    return tuple(out)
 
 
 def _stacks(layers, cfg: LlamaConfig):
@@ -929,13 +1216,18 @@ def _layers(layers, x, kv, rows: _Rows, valid, cfg: LlamaConfig, impl):
     layers of its kind: its operator's entry of the page pool or of its
     state leaves, the expert layers' [layer, expert] weights, which stay
     closed over and whole. Depth does not unroll: the leading layers run
-    once, then ONE scan over the periods of the pattern (``_pattern``),
-    its body one period; the Llama block is a pattern of period 1. The
+    once, then ONE scan a segment over the periods of its pattern
+    (``_pattern``), its body one period; the Llama block is one segment of
+    period 1, and a kind's ordinals go on counting from scan to scan. The
     pool is a carry, whole, updated in place at the ordinals; the weights
     are closed over and indexed by the scan's counter. Returns (x, kv,
     counters summed over the expert layers, or None)."""
-    lead, period, n_periods = _pattern(cfg)
+    lead, *segments = _pattern(cfg)
     layers = _stacks(layers, cfg)
+    if cfg.layers_of(MAMBA1):
+        # what a Mamba-1 layer hands the gated memory units after it
+        kv = {**kv, MEMORY: jnp.zeros((x.shape[1], cfg.ssm1_channels),
+                                      cfg.dtype)}
     experts = {k: layers["moe"][k] for k in _EXPERT_LEAVES} \
         if cfg.n_experts else None
 
@@ -974,10 +1266,15 @@ def _layers(layers, x, kv, rows: _Rows, valid, cfg: LlamaConfig, impl):
         len(moe.COUNTERS) + bool(cfg.experts_held), jnp.int32))
     carry, seen = run(carry, lead, dict.fromkeys((*OPERATORS, "dense",
                                                   "moe"), 0))
-    per = collections.Counter(k for kinds in period for k in kinds)
-    (x, kv, counters), _ = lax.scan(
-        lambda carry, j: (run(carry, period, seen, j, per)[0], None),
-        carry, jnp.arange(n_periods, dtype=jnp.int32))
+    for period, n_periods in zip(segments[::2], segments[1::2]):
+        per = collections.Counter(k for kinds in period for k in kinds)
+        carry, _ = lax.scan(
+            lambda carry, j, period=period, seen=seen, per=per:
+            (run(carry, period, seen, j, per)[0], None),
+            carry, jnp.arange(n_periods, dtype=jnp.int32))
+        seen = {k: at + n_periods * per[k] for k, at in seen.items()}
+    x, kv, counters = carry
+    kv = {k: leaf for k, leaf in kv.items() if k != MEMORY}
     return x, kv, counters if cfg.n_experts else None
 
 
@@ -1032,7 +1329,7 @@ def _ragged_logits(params: Params, tokens: jax.Array,
               token_page, token_slot, page_table, kv_len, max_q_len,
               tp_axis, token_page_win, page_table_win, page_base_win),
         valid, cfg, paged_impl)
-    x = _norm(x, params["final_norm"], cfg)
+    x = _norm(x, params["final_norm"], cfg, params.get("final_norm_b"))
     last = jnp.clip(q_start + q_len - 1, 0, T - 1)        # [R]
     xl = x[0][last]
     head = params["embed"] if cfg.tie_embeddings else params["lm_head"]

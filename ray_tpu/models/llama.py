@@ -56,7 +56,14 @@ Params = Dict[str, Any]
 ATTENTION, CONV, MAMBA, RETENTION, WINDOW, DELTA = \
     "full_attention", "conv", "mamba", "retention", "sliding_attention", \
     "linear_attention"
-LAYER_KINDS = (ATTENTION, CONV, MAMBA, RETENTION, WINDOW, DELTA)
+#: ... and a decoder-hybrid-decoder's (Phi-4-mini-flash, of the phi4flash
+#: family, is the first such block; its published file names no layer's
+#: kind, the names are the program's): a Mamba-1 selective scan, a gated
+#: memory unit that reads the newest scan's output, and attention whose keys
+#: and values are the newest full-attention layer's pages
+MAMBA1, GMU, CROSS = "mamba1", "gmu", "cross_attention"
+LAYER_KINDS = (ATTENTION, CONV, MAMBA, RETENTION, WINDOW, DELTA, MAMBA1, GMU,
+               CROSS)
 #: range of the seeded attention sinks (float32, one a query head of a
 #: window layer): a full window's 128 scores under seeded weights sum to
 #: about e^4.9, so a sink in [3, 6] takes between a sixth and three quarters
@@ -113,7 +120,13 @@ MECHANISMS = {
     "no positions": ("rope",),
     "gated block": ("attn_gate", "post_norms", "full_rope"),
     "norm_gate": ("norm_gate",),
-    "ffn_clamp": ("ffn_clamp",)}
+    "ffn_clamp": ("ffn_clamp",),
+    MAMBA1: ("ssm1_state", "ssm1_expand", "ssm1_conv", "ssm1_dt_rank"),
+    GMU: (),
+    CROSS: (),
+    "differential attention": ("diff_attention",),
+    "layer norm": ("layer_norm",),
+    "biases": ("attn_bias",)}
 #: Constants of a mechanism's arithmetic (taps, a chunk, an epsilon, the
 #: router's score) that nothing reads where the mechanism is off: set alone
 #: they do not turn it on, and a configuration that sets them is accepted as
@@ -122,10 +135,14 @@ IDLE_WHERE_OFF = frozenset((
     "experts_per_token", "norm_topk_prob", "router_score", "router_bias",
     "router_eps", "router_scale", "dense_ffn_dim", "conv_kernel", "ssm_conv",
     "ssm_chunk", "retention_chunk", "delta_conv", "delta_chunk",
-    "delta_norm_eps", "delta_gate_scale"))
+    "delta_norm_eps", "delta_gate_scale", "ssm1_expand", "ssm1_conv"))
 #: What adds to any block: no mechanism below is refused beside these
 _ANYWHERE = (ATTENTION, "untied head", "multipliers", "ffn_clamp",
              "experts_held", "norm_gate")
+#: What a decoder-hybrid-decoder is made of (Phi-4-mini-flash): each is
+#: built beside the others, position-free, and beside nothing else yet
+_SAMBAY = (MAMBA1, WINDOW, GMU, CROSS, "differential attention",
+           "layer norm", "biases", "no positions")
 #: mechanism -> the mechanisms it is BUILT beside (llm/model.py has the
 #: program, a reference under benchmark/ the comparison); one with no row
 #: here is built beside whatever lists it. A new mechanism is in no row:
@@ -136,7 +153,8 @@ BUILT_BESIDE = {
     RETENTION: _ANYWHERE + (CONV, MAMBA, "qk_norm", "qk_norm_per_head",
                             "attn_scale", "no positions"),
     WINDOW: _ANYWHERE + ("experts", "qk_norm_per_head", "head widths",
-                         "gated block"),
+                         "gated block")
+    + tuple(m for m in _SAMBAY if m != "no positions"),
     DELTA: _ANYWHERE + ("experts", "latent attention", "qk_norm",
                         "qk_norm_per_head", "head widths", "attn_scale",
                         "no positions", "gated block"),
@@ -147,7 +165,14 @@ BUILT_BESIDE = {
         CONV, MAMBA, WINDOW, DELTA, "experts", "qk_norm_per_head",
         "attn_scale", "no positions", "gated block"),
     "latent attention": _ANYWHERE + (DELTA, "experts", "attn_scale",
-                                     "no positions", "gated block")}
+                                     "no positions", "gated block"),
+    **{name: _ANYWHERE + _SAMBAY for name in _SAMBAY
+       if name not in (WINDOW, "no positions")}}
+#: ... and what a mechanism is built beside only WHERE another is on too:
+#: window attention without positions is the differential body's
+#: (llm/model.py:_diff_attention); the plain window operator's is refused as
+#: it was (no reference computes it)
+BUILT_BESIDE_WHERE = {"differential attention": {WINDOW: ("no positions",)}}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -281,6 +306,29 @@ class LlamaConfig:
     rope_yarn: Tuple[float, ...] = ()
     norm_gate: float = 0.0           # every norm x/rms * this * sigmoid(w)
     ffn_clamp: float = 0.0           # silu(min(g, c)) * clip(u, -c, c)
+    # A decoder-hybrid-decoder (SambaY; Phi-4-mini-flash-reasoning is the
+    # first such block): layer_types names "mamba1" layers (a Mamba-1
+    # selective scan: every (channel, state index) of a [ssm1_expand * dim,
+    # ssm1_state] state decays on its own, dt from a rank-ssm1_dt_rank
+    # projection; per batch slot that state and the last ssm1_conv - 1
+    # inputs of a depthwise conv; ops/selective_scan.py), "gmu" layers (a
+    # gated memory unit: W2(m * silu(h W1)), m the NEWEST mamba1 layer's
+    # scan output at the same token, before that layer's gate; nothing
+    # kept) and "cross_attention" layers (own queries over the NEWEST
+    # full_attention layer's pages; nothing written, nothing kept). Off =
+    # every other block's program.
+    ssm1_state: int = 0              # N: state values a channel
+    ssm1_expand: int = 2             # channels = this * dim
+    ssm1_conv: int = 4               # taps of its depthwise causal conv
+    ssm1_dt_rank: int = 0            # R: dt = softplus(delta W_dt + b_dt)
+    # ... whose attention of every kind is DIFFERENTIAL: heads come in
+    # adjacent pairs (2j, 2j+1), a pair's two softmaxes each over the
+    # pair's joined values, o = rms(a1 - lambda a2; 2 head_dim) (1 -
+    # lambda_init), lambda from four learned vectors a layer and
+    # lambda_init from the layer's index in the model
+    diff_attention: bool = False
+    layer_norm: bool = False         # every norm a LayerNorm with a bias
+    attn_bias: bool = False          # biases on the attention projections
 
     def __post_init__(self):
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
@@ -325,6 +373,9 @@ class LlamaConfig:
                     + ("layer_types names none" if name in LAYER_KINDS
                        else "they need kv_lora_rank"))
         for name, beside in BUILT_BESIDE.items():
+            beside = beside + tuple(
+                m for where, rows in BUILT_BESIDE_WHERE.items() if where in on
+                for m in rows.get(name, ()))
             others = [m for m in on if m != name and m not in beside]
             if name in on and others:
                 raise ValueError(
@@ -395,6 +446,36 @@ class LlamaConfig:
             raise ValueError(
                 f"rope_yarn = (factor > 1, original positions, beta_fast, "
                 f"beta_slow, mscale, mscale_all_dim), got {self.rope_yarn}")
+        ssm1 = (self.ssm1_state, self.ssm1_expand, self.ssm1_dt_rank)
+        if MAMBA1 in self.layer_types and (
+                min(ssm1) <= 0 or self.ssm1_conv < 2):
+            raise ValueError(
+                f"mamba1 layers need ssm1_state, ssm1_expand, ssm1_dt_rank "
+                f"and at least 2 conv taps, got {ssm1}, {self.ssm1_conv}")
+        for kind, source in ((GMU, MAMBA1), (CROSS, ATTENTION)):
+            if kind in self.layer_types and source not in \
+                    self.layer_types[:self.layer_types.index(kind)]:
+                raise ValueError(
+                    f"a {kind} layer reads what the newest {source} layer "
+                    f"before it left: layer_types names none before layer "
+                    f"{self.layer_types.index(kind)}")
+        if CROSS in self.layer_types and self.kv_lora_rank:
+            raise ValueError("cross_attention layers read per-head K and V "
+                             "pages, not a latent pool (kv_lora_rank)")
+        if self.diff_attention and (
+                self.n_heads % 2 or self.n_kv_heads % 2
+                or self.n_heads % self.n_kv_heads
+                or (WINDOW in self.layer_types
+                    and self.window_kv_heads % 2)):
+            raise ValueError(
+                f"diff_attention pairs adjacent heads: n_heads, n_kv_heads "
+                f"(and window_kv_heads) must be even, got {self.n_heads}, "
+                f"{self.n_kv_heads}, {self.window_kv_heads}")
+        for field in ("layer_norm", "attn_bias"):
+            if getattr(self, field) and not self.diff_attention:
+                raise ValueError(
+                    f"{field} is built in the differential operators and "
+                    f"the layers beside them only: it needs diff_attention")
         delta = (self.delta_key_heads, self.delta_value_heads,
                  self.delta_key_dim, self.delta_value_dim)
         chunk = self.delta_chunk
@@ -436,15 +517,22 @@ class LlamaConfig:
         return self.ssm_heads * self.ssm_head_dim + 2 * self.ssm_state
 
     @property
+    def ssm1_channels(self) -> int:
+        """Channels of a mamba1 layer: its conv's, its state's rows."""
+        return self.ssm1_expand * self.dim
+
+    @property
     def hybrid(self) -> bool:
         """The layers differ in kind (operator or feed-forward), or the
         block has leaves the Llama tree has no place for (latent
-        attention, a shared expert, an output gate, a norm after a branch):
+        attention, a shared expert, an output gate, a norm after a branch,
+        a differential layer's lambdas):
         weights are stacked per kind and the serving step runs the
         pattern."""
         return bool(self.layer_types or self.n_dense_layers
                     or self.kv_lora_rank or self.shared_ffn_dim
-                    or self.attn_gate or self.post_norms)
+                    or self.attn_gate or self.post_norms
+                    or self.diff_attention)
 
     def layers_of(self, kind: str) -> Tuple[int, ...]:
         """Indices of the layers whose operator is ``kind``."""
@@ -634,12 +722,34 @@ def _init_hybrid_params(cfg: LlamaConfig, key: jax.Array) -> Params:
         return jax.random.uniform(next(keys), shape, jnp.float32, -0.5,
                                   0.5).astype(pd)
 
+    def bias(*shape, std=0.02, dtype=pd):
+        """A drawn bias (or a differential layer's lambda vector): zero
+        would hide one that is left out."""
+        return (std * jax.random.normal(next(keys), shape)).astype(dtype)
+
+    def lnorm(stack, name, n, width=d):
+        """... a LayerNorm's bias beside the weight ``name`` (layer_norm)."""
+        if cfg.layer_norm:
+            stack[name + "_b"] = bias(n, width)
+        return stack
+
     def swiglu(n, *E, f):
         stack = {"mlp_norm": norm(n, d),
                  "w_gate": dense(n, *E, d, f), "w_up": dense(n, *E, d, f),
                  "w_down": dense(n, *E, f, d)}
         if cfg.post_norms:
             stack["mlp_post_norm"] = norm(n, d)
+        return lnorm(stack, "mlp_norm", n)
+
+    def differential(stack, n):
+        """... what a differential layer adds: the four lambda vectors a
+        layer, float32, N(0, 0.1) (lambda then strays ~0.1 off lambda_init:
+        one left at lambda_init is seen), and the weight of the norm over a
+        pair's joined output."""
+        if cfg.diff_attention:
+            for name in ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2"):
+                stack[name] = bias(n, dk, std=0.1, dtype=jnp.float32)
+            stack["subln"] = jnp.ones((n, 2 * dv), pd)
         return stack
 
     def qkv(n, hkv=cfg.n_kv_heads):
@@ -658,7 +768,10 @@ def _init_hybrid_params(cfg: LlamaConfig, key: jax.Array) -> Params:
         elif cfg.qk_norm:
             stack["q_norm"] = jnp.ones((n, hq * hd), pd)
             stack["k_norm"] = jnp.ones((n, hkv * hd), pd)
-        return stack
+        if cfg.attn_bias:
+            stack.update(bq=bias(n, hq * dk), bk=bias(n, hkv * dk),
+                         bv=bias(n, hkv * dv), bo=bias(n, d))
+        return differential(lnorm(stack, "attn_norm", n), n)
 
     def gated(stack, n, dv=dv):
         """... and what the attention and window operators of a gated
@@ -755,6 +868,52 @@ def _init_hybrid_params(cfg: LlamaConfig, key: jax.Array) -> Params:
             "w_out": dense(Dn, wide, d)}
         if cfg.post_norms:
             layers["delta"]["attn_post_norm"] = norm(Dn, d)
+    S1 = len(cfg.layers_of(MAMBA1))
+    if S1:
+        di, N, R = cfg.ssm1_channels, cfg.ssm1_state, cfg.ssm1_dt_rank
+        f32 = jnp.float32
+        # the family's initialisation: A[c, n] = -(n + 1) for every channel
+        # and softplus(b_dt) log-uniform in [1e-3, 1e-1], so a (channel,
+        # state index) pair decays by 0.2 to 0.9999 a token: a state
+        # remembers between one and thousands of tokens. W_dt at a tenth of
+        # its fan-in spread: the token moves dt by a third, b_dt sets its
+        # range (at the full spread dt reaches 2 and a state forgets within
+        # the token)
+        dt = jnp.exp(jax.random.uniform(
+            next(keys), (S1, di), f32, jnp.log(1e-3), jnp.log(1e-1)))
+        layers["mamba1"] = lnorm({
+            "mamba1_norm": norm(S1, d),
+            # the published in_proj [d, 2 di] as its halves: to the conv's
+            # input, to the gate
+            "w_x": dense(S1, d, di), "w_z": dense(S1, d, di),
+            "w_conv": dense(S1, cfg.ssm1_conv, di, fan_in=cfg.ssm1_conv,
+                            dtype=f32),
+            "b_conv": dense(S1, di, fan_in=cfg.ssm1_conv, dtype=f32),
+            # x_proj: to delta (R), B (N) and C (N), in that order
+            "w_xproj": dense(S1, di, R + 2 * N),
+            "w_dt": 0.1 * dense(S1, R, di),
+            "b_dt": dt + jnp.log(-jnp.expm1(-dt)),      # softplus^-1(dt)
+            # [N, channels]: transposed, as the state lies (llm/cache.py)
+            "A_log": jnp.broadcast_to(jnp.log(jnp.arange(
+                1, N + 1, dtype=f32))[None, :, None], (S1, N, di)),
+            "D": jnp.ones((S1, di), f32),
+            "w_out": dense(S1, di, d)}, "mamba1_norm", S1)
+    Gn = len(cfg.layers_of(GMU))
+    if Gn:
+        di = cfg.ssm1_channels
+        layers["gmu"] = lnorm({
+            "gmu_norm": norm(Gn, d), "w_in": dense(Gn, d, di),
+            "w_out": dense(Gn, di, d)}, "gmu_norm", Gn)
+    Cn = len(cfg.layers_of(CROSS))
+    if Cn:
+        # a query and an output projection only: keys and values are the
+        # newest full-attention layer's
+        stack = {"attn_norm": norm(Cn, d), "wq": dense(Cn, d, hq * dk),
+                 "wo": dense(Cn, hq * dv, d)}
+        if cfg.attn_bias:
+            stack.update(bq=bias(Cn, hq * dk), bo=bias(Cn, d))
+        layers["attn_cross"] = differential(
+            lnorm(stack, "attn_norm", Cn), Cn)
     Rt = len(cfg.layers_of(RETENTION))
     if Rt:
         # sigmoid(b_g) log-uniform in 1 - [1e-3, 1e-1]: a head forgets over
@@ -790,6 +949,8 @@ def _init_hybrid_params(cfg: LlamaConfig, key: jax.Array) -> Params:
               "layers": layers, "final_norm": norm(d)}
     if not cfg.tie_embeddings:
         params["lm_head"] = dense(cfg.vocab_size, d, fan_in=d)
+    if cfg.layer_norm:
+        params["final_norm_b"] = bias(d)
     return params
 
 

@@ -1602,6 +1602,7 @@ class ClusterBackend:
             except Exception:
                 try:
                     proc.kill()
+                    proc.wait(timeout=30.0)     # gone, not merely signalled
                 except Exception:
                     pass
 

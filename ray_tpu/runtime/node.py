@@ -1032,6 +1032,12 @@ class NodeDaemon:
                 w.proc.wait(timeout=remaining)
             except subprocess.TimeoutExpired:
                 w.proc.kill()
+                # stop() returns when its workers are GONE: one that holds
+                # a chip and gigabytes of weights takes a while to die
+                try:
+                    w.proc.wait(timeout=30.0)
+                except subprocess.TimeoutExpired:
+                    pass
         if self.cgroups is not None:
             self.cgroups.shutdown()
         try:

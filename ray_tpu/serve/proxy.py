@@ -25,6 +25,18 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any
 
 
+class _ProxyServer(ThreadingHTTPServer):
+    """The stdlib server with a listen backlog a serving front end needs.
+    socketserver's default is 5: callers that each wait for their reply
+    reconnect in bursts (a decode block ends for many sequences at once; a
+    closed loop of 160 clients opens at once), the accept queue overflows,
+    and the kernel resets connections whose handshake it had already
+    completed: ten of 380 requests of one benchmark run ended in
+    ConnectionResetError (PERF.md, PR 63). The kernel caps the value at
+    net.core.somaxconn."""
+    request_queue_size = 1024
+
+
 class HTTPProxy:
     def __init__(self, controller, port: int = 0):
         from ray_tpu.serve.router import HandleCache
@@ -178,7 +190,7 @@ class HTTPProxy:
                     body = raw.decode("utf-8", "replace")
                 self._dispatch(body)
 
-        self._server = ThreadingHTTPServer(("127.0.0.1", port), Handler)
+        self._server = _ProxyServer(("127.0.0.1", port), Handler)
         self._port = self._server.server_address[1]
         self._thread = threading.Thread(target=self._server.serve_forever,
                                         daemon=True, name="serve-http")

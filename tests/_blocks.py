@@ -128,7 +128,19 @@ BLOCKS = {
              post_norms=True, norm_gate=2.0,
              ffn_clamp=10.0, delta_key_heads=2, delta_value_heads=4,
              delta_key_dim=8, delta_value_dim=16, delta_chunk=8),
-        "reference_gigachat", tol=3e-4, state=("delta", "delta_conv"))}
+        "reference_gigachat", tol=3e-4, state=("delta", "delta_conv")),
+    # a decoder-hybrid-decoder: two periods of (Mamba-1, window), the
+    # (Mamba-1, full) pair whose memory and pages the cross-decoder reads,
+    # and one period of it (gated memory unit, cross attention)
+    "phi4flash": Block(
+        dict(n_layers=8, n_heads=8, n_kv_heads=4, window_kv_heads=4,
+             ffn_dim=96,
+             layer_types=["mamba1", "sliding_attention"] * 2
+             + ["mamba1", "full_attention", "gmu", "cross_attention"],
+             ssm1_state=16, ssm1_dt_rank=4, sliding_window=16,
+             window_rope_theta=1e4, rope=False, diff_attention=True,
+             layer_norm=True, attn_bias=True),
+        "reference_phi4flash", state=("ssm1", "ssm1_conv"), window=True)}
 
 #: the engine every case serves on: pages of 8, chunk rows of 16, two a
 #: step, decode loops of 4

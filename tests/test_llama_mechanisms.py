@@ -33,7 +33,9 @@ WITNESS = dict(
     delta_gate_scale=1.0, score_head_dim=16, value_head_dim=16, rotary_dim=4,
     value_scale=0.5, embed_scale=2.0, residual_scale=0.5, logits_divisor=2.0,
     attn_scale=0.1, rope=False, attn_gate=True, post_norms=True,
-    full_rope=False, norm_gate=2.0, ffn_clamp=5.0)
+    full_rope=False, norm_gate=2.0, ffn_clamp=5.0, ssm1_state=8,
+    ssm1_expand=4, ssm1_conv=3, ssm1_dt_rank=4, diff_attention=True,
+    layer_norm=True, attn_bias=True)
 
 
 def _consumers(cfg):
@@ -119,4 +121,8 @@ def test_the_tables_rows_say_what_the_blocks_are():
         found = mechanisms_beyond(config(block))
         assert bool(found) == (block != "mistral"), block
         for name in found.keys() & BUILT_BESIDE.keys():
-            assert set(found) - {name} <= set(BUILT_BESIDE[name]), block
+            # ... or beside it WHERE another mechanism of the block is on
+            where = {m for on, rows in llama.BUILT_BESIDE_WHERE.items()
+                     if on in found for m in rows.get(name, ())}
+            assert set(found) - {name} <= set(BUILT_BESIDE[name]) | where, \
+                block

@@ -61,6 +61,12 @@ ONLY_WINDOW = ("attn_window", "attn_full_proj", "k_win", "v_win", "'sink'",
 #: ... a gated block's: the gate's and the head's scopes, the gate's
 #: projection, the norms after a branch
 ONLY_GATED = ("attn_gate", "w_og", "attn_post_norm", "mlp_post_norm")
+#: ... a decoder-hybrid-decoder's: the Mamba-1 operator's scopes and
+#: leaves, the gated memory unit's and the cross operator's scopes, the
+#: differential combine and its leaves, a LayerNorm's bias, the
+#: projections' biases
+ONLY_SAMBAY = ("ssm1", "w_xproj", "gmu_norm", "attn_cross", "shared_kv",
+               "attn_diff", "lambda_q1", "subln", "norm_b", "'bq'")
 #: ... and its head's scope, in a program's text (in a parameter tree it
 #: is every untied head's leaf)
 SCOPE_HEAD = "lm_head"
@@ -109,14 +115,18 @@ def traced(block: str) -> dict:
         out[f"{block}.{impl}.pool"] = str(jax.tree.map(
             lambda a: (a.shape, str(a.dtype)), kv))
         if windowed:
-            # the second page group's fields ride the descriptor
+            # the second page group's fields ride the descriptor (and each
+            # token's slot, where a layer keeps state beside the two groups)
+            state = (i32(T),) if C.keeps_slot_state(cfg) else ()
             out[f"{block}.{impl}.step"] = jax.make_jaxpr(
                 lambda *a: M._ragged_step_body(
                     *a[:10], cfg=cfg, paged_impl=impl, max_q_len=8,
                     decode_rows=3, token_page_win=a[10],
-                    page_table_win=a[11], page_base_win=a[12]))(
+                    page_table_win=a[11], page_base_win=a[12],
+                    token_state=a[13] if len(a) > 13 else None))(
                 params, i32(T), i32(T), i32(T), i32(T), i32(R, mp), i32(R),
-                i32(R), i32(R), kv, i32(T), i32(R, _WINDOW_PAGES), i32(R))
+                i32(R), i32(R), kv, i32(T), i32(R, _WINDOW_PAGES), i32(R),
+                *state)
             out[f"{block}.{impl}.loop"] = jax.make_jaxpr(
                 lambda p, t, pos, kv, pt, sl, wt, wb: M._ragged_decode_loop(
                     p, t, pos, kv, pt, sl, 4, cfg, None, impl, wt, wb))(
@@ -178,11 +188,14 @@ def test_a_block_takes_no_other_blocks_code(block):
         assert all("'v'" not in texts[f"{block}.{impl}.pool"]
                    for impl in ("reference", "kernel"))
         return
+    sambay = "mamba1" in cfg.layer_types
     absent = ONLY_LATENT + ONLY_DELTA \
         + (() if cfg.shared_ffn_dim else ONLY_SHARED) \
         + (() if cfg.gated_block else ONLY_GATED) \
         + (() if "conv" in cfg.layer_types else ONLY_CONV) \
-        + (() if "mamba" in cfg.layer_types else ONLY_SSM + ONLY_DECAY) \
+        + (() if "mamba" in cfg.layer_types else ONLY_SSM) \
+        + (() if sambay else ONLY_SAMBAY) \
+        + (() if sambay or "mamba" in cfg.layer_types else ONLY_DECAY) \
         + (() if "retention" in cfg.layer_types else ONLY_RETENTION) \
         + (() if "sliding_attention" in cfg.layer_types else ONLY_WINDOW)
     for kind, words in (("mamba", ONLY_SSM + ONLY_DECAY),
@@ -197,6 +210,9 @@ def test_a_block_takes_no_other_blocks_code(block):
         missing = [w for w in ONLY_GATED + ONLY_SHARED
                    if w not in everything]
         assert not missing, f"the gated block's texts lack {missing}"
+    if sambay:
+        missing = [w for w in ONLY_SAMBAY if w not in everything]
+        assert not missing, f"the hybrid decoder's texts lack {missing}"
     for name, text in texts.items():
         found = [word for word in absent if word in text]
         assert not found, f"{name} holds {found}"
@@ -235,7 +251,13 @@ PATTERNS = {
                 + [("full_attention", "moe")], 1),
     "gigachat": ([("linear_attention", "dense")],
                  [("linear_attention", "moe")] * 3
-                 + [("full_attention", "moe")], 1)}
+                 + [("full_attention", "moe")], 1),
+    # eight layers are cheaper as one period run once than as segments
+    # (model.py: _SEGMENT_COST); the published depth is three segments, a
+    # scan each (tests/test_llm_phi4flash.py)
+    "phi4flash": ([], [("mamba1", "dense"), ("sliding_attention", "dense")]
+                  * 2 + [("mamba1", "dense"), ("full_attention", "dense"),
+                         ("gmu", "dense"), ("cross_attention", "dense")], 1)}
 
 
 @pytest.mark.parametrize("block", sorted(BLOCKS))
@@ -246,9 +268,10 @@ def test_every_block_is_a_pattern_of_the_one_walk(block):
     # tree's by the split of its flat leaves: the same arrays
     params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
     stacks = M._stacks(params["layers"], cfg)
-    lead, period, _ = PATTERNS[block]
-    assert set(stacks) == {M.OPERATORS[op][0] for op, _ in lead + period} \
-        | {ffn for _, ffn in lead + period}
+    lead, *segments = PATTERNS[block]
+    walked = lead + sum(segments[::2], [])
+    assert set(stacks) == {M.OPERATORS[op][0] for op, _ in walked} \
+        | {ffn for _, ffn in walked}
     assert sorted(map(id, jax.tree.leaves(stacks))) \
         == sorted(map(id, jax.tree.leaves(params["layers"])))
 
@@ -260,7 +283,8 @@ def test_every_kind_of_layer_is_declared_once_in_each_table():
     for kind, (stack, body) in M.OPERATORS.items():
         assert isinstance(stack, str) and callable(body), kind
     assert C.STATE_LEAVES == ("conv", "ssm", "ssm_conv", "retention",
-                              "retention_norm", "delta", "delta_conv")
+                              "retention_norm", "delta", "delta_conv",
+                              "ssm1", "ssm1_conv")
 
 
 @pytest.mark.parametrize("block", sorted(BLOCKS))

@@ -928,3 +928,87 @@ def test_one_row_mixed_step_compiles_at_benchmark_shapes(chip, widths):
     assert m1.alias_size_in_bytes == m2.alias_size_in_bytes > 0
     assert m1.argument_size_in_bytes <= m2.argument_size_in_bytes
     assert m1.temp_size_in_bytes <= m2.temp_size_in_bytes + 2**26
+
+
+def _phi4flash_cfg():
+    """phi4-mini-flash-serve-1chip's widths from its own file: all 32
+    layers, every width and the whole vocabulary."""
+    import json
+    import os
+
+    from benchmark.runners import serve_phi4flash
+    from ray_tpu.models.llama import LlamaConfig
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "phi4-mini-flash-serve-1chip.json")) as f:
+        config = json.load(f)
+    return LlamaConfig.tiny(**serve_phi4flash.model_fields(config)), \
+        config["engine"]
+
+
+def test_selective_scan_kernels_compile_at_published_shapes(chip):
+    """ops/selective_scan.py's two kernels at the published sizes (a
+    float32 state [16, 5120] a slot, 9 layers, 160 slots): Mosaic takes the
+    one-token update (a decay TILE made in the kernel) and the chunk rows'
+    scan (every row's state resident in VMEM, tokens eight at a time: it
+    loads no single row at a dynamic index)."""
+    from ray_tpu.ops import selective_scan as ss
+    f32, i32 = jnp.float32, jnp.int32
+    state = _sds(chip, (9, 161, 16, 5120), f32)
+    A = _sds(chip, (16, 5120), f32)
+
+    def operands(T):
+        return (_sds(chip, (T, 5120), f32), _sds(chip, (T, 5120), f32), A,
+                _sds(chip, (T, 16), f32), _sds(chip, (T, 16), f32))
+
+    row = _sds(chip, (160,), i32)
+    assert _kernel_calls(ss._selective_update_pallas.lower(
+        state, *operands(160), row, _sds(chip, (160,), jnp.bool_),
+        _sds(chip, (1,), i32))) == 1
+    two = _sds(chip, (2,), i32)
+    assert _kernel_calls(ss._selective_scan_pallas.lower(
+        state, *operands(1024), _sds(chip, (1024,), i32), two, two, two,
+        _sds(chip, (1,), i32))) == 1
+
+
+@pytest.mark.parametrize("program", ["mixed", "decode"])
+def test_phi4flash_step_programs_compile_at_benchmark_shapes(chip, program):
+    """phi4-mini-flash-serve-1chip's two step programs at its published
+    widths and FULL depth (32 layers in three scans: six layer bodies):
+    the selective-scan kernels, the window form at a window of 512 over
+    pages of 64 and the ragged kernel at a differential pair as one
+    128-lane head (10 key/value heads, 4 queries a head), the cross
+    layers' reading of the one full layer's pages with no write, and the
+    head's [rows, 200064] float32 logits. Every pool leaf aliased from
+    argument to result, and both programs' peak (arguments + temporaries;
+    the configuration file keeps the numbers) fits the chip beside the
+    reference's scoring."""
+    cfg, engine = _phi4flash_cfg()
+    compiled, kv, rows = _compile_step_program(
+        chip, cfg, program, max_batch=engine["max_batch"],
+        pages=engine["total_pages"], max_seq=engine["max_seq_len"],
+        ps=engine["page_size"], chunk=engine["prefill_chunk"],
+        rows=engine["prefill_rows"])
+    slots = engine["max_batch"] + 1
+    assert kv["k"].shape == kv["v"].shape \
+        == (1, engine["total_pages"], 10, 64, 128)
+    assert kv["k_win"].shape[0] == 8 and kv["k_win"].shape[2:] == (10, 64, 128)
+    assert kv["ssm1"].shape == (9, slots, 16, 5120) \
+        and kv["ssm1"].dtype == jnp.float32
+    assert kv["ssm1_conv"].shape == (9, slots, 3, 5120)
+    text = compiled.as_text()
+    assert "ragged_window_kernel" in text \
+        and "_selective_update_pallas" in text
+    assert ("_selective_scan_pallas" in text) == (program == "mixed")
+    # a scan's body holds each kernel once: the update (x 2 scans) and, in
+    # the mixed step, the chunk scan beside it; a write and a read in the
+    # window and the full layer; a read and NO write in the cross layer
+    assert text.count("tpu_custom_call") == (12 if program == "mixed" else 7)
+    mem = compiled.memory_analysis()
+    held = sum(a.size * a.dtype.itemsize for a in kv.values())
+    assert mem.alias_size_in_bytes >= held
+    peak = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    print(f"phi4flash {program}: arguments "
+          f"{mem.argument_size_in_bytes / 1e9:.3f} GB, temporaries "
+          f"{mem.temp_size_in_bytes / 1e9:.3f} GB")
+    assert peak < 14.7e9      # + the reference's ~0.7 GB: under 15.5
