@@ -355,6 +355,16 @@ SLOT_STATE = {
 }
 STATE_LEAVES = tuple(leaf for leaves in SLOT_STATE.values()
                      for leaf in leaves)
+#: the kinds whose layers hold PAGES: the full group's and the second
+#: group's (``make_kv_cache`` stacks each group's leaves over them)
+PAGE_KINDS = (ATTENTION, WINDOW)
+
+
+def keeps_nothing(kind: str) -> bool:
+    """Whether a layer of ``kind`` leaves nothing in the pool: no page and
+    no state a slot. What it computes for a token only that token's later
+    layers read (llm/model.py: tail_start)."""
+    return kind not in PAGE_KINDS and not SLOT_STATE[kind]
 
 
 def delta_channels(cfg: LlamaConfig) -> int:

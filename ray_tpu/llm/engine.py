@@ -600,6 +600,11 @@ class InferenceEngine:
                       # rows that were a sequence's second or later in
                       # their step (_deal_chunk_rows)
                       "chunk_rows": 0, "chunk_rows_joined": 0,
+                      # valid tokens packed into mixed steps and, of them,
+                      # those that left the walk before its tail (every
+                      # token of a chunk row but its last; 0 where the
+                      # block's tail is empty: model.leaves_early)
+                      "walk_tokens": 0, "walk_tokens_left": 0,
                       # mixed steps that ran a shape of fewer chunk rows
                       # than prefill_rows (of ragged_dispatches)
                       "ragged_small_dispatches": 0,
@@ -1524,6 +1529,9 @@ class InferenceEngine:
                 stats["prefill_tokens"] += chunk_tokens
                 stats["chunk_rows"] += len(rows)
                 stats["chunk_rows_joined"] += flight.rows_joined
+                stats["walk_tokens"] += real
+                if self._fns.leaves_early:
+                    stats["walk_tokens_left"] += chunk_tokens - len(rows)
                 if self._has_state:
                     stats["state_resets"] += sum(
                         start == 0 for _, start, _ in rows)

@@ -12,7 +12,12 @@ feed-forward kind): the leading layers run once, then ONE ``lax.scan`` a
 segment over its periods, its body one period, so depth does not unroll.
 Every block but one is ONE segment (the Llama / Mistral and OLMoE blocks
 patterns of period 1); a decoder-hybrid-decoder, whose depth is not one
-period repeated, is three. Weights are one stack per KIND
+period repeated, is three. A token LEAVES the walk where nothing more is
+kept of it: the longest suffix of layers whose kind holds no page and no
+state a slot (``tail_start``: a decoder-hybrid-decoder's cross-decoder;
+empty in every other block) starts a segment, and in a step whose rows
+hold more than one token that segment runs on each row's LAST token only
+(``_layers``). Weights are one stack per KIND
 (models/llama.py; the Llama tree holds every layer's leaves flat and is
 split by name, ``_stacks``), closed over and indexed by a layer's ordinal
 among the layers of its kind; the routed experts' [layer, expert] weights
@@ -180,8 +185,8 @@ from ray_tpu.llm.cache import (DELTA_CONV_LEAF, DELTA_LEAF, RET_LEAF,
                                RET_NORM_LEAF, SCRATCH_PAGE, SSM1_CONV_LEAF,
                                SSM1_LEAF, SSM_CONV_LEAF, SSM_LEAF,
                                STATE_LEAF, STATE_LEAVES, WINDOW_LEAVES,
-                               keeps_slot_state, make_kv_cache,
-                               window_table_width)
+                               keeps_nothing, keeps_slot_state,
+                               make_kv_cache, window_table_width)
 from ray_tpu.models.llama import (ATTENTION, CONV, CROSS, DELTA, GMU, MAMBA,
                                   MAMBA1, RETENTION, WINDOW, LlamaConfig,
                                   Params, _rmsnorm, _rope, _rope_pairs,
@@ -1157,21 +1162,40 @@ OPERATORS = {ATTENTION: ("attn", _attention), CONV: ("conv", _short_conv),
 _SEGMENT_COST = 8
 
 
-def _pattern(cfg: LlamaConfig):
-    """(leading layers, then for each SEGMENT of the rest: one period, how
-    many periods): each layer is (operator kind, feed-forward kind). The
-    leading dense layers run before the scans; the rest is covered by
-    segments, each whole repeats of its period and a scan of its own, so
-    that the layer bodies traced, and ``_SEGMENT_COST`` a segment, come to
-    the least (fewest segments, then the shortest first period, where two
-    covers cost the same). A depth that is one period repeated is one
-    segment, of its shortest period."""
+def _kinds(cfg: LlamaConfig):
+    """(how many leading dense layers run before the scans, every layer as
+    (operator kind, feed-forward kind))."""
     L = cfg.n_layers
     ops = cfg.layer_types or (ATTENTION,) * L
     lead = cfg.n_dense_layers if cfg.n_experts else 0
-    kinds = [(ops[i], "moe" if cfg.n_experts and i >= lead else "dense")
-             for i in range(L)]
-    rest = kinds[lead:]
+    return lead, [(ops[i], "moe" if cfg.n_experts and i >= lead else "dense")
+                  for i in range(L)]
+
+
+def tail_start(cfg: LlamaConfig) -> int:
+    """The first layer of the walk's TAIL: the longest suffix of the layers
+    (behind the leading ones) whose operator keeps nothing (llm/cache.py:
+    ``keeps_nothing``: no page, no leaf of ``SLOT_STATE``) and whose
+    feed-forward is dense (row-wise, and counts nothing). What a tail
+    layer computes for a token, only that token's later layers and the
+    head read, and the head reads a row's LAST token: every other token of
+    a row may leave the walk here (``_layers``). ``cfg.n_layers`` where
+    the tail is empty: every block but a decoder-hybrid-decoder, whose
+    cross-decoder it is."""
+    lead, kinds = _kinds(cfg)
+    at = len(kinds)
+    while at > lead and keeps_nothing(kinds[at - 1][0]) \
+            and kinds[at - 1][1] == "dense":
+        at -= 1
+    return at
+
+
+def _cover(rest):
+    """``rest`` (layers, each (operator kind, feed-forward kind)) as
+    SEGMENTS, [one period, how many periods, ...]: each whole repeats of
+    its period and a scan of its own, so that the layer bodies traced, and
+    ``_SEGMENT_COST`` a segment, come to the least (fewest segments, then
+    the shortest first period, where two covers cost the same)."""
     n = len(rest)
     # best[i]: (cost, segments, their (period, repeats)) of rest[i:]
     best = {n: (0, 0, ())}
@@ -1188,11 +1212,24 @@ def _pattern(cfg: LlamaConfig):
                 r += 1
         cost, segs, _, cover = min(covers)
         best[i] = (cost, segs, cover)
-    out, i = [kinds[:lead]], 0
+    out, i = [], 0
     for p, r in best[0][2]:
         out += [rest[i:i + p], r]
         i += p * r
-    return tuple(out)
+    return out
+
+
+def _pattern(cfg: LlamaConfig):
+    """(leading layers, then for each SEGMENT of the rest: one period, how
+    many periods): each layer is (operator kind, feed-forward kind). The
+    leading dense layers run before the scans; the rest is covered by
+    segments (``_cover``), the layers before the tail and the tail
+    (``tail_start``) each on their own, so that a segment STARTS at the
+    tail. A depth that is one period repeated is one segment, of its
+    shortest period."""
+    lead, kinds = _kinds(cfg)
+    cut = tail_start(cfg)
+    return (kinds[:lead], *_cover(kinds[lead:cut]), *_cover(kinds[cut:]))
 
 
 def _stacks(layers, cfg: LlamaConfig):
@@ -1210,6 +1247,20 @@ def _stacks(layers, cfg: LlamaConfig):
                 {k: w for k, w in layers.items() if k in _FFN_LEAVES}}
 
 
+def _last_tokens(rows: _Rows, T: int):
+    """[R]: each row's last token among the step's ``T`` (an empty row's:
+    some token, which nothing reads)."""
+    return jnp.clip(rows.q_start + rows.q_len - 1, 0, T - 1)
+
+
+def leaves_early(cfg: LlamaConfig, max_q_len: Optional[int]) -> bool:
+    """Whether a step whose rows hold up to ``max_q_len`` tokens carries
+    one token a row through the walk's tail: there is a tail, and a row
+    may hold more than one token (in the decode loop every token IS its
+    row's last)."""
+    return tail_start(cfg) < cfg.n_layers and max_q_len != 1
+
+
 def _layers(layers, x, kv, rows: _Rows, valid, cfg: LlamaConfig, impl):
     """THE walk over the layers, of every block: the weights one stack per
     kind (``_stacks``), a layer finding its own by its ordinal among the
@@ -1220,8 +1271,18 @@ def _layers(layers, x, kv, rows: _Rows, valid, cfg: LlamaConfig, impl):
     (``_pattern``), its body one period; the Llama block is one segment of
     period 1, and a kind's ordinals go on counting from scan to scan. The
     pool is a carry, whole, updated in place at the ordinals; the weights
-    are closed over and indexed by the scan's counter. Returns (x, kv,
-    counters summed over the expert layers, or None)."""
+    are closed over and indexed by the scan's counter.
+
+    A token leaves the walk where nothing more is kept of it: at the tail
+    (``tail_start``; ``leaves_early``) the stream and the memory go on at
+    each row's LAST token only, as R one-token rows over the pages and
+    lengths the step has (a chunk row's last token sees its whole chunk:
+    the layers before wrote it into the carried pool), so the tail's
+    attention takes the kernel's one-token tile for every row and its
+    products have R rows where they had T.
+
+    Returns (x, kv, counters summed over the expert layers, or None); x is
+    [1, T, d], or [1, R, d] (a row's last token) where the walk was cut."""
     lead, *segments = _pattern(cfg)
     layers = _stacks(layers, cfg)
     if cfg.layers_of(MAMBA1):
@@ -1236,7 +1297,7 @@ def _layers(layers, x, kv, rows: _Rows, valid, cfg: LlamaConfig, impl):
         return {k: w[i] for k, w in layers[kind].items()
                 if not (kind == "moe" and k in _EXPERT_LEAVES)}
 
-    def one(x, kv, counters, kinds, ordinal):
+    def one(x, kv, counters, rows, kinds, ordinal):
         op, ffn = kinds
         stack, body = OPERATORS[op]
         x, kv = body(at(stack, ordinal[op]), ordinal[op], x, kv, rows, cfg,
@@ -1249,7 +1310,7 @@ def _layers(layers, x, kv, rows: _Rows, valid, cfg: LlamaConfig, impl):
             x = _mlp(at("dense", ordinal[ffn]), x, cfg, rows.tp_axis)
         return x, kv, counters
 
-    def run(carry, some, first, j=0, per=None):
+    def run(carry, rows, some, first, j=0, per=None):
         """``some`` layers in turn; a layer's ordinal among its kind is
         ``first`` + those before it here (+ j whole periods of ``per``)."""
         x, kv, counters = carry
@@ -1257,22 +1318,40 @@ def _layers(layers, x, kv, rows: _Rows, valid, cfg: LlamaConfig, impl):
         for kinds in some:
             ordinal = {k: first[k] + (j * per[k] if per else 0)
                        for k in kinds}
-            x, kv, counters = one(x, kv, counters, kinds, ordinal)
+            x, kv, counters = one(x, kv, counters, rows, kinds, ordinal)
             for k in kinds:
                 first[k] += 1
         return (x, kv, counters), first
 
+    def leave(carry, rows):
+        """(carry, rows) at each row's last token: R rows of one token."""
+        x, kv, counters = carry
+        R = rows.q_start.shape[0]
+        last = _last_tokens(rows, x.shape[1])
+        if MEMORY in kv:
+            kv = {**kv, MEMORY: kv[MEMORY][last]}
+        return (x[:, last], kv, counters), _Rows(
+            rows.token_pos[last], None, jnp.arange(R, dtype=jnp.int32),
+            jnp.minimum(rows.q_len, 1), decode_rows=R,
+            page_table=rows.page_table, kv_len=rows.kv_len, max_q_len=1,
+            tp_axis=rows.tp_axis)
+
     carry = (x, kv, jnp.zeros(
         len(moe.COUNTERS) + bool(cfg.experts_held), jnp.int32))
-    carry, seen = run(carry, lead, dict.fromkeys((*OPERATORS, "dense",
-                                                  "moe"), 0))
+    carry, seen = run(carry, rows, lead, dict.fromkeys(
+        (*OPERATORS, "dense", "moe"), 0))
+    done = len(lead)
+    cut = tail_start(cfg) if leaves_early(cfg, rows.max_q_len) else None
     for period, n_periods in zip(segments[::2], segments[1::2]):
+        if done == cut:
+            carry, rows = leave(carry, rows)
         per = collections.Counter(k for kinds in period for k in kinds)
         carry, _ = lax.scan(
-            lambda carry, j, period=period, seen=seen, per=per:
-            (run(carry, period, seen, j, per)[0], None),
+            lambda carry, j, rows=rows, period=period, seen=seen, per=per:
+            (run(carry, rows, period, seen, j, per)[0], None),
             carry, jnp.arange(n_periods, dtype=jnp.int32))
         seen = {k: at + n_periods * per[k] for k, at in seen.items()}
+        done += len(period) * n_periods
     x, kv, counters = carry
     kv = {k: leaf for k, leaf in kv.items() if k != MEMORY}
     return x, kv, counters if cfg.n_experts else None
@@ -1323,15 +1402,17 @@ def _ragged_logits(params: Params, tokens: jax.Array,
         x = x * jnp.asarray(cfg.embed_scale, cd)
     # a padding token is one whose page is the scratch page
     valid = token_page != SCRATCH_PAGE if cfg.n_experts else None
-    x, kv, counters = _layers(
-        params["layers"], x, kv,
-        _Rows(token_pos, token_state, q_start, q_len, decode_rows,
-              token_page, token_slot, page_table, kv_len, max_q_len,
-              tp_axis, token_page_win, page_table_win, page_base_win),
-        valid, cfg, paged_impl)
+    rows = _Rows(token_pos, token_state, q_start, q_len, decode_rows,
+                 token_page, token_slot, page_table, kv_len, max_q_len,
+                 tp_axis, token_page_win, page_table_win, page_base_win)
+    x, kv, counters = _layers(params["layers"], x, kv, rows, valid, cfg,
+                              paged_impl)
     x = _norm(x, params["final_norm"], cfg, params.get("final_norm_b"))
-    last = jnp.clip(q_start + q_len - 1, 0, T - 1)        # [R]
-    xl = x[0][last]
+    if leaves_early(cfg, max_q_len):
+        xl = x[0]                     # [R, d] already: the walk was cut
+    else:
+        last = _last_tokens(rows, T)                      # [R]
+        xl = x[0][last]
     head = params["embed"] if cfg.tie_embeddings else params["lm_head"]
     with _head_scope(cfg):
         logits = jnp.einsum("rd,vd->rv", xl.astype(cd), head.astype(cd),
@@ -1689,6 +1770,9 @@ class StepPrograms:
             n: step_layout(decode_rows, n, max_q_len, max_pages,
                            keeps_slot_state(cfg), self.window_pages["step"])
             for n in self.row_shapes}
+        #: whether a mixed step's tokens that are not their row's last
+        #: leave the walk before its tail (the engine counts them)
+        self.leaves_early = leaves_early(cfg, max_q_len)
         step_statics = dict(
             layouts=tuple(self.step_layouts.values()), cfg=cfg,
             paged_impl=impl, max_q_len=max_q_len, decode_rows=decode_rows)

@@ -261,3 +261,64 @@ def test_hold_readings_method_at_tiny_widths():
     assert row["bf16"]["worst"] <= row["fp8"]["worst"]
     assert [s["tokens"] for s in row["fp8"]["groups"].values()] == [24, 80]
     assert row["bf16"]["correct"] == (not row["bf16"]["faults"])
+
+
+# ----------------------- the tokens that leave the walk before its tail
+
+def _served_stats(block: str) -> dict:
+    """The engine's counters after three prompts of one to three chunks of
+    16 beside decode rows, at the block's tiny widths."""
+    import numpy as np
+
+    from _blocks import ENGINE, config, run
+    from ray_tpu.llm import InferenceEngine
+    cfg = config(block)
+    eng = InferenceEngine(cfg, **ENGINE)
+    rng = np.random.default_rng(1)
+    for n, new in ((7, 9), (40, 3), (33, 3)):
+        eng.add_request(rng.integers(1, cfg.vocab_size, n).tolist(), new)
+    run(eng)
+    return eng.stats
+
+
+@pytest.mark.parametrize("block,tail", [("mistral", False), ("granite", False),
+                                        ("phi4flash", True)])
+def test_the_engine_counts_the_tokens_that_left_before_the_tail(block, tail):
+    """`walk_tokens`: every valid token of a mixed step; `walk_tokens_left`:
+    those of them that are not their row's last, where the block has a
+    tail (a decoder-hybrid-decoder's cross-decoder), and 0 where it has
+    none: the Llama block's walk, and a state-space block's, is cut
+    nowhere."""
+    stats = _served_stats(block)
+    assert stats["walk_tokens"] == stats["ragged_real_tokens"] > 0
+    assert stats["prefill_tokens"] == 80 and stats["chunk_rows"] >= 6
+    assert stats["walk_tokens_left"] == (
+        stats["prefill_tokens"] - stats["chunk_rows"] if tail else 0)
+
+
+def test_tail_skipped_pct_is_the_two_counters_ratio_and_silent_without():
+    """The metric file ISSUE 64 names, as data only: engine_clocks.py's
+    ratio of the two counters over the window, in the one cell whose block
+    has a tail; a program without the counters (the parent's) reads
+    nothing and does not raise."""
+    from benchmark.readers import engine_clocks
+    spec = _load("metrics", "tail_skipped_pct.phi4flash.json")
+    assert (spec["name"], spec["reader"]) == ("tail_skipped_pct.phi4flash",
+                                              "engine_clocks")
+    assert spec["args"] == {"num": ["walk_tokens_left"],
+                            "den": ["walk_tokens"], "scale": 100}
+    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+    assert bench["per_layer"][-1] == {
+        "name": "tail_skipped_pct.phi4flash", "unit": "%",
+        "better": "higher", "source": "program_counter",
+        "layer": "step programs (llm/model.py, llm/tp.py)",
+        "moves": "out_tok_per_s", "workloads": ["reason-phi4flash-1chip"]}
+    opened = {"walk_tokens": 1000, "walk_tokens_left": 700}
+    closed = {"walk_tokens": 1000 + 670 + 1182,
+              "walk_tokens_left": 700 + 511 + 1022}
+    got = engine_clocks.read({"stats_open": opened, "stats_close": closed},
+                             spec["args"])
+    assert got == pytest.approx(100 * 1533 / 1852)
+    assert engine_clocks.read(
+        {"stats_open": {"chunk_rows": 1}, "stats_close": {"chunk_rows": 9}},
+        spec["args"]) is None

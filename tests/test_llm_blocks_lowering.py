@@ -95,12 +95,12 @@ def loops(jaxpr) -> dict:
     return n
 
 
-def traced(block: str) -> dict:
+def traced(block: str, **over) -> dict:
     """name -> a tree's text or a jaxpr: the parameter tree, and for
     the reference and the kernel path the pool's tree and the jaxprs of
     the mixed step, the decode loop and the page copy, of ``block`` at
-    tiny widths."""
-    cfg = config(block)
+    tiny widths (``over``: fields on top of the row's)."""
+    cfg = config(block, **over)
     params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
     out = {f"{block}.params": str(jax.tree.map(
         lambda a: (a.shape, str(a.dtype)), params))}
@@ -252,12 +252,13 @@ PATTERNS = {
     "gigachat": ([("linear_attention", "dense")],
                  [("linear_attention", "moe")] * 3
                  + [("full_attention", "moe")], 1),
-    # eight layers are cheaper as one period run once than as segments
-    # (model.py: _SEGMENT_COST); the published depth is three segments, a
-    # scan each (tests/test_llm_phi4flash.py)
+    # a segment starts at the tail (model.py: tail_start), and the six
+    # layers before it are cheaper as one period run once than as segments
+    # (_SEGMENT_COST); the published depth is three segments, a scan each
+    # (tests/test_llm_phi4flash.py)
     "phi4flash": ([], [("mamba1", "dense"), ("sliding_attention", "dense")]
-                  * 2 + [("mamba1", "dense"), ("full_attention", "dense"),
-                         ("gmu", "dense"), ("cross_attention", "dense")], 1)}
+                  * 2 + [("mamba1", "dense"), ("full_attention", "dense")], 1,
+                  [("gmu", "dense"), ("cross_attention", "dense")], 1)}
 
 
 @pytest.mark.parametrize("block", sorted(BLOCKS))
@@ -274,6 +275,41 @@ def test_every_block_is_a_pattern_of_the_one_walk(block):
         | {ffn for _, ffn in walked}
     assert sorted(map(id, jax.tree.leaves(stacks))) \
         == sorted(map(id, jax.tree.leaves(params["layers"])))
+
+
+#: the published decoder-hybrid-decoder's depth, on the row's tiny widths
+PUBLISHED_DEPTH = dict(n_layers=32, layer_types=(
+    ["mamba1", "sliding_attention"] * 8 + ["mamba1", "full_attention"]
+    + ["gmu", "cross_attention"] * 7))
+#: the layers of each block's TAIL (model.py: tail_start), which keep
+#: nothing: every token of a row but its last leaves the walk before them
+TAILS = {**dict.fromkeys(BLOCKS, ()),
+         "phi4flash": ("gmu", "cross_attention"),
+         "phi4flash at the published depth": ("gmu", "cross_attention") * 7}
+
+
+@pytest.mark.parametrize("case", sorted(TAILS))
+def test_the_tail_is_the_layers_that_keep_nothing(case):
+    """Empty for every other block (their walk is cut nowhere), the
+    cross-decoder for this one (layers 18-31 of the published 32), read
+    from the two tables: no page group's kind, no leaf of SLOT_STATE, a dense
+    feed-forward. The pattern starts a segment there."""
+    block, _, deep = case.partition(" at ")
+    cfg = config(block, **(PUBLISHED_DEPTH if deep else {}))
+    kinds = cfg.layer_types or ("full_attention",) * cfg.n_layers
+    at = M.tail_start(cfg)
+    assert tuple(kinds[at:]) == TAILS[case]
+    assert at == (18 if deep else cfg.n_layers - len(TAILS[case]))
+    assert all(C.keeps_nothing(k) for k in kinds[at:])
+    assert at == 0 or not C.keeps_nothing(kinds[at - 1]) or cfg.n_experts
+    lead, *segments = M._pattern(cfg)
+    starts = [len(lead)]
+    for period, n in zip(segments[::2], segments[1::2]):
+        starts.append(starts[-1] + len(period) * n)
+    assert at in starts
+    # a one-token row is its own last token: the decode loop is cut nowhere
+    assert not M.leaves_early(cfg, 1)
+    assert M.leaves_early(cfg, 8) == bool(TAILS[case])
 
 
 def test_every_kind_of_layer_is_declared_once_in_each_table():
@@ -346,6 +382,12 @@ if __name__ == "__main__":
         except ValueError as e:      # a checkout from before the block
             print(block, "not built here:", str(e)[:60])
             continue
+        if block == "phi4flash":
+            # the depth the cell serves: its segments are the published
+            # ones, where the row's eight layers are cut to fewer
+            found.update({name.replace(block, block + "@32", 1): t
+                          for name, t in traced(
+                              block, **PUBLISHED_DEPTH).items()})
         for name, t in found.items():
             print(name, hashlib.sha256(_text(t).encode()).hexdigest()[:16],
                   *(() if isinstance(t, str) or name.endswith(".copy")

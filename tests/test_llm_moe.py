@@ -240,8 +240,9 @@ def test_dense_configuration_is_untouched():
         "steps", "prefill_tokens", "decode_steps", "decode_tokens",
         "decode_dispatches", "cached_tokens", "ragged_dispatches",
         "ragged_real_tokens", "ragged_slot_tokens", "cow_copies",
-        "preemptions", "chunk_rows", "chunk_rows_joined",
-        "ragged_small_dispatches", "h2d_arrays", "ahead_dispatches",
+        "preemptions", "chunk_rows", "chunk_rows_joined", "walk_tokens",
+        "walk_tokens_left", "ragged_small_dispatches", "h2d_arrays",
+        "ahead_dispatches",
         "late_retired_rows", "ahead_drains", "held_launches",
         "late_launches", "late_mixed_launches"} \
         | set(WALL_KEYS + (CPU_KEY,)) \
