@@ -1,7 +1,14 @@
-"""Every block the engine serves, through the same engine-level cases:
-each row of tests/_blocks.py:BLOCKS on seeded weights, tiny widths on the
-CPU, float32 compute, against the block's plain reference under
-benchmark/ (whole sequences, no page, no cache, no state).
+"""The engine-level cases every block the engine serves goes through,
+written ONCE: a row of tests/_blocks.py:BLOCKS on seeded weights, tiny
+widths on the CPU, float32 compute, against the block's plain reference
+under benchmark/ (whole sequences, no page, no cache, no state).
+
+Not a test file. A test FILE is what a worker of the suite is handed
+(`--dist loadfile`), so a block's cases are collected under a file of its
+own, tests/test_llm_block_<row>.py: a stub that takes the cases from here
+and names its row (``BLOCK``); one engine and one set of compiles a block,
+and six workers share the blocks. tests/test_llm_blocks_lowering.py holds
+the stubs to the rows of BLOCKS.
 
 Chunked prefill and the decode loop, a mixed batch with padding rows, one
 prompt at several chunk sizes and decode rows behind a chunk row (LOGITS),
@@ -38,12 +45,21 @@ from ray_tpu.llm.cache import (WINDOW_LEAVES, make_kv_cache,  # noqa: E402
 from ray_tpu.models import llama  # noqa: E402
 
 
-@pytest.fixture(scope="module", params=sorted(BLOCKS))
+def pytest_generate_tests(metafunc):
+    """The file that collects these cases names ONE row: a parameter list
+    of one, so a case's id says its block as it did when one file ran every
+    row (``[granite-40-13]``)."""
+    if "served" in metafunc.fixturenames:
+        metafunc.parametrize("served", [metafunc.module.BLOCK],
+                             indirect=True, scope="module")
+
+
+@pytest.fixture(scope="module")
 def served(request):
     """(the block's name, its row, its configuration, an engine on seeded
     weights): one engine a block, every case of the block in turn."""
     # compiled_step_programs() counts the process's shared jits: whatever
-    # this worker ran before must not count against this engine
+    # file this worker ran before must not count against this engine
     jax.clear_caches()
     block = request.param
     cfg, params = built(block)

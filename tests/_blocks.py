@@ -4,14 +4,24 @@ compare what was served (its plain reference under benchmark/, the
 tolerance, the state it keeps beside its pages), and the helpers every
 engine-level comparison is written with.
 
-Not a test file. tests/test_llm_blocks.py runs every engine-level case
-over every row; tests/test_llm_blocks_lowering.py reads the programs'
-texts, tests/test_llm_ahead.py the engine that runs ahead; a block's own
-file (tests/test_llm_<block>.py) keeps its operator's arithmetic and the
-faults its reference must tell apart, on a configuration of its own.
+Not a test file. The engine-level cases are written once, in
+tests/_block_cases.py, and run over every row, each row under a collected
+file of its own (tests/test_llm_block_<row>.py: a file is what a worker of
+the suite is handed, so no file runs every row);
+tests/test_llm_blocks_lowering.py reads the programs' texts,
+tests/test_llm_ahead.py the engine that runs ahead; a block's own file
+(tests/test_llm_<block>.py) keeps its operator's arithmetic and the faults
+its reference must tell apart, on a configuration of its own.
 
 A new block is a row here: it gets every engine case the day it is added,
-and a change that breaks an older block fails a case that names it.
+and a change that breaks an older block fails a case that names it. The
+row's file is three lines, a docstring and
+
+    from _block_cases import *  # noqa: F401,F403
+    BLOCK = "<row>"
+
+and tests/test_llm_blocks_lowering.py fails, with these lines in its
+message, while a row has no file or a file names a row that is gone.
 """
 
 import dataclasses

@@ -27,6 +27,10 @@ jax.config.update("jax_platforms", "cpu")
 
 import pytest  # noqa: E402
 
+# helper modules that hold test functions for several collected files: their
+# asserts say what they compared, as a test file's do
+pytest.register_assert_rewrite("_block_cases", "_tpu_compile")
+
 
 def pytest_configure(config):
     config.addinivalue_line(

@@ -199,10 +199,11 @@ def test_wrap_probeless_signature_novelty_path():
     assert st["last_sig"] == ["f32[5]", "flag=bool"]
 
 
-def _fire(event: str, duration: float) -> None:
+def _fire(event: str, duration: float, now: float = None) -> None:
     """jax.monitoring reporting one phase as it ends: a span of
-    ``duration`` seconds that ends now, handed to the tracker's listener."""
-    now = time.time()
+    ``duration`` seconds that ends now (by the test's clock, where it has
+    one), handed to the tracker's listener."""
+    now = time.time() if now is None else now
     ct._on_jax_span(event, now - duration, now)
 
 
@@ -237,7 +238,28 @@ def test_wrap_attributes_inflight_monitoring_durations():
     assert rec["duration_s"] > 0
 
 
-def test_callable_stats_keep_the_split_and_the_hits():
+class _SteppedClock:
+    """The ``time`` the tracker's module reads, moved by the test alone:
+    ``sleep`` steps it and returns at once, so which event HOLDS which, and
+    a call's wall seconds, are what the test wrote and not how long a
+    loaded machine overslept."""
+
+    def __init__(self):
+        self.now = 1000.0
+
+    def sleep(self, seconds):
+        self.now += seconds
+
+    def time(self):
+        return self.now
+
+    perf_counter = time
+
+    def __getattr__(self, name):
+        return getattr(time, name)
+
+
+def test_callable_stats_keep_the_split_and_the_hits(monkeypatch):
     """What the listener attributes to a wrapped call is kept by phase
     (trace_s, lower_s, backend_s), per callable and in the ring record,
     with how many compiles were persistent-cache hits and how many cold;
@@ -245,21 +267,26 @@ def test_callable_stats_keep_the_split_and_the_hits():
     on its own and inside the trace that met it) counts them once; and a
     wrapped call's compile is this callable's alone: no nameless record,
     no backend_compile count beside it."""
+    clock = _SteppedClock()
+    monkeypatch.setattr(ct, "time", clock)
     tr = ct.CompileTracker(role="w", storm_threshold=0)
     ct.stop_global()
-    fire, pre = _fire, "/jax/core/compile/"
+    pre = "/jax/core/compile/"
+
+    def fire(event, duration):
+        _fire(event, duration, clock.now)
 
     def fn(x, hit):
-        time.sleep(0.05)
+        clock.sleep(0.05)
         fire(pre + "jaxpr_trace_duration", 0.01)       # a nested jit ...
-        time.sleep(0.02)
+        clock.sleep(0.02)
         fire(pre + "jaxpr_trace_duration", 0.015)      # ... and a second
         fire(pre + "jaxpr_trace_duration", 0.05)       # the trace holding both
-        time.sleep(0.02)
+        clock.sleep(0.02)
         fire(pre + "jaxpr_to_mlir_module_duration", 0.02)
         if hit:
             ct._on_jax_event("/jax/compilation_cache/cache_hits")
-        time.sleep(0.03)
+        clock.sleep(0.03)
         fire(pre + "backend_compile_duration", 0.03)
         return x
 

@@ -23,9 +23,11 @@ entry keeps the finding; no digest is kept here, where every later change
 to model.py or a kernel would have to overwrite it.
 """
 
+import glob
 import hashlib
 import json
 import os
+import re
 import sys
 
 ROOT = os.path.abspath(sys.argv[1]) if __name__ == "__main__" \
@@ -372,6 +374,30 @@ def test_pool_descriptor_and_engine_follow_the_declared_state(block):
     assert (eng.prefix is None) == (has_state or windowed)
     assert eng.device_report()["state_bytes"] == sum(
         a.nbytes for k, a in eng.kv.items() if k in declared)
+
+
+def test_every_block_has_the_file_that_collects_its_engine_cases():
+    """A new block is a row of BLOCKS, and a row's engine-level cases
+    (tests/_block_cases.py) are collected under a file of their own, the
+    unit a worker of the suite is handed: every row has its file, and no
+    file names a row that is gone."""
+    here = os.path.dirname(os.path.abspath(__file__))
+    named = {}
+    for path in glob.glob(os.path.join(here, "test_llm_block_*.py")):
+        with open(path) as f:
+            named[os.path.basename(path)] = re.findall(
+                r'^BLOCK = "(\w+)"$', f.read(), re.M)
+    want = {f"test_llm_block_{block}.py": [block] for block in BLOCKS}
+    missing = sorted(b for b in BLOCKS
+                     if f"test_llm_block_{b}.py" not in named)
+    stray = sorted(f for f, rows in named.items() if rows != want.get(f))
+    assert not missing and not stray, (
+        f"rows of tests/_blocks.py:BLOCKS without their file: {missing}; "
+        f"files that name no row, or not the row of their name: {stray}. "
+        "A row's file is tests/test_llm_block_<row>.py and three lines (as "
+        "tests/test_llm_block_mistral.py): a docstring, `from _block_cases "
+        'import *  # noqa: F401,F403` and `BLOCK = "<row>"`; a row that '
+        "is gone takes its file with it.")
 
 
 if __name__ == "__main__":

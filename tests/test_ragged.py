@@ -8,6 +8,15 @@ the kernel logic — the static tiling from ``decode_rows`` / ``max_q_len``,
 page copies driven by the scalar-prefetched table, the per-tile block
 count and causal mask, online softmax across a tile's KV blocks — is
 exercised in tier-1 on CPU.
+
+A test file is what a worker of the suite is handed, so the interpreter's
+long cases stand in files of their own: what blocking can break in
+tests/test_ragged_blocked.py, the latent one-token tile in
+tests/test_ragged_latent.py, the window block's full layers in
+tests/test_ragged_full_layer.py. Here: the reference against the dense
+oracle (tests/_ragged.py), the kernel on the small batch, int8 pages, the
+in-place write, the step programs through the kernels, rows of one
+sequence.
 """
 
 import jax
@@ -15,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _ragged import dense_oracle, mixed_batch, unowned
 from ray_tpu.ops.int8 import dequantize_kv, quantize_kv
 from ray_tpu.ops.paged_attention import (_ragged_attention_pallas,
                                          ragged_paged_attention,
@@ -22,64 +32,10 @@ from ray_tpu.ops.paged_attention import (_ragged_attention_pallas,
                                          write_ragged_kv)
 
 
-def _dense_oracle(q, kp, vp, pt, q_start, q_len, kv_len,
-                  k_scale=None, v_scale=None):
-    """Per-token dense attention: gather row pages, causal-mask by the
-    token's absolute position, fp32 softmax. Padding tokens -> 0."""
-    q, kp, vp = map(lambda a: np.asarray(a, np.float64), (q, kp, vp))
-    if k_scale is not None:
-        kp = kp * np.asarray(k_scale, np.float64)[..., None]
-        vp = vp * np.asarray(v_scale, np.float64)[..., None]
-    T, Hq, D = q.shape
-    Hkv, ps = kp.shape[1], kp.shape[2]
-    g = Hq // Hkv
-    out = np.zeros((T, Hq, D))
-    for r in range(len(q_start)):
-        for j in range(int(q_len[r])):
-            t = int(q_start[r]) + j
-            vis = int(kv_len[r]) - int(q_len[r]) + j + 1
-            pages = np.asarray(pt[r])[: -(-vis // ps)]
-            k = kp[pages].transpose(1, 0, 2, 3).reshape(Hkv, -1, D)[:, :vis]
-            v = vp[pages].transpose(1, 0, 2, 3).reshape(Hkv, -1, D)[:, :vis]
-            qg = q[t].reshape(Hkv, g, D)
-            s = np.einsum("hgd,htd->hgt", qg, k) * D ** -0.5
-            p = np.exp(s - s.max(-1, keepdims=True))
-            p /= p.sum(-1, keepdims=True)
-            out[t] = np.einsum("hgt,htd->hgd", p, v).reshape(Hq, D)
-    return out
-
-
-def _mixed_batch(key, Hq, Hkv, D, ps=8, pages=12, max_pages=4):
-    """2 decode rows + 1 inactive row + 2 prefill chunks, one chunk
-    straddling a page boundary (ends mid-page after crossing one)."""
-    ks = jax.random.split(key, 3)
-    T = 16
-    q = jax.random.normal(ks[0], (T, Hq, D), jnp.float32)
-    kp = jax.random.normal(ks[1], (pages, Hkv, ps, D), jnp.float32)
-    vp = jax.random.normal(ks[2], (pages, Hkv, ps, D), jnp.float32)
-    pt = jnp.array([[1, 2, 3, 4], [5, 6, 7, 8], [0, 0, 0, 0],
-                    [9, 10, 11, 1], [2, 3, 4, 5]], jnp.int32)
-    # rows: decode len 11, decode len 24, inactive, 6-tok chunk ending
-    # at kv position 21 (straddles the page-2 -> page-3 boundary), 4-tok
-    # chunk fully inside page 0 of its table
-    q_start = jnp.array([0, 1, 0, 3, 9], jnp.int32)
-    q_len = jnp.array([1, 1, 0, 6, 4], jnp.int32)
-    kv_len = jnp.array([11, 24, 0, 21, 4], jnp.int32)
-    return q, kp, vp, pt, q_start, q_len, kv_len
-
-
-def _unowned(args):
-    """Mask of the padding tokens of a ragged batch (owned by no row)."""
-    owned = np.zeros(args[0].shape[0], bool)
-    for s, l in zip(args[4], args[5]):
-        owned[int(s):int(s) + int(l)] = True
-    return ~owned
-
-
 @pytest.mark.parametrize("Hq,Hkv", [(8, 8), (8, 4), (8, 1)])
 def test_ragged_reference_matches_dense_gqa(Hq, Hkv):
-    args = _mixed_batch(jax.random.PRNGKey(Hq * 10 + Hkv), Hq, Hkv, 32)
-    want = _dense_oracle(*args)
+    args = mixed_batch(jax.random.PRNGKey(Hq * 10 + Hkv), Hq, Hkv, 32)
+    want = dense_oracle(*args)
     got = ragged_paged_attention_reference(*args, max_q_len=6,
                                            decode_rows=2)
     np.testing.assert_allclose(np.asarray(got), want, atol=1e-5)
@@ -87,14 +43,14 @@ def test_ragged_reference_matches_dense_gqa(Hq, Hkv):
     got2 = ragged_paged_attention_reference(*args)
     np.testing.assert_allclose(np.asarray(got2), want, atol=1e-5)
     # padding tokens (owned by no row) must come back exactly zero
-    assert np.all(np.asarray(got)[_unowned(args)] == 0.0)
+    assert np.all(np.asarray(got)[unowned(args)] == 0.0)
 
 
 @pytest.mark.pallas_interpret
 @pytest.mark.parametrize("Hq,Hkv", [(8, 8), (8, 4), (8, 1)])
 def test_ragged_pallas_interpret_matches_reference(Hq, Hkv, pallas_interpret):
     D = 128   # lane-width head_dim, the TPU-shaped case
-    args = _mixed_batch(jax.random.PRNGKey(Hq + Hkv), Hq, Hkv, D, ps=16)
+    args = mixed_batch(jax.random.PRNGKey(Hq + Hkv), Hq, Hkv, D, ps=16)
     ref = ragged_paged_attention_reference(*args)
     out = _ragged_attention_pallas(*args, None, None, D ** -0.5,
                                    interpret=pallas_interpret)
@@ -105,7 +61,7 @@ def test_ragged_pallas_interpret_matches_reference(Hq, Hkv, pallas_interpret):
 @pytest.mark.pallas_interpret
 def test_ragged_pallas_int8_pages(pallas_interpret):
     Hq, Hkv, D = 8, 4, 128
-    q, kp, vp, pt, q_start, q_len, kv_len = _mixed_batch(
+    q, kp, vp, pt, q_start, q_len, kv_len = mixed_batch(
         jax.random.PRNGKey(11), Hq, Hkv, D, ps=16)
     kq, ksc = quantize_kv(kp)
     vq, vsc = quantize_kv(vp)
@@ -135,12 +91,12 @@ def test_ragged_single_row_degenerate():
     q1 = jax.random.normal(ks[0], (1, Hq, D), jnp.float32)
     dec = ragged_paged_attention_reference(
         q1, kp, vp, pt, jnp.array([0]), jnp.array([1]), jnp.array([17]))
-    want = _dense_oracle(q1, kp, vp, pt, [0], [1], [17])
+    want = dense_oracle(q1, kp, vp, pt, [0], [1], [17])
     np.testing.assert_allclose(np.asarray(dec), want, atol=1e-5)
     q5 = jax.random.normal(ks[0], (5, Hq, D), jnp.float32)
     pf = ragged_paged_attention_reference(
         q5, kp, vp, pt, jnp.array([0]), jnp.array([5]), jnp.array([13]))
-    want = _dense_oracle(q5, kp, vp, pt, [0], [5], [13])
+    want = dense_oracle(q5, kp, vp, pt, [0], [5], [13])
     np.testing.assert_allclose(np.asarray(pf), want, atol=1e-5)
 
 
@@ -159,365 +115,18 @@ def test_ragged_all_decode_matches_decode_reference():
     rag = ragged_paged_attention_reference(
         q, kp, vp, pt, jnp.arange(B, dtype=jnp.int32),
         jnp.ones(B, jnp.int32), sl, decode_rows=B, max_q_len=1)
-    want = _dense_oracle(q, kp, vp, pt, range(B), [1] * B, sl)
+    want = dense_oracle(q, kp, vp, pt, range(B), [1] * B, sl)
     np.testing.assert_allclose(np.asarray(rag), want, atol=1e-5)
 
 
 def test_ragged_dispatcher_interpret_path():
     """The public entry point routes to the kernel (interpret=True on
     CPU) and matches the reference on a mixed batch."""
-    args = _mixed_batch(jax.random.PRNGKey(2), 8, 4, 128, ps=16)
+    args = mixed_batch(jax.random.PRNGKey(2), 8, 4, 128, ps=16)
     ref = ragged_paged_attention_reference(*args)
     out = ragged_paged_attention(*args, impl="kernel", interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-2)
-
-
-# ------------------------------------------- what blocking can break
-#
-# name -> (Hq, Hkv, T, decode_rows, max_q_len, [(q_start, q_len, kv_len)]).
-# page_size 16, so prefill tiles are min(128, max_q_len rounded up to 8)
-# tokens against blocks of 256 kv slots and decode tiles are one token
-# against blocks of 384 (the whole 24-page table; 512 with a wider one).
-_SMALL_ROWS = [(0, 1, 50), (1, 1, 7), (2, 0, 0), (3, 1, 130), (4, 12, 44),
-               (16, 5, 5)]
-_BLOCKED_CASES = {
-    # a chunk that starts mid-tile in q and is no multiple of the tile
-    "chunk_mid_tile_ragged_len": (
-        8, 2, 176, 2, 160, [(0, 1, 40), (1, 1, 300), (5, 150, 150)]),
-    # cached prefixes that end mid-page and mid-block (70, 200 tokens)
-    "prefix_mid_page_mid_block": (
-        8, 2, 192, 0, 152, [(2, 150, 220), (152, 30, 230)]),
-    # kv_len exactly at a block edge, and one past it
-    "kv_len_at_block_edge": (
-        8, 2, 296, 4, 136,
-        [(0, 1, 256), (1, 1, 257), (2, 1, 128), (3, 1, 129),
-         (4, 128, 128), (132, 129, 129), (261, 28, 128)]),
-    "empty_rows_between_live": (
-        8, 2, 64, 4, 24,
-        [(0, 1, 33), (0, 0, 0), (1, 1, 18), (0, 0, 0),
-         (0, 0, 0), (4, 20, 20), (0, 0, 0), (24, 9, 50)]),
-    # gaps between rows and a long tail that no row owns
-    "padding_tokens_exact_zeros": (
-        8, 2, 96, 1, 16, [(3, 1, 20), (10, 7, 7), (30, 16, 40)]),
-    # the decode loop's shape, with free batch slots
-    "decode_rows_with_empty_slots": (
-        8, 2, 8, 8, 1,
-        [(0, 1, 17), (1, 0, 0), (2, 1, 256), (3, 0, 0), (4, 1, 1),
-         (5, 1, 300), (6, 0, 0), (7, 1, 96)]),
-    # Mistral-7B's head shapes and its tp=4 shard's, at a small T
-    "mistral_heads_32_8": (32, 8, 24, 4, 12, _SMALL_ROWS),
-    "tp4_shard_heads_8_2": (8, 2, 24, 4, 12, _SMALL_ROWS),
-}
-
-
-def _blocked_batch(name, kv, poison_unused_pages=False):
-    """bf16 q and a bf16 or int8 pool for one of _BLOCKED_CASES; every
-    row gets its own pages. ``poison_unused_pages`` points the table
-    entries past a row's length at a page of NaNs."""
-    Hq, Hkv, T, decode_rows, max_q_len, rows = _BLOCKED_CASES[name]
-    D, ps, max_pages, P = 128, 16, 24, 8 * 24 + 2
-    ks = jax.random.split(jax.random.PRNGKey(len(name)), 3)
-    q = jax.random.normal(ks[0], (T, Hq, D), jnp.float32)
-    kp = jax.random.normal(ks[1], (P, Hkv, ps, D), jnp.float32)
-    vp = jax.random.normal(ks[2], (P, Hkv, ps, D), jnp.float32)
-    pt = 1 + np.random.default_rng(len(name)).permutation(
-        len(rows) * max_pages).reshape(len(rows), max_pages)
-    if poison_unused_pages:
-        kp, vp = kp.at[P - 1].set(jnp.nan), vp.at[P - 1].set(jnp.nan)
-        for r, (_, _, kv_len) in enumerate(rows):
-            pt[r, -(-kv_len // ps):] = P - 1
-    q_start, q_len, kv_len = (jnp.array(c, jnp.int32) for c in zip(*rows))
-    q = q.astype(jnp.bfloat16)
-    if kv == "int8":
-        (kp, ksc), (vp, vsc) = quantize_kv(kp), quantize_kv(vp)
-    else:
-        kp, vp, ksc, vsc = (kp.astype(jnp.bfloat16),
-                            vp.astype(jnp.bfloat16), None, None)
-    args = (q, kp, vp, jnp.asarray(pt, jnp.int32), q_start, q_len, kv_len)
-    return args, dict(k_scale=ksc, v_scale=vsc, max_q_len=max_q_len,
-                      decode_rows=decode_rows)
-
-
-@pytest.mark.pallas_interpret
-@pytest.mark.parametrize("kv", ["bf16", "int8"])
-@pytest.mark.parametrize("name", list(_BLOCKED_CASES))
-def test_ragged_blocked_kernel_matches_reference(name, kv):
-    args, kw = _blocked_batch(name, kv)
-    ref = ragged_paged_attention_reference(
-        args[0].astype(jnp.float32), *args[1:], **kw)
-    out = ragged_paged_attention(*args, **kw, impl="kernel", interpret=True)
-    assert out.dtype == args[0].dtype and out.shape == args[0].shape
-    # bf16 operands and bf16 probabilities against an fp32 reference
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref), atol=3e-2)
-    assert np.all(np.asarray(out, np.float32)[_unowned(args)] == 0.0)
-
-
-@pytest.mark.pallas_interpret
-@pytest.mark.parametrize("name", ["chunk_mid_tile_ragged_len",
-                                  "decode_rows_with_empty_slots"])
-def test_ragged_blocked_kernel_skips_pages_past_length(name):
-    """Table entries past a row's length may name anything: here a page
-    of NaNs, which one copied page (p = 0 times NaN) would leak."""
-    args, kw = _blocked_batch(name, "bf16", poison_unused_pages=True)
-    want = _dense_oracle(*args)
-    out = ragged_paged_attention(*args, **kw, impl="kernel", interpret=True)
-    np.testing.assert_allclose(np.asarray(out, np.float32), want,
-                               atol=3e-2)
-
-
-def test_ragged_tiling_clamps_to_small_shapes():
-    """Tile sizes follow the shapes: MXU-sized for the engine's chunks,
-    clamped for the tiny chunks and page tables tier-1 runs."""
-    from ray_tpu.ops.paged_attention import _ragged_tiling
-    # (n_tokens, q_per_kv, page_size, max_pages) -> (bq, nq, mrows, bkp)
-    assert _ragged_tiling(512, 4, 16, 144) == (128, 4, 512, 16)
-    assert _ragged_tiling(1, 4, 16, 144) == (1, 1, 16, 32)
-    assert _ragged_tiling(4, 1, 8, 4) == (8, 1, 16, 4)
-    assert _ragged_tiling(130, 2, 32, 3) == (128, 2, 256, 3)
-
-
-# ((n_tokens, q_per_kv, page_size, max_pages), the pool's other numbers) at
-# the shapes the benchmark's serve cells run: what _ragged_tiling gave them
-# before the latent one-token tile had a rule of its own (PR 33's tree),
-# that tile's value, and since PR 48 the chunk tile of a table that reaches
-# 8192 slots or more over slot rows narrower than the latent pool's (the
-# window block's full layers')
-_BENCHMARK_TILINGS = {
-    "mistral_one_token": (((1, 4, 16, 144), {}), (1, 1, 16, 32)),
-    "mistral_chunk": (((512, 4, 16, 144), {}), (128, 4, 512, 16)),
-    "olmoe_one_token": (((1, 1, 16, 96), {}), (1, 1, 16, 32)),
-    "olmoe_chunk": (((512, 1, 16, 96), {}), (128, 4, 128, 16)),
-    "lfm2_one_token": (((1, 4, 16, 192), {}), (1, 1, 16, 32)),
-    "lfm2_chunk": (((512, 4, 16, 192), {}), (128, 4, 512, 16)),
-    # a slot row of 640 + 512 values: 256 slots hold a block's worth (1024
-    # bought 5 %, PR 48: not kept), though the table reaches 9728 slots
-    "latent_chunk": (((512, 32, 16, 608),
-                      dict(latent_row_bytes=1280, kv_width=1152)),
-                     (32, 16, 1024, 16)),
-    # 2048 slots of 1280 B a block (was 512: (1, 1, 32, 32))
-    "latent_one_token": (((1, 32, 16, 608), dict(latent_row_bytes=1280)),
-                         (1, 1, 32, 128)),
-    # the same bytes a block where a slot is wider, and never more pages
-    # than the table has
-    "latent_one_token_fp32": (((1, 32, 16, 608),
-                               dict(latent_row_bytes=2560)), (1, 1, 32, 64)),
-    "latent_one_token_short_table": (((1, 32, 16, 40),
-                                      dict(latent_row_bytes=1280)),
-                                     (1, 1, 32, 40)),
-    # the window block (MiMo-V2-Flash), pages of 64. Full layers: 4 KV
-    # heads of 16 query heads, a table of 19456 slots: the one-token tile
-    # keeps its 512 slots (PR 48's study: no block, walk or order of the
-    # heads' chains beat it), the chunk tile takes 1024 (was 256:
-    # (64, 8, 1024, 4))
-    "mimo_full_one_token": (((1, 16, 64, 304),
-                             dict(kv_heads=4, kv_width=384)), (1, 1, 16, 8)),
-    "mimo_full_chunk": (((512, 16, 64, 304), dict(kv_heads=4, kv_width=384)),
-                        (64, 8, 1024, 16)),
-    # window layers: 8 KV heads of 8, ONE block of 256 slots a tile
-    "mimo_window_one_token": (((1, 8, 64, 8),
-                               dict(kv_heads=8, kv_width=384, window=128)),
-                              (1, 1, 16, 4)),
-    "mimo_window_chunk": (((512, 8, 64, 8),
-                           dict(kv_heads=8, kv_width=384, window=128)),
-                          (64, 8, 512, 4)),
-    # a long table alone does not grow a block whose scores would not fit:
-    # 16 KV heads of 8 query heads keep 256 slots
-    "long_table_many_heads_chunk": (((512, 8, 16, 1024),
-                                     dict(kv_heads=16, kv_width=256)),
-                                    (128, 4, 1024, 16)),
-    # ... and Mistral's heads under a long table take 1024 (2048 slots of
-    # 256 values would meet the latent block's, their scores would not fit)
-    "long_table_mistral_chunk": (((512, 4, 16, 1024),
-                                  dict(kv_heads=8, kv_width=256)),
-                                 (128, 4, 512, 64)),
-}
-
-
-@pytest.mark.parametrize("name", list(_BENCHMARK_TILINGS))
-def test_ragged_tiling_at_the_benchmarks_shapes(name):
-    """(bq, nq, mrows, bkp) pinned: the per-head forms of the short-context
-    cells keep their tiling whatever the latent one-token tile and the
-    long tables' chunk tiles take."""
-    from ray_tpu.ops.paged_attention import _ragged_tiling
-    (args, kw), want = _BENCHMARK_TILINGS[name]
-    assert _ragged_tiling(*args, **kw) == want
-    if "latent_row_bytes" not in kw:
-        return
-    # the latent form differs from the per-head one in one-token tiles only
-    assert (_ragged_tiling(*args) == want) == (args[0] > 1)
-
-
-# the latent one-token tile at Kanana-2's head shape (32 query heads on
-# ONE kv head, rows of 640 lanes, value the leading 512), small pool
-_LATENT_W, _LATENT_VW, _LATENT_HQ, _LATENT_PS = 640, 512, 32, 16
-
-
-def _latent_block(dtype) -> int:
-    """Slots of the one-token tile's KV block for a pool of ``dtype``."""
-    from ray_tpu.ops.paged_attention import _ragged_tiling
-    return _LATENT_PS * _ragged_tiling(
-        1, _LATENT_HQ, _LATENT_PS, 1 << 20,
-        _LATENT_W * jnp.dtype(dtype).itemsize)[3]
-
-
-# a case's length = blocks * (the tile's block) + walks * (16 pages' slots
-# times the walk's unroll) + slots
-_LATENT_LENGTHS = {
-    "1": (0, 0, 1), "15": (0, 0, 15), "16": (0, 0, 16), "17": (0, 0, 17),
-    "block-1": (1, 0, -1), "block": (1, 0, 0), "block+1": (1, 0, 1),
-    "2*block+5": (2, 0, 5), "walk-1": (0, 1, -1), "walk+1": (0, 1, 1),
-    "bf16:block+1": (1, 0, 1), "bf16:2*block+5": (2, 0, 5)}
-
-
-@pytest.mark.pallas_interpret
-@pytest.mark.parametrize("case", list(_LATENT_LENGTHS))
-def test_latent_one_token_tile_matches_reference(case):
-    """Three decode rows (the case's length, an EMPTY row, a row whose
-    last page is partial) through the kernel's latent one-token tile in
-    interpret mode against the gather path: lengths around a page, around
-    the tile's block (a wrong block boundary or a wrong count of the
-    unrolled page walk shows here, not in the benchmark's ``correct``),
-    around a whole turn of the walk's unrolled loop. The table's entries
-    past a row's length name a page of NaNs."""
-    from ray_tpu.ops.paged_attention import _LATENT_WALK_UNROLL
-    dtype = jnp.bfloat16 if case.startswith("bf16:") else jnp.float32
-    blocks, walks, slots = _LATENT_LENGTHS[case]
-    kv_len = blocks * _latent_block(dtype) \
-        + walks * _LATENT_WALK_UNROLL * _LATENT_PS + slots
-    ps, W = _LATENT_PS, _LATENT_W
-    lens = np.array([kv_len, 0, 21])
-    need = -(-lens // ps)
-    mp = max(int(need.max()), 2) + 1
-    P = int(need.sum()) + 2
-    pt = np.full((3, mp), P - 1, np.int32)          # the page of NaNs
-    perm = 1 + np.random.default_rng(kv_len).permutation(P - 2)
-    pt[0, :need[0]], pt[2, :need[2]] = perm[:need[0]], perm[need[0]:]
-    ks = jax.random.split(jax.random.PRNGKey(kv_len), 2)
-    pool = jax.random.normal(ks[0], (2, P, 1, ps, W), jnp.float32)
-    pool = pool.at[:, P - 1].set(jnp.nan).astype(dtype)
-    q = jax.random.normal(ks[1], (3, _LATENT_HQ, W), jnp.float32)
-    args = (pool, None, jnp.asarray(pt), jnp.arange(3, dtype=jnp.int32),
-            jnp.asarray(lens > 0, jnp.int32), jnp.asarray(lens, jnp.int32))
-    kw = dict(sm_scale=192 ** -0.5, decode_rows=3, layer=1,
-              v_width=_LATENT_VW)
-    # the reference reads the whole table: give it zeros where the NaNs are
-    want = ragged_paged_attention_reference(
-        q, jnp.nan_to_num(pool.astype(jnp.float32)), *args[1:], **kw)
-    got = ragged_paged_attention(q.astype(dtype), *args, interpret=True, **kw)
-    assert got.shape == (3, _LATENT_HQ, _LATENT_VW) and got.dtype == dtype
-    got = np.asarray(got, np.float32)
-    assert np.all(got[1] == 0.0)                    # the empty row
-    # fp32 throughout; bf16 operands and probabilities as the chip runs it
-    np.testing.assert_allclose(got, np.asarray(want),
-                               atol=1e-4 if dtype == jnp.float32 else 3e-2)
-
-
-# the window block's FULL layers (MiMo-V2-Flash): 4 KV heads of 16 query
-# heads, K rows of 256 lanes and V rows of 128, pages of 64, a table that
-# reaches 8192 slots; small pool
-_FULL_HKV, _FULL_QPK, _FULL_DK, _FULL_DV, _FULL_PS, _FULL_MP = \
-    4, 16, 256, 128, 64, 128
-
-
-def _full_tiling(n_tokens):
-    from ray_tpu.ops.paged_attention import _ragged_tiling
-    return _ragged_tiling(n_tokens, _FULL_QPK, _FULL_PS, _FULL_MP,
-                          kv_heads=_FULL_HKV, kv_width=_FULL_DK + _FULL_DV)
-
-
-def _full_layer_batch(lens, q_lens, dtype):
-    """Rows of ``lens`` cached slots (their last ``q_lens`` the query
-    tokens; an empty row still owns one slot of q) over a stacked pool of
-    two layers whose pages are dealt at random; table entries past a row's
-    length name a page of NaNs. -> (q, pool and descriptors for
-    ragged_paged_attention, the same with the NaNs zeroed for the gather
-    path)."""
-    lens, q_lens = np.asarray(lens), np.asarray(q_lens)
-    ps, mp = _FULL_PS, _FULL_MP
-    need = -(-lens // ps)
-    P = int(need.sum()) + 2
-    pt = np.full((len(lens), mp), P - 1, np.int32)      # the page of NaNs
-    perm = 1 + np.random.default_rng(int(lens.sum())).permutation(P - 2)
-    at = 0
-    for r, n in enumerate(need):
-        pt[r, :n] = perm[at:at + n]
-        at += n
-    ks = jax.random.split(jax.random.PRNGKey(int(lens.sum())), 3)
-    k = jax.random.normal(ks[0], (2, P, _FULL_HKV, ps, _FULL_DK), jnp.float32)
-    v = jax.random.normal(ks[1], (2, P, _FULL_HKV, ps, _FULL_DV), jnp.float32)
-    k, v = (a.at[:, P - 1].set(jnp.nan).astype(dtype) for a in (k, v))
-    spans = np.maximum(q_lens, 1)
-    q = jax.random.normal(ks[2], (int(spans.sum()), _FULL_HKV * _FULL_QPK,
-                                  _FULL_DK), jnp.float32).astype(dtype)
-    q_start = np.cumsum(spans) - spans
-    rows = (jnp.asarray(pt), jnp.asarray(q_start, jnp.int32),
-            jnp.asarray(q_lens, jnp.int32), jnp.asarray(lens, jnp.int32))
-    zeroed = tuple(jnp.nan_to_num(a.astype(jnp.float32)) for a in (k, v))
-    return q, (k, v) + rows, zeroed + rows
-
-
-# a one-token case's length = blocks * (the tile's block) + slots
-_FULL_ONE_TOKEN = {
-    "1": (0, 1), "63": (0, 63), "64": (0, 64), "65": (0, 65),
-    "block-1": (1, -1), "block": (1, 0), "block+1": (1, 1),
-    "2*block+5": (2, 5), "bf16:block+1": (1, 1), "bf16:3*block-70": (3, -70)}
-
-
-@pytest.mark.pallas_interpret
-@pytest.mark.parametrize("case", list(_FULL_ONE_TOKEN))
-def test_full_layer_one_token_tile_matches_reference(case):
-    """Three decode rows (the case's length, an EMPTY row, a row whose last
-    page is partial) through the per-head one-token tile at the window
-    block's full-layer shape, in interpret mode against the gather path:
-    lengths around a page of 64 and around the tile's block, whatever
-    _ragged_tiling gives it."""
-    dtype = jnp.bfloat16 if case.startswith("bf16:") else jnp.float32
-    blocks, slots = _FULL_ONE_TOKEN[case]
-    bk = _FULL_PS * _full_tiling(1)[3]
-    lens = np.array([blocks * bk + slots, 0, 150])
-    q, args, zeroed = _full_layer_batch(lens, lens > 0, dtype)
-    kw = dict(sm_scale=192 ** -0.5, decode_rows=3, layer=1)
-    want = ragged_paged_attention_reference(q.astype(jnp.float32), *zeroed,
-                                            **kw)
-    got = ragged_paged_attention(q, *args, interpret=True, **kw)
-    assert got.shape == (3, 64, _FULL_DV) and got.dtype == dtype
-    got = np.asarray(got, np.float32)
-    assert np.all(got[1] == 0.0)                    # the empty row
-    np.testing.assert_allclose(got, np.asarray(want),
-                               atol=1e-4 if dtype == jnp.float32 else 3e-2)
-
-
-# a chunk case: (blocks, slots) of the prefix the chunk's 70 tokens follow
-_FULL_CHUNK = {"0": (0, 0), "block-70": (1, -70), "block-30": (1, -30),
-               "block+1": (1, 1), "bf16:2*block-5": (2, -5)}
-
-
-@pytest.mark.pallas_interpret
-@pytest.mark.parametrize("case", list(_FULL_CHUNK))
-def test_full_layer_chunk_tile_matches_reference(case):
-    """Two chunk rows of 70 and 9 tokens (two tiles of 64 a row: a whole
-    one and a partial one, and a tile past the second row's length) behind
-    one decode row, at the window block's full-layer shape, whose table
-    reaches far enough for the long chunk block: prefixes that end a tile
-    short of the block's edge, inside it and past it, so a block boundary
-    falls before, inside and after a tile's own (masked) positions."""
-    dtype = jnp.bfloat16 if case.startswith("bf16:") else jnp.float32
-    bq, _, mrows, bkp = _full_tiling(70)
-    assert (bq, mrows, bkp * _FULL_PS) == (64, 1024, 1024)
-    blocks, slots = _FULL_CHUNK[case]
-    prefix = blocks * bkp * _FULL_PS + slots
-    q_lens = np.array([1, 70, 9])
-    lens = np.array([200, prefix + 70, 130 + 9])
-    q, args, zeroed = _full_layer_batch(lens, q_lens, dtype)
-    kw = dict(sm_scale=192 ** -0.5, decode_rows=1, max_q_len=70, layer=0)
-    want = ragged_paged_attention_reference(q.astype(jnp.float32), *zeroed,
-                                            **kw)
-    got = ragged_paged_attention(q, *args, interpret=True, **kw)
-    assert got.shape == (80, 64, _FULL_DV) and got.dtype == dtype
-    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want),
-                               atol=1e-4 if dtype == jnp.float32 else 3e-2)
 
 
 # ---------------------------------------------------------------- int8 KV

@@ -434,10 +434,17 @@ def test_timeseries_dump_and_top_two_node_e2e():
                               "metric": "node_mem_used_bytes"}, timeout=5)
         assert len(ring["points"]) >= 1
 
-        buf = io.StringIO()
-        with redirect_stdout(buf):
-            assert cli.main(["top", "--address", address]) == 0
-        out = buf.getvalue()
+        # a frame is one reading of the head's tables: wait for the one
+        # that counts both daemons (how soon is the machine's timing)
+        deadline = time.monotonic() + 60
+        while True:
+            buf = io.StringIO()
+            with redirect_stdout(buf):
+                assert cli.main(["top", "--address", address]) == 0
+            out = buf.getvalue()
+            if "nodes 2/2" in out or time.monotonic() > deadline:
+                break
+            time.sleep(0.3)
         assert "NODE" in out and "MEM" in out
         for nid in sampled_nodes:
             assert nid[:12] in out, out
@@ -447,6 +454,8 @@ def test_timeseries_dump_and_top_two_node_e2e():
         for p in nodes:
             p.terminate()
         head_proc.terminate()
+        # a daemon stops its workers first (seconds for one that a loaded
+        # machine is still starting): nothing here asserts how fast
         for p in nodes:
-            p.wait(timeout=10)
-        head_proc.wait(timeout=10)
+            p.wait(timeout=60)
+        head_proc.wait(timeout=60)

@@ -8,9 +8,11 @@ virtual CPU devices as it was leased chips — and the phase CHECKS are left
 as they are, so every rehearsal must end in exactly the failures that say
 "this was not a TPU" and in no other.
 
-(The file is named to run last: its two rehearsals boot seven clusters and
+(The file is named to run last: the two rehearsals boot seven clusters and
 are the most expensive tests in the suite — a run that hits its time limit
-should lose these before anything else.)
+should lose these before anything else. The `--chips 4` rehearsal stands in
+tests/test_zz_chip_smoke_4chips.py: a file is what a worker of the suite is
+handed, and two workers share the two rehearsals.)
 """
 
 import os
@@ -18,8 +20,6 @@ import subprocess
 import sys
 
 import pytest
-
-import chip_smoke
 
 TINY = dict(vocab_size=256, dim=64, n_heads=8, n_kv_heads=4, ffn_dim=128,
             rope_theta=10000.0)
@@ -49,7 +49,7 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def _env():
     """os.environ, spelled out: a child given no env= inherits the C-level
     environment instead, and libtpu setenv()s TPU_* placeholders there
-    once anything (tests/test_tpu_compile.py) has described a topology —
+    once anything (tests/_tpu_compile.py) has described a topology —
     "WARNING: could not determine ..." strings no daemon can parse."""
     return dict(os.environ)
 
@@ -129,33 +129,6 @@ def test_one_chip_phases_rehearsed_on_cpu(fake_chips):
     assert len(losses) == 3 and losses[-1] < losses[0]
     assert trn["checkpoint_bytes"] >= trn["runs"]["mesh"]["param_bytes"]
     assert trn["worker_pid"] != trn["driver_pid"]
-
-
-def test_four_chip_phases_rehearsed_on_cpu(fake_chips):
-    """--chips 4: the fsdp=2 x tp=2 train run against its one-device twin,
-    and tp=4 serving against tp=1, on four virtual devices."""
-    fake_chips(4)
-    trn = chip_smoke.run_train(TRAIN, seed=5, chips=4,
-                               mesh={"fsdp": 2, "tp": 2})
-    # the CPU backend keeps no allocator statistics
-    no_stats = ["train: a device reports no memory_stats()"]
-    _only_not_a_tpu(chip_smoke.check_train(trn, TRAIN, chips=4),
-                    NOT_A_TPU["train"], also=no_stats)
-    assert trn["device_count"] == 4
-    assert trn["runs"]["mesh"]["mesh"] == {"fsdp": 2, "tp": 2}
-    assert len(trn["runs"]["mesh"]["param_bytes_per_device"]) == 4
-
-    srv = chip_smoke.run_serve_tp(SERVE, seed=5, tp=4)
-    bad = chip_smoke.check_serve_tp(srv, SERVE, tp=4)
-    assert srv["sharded"]["device_count"] == 4
-    assert len(srv["sharded"]["devices"]) == 4
-    assert srv["prompts_with_identical_tokens"] == 4
-    assert all(c["max_gap"] <= chip_smoke.LOGIT_TOL
-               for c in srv["plain_check"]["tp4"])
-    _only_not_a_tpu(bad, [f"serve_tp{n}: {what}" for n in (4, 1) for what in (
-        "replica ran on platform 'cpu'", "paged attention impl 'reference'")],
-        also=[f"serve_tp4: device {i} reports no memory_stats()"
-              for i in range(4)])
 
 
 def test_no_chip_means_no_result():
