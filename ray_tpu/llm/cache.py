@@ -65,6 +65,15 @@ at once), so it never binds. No prefix cache with such a group
 (``prefix_cache_supported``): a hit at a page boundary would need the
 window's tokens before it, which are gone (keeping a cached boundary's last
 window of pages is open, ROADMAP Queue 2).
+
+PAGE PLANES, where the layers run several times (``cfg.ut_steps`` passes
+over the same weights; Ouro-2.6B is the first such block): each pass keeps
+keys and values of its own, so the page leaves' leading axis counts PLANES,
+passes x attention layers (``page_planes``), pass u's layer l at plane
+u * layers + l. A page is a page in every plane: ONE page table, one
+allocator, one copy on write and one prefix hash serve them all, and a
+prefix hit restores every plane (pages stay the only state). A token costs
+``page_planes`` times a layer's bytes, which is what sets the batch.
 """
 
 from __future__ import annotations
@@ -420,6 +429,13 @@ def page_heads(cfg: LlamaConfig, window: bool = False) -> Tuple[int, int,
     return heads // pair, cfg.qk_head_dim * pair, cfg.v_dim * pair
 
 
+def page_planes(cfg: LlamaConfig) -> int:
+    """Entries of the full page group's leading axis: one a pass of the
+    walk and attention layer (pass u, layer l at u * layers + l); the
+    attention layers themselves where they run once."""
+    return cfg.ut_steps * len(cfg.layers_of(ATTENTION))
+
+
 def slot_state_kinds(cfg: LlamaConfig) -> Tuple[str, ...]:
     """The kinds of ``cfg``'s layers that keep state per batch slot."""
     return tuple(kind for kind, leaves in SLOT_STATE.items()
@@ -441,7 +457,8 @@ def make_kv_cache(cfg: LlamaConfig, total_pages: int, page_size: int,
 
     {"k", "v"}: [n_attn, total_pages, Hkv, page_size, Dk | Dv], one entry
     of the leading axis for each ATTENTION layer (every layer, unless the
-    configuration names others: those have no pages). Dk and Dv are the
+    configuration names others: those have no pages) and, where the layers
+    run several times, each pass (``page_planes``). Dk and Dv are the
     score head's and the value head's width (``cfg.qk_head_dim``,
     ``cfg.v_dim``: both head_dim unless the configuration says otherwise;
     192 and 128 give K rows of 256 lanes and V rows of 128 under
@@ -510,14 +527,14 @@ def make_kv_cache(cfg: LlamaConfig, total_pages: int, page_size: int,
                 "(kv_lora_rank): one scale a token would cover the normed "
                 "latent and the rotary key part, two ranges in one row")
         kv = {"k": jnp.zeros(
-            (len(cfg.layers_of(ATTENTION)), total_pages, 1, page_size,
+            (page_planes(cfg), total_pages, 1, page_size,
              latent_row_width(cfg, lane_pad)), dtype or cfg.dtype)}
     else:
         def rows(width):
             return -(-width // LANES) * LANES if lane_pad else width
 
         hkv, dk, dv = page_heads(cfg)
-        shape = (len(cfg.layers_of(ATTENTION)), total_pages, hkv, page_size)
+        shape = (page_planes(cfg), total_pages, hkv, page_size)
         dk, dv = rows(dk), rows(dv)
         windowed = bool(cfg.layers_of(WINDOW))
         if kv_dtype == "int8":
@@ -573,7 +590,7 @@ def kv_cache_tag(cfg: LlamaConfig, kv_dtype: Optional[str]) -> str:
     latent pool's pages hold other values than a K/V pool's; K and V rows
     of different width, and a second page group behind a window, name
     themselves too, though a configuration with such a group has no prefix
-    cache to seed)."""
+    cache to seed; so does a pool of a plane a pass)."""
     if kv_dtype == "int8":
         return "int8"
     name = str(jnp.dtype(cfg.dtype).name)
@@ -585,6 +602,8 @@ def kv_cache_tag(cfg: LlamaConfig, kv_dtype: Optional[str]) -> str:
         # two groups: the full layers' pages, and the window layers' that
         # free behind the window
         name += f"-window{cfg.sliding_window}x{cfg.window_kv_heads}"
+    if cfg.ut_steps > 1:
+        name += f"-passes{cfg.ut_steps}"
     return name
 
 

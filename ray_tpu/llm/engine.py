@@ -67,6 +67,9 @@ admission, scheduling), rebuilt TPU-first around ONE ragged step:
     Phi-4-mini-flash, the first configuration with state a slot, a window
     group and a full group at once), stats kv_token_layer_bytes and
     shared_kv_readers say what a token costs there and how many read it;
+    where the layers run several times (a looped stack: Ouro-2.6B), each
+    pass on page planes of its own, stats kv_planes and kv_token_bytes
+    say how many planes a token's pages span and what it costs over all;
   - ONE DESCRIPTOR a dispatch: every integer a program takes (tokens,
     positions, pages, the rows' spans, the page table) is a field of one
     flat int32 buffer kept on the host a program shape (_descriptor_turns, in
@@ -185,9 +188,9 @@ import numpy as np
 
 from ray_tpu.llm.cache import (SCRATCH_PAGE, STATE_LEAVES, WINDOW_LEAVES,
                                PageAllocator, PrefixCache, SequenceState,
-                               kv_cache_tag, prefix_cache_supported,
-                               slot_state_kinds, window_first_page,
-                               window_group_pages)
+                               kv_cache_tag, page_planes,
+                               prefix_cache_supported, slot_state_kinds,
+                               window_first_page, window_group_pages)
 from ray_tpu.llm import model as M
 from ray_tpu.llm.tp import build_tp_mesh
 from ray_tpu.models.llama import ATTENTION, CROSS, LlamaConfig
@@ -654,19 +657,25 @@ class InferenceEngine:
             # group has freed nothing for it
             self.stats.update(window_pages_freed=0, page_steps_full=0,
                               page_steps_window=0, rows_inside_window=0)
+        if cfg.kv_lora_rank or cfg.layers_of(CROSS) or cfg.ut_steps > 1:
+            # what a token costs in ONE plane of the full group's pages,
+            # for each block whose pages are not a layer's own K and V
+            self.stats["kv_token_layer_bytes"] = self._kv_token_layer_bytes
         if cfg.kv_lora_rank:
-            # a latent pool: what a token costs a layer, and the row held
-            self.stats.update(
-                kv_token_layer_bytes=self._kv_token_layer_bytes,
-                kv_row_width=self._kv_row_width)
+            # a latent pool: the row as held
+            self.stats["kv_row_width"] = self._kv_row_width
         if cfg.layers_of(CROSS):
-            # pages that layers with none of their own read: what a token
-            # costs in the layer that holds them, and how many layers read
-            # that one layer's pages (itself counted)
+            # pages that layers with none of their own read: how many
+            # layers read that one layer's pages (itself counted)
+            self.stats["shared_kv_readers"] = len(cfg.layers_of(CROSS)) \
+                + bool(cfg.layers_of(ATTENTION))
+        if cfg.ut_steps > 1:
+            # a looped stack: a plane a pass and layer, and what a token
+            # costs over all of them (what sets the batch)
+            planes = page_planes(cfg)
             self.stats.update(
-                kv_token_layer_bytes=self._kv_token_layer_bytes,
-                shared_kv_readers=len(cfg.layers_of(CROSS))
-                + bool(cfg.layers_of(ATTENTION)))
+                kv_planes=planes,
+                kv_token_bytes=planes * self._kv_token_layer_bytes)
         # every span of the host loop and its wall / CPU counters; the
         # serve loop opens serve.publish and serve.wait through it too
         self.phase = PhaseClocks(self.stats).phase

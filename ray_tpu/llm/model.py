@@ -104,6 +104,20 @@ kind keeps per batch slot is declared beside it in llm/cache.py
     group at once, and the first whose full group has ONE layer that
     eight operators read.
 
+  - a LOOPED stack (Ouro-2.6B; ``cfg.ut_steps`` > 1, ``_passes``): the
+    walk is re-entered a pass by ONE scan over the passes around the
+    segments' scans (one layer body traced, not passes x layers), over the
+    SAME weights; a layer's ordinal still finds its weights, and its pages
+    are plane pass x layers + ordinal of the pool's leading axis, which the
+    operator is handed where it was handed the ordinal; the final norm runs
+    after EVERY pass and its output is what the next pass takes; the exit
+    gate (one linear map with a bias, shared by the passes) reads each
+    pass's normed stream at each row's last token, and the step programs
+    count the rows by the pass at which the exit distribution's cumulative
+    mass first reaches ``EXIT_MASS`` (``step_counters``: what an early exit
+    WOULD have done; every token runs every pass). The norm and the gate
+    between passes run under ``SCOPE_UT_EXIT``.
+
 The four recurrences take the rows of a ragged batch by ONE protocol
 (``_slot_rows``): the leading one-token rows update their slots in place
 (a Pallas kernel each, ops/ssm.py, ops/retention.py, ops/delta.py,
@@ -309,10 +323,19 @@ _EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
 _FFN_LEAVES = ("mlp_norm", "router") + _EXPERT_LEAVES
 
 
+#: a looped stack's counters: ``EXIT_COUNTER + "<pass>"`` (1-based) counts
+#: the valid rows whose exit distribution's cumulative mass first reaches
+#: EXIT_MASS at that pass
+EXIT_COUNTER, EXIT_MASS = "ut_exit_at_", 0.5
+
+
 def step_counters(cfg: LlamaConfig) -> Tuple[str, ...]:
     """Names of the counters the step programs append to their tokens:
     the routing counters and, where a layer holds a share of its experts,
-    the pairs routed to experts held elsewhere."""
+    the pairs routed to experts held elsewhere; a looped stack's rows by
+    the pass at which they would have left (``_exit_counts``)."""
+    if cfg.ut_steps > 1:
+        return tuple(f"{EXIT_COUNTER}{u + 1}" for u in range(cfg.ut_steps))
     if not cfg.n_experts:
         return ()
     return moe.COUNTERS + ((moe.COUNTER_ABSENT,) if cfg.experts_held else ())
@@ -369,6 +392,9 @@ SCOPE_SSM1, SCOPE_SSM1_PROJ, SCOPE_SSM1_UPDATE, SCOPE_SSM1_SCAN = \
     "ssm1", "ssm1_proj", "ssm1_update", "ssm1_scan"
 SCOPE_GMU, SCOPE_CROSS, SCOPE_CROSS_PROJ, SCOPE_SHARED_KV, SCOPE_DIFF = \
     "gmu", "attn_cross", "attn_cross_proj", "shared_kv", "attn_diff"
+#: ... a looped stack: what runs BETWEEN two passes of the walk (the final
+#: norm over every token, the exit gate at each row's last)
+SCOPE_UT_EXIT = "ut_exit"
 #: what one layer hands a later one that is neither a page nor a slot's
 #: state rides the walk's carry beside the pool, under this key (``_layers``
 #: puts it there and takes it out): the newest Mamba-1 layer's scan output
@@ -1261,7 +1287,8 @@ def leaves_early(cfg: LlamaConfig, max_q_len: Optional[int]) -> bool:
     return tail_start(cfg) < cfg.n_layers and max_q_len != 1
 
 
-def _layers(layers, x, kv, rows: _Rows, valid, cfg: LlamaConfig, impl):
+def _layers(layers, x, kv, rows: _Rows, valid, cfg: LlamaConfig, impl,
+            plane=None):
     """THE walk over the layers, of every block: the weights one stack per
     kind (``_stacks``), a layer finding its own by its ordinal among the
     layers of its kind: its operator's entry of the page pool or of its
@@ -1280,6 +1307,12 @@ def _layers(layers, x, kv, rows: _Rows, valid, cfg: LlamaConfig, impl):
     the layers before wrote it into the carried pool), so the tail's
     attention takes the kernel's one-token tile for every row and its
     products have R rows where they had T.
+
+    ``plane`` (a looped stack's pass, ``_passes``): the first page plane of
+    this pass. An attention layer then finds its weights at its ordinal, as
+    ever, and its pages at ``plane`` + ordinal: the operator is handed the
+    plane where it was handed the ordinal, and the kernels under it index
+    the pool's leading axis without a care for what it counts.
 
     Returns (x, kv, counters summed over the expert layers, or None); x is
     [1, T, d], or [1, R, d] (a row's last token) where the walk was cut."""
@@ -1300,8 +1333,10 @@ def _layers(layers, x, kv, rows: _Rows, valid, cfg: LlamaConfig, impl):
     def one(x, kv, counters, rows, kinds, ordinal):
         op, ffn = kinds
         stack, body = OPERATORS[op]
-        x, kv = body(at(stack, ordinal[op]), ordinal[op], x, kv, rows, cfg,
-                     impl)
+        entry = ordinal[op]       # of the operator's pages or slot state
+        if plane is not None and op == ATTENTION:
+            entry = plane + entry
+        x, kv = body(at(stack, ordinal[op]), entry, x, kv, rows, cfg, impl)
         if ffn == "moe":
             x, c = _moe_mlp(at("moe", ordinal[ffn]), experts, ordinal[ffn],
                             x, valid, cfg, impl)
@@ -1357,6 +1392,50 @@ def _layers(layers, x, kv, rows: _Rows, valid, cfg: LlamaConfig, impl):
     return x, kv, counters if cfg.n_experts else None
 
 
+def _exit_gate(params: Params, h, cfg: LlamaConfig):
+    """A looped stack's exit gate on normed rows h [.., d]: sigmoid(h . w_e
+    + b_e), float32, ONE linear map for every pass."""
+    f32 = jnp.float32
+    return jax.nn.sigmoid(h.astype(f32) @ params["exit_w"].astype(f32)
+                          + params["exit_b"].astype(f32)[0])
+
+
+def _exit_counts(gates, valid):
+    """[passes] int32: the ``valid`` rows [R] by the pass at which the exit
+    distribution's cumulative mass first reaches EXIT_MASS. With gates
+    lambda [passes, R], a row leaves at pass u with probability lambda_u
+    prod_{j<u} (1 - lambda_j), at the last pass with what is left: the
+    mass by pass u is 1 - prod_{j<=u} (1 - lambda_j), and 1 at the last."""
+    stays = jnp.cumprod(1.0 - gates, axis=0)[:-1]           # [passes-1, R]
+    left_at = jnp.sum(stays > 1.0 - EXIT_MASS, axis=0)      # 0-based pass
+    return jnp.sum((left_at[None] == jnp.arange(gates.shape[0])[:, None])
+                   & valid[None], axis=1).astype(jnp.int32)
+
+
+def _passes(params: Params, x, kv, rows: _Rows, cfg: LlamaConfig, impl):
+    """A looped stack's forward: ``cfg.ut_steps`` passes of the walk
+    (``_layers``) over the same weights, pass u on page planes u * layers
+    .. (u + 1) * layers - 1, ONE scan over the passes around the walk's own
+    scans; after EVERY pass the final norm, whose output the next pass
+    takes, and the exit gate at each row's last token. Returns (the last
+    pass's normed stream [1, T, d], kv, the gates [passes, R] float32)."""
+    per_pass = len(cfg.layers_of(ATTENTION))      # planes a pass
+    last = _last_tokens(rows, x.shape[1])
+
+    def one(carry, u):
+        x, kv = carry
+        x, kv, _ = _layers(params["layers"], x, kv, rows, None, cfg, impl,
+                           plane=u * per_pass)
+        with jax.named_scope(SCOPE_UT_EXIT):
+            x = _norm(x, params["final_norm"], cfg)
+            gate = _exit_gate(params, x[0][last], cfg)
+        return (x, kv), gate
+
+    (x, kv), gates = lax.scan(
+        one, (x, kv), jnp.arange(cfg.ut_steps, dtype=jnp.int32))
+    return x, kv, gates
+
+
 def _ragged_logits(params: Params, tokens: jax.Array,
                    token_pos: jax.Array, token_page: jax.Array,
                    token_slot: jax.Array, page_table: jax.Array,
@@ -1391,9 +1470,12 @@ def _ragged_logits(params: Params, tokens: jax.Array,
     ``counters`` is None for a dense configuration; with experts it is
     the step's routing counters (ops.moe.COUNTERS, summed over layers,
     valid tokens only: a padding token is one whose page is the scratch
-    page).
+    page); of a looped stack, the valid rows by the pass at which they
+    would have left (``_exit_counts``; a row is valid where it holds a
+    token whose page is not the scratch page).
 
-    The layers are ``_layers``'s: one walk for every block.
+    The layers are ``_layers``'s: one walk for every block (a looped
+    stack's several times: ``_passes``).
     """
     T = tokens.shape[0]
     cd = cfg.dtype
@@ -1405,9 +1487,14 @@ def _ragged_logits(params: Params, tokens: jax.Array,
     rows = _Rows(token_pos, token_state, q_start, q_len, decode_rows,
                  token_page, token_slot, page_table, kv_len, max_q_len,
                  tp_axis, token_page_win, page_table_win, page_base_win)
-    x, kv, counters = _layers(params["layers"], x, kv, rows, valid, cfg,
-                              paged_impl)
-    x = _norm(x, params["final_norm"], cfg, params.get("final_norm_b"))
+    if cfg.ut_steps > 1:
+        x, kv, gates = _passes(params, x, kv, rows, cfg, paged_impl)
+        counters = _exit_counts(gates, (q_len > 0) & (
+            token_page[_last_tokens(rows, T)] != SCRATCH_PAGE))
+    else:
+        x, kv, counters = _layers(params["layers"], x, kv, rows, valid, cfg,
+                                  paged_impl)
+        x = _norm(x, params["final_norm"], cfg, params.get("final_norm_b"))
     if leaves_early(cfg, max_q_len):
         xl = x[0]                     # [R, d] already: the walk was cut
     else:
@@ -1527,9 +1614,10 @@ def _ragged_decode_loop(params: Params, tokens: jax.Array,
     so the next block chains without host recomputation; ``newest`` is
     the last step's tokens, every slot's newest, for the NEXT program's
     ``last``: a slot whose ``tokens`` entry is negative starts from its
-    entry of this program's ``last`` (``_newest_from``). With experts,
-    tokens_out is flat [num_steps * B + n counters]: the tokens, then the
-    dispatch's counters summed over its steps (one transfer, as above).
+    entry of this program's ``last`` (``_newest_from``). With counters
+    (``step_counters``: experts, a looped stack), tokens_out is flat
+    [num_steps * B + n counters]: the tokens, then the dispatch's counters
+    summed over its steps (one transfer, as above).
 
     page_table_win [B, a window row's pages over the block] and
     page_base_win [B], where some layer is a window layer: the slots'
@@ -1565,7 +1653,7 @@ def _ragged_decode_loop(params: Params, tokens: jax.Array,
     (newest, positions, kv, seq_lens), toks_out = lax.scan(
         one, (_newest_from(tokens, last, R), positions, kv, seq_lens), None,
         length=num_steps)
-    if cfg.n_experts:
+    if step_counters(cfg):
         toks, counters = toks_out
         toks_out = jnp.concatenate([toks.reshape(-1), counters.sum(axis=0)])
     return toks_out, kv, positions, seq_lens, newest

@@ -126,7 +126,8 @@ MECHANISMS = {
     CROSS: (),
     "differential attention": ("diff_attention",),
     "layer norm": ("layer_norm",),
-    "biases": ("attn_bias",)}
+    "biases": ("attn_bias",),
+    "looped layers": ("ut_steps",)}
 #: Constants of a mechanism's arithmetic (taps, a chunk, an epsilon, the
 #: router's score) that nothing reads where the mechanism is off: set alone
 #: they do not turn it on, and a configuration that sets them is accepted as
@@ -160,7 +161,12 @@ BUILT_BESIDE = {
                         "no positions", "gated block"),
     "gated block": _ANYWHERE + (
         WINDOW, DELTA, "experts", "latent attention", "qk_norm",
-        "qk_norm_per_head", "head widths", "attn_scale", "no positions"),
+        "qk_norm_per_head", "head widths", "attn_scale", "no positions",
+        "looped layers"),
+    # the walk re-entered a pass, attention layers only: a kind that keeps
+    # state a slot, a window group, experts (their counters) and a latent
+    # pool are each refused until someone builds them beside it
+    "looped layers": (ATTENTION, "untied head", "gated block"),
     "head widths": _ANYWHERE + (
         CONV, MAMBA, WINDOW, DELTA, "experts", "qk_norm_per_head",
         "attn_scale", "no positions", "gated block"),
@@ -329,6 +335,18 @@ class LlamaConfig:
     diff_attention: bool = False
     layer_norm: bool = False         # every norm a LayerNorm with a bias
     attn_bias: bool = False          # biases on the attention projections
+    # A LOOPED stack (a universal transformer; Ouro-2.6B, model_type ouro,
+    # is the first such block): the n_layers layers run ut_steps times over
+    # the SAME weights, the final norm after EVERY pass (its output is what
+    # the next pass starts from), and each pass keeps keys and values of
+    # its own: pass u, layer l writes and reads page plane u * n_layers + l
+    # (llm/cache.py). More than one pass IMPLIES the exit gate, one linear
+    # map dim -> 1 with a bias on each pass's normed stream, shared by the
+    # passes (no switch of its own: the published family has none without
+    # it). Every token runs every pass (the published early_exit_threshold
+    # of 1); the gates are counted, nothing acts on them. 1 = every other
+    # block's program, text for text.
+    ut_steps: int = 1
 
     def __post_init__(self):
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
@@ -386,6 +404,11 @@ class LlamaConfig:
                 "rotate and whose full layers do not: layer_types "
                 "names no sliding_attention layer (rope=False is the "
                 "block with no positions at all)")
+        if self.ut_steps < 1 or (self.ut_steps > 1 and self.attn_gate):
+            raise ValueError(
+                f"ut_steps={self.ut_steps} passes over the layers: at "
+                f"least 1, and built beside a gated block's post_norms "
+                f"only (no reference computes attn_gate in a looped stack)")
         if self.norm_gate and not self.kv_lora_rank:
             raise ValueError(
                 "norm_gate gates EVERY norm of a block, and only latent "
@@ -526,13 +549,13 @@ class LlamaConfig:
         """The layers differ in kind (operator or feed-forward), or the
         block has leaves the Llama tree has no place for (latent
         attention, a shared expert, an output gate, a norm after a branch,
-        a differential layer's lambdas):
+        a differential layer's lambdas, a looped stack's exit gate):
         weights are stacked per kind and the serving step runs the
         pattern."""
         return bool(self.layer_types or self.n_dense_layers
                     or self.kv_lora_rank or self.shared_ffn_dim
                     or self.attn_gate or self.post_norms
-                    or self.diff_attention)
+                    or self.diff_attention or self.ut_steps > 1)
 
     def layers_of(self, kind: str) -> Tuple[int, ...]:
         """Indices of the layers whose operator is ``kind``."""
@@ -701,7 +724,12 @@ def _init_hybrid_params(cfg: LlamaConfig, key: jax.Array) -> Params:
     With norm_gate every norm's weight w enters as norm_gate * sigmoid(w)
     and is drawn uniform in [-0.5, 0.5] (a factor of 0.76 to 1.24 at 2:
     at w = 0 the factor is 1 and no check could see the sigmoid left out);
-    without it every norm's weight is one."""
+    without it every norm's weight is one.
+
+    A looped stack (ut_steps > 1) adds the exit gate beside final_norm:
+    "exit_w" [d] at the spread of its fan-in and "exit_b" [1], N(0, 0.02)
+    (drawn: a zero bias would hide one that is left out), both held as the
+    norms are."""
     d, L, pd = cfg.dim, cfg.n_layers, cfg.param_dtype
     hq, hd = cfg.n_heads, cfg.head_dim
     dk, dv = cfg.qk_head_dim, cfg.v_dim
@@ -951,6 +979,9 @@ def _init_hybrid_params(cfg: LlamaConfig, key: jax.Array) -> Params:
         params["lm_head"] = dense(cfg.vocab_size, d, fan_in=d)
     if cfg.layer_norm:
         params["final_norm_b"] = bias(d)
+    if cfg.ut_steps > 1:
+        params["exit_w"] = dense(d, fan_in=d)
+        params["exit_b"] = bias(1)
     return params
 
 

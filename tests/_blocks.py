@@ -150,7 +150,13 @@ BLOCKS = {
              ssm1_state=16, ssm1_dt_rank=4, sliding_window=16,
              window_rope_theta=1e4, rope=False, diff_attention=True,
              layer_norm=True, attn_bias=True),
-        "reference_phi4flash", state=("ssm1", "ssm1_conv"), window=True)}
+        "reference_phi4flash", state=("ssm1", "ssm1_conv"), window=True),
+    # a looped stack: two layers walked three times, a page plane a pass
+    # and layer (a pass / layer mix-up cannot cancel at 2 x 3)
+    "ouro": Block(
+        dict(n_layers=2, n_heads=4, n_kv_heads=4, ffn_dim=96,
+             post_norms=True, tie_embeddings=False, ut_steps=3),
+        "reference_ouro")}
 
 #: the engine every case serves on: pages of 8, chunk rows of 16, two a
 #: step, decode loops of 4

@@ -153,7 +153,8 @@ def one_row_mixed_step_cases(**configurations):
                                             **{**sizes, "rows": 1})
         assert rows == sizes["max_batch"] + 1 <= full_rows
         assert (rows == full_rows) == (widths == "brumby")
-        counters = (3 + bool(cfg.experts_held)) if cfg.n_experts else 0
+        from ray_tpu.llm.model import step_counters
+        counters = len(step_counters(cfg))
         assert jax.tree.leaves(one.out_info)[0].shape == (rows + counters,)
         assert one.as_text().count("tpu_custom_call") \
             == full.as_text().count("tpu_custom_call") > 0

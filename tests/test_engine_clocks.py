@@ -39,7 +39,8 @@ CELLS = ["reason-1chip", "reason-moe-1chip", "reason-lfm2-1chip",
          "context-kanana-1chip", "reason-granite-1chip",
          "context-brumby-1chip", "context-mimo-1chip",
          "mixed-trinity-1chip",
-         "reason-gigachat-1chip", "reason-phi4flash-1chip"]
+         "reason-gigachat-1chip", "reason-phi4flash-1chip",
+         "reason-ouro-1chip"]
 ENGINE = dict(page_size=8, total_pages=64, max_batch=4, max_seq_len=96,
               prefill_chunk=16, prefill_rows=2, decode_chunk=4)
 

@@ -35,7 +35,7 @@ WITNESS = dict(
     attn_scale=0.1, rope=False, attn_gate=True, post_norms=True,
     full_rope=False, norm_gate=2.0, ffn_clamp=5.0, ssm1_state=8,
     ssm1_expand=4, ssm1_conv=3, ssm1_dt_rank=4, diff_attention=True,
-    layer_norm=True, attn_bias=True)
+    layer_norm=True, attn_bias=True, ut_steps=3)
 
 
 def _consumers(cfg):

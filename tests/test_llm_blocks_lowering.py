@@ -69,6 +69,8 @@ ONLY_GATED = ("attn_gate", "w_og", "attn_post_norm", "mlp_post_norm")
 #: projections' biases
 ONLY_SAMBAY = ("ssm1", "w_xproj", "gmu_norm", "attn_cross", "shared_kv",
                "attn_diff", "lambda_q1", "subln", "norm_b", "'bq'")
+#: ... a looped stack's: the scope between two passes, the gate's leaves
+ONLY_LOOPED = ("ut_exit", "exit_w", "exit_b")
 #: ... and its head's scope, in a program's text (in a parameter tree it
 #: is every untied head's leaf)
 SCOPE_HEAD = "lm_head"
@@ -197,6 +199,7 @@ def test_a_block_takes_no_other_blocks_code(block):
         + (() if "conv" in cfg.layer_types else ONLY_CONV) \
         + (() if "mamba" in cfg.layer_types else ONLY_SSM) \
         + (() if sambay else ONLY_SAMBAY) \
+        + (() if cfg.ut_steps > 1 else ONLY_LOOPED) \
         + (() if sambay or "mamba" in cfg.layer_types else ONLY_DECAY) \
         + (() if "retention" in cfg.layer_types else ONLY_RETENTION) \
         + (() if "sliding_attention" in cfg.layer_types else ONLY_WINDOW)
@@ -208,7 +211,20 @@ def test_a_block_takes_no_other_blocks_code(block):
             missing = [w for w in words if w not in everything
                        and (cfg.attn_sink or w != "'sink'")]
             assert not missing, f"the {kind} block's texts lack {missing}"
-    if cfg.gated_block:
+    if cfg.ut_steps > 1:
+        # the looped stack's own words, and of a gated block's the norms
+        # after a branch alone (no output gate, no shared expert); one scan
+        # more than the walk's in each program: the passes
+        missing = [w for w in ONLY_LOOPED + ONLY_GATED[2:]
+                   if w not in everything]
+        assert not missing, f"the looped block's texts lack {missing}"
+        assert not [w for w in ONLY_GATED[:2] + ONLY_SHARED
+                    if w in everything]
+        found = traced(block)
+        assert [loops(found[f"{block}.{impl}.{program}"])["scan"]
+                for impl in ("reference", "kernel")
+                for program in ("step", "loop")] == [2, 3, 2, 3]
+    elif cfg.gated_block:
         missing = [w for w in ONLY_GATED + ONLY_SHARED
                    if w not in everything]
         assert not missing, f"the gated block's texts lack {missing}"
@@ -229,9 +245,11 @@ def test_a_block_takes_no_other_blocks_code(block):
             cfg, 16, 8, max_batch=3, lane_pad=impl == "kernel"))
         assert kv["k"].shape == kv["v"].shape
         assert kv["k"].shape[2] == cfg.n_kv_heads
-        # a layer for each attention layer: none where every layer is a
-        # retention layer
-        assert kv["k"].shape[0] == len(cfg.layers_of("full_attention"))
+        # a layer for each attention layer (a plane a pass and layer where
+        # they run several times): none where every layer is a retention
+        # layer
+        assert kv["k"].shape[0] == cfg.ut_steps * len(
+            cfg.layers_of("full_attention")) == C.page_planes(cfg)
 
 
 #: (leading layers, one period, periods) of each block: what the one walk
@@ -260,7 +278,9 @@ PATTERNS = {
     # (tests/test_llm_phi4flash.py)
     "phi4flash": ([], [("mamba1", "dense"), ("sliding_attention", "dense")]
                   * 2 + [("mamba1", "dense"), ("full_attention", "dense")], 1,
-                  [("gmu", "dense"), ("cross_attention", "dense")], 1)}
+                  [("gmu", "dense"), ("cross_attention", "dense")], 1),
+    # the pattern of ONE pass: the passes are a scan around the walk
+    "ouro": ([], [("full_attention", "dense")], 2)}
 
 
 @pytest.mark.parametrize("block", sorted(BLOCKS))
@@ -397,7 +417,11 @@ def test_every_block_has_the_file_that_collects_its_engine_cases():
         "A row's file is tests/test_llm_block_<row>.py and three lines (as "
         "tests/test_llm_block_mistral.py): a docstring, `from _block_cases "
         'import *  # noqa: F401,F403` and `BLOCK = "<row>"`; a row that '
-        "is gone takes its file with it.")
+        "is gone takes its file with it. A new row also brings its entry "
+        "of PATTERNS above (what the one walk runs for it), the words "
+        "only it may bring into a text (an ONLY_* tuple, as ONLY_LOOPED "
+        "is the looped stack's) and its plain reference under benchmark/ "
+        "(reference_<block>.py: dims_of, forward_logits, score_greedy).")
 
 
 if __name__ == "__main__":
